@@ -327,6 +327,19 @@ def collective_census(hlo_text: str) -> typing.Dict[str, int]:
     return census
 
 
+_CUSTOM_CALL_TARGET_RE = re.compile(r'custom_call_target="([^"]+)"')
+
+
+def custom_call_census(hlo_text: str) -> typing.Dict[str, int]:
+    """Count of each ``custom_call_target`` in the module — Pallas/Mosaic
+    kernels appear as ``tpu_custom_call``, so this says whether the kernel
+    path (and not a dense fallback) is what was compiled."""
+    census: typing.Dict[str, int] = {}
+    for m in _CUSTOM_CALL_TARGET_RE.finditer(hlo_text):
+        census[m.group(1)] = census.get(m.group(1), 0) + 1
+    return census
+
+
 #: one instruction line carrying a collective: the full result segment
 #: (between '=' and the op name) is captured for byte accounting
 _COLLECTIVE_LINE_RE = re.compile(

@@ -34,11 +34,13 @@ devices and audits the compiled per-mesh HLO against three contracts:
    budget-checked against the committed value AND the target chip's HBM —
    an OOM-at-32-chips regression fails CI on this CPU-only box.
 
-Environment gaps are classified, not papered over: jax 0.4.37 cannot
-compile the pipeline schedules' partial-manual ``axis_index``
-("PartitionId ... not supported"), so those strategies carry a
-``pending`` budget row and are skipped LOUDLY until an environment that
-lowers them regenerates their budgets.
+Environment gaps are classified, not papered over: on jax 0.9.0 XLA:CPU
+ABORTS the process compiling the three pipeline strategies at audit scale
+(``hlo_instruction.cc] Invalid binary instruction opcode copy`` — a C++
+CHECK no ``except`` can catch), so each pipeline strategy is lowered in a
+throwaway subprocess first (:func:`_lowering_abort`); a strategy that
+kills its probe carries a ``pending`` budget row and is skipped LOUDLY
+until an environment that lowers it regenerates its budgets.
 
 jax is imported inside functions only (package convention — the AST-only
 consumers must import cheaply).
@@ -62,14 +64,18 @@ MESH_DEVICES = 8
 #: relative drift in committed counts / bytes the audit tolerates
 DEFAULT_TOLERANCE = 0.10
 
+#: what :func:`_lowering_abort` reports for a strategy whose lowering
+#: killed its probe subprocess
+_ABORT_MARKER = "XLA aborted the lowering process"
+
 #: substrings identifying a lowering failure as an ENVIRONMENT gap (the
 #: strategy is skipped with a notice) rather than a repo regression.
-#: Deliberately NARROW: only the old-XLA partial-manual axis_index gap
-#: qualifies — a TypeError/AttributeError around shard_map now means a
-#: call site bypassed ``parallel/compat.py`` (a repo bug that must FAIL,
-#: not skip; the compat shim translates every legitimate spelling)
+#: Deliberately NARROW: the partial-manual axis_index gap of old XLA and a
+#: compiler abort of the probe subprocess — any python-level error around
+#: shard_map is a repo bug that must FAIL, not skip
 _ENV_GAP_MARKERS = (
     "PartitionId instruction is not supported",
+    _ABORT_MARKER,
 )
 
 
@@ -288,6 +294,33 @@ def classify_env_gap(exc: BaseException) -> typing.Optional[str]:
     return None
 
 
+def _lowering_abort(name: str) -> typing.Optional[str]:
+    """Lower strategy ``name`` in a throwaway subprocess and return why it
+    DIED (killed by a signal: an XLA fatal CHECK aborts the interpreter and
+    would take the whole lint / test run with it), or None when the process
+    survived — python-level failures are left to reproduce in-process,
+    where ``classify_env_gap`` sees the exception."""
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=repo,
+               XLA_FLAGS="--xla_force_host_platform_device_count="
+                         f"{MESH_DEVICES}")
+    proc = subprocess.run(
+        [sys.executable, "-c",
+         "from homebrewnlp_tpu.analysis import mesh_audit as m; "
+         f"m.lower_strategy(m.MESH_STRATEGIES[{name!r}])"],
+        cwd=repo, env=env, capture_output=True, text=True, timeout=1800)
+    if proc.returncode >= 0:
+        return None
+    fatal = next((ln for ln in proc.stderr.splitlines()
+                  if re.match(r"F\d{4} ", ln)), "no fatal line on stderr")
+    return f"{_ABORT_MARKER} (signal {-proc.returncode}): {fatal[-160:]}"
+
+
 def _strategy_params_model(strategy: MeshStrategy):
     from ..config import ModelParameter
     from ..model import Model
@@ -386,7 +419,7 @@ def lower_train_under_mesh(strategy: MeshStrategy, devices=None,
         params, model, mesh, cheap_init=False)
     trainer = Trainer(params, model, mesh)
     trainer.optimizer = info["optimizer"]
-    compiled = trainer._build_step().lower(
+    compiled = trainer._build_step(state=state_avals).lower(
         state_avals, batch_avals, rng_aval).compile()
     hlo = compiled.as_text()
     context = {
@@ -538,6 +571,13 @@ def lower_strategies(devices=None, strategies=None):
     skipped: typing.Dict[str, str] = {}
     for name in (strategies or MESH_STRATEGIES):
         strategy = MESH_STRATEGIES[name]
+        if strategy.overrides.get("mesh_shape_override", {}).get("pipe", 1) > 1:
+            # the pipeline schedules are what aborts XLA:CPU (module
+            # docstring): probe out of process before compiling in this one
+            abort = _lowering_abort(name)
+            if abort is not None:
+                skipped[name] = abort
+                continue
         out, gaps = lower_strategy(strategy, devices)
         if out:
             lowered[name] = out

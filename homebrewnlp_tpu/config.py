@@ -479,12 +479,6 @@ class ModelParameter:
         # must degrade to plain-speed serving, not crawl through rejected
         # drafts.  0 = never self-disable
         self.spec_min_accept_rate = 0.2
-        # ---- persistent compilation cache (ROADMAP item 5, first sliver) --
-        # directory for jax's persistent XLA compilation cache
-        # (jax_compilation_cache_dir): warm restarts, run_manager
-        # relaunches, and serving-child respawns skip the ~100s
-        # compile+warmup tax when the program is unchanged.  "" = off
-        self.compile_cache_dir = ""
         # ---- telemetry (docs/OBSERVABILITY.md) ----
         # master switch for TRAIN-LOOP instrumentation: step-phase histograms
         # (data-wait / dispatch / device-block), prefetcher gauges, JSONL /

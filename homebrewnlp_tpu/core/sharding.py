@@ -62,8 +62,37 @@ def build_mesh(params: ModelParameter,
         if name in shape or name == "data":
             axes.append(name)
             sizes.append(size)
-    dev_array = np.asarray(devices[: int(np.prod(sizes))]).reshape(sizes)
-    return Mesh(dev_array, tuple(axes))
+    if int(np.prod(sizes)) != ndev:
+        # devices[:prod] would leave the rest idle with nothing said
+        raise ValueError(
+            f"mesh {dict(zip(axes, sizes))} (from config mesh_shape "
+            f"{shape}) covers {int(np.prod(sizes))} of {ndev} devices — "
+            "set mesh_shape_override / tpu_size to a layout that uses "
+            "every device, or pass the devices to use")
+    return Mesh(np.asarray(devices).reshape(sizes), tuple(axes))
+
+
+def placement_report(variables: typing.Mapping[str, jax.Array],
+                     mesh: typing.Optional[Mesh]) -> str:
+    """One start-up line saying where the parameters actually are: the mesh,
+    how many of this process's devices hold parameter shards, and each
+    device's ``bytes_in_use`` as the runtime reports it (None on backends
+    without memory_stats).  Raises if a local device holds no shard — a
+    mesh that quietly uses fewer chips than the host has is a broken run,
+    not a slow one."""
+    holding = set()
+    for v in variables.values():
+        holding |= v.sharding.device_set
+    local = jax.local_devices()
+    idle = [d.id for d in local if d not in holding]
+    in_use = {d.id: (d.memory_stats() or {}).get("bytes_in_use")
+              for d in local}
+    line = (f"placement: mesh={dict(mesh.shape) if mesh is not None else None}"
+            f" parameter shards on {len(local) - len(idle)}/{len(local)} "
+            f"local devices; bytes_in_use={in_use}")
+    if idle:
+        raise RuntimeError(f"{line} — devices {idle} hold no parameters")
+    return line
 
 
 def inference_mesh(params: ModelParameter,
@@ -175,7 +204,12 @@ def place_tree(template_tree, host_tree):
     (``make_array_from_callback``)."""
     def place(template, host):
         host = np.asarray(host)
-        if not isinstance(template, jax.Array):
+        if not isinstance(template, jax.Array) or not template.committed:
+            # an uncommitted template (single-device state built with
+            # jnp.asarray) gets an uncommitted copy: a committed one lowers
+            # to a DIFFERENT module (explicit single-device sharding
+            # annotations), so a resumed run would miss the persistent
+            # compile cache its first run filled
             return jnp.asarray(host)
         assert template.shape == host.shape, (template.shape, host.shape)
         return jax.make_array_from_callback(

@@ -12,7 +12,7 @@ import typing
 
 import numpy as np
 
-from ._native import load_library
+from ._native import library_path, load_errors, load_library
 
 
 def _declare(lib: ctypes.CDLL) -> None:
@@ -42,6 +42,13 @@ def _load() -> typing.Optional[ctypes.CDLL]:
 
 def available() -> bool:
     return _load() is not None
+
+
+def describe() -> str:
+    """Which record reader this process uses, for start-up logs."""
+    if available():
+        return f"native ({os.path.basename(library_path('recordio'))})"
+    return f"python ({load_errors.get('recordio', 'native library absent')})"
 
 
 def read_records(path: str) -> typing.Iterator[bytes]:

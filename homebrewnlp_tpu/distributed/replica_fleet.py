@@ -21,11 +21,64 @@ recursively spawn its own tier.  The router's per-replica breaker handles
 the WINDOW while a replica relaunches: its port refuses connections, the
 breaker opens, dispatch skips it, and the probe recloses it once the
 relaunched replica binds.
+
+One process per chip: a TPU chip belongs to the first process that
+initialises a backend on it, so N replicas left to find devices on their
+own would all contend for every chip of the host.  On a host with TPU chips
+the fleet binds replica *i* to chip *i* through the environment its
+subprocess inherits (``TPU_VISIBLE_CHIPS`` and single-chip process bounds,
+which libtpu reads when the replica's backend starts) and refuses at
+start-up — :class:`ReplicaChipError` — when there are more replicas than
+chips.  The parent itself never starts a backend; it counts chips from the
+device nodes libtpu would open.
 """
 from __future__ import annotations
 
+import contextlib
+import glob
+import os
+import re
 import time
 import typing
+
+
+class ReplicaChipError(RuntimeError):
+    """More replicas requested than this host has TPU chips."""
+
+
+def local_tpu_chips() -> typing.List[str]:
+    """The TPU chips of this host as libtpu numbers them (the numeric device
+    nodes it opens: ``/dev/accel<N>`` or ``/dev/vfio/<N>``), sorted; empty
+    on a host without TPU chips or when the caller pinned jax to the CPU.
+    Reads the filesystem only — never a jax backend."""
+    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        return []
+    nodes = glob.glob("/dev/accel[0-9]*") or glob.glob("/dev/vfio/[0-9]*")
+    ids = [re.search(r"(\d+)$", n).group(1) for n in nodes]
+    return sorted(ids, key=int)
+
+
+@contextlib.contextmanager
+def _bound_to_chip(chip: typing.Optional[str]):
+    """Bind a subprocess started inside this block to ONE chip: the spawn
+    context copies ``os.environ`` at ``Process.start()``.  No-op for
+    ``chip=None`` (no TPU chips on the host)."""
+    if chip is None:
+        yield
+        return
+    binding = {"TPU_VISIBLE_CHIPS": chip,
+               "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+               "TPU_PROCESS_BOUNDS": "1,1,1"}
+    saved = {k: os.environ.get(k) for k in binding}
+    os.environ.update(binding)
+    try:
+        yield
+    finally:
+        for k, v in saved.items():
+            if v is None:
+                os.environ.pop(k, None)
+            else:
+                os.environ[k] = v
 
 
 def install_replica_stop():
@@ -104,6 +157,17 @@ class ReplicaFleet:
             getattr(params, "serve_child_restart_backoff_s", 0.5)
             if restart_backoff_s is None else restart_backoff_s)
         self._ctx = mp.get_context("spawn")
+        #: chip id per replica on a TPU host (None entries elsewhere)
+        self._chips: typing.List[typing.Optional[str]] = [None] * self.n
+        chips = local_tpu_chips()
+        if chips:
+            if self.n > len(chips):
+                raise ReplicaChipError(
+                    f"{self.n} replicas requested but this host has "
+                    f"{len(chips)} TPU chip(s) ({', '.join(chips)}): a chip "
+                    "belongs to one process — lower serve_replicas / "
+                    "serve_replica_classes to the chip count")
+            self._chips = list(chips[:self.n])
         self._procs: typing.List[typing.Optional[typing.Any]] = [None] * n
         self._restarts = [0] * n
         self._backoff = [self.base_backoff] * n
@@ -128,7 +192,8 @@ class ReplicaFleet:
         p = self._ctx.Process(
             target=self.target,
             args=(cfg, self.port(index), index), daemon=False)
-        p.start()
+        with _bound_to_chip(self._chips[index]):
+            p.start()
         self._procs[index] = p
         self._up_since[index] = time.monotonic()
 

@@ -237,11 +237,8 @@ _HBM_PEAK = None
 def _hbm_peak() -> float:
     global _HBM_PEAK
     if _HBM_PEAK is None:
-        try:
-            from ..utils.flops import peak_hbm_bandwidth
-            _HBM_PEAK = float(peak_hbm_bandwidth())
-        except Exception:
-            _HBM_PEAK = 0.0
+        from ..utils.flops import peak_hbm_bandwidth
+        _HBM_PEAK = float(peak_hbm_bandwidth())
     return _HBM_PEAK
 
 
@@ -1079,6 +1076,14 @@ def _process_group(handlers, interface: InterfaceWrapper,
 
 # ---- continuous-batching engine wiring (docs/SERVING.md) --------------------
 
+#: what an optional engine component (draft model, block pool) raises when
+#: THIS deployment cannot carry it — no or unreadable draft config, a
+#: geometry the component refuses.  Under an "auto" knob these drop the
+#: component; every other exception (a trace, compile or device failure) is
+#: a broken deployment and propagates
+_ENGINE_REFUSALS = (NotImplementedError, ValueError, OSError)
+
+
 def _resolve_engine(params: ModelParameter, interface):
     """Build the continuous engine's executor, or None for the batch path.
 
@@ -1087,7 +1092,8 @@ def _resolve_engine(params: ModelParameter, interface):
     through the engine when the interface can carry it — a real
     ``InterfaceWrapper`` over a text model with a streaming decode form —
     and falls back to batch-to-completion otherwise (stub interfaces, video
-    models, layers without a streaming form)."""
+    models, layers without a streaming form).  Which one was taken is on
+    ``/health`` (``engine.mode`` / ``engine.program``)."""
     mode = str(getattr(params, "serve_engine", "auto") or "auto")
     spec_mode = str(getattr(params, "spec_decode", "off") or "off")
     paging = str(getattr(params, "kv_paging", "off") or "off")
@@ -1132,7 +1138,7 @@ def _resolve_engine(params: ModelParameter, interface):
                                               "spec_min_accept_rate", 0.0)),
                 block_tokens=int(getattr(params, "kv_block_tokens", 16)),
                 pool_blocks=int(getattr(params, "kv_pool_blocks", 0) or 0))
-        except Exception as e:
+        except _ENGINE_REFUSALS as e:
             if paging == "on" and spec_mode == "draft":
                 raise RuntimeError(
                     "kv_paging=\"on\" and spec_decode=\"draft\" but the "
@@ -1182,17 +1188,25 @@ def _resolve_engine(params: ModelParameter, interface):
                 draft_tokens=int(getattr(params, "spec_draft_tokens", 4)),
                 min_accept_rate=float(getattr(params,
                                               "spec_min_accept_rate", 0.0)))
-        except Exception as e:
+        except _ENGINE_REFUSALS as e:
             if spec_mode == "draft":
                 raise RuntimeError(
                     "spec_decode=draft but speculative decoding cannot "
                     f"serve this deployment: {e!r}") from e
             print(f"speculative decoding unavailable ({e!r}); serving the "
                   "plain continuous engine")
+    # "auto" gives way to batch-to-completion on exactly two signals: the
+    # interface is not an InterfaceWrapper (test stubs), or the executor
+    # says this MODEL has no per-slot streaming form (NotImplementedError
+    # at construction).  Anything else — a trace, compile or placement
+    # failure on the device — is a broken deployment and propagates
     try:
         from .engine import EngineExecutor
+        if not hasattr(interface, "_model_for_width"):
+            raise NotImplementedError(
+                f"{type(interface).__name__} is not an InterfaceWrapper")
         return EngineExecutor(interface, slots)
-    except Exception as e:
+    except NotImplementedError as e:
         if mode == "continuous":
             raise RuntimeError(
                 "serve_engine=continuous but the engine cannot serve this "
@@ -1546,6 +1560,7 @@ def serve(params: ModelParameter, interface: InterfaceWrapper,
     # loop's published snapshot).  Git rev read once, here — never on the
     # request path.
     telemetry.register_build_info()
+    _hbm_peak()  # an unknown device kind fails here, not inside a chunk hook
     if not isolate:
         print(f"serving on :{port} (in-process)")
         return _run_http(port, list(handlers),

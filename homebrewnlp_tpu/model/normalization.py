@@ -123,8 +123,7 @@ def _norm_bwd_pallas(axes, eps, has_scale, has_shift, res, dy,
     blocks.  Statistics are recomputed from x in VMEM (cheaper than reading
     saved mu/inv from HBM)."""
     from jax.experimental import pallas as pl
-
-    from ..parallel.compat import tpu_compiler_params
+    from jax.experimental.pallas import tpu as pltpu
 
     x, scale, shift, mu, inv = res
     nd = x.ndim
@@ -172,7 +171,7 @@ def _norm_bwd_pallas(axes, eps, has_scale, has_shift, res, dy,
         out_shape=[jax.ShapeDtypeStruct((rows, h, f), x.dtype),
                    jax.ShapeDtypeStruct((nb, h, f), jnp.float32),
                    jax.ShapeDtypeStruct((nb, h, f), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x3, dy3, scale2)

@@ -177,7 +177,7 @@ def _maybe_flash_attention(args: BlockArgs, dim: Dim, qry: NamedTensor,
     else:
         from jax.sharding import PartitionSpec as P
 
-        from ..parallel.compat import shard_map
+        from jax import shard_map
         spec = P(shardlib.DATA_AXIS if shardlib.DATA_AXIS in mesh.axis_names
                  else None, None,
                  shardlib.MODEL_AXIS if shardlib.MODEL_AXIS in mesh.axis_names
@@ -285,7 +285,7 @@ def _maybe_map_mixer(args: BlockArgs, dim: Dim, bias: NamedTensor,
     else:
         from jax.sharding import PartitionSpec as P
 
-        from ..parallel.compat import shard_map
+        from jax import shard_map
         spec_v = P(shardlib.DATA_AXIS if shardlib.DATA_AXIS
                    in mesh.axis_names else None, None,
                    shardlib.MODEL_AXIS if shardlib.MODEL_AXIS

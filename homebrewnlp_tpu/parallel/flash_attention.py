@@ -232,8 +232,6 @@ def _fwd_flat(qt, kt, vt, scale, causal, block_q, block_k, interpret,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from .compat import tpu_compiler_params
-
     bh, s, d = qt.shape
     sk = kt.shape[1]
     block_q = min(block_q, s)
@@ -263,7 +261,7 @@ def _fwd_flat(qt, kt, vt, scale, causal, block_q, block_k, interpret,
         # the d128-tuned tiles overflow the compiler's 16M default by <1M at
         # d=256 (the [blk, d] operand blocks scale with d); v5e has 128M
         # physical VMEM, so raise the budget instead of shrinking tiles
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_KERNEL_VMEM_BUDGET),
         # "causal" in the name lets the FLOP counter subtract the skipped
@@ -504,12 +502,9 @@ def _fused_dqp_cap() -> int:
     gb = os.environ.get("HBNLP_FUSED_DQP_CAP_GB")
     if gb:
         return int(float(gb) * 1024 ** 3)
-    try:
-        from ..utils.flops import device_hbm_bytes
-        return max(_FUSED_DQP_CAP,
-                   int(_FUSED_DQP_HBM_FRACTION * device_hbm_bytes()))
-    except Exception:
-        return _FUSED_DQP_CAP
+    from ..utils.flops import device_hbm_bytes
+    return max(_FUSED_DQP_CAP,
+               int(_FUSED_DQP_HBM_FRACTION * device_hbm_bytes()))
 
 
 def _use_fused_bwd(bh: int, s: int, sk: int, d: int, bk: int) -> bool:
@@ -550,8 +545,6 @@ def _bwd_flat_fused(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from .compat import tpu_compiler_params
-
     bh, s, d = qt.shape
     sk = kt.shape[1]
     nq, nk = s // bq, sk // bk
@@ -591,7 +584,7 @@ def _bwd_flat_fused(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
             # 16M default scoped-vmem budget at (1024, 1024, G=2); v5e has
             # 128M physical VMEM — raise the kernel's budget instead of
             # shrinking tiles (measured faster than any fitting tile combo)
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
                 vmem_limit_bytes=_KERNEL_VMEM_BUDGET),
             # deliberately NOT named "*_causal": the split FLOP counter
@@ -626,7 +619,7 @@ def _bwd_flat_fused(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
                    jax.ShapeDtypeStruct((bh, sk, d), dv_dtype)],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_KERNEL_VMEM_BUDGET),
         name="flash_bwd_fused_causal" if causal else "flash_bwd_fused",
@@ -652,8 +645,6 @@ def _bwd_flat(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
     does a dq-partial buffer above ``_FUSED_DQP_CAP``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-
-    from .compat import tpu_compiler_params
 
     bh, s, d = qt.shape
     sk = kt.shape[1]
@@ -681,7 +672,7 @@ def _bwd_flat(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
         out_specs=pl.BlockSpec((None, bq, d), lambda i, j, kk: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, s, d), dq_dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_KERNEL_VMEM_BUDGET),
         name="flash_bwd_dq_causal" if causal else "flash_bwd_dq",
@@ -704,7 +695,7 @@ def _bwd_flat(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
                    jax.ShapeDtypeStruct((bh, sk, d), dv_dtype)],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_KERNEL_VMEM_BUDGET),
         name="flash_bwd_dkv_causal" if causal else "flash_bwd_dkv",

@@ -162,8 +162,8 @@ def _dbias_kernel(g_ref, v_ref, dbp_ref, *, block_q: int, block_k: int,
 
 
 def _compiler_params():
-    from .compat import tpu_compiler_params
-    return tpu_compiler_params(
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"),
         vmem_limit_bytes=_KERNEL_VMEM_BUDGET)
 

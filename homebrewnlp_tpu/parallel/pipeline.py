@@ -35,7 +35,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..core.dims import Dim
 from ..core.tensor import NamedTensor, nt
-from .compat import shard_map
+from jax import shard_map
 
 AXIS = "pipe"
 

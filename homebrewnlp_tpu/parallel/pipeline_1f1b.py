@@ -34,7 +34,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..core.dims import Dim
 from ..core.tensor import NamedTensor, nt
 from .pipeline import AXIS, _stack_stages, _stage_layout
-from .compat import shard_map
+from jax import shard_map
 
 # kinds, mbs, chunks: [ticks, S] int32 tables
 Schedule = typing.Tuple[np.ndarray, np.ndarray, np.ndarray]

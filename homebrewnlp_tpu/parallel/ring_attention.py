@@ -58,7 +58,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .compat import shard_map
+from jax import shard_map
 
 _NEG_INF = -1e30
 

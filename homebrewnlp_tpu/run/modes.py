@@ -71,14 +71,17 @@ def _load_model(params: ModelParameter, batch_size: int = 1):
         print(f"loaded checkpoint at step {step}")
     else:
         print("no checkpoint found — sampling from random init")
+    from ..utils.flops import describe_devices
+    print(describe_devices(), flush=True)
+    mesh = None
     if len(jax.devices()) > 1:
         mesh = shardlib.inference_mesh(params)
         variables = shardlib.shard_params(params, variables,
                                           model.param_dims, mesh)
-        print(f"serving mesh: {dict(mesh.shape)}")
-        return params, model, variables, mesh
-    return params, model, {k: jax.numpy.asarray(v)
-                           for k, v in variables.items()}, None
+    else:
+        variables = {k: jax.numpy.asarray(v) for k, v in variables.items()}
+    print(shardlib.placement_report(variables, mesh), flush=True)
+    return params, model, variables, mesh
 
 
 def train_mode(params: ModelParameter, args):

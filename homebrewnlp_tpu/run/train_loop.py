@@ -267,7 +267,10 @@ def train(params: ModelParameter, train_steps: typing.Optional[int] = None,
     retry_mod.set_default_policy(retry_mod.RetryPolicy(
         max_attempts=params.storage_retry_attempts,
         base_delay=params.storage_retry_base_delay))
+    t_entry = time.monotonic()
     devices = jax.devices()
+    from ..utils import flops as flops_mod
+    print(flops_mod.describe_devices(), flush=True)
     mesh = shardlib.build_mesh(params) if len(devices) > 1 else None
     model = Model(params)
     trainer = Trainer(params, model, mesh=mesh)
@@ -335,6 +338,9 @@ def train(params: ModelParameter, train_steps: typing.Optional[int] = None,
         flight.record("restore", step=int(restored[2]))
 
     data = make_dataset(params, mesh=mesh)
+    if not params.use_video:
+        from ..data import native_recordio
+        print(f"record reader: {native_recordio.describe()}", flush=True)
     first_batch = next(iter(data))
     state = trainer.init_state(first_batch)
     if restored:
@@ -349,9 +355,11 @@ def train(params: ModelParameter, train_steps: typing.Optional[int] = None,
         state = TrainState(
             shardlib.place_tree(state.variables, variables),
             shardlib.place_tree(state.opt_state, opt_state),
-            jnp.asarray(step, jnp.int32))
+            shardlib.place_tree(state.step, np.asarray(step, np.int32)))
         print(f"restored checkpoint at step {step}")
+    print(shardlib.placement_report(state.variables, mesh), flush=True)
 
+    compile_s = 0.0
     if is_chief:
         # analyze_model reads shapes only — no device_get (which would also
         # fail on non-fully-addressable arrays in multi-host model sharding)
@@ -362,11 +370,24 @@ def train(params: ModelParameter, train_steps: typing.Optional[int] = None,
             append_runs_log(params, 0, data_slice_geometry(mesh)[1])
         if params.save_graph:
             # reference saved the TF graph_def with checkpoints
-            # (run.py:171); the XLA-native artifact is the lowered step
+            # (run.py:171); the XLA-native artifacts are the lowered step
+            # and the executable XLA makes of it.  The compile is not paid
+            # twice: the step's own jit finds it in the persistent cache
+            lowered = trainer.lowered(state, first_batch)
             path = fs.join(params.model_path, "train_step.stablehlo.txt")
             with fs.open_(path, "w") as f:
-                f.write(trainer.lowered(state, first_batch).as_text())
-            print(f"save_graph: lowered train step written to {path}")
+                f.write(lowered.as_text())
+            t0 = time.monotonic()
+            hlo = lowered.compile().as_text()
+            compile_s = time.monotonic() - t0
+            hlo_path = fs.join(params.model_path, "train_step.hlo.txt")
+            with fs.open_(hlo_path, "w") as f:
+                f.write(hlo)
+            from ..analysis import hlo_lint
+            print(f"save_graph: lowered train step written to {path}, "
+                  f"compiled ({compile_s:.1f}s) to {hlo_path}; kernels in "
+                  f"the executable: {hlo_lint.custom_call_census(hlo)}",
+                  flush=True)
 
     # ---- elastic membership (docs/DISTRIBUTED.md 'Elasticity'): a daemon
     # thread heartbeats a lease in the coordination KV and scans its peers;
@@ -541,7 +562,6 @@ def train(params: ModelParameter, train_steps: typing.Optional[int] = None,
                 "hbnlp_train_tokens_total",
                 "tokens fed to the device (rate() of this is tokens/sec)")
             try:
-                from ..utils import flops as flops_mod
                 micro = {k: v[0] if params.macro_batching > 1 else v
                          for k, v in first_batch.items() if v is not None}
                 fwd = flops_mod.forward_flops(
@@ -672,6 +692,7 @@ def train(params: ModelParameter, train_steps: typing.Optional[int] = None,
             return shutdown.requested
 
     mono = time.monotonic
+    setup_s = mono() - t_entry - compile_s
     try:
         batch = first_batch
         data_it = iter(data)
@@ -724,12 +745,15 @@ def train(params: ModelParameter, train_steps: typing.Optional[int] = None,
             # signal is the rank that never ARRIVED at the step its peers
             # already entered — the classic barrier-arrival skew
             progress_ref[0] = step_now + params.macro_batching
-            if phases is None:
-                state, metrics = trainer.step(state, batch)
-            else:
-                t0 = mono()
-                state, metrics = trainer.step(state, batch)
-                t1 = mono()
+            t0 = mono()
+            state, metrics = trainer.step(state, batch)
+            t1 = mono()
+            if it_count == 1:
+                # the first call traces and compiles (or reloads the
+                # executable from the persistent cache) before it
+                # dispatches; later calls only dispatch
+                compile_s += t1 - t0
+            if phases is not None:
                 phases.dispatch.rec(t0, t1 - t0)
                 # attributing device time requires waiting for the step to
                 # finish: one device sync per step, the same documented cost
@@ -992,6 +1016,7 @@ def train(params: ModelParameter, train_steps: typing.Optional[int] = None,
               "elastic controller resumes the survivors from the freshest "
               "complete checkpoint", flush=True)
     return {"steps": steps_done, "wall_s": wall,
+            "setup_s": setup_s, "compile_s": compile_s,
             "final_step": int(state.step),
             "preempted": stopped,
             "membership_change": elastic_agent.event if membership else None,

@@ -5,11 +5,13 @@ Prometheus build-info convention: a constant gauge whose LABELS carry the
 identity, so any scraped series (and any ``telemetry.jsonl`` line) joins
 back to the exact build that produced it.
 
-Stdlib-only like the rest of the package: jax is consulted ONLY when the
-importing process already loaded it (the HTTP child never does — it
-reports the jax version from package metadata and leaves backend fields
-``unknown``).  The git rev is read once per process at first call, never
-on a hot path.
+Stdlib-only like the rest of the package, and it never STARTS a jax
+backend: the backend fields are read only in a process that has already
+initialised one (the trainer, a serving device loop).  A process that
+merely imported jax — the router parent, the HTTP child — reports them
+``unknown``: a chip belongs to one process, and asking
+``jax.devices()`` here would take it from the replica that needs it.
+The git rev is read once per process at first call, never on a hot path.
 """
 from __future__ import annotations
 
@@ -49,19 +51,18 @@ def _jax_version() -> str:
 
 def build_info() -> typing.Dict[str, str]:
     """``{git_rev, jax_version, backend, device_kind}`` — computed once per
-    process and cached.  Backend fields stay ``unknown`` unless jax is
-    ALREADY imported (never triggers a backend init of its own)."""
+    process and cached.  Backend fields stay ``unknown`` unless this
+    process has ALREADY initialised a jax backend (never triggers an init
+    of its own — see the module docstring)."""
     global _BUILD_INFO
     if _BUILD_INFO is not None:
         return _BUILD_INFO
     backend = device_kind = "unknown"
-    mod = sys.modules.get("jax")
-    if mod is not None:
-        try:
-            backend = mod.default_backend()
-            device_kind = getattr(mod.devices()[0], "device_kind", "unknown")
-        except Exception:
-            pass
+    bridge = sys.modules.get("jax._src.xla_bridge")
+    if bridge is not None and bridge.backends_are_initialized():
+        mod = sys.modules["jax"]
+        backend = mod.default_backend()
+        device_kind = mod.devices()[0].device_kind
     _BUILD_INFO = {"git_rev": _git_rev(), "jax_version": _jax_version(),
                    "backend": backend, "device_kind": device_kind}
     return _BUILD_INFO

@@ -130,8 +130,14 @@ class Trainer:
         else:
             variables = {k: jnp.asarray(v) for k, v in variables.items()}
         opt_state = self.optimizer.init(variables)
-        return TrainState(variables, opt_state,
-                          jnp.asarray(self.params.current_step, jnp.int32))
+        step = jnp.asarray(self.params.current_step, jnp.int32)
+        if self.mesh is not None:
+            # committed and replicated like every other leaf: the step
+            # comes back on the mesh, and an uncommitted one going in would
+            # make the second call a different (re-compiled) program
+            step = jax.device_put(step, jax.sharding.NamedSharding(
+                self.mesh, jax.sharding.PartitionSpec()))
+        return TrainState(variables, opt_state, step)
 
     # -- one micro step ----------------------------------------------------
     def _1f1b_exclusion(self) -> typing.Optional[str]:
@@ -280,7 +286,6 @@ class Trainer:
         backward compute.  mean-of-shard-means == the global mean exactly
         in real arithmetic (equal shard sizes); floats differ only in
         reduction order (documented tolerance, tests/elastic_test.py)."""
-        from ..parallel import compat
         from jax.sharding import PartitionSpec as P
 
         p = self.params
@@ -340,7 +345,7 @@ class Trainer:
             metrics = {k: packed[i] for i, k in enumerate(names)}
             return {k: out[k] for k in grads}, metrics
 
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(), P(shardlib.DATA_AXIS), P(shardlib.DATA_AXIS)),
             out_specs=(P(), P()),
@@ -465,7 +470,14 @@ class Trainer:
         return (new_vars, new_opt, step + 1), metrics
 
     # -- the jitted step ---------------------------------------------------
-    def _build_step(self, donate: bool = True):
+    def _build_step(self, donate: bool = True,
+                    state: typing.Optional[TrainState] = None):
+        """``state`` (arrays, or avals carrying shardings): under a mesh the
+        new state is pinned to come back laid out exactly as this one went
+        in.  Left to the compiler, reduced-shape optimizer slots (SM3's
+        per-dim buckets) return sharded over 'model' although they went in
+        replicated; the second step then sees new input shardings and the
+        whole step compiles a second time."""
         p = self.params
         self._resolve_grad_allreduce()
 
@@ -506,16 +518,23 @@ class Trainer:
                     (state.variables, state.opt_state, state.step))
             return TrainState(variables, opt_state, step), metrics
 
+        out_shardings = None
+        if self.mesh is not None and state is not None:
+            named = jax.sharding.NamedSharding
+            out_shardings = (jax.tree_util.tree_map(
+                lambda x: x.sharding if isinstance(x.sharding, named)
+                else None, state), None)
         # ``donate=False`` compiles the identical step without donation —
         # the HLO donation audit's negative control (analysis/entry_points)
-        return jax.jit(step_fn, donate_argnums=(0,) if donate else ())
+        return jax.jit(step_fn, donate_argnums=(0,) if donate else (),
+                       out_shardings=out_shardings)
 
     def lowered(self, state: TrainState, batch: typing.Dict[str, jax.Array]):
         """Lowered (StableHLO) train step for ``save_graph`` dumps — the
         TPU-native analogue of the reference's save_graph_def
         (src/run/run.py:171)."""
         if self._step_fn is None:
-            self._step_fn = self._build_step()
+            self._step_fn = self._build_step(state=state)
         if self.mesh is not None:
             batch = shardlib.shard_batch(self.params, batch, self.mesh)
         return self._step_fn.lower(state, batch, jax.random.PRNGKey(0))
@@ -547,7 +566,7 @@ class Trainer:
     def step(self, state: TrainState, batch: typing.Dict[str, jax.Array],
              rng: typing.Optional[jax.Array] = None):
         if self._step_fn is None:
-            self._step_fn = self._build_step()
+            self._step_fn = self._build_step(state=state)
             self._rng_counter = 0
         if rng is None:
             # host counter offset by the restored step, never a device sync

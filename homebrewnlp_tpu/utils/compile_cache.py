@@ -1,160 +1,62 @@
-"""Persistent XLA compilation cache wiring (ROADMAP item 5, first sliver).
+"""Persistent XLA compilation cache placement.
 
-Every BENCH round and every serving relaunch pays ~90s setup + ~100s
-compile+warmup before the first useful step.  jax ships a persistent
-compilation cache (``jax_compilation_cache_dir``) that serves an unchanged
-program's compile from disk; this module turns the config knob
-``compile_cache_dir`` into that configuration, applied once per process
-BEFORE the first jit compile (main.py does it for every run mode, the
-serving bench for its spawned servers).
+A flagship process pays ~100 s of train-step compile (and the serving
+engine several chunk programs) before its first useful step; jax's
+persistent compilation cache serves an unchanged program from disk on the
+next launch (resume after preemption, serving relaunch, the legs of
+``chip_smoke.py``).  ``main.py`` installs it for every run mode, before the
+first jit compile.
 
-The two threshold knobs are forced permissive: jax's defaults only persist
-compiles slower than ~1s / larger than a floor, which silently skips
-exactly the many-small-programs profile of the stepped decode path (dozens
-of chunk-step variants, each fast to compile but slow in aggregate).
+Placement is decided from OUTSIDE the program:
 
-tests/continuous_batching_test.py asserts a second in-process build of the
-same program HITS the cache (entries appear on the first compile, none are
-added by the second after ``jax.clear_caches()``).
+* ``JAX_COMPILATION_CACHE_DIR`` set — jax reads the variable itself; this
+  module sets no directory in code, so whoever launched the process (a
+  measurement harness, a deployment) owns where the cache lives and
+  whether it survives the machine.
+* unset — one fixed, git-ignored directory inside the checkout
+  (:data:`DEFAULT_DIR`).  Fixed because the path is part of what makes a
+  later process find the entries; inside the checkout so a relaunch from
+  the same tree is warm with no configuration.
 
-**Reload-broken environments** (docs/PERFORMANCE.md 'Round 11'): on
-jax-0.4.37's CPU backend, DESERIALIZING a cached train-step executable on a
-warm relaunch corrupts the heap (SIGSEGV/SIGABRT) — the cold run that
-POPULATES the cache works, so the knob looks fine until the restart it
-exists to speed up dies.  ``bench.py --compile-probe`` classifies this
-structurally and, when probing a persistent cache dir, records the verdict
-into ``<cache_dir>/compile_probe_verdict.json``
-(:func:`record_reload_verdict`).  ``install_compile_cache`` reads that
-verdict: a matching backend + jax version marked broken REFUSES to enable
-the cache with a loud structured warning instead of letting the warm
-relaunch crash — graceful degradation to cold compiles, not a mystery
-segfault (tests/spec_decode_test.py pins the refusal).
+The two threshold knobs are forced permissive either way: jax's defaults
+only persist compiles slower than ~1s / larger than a floor, which silently
+skips exactly the many-small-programs profile of the stepped decode path.
 """
 from __future__ import annotations
 
-import json
 import os
-import typing
-import warnings
 
-#: the probe's verdict marker inside a persistent cache dir
-VERDICT_FILE = "compile_probe_verdict.json"
+#: the variable jax itself reads for ``jax_compilation_cache_dir``
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+
+#: where the cache lives when :data:`ENV_VAR` is unset (listed in .gitignore)
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 
-def _env_fingerprint() -> typing.Tuple[str, str]:
-    """(backend, jax_version) WITHOUT initialising jax's backends — the
-    install runs before ``jax.distributed`` bootstrap on multi-host, where
-    touching ``jax.default_backend()`` would bind the wrong topology."""
+def install_compile_cache() -> str:
+    """Turn the persistent cache on for this process and return its
+    directory (the environment's when :data:`ENV_VAR` is set, else
+    :data:`DEFAULT_DIR`).  Idempotent; call before the first jit compile."""
     import jax
-    backend = (os.environ.get("JAX_PLATFORMS") or "default").split(",")[0]
-    return backend or "default", jax.__version__
-
-
-def record_reload_verdict(cache_dir: str, broken: bool,
-                          evidence: str = "") -> str:
-    """Write the compile-probe's warm-reload verdict into ``cache_dir``.
-
-    ``bench.py --compile-probe`` calls this after classifying the warm
-    relaunch; operators arm the guard by probing the deployment's actual
-    ``compile_cache_dir`` once.  Returns the verdict path."""
-    backend, jax_version = _env_fingerprint()
-    os.makedirs(cache_dir, exist_ok=True)
-    path = os.path.join(cache_dir, VERDICT_FILE)
-    with open(path, "w") as f:
-        json.dump({"backend": backend, "jax_version": jax_version,
-                   "reload_broken": bool(broken), "evidence": evidence}, f,
-                  indent=1)
-    return path
-
-
-def read_reload_verdict(cache_dir: str) -> typing.Optional[dict]:
-    """The recorded verdict, or None (no probe ran / unreadable file —
-    unreadable is treated as no evidence, never as broken)."""
-    try:
-        with open(os.path.join(cache_dir, VERDICT_FILE)) as f:
-            out = json.load(f)
-        return out if isinstance(out, dict) else None
-    except (OSError, ValueError):
-        return None
-
-
-def _reload_refusal(path: str) -> typing.Optional[dict]:
-    """The verdict blocking installation for THIS environment, if any: the
-    probe must have marked reload broken for the same jax version (an
-    upgrade invalidates the classification — re-probe) and a COMPATIBLE
-    backend.  "default" (JAX_PLATFORMS unset) matches any recorded
-    backend and vice versa: the fingerprint is read without initialising
-    jax's backends, so an unset variable is "unknown", and refusing on
-    unknown is the safe direction — the cost of a false refusal is cold
-    compiles, the cost of a false install is the warm-relaunch segfault
-    this guard exists for."""
-    verdict = read_reload_verdict(path)
-    if not verdict or not verdict.get("reload_broken"):
-        return None
-    backend, jax_version = _env_fingerprint()
-    if verdict.get("jax_version") != jax_version:
-        return None
-    recorded = verdict.get("backend") or "default"
-    if recorded != backend and "default" not in (recorded, backend):
-        return None
-    return verdict
-
-
-def install_compile_cache(params_or_dir) -> typing.Optional[str]:
-    """Point jax's persistent compilation cache at the configured directory.
-
-    Accepts a ``ModelParameter`` (reads ``compile_cache_dir``) or a path
-    string; returns the installed path, or None when the knob is off — or
-    when ``bench.py --compile-probe`` has classified this backend + jax
-    version as RELOAD-BROKEN for this cache dir (loud structured warning;
-    the warm relaunch would segfault deserializing the cache, so cold
-    compiles are the fast path that actually finishes).  Idempotent — safe
-    to call from every entry point that might run first.
-    """
-    path = getattr(params_or_dir, "compile_cache_dir", params_or_dir)
-    if not path:
-        return None
-    path = os.path.abspath(os.path.expanduser(str(path)))
-    os.makedirs(path, exist_ok=True)
-    # the probe's own subprocesses must BYPASS the refusal: re-probing an
-    # armed dir has to actually exercise the cache to find out whether a
-    # jax upgrade fixed the reload — refusing inside the probe would
-    # measure two uncached runs and record a vacuous "healthy"
-    ignore = os.environ.get("HBNLP_COMPILE_CACHE_IGNORE_VERDICT") == "1"
-    refusal = None if ignore else _reload_refusal(path)
-    if refusal is not None:
-        msg = ("compile_cache_dir REFUSED: bench.py --compile-probe "
-               f"classified backend={refusal.get('backend')!r} "
-               f"jax={refusal.get('jax_version')!r} as reload-broken for "
-               f"{path!r} ({refusal.get('evidence') or 'no evidence text'}); "
-               "serving cold compiles instead of crashing the warm "
-               "relaunch.  Re-probe after a jax upgrade to re-enable.")
-        print("WARNING: " + msg, flush=True)
-        warnings.warn(msg)
-        return None
-    import jax
-    # persist EVERYTHING: the default min-compile-time (~1s) skips the
-    # decode chunk steps this exists for
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    try:
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except AttributeError:  # knob renamed across jax versions — best effort
-        pass
-    jax.config.update("jax_compilation_cache_dir", path)
-    # ALSO reset the cache object: jax initialises it lazily on the first
-    # compile and never re-reads the config after — without the reset, any
-    # earlier jit in the process (warmup, another mode) would leave the
-    # knob silently dead for the rest of the process
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    path = os.environ.get(ENV_VAR)
+    if path:
+        return path
+    os.makedirs(DEFAULT_DIR, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
+    # jax initialises its cache object lazily on the first compile and
+    # never re-reads the config after: without the reset, a jit that ran
+    # earlier in the process would leave the directory silently unused
     _reset_cache_object()
-    return path
+    return DEFAULT_DIR
 
 
 def _reset_cache_object() -> None:
-    try:
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except (ImportError, AttributeError):
-        pass
+    from jax._src import compilation_cache as _cc
+    _cc.reset_cache()
 
 
 def uninstall_compile_cache() -> None:

@@ -46,9 +46,7 @@ def main():
     # configured before the first jit compile: warm restarts (run_manager
     # relaunches, serving respawns) then skip the compile+warmup tax
     from homebrewnlp_tpu.utils.compile_cache import install_compile_cache
-    cache_dir = install_compile_cache(params)
-    if cache_dir:
-        print(f"persistent compilation cache: {cache_dir}")
+    print(f"persistent compilation cache: {install_compile_cache()}")
     # storage retry knobs apply to EVERY run mode (serving restores through
     # the same flaky bucket as training; train() re-installs identically)
     retry.set_default_policy(retry.RetryPolicy(

@@ -82,7 +82,7 @@ def lower_target(config_path: str, topology: str, hbm_key: str = "v5p",
     trainer = Trainer(params, model, mesh)
 
     # memory-aware kernel/stash heuristics must budget against the TARGET
-    # chips, not the local client (a CPU/tunnel process lowering for a v5p
+    # chips, not the local client (a CPU process lowering for a v5p
     # pod would otherwise bake a 16GiB-derived dq-partial cap into a 95GiB
     # chip's executable).  resolve_stash reads the mesh's own devices; the
     # fused-backward cap has no device argument, so pin it via its env
@@ -104,7 +104,7 @@ def lower_target(config_path: str, topology: str, hbm_key: str = "v5p",
         n_params = info["n_params"]
         trainer.optimizer = info["optimizer"]
 
-        step_fn = trainer._build_step()
+        step_fn = trainer._build_step(state=state_avals)
         t_trace = time.monotonic()
         lowered = step_fn.lower(state_avals, batch_avals, rng_aval)
         t_lower = time.monotonic()

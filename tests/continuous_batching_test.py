@@ -426,24 +426,26 @@ def engine_rest_roundtrip_test():
 
 # ------------------------------------------------- compile-cache persistence
 
-def compile_cache_second_build_hits_test(tmp_path):
-    """compile_cache_dir wires jax's persistent compilation cache: the
-    first build writes entries, and a second in-process build of the same
-    program (after clearing jax's in-memory caches) adds NO new entries —
-    it was served from disk."""
+def compile_cache_default_dir_second_build_hits_test(tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR unset: the cache lives at the one fixed
+    in-checkout path (redirected here), the first build writes entries, and
+    a second in-process build of the same program (after clearing jax's
+    in-memory caches) adds NO new entries — it was served from disk."""
     import glob
     import os
     import jax
     import jax.numpy as jnp
-    from homebrewnlp_tpu.utils.compile_cache import (install_compile_cache,
-                                                     uninstall_compile_cache)
+    from homebrewnlp_tpu.utils import compile_cache as cc
 
-    class _P:
-        compile_cache_dir = str(tmp_path / "xla-cache")
-
+    assert os.path.basename(cc.DEFAULT_DIR) == ".jax_cache"
+    assert os.path.isdir(os.path.join(os.path.dirname(cc.DEFAULT_DIR),
+                                      "homebrewnlp_tpu"))
+    monkeypatch.delenv(cc.ENV_VAR, raising=False)
+    monkeypatch.setattr(cc, "DEFAULT_DIR", str(tmp_path / "xla-cache"))
     try:
-        path = install_compile_cache(_P())
+        path = cc.install_compile_cache()
         assert path == str(tmp_path / "xla-cache") and os.path.isdir(path)
+        assert jax.config.jax_compilation_cache_dir == path
 
         def entries():
             # only the named program under test: trivial helper jits
@@ -466,57 +468,33 @@ def compile_cache_second_build_hits_test(tmp_path):
         build()(jnp.ones((32, 32))).block_until_ready()
         assert entries() == first, "second build missed the disk cache"
     finally:
-        uninstall_compile_cache()
-    # off by default: blank knob is a no-op
-    class _Off:
-        compile_cache_dir = ""
-    assert install_compile_cache(_Off()) is None
-
-
-def compile_cache_reload_broken_refusal_test(tmp_path):
-    """A reload-broken probe verdict (the jax-0.4.37 CPU warm-cache
-    segfault, classified by ``bench.py --compile-probe``) makes
-    install_compile_cache REFUSE the persistent cache for that backend +
-    jax version with a loud structured warning — graceful degradation to
-    cold compiles, not a warm-relaunch crash.  A different jax version or
-    a healthy re-probe re-enables it."""
-    import warnings as warnings_mod
-    from homebrewnlp_tpu.utils import compile_cache as cc
-
-    cache = str(tmp_path / "xla-cache")
-
-    class _P:
-        compile_cache_dir = cache
-
-    try:
-        # no verdict: installs normally
-        assert cc.install_compile_cache(_P()) == cache
         cc.uninstall_compile_cache()
-        # a broken verdict for THIS env refuses, loudly
-        path = cc.record_reload_verdict(cache, True,
-                                        evidence="injected by test")
-        assert path.endswith(cc.VERDICT_FILE)
-        with warnings_mod.catch_warnings(record=True) as caught:
-            warnings_mod.simplefilter("always")
-            assert cc.install_compile_cache(_P()) is None
-        assert any("reload-broken" in str(w.message) for w in caught)
-        # verdicts are env-scoped: a different jax version installs fine
-        # (an upgrade invalidates the classification — re-probe)
-        import json as json_mod
-        with open(path) as f:
-            verdict = json_mod.load(f)
-        verdict["jax_version"] = "999.0.0"
-        with open(path, "w") as f:
-            json_mod.dump(verdict, f)
-        assert cc.install_compile_cache(_P()) == cache
-        cc.uninstall_compile_cache()
-        # a healthy re-probe clears the refusal
-        cc.record_reload_verdict(cache, True, evidence="stale")
-        cc.record_reload_verdict(cache, False, evidence="healthy re-probe")
-        assert cc.install_compile_cache(_P()) == cache
-        # unreadable verdict = no evidence, never "broken"
-        with open(path, "w") as f:
-            f.write("{not json")
-        assert cc.install_compile_cache(_P()) == cache
-    finally:
-        cc.uninstall_compile_cache()
+
+
+def compile_cache_env_dir_wins_test(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: jax reads it itself, the installer
+    sets NO directory in code (the in-checkout default is never created),
+    and entries land where the environment said."""
+    import os
+    import subprocess
+    import sys
+
+    env_dir = tmp_path / "from-env"
+    default = tmp_path / "in-checkout-default"
+    code = (
+        "import os, jax, jax.numpy as jnp\n"
+        "from homebrewnlp_tpu.utils import compile_cache as cc\n"
+        f"cc.DEFAULT_DIR = {str(default)!r}\n"
+        "path = cc.install_compile_cache()\n"
+        "assert path == os.environ[cc.ENV_VAR], path\n"
+        "assert jax.config.jax_compilation_cache_dir == path\n"
+        "jax.jit(lambda x: (x @ x.T).sum())(jnp.ones((8, 8)))"
+        ".block_until_ready()\n"
+        "assert any(f.endswith('-cache') for f in os.listdir(path))\n"
+        "assert not os.path.exists(cc.DEFAULT_DIR)\n")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(env_dir),
+               JAX_PLATFORMS="cpu", PYTHONPATH=repo)
+    res = subprocess.run([sys.executable, "-c", code], env=env, cwd=repo,
+                         capture_output=True, text=True, timeout=120)
+    assert res.returncode == 0, res.stderr[-2000:]
