@@ -1,0 +1,9 @@
+"""The flash-attention kernels' share of their roofline, percent."""
+from ..lib import readers
+
+LAYER = "L4_kernels"
+MOVES = "train_tokens_per_sec_chip"
+
+
+def read(run):
+    return readers.kernel_roofline(run, r"^flash_")

@@ -1,0 +1,10 @@
+"""Device time of the flash-attention Pallas kernels over the device's busy
+time, percent."""
+from ..lib import readers
+
+LAYER = "L4_kernels"
+MOVES = "train_tokens_per_sec_chip"
+
+
+def read(run):
+    return readers.kernel_time_share(run, r"^flash_")

@@ -1,0 +1,15 @@
+"""Required train FLOPs per token (3 x forward from ``roofline/costs.py``,
+recomputation not credited) times the measured tokens/s/chip over the chip's
+bf16 peak, percent."""
+from ..roofline import costs
+
+LAYER = "L3_model_graph"
+MOVES = "train_tokens_per_sec_chip"
+
+
+def read(run):
+    rate = run.result.end_to_end.get("train_tokens_per_sec_chip")
+    if rate is None:
+        return None
+    peak = costs.peaks(run.result.device["kind"])["bf16_flops_per_s"]
+    return 100.0 * costs.train_flops_per_token(run.config) * rate / peak
