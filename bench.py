@@ -155,7 +155,9 @@ def main(argv=None) -> int:
     reg = telemetry.Registry()
     prev_reg = telemetry.set_registry(reg)
     try:
-        phases = telemetry.StepPhases(registry=reg)
+        data_wait, dispatch, device_block = (
+            telemetry.Phase(f"train/{n}", reg)
+            for n in ("data_wait", "dispatch", "device_block"))
         mono = time.monotonic
         feed = Prefetcher((make_batch() for _ in range(PHASE_STEPS)),
                           depth=2, telemetry_label="bench")
@@ -164,12 +166,12 @@ def main(argv=None) -> int:
                 tp0 = mono()
                 b = next(feed)
                 tp1 = mono()
-                phases.data_wait.rec(tp0, tp1 - tp0)
+                data_wait.rec(tp0, tp1 - tp0)
                 state, pm = trainer.step(state, b)
                 tp2 = mono()
-                phases.dispatch.rec(tp1, tp2 - tp1)
+                dispatch.rec(tp1, tp2 - tp1)
                 float(pm["loss"])  # device sync attributes device time
-                phases.device_block.rec(tp2, mono() - tp2)
+                device_block.rec(tp2, mono() - tp2)
         finally:
             # a mid-pass failure must not leak the fill thread and its
             # pinned batches into the decode companion's memory budget

@@ -1,0 +1,11 @@
+"""Device self time on instructions of scope ``body/attention`` (the
+sequence-mixing layer: learned-map mixer or dot-product attention, kernels
+AND what surrounds them) over busy time, percent."""
+from ..lib import program_readers
+
+LAYER = "L3_model_graph"
+MOVES = "train_tokens_per_sec_chip"
+
+
+def read(run):
+    return program_readers.scope_share(run, "body/attention")

@@ -83,10 +83,6 @@ GUARDED_BY: typing.Dict[str, dict] = {
         "guards": {"_events": "rw", "_seq": "rw", "_dirty": "rw",
                    "_last_flush": "rw", "model_path": "w", "tag": "w"},
     },
-    "homebrewnlp_tpu/telemetry/spans.py::ChromeTrace": {
-        "lock": "_lock",
-        "guards": {"_events": "rw"},
-    },
     "homebrewnlp_tpu/telemetry/registry.py::_Metric": {
         "lock": "_lock",
         "guards": {"_series": "rw"},

@@ -315,6 +315,7 @@ _INSTR_RE = re.compile(
 _COMP_RE = re.compile(r"^(?:ENTRY\s+)?%?([A-Za-z0-9_.$-]+)\s+\(")
 _OP_NAME_RE = re.compile(r'op_name="([^"]+)"')
 _CALLS_RE = re.compile(r"(?:calls|to_apply|body)=%?([A-Za-z0-9_.$-]+)")
+_OPERAND_RE = re.compile(r"%([A-Za-z0-9_.$-]+)")
 
 #: instruction kinds whose profiler event WRAPS its children's events
 #: (the body ops report separately) — excluded from attribution totals or
@@ -329,7 +330,11 @@ def instruction_table(hlo_text: str
 
     Fusion/call instructions often carry no ``op_name`` of their own; their
     scope is inherited from the called computation's ROOT instruction (one
-    ``calls=`` hop at lookup time, :func:`attribute_events`)."""
+    ``calls=`` hop at lookup time, :func:`attribute_events`).  What the
+    compiler itself inserts to move data (XLA:CPU's layout ``copy``,
+    ``wrapped_convert`` / ``wrapped_broadcast`` fusions) carries no metadata
+    anywhere: it belongs to the scope that made the value it moves, so it
+    inherits from its first operand's producer."""
     table: typing.Dict[str, typing.Dict[str, typing.Any]] = {}
     comp_root_op: typing.Dict[str, typing.Optional[str]] = {}
     comp_root_instr: typing.Dict[str, str] = {}
@@ -347,8 +352,10 @@ def instruction_table(hlo_text: str
         op = _OP_NAME_RE.search(line)
         op_name = op.group(1) if op else None
         calls = _CALLS_RE.search(line)
+        operand = _OPERAND_RE.search(line, m.end())
         table[name] = {"kind": kind, "op_name": op_name,
-                       "calls": calls.group(1) if calls else None}
+                       "calls": calls.group(1) if calls else None,
+                       "operand": operand.group(1) if operand else None}
         if current_comp is not None:
             if op_name is not None:
                 votes = comp_votes.setdefault(current_comp, {})
@@ -380,6 +387,16 @@ def instruction_table(hlo_text: str
             # to whatever ITS root instruction calls (call->fusion chains)
             root = table.get(comp_root_instr.get(comp, ""))
             comp = root["calls"] if root else None
+            hops += 1
+    # still nameless: compiler-made data movement.  Follow the first operand
+    # to the nearest producer that has a scope (bounded: copy of a convert
+    # of a get-tuple-element ...); parameters end the walk unnamed
+    for name, info in table.items():
+        src, hops = info, 0
+        while info["op_name"] is None and src is not None and hops < 8:
+            src = table.get(src.get("operand") or "")
+            if src is not None and src["op_name"] is not None:
+                info["op_name"] = src["op_name"]
             hops += 1
     return table
 

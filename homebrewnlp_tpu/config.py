@@ -480,23 +480,20 @@ class ModelParameter:
         # drafts.  0 = never self-disable
         self.spec_min_accept_rate = 0.2
         # ---- telemetry (docs/OBSERVABILITY.md) ----
-        # master switch for TRAIN-LOOP instrumentation: step-phase histograms
-        # (data-wait / dispatch / device-block), prefetcher gauges, JSONL /
-        # chrome-trace dumps.  Costs one device sync per step to attribute
-        # device time (same trap/cost note as nonfinite_loss_tolerance);
-        # measured <2% of step time.  Off = exactly ZERO registry calls on
-        # the step hot path.  Rare-event layers (storage retries, checkpoint
-        # IO, serving decode rounds) record regardless — their cadence is
-        # storage/request-bound, and GET /metrics is always served
+        # master switch for the TRAIN LOOP's per-step registry series: the
+        # step spans' histograms (train/step_dispatch, data/next,
+        # data/place), the token counter, prefetcher gauges, the JSONL dump.
+        # It adds no device sync and changes no timing; the spans' trace
+        # annotations are written either way.  Off = exactly ZERO registry
+        # calls on the step hot path.  Set-up and rare-event sites (model
+        # init, compiles, metric log, storage retries, checkpoint IO,
+        # serving decode rounds) record regardless — their cadence is never
+        # per-step — and GET /metrics is always served
         self.telemetry_enabled = False
         # with telemetry on: append a registry-snapshot JSONL line to
         # <model_path>/telemetry.jsonl at most every N seconds (checked at
         # the metric-log cadence).  0 = no JSONL dump
         self.telemetry_jsonl_interval_s = 0.0
-        # with telemetry on: keep the last N span events and write them as
-        # Chrome-trace JSON (<model_path>/telemetry_trace.json, loadable in
-        # Perfetto / chrome://tracing) at run end.  0 = no trace recording
-        self.telemetry_chrome_trace_events = 0
         # opt-in: SIGUSR2 captures a jax.profiler trace of the next
         # telemetry_profile_steps steps into <model_path>/profile/
         # on_demand_<step> (a second SIGUSR2 stops early).  Independent of
@@ -631,7 +628,6 @@ class ModelParameter:
             if v < 0:
                 raise ValueError(f"{knob} must be >= 0, got {v}")
         for knob in ("telemetry_jsonl_interval_s",
-                     "telemetry_chrome_trace_events",
                      "telemetry_blackbox_events", "telemetry_max_file_mb",
                      "elastic_straggler_factor"):
             if getattr(self, knob) < 0:

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import time
 import typing
 import zlib
 
@@ -26,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..telemetry import registry as _registry
 from .tensor import NamedTensor, nt
 
 Params = typing.Dict[str, jax.Array]
@@ -162,6 +164,24 @@ def name_seed(name: str, seed: int) -> np.random.Generator:
                                                        zlib.crc32(name.encode())]))
 
 
+def init_value(initializer, name: str, seed: int, sizes) -> np.ndarray:
+    """One parameter's float32 value from its per-name seeded initializer
+    (host numpy), counted: ``hbnlp_init_values_total`` values made in
+    ``hbnlp_init_values_seconds_total`` seconds — the part of
+    ``setup/model_init`` that is not the graph walk.  Once a parameter, at
+    set-up: always recorded."""
+    t0 = time.monotonic()
+    value = np.asarray(initializer(name_seed(name, seed), sizes),
+                       dtype=np.float32)
+    r = _registry()
+    r.counter("hbnlp_init_values_seconds_total",
+              "seconds in parameter initializers (host numpy)"
+              ).inc(time.monotonic() - t0)
+    r.counter("hbnlp_init_values_total",
+              "parameter values made by an initializer").inc()
+    return value
+
+
 def get_param(name_leaf: str, dims, initializer, slice_dtype, calc_dtype
               ) -> NamedTensor:
     """Create (init) or fetch (apply) a parameter as a NamedTensor.
@@ -177,8 +197,7 @@ def get_param(name_leaf: str, dims, initializer, slice_dtype, calc_dtype
     if ctx.mode == "init":
         if name in ctx.params:
             raise ValueError(f"duplicate parameter {name}")
-        value = np.asarray(initializer(name_seed(name, ctx.seed), sizes),
-                           dtype=np.float32)
+        value = init_value(initializer, name, ctx.seed, sizes)
         assert value.shape == sizes, (name, value.shape, sizes)
         # init stores host numpy (the "master" copy, mtf Saver-style);
         # device placement + sharding happen at train setup, so init never

@@ -29,6 +29,7 @@ import typing
 import numpy as np
 
 from ..config import ModelParameter
+from ..telemetry import span
 from . import native_recordio
 from .tfrecord import decode_example, read_records
 
@@ -440,7 +441,12 @@ class Prefetcher:
     ``telemetry_enabled``), the prefetcher records a queue-depth gauge,
     fill-stall and bounded-put retry counters, and item totals into the
     process registry under ``queue=<label>`` (docs/OBSERVABILITY.md).
-    None (the default) makes zero registry calls."""
+    None (the default) makes zero registry calls per item.
+
+    Spans (telemetry/spans.py): ``setup/data_first_batch`` from construction
+    to the first item handed out (once: always recorded — a cold pipeline's
+    first decode is set-up time) and ``data/next`` around every consumer
+    wait (recorded under ``telemetry_label`` only; annotated always)."""
 
     def __init__(self, iterable, depth: int = 2,
                  telemetry_label: typing.Optional[str] = None):
@@ -449,6 +455,8 @@ class Prefetcher:
         self._stop = False
         self._error: typing.Optional[BaseException] = None
         self._tel = None
+        self._first = span("setup/data_first_batch")
+        self._first.__enter__()
         if telemetry_label is not None:
             from ..telemetry import registry as _reg
             r = _reg()
@@ -523,7 +531,11 @@ class Prefetcher:
         tel = self._tel
         if tel is not None and self.q.qsize() == 0:
             tel[3].inc()
-        item = self.q.get()
+        with span("data/next", record=tel is not None):
+            item = self.q.get()
+        if self._first is not None:
+            self._first.__exit__(None, None, None)
+            self._first = None
         if item is self._done:
             if self._error is not None:
                 error, self._error = self._error, None

@@ -17,6 +17,7 @@ import typing
 import jax
 import jax.numpy as jnp
 
+from .. import telemetry
 from ..config import BlockArgs, ModelParameter
 from ..core import scope
 from ..core.dims import Dim, shape_sub
@@ -283,8 +284,11 @@ class Model:
                 info, self.plan = build(self.params, *args, plan=None)
             return info.total_loss
 
-        jax.eval_shape(_run, {k: jax.ShapeDtypeStruct(v.shape, v.dtype)
-                              for k, v in batch.items() if v is not None})
+        # once a run: the graph walk in init mode plus every parameter's
+        # host-numpy value (core/scope.init_value counts the latter apart)
+        with telemetry.span("setup/model_init"):
+            jax.eval_shape(_run, {k: jax.ShapeDtypeStruct(v.shape, v.dtype)
+                                  for k, v in batch.items() if v is not None})
         self.param_dims = dict(ctx.param_dims)
         self.param_fan_in = dict(ctx.param_fan_in)
         return ctx.params

@@ -92,8 +92,7 @@ def get_var(args: BlockArgs, shape: SHAPE, initializer) -> NamedTensor:
                               name)
     sizes = tuple(d.size for d in shape)
     if ctx.mode == "init" and canonical not in ctx.params:
-        value = np.asarray(initializer(scope.name_seed(canonical, ctx.seed), sizes),
-                           dtype=np.float32)
+        value = scope.init_value(initializer, canonical, ctx.seed, sizes)
         ctx.params[canonical] = value.astype(params.slice_dtype)
         ctx.param_dims[canonical] = tuple(shape)
         fan_in = getattr(initializer, "fan_in_names", None)

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import telemetry
 from ..config import ModelParameter
 from ..core import sharding as shardlib
 from ..data.inputs import (Prefetcher, TextDataset, append_runs_log,
@@ -132,8 +133,7 @@ class _AsyncFeeder:
 
     The historical loop ordering was fetch -> transfer -> dispatch: the
     next batch's host->device copy only STARTED after the previous step's
-    dispatch returned, so the step-phase spans showed ``data_wait`` +
-    ``dispatch`` serialized against device compute.  This iterator keeps
+    dispatch returned, serialized against device compute.  This iterator keeps
     ONE batch in flight: each ``__next__`` returns the batch whose
     transfer was already started on the PREVIOUS call, then immediately
     starts the next one via ``Trainer.place_batch`` (``jax.device_put`` /
@@ -435,25 +435,13 @@ def train(params: ModelParameter, train_steps: typing.Optional[int] = None,
     #: below runs after the agent starts); the agent's callback reads the
     #: cell at flag time
     straggler_counter: typing.List[typing.Any] = [None]
-    # will hold the chrome-trace recorder once the telemetry block builds
-    # it; the force-exit hook below dumps whatever is there at exit time
-    tel_trace = None
 
     def _force_exit_flush():
-        """Everything ``os._exit`` would lose, shared by the agent's
-        force-exit hook (the finally path never runs there): the chief's
-        DataLog rewrite and the chrome-trace ring.  The blackbox itself is
-        flushed by the agent AFTER this hook — satellite: the span trace
-        ring flushes on the membership exit path too, not just close."""
+        """What ``os._exit`` would lose, for the agent's force-exit hook
+        (the finally path never runs there): the chief's DataLog rewrite.
+        The blackbox itself is flushed by the agent AFTER this hook."""
         if datalog_flush is not None:
             datalog_flush()
-        if tel_trace is not None and is_chief:
-            try:
-                tel_trace.dump(fs.join(params.model_path,
-                                       "telemetry_trace.json"))
-            except Exception as e:
-                print(f"WARNING: force-exit chrome trace dump failed: {e}",
-                      flush=True)
 
     if params.elastic_training and jax.process_count() > 1:
         from ..distributed.elastic import ElasticAgent
@@ -492,19 +480,17 @@ def train(params: ModelParameter, train_steps: typing.Optional[int] = None,
         logger.note(data_seed=int(params.data_seed),
                     data_seed_auto_generated=True)
     # ---- telemetry (docs/OBSERVABILITY.md): everything below is created
-    # ONCE, outside the loop; when telemetry_enabled is false, `phases` is
-    # None and the step loop makes exactly zero registry calls
-    phases = None
+    # ONCE, outside the loop; when telemetry_enabled is false every handle
+    # stays None and the step loop makes exactly zero registry calls (the
+    # per-step spans live where the work is: Trainer.step, place_batch and
+    # the prefetcher's __next__, gated the same way)
     tel_nonfinite = tel_preempt = None
     tel_jsonl = None
     tel_jsonl_last = [0.0]
     tel_publish = tel_gather = None
-    tel_mfu = tel_tokens = None
+    tel_tokens = None
     tel_membership = None
-    mfu_flops_per_step = 0.0
-    mfu_peak_total = 1.0
     if params.telemetry_enabled:
-        from .. import telemetry
         telemetry.register_build_info()
         if jax.process_count() > 1:
             # every exported series names the host it came from; the chief's
@@ -512,10 +498,6 @@ def train(params: ModelParameter, train_steps: typing.Optional[int] = None,
             # summing different hosts into anonymity (docs/DISTRIBUTED.md)
             telemetry.set_constant_labels(
                 {"process": str(jax.process_index())})
-        if params.telemetry_chrome_trace_events:
-            tel_trace = telemetry.ChromeTrace(
-                params.telemetry_chrome_trace_events)
-        phases = telemetry.StepPhases(trace=tel_trace)
         reg = telemetry.registry()
         tel_nonfinite = reg.counter(
             "hbnlp_train_nonfinite_skips_total",
@@ -548,36 +530,17 @@ def train(params: ModelParameter, train_steps: typing.Optional[int] = None,
                     "slow-but-alive ranks flagged by the chief's straggler "
                     "detector (step-time skew vs fleet median, before the "
                     "lease lapses)")
-        # live MFU (docs/OBSERVABILITY.md 'Cost attribution'): analytical
-        # forward FLOPs traced ONCE here (abstract — no device work), the
-        # per-step gauge is ledger-FLOPs / measured step time / peak.
-        # Failure to trace (e.g. exotic video configs) degrades to no gauge,
-        # never to a dead run.
-        # chief-only: tokens_per_step and the MFU FLOP count are GLOBAL
-        # quantities — every host registering them would make a cross-host
-        # merge (or a per-host scrape summed downstream) report N× the real
-        # token rate and utilization
+        # chief-only: tokens_per_step is a GLOBAL quantity — every host
+        # registering it would make a cross-host merge (or a per-host scrape
+        # summed downstream) report N× the real token rate.  A rate over
+        # this counter is the operator's throughput; there is no per-step
+        # utilization gauge, because a step's time is only known to the host
+        # through a device sync, and measuring must not change what is
+        # measured
         if is_chief:
             tel_tokens = reg.counter(
                 "hbnlp_train_tokens_total",
                 "tokens fed to the device (rate() of this is tokens/sec)")
-            try:
-                micro = {k: v[0] if params.macro_batching > 1 else v
-                         for k, v in first_batch.items() if v is not None}
-                fwd = flops_mod.forward_flops(
-                    lambda v, b: model.apply(v, b).total_loss.data,
-                    state.variables, micro)
-                # 3x-forward convention (forward + 2x backward, no remat
-                # credit) x the micro steps one loop iteration executes
-                mfu_flops_per_step = 3.0 * fwd * max(1, params.macro_batching)
-                mfu_peak_total = flops_mod.peak_flops() * max(1, len(devices))
-                tel_mfu = reg.gauge(
-                    "hbnlp_train_mfu",
-                    "model FLOPs utilization of the last step (3x-forward "
-                    "analytical FLOPs / measured step time / peak)")
-            except Exception as exc:
-                print(f"WARNING: MFU gauge disabled (FLOP trace failed: "
-                      f"{exc})", flush=True)
         if is_chief and params.telemetry_jsonl_interval_s > 0:
             # size-capped rotation (telemetry_max_file_mb, keep-last-N):
             # a long run's trajectory can no longer fill the disk.  The
@@ -702,16 +665,6 @@ def train(params: ModelParameter, train_steps: typing.Optional[int] = None,
             # already consumed above, so the feeder wraps the remainder
             data_it = _AsyncFeeder(data_it, trainer.place_batch)
 
-        def next_batch():
-            """One data fetch, with the data-wait phase recorded when
-            telemetry is on (StopIteration propagates untimed)."""
-            if phases is None:
-                return next(data_it)
-            t0 = mono()
-            b = next(data_it)
-            phases.data_wait.rec(t0, mono() - t0)
-            return b
-
         profiling = False
         # host-side step mirror: never block on state.step (a device sync per
         # step would serialise dispatch against compute)
@@ -729,12 +682,15 @@ def train(params: ModelParameter, train_steps: typing.Optional[int] = None,
                 break
             if profile_steps is not None:
                 if not profiling and step_now >= profile_steps[0]:
-                    jax.profiler.start_trace(os.path.join(params.model_path,
-                                                          "profile"))
+                    telemetry.start_capture(os.path.join(params.model_path,
+                                                         "profile"))
                     profiling = True
                 elif profiling and step_now >= profile_steps[1]:
                     jax.profiler.stop_trace()
                     profiling = False
+                    # one window: left set, the next turn would find
+                    # "not profiling and past the start" and capture again
+                    profile_steps = None
             if profiler_od is not None:
                 profiler_od.poll(step_now)
             it_count += 1
@@ -753,23 +709,8 @@ def train(params: ModelParameter, train_steps: typing.Optional[int] = None,
                 # executable from the persistent cache) before it
                 # dispatches; later calls only dispatch
                 compile_s += t1 - t0
-            if phases is not None:
-                phases.dispatch.rec(t0, t1 - t0)
-                # attributing device time requires waiting for the step to
-                # finish: one device sync per step, the same documented cost
-                # as nonfinite_loss_tolerance (CONFIG.md; measured <2% of
-                # step time — dispatch of the NEXT step is sub-ms and the
-                # prefetcher keeps data decode off this thread)
-                jax.block_until_ready(metrics["loss"])
-                t2 = mono()
-                phases.device_block.rec(t1, t2 - t1)
-                if tel_tokens is not None:
-                    tel_tokens.inc(tokens_per_step)
-                if tel_mfu is not None and t2 > t0:
-                    # dispatch + device time of THIS step; the clock reads
-                    # are the ones the phases above already paid
-                    tel_mfu.set(mfu_flops_per_step / (t2 - t0)
-                                / mfu_peak_total)
+            if tel_tokens is not None:
+                tel_tokens.inc(tokens_per_step)
             consumed += params.macro_batching
             consumed_ref[0] = consumed
             if params.nonfinite_loss_tolerance > 0:
@@ -802,7 +743,7 @@ def train(params: ModelParameter, train_steps: typing.Optional[int] = None,
                         stopped = True
                         break
                     try:
-                        batch = next_batch()
+                        batch = next(data_it)
                     except StopIteration:
                         break
                     continue
@@ -815,7 +756,7 @@ def train(params: ModelParameter, train_steps: typing.Optional[int] = None,
                 print(f"debug_train_step: dispatched step {step_now}; "
                       f"fetching next batch", flush=True)
             try:
-                batch = next_batch()
+                batch = next(data_it)
             except StopIteration:
                 break
             if params.moe_metrics_interval and \
@@ -830,16 +771,20 @@ def train(params: ModelParameter, train_steps: typing.Optional[int] = None,
             ran_eval = (eval_batches is not None and
                         step_now % params.eval_interval < params.macro_batching)
             if ran_eval:
-                vals = [jax.device_get(trainer.eval_loss(state, eb))
-                        for eb in eval_batches]
+                with telemetry.span("train/eval"):
+                    vals = [jax.device_get(trainer.eval_loss(state, eb))
+                            for eb in eval_batches]
                 metrics = dict(metrics, **{
                     f"val/{k}": float(np.mean([v[k] for v in vals]))
                     for k in vals[0]})
             # an eval step always reaches the metric log, so every recorded
             # val/loss point lands in metrics.jsonl/TB even off-cadence
             if ran_eval or step_now % log_every < params.macro_batching:
-                last_metrics = {**last_metrics,
-                                **{k: float(v) for k, v in metrics.items()}}
+                with telemetry.span("train/metric_log"):
+                    # the float conversions wait for the step: the loop's
+                    # one device sync, every log_every steps
+                    last_metrics = {**last_metrics, **{
+                        k: float(v) for k, v in metrics.items()}}
                 # step record at the metric-log cadence (NOT per step —
                 # the float conversions above already paid the sync)
                 flight.record("step", step=step_now,
@@ -863,7 +808,8 @@ def train(params: ModelParameter, train_steps: typing.Optional[int] = None,
             # saves are chief-trivially
             if params.use_checkpointing and \
                     step_now % params.steps_per_checkpoint < params.macro_batching:
-                save_state(step_now)
+                with telemetry.span("train/checkpoint_save"):
+                    save_state(step_now)
             if should_stop(it_count):
                 # graceful preemption: the in-flight step finished; fall
                 # through to the finally path's emergency checkpoint + run
@@ -971,16 +917,6 @@ def train(params: ModelParameter, train_steps: typing.Optional[int] = None,
                     except Exception as e:
                         print(f"WARNING: final telemetry.jsonl write failed:"
                               f" {e}", flush=True)
-                if tel_trace is not None and is_chief:
-                    try:
-                        path = fs.join(params.model_path,
-                                       "telemetry_trace.json")
-                        tel_trace.dump(path)
-                        print(f"telemetry: chrome trace written to {path}",
-                              flush=True)
-                    except Exception as e:
-                        print(f"WARNING: chrome trace dump failed: {e}",
-                              flush=True)
                 # blackbox dump on EVERY exit that reaches this finally:
                 # normal completion, the 143 emergency-save path, the
                 # clean half of a membership exit, and any crash unwind

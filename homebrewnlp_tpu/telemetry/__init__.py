@@ -5,9 +5,11 @@ Three small stdlib-only pieces every layer shares:
 * ``registry`` — process-wide Counter/Gauge/Histogram table with labels,
   picklable ``snapshot()`` for IPC, Prometheus text-exposition and JSONL
   renderers (``GET /metrics`` is ``prometheus_text(snapshot())``).
-* ``spans`` — ``with span("name"): ...`` + pre-bound ``StepPhases`` for the
-  train loop's data-wait / dispatch / device-block breakdown, with an
-  optional bounded Chrome-trace recorder.
+* ``spans`` — ``with span("name"): ...``: a ``jax.profiler`` trace
+  annotation (one clock with the device planes) and, where the site
+  records, an observation in ``hbnlp_span_seconds``.
+* ``compiles`` — jax's trace / lower / backend-compile / cache-load events
+  as counters by phase and function.
 * ``profiler`` — on-demand ``jax.profiler`` capture (SIGUSR2 or
   programmatic) written under ``model_path``.
 
@@ -18,20 +20,21 @@ their cadence is storage/request-bound, never per-step.
 """
 from . import events, tracectx
 from .buildinfo import build_info, register_build_info
+from .compiles import install_compile_listener
 from .events import FlightRecorder, RotatingJsonl
-from .profiler import OnDemandProfiler
+from .profiler import OnDemandProfiler, start_capture
 from .registry import (DEFAULT_BUCKETS, Registry, histogram_quantile,
                        jsonl_line, merge_snapshots, prometheus_text,
                        registry, render_json, set_constant_labels,
                        set_registry, snapshot, summarize, with_labels)
-from .spans import SPAN_METRIC, ChromeTrace, Phase, StepPhases, span
+from .spans import SPAN_METRIC, Phase, span
 
 __all__ = [
     "DEFAULT_BUCKETS", "Registry", "histogram_quantile", "jsonl_line",
     "merge_snapshots", "prometheus_text", "registry", "render_json",
     "set_constant_labels", "set_registry", "snapshot", "summarize",
     "with_labels",
-    "SPAN_METRIC", "ChromeTrace", "Phase", "StepPhases", "span",
-    "OnDemandProfiler", "build_info", "register_build_info",
+    "SPAN_METRIC", "Phase", "span", "install_compile_listener",
+    "OnDemandProfiler", "start_capture", "build_info", "register_build_info",
     "events", "tracectx", "FlightRecorder", "RotatingJsonl",
 ]

@@ -20,9 +20,19 @@ import signal
 import typing
 
 
-def _default_start(logdir: str):
+def start_capture(logdir: str):
+    """Start a ``jax.profiler`` capture with the PYTHON tracer off: the
+    program's spans (telemetry/spans.py) name what the host does, while the
+    Python tracer records every call (thousands of events a step), slows
+    the host loop it is meant to observe, and buries the spans' line.  Host
+    TraceMe events (level 2: the spans, jax's own dispatch and transfer
+    annotations) and the device planes are kept.  Used by both capture
+    paths: ``train(profile_steps=...)`` and SIGUSR2."""
     import jax
-    jax.profiler.start_trace(logdir)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(logdir, profiler_options=opts)
 
 
 def _default_stop():
@@ -32,7 +42,7 @@ def _default_stop():
 
 class OnDemandProfiler:
     def __init__(self, out_dir: str, capture_steps: int = 10,
-                 start: typing.Callable[[str], None] = _default_start,
+                 start: typing.Callable[[str], None] = start_capture,
                  stop: typing.Callable[[], None] = _default_stop):
         self.out_dir = out_dir
         self.capture_steps = max(1, int(capture_steps))

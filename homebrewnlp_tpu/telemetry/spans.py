@@ -1,20 +1,29 @@
-"""Span API: ``with span("train/data_wait"): ...`` feeds the registry's
-span histogram and (optionally) a bounded Chrome-trace recorder, so one
-``chrome://tracing`` / Perfetto load shows where a slow step actually went.
+"""Span API: ``with span("train/checkpoint_save"): ...`` marks a block on the
+PROFILER's clock and, where the site records, in the registry's span
+histogram.
 
-Stdlib-only, like the registry.  The train loop's per-step phases bypass
-the context-manager form for the three hottest sites (pre-bound ``Phase``
-handles, run/train_loop.py) — same histogram, fewer allocations.
+A span is a ``jax.profiler.TraceAnnotation`` of its name: while a profile is
+being captured (``profile_steps``, SIGUSR2, a harness's own
+``start_trace``) it lands on the calling thread's line of the ``/host:CPU``
+plane, on the same time base as the ``/device:TPU:*`` planes, so a host
+span can be laid against a device gap; while none is, it costs well under
+a microsecond.  Spans nest by time on one thread — that is the parent /
+child relation a trace reducer needs; there are no ids.
+
+Recording rule (docs/OBSERVABILITY.md): set-up and rare sites observe
+``hbnlp_span_seconds{span=...}`` always; per-step sites pass
+``record=params.telemetry_enabled``, so a run with telemetry off makes zero
+registry calls on the step hot path.  Every site writes the annotation.
+
+Stdlib-only at import, like the registry: ``jax`` is looked up in
+``sys.modules`` when a span opens, never imported here — a process that has
+not loaded jax (the HTTP child) has no profiler to annotate.
 """
 from __future__ import annotations
 
-import collections
-import json
-import threading
+import sys
 import time
 import typing
-
-from ..utils import locks
 
 # NOT `from . import registry`: the package __init__ rebinds its `registry`
 # attribute to the registry() FUNCTION, shadowing the submodule
@@ -25,102 +34,65 @@ from .registry import Registry, registry as _process_registry
 SPAN_METRIC = "hbnlp_span_seconds"
 
 
-class ChromeTrace:
-    """Bounded ring buffer of span events, dumped as Chrome-trace JSON
-    (the ``[{"ph": "X", ...}]`` array form Perfetto and chrome://tracing
-    load directly).  Bounded so a long run cannot grow host memory without
-    limit — the LAST ``max_events`` spans survive."""
-
-    def __init__(self, max_events: int = 100_000):
-        self._events: typing.Deque[tuple] = collections.deque(
-            maxlen=max(1, int(max_events)))
-        self._lock = locks.named_lock("ChromeTrace._lock")
-
-    def add(self, name: str, start_s: float, duration_s: float):
-        with self._lock:
-            self._events.append((name, threading.get_ident(), start_s,
-                                 duration_s))
-
-    def __len__(self):
-        # approximate occupancy gauge: a torn read of a bounded deque's
-        # len costs nothing  # graft-lint: allow[lock-guard]
-        return len(self._events)
-
-    def events(self) -> typing.List[dict]:
-        with self._lock:
-            items = list(self._events)
-        return [{"name": name, "ph": "X", "pid": 0, "tid": tid,
-                 "ts": round(start * 1e6, 3), "dur": round(dur * 1e6, 3)}
-                for name, tid, start, dur in items]
-
-    def dump(self, path: str) -> str:
-        """Write the trace under ``path`` (any fs-seam scheme, so it lands
-        next to checkpoints on remote model_paths)."""
-        from ..utils import fs
-        with fs.open_(path, "w") as f:
-            json.dump(self.events(), f)
-        return path
-
-
 class Phase:
-    """A pre-bound span target: one histogram child + optional trace.
-    ``rec(t0, dt)`` is the whole hot-path cost — call sites own the clock
-    so a disabled run makes zero clock reads AND zero registry calls."""
+    """A pre-bound histogram child for callers that own the clock:
+    ``rec(t0, dt)`` is the whole cost (``bench.py``'s instrumented pass)."""
 
-    __slots__ = ("_child", "_trace", "name")
+    __slots__ = ("_child", "name")
 
-    def __init__(self, name: str, registry: typing.Optional[Registry] = None,
-                 trace: typing.Optional[ChromeTrace] = None):
+    def __init__(self, name: str, registry: typing.Optional[Registry] = None):
         r = registry if registry is not None else _process_registry()
         self._child = r.histogram(
-            SPAN_METRIC, "span / step-phase duration in seconds",
-            ("span",)).labels(name)
-        self._trace = trace
+            SPAN_METRIC, "span duration in seconds", ("span",)).labels(name)
         self.name = name
 
     def rec(self, start_s: float, duration_s: float):
         self._child.observe(duration_s)
-        if self._trace is not None:
-            self._trace.add(self.name, start_s, duration_s)
+
+
+def _annotation(name: str):
+    """The profiler's annotation of ``name``, or None in a process that has
+    not imported jax."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    return jax.profiler.TraceAnnotation(name)
 
 
 class _Span:
-    __slots__ = ("_phase", "_clock", "_t0")
+    __slots__ = ("name", "_registry", "_record", "_clock", "_t0", "_ann")
 
-    def __init__(self, phase: Phase, clock):
-        self._phase = phase
+    def __init__(self, name, registry, record, clock):
+        self.name = name
+        self._registry = registry
+        self._record = record
         self._clock = clock
+        self._ann = None
 
     def __enter__(self):
-        self._t0 = self._clock()
+        self._ann = _annotation(self.name)
+        if self._ann is not None:
+            self._ann.__enter__()
+        if self._record:
+            self._t0 = self._clock()
         return self
 
     def __exit__(self, *exc):
-        self._phase.rec(self._t0, self._clock() - self._t0)
+        if self._record:
+            t1 = self._clock()
+            Phase(self.name, self._registry).rec(self._t0, t1 - self._t0)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
         return False
 
 
 def span(name: str, registry: typing.Optional[Registry] = None,
-         trace: typing.Optional[ChromeTrace] = None,
-         clock: typing.Callable[[], float] = time.monotonic) -> _Span:
-    """Context manager timing a block into the span histogram:
-    ``with span("ckpt/save"): ...``.  For per-step hot paths prefer a
-    pre-bound ``Phase`` (this form pays a metric + child lookup per call,
-    fine at checkpoint/request cadence)."""
-    return _Span(Phase(name, registry, trace), clock)
-
-
-class StepPhases:
-    """The train loop's step-phase breakdown: pre-bound Phase handles for
-    data-wait (blocked on the prefetcher), dispatch (host tracing +
-    enqueue of the jitted step), and device-block (waiting for the device
-    to finish the step) — the three-way split that tells data stalls from
-    host overhead from device time (docs/OBSERVABILITY.md)."""
-
-    def __init__(self, registry: typing.Optional[Registry] = None,
-                 trace: typing.Optional[ChromeTrace] = None,
-                 prefix: str = "train"):
-        self.data_wait = Phase(f"{prefix}/data_wait", registry, trace)
-        self.dispatch = Phase(f"{prefix}/dispatch", registry, trace)
-        self.device_block = Phase(f"{prefix}/device_block", registry, trace)
-        self.trace = trace
+         clock: typing.Callable[[], float] = time.monotonic,
+         record: bool = True) -> _Span:
+    """Context manager marking a block: a trace annotation always, an
+    observation in the span histogram when ``record`` (per-step sites pass
+    ``telemetry_enabled``).  A site whose block opens in one call and closes
+    in another (the prefetcher's first batch) calls ``__enter__`` /
+    ``__exit__`` itself, on one thread."""
+    return _Span(name, registry, record, clock)

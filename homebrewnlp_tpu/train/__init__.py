@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import telemetry
 from ..config import ModelParameter
 from ..core import sharding as shardlib
 from ..model import Model
@@ -103,6 +104,10 @@ class Trainer:
         self._stats_fn = None
         self._eval_fn = None
         self._rng_counter = 0
+        # per-step spans observe the registry only under telemetry_enabled
+        # (zero registry calls on the hot path when off); the trace
+        # annotation is written either way
+        self._record_steps = bool(params.telemetry_enabled)
         # resolved lazily on the first traced step (warns once on fallback)
         self._grad_allreduce_resolved: typing.Optional[str] = None
 
@@ -124,12 +129,17 @@ class Trainer:
                    for k, v in one.items()}
         variables = self.model.init(one, seed)
         self.optimizer = Optimizer(self.params, self.model.param_dims)
-        if self.mesh is not None:
-            variables = shardlib.shard_params(self.params, variables,
-                                              self.model.param_dims, self.mesh)
-        else:
-            variables = {k: jnp.asarray(v) for k, v in variables.items()}
-        opt_state = self.optimizer.init(variables)
+        # set-up spans (once a run, always recorded; docs/OBSERVABILITY.md):
+        # with setup/model_init they split what a caller's clock around
+        # init_state sees
+        with telemetry.span("setup/place_params"):
+            if self.mesh is not None:
+                variables = shardlib.shard_params(
+                    self.params, variables, self.model.param_dims, self.mesh)
+            else:
+                variables = {k: jnp.asarray(v) for k, v in variables.items()}
+        with telemetry.span("setup/opt_init"):
+            opt_state = self.optimizer.init(variables)
         step = jnp.asarray(self.params.current_step, jnp.int32)
         if self.mesh is not None:
             # committed and replicated like every other leaf: the step
@@ -137,7 +147,13 @@ class Trainer:
             # make the second call a different (re-compiled) program
             step = jax.device_put(step, jax.sharding.NamedSharding(
                 self.mesh, jax.sharding.PartitionSpec()))
-        return TrainState(variables, opt_state, step)
+        state = TrainState(variables, opt_state, step)
+        with telemetry.span("setup/init_wait"):
+            # placement and slot building are asynchronous: wait here, where
+            # every caller waits anyway, so that what the device still owes
+            # is not charged to whatever the caller does next
+            jax.block_until_ready(state)
+        return state
 
     # -- one micro step ----------------------------------------------------
     def _1f1b_exclusion(self) -> typing.Optional[str]:
@@ -547,10 +563,11 @@ class Trainer:
         arrays and skips re-sharding — the seam the train loop's
         double-buffered input overlap uses (run/train_loop.py
         ``_AsyncFeeder``; ``async_input_transfer``)."""
-        if self.mesh is not None:
-            return shardlib.shard_batch(self.params, batch, self.mesh)
-        return {k: (jax.device_put(v) if v is not None else v)
-                for k, v in batch.items()}
+        with telemetry.span("data/place", record=self._record_steps):
+            if self.mesh is not None:
+                return shardlib.shard_batch(self.params, batch, self.mesh)
+            return {k: (jax.device_put(v) if v is not None else v)
+                    for k, v in batch.items()}
 
     def _batch_placed(self, batch: typing.Dict[str, jax.Array]) -> bool:
         """True when every leaf already carries this trainer's mesh
@@ -565,19 +582,25 @@ class Trainer:
 
     def step(self, state: TrainState, batch: typing.Dict[str, jax.Array],
              rng: typing.Optional[jax.Array] = None):
-        if self._step_fn is None:
-            self._step_fn = self._build_step(state=state)
-            self._rng_counter = 0
-        if rng is None:
-            # host counter offset by the restored step, never a device sync
-            # on state.step: a resumed run continues the dropout-key
-            # sequence instead of replaying it from its first step
-            self._rng_counter += 1
-            rng = jax.random.PRNGKey(self.params.current_step
-                                     + self._rng_counter)
-        if self.mesh is not None and not self._batch_placed(batch):
-            batch = shardlib.shard_batch(self.params, batch, self.mesh)
-        return self._step_fn(state, batch, rng)
+        # the host's whole part of a step — key build, placement check, the
+        # jitted call's enqueue (and, the first time, its trace + compile) —
+        # under one span, here and not around the call, so every caller of
+        # step() has it
+        with telemetry.span("train/step_dispatch", record=self._record_steps):
+            if self._step_fn is None:
+                self._step_fn = self._build_step(state=state)
+                self._rng_counter = 0
+            if rng is None:
+                # host counter offset by the restored step, never a device
+                # sync on state.step: a resumed run continues the
+                # dropout-key sequence instead of replaying it from its
+                # first step
+                self._rng_counter += 1
+                rng = jax.random.PRNGKey(self.params.current_step
+                                         + self._rng_counter)
+            if self.mesh is not None and not self._batch_placed(batch):
+                batch = shardlib.shard_batch(self.params, batch, self.mesh)
+            return self._step_fn(state, batch, rng)
 
     def eval_loss(self, state: TrainState,
                   batch: typing.Dict[str, jax.Array]

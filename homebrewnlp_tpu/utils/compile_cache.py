@@ -40,6 +40,10 @@ def install_compile_cache() -> str:
     directory (the environment's when :data:`ENV_VAR` is set, else
     :data:`DEFAULT_DIR`).  Idempotent; call before the first jit compile."""
     import jax
+    from ..telemetry import install_compile_listener
+    # the compile counters ride along: every run mode calls this before its
+    # first jit, which is when the listener has to be there
+    install_compile_listener()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     path = os.environ.get(ENV_VAR)

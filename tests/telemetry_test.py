@@ -3,13 +3,14 @@
 Unit sweep: registry semantics, Prometheus text-exposition conformance
 (rendered text is parsed BACK and checked against the snapshot), histogram
 bucket accounting under concurrent writers, snapshot merge/JSONL/summary
-renderers, span -> histogram + chrome trace, the on-demand profiler state
-machine, the MetricLogger monotonic-clock fix, and the per-layer wiring
-(prefetcher, retry sites, checkpoint IO).
+renderers, span -> histogram + profiler annotation, the on-demand profiler
+state machine, the MetricLogger monotonic-clock fix, and the per-layer wiring
+(prefetcher, retry sites, checkpoint IO, set-up spans, compile counters).
 
-Integration sweep: a train smoke run emitting the data-wait / dispatch /
-device-block step-phase breakdown (and ZERO phase series when
-``telemetry_enabled`` is false), SIGUSR2-triggered profile capture, and —
+Integration sweep: a train smoke run whose per-step spans reach the registry
+only under ``telemetry_enabled`` (and cost no device sync either way), a
+``profile_steps`` capture holding the program's spans on the profiler's
+clock, SIGUSR2-triggered profile capture, and —
 device-free, on the serving_robustness_test harness — ``GET /metrics``
 answering valid exposition from the HTTP child while the device loop is
 wedged inside a decode."""
@@ -271,25 +272,89 @@ def gauge_last_wins_interleaved_test():
         snap_dev2, snap_child)["depth"]["series"][("a",)] == 3
 
 
-def span_and_chrome_trace_test():
+def _host_spans(logdir):
+    """``{name: [(start_ns, end_ns)]}`` of the ``python`` line of the
+    newest capture under ``logdir`` (the line ``TraceAnnotation`` spans of
+    the main thread land on), and how many captures there are."""
+    import glob
+    from jax.profiler import ProfileData
+    found = sorted(glob.glob(os.path.join(
+        str(logdir), "plugins", "profile", "*", "*.xplane.pb")))
+    assert found, f"no capture under {logdir}"
+    out = {}
+    for plane in ProfileData.from_file(found[-1]).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            if line.name != "python":
+                continue
+            for e in line.events:
+                out.setdefault(e.name, []).append(
+                    (e.start_ns, e.start_ns + e.duration_ns))
+    return out, len(found)
+
+
+def span_records_and_annotates_test(monkeypatch):
+    """A span observes the histogram when it records and never when it
+    does not; ``Phase.rec`` serves callers that own the clock; a process
+    that has not imported jax gets no annotation and no import."""
+    import sys
+    from homebrewnlp_tpu.telemetry import spans
     r = telemetry.Registry()
-    trace = telemetry.ChromeTrace(max_events=3)
     clock = iter([1.0, 1.25]).__next__
-    with telemetry.span("ckpt/save", r, trace, clock=clock):
+    with telemetry.span("ckpt/save", r, clock=clock):
         pass
     snap = r.snapshot()
     state = snap[telemetry.SPAN_METRIC]["series"][("ckpt/save",)]
     assert sum(state["counts"]) == 1 and state["sum"] == pytest.approx(0.25)
-    for i in range(5):  # bounded: only the last 3 survive (ckpt/save evicted)
-        trace.add(f"s{i}", float(i), 0.5)
-    events = trace.events()
-    assert [e["name"] for e in events] == ["s2", "s3", "s4"]
-    assert events[0]["ph"] == "X" and events[0]["dur"] == 500000.0
-    phases = telemetry.StepPhases(registry=r, trace=trace)
-    phases.device_block.rec(9.0, 0.125)
+    # a per-step site with telemetry off: annotation only, no clock read,
+    # no registry call
+    def no_clock():
+        raise AssertionError("a span that does not record read the clock")
+    with telemetry.span("train/step_dispatch", r, clock=no_clock,
+                        record=False):
+        pass
+    assert ("train/step_dispatch",) not in \
+        r.snapshot()[telemetry.SPAN_METRIC]["series"]
+    telemetry.Phase("bench/device_block", r).rec(9.0, 0.125)
     assert snap is not r.snapshot()  # snapshot is a copy, not a live view
     got = r.snapshot()[telemetry.SPAN_METRIC]["series"]
-    assert ("train/device_block",) in got
+    assert got[("bench/device_block",)]["sum"] == pytest.approx(0.125)
+    # stdlib-only consumers (the HTTP child): jax is looked up, not imported
+    assert spans._annotation("x") is not None     # jax is loaded here
+    monkeypatch.delitem(sys.modules, "jax")
+    assert spans._annotation("x") is None
+    with telemetry.span("child/handler", r):
+        pass
+    assert "jax" not in sys.modules
+    assert ("child/handler",) in r.snapshot()[telemetry.SPAN_METRIC]["series"]
+
+
+def span_under_capture_lands_in_histogram_and_trace_test(tmp_path):
+    """One clock: the same ``with telemetry.span(...)`` is an observation
+    in the registry and an event of the profiler's trace, nested in time
+    under the span that encloses it."""
+    r = telemetry.Registry()
+    telemetry.start_capture(str(tmp_path))
+    try:
+        with telemetry.span("unit/outer", r):
+            with telemetry.span("unit/inner", r):
+                time.sleep(0.01)
+    finally:
+        import jax
+        jax.profiler.stop_trace()
+    series = r.snapshot()[telemetry.SPAN_METRIC]["series"]
+    assert sum(series[("unit/inner",)]["counts"]) == 1
+    assert series[("unit/inner",)]["sum"] >= 0.01
+    spans, _ = _host_spans(tmp_path)
+    (o0, o1), = spans["unit/outer"]
+    (i0, i1), = spans["unit/inner"]
+    assert o0 <= i0 and i1 <= o1
+    # both clocks saw the same block: within a millisecond of each other
+    assert (i1 - i0) * 1e-9 == pytest.approx(
+        series[("unit/inner",)]["sum"], abs=1e-3)
+    # the Python tracer is off: the capture is not buried in call events
+    assert not [n for n in spans if n.startswith("$")]
 
 
 def on_demand_profiler_test(tmp_path):
@@ -343,14 +408,22 @@ def metric_logger_monotonic_test(tmp_path):
 
 def prefetcher_telemetry_gating_test(fresh_registry):
     from homebrewnlp_tpu.data.inputs import Prefetcher
-    # no label (the telemetry_enabled=false path): ZERO registry calls
+    # no label (the telemetry_enabled=false path): ZERO registry calls per
+    # item — the one thing recorded is the set-up span of the first batch
     list(Prefetcher(iter(range(4)), depth=2))
-    assert fresh_registry.snapshot() == {}
+    snap = fresh_registry.snapshot()
+    assert set(snap) == {telemetry.SPAN_METRIC}
+    first, = snap[telemetry.SPAN_METRIC]["series"].items()
+    assert first[0] == ("setup/data_first_batch",)
+    assert sum(first[1]["counts"]) == 1
     out = list(Prefetcher(iter(range(5)), depth=2, telemetry_label="train"))
     assert out == list(range(5))
     snap = fresh_registry.snapshot()
     assert snap["hbnlp_prefetch_items_total"]["series"][("train",)] == 5
     assert ("train",) in snap["hbnlp_prefetch_queue_depth"]["series"]
+    # with the label the consumer's waits are recorded: 5 items + the end
+    waits = snap[telemetry.SPAN_METRIC]["series"][("data/next",)]
+    assert sum(waits["counts"]) == 6
 
 
 def retry_site_counters_test(fresh_registry):
@@ -408,14 +481,26 @@ def checkpoint_io_metrics_test(tmp_path, fresh_registry, monkeypatch):
 
 # -------------------------------------------------------- integration sweep
 
+#: what a run records whatever ``telemetry_enabled`` says: once a run or at
+#: log / checkpoint / compile cadence, never per step
+_RARE_SERIES = {telemetry.SPAN_METRIC, "hbnlp_init_values_seconds_total",
+                "hbnlp_init_values_total", "hbnlp_compile_seconds_total",
+                "hbnlp_compiles_total"}
+_RARE_SPANS = {"setup/data_first_batch", "setup/model_init",
+               "setup/place_params", "setup/opt_init", "setup/init_wait",
+               "train/metric_log", "train/checkpoint_save", "train/eval"}
+_STEP_SPANS = ("train/step_dispatch", "data/next", "data/place")
+
+
 def train_step_phase_breakdown_test(tmp_path, fresh_registry):
-    """Tentpole acceptance: with telemetry on, a train smoke run emits the
-    data-wait / dispatch / device-block step-phase breakdown, prefetcher
-    series, a telemetry.jsonl trajectory and a chrome trace; with it off,
-    the registry sees ZERO calls from the whole run — INCLUDING from the
-    event layer, whose flight recorder keeps recording (rare-event cadence
-    only: step records at the log cadence, never per step, never into the
-    registry)."""
+    """Tentpole acceptance: with telemetry off a train smoke run records
+    its set-up and rare spans and NOTHING per step — the flight recorder
+    included, which keeps recording at rare-event cadence only (step
+    records at the log cadence, never per step, never into the registry);
+    with it on, the per-step spans placed where the work happens
+    (``Trainer.step``, ``place_batch``, the prefetcher) reach the histogram,
+    with the prefetcher series, the token counter and a telemetry.jsonl
+    trajectory — and no second exporter, no utilization gauge."""
     from robustness_test import _train_cfg, _write_records
     from homebrewnlp_tpu.run import train_loop as tl
     from homebrewnlp_tpu.telemetry import events as flight
@@ -426,9 +511,14 @@ def train_step_phase_breakdown_test(tmp_path, fresh_registry):
     try:
         result = tl.train(ModelParameter(cfg), log_every=2)
         assert result["final_step"] == cfg["train_steps"]
-        assert fresh_registry.snapshot() == {}, \
-            "telemetry_enabled=false must make zero registry calls " \
-            "(event layer included)"
+        snap = fresh_registry.snapshot()
+        assert set(snap) <= _RARE_SERIES, set(snap) - _RARE_SERIES
+        recorded = {k[0] for k in snap[telemetry.SPAN_METRIC]["series"]}
+        assert recorded <= _RARE_SPANS, recorded - _RARE_SPANS
+        assert {"setup/model_init", "train/metric_log"} <= recorded
+        # the metric log is the loop's one sync: log cadence, not per step
+        logs = snap[telemetry.SPAN_METRIC]["series"][("train/metric_log",)]
+        assert sum(logs["counts"]) == cfg["train_steps"] // 2
         # the flight recorder recorded UNCONDITIONALLY — but at rare-event
         # cadence: step events ride the log cadence, not the hot path
         rec = flight.recorder()
@@ -449,23 +539,24 @@ def train_step_phase_breakdown_test(tmp_path, fresh_registry):
     cfg = _train_cfg(tmp_path, data_dir, use_checkpointing=False,
                      model_path=str(tmp_path / "run2"),
                      telemetry_enabled=True,
-                     telemetry_jsonl_interval_s=1e-6,
-                     telemetry_chrome_trace_events=1000)
+                     telemetry_jsonl_interval_s=1e-6)
     result = tl.train(ModelParameter(cfg), log_every=2)
     assert result["final_step"] == cfg["train_steps"]
     snap = fresh_registry.snapshot()
     spans = snap[telemetry.SPAN_METRIC]["series"]
     steps = cfg["train_steps"]
-    for phase in ("train/data_wait", "train/dispatch", "train/device_block"):
+    for phase in _STEP_SPANS:
         state = spans[(phase,)]
-        # first_batch is fetched before the loop: data_wait sees steps - 1
+        # first_batch is fetched before the loop and handed to the first
+        # step unplaced: the loop itself sees steps - 1 of the data spans
         assert sum(state["counts"]) >= steps - 1, phase
         assert state["sum"] >= 0
+    assert sum(spans[("train/step_dispatch",)]["counts"]) == steps
     assert snap["hbnlp_prefetch_items_total"]["series"][("train",)] >= steps
-    # live MFU + token throughput (docs/OBSERVABILITY.md 'Cost
-    # attribution'): a real utilization in (0, 1] and every consumed token
-    # counted; the build-info gauge identifies the run
-    assert 0 < snap["hbnlp_train_mfu"]["series"][()] <= 1
+    # token throughput: every consumed token counted (a rate over it is the
+    # operator's tokens/s); the build-info gauge identifies the run; the
+    # per-step MFU gauge is gone with the sync it needed
+    assert "hbnlp_train_mfu" not in snap
     tokens_per_step = (cfg["train_batch_size"] * cfg["sequence_length"]
                        * max(1, cfg.get("macro_batching", 1)))
     assert snap["hbnlp_train_tokens_total"]["series"][()] \
@@ -480,15 +571,175 @@ def train_step_phase_breakdown_test(tmp_path, fresh_registry):
                                            "backend", "device_kind"}
     assert lines and telemetry.SPAN_METRIC in lines[-1]["metrics"]
     assert lines[-1]["step"] == steps
-    assert "hbnlp_train_mfu" in lines[-1]["metrics"]
-    # the chrome trace is valid and its spans carry durations
-    trace = json.load(open(os.path.join(cfg["model_path"],
-                                        "telemetry_trace.json")))
-    assert len(trace) >= 3 * (steps - 1)
-    assert {e["name"] for e in trace} >= {"train/data_wait",
-                                          "train/dispatch",
-                                          "train/device_block"}
-    assert all(e["ph"] == "X" and e["dur"] >= 0 for e in trace)
+    # one exporter: the spans are in the XLA profile, not in a file of
+    # their own
+    assert not os.path.exists(os.path.join(cfg["model_path"],
+                                           "telemetry_trace.json"))
+
+
+def train_profile_capture_holds_program_spans_test(tmp_path, fresh_registry):
+    """``train(profile_steps=...)`` on the CPU: the capture's ``python``
+    line holds the program's own spans — one ``train/step_dispatch``,
+    ``data/next`` and ``data/place`` per captured step, in loop order, and
+    the metric log's sync — so a host span can be laid against a device
+    gap.  One window gives one capture (it used to start a second one on
+    the turn after it stopped)."""
+    from robustness_test import _train_cfg, _write_records
+    from homebrewnlp_tpu.run import train_loop as tl
+
+    cfg = _train_cfg(tmp_path, _write_records(tmp_path),
+                     use_checkpointing=False, async_input_transfer=True)
+    tl.train(ModelParameter(cfg), log_every=2, profile_steps=(2, 6))
+    spans, captures = _host_spans(os.path.join(cfg["model_path"], "profile"))
+    assert captures == 1
+    for name in _STEP_SPANS:
+        assert len(spans[name]) == 4, (name, len(spans.get(name, ())))
+    assert len(spans["train/metric_log"]) == 2
+    # by time on one thread: dispatch, then the wait on the queue, then the
+    # placement of the batch after next, step after step, none overlapping
+    order = sorted((iv, n) for n in _STEP_SPANS for iv in spans[n])
+    assert [n for _, n in order] == list(_STEP_SPANS) * 4
+    assert all(a[0][1] <= b[0][0] for a, b in zip(order, order[1:]))
+    # telemetry is off: the capture cost the registry nothing per step
+    recorded = {k[0] for k in fresh_registry.snapshot()
+                [telemetry.SPAN_METRIC]["series"]}
+    assert not recorded & set(_STEP_SPANS)
+
+
+def _toy_trainer(tmp_path, **overrides):
+    from robustness_test import _train_cfg, _write_records
+    from homebrewnlp_tpu.model import Model
+    from homebrewnlp_tpu.train import Trainer
+    params = ModelParameter(_train_cfg(tmp_path, _write_records(tmp_path),
+                                       use_checkpointing=False, **overrides))
+    rng = np.random.default_rng(0)
+
+    def batch(dtype=np.int32):
+        x = rng.integers(0, 32, (8, 16, 1)).astype(dtype)
+        return {"token_x": x, "token_y": x}
+
+    return Trainer(params, Model(params)), batch
+
+
+def setup_spans_and_init_value_count_test(tmp_path, fresh_registry):
+    """``Trainer.init_state`` splits itself: the model's graph walk, the
+    initializer calls counted one per parameter made, placement, optimizer
+    slots, and the one wait that closes it."""
+    trainer, batch = _toy_trainer(tmp_path)
+    state = trainer.init_state(batch())
+    snap = fresh_registry.snapshot()
+    spans = snap[telemetry.SPAN_METRIC]["series"]
+    for name in ("setup/model_init", "setup/place_params", "setup/opt_init",
+                 "setup/init_wait"):
+        assert sum(spans[(name,)]["counts"]) == 1, name
+    made = snap["hbnlp_init_values_total"]["series"][()]
+    assert made == len(state.variables) > 0
+    in_values = snap["hbnlp_init_values_seconds_total"]["series"][()]
+    assert 0 < in_values <= spans[("setup/model_init",)]["sum"]
+    # a second init makes the values again and counts them again
+    trainer.init_state(batch())
+    assert fresh_registry.snapshot()["hbnlp_init_values_total"] \
+        ["series"][()] == 2 * made
+
+
+def compile_counter_counts_recompiles_test(tmp_path, fresh_registry):
+    """``hbnlp_compiles_total{phase="backend", fun="step_fn"}`` answers
+    "which step recompiled": a batch of another signature raises it by one,
+    an unchanged one by none.  (The toy model pins the batch's shape to its
+    dims, so the changed signature is the tokens' dtype.)"""
+    telemetry.install_compile_listener()
+    telemetry.install_compile_listener()        # idempotent: no double count
+    trainer, batch = _toy_trainer(tmp_path)
+    state = trainer.init_state(batch())
+
+    def compiles(phase="backend"):
+        series = fresh_registry.snapshot().get(
+            "hbnlp_compiles_total", {}).get("series", {})
+        return series.get((phase, "step_fn"), 0)
+
+    assert compiles() == 0
+    state, _ = trainer.step(state, batch())
+    assert compiles() == 1 and compiles("lower") == 1
+    state, _ = trainer.step(state, batch())
+    assert compiles() == 1
+    state, _ = trainer.step(state, batch(np.uint8))
+    assert compiles() == 2
+    state, _ = trainer.step(state, batch(np.uint8))
+    assert compiles() == 2
+    seconds = fresh_registry.snapshot()["hbnlp_compile_seconds_total"]
+    assert seconds["series"][("backend", "step_fn")] > 0
+    assert seconds["series"][("trace", "step_fn")] > 0
+
+
+def telemetry_enabled_adds_no_per_step_sync_test(tmp_path, fresh_registry,
+                                                  monkeypatch):
+    """Turning the measurement on does not change what is measured:
+    ``train()`` under ``telemetry_enabled`` calls ``block_until_ready``
+    once (the end of ``init_state``), whatever the number of steps."""
+    import jax
+    from robustness_test import _train_cfg, _write_records
+    from homebrewnlp_tpu.run import train_loop as tl
+
+    calls = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: calls.append(1) or real(x))
+    data_dir = _write_records(tmp_path)
+    for steps in (4, 8):
+        del calls[:]
+        cfg = _train_cfg(tmp_path, data_dir, use_checkpointing=False,
+                         model_path=str(tmp_path / f"run{steps}"),
+                         train_steps=steps, telemetry_enabled=True)
+        tl.train(ModelParameter(cfg), log_every=100)
+        assert len(calls) == 1, (steps, len(calls))
+    spans = fresh_registry.snapshot()[telemetry.SPAN_METRIC]["series"]
+    assert sum(spans[("train/step_dispatch",)]["counts"]) == 12
+
+
+class _CountingRegistry(telemetry.Registry):
+    """Counts every metric lookup: each registry call on any path goes
+    through ``counter`` / ``gauge`` / ``histogram``, or through a child
+    bound by one of them."""
+
+    def __init__(self):
+        super().__init__()
+        self.lookups = 0
+
+    def _get_or_create(self, *args, **kwargs):
+        self.lookups += 1
+        return super()._get_or_create(*args, **kwargs)
+
+
+def telemetry_off_step_makes_no_registry_call_test(tmp_path):
+    """With ``telemetry_enabled`` false a steady-state step — queue wait,
+    placement, dispatch — makes no registry call; with it true the same
+    three sites each make one (the control that the counter can see them)."""
+    from homebrewnlp_tpu.data.inputs import Prefetcher
+    for enabled, expected in ((False, 0), (True, 9)):
+        counting = _CountingRegistry()
+        prev = telemetry.set_registry(counting)
+        try:
+            trainer, batch = _toy_trainer(tmp_path,
+                                          telemetry_enabled=enabled)
+            state = trainer.init_state(batch())
+            feed = Prefetcher((batch() for _ in range(8)), depth=2,
+                              telemetry_label="t" if enabled else None)
+            try:
+                for _ in range(2):      # warm-up: the compile records
+                    state, _ = trainer.step(
+                        state, trainer.place_batch(next(feed)))
+                before = counting.lookups
+                snap = counting.snapshot()
+                for _ in range(3):
+                    state, _ = trainer.step(
+                        state, trainer.place_batch(next(feed)))
+                assert counting.lookups - before == expected, enabled
+                if not enabled:
+                    assert counting.snapshot() == snap
+            finally:
+                feed.close()
+        finally:
+            telemetry.set_registry(prev)
 
 
 def sigusr2_profile_capture_test(tmp_path, fresh_registry, monkeypatch):

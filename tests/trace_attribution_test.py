@@ -135,6 +135,15 @@ def instruction_table_chained_inheritance_test():
     table = cost_ledger.instruction_table(_CHAINED_HLO)
     assert table["fusion.2"]["op_name"].endswith("norm_0/mul")
     assert table["call.3"]["op_name"].endswith("norm_0/mul")
+    # data the compiler moves (a layout copy of a convert of a scoped value,
+    # no metadata on either) belongs to the scope that made the value; a
+    # copy of a parameter stays nameless
+    moved = cost_ledger.instruction_table(_CHAINED_HLO.replace(
+        "  ROOT %tuple.4", "  %convert.7 = bf16[4]{0} convert(f32[4]{0} "
+        "%call.3)\n  %copy.8 = bf16[4]{0} copy(bf16[4]{0} %convert.7)\n"
+        "  %copy.9 = f32[4]{0} copy(f32[4]{0} %x)\n  ROOT %tuple.4"))
+    assert moved["copy.8"]["op_name"].endswith("norm_0/mul")
+    assert moved["copy.9"]["op_name"] is None
 
 
 def attribute_events_test():
@@ -236,11 +245,10 @@ def ledger_inflated_negative_control_test():
     regression check (and an identical one must pass)."""
     stored = cost_ledger.load_ledger()
     assert stored is not None, "analysis/cost_ledger.json must be committed"
-    assert set(stored["entry_points"]) == {"train_step", "decode_chunk_step",
-                                           "prefill_entry_step", "eval_fn",
-                                           "engine_chunk_step",
-                                           "spec_chunk_step",
-                                           "paged_chunk_step"}
+    # the ledger covers exactly the audited entry points: the list lives in
+    # analysis/entry_points.py, not in a copy here
+    from homebrewnlp_tpu.analysis import entry_points
+    assert set(stored["entry_points"]) == set(entry_points.ENTRY_POINTS)
     clean = cost_ledger.ledger_audit(current=copy.deepcopy(stored))
     assert clean == []
     bad = copy.deepcopy(stored)
@@ -574,7 +582,16 @@ def committed_ledger_matches_fresh_build_test(audit_rig):
 def attribute_step_end_to_end_test(audit_rig, tmp_path, capsys):
     """PR acceptance: attribute_step on a CPU profile_steps-style capture
     of the audit model prints a per-scope table with >= 5 distinct model
-    scopes attributed and < 15% of device time unattributed."""
+    scopes attributed and < 15% of device time unattributed.
+
+    It shares no state with the tests before it: run as the first file of
+    its worker it read 6-12% alone and 40% in one loaded run of the whole
+    suite (PR 23).  The share is a TIME share on the CPU, and what was
+    unattributed — XLA:CPU's metadata-less layout copies and
+    ``wrapped_convert`` fusions, hundreds of tiny memory-bound thunks —
+    swells out of proportion when six workers compile at once.  With those
+    inherited from their operand's producer (``instruction_table``) the
+    share alone is 2-4%, which leaves the threshold its margin."""
     import jax
     trainer, state, batch = (audit_rig["trainer"], audit_rig["state"],
                              audit_rig["batch"])
