@@ -7,8 +7,9 @@ can be re-traced in isolation inside a ``jax.custom_vjp`` backward pass and
 still resolve the same parameter names.
 
 Two phases, haiku-style but in-tree:
-  * init: layer code runs once eagerly; ``get_param`` materialises numpy
-    values from per-name seeded initializers and records them.
+  * init: layer code runs once; ``get_param`` records each parameter and
+    its numpy value from a per-name seeded initializer — made on the spot,
+    or, under ``Model.init``'s abstract walk, by a pool of threads.
   * apply: same code path; ``get_param`` fetches arrays from the provided
     dict (casting storage/slice dtype -> calculation dtype).
 
@@ -19,7 +20,8 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import time
+import functools
+import math
 import typing
 import zlib
 
@@ -81,6 +83,10 @@ class Context:
         # config.matmul_accumulation); consumed by core.tensor.einsum and
         # propagated by ReplayBlock like quant_scales
         self.matmul_accumulation: typing.Optional[str] = None
+        # init mode under Model.init: the core.value_pool.ValuePool that
+        # makes the values while the walk goes on (new_param); None = each
+        # value is made where the walk meets it
+        self.value_pool = None
         self._rng_count = 0
 
     # -- naming ------------------------------------------------------------
@@ -164,56 +170,73 @@ def name_seed(name: str, seed: int) -> np.random.Generator:
                                                        zlib.crc32(name.encode())]))
 
 
-def init_value(initializer, name: str, seed: int, sizes) -> np.ndarray:
-    """One parameter's float32 value from its per-name seeded initializer
-    (host numpy), counted: ``hbnlp_init_values_total`` values made in
-    ``hbnlp_init_values_seconds_total`` seconds — the part of
-    ``setup/model_init`` that is not the graph walk.  Once a parameter, at
-    set-up: always recorded."""
-    t0 = time.monotonic()
+def init_value(initializer, name: str, seed: int, sizes, dtype) -> np.ndarray:
+    """One parameter's value: float32 from its per-name seeded initializer
+    (host numpy), cast to the stored ``dtype``.  It depends on nothing but
+    its arguments, so any thread may make it at any time
+    (``core/value_pool.py``).  Counted in ``hbnlp_init_values_total``."""
     value = np.asarray(initializer(name_seed(name, seed), sizes),
                        dtype=np.float32)
-    r = _registry()
-    r.counter("hbnlp_init_values_seconds_total",
-              "seconds in parameter initializers (host numpy)"
-              ).inc(time.monotonic() - t0)
-    r.counter("hbnlp_init_values_total",
-              "parameter values made by an initializer").inc()
-    return value
+    assert value.shape == tuple(sizes), (name, value.shape, sizes)
+    _registry().counter("hbnlp_init_values_total",
+                        "parameter values made by an initializer").inc()
+    return value.astype(dtype, copy=False)
 
 
-def get_param(name_leaf: str, dims, initializer, slice_dtype, calc_dtype
-              ) -> NamedTensor:
-    """Create (init) or fetch (apply) a parameter as a NamedTensor.
+def new_param(ctx: Context, name: str, dims, initializer, slice_dtype):
+    """Init mode: record parameter ``name`` and its value.  Under
+    ``Model.init`` the value is a job for ``ctx.value_pool`` and the walk,
+    which is abstract and never reads it, goes on with a stand-in of its
+    shape and stored dtype; without a pool it is made here.
 
     ``initializer(rng, sizes) -> np.ndarray`` runs in float32; stored in
     slice_dtype (the mtf VariableDType.slice_dtype analogue,
-    /root/reference/src/dataclass.py:253-255), computed in calc_dtype.
-    """
-    ctx = current()
-    name = ctx.full_name(name_leaf)
+    /root/reference/src/dataclass.py:253-255).  Host numpy, the "master"
+    copy, mtf Saver-style: device placement + sharding happen at train
+    setup, so init never touches an accelerator."""
+    if name in ctx.params:
+        raise ValueError(f"duplicate parameter {name}")
     dims = tuple(dims)
     sizes = tuple(d.size for d in dims)
-    if ctx.mode == "init":
-        if name in ctx.params:
-            raise ValueError(f"duplicate parameter {name}")
-        value = init_value(initializer, name, ctx.seed, sizes)
-        assert value.shape == sizes, (name, value.shape, sizes)
-        # init stores host numpy (the "master" copy, mtf Saver-style);
-        # device placement + sharding happen at train setup, so init never
-        # touches an accelerator.
-        ctx.params[name] = value.astype(slice_dtype)
-        ctx.param_dims[name] = dims
-        fan_in = getattr(initializer, "fan_in_names", None)
-        if fan_in:
-            ctx.param_fan_in[name] = tuple(fan_in)
+    make = functools.partial(init_value, initializer, name, ctx.seed, sizes,
+                             slice_dtype)
+    if ctx.value_pool is None:
+        ctx.params[name] = make()
+    else:
+        ctx.value_pool.submit(name, math.prod(sizes), make)
+        ctx.params[name] = jax.ShapeDtypeStruct(sizes, slice_dtype)
+    ctx.param_dims[name] = dims
+    fan_in = getattr(initializer, "fan_in_names", None)
+    if fan_in:
+        ctx.param_fan_in[name] = tuple(fan_in)
+
+
+def param_tensor(ctx: Context, name: str, dims, calc_dtype) -> NamedTensor:
+    """Parameter ``name`` of the context, touched, in calc_dtype."""
     if name not in ctx.params:
         raise KeyError(f"parameter {name} missing from provided params")
     if ctx.touched is not None and name not in ctx.touched:
         ctx.touched.append(name)
     data = ctx.params[name]
+    sizes = tuple(d.size for d in dims)
     assert tuple(data.shape) == sizes, (name, data.shape, sizes)
+    if isinstance(data, jax.ShapeDtypeStruct):
+        # init mode's stand-in for a value the pool is still making: the
+        # walk is abstract, a traced constant of the shape serves it
+        return nt(jax.lax.full(sizes, 0, calc_dtype), dims)
     return nt(materialize_param(ctx, name, data, calc_dtype), dims)
+
+
+def get_param(name_leaf: str, dims, initializer, slice_dtype, calc_dtype
+              ) -> NamedTensor:
+    """Create (init) or fetch (apply) a parameter as a NamedTensor, stored
+    in slice_dtype and computed in calc_dtype."""
+    ctx = current()
+    name = ctx.full_name(name_leaf)
+    dims = tuple(dims)
+    if ctx.mode == "init":
+        new_param(ctx, name, dims, initializer, slice_dtype)
+    return param_tensor(ctx, name, dims, calc_dtype)
 
 
 def materialize_param(ctx: Context, name: str, data, calc_dtype):

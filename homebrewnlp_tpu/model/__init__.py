@@ -12,6 +12,7 @@ checkpointed body (model/blocks.py).
 """
 from __future__ import annotations
 
+import time
 import typing
 
 import jax
@@ -25,6 +26,7 @@ from ..core.tensor import (NamedTensor, add_n, argmax, cast, concat,
                            dropout as tensor_dropout, einsum, equal,
                            nt, ones, reciprocal, reduce_sum, sigmoid, sign,
                            slice_, sqrt, square, weighted_add)
+from ..core.value_pool import ValuePool
 from .backend import linear_from_features, linear_to_features
 from .blocks import BlockSpec, run_body_blocks
 from .embedding import batched_gather, embed, gather_embed
@@ -272,7 +274,9 @@ class Model:
 
         The forward pass is traced abstractly (eval_shape) so init performs
         no device computation at all — parameters are numpy master copies;
-        the trainer device_puts them with their NamedShardings.
+        the trainer device_puts them with their NamedShardings.  The walk
+        only names each value; a pool of host threads makes them meanwhile
+        (core/value_pool.py), and init returns when the last one is stored.
         """
         ctx = scope.Context("init", seed=self.params.data_seed if seed is None else seed,
                             record_touched=True)
@@ -284,11 +288,26 @@ class Model:
                 info, self.plan = build(self.params, *args, plan=None)
             return info.total_loss
 
-        # once a run: the graph walk in init mode plus every parameter's
-        # host-numpy value (core/scope.init_value counts the latter apart)
-        with telemetry.span("setup/model_init"):
+        # once a run: the graph walk in init mode, then the wait for the
+        # values still being made — hbnlp_init_values_seconds_total, in wall
+        # seconds inside the span, so span - counter stays the walk
+        with telemetry.span("setup/model_init"), ValuePool() as pool:
+            ctx.value_pool = pool
             jax.eval_shape(_run, {k: jax.ShapeDtypeStruct(v.shape, v.dtype)
                                   for k, v in batch.items() if v is not None})
+            t0 = time.monotonic()
+            ctx.params.update(pool.finish())
+            waited = time.monotonic() - t0
+        r = telemetry.registry()
+        r.counter("hbnlp_init_values_seconds_total",
+                  "wall seconds Model.init waited for parameter values "
+                  "after its graph walk").inc(waited)
+        r.counter("hbnlp_init_values_cpu_seconds_total",
+                  "thread CPU seconds in parameter initializers and casts, "
+                  "over all workers").inc(pool.cpu_seconds)
+        r.gauge("hbnlp_init_workers",
+                "threads Model.init made its values on (1 = one after "
+                "another)").set(pool.workers)
         self.param_dims = dict(ctx.param_dims)
         self.param_fan_in = dict(ctx.param_fan_in)
         return ctx.params

@@ -90,22 +90,9 @@ def get_var(args: BlockArgs, shape: SHAPE, initializer) -> NamedTensor:
     name = ctx.full_name("var")
     canonical = _BLOCK_RE.sub(lambda m: f"{m.group(1)}block0_{m.group(3)}_{m.group(4)}/",
                               name)
-    sizes = tuple(d.size for d in shape)
     if ctx.mode == "init" and canonical not in ctx.params:
-        value = scope.init_value(initializer, canonical, ctx.seed, sizes)
-        ctx.params[canonical] = value.astype(params.slice_dtype)
-        ctx.param_dims[canonical] = tuple(shape)
-        fan_in = getattr(initializer, "fan_in_names", None)
-        if fan_in:
-            ctx.param_fan_in[canonical] = tuple(fan_in)
-    if canonical not in ctx.params:
-        raise KeyError(f"shared parameter {canonical} missing")
-    if ctx.touched is not None and canonical not in ctx.touched:
-        ctx.touched.append(canonical)
-    data = ctx.params[canonical]
-    from ..core.tensor import nt
-    return nt(scope.materialize_param(ctx, canonical, data,
-                                      params.calculation_dtype), shape)
+        scope.new_param(ctx, canonical, shape, initializer, params.slice_dtype)
+    return scope.param_tensor(ctx, canonical, shape, params.calculation_dtype)
 
 
 def orthogonal_var(args: BlockArgs, shape: SHAPE,

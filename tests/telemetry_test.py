@@ -484,8 +484,9 @@ def checkpoint_io_metrics_test(tmp_path, fresh_registry, monkeypatch):
 #: what a run records whatever ``telemetry_enabled`` says: once a run or at
 #: log / checkpoint / compile cadence, never per step
 _RARE_SERIES = {telemetry.SPAN_METRIC, "hbnlp_init_values_seconds_total",
-                "hbnlp_init_values_total", "hbnlp_compile_seconds_total",
-                "hbnlp_compiles_total"}
+                "hbnlp_init_values_total",
+                "hbnlp_init_values_cpu_seconds_total", "hbnlp_init_workers",
+                "hbnlp_compile_seconds_total", "hbnlp_compiles_total"}
 _RARE_SPANS = {"setup/data_first_batch", "setup/model_init",
                "setup/place_params", "setup/opt_init", "setup/init_wait",
                "train/metric_log", "train/checkpoint_save", "train/eval"}
@@ -623,8 +624,8 @@ def _toy_trainer(tmp_path, **overrides):
 
 def setup_spans_and_init_value_count_test(tmp_path, fresh_registry):
     """``Trainer.init_state`` splits itself: the model's graph walk, the
-    initializer calls counted one per parameter made, placement, optimizer
-    slots, and the one wait that closes it."""
+    wait for the values (counted one per parameter made), placement,
+    optimizer slots, and the one wait that closes it."""
     trainer, batch = _toy_trainer(tmp_path)
     state = trainer.init_state(batch())
     snap = fresh_registry.snapshot()
@@ -635,11 +636,35 @@ def setup_spans_and_init_value_count_test(tmp_path, fresh_registry):
     made = snap["hbnlp_init_values_total"]["series"][()]
     assert made == len(state.variables) > 0
     in_values = snap["hbnlp_init_values_seconds_total"]["series"][()]
-    assert 0 < in_values <= spans[("setup/model_init",)]["sum"]
+    assert 0 <= in_values <= spans[("setup/model_init",)]["sum"]
     # a second init makes the values again and counts them again
     trainer.init_state(batch())
     assert fresh_registry.snapshot()["hbnlp_init_values_total"] \
         ["series"][()] == 2 * made
+
+
+@pytest.mark.parametrize("cores", [1, 4])
+def init_counter_contract_test(tmp_path, fresh_registry, monkeypatch, cores):
+    """After one ``Model.init``: a value counted per parameter; the wall
+    seconds waited for values never more than the ``setup/model_init`` span
+    (the benchmark's ``init_trace_s`` = span - counter stays the walk, >= 0);
+    CPU seconds across the workers; the pool's width, 1 on one core."""
+    from homebrewnlp_tpu.core import value_pool
+    monkeypatch.setattr(value_pool, "usable_cores", lambda: cores)
+    trainer, batch = _toy_trainer(tmp_path)
+    variables = trainer.model.init(batch())
+    snap = fresh_registry.snapshot()
+
+    def series(name):
+        return snap[name]["series"][()]
+
+    span = snap[telemetry.SPAN_METRIC]["series"][("setup/model_init",)]
+    assert sum(span["counts"]) == 1
+    assert series("hbnlp_init_values_total") == len(variables) > 0
+    assert 0 <= series("hbnlp_init_values_seconds_total") <= span["sum"]
+    assert series("hbnlp_init_values_cpu_seconds_total") > 0
+    assert series("hbnlp_init_workers") == min(cores, len(variables)) >= 1
+    assert snap["hbnlp_init_workers"]["kind"] == "gauge"
 
 
 def compile_counter_counts_recompiles_test(tmp_path, fresh_registry):
