@@ -1,0 +1,16 @@
+"""Required train FLOPs per token of the ACTIVE parameters (3 x forward from
+``roofline/olmoe_costs.py``: top-k experts, causal scores, recomputation not
+credited) times the measured tokens/s/chip over the chip's bf16 peak,
+percent."""
+from ..roofline import costs, olmoe_costs
+
+LAYER = "L3_model_graph"
+MOVES = "train_tokens_per_sec_chip"
+
+
+def read(run):
+    rate = run.result.end_to_end.get("train_tokens_per_sec_chip")
+    if rate is None or "moe_top_k" not in run.config:
+        return None
+    peak = costs.peaks(run.result.device["kind"])["bf16_flops_per_s"]
+    return 100.0 * olmoe_costs.train_flops_per_token(run.config) * rate / peak

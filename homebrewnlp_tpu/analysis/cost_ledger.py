@@ -55,7 +55,9 @@ _PHASES = ("input", "body", "output", "loss")
 _SPECIAL = {"cache_read": "decode/cache_read",
             "cache_write": "decode/cache_write",
             "sampling": "decode/sampling",
-            "optimizer": "optimizer"}
+            "optimizer": "optimizer",
+            # the head matmul with its cross-entropy (model/__init__.py)
+            "head_loss": "head_loss"}
 #: model/frontend.py LAYER_FUNCTIONS keys (mirrored, not imported — this
 #: module must stay importable without jax); update together
 _LAYER_NAMES = frozenset((
@@ -63,7 +65,10 @@ _LAYER_NAMES = frozenset((
     "activation", "convolution", "dropout", "group_linear", "split_path",
     "feed_forward_product_key_memory", "product_key_memory",
     "reduced_half_linear", "transpose_sequence_features",
-    "bottleneck_group_linear", "sum_heads"))
+    "bottleneck_group_linear", "sum_heads", "moe"))
+#: the parts of layer ``moe`` (model/moe.py), each a scope of its own below
+#: ``body/moe``
+_MOE_PARTS = frozenset(("router", "dispatch", "experts", "combine"))
 
 
 def _unwrap(comp: str) -> str:
@@ -85,7 +90,8 @@ def scope_key(path: str) -> str:
     """Fold a name-stack / HLO ``op_name`` path into a coarse model scope.
 
     Keys: ``decode/cache_read|cache_write|sampling``, ``optimizer``,
-    ``input/embed``, ``input``, ``body/<layer>``, ``output/unembed``,
+    ``head_loss``, ``input/embed``, ``input``, ``body/<layer>``,
+    ``body/moe/router|dispatch|experts|combine``, ``output/unembed``,
     ``output``, ``loss``, ``unscoped``.  Transform decorations
     (``jvp``/``transpose``/``jit`` wrappers) are unwrapped, so forward and
     backward ops of one block fold into the same scope — per-block
@@ -102,6 +108,8 @@ def scope_key(path: str) -> str:
             phase = base
         elif phase is not None and layer is None and base in _LAYER_NAMES:
             layer = base
+        elif layer == "moe" and base in _MOE_PARTS:
+            return f"body/moe/{base}"
     if phase == "body" and layer is not None:
         return f"body/{layer}"
     if phase == "input":

@@ -190,6 +190,9 @@ class ModelParameter:
         # every N steps, run a forward-only routing probe and merge per-layer
         # expert utilization / dropped-token stats into the step metrics
         self.moe_metrics_interval = 0
+        # base of the rotary position embedding's frequencies (attention
+        # flag "rope"): feature pair i turns by pos * rope_theta^(-2i/width)
+        self.rope_theta = 10000.0
         self.pkm_axes = 2
         self.use_bit_fold_input_pipeline = False
         self.bit_fold_value = 4

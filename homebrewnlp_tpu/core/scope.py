@@ -79,6 +79,11 @@ class Context:
         # where no lax.scan/custom_vjp separates the layer trace from the
         # consumer — ReplayBlock propagates it into its per-block contexts.
         self.stats_sink: typing.Optional[list] = None
+        # when not None, layers append {name: scalar} dicts of per-step
+        # statistics (layer moe's expert load) that the block machinery
+        # returns out of its checkpoint / scan regions as explicit outputs
+        # (model/blocks.py); the plain residual strategies only
+        self.layer_stats: typing.Optional[list] = None
         # matmul-accumulation policy for bf16 einsums ("auto"/"f32"/"bf16",
         # config.matmul_accumulation); consumed by core.tensor.einsum and
         # propagated by ReplayBlock like quant_scales

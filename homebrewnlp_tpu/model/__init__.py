@@ -12,6 +12,7 @@ checkpointed body (model/blocks.py).
 """
 from __future__ import annotations
 
+import math
 import time
 import typing
 
@@ -28,17 +29,22 @@ from ..core.tensor import (NamedTensor, add_n, argmax, cast, concat,
                            slice_, sqrt, square, weighted_add)
 from ..core.value_pool import ValuePool
 from .backend import linear_from_features, linear_to_features
-from .blocks import BlockSpec, run_body_blocks
+from .blocks import BlockSpec, _merge_stats, run_body_blocks
 from .embedding import batched_gather, embed, gather_embed
 from .frontend import block_part_fn
 
-LossInfo = typing.NamedTuple("LossInfo", [("total_loss", typing.Any),
-                                          ("loss_list", list),
-                                          ("video_loss", typing.Any),
-                                          ("accuracy", typing.Any),
-                                          ("token_loss", typing.Any),
-                                          ("frame_out", typing.Any),
-                                          ("token_out", typing.Any)])
+class LossInfo(typing.NamedTuple):
+    total_loss: typing.Any
+    loss_list: list
+    video_loss: typing.Any
+    accuracy: typing.Any
+    token_loss: typing.Any
+    frame_out: typing.Any
+    token_out: typing.Any
+    #: {name: 1-D array over the layers that reported it} when
+    #: ``Model.apply(layer_stats=True)`` ran the plain residual stream
+    #: (layer moe's expert load; model/blocks.py), else None
+    layer_stats: typing.Optional[dict] = None
 
 
 def _default_ones(params: ModelParameter, inp) -> NamedTensor:
@@ -92,10 +98,21 @@ def _input(params: ModelParameter, vid, cat_msk_src, txt_src, vid_msk_src,
         intermediate = Dim(params.intermediate[0].name,
                            int(params.intermediate[0].size * params.vocab_weight_factorization))
         txt_args = base_args(txt_src, list(params.token_embedding))
-        txt = gather_embed(txt_args, [params.vocab_dim, intermediate], storage=storage)
+        # vocab_weight_factorization 0: no narrow table and projection, one
+        # row of all features a token, h = E[token]
+        direct = not params.vocab_weight_factorization
+        if direct and params.token_patch_size != 1:
+            raise ValueError("vocab_weight_factorization 0 (a direct "
+                             "embedding) needs token_patch_size 1")
+        txt = gather_embed(
+            txt_args, [params.vocab_dim] + (list(params.feature_dims)
+                                            if direct else [intermediate]),
+            storage=storage)
         txt = tensor_dropout(txt, params.train, 1 - params.input_dropout,
                              scope.current().next_rng())
-        txt = linear_to_features(base_args(txt), [params.token_patch_dim, intermediate])
+        txt = reduce_sum(txt, reduced_dim=params.token_patch_dim) if direct \
+            else linear_to_features(base_args(txt),
+                                    [params.token_patch_dim, intermediate])
 
         for config_idx, config in enumerate(params.input_block_config):
             txt = block_part_fn(params, config, txt, f'lang_inp{config_idx}')
@@ -119,7 +136,12 @@ def _body(params: ModelParameter, src: NamedTensor,
     return run_body_blocks(params, src, plan)
 
 
-def _output(params: ModelParameter, out: NamedTensor, spatial_ctx: Dim):
+def _output(params: ModelParameter, out: NamedTensor, spatial_ctx: Dim,
+            storage: typing.Optional[dict] = None):
+    """``storage`` (the build's own dict) receives ``head``: the head
+    matmul's two operands, from which ``_loss`` takes the cross-entropy
+    without the logits (model/loss.py).  ``token_out`` is made all the same;
+    a jitted caller that does not read it never computes it."""
     base_args = BlockArgs(params, out, [''])
     token_out = frame_out = None
 
@@ -134,6 +156,8 @@ def _output(params: ModelParameter, out: NamedTensor, spatial_ctx: Dim):
             new = [params.token_patch_dim, params.vocab_dim]
             old = list(params.feature_dims)
             emb = embed(base_args(list(params.output_embedding)), old + new)
+            if storage is not None:
+                storage["head"] = (token_out, emb)
             token_out = einsum([token_out, emb],
                                output_shape=shape_sub(token_out.dims, old) + new)
 
@@ -147,20 +171,37 @@ def _output(params: ModelParameter, out: NamedTensor, spatial_ctx: Dim):
     return frame_out, token_out
 
 
-def softmax_cross_entropy_with_logits(params: ModelParameter, logits: NamedTensor,
-                                      targets: NamedTensor) -> NamedTensor:
-    """Max-subtracted xent + z-loss (reference: src/mtf_wrapper.py:64-71)."""
-    from ..core.tensor import (exp, log, one_hot, reduce_max, stop_gradient,
-                               reduce_sum as rsum, constant)
-    max_logit = reduce_max(stop_gradient(logits), reduced_dim=params.vocab_dim)
-    log_z = log(rsum(exp(logits - max_logit), reduced_dim=params.vocab_dim)) + max_logit
-    tgt_size = targets.size
-    oh = one_hot(targets, params.vocab_dim, dtype=logits.dtype)
-    loss = einsum([logits - log_z, oh, constant(-1 / tgt_size, logits.dtype)], [])
-    if params.z_loss:
-        loss = loss + einsum([log_z, log_z,
-                              constant(params.z_loss / tgt_size, logits.dtype)], [])
-    return loss
+def softmax_cross_entropy_with_logits(params: ModelParameter,
+                                      logits: NamedTensor,
+                                      targets: NamedTensor,
+                                      head: typing.Optional[NamedTensor] = None
+                                      ) -> NamedTensor:
+    """Mean softmax cross-entropy + z-loss (reference:
+    src/mtf_wrapper.py:64-71) through ``model/loss.py``, which walks the
+    sequence in chunks and holds neither a float32 ``[tokens, vocab]`` array
+    beyond its chunk nor a one-hot of the targets.  With ``head`` (the
+    output embedding) ``logits`` is the head's INPUT and the matmul is part
+    of the walk.  Reported in the calculation dtype, as ever."""
+    from ..core.tensor import transpose_to
+    from .loss import head_xent
+    seq = [d for d in targets.dims if d.name == params.sequence_dim.name]
+    last = [params.token_patch_dim]
+    lead = [d for d in targets.dims if d not in seq + last]
+    inner = list(params.feature_dims) if head is not None \
+        else last + [params.vocab_dim]
+
+    def flat(t: NamedTensor, tail):
+        tail = list(tail)
+        data = transpose_to(t, lead + seq + tail).data
+        return data.reshape((math.prod(d.size for d in lead),
+                             math.prod(d.size for d in seq))
+                            + tuple(d.size for d in tail))
+
+    w = None if head is None else transpose_to(
+        head, list(params.feature_dims) + last + [params.vocab_dim]).data
+    loss = head_xent(flat(logits, inner), w, flat(targets, last),
+                     params.z_loss)
+    return nt(loss.astype(logits.dtype), ())
 
 
 def _loss(params: ModelParameter, frame_out, token_out, txt_tgt, loss_list,
@@ -183,6 +224,9 @@ def _loss(params: ModelParameter, frame_out, token_out, txt_tgt, loss_list,
             gathered = batched_gather(emb, txt_tgt, [params.head_dim])
             token_loss = token_loss - einsum([token_out, gathered], []) * 2
             token_loss = token_loss / (token_out.size * params.vocab_size)
+        elif "head" in storage:
+            token_loss = softmax_cross_entropy_with_logits(
+                params, storage["head"][0], txt_tgt, storage["head"][1])
         else:
             token_loss = softmax_cross_entropy_with_logits(params, token_out, txt_tgt)
         loss_list.append(token_loss)
@@ -223,7 +267,8 @@ def _build(params: ModelParameter, vid, cat_msk_src, cat_msk_tgt, txt_src,
     src, vid_tgt = scope.scoped("input", _input, params, vid, cat_msk_src,
                                 txt_src, vid_msk_src, spatial_ctx, storage)
     out, plan = scope.scoped("body", _body, params, src, plan)
-    frame_out, token_out = scope.scoped("output", _output, params, out, spatial_ctx)
+    frame_out, token_out = scope.scoped("output", _output, params, out,
+                                        spatial_ctx, storage)
     loss_list, token_loss, accuracy, video_loss = scope.scoped(
         "loss", _loss, params, frame_out, token_out, txt_tgt, loss_list,
         vid_msk_tgt, cat_msk_tgt, vid_tgt, storage)
@@ -316,16 +361,21 @@ class Model:
               batch: typing.Dict[str, jax.Array],
               rng: typing.Optional[jax.Array] = None,
               mesh: typing.Any = None,
-              stats_sink: typing.Optional[list] = None) -> LossInfo:
+              stats_sink: typing.Optional[list] = None,
+              layer_stats: bool = False) -> LossInfo:
         assert self.plan is not None, "call init() first (or assign .plan)"
         ctx = scope.Context("apply", params=variables, rng_key=rng, mesh=mesh)
         ctx.quant_scales = getattr(self, "quant_scales", None)
         ctx.matmul_accumulation = self.params.matmul_accumulation
         ctx.stats_sink = stats_sink
+        if layer_stats:
+            ctx.layer_stats = []
         with scope.context(ctx):
             args = self._named_inputs(batch)
             self.params.attention_idx = 0
             info, _ = build(self.params, *args, plan=self.plan)
+        if ctx.layer_stats:
+            info = info._replace(layer_stats=_merge_stats(ctx.layer_stats))
         return info
 
     def train_grads_1f1b(self, variables: typing.Dict[str, jax.Array],

@@ -59,7 +59,8 @@ class ReplayBlock:
 
     def __call__(self, subset: Subset, x: NamedTensor,
                  it: typing.Optional[jax.Array] = None,
-                 attn_stash: typing.Optional[dict] = None) -> NamedTensor:
+                 attn_stash: typing.Optional[dict] = None,
+                 layer_stats: typing.Optional[list] = None) -> NamedTensor:
         outer_rng = None
         outer_mesh = None
         outer_decode = None
@@ -89,6 +90,9 @@ class ReplayBlock:
         # by the strategy code — never inherited from the outer context, so
         # a mode can't leak across custom_vjp replay boundaries
         ctx.attn_stash = attn_stash
+        # per-step layer statistics (core/scope.py Context.layer_stats): the
+        # caller's own list, so that it can return them out of its region
+        ctx.layer_stats = layer_stats
         if outer_rng is not None:
             # `it` is the (possibly traced) depth index under scan-over-layers
             idx = self.depth_idx if it is None else it
@@ -402,24 +406,47 @@ def _checkpoint_policy(params: ModelParameter):
                    params.gradient_checkpointing_policy)
 
 
+def _merge_stats(parts) -> dict:
+    """One ``{name: 1-D array}`` from layers' ``{name: scalar}`` reports, or
+    from blocks' (or a scan's stacked) merged ones, in execution order."""
+    return {k: jnp.concatenate([jnp.reshape(p[k], (-1,))
+                                for p in parts if k in p])
+            for k in sorted({k for p in parts for k in p})}
+
+
+def _block_with_stats(f, collect: bool):
+    """``(subset, x, it) -> (out, {name: [n] array})``: the block, and the
+    statistics its layers reported (``Context.layer_stats``) as an explicit
+    output, so that they can leave a checkpoint or scan region."""
+    def call(subset, x, it=None):
+        if not collect:
+            return f(subset, x, it=it), {}
+        sink: list = []
+        out = f(subset, x, it=it, layer_stats=sink)
+        return out, _merge_stats(sink)
+    return call
+
+
 def _plain_scan(fns, stacked, shared, x, use_checkpoint: bool,
-                unroll: int = 1, ckpt_policy=None):
+                unroll: int = 1, ckpt_policy=None, collect: bool = False):
     """Scanned 'checkpoint' / 'none' strategies: O(depth) carries saved by
-    scan AD; with use_checkpoint each block recomputes its interior."""
+    scan AD; with use_checkpoint each block recomputes its interior.
+    Returns the output and the layers' statistics (empty unless
+    ``collect``)."""
     def step(carry, sl):
         x, it = carry
+        parts = []
         for f, stk, shr in zip(fns, sl, shared):
+            call = _block_with_stats(f, collect)
             if use_checkpoint:
-                x = jax.checkpoint(
-                    lambda sub, x_, it_, f_=f: f_(sub, x_, it=it_),
-                    policy=ckpt_policy,
-                )({**stk, **shr}, x, it)
-            else:
-                x = f({**stk, **shr}, x, it=it)
-        return (x, it + 1), None
+                call = jax.checkpoint(call, policy=ckpt_policy)
+            x, stats = call({**stk, **shr}, x, it)
+            parts.append(stats)
+        return (x, it + 1), _merge_stats(parts)
 
-    (x, _), _ = jax.lax.scan(step, (x, jnp.int32(0)), stacked, unroll=unroll)
-    return x
+    (x, _), stats = jax.lax.scan(step, (x, jnp.int32(0)), stacked,
+                                 unroll=unroll)
+    return x, stats
 
 
 def _strategy_scan_save(params: ModelParameter, fns, stacked, shared, src,
@@ -561,8 +588,13 @@ def _try_scan(params: ModelParameter, ctx, plan, src: NamedTensor,
         x, v = momentum_scan(fns, params.momentumnet_alpha, params.scan_unroll,
                              stacked, shared, src, src, stash)
         return x + v
-    return _plain_scan(fns, stacked, shared, src, strategy == "checkpoint",
-                       params.scan_unroll, _checkpoint_policy(params))
+    out, stats = _plain_scan(fns, stacked, shared, src,
+                             strategy == "checkpoint", params.scan_unroll,
+                             _checkpoint_policy(params),
+                             collect=ctx.layer_stats is not None)
+    if stats:
+        ctx.layer_stats.append(_merge_stats([stats]))
+    return out
 
 
 def _forward_recurrence(strategy: str, alpha: float, pairs, carry,
@@ -956,13 +988,15 @@ def run_body_blocks(params: ModelParameter, src: NamedTensor,
         x, v = momentum_sequence(tuple(fns), params.momentumnet_alpha,
                                  tuple(subsets), src, src, stash)
         return x + v, plan
-    if strategy == "checkpoint":
-        out = src
-        for f, s in zip(fns, subsets):
-            out = jax.checkpoint(f, policy=_checkpoint_policy(params))(s, out)
-        return out, plan
-    # none
-    out = src
+    # checkpoint / none: the plain stream, each block's layer statistics
+    # an explicit output of its region
+    out, parts = src, []
     for f, s in zip(fns, subsets):
-        out = f(s, out)
+        call = _block_with_stats(f, ctx.layer_stats is not None)
+        if strategy == "checkpoint":
+            call = jax.checkpoint(call, policy=_checkpoint_policy(params))
+        out, stats = call(s, out)
+        parts.append(stats)
+    if any(parts):
+        ctx.layer_stats.append(_merge_stats(parts))
     return out, plan

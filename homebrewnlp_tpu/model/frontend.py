@@ -15,6 +15,7 @@ from .basic import (bottleneck_group_linear, dropout, feed_forward,
                     feed_forward_product_key_memory, group_linear,
                     product_key_memory, reduced_half_linear, rezero, sum_heads,
                     transpose_sequence_features)
+from .moe import moe
 from .normalization import norm
 from .spatial import attention, cummean, cumsum
 
@@ -133,4 +134,5 @@ LAYER_FUNCTIONS = {'feed_forward': feed_forward,
                    'transpose_sequence_features': transpose_sequence_features,
                    'bottleneck_group_linear': bottleneck_group_linear,
                    'sum_heads': sum_heads,
+                   'moe': moe,
                    }

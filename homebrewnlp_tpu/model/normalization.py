@@ -2,7 +2,8 @@
 
 Mean-subtract + RMS rescale with optional learned scale/shift.  The 'group'
 flag keeps the head dim out of the normalized axes, giving per-head groupnorm
-over features_per_head only (normalization.py:22-34).
+over features_per_head only (normalization.py:22-34).  The 'rms' flag leaves
+the mean where it is: ``x * rsqrt(mean(x^2) + eps)``, RMSNorm.
 
 The computation runs through a fused ``jax.custom_vjp`` core: statistics are
 computed in one f32 pass (E[x] and E[x^2] share the read), the output in a
@@ -28,20 +29,29 @@ from .backend import normal_var
 from .utils import linear_shapes
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _norm_core(x, scale, shift, axes: typing.Tuple[int, ...], eps: float,
-               has_scale: bool, has_shift: bool):
-    y, _, _ = _norm_fwd_impl(x, scale, shift, axes, eps, has_scale, has_shift)
+               has_scale: bool, has_shift: bool, center: bool = True):
+    y, _, _ = _norm_fwd_impl(x, scale, shift, axes, eps, has_scale, has_shift,
+                             center)
     return y
 
 
-def _norm_fwd_impl(x, scale, shift, axes, eps, has_scale, has_shift):
+def _norm_fwd_impl(x, scale, shift, axes, eps, has_scale, has_shift,
+                   center=True):
     xf = x.astype(jnp.float32)
-    mu = jnp.mean(xf, axis=axes, keepdims=True)
-    # E[x^2] - mu^2 == E[(x-mu)^2]: both reductions share one read of x.
-    # Unlike the subtractive form this can cancel to a small NEGATIVE value
-    # when |mu| >> std, and rsqrt(negative) is NaN — clamp at 0
-    var = jnp.mean(jnp.square(xf), axis=axes, keepdims=True) - jnp.square(mu)
+    if center:
+        mu = jnp.mean(xf, axis=axes, keepdims=True)
+        # E[x^2] - mu^2 == E[(x-mu)^2]: both reductions share one read of
+        # x.  Unlike the subtractive form this can cancel to a small
+        # NEGATIVE value when |mu| >> std, and rsqrt(negative) is NaN —
+        # clamp at 0
+        var = jnp.mean(jnp.square(xf), axis=axes, keepdims=True) \
+            - jnp.square(mu)
+    else:
+        # RMSNorm: the mean of squares itself, nothing subtracted
+        mu = jnp.zeros((1,) * x.ndim, jnp.float32)
+        var = jnp.mean(jnp.square(xf), axis=axes, keepdims=True)
     inv = jax.lax.rsqrt(jnp.maximum(var, 0.0) + eps)
     y = (xf - mu) * inv
     if has_scale:
@@ -51,19 +61,21 @@ def _norm_fwd_impl(x, scale, shift, axes, eps, has_scale, has_shift):
     return y.astype(x.dtype), mu, inv
 
 
-def _norm_fwd(x, scale, shift, axes, eps, has_scale, has_shift):
-    y, mu, inv = _norm_fwd_impl(x, scale, shift, axes, eps, has_scale, has_shift)
+def _norm_fwd(x, scale, shift, axes, eps, has_scale, has_shift, center=True):
+    y, mu, inv = _norm_fwd_impl(x, scale, shift, axes, eps, has_scale,
+                                has_shift, center)
     return y, (x, scale, shift, mu, inv)
 
 
-def _norm_bwd_xla(axes, eps, has_scale, has_shift, res, dy):
+def _norm_bwd_xla(axes, eps, has_scale, has_shift, res, dy, center=True):
     x, scale, shift, mu, inv = res
     xf = x.astype(jnp.float32)
     dyf = dy.astype(jnp.float32)
     xhat = (xf - mu) * inv
     g = dyf * scale.astype(jnp.float32) if has_scale else dyf
-    m1 = jnp.mean(g, axis=axes, keepdims=True)
     m2 = jnp.mean(g * xhat, axis=axes, keepdims=True)
+    # without centering the mean's own gradient path (m1) does not exist
+    m1 = jnp.mean(g, axis=axes, keepdims=True) if center else 0.0
     dx = ((g - m1 - xhat * m2) * inv).astype(x.dtype)
     # param cotangents reduce over the axes the (broadcast-shaped) params
     # have size 1; zeros for the unused placeholder operands
@@ -192,14 +204,15 @@ def _norm_bwd_pallas(axes, eps, has_scale, has_shift, res, dy,
 # hit HBM as standalone tensors — costing more than the saved reduction
 # passes.  Kept (tested, numerics-pinned) for layouts where the fusion
 # context differs; enable with HBNLP_NORM_BWD_PALLAS=1.
-def _norm_bwd(axes, eps, has_scale, has_shift, res, dy):
+def _norm_bwd(axes, eps, has_scale, has_shift, center, res, dy):
     import os
-    if ((has_scale or has_shift) and jax.default_backend() == "tpu"
+    if (center and (has_scale or has_shift)
+            and jax.default_backend() == "tpu"
             and os.environ.get("HBNLP_NORM_BWD_PALLAS") == "1"):
         out = _norm_bwd_pallas(axes, eps, has_scale, has_shift, res, dy)
         if out is not None:
             return out
-    return _norm_bwd_xla(axes, eps, has_scale, has_shift, res, dy)
+    return _norm_bwd_xla(axes, eps, has_scale, has_shift, res, dy, center)
 
 
 _norm_core.defvjp(_norm_fwd, _norm_bwd)
@@ -225,5 +238,6 @@ def norm(args: BlockArgs, feature_shape: typing.Optional[SHAPE] = None) -> Named
         if has_scale else one
     shift = _align(normal_var(args, feature_shape, mean=0), block_input.dims) \
         if has_shift else one
-    out = _norm_core(x, scale, shift, axes, 1e-5, has_scale, has_shift)
+    out = _norm_core(x, scale, shift, axes, 1e-5, has_scale, has_shift,
+                     "rms" not in args.name_extras)
     return nt(out, block_input.dims)

@@ -165,29 +165,37 @@ def _maybe_flash_attention(args: BlockArgs, dim: Dim, qry: NamedTensor,
     if qkv is None:
         return None
     q, k, v, canonical, shp = qkv
-    from ..parallel.flash_attention import attention as flash
+    # scale 1.0: qry already carries the reference scale
+    out = _flash(ctx, q, k, v, 1.0)
+    out_nt = nt(out.reshape([d.size for d in canonical]), canonical)
+    return transpose_to(out_nt, args.tensor.dims)
 
+
+def _flash(ctx, q, k, v, scale: float):
+    """Causal flash attention on ``[lead, seq, heads, features]`` arrays: the
+    kernel itself single-device, per device under shard_map on a data x model
+    mesh (batch on 'data', heads on 'model'; the sequence is whole, so local
+    causality is global causality)."""
+    from ..parallel.flash_attention import attention as flash
+    mesh = ctx.mesh
     if mesh is None:
         # causal=True always: the dense softmax branch masks unconditionally.
         # attn_stash: the strategy machinery's attention-output stash channel
         # (model/blocks.py) — single-device path only; the shard_map branch
         # keeps the plain kernel
-        out = flash(q, k, v, scale=1.0, causal=True,
-                    stash=getattr(ctx, "attn_stash", None))
-    else:
-        from jax.sharding import PartitionSpec as P
+        return flash(q, k, v, scale=scale, causal=True,
+                     stash=getattr(ctx, "attn_stash", None))
+    from jax.sharding import PartitionSpec as P
 
-        from jax import shard_map
-        spec = P(shardlib.DATA_AXIS if shardlib.DATA_AXIS in mesh.axis_names
-                 else None, None,
-                 shardlib.MODEL_AXIS if shardlib.MODEL_AXIS in mesh.axis_names
-                 else None, None)
-        out = shard_map(
-            lambda q_, k_, v_: flash(q_, k_, v_, scale=1.0, causal=True),
-            mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            check_vma=False)(q, k, v)
-    out_nt = nt(out.reshape([d.size for d in canonical]), canonical)
-    return transpose_to(out_nt, args.tensor.dims)
+    from jax import shard_map
+    spec = P(shardlib.DATA_AXIS if shardlib.DATA_AXIS in mesh.axis_names
+             else None, None,
+             shardlib.MODEL_AXIS if shardlib.MODEL_AXIS in mesh.axis_names
+             else None, None)
+    return shard_map(
+        lambda q_, k_, v_: flash(q_, k_, v_, scale=scale, causal=True),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False)(q, k, v)
 
 
 def _masked_map(args: BlockArgs) -> typing.Tuple[NamedTensor, typing.Union[NamedTensor, int]]:
@@ -328,9 +336,94 @@ def cummean(args: BlockArgs) -> NamedTensor:
     return cumsum(args) / (1 + range_(dim, args.tensor.dtype))
 
 
+def rotary(x, theta: float):
+    """Rotary position embedding on ``x [..., seq, heads, width]``, HF's
+    rotate-half convention over the whole head width: feature ``i`` pairs
+    with ``i + width/2``, both turn by ``pos * theta ** (-2i / width)``.
+    Computed in float32, returned in ``x``'s dtype."""
+    import jax.numpy as jnp
+    seq, width = x.shape[-3], x.shape[-1]
+    half = width // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2 / width)
+    angle = jnp.arange(seq, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    cos = jnp.cos(angle)[:, None, :]
+    sin = jnp.sin(angle)[:, None, :]
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :half], xf[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def _rope_attention(args: BlockArgs) -> NamedTensor:
+    """The standard pre-norm transformer's attention (flag ``rope``): key,
+    query and value are three bias-free projections of the block's input to
+    all heads (no bottleneck), with ``qk_norm`` an RMSNorm with a learned
+    scale over ALL heads' features of the query and of the key before the
+    head split (OLMoE), rotary positions on both (``rope_theta``), causal
+    ``softmax(q k^T / sqrt(features_per_head)) v`` through the flash kernel,
+    and an output projection.  Weights are normal(0.02).  Training and
+    full-sequence forward only: a decode step for it is a later issue."""
+    import jax
+    from ..core import scope as scope_mod
+    from ..core.tensor import nt, rename_dim, transpose_to
+    from .backend import normal_var
+    from .normalization import norm
+    from .utils import anonymize_dim
+    params = args.params
+    ctx = scope_mod.current()
+    dim = get_attention_dim(args).dim
+    if ctx.decode is not None or decode_mod.prefill_active() is not None:
+        raise NotImplementedError(
+            "attention-rope has no incremental decode / prefill form yet")
+    mesh = ctx.mesh
+    if mesh is not None and (mesh.shape.get(shardlib.SEQUENCE_AXIS, 1) > 1
+                             or mesh.shape.get(shardlib.PIPE_AXIS, 1) > 1):
+        raise NotImplementedError(
+            "attention-rope on a sequence- or pipe-sharded mesh")
+    feats = list(params.feature_dims)
+    anon = [anonymize_dim(d) for d in feats]
+
+    def project(x: NamedTensor) -> NamedTensor:
+        """All features -> all features, the input's feature dims renamed so
+        that the einsum contracts them."""
+        for d, a in zip(feats, anon):
+            x = rename_dim(x, d.name, a.name)
+        return einsum([x, normal_var(args, anon + feats)],
+                      shape_sub(x.dims, anon) + feats)
+
+    # creation order: key, query, value (as the dense path), their norms
+    key, qry, val = (project(args.tensor) for _ in range(3))
+    if "qk_norm" in args.name_extras:
+        qry = norm(args(qry, ["rms", "scale"]), feats)
+        key = norm(args(key, ["rms", "scale"]), feats)
+    canonical = [d for d in args.tensor.dims if d not in [dim] + feats] \
+        + [dim] + feats
+    lead = 1
+    for d in canonical[:-3]:
+        lead *= d.size
+    shp = (lead, dim.size, params.head_dim.size, params.key_dim.size)
+    q, k, v = (transpose_to(t, canonical).data.reshape(shp)
+               for t in (qry, key, val))
+    with jax.named_scope("rope"):
+        q = rotary(q, params.rope_theta)
+        k = rotary(k, params.rope_theta)
+    scale = params.key_dim.size ** -0.5
+    if params.use_flash_attention:
+        out = _flash(ctx, q, k, v, scale)
+    else:
+        from ..parallel.flash_attention import _xla_reference
+        with jax.named_scope("attention_dense"):
+            out = _xla_reference(q, k, v, scale, True)
+    out_nt = transpose_to(nt(out.reshape([d.size for d in canonical]),
+                             canonical), args.tensor.dims)
+    return project(out_nt)
+
+
 def attention(args: BlockArgs) -> NamedTensor:
     params = args.params
     params.attention_idx += 1
+    if "rope" in args.name_extras:
+        return _rope_attention(args)
     base = None
     if "dot_product" in args.name_extras or "input_as_value" not in args.name_extras:
         base = args(activated_linear_in(args))

@@ -15,6 +15,7 @@ Replaces the reference's TF1 session loop + TPUEstimator machinery
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import functools
 import typing
@@ -63,8 +64,17 @@ def _local_batch_dims(p: ModelParameter, local: int):
 
 
 def _info_metrics(info) -> typing.Dict[str, jax.Array]:
-    """Loss/accuracy metrics from a model BuildInfo (None -> 0)."""
+    """Loss/accuracy metrics from a model BuildInfo (None -> 0), and what
+    its layers reported of themselves (``LossInfo.layer_stats``): layer
+    moe's worst expert load and the (token, choice) pairs it routed."""
+    stats = getattr(info, "layer_stats", None) or {}
+    extra = {}
+    if "moe_routed_pairs" in stats:
+        extra = {"moe_load_max_over_mean":
+                 jnp.max(stats["moe_load_max_over_mean"]),
+                 "moe_routed_pairs": jnp.sum(stats["moe_routed_pairs"])}
     return {
+        **extra,
         "loss": info.total_loss.data.astype(jnp.float32),
         "token_loss": (info.token_loss.data.astype(jnp.float32)
                        if info.token_loss is not None else jnp.float32(0)),
@@ -108,6 +118,9 @@ class Trainer:
         # (zero registry calls on the hot path when off); the trace
         # annotation is written either way
         self._record_steps = bool(params.telemetry_enabled)
+        # (worst expert load, routed pairs) of steps already dispatched,
+        # waiting for the device to finish them (_publish_layer_stats)
+        self._pending_layer_stats: collections.deque = collections.deque()
         # resolved lazily on the first traced step (warns once on fallback)
         self._grad_allreduce_resolved: typing.Optional[str] = None
 
@@ -405,7 +418,8 @@ class Trainer:
                     v, self.model.param_dims,
                     getattr(self.model, "param_fan_in", {}),
                     p.calculation_dtype)
-            info = self.model.apply(v, batch, rng, mesh=mesh)
+            info = self.model.apply(v, batch, rng, mesh=mesh,
+                                    layer_stats=self._record_steps)
             return (info.total_loss.data if idx is None
                     else info.loss_list[idx].data), info
 
@@ -600,7 +614,31 @@ class Trainer:
                                          + self._rng_counter)
             if self.mesh is not None and not self._batch_placed(batch):
                 batch = shardlib.shard_batch(self.params, batch, self.mesh)
-            return self._step_fn(state, batch, rng)
+            state, metrics = self._step_fn(state, batch, rng)
+            if "moe_routed_pairs" in metrics:
+                self._publish_layer_stats(metrics)
+            return state, metrics
+
+    def _publish_layer_stats(self, metrics) -> None:
+        """``hbnlp_moe_load_max_over_mean`` and
+        ``hbnlp_moe_routed_pairs_total`` (under ``telemetry_enabled``: only
+        then does the step report them) from the scalars of EARLIER steps
+        the device has finished; a step still running is left for a later
+        call, so this never waits.  The last steps of a run stay unread."""
+        pending = self._pending_layer_stats
+        pending.append((metrics["moe_load_max_over_mean"],
+                        metrics["moe_routed_pairs"]))
+        r = telemetry.registry()
+        gauge = r.gauge("hbnlp_moe_load_max_over_mean",
+                        "pairs of the busiest expert over the mean, worst "
+                        "moe layer of the newest finished step")
+        counter = r.counter("hbnlp_moe_routed_pairs_total",
+                            "(token, choice) pairs routed to an expert, all "
+                            "moe layers")
+        while pending and all(v.is_ready() for v in pending[0]):
+            load, pairs = pending.popleft()
+            gauge.set(float(load))
+            counter.inc(float(pairs))
 
     def eval_loss(self, state: TrainState,
                   batch: typing.Dict[str, jax.Array]
