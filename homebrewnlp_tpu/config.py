@@ -262,8 +262,9 @@ class ModelParameter:
         # when the sequence is long enough to pay and the stash fits a small
         # HBM fraction (model/blocks.py resolve_stash) — the measured 16k/32k
         # recipes then need no explicit flag.
-        # DEPRECATED ALIAS (PR 11): an explicit true/false here maps onto
-        # remat_policy "stash"/"recompute" when remat_policy is "auto"; the
+        # DEPRECATED ALIAS (PR 11): with remat_policy "auto" an explicit
+        # true forces the attention kind of the stash on (the bottleneck
+        # kind still resolves by its own rule), false is "recompute"; the
         # policy layer below is the real knob
         self.stash_attention_outputs = "auto"
         # ---- measured remat policy (model/remat.py, docs/PERFORMANCE.md
@@ -272,10 +273,14 @@ class ModelParameter:
         #   "recompute"  — the strategy custom_vjp re-runs each block's
         #                  forward inside jax.vjp (O(1) activation memory;
         #                  the historical default behavior),
-        #   "stash"      — recompute, but each flash/ring attention layer's
-        #                  (out, lse) rides the strategy residuals so the
-        #                  backward replay runs no forward attention kernels
-        #                  (the old stash_attention_outputs=true),
+        #   "stash"      — recompute, but what is dear to replay per byte
+        #                  rides the strategy residuals, both kinds: each
+        #                  flash/ring attention layer's (out, lse) (no
+        #                  forward attention kernels in the replay; the old
+        #                  stash_attention_outputs=true) and
+        #                  bottleneck_group_linear's in-projection output
+        #                  (no second matmul and, where it contracts a
+        #                  mesh-sharded axis, no second all-reduce),
         #   "save"       — NO custom_vjp: the plain recurrence under native
         #                  scan AD, every linearization residual saved
         #                  (zero recompute, O(depth) residual memory),
@@ -283,8 +288,11 @@ class ModelParameter:
         #                  (policy dots_saveable): GEMM outputs saved,
         #                  elementwise recomputed — the middle ground for
         #                  compute-bound chips with spare HBM,
-        #   "auto"       — the old stash auto rule (stash when long-context
-        #                  pays and fits, else recompute); the save modes
+        #   "auto"       — each stash kind by its own rule (attention:
+        #                  long-context pays and fits; bottleneck: its
+        #                  contraction crosses a 'model' axis > 1 and the
+        #                  bytes fit what attention leaves of the budget),
+        #                  else recompute; the save modes
         #                  are measured opt-ins — the round-11 A/B lost on
         #                  the hbm-bound rig and model/remat.py documents
         #                  the analytic comparison (remat_report) for

@@ -108,13 +108,16 @@ def normal_var(args: BlockArgs, shape: SHAPE, stddev: float = 0.02,
     return scope.scoped("normal_var", get_var, args, shape, NormalInit(stddev, mean))
 
 
-def linear(args: BlockArgs, old: SHAPE, new: SHAPE) -> NamedTensor:
-    """einsum(x, W[old+new]) -> x.shape - old + new (backend.py:108-110)."""
+def linear(args: BlockArgs, old: SHAPE, new: SHAPE, contract=einsum
+           ) -> NamedTensor:
+    """einsum(x, W[old+new]) -> x.shape - old + new (backend.py:108-110).
+    ``contract`` stands in for the einsum (same inputs, same output dims):
+    the replay-stashed in-projection of model/basic.py."""
     old = list(old)
     new = list(new)
     var = orthogonal_var(args, old + new, old)
     out_shape = deduplicate([d for d in args.tensor.dims if d not in old] + new)
-    return einsum([args.tensor, var], out_shape)
+    return contract([args.tensor, var], out_shape)
 
 
 def linear_to_features(args: BlockArgs,

@@ -230,13 +230,69 @@ def routed_mixture_of_experts(args: BlockArgs) -> NamedTensor:
                         shape_sub(x.dims, old) + list(new))
 
 
-def activated_linear(args: BlockArgs, prefix: str) -> NamedTensor:
+def _provided_contract(inputs, output_shape, provided: jax.Array
+                       ) -> NamedTensor:
+    """The einsum whose value the forward pass already made: ``provided``
+    IS the primal output, and the backward is the einsum's own (the input's
+    cotangent from the weight, the weight's from the replayed input) — so
+    neither the matmul nor, where it contracts a mesh-sharded axis, its
+    all-reduce runs again."""
+    dims = [t.dims for t in inputs]
+    ctx = scope.current()
+
+    def contract(*arrays):
+        # the backward is traced after the block's scope has closed; the
+        # einsum reads its accumulation policy from it
+        with scope.context(ctx):
+            return einsum([nt(a, d) for a, d in zip(arrays, dims)],
+                          output_shape).data
+
+    @jax.custom_vjp
+    def provide(y, *arrays):
+        return y
+
+    def fwd(y, *arrays):
+        return y, arrays
+
+    def bwd(arrays, ct):
+        # the forward value inside jax.vjp is dead code: nothing reads it
+        return (jnp.zeros_like(ct), *jax.vjp(contract, *arrays)[1](ct))
+
+    provide.defvjp(fwd, bwd)
+    return nt(provide(provided, *[t.data for t in inputs]), output_shape)
+
+
+def replay_stashed_linear(args: BlockArgs, chan: dict) -> NamedTensor:
+    """``wrapped_linear`` whose output rides the strategy residuals through
+    the replay stash channel (model/blocks.py; kind "bottleneck",
+    model/remat.py decides): the forward rule's trace pushes the — already
+    all-reduced — output, the backward replay pops it instead of making it
+    again.  The layout is pinned (batch on 'data', replicated over
+    'model') on both sides so that GSPMD does not re-shard the stack."""
+    from ..core.sharding import with_constraint
+    from .blocks import stash_collecting, stash_pop, stash_push
+    params, mesh = args.params, scope.current().mesh
+    if stash_collecting(chan):
+        out = with_constraint(wrapped_linear(args), params, mesh)
+        stash_push(chan, out.data)
+        return out
+    provided = stash_pop(chan)
+
+    def contract(inputs, output_shape):
+        y = with_constraint(nt(provided, output_shape), params, mesh)
+        return _provided_contract(inputs, output_shape, y.data)
+
+    return linear(args, *linear_shapes(args), contract=contract)
+
+
+def activated_linear(args: BlockArgs, prefix: str,
+                     linear_fn=wrapped_linear) -> NamedTensor:
     args = args([a[len(prefix):] for a in args if a.startswith(prefix)])
     if "mixture_of_experts" in args.name_extras:
         feed_forward_fn = routed_mixture_of_experts \
             if "routed" in args.name_extras else mixture_of_experts
     else:
-        feed_forward_fn = wrapped_linear
+        feed_forward_fn = linear_fn
     out = dropout(args(activate(args(feed_forward_fn(args)))))
     if "glu" in args.name_extras or "glu_add" in args.name_extras:
         out = multiply(out, sigmoid(feed_forward_fn(args)))
@@ -331,7 +387,11 @@ def feed_forward_product_key_memory(args: BlockArgs) -> NamedTensor:
 def bottleneck_group_linear(args: BlockArgs) -> NamedTensor:
     """features -> bottleneck(intermediate) -> widened grouped mid -> grouped
     out (basic.py:122-126); the workhorse of the flagship mixer configs."""
-    args = args(activated_linear_in(args))
+    from .blocks import stash_channel
+    chan = stash_channel(scope.current(), "bottleneck")
+    linear_in = wrapped_linear if chan is None \
+        else functools.partial(replay_stashed_linear, chan=chan)
+    args = args(activated_linear(args, "in:", linear_in))
     args.name_extras.extend(["group", "mid:group", "out:group"])
     args = args(activated_linear(args, "mid:"))
     return activated_linear_out(args)

@@ -59,7 +59,7 @@ class ReplayBlock:
 
     def __call__(self, subset: Subset, x: NamedTensor,
                  it: typing.Optional[jax.Array] = None,
-                 attn_stash: typing.Optional[dict] = None,
+                 stash: typing.Optional[dict] = None,
                  layer_stats: typing.Optional[list] = None) -> NamedTensor:
         outer_rng = None
         outer_mesh = None
@@ -86,10 +86,10 @@ class ReplayBlock:
         # consume raw -127..127 integers
         ctx.quant_scales = outer_quant
         ctx.matmul_accumulation = outer_acc
-        # attention-output stash channel (collect/provide), handed EXPLICITLY
+        # the replay stash channel (collect/provide, below), handed EXPLICITLY
         # by the strategy code — never inherited from the outer context, so
         # a mode can't leak across custom_vjp replay boundaries
-        ctx.attn_stash = attn_stash
+        ctx.replay_stash = stash
         # per-step layer statistics (core/scope.py Context.layer_stats): the
         # caller's own list, so that it can return them out of its region
         ctx.layer_stats = layer_stats
@@ -130,35 +130,57 @@ def _call_block(f, subset, x, it=None, chan=None):
     if it is not None:
         kwargs["it"] = it
     if chan is not None:
-        kwargs["attn_stash"] = chan
+        kwargs["stash"] = chan
     return f(subset, x, **kwargs)
 
 
-def _collect_chan(stash: bool):
-    return {"mode": "collect", "items": []} if stash else None
+# The replay stash channel: what is dear to rebuild per byte rides the
+# strategy residuals instead of being recomputed in the backward replay.
+# The forward rule runs each block part with a "collect" channel; layers
+# whose ``kind`` the channel carries (model/remat.py STASH_KINDS:
+# "attention" — a flash/ring layer's (out, lse); "bottleneck" —
+# bottleneck_group_linear's in-projection output) ``stash_push`` a pytree
+# of arrays; the scan forms stack the items over depth.  The backward rule
+# replays the part with a "provide" channel and the same layers
+# ``stash_pop``.  ORDERING CONTRACT: one channel per block part; items pop
+# in push order, whatever their kind, so a consumer's gate (kind, shapes,
+# mesh) must be a function of what BOTH traces see — it must push in
+# collect mode exactly when it pops in provide mode.
+
+def _collect_chan(stash: typing.FrozenSet[str]):
+    return {"mode": "collect", "items": [], "kinds": stash} if stash else None
 
 
-def _provide_chan(stash: bool, items):
-    """items: the block's stashed (out, lse) tuples from the forward rule's
-    residuals; an empty tuple (no flash calls in the block) degrades to the
+def _provide_chan(stash: typing.FrozenSet[str], items):
+    """items: the block part's stashed pytrees from the forward rule's
+    residuals; an empty tuple (no consumer in the part) degrades to the
     plain replay."""
     if not stash or not items:
         return None
-    return {"mode": "provide", "items": list(items), "i": 0}
+    return {"mode": "provide", "items": list(items), "i": 0, "kinds": stash}
 
 
 def _chan_items(chan):
     return tuple(chan["items"]) if chan is not None else ()
 
 
+def stash_channel(ctx, kind: str) -> typing.Optional[dict]:
+    """The scope context's channel if it carries ``kind``, else None —
+    how a layer asks whether its output rides the residuals."""
+    chan = getattr(ctx, "replay_stash", None)
+    return chan if chan is not None and kind in chan["kinds"] else None
+
+
 def stash_push(chan, item) -> None:
     """Consumer-side half of the stash-channel contract (collect mode) —
-    the single definition shared by the flash and ring attention paths."""
+    the single definition every consumer shares (flash and ring attention,
+    the bottleneck in-projection)."""
     chan["items"].append(item)
 
 
 def stash_pop(chan):
-    """Consumer-side half of the stash-channel contract (provide mode)."""
+    """Consumer-side half of the stash-channel contract (provide mode):
+    the next item in push order."""
     item = chan["items"][chan["i"]]
     chan["i"] += 1
     return item
@@ -169,7 +191,8 @@ def stash_collecting(chan) -> bool:
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 4))
-def rev_sequence(fns, subsets, x1, x2, stash: bool = False):
+def rev_sequence(fns, subsets, x1, x2,
+                 stash: typing.FrozenSet[str] = frozenset()):
     for f, s in zip(fns, subsets):
         x1, x2 = x2, x1 + f(s, x2)
     return x1, x2
@@ -210,7 +233,8 @@ rev_sequence.defvjp(_rev_fwd, _rev_bwd)
 # ---- invertible momentum sequence ---------------------------------------
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 5))
-def momentum_sequence(fns, alpha, subsets, x, v, stash: bool = False):
+def momentum_sequence(fns, alpha, subsets, x, v,
+                      stash: typing.FrozenSet[str] = frozenset()):
     for f, s in zip(fns, subsets):
         v = v * alpha + f(s, x) * (1 - alpha)
         x = x + v
@@ -280,8 +304,10 @@ def _rev_scan_run(fns, unroll, stacked, shared, x1, x2, stash):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 6))
-def rev_scan(fns, unroll, stacked, shared, x1, x2, stash: bool = False):
-    x1, x2, _ = _rev_scan_run(fns, unroll, stacked, shared, x1, x2, False)
+def rev_scan(fns, unroll, stacked, shared, x1, x2,
+             stash: typing.FrozenSet[str] = frozenset()):
+    x1, x2, _ = _rev_scan_run(fns, unroll, stacked, shared, x1, x2,
+                              frozenset())
     return x1, x2
 
 
@@ -347,8 +373,9 @@ def _mom_scan_run(fns, alpha, unroll, stacked, shared, x, v, stash):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 7))
 def momentum_scan(fns, alpha, unroll, stacked, shared, x, v,
-                  stash: bool = False):
-    x, v, _ = _mom_scan_run(fns, alpha, unroll, stacked, shared, x, v, False)
+                  stash: typing.FrozenSet[str] = frozenset()):
+    x, v, _ = _mom_scan_run(fns, alpha, unroll, stacked, shared, x, v,
+                            frozenset())
     return x, v
 
 
@@ -558,14 +585,12 @@ def _scan_prologue(params: ModelParameter, ctx, plan, src: NamedTensor,
 
 def resolve_stash(params: ModelParameter, mesh=None) -> bool:
     """Back-compat boolean view of the remat policy: ``True`` iff the
-    resolved policy is ``"stash"`` — the attention-output stash decision
-    (the (out, lse) pairs riding the strategy custom_vjp residuals; +23%
-    at 16k ctx, docs/PERFORMANCE.md).  The full policy — including the
-    save-vs-recompute choice — lives in :func:`model.remat.resolve_remat`;
-    an explicit legacy ``stash_attention_outputs`` boolean still maps
-    straight onto stash/recompute there."""
-    from .remat import resolve_remat
-    return resolve_remat(params, mesh) == "stash"
+    ATTENTION kind rides the strategy residuals (the (out, lse) pairs;
+    +23% at 16k ctx, docs/PERFORMANCE.md).  The full decision — every
+    kind, and the save-vs-recompute choice — lives in
+    :func:`model.remat.stash_kinds` / :func:`model.remat.resolve_remat`."""
+    from .remat import stash_kinds
+    return "attention" in stash_kinds(params, mesh)
 
 
 def _try_scan(params: ModelParameter, ctx, plan, src: NamedTensor,
@@ -574,13 +599,13 @@ def _try_scan(params: ModelParameter, ctx, plan, src: NamedTensor,
     if pro is None:
         return None
     stacked, shared, fns = pro
-    from .remat import resolve_remat
+    from .remat import resolve_remat, stash_kinds
     policy = resolve_remat(params, ctx.mesh)
     if strategy in ("revnet", "momentum"):
         if policy in ("save", "save_dots"):
             return _strategy_scan_save(params, fns, stacked, shared, src,
                                        strategy, policy)
-        stash = policy == "stash"
+        stash = stash_kinds(params, ctx.mesh)
         if strategy == "revnet":
             x1, x2 = rev_scan(fns, params.scan_unroll, stacked, shared, src,
                               src, stash)
@@ -969,9 +994,9 @@ def run_body_blocks(params: ModelParameter, src: NamedTensor,
         if scanned is not None:
             return scanned, plan
 
-    from .remat import block_caller, resolve_remat
+    from .remat import block_caller, resolve_remat, stash_kinds
     policy = resolve_remat(params, ctx.mesh)
-    stash = policy == "stash"
+    stash = stash_kinds(params, ctx.mesh)
     if strategy in ("revnet", "momentum") and policy in ("save",
                                                          "save_dots"):
         # unrolled save modes: the identical primal recurrence under native

@@ -11,17 +11,23 @@ policy          behavior
 ``recompute``   the strategy ``custom_vjp`` re-runs each block's forward
                 inside ``jax.vjp`` — O(1) activation memory in depth, one
                 extra forward of compute (the historical default)
-``stash``       recompute, but every flash/ring attention layer's
-                ``(out, lse)`` rides the strategy residuals so the backward
-                replay runs no forward attention kernels (and no ring hops)
-                — the old ``stash_attention_outputs: true``
+``stash``       recompute, but what is dear to replay per byte rides the
+                strategy residuals (the stash channel, model/blocks.py),
+                BOTH kinds: ``attention`` — every flash/ring attention
+                layer's ``(out, lse)``, so the backward replay runs no
+                forward attention kernels (and no ring hops; the old
+                ``stash_attention_outputs: true``) — and ``bottleneck`` —
+                ``bottleneck_group_linear``'s in-projection output
+                ``[b, s, intermediate]``, so the replay runs neither that
+                matmul nor, where it contracts a mesh-sharded axis, its
+                tensor-parallel all-reduce (PR 27)
 ``save``        NO ``custom_vjp``: the identical primal recurrence under
                 native scan AD; every linearization residual is saved —
                 zero recompute, O(depth) residual memory
 ``save_dots``   ``save`` with each block wrapped in ``jax.checkpoint``
                 (policy ``dots_saveable``): GEMM outputs saved, elementwise
                 recomputed — the middle ground for compute-bound chips
-``auto``        resolved below
+``auto``        resolved below, per kind
 ==============  =============================================================
 
 All four execute the SAME primal recurrence — losses are bit-identical
@@ -38,8 +44,16 @@ classification this resolver keys on.  ``auto`` therefore picks:
 1. the explicit ``remat_policy`` value when set;
 2. the legacy ``stash_attention_outputs`` boolean when the user set one
    (``true`` → ``stash``, ``false`` → ``recompute``);
-3. ``stash`` when the long-context stash rule pays and fits (seq >= 2048,
+3. ``stash`` when a kind's own rule engages (:func:`stash_kinds`):
+   ``attention`` when the long-context rule pays and fits (seq >= 2048,
    % 128 == 0, per-device stash <= 15% of HBM — the measured +23% at 16k);
+   ``bottleneck`` when the in-projection's contraction crosses a ``model``
+   mesh axis > 1 (each chip then holds a partial sum and the replay would
+   all-reduce it a second time — one of three exposed collectives a layer
+   on the {data: 2, model: 2} flagship, PERF.md PR 27) and its per-device
+   bytes fit what the attention stash leaves of the same 15%.  The legacy
+   boolean ``true`` forces the attention kind only (its name; the
+   bottleneck kind still resolves by its rule), ``false`` is "recompute";
 4. else ``recompute``.  The save modes stay measured OPT-INS: the A/B
    lost on the rig, the committed ledger classifies every body scope
    hbm-bound (residual round-trips are the expensive direction there),
@@ -70,6 +84,9 @@ SAVE_HBM_FRACTION = 0.35
 SAVE_RESIDUALS_PER_BLOCK = 16
 
 POLICIES = ("recompute", "stash", "save", "save_dots")
+#: what can ride the strategy residuals under "stash" (the channel's kinds,
+#: model/blocks.py ``stash_channel``)
+STASH_KINDS = ("attention", "bottleneck")
 
 
 def _mesh_geometry(params: ModelParameter, mesh):
@@ -93,6 +110,50 @@ def _stash_bytes(params: ModelParameter) -> int:
                  * params.features_per_head * calc_bytes
                  + params.train_batch_size * params.heads * seq * 4)
     return per_layer * params.depth * max(1, params.macro_batching)
+
+
+def _layers(params: ModelParameter):
+    """``(name, {flags})`` of every layer of one depth-unit."""
+    for block in params.block_config:
+        for layer in block.layer:
+            name, *extras = layer.split("-")
+            yield name, set(extras)
+
+
+def _bottleneck_sites(params: ModelParameter) -> int:
+    """In-projection outputs one depth-unit pushes: every ``in:`` linear of
+    every ``bottleneck_group_linear`` layer (one, plus the glu branches;
+    an expert in-projection is not a plain linear and is left alone)."""
+    sites = 0
+    for name, extras in _layers(params):
+        if name == "bottleneck_group_linear" \
+                and "in:mixture_of_experts" not in extras:
+            glu_add = "in:glu_add" in extras
+            sites += 1 + ("in:glu" in extras or glu_add) + glu_add
+    return sites
+
+
+def _bottleneck_stash(params: ModelParameter, mesh) -> typing.Tuple[int, int, bool]:
+    """``(layers, per-device bytes, crosses)`` of the bottleneck stash: the
+    in-projection outputs ``[batch, sequence, intermediate]`` of the whole
+    depth, laid out as ``core/sharding.with_constraint`` pins them (batch
+    on 'data', replicated over 'model'), and whether the contraction (over
+    the feature dims) crosses a 'model' mesh axis > 1 — each chip then
+    holds a partial sum that costs an all-reduce wherever it is made."""
+    from ..core import sharding as shardlib
+    layers = _bottleneck_sites(params) * params.depth
+    out_dims = [params.batch_dim, params.sequence_dim, *params.intermediate]
+    nbytes = (int(np.prod([d.size for d in out_dims]))
+              * np.dtype(params.calculation_dtype).itemsize * layers
+              * max(1, params.macro_batching))
+    crosses = False
+    if mesh is not None and getattr(mesh, "devices", None) is not None:
+        for axis in shardlib.spec_for_dims(params, out_dims, mesh):
+            nbytes //= mesh.shape[axis] if axis is not None else 1
+        crosses = mesh.shape.get(shardlib.MODEL_AXIS, 1) > 1 \
+            and shardlib.MODEL_AXIS in shardlib.spec_for_dims(
+                params, list(params.feature_dims), mesh)
+    return layers, nbytes, crosses
 
 
 def _save_residual_bytes(params: ModelParameter) -> int:
@@ -126,8 +187,12 @@ def remat_report(params: ModelParameter, mesh=None) -> typing.Dict[str, typing.A
     bytes_block = tokens * d_model * calc_bytes * 12
     resid_block = tokens * d_model * 4 * SAVE_RESIDUALS_PER_BLOCK
     peak, bw = peak_flops(device), peak_hbm_bandwidth(device)
+    layers, bottleneck_bytes, crosses = _bottleneck_stash(params, mesh)
     return {
         "stash_bytes_per_device": -(-_stash_bytes(params) // shards),
+        "bottleneck_stash_layers": layers,
+        "bottleneck_stash_bytes_per_device": bottleneck_bytes,
+        "bottleneck_crosses_model_axis": crosses,
         "save_residual_bytes_per_device":
             -(-_save_residual_bytes(params) // shards),
         "hbm_bytes": hbm,
@@ -139,20 +204,101 @@ def remat_report(params: ModelParameter, mesh=None) -> typing.Dict[str, typing.A
     }
 
 
-def resolve_remat(params: ModelParameter, mesh=None) -> str:
-    """The resolved remat policy for this (config, mesh) — see the module
-    docstring for the decision order."""
+def _explicit_policy(params: ModelParameter) -> typing.Optional[str]:
+    """The policy the configuration itself names (``remat_policy``, else
+    the legacy boolean's ``false``), or None where a rule has to decide."""
     v = getattr(params, "remat_policy", "auto")
     if v != "auto":
         return v
-    legacy = getattr(params, "stash_attention_outputs", "auto")
-    if legacy is True:
-        return "stash"
-    if legacy is False:
+    if getattr(params, "stash_attention_outputs", "auto") is False:
         return "recompute"
+    return None
+
+
+def stash_kinds(params: ModelParameter, mesh=None) -> typing.FrozenSet[str]:
+    """Which :data:`STASH_KINDS` ride the strategy residuals for this
+    (config, mesh): both under an explicit ``"stash"``, none under any
+    other explicit policy, else each kind by its own rule (the module
+    docstring's item 3).  The attention rule is the historical one and is
+    decided FIRST: the bottleneck kind only gets what it leaves of the
+    budget, so adding that kind moved no configuration's attention
+    decision."""
+    explicit = _explicit_policy(params)
+    if explicit is not None:
+        return frozenset(STASH_KINDS if explicit == "stash" else ())
     rep = remat_report(params, mesh)
-    if rep["seq"] >= 2048 and rep["seq"] % 128 == 0 \
-            and rep["stash_bytes_per_device"] <= rep["stash_budget_bytes"]:
+    budget = rep["stash_budget_bytes"]
+    kinds = set()
+    if getattr(params, "stash_attention_outputs", "auto") is True or (
+            rep["seq"] >= 2048 and rep["seq"] % 128 == 0
+            and rep["stash_bytes_per_device"] <= budget):
+        kinds.add("attention")
+        budget -= rep["stash_bytes_per_device"]
+    if rep["bottleneck_crosses_model_axis"] \
+            and 0 < rep["bottleneck_stash_bytes_per_device"] <= budget:
+        kinds.add("bottleneck")
+    return frozenset(kinds)
+
+
+def _attention_sites(params: ModelParameter, mesh) -> int:
+    """Attention layers a depth-unit holds whose kernel route consumes the
+    channel: plain softmax dot-product attention through the one-device
+    flash path or the sequence-parallel ring (model/spatial.py; the flash
+    kernel under a data x model shard_map keeps the plain kernel)."""
+    from ..core.sharding import SEQUENCE_AXIS
+    has_mesh = mesh is not None and getattr(mesh, "devices", None) is not None
+    ring = has_mesh and mesh.shape.get(SEQUENCE_AXIS, 1) > 1
+    if not ring and (has_mesh or not params.use_flash_attention
+                     or params.sequence_dim.size % 128):
+        return 0
+    dense_only = {"biased_softmax", "biased_attention_map",
+                  "scale_attention_map", "shared_key_value"}
+    return sum(name == "attention" and "dot_product" in extras
+               and not dense_only & extras
+               for name, extras in _layers(params))
+
+
+def stash_plan(params: ModelParameter, mesh=None
+               ) -> typing.Dict[str, typing.Tuple[int, int]]:
+    """``{kind: (layers, per-device bytes)}`` of what rides the strategy
+    residuals of the step this (config, mesh) builds, from its shapes;
+    ``(0, 0)`` for a kind that is not engaged (no reversible strategy, a
+    pipeline mesh, an explicit policy, a rule that declined, no such
+    layer).  ``Trainer`` publishes it as ``hbnlp_remat_stash_bytes{kind}``
+    / ``hbnlp_remat_stash_layers{kind}`` (docs/OBSERVABILITY.md)."""
+    from ..core.sharding import PIPE_AXIS
+    plan = {kind: (0, 0) for kind in STASH_KINDS}
+    piped = mesh is not None and mesh.shape.get(PIPE_AXIS, 1) > 1
+    if params.memory_reduction_strategy not in ("revnet", "momentum") or piped:
+        return plan
+    kinds = stash_kinds(params, mesh)
+    rep = remat_report(params, mesh)
+    if "attention" in kinds:
+        layers = _attention_sites(params, mesh) * params.depth
+        # remat_report sizes one pair a depth-unit
+        plan["attention"] = (layers, rep["stash_bytes_per_device"]
+                             * layers // params.depth)
+    if "bottleneck" in kinds and rep["bottleneck_stash_layers"]:
+        plan["bottleneck"] = (rep["bottleneck_stash_layers"],
+                              rep["bottleneck_stash_bytes_per_device"])
+    return plan
+
+
+def stash_line(plan: typing.Dict[str, typing.Tuple[int, int]]) -> str:
+    """The start-up line beside ``placement_report``'s."""
+    return "remat stash: " + "; ".join(
+        f"{kind} {layers} layers, {nbytes} bytes a device"
+        for kind, (layers, nbytes) in plan.items())
+
+
+def resolve_remat(params: ModelParameter, mesh=None) -> str:
+    """The resolved remat policy for this (config, mesh) — see the module
+    docstring for the decision order; ``"stash"`` where any kind rides
+    (:func:`stash_kinds` says which)."""
+    explicit = _explicit_policy(params)
+    if explicit is not None:
+        return explicit
+    if stash_kinds(params, mesh):
         return "stash"
     # the save modes stay MEASURED opt-ins: the round-11 A/B on the
     # flagship step measured recompute 204 / save 280 / save_dots 249

@@ -114,12 +114,13 @@ def _maybe_ring_attention(args: BlockArgs, dim: Dim, qry: NamedTensor,
 
     # causal=True always: the dense softmax branch masks unconditionally
     # (reference spatial.py:68), regardless of masked_attention_dimensions.
-    # attn_stash: the strategy machinery's attention-output stash channel —
-    # the zigzag ring collects/provides (out, lse) so the strategy
-    # backward's recompute skips the whole ring
+    # stash: the strategy machinery's replay stash channel
+    # (model/blocks.py) — the zigzag ring collects/provides (out, lse) so
+    # the strategy backward's recompute skips the whole ring
+    from .blocks import stash_channel
     out = ring_attention(q, k, v, mesh, causal=True,
                          scale=1.0,  # qry already carries the reference scale
-                         stash=getattr(ctx, "attn_stash", None))
+                         stash=stash_channel(ctx, "attention"))
     out_nt = nt(out.reshape([d.size for d in canonical]), canonical)
     return transpose_to(out_nt, args.tensor.dims)
 
@@ -180,11 +181,12 @@ def _flash(ctx, q, k, v, scale: float):
     mesh = ctx.mesh
     if mesh is None:
         # causal=True always: the dense softmax branch masks unconditionally.
-        # attn_stash: the strategy machinery's attention-output stash channel
+        # stash: the strategy machinery's replay stash channel
         # (model/blocks.py) — single-device path only; the shard_map branch
         # keeps the plain kernel
+        from .blocks import stash_channel
         return flash(q, k, v, scale=scale, causal=True,
-                     stash=getattr(ctx, "attn_stash", None))
+                     stash=stash_channel(ctx, "attention"))
     from jax.sharding import PartitionSpec as P
 
     from jax import shard_map

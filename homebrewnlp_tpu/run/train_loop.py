@@ -358,6 +358,7 @@ def train(params: ModelParameter, train_steps: typing.Optional[int] = None,
             shardlib.place_tree(state.step, np.asarray(step, np.int32)))
         print(f"restored checkpoint at step {step}")
     print(shardlib.placement_report(state.variables, mesh), flush=True)
+    print(trainer.publish_stash_plan(), flush=True)
 
     compile_s = 0.0
     if is_chief:

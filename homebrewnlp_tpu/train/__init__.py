@@ -510,6 +510,7 @@ class Trainer:
         whole step compiles a second time."""
         p = self.params
         self._resolve_grad_allreduce()
+        self.publish_stash_plan()
 
         def step_fn(state: TrainState, batch, rng):
             carry = (state.variables, state.opt_state, state.step)
@@ -558,6 +559,26 @@ class Trainer:
         # the HLO donation audit's negative control (analysis/entry_points)
         return jax.jit(step_fn, donate_argnums=(0,) if donate else (),
                        out_shardings=out_shardings)
+
+    def publish_stash_plan(self) -> str:
+        """``hbnlp_remat_stash_bytes{kind}`` / ``hbnlp_remat_stash_layers
+        {kind}``: what rides the memory strategy's residuals in the step
+        this trainer builds (model/remat.py ``stash_plan``; 0 for a kind
+        that is not engaged).  Set when the step is built; returns the
+        start-up line that says the same."""
+        from ..model.remat import stash_line, stash_plan
+        plan = stash_plan(self.params, self.mesh)
+        r = telemetry.registry()
+        nbytes = r.gauge("hbnlp_remat_stash_bytes",
+                         "per-device bytes riding the memory strategy's "
+                         "residuals instead of being replayed", ("kind",))
+        nlayers = r.gauge("hbnlp_remat_stash_layers",
+                          "layer outputs riding the memory strategy's "
+                          "residuals", ("kind",))
+        for kind, (layers, size) in plan.items():
+            nbytes.labels(kind).set(size)
+            nlayers.labels(kind).set(layers)
+        return stash_line(plan)
 
     def lowered(self, state: TrainState, batch: typing.Dict[str, jax.Array]):
         """Lowered (StableHLO) train step for ``save_graph`` dumps — the

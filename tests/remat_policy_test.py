@@ -96,3 +96,198 @@ def auto_is_recompute_at_flagship_shapes_test():
                          depth=4, train_batch_size=8,
                          memory_reduction_strategy="revnet")
     assert resolve_remat(params) == "recompute"
+
+
+# ---- the bottleneck kind (PR 27): the in-projection's all-reduced output
+# rides the strategy residuals where its contraction crosses 'model' -------
+
+_TP_CFG = dict(sequence_length=32, features_per_head=16, heads=4, depth=2,
+               train_batch_size=8, vocab_size=64, calculation_dtype="float32",
+               optimizer="momentum:0.9:1:1-learning_rate", learning_rate=0.01,
+               mesh_shape_override={"data": 2, "model": 2}, tpu_size=4)
+
+
+def _tp_trainer(policy, strategy="revnet", scan=True, **kw):
+    """Toy flagship widths (the mixer blocks of tests/backend.py) on a
+    {data: 2, model: 2} mesh over four of the eight virtual devices."""
+    from homebrewnlp_tpu.core import sharding as shardlib
+    if len(jax.devices()) < 4:
+        pytest.skip("needs 4 virtual devices")
+    params = make_params(**{**_TP_CFG, "memory_reduction_strategy": strategy,
+                            "scan_layers": scan, "remat_policy": policy, **kw})
+    mesh = shardlib.build_mesh(params, jax.devices()[:4])
+    assert dict(mesh.shape) == {"data": 2, "model": 2}
+    trainer = Trainer(params, Model(params), mesh=mesh)
+    x = np.random.default_rng(0).integers(
+        0, params.vocab_size,
+        (params.train_batch_size, params.sequence_length, 1))
+    batch = {"token_x": jnp.asarray(x),
+             "token_y": jnp.asarray((x + 1) % params.vocab_size)}
+    return params, mesh, trainer, trainer.init_state(batch), batch
+
+
+@pytest.mark.parametrize("policy,expected", [("auto", 2), ("stash", 2),
+                                             ("recompute", 3)])
+def bottleneck_stash_allreduce_count_test(policy, expected):
+    """The compiled step holds the in-projection's all-reduce (a partial
+    [b_local, s, intermediate] sum over the model-sharded heads) in the
+    forward scan body, for the backward's cotangent, and — only without the
+    stash — once more in the replay."""
+    import re
+    params, _, trainer, state, batch = _tp_trainer(policy)
+    hlo = trainer.lowered(state, batch).compile().as_text()
+    shape = (params.train_batch_size // 2, params.sequence_dim.size,
+             params.intermediate[-1].size)
+    found = re.findall(r"= f32\[(\d+),(\d+),(\d+)\]\S* all-reduce(?:-start)?\(",
+                       hlo)
+    assert sum(tuple(map(int, f)) == shape for f in found) == expected, found
+
+
+@pytest.mark.parametrize("strategy", ["revnet", "momentum"])
+@pytest.mark.parametrize("scan", [True, False])
+def bottleneck_stash_parity_test(strategy, scan):
+    """Same primal recurrence: the loss is bit-identical to "recompute";
+    the replay starts the block's tail from the forward's exact
+    intermediate instead of one rebuilt from the reconstructed stream, so
+    updated parameters agree to reconstruction ulps."""
+    results = []
+    for policy in ("recompute", "auto"):
+        _, _, trainer, state, batch = _tp_trainer(policy, strategy, scan)
+        results.append(trainer.step(state, batch, jax.random.PRNGKey(0)))
+    (s0, m0), (s1, m1) = results
+    assert float(m0["loss"]) == float(m1["loss"])
+    for n in s0.variables:
+        np.testing.assert_allclose(np.asarray(s0.variables[n], np.float32),
+                                   np.asarray(s1.variables[n], np.float32),
+                                   rtol=2e-4, atol=1e-5, err_msg=n)
+
+
+def _flagship(**kw):
+    """The four-chip cell's shapes (benchmark/workloads/
+    train_32big_mixer_dp2tp2.json), for the resolver alone: nothing is
+    built."""
+    return make_params(**{**dict(
+        sequence_length=512, features_per_head=512, heads=8, depth=32,
+        train_batch_size=256, calculation_dtype="bfloat16",
+        memory_reduction_strategy="revnet", tpu_size=4,
+        mesh_shape_override={"data": 2, "model": 2}), **kw})
+
+
+_LONG_BLOCKS = [{"layer": ["norm-shift-scale-features-group",
+                           "attention-dot_product-context-in:relu"]}]
+_MIXER_LONG_BLOCKS = [
+    {"layer": ["norm-shift-scale-features-group",
+               "bottleneck_group_linear-in:relu-mid:relu-mid:norm-mid:shift"
+               "-mid:scale-mid:features"]}] + _LONG_BLOCKS
+
+
+@pytest.mark.parametrize("case", ["no_mesh", "model_axis_1", "engaged",
+                                  "over_budget", "legacy_false",
+                                  "checkpoint_strategy",
+                                  "attention_unmoved"])
+def bottleneck_stash_resolver_test(case):
+    from homebrewnlp_tpu.core import sharding as shardlib
+    from homebrewnlp_tpu.model.remat import stash_kinds, stash_plan
+    if len(jax.devices()) < 4:
+        pytest.skip("needs 4 virtual devices")
+    devices = jax.devices()[:4]
+    if case == "no_mesh":
+        p = _flagship()
+        assert stash_kinds(p) == frozenset()
+        assert resolve_remat(p) == "recompute"
+        assert stash_plan(p)["bottleneck"] == (0, 0)
+    elif case == "model_axis_1":
+        p = _flagship(mesh_shape_override={"data": 4})
+        mesh = shardlib.build_mesh(p, devices)
+        assert mesh.shape.get("model", 1) == 1
+        assert stash_kinds(p, mesh) == frozenset()
+    elif case == "engaged":
+        p = _flagship()
+        mesh = shardlib.build_mesh(p, devices)
+        assert stash_kinds(p, mesh) == {"bottleneck"}
+        assert resolve_remat(p, mesh) == "stash"
+        # 128 sequences a chip x 512 x 512 x bfloat16 x 32 layers
+        assert stash_plan(p, mesh) == {
+            "attention": (0, 0), "bottleneck": (32, 128 * 512 * 512 * 2 * 32)}
+    elif case == "over_budget":
+        # the published deployment's share, 256 sequences a chip: 4.3 GB
+        # against 15% of the planning figure
+        p = _flagship(train_batch_size=512)
+        mesh = shardlib.build_mesh(p, devices)
+        rep = remat_report(p, mesh)
+        assert rep["bottleneck_stash_bytes_per_device"] \
+            > rep["stash_budget_bytes"]
+        assert stash_kinds(p, mesh) == frozenset()
+        assert resolve_remat(p, mesh) == "recompute"
+    elif case == "legacy_false":
+        p = _flagship(stash_attention_outputs=False)
+        assert stash_kinds(p, shardlib.build_mesh(p, devices)) == frozenset()
+    elif case == "checkpoint_strategy":
+        p = _flagship(memory_reduction_strategy="checkpoint")
+        plan = stash_plan(p, shardlib.build_mesh(p, devices))
+        assert plan == {"attention": (0, 0), "bottleneck": (0, 0)}
+    else:
+        # a long-context configuration's attention decision is the same
+        # with and without a bottleneck in the block, on one device and on
+        # the mesh, for the rule and for the legacy boolean — and the
+        # bottleneck only gets what attention leaves of the budget
+        for extra in ({}, {"stash_attention_outputs": True},
+                      {"train_batch_size": 64, "sequence_length": 32768}):
+            decisions = []
+            for blocks in (_LONG_BLOCKS, _MIXER_LONG_BLOCKS):
+                p = _flagship(**{**dict(
+                    sequence_length=16384, features_per_head=128, depth=16,
+                    train_batch_size=2, use_flash_attention=True,
+                    block_config=blocks), **extra})
+                mesh = shardlib.build_mesh(p, devices)
+                decisions.append(("attention" in stash_kinds(p),
+                                  "attention" in stash_kinds(p, mesh)))
+            assert decisions[0] == decisions[1], (extra, decisions)
+        p = _flagship(sequence_length=16384, train_batch_size=4, depth=16,
+                      use_flash_attention=True,
+                      block_config=_MIXER_LONG_BLOCKS)
+        mesh = shardlib.build_mesh(p, devices)
+        rep = remat_report(p, mesh)
+        assert rep["bottleneck_stash_bytes_per_device"] \
+            <= rep["stash_budget_bytes"] \
+            < rep["bottleneck_stash_bytes_per_device"] \
+            + rep["stash_bytes_per_device"]
+        assert stash_kinds(p, mesh) == {"attention"}
+
+
+@pytest.mark.parametrize("engaged", [True, False])
+def remat_stash_gauges_test(engaged):
+    """``hbnlp_remat_stash_bytes{kind}`` / ``hbnlp_remat_stash_layers{kind}``
+    read what the shapes give once the step is built, 0 when not engaged."""
+    from homebrewnlp_tpu import telemetry
+    params, _, trainer, state, _ = _tp_trainer(
+        "auto" if engaged else "recompute")
+    trainer._build_step(state=state)
+    snap = telemetry.registry().snapshot()
+    got = {name: snap[name]["series"] for name in
+           ("hbnlp_remat_stash_bytes", "hbnlp_remat_stash_layers")}
+    item = (params.train_batch_size // 2) * params.sequence_dim.size \
+        * params.intermediate[-1].size * 4
+    assert got["hbnlp_remat_stash_bytes"] == {
+        ("attention",): 0,
+        ("bottleneck",): item * params.depth if engaged else 0}
+    assert got["hbnlp_remat_stash_layers"] == {
+        ("attention",): 0, ("bottleneck",): params.depth if engaged else 0}
+    assert trainer.publish_stash_plan().startswith("remat stash: attention 0")
+
+
+@pytest.mark.parametrize("strategy", ["revnet", "momentum"])
+def one_device_step_is_untouched_test(strategy):
+    """No mesh, no model axis crossed: "auto" stashes nothing and adds no
+    residual — the lowered step is "recompute"'s, text for text."""
+    texts = []
+    for policy in ("recompute", "auto"):
+        params = make_params(memory_reduction_strategy=strategy,
+                             remat_policy=policy, **_CFG)
+        trainer = Trainer(params, Model(params))
+        x = np.zeros((params.train_batch_size, params.sequence_length, 1),
+                     np.int32)
+        batch = {"token_x": jnp.asarray(x), "token_y": jnp.asarray(x)}
+        texts.append(trainer.lowered(trainer.init_state(batch),
+                                     batch).as_text())
+    assert texts[0] == texts[1]
