@@ -36,6 +36,13 @@ mesh-axis-literal  hardcoded mesh-axis name strings ("data", "model",
 config-docs     every ModelParameter knob has a docs/CONFIG.md table row
                 (absorbed from scripts/check_config_docs.py, which now
                 shims onto this rule).
+env-knob        no ``os.environ`` / ``os.getenv`` read (``.get``, subscript,
+                ``in``, ``setdefault``, ``pop``) under
+                ``homebrewnlp_tpu/{model,parallel,train,optim,core}``: what
+                the step program computes follows the configuration and
+                what the code observes, never the shell it was started
+                from.  ``ENV_KNOB_ALLOWED`` names the reads that are left,
+                each with the debt that removes it.
 metric-docs     every ``hbnlp_*`` metric name registered via a registry
                 ``counter()``/``gauge()``/``histogram()`` call must have a
                 row in docs/OBSERVABILITY.md's catalog (mirrors the
@@ -114,6 +121,26 @@ MESH_AXIS_NAMES = frozenset(("data", "pipe", "model", "sequence"))
 MESH_AXIS_ALLOWED = ("homebrewnlp_tpu/parallel/",
                      "homebrewnlp_tpu/core/sharding.py",
                      "homebrewnlp_tpu/config.py")
+
+#: the layers that build the step program: the env-knob rule's scope
+ENV_KNOB_DIRS = tuple(f"homebrewnlp_tpu/{d}/" for d in
+                      ("model", "parallel", "train", "optim", "core"))
+
+#: environment reads the env-knob rule still admits there -> the debt
+#: (ROADMAP.md) whose payment deletes the entry
+ENV_KNOB_ALLOWED: typing.Dict[str, str] = {
+    "HBNLP_FUSED_DQP_CAP_GB":
+        "scripts/pod_lowering.py pins the fused-backward cap for a chip "
+        "that is not the local client's; goes when the kernel choice takes "
+        "the mesh's device as model/remat.py does",
+    "HBNLP_MAP_MIXER_INTERPRET":
+        "runs the map-mixer kernel in interpret mode off the TPU; goes "
+        "with the kernel (S1) or when its tests pass interpret themselves",
+}
+
+_ENV_MAPPINGS = ("os.environ", "environ")
+_ENV_READ_CALLS = ("os.getenv", "getenv") + tuple(
+    f"{m}.{f}" for m in _ENV_MAPPINGS for f in ("get", "setdefault", "pop"))
 
 #: callee basenames whose string arguments are axis names
 _AXIS_CALLEES = ("PartitionSpec", "NamedSharding", "P",
@@ -215,9 +242,25 @@ class _FileVisitor(ast.NodeVisitor):
                           "MODEL_AXIS, SEQUENCE_AXIS, PIPE_AXIS) or mark "
                           "the line `graft-lint: allow[mesh-axis-literal]`")
 
+    # -- env-knob ------------------------------------------------------------
+
+    def _env_read(self, node: ast.AST, key: typing.Optional[ast.AST]):
+        if not self.rel.startswith(ENV_KNOB_DIRS):
+            return
+        name = key.value if isinstance(key, ast.Constant) else None
+        if name not in ENV_KNOB_ALLOWED:
+            self._add("env-knob", node,
+                      f"environment read of {name or 'a computed name'!r} in "
+                      "a layer that builds the step program — choose from "
+                      "the configuration or from what the code observes "
+                      "(shapes, the device), or add the name with its debt "
+                      "to analysis/ast_lint.py ENV_KNOB_ALLOWED")
+
     def visit_Subscript(self, node: ast.Subscript):
         if "mesh" in _dotted(node.value).lower():
             self._axis_literal(node.slice, "a mesh-shape subscript")
+        if _dotted(node.value) in _ENV_MAPPINGS:
+            self._env_read(node, node.slice)
         self.generic_visit(node)
 
     def visit_Compare(self, node: ast.Compare):
@@ -226,6 +269,9 @@ class _FileVisitor(ast.NodeVisitor):
             if "axis_names" in others or "mesh_shape" in others \
                     or "mesh" in others.lower():
                 self._axis_literal(node.left, "an axis-membership test")
+            # `"X" in os.environ` is the simplest on/off switch there is
+            if any(_dotted(c) in _ENV_MAPPINGS for c in node.comparators):
+                self._env_read(node, node.left)
         self.generic_visit(node)
 
     def visit_Call(self, node: ast.Call):
@@ -236,6 +282,8 @@ class _FileVisitor(ast.NodeVisitor):
                 self._axis_literal(arg, f"a {base}(...) argument")
         elif base == "get" and "mesh" in name.lower() and node.args:
             self._axis_literal(node.args[0], "a mesh-shape .get() key")
+        if name in _ENV_READ_CALLS:
+            self._env_read(node, node.args[0] if node.args else None)
         if self._is_wallclock(name):
             self._add("wallclock", node,
                       "time.time() is wall clock — an NTP step corrupts "

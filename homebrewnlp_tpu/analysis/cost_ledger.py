@@ -18,9 +18,9 @@ module turns that into a budgeted artifact:
   ``budgets.json`` gates collectives (drift beyond ``tolerance`` = lint
   finding; update protocol: ``python -m homebrewnlp_tpu.analysis.cost_ledger
   --write`` and review the diff, docs/STATIC_ANALYSIS.md).
-* :func:`scope_map_from_hlo` — {instruction name -> op_name} from compiled
-  HLO text, the join key ``scripts/attribute_step.py`` uses to attribute
-  profiler trace time to the same scopes.
+* :func:`scope_key` also folds the ``tf_op`` of a TPU trace's device ops
+  (``benchmark/lib/program_readers.py``), so measured time and these
+  budgets share their scope names.
 
 Import stays cheap: jax only inside functions (the AST-only consumers of
 the package import this module's :func:`scope_key` without jax).
@@ -144,9 +144,8 @@ def scope_table(jaxpr, peak: typing.Optional[float] = None,
                 bandwidth: typing.Optional[float] = None
                 ) -> typing.Dict[str, typing.Any]:
     """``{"total": {...}, "scopes": {scope: {flops, bytes, flops_share,
-    bytes_share, intensity, bound}}}`` for ONE traced jaxpr — the shared
-    core of the per-entry ledger, also consumed directly by ``bench.py``
-    (the ``"cost_ledger"`` result key).
+    bytes_share, intensity, bound}}}`` for ONE traced jaxpr — the core of the
+    per-entry ledger.
 
     ``peak``/``bandwidth`` override the :data:`ROOFLINE_DEVICE` ridge.
     The committed ledger always classifies against the fixed reference
@@ -313,156 +312,6 @@ def ledger_audit(lowered: typing.Optional[dict] = None,
                         f"{a:.3g} -> {b:.3g} (> {tol:.0%} tolerance); "
                         + _UPDATE_HINT))
     return findings
-
-
-# ---- HLO instruction -> scope join (scripts/attribute_step.py) -------------
-
-_INSTR_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%([A-Za-z0-9_.$-]+)\s*=\s*"
-    r"(?:\([^)]*\)|[a-z0-9]+\[[^\]]*\](?:\{[^}]*\})?)\s+([a-zA-Z][\w-]*)\(")
-_COMP_RE = re.compile(r"^(?:ENTRY\s+)?%?([A-Za-z0-9_.$-]+)\s+\(")
-_OP_NAME_RE = re.compile(r'op_name="([^"]+)"')
-_CALLS_RE = re.compile(r"(?:calls|to_apply|body)=%?([A-Za-z0-9_.$-]+)")
-_OPERAND_RE = re.compile(r"%([A-Za-z0-9_.$-]+)")
-
-#: instruction kinds whose profiler event WRAPS its children's events
-#: (the body ops report separately) — excluded from attribution totals or
-#: every while/call body would double-count
-CONTAINER_KINDS = frozenset(("while", "call", "conditional"))
-
-
-def instruction_table(hlo_text: str
-                      ) -> typing.Dict[str, typing.Dict[str, typing.Any]]:
-    """``{instruction_name: {"kind", "op_name", "calls"}}`` over every
-    instruction of one compiled module's text, computation bodies included.
-
-    Fusion/call instructions often carry no ``op_name`` of their own; their
-    scope is inherited from the called computation's ROOT instruction (one
-    ``calls=`` hop at lookup time, :func:`attribute_events`).  What the
-    compiler itself inserts to move data (XLA:CPU's layout ``copy``,
-    ``wrapped_convert`` / ``wrapped_broadcast`` fusions) carries no metadata
-    anywhere: it belongs to the scope that made the value it moves, so it
-    inherits from its first operand's producer."""
-    table: typing.Dict[str, typing.Dict[str, typing.Any]] = {}
-    comp_root_op: typing.Dict[str, typing.Optional[str]] = {}
-    comp_root_instr: typing.Dict[str, str] = {}
-    comp_votes: typing.Dict[str, typing.Dict[str, int]] = {}
-    current_comp = None
-    for line in hlo_text.splitlines():
-        m = _INSTR_RE.match(line)
-        if m is None:
-            if line and not line[0].isspace():
-                c = _COMP_RE.match(line)
-                if c is not None:
-                    current_comp = c.group(1)
-            continue
-        name, kind = m.group(1), m.group(2)
-        op = _OP_NAME_RE.search(line)
-        op_name = op.group(1) if op else None
-        calls = _CALLS_RE.search(line)
-        operand = _OPERAND_RE.search(line, m.end())
-        table[name] = {"kind": kind, "op_name": op_name,
-                       "calls": calls.group(1) if calls else None,
-                       "operand": operand.group(1) if operand else None}
-        if current_comp is not None:
-            if op_name is not None:
-                votes = comp_votes.setdefault(current_comp, {})
-                votes[op_name] = votes.get(op_name, 0) + 1
-            if line.lstrip().startswith("ROOT "):
-                comp_root_instr[current_comp] = name
-                if op_name is not None:
-                    comp_root_op[current_comp] = op_name
-    # a computation's scope: its ROOT's op_name when present, else the
-    # majority op_name among its member instructions (fusion roots are
-    # often metadata-less bitcasts/copies while the fused math carries the
-    # scope)
-    comp_op: typing.Dict[str, str] = {}
-    for comp, votes in comp_votes.items():
-        root = comp_root_op.get(comp)
-        comp_op[comp] = root if root is not None else \
-            max(votes.items(), key=lambda kv: kv[1])[0]
-    # resolve missing op_names through the calls -> computation chain
-    # (bounded hops: e.g. call -> computation whose root is a fusion)
-    for name, info in table.items():
-        comp = info["calls"]
-        hops = 0
-        while info["op_name"] is None and comp and hops < 4:
-            inherited = comp_op.get(comp)
-            if inherited is not None:
-                info["op_name"] = inherited
-                break
-            # the called computation carries no metadata anywhere: delegate
-            # to whatever ITS root instruction calls (call->fusion chains)
-            root = table.get(comp_root_instr.get(comp, ""))
-            comp = root["calls"] if root else None
-            hops += 1
-    # still nameless: compiler-made data movement.  Follow the first operand
-    # to the nearest producer that has a scope (bounded: copy of a convert
-    # of a get-tuple-element ...); parameters end the walk unnamed
-    for name, info in table.items():
-        src, hops = info, 0
-        while info["op_name"] is None and src is not None and hops < 8:
-            src = table.get(src.get("operand") or "")
-            if src is not None and src["op_name"] is not None:
-                info["op_name"] = src["op_name"]
-            hops += 1
-    return table
-
-
-def scope_map_from_hlo(hlo_text: str) -> typing.Dict[str, str]:
-    """``{instruction_name: op_name}`` (inheritance applied) — profiler
-    trace events carry the instruction name (``args.hlo_op``), metadata
-    carries the named-scope path; this map is the join between them."""
-    return {name: info["op_name"]
-            for name, info in instruction_table(hlo_text).items()
-            if info["op_name"] is not None}
-
-
-def _lookup_instr(table: typing.Mapping[str, dict], hlo_op: str
-                  ) -> typing.Optional[dict]:
-    """The trace's ``hlo_op`` vs the HLO text name can differ by a
-    ``.clone`` suffix in either direction (CPU thunks clone parallelized
-    fusion roots) — try all three spellings."""
-    for cand in (hlo_op, hlo_op + ".clone",
-                 hlo_op[:-len(".clone")] if hlo_op.endswith(".clone")
-                 else hlo_op):
-        info = table.get(cand)
-        if info is not None:
-            return info
-    return None
-
-
-def attribute_events(events: typing.Iterable[typing.Tuple[str, float]],
-                     table: typing.Mapping[str, dict]
-                     ) -> typing.Tuple[typing.Dict[str, float],
-                                       typing.Dict[str, float], float]:
-    """Attribute ``(hlo_op, duration)`` device events to model scopes.
-
-    Returns ``(per_scope_duration, unattributed_by_op, total_duration)``.
-    Container instructions (while/call/conditional — their events wrap the
-    body ops' own events) are excluded from the total entirely; everything
-    else either folds into its :func:`scope_key` or lands in
-    ``unattributed`` (which the caller should report loudly — a growing
-    unattributed share means the scope annotations or this join broke)."""
-    per_scope: typing.Dict[str, float] = {}
-    unattr: typing.Dict[str, float] = {}
-    total = 0.0
-    for hlo_op, dur in events:
-        info = _lookup_instr(table, hlo_op)
-        if info is not None and info["kind"] in CONTAINER_KINDS:
-            continue
-        base = hlo_op.split(".")[0]
-        if info is None and base in CONTAINER_KINDS:
-            continue
-        total += dur
-        if info is None or info["op_name"] is None:
-            unattr[hlo_op] = unattr.get(hlo_op, 0.0) + dur
-            per_scope["unattributed"] = per_scope.get("unattributed",
-                                                      0.0) + dur
-            continue
-        key = scope_key(info["op_name"])
-        per_scope[key] = per_scope.get(key, 0.0) + dur
-    return per_scope, unattr, total
 
 
 # ---- CLI -------------------------------------------------------------------

@@ -36,9 +36,9 @@ AUDIT_CONFIG: typing.Dict[str, typing.Any] = {
     "intermediate_feed_forward_multiplier_multiplier": 0.5,
     "calculation_dtype": "bfloat16", "storage_dtype": "bfloat16",
     "memory_reduction_strategy": "none",
-    # the flagship optimizer chain (bench.py): its sm3/momentum slots put
-    # real optimizer state into the donated carry, so the donation audit
-    # covers opt-state aliasing too, not just params
+    # the flagship optimizer chain (configs/32big_mixer.json): its
+    # sm3/momentum slots put real optimizer state into the donated carry, so
+    # the donation audit covers opt-state aliasing too, not just params
     "optimizer": "adaptive_clip:0.003-sm3-momentum:0.9:1:1-learning_rate",
     "block_config": [
         {"layer": ["norm-shift-scale-features-group",
@@ -562,44 +562,6 @@ def lower_all(overrides: typing.Optional[dict] = None
         model, variables, jnp.asarray(token_x), draft_model=dmodel,
         draft_variables=dvariables)
     return out
-
-
-def lower_one(entry: str, overrides: typing.Optional[dict] = None
-              ) -> typing.Tuple[str, dict]:
-    """``(hlo_text, context)`` for ONE entry point — what
-    ``scripts/attribute_step.py`` uses so a single-entry trace join pays
-    one compile, not four."""
-    import jax.numpy as jnp
-
-    if entry not in ENTRY_POINTS:
-        raise ValueError(f"unknown entry point {entry!r}; one of "
-                         f"{ENTRY_POINTS}")
-    params, model, variables, token_x, batch = build_audit_model(overrides)
-    if entry in ("train_step", "eval_fn"):
-        trainer, state = make_trainer(params, model, batch)
-        if entry == "train_step":
-            return lower_train_step(params, model, variables, batch,
-                                    trainer=trainer, state=state)
-        return lower_eval_fn(params, model, variables, batch,
-                             trainer=trainer, state=state)
-    if entry == "decode_chunk_step":
-        return lower_decode_step(model, variables, jnp.asarray(token_x))
-    if entry == "engine_chunk_step":
-        return lower_engine_step(model, variables, jnp.asarray(token_x))
-    if entry == "paged_chunk_step":
-        return lower_paged_step(model, variables, jnp.asarray(token_x))
-    if entry in ("spec_chunk_step", "spec_paged_chunk_step"):
-        # the draft shares the caller's overrides (sequence geometry must
-        # match the target — the lower_all merge rule)
-        draft_overrides = dict(overrides or {})
-        draft_overrides.update(DRAFT_AUDIT_OVERRIDES)
-        _, dmodel, dvariables, _, _ = build_audit_model(draft_overrides,
-                                                        seed=1)
-        lower = (lower_spec_step if entry == "spec_chunk_step"
-                 else lower_spec_paged_step)
-        return lower(model, variables, jnp.asarray(token_x),
-                     draft_model=dmodel, draft_variables=dvariables)
-    return lower_prefill_entry(model, variables, jnp.asarray(token_x))
 
 
 def audit_lowered(lowered: "typing.Dict[str, typing.Tuple[str, dict]]",

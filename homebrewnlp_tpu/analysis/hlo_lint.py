@@ -347,6 +347,10 @@ _COLLECTIVE_LINE_RE = re.compile(
 
 _SHAPE_TOKEN_RE = re.compile(r"([a-z0-9]+)\[([0-9,]*)\]")
 
+#: XLA numbers the elements of a long tuple shape (``/*index=5*/``); the
+#: ``=`` inside would cut the result segment short
+_TUPLE_INDEX_RE = re.compile(r"/\*index=\d+\*/")
+
 _REPLICA_GROUPS_RE = re.compile(
     r"replica_groups=(\{\{[^}]*(?:\},\{[^}]*)*\}\}|\[[0-9,]+\]<=\[[0-9,]+\]"
     r"(?:T\([0-9,]+\))?)")
@@ -440,7 +444,7 @@ def collective_inventory(hlo_text: str,
     over."""
     inv: typing.Dict[str, dict] = {}
     for line in hlo_text.splitlines():
-        m = _COLLECTIVE_LINE_RE.search(line)
+        m = _COLLECTIVE_LINE_RE.search(_TUPLE_INDEX_RE.sub("", line))
         if m is None:
             continue
         result_seg, kind, suffix = m.groups()

@@ -318,6 +318,8 @@ def _lowering_abort(name: str) -> typing.Optional[str]:
         return None
     fatal = next((ln for ln in proc.stderr.splitlines()
                   if re.match(r"F\d{4} ", ln)), "no fatal line on stderr")
+    # without glog's date, time and pid: the banked reason must not churn
+    fatal = re.sub(r"^F\d{4} [\d:.]+\s+\d+ ", "", fatal)
     return f"{_ABORT_MARKER} (signal {-proc.returncode}): {fatal[-160:]}"
 
 

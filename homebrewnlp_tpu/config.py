@@ -340,7 +340,7 @@ class ModelParameter:
         # batch-1 aggregate throughput, BASELINE.md 'Decoding'); 1 = the
         # reference's strictly-serial completions
         self.serve_batch_size = 8
-        # weight-only int8 for serving (infer/quant.py): batch-1 decode is
+        # weight-only int8 for serving (core/quant.py): batch-1 decode is
         # weight-READ bound, so int8 weights halve the bytes per generated
         # token; dequantize fuses into the dots.  Off by default (greedy
         # tokens can differ from full precision by quantization error)

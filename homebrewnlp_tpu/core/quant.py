@@ -1,10 +1,9 @@
 """Weight-only int8 quantization — shared by serving AND training.
 
-Promoted from ``infer/quant.py`` (which remains as an import shim): the
-eligibility rules, per-channel scale-axis selection and the
-``quantize_variables`` entry the serving path has always used now live in
-``core`` next to the scope/materialize machinery that consumes the scales,
-and a TRAINING entry point joins them:
+The eligibility rules, per-channel scale-axis selection and the
+``quantize_variables`` entry of the serving path live in ``core`` next to
+the scope/materialize machinery that consumes the scales, with a TRAINING
+entry point beside them:
 
 * Serving (``serve_quantized_weights``, unchanged semantics): quantize a
   loaded checkpoint ONCE on the host; ``core.scope.materialize_param``

@@ -154,8 +154,8 @@ def name_scope(name: str):
         # mirror the scope frame into jax's name stack: every op traced
         # inside lands in compiled-HLO ``metadata={op_name=...}`` and in
         # jaxpr ``source_info.name_stack`` with its block/layer identity —
-        # the substrate the cost ledger (analysis/cost_ledger.py) and trace
-        # attribution (scripts/attribute_step.py) join on.  Metadata only:
+        # what the cost ledger (analysis/cost_ledger.py) and the trace's
+        # ``tf_op`` (benchmark/lib/program_readers.py) fold by.  Metadata only:
         # the compiled program is unchanged.
         with jax.named_scope(scoped_name):
             yield
@@ -246,7 +246,7 @@ def get_param(name_leaf: str, dims, initializer, slice_dtype, calc_dtype
 
 def materialize_param(ctx: Context, name: str, data, calc_dtype):
     """Parameter value in calculation dtype; int8-quantized serving weights
-    (infer/quant.py) dequantize here — the convert+scale chain fuses into
+    (core/quant.py) dequantize here — the convert+scale chain fuses into
     the consuming dot's operand read, so the HBM traffic stays int8.
 
     The dtype gate (not just name-in-scales) makes a stale ``quant_scales``

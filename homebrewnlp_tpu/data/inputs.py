@@ -434,8 +434,7 @@ class Prefetcher:
 
     ``close()`` releases an abandoned prefetcher: without it the fill
     thread stays blocked on its full queue forever, pinning the source
-    iterator's open file buffers (measured skewing co-resident
-    measurements badly — scripts/bench_loader.py).
+    iterator's open file buffers.
 
     ``telemetry_label``: when set (the train loop passes it under
     ``telemetry_enabled``), the prefetcher records a queue-depth gauge,

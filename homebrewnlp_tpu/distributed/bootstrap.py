@@ -37,7 +37,7 @@ _initialized_here = False
 
 def free_port() -> int:
     """An OS-assigned free localhost port — for launching a coordinator on
-    the local rig (run_manager fleet, bench_multihost, tests).  One shared
+    the local rig (run_manager fleet, tests).  One shared
     helper so a future fix (SO_REUSEADDR, IPv6) lands everywhere at once."""
     import socket
     with socket.socket() as s:

@@ -79,9 +79,9 @@ class InterfaceWrapper:
         self.variables = variables
         self.mesh = mesh
         if getattr(params, "serve_quantized_weights", False):
-            # weight-only int8 for the decode matvecs (infer/quant.py):
+            # weight-only int8 for the decode matvecs (core/quant.py):
             # batch-1 decode is weight-read bound, int8 halves the bytes
-            from .quant import quantize_variables
+            from ..core.quant import quantize_variables
             self.variables, scales = quantize_variables(
                 variables, model.param_dims, model.param_fan_in)
             model.quant_scales = scales

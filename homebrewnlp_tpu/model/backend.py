@@ -37,7 +37,7 @@ class OrthogonalInit:
             fan_in_dims = []
         self.sizes = [d.size for d in shape]
         # contracted-dim names, recorded per parameter at init: serving
-        # quantization (infer/quant.py) scales per-channel over every
+        # quantization (core/quant.py) scales per-channel over every
         # NON-contracted axis, which needs to know which axes the consuming
         # einsum sums over
         self.fan_in_names = tuple(d.name for d in fan_in_dims)

@@ -8,12 +8,14 @@ blockwise pass.  Grid: (batch·heads, q blocks, k blocks) with the
 online-softmax state (m, l, acc) carried in VMEM scratch across the
 innermost k dimension, so VMEM use is O(block) regardless of sequence
 length; causal blocks above the diagonal are skipped via a pl.when
-predicate.  Backward is a flash-2-style chunked XLA pass under
-``jax.custom_vjp`` — a lax.scan over q-row blocks recomputing softmax rows —
-so training needs neither the O(s²) residual nor an O(s²) recompute buffer.
+predicate.  Backward is flash-2's, in pallas under ``jax.custom_vjp``: p is
+recomputed per block from the saved lse, so training needs neither the O(s²)
+residual nor an O(s²) recompute buffer.  One fused pass gives dq, dk and dv
+while its dq-partial buffer fits the chip (``_use_fused_bwd``); a dq and a
+dk/dv kernel above that.
 
-Falls back transparently to a fused XLA implementation on CPU or when pallas
-lowering is unavailable (tests run the kernel in interpret mode).
+Off the TPU ``attention`` runs the dense XLA form (``_xla_reference``, also
+the tests' reference; tests run the kernels in interpret mode).
 """
 from __future__ import annotations
 
@@ -133,23 +135,6 @@ def _frontier_q_map(block_q: int, block_k: int, causal: bool):
         def q_map(i, kk, j):
             return (i, j, 0)
     return q_map
-
-
-def _bwd_tiles(s: int, blk: int):
-    """Backward kernel tiles: the forward tile by default;
-    ``HBNLP_BWD_BQ``/``HBNLP_BWD_BK`` override for retuning on other chips
-    (rounded DOWN to a power-of-two divisor of the sequence — the grids
-    and the ``_causal_split`` liveness arithmetic require block-aligned
-    tiles, so a non-divisor override must not reach the kernels)."""
-    import os
-    bwq = int(os.environ.get("HBNLP_BWD_BQ", 0)) or blk
-    bwk = int(os.environ.get("HBNLP_BWD_BK", 0)) or blk
-    # floor each override to a power of two (kernel_block halves from its
-    # cap, so a non-power-of-two cap would never land on a divisor), then
-    # to a divisor of s, with a floor of 128 (s % 128 == 0 at every caller)
-    floor = kernel_block(s, cap=128)
-    return (max(kernel_block(s, cap=1 << (max(bwq, 1).bit_length() - 1)), floor),
-            max(kernel_block(s, cap=1 << (max(bwk, 1).bit_length() - 1)), floor))
 
 
 def _make_score(q_ref, k_ref, scale):
@@ -416,84 +401,16 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, dqp_ref,
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _bwd_fused_group_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref,
-                            dqp_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc,
-                            *, block_q: int, block_k: int, group: int,
-                            num_q: int, scale: float, causal: bool):
-    """Group-of-k fused backward: grid (b*h, k GROUPS, q blocks), q
-    innermost, each grid step sweeping ``group`` k blocks in an in-body
-    loop against one resident [group*bk, d] K/V tile.
-
-    Purpose: shrink the dq partial buffer.  The flat fused kernel writes
-    one dq partial per k BLOCK ([bh, nk, sq, d] f32 — ~1 GB per layer at
-    16k, ~45 ms/step of write+reduce HBM traffic); here dq accumulates in
-    VMEM scratch across the in-group loop and flushes one partial per k
-    GROUP, dividing that traffic by ``group``.  dk/dv accumulate across
-    the q sweep in a group-sized scratch, exactly as the flat kernel does
-    per block.  Per-pair math is identical."""
-    from jax.experimental import pallas as pl
-
-    ko = pl.program_id(1)
-    qi = pl.program_id(2)
-
-    @pl.when(qi == 0)
-    def _init_kv():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
-
-    dq_acc[...] = jnp.zeros_like(dq_acc)
-
-    for ki in range(group):
-        lo = ki * block_k
-        k_blk = k_ref[lo:lo + block_k, :]
-        v_blk = v_ref[lo:lo + block_k, :]
-
-        def _score(k_blk=k_blk):
-            return jax.lax.dot_general(
-                q_ref[...], k_blk, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-
-        def _accumulate(s, k_blk=k_blk, v_blk=v_blk, lo=lo):
-            p = jnp.exp(s - lse_ref[...])
-            dp = jax.lax.dot_general(do_ref[...], v_blk,
-                                     (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            ds = (p * (dp - d_ref[...]) * scale).astype(q_ref.dtype)
-            dq_acc[...] += jax.lax.dot_general(
-                ds, k_blk, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            dk_acc[lo:lo + block_k, :] += jax.lax.dot_general(
-                ds, q_ref[...], (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            dv_acc[lo:lo + block_k, :] += jax.lax.dot_general(
-                p.astype(do_ref.dtype), do_ref[...], (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-
-        # per-pair causal dispatch at the BLOCK index kk = ko*group + ki
-        # (the group's k_ref tile spans blocks [ko*group, ko*group+group))
-        _masked_step(qi, ko * group + ki, block_q, block_k, causal,
-                     _score, _accumulate)
-
-    dqp_ref[...] = dq_acc[...].astype(dqp_ref.dtype)
-
-    @pl.when(qi == num_q - 1)
-    def _finish():
-        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
-
-
 # dq-partial buffer cap for the fused backward (bytes); above it the split
 # kernels run instead (the buffer is nk x the dq size — negligible for ring
-# hop chunks, ~1GB at the 16k single-chip shape, and quadratic beyond).
-# HBNLP_FUSED_DQP_CAP_GB overrides (fractional OK): at 32k/batch-1 the
-# 4.3GB buffer fits the 16GB chip and the fused kernel still wins — but
-# that headroom is workload-dependent, so the default stays conservative
+# hop chunks, ~1GB a layer at 16k and head width 128, and quadratic beyond)
 _FUSED_DQP_CAP = 2 * 1024 ** 3
 # admit dq-partial buffers up to this fraction of per-chip HBM (floored at
 # the old fixed 2GB cap): the 32k-context recipe's 4.3GB buffer fits a
-# 16GB v5e alongside its activations (measured, BASELINE.md '32k context
-# single-chip'), so the shipped configs hit their quoted numbers with NO
-# env override; HBNLP_FUSED_DQP_CAP_GB still pins it exactly
+# 16GB v5e alongside its activations (BASELINE.md '32k context
+# single-chip').  HBNLP_FUSED_DQP_CAP_GB pins the cap (fractional OK) for
+# scripts/pod_lowering.py, which lowers for a chip that is not the local
+# client's (ROADMAP.md: the choice should take the mesh's device)
 _FUSED_DQP_HBM_FRACTION = 0.30
 
 
@@ -508,35 +425,10 @@ def _fused_dqp_cap() -> int:
 
 
 def _use_fused_bwd(bh: int, s: int, sk: int, d: int, bk: int) -> bool:
-    import os
-    if os.environ.get("HBNLP_FLASH_BWD_SPLIT"):
-        return False
-    # gate on the GROUPED partial-buffer size so HBNLP_FUSED_GROUP routes
-    # to the group kernel (not silently to the split kernels) at exactly
-    # the large shapes where shrinking the buffer matters
-    nko = max(1, (sk // bk) // _fused_group(sk // bk))
-    return bh * nko * s * d * 4 <= _fused_dqp_cap()
-
-
-def _fused_group(nk: int) -> int:
-    """k blocks per grid step for the GROUP kernel — default 1 (flat fused
-    kernel), i.e. the group variant is OFF.
-
-    Measured dead end, kept for the record (``HBNLP_FUSED_GROUP=N`` to
-    re-measure; clamped to a divisor of nk): grouping k blocks shrinks the
-    dq partial buffer by N (~45 ms/step of write+reduce HBM traffic at the
-    16k shape) but the longer kernel body loses more than that to pipeline
-    stalls — v5e, 16k recipe, 64M vmem budget: flat 48-49k tok/s,
-    group 2 45.8k, group 4 35.7k.  Same economics as the norm-backward
-    pallas kernel (docs/PERFORMANCE.md round 3): the pipeline overlaps DMA
-    with compute ACROSS grid steps, and a grid step that serializes N pair
-    computations against one resident K/V tile starves that overlap."""
-    import os
-    want = int(os.environ.get("HBNLP_FUSED_GROUP", 0)) or 1
-    want = min(want, nk)
-    while want > 1 and nk % want:
-        want -= 1
-    return max(1, want)
+    """Fused one-pass backward while its float32 dq-partial buffer
+    [bh, sk // bk, s, d] fits under ``_fused_dqp_cap()``; the split dq /
+    dk-dv pair above it."""
+    return bh * max(1, sk // bk) * s * d * 4 <= _fused_dqp_cap()
 
 
 def _bwd_flat_fused(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
@@ -553,51 +445,6 @@ def _bwd_flat_fused(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
     dq_dtype = qt.dtype if out_dtype is None else out_dtype
     dk_dtype = kt.dtype if out_dtype is None else out_dtype
     dv_dtype = vt.dtype if out_dtype is None else out_dtype
-
-    group = _fused_group(nk)
-    if group > 1:
-        nko = nk // group
-        gbk = group * bk
-        _q_map = _frontier_q_map(bq, gbk, causal)
-        qrow_spec = pl.BlockSpec((None, bq, 1), _q_map)
-        dqp, dk, dv = pl.pallas_call(
-            functools.partial(_bwd_fused_group_kernel, block_q=bq,
-                              block_k=bk, group=group, num_q=nq, scale=scale,
-                              causal=causal),
-            grid=(bh, nko, nq),
-            in_specs=[pl.BlockSpec((None, bq, d), _q_map),
-                      pl.BlockSpec((None, gbk, d), lambda i, ko, j: (i, ko, 0)),
-                      pl.BlockSpec((None, gbk, d), lambda i, ko, j: (i, ko, 0)),
-                      pl.BlockSpec((None, bq, d), _q_map),
-                      qrow_spec, qrow_spec],
-            out_specs=[pl.BlockSpec((None, None, bq, d),
-                                    lambda i, ko, j: (i, ko, j, 0)),
-                       pl.BlockSpec((None, gbk, d), lambda i, ko, j: (i, ko, 0)),
-                       pl.BlockSpec((None, gbk, d), lambda i, ko, j: (i, ko, 0))],
-            out_shape=[jax.ShapeDtypeStruct((bh, nko, s, d), jnp.float32),
-                       jax.ShapeDtypeStruct((bh, sk, d), dk_dtype),
-                       jax.ShapeDtypeStruct((bh, sk, d), dv_dtype)],
-            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),
-                            pltpu.VMEM((gbk, d), jnp.float32),
-                            pltpu.VMEM((gbk, d), jnp.float32)],
-            # the group-sized dk/dv scratch + pair temporaries exceed the
-            # 16M default scoped-vmem budget at (1024, 1024, G=2); v5e has
-            # 128M physical VMEM — raise the kernel's budget instead of
-            # shrinking tiles (measured faster than any fitting tile combo)
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary"),
-                vmem_limit_bytes=_KERNEL_VMEM_BUDGET),
-            # deliberately NOT named "*_causal": the split FLOP counter
-            # models dead cells at grid-tile granularity, but this kernel
-            # masks at bk-sub-block granularity inside its unrolled group
-            # loop (and the body's `group` identical cond pairs defeat the
-            # counter's dedup) — leaving the name unmarked keeps its
-            # executed count conservatively equal to full-square
-            name="flash_bwd_fused_group",
-            interpret=interpret,
-        )(qt, kt, vt, dot, lse3, delta)
-        dq = dqp.sum(axis=1).astype(dq_dtype)
-        return dq, dk, dv
 
     _q_map = _frontier_q_map(bq, bk, causal)
     qrow_spec = pl.BlockSpec((None, bq, 1), _q_map)
@@ -639,10 +486,9 @@ def _bwd_flat(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
     (``out_dtype=f32`` there: per-hop grad pieces accumulate across P hops
     and must not round per hop).
 
-    Default path: the one-pass FUSED kernel (``_bwd_fused_kernel`` — 5 dots
-    + 1 exp per pair instead of the split kernels' 7 + 2);
-    ``HBNLP_FLASH_BWD_SPLIT=1`` forces the split dq / dk/dv kernels, as
-    does a dq-partial buffer above ``_FUSED_DQP_CAP``."""
+    The one-pass FUSED kernel (``_bwd_fused_kernel`` — 5 dots + 1 exp per
+    pair instead of the split kernels' 7 + 2) while ``_use_fused_bwd`` says
+    its dq-partial buffer fits; the split dq / dk/dv kernels above that."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -706,10 +552,8 @@ def _bwd_flat(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
 
 def _flash_bwd_pallas(q, k, v, out, lse, dout, scale, causal, block_q,
                       block_k, interpret):
-    """Flash-2 pallas backward: separate dq and dk/dv kernels, each skipping
-    causally-dead blocks — the dead half of the O(s²) work the XLA-scan
-    backward paid (it computed every q block against the FULL K row and
-    masked afterwards, VERDICT r3 weak #1)."""
+    """Flash-2 pallas backward over [b, s, h, d] operands; every kernel
+    skips the causally-dead blocks."""
     b, s, h, d = q.shape
     # caller-chosen block sizes, exactly as in the forward — attention()
     # passes the tuned 1024 tiles for both passes; tests pass small blocks
@@ -757,58 +601,10 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd_xla(scale, causal, block_q, res, dout):
-    """The previous XLA-scan backward, kept as the measured A/B fallback
-    (HBNLP_FLASH_BWD_XLA=1): lax.scan over q-row blocks recomputing softmax
-    rows per block — O(block_q·s) peak memory, but every q block multiplies
-    against the FULL K row and masks afterwards, paying the causally-dead
-    half of the O(s²) work."""
-    q, k, v, _, _ = res
-    b, s, h, d = q.shape
-    bq = min(block_q, s)
-    f32 = jnp.float32
-    qt = q.transpose(0, 2, 1, 3).reshape(b * h, s, d).astype(f32)
-    kt = k.transpose(0, 2, 1, 3).reshape(b * h, s, d).astype(f32)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * h, s, d).astype(f32)
-    dot = dout.transpose(0, 2, 1, 3).reshape(b * h, s, d).astype(f32)
-    k_pos = jnp.arange(s)[None, :]
-
-    def step(carry, i):
-        dk, dv = carry
-        qb = jax.lax.dynamic_slice_in_dim(qt, i * bq, bq, 1)
-        dob = jax.lax.dynamic_slice_in_dim(dot, i * bq, bq, 1)
-        scores = jnp.einsum("zqd,zkd->zqk", qb, kt) * scale
-        if causal:
-            q_pos = i * bq + jnp.arange(bq)[:, None]
-            scores = jnp.where(q_pos >= k_pos, scores, _NEG_INF)
-        p = jax.nn.softmax(scores, axis=-1)
-        ob = jnp.einsum("zqk,zkd->zqd", p, vt)
-        delta = jnp.sum(dob * ob, -1)
-        dp = jnp.einsum("zqd,zkd->zqk", dob, vt)
-        ds = p * (dp - delta[..., None]) * scale
-        dqb = jnp.einsum("zqk,zkd->zqd", ds, kt)
-        dk = dk + jnp.einsum("zqk,zqd->zkd", ds, qb)
-        dv = dv + jnp.einsum("zqk,zqd->zkd", p, dob)
-        return (dk, dv), dqb
-
-    zeros = jnp.zeros_like(kt)
-    (dk, dv), dqs = jax.lax.scan(step, (zeros, zeros), jnp.arange(s // bq))
-    dq = jnp.moveaxis(dqs, 0, 1).reshape(b * h, s, d)
-
-    def back(x):
-        return x.reshape(b, h, s, d).transpose(0, 2, 1, 3)
-
-    return (back(dq).astype(q.dtype), back(dk).astype(k.dtype),
-            back(dv).astype(v.dtype))
-
-
 def _flash_bwd(scale, causal, block_q, block_k, interpret, bwd_block_q,
                bwd_block_k, res, dout):
-    import os
     bq = block_q if bwd_block_q is None else bwd_block_q
     bk = block_k if bwd_block_k is None else bwd_block_k
-    if os.environ.get("HBNLP_FLASH_BWD_XLA"):
-        return _flash_bwd_xla(scale, causal, bq, res, dout)
     q, k, v, out, lse = res
     return _flash_bwd_pallas(q, k, v, out, lse, dout, scale, causal,
                              bq, bk, interpret)
@@ -840,16 +636,9 @@ def _flash_pre_fwd(q, k, v, out, lse, scale, causal, block_q, block_k,
 
 
 def _flash_pre_bwd(scale, causal, block_q, block_k, interpret, res, dout):
-    import os
     q, k, v, out, lse = res
-    if os.environ.get("HBNLP_FLASH_BWD_XLA"):
-        # the standing backward A/B (scripts/bench_long_context.py --bwd
-        # xla) must route here too — the stash path would otherwise
-        # silently measure the pallas backward under the 'xla' label
-        dq, dk, dv = _flash_bwd_xla(scale, causal, block_q, res, dout)
-    else:
-        dq, dk, dv = _flash_bwd_pallas(q, k, v, out, lse, dout, scale,
-                                       causal, block_q, block_k, interpret)
+    dq, dk, dv = _flash_bwd_pallas(q, k, v, out, lse, dout, scale, causal,
+                                   block_q, block_k, interpret)
     # out/lse are stashed residual constants of the OUTER custom_vjp; their
     # cotangents are discarded upstream
     return dq, dk, dv, jnp.zeros_like(out), jnp.zeros_like(lse)
@@ -888,7 +677,6 @@ def attention(q, k, v, scale: typing.Optional[float] = None,
         interpret = not on_tpu
     s = q.shape[1]
     blk = kernel_block(s)
-    bwq, bwk = _bwd_tiles(s, blk)
     # named-scope regions (docs/OBSERVABILITY.md 'Cost attribution'): which
     # attention implementation actually ran — flash kernel vs the dense XLA
     # fallback — is visible per-op in HLO metadata and profiler traces
@@ -908,11 +696,11 @@ def attention(q, k, v, scale: typing.Optional[float] = None,
         out_s, lse_s = stash_pop(stash)
         with jax.named_scope("flash_attention"):
             return flash_precomputed(q, k, v, out_s, lse_s, scale, causal,
-                                     bwq, bwk, interpret)
+                                     blk, blk, interpret)
     if not on_tpu or s % 128 != 0:
         with jax.named_scope("attention_dense"):
             return _xla_reference(q, k, v, scale, causal)
     with jax.named_scope("flash_attention"):
         return flash_attention(q, k, v, scale, causal, blk,
                                kernel_block(s, cap=2048), interpret,
-                               bwd_block_q=bwq, bwd_block_k=bwk)
+                               bwd_block_q=blk, bwd_block_k=blk)

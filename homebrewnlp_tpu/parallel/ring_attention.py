@@ -46,8 +46,8 @@ On TPU the zigzag hop pairs run the pallas flash kernels
 the forward merges each pair's normalized (out, lse) by log-sum-exp
 arithmetic, the backward feeds the GLOBAL lse/delta so per-hop pieces
 accumulate exactly, and k/v rotate in the raw (bf16) dtype — half the ICI
-bytes.  ``HBNLP_RING_XLA=1`` or ``use_pallas=False`` keeps the scan path
-(CPU default; also the pod-scale A/B lever, docs/PERFORMANCE.md round 4b).
+bytes.  ``use_pallas=False`` keeps the scan path (the CPU's default, and
+the path of chunks that 128 does not divide).
 """
 from __future__ import annotations
 
@@ -283,18 +283,15 @@ def _from_zigzag(x, axis_name, n_shards):
 def _use_pallas_hops(use_pallas, cs: int) -> bool:
     """Route zigzag hop pairs through the pallas flash kernels?
 
-    Default: on TPU (``HBNLP_RING_XLA=1`` forces the XLA chunk scans for
-    A/B).  The kernels need 128-divisible chunks; the XLA path remains for
-    everything else and for CPU (tests force ``use_pallas`` to exercise the
-    kernel path in interpret mode).  The forward and backward gate
-    independently — both produce/consume the same (out, lse) residual
+    Default: on TPU.  The kernels need 128-divisible chunks; the XLA path
+    remains for everything else and for CPU (tests force ``use_pallas`` to
+    exercise the kernel path in interpret mode).  The forward and backward
+    gate independently — both produce/consume the same (out, lse) residual
     contract, so mixing paths is numerically sound."""
-    import os
     if cs % 128:
         return False
     if use_pallas is None:
-        return (jax.default_backend() not in ("cpu",)
-                and not os.environ.get("HBNLP_RING_XLA"))
+        return jax.default_backend() not in ("cpu",)
     return use_pallas
 
 
@@ -631,9 +628,8 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, mesh: Mesh,
     O(seq/P · d) residual memory (see module docstring).
 
     ``use_pallas``: route zigzag hop pairs through the pallas flash
-    kernels (None = auto: TPU yes, CPU no, ``HBNLP_RING_XLA=1`` forces the
-    XLA chunk scans); tests pass True to exercise the kernel path in
-    interpret mode.
+    kernels (None = auto: TPU yes, CPU no); tests pass True to exercise
+    the kernel path in interpret mode.
 
     ``stash``: attention-output stash channel (model/blocks.py) — the
     zigzag path collects (out, lse-in-zigzag-row-order) globals, and on

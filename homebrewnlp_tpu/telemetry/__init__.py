@@ -23,17 +23,16 @@ from .buildinfo import build_info, register_build_info
 from .compiles import install_compile_listener
 from .events import FlightRecorder, RotatingJsonl
 from .profiler import OnDemandProfiler, start_capture
-from .registry import (DEFAULT_BUCKETS, Registry, histogram_quantile,
-                       jsonl_line, merge_snapshots, prometheus_text,
-                       registry, render_json, set_constant_labels,
-                       set_registry, snapshot, summarize, with_labels)
+from .registry import (DEFAULT_BUCKETS, Registry, jsonl_line,
+                       merge_snapshots, prometheus_text, registry,
+                       render_json, set_constant_labels, set_registry,
+                       snapshot, with_labels)
 from .spans import SPAN_METRIC, Phase, span
 
 __all__ = [
-    "DEFAULT_BUCKETS", "Registry", "histogram_quantile", "jsonl_line",
+    "DEFAULT_BUCKETS", "Registry", "jsonl_line",
     "merge_snapshots", "prometheus_text", "registry", "render_json",
-    "set_constant_labels", "set_registry", "snapshot", "summarize",
-    "with_labels",
+    "set_constant_labels", "set_registry", "snapshot", "with_labels",
     "SPAN_METRIC", "Phase", "span", "install_compile_listener",
     "OnDemandProfiler", "start_capture", "build_info", "register_build_info",
     "events", "tracectx", "FlightRecorder", "RotatingJsonl",

@@ -331,42 +331,6 @@ def merge_snapshots(*snapshots: dict) -> dict:
     return out
 
 
-def histogram_quantile(bounds: typing.Sequence[float],
-                       counts: typing.Sequence[int], q: float
-                       ) -> typing.Optional[float]:
-    """Approximate quantile from bucket counts (the upper bound of the
-    bucket the q-th observation falls in; +Inf bucket reports the largest
-    finite bound).  None when empty."""
-    total = sum(counts)
-    if not total:
-        return None
-    rank = q * total
-    cum = 0
-    for i, c in enumerate(counts):
-        cum += c
-        if cum >= rank and c:
-            return float(bounds[i]) if i < len(bounds) \
-                else float(bounds[-1]) if bounds else math.inf
-    return float(bounds[-1]) if bounds else math.inf
-
-
-def summarize(snap: dict) -> dict:
-    """Compact one-level dict for result JSONs (bench.py): counters/gauges
-    flatten to ``name{a=b}: value``, histograms to ``{count, sum, p50}``."""
-    out = {}
-    for name, m in snap.items():
-        for key, val in m["series"].items():
-            k = name + _label_str(tuple(m.get("labels", ())), key)
-            if m["kind"] == "histogram":
-                count = sum(val["counts"])
-                out[k] = {"count": count, "sum": round(val["sum"], 6),
-                          "p50": histogram_quantile(m["buckets"],
-                                                    val["counts"], 0.5)}
-            else:
-                out[k] = val
-    return out
-
-
 def with_labels(snap: dict, labels: typing.Dict[str, str]) -> dict:
     """A copy of ``snap`` with constant ``labels`` appended to EVERY series
     (label names already present on a metric are left alone — the caller's

@@ -36,7 +36,7 @@ SPAN_METRIC = "hbnlp_span_seconds"
 
 class Phase:
     """A pre-bound histogram child for callers that own the clock:
-    ``rec(t0, dt)`` is the whole cost (``bench.py``'s instrumented pass)."""
+    ``rec(t0, dt)`` is the whole cost."""
 
     __slots__ = ("_child", "name")
 

@@ -86,9 +86,8 @@ class RequestTrace:
                 for s in self.spans]
 
     def hops(self) -> typing.Dict[str, float]:
-        """Total seconds per hop category — the per-request breakdown
-        ``bench_serving.py`` aggregates into p50/p99 rows.  Chunk spans sum
-        per phase; singleton spans report their own duration."""
+        """Total seconds per hop category, one request's breakdown.  Chunk
+        spans sum per phase; singleton spans report their own duration."""
         out: typing.Dict[str, float] = {}
         for s in self.spans:
             name = s["name"]

@@ -6,8 +6,8 @@ chip, checked here where there is none.
   parent logic (config derivation, seeded corpus, child sequencing, exit
   codes, the shared compile cache) runs under the explicit CPU rehearsal,
   which can never pass;
-* no path moves itself to the CPU: ``bench.py`` and
-  ``dryrun_multichip`` fail when the devices they need are absent;
+* no path moves itself to the CPU: ``dryrun_multichip`` fails when the
+  devices it needs are absent;
 * one process per chip: the router parent starts no jax backend, and the
   replica fleet binds replicas to chips or refuses;
 * what steers the program says where it came from: an unknown device kind
@@ -130,14 +130,6 @@ def smoke_module_is_jax_free_test():
 
 
 # ---- no path moves itself to the CPU ---------------------------------------
-
-def bench_refuses_the_cpu_test():
-    res = subprocess.run([sys.executable, "bench.py"], cwd=REPO,
-                         env=_cpu_env(), capture_output=True, text=True,
-                         timeout=120)
-    assert res.returncode == 2 and res.stdout == ""
-    assert "no accelerator" in res.stderr
-
 
 def dryrun_multichip_raises_without_the_devices_test():
     res = subprocess.run(

@@ -4,6 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from homebrewnlp_tpu.parallel import flash_attention as fa
 from homebrewnlp_tpu.parallel.flash_attention import (_xla_reference,
                                                       flash_attention)
 
@@ -83,32 +84,6 @@ def flash_grad_uneven_blocks_test(causal):
                                    rtol=2e-4, atol=2e-5)
 
 
-def flash_bwd_xla_fallback_test(monkeypatch):
-    """HBNLP_FLASH_BWD_XLA=1 routes the backward through the kept XLA-scan
-    path; gradients agree with the pallas kernels."""
-    import os
-    rng = np.random.default_rng(4)
-    b, s, h, d = 1, 32, 2, 8
-    q = jnp.asarray(rng.standard_normal((b, s, h, d)).astype(np.float32))
-    k = jnp.asarray(rng.standard_normal((b, s, h, d)).astype(np.float32))
-    v = jnp.asarray(rng.standard_normal((b, s, h, d)).astype(np.float32))
-
-    def grads():
-        return jax.grad(lambda q, k, v: jnp.sum(
-            flash_attention(q, k, v, 0.35, True, 16, 16, True) ** 2),
-            argnums=(0, 1, 2))(q, k, v)
-
-    g_pallas = grads()
-    monkeypatch.setenv("HBNLP_FLASH_BWD_XLA", "1")
-    jax.clear_caches()
-    g_xla = grads()
-    monkeypatch.delenv("HBNLP_FLASH_BWD_XLA")
-    jax.clear_caches()
-    for a, b_ in zip(g_pallas, g_xla):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
-                                   rtol=2e-4, atol=2e-5)
-
-
 def bwd_block_override_parity_test():
     """bwd_block_q/bwd_block_k override the backward kernels' tiles
     independently of the forward's (attention() uses a wider forward k tile
@@ -156,10 +131,11 @@ def fused_bwd_matches_split_test(causal, bq, bk, monkeypatch):
             argnums=(0, 1, 2))(q, k, v)
 
     g_fused = grads()
-    monkeypatch.setenv("HBNLP_FLASH_BWD_SPLIT", "1")
+    # no buffer fits a cap of 0: the split pair runs
+    monkeypatch.setattr(fa, "_fused_dqp_cap", lambda: 0)
     jax.clear_caches()
     g_split = grads()
-    monkeypatch.delenv("HBNLP_FLASH_BWD_SPLIT")
+    monkeypatch.undo()
     jax.clear_caches()
     g_ref = jax.grad(lambda q, k, v: jnp.sum(
         _xla_reference(q, k, v, 0.35, causal) ** 2), argnums=(0, 1, 2))(q, k, v)
@@ -172,7 +148,7 @@ def fused_bwd_matches_split_test(causal, bq, bk, monkeypatch):
                                    rtol=2e-4, atol=2e-5)
 
 
-def fused_bwd_uneven_lengths_test():
+def fused_bwd_uneven_lengths_test(monkeypatch):
     """_bwd_flat with sq != sk (the ring-hop contract allows it): fused vs
     split parity on a rectangular non-causal pair."""
     from homebrewnlp_tpu.parallel.flash_attention import _bwd_flat
@@ -192,72 +168,17 @@ def fused_bwd_uneven_lengths_test():
     out = jnp.einsum("zqk,zkd->zqd", p_un / l[..., None], vt)
     delta = jnp.sum(dot * out, -1, keepdims=True)
 
-    import os
     res_fused = _bwd_flat(qt, kt, vt, dot, lse[..., None], delta, 0.35,
                           False, 16, 16, True)
-    os.environ["HBNLP_FLASH_BWD_SPLIT"] = "1"
-    try:
-        jax.clear_caches()
-        res_split = _bwd_flat(qt, kt, vt, dot, lse[..., None], delta, 0.35,
-                              False, 16, 16, True)
-    finally:
-        del os.environ["HBNLP_FLASH_BWD_SPLIT"]
+    monkeypatch.setattr(fa, "_fused_dqp_cap", lambda: 0)
+    jax.clear_caches()
+    res_split = _bwd_flat(qt, kt, vt, dot, lse[..., None], delta, 0.35,
+                          False, 16, 16, True)
+    monkeypatch.undo()
     jax.clear_caches()
     for a, b_ in zip(res_fused, res_split):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    rtol=1e-5, atol=1e-5)
-
-
-def bwd_tile_env_rounding_test(monkeypatch):
-    """HBNLP_BWD_BQ/BK retuning overrides round to power-of-two divisors of
-    the sequence (non-divisor junk must never reach the kernels — the grids
-    and _causal_split assume block-aligned tiles) with a floor of 128."""
-    from homebrewnlp_tpu.parallel.flash_attention import _bwd_tiles
-    assert _bwd_tiles(16384, 1024) == (1024, 1024)
-    monkeypatch.setenv("HBNLP_BWD_BQ", "2048")
-    assert _bwd_tiles(16384, 1024) == (2048, 1024)
-    monkeypatch.setenv("HBNLP_BWD_BQ", "1536")   # non-power-of-two junk
-    assert _bwd_tiles(16384, 1024) == (1024, 1024)
-    monkeypatch.setenv("HBNLP_BWD_BQ", "7")      # degenerate: floored to 128
-    assert _bwd_tiles(16384, 1024) == (128, 1024)
-    monkeypatch.setenv("HBNLP_BWD_BK", "512")
-    assert _bwd_tiles(16384, 1024)[1] == 512
-
-
-def fused_group_kernel_parity_test(monkeypatch):
-    """HBNLP_FUSED_GROUP=2 routes the group-of-k fused backward (a kept
-    measured dead end — see _fused_group); gradients must match the flat
-    fused kernel and dense autodiff."""
-    rng = np.random.default_rng(13)
-    b, s, h, d = 1, 96, 2, 8
-    q = jnp.asarray(rng.standard_normal((b, s, h, d)).astype(np.float32))
-    k = jnp.asarray(rng.standard_normal((b, s, h, d)).astype(np.float32))
-    v = jnp.asarray(rng.standard_normal((b, s, h, d)).astype(np.float32))
-
-    def grads():
-        return jax.grad(lambda q, k, v: jnp.sum(
-            flash_attention(q, k, v, 0.35, True, 16, 16, True) ** 2),
-            argnums=(0, 1, 2))(q, k, v)
-
-    g_flat = grads()
-    monkeypatch.setenv("HBNLP_FUSED_GROUP", "2")
-    jax.clear_caches()
-    # guard against a vacuous pass: the env must actually select the group
-    # kernel for this shape (s=96, blocks 16 -> nk=6, divisible by 2)
-    from homebrewnlp_tpu.parallel.flash_attention import (_fused_group,
-                                                          _use_fused_bwd)
-    assert _fused_group(6) == 2
-    assert _use_fused_bwd(2, 96, 96, 8, 16)
-    g_group = grads()
-    monkeypatch.delenv("HBNLP_FUSED_GROUP")
-    jax.clear_caches()
-    g_ref = jax.grad(lambda q, k, v: jnp.sum(
-        _xla_reference(q, k, v, 0.35, True) ** 2), argnums=(0, 1, 2))(q, k, v)
-    for a, b_, c in zip(g_group, g_flat, g_ref):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
-                                   rtol=1e-5, atol=1e-5)
-        np.testing.assert_allclose(np.asarray(a), np.asarray(c),
-                                   rtol=2e-4, atol=2e-5)
 
 
 def flash_wide_head_dim_test():
@@ -309,3 +230,26 @@ def fused_bwd_random_shapes_property_test():
             np.testing.assert_allclose(
                 np.asarray(a), np.asarray(b_), rtol=3e-4, atol=3e-5,
                 err_msg=f"trial={trial} s={s} bq={bq} bk={bk} causal={causal}")
+
+
+@pytest.mark.parametrize("bh,s,d,fused", [
+    # train_1b_long_context_s16k: 16 heads x 512, 8.6 GB of dq partials
+    (16, 16384, 512, False),
+    # train_olmoe_1b_7b_s4k: batch 2 x 16 heads x 128, 268 MB
+    (32, 4096, 128, True),
+    # one ring hop's chunk pair of configs/1b_long_context.json, 134 MB
+    (16, 2048, 512, True),
+    # BASELINE.md '32k context single-chip': 8 heads x 128, batch 1, 4.3 GB
+    (8, 32768, 128, True),
+])
+def backward_path_follows_the_buffer_test(bh, s, d, fused, monkeypatch):
+    """The one fork the backward keeps is chosen from what the code
+    observes — the dq-partial buffer's bytes against the chip's memory, here
+    a v5e's 16 GiB — and the benchmark has a cell on each side of it."""
+    from homebrewnlp_tpu.utils import flops
+    monkeypatch.delenv("HBNLP_FUSED_DQP_CAP_GB", raising=False)
+    monkeypatch.setattr(flops, "device_hbm_bytes",
+                        lambda device=None: 16 * 1024 ** 3)
+    bk = fa.kernel_block(s)
+    assert bk == 1024
+    assert fa._use_fused_bwd(bh, s, s, d, bk) is fused

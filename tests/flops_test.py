@@ -1,5 +1,5 @@
 """Jaxpr matmul-FLOP counter (homebrewnlp_tpu/utils/flops.py) — feeds the
-MFU number bench.py reports."""
+MFU numbers the program reports."""
 import jax
 import jax.numpy as jnp
 

@@ -72,6 +72,13 @@ def collective_inventory_axes_and_bytes_test():
     census = hlo_lint.collective_census(hlo)
     assert {k: v["count"] for k, v in inv.items()} \
         == {k: v for k, v in census.items() if v}
+    # a combined all-reduce: XLA numbers a long tuple's elements, and the
+    # "=" of the comment must not cut the result short (it read 24 of 40)
+    combined = ("%c = (f32[2]{0}, f32[2]{0}, f32[2]{0}, f32[2]{0}, "
+                "/*index=5*/f32[2]{0}) all-reduce(%a, %b, %c, %d, %e), "
+                "replica_groups=[4,2]<=[8]")
+    assert hlo_lint.collective_inventory(combined)["all-reduce"] \
+        == {"count": 1, "bytes": 40}
 
 
 # ---- pass 1 negative control: surplus collective names its axis ------------

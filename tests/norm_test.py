@@ -49,53 +49,3 @@ def fused_matches_autodiff_test():
                       argnums=(0, 1, 2))(x, scale, shift)
         for a, b in zip(g1, g2):
             np.testing.assert_allclose(b, a, atol=1e-4, rtol=1e-5)
-
-
-def pallas_backward_matches_xla_test():
-    """The one-pass pallas backward (interpret mode on CPU) matches the XLA
-    backward bit-for-bit-ish on both the group (trailing-axis) and
-    full-feature layouts, with every scale/shift combination."""
-    from homebrewnlp_tpu.model.normalization import (_norm_bwd_pallas,
-                                                     _norm_bwd_xla)
-    rng = np.random.default_rng(3)
-    # 1024 rows -> multiple grid blocks, so the per-block partial-sum
-    # outputs and the outside sum(0) are exercised (nb > 1), not just the
-    # degenerate single-block case
-    x = jnp.asarray(rng.standard_normal((128, 8, 2, 128)) * 2 + 0.3,
-                    jnp.float32)
-    dy = jnp.asarray(rng.standard_normal(x.shape), jnp.float32)
-    scale = jnp.asarray(rng.standard_normal((1, 1, 2, 128)) + 1, jnp.float32)
-    shift = jnp.asarray(rng.standard_normal((1, 1, 2, 128)), jnp.float32)
-    one = jnp.ones((1, 1, 1, 1), jnp.float32)
-    for axes in ((3,), (2, 3)):
-        mu = jnp.mean(x, axes, keepdims=True)
-        var = jnp.mean(jnp.square(x), axes, keepdims=True) - jnp.square(mu)
-        inv = jax.lax.rsqrt(jnp.maximum(var, 0.0) + 1e-5)
-        for has_scale, has_shift in ((True, True), (True, False),
-                                     (False, True)):
-            res = (x, scale if has_scale else one,
-                   shift if has_shift else one, mu, inv)
-            out_p = _norm_bwd_pallas(axes, 1e-5, has_scale, has_shift, res,
-                                     dy, interpret=True)
-            assert out_p is not None, (axes, has_scale, has_shift)
-            out_x = _norm_bwd_xla(axes, 1e-5, has_scale, has_shift, res, dy)
-            for a, b in zip(out_p, out_x):
-                np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                           atol=2e-4, rtol=1e-5)
-
-
-def pallas_backward_layout_gates_test():
-    """Unsupported layouts return None (caller falls back to XLA)."""
-    from homebrewnlp_tpu.model.normalization import _norm_bwd_pallas
-    x = jnp.ones((4, 4, 2, 64), jnp.float32)  # f=64 not lane-aligned
-    one = jnp.ones((1, 1, 2, 64), jnp.float32)
-    mu = jnp.zeros((4, 4, 2, 1), jnp.float32)
-    res = (x, one, one, mu, mu + 1)
-    assert _norm_bwd_pallas((3,), 1e-5, True, True, res, x,
-                            interpret=True) is None
-    # non-trailing reduce axes
-    x2 = jnp.ones((4, 128, 2, 128), jnp.float32)
-    res2 = (x2, jnp.ones((1, 128, 1, 1)), jnp.ones((1, 128, 1, 1)),
-            jnp.zeros(()), jnp.ones(()))
-    assert _norm_bwd_pallas((1,), 1e-5, True, True, res2, x2,
-                            interpret=True) is None

@@ -1,4 +1,4 @@
-"""Weight-only int8 serving quantization (infer/quant.py).
+"""Weight-only int8 serving quantization (core/quant.py).
 
 Batch-1 decode is weight-read bound; int8 weights halve the bytes.  The
 contract tested here: eligible weights round-trip within per-tensor int8
@@ -11,8 +11,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from backend import MIXER_BLOCKS, make_params
+from homebrewnlp_tpu.core.quant import quantize_variables
 from homebrewnlp_tpu.infer.interface import InterfaceWrapper
-from homebrewnlp_tpu.infer.quant import quantize_variables
 from homebrewnlp_tpu.infer.sampler import sample_text
 from homebrewnlp_tpu.model import Model
 

@@ -330,6 +330,43 @@ def engine_programs_mirror_entry_points_test():
         assert name in row, (name, row)
 
 
+def env_knob_rule_clean_tree_test():
+    """No environment read in the layers that build the step program but
+    the allowlisted ones — and every allowlisted name is still read there
+    (an entry whose debt was paid must leave the list)."""
+    read = set()
+    for path, rel in ast_lint.iter_source_files():
+        if not rel.startswith(ast_lint.ENV_KNOB_DIRS):
+            continue
+        with open(path) as f:
+            src = f.read()
+        found = [f_ for f_ in ast_lint.lint_source(rel, src)
+                 if f_.rule == "env-knob"]
+        assert found == [], "\n".join(str(f_) for f_ in found)
+        read |= {k for k in ast_lint.ENV_KNOB_ALLOWED if f'"{k}"' in src}
+    assert read == set(ast_lint.ENV_KNOB_ALLOWED)
+
+
+def env_knob_rule_negative_control_test():
+    rel = "homebrewnlp_tpu/parallel/new_kernel.py"
+    for bad in ('import os\nx = os.environ.get("HBNLP_NEW_TILE")\n',
+                'import os\nx = os.getenv("HBNLP_NEW_TILE", "1")\n',
+                'import os\nx = os.environ["HBNLP_NEW_TILE"]\n',
+                'from os import environ\nx = environ.get("HBNLP_NEW_TILE")\n',
+                'import os\nk = "A" + "B"\nx = os.environ.get(k)\n',
+                'import os\nif "HBNLP_NEW_PATH" in os.environ:\n    x = 1\n',
+                'import os\nx = "HBNLP_NEW_PATH" not in os.environ\n',
+                'import os\nx = os.environ.setdefault("HBNLP_NEW_TILE", "8")\n',
+                'import os\nx = os.environ.pop("HBNLP_NEW_TILE", None)\n'):
+        assert [f.rule for f in ast_lint.lint_source(rel, bad)] \
+            == ["env-knob"], bad
+    allowed = 'import os\nx = os.environ.get("HBNLP_FUSED_DQP_CAP_GB")\n'
+    assert ast_lint.lint_source(rel, allowed) == []
+    # the other layers keep their deployment settings (addresses, paths)
+    assert ast_lint.lint_source("homebrewnlp_tpu/distributed/x.py",
+                                'import os\nx = os.environ["HOST"]\n') == []
+
+
 def config_docs_rule_negative_control_test(tmp_path):
     cfg = tmp_path / "config.py"
     lines = ["class ModelParameter:",

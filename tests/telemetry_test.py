@@ -177,11 +177,6 @@ def merge_and_render_test():
     assert doc["step"] == 7
     assert doc["metrics"]["n_total"]["series"][""] == 5
     assert doc["metrics"]["h"]["series"][""]["count"] == 2
-    # summarize: flat keys, histogram medians
-    summary = telemetry.summarize(merged)
-    assert summary["n_total"] == 5
-    assert summary["h"]["count"] == 2 and summary["h"]["p50"] == 1.0
-    assert telemetry.histogram_quantile((1.0,), [0, 0], 0.5) is None
 
 
 def merged_histogram_inf_cumulativity_test():

@@ -1,42 +1,26 @@
 """Cost-attribution layer (marker: attribution; docs/OBSERVABILITY.md
 'Cost attribution').
 
-Cheap half: scope folding, the HLO instruction->scope join on synthetic
-text, trace loading/filtering on the checked-in miniature fixture
-(tests/data/mini_trace), the ledger regression check's negative controls
+Cheap half: scope folding, the ledger regression check's negative controls
 (an inflated ledger MUST fail the lint), and the serving TTFT/ITL/cache-
 bandwidth recording driven through the real hook plumbing.
 
 Expensive half (one audit-model build per module): the committed
-``analysis/cost_ledger.json`` matches a fresh build, and
-``scripts/attribute_step.py`` on a real CPU ``jax.profiler`` capture of
-the audit train step attributes >= 5 distinct model scopes with < 15% of
-device time unattributed — the PR's acceptance criterion.
+``analysis/cost_ledger.json`` matches a fresh build.  Reading a device
+trace is the benchmark's (``benchmark/trace/reduce.py``, tested on a v5e
+fixture by ``benchmark/tests/trace_test.py``).
 """
 import copy
-import glob
-import json
-import os
-import sys
 import time
 
 import numpy as np
 import pytest
 
-sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "scripts"))
-
-import analyze_trace  # noqa: E402
-import attribute_step  # noqa: E402
-from backend import make_params  # noqa: E402
-from homebrewnlp_tpu import telemetry  # noqa: E402
-from homebrewnlp_tpu.analysis import cost_ledger  # noqa: E402
+from backend import make_params
+from homebrewnlp_tpu import telemetry
+from homebrewnlp_tpu.analysis import cost_ledger
 
 pytestmark = pytest.mark.attribution
-
-MINI_TRACE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                          "data", "mini_trace")
-
 
 @pytest.fixture
 def fresh_registry():
@@ -76,163 +60,6 @@ def scope_key_test():
 
 
 # ------------------------------------------- instruction table + event join
-
-_SYNTH_HLO = """\
-HloModule jit_step_fn, entry_computation_layout={()->f32[4]}
-
-%fused_computation.1 (p0: f32[4]) -> f32[4] {
-  %p0 = f32[4]{0} parameter(0)
-  %mul.3 = f32[4]{0} multiply(f32[4]{0} %p0, f32[4]{0} %p0), metadata={op_name="jit(step_fn)/jit(main)/gpt0/body0/block0_0_0/norm_0/mul"}
-  ROOT %bitcast.9 = f32[4]{0} bitcast(f32[4]{0} %mul.3)
-}
-
-ENTRY %main.10 () -> f32[4] {
-  %dot.5 = f32[4]{0} dot(f32[4]{0} %x, f32[4]{0} %y), lhs_contracting_dims={0}, rhs_contracting_dims={0}, metadata={op_name="jit(step_fn)/jit(main)/gpt0/body0/block0_1_0/attention_0/dot_general"}
-  %convert_add_fusion.clone = f32[4]{0} fusion(f32[4]{0} %dot.5), kind=kLoop, calls=%fused_computation.1
-  %copy_bitcast_fusion.2 = f32[4]{0} fusion(f32[4]{0} %dot.5), kind=kLoop, calls=%fused_computation.1
-  %while.1 = (s32[], f32[4]{0}) while((s32[], f32[4]{0}) %tup), condition=%cond, body=%bodyc
-  ROOT %broadcast.9 = f32[4]{0} broadcast(f32[] %c), dimensions={}
-}
-"""
-
-
-def instruction_table_test():
-    table = cost_ledger.instruction_table(_SYNTH_HLO)
-    assert table["dot.5"]["kind"] == "dot"
-    assert table["dot.5"]["op_name"].endswith("attention_0/dot_general")
-    # fusion without own metadata inherits through calls= (root is a
-    # metadata-less bitcast -> majority vote of the computation's members)
-    assert table["convert_add_fusion.clone"]["op_name"].endswith("norm_0/mul")
-    assert table["copy_bitcast_fusion.2"]["op_name"].endswith("norm_0/mul")
-    assert table["while.1"]["kind"] == "while"
-
-
-_CHAINED_HLO = """\
-HloModule jit_chain
-
-%inner (p0: f32[4]) -> f32[4] {
-  %p0 = f32[4]{0} parameter(0)
-  ROOT %mul.1 = f32[4]{0} multiply(f32[4]{0} %p0, f32[4]{0} %p0), metadata={op_name="jit(f)/gpt0/body0/block0_0_0/norm_0/mul"}
-}
-
-%wrapper (p1: f32[4]) -> f32[4] {
-  %p1 = f32[4]{0} parameter(0)
-  ROOT %fusion.2 = f32[4]{0} fusion(f32[4]{0} %p1), kind=kLoop, calls=%inner
-}
-
-ENTRY %main () -> f32[4] {
-  %call.3 = f32[4]{0} call(f32[4]{0} %x), to_apply=%wrapper
-  ROOT %tuple.4 = (f32[4]{0}) tuple(f32[4]{0} %call.3)
-}
-"""
-
-
-def instruction_table_chained_inheritance_test():
-    """A metadata-less call into a computation whose ONLY member is a
-    metadata-less fusion must hop through that fusion's computation: the
-    'call -> computation whose root is a fusion' chain resolves instead of
-    inflating the unattributed share."""
-    table = cost_ledger.instruction_table(_CHAINED_HLO)
-    assert table["fusion.2"]["op_name"].endswith("norm_0/mul")
-    assert table["call.3"]["op_name"].endswith("norm_0/mul")
-    # data the compiler moves (a layout copy of a convert of a scoped value,
-    # no metadata on either) belongs to the scope that made the value; a
-    # copy of a parameter stays nameless
-    moved = cost_ledger.instruction_table(_CHAINED_HLO.replace(
-        "  ROOT %tuple.4", "  %convert.7 = bf16[4]{0} convert(f32[4]{0} "
-        "%call.3)\n  %copy.8 = bf16[4]{0} copy(bf16[4]{0} %convert.7)\n"
-        "  %copy.9 = f32[4]{0} copy(f32[4]{0} %x)\n  ROOT %tuple.4"))
-    assert moved["copy.8"]["op_name"].endswith("norm_0/mul")
-    assert moved["copy.9"]["op_name"] is None
-
-
-def attribute_events_test():
-    table = cost_ledger.instruction_table(_SYNTH_HLO)
-    events = [("dot.5", 300.0),
-              ("convert_add_fusion", 200.0),   # .clone fallback lookup
-              ("copy_bitcast_fusion.2", 100.0),
-              ("while.1", 650.0),              # container: excluded
-              ("broadcast.9", 50.0)]           # no metadata: unattributed
-    per_scope, unattr, total = cost_ledger.attribute_events(events, table)
-    assert total == 650.0                      # while excluded from total
-    assert per_scope["body/attention"] == 300.0
-    assert per_scope["body/norm"] == 300.0
-    assert per_scope["unattributed"] == 50.0 and unattr == {"broadcast.9": 50.0}
-
-
-def attribute_fn_with_ledger_test():
-    ledger_entry = {"scopes": {
-        "body/attention": {"flops_share": 0.9, "bytes_share": 0.5,
-                           "bound": "compute"},
-        "body/norm": {"flops_share": 0.0, "bytes_share": 0.1,
-                      "bound": "hbm"}}}
-    table_events = [("dot.5", 100.0), ("convert_add_fusion", 400.0)]
-    rows, unattributed, total = attribute_step.attribute(
-        table_events, _SYNTH_HLO, ledger_entry)
-    by_scope = {r["scope"]: r for r in rows}
-    # norm burns 80% of time with ~0 flops and 10% of bytes: pure overhead
-    assert by_scope["body/norm"]["overhead"] is True
-    assert by_scope["body/attention"]["overhead"] is False
-    assert unattributed == 0.0 and total == 500.0
-
-
-# ---------------------------------------------------- trace loading fixture
-
-def mini_trace_load_test():
-    evs = analyze_trace.load_events(MINI_TRACE)
-    # 0-duration and non-X events dropped
-    assert len(evs) == 9
-    dev = analyze_trace.device_events(evs)
-    assert len(dev) == 6
-    assert all(e["args"]["hlo_op"] for e in dev)
-    mods = {e["args"]["hlo_module"] for e in dev}
-    assert mods == {"jit_step_fn", "jit_other"}
-
-
-def mini_trace_categorize_test():
-    assert analyze_trace.categorize("dynamic-update-slice.3") \
-        == "scan-stack (DUS)"
-    assert analyze_trace.categorize("convert_bitcast_fusion.9") \
-        == "convert/copy/transpose"
-    assert analyze_trace.categorize("copy_bitcast_fusion.2") \
-        == "convert/copy/transpose"
-    assert analyze_trace.categorize("reduce.17") == "reduce"
-    assert analyze_trace.categorize("fusion.3") == "fusion (dot-rooted)"
-    # loop/input fusions are elementwise bodies, NOT dot-rooted
-    assert analyze_trace.categorize("loop_fusion.42") \
-        == "fusion (loop/elementwise)"
-    assert analyze_trace.categorize("input_fusion.7") \
-        == "fusion (loop/elementwise)"
-
-
-def empty_trace_fails_loudly_test(tmp_path):
-    import gzip
-    import subprocess
-    d = tmp_path / "plugins" / "profile" / "0"
-    d.mkdir(parents=True)
-    p = d / "host.trace.json.gz"
-    with gzip.open(p, "wt") as f:
-        json.dump({"traceEvents": [{"ph": "M", "name": "meta"}]}, f)
-    assert analyze_trace.load_events(str(tmp_path)) == []
-    # the CLI: zero timed events exits nonzero NAMING the file, instead of
-    # printing an empty table
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    r = subprocess.run([sys.executable,
-                        os.path.join(repo, "scripts", "analyze_trace.py"),
-                        str(tmp_path)], capture_output=True, text=True)
-    assert r.returncode != 0
-    assert "host.trace.json.gz" in (r.stderr + r.stdout)
-    # attribute_step fails loudly too
-    with pytest.raises(SystemExit, match="zero device-side"):
-        attribute_step.main([str(tmp_path), "--hlo", os.devnull])
-
-
-def missing_trace_dir_fails_test(tmp_path):
-    with pytest.raises(SystemExit, match="no .*trace.json.gz"):
-        analyze_trace.resolve_trace_file(str(tmp_path))
-
-
-# ------------------------------------------------- ledger negative controls
 
 def ledger_missing_file_is_finding_test(tmp_path):
     f = cost_ledger.ledger_audit(path=str(tmp_path / "absent.json"),
@@ -577,48 +404,3 @@ def committed_ledger_matches_fresh_build_test(audit_rig):
         for metric in ("flops", "bytes"):
             a, b = old["scopes"][scope][metric], s[metric]
             assert abs(b - a) <= tol * max(abs(a), 1), (scope, metric, a, b)
-
-
-def attribute_step_end_to_end_test(audit_rig, tmp_path, capsys):
-    """PR acceptance: attribute_step on a CPU profile_steps-style capture
-    of the audit model prints a per-scope table with >= 5 distinct model
-    scopes attributed and < 15% of device time unattributed.
-
-    It shares no state with the tests before it: run as the first file of
-    its worker it read 6-12% alone and 40% in one loaded run of the whole
-    suite (PR 23).  The share is a TIME share on the CPU, and what was
-    unattributed — XLA:CPU's metadata-less layout copies and
-    ``wrapped_convert`` fusions, hundreds of tiny memory-bound thunks —
-    swells out of proportion when six workers compile at once.  With those
-    inherited from their operand's producer (``instruction_table``) the
-    share alone is 2-4%, which leaves the threshold its margin."""
-    import jax
-    trainer, state, batch = (audit_rig["trainer"], audit_rig["state"],
-                             audit_rig["batch"])
-    state, m = trainer.step(state, batch)    # compile outside the capture
-    jax.block_until_ready(m["loss"])
-    jax.profiler.start_trace(str(tmp_path))
-    for _ in range(3):
-        state, m = trainer.step(state, batch)
-    jax.block_until_ready(m["loss"])
-    jax.profiler.stop_trace()
-    assert glob.glob(str(tmp_path / "**" / "*.trace.json.gz"),
-                     recursive=True)
-
-    hlo_file = tmp_path / "train_step_compiled.txt"
-    hlo_file.write_text(audit_rig["train_hlo"])
-    rc = attribute_step.main([str(tmp_path), "--steps", "3",
-                              "--hlo", str(hlo_file)])
-    assert rc == 0
-    out = capsys.readouterr().out
-    assert "scope attribution" in out and "ms/step" in out
-    model_scopes = [ln.split()[0] for ln in out.splitlines()
-                    if ln.strip() and ln.split()[0].startswith(
-                        ("body", "input", "output", "loss", "optimizer",
-                         "decode"))]
-    assert len(set(model_scopes)) >= 5, out
-    unattr = [ln for ln in out.splitlines()
-              if ln.startswith("unattributed device time:")]
-    assert unattr, out
-    share = float(unattr[0].split(":")[1].split("%")[0])
-    assert share < 15.0, out
