@@ -263,8 +263,8 @@ class ModelParameter:
         # HBM fraction (model/blocks.py resolve_stash) — the measured 16k/32k
         # recipes then need no explicit flag.
         # DEPRECATED ALIAS (PR 11): with remat_policy "auto" an explicit
-        # true forces the attention kind of the stash on (the bottleneck
-        # kind still resolves by its own rule), false is "recompute"; the
+        # true forces the attention kind of the stash on (the other kinds
+        # still resolve by their own rules), false is "recompute"; the
         # policy layer below is the real knob
         self.stash_attention_outputs = "auto"
         # ---- measured remat policy (model/remat.py, docs/PERFORMANCE.md
@@ -280,7 +280,11 @@ class ModelParameter:
         #                  stash_attention_outputs=true) and
         #                  bottleneck_group_linear's in-projection output
         #                  (no second matmul and, where it contracts a
-        #                  mesh-sharded axis, no second all-reduce),
+        #                  mesh-sharded axis, no second all-reduce); under
+        #                  the "checkpoint" strategy the experts kind:
+        #                  layer moe's three grouped-matmul outputs and
+        #                  routing triple, saved by the block's
+        #                  jax.checkpoint (model/remat.py),
         #   "save"       — NO custom_vjp: the plain recurrence under native
         #                  scan AD, every linearization residual saved
         #                  (zero recompute, O(depth) residual memory),
@@ -291,7 +295,9 @@ class ModelParameter:
         #   "auto"       — each stash kind by its own rule (attention:
         #                  long-context pays and fits; bottleneck: its
         #                  contraction crosses a 'model' axis > 1 and the
-        #                  bytes fit what attention leaves of the budget),
+        #                  bytes fit what attention leaves of the budget;
+        #                  experts: the strategy is "checkpoint", a moe
+        #                  layer, the whole depth's outputs fit 15% of HBM),
         #                  else recompute; the save modes
         #                  are measured opt-ins — the round-11 A/B lost on
         #                  the hbm-bound rig and model/remat.py documents

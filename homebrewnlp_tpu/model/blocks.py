@@ -425,12 +425,21 @@ def _mom_scan_bwd(fns, alpha, unroll, stash, res, cot):
 momentum_scan.defvjp(_mom_scan_fwd, _mom_scan_bwd)
 
 
-def _checkpoint_policy(params: ModelParameter):
-    """The named ``jax.checkpoint`` policy for the 'checkpoint' strategy
-    (``gradient_checkpointing_policy``; the default "nothing_saveable" is
-    jax.checkpoint's own default, so reference configs are unchanged)."""
-    return getattr(jax.checkpoint_policies,
-                   params.gradient_checkpointing_policy)
+def _checkpoint_policy(params: ModelParameter, mesh=None):
+    """The ``jax.checkpoint`` policy for the 'checkpoint' strategy: the
+    named one (``gradient_checkpointing_policy``; the default
+    "nothing_saveable" is jax.checkpoint's own default, so reference
+    configs are unchanged) and, where model/remat.py's ``experts`` kind
+    rides, also layer ``moe``'s named outputs (model/moe.py
+    ``SAVED_NAMES``)."""
+    named = getattr(jax.checkpoint_policies,
+                    params.gradient_checkpointing_policy)
+    from .remat import stash_plan
+    if not stash_plan(params, mesh)["experts"][0]:
+        return named
+    from .moe import SAVED_NAMES
+    return jax.checkpoint_policies.save_from_both_policies(
+        named, jax.checkpoint_policies.save_only_these_names(*SAVED_NAMES))
 
 
 def _merge_stats(parts) -> dict:
@@ -615,7 +624,7 @@ def _try_scan(params: ModelParameter, ctx, plan, src: NamedTensor,
         return x + v
     out, stats = _plain_scan(fns, stacked, shared, src,
                              strategy == "checkpoint", params.scan_unroll,
-                             _checkpoint_policy(params),
+                             _checkpoint_policy(params, ctx.mesh),
                              collect=ctx.layer_stats is not None)
     if stats:
         ctx.layer_stats.append(_merge_stats([stats]))
@@ -1016,10 +1025,11 @@ def run_body_blocks(params: ModelParameter, src: NamedTensor,
     # checkpoint / none: the plain stream, each block's layer statistics
     # an explicit output of its region
     out, parts = src, []
+    checkpoint_policy = _checkpoint_policy(params, ctx.mesh)
     for f, s in zip(fns, subsets):
         call = _block_with_stats(f, ctx.layer_stats is not None)
         if strategy == "checkpoint":
-            call = jax.checkpoint(call, policy=_checkpoint_policy(params))
+            call = jax.checkpoint(call, policy=checkpoint_policy)
         out, stats = call(s, out)
         parts.append(stats)
     if any(parts):

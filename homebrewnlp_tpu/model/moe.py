@@ -19,6 +19,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..config import BlockArgs
 from ..core import scope
@@ -89,6 +90,18 @@ def _combine_bwd(k, res, g):
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+#: the names layer ``moe`` gives, by ``checkpoint_name``, to what is dear to
+#: replay per byte: the three grouped matmuls' outputs and the routing triple.
+#: Free where no policy names them; under the ``checkpoint`` strategy the
+#: block's ``jax.checkpoint`` saves them where model/remat.py's ``experts``
+#: kind rides (model/blocks.py ``_checkpoint_policy``), and the replay then
+#: runs neither the three forward kernels (megablox keeps only its INPUTS as
+#: residuals) nor the pairs' sort; the router's softmax and top-k stay in
+#: it, their ``weights`` feed ``_combine``'s backward.
+SAVED_NAMES = ("moe_gate", "moe_up", "moe_down",
+               "moe_order", "moe_inverse", "moe_sizes")
 
 
 #: rows, contraction and columns of one tile of the grouped-matmul kernel;
@@ -184,6 +197,9 @@ def moe(args: BlockArgs) -> NamedTensor:
         weights, experts = route(logits, top_k)
     with jax.named_scope("dispatch"):
         order, inverse, sizes = sort_pairs(experts, n_exp)
+        order = checkpoint_name(order, "moe_order")
+        inverse = checkpoint_name(inverse, "moe_inverse")
+        sizes = checkpoint_name(sizes, "moe_sizes")
         rows = _dispatch(xf, order, inverse, top_k)
     if ctx.layer_stats is not None:
         ctx.layer_stats.append({
@@ -192,12 +208,14 @@ def moe(args: BlockArgs) -> NamedTensor:
                 jnp.max(sizes).astype(jnp.float32) * n_exp / (t_sz * top_k),
             "moe_routed_pairs": jnp.sum(sizes).astype(jnp.float32)})
     with jax.named_scope("experts"):
-        gate = grouped_dot(rows, w_gate.data.reshape(n_exp, f_sz, i_sz), sizes)
-        up = grouped_dot(rows, w_up.data.reshape(n_exp, f_sz, i_sz), sizes)
+        gate = checkpoint_name(grouped_dot(
+            rows, w_gate.data.reshape(n_exp, f_sz, i_sz), sizes), "moe_gate")
+        up = checkpoint_name(grouped_dot(
+            rows, w_up.data.reshape(n_exp, f_sz, i_sz), sizes), "moe_up")
         hidden = act(args(nt(gate, [Dim("_pairs", t_sz * top_k),
                                     Dim("_width", i_sz)]))).data * up
-        out = grouped_dot(hidden, w_down.data.reshape(n_exp, i_sz, f_sz),
-                          sizes)
+        out = checkpoint_name(grouped_dot(
+            hidden, w_down.data.reshape(n_exp, i_sz, f_sz), sizes), "moe_down")
     with jax.named_scope("combine"):
         out = _combine(out, weights, order, inverse, top_k)
     out = out.reshape([d.size for d in token_dims + feats])

@@ -1,4 +1,4 @@
-"""Measured remat policy for the revnet/momentum backward (PR 11).
+"""Measured remat policy for the memory strategies' backward (PR 11).
 
 Replaces the boolean/``auto`` ``stash_attention_outputs`` tri-state with a
 POLICY layer: what the memory-strategy backward does about
@@ -20,7 +20,13 @@ policy          behavior
                 ``bottleneck_group_linear``'s in-projection output
                 ``[b, s, intermediate]``, so the replay runs neither that
                 matmul nor, where it contracts a mesh-sharded axis, its
-                tensor-parallel all-reduce (PR 27)
+                tensor-parallel all-reduce (PR 27).  Under the
+                ``checkpoint`` strategy there is no channel: the third
+                kind, ``experts`` — layer ``moe``'s gate, up and down
+                outputs and its routing triple — is SAVED by each block's
+                ``jax.checkpoint`` through a policy over named values
+                (``_checkpoint_policy``), so the replay runs none of the
+                three forward grouped matmuls, 3 of a layer's 12 (PR 29)
 ``save``        NO ``custom_vjp``: the identical primal recurrence under
                 native scan AD; every linearization residual is saved —
                 zero recompute, O(depth) residual memory
@@ -51,9 +57,18 @@ classification this resolver keys on.  ``auto`` therefore picks:
    mesh axis > 1 (each chip then holds a partial sum and the replay would
    all-reduce it a second time — one of three exposed collectives a layer
    on the {data: 2, model: 2} flagship, PERF.md PR 27) and its per-device
-   bytes fit what the attention stash leaves of the same 15%.  The legacy
-   boolean ``true`` forces the attention kind only (its name; the
-   bottleneck kind still resolves by its rule), ``false`` is "recompute";
+   bytes fit what the attention stash leaves of the same 15%;
+   ``experts``, decided LAST, when the strategy is ``checkpoint``, the
+   model has a ``moe`` layer and the saved outputs of the WHOLE depth —
+   ``pairs x (2 x intermediate + features) x itemsize`` a layer, ``pairs
+   = tokens x top-k``, plus the routing triple — fit the same 15% (the
+   other two kinds ride the revnet / momentum residuals and take nothing
+   of it under ``checkpoint``).  All layers or none: OLMoE-1B-7B at depth
+   2 on 8,192 tokens is 1.07 GB of ~2.5 and rides, at its published depth
+   16 it is 8.6 GB and the rule declines; saving some layers only is a
+   later issue.  The legacy boolean ``true`` forces the attention kind
+   only (its name; the other kinds still resolve by their rules),
+   ``false`` is "recompute";
 4. else ``recompute``.  The save modes stay measured OPT-INS: the A/B
    lost on the rig, the committed ledger classifies every body scope
    hbm-bound (residual round-trips are the expensive direction there),
@@ -84,9 +99,11 @@ SAVE_HBM_FRACTION = 0.35
 SAVE_RESIDUALS_PER_BLOCK = 16
 
 POLICIES = ("recompute", "stash", "save", "save_dots")
-#: what can ride the strategy residuals under "stash" (the channel's kinds,
-#: model/blocks.py ``stash_channel``)
-STASH_KINDS = ("attention", "bottleneck")
+#: what a memory strategy can keep for its backward under "stash":
+#: ``attention`` and ``bottleneck`` ride the revnet / momentum residuals (the
+#: channel's kinds, model/blocks.py ``stash_channel``), ``experts`` the
+#: ``checkpoint`` strategy's ``jax.checkpoint`` (``_checkpoint_policy``)
+STASH_KINDS = ("attention", "bottleneck", "experts")
 
 
 def _mesh_geometry(params: ModelParameter, mesh):
@@ -156,6 +173,25 @@ def _bottleneck_stash(params: ModelParameter, mesh) -> typing.Tuple[int, int, bo
     return layers, nbytes, crosses
 
 
+def _experts_stash(params: ModelParameter, shards: int
+                   ) -> typing.Tuple[int, int]:
+    """``(layers, per-device bytes)`` of the experts kind over the whole
+    depth: per ``moe`` layer the three grouped matmuls' outputs — gate and
+    up ``[pairs, intermediate]``, down ``[pairs, features]``, in the
+    calculation dtype — and the routing triple (``order`` and ``inverse``
+    ``[pairs]``, ``sizes`` ``[experts]``, int32), ``pairs = tokens x
+    min(moe_top_k, experts)``: model/moe.py ``SAVED_NAMES``."""
+    layers = sum(name == "moe" for name, _ in _layers(params)) * params.depth
+    pairs = params.batch_dim.size * params.sequence_dim.size \
+        * min(params.moe_top_k, params.expert_dim.size)
+    width = 2 * int(np.prod([d.size for d in params.intermediate])) \
+        + int(np.prod([d.size for d in params.feature_dims]))
+    per_layer = pairs * width * np.dtype(params.calculation_dtype).itemsize \
+        + (2 * pairs + params.expert_dim.size) * 4
+    return layers, -(-per_layer * layers * max(1, params.macro_batching)
+                     // shards)
+
+
 def _save_residual_bytes(params: ModelParameter) -> int:
     """Global estimate of the native-AD linearization residuals the save
     policy keeps: f32 activation-sized intermediates per block part,
@@ -188,11 +224,14 @@ def remat_report(params: ModelParameter, mesh=None) -> typing.Dict[str, typing.A
     resid_block = tokens * d_model * 4 * SAVE_RESIDUALS_PER_BLOCK
     peak, bw = peak_flops(device), peak_hbm_bandwidth(device)
     layers, bottleneck_bytes, crosses = _bottleneck_stash(params, mesh)
+    experts_layers, experts_bytes = _experts_stash(params, shards)
     return {
         "stash_bytes_per_device": -(-_stash_bytes(params) // shards),
         "bottleneck_stash_layers": layers,
         "bottleneck_stash_bytes_per_device": bottleneck_bytes,
         "bottleneck_crosses_model_axis": crosses,
+        "experts_stash_layers": experts_layers,
+        "experts_stash_bytes_per_device": experts_bytes,
         "save_residual_bytes_per_device":
             -(-_save_residual_bytes(params) // shards),
         "hbm_bytes": hbm,
@@ -216,13 +255,13 @@ def _explicit_policy(params: ModelParameter) -> typing.Optional[str]:
 
 
 def stash_kinds(params: ModelParameter, mesh=None) -> typing.FrozenSet[str]:
-    """Which :data:`STASH_KINDS` ride the strategy residuals for this
-    (config, mesh): both under an explicit ``"stash"``, none under any
+    """Which :data:`STASH_KINDS` the strategy keeps for its backward for
+    this (config, mesh): all under an explicit ``"stash"``, none under any
     other explicit policy, else each kind by its own rule (the module
     docstring's item 3).  The attention rule is the historical one and is
     decided FIRST: the bottleneck kind only gets what it leaves of the
     budget, so adding that kind moved no configuration's attention
-    decision."""
+    decision; the experts kind is decided LAST and moves neither."""
     explicit = _explicit_policy(params)
     if explicit is not None:
         return frozenset(STASH_KINDS if explicit == "stash" else ())
@@ -237,6 +276,12 @@ def stash_kinds(params: ModelParameter, mesh=None) -> typing.FrozenSet[str]:
     if rep["bottleneck_crosses_model_axis"] \
             and 0 < rep["bottleneck_stash_bytes_per_device"] <= budget:
         kinds.add("bottleneck")
+    # the whole budget: what the two kinds above name rides the revnet /
+    # momentum residuals, so under "checkpoint" they hold no byte of it
+    if params.memory_reduction_strategy == "checkpoint" \
+            and 0 < rep["experts_stash_bytes_per_device"] \
+            <= rep["stash_budget_bytes"]:
+        kinds.add("experts")
     return frozenset(kinds)
 
 
@@ -260,19 +305,27 @@ def _attention_sites(params: ModelParameter, mesh) -> int:
 
 def stash_plan(params: ModelParameter, mesh=None
                ) -> typing.Dict[str, typing.Tuple[int, int]]:
-    """``{kind: (layers, per-device bytes)}`` of what rides the strategy
-    residuals of the step this (config, mesh) builds, from its shapes;
-    ``(0, 0)`` for a kind that is not engaged (no reversible strategy, a
-    pipeline mesh, an explicit policy, a rule that declined, no such
-    layer).  ``Trainer`` publishes it as ``hbnlp_remat_stash_bytes{kind}``
-    / ``hbnlp_remat_stash_layers{kind}`` (docs/OBSERVABILITY.md)."""
+    """``{kind: (layers, per-device bytes)}`` of what the memory strategy
+    of the step this (config, mesh) builds keeps for its backward, from its
+    shapes; ``(0, 0)`` for a kind that is not engaged (a strategy that has
+    no way to keep it, a pipeline mesh, an explicit policy, a rule that
+    declined, no such layer).  ``Trainer`` publishes it as
+    ``hbnlp_remat_stash_bytes{kind}`` / ``hbnlp_remat_stash_layers{kind}``
+    (docs/OBSERVABILITY.md); ``_checkpoint_policy`` saves the experts
+    kind's names exactly where this says it rides."""
     from ..core.sharding import PIPE_AXIS
     plan = {kind: (0, 0) for kind in STASH_KINDS}
+    strategy = params.memory_reduction_strategy
     piped = mesh is not None and mesh.shape.get(PIPE_AXIS, 1) > 1
-    if params.memory_reduction_strategy not in ("revnet", "momentum") or piped:
+    if strategy not in ("revnet", "momentum", "checkpoint") or piped:
         return plan
     kinds = stash_kinds(params, mesh)
     rep = remat_report(params, mesh)
+    if strategy == "checkpoint":
+        if "experts" in kinds and rep["experts_stash_layers"]:
+            plan["experts"] = (rep["experts_stash_layers"],
+                               rep["experts_stash_bytes_per_device"])
+        return plan
     if "attention" in kinds:
         layers = _attention_sites(params, mesh) * params.depth
         # remat_report sizes one pair a depth-unit

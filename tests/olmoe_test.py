@@ -195,6 +195,65 @@ def the_step_reports_the_routed_layers_load_test():
     assert all(np.all(np.isfinite(np.asarray(g))) for g in grads.values())
 
 
+def _count_primitives(jaxpr, prefixes):
+    """Equations whose primitive's name starts with each prefix, through
+    every nested jaxpr (a scan's body counts once)."""
+    counts = dict.fromkeys(prefixes, 0)
+    for eqn in jaxpr.eqns:
+        for prefix in prefixes:
+            counts[prefix] += eqn.primitive.name.startswith(prefix)
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    for k, n in _count_primitives(sub, prefixes).items():
+                        counts[k] += n
+    return counts
+
+
+@pytest.mark.parametrize("scan", [False, True])
+def saved_expert_outputs_change_no_bit_test(scan):
+    """The experts kind (model/remat.py; PR 29) against ``remat_policy:
+    "recompute"``: the same loss and the same gradients, bit for bit — the
+    backward reads the forward's own gate / up / down outputs and routing
+    triple instead of equal recomputed ones — with three grouped matmuls
+    and one sort a layer fewer in the gradient's program."""
+    from homebrewnlp_tpu import telemetry
+    from homebrewnlp_tpu.train import Trainer
+    results, counts = {}, {}
+    for policy in ("auto", "recompute"):
+        _, params, model, batch, variables = _build(
+            8, 2, "float32", remat_policy=policy, scan_layers=scan)
+        variables = {k: jnp.asarray(v) for k, v in variables.items()}
+        step = jax.value_and_grad(
+            lambda v: model.apply(v, batch).total_loss.data)
+        counts[policy] = _count_primitives(
+            jax.make_jaxpr(step)(variables).jaxpr, ("ragged_dot", "sort"))
+        results[policy] = jax.jit(step)(variables)
+        # 256 pairs x (2 x 32 + 64) x float32 + (2 x 256 + 8) x int32, twice
+        engaged = (2, 266304) if policy == "auto" else (0, 0)
+        line = Trainer(params, model).publish_stash_plan()
+        assert line.endswith(f"experts {engaged[0]} layers, {engaged[1]} "
+                             "bytes a device")
+        snap = telemetry.registry().snapshot()
+        assert snap["hbnlp_remat_stash_layers"]["series"][("experts",)] \
+            == engaged[0]
+        assert snap["hbnlp_remat_stash_bytes"]["series"][("experts",)] \
+            == engaged[1]
+    (loss, grads), (want_loss, want) = results["auto"], results["recompute"]
+    assert float(loss) == float(want_loss)
+    assert set(grads) == set(want)
+    for name in sorted(want):
+        assert np.array_equal(np.asarray(grads[name]),
+                              np.asarray(want[name])), name
+    # a scanned body is one jaxpr whatever the depth
+    layers = 1 if scan else params.depth
+    # forward 3, replay 3, input and weight gradients 6 a layer: 12 -> 9
+    assert counts["recompute"]["ragged_dot"] == 12 * layers
+    assert counts["auto"]["ragged_dot"] == 9 * layers
+    assert counts["recompute"]["sort"] - counts["auto"]["sort"] == layers
+
+
 def _old_cross_entropy(logits, targets, z_loss):
     """model/__init__.py's form before ISSUE 26: max-subtracted log-softmax
     against a one-hot of the targets."""

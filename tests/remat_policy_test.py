@@ -208,7 +208,8 @@ def bottleneck_stash_resolver_test(case):
         assert resolve_remat(p, mesh) == "stash"
         # 128 sequences a chip x 512 x 512 x bfloat16 x 32 layers
         assert stash_plan(p, mesh) == {
-            "attention": (0, 0), "bottleneck": (32, 128 * 512 * 512 * 2 * 32)}
+            "attention": (0, 0), "bottleneck": (32, 128 * 512 * 512 * 2 * 32),
+            "experts": (0, 0)}
     elif case == "over_budget":
         # the published deployment's share, 256 sequences a chip: 4.3 GB
         # against 15% of the planning figure
@@ -225,7 +226,8 @@ def bottleneck_stash_resolver_test(case):
     elif case == "checkpoint_strategy":
         p = _flagship(memory_reduction_strategy="checkpoint")
         plan = stash_plan(p, shardlib.build_mesh(p, devices))
-        assert plan == {"attention": (0, 0), "bottleneck": (0, 0)}
+        assert plan == {"attention": (0, 0), "bottleneck": (0, 0),
+                        "experts": (0, 0)}
     else:
         # a long-context configuration's attention decision is the same
         # with and without a bottleneck in the block, on one device and on
@@ -270,9 +272,11 @@ def remat_stash_gauges_test(engaged):
         * params.intermediate[-1].size * 4
     assert got["hbnlp_remat_stash_bytes"] == {
         ("attention",): 0,
-        ("bottleneck",): item * params.depth if engaged else 0}
+        ("bottleneck",): item * params.depth if engaged else 0,
+        ("experts",): 0}
     assert got["hbnlp_remat_stash_layers"] == {
-        ("attention",): 0, ("bottleneck",): params.depth if engaged else 0}
+        ("attention",): 0, ("bottleneck",): params.depth if engaged else 0,
+        ("experts",): 0}
     assert trainer.publish_stash_plan().startswith("remat stash: attention 0")
 
 
@@ -291,3 +295,127 @@ def one_device_step_is_untouched_test(strategy):
         texts.append(trainer.lowered(trainer.init_state(batch),
                                      batch).as_text())
     assert texts[0] == texts[1]
+
+
+# ---- the experts kind (PR 29): layer moe's three grouped-matmul outputs
+# and its routing triple are saved by the checkpoint strategy's
+# jax.checkpoint where their bytes fit ---------------------------------------
+
+def _cell_params(cell: str, **kw):
+    """A benchmark cell's configuration as the cell runs it, for the
+    resolver alone: nothing is built."""
+    from benchmark.lib.cell import load_cell
+    from homebrewnlp_tpu.config import ModelParameter
+    return ModelParameter({**load_cell(cell).model_config(),
+                           "model_path": "/tmp/remat_policy_test", **kw})
+
+
+#: 65,536 pairs x (2 x 1,024 + 2,048) x bfloat16, and order + inverse + the
+#: 64 sizes in int32, a layer
+_OLMOE_LAYER = 65536 * (2 * 1024 + 2048) * 2 + (2 * 65536 + 64) * 4
+
+
+@pytest.mark.parametrize("case", [
+    "engaged", "depth_16", "recompute", "stash", "legacy_false",
+    "no_moe_layer", "revnet", "none", "macro_batching"])
+def experts_stash_resolver_test(case):
+    from homebrewnlp_tpu.model.remat import stash_kinds, stash_plan
+    idle = {"attention": (0, 0), "bottleneck": (0, 0), "experts": (0, 0)}
+    if case == "engaged":
+        p = _cell_params("train_olmoe_1b_7b_s4k")
+        rep = remat_report(p)
+        assert rep["experts_stash_bytes_per_device"] == 2 * _OLMOE_LAYER \
+            == 1073741824 + 1049088 <= rep["stash_budget_bytes"]
+        assert "experts" in stash_kinds(p)
+        # the attention kind is named by its sequence rule and rides
+        # nothing under "checkpoint": it took nothing from the budget
+        assert stash_plan(p) == {**idle, "experts": (2, 2 * _OLMOE_LAYER)}
+    elif case == "depth_16":
+        # the published depth: 8.6 GB, all layers or none
+        p = _cell_params("train_olmoe_1b_7b_s4k", depth=16)
+        rep = remat_report(p)
+        assert rep["experts_stash_bytes_per_device"] == 16 * _OLMOE_LAYER \
+            > rep["stash_budget_bytes"]
+        assert "experts" not in stash_kinds(p)
+        assert stash_plan(p) == idle
+    elif case == "recompute":
+        p = _cell_params("train_olmoe_1b_7b_s4k", remat_policy="recompute")
+        assert stash_kinds(p) == frozenset() and stash_plan(p) == idle
+    elif case == "stash":
+        # explicit: on whatever the bytes
+        p = _cell_params("train_olmoe_1b_7b_s4k", remat_policy="stash",
+                         depth=16)
+        assert stash_plan(p) == {**idle, "experts": (16, 16 * _OLMOE_LAYER)}
+    elif case == "legacy_false":
+        p = _cell_params("train_olmoe_1b_7b_s4k",
+                         stash_attention_outputs=False)
+        assert stash_plan(p) == idle
+    elif case == "no_moe_layer":
+        p = _cell_params("train_32big_mixer_b32",
+                         memory_reduction_strategy="checkpoint")
+        assert remat_report(p)["experts_stash_layers"] == 0
+        assert "experts" not in stash_kinds(p) and stash_plan(p) == idle
+        p = _cell_params("train_32big_mixer_b32", remat_policy="stash",
+                         memory_reduction_strategy="checkpoint")
+        assert stash_plan(p) == idle
+    elif case in ("revnet", "none"):
+        p = _cell_params("train_olmoe_1b_7b_s4k",
+                         memory_reduction_strategy=case)
+        assert "experts" not in stash_kinds(p)
+        assert stash_plan(p)["experts"] == (0, 0)
+        p = _cell_params("train_olmoe_1b_7b_s4k", remat_policy="stash",
+                         memory_reduction_strategy=case)
+        assert stash_plan(p)["experts"] == (0, 0)
+    else:
+        # three micro-batches hold three sets of outputs: over the budget
+        p = _cell_params("train_olmoe_1b_7b_s4k", macro_batching=3)
+        assert remat_report(p)["experts_stash_bytes_per_device"] \
+            == 6 * _OLMOE_LAYER
+        assert stash_plan(p) == idle
+
+
+@pytest.mark.parametrize("cell,kinds,policy,plan", [
+    ("train_32big_mixer_b32", set(), "recompute", {}),
+    ("train_32big_mixer_dp2tp2", {"bottleneck"}, "stash",
+     {"bottleneck": (32, 2147483648)}),
+    ("train_1b_long_context_s16k", {"attention"}, "stash",
+     {"attention": (8, 2155872256)})])
+def experts_kind_moves_no_other_cell_test(cell, kinds, policy, plan):
+    """What the three cells without a ``moe`` layer resolved to before the
+    experts kind existed (read off the parent commit), kind for kind and
+    byte for byte."""
+    from benchmark.lib.cell import load_cell
+    from homebrewnlp_tpu.core import sharding as shardlib
+    from homebrewnlp_tpu.model.remat import stash_kinds, stash_plan
+    p = _cell_params(cell)
+    mesh = None
+    if load_cell(cell).chips > 1:
+        if len(jax.devices()) < 4:
+            pytest.skip("needs 4 virtual devices")
+        mesh = shardlib.build_mesh(p, jax.devices()[:4])
+    assert stash_kinds(p, mesh) == kinds
+    assert resolve_remat(p, mesh) == policy
+    assert stash_plan(p, mesh) == {
+        "attention": (0, 0), "bottleneck": (0, 0), "experts": (0, 0), **plan}
+
+
+def experts_stash_line_and_policy_test():
+    """The start-up line names the kind, and ``_checkpoint_policy`` saves
+    layer moe's names exactly where the plan says the kind rides: today's
+    object (the named policy itself) everywhere else."""
+    from homebrewnlp_tpu.model.blocks import _checkpoint_policy
+    from homebrewnlp_tpu.model.remat import stash_line, stash_plan
+    p = _cell_params("train_olmoe_1b_7b_s4k")
+    assert stash_line(stash_plan(p)) == (
+        "remat stash: attention 0 layers, 0 bytes a device; bottleneck 0 "
+        f"layers, 0 bytes a device; experts 2 layers, {2 * _OLMOE_LAYER} "
+        "bytes a device")
+    nothing = jax.checkpoint_policies.nothing_saveable
+    assert _checkpoint_policy(p) is not nothing
+    for kw in ({"remat_policy": "recompute"}, {"depth": 16}):
+        assert _checkpoint_policy(
+            _cell_params("train_olmoe_1b_7b_s4k", **kw)) is nothing
+    assert _checkpoint_policy(_cell_params(
+        "train_olmoe_1b_7b_s4k", depth=16,
+        gradient_checkpointing_policy="dots_saveable")) \
+        is jax.checkpoint_policies.dots_saveable
