@@ -1,0 +1,29 @@
+"""Device self time on instructions of scope ``body/mamba/ssd`` — the
+chunked state-space-duality scan: cumulative log-decays, the masked product
+inside a chunk, the chunks' states, the serial recurrence over the chunks
+and the entering states' part, forward, recomputed and backward — over busy
+time, percent.  The notes split it by the scan's own steps."""
+from ..lib import program_readers, readers
+
+LAYER = "L4_kernels"
+MOVES = "train_tokens_per_sec_chip"
+STEPS = ("intra_chunk", "chunk_states", "inter_chunk", "state_out")
+
+
+def read(run):
+    share = program_readers.scope_share(run, "body/mamba/ssd")
+    if share is None:
+        return None
+    tf_op = program_readers._tf_ops(run.result.trace_path) or {}
+    scopes = program_readers._op_scopes(run.result.trace_path) or {}
+    by_step = {}
+    for name, seconds in run.trace["ops"].items():
+        if scopes.get(name) != "body/mamba/ssd":
+            continue
+        step = next((s for s in STEPS if f"/{s}/" in f"/{tf_op[name]}/"),
+                    "other")
+        by_step[step] = by_step.get(step, 0.0) + seconds
+    run.notes.append("body/mamba/ssd by step: " + ", ".join(
+        f"{k} {readers.share(v, run.trace['busy_s']):.2f}%"
+        for k, v in sorted(by_step.items(), key=lambda kv: -kv[1])))
+    return share

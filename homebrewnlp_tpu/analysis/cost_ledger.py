@@ -65,10 +65,14 @@ _LAYER_NAMES = frozenset((
     "activation", "convolution", "dropout", "group_linear", "split_path",
     "feed_forward_product_key_memory", "product_key_memory",
     "reduced_half_linear", "transpose_sequence_features",
-    "bottleneck_group_linear", "sum_heads", "moe"))
+    "bottleneck_group_linear", "sum_heads", "moe", "mamba", "mlp"))
 #: the parts of layer ``moe`` (model/moe.py), each a scope of its own below
 #: ``body/moe``
 _MOE_PARTS = frozenset(("router", "dispatch", "experts", "combine"))
+#: the parts of layer ``mamba`` (model/mamba.py) below ``body/mamba``; the
+#: scan's own steps (``intra_chunk``, ``chunk_states``, ``inter_chunk``,
+#: ``state_out``) stay inside ``body/mamba/ssd``
+_MAMBA_PARTS = frozenset(("in_proj", "conv", "ssd", "gate_norm", "out_proj"))
 
 
 def _unwrap(comp: str) -> str:
@@ -91,7 +95,8 @@ def scope_key(path: str) -> str:
 
     Keys: ``decode/cache_read|cache_write|sampling``, ``optimizer``,
     ``head_loss``, ``input/embed``, ``input``, ``body/<layer>``,
-    ``body/moe/router|dispatch|experts|combine``, ``output/unembed``,
+    ``body/moe/router|dispatch|experts|combine``,
+    ``body/mamba/in_proj|conv|ssd|gate_norm|out_proj``, ``output/unembed``,
     ``output``, ``loss``, ``unscoped``.  Transform decorations
     (``jvp``/``transpose``/``jit`` wrappers) are unwrapped, so forward and
     backward ops of one block fold into the same scope — per-block
@@ -110,6 +115,8 @@ def scope_key(path: str) -> str:
             layer = base
         elif layer == "moe" and base in _MOE_PARTS:
             return f"body/moe/{base}"
+        elif layer == "mamba" and base in _MAMBA_PARTS:
+            return f"body/mamba/{base}"
     if phase == "body" and layer is not None:
         return f"body/{layer}"
     if phase == "input":

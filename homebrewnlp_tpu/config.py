@@ -193,6 +193,28 @@ class ModelParameter:
         # base of the rotary position embedding's frequencies (attention
         # flag "rope"): feature pair i turns by pos * rope_theta^(-2i/width)
         self.rope_theta = 10000.0
+        # the standard attention (flags "rope" / "nope", model/spatial.py):
+        # query heads per key / value head (1 = multi-head attention; more =
+        # grouped queries, heads // query_group K/V heads) and the softmax
+        # scale (0 = features_per_head ** -0.5)
+        self.query_group = 1
+        self.attention_scale = 0.0
+        # layer "mamba" (Mamba-2, model/mamba.py): heads x width of the inner
+        # stream, the state's size, the causal depthwise conv's width, and
+        # the chunk of the state-space-duality scan
+        self.mamba_heads = 64
+        self.mamba_head_features = 64
+        self.mamba_state = 128
+        self.mamba_conv_size = 4
+        self.mamba_chunk = 256
+        # Granite's three multipliers: on the token embedding, on every
+        # block's output before it joins the residual stream, and the
+        # divisor of the logits; 1 = off
+        self.embedding_multiplier = 1.0
+        self.residual_multiplier = 1.0
+        self.logits_scaling = 1.0
+        # the head is the (direct) token embedding itself: one parameter
+        self.tie_word_embeddings = False
         self.pkm_axes = 2
         self.use_bit_fold_input_pipeline = False
         self.bit_fold_value = 4
@@ -858,6 +880,21 @@ class ModelParameter:
             raise ValueError("use_video + use_language requires "
                              "three_axes=false (height and width fold into "
                              "one spatial axis that text tokens join on)")
+        if self.query_group < 1 or self.heads % self.query_group:
+            raise ValueError(f"query_group {self.query_group} must divide "
+                             f"heads {self.heads}")
+        if self.tie_word_embeddings and (self.vocab_weight_factorization
+                                         or self.token_patch_size != 1
+                                         or self.use_video):
+            raise ValueError("tie_word_embeddings needs a direct token "
+                             "embedding: vocab_weight_factorization 0, "
+                             "token_patch_size 1, text only")
+        if self.residual_multiplier != 1 and \
+                self.memory_reduction_strategy not in ("none", "checkpoint"):
+            raise ValueError("residual_multiplier scales a block's output "
+                             "where it joins the plain residual stream: "
+                             "memory_reduction_strategy \"none\" or "
+                             "\"checkpoint\"")
         if self.intermediate_feed_forward_multiplier_multiplier is not None:
             self.intermediate_feed_forward_multiplier = (
                 self.group_linear_factor

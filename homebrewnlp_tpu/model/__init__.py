@@ -113,6 +113,8 @@ def _input(params: ModelParameter, vid, cat_msk_src, txt_src, vid_msk_src,
         txt = reduce_sum(txt, reduced_dim=params.token_patch_dim) if direct \
             else linear_to_features(base_args(txt),
                                     [params.token_patch_dim, intermediate])
+        if params.embedding_multiplier != 1:
+            txt = txt * params.embedding_multiplier
 
         for config_idx, config in enumerate(params.input_block_config):
             txt = block_part_fn(params, config, txt, f'lang_inp{config_idx}')
@@ -155,7 +157,19 @@ def _output(params: ModelParameter, out: NamedTensor, spatial_ctx: Dim,
                 token_out = block_part_fn(params, config, token_out, f'lang_out{config_idx}')
             new = [params.token_patch_dim, params.vocab_dim]
             old = list(params.feature_dims)
-            emb = embed(base_args(list(params.output_embedding)), old + new)
+            if params.tie_word_embeddings:
+                # the head IS the (direct) token embedding [vocab, features]:
+                # one parameter, and autodiff adds the head's gradient to the
+                # gather's
+                table = storage["text_input_embedding"]
+                emb = nt(table.data[:, None],
+                         [table.dims[0], params.token_patch_dim]
+                         + list(table.dims[1:]))
+            else:
+                emb = embed(base_args(list(params.output_embedding)),
+                            old + new)
+            if params.logits_scaling != 1:
+                token_out = token_out * (1 / params.logits_scaling)
             if storage is not None:
                 storage["head"] = (token_out, emb)
             token_out = einsum([token_out, emb],

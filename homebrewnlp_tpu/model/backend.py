@@ -74,6 +74,17 @@ class ConstantInit:
         return np.full(sizes, self.value, dtype=np.float32)
 
 
+class UniformInit:
+    """``transform(U[low, high))``, elementwise."""
+
+    def __init__(self, low: float, high: float, transform=None):
+        self.low, self.high, self.transform = low, high, transform
+
+    def __call__(self, rng: np.random.Generator, sizes) -> np.ndarray:
+        value = rng.uniform(self.low, self.high, sizes).astype(np.float32)
+        return value if self.transform is None else self.transform(value)
+
+
 def get_var(args: BlockArgs, shape: SHAPE, initializer) -> NamedTensor:
     """Create/fetch a parameter; resolve to the depth-0 name when shared."""
     params = args.params

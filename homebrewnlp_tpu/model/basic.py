@@ -21,8 +21,9 @@ from ..core.tensor import (NamedTensor, cast, dropout as tensor_dropout, nt,
                            einsum, exp, multiply, reduce_max, reduce_sum,
                            reciprocal, rename_dim, reshape, sigmoid,
                            stop_gradient, top_1, transpose_to, unbind)
-from .activation import activate
-from .backend import ConstantInit, get_var, linear, orthogonal_var
+from .activation import ACTIVATIONS, activate
+from .backend import (ConstantInit, get_var, linear, normal_var,
+                      orthogonal_var)
 from .embedding import gather_embed
 from .normalization import norm
 from .utils import anonymize_dim, anonymize_shape, linear_shapes
@@ -309,6 +310,29 @@ def activated_linear_in(args: BlockArgs) -> NamedTensor:
 
 def activated_linear_out(args: BlockArgs) -> NamedTensor:
     return activated_linear(args, "out:")
+
+
+def mlp(args: BlockArgs) -> NamedTensor:
+    """Layer ``mlp``: the dense gated MLP of today's transformers,
+    ``down(act(gate(x)) * up(x))``, all features -> ``intermediate`` -> all
+    features, three bias-free matrices, normal(0.02), created in the order
+    gate, up, down; an activation name as flag (default ``silu``: SwiGLU).
+    (``feed_forward``'s ``glu`` is the reference's sigmoid gate of another
+    form; the routed experts of model/moe.py each are one of these.)"""
+    params = args.params
+    act = next((ACTIVATIONS[a] for a in args.name_extras if a in ACTIVATIONS),
+               ACTIVATIONS["silu"])
+    feats = list(params.feature_dims)
+    anon = [anonymize_dim(d) for d in feats]
+    inter = list(params.intermediate)
+    x = args.tensor
+    for d, a in zip(feats, anon):
+        x = rename_dim(x, d.name, a.name)
+    hidden_dims = shape_sub(x.dims, anon) + inter
+    gate = einsum([x, normal_var(args, anon + inter)], hidden_dims)
+    up = einsum([x, normal_var(args, anon + inter)], hidden_dims)
+    return einsum([act(args(gate)) * up, normal_var(args, inter + feats)],
+                  list(args.tensor.dims))
 
 
 def feed_forward(args: BlockArgs) -> NamedTensor:

@@ -12,9 +12,10 @@ from ..core import scope
 from ..core.tensor import NamedTensor, add, multiply
 from .activation import activate
 from .basic import (bottleneck_group_linear, dropout, feed_forward,
-                    feed_forward_product_key_memory, group_linear,
+                    feed_forward_product_key_memory, group_linear, mlp,
                     product_key_memory, reduced_half_linear, rezero, sum_heads,
                     transpose_sequence_features)
+from .mamba import mamba
 from .moe import moe
 from .normalization import norm
 from .spatial import attention, cummean, cumsum
@@ -91,6 +92,8 @@ def _get_block_part(block_part_config: BlockConfig, params: ModelParameter,
         args = BlockArgs(params, out, extras, idx == len(block_part_config.layer))
         out = scope.scoped(name + '_', LAYER_FUNCTIONS[name], args)
     if block_part_config.skip and block_part_config.memory_reduction_strategy in ("none", "checkpoint"):
+        if params.residual_multiplier != 1:
+            out = out * params.residual_multiplier
         out = out + block_input
     return out
 
@@ -135,4 +138,6 @@ LAYER_FUNCTIONS = {'feed_forward': feed_forward,
                    'bottleneck_group_linear': bottleneck_group_linear,
                    'sum_heads': sum_heads,
                    'moe': moe,
+                   'mamba': mamba,
+                   'mlp': mlp,
                    }

@@ -1,0 +1,208 @@
+"""Mamba-2 mixer (layer ``mamba``): a selective state space recurrence in its
+chunked state-space-duality form (Dao & Gu, arXiv:2405.21060, section 6 and
+listing 1).
+
+On the block's (already normalised) input ``u [b, s, features]``, with
+``d_inner = mamba_heads x mamba_head_features``, one group of ``B`` / ``C``
+shared by all heads, state size ``n = mamba_state``:
+
+    z, xBC, dt = split(u W_in)            W_in: features x (2 d_inner + 2 n
+                                          + heads), no bias
+    xBC = silu(conv(xBC))                 causal depthwise conv over the
+                                          sequence, width mamba_conv_size,
+                                          with bias
+    x, B, C = split(xBC)                  d_inner, n, n
+    dt = softplus(dt + dt_bias);  A = -exp(A_log)          per head, float32
+    S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T             S: [width, n]
+    y_t = S_t C_t + D x_t
+    y = rms(y * silu(z)) * w_norm         gate FIRST, then RMSNorm over all
+                                          d_inner columns, eps 1e-5
+    out = y W_out                         d_inner x features, no bias
+
+The recurrence is never run position by position: inside a chunk of
+``mamba_chunk`` positions it is the masked ``C B^T`` product against ``x``
+(``intra_chunk``), every chunk leaves one state (``chunk_states``), a serial
+``lax.scan`` over the chunks carries the states across (``inter_chunk``), and
+the state entering a chunk adds its part to that chunk's outputs
+(``state_out``).  Decays are ``exp`` of DIFFERENCES of a float32 cumulative
+sum of ``dt A`` within the chunk — masked before the ``exp``, never a product
+or quotient of exponentials, so nothing under- or overflows on the way —
+and matmul operands are the calculation dtype with float32 accumulation.
+Autodiff gives the backward.
+
+Training and full-sequence forward on one device; a decode / prefill form
+(a state and a conv window per sequence) is ROADMAP R3's serving half.
+"""
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..config import BlockArgs
+from ..core import scope
+from ..core.dims import Dim
+from ..core.tensor import NamedTensor, nt, transpose_to
+from .backend import ConstantInit, UniformInit, normal_var
+from .loss import _matmul
+from .normalization import _norm_core
+from .utils import anonymize_dim
+
+
+def _inverse_softplus_of_exp(log_dt: np.ndarray) -> np.ndarray:
+    """``dt_bias`` with ``softplus(dt_bias) = exp(log_dt)``: the Mamba-2
+    code's ``dt + log(-expm1(-dt))``."""
+    dt = np.exp(log_dt)
+    return (dt + np.log(-np.expm1(-dt))).astype(np.float32)
+
+
+def _small_var(args: BlockArgs, name: str, shape, initializer) -> jax.Array:
+    """A per-channel vector the recurrence reads in float32 (``A_log``,
+    ``dt_bias``, ``D``, the conv and norm weights): stored in the slice dtype
+    like every parameter, never rounded to the calculation dtype."""
+    params = args.params
+    return scope.scoped(name, scope.get_param, "var", shape, initializer,
+                        params.slice_dtype, jnp.float32).data
+
+
+def causal_depthwise_conv(x, weight, bias):
+    """``y[t] = bias + sum_k weight[k] x[t - (K - 1) + k]`` on ``x [b, s,
+    channels]``, zeros before the sequence: K shifted multiplies."""
+    k = weight.shape[0]
+    s = x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    out = bias
+    for i in range(k):
+        out = out + padded[:, i:i + s] * weight[i]
+    return out
+
+
+def ssd(x, dt, a, b_mat, c_mat, chunk: int):
+    """The chunked scan.  ``x [b, s, h, p]`` (calculation dtype), ``dt [b,
+    s, h]`` and ``a [h]`` float32 (``a`` negative), ``b_mat`` / ``c_mat``
+    ``[b, s, n]``; ``s`` a multiple of ``chunk``.  Returns ``(y [b, s, h,
+    p]`` in float32 WITHOUT the ``D x`` skip, the most negative within-chunk
+    cumulative ``dt a``)``."""
+    bsz, s, h, p = x.shape
+    n = b_mat.shape[-1]
+    c, l = s // chunk, chunk
+    dtype = x.dtype
+    xc = x.reshape(bsz, c, l, h, p)
+    bc = b_mat.reshape(bsz, c, l, n)
+    cc = c_mat.reshape(bsz, c, l, n)
+    dtc = dt.reshape(bsz, c, l, h)
+    # log-decay from the chunk's start to each position, [b, c, h, l]
+    a_cum = jnp.cumsum(jnp.moveaxis(dtc * a, 3, 2), axis=-1)
+    x_dt = (xc.astype(jnp.float32) * dtc[..., None])
+    with jax.named_scope("intra_chunk"):
+        scores = _matmul("bcin,bcjn->bcij", cc, bc).astype(jnp.float32)
+        diff = a_cum[..., :, None] - a_cum[..., None, :]
+        causal = jnp.arange(l)[:, None] >= jnp.arange(l)[None, :]
+        decay = jnp.exp(jnp.where(causal, diff, -jnp.inf))
+        mixed = (scores[:, :, None] * decay).astype(dtype)
+        y = _matmul("bchij,bcjhp->bcihp", mixed, x_dt.astype(dtype)
+                    ).astype(jnp.float32)
+    with jax.named_scope("chunk_states"):
+        # what each position still contributes at its chunk's end
+        to_end = jnp.exp(a_cum[..., -1:] - a_cum)
+        weighted = (x_dt * jnp.moveaxis(to_end, 2, 3)[..., None]).astype(dtype)
+        states = _matmul("bclhp,bcln->bchpn", weighted, bc
+                         ).astype(jnp.float32)
+    with jax.named_scope("inter_chunk"):
+        chunk_decay = jnp.exp(a_cum[..., -1])                  # [b, c, h]
+
+        def step(carry, inp):
+            decay_c, states_c = inp
+            return carry * decay_c[..., None, None] + states_c, carry
+
+        _, entering = jax.lax.scan(
+            step, jnp.zeros((bsz, h, p, n), jnp.float32),
+            (jnp.moveaxis(chunk_decay, 1, 0), jnp.moveaxis(states, 1, 0)))
+        entering = jnp.moveaxis(entering, 0, 1)                # [b, c, h, p, n]
+    with jax.named_scope("state_out"):
+        from_start = jnp.moveaxis(jnp.exp(a_cum), 2, 3)        # [b, c, l, h]
+        y = y + _matmul("bcln,bchpn->bclhp", cc, entering.astype(dtype)
+                        ).astype(jnp.float32) * from_start[..., None]
+    return y.reshape(bsz, s, h, p), jnp.min(a_cum)
+
+
+def mamba(args: BlockArgs) -> NamedTensor:
+    """Layer ``mamba`` (module docstring).  Parameters in creation order:
+    ``W_in`` normal(0.02); the conv's weight ``[K, channels]`` and bias,
+    U(-1/sqrt(K), 1/sqrt(K)) (torch's Conv1d default, as the Mamba-2 code
+    leaves it); ``dt_bias`` with ``softplus`` log-uniform in [1e-3, 1e-1],
+    ``A_log = log U[1, 16]``, ``D = 1``, the norm's scale 1; ``W_out``
+    normal(0.02)."""
+    params = args.params
+    ctx = scope.current()
+    if ctx.decode is not None or getattr(ctx, "prefill", None) is not None:
+        raise NotImplementedError(
+            "layer mamba has no incremental decode / prefill form yet")
+    if ctx.mesh is not None and ctx.mesh.size > 1:
+        raise NotImplementedError("layer mamba on a mesh is a later issue")
+    h, p, n = params.mamba_heads, params.mamba_head_features, params.mamba_state
+    k = params.mamba_conv_size
+    d_inner, conv_dim = h * p, h * p + 2 * n
+    feats = list(params.feature_dims)
+    anon = [anonymize_dim(d) for d in feats]
+    x = args.tensor
+    token_dims = [d for d in x.dims if d not in feats]
+    if len(token_dims) != 2 or token_dims[1] != params.sequence_dim:
+        raise ValueError("layer mamba mixes [batch, sequence, features]; got "
+                         f"{x.dims}")
+    bsz, s = (d.size for d in token_dims)
+    chunk = min(params.mamba_chunk, s)        # a short sequence: one chunk
+    if s % chunk:
+        raise ValueError(f"sequence {s} is not a multiple of mamba_chunk "
+                         f"{chunk}")
+    f_sz = math.prod(d.size for d in feats)
+    inner, channels = Dim("mamba_inner", d_inner), Dim("mamba_conv", conv_dim)
+    head_dim = Dim("mamba_heads", h)
+
+    w_in = normal_var(args, anon + [Dim("mamba_in", d_inner + conv_dim + h)])
+    bound = k ** -0.5
+    conv_w = _small_var(args, "uniform_var", [Dim("mamba_conv_k", k), channels],
+                        UniformInit(-bound, bound))
+    conv_b = _small_var(args, "uniform_var", [channels],
+                        UniformInit(-bound, bound))
+    dt_bias = _small_var(args, "uniform_var", [head_dim], UniformInit(
+        math.log(1e-3), math.log(1e-1), _inverse_softplus_of_exp))
+    a_log = _small_var(args, "uniform_var", [head_dim],
+                       UniformInit(1.0, 16.0, np.log))
+    skip = _small_var(args, "constant_var", [head_dim], ConstantInit(1.0))
+    w_norm = _small_var(args, "constant_var", [inner], ConstantInit(1.0))
+
+    dtype = x.dtype
+    u = transpose_to(x, token_dims + feats).data.reshape(bsz, s, f_sz)
+    with jax.named_scope("in_proj"):
+        proj = _matmul("bsf,fo->bso", u, w_in.data.reshape(f_sz, -1)
+                       ).astype(dtype)
+        z = proj[..., :d_inner]
+        xbc = proj[..., d_inner:d_inner + conv_dim]
+        dt = proj[..., d_inner + conv_dim:]
+    with jax.named_scope("conv"):
+        xbc = jax.nn.silu(causal_depthwise_conv(
+            xbc.astype(jnp.float32), conv_w, conv_b)).astype(dtype)
+    with jax.named_scope("ssd"):
+        xs = xbc[..., :d_inner].reshape(bsz, s, h, p)
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
+        y, log_decay_min = ssd(xs, dt, -jnp.exp(a_log),
+                               xbc[..., d_inner:d_inner + n],
+                               xbc[..., d_inner + n:], chunk)
+        y = y + xs.astype(jnp.float32) * skip[:, None]
+    if ctx.layer_stats is not None:
+        ctx.layer_stats.append({"ssd_log_decay_min": log_decay_min})
+    with jax.named_scope("gate_norm"):
+        gated = (y.reshape(bsz, s, d_inner)
+                 * jax.nn.silu(z.astype(jnp.float32))).astype(dtype)
+        gated = _norm_core(gated, w_norm.reshape(1, 1, d_inner),
+                           jnp.ones((1, 1, 1), jnp.float32), (2,), 1e-5,
+                           True, False, False)
+    w_out = normal_var(args, [inner] + feats)
+    with jax.named_scope("out_proj"):
+        out = _matmul("bsi,if->bsf", gated, w_out.data.reshape(d_inner, f_sz)
+                      ).astype(dtype)
+    out = out.reshape([d.size for d in token_dims + feats])
+    return transpose_to(nt(out, token_dims + feats), x.dims)

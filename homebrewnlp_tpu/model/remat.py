@@ -337,6 +337,28 @@ def stash_plan(params: ModelParameter, mesh=None
     return plan
 
 
+def ssd_state_bytes(params: ModelParameter, mesh=None) -> int:
+    """Per-device bytes of layer ``mamba``'s chunk states — ``[batch,
+    sequence / mamba_chunk, mamba_heads, mamba_head_features, mamba_state]``
+    float32 a layer, what the inter-chunk scan's backward reads — that are
+    alive at once for the backward: ONE layer's under ``checkpoint`` /
+    ``revnet`` / ``momentum`` (no policy saves them across the forward; the
+    block's replay makes them again and drops them with the block), every
+    layer's under ``none``.  0 without a ``mamba`` layer.  ``Trainer``
+    publishes it as ``hbnlp_ssd_state_bytes``."""
+    layers = sum(name == "mamba" for name, _ in _layers(params))
+    if not layers:
+        return 0
+    shards, _ = _mesh_geometry(params, mesh)
+    per_layer = params.batch_dim.size \
+        * max(1, params.sequence_dim.size // params.mamba_chunk) \
+        * params.mamba_heads * params.mamba_head_features \
+        * params.mamba_state * 4
+    alive = layers * params.depth \
+        if params.memory_reduction_strategy == "none" else 1
+    return -(-per_layer * alive // shards)
+
+
 def stash_line(plan: typing.Dict[str, typing.Tuple[int, int]]) -> str:
     """The start-up line beside ``placement_report``'s."""
     return "remat stash: " + "; ".join(

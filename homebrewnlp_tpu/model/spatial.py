@@ -356,15 +356,22 @@ def rotary(x, theta: float):
                            axis=-1).astype(x.dtype)
 
 
-def _rope_attention(args: BlockArgs) -> NamedTensor:
-    """The standard pre-norm transformer's attention (flag ``rope``): key,
-    query and value are three bias-free projections of the block's input to
-    all heads (no bottleneck), with ``qk_norm`` an RMSNorm with a learned
-    scale over ALL heads' features of the query and of the key before the
-    head split (OLMoE), rotary positions on both (``rope_theta``), causal
-    ``softmax(q k^T / sqrt(features_per_head)) v`` through the flash kernel,
-    and an output projection.  Weights are normal(0.02).  Training and
-    full-sequence forward only: a decode step for it is a later issue."""
+def _standard_attention(args: BlockArgs) -> NamedTensor:
+    """The standard pre-norm transformer's attention (flag ``rope`` or
+    ``nope``): query, key and value are three bias-free projections of the
+    block's input (no bottleneck) — the query to all ``heads``, key and value
+    to ``heads // query_group`` of them (``query_group`` 1 = all; more =
+    grouped queries, K/V head ``j`` serves query heads ``j * query_group
+    ..``: K and V are repeated over the group for the kernel) — with
+    ``qk_norm`` an RMSNorm with a learned scale over ALL heads' features of
+    the query and of the key before the head split (OLMoE), rotary positions
+    on both under
+    ``rope`` (``rope_theta``) and none under ``nope`` (Granite 4.0-H: the
+    Mamba layers carry the order), causal ``softmax(scale q k^T) v`` with
+    ``scale = attention_scale`` (0 = ``features_per_head ** -0.5``) through
+    the flash kernel, and an output projection.  Weights are normal(0.02).
+    Training and full-sequence forward only: a decode step for it is a later
+    issue."""
     import jax
     from ..core import scope as scope_mod
     from ..core.tensor import nt, rename_dim, transpose_to
@@ -376,40 +383,60 @@ def _rope_attention(args: BlockArgs) -> NamedTensor:
     dim = get_attention_dim(args).dim
     if ctx.decode is not None or decode_mod.prefill_active() is not None:
         raise NotImplementedError(
-            "attention-rope has no incremental decode / prefill form yet")
+            "the standard attention (attention-rope / attention-nope, "
+            "grouped heads or not) has no incremental decode / prefill form "
+            "yet")
     mesh = ctx.mesh
     if mesh is not None and (mesh.shape.get(shardlib.SEQUENCE_AXIS, 1) > 1
                              or mesh.shape.get(shardlib.PIPE_AXIS, 1) > 1):
         raise NotImplementedError(
-            "attention-rope on a sequence- or pipe-sharded mesh")
+            "the standard attention (attention-rope / attention-nope) on a "
+            "sequence- or pipe-sharded mesh")
     feats = list(params.feature_dims)
     anon = [anonymize_dim(d) for d in feats]
+    grouped = params.query_group > 1
+    kv_heads = params.head_dim.size // params.query_group
+    kv_feats = [Dim("kv_heads", kv_heads), params.key_dim] if grouped \
+        else feats
+    if grouped and mesh is not None and mesh.size > 1:
+        raise NotImplementedError("grouped key / value heads on a mesh")
 
-    def project(x: NamedTensor) -> NamedTensor:
-        """All features -> all features, the input's feature dims renamed so
-        that the einsum contracts them."""
+    def project(x: NamedTensor, new=feats) -> NamedTensor:
+        """All features -> ``new``, the input's feature dims renamed so that
+        the einsum contracts them."""
         for d, a in zip(feats, anon):
             x = rename_dim(x, d.name, a.name)
-        return einsum([x, normal_var(args, anon + feats)],
-                      shape_sub(x.dims, anon) + feats)
+        return einsum([x, normal_var(args, anon + new)],
+                      shape_sub(x.dims, anon) + new)
 
     # creation order: key, query, value (as the dense path), their norms
-    key, qry, val = (project(args.tensor) for _ in range(3))
+    key = project(args.tensor, kv_feats)
+    qry = project(args.tensor)
+    val = project(args.tensor, kv_feats)
     if "qk_norm" in args.name_extras:
         qry = norm(args(qry, ["rms", "scale"]), feats)
-        key = norm(args(key, ["rms", "scale"]), feats)
-    canonical = [d for d in args.tensor.dims if d not in [dim] + feats] \
-        + [dim] + feats
+        key = norm(args(key, ["rms", "scale"]), kv_feats)
+    lead_dims = [d for d in args.tensor.dims if d not in [dim] + feats]
+    canonical = lead_dims + [dim] + feats
     lead = 1
-    for d in canonical[:-3]:
+    for d in lead_dims:
         lead *= d.size
-    shp = (lead, dim.size, params.head_dim.size, params.key_dim.size)
-    q, k, v = (transpose_to(t, canonical).data.reshape(shp)
-               for t in (qry, key, val))
-    with jax.named_scope("rope"):
-        q = rotary(q, params.rope_theta)
-        k = rotary(k, params.rope_theta)
-    scale = params.key_dim.size ** -0.5
+    q = transpose_to(qry, canonical).data.reshape(
+        lead, dim.size, params.head_dim.size, params.key_dim.size)
+    k, v = (transpose_to(t, lead_dims + [dim] + kv_feats).data.reshape(
+        lead, dim.size, kv_heads, params.key_dim.size) for t in (key, val))
+    if "rope" in args.name_extras:
+        with jax.named_scope("rope"):
+            q = rotary(q, params.rope_theta)
+            k = rotary(k, params.rope_theta)
+    scale = params.attention_scale or params.key_dim.size ** -0.5
+    if grouped:
+        # grouped queries: each K/V head repeated over its group before the
+        # kernel, autodiff sums dk and dv over it (the flash kernels are
+        # multi-head only; PERF.md section 6, PR 30 has the A/B against K/V
+        # index maps)
+        import jax.numpy as jnp
+        k, v = (jnp.repeat(t, params.query_group, axis=2) for t in (k, v))
     if params.use_flash_attention:
         out = _flash(ctx, q, k, v, scale)
     else:
@@ -424,8 +451,8 @@ def _rope_attention(args: BlockArgs) -> NamedTensor:
 def attention(args: BlockArgs) -> NamedTensor:
     params = args.params
     params.attention_idx += 1
-    if "rope" in args.name_extras:
-        return _rope_attention(args)
+    if "rope" in args.name_extras or "nope" in args.name_extras:
+        return _standard_attention(args)
     base = None
     if "dot_product" in args.name_extras or "input_as_value" not in args.name_extras:
         base = args(activated_linear_in(args))

@@ -1,5 +1,13 @@
 """Pallas TPU flash attention (single-device causal softmax attention).
 
+Head layout: multi-head attention only, ``q``, ``k``, ``v`` all ``[b, s,
+heads, d]``.  Grouped-query attention reaches these kernels with each K/V
+head already repeated over its group of query heads (``model/spatial.py``
+``_standard_attention``; autodiff sums dk and dv over the group): K/V index
+maps that read row ``i // group`` with the group's sum inside the dk/dv pass
+were built and measured against that in PR 30 and lost end to end (PERF.md
+section 6), so they are not here.
+
 The dot-product attention path's hot op for long context: computes
 softmax(q·kᵀ)·v blockwise in VMEM with an online softmax so the [seq, seq]
 score matrix never reaches HBM.  Complements parallel/ring_attention.py

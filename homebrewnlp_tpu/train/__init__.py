@@ -66,13 +66,16 @@ def _local_batch_dims(p: ModelParameter, local: int):
 def _info_metrics(info) -> typing.Dict[str, jax.Array]:
     """Loss/accuracy metrics from a model BuildInfo (None -> 0), and what
     its layers reported of themselves (``LossInfo.layer_stats``): layer
-    moe's worst expert load and the (token, choice) pairs it routed."""
+    moe's worst expert load and the (token, choice) pairs it routed, layer
+    mamba's most negative within-chunk cumulative log-decay."""
     stats = getattr(info, "layer_stats", None) or {}
     extra = {}
     if "moe_routed_pairs" in stats:
         extra = {"moe_load_max_over_mean":
                  jnp.max(stats["moe_load_max_over_mean"]),
                  "moe_routed_pairs": jnp.sum(stats["moe_routed_pairs"])}
+    if "ssd_log_decay_min" in stats:
+        extra["ssd_log_decay_min"] = jnp.min(stats["ssd_log_decay_min"])
     return {
         **extra,
         "loss": info.total_loss.data.astype(jnp.float32),
@@ -97,6 +100,24 @@ def _grad_norm_metrics(grads: Params, debug: bool) -> typing.Dict[str, jax.Array
     return extra
 
 
+#: step metrics the layers report of themselves (``_info_metrics``) and what
+#: ``Trainer._publish_layer_stats`` publishes each as
+_LAYER_STATS = {
+    "moe_load_max_over_mean": (
+        "gauge", "hbnlp_moe_load_max_over_mean",
+        "pairs of the busiest expert over the mean, worst moe layer of the "
+        "newest finished step"),
+    "moe_routed_pairs": (
+        "counter", "hbnlp_moe_routed_pairs_total",
+        "(token, choice) pairs routed to an expert, all moe layers"),
+    "ssd_log_decay_min": (
+        "gauge", "hbnlp_ssd_log_decay_min",
+        "most negative within-chunk cumulative dt * A of the newest finished "
+        "step, all mamba layers: exp of it is the smallest decay the chunked "
+        "scan formed"),
+}
+
+
 class TrainState(typing.NamedTuple):
     variables: Params
     opt_state: typing.Dict[str, typing.Dict[str, jax.Array]]
@@ -118,8 +139,8 @@ class Trainer:
         # (zero registry calls on the hot path when off); the trace
         # annotation is written either way
         self._record_steps = bool(params.telemetry_enabled)
-        # (worst expert load, routed pairs) of steps already dispatched,
-        # waiting for the device to finish them (_publish_layer_stats)
+        # the layers' own statistics of steps already dispatched, waiting
+        # for the device to finish them (_publish_layer_stats)
         self._pending_layer_stats: collections.deque = collections.deque()
         # resolved lazily on the first traced step (warns once on fallback)
         self._grad_allreduce_resolved: typing.Optional[str] = None
@@ -564,11 +585,17 @@ class Trainer:
         """``hbnlp_remat_stash_bytes{kind}`` / ``hbnlp_remat_stash_layers
         {kind}``: what rides the memory strategy's residuals in the step
         this trainer builds (model/remat.py ``stash_plan``; 0 for a kind
-        that is not engaged).  Set when the step is built; returns the
-        start-up line that says the same."""
-        from ..model.remat import stash_line, stash_plan
+        that is not engaged), and ``hbnlp_ssd_state_bytes``: layer mamba's
+        chunk states alive at once for the backward (``ssd_state_bytes``).
+        Set when the step is built; returns the start-up line that says the
+        same."""
+        from ..model.remat import ssd_state_bytes, stash_line, stash_plan
         plan = stash_plan(self.params, self.mesh)
         r = telemetry.registry()
+        states = ssd_state_bytes(self.params, self.mesh)
+        r.gauge("hbnlp_ssd_state_bytes",
+                "per-device bytes of layer mamba's float32 chunk states "
+                "alive at once for the backward").set(states)
         nbytes = r.gauge("hbnlp_remat_stash_bytes",
                          "per-device bytes riding the memory strategy's "
                          "residuals instead of being replayed", ("kind",))
@@ -578,7 +605,8 @@ class Trainer:
         for kind, (layers, size) in plan.items():
             nbytes.labels(kind).set(size)
             nlayers.labels(kind).set(layers)
-        return stash_line(plan)
+        return stash_line(plan) + (
+            f"; ssd chunk states {states} bytes a device" if states else "")
 
     def lowered(self, state: TrainState, batch: typing.Dict[str, jax.Array]):
         """Lowered (StableHLO) train step for ``save_graph`` dumps — the
@@ -636,30 +664,26 @@ class Trainer:
             if self.mesh is not None and not self._batch_placed(batch):
                 batch = shardlib.shard_batch(self.params, batch, self.mesh)
             state, metrics = self._step_fn(state, batch, rng)
-            if "moe_routed_pairs" in metrics:
+            if any(k in metrics for k in _LAYER_STATS):
                 self._publish_layer_stats(metrics)
             return state, metrics
 
     def _publish_layer_stats(self, metrics) -> None:
-        """``hbnlp_moe_load_max_over_mean`` and
-        ``hbnlp_moe_routed_pairs_total`` (under ``telemetry_enabled``: only
+        """``hbnlp_moe_load_max_over_mean``, ``hbnlp_moe_routed_pairs_total``
+        and ``hbnlp_ssd_log_decay_min`` (under ``telemetry_enabled``: only
         then does the step report them) from the scalars of EARLIER steps
         the device has finished; a step still running is left for a later
         call, so this never waits.  The last steps of a run stay unread."""
         pending = self._pending_layer_stats
-        pending.append((metrics["moe_load_max_over_mean"],
-                        metrics["moe_routed_pairs"]))
+        pending.append({k: metrics[k] for k in _LAYER_STATS if k in metrics})
         r = telemetry.registry()
-        gauge = r.gauge("hbnlp_moe_load_max_over_mean",
-                        "pairs of the busiest expert over the mean, worst "
-                        "moe layer of the newest finished step")
-        counter = r.counter("hbnlp_moe_routed_pairs_total",
-                            "(token, choice) pairs routed to an expert, all "
-                            "moe layers")
-        while pending and all(v.is_ready() for v in pending[0]):
-            load, pairs = pending.popleft()
-            gauge.set(float(load))
-            counter.inc(float(pairs))
+        while pending and all(v.is_ready() for v in pending[0].values()):
+            for key, value in pending.popleft().items():
+                kind, name, text = _LAYER_STATS[key]
+                if kind == "gauge":
+                    r.gauge(name, text).set(float(value))
+                else:
+                    r.counter(name, text).inc(float(value))
 
     def eval_loss(self, state: TrainState,
                   batch: typing.Dict[str, jax.Array]

@@ -483,7 +483,9 @@ _RARE_SERIES = {telemetry.SPAN_METRIC, "hbnlp_init_values_seconds_total",
                 "hbnlp_init_values_cpu_seconds_total", "hbnlp_init_workers",
                 "hbnlp_compile_seconds_total", "hbnlp_compiles_total",
                 # set once, when the step is built (PR 27)
-                "hbnlp_remat_stash_bytes", "hbnlp_remat_stash_layers"}
+                "hbnlp_remat_stash_bytes", "hbnlp_remat_stash_layers",
+                # likewise (PR 30; 0 without a mamba layer)
+                "hbnlp_ssd_state_bytes"}
 _RARE_SPANS = {"setup/data_first_batch", "setup/model_init",
                "setup/place_params", "setup/opt_init", "setup/init_wait",
                "train/metric_log", "train/checkpoint_save", "train/eval"}
