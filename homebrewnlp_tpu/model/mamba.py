@@ -10,7 +10,10 @@ shared by all heads, state size ``n = mamba_state``:
                                           + heads), no bias
     xBC = silu(conv(xBC))                 causal depthwise conv over the
                                           sequence, width mamba_conv_size,
-                                          with bias
+                                          with bias: one Pallas kernel pair
+                                          where parallel/causal_conv.py
+                                          ``kernel_applies``, else K shifted
+                                          multiplies in XLA
     x, B, C = split(xBC)                  d_inner, n, n
     dt = softplus(dt + dt_bias);  A = -exp(A_log)          per head, float32
     S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T             S: [width, n]
@@ -45,6 +48,7 @@ from ..config import BlockArgs
 from ..core import scope
 from ..core.dims import Dim
 from ..core.tensor import NamedTensor, nt, transpose_to
+from ..parallel.causal_conv import causal_conv_silu, kernel_applies
 from .backend import ConstantInit, UniformInit, normal_var
 from .loss import _matmul
 from .normalization import _norm_core
@@ -69,7 +73,9 @@ def _small_var(args: BlockArgs, name: str, shape, initializer) -> jax.Array:
 
 def causal_depthwise_conv(x, weight, bias):
     """``y[t] = bias + sum_k weight[k] x[t - (K - 1) + k]`` on ``x [b, s,
-    channels]``, zeros before the sequence: K shifted multiplies."""
+    channels]``, zeros before the sequence: K shifted multiplies.  The path
+    off the TPU and at shapes ``parallel/causal_conv.py`` declines, and that
+    kernel's reference."""
     k = weight.shape[0]
     s = x.shape[1]
     padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
@@ -183,8 +189,12 @@ def mamba(args: BlockArgs) -> NamedTensor:
         xbc = proj[..., d_inner:d_inner + conv_dim]
         dt = proj[..., d_inner + conv_dim:]
     with jax.named_scope("conv"):
-        xbc = jax.nn.silu(causal_depthwise_conv(
-            xbc.astype(jnp.float32), conv_w, conv_b)).astype(dtype)
+        if kernel_applies(conv_dim, s, k, d_inner):
+            # its channels read in place out of proj: no copy of the slice
+            xbc = causal_conv_silu(proj, conv_w, conv_b, d_inner)
+        else:
+            xbc = jax.nn.silu(causal_depthwise_conv(
+                xbc.astype(jnp.float32), conv_w, conv_b)).astype(dtype)
     with jax.named_scope("ssd"):
         xs = xbc[..., :d_inner].reshape(bsz, s, h, p)
         dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)

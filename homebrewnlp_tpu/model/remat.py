@@ -359,6 +359,21 @@ def ssd_state_bytes(params: ModelParameter, mesh=None) -> int:
     return -(-per_layer * alive // shards)
 
 
+def conv_kernel_layers(params: ModelParameter, backend=None) -> int:
+    """How many ``mamba`` layers of the step take the Pallas conv kernel pair
+    (``parallel/causal_conv.py``): all of them or none, by the predicate the
+    layer itself calls.  ``Trainer`` publishes it as
+    ``hbnlp_mamba_conv_kernel_layers``."""
+    from ..parallel.causal_conv import kernel_applies
+    layers = sum(name == "mamba" for name, _ in _layers(params))
+    inner = params.mamba_heads * params.mamba_head_features
+    if not layers or not kernel_applies(
+            inner + 2 * params.mamba_state, params.sequence_dim.size,
+            params.mamba_conv_size, inner, backend):
+        return 0
+    return layers * params.depth
+
+
 def stash_line(plan: typing.Dict[str, typing.Tuple[int, int]]) -> str:
     """The start-up line beside ``placement_report``'s."""
     return "remat stash: " + "; ".join(

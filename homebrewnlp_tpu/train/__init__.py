@@ -585,17 +585,23 @@ class Trainer:
         """``hbnlp_remat_stash_bytes{kind}`` / ``hbnlp_remat_stash_layers
         {kind}``: what rides the memory strategy's residuals in the step
         this trainer builds (model/remat.py ``stash_plan``; 0 for a kind
-        that is not engaged), and ``hbnlp_ssd_state_bytes``: layer mamba's
-        chunk states alive at once for the backward (``ssd_state_bytes``).
-        Set when the step is built; returns the start-up line that says the
-        same."""
-        from ..model.remat import ssd_state_bytes, stash_line, stash_plan
+        that is not engaged), ``hbnlp_ssd_state_bytes``: layer mamba's
+        chunk states alive at once for the backward (``ssd_state_bytes``),
+        and ``hbnlp_mamba_conv_kernel_layers``: how many of its layers took
+        the Pallas conv (``conv_kernel_layers``).  Set when the step is
+        built; returns the start-up line that says the same."""
+        from ..model.remat import (conv_kernel_layers, ssd_state_bytes,
+                                   stash_line, stash_plan)
         plan = stash_plan(self.params, self.mesh)
         r = telemetry.registry()
         states = ssd_state_bytes(self.params, self.mesh)
         r.gauge("hbnlp_ssd_state_bytes",
                 "per-device bytes of layer mamba's float32 chunk states "
                 "alive at once for the backward").set(states)
+        conv_layers = conv_kernel_layers(self.params)
+        r.gauge("hbnlp_mamba_conv_kernel_layers",
+                "mamba layers of the built step whose conv is the Pallas "
+                "kernel pair (0 on the XLA fallback)").set(conv_layers)
         nbytes = r.gauge("hbnlp_remat_stash_bytes",
                          "per-device bytes riding the memory strategy's "
                          "residuals instead of being replayed", ("kind",))
@@ -606,7 +612,8 @@ class Trainer:
             nbytes.labels(kind).set(size)
             nlayers.labels(kind).set(layers)
         return stash_line(plan) + (
-            f"; ssd chunk states {states} bytes a device" if states else "")
+            f"; ssd chunk states {states} bytes a device; conv kernel "
+            f"{conv_layers} layers" if states else "")
 
     def lowered(self, state: TrainState, batch: typing.Dict[str, jax.Array]):
         """Lowered (StableHLO) train step for ``save_graph`` dumps — the

@@ -353,6 +353,59 @@ def new_layers_fold_into_their_scopes_test(path, scope):
     assert scope_key(path) == scope
 
 
+@pytest.fixture(scope="module")
+def v5e():
+    """One chip of a described (not attached) v5e; libtpu is loaded here,
+    inside a test of this file, never while a module is imported."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+
+def conv_kernels_keep_their_scope_and_names_test(v5e, monkeypatch):
+    """One ``mamba`` layer at the published widths, 1 x 8,192 tokens, loss
+    and gradients compiled for a v5e as a TPU process traces them: the conv
+    is the Pallas pair in its three forms (forward, ``checkpoint``'s replay,
+    backward), Mosaic accepts both kernels, every one folds into
+    ``body/mamba/conv`` and none bears a name another metric's reader takes
+    (``^flash_``, ``^map_mixer_``)."""
+    import re
+    from benchmark.lib.cell import load_cell
+    from homebrewnlp_tpu.model import remat
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cell = load_cell("train_granite_4_0_h_micro_long").model_config()
+    assert cell["block_config"][0]["layer"][-1] == "mamba"
+    params = ModelParameter({**cell, "block_config": cell["block_config"][:1],
+                             "vocab_size": 512, "model_path": "/tmp/granite"})
+    assert remat.conv_kernel_layers(params) == 1
+    model = Model(params)
+    batch = {k: np.zeros((1, 8192, 1), np.int32)
+             for k in ("token_x", "token_y")}
+    variables = model.init(batch, seed=1)
+    avals = [{k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=v5e)
+              for k, v in tree.items()} for tree in (variables, batch)]
+    hlo = jax.jit(jax.value_and_grad(
+        lambda v, b: model.apply(v, b).total_loss.data)).lower(
+        *avals).compile().as_text()
+    calls = re.findall(r'%([\w.-]+) = [^\n]*?custom_call_target='
+                       r'"tpu_custom_call"[^\n]*?op_name="([^"]+)"', hlo)
+    assert sorted(re.sub(r"\.\d+$", "", name) for name, _ in calls) \
+        == ["mamba_conv_bwd", "mamba_conv_fwd", "mamba_conv_fwd"]
+    for name, op_name in calls:
+        assert scope_key(op_name) == "body/mamba/conv", op_name
+        assert not re.match(r"flash_|map_mixer_", name)
+    forms = sorted(op_name for _, op_name in calls)
+    assert forms[0].split("/")[1].startswith("jvp(")
+    assert "rematted_computation" in forms[2] and "mamba_conv_fwd" in forms[2]
+    assert forms[1].split("/")[1].startswith("transpose(jvp(") \
+        and "mamba_conv_bwd" in forms[1]
+
+
 def experts_rule_declines_without_a_moe_layer_test():
     """``checkpoint`` with no ``moe`` layer: nothing rides, the policy stays
     the named one, and the chunk states' gauge counts one layer's."""
@@ -370,9 +423,10 @@ def experts_rule_declines_without_a_moe_layer_test():
     assert remat.ssd_state_bytes(params) == 2 * 4 * 4 * 8 * 16 * 4
     line = Trainer(params, model).publish_stash_plan()
     assert line.endswith("experts 0 layers, 0 bytes a device; ssd chunk "
-                         "states 16384 bytes a device")
+                         "states 16384 bytes a device; conv kernel 0 layers")
     snap = telemetry.registry().snapshot()
     assert snap["hbnlp_ssd_state_bytes"]["series"][()] == 16384
+    assert snap["hbnlp_mamba_conv_kernel_layers"]["series"][()] == 0
     _, none, _, _, _ = _build("float32", memory_reduction_strategy="none")
     assert remat.ssd_state_bytes(none) == 9 * 16384
 

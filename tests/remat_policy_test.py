@@ -399,6 +399,22 @@ def experts_kind_moves_no_other_cell_test(cell, kinds, policy, plan):
         "attention": (0, 0), "bottleneck": (0, 0), "experts": (0, 0), **plan}
 
 
+@pytest.mark.parametrize("cell,layers", [
+    ("train_32big_mixer_b32", 0), ("train_32big_mixer_dp2tp2", 0),
+    ("train_1b_long_context_s16k", 0), ("train_olmoe_1b_7b_s4k", 0),
+    ("train_granite_4_0_h_micro_long", 9)])
+def conv_kernel_moves_no_other_cell_test(cell, layers):
+    """The Pallas conv pair is chosen inside layer ``mamba`` alone: on a TPU
+    the granite cell's nine layers take it, the four other cells have no
+    such layer to trace anything new (their lowered steps hashed equal to
+    the parent's, ``PERF.md`` section 6, PR 31); off the TPU nobody does."""
+    from homebrewnlp_tpu.model.remat import _layers, conv_kernel_layers
+    p = _cell_params(cell)
+    assert conv_kernel_layers(p, "tpu") == layers
+    assert conv_kernel_layers(p) == 0
+    assert any(name == "mamba" for name, _ in _layers(p)) is bool(layers)
+
+
 def experts_stash_line_and_policy_test():
     """The start-up line names the kind, and ``_checkpoint_policy`` saves
     layer moe's names exactly where the plan says the kind rides: today's

@@ -484,8 +484,8 @@ _RARE_SERIES = {telemetry.SPAN_METRIC, "hbnlp_init_values_seconds_total",
                 "hbnlp_compile_seconds_total", "hbnlp_compiles_total",
                 # set once, when the step is built (PR 27)
                 "hbnlp_remat_stash_bytes", "hbnlp_remat_stash_layers",
-                # likewise (PR 30; 0 without a mamba layer)
-                "hbnlp_ssd_state_bytes"}
+                # likewise (PR 30, PR 31; 0 without a mamba layer)
+                "hbnlp_ssd_state_bytes", "hbnlp_mamba_conv_kernel_layers"}
 _RARE_SPANS = {"setup/data_first_batch", "setup/model_init",
                "setup/place_params", "setup/opt_init", "setup/init_wait",
                "train/metric_log", "train/checkpoint_save", "train/eval"}
