@@ -65,7 +65,8 @@ _LAYER_NAMES = frozenset((
     "activation", "convolution", "dropout", "group_linear", "split_path",
     "feed_forward_product_key_memory", "product_key_memory",
     "reduced_half_linear", "transpose_sequence_features",
-    "bottleneck_group_linear", "sum_heads", "moe", "mamba", "mlp"))
+    "bottleneck_group_linear", "sum_heads", "moe", "mamba", "gated_delta",
+    "mlp"))
 #: the parts of layer ``moe`` (model/moe.py), each a scope of its own below
 #: ``body/moe``
 _MOE_PARTS = frozenset(("router", "dispatch", "experts", "combine"))
@@ -73,6 +74,12 @@ _MOE_PARTS = frozenset(("router", "dispatch", "experts", "combine"))
 #: scan's own steps (``intra_chunk``, ``chunk_states``, ``inter_chunk``,
 #: ``state_out``) stay inside ``body/mamba/ssd``
 _MAMBA_PARTS = frozenset(("in_proj", "conv", "ssd", "gate_norm", "out_proj"))
+#: the parts of layer ``gated_delta`` (model/gated_delta.py) below
+#: ``body/gated_delta``; the rule's own steps (``decay``, ``solve``,
+#: ``intra_chunk``, ``inter_chunk``, ``state_out``) stay inside
+#: ``body/gated_delta/delta_rule``
+_DELTA_PARTS = frozenset(("in_proj", "conv", "delta_rule", "gate_norm",
+                          "out_proj"))
 
 
 def _unwrap(comp: str) -> str:
@@ -96,7 +103,9 @@ def scope_key(path: str) -> str:
     Keys: ``decode/cache_read|cache_write|sampling``, ``optimizer``,
     ``head_loss``, ``input/embed``, ``input``, ``body/<layer>``,
     ``body/moe/router|dispatch|experts|combine``,
-    ``body/mamba/in_proj|conv|ssd|gate_norm|out_proj``, ``output/unembed``,
+    ``body/mamba/in_proj|conv|ssd|gate_norm|out_proj``,
+    ``body/gated_delta/in_proj|conv|delta_rule|gate_norm|out_proj``,
+    ``output/unembed``,
     ``output``, ``loss``, ``unscoped``.  Transform decorations
     (``jvp``/``transpose``/``jit`` wrappers) are unwrapped, so forward and
     backward ops of one block fold into the same scope — per-block
@@ -117,6 +126,8 @@ def scope_key(path: str) -> str:
             return f"body/moe/{base}"
         elif layer == "mamba" and base in _MAMBA_PARTS:
             return f"body/mamba/{base}"
+        elif layer == "gated_delta" and base in _DELTA_PARTS:
+            return f"body/gated_delta/{base}"
     if phase == "body" and layer is not None:
         return f"body/{layer}"
     if phase == "input":

@@ -207,6 +207,20 @@ class ModelParameter:
         self.mamba_state = 128
         self.mamba_conv_size = 4
         self.mamba_chunk = 256
+        # layer "gated_delta" (gated delta-rule linear attention,
+        # model/gated_delta.py): heads, the width of a head's key / query and
+        # of its value, the causal depthwise conv's taps, the chunk of the
+        # WY form, and whether the write strength beta reaches 2 (negative
+        # eigenvalues of the transition) or stops at 1
+        self.delta_heads = 30
+        self.delta_key_features = 96
+        self.delta_value_features = 192
+        self.delta_conv_size = 4
+        self.delta_chunk = 64
+        self.delta_allow_neg_eigval = True
+        # the eps of layer "norm" and of gated_delta's gated norm (a
+        # published config's rms_norm_eps / layer_norm_eps)
+        self.norm_epsilon = 1e-5
         # Granite's three multipliers: on the token embedding, on every
         # block's output before it joins the residual stream, and the
         # divisor of the logits; 1 = off
@@ -883,6 +897,19 @@ class ModelParameter:
         if self.query_group < 1 or self.heads % self.query_group:
             raise ValueError(f"query_group {self.query_group} must divide "
                              f"heads {self.heads}")
+        for key in ("delta_heads", "delta_key_features",
+                    "delta_value_features", "delta_chunk"):
+            if not isinstance(getattr(self, key), int) \
+                    or getattr(self, key) < 1:
+                raise ValueError(f"{key} {getattr(self, key)!r} must be a "
+                                 "positive whole number")
+        if not isinstance(self.delta_conv_size, int) \
+                or not 1 <= self.delta_conv_size <= 128:
+            raise ValueError(f"delta_conv_size {self.delta_conv_size!r} must "
+                             "be 1 to 128 taps")
+        if not self.norm_epsilon > 0:
+            raise ValueError(f"norm_epsilon {self.norm_epsilon!r} must be "
+                             "positive")
         if self.tie_word_embeddings and (self.vocab_weight_factorization
                                          or self.token_patch_size != 1
                                          or self.use_video):

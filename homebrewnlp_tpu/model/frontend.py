@@ -15,6 +15,7 @@ from .basic import (bottleneck_group_linear, dropout, feed_forward,
                     feed_forward_product_key_memory, group_linear, mlp,
                     product_key_memory, reduced_half_linear, rezero, sum_heads,
                     transpose_sequence_features)
+from .gated_delta import gated_delta
 from .mamba import mamba
 from .moe import moe
 from .normalization import norm
@@ -139,5 +140,6 @@ LAYER_FUNCTIONS = {'feed_forward': feed_forward,
                    'sum_heads': sum_heads,
                    'moe': moe,
                    'mamba': mamba,
+                   'gated_delta': gated_delta,
                    'mlp': mlp,
                    }

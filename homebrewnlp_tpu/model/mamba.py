@@ -44,7 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..config import BlockArgs
+from ..config import BlockArgs, ModelParameter
 from ..core import scope
 from ..core.dims import Dim
 from ..core.tensor import NamedTensor, nt, transpose_to
@@ -52,37 +52,9 @@ from ..parallel.causal_conv import causal_conv_silu, kernel_applies
 from .backend import ConstantInit, UniformInit, normal_var
 from .loss import _matmul
 from .normalization import _norm_core
+from .recurrent import (Recurrent, _inverse_softplus_of_exp, _small_var,
+                        causal_depthwise_conv, token_layout)
 from .utils import anonymize_dim
-
-
-def _inverse_softplus_of_exp(log_dt: np.ndarray) -> np.ndarray:
-    """``dt_bias`` with ``softplus(dt_bias) = exp(log_dt)``: the Mamba-2
-    code's ``dt + log(-expm1(-dt))``."""
-    dt = np.exp(log_dt)
-    return (dt + np.log(-np.expm1(-dt))).astype(np.float32)
-
-
-def _small_var(args: BlockArgs, name: str, shape, initializer) -> jax.Array:
-    """A per-channel vector the recurrence reads in float32 (``A_log``,
-    ``dt_bias``, ``D``, the conv and norm weights): stored in the slice dtype
-    like every parameter, never rounded to the calculation dtype."""
-    params = args.params
-    return scope.scoped(name, scope.get_param, "var", shape, initializer,
-                        params.slice_dtype, jnp.float32).data
-
-
-def causal_depthwise_conv(x, weight, bias):
-    """``y[t] = bias + sum_k weight[k] x[t - (K - 1) + k]`` on ``x [b, s,
-    channels]``, zeros before the sequence: K shifted multiplies.  The path
-    off the TPU and at shapes ``parallel/causal_conv.py`` declines, and that
-    kernel's reference."""
-    k = weight.shape[0]
-    s = x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
-    out = bias
-    for i in range(k):
-        out = out + padded[:, i:i + s] * weight[i]
-    return out
 
 
 def ssd(x, dt, a, b_mat, c_mat, chunk: int):
@@ -143,26 +115,14 @@ def mamba(args: BlockArgs) -> NamedTensor:
     normal(0.02)."""
     params = args.params
     ctx = scope.current()
-    if ctx.decode is not None or getattr(ctx, "prefill", None) is not None:
-        raise NotImplementedError(
-            "layer mamba has no incremental decode / prefill form yet")
-    if ctx.mesh is not None and ctx.mesh.size > 1:
-        raise NotImplementedError("layer mamba on a mesh is a later issue")
+    token_dims, bsz, s, chunk = token_layout(args, "mamba",
+                                             params.mamba_chunk)
     h, p, n = params.mamba_heads, params.mamba_head_features, params.mamba_state
     k = params.mamba_conv_size
     d_inner, conv_dim = h * p, h * p + 2 * n
     feats = list(params.feature_dims)
     anon = [anonymize_dim(d) for d in feats]
     x = args.tensor
-    token_dims = [d for d in x.dims if d not in feats]
-    if len(token_dims) != 2 or token_dims[1] != params.sequence_dim:
-        raise ValueError("layer mamba mixes [batch, sequence, features]; got "
-                         f"{x.dims}")
-    bsz, s = (d.size for d in token_dims)
-    chunk = min(params.mamba_chunk, s)        # a short sequence: one chunk
-    if s % chunk:
-        raise ValueError(f"sequence {s} is not a multiple of mamba_chunk "
-                         f"{chunk}")
     f_sz = math.prod(d.size for d in feats)
     inner, channels = Dim("mamba_inner", d_inner), Dim("mamba_conv", conv_dim)
     head_dim = Dim("mamba_heads", h)
@@ -216,3 +176,20 @@ def mamba(args: BlockArgs) -> NamedTensor:
                       ).astype(dtype)
     out = out.reshape([d.size for d in token_dims + feats])
     return transpose_to(nt(out, token_dims + feats), x.dims)
+
+
+def _state_bytes(params: ModelParameter) -> int:
+    """``[batch, sequence / mamba_chunk, mamba_heads, mamba_head_features,
+    mamba_state]`` float32: what the inter-chunk scan's backward reads."""
+    return params.batch_dim.size \
+        * max(1, params.sequence_dim.size // params.mamba_chunk) \
+        * params.mamba_heads * params.mamba_head_features \
+        * params.mamba_state * 4
+
+
+def _conv(params: ModelParameter):
+    inner = params.mamba_heads * params.mamba_head_features
+    return inner + 2 * params.mamba_state, params.mamba_conv_size, inner
+
+
+mamba.recurrent = Recurrent(_state_bytes, _conv)

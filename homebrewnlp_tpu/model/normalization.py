@@ -115,6 +115,6 @@ def norm(args: BlockArgs, feature_shape: typing.Optional[SHAPE] = None) -> Named
         if has_scale else one
     shift = _align(normal_var(args, feature_shape, mean=0), block_input.dims) \
         if has_shift else one
-    out = _norm_core(x, scale, shift, axes, 1e-5, has_scale, has_shift,
-                     "rms" not in args.name_extras)
+    out = _norm_core(x, scale, shift, axes, params.norm_epsilon, has_scale,
+                     has_shift, "rms" not in args.name_extras)
     return nt(out, block_input.dims)

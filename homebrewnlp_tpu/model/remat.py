@@ -337,40 +337,47 @@ def stash_plan(params: ModelParameter, mesh=None
     return plan
 
 
+def _recurrent_layers(params: ModelParameter):
+    """What each recurrent mixer of one depth unit declares of itself
+    (``model/recurrent.py`` ``Recurrent``, set on the layer's function), in
+    execution order."""
+    from .frontend import LAYER_FUNCTIONS
+    found = (getattr(LAYER_FUNCTIONS.get(name), "recurrent", None)
+             for name, _ in _layers(params))
+    return [spec for spec in found if spec is not None]
+
+
 def ssd_state_bytes(params: ModelParameter, mesh=None) -> int:
-    """Per-device bytes of layer ``mamba``'s chunk states — ``[batch,
-    sequence / mamba_chunk, mamba_heads, mamba_head_features, mamba_state]``
-    float32 a layer, what the inter-chunk scan's backward reads — that are
-    alive at once for the backward: ONE layer's under ``checkpoint`` /
-    ``revnet`` / ``momentum`` (no policy saves them across the forward; the
-    block's replay makes them again and drops them with the block), every
-    layer's under ``none``.  0 without a ``mamba`` layer.  ``Trainer``
-    publishes it as ``hbnlp_ssd_state_bytes``."""
-    layers = sum(name == "mamba" for name, _ in _layers(params))
-    if not layers:
+    """Per-device bytes of the recurrent mixers' chunk states — what a layer
+    declares (``mamba``: ``[batch, sequence / mamba_chunk, mamba_heads,
+    mamba_head_features, mamba_state]`` float32, ``gated_delta``: ``[batch,
+    sequence / delta_chunk, delta_heads, delta_value_features,
+    delta_key_features]`` in the calculation dtype), what the inter-chunk
+    scan's backward reads — that are alive at once for the backward: ONE
+    layer's (the largest) under ``checkpoint`` / ``revnet`` / ``momentum``
+    (no policy saves them across the forward; the block's replay makes them
+    again and drops them with the block), every layer's under ``none``.  0 without such a layer.
+    ``Trainer`` publishes it as ``hbnlp_ssd_state_bytes``."""
+    sizes = [spec.state_bytes(params) for spec in _recurrent_layers(params)]
+    if not sizes:
         return 0
     shards, _ = _mesh_geometry(params, mesh)
-    per_layer = params.batch_dim.size \
-        * max(1, params.sequence_dim.size // params.mamba_chunk) \
-        * params.mamba_heads * params.mamba_head_features \
-        * params.mamba_state * 4
-    alive = layers * params.depth \
-        if params.memory_reduction_strategy == "none" else 1
-    return -(-per_layer * alive // shards)
+    alive = sum(sizes) * params.depth \
+        if params.memory_reduction_strategy == "none" else max(sizes)
+    return -(-alive // shards)
 
 
 def conv_kernel_layers(params: ModelParameter, backend=None) -> int:
-    """How many ``mamba`` layers of the step take the Pallas conv kernel pair
-    (``parallel/causal_conv.py``): all of them or none, by the predicate the
-    layer itself calls.  ``Trainer`` publishes it as
-    ``hbnlp_mamba_conv_kernel_layers``."""
+    """How many recurrent mixers of the step (``mamba``, ``gated_delta``)
+    take the Pallas conv kernel pair (``parallel/causal_conv.py``), by the
+    predicate the layers themselves call on the conv each declares.
+    ``Trainer`` publishes it as ``hbnlp_mamba_conv_kernel_layers``."""
     from ..parallel.causal_conv import kernel_applies
-    layers = sum(name == "mamba" for name, _ in _layers(params))
-    inner = params.mamba_heads * params.mamba_head_features
-    if not layers or not kernel_applies(
-            inner + 2 * params.mamba_state, params.sequence_dim.size,
-            params.mamba_conv_size, inner, backend):
-        return 0
+    layers = 0
+    for spec in _recurrent_layers(params):
+        channels, taps, offset = spec.conv(params)
+        layers += kernel_applies(channels, params.sequence_dim.size, taps,
+                                 offset, backend)
     return layers * params.depth
 
 

@@ -1,4 +1,5 @@
-"""Pallas TPU causal depthwise conv + bias + SiLU (layer ``mamba``'s conv):
+"""Pallas TPU causal depthwise conv + bias + SiLU (layer ``mamba``'s conv;
+layer ``gated_delta``'s, which has no bias: a zero one):
 
     y[t] = silu(bias + sum_k weight[k] x[t - (K - 1) + k])     zeros before 0
 
@@ -266,7 +267,13 @@ def _bwd_impl(xt, weight, bias, gt, offset, interpret):
     return dxt, sums[:k], sums[k]
 
 
+def _or_zeros(bias, weight):
+    """A conv without a bias runs the kernels with a zero one."""
+    return jnp.zeros(weight.shape[1], weight.dtype) if bias is None else bias
+
+
 def _forward(x, weight, bias, offset, interpret):
+    bias = _or_zeros(bias, weight)
     return jnp.swapaxes(_fwd_impl(jnp.swapaxes(x, 1, 2), weight, bias,
                                   offset, interpret), 1, 2)
 
@@ -275,8 +282,8 @@ def _forward(x, weight, bias, offset, interpret):
 def causal_conv_silu(x, weight, bias, offset: int = 0,
                      interpret: bool = False):
     """``silu(bias + causal depthwise conv(x[..., offset:offset + channels],
-    weight))`` in ``x``'s dtype, ``channels = weight.shape[1]``; shapes as
-    ``kernel_applies`` accepts them."""
+    weight))`` in ``x``'s dtype, ``channels = weight.shape[1]``, ``bias``
+    None = none; shapes as ``kernel_applies`` accepts them."""
     return _forward(x, weight, bias, offset, interpret)
 
 
@@ -286,13 +293,15 @@ def _vjp_fwd(x, weight, bias, offset, interpret):
 
 def _vjp_bwd(offset, interpret, res, g):
     x, weight, bias = res
-    dxt, dw, db = _bwd_impl(jnp.swapaxes(x, 1, 2), weight, bias,
-                            jnp.swapaxes(g, 1, 2), offset, interpret)
+    dxt, dw, db = _bwd_impl(jnp.swapaxes(x, 1, 2), weight,
+                            _or_zeros(bias, weight), jnp.swapaxes(g, 1, 2),
+                            offset, interpret)
     dx = jnp.swapaxes(dxt, 1, 2)
     after = x.shape[-1] - offset - weight.shape[1]
     if offset or after:
         dx = jnp.pad(dx, ((0, 0), (0, 0), (offset, after)))
-    return dx, dw.astype(weight.dtype), db.astype(bias.dtype)
+    return dx, dw.astype(weight.dtype), \
+        None if bias is None else db.astype(bias.dtype)
 
 
 causal_conv_silu.defvjp(_vjp_fwd, _vjp_bwd)

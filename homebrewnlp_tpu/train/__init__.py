@@ -67,7 +67,8 @@ def _info_metrics(info) -> typing.Dict[str, jax.Array]:
     """Loss/accuracy metrics from a model BuildInfo (None -> 0), and what
     its layers reported of themselves (``LossInfo.layer_stats``): layer
     moe's worst expert load and the (token, choice) pairs it routed, layer
-    mamba's most negative within-chunk cumulative log-decay."""
+    mamba's most negative within-chunk cumulative log-decay, layer
+    gated_delta's largest solved transform."""
     stats = getattr(info, "layer_stats", None) or {}
     extra = {}
     if "moe_routed_pairs" in stats:
@@ -76,6 +77,9 @@ def _info_metrics(info) -> typing.Dict[str, jax.Array]:
                  "moe_routed_pairs": jnp.sum(stats["moe_routed_pairs"])}
     if "ssd_log_decay_min" in stats:
         extra["ssd_log_decay_min"] = jnp.min(stats["ssd_log_decay_min"])
+    if "delta_transform_abs_max" in stats:
+        extra["delta_transform_abs_max"] = jnp.max(
+            stats["delta_transform_abs_max"])
     return {
         **extra,
         "loss": info.total_loss.data.astype(jnp.float32),
@@ -115,6 +119,12 @@ _LAYER_STATS = {
         "most negative within-chunk cumulative dt * A of the newest finished "
         "step, all mamba layers: exp of it is the smallest decay the chunked "
         "scan formed"),
+    "delta_transform_abs_max": (
+        "gauge", "hbnlp_delta_transform_abs_max",
+        "largest magnitude in any chunk's solved transform T = (I + "
+        "strict_tril(diag(beta) (K K^T o Gamma)))^-1 diag(beta) of the newest "
+        "finished step, all gated_delta layers: what its lower-precision "
+        "matmul operands have to carry"),
 }
 
 
@@ -585,9 +595,10 @@ class Trainer:
         """``hbnlp_remat_stash_bytes{kind}`` / ``hbnlp_remat_stash_layers
         {kind}``: what rides the memory strategy's residuals in the step
         this trainer builds (model/remat.py ``stash_plan``; 0 for a kind
-        that is not engaged), ``hbnlp_ssd_state_bytes``: layer mamba's
-        chunk states alive at once for the backward (``ssd_state_bytes``),
-        and ``hbnlp_mamba_conv_kernel_layers``: how many of its layers took
+        that is not engaged), ``hbnlp_ssd_state_bytes``: the recurrent
+        mixers' (``mamba``, ``gated_delta``) chunk states alive at once for
+        the backward (``ssd_state_bytes``), and
+        ``hbnlp_mamba_conv_kernel_layers``: how many of those layers took
         the Pallas conv (``conv_kernel_layers``).  Set when the step is
         built; returns the start-up line that says the same."""
         from ..model.remat import (conv_kernel_layers, ssd_state_bytes,
@@ -596,12 +607,14 @@ class Trainer:
         r = telemetry.registry()
         states = ssd_state_bytes(self.params, self.mesh)
         r.gauge("hbnlp_ssd_state_bytes",
-                "per-device bytes of layer mamba's float32 chunk states "
-                "alive at once for the backward").set(states)
+                "per-device bytes of the recurrent mixers' (mamba, "
+                "gated_delta) chunk states alive at once for the backward"
+                ).set(states)
         conv_layers = conv_kernel_layers(self.params)
         r.gauge("hbnlp_mamba_conv_kernel_layers",
-                "mamba layers of the built step whose conv is the Pallas "
-                "kernel pair (0 on the XLA fallback)").set(conv_layers)
+                "recurrent mixers (mamba, gated_delta) of the built step "
+                "whose conv is the Pallas kernel pair (0 on the XLA "
+                "fallback)").set(conv_layers)
         nbytes = r.gauge("hbnlp_remat_stash_bytes",
                          "per-device bytes riding the memory strategy's "
                          "residuals instead of being replayed", ("kind",))
@@ -676,8 +689,8 @@ class Trainer:
             return state, metrics
 
     def _publish_layer_stats(self, metrics) -> None:
-        """``hbnlp_moe_load_max_over_mean``, ``hbnlp_moe_routed_pairs_total``
-        and ``hbnlp_ssd_log_decay_min`` (under ``telemetry_enabled``: only
+        """``hbnlp_moe_load_max_over_mean``, ``hbnlp_moe_routed_pairs_total``,
+        ``hbnlp_ssd_log_decay_min`` and ``hbnlp_delta_transform_abs_max`` (under ``telemetry_enabled``: only
         then does the step report them) from the scalars of EARLIER steps
         the device has finished; a step still running is left for a later
         call, so this never waits.  The last steps of a run stay unread."""

@@ -87,6 +87,33 @@ def kernel_matches_the_shifted_multiplies_test(tiles, taps, dtype, s, cap,
                                    atol=1e-5 * float(np.abs(r).max()))
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def a_conv_without_a_bias_test(tiles, dtype):
+    """``bias`` None (layer ``gated_delta``'s conv): the kernels run with a
+    zero bias, bit for bit; against the shifted multiplies the forward is
+    within an ulp (XLA starts its sum at the first tap, the kernel at the
+    zero), and no cotangent comes back for the bias."""
+    tiles(128)
+    x, w, _, g = _inputs(2, 256, 256, 4, dtype)
+    no_bias = lambda f: lambda x, w: f(x, w, None)  # noqa: E731
+    got, pull = jax.vjp(no_bias(_kernel), x, w)
+    want, pull_ref = jax.vjp(jax.jit(no_bias(_reference)), x, w)
+    ulp = 2.0 ** -7 if dtype == jnp.bfloat16 else 2.0 ** -21
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), rtol=ulp,
+                               atol=2.0 ** -21)
+    (dx, dw), (dx_r, dw_r) = pull(g), pull_ref(g)
+    dx_r = np.asarray(dx_r, np.float32)
+    np.testing.assert_allclose(np.asarray(dx, np.float32), dx_r, rtol=ulp,
+                               atol=2.0 ** -21 * np.abs(dx_r).max())
+    np.testing.assert_allclose(np.asarray(dw), np.asarray(dw_r), rtol=2e-5,
+                               atol=1e-5 * float(np.abs(dw_r).max()))
+    # with a zero bias: the same program
+    zero = jnp.zeros((256,), jnp.float32)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(_kernel(x, w, zero), np.float32))
+
+
 def halo_rows_are_the_neighbours_test(tiles):
     """An impulse on a tile's last row reaches the next tile's first K - 1
     outputs, and its gradient comes back across the same edge."""

@@ -406,6 +406,39 @@ def conv_kernels_keep_their_scope_and_names_test(v5e, monkeypatch):
         and "mamba_conv_bwd" in forms[1]
 
 
+def delta_layers_conv_is_the_same_kernel_pair_test(v5e, monkeypatch):
+    """One ``gated_delta`` layer at Olmo-Hybrid's published widths, 1 x 8,192
+    tokens (half the cell's), compiled for a v5e as a TPU process traces it: the bias-free conv
+    over 11,520 channels is the same Pallas pair in its three forms, every
+    one folds into ``body/gated_delta/conv``, and the rule brings no custom
+    call of its own."""
+    import re
+    from benchmark.lib.cell import load_cell
+    from homebrewnlp_tpu.model import remat
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cell = load_cell("train_olmo_hybrid_7b_long").model_config()
+    assert cell["block_config"][0]["layer"][0] == "gated_delta"
+    params = ModelParameter({**cell, "block_config": cell["block_config"][:1],
+                             "vocab_size": 512, "sequence_length": 8192,
+                             "model_path": "/tmp/olmo"})
+    assert remat.conv_kernel_layers(params) == 1
+    model = Model(params)
+    batch = {k: np.zeros((1, 8192, 1), np.int32)
+             for k in ("token_x", "token_y")}
+    variables = model.init(batch, seed=1)
+    avals = [{k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=v5e)
+              for k, v in tree.items()} for tree in (variables, batch)]
+    hlo = jax.jit(jax.value_and_grad(
+        lambda v, b: model.apply(v, b).total_loss.data)).lower(
+        *avals).compile().as_text()
+    calls = re.findall(r'%([\w.-]+) = [^\n]*?custom_call_target='
+                       r'"tpu_custom_call"[^\n]*?op_name="([^"]+)"', hlo)
+    assert sorted(re.sub(r"\.\d+$", "", name) for name, _ in calls) \
+        == ["mamba_conv_bwd", "mamba_conv_fwd", "mamba_conv_fwd"]
+    for _, op_name in calls:
+        assert scope_key(op_name) == "body/gated_delta/conv", op_name
+
+
 def experts_rule_declines_without_a_moe_layer_test():
     """``checkpoint`` with no ``moe`` layer: nothing rides, the policy stays
     the named one, and the chunk states' gauge counts one layer's."""
