@@ -429,17 +429,18 @@ def _checkpoint_policy(params: ModelParameter, mesh=None):
     """The ``jax.checkpoint`` policy for the 'checkpoint' strategy: the
     named one (``gradient_checkpointing_policy``; the default
     "nothing_saveable" is jax.checkpoint's own default, so reference
-    configs are unchanged) and, where model/remat.py's ``experts`` kind
-    rides, also layer ``moe``'s named outputs (model/moe.py
-    ``SAVED_NAMES``)."""
+    configs are unchanged) and, where a kind of model/remat.py rides it
+    (``stash_names``: ``experts`` — layer ``moe``'s named outputs —
+    ``recurrent`` — the output a recurrent mixer offers), also those
+    names."""
     named = getattr(jax.checkpoint_policies,
                     params.gradient_checkpointing_policy)
-    from .remat import stash_plan
-    if not stash_plan(params, mesh)["experts"][0]:
+    from .remat import stash_names
+    names = stash_names(params, mesh)
+    if not names:
         return named
-    from .moe import SAVED_NAMES
     return jax.checkpoint_policies.save_from_both_policies(
-        named, jax.checkpoint_policies.save_only_these_names(*SAVED_NAMES))
+        named, jax.checkpoint_policies.save_only_these_names(*names))
 
 
 def _merge_stats(parts) -> dict:

@@ -61,7 +61,13 @@ before they cancel, and float32 returns garbage.)  Decays, cumulative
 sums, ``beta``, the solve and the carried state are float32; the other
 matmuls take the calculation dtype with float32 accumulation.  Autodiff gives
 the backward, a group of heads at a time (``grouped_rule``): what it keeps of
-the rule over all heads at once does not fit a chip at 16,384 tokens.
+the rule over all heads at once does not fit a chip at 16,384 tokens.  Under
+the ``checkpoint`` strategy a step therefore runs the rule forward TWICE and
+backward once — the step's forward and the group's own re-materialisation —
+where the block's ``jax.checkpoint`` saves the rule's output (``SAVED_NAMES``;
+model/remat.py's ``recurrent`` kind, 189 MB a layer at 16,384 tokens), and
+three times where it does not: the block's replay then runs it once more, for
+nothing but ``o``.
 
 Training and full-sequence forward on one device; a decode / prefill form (a
 state and a conv window per sequence) is a later issue.
@@ -73,6 +79,7 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from ..config import BlockArgs, ModelParameter
 from ..core import scope
@@ -90,6 +97,16 @@ L2_EPS = 1e-6
 #: the most bytes of one ``[b, chunks, heads, chunk, chunk]`` float32 matrix a
 #: group of heads may have (``grouped_rule``): 10 of 30 heads at 16,384 tokens
 GROUP_BYTES = 48 << 20
+#: the name layer ``gated_delta`` gives the rule's output ``o [b, s, heads,
+#: d_v]`` (``checkpoint_name``; free where no policy names it).  Under the
+#: ``checkpoint`` strategy the block's ``jax.checkpoint`` saves it where
+#: model/remat.py's ``recurrent`` kind rides (model/blocks.py
+#: ``_checkpoint_policy``): the block's replay then runs no forward of the
+#: rule — the gate norm and the out-projection differentiate through the
+#: saved ``o``, and the rule's own backward makes everything it needs again
+#: from ``q, k, v, beta, g`` inside ``grouped_rule``'s ``jax.checkpoint``.
+#: ``transform_max`` is not named: the replay needs no statistic.
+SAVED_NAMES = ("gated_delta_out",)
 
 
 def _dot(a, b):
@@ -222,7 +239,10 @@ def grouped_rule(q, k, v, beta, g, chunk: int):
     the scan's float32 states of whatever it differentiates at once, 6.6 GiB
     a layer over 30 heads at 16,384 tokens, and heads are independent.  A
     group is the most heads (a divisor of all) whose one such matrix stays
-    within ``GROUP_BYTES``; where that is all of them, one group."""
+    within ``GROUP_BYTES``; where that is all of them, one group.  The
+    group's backward needs nothing of an enclosing forward but ``q, k, v,
+    beta, g``: an enclosing ``jax.checkpoint`` that saves the output
+    (``SAVED_NAMES``) replays none of this."""
     bsz, s, h, _ = q.shape
     group = _group_heads(bsz, s, h, chunk)
 
@@ -304,6 +324,7 @@ def gated_delta(args: BlockArgs) -> NamedTensor:
         o, transform_max = grouped_rule(
             q, key, qkv[..., 2 * d_key:].reshape(bsz, s, h, dv), beta, g,
             chunk)
+        o = checkpoint_name(o, SAVED_NAMES[0])
     if ctx.layer_stats is not None:
         ctx.layer_stats.append({"delta_transform_abs_max": transform_max})
     with jax.named_scope("gate_norm"):
@@ -341,4 +362,13 @@ def _conv(params: ModelParameter):
             params.delta_conv_size, 0)
 
 
-gated_delta.recurrent = Recurrent(_state_bytes, _conv)
+def _output_bytes(params: ModelParameter) -> int:
+    """The rule's output ``[batch, sequence, delta_heads,
+    delta_value_features]`` in the calculation dtype: ``SAVED_NAMES``."""
+    return params.batch_dim.size * params.sequence_dim.size \
+        * params.delta_heads * params.delta_value_features \
+        * jnp.dtype(params.calculation_dtype).itemsize
+
+
+gated_delta.recurrent = Recurrent(_state_bytes, _conv, SAVED_NAMES,
+                                  _output_bytes)

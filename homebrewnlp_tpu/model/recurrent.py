@@ -1,7 +1,8 @@
 """What the recurrent mixers (layers ``mamba`` and ``gated_delta``) share:
 what they refuse and the layout they take, the per-channel float32
 parameters and their initialisers, the causal depthwise conv's XLA form, and what a layer declares of itself for
-``model/remat.py`` (its chunk states, its conv)."""
+``model/remat.py`` (its chunk states, its conv, the output it offers to save
+across the block's replay)."""
 from __future__ import annotations
 
 import typing
@@ -18,9 +19,25 @@ class Recurrent(typing.NamedTuple):
     """Set as ``<layer function>.recurrent``: ``state_bytes(params)`` — the
     bytes of chunk states one layer keeps for its backward, for the whole
     batch — and ``conv(params)`` — ``(channels, taps, offset)`` of its causal
-    depthwise conv, as ``parallel/causal_conv.kernel_applies`` takes them."""
+    depthwise conv, as ``parallel/causal_conv.kernel_applies`` takes them.
+
+    A layer that re-materialises its own interior in the backward also
+    OFFERS ITS OUTPUT to the ``checkpoint`` strategy (``model/remat.py``'s
+    ``recurrent`` kind): ``saved_names`` — what it tags with
+    ``jax.ad_checkpoint.checkpoint_name`` — and ``saved_bytes(params)`` —
+    their bytes for the whole batch.  Where the block's ``jax.checkpoint``
+    saves them, the replay runs no forward of the recurrence: everything its
+    backward needs the layer's own ``jax.checkpoint`` makes again from the
+    recurrence's inputs.  ``gated_delta`` offers the rule's output (``batch x
+    sequence x delta_heads x delta_value_features`` in the calculation
+    dtype).  ``mamba`` offers nothing: its scan has no inner
+    ``jax.checkpoint``, so the replay's forward IS the pass that makes the
+    backward's residuals, and a saved output would skip none of it."""
     state_bytes: typing.Callable[[ModelParameter], int]
     conv: typing.Callable[[ModelParameter], typing.Tuple[int, int, int]]
+    saved_names: typing.Tuple[str, ...] = ()
+    saved_bytes: typing.Optional[
+        typing.Callable[[ModelParameter], int]] = None
 
 
 def token_layout(args: BlockArgs, layer: str, chunk: int):

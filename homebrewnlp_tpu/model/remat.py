@@ -26,7 +26,13 @@ policy          behavior
                 outputs and its routing triple — is SAVED by each block's
                 ``jax.checkpoint`` through a policy over named values
                 (``_checkpoint_policy``), so the replay runs none of the
-                three forward grouped matmuls, 3 of a layer's 12 (PR 29)
+                three forward grouped matmuls, 3 of a layer's 12 (PR 29).
+                The fourth, ``recurrent``, rides the same policy: the
+                OUTPUT a recurrent mixer offers because it re-materialises
+                its own interior (``model/recurrent.py`` ``Recurrent``:
+                layer ``gated_delta``'s rule, a group of heads at a time) —
+                the replay then runs no forward of the recurrence, two
+                forwards of the rule a step instead of three (PR 33)
 ``save``        NO ``custom_vjp``: the identical primal recurrence under
                 native scan AD; every linearization residual is saved —
                 zero recompute, O(depth) residual memory
@@ -66,9 +72,17 @@ classification this resolver keys on.  ``auto`` therefore picks:
    of it under ``checkpoint``).  All layers or none: OLMoE-1B-7B at depth
    2 on 8,192 tokens is 1.07 GB of ~2.5 and rides, at its published depth
    16 it is 8.6 GB and the rule declines; saving some layers only is a
-   later issue.  The legacy boolean ``true`` forces the attention kind
-   only (its name; the other kinds still resolve by their rules),
-   ``false`` is "recompute";
+   later issue.  ``recurrent`` is decided AFTER it, from what ``experts``
+   leaves of that 15%: strategy ``checkpoint``, at least one layer that
+   DECLARES an output to save (``Recurrent.saved_names`` / ``saved_bytes``;
+   the resolver tests no layer's and no model's name) and the whole
+   depth's declared bytes within what is left — all layers or none.
+   Olmo-Hybrid-7B's period of four layers on 16,384 tokens is 3 x 189 MB =
+   566 MB of ~2.5 GB and rides; layer ``mamba`` declares nothing (its scan
+   has no inner ``jax.checkpoint``: the replay's forward is the pass that
+   makes its backward's residuals).  The legacy boolean ``true`` forces
+   the attention kind only (its name; the other kinds still resolve by
+   their rules), ``false`` is "recompute";
 4. else ``recompute``.  The save modes stay measured OPT-INS: the A/B
    lost on the rig, the committed ledger classifies every body scope
    hbm-bound (residual round-trips are the expensive direction there),
@@ -101,9 +115,10 @@ SAVE_RESIDUALS_PER_BLOCK = 16
 POLICIES = ("recompute", "stash", "save", "save_dots")
 #: what a memory strategy can keep for its backward under "stash":
 #: ``attention`` and ``bottleneck`` ride the revnet / momentum residuals (the
-#: channel's kinds, model/blocks.py ``stash_channel``), ``experts`` the
-#: ``checkpoint`` strategy's ``jax.checkpoint`` (``_checkpoint_policy``)
-STASH_KINDS = ("attention", "bottleneck", "experts")
+#: channel's kinds, model/blocks.py ``stash_channel``), ``experts`` and
+#: ``recurrent`` the ``checkpoint`` strategy's ``jax.checkpoint``
+#: (``_checkpoint_policy``)
+STASH_KINDS = ("attention", "bottleneck", "experts", "recurrent")
 
 
 def _mesh_geometry(params: ModelParameter, mesh):
@@ -192,6 +207,17 @@ def _experts_stash(params: ModelParameter, shards: int
                      // shards)
 
 
+def _recurrent_stash(params: ModelParameter, shards: int
+                     ) -> typing.Tuple[int, int]:
+    """``(layers, per-device bytes)`` of the recurrent kind over the whole
+    depth: what every recurrent mixer that offers its output DECLARES
+    (``Recurrent.saved_bytes``, for the whole batch)."""
+    offers = [spec.saved_bytes(params) for spec in _recurrent_layers(params)
+              if spec.saved_names]
+    return len(offers) * params.depth, -(
+        -sum(offers) * params.depth * max(1, params.macro_batching) // shards)
+
+
 def _save_residual_bytes(params: ModelParameter) -> int:
     """Global estimate of the native-AD linearization residuals the save
     policy keeps: f32 activation-sized intermediates per block part,
@@ -225,6 +251,7 @@ def remat_report(params: ModelParameter, mesh=None) -> typing.Dict[str, typing.A
     peak, bw = peak_flops(device), peak_hbm_bandwidth(device)
     layers, bottleneck_bytes, crosses = _bottleneck_stash(params, mesh)
     experts_layers, experts_bytes = _experts_stash(params, shards)
+    recurrent_layers, recurrent_bytes = _recurrent_stash(params, shards)
     return {
         "stash_bytes_per_device": -(-_stash_bytes(params) // shards),
         "bottleneck_stash_layers": layers,
@@ -232,6 +259,8 @@ def remat_report(params: ModelParameter, mesh=None) -> typing.Dict[str, typing.A
         "bottleneck_crosses_model_axis": crosses,
         "experts_stash_layers": experts_layers,
         "experts_stash_bytes_per_device": experts_bytes,
+        "recurrent_stash_layers": recurrent_layers,
+        "recurrent_stash_bytes_per_device": recurrent_bytes,
         "save_residual_bytes_per_device":
             -(-_save_residual_bytes(params) // shards),
         "hbm_bytes": hbm,
@@ -261,7 +290,8 @@ def stash_kinds(params: ModelParameter, mesh=None) -> typing.FrozenSet[str]:
     docstring's item 3).  The attention rule is the historical one and is
     decided FIRST: the bottleneck kind only gets what it leaves of the
     budget, so adding that kind moved no configuration's attention
-    decision; the experts kind is decided LAST and moves neither."""
+    decision; the experts kind is decided after them and moves neither;
+    the recurrent kind is decided LAST, from what experts leaves."""
     explicit = _explicit_policy(params)
     if explicit is not None:
         return frozenset(STASH_KINDS if explicit == "stash" else ())
@@ -278,10 +308,13 @@ def stash_kinds(params: ModelParameter, mesh=None) -> typing.FrozenSet[str]:
         kinds.add("bottleneck")
     # the whole budget: what the two kinds above name rides the revnet /
     # momentum residuals, so under "checkpoint" they hold no byte of it
-    if params.memory_reduction_strategy == "checkpoint" \
-            and 0 < rep["experts_stash_bytes_per_device"] \
-            <= rep["stash_budget_bytes"]:
-        kinds.add("experts")
+    if params.memory_reduction_strategy == "checkpoint":
+        budget = rep["stash_budget_bytes"]
+        if 0 < rep["experts_stash_bytes_per_device"] <= budget:
+            kinds.add("experts")
+            budget -= rep["experts_stash_bytes_per_device"]
+        if 0 < rep["recurrent_stash_bytes_per_device"] <= budget:
+            kinds.add("recurrent")
     return frozenset(kinds)
 
 
@@ -311,8 +344,9 @@ def stash_plan(params: ModelParameter, mesh=None
     no way to keep it, a pipeline mesh, an explicit policy, a rule that
     declined, no such layer).  ``Trainer`` publishes it as
     ``hbnlp_remat_stash_bytes{kind}`` / ``hbnlp_remat_stash_layers{kind}``
-    (docs/OBSERVABILITY.md); ``_checkpoint_policy`` saves the experts
-    kind's names exactly where this says it rides."""
+    (docs/OBSERVABILITY.md); ``_checkpoint_policy`` saves the experts and
+    the recurrent kind's names exactly where this says they ride
+    (:func:`stash_names`)."""
     from ..core.sharding import PIPE_AXIS
     plan = {kind: (0, 0) for kind in STASH_KINDS}
     strategy = params.memory_reduction_strategy
@@ -322,9 +356,10 @@ def stash_plan(params: ModelParameter, mesh=None
     kinds = stash_kinds(params, mesh)
     rep = remat_report(params, mesh)
     if strategy == "checkpoint":
-        if "experts" in kinds and rep["experts_stash_layers"]:
-            plan["experts"] = (rep["experts_stash_layers"],
-                               rep["experts_stash_bytes_per_device"])
+        for kind in ("experts", "recurrent"):
+            if kind in kinds and rep[f"{kind}_stash_layers"]:
+                plan[kind] = (rep[f"{kind}_stash_layers"],
+                              rep[f"{kind}_stash_bytes_per_device"])
         return plan
     if "attention" in kinds:
         layers = _attention_sites(params, mesh) * params.depth
@@ -345,6 +380,23 @@ def _recurrent_layers(params: ModelParameter):
     found = (getattr(LAYER_FUNCTIONS.get(name), "recurrent", None)
              for name, _ in _layers(params))
     return [spec for spec in found if spec is not None]
+
+
+def stash_names(params: ModelParameter, mesh=None) -> typing.Tuple[str, ...]:
+    """The ``checkpoint_name``s the ``checkpoint`` strategy's
+    ``jax.checkpoint`` saves beside its named policy: those of every kind
+    :func:`stash_plan` says rides it — layer ``moe``'s (model/moe.py
+    ``SAVED_NAMES``), then what the recurrent mixers declare, in execution
+    order.  Empty where none does."""
+    plan = stash_plan(params, mesh)
+    names = []
+    if plan["experts"][0]:
+        from .moe import SAVED_NAMES
+        names += SAVED_NAMES
+    if plan["recurrent"][0]:
+        names += [name for spec in _recurrent_layers(params)
+                  for name in spec.saved_names]
+    return tuple(dict.fromkeys(names))     # a name once, in order
 
 
 def ssd_state_bytes(params: ModelParameter, mesh=None) -> int:

@@ -450,13 +450,17 @@ def experts_rule_declines_without_a_moe_layer_test():
     assert params.memory_reduction_strategy == "checkpoint"
     assert remat.stash_plan(params)["experts"] == (0, 0)
     assert "experts" not in remat.stash_kinds(params)
+    # layer mamba offers no output to save (PR 33): the recurrent kind too
+    assert remat.stash_plan(params)["recurrent"] == (0, 0)
+    assert "recurrent" not in remat.stash_kinds(params)
     assert _checkpoint_policy(params) \
         is jax.checkpoint_policies.nothing_saveable
     # [2, 64 / 16, 4, 8, 16] float32
     assert remat.ssd_state_bytes(params) == 2 * 4 * 4 * 8 * 16 * 4
     line = Trainer(params, model).publish_stash_plan()
-    assert line.endswith("experts 0 layers, 0 bytes a device; ssd chunk "
-                         "states 16384 bytes a device; conv kernel 0 layers")
+    assert line.endswith("experts 0 layers, 0 bytes a device; recurrent 0 "
+                         "layers, 0 bytes a device; ssd chunk states 16384 "
+                         "bytes a device; conv kernel 0 layers")
     snap = telemetry.registry().snapshot()
     assert snap["hbnlp_ssd_state_bytes"]["series"][()] == 16384
     assert snap["hbnlp_mamba_conv_kernel_layers"]["series"][()] == 0

@@ -455,10 +455,11 @@ def traced_ops_carry_the_rules_steps_test():
 
 
 def remat_rules_count_the_new_layer_test():
-    """``checkpoint`` with no ``moe`` layer: nothing rides; the chunk states'
-    gauge counts one layer's (the largest of the declared), every layer's
-    under ``none``; the conv gauge counts the three bias-free convs where the
-    kernel takes them."""
+    """``checkpoint`` with no ``moe`` layer: the experts kind rides nothing
+    (and under ``"recompute"`` nothing does); the chunk states' gauge counts
+    one layer's (the largest of the declared), every layer's under ``none``;
+    the conv gauge counts the three bias-free convs where the kernel takes
+    them."""
     from homebrewnlp_tpu import telemetry
     from homebrewnlp_tpu.model.blocks import _checkpoint_policy
     from homebrewnlp_tpu.train import Trainer
@@ -466,14 +467,18 @@ def remat_rules_count_the_new_layer_test():
     assert params.memory_reduction_strategy == "checkpoint"
     assert remat.stash_plan(params)["experts"] == (0, 0)
     assert "experts" not in remat.stash_kinds(params)
+    # the rule's output of three layers, [2, 64, 3, 16] float32 each (PR 33)
+    assert remat.stash_plan(params)["recurrent"] == (3, 3 * 24576)
+    _, params, model, _, _ = _build("float32", remat_policy="recompute")
     assert _checkpoint_policy(params) \
         is jax.checkpoint_policies.nothing_saveable
     # [2, 64 / 16, 3, 16, 8] in the calculation dtype, here float32
     assert remat.ssd_state_bytes(params) == 2 * 4 * 3 * 16 * 8 * 4 == 12288
     assert remat.conv_kernel_layers(params, "tpu") == 0     # 96 channels
     line = Trainer(params, model).publish_stash_plan()
-    assert line.endswith("experts 0 layers, 0 bytes a device; ssd chunk "
-                         "states 12288 bytes a device; conv kernel 0 layers")
+    assert line.endswith("experts 0 layers, 0 bytes a device; recurrent 0 "
+                         "layers, 0 bytes a device; ssd chunk states 12288 "
+                         "bytes a device; conv kernel 0 layers")
     snap = telemetry.registry().snapshot()
     assert snap["hbnlp_ssd_state_bytes"]["series"][()] == 12288
     _, none, _, _, _ = _build("float32", memory_reduction_strategy="none")
@@ -549,3 +554,82 @@ def step_reports_the_transform_watch_test():
     assert all(np.isfinite(losses)) and losses[-1] < losses[0]
     snap = telemetry.registry().snapshot()
     assert 0.5 < snap["hbnlp_delta_transform_abs_max"]["series"][()] < 10
+
+
+# ---- the recurrent kind (PR 33): the rule's output rides the block's
+# jax.checkpoint, so the replay runs no forward of the rule ---------------------
+
+def _forward_carries(jaxpr, found=None, path=""):
+    """The forward ``inter_chunk`` scans of a jaxpr, through every nested
+    one (``checkpoint``, ``lax.map``, the scan over the depth): one a forward
+    of the rule over a group of heads."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        here = f"{path}/{eqn.source_info.name_stack}"
+        if eqn.primitive.name == "scan" and "inter_chunk" in here \
+                and not eqn.params["reverse"]:
+            found.append(here)
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _forward_carries(sub, found, here)
+    return found
+
+
+@pytest.mark.parametrize("groups", [1, 3])
+@pytest.mark.parametrize("scan_layers", [False, True],
+                         ids=["unrolled", "scan_layers"])
+def saved_rule_output_changes_no_bit_test(monkeypatch, scan_layers, groups):
+    """Two periods under ``checkpoint`` with the rule's output saved by each
+    block's ``jax.checkpoint`` (``"auto"``: the bytes fit): the loss and every
+    gradient are bit for bit those of ``remat_policy: "recompute"``, the
+    gradient's jaxpr runs the rule forward twice a ``gated_delta`` layer
+    instead of three times (the step and the group's own re-materialisation;
+    the block's replay no longer), and the gauges read the declared bytes —
+    for the rule over all heads at once and a head a group."""
+    from homebrewnlp_tpu import telemetry
+    from homebrewnlp_tpu.model.blocks import _checkpoint_policy
+    from homebrewnlp_tpu.train import Trainer
+    if groups == 3:
+        # [2, 64] tokens x chunk 16 x float32: one head's matrix
+        monkeypatch.setattr(delta_mod, "GROUP_BYTES", 2 * 64 * 16 * 4)
+    assert 3 // delta_mod._group_heads(2, 64, 3, 16) == groups
+    depth = 2
+    results = {}
+    for policy in ("recompute", "auto"):
+        _, params, model, batch, variables = _build(
+            "float32", depth=depth, scan_layers=scan_layers,
+            remat_policy=policy)
+        fn = jax.value_and_grad(
+            lambda v: model.apply(v, batch).total_loss.data)
+        # the depth's scan traces its three linear layers once
+        layers = 3 if scan_layers else 3 * depth
+        forwards = len(_forward_carries(jax.make_jaxpr(fn)(variables).jaxpr))
+        results[policy] = (params, model, forwards / layers,
+                           jax.jit(fn)(variables))
+    params, model, forwards, (loss, grads) = results["auto"]
+    _, _, before, (want_loss, want) = results["recompute"]
+    assert (before, forwards) == (3, 2)
+    assert float(loss) == float(want_loss) and np.isfinite(float(loss))
+    assert set(grads) == set(want)
+    for name in want:
+        np.testing.assert_array_equal(np.asarray(grads[name]),
+                                      np.asarray(want[name]), err_msg=name)
+    # [2, 64, 3, 16] float32 a layer, three linear layers a period
+    saved = 2 * 64 * 3 * 16 * 4 * 3 * depth
+    assert delta_mod.gated_delta.recurrent.saved_names == ("gated_delta_out",)
+    assert remat.stash_plan(params)["recurrent"] == (3 * depth, saved)
+    assert remat.stash_names(params) == ("gated_delta_out",)
+    assert _checkpoint_policy(params) \
+        is not jax.checkpoint_policies.nothing_saveable
+    line = Trainer(params, model).publish_stash_plan()
+    assert f"recurrent {3 * depth} layers, {saved} bytes a device" in line
+    snap = telemetry.registry().snapshot()
+    assert snap["hbnlp_remat_stash_bytes"]["series"][("recurrent",)] == saved
+    assert snap["hbnlp_remat_stash_layers"]["series"][("recurrent",)] \
+        == 3 * depth
+    off = results["recompute"][0]
+    assert remat.stash_plan(off)["recurrent"] == (0, 0)
+    assert remat.stash_names(off) == ()
+    assert _checkpoint_policy(off) is jax.checkpoint_policies.nothing_saveable

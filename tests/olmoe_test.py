@@ -234,7 +234,8 @@ def saved_expert_outputs_change_no_bit_test(scan):
         engaged = (2, 266304) if policy == "auto" else (0, 0)
         line = Trainer(params, model).publish_stash_plan()
         assert line.endswith(f"experts {engaged[0]} layers, {engaged[1]} "
-                             "bytes a device")
+                             "bytes a device; recurrent 0 layers, 0 bytes a "
+                             "device")
         snap = telemetry.registry().snapshot()
         assert snap["hbnlp_remat_stash_layers"]["series"][("experts",)] \
             == engaged[0]

@@ -209,7 +209,7 @@ def bottleneck_stash_resolver_test(case):
         # 128 sequences a chip x 512 x 512 x bfloat16 x 32 layers
         assert stash_plan(p, mesh) == {
             "attention": (0, 0), "bottleneck": (32, 128 * 512 * 512 * 2 * 32),
-            "experts": (0, 0)}
+            "experts": (0, 0), "recurrent": (0, 0)}
     elif case == "over_budget":
         # the published deployment's share, 256 sequences a chip: 4.3 GB
         # against 15% of the planning figure
@@ -227,7 +227,7 @@ def bottleneck_stash_resolver_test(case):
         p = _flagship(memory_reduction_strategy="checkpoint")
         plan = stash_plan(p, shardlib.build_mesh(p, devices))
         assert plan == {"attention": (0, 0), "bottleneck": (0, 0),
-                        "experts": (0, 0)}
+                        "experts": (0, 0), "recurrent": (0, 0)}
     else:
         # a long-context configuration's attention decision is the same
         # with and without a bottleneck in the block, on one device and on
@@ -273,10 +273,10 @@ def remat_stash_gauges_test(engaged):
     assert got["hbnlp_remat_stash_bytes"] == {
         ("attention",): 0,
         ("bottleneck",): item * params.depth if engaged else 0,
-        ("experts",): 0}
+        ("experts",): 0, ("recurrent",): 0}
     assert got["hbnlp_remat_stash_layers"] == {
         ("attention",): 0, ("bottleneck",): params.depth if engaged else 0,
-        ("experts",): 0}
+        ("experts",): 0, ("recurrent",): 0}
     assert trainer.publish_stash_plan().startswith("remat stash: attention 0")
 
 
@@ -320,7 +320,8 @@ _OLMOE_LAYER = 65536 * (2 * 1024 + 2048) * 2 + (2 * 65536 + 64) * 4
     "no_moe_layer", "revnet", "none", "macro_batching"])
 def experts_stash_resolver_test(case):
     from homebrewnlp_tpu.model.remat import stash_kinds, stash_plan
-    idle = {"attention": (0, 0), "bottleneck": (0, 0), "experts": (0, 0)}
+    idle = {"attention": (0, 0), "bottleneck": (0, 0), "experts": (0, 0),
+            "recurrent": (0, 0)}
     if case == "engaged":
         p = _cell_params("train_olmoe_1b_7b_s4k")
         rep = remat_report(p)
@@ -374,19 +375,29 @@ def experts_stash_resolver_test(case):
         assert stash_plan(p) == idle
 
 
-@pytest.mark.parametrize("cell,kinds,policy,plan", [
-    ("train_32big_mixer_b32", set(), "recompute", {}),
+@pytest.mark.parametrize("cell,kinds,policy,plan,names", [
+    ("train_32big_mixer_b32", set(), "recompute", {}, ()),
     ("train_32big_mixer_dp2tp2", {"bottleneck"}, "stash",
-     {"bottleneck": (32, 2147483648)}),
+     {"bottleneck": (32, 2147483648)}, ()),
     ("train_1b_long_context_s16k", {"attention"}, "stash",
-     {"attention": (8, 2155872256)})])
-def experts_kind_moves_no_other_cell_test(cell, kinds, policy, plan):
+     {"attention": (8, 2155872256)}, ()),
+    ("train_olmoe_1b_7b_s4k", {"attention", "experts"}, "stash",
+     {"experts": (2, 1074790912)},
+     ("moe_gate", "moe_up", "moe_down", "moe_order", "moe_inverse",
+      "moe_sizes")),
+    ("train_granite_4_0_h_micro_long", {"attention"}, "stash", {}, ())])
+def experts_kind_moves_no_other_cell_test(cell, kinds, policy, plan, names):
     """What the three cells without a ``moe`` layer resolved to before the
-    experts kind existed (read off the parent commit), kind for kind and
-    byte for byte."""
+    experts kind existed, and (PR 33) what the five cells without a layer
+    that offers its output resolved to before the recurrent kind existed
+    (read off the parent commit), kind for kind and byte for byte; the
+    ``jax.checkpoint`` policy is the named object itself where the parent's
+    was, and saves layer ``moe``'s names alone where the parent's did."""
     from benchmark.lib.cell import load_cell
     from homebrewnlp_tpu.core import sharding as shardlib
-    from homebrewnlp_tpu.model.remat import stash_kinds, stash_plan
+    from homebrewnlp_tpu.model.blocks import _checkpoint_policy
+    from homebrewnlp_tpu.model.remat import (stash_kinds, stash_names,
+                                             stash_plan)
     p = _cell_params(cell)
     mesh = None
     if load_cell(cell).chips > 1:
@@ -396,7 +407,11 @@ def experts_kind_moves_no_other_cell_test(cell, kinds, policy, plan):
     assert stash_kinds(p, mesh) == kinds
     assert resolve_remat(p, mesh) == policy
     assert stash_plan(p, mesh) == {
-        "attention": (0, 0), "bottleneck": (0, 0), "experts": (0, 0), **plan}
+        "attention": (0, 0), "bottleneck": (0, 0), "experts": (0, 0),
+        "recurrent": (0, 0), **plan}
+    assert stash_names(p, mesh) == names
+    assert (_checkpoint_policy(p, mesh)
+            is jax.checkpoint_policies.nothing_saveable) == (not names)
 
 
 @pytest.mark.parametrize("cell,layers", [
@@ -425,7 +440,7 @@ def experts_stash_line_and_policy_test():
     assert stash_line(stash_plan(p)) == (
         "remat stash: attention 0 layers, 0 bytes a device; bottleneck 0 "
         f"layers, 0 bytes a device; experts 2 layers, {2 * _OLMOE_LAYER} "
-        "bytes a device")
+        "bytes a device; recurrent 0 layers, 0 bytes a device")
     nothing = jax.checkpoint_policies.nothing_saveable
     assert _checkpoint_policy(p) is not nothing
     for kw in ({"remat_policy": "recompute"}, {"depth": 16}):
@@ -435,3 +450,140 @@ def experts_stash_line_and_policy_test():
         "train_olmoe_1b_7b_s4k", depth=16,
         gradient_checkpointing_policy="dots_saveable")) \
         is jax.checkpoint_policies.dots_saveable
+
+
+# ---- the recurrent kind (PR 33): the output a recurrent mixer offers
+# because it re-materialises its own interior (layer gated_delta's rule) is
+# saved by the checkpoint strategy's jax.checkpoint where the whole depth's
+# declared bytes fit what the experts kind leaves ------------------------------
+
+#: the rule's output [1, 16384, 30, 192] in bfloat16, a layer
+_OLMO_LAYER = 16384 * 30 * 192 * 2
+_OLMO = "train_olmo_hybrid_7b_long"
+
+
+def _with_moe(top_k: int):
+    """The Olmo-Hybrid cell with its first MLP made a ``moe`` layer of 64
+    experts as wide as the MLP: 16,384 x top-k pairs x (2 x 11,008 + 3,840)
+    x bfloat16 and the routing triple, for the resolver alone."""
+    blocks = _cell_params(_OLMO).block_config
+    blocks = [{"layer": list(b.layer), "skip": b.skip} for b in blocks]
+    at = next(i for i, b in enumerate(blocks) if b["layer"][0] == "mlp-silu")
+    blocks[at]["layer"][0] = "moe"
+    p = _cell_params(_OLMO, block_config=blocks, experts=64, moe_top_k=top_k)
+    pairs = 16384 * top_k
+    return p, pairs * (2 * 11008 + 3840) * 2 + (2 * pairs + 64) * 4
+
+
+@pytest.mark.parametrize("case", [
+    "engaged", "over_budget", "recompute", "stash", "legacy_false", "revnet",
+    "none", "pipe_mesh", "macro_batching", "experts_leave_room",
+    "experts_leave_none", "experts_decline", "mamba_declares_nothing"])
+def recurrent_stash_resolver_test(case):
+    from homebrewnlp_tpu.model.blocks import _checkpoint_policy
+    from homebrewnlp_tpu.model.remat import (_recurrent_layers, stash_kinds,
+                                             stash_names, stash_plan)
+    idle = {kind: (0, 0) for kind in
+            ("attention", "bottleneck", "experts", "recurrent")}
+    nothing = jax.checkpoint_policies.nothing_saveable
+    if case == "engaged":
+        p = _cell_params(_OLMO)
+        rep = remat_report(p)
+        assert (rep["recurrent_stash_layers"],
+                rep["recurrent_stash_bytes_per_device"]) \
+            == (3, 3 * _OLMO_LAYER) == (3, 566231040)
+        assert 3 * _OLMO_LAYER <= rep["stash_budget_bytes"]
+        assert stash_kinds(p) == {"attention", "recurrent"}
+        assert stash_plan(p) == {**idle, "recurrent": (3, 566231040)}
+        assert stash_names(p) == ("gated_delta_out",)
+        assert _checkpoint_policy(p) is not nothing
+    elif case == "over_budget":
+        # the published depth, eight periods: 4.5 GB, all layers or none
+        p = _cell_params(_OLMO, depth=8)
+        rep = remat_report(p)
+        assert rep["recurrent_stash_bytes_per_device"] == 24 * _OLMO_LAYER \
+            > rep["stash_budget_bytes"]
+        assert "recurrent" not in stash_kinds(p) and stash_plan(p) == idle
+        assert _checkpoint_policy(p) is nothing
+    elif case == "recompute":
+        p = _cell_params(_OLMO, remat_policy="recompute")
+        assert stash_kinds(p) == frozenset() and stash_plan(p) == idle
+        assert _checkpoint_policy(p) is nothing
+    elif case == "stash":
+        # explicit: on whatever the bytes
+        p = _cell_params(_OLMO, remat_policy="stash", depth=8)
+        assert stash_plan(p) == {**idle, "recurrent": (24, 24 * _OLMO_LAYER)}
+    elif case == "legacy_false":
+        p = _cell_params(_OLMO, stash_attention_outputs=False)
+        assert stash_plan(p) == idle and _checkpoint_policy(p) is nothing
+    elif case in ("revnet", "none"):
+        for kw in ({}, {"remat_policy": "stash"}):
+            p = _cell_params(_OLMO, memory_reduction_strategy=case, **kw)
+            assert kw or "recurrent" not in stash_kinds(p)
+            assert stash_plan(p)["recurrent"] == (0, 0)
+            assert stash_names(p) == ()
+    elif case == "pipe_mesh":
+        from homebrewnlp_tpu.core.sharding import PIPE_AXIS
+
+        class Piped:
+            devices = None
+            shape = {PIPE_AXIS: 2}
+
+        for kw in ({}, {"remat_policy": "stash"}):
+            p = _cell_params(_OLMO, **kw)
+            assert stash_plan(p, Piped()) == idle
+            assert _checkpoint_policy(p, Piped()) is nothing
+    elif case == "macro_batching":
+        # five micro-batches hold five sets of outputs: over the budget
+        p = _cell_params(_OLMO, macro_batching=5)
+        rep = remat_report(p)
+        assert rep["recurrent_stash_bytes_per_device"] == 15 * _OLMO_LAYER \
+            > rep["stash_budget_bytes"]
+        assert stash_plan(p) == idle
+    elif case == "experts_leave_room":
+        # decided AFTER experts, from what experts leaves of the same 15%
+        p, experts = _with_moe(2)
+        budget = remat_report(p)["stash_budget_bytes"]
+        assert experts + 3 * _OLMO_LAYER <= budget
+        assert stash_plan(p) == {**idle, "experts": (1, experts),
+                                 "recurrent": (3, 3 * _OLMO_LAYER)}
+        assert stash_names(p) == (
+            "moe_gate", "moe_up", "moe_down", "moe_order", "moe_inverse",
+            "moe_sizes", "gated_delta_out")
+    elif case == "experts_leave_none":
+        p, experts = _with_moe(3)
+        budget = remat_report(p)["stash_budget_bytes"]
+        assert experts <= budget and 3 * _OLMO_LAYER <= budget \
+            < experts + 3 * _OLMO_LAYER
+        assert stash_kinds(p) == {"attention", "experts"}
+        assert stash_plan(p) == {**idle, "experts": (1, experts)}
+        assert stash_names(p) == (
+            "moe_gate", "moe_up", "moe_down", "moe_order", "moe_inverse",
+            "moe_sizes")
+    elif case == "experts_decline":
+        # experts over the budget take none of it
+        p, experts = _with_moe(8)
+        assert experts > remat_report(p)["stash_budget_bytes"]
+        assert stash_plan(p) == {**idle, "recurrent": (3, 3 * _OLMO_LAYER)}
+        assert stash_names(p) == ("gated_delta_out",)
+    else:
+        # nine mamba layers under "checkpoint": recurrent mixers that offer
+        # nothing (no inner jax.checkpoint: a saved output would skip none
+        # of the replay), whatever the policy says
+        for kw in ({}, {"remat_policy": "stash"}):
+            p = _cell_params("train_granite_4_0_h_micro_long", **kw)
+            specs = _recurrent_layers(p)
+            assert len(specs) == 9
+            assert all(s.saved_names == () and s.saved_bytes is None
+                       for s in specs)
+            assert remat_report(p)["recurrent_stash_layers"] == 0
+            assert stash_plan(p) == idle and _checkpoint_policy(p) is nothing
+
+
+def recurrent_stash_line_test():
+    """The start-up line names the kind last, before the chunk states."""
+    from homebrewnlp_tpu.model.remat import stash_line, stash_plan
+    assert stash_line(stash_plan(_cell_params(_OLMO))) == (
+        "remat stash: attention 0 layers, 0 bytes a device; bottleneck 0 "
+        "layers, 0 bytes a device; experts 0 layers, 0 bytes a device; "
+        "recurrent 3 layers, 566231040 bytes a device")
