@@ -21,6 +21,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..config import ModelParameter
+from ..telemetry import memory
 from .dims import Dim
 from .tensor import NamedTensor, nt
 
@@ -85,8 +86,8 @@ def placement_report(variables: typing.Mapping[str, jax.Array],
         holding |= v.sharding.device_set
     local = jax.local_devices()
     idle = [d.id for d in local if d not in holding]
-    in_use = {d.id: (d.memory_stats() or {}).get("bytes_in_use")
-              for d in local}
+    in_use = {d.id: (stats or {}).get("in_use")
+              for d, stats in memory.read(local)}
     line = (f"placement: mesh={dict(mesh.shape) if mesh is not None else None}"
             f" parameter shards on {len(local) - len(idle)}/{len(local)} "
             f"local devices; bytes_in_use={in_use}")

@@ -359,6 +359,7 @@ def train(params: ModelParameter, train_steps: typing.Optional[int] = None,
         print(f"restored checkpoint at step {step}")
     print(shardlib.placement_report(state.variables, mesh), flush=True)
     print(trainer.publish_stash_plan(), flush=True)
+    print(trainer.state_memory_line, flush=True)
 
     compile_s = 0.0
     if is_chief:
@@ -710,6 +711,10 @@ def train(params: ModelParameter, train_steps: typing.Optional[int] = None,
                 # executable from the persistent cache) before it
                 # dispatches; later calls only dispatch
                 compile_s += t1 - t0
+            if trainer.step_memory_line is not None:
+                # left once, by the step that found the program loaded
+                print(trainer.step_memory_line, flush=True)
+                trainer.step_memory_line = None
             if tel_tokens is not None:
                 tel_tokens.inc(tokens_per_step)
             consumed += params.macro_batching
@@ -786,6 +791,8 @@ def train(params: ModelParameter, train_steps: typing.Optional[int] = None,
                     # one device sync, every log_every steps
                     last_metrics = {**last_metrics, **{
                         k: float(v) for k, v in metrics.items()}}
+                    if params.telemetry_enabled:
+                        telemetry.memory.mark("running")
                 # step record at the metric-log cadence (NOT per step —
                 # the float conversions above already paid the sync)
                 flight.record("step", step=step_now,

@@ -1,6 +1,6 @@
 """Telemetry subsystem (docs/OBSERVABILITY.md).
 
-Three small stdlib-only pieces every layer shares:
+Small stdlib-only pieces every layer shares:
 
 * ``registry`` — process-wide Counter/Gauge/Histogram table with labels,
   picklable ``snapshot()`` for IPC, Prometheus text-exposition and JSONL
@@ -10,6 +10,9 @@ Three small stdlib-only pieces every layer shares:
   records, an observation in ``hbnlp_span_seconds``.
 * ``compiles`` — jax's trace / lower / backend-compile / cache-load events
   as counters by phase and function.
+* ``memory`` — the one reader of ``device.memory_stats()``: the chip's
+  memory at the phase marks of a train run, as gauges, spans and
+  flight-recorder events.
 * ``profiler`` — on-demand ``jax.profiler`` capture (SIGUSR2 or
   programmatic) written under ``model_path``.
 
@@ -18,22 +21,21 @@ ZERO registry calls unless ``telemetry_enabled`` is set; rare-event layers
 (storage retries, checkpoint IO, serving decode rounds) record always —
 their cadence is storage/request-bound, never per-step.
 """
-from . import events, tracectx
+from . import events, memory, tracectx
 from .buildinfo import build_info, register_build_info
 from .compiles import install_compile_listener
 from .events import FlightRecorder, RotatingJsonl
 from .profiler import OnDemandProfiler, start_capture
-from .registry import (DEFAULT_BUCKETS, Registry, jsonl_line,
-                       merge_snapshots, prometheus_text, registry,
-                       render_json, set_constant_labels, set_registry,
-                       snapshot, with_labels)
+from .registry import (Registry, jsonl_line, merge_snapshots,
+                       prometheus_text, registry, set_constant_labels,
+                       set_registry, snapshot, with_labels)
 from .spans import SPAN_METRIC, Phase, span
 
 __all__ = [
-    "DEFAULT_BUCKETS", "Registry", "jsonl_line",
-    "merge_snapshots", "prometheus_text", "registry", "render_json",
+    "Registry", "jsonl_line",
+    "merge_snapshots", "prometheus_text", "registry",
     "set_constant_labels", "set_registry", "snapshot", "with_labels",
     "SPAN_METRIC", "Phase", "span", "install_compile_listener",
     "OnDemandProfiler", "start_capture", "build_info", "register_build_info",
-    "events", "tracectx", "FlightRecorder", "RotatingJsonl",
+    "events", "memory", "tracectx", "FlightRecorder", "RotatingJsonl",
 ]

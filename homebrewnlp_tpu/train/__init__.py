@@ -30,6 +30,7 @@ from ..core import sharding as shardlib
 from ..model import Model
 from ..optim import Optimizer
 from ..optim.gradients import MULTI_LOSS_GRADIENTS
+from ..telemetry import memory
 
 Params = typing.Dict[str, jax.Array]
 
@@ -128,6 +129,10 @@ _LAYER_STATS = {
 }
 
 
+#: ``Trainer._loaded_probe`` before the first step is dispatched
+_FIRST_STEP = object()
+
+
 class TrainState(typing.NamedTuple):
     variables: Params
     opt_state: typing.Dict[str, typing.Dict[str, jax.Array]]
@@ -154,6 +159,14 @@ class Trainer:
         self._pending_layer_stats: collections.deque = collections.deque()
         # resolved lazily on the first traced step (warns once on fallback)
         self._grad_allreduce_resolved: typing.Optional[str] = None
+        # the chip's memory (telemetry/memory.py): the start-up line of
+        # init_state's marks, and the one ``step`` leaves here at its mark
+        # (None until then, and on a backend that reports nothing);
+        # ``train()`` prints both.  ``_loaded_probe``: the first step's
+        # loss once that step is dispatched, None once the mark is made
+        self.state_memory_line: str = memory.NOT_REPORTED
+        self.step_memory_line: typing.Optional[str] = None
+        self._loaded_probe: typing.Any = _FIRST_STEP
 
     # -- state -------------------------------------------------------------
     def init_state(self, batch: typing.Dict[str, jax.Array],
@@ -182,6 +195,7 @@ class Trainer:
                     self.params, variables, self.model.param_dims, self.mesh)
             else:
                 variables = {k: jnp.asarray(v) for k, v in variables.items()}
+        memory.mark("params_placed")
         with telemetry.span("setup/opt_init"):
             opt_state = self.optimizer.init(variables)
         step = jnp.asarray(self.params.current_step, jnp.int32)
@@ -197,6 +211,12 @@ class Trainer:
             # every caller waits anyway, so that what the device still owes
             # is not charged to whatever the caller does next
             jax.block_until_ready(state)
+        # parameters + optimizer slots + the caller's first batch, before
+        # anything a caller does next can allocate
+        self.state_memory_line = memory.publish_state(
+            memory.mark("state_ready"),
+            {"params": variables.values(),
+             "opt_slots": jax.tree_util.tree_leaves(opt_state)})
         return state
 
     # -- one micro step ----------------------------------------------------
@@ -684,9 +704,25 @@ class Trainer:
             if self.mesh is not None and not self._batch_placed(batch):
                 batch = shardlib.shard_batch(self.params, batch, self.mesh)
             state, metrics = self._step_fn(state, batch, rng)
+            if self._loaded_probe is not None:
+                self._mark_step_loaded(metrics["loss"])
             if any(k in metrics for k in _LAYER_STATS):
                 self._publish_layer_stats(metrics)
             return state, metrics
+
+    def _mark_step_loaded(self, loss: jax.Array) -> None:
+        """Point ``step_loaded`` of telemetry/memory.py, at the first call
+        that finds the loss of the FIRST step (the one that traced,
+        compiled and loaded the program) ready: the program has run once,
+        so the runtime's reservation is the step's scratch and ``in_use``
+        what the loop keeps.  Like ``_publish_layer_stats`` it never waits;
+        once made, a step pays one ``is not None``."""
+        if self._loaded_probe is _FIRST_STEP:
+            self._loaded_probe = loss
+        elif self._loaded_probe.is_ready():
+            self._loaded_probe = None
+            self.step_memory_line = memory.loaded_line(
+                memory.mark("step_loaded"))
 
     def _publish_layer_stats(self, metrics) -> None:
         """``hbnlp_moe_load_max_over_mean``, ``hbnlp_moe_routed_pairs_total``,

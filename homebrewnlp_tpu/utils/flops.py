@@ -15,6 +15,8 @@ import typing
 import jax
 import numpy as np
 
+from ..telemetry import memory
+
 # Per-chip figures by ``device_kind``.  TPU rows are the published chip
 # figures (Google Cloud TPU documentation; v5e: 197 TFLOP/s bf16, 16 GB HBM
 # at 819 GB/s); int8 peaks are 2x the bf16 ones.  The ``cpu`` rows are
@@ -93,12 +95,9 @@ def hbm_capacity(device: typing.Optional[jax.Device] = None
     lite"``)."""
     if device is None:
         device = jax.devices()[0]
-    try:
-        stats = device.memory_stats()
-    except Exception:  # noqa: BLE001 — AOT topology devices raise here
-        stats = None
-    if stats and stats.get("bytes_limit"):
-        return int(stats["bytes_limit"]), "memory_stats"
+    stats = memory.device_stats(device)
+    if stats and stats.get("limit"):
+        return stats["limit"], "memory_stats"
     kind = "cpu" if device.platform == "cpu" else device.device_kind
     return int(_kind_lookup(HBM_BYTES, device)), f"table:{kind}"
 

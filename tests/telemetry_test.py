@@ -485,10 +485,16 @@ _RARE_SERIES = {telemetry.SPAN_METRIC, "hbnlp_init_values_seconds_total",
                 # set once, when the step is built (PR 27)
                 "hbnlp_remat_stash_bytes", "hbnlp_remat_stash_layers",
                 # likewise (PR 30, PR 31; 0 without a mamba layer)
-                "hbnlp_ssd_state_bytes", "hbnlp_mamba_conv_kernel_layers"}
+                "hbnlp_ssd_state_bytes", "hbnlp_mamba_conv_kernel_layers",
+                # set at the marks of telemetry/memory.py, where the backend
+                # reports memory (PR 34; XLA:CPU: no series)
+                "hbnlp_hbm_bytes", "hbnlp_train_state_bytes"}
 _RARE_SPANS = {"setup/data_first_batch", "setup/model_init",
                "setup/place_params", "setup/opt_init", "setup/init_wait",
-               "train/metric_log", "train/checkpoint_save", "train/eval"}
+               "train/metric_log", "train/checkpoint_save", "train/eval",
+               # once each; memory/running only under telemetry_enabled
+               "memory/params_placed", "memory/state_ready",
+               "memory/step_loaded"}
 _STEP_SPANS = ("train/step_dispatch", "data/next", "data/place")
 
 
@@ -734,11 +740,18 @@ class _CountingRegistry(telemetry.Registry):
         return super()._get_or_create(*args, **kwargs)
 
 
-def telemetry_off_step_makes_no_registry_call_test(tmp_path):
+def telemetry_off_step_makes_no_registry_call_test(tmp_path, monkeypatch):
     """With ``telemetry_enabled`` false a steady-state step — queue wait,
     placement, dispatch — makes no registry call; with it true the same
-    three sites each make one (the control that the counter can see them)."""
+    three sites each make one (the control that the counter can see them).
+    Steady state begins once ``step_loaded`` is marked (telemetry/memory.py:
+    the devices report memory here, so that mark does set its gauges): the
+    step after it pays one ``is not None``."""
+    import jax
+    from memory_marks_test import _report_memory
     from homebrewnlp_tpu.data.inputs import Prefetcher
+    from homebrewnlp_tpu.telemetry import memory
+    _report_memory(monkeypatch)
     for enabled, expected in ((False, 0), (True, 9)):
         counting = _CountingRegistry()
         prev = telemetry.set_registry(counting)
@@ -750,10 +763,14 @@ def telemetry_off_step_makes_no_registry_call_test(tmp_path):
                               telemetry_label="t" if enabled else None)
             try:
                 for _ in range(2):      # warm-up: the compile records
-                    state, _ = trainer.step(
+                    state, metrics = trainer.step(
                         state, trainer.place_batch(next(feed)))
+                    jax.block_until_ready(metrics["loss"])
+                assert trainer._loaded_probe is None
                 before = counting.lookups
                 snap = counting.snapshot()
+                assert ("step_loaded", "in_use") in \
+                    snap[memory.HBM_METRIC]["series"]
                 for _ in range(3):
                     state, _ = trainer.step(
                         state, trainer.place_batch(next(feed)))
