@@ -13,10 +13,11 @@ parallel/flash_attention.py), and interior blocks multiply unmasked.
 Backward under ``jax.custom_vjp``: the op is LINEAR in both operands, so the
 backward is two more blocked contractions —
 ``dval = (bias·mask)ᵀ @ g`` with the mirrored dead-block skip, and
-``dbias = mask · Σ_batch g @ valᵀ`` via a per-(batch·head) partial buffer
-summed outside the kernel (the dq-partial idiom of the flash fused
-backward); the elementwise mask applies to the summed [h, s, t] map, not
-per partial.
+``dbias = mask · Σ_batch g @ valᵀ``, ONE contraction over batch and
+features: the batch is the innermost grid dimension, so a head's [bq, bk]
+block of the [h, s, t] map stays in VMEM for the whole batch sweep,
+accumulates there in float32, takes the elementwise mask where it crosses
+the diagonal, and is written to HBM once.
 
 Dispatch (``mix``): pallas kernel on TPU, fused XLA reference elsewhere;
 ``HBNLP_MAP_MIXER_INTERPRET=1`` forces the kernels in interpret mode
@@ -130,42 +131,47 @@ def _dval_kernel(b_ref, g_ref, dv_ref, acc_ref, *, block_q: int,
         dv_ref[...] = acc_ref[...].astype(dv_ref.dtype)
 
 
-def _dbias_kernel(g_ref, v_ref, dbp_ref, *, block_q: int, block_k: int,
-                  causal: bool):
-    """Per-(batch·head) dbias partials: grid (batch·heads, s blocks,
-    t blocks); each live cell writes g @ valᵀ to its [bq, bk] output block,
-    dead cells zero-fill theirs so the caller's batch sum never reads
-    uninitialised memory.  The elementwise causal mask applies OUTSIDE, on
-    the batch-summed [h, s, t] map — cheaper than per-partial masking."""
+def _dbias_kernel(g_ref, v_ref, db_ref, *, block_q: int, block_k: int,
+                  num_b: int, causal: bool):
+    """dbias = mask · Σ_batch g @ valᵀ: grid (heads, s blocks, t blocks,
+    batch), batch innermost; the [bq, bk] float32 output block does not move
+    during the batch sweep, so it accumulates in VMEM and reaches HBM once.
+    Dead blocks only zero-fill; diagonal-crossing blocks take the
+    elementwise mask on the summed block, before it leaves."""
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
     ki = pl.program_id(2)
+    bi = pl.program_id(3)
 
-    def _write():
-        dbp_ref[...] = jax.lax.dot_general(
+    @pl.when(bi == 0)
+    def _init():
+        db_ref[...] = jnp.zeros_like(db_ref)
+
+    def _acc():
+        db_ref[...] += jax.lax.dot_general(
             g_ref[...], v_ref[...], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     if causal:
-        live, _ = _causal_split(qi, ki, block_q, block_k)
+        live, full = _causal_split(qi, ki, block_q, block_k)
+        pl.when(live)(_acc)
 
-        @pl.when(live)
-        def _live():
-            _write()
-
-        @pl.when(jnp.logical_not(live))
-        def _dead():
-            dbp_ref[...] = jnp.zeros_like(dbp_ref)
+        @pl.when(live & jnp.logical_not(full) & (bi == num_b - 1))
+        def _mask():
+            db_ref[...] = _masked_bias(db_ref, qi, ki, block_q, block_k)
     else:
-        _write()
+        _acc()
 
 
-def _compiler_params():
+def _compiler_params(grid_rank: int = 3,
+                     vmem_limit_bytes: int = _KERNEL_VMEM_BUDGET):
+    """Every kernel here sweeps its innermost grid dimension into one
+    resident block; the dimensions before it own their outputs."""
     from jax.experimental.pallas import tpu as pltpu
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
-        vmem_limit_bytes=_KERNEL_VMEM_BUDGET)
+        dimension_semantics=("parallel",) * (grid_rank - 1) + ("arbitrary",),
+        vmem_limit_bytes=vmem_limit_bytes)
 
 
 def _fwd_impl(bias, v, causal, block_q, block_k, interpret):
@@ -246,32 +252,42 @@ def _bwd_impl(bias, v, g, causal, block_q, block_k, interpret):
         interpret=interpret,
     )(bias, g)
 
+    nb = bh // h
     if causal:
-        def _v_idx(j, kk):
-            return jnp.minimum(kk, (j * bq + bq - 1) // bk)
+        # a dead block repeats the indices of the last live fetch before it
+        # (the last batch row, the frontier t block), so its whole batch
+        # sweep fetches nothing
+        def _row_and_t(hh, j, kk, bb):
+            frontier = (j * bq + bq - 1) // bk
+            return (jnp.where(kk <= frontier, bb, nb - 1) * h + hh,
+                    jnp.minimum(kk, frontier))
     else:
-        def _v_idx(j, kk):
-            return kk
+        def _row_and_t(hh, j, kk, bb):
+            return bb * h + hh, kk
 
-    dbp = pl.pallas_call(
-        functools.partial(_dbias_kernel, block_q=bq, block_k=bk,
+    # the VMEM a call reserves is taken from what XLA can keep there across
+    # it — the map the next forward kernel reads (0.48 ms from VMEM, 0.66
+    # from HBM at the flagship shape) — so ask for the double-buffered
+    # blocks with room for Mosaic's temporaries, never under the compiler's
+    # own default, not for the flat budget
+    blocks = 2 * (bq * bk * 4 + (bq + bk) * f * v.dtype.itemsize)
+    vmem = min(_KERNEL_VMEM_BUDGET, max(4 * blocks, 16 * 1024 * 1024))
+    db = pl.pallas_call(
+        functools.partial(_dbias_kernel, block_q=bq, block_k=bk, num_b=nb,
                           causal=causal),
-        grid=(bh, nq, nk),
+        grid=(h, nq, nk, nb),
         in_specs=[
-            pl.BlockSpec((None, bq, f), lambda i, j, kk: (i, j, 0)),
-            pl.BlockSpec((None, bk, f),
-                         lambda i, j, kk: (i, _v_idx(j, kk), 0))],
-        out_specs=pl.BlockSpec((None, bq, bk), lambda i, j, kk: (i, j, kk)),
-        out_shape=jax.ShapeDtypeStruct((bh, s, t), jnp.float32),
-        compiler_params=_compiler_params(),
+            pl.BlockSpec((None, bq, f),
+                         lambda *i: (_row_and_t(*i)[0], i[1], 0)),
+            pl.BlockSpec((None, bk, f), lambda *i: (*_row_and_t(*i), 0))],
+        out_specs=pl.BlockSpec((None, bq, bk),
+                               lambda hh, j, kk, bb: (hh, j, kk)),
+        out_shape=jax.ShapeDtypeStruct((h, s, t), jnp.float32),
+        compiler_params=_compiler_params(grid_rank=4, vmem_limit_bytes=vmem),
         name="map_mixer_bwd_dbias_causal" if causal
         else "map_mixer_bwd_dbias",
         interpret=interpret,
     )(g, v)
-    db = dbp.reshape(bh // h, h, s, t).sum(0)
-    if causal:
-        db = jnp.where(jnp.arange(s)[:, None] >= jnp.arange(t)[None, :],
-                       db, 0.0)
     return db.astype(bias.dtype), dv
 
 

@@ -253,21 +253,23 @@ def _count_split(jaxpr) -> typing.Tuple[int, int]:
             # full-square convention for causal flash kernels, kept stable
             # round-over-round.  Causal kernels (name carries "causal";
             # grid (batch·heads, a, b) with {a, b} = {q blocks, k blocks}
-            # in either order) additionally report their skipped cells in
-            # ``dead``: live block pairs are the ones overlapping the lower
-            # triangle, sum_j min(b, ceil(j·b/a)) — transpose-symmetric, so
-            # the (i, q, k) and (i, k, q) grids count identically
+            # in either order, or (heads, a, b, batch): every dimension
+            # but a and b multiplies) additionally report their skipped
+            # cells in ``dead``: live block pairs are the ones overlapping
+            # the lower triangle, sum_j min(b, ceil(j·b/a)) —
+            # transpose-symmetric, so the (i, q, k) and (i, k, q) grids
+            # count identically
             body_jaxpr, grid, cells = _pallas_grid(eqn)
             if body_jaxpr is not None:
                 body = _pallas_body_flops(body_jaxpr)
                 total += cells * body
                 name = str(eqn.params.get("name", ""))
-                if "causal" in name and len(grid) == 3 \
+                if "causal" in name and len(grid) >= 3 \
                         and all(isinstance(g, int) for g in grid):
                     a, b = grid[1], grid[2]
                     live = sum(min(b, (j * b + a - 1) // a)
                                for j in range(1, a + 1))
-                    dead += grid[0] * (a * b - live) * body
+                    dead += cells // (a * b) * (a * b - live) * body
     return total, dead
 
 

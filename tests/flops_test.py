@@ -53,3 +53,28 @@ def flops_split_causal_flash_test():
     n = s // blk
     live_frac = (n + 1) / (2 * n)
     assert exec_c == int(full_c * live_frac)
+
+
+def flops_split_map_mixer_batch_sweep_grid_test():
+    """The map mixer's dbias kernel runs a grid (heads, s blocks, t blocks,
+    batch); the counter takes the block pair from the two middle dimensions
+    and every other dimension as a multiplier, so forward + backward at a
+    4 x 4 causal grid of tiles count what the (batch·heads, a, b) grids
+    counted: three contractions of 2·b·h·s²·f = 50,331,648 each in full,
+    and 10 of 16 cells of each executed."""
+    from homebrewnlp_tpu.parallel.map_mixer import map_mixer
+    from homebrewnlp_tpu.utils.flops import forward_flops_split
+
+    h, s, f, b, blk = 2, 512, 16, 3, 128
+    bias = jnp.zeros((h, s, s))
+    v = jnp.zeros((b * h, s, f))
+
+    def fwd_bwd(causal):
+        return jax.grad(lambda bias_, v_: jnp.sum(
+            map_mixer(bias_, v_, causal, blk, blk, True) ** 2),
+            argnums=(0, 1))
+
+    assert forward_flops_split(fwd_bwd(True), bias, v) \
+        == (150_994_944, 94_371_840)
+    assert forward_flops_split(fwd_bwd(False), bias, v) \
+        == (150_994_944, 150_994_944)

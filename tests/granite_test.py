@@ -489,3 +489,31 @@ def step_reports_the_log_decay_watch_test():
     assert all(np.isfinite(losses)) and losses[-1] < losses[0]
     snap = telemetry.registry().snapshot()
     assert snap["hbnlp_ssd_log_decay_min"]["series"][()] < 0
+
+
+@pytest.mark.parametrize("batch,heads", [(32, 8), (128, 4)],
+                         ids=["flagship_chip", "dp2tp2_chip"])
+def map_mixer_backward_compiles_to_one_map_test(v5e, batch, heads):
+    """The map mixer's backward at one chip's share of the two flagship
+    cells (sequence 512, 512 features a head, bfloat16), compiled for a v5e:
+    Mosaic accepts the batch-sweeping dbias kernel, it writes ONE float32
+    ``[heads, 512, 512]`` map, and no per-(batch, head) map is left in the
+    program.  (This file holds the described topology: a second file's
+    fixture would find libtpu taken.)"""
+    import re
+    from homebrewnlp_tpu.parallel import map_mixer as mm
+    s = f = 512
+    bias = jax.ShapeDtypeStruct((heads, s, s), jnp.bfloat16, sharding=v5e)
+    act = jax.ShapeDtypeStruct((batch * heads, s, f), jnp.bfloat16,
+                               sharding=v5e)
+    block = mm.kernel_block(s, cap=512)
+    hlo = jax.jit(lambda bias_, v, g: mm._bwd_impl(
+        bias_, v, g, True, block, block, False)).lower(
+        bias, act, act).compile().as_text()
+    calls = dict(re.findall(r"%(map_mixer_\w+?)(?:\.\d+)? = (\w+\[[\d,]*\])",
+                            hlo))
+    assert calls == {
+        "map_mixer_bwd_dbias_causal": f"f32[{heads},512,512]",
+        "map_mixer_bwd_dval_causal": f"bf16[{batch * heads},512,512]"}, calls
+    assert f"f32[{batch * heads},512,512]" not in hlo
+    assert f"f32[{batch},{heads},512,512]" not in hlo

@@ -121,7 +121,7 @@ def map_mixer_kernel_blocked_causal_test():
             err_msg=f"causal={causal}")
         db_k, dv_k = jax.grad(k_loss, argnums=(0, 1))(bias, vt)
         db_r, dv_r = jax.grad(r_loss, argnums=(0, 1))(bias, v4)
-        # atol 1e-3: the partial-buffer batch sum reorders the f32
+        # atol 1e-3: the kernel's batch sweep reorders the f32
         # accumulation vs the reference einsum (values are O(10-100))
         np.testing.assert_allclose(np.asarray(db_k), np.asarray(db_r),
                                    rtol=1e-4, atol=1e-3,
@@ -150,3 +150,73 @@ def map_mixer_knob_off_is_silent_test(capsys):
     spatial._MAP_MIXER_FALLBACK_SEEN.clear()
     _step(False)
     assert "map-mixer kernel fallback" not in capsys.readouterr().out
+
+
+@pytest.mark.parametrize("s", [128, 512],
+                         ids=["one_tile", "tiles_with_dead_blocks"])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def map_mixer_dbias_sums_the_batch_in_the_kernel_test(s, causal):
+    """The map's gradient out of the kernel that sweeps the batch inside
+    (interpret mode, 128-wide tiles) against the reference's: one tile (the
+    flagship's form: the only block crosses the diagonal), and a 4 x 4 grid
+    of tiles with interior, diagonal and dead blocks.  An odd batch, so a
+    sweep that stopped early or began late shows."""
+    import jax
+    import jax.numpy as jnp
+    from homebrewnlp_tpu.parallel.map_mixer import _xla_reference, map_mixer
+    rng = np.random.default_rng(5)
+    b, h, f = 3, 2, 16
+    bias = jnp.asarray(rng.normal(size=(h, s, s)), jnp.float32)
+    v4 = jnp.asarray(rng.normal(size=(b, s, h, f)), jnp.float32)
+    vt = v4.transpose(0, 2, 1, 3).reshape(b * h, s, f)
+    db_k = jax.grad(lambda bias_: jnp.sum(
+        map_mixer(bias_, vt, causal, 128, 128, True) ** 2))(bias)
+    db_r = jax.grad(lambda bias_: jnp.sum(
+        _xla_reference(bias_, v4, causal) ** 2))(bias)
+    np.testing.assert_allclose(np.asarray(db_k), np.asarray(db_r),
+                               rtol=1e-4, atol=1e-3)
+    if causal:  # dead cells are zeros the kernel wrote, not leftovers
+        assert not np.triu(np.asarray(db_k), 1).any()
+
+
+def _walk_eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs it calls into (the
+    counter's own table, utils/flops.py ``_descend``); kernel bodies are
+    leaves there."""
+    from homebrewnlp_tpu.utils.flops import _descend
+    for eqn in jaxpr.eqns:
+        yield eqn
+        inner = _descend(eqn)
+        if inner is not None:
+            yield from _walk_eqns(inner[0])
+
+
+def map_mixer_backward_holds_no_per_batch_map_test():
+    """Structure of the mixer's backward: the dbias call writes ONE
+    [heads, s, t] map; no value of shape [batch·heads, s, t] (or its
+    unfolded form) exists, and no reduce_sum follows the call."""
+    import jax
+    import jax.numpy as jnp
+    from homebrewnlp_tpu.parallel.map_mixer import map_mixer
+    b, h, s, f = 3, 2, 256, 16
+    bias = jnp.zeros((h, s, s), jnp.float32)
+    vt = jnp.zeros((b * h, s, f), jnp.float32)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda bias_, v_: jnp.sum(
+        map_mixer(bias_, v_, True, 128, 128, True) ** 2), argnums=(0, 1)))(
+        bias, vt)
+    eqns = list(_walk_eqns(jaxpr.jaxpr))
+    calls = {str(e.params["name"]): e for e in eqns
+             if e.primitive.name == "pallas_call"}
+    assert sorted(calls) == ["map_mixer_bwd_dbias_causal",
+                             "map_mixer_bwd_dval_causal",
+                             "map_mixer_fwd_causal"]
+    dbias = calls["map_mixer_bwd_dbias_causal"]
+    assert [tuple(o.shape) for o in dbias.params["out_avals"]] \
+        == [(h, s, s)]
+    assert tuple(dbias.params["grid_mapping"].grid) == (h, 2, 2, b)
+    shapes = {tuple(v.aval.shape) for e in eqns
+              for v in list(e.invars) + list(e.outvars)
+              if hasattr(v.aval, "shape")}
+    assert (b * h, s, s) not in shapes and (b, h, s, s) not in shapes, shapes
+    after = eqns[eqns.index(dbias) + 1:]
+    assert not [e for e in after if e.primitive.name == "reduce_sum"], after
