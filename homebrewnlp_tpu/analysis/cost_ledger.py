@@ -69,7 +69,11 @@ _LAYER_NAMES = frozenset((
     "mlp"))
 #: the parts of layer ``moe`` (model/moe.py), each a scope of its own below
 #: ``body/moe``
-_MOE_PARTS = frozenset(("router", "dispatch", "experts", "combine"))
+_MOE_PARTS = frozenset(("router", "dispatch", "experts", "combine",
+                        "shared"))
+#: the standard attention's per-head output gate (model/spatial.py), a
+#: scope of its own below ``body/attention``
+_ATTENTION_PARTS = frozenset(("gate",))
 #: the parts of layer ``mamba`` (model/mamba.py) below ``body/mamba``; the
 #: scan's own steps (``intra_chunk``, ``chunk_states``, ``inter_chunk``,
 #: ``state_out``) stay inside ``body/mamba/ssd``
@@ -102,7 +106,8 @@ def scope_key(path: str) -> str:
 
     Keys: ``decode/cache_read|cache_write|sampling``, ``optimizer``,
     ``head_loss``, ``input/embed``, ``input``, ``body/<layer>``,
-    ``body/moe/router|dispatch|experts|combine``,
+    ``body/moe/router|dispatch|experts|combine|shared``,
+    ``body/attention/gate``,
     ``body/mamba/in_proj|conv|ssd|gate_norm|out_proj``,
     ``body/gated_delta/in_proj|conv|delta_rule|gate_norm|out_proj``,
     ``output/unembed``,
@@ -124,11 +129,14 @@ def scope_key(path: str) -> str:
             layer = base
         elif layer == "moe" and base in _MOE_PARTS:
             return f"body/moe/{base}"
+        elif layer == "attention" and base in _ATTENTION_PARTS:
+            return f"body/attention/{base}"
         elif layer == "mamba" and base in _MAMBA_PARTS:
             return f"body/mamba/{base}"
         elif layer == "gated_delta" and base in _DELTA_PARTS:
             return f"body/gated_delta/{base}"
-    if phase == "body" and layer is not None:
+    # a leading block (input_block_config) is a body layer that runs once
+    if phase in ("body", "input") and layer is not None:
         return f"body/{layer}"
     if phase == "input":
         return "input/embed" if ("embed" in bases or "gather" in bases) \

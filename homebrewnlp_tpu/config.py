@@ -199,6 +199,26 @@ class ModelParameter:
         # scale (0 = features_per_head ** -0.5)
         self.query_group = 1
         self.attention_scale = 0.0
+        # attention flag "yarn" (rotary positions at YaRN's frequencies,
+        # model/spatial.py yarn_inv_freq): the context's growth factor, the
+        # positions the frequencies were trained on, the turns in them above
+        # which a frequency stays / below which it is divided by the factor,
+        # and what multiplies cos and sin (0 = 0.1 ln(factor) + 1)
+        self.rope_yarn_factor = 1.0
+        self.rope_yarn_original_positions = 4096
+        self.rope_yarn_beta_fast = 32.0
+        self.rope_yarn_beta_slow = 1.0
+        self.rope_yarn_attention_factor = 0.0
+        # layer "moe" (model/moe.py): the experts' width beside the dense
+        # MLP's (0 = intermediate_feed_forward_multiplier x features); which
+        # of the "experts" routed experts THIS layer holds — experts_held
+        # consecutive ones from experts_first (0 held = all of them); the
+        # chosen probabilities renormalised to sum to one, and a scale on them
+        self.expert_width = 0
+        self.experts_held = 0
+        self.experts_first = 0
+        self.moe_norm_topk = False
+        self.moe_route_scale = 1.0
         # layer "mamba" (Mamba-2, model/mamba.py): heads x width of the inner
         # stream, the state's size, the causal depthwise conv's width, and
         # the chunk of the state-space-duality scan
@@ -897,6 +917,27 @@ class ModelParameter:
         if self.query_group < 1 or self.heads % self.query_group:
             raise ValueError(f"query_group {self.query_group} must divide "
                              f"heads {self.heads}")
+        for key in ("expert_width", "experts_held", "experts_first"):
+            if not isinstance(getattr(self, key), int) \
+                    or getattr(self, key) < 0:
+                raise ValueError(f"{key} {getattr(self, key)!r} must be a "
+                                 "whole number >= 0")
+        if self.experts_first + self.experts_held > self.experts:
+            raise ValueError(
+                f"experts_first {self.experts_first} + experts_held "
+                f"{self.experts_held} exceeds experts {self.experts}")
+        if self.experts_first and not self.experts_held:
+            raise ValueError("experts_first without experts_held")
+        if not self.moe_route_scale > 0:
+            raise ValueError(f"moe_route_scale {self.moe_route_scale!r} "
+                             "must be > 0")
+        if self.rope_yarn_factor < 1 or self.rope_yarn_original_positions < 1 \
+                or not (self.rope_yarn_beta_fast > self.rope_yarn_beta_slow
+                        > 0) or self.rope_yarn_attention_factor < 0:
+            raise ValueError(
+                "rope_yarn_*: factor >= 1, original_positions >= 1, "
+                "beta_fast > beta_slow > 0, attention_factor >= 0 (0 = "
+                "0.1 ln(factor) + 1)")
         for key in ("delta_heads", "delta_key_features",
                     "delta_value_features", "delta_chunk"):
             if not isinstance(getattr(self, key), int) \
@@ -1039,6 +1080,9 @@ class ModelParameter:
         self.intermediate = [Dim("intermediate",
                                  int(self.heads * self.key_dim.size
                                      * self.intermediate_feed_forward_multiplier))]
+        # the width of ONE routed (or shared) expert of layer moe
+        self.expert_intermediate = [Dim("intermediate", self.expert_width)] \
+            if self.expert_width else self.intermediate
         self.expert_dim = Dim("experts", self.experts)
         self.macro_batch_dim = Dim("batch", self.train_batch_size * self.macro_batching)
         self.vocab_dim = Dim("vocab", self.vocab_size)

@@ -9,6 +9,27 @@ over the ``experts`` groups of whatever sizes the router made, the rows are
 weighted and summed back per token.  Memory for the dispatch is
 O(tokens * k * features), never O(tokens * experts * capacity).
 
+A layer told which experts it HOLDS (``experts_held`` consecutive ones from
+``experts_first``; 0 = all) is one rank of an expert-parallel group: the
+router keeps its ``experts`` outputs and its ``moe_top_k`` choices a token,
+the layer computes its own experts' part of the sum for the pairs routed to
+them, and what the absent experts would have added is left out (on one chip
+it runs without the exchange that would bring the other ranks' tokens).
+Still dropless, with static shapes: a token's choices are distinct, so at
+most ``min(moe_top_k, experts_held)`` of them are held, and the row buffer
+has that many SLOTS a token — ``held_rows_bound`` rows, which no routing can
+overflow.  Each token's held choices fill its slots from the left, the rest
+hold a sentinel group that sorts last; the grouped matmuls run over the held
+groups' rows only (megablox visits tiles of real rows, the rows past them
+are never written), and neither ``_combine`` nor the dispatch's backward
+reads a sentinel slot's row.
+
+``moe_norm_topk`` renormalises the chosen probabilities to sum to one,
+``moe_route_scale`` multiplies them; flag ``shared_expert`` (the DSL's
+``shared`` is cross-layer weight sharing) adds a shared expert (a
+SwiGLU of the experts' width that every token passes through, weight 1,
+scope ``shared``) to the routed sum.
+
 ``basic.routed_mixture_of_experts`` (one routed linear, capacity-padded
 one-hot dispatch) stays beside it until ROADMAP D7 merges the two.
 """
@@ -38,46 +59,57 @@ from .utils import anonymize_dim
 # know that these indices are permutations, so both directions of both
 # functions are written as gathers, the backward passes by hand.
 
-def _gather_sum(rows, inverse, k: int, weights=None):
-    """``sum_j rows[inverse[t, j]] (* weights[t, j])`` in float32."""
+#
+# ``real`` (``[t, k]`` booleans, or None where every pair is an expert's):
+# the slots of a layer that holds a share of the experts which carry a held
+# choice.  The rows of the others lie past the held groups, where no kernel
+# wrote, so they are selected away and never multiplied.
+
+def _gather_sum(rows, inverse, k: int, weights=None, real=None):
+    """``sum_j rows[inverse[t, j]] (* weights[t, j])`` in float32, over the
+    ``real`` pairs."""
     pairs = rows[inverse].reshape(-1, k, rows.shape[-1]).astype(jnp.float32)
     if weights is not None:
         pairs = pairs * weights[..., None]
+    if real is not None:
+        pairs = jnp.where(real[..., None], pairs, 0.0)
     return jnp.sum(pairs, axis=1)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _dispatch(x, order, inverse, k: int):
+def _dispatch(x, order, inverse, k: int, real=None):
     """Rows of ``x [t, f]`` for the sorted pairs: ``[t * k, f]``."""
     return x[order // k]
 
 
-def _dispatch_fwd(x, order, inverse, k):
-    return x[order // k], inverse
+def _dispatch_fwd(x, order, inverse, k, real=None):
+    return x[order // k], (inverse, real)
 
 
-def _dispatch_bwd(k, inverse, g):
-    return _gather_sum(g, inverse, k).astype(g.dtype), None, None
+def _dispatch_bwd(k, res, g):
+    inverse, real = res
+    return _gather_sum(g, inverse, k, real=real).astype(g.dtype), None, \
+        None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _combine(rows, weights, order, inverse, k: int):
+def _combine(rows, weights, order, inverse, k: int, real=None):
     """``out[t] = sum_j weights[t, j] * rows[inverse[t, j]]``: the sorted
     pairs' rows ``[t * k, f]`` weighted and summed back per token, float32
     sums returned in ``rows``' dtype."""
-    return _gather_sum(rows, inverse, k, weights).astype(rows.dtype)
+    return _gather_sum(rows, inverse, k, weights, real).astype(rows.dtype)
 
 
-def _combine_fwd(rows, weights, order, inverse, k):
-    return _combine(rows, weights, order, inverse, k), \
-        (rows, weights, order, inverse)
+def _combine_fwd(rows, weights, order, inverse, k, real=None):
+    return _combine(rows, weights, order, inverse, k, real), \
+        (rows, weights, order, inverse, real)
 
 
 def _combine_bwd(k, res, g):
-    rows, weights, order, inverse = res
+    rows, weights, order, inverse, real = res
     # in sorted space: every pair's row of g, a gather from [t, f]
     spread = g[order // k]
     flat = weights.reshape(-1)
@@ -85,8 +117,10 @@ def _combine_bwd(k, res, g):
               ).astype(rows.dtype)
     d_flat = jnp.sum(rows.astype(jnp.float32) * spread.astype(jnp.float32),
                      axis=-1)
-    return d_rows, d_flat[inverse].reshape(weights.shape).astype(
-        weights.dtype), None, None
+    d_weights = d_flat[inverse].reshape(weights.shape)
+    if real is not None:
+        d_weights = jnp.where(real, d_weights, 0.0)
+    return d_rows, d_weights.astype(weights.dtype), None, None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -133,11 +167,41 @@ def grouped_dot(lhs, rhs, group_sizes):
                               preferred_element_type=prefer).astype(lhs.dtype)
 
 
-def route(logits, top_k: int):
+def route(logits, top_k: int, norm_topk: bool = False, scale: float = 1.0):
     """Float32 softmax over all experts and its ``top_k`` largest per token:
-    ``(weights [t, k], experts [t, k])``."""
+    ``(weights [t, k], experts [t, k])``; with ``norm_topk`` the chosen
+    probabilities are divided by their sum, and ``scale`` multiplies them
+    (``w_e = scale * p_e / sum_{top-k} p``)."""
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    return jax.lax.top_k(probs, top_k)
+    weights, experts = jax.lax.top_k(probs, top_k)
+    if norm_topk:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    if scale != 1.0:
+        weights = weights * scale
+    return weights, experts
+
+
+def held_rows_bound(tokens: int, top_k: int, held: int) -> int:
+    """Rows of the static dispatch buffer of a layer that holds ``held``
+    experts: ``min(top_k, held)`` slots a token (a token's choices are
+    distinct, so no routing overflows them)."""
+    return tokens * min(top_k, held)
+
+
+def held_slots(weights, experts, first: int, held: int):
+    """A token's choices among experts ``first .. first + held - 1``, moved
+    to the left of ``min(top_k, held)`` slots: ``(weights, local expert,
+    real)``, each ``[t, slots]``; a slot without a held choice has weight 0,
+    expert ``held`` (the sentinel group, which sorts last) and ``real``
+    false."""
+    local = experts - first
+    local = jnp.where((local >= 0) & (local < held), local, held)
+    slots = min(experts.shape[-1], held)
+    pick = jnp.argsort(local, axis=-1, stable=True)[:, :slots]
+    local = jnp.take_along_axis(local, pick, axis=-1)
+    real = local < held
+    return jnp.where(real, jnp.take_along_axis(weights, pick, axis=-1), 0.0), \
+        local, real
 
 
 def sort_pairs(experts, n_experts: int):
@@ -152,12 +216,33 @@ def sort_pairs(experts, n_experts: int):
     return order, inverse, sizes
 
 
+def _shared_expert(args: BlockArgs, act, xf, anon, inter, feats):
+    """The shared expert: ``down(act(gate x) * up x)`` on every token's row
+    of ``xf [t, f]``, three matrices created in the order gate, up, down."""
+    f_sz, i_sz = xf.shape[-1], math.prod(d.size for d in inter)
+    prefer = None if jax.default_backend() == "cpu" else jnp.float32
+
+    def dot(lhs, weight, shape):
+        return jnp.dot(lhs, weight.data.reshape(shape).astype(lhs.dtype),
+                       preferred_element_type=prefer).astype(lhs.dtype)
+
+    gate = dot(xf, normal_var(args, anon + inter), (f_sz, i_sz))
+    up = dot(xf, normal_var(args, anon + inter), (f_sz, i_sz))
+    hidden = act(args(nt(gate, [Dim("_tokens", xf.shape[0]),
+                                Dim("_width", i_sz)]))).data * up
+    return dot(hidden, normal_var(args, inter + feats), (i_sz, f_sz))
+
+
 def moe(args: BlockArgs) -> NamedTensor:
-    """Layer ``moe``: ``experts`` experts of width ``intermediate``,
-    ``moe_top_k`` a token; an activation name as flag (default ``silu``).
-    Parameters, normal(0.02), in creation order: router
-    ``[features, experts]``, gate and up ``[experts, features,
-    intermediate]``, down ``[experts, intermediate, features]``."""
+    """Layer ``moe``: ``experts`` experts of width ``expert_width`` (0 = the
+    dense MLP's ``intermediate``), ``moe_top_k`` a token; an activation name
+    as flag (default ``silu``), ``shared_expert`` for a shared expert beside
+    them.
+    The layer holds ``experts_held`` of them from ``experts_first`` on (0 =
+    all, OLMoE's form).  Parameters, normal(0.02), in creation order: router
+    ``[features, experts]``, gate and up ``[held, features, width]``, down
+    ``[held, width, features]``, then the shared expert's gate, up
+    ``[features, width]`` and down ``[width, features]``."""
     params = args.params
     ctx = scope.current()
     if ctx.decode is not None:
@@ -165,14 +250,22 @@ def moe(args: BlockArgs) -> NamedTensor:
     if ctx.mesh is not None and ctx.mesh.size > 1:
         raise NotImplementedError(
             "layer moe on a mesh (expert-parallel dispatch) is a later issue")
+    unknown = [a for a in args.name_extras
+               if a not in ACTIVATIONS and a != "shared_expert"]
+    if unknown:
+        raise ValueError(f"layer moe does not know flag(s) {unknown} (known: "
+                         "an activation's name, shared_expert)")
     n_exp = params.expert_dim.size
     top_k = min(params.moe_top_k, n_exp)
+    held, first = params.experts_held or n_exp, params.experts_first
+    partial = held < n_exp
+    held_dim = Dim("experts", held) if partial else params.expert_dim
     act = next((ACTIVATIONS[a] for a in args.name_extras if a in ACTIVATIONS),
                ACTIVATIONS["silu"])
 
     feats = list(params.feature_dims)
     anon = [anonymize_dim(d) for d in feats]
-    inter = list(params.intermediate)
+    inter = list(params.expert_intermediate)
     x = args.tensor
     token_dims = [d for d in x.dims if d not in feats]
     t_sz = math.prod(d.size for d in token_dims)
@@ -180,9 +273,9 @@ def moe(args: BlockArgs) -> NamedTensor:
     i_sz = math.prod(d.size for d in inter)
 
     w_router = normal_var(args, anon + [params.expert_dim])
-    w_gate = normal_var(args, [params.expert_dim] + anon + inter)
-    w_up = normal_var(args, [params.expert_dim] + anon + inter)
-    w_down = normal_var(args, [params.expert_dim] + inter + feats)
+    w_gate = normal_var(args, [held_dim] + anon + inter)
+    w_up = normal_var(args, [held_dim] + anon + inter)
+    w_down = normal_var(args, [held_dim] + inter + feats)
 
     xf = transpose_to(x, token_dims + feats).data.reshape(t_sz, f_sz)
     with jax.named_scope("router"):
@@ -194,14 +287,32 @@ def moe(args: BlockArgs) -> NamedTensor:
         if params.train and (wb or wz):
             # one routing group: the balance term is over the step's tokens
             logits = _router_aux_inject(wb, wz, top_k, logits[None])[0]
-        weights, experts = route(logits, top_k)
+        weights, experts = route(logits, top_k, params.moe_norm_topk,
+                                 float(params.moe_route_scale))
+    # a layer that holds a share sorts SLOTS (held_slots), not choices, into
+    # held + 1 groups, the sentinel last; its kernels see the held groups
+    real, slots, groups = None, top_k, n_exp
     with jax.named_scope("dispatch"):
-        order, inverse, sizes = sort_pairs(experts, n_exp)
+        if partial:
+            weights, experts, real = held_slots(weights, experts, first, held)
+            slots, groups = weights.shape[-1], held + 1
+        order, inverse, sizes = sort_pairs(experts, groups)
         order = checkpoint_name(order, "moe_order")
         inverse = checkpoint_name(inverse, "moe_inverse")
         sizes = checkpoint_name(sizes, "moe_sizes")
-        rows = _dispatch(xf, order, inverse, top_k)
-    if ctx.layer_stats is not None:
+        rows = _dispatch(xf, order, inverse, slots, real)
+        if partial:
+            sizes = sizes[:held]
+    if ctx.layer_stats is not None and partial:
+        held_pairs = jnp.sum(sizes).astype(jnp.float32)
+        ctx.layer_stats.append({
+            # over the HELD experts: the busiest one's pairs over their mean
+            "moe_load_max_over_mean":
+                jnp.max(sizes).astype(jnp.float32) * held
+                / jnp.maximum(held_pairs, 1.0),
+            "moe_routed_pairs": jnp.float32(t_sz * top_k),
+            "moe_held_pairs": held_pairs})
+    elif ctx.layer_stats is not None:
         ctx.layer_stats.append({
             # the largest expert's pair count over the mean: 1.0 = balanced
             "moe_load_max_over_mean":
@@ -209,14 +320,17 @@ def moe(args: BlockArgs) -> NamedTensor:
             "moe_routed_pairs": jnp.sum(sizes).astype(jnp.float32)})
     with jax.named_scope("experts"):
         gate = checkpoint_name(grouped_dot(
-            rows, w_gate.data.reshape(n_exp, f_sz, i_sz), sizes), "moe_gate")
+            rows, w_gate.data.reshape(held, f_sz, i_sz), sizes), "moe_gate")
         up = checkpoint_name(grouped_dot(
-            rows, w_up.data.reshape(n_exp, f_sz, i_sz), sizes), "moe_up")
-        hidden = act(args(nt(gate, [Dim("_pairs", t_sz * top_k),
+            rows, w_up.data.reshape(held, f_sz, i_sz), sizes), "moe_up")
+        hidden = act(args(nt(gate, [Dim("_pairs", t_sz * slots),
                                     Dim("_width", i_sz)]))).data * up
         out = checkpoint_name(grouped_dot(
-            hidden, w_down.data.reshape(n_exp, i_sz, f_sz), sizes), "moe_down")
+            hidden, w_down.data.reshape(held, i_sz, f_sz), sizes), "moe_down")
     with jax.named_scope("combine"):
-        out = _combine(out, weights, order, inverse, top_k)
+        out = _combine(out, weights, order, inverse, slots, real)
+    if "shared_expert" in args.name_extras:
+        with jax.named_scope("shared"):
+            out = out + _shared_expert(args, act, xf, anon, inter, feats)
     out = out.reshape([d.size for d in token_dims + feats])
     return transpose_to(nt(out, token_dims + feats), x.dims)

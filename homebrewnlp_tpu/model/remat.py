@@ -195,16 +195,34 @@ def _experts_stash(params: ModelParameter, shards: int
     up ``[pairs, intermediate]``, down ``[pairs, features]``, in the
     calculation dtype — and the routing triple (``order`` and ``inverse``
     ``[pairs]``, ``sizes`` ``[experts]``, int32), ``pairs = tokens x
-    min(moe_top_k, experts)``: model/moe.py ``SAVED_NAMES``."""
+    min(moe_top_k, experts)``: model/moe.py ``SAVED_NAMES``.  A layer that
+    holds a share of the experts saves its whole static buffer:
+    ``moe_held_rows`` rows, ``experts_held + 1`` sizes."""
     layers = sum(name == "moe" for name, _ in _layers(params)) * params.depth
-    pairs = params.batch_dim.size * params.sequence_dim.size \
-        * min(params.moe_top_k, params.expert_dim.size)
-    width = 2 * int(np.prod([d.size for d in params.intermediate])) \
+    held_rows = moe_held_rows(params)
+    pairs = held_rows or (
+        params.batch_dim.size * params.sequence_dim.size
+        * min(params.moe_top_k, params.expert_dim.size))
+    width = 2 * int(np.prod([d.size for d in params.expert_intermediate])) \
         + int(np.prod([d.size for d in params.feature_dims]))
+    groups = params.experts_held + 1 if held_rows else params.expert_dim.size
     per_layer = pairs * width * np.dtype(params.calculation_dtype).itemsize \
-        + (2 * pairs + params.expert_dim.size) * 4
+        + (2 * pairs + groups) * 4
     return layers, -(-per_layer * layers * max(1, params.macro_batching)
                      // shards)
+
+
+def moe_held_rows(params: ModelParameter) -> int:
+    """Rows of the static dispatch buffer of a ``moe`` layer that holds a
+    share of the experts (model/moe.py ``held_rows_bound``) for one micro
+    batch; 0 where no layer holds a share."""
+    if not 0 < params.experts_held < params.expert_dim.size \
+            or not any(name == "moe" for name, _ in _layers(params)):
+        return 0
+    from .moe import held_rows_bound
+    return held_rows_bound(
+        params.batch_dim.size * params.sequence_dim.size,
+        min(params.moe_top_k, params.expert_dim.size), params.experts_held)
 
 
 def _recurrent_stash(params: ModelParameter, shards: int

@@ -172,11 +172,11 @@ def _maybe_flash_attention(args: BlockArgs, dim: Dim, qry: NamedTensor,
     return transpose_to(out_nt, args.tensor.dims)
 
 
-def _flash(ctx, q, k, v, scale: float):
+def _flash(ctx, q, k, v, scale: float, window=None):
     """Causal flash attention on ``[lead, seq, heads, features]`` arrays: the
     kernel itself single-device, per device under shard_map on a data x model
     mesh (batch on 'data', heads on 'model'; the sequence is whole, so local
-    causality is global causality)."""
+    causality is global causality).  ``window``: the kernels' own."""
     from ..parallel.flash_attention import attention as flash
     mesh = ctx.mesh
     if mesh is None:
@@ -186,7 +186,7 @@ def _flash(ctx, q, k, v, scale: float):
         # keeps the plain kernel
         from .blocks import stash_channel
         return flash(q, k, v, scale=scale, causal=True,
-                     stash=stash_channel(ctx, "attention"))
+                     stash=stash_channel(ctx, "attention"), window=window)
     from jax.sharding import PartitionSpec as P
 
     from jax import shard_map
@@ -195,7 +195,8 @@ def _flash(ctx, q, k, v, scale: float):
              shardlib.MODEL_AXIS if shardlib.MODEL_AXIS in mesh.axis_names
              else None, None)
     return shard_map(
-        lambda q_, k_, v_: flash(q_, k_, v_, scale=scale, causal=True),
+        lambda q_, k_, v_: flash(q_, k_, v_, scale=scale, causal=True,
+                                 window=window),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False)(q, k, v)
 
@@ -338,27 +339,101 @@ def cummean(args: BlockArgs) -> NamedTensor:
     return cumsum(args) / (1 + range_(dim, args.tensor.dtype))
 
 
-def rotary(x, theta: float):
+def yarn_inv_freq(theta: float, width: int, factor: float, original: int,
+                  beta_fast: float, beta_slow: float):
+    """YaRN's rotary frequencies for ``width`` rotated features (Peng et
+    al., arXiv:2309.00071; HF ``_compute_yarn_parameters``, ``truncate``
+    true): frequency ``i`` is ``theta ** (-2i / width)`` where it turns more
+    than ``beta_fast`` times in ``original`` positions, that over ``factor``
+    where it turns fewer than ``beta_slow`` times, and a linear blend over
+    the feature index in between.  A numpy float32 vector ``[width / 2]``,
+    made while tracing."""
+    import math
+    import numpy as np
+
+    def correction_dim(rotations: float) -> float:
+        return width * math.log(original / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), width - 1)
+    if low == high:
+        high += 0.001
+    plain = theta ** (-np.arange(0, width, 2, dtype=np.float64) / width)
+    ramp = np.clip((np.arange(width // 2, dtype=np.float64) - low)
+                   / (high - low), 0, 1)
+    return (plain / factor * ramp + plain * (1 - ramp)).astype(np.float32)
+
+
+def rotary(x, theta: float, width: typing.Optional[int] = None,
+           inv_freq=None, factor: float = 1.0):
     """Rotary position embedding on ``x [..., seq, heads, width]``, HF's
     rotate-half convention over the whole head width: feature ``i`` pairs
     with ``i + width/2``, both turn by ``pos * theta ** (-2i / width)``.
-    Computed in float32, returned in ``x``'s dtype."""
+    Computed in float32, returned in ``x``'s dtype.  ``width`` (None = all):
+    only the FIRST ``width`` features of each head turn, rotate-half inside
+    them, the rest pass (HF's ``partial_rotary_factor``); ``inv_freq``
+    ``[width / 2]`` replaces the plain frequencies (``yarn_inv_freq``);
+    ``factor`` multiplies cos and sin (YaRN's ``attention_factor``)."""
     import jax.numpy as jnp
-    seq, width = x.shape[-3], x.shape[-1]
+    seq, full = x.shape[-3], x.shape[-1]
+    width = full if width is None else width
     half = width // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2 / width)
-    angle = jnp.arange(seq, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    if inv_freq is None:
+        inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2 / width)
+    angle = jnp.arange(seq, dtype=jnp.float32)[:, None] \
+        * jnp.asarray(inv_freq)[None, :]
     cos = jnp.cos(angle)[:, None, :]
     sin = jnp.sin(angle)[:, None, :]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     xf = x.astype(jnp.float32)
-    x1, x2 = xf[..., :half], xf[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1).astype(x.dtype)
+    x1, x2 = xf[..., :half], xf[..., half:width]
+    parts = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    if width != full:
+        parts.append(xf[..., width:])
+    return jnp.concatenate(parts, axis=-1).astype(x.dtype)
+
+
+#: the standard attention's flags: the three that select it (how positions
+#: enter), the plain ones, and the ones that carry a whole number
+_STANDARD_POSITION = ("rope", "nope", "yarn")
+_STANDARD_PLAIN = ("qk_norm", "gate")
+_STANDARD_NUMBERED = ("q_heads", "kv_heads", "window", "rotary_pct", "theta")
+
+
+def _standard_flags(extras) -> typing.Dict[str, typing.Any]:
+    """The standard attention's flags as ``{name: True | int}``; a flag it
+    does not know, or two ways of placing positions, refuse by name."""
+    out: typing.Dict[str, typing.Any] = {}
+    for extra in extras:
+        if extra in _STANDARD_POSITION + _STANDARD_PLAIN:
+            out[extra] = True
+            continue
+        name = extra.rstrip("0123456789")
+        if name not in _STANDARD_NUMBERED or name == extra:
+            raise ValueError(
+                f"the standard attention does not know flag {extra!r} "
+                f"(known: {_STANDARD_POSITION + _STANDARD_PLAIN}, and "
+                f"{_STANDARD_NUMBERED} followed by a whole number)")
+        out[name] = int(extra[len(name):])
+    if sum(f in out for f in _STANDARD_POSITION) != 1:
+        raise ValueError("the standard attention takes exactly one of "
+                         f"{_STANDARD_POSITION}, got {list(extras)}")
+    if "nope" in out and any(f in out for f in ("rotary_pct", "theta")):
+        raise ValueError("nope (no rotary positions) with rotary_pct / theta")
+    if ("q_heads" in out) != ("kv_heads" in out):
+        raise ValueError("q_heads<n> and kv_heads<n> come together")
+    if "q_heads" in out and (out["kv_heads"] < 1
+                             or out["q_heads"] % out["kv_heads"]):
+        raise ValueError(f"kv_heads{out['kv_heads']} must divide "
+                         f"q_heads{out['q_heads']}")
+    return out
 
 
 def _standard_attention(args: BlockArgs) -> NamedTensor:
-    """The standard pre-norm transformer's attention (flag ``rope`` or
-    ``nope``): query, key and value are three bias-free projections of the
+    """The standard pre-norm transformer's attention (flag ``rope``, ``nope``
+    or ``yarn``): query, key and value are three bias-free projections of the
     block's input (no bottleneck) — the query to all ``heads``, key and value
     to ``heads // query_group`` of them (``query_group`` 1 = all; more =
     grouped queries, K/V head ``j`` serves query heads ``j * query_group
@@ -370,15 +445,35 @@ def _standard_attention(args: BlockArgs) -> NamedTensor:
     Mamba layers carry the order), causal ``softmax(scale q k^T) v`` with
     ``scale = attention_scale`` (0 = ``features_per_head ** -0.5``) through
     the flash kernel, and an output projection.  Weights are normal(0.02).
+
+    A layer's own head counts: ``q_heads<n>-kv_heads<m>`` project the query
+    to ``n`` heads and key and value to ``m`` of ``features_per_head`` each,
+    whatever the stream's ``heads`` (the stream is ``heads x
+    features_per_head`` wide, the attention ``n x features_per_head``; the
+    output projection leads back); ``query_group`` is then ``n / m`` for
+    this layer.  ``window<w>``: query ``i`` sees keys ``i - w + 1 .. i``
+    (the flash kernels skip the blocks behind the window).  ``gate``: a
+    sigmoid gate a query head on the attention's output, ``o * sigmoid(a
+    Wg)`` with ``Wg [features, heads]`` from the block's (normed) input,
+    created after the value projection (Qiu et al., arXiv:2505.06708,
+    head-wise).  ``theta<t>`` replaces ``rope_theta`` for this layer,
+    ``rotary_pct<p>`` turns only the first ``p`` percent of each head's
+    features (HF's ``partial_rotary_factor``; an even count), and
+    ``yarn`` is ``rope`` at YaRN's frequencies (``rope_yarn_factor``,
+    ``rope_yarn_original_positions``, ``rope_yarn_beta_fast`` /
+    ``_beta_slow``) with cos and sin times ``rope_yarn_attention_factor``
+    (0 = ``0.1 ln(factor) + 1``).  Any other flag refuses by name.
     Training and full-sequence forward only: a decode step for it is a later
     issue."""
     import jax
+    import jax.numpy as jnp
     from ..core import scope as scope_mod
     from ..core.tensor import nt, rename_dim, transpose_to
     from .backend import normal_var
     from .normalization import norm
     from .utils import anonymize_dim
     params = args.params
+    flags = _standard_flags(args.name_extras)
     ctx = scope_mod.current()
     dim = get_attention_dim(args).dim
     if ctx.decode is not None or decode_mod.prefill_active() is not None:
@@ -394,64 +489,104 @@ def _standard_attention(args: BlockArgs) -> NamedTensor:
             "sequence- or pipe-sharded mesh")
     feats = list(params.feature_dims)
     anon = [anonymize_dim(d) for d in feats]
-    grouped = params.query_group > 1
-    kv_heads = params.head_dim.size // params.query_group
-    kv_feats = [Dim("kv_heads", kv_heads), params.key_dim] if grouped \
-        else feats
-    if grouped and mesh is not None and mesh.size > 1:
-        raise NotImplementedError("grouped key / value heads on a mesh")
+    own_heads = "q_heads" in flags
+    if own_heads:
+        group = flags["q_heads"] // flags["kv_heads"]
+        kv_heads = flags["kv_heads"]
+        q_feats = [Dim("q_heads", flags["q_heads"]), params.key_dim]
+    else:
+        group = params.query_group
+        kv_heads = params.head_dim.size // group
+        q_feats = feats
+    grouped = group > 1
+    kv_feats = [Dim("kv_heads", kv_heads), params.key_dim] \
+        if grouped or own_heads else feats
+    if (grouped or own_heads) and mesh is not None and mesh.size > 1:
+        raise NotImplementedError(
+            "grouped key / value heads, or a layer's own head counts, on a "
+            "mesh")
 
-    def project(x: NamedTensor, new=feats) -> NamedTensor:
-        """All features -> ``new``, the input's feature dims renamed so that
-        the einsum contracts them."""
-        for d, a in zip(feats, anon):
+    def project(x: NamedTensor, new=q_feats, old=feats) -> NamedTensor:
+        """``old`` features -> ``new``, the input's ``old`` dims renamed so
+        that the einsum contracts them."""
+        hidden = [anonymize_dim(d) for d in old]
+        for d, a in zip(old, hidden):
             x = rename_dim(x, d.name, a.name)
-        return einsum([x, normal_var(args, anon + new)],
-                      shape_sub(x.dims, anon) + new)
+        return einsum([x, normal_var(args, hidden + new)],
+                      shape_sub(x.dims, hidden) + new)
 
-    # creation order: key, query, value (as the dense path), their norms
+    # creation order: key, query, value (as the dense path), the gate, the
+    # two norms, the output projection
     key = project(args.tensor, kv_feats)
     qry = project(args.tensor)
     val = project(args.tensor, kv_feats)
-    if "qk_norm" in args.name_extras:
-        qry = norm(args(qry, ["rms", "scale"]), feats)
+    gate = None
+    if "gate" in flags:
+        with jax.named_scope("gate"):
+            gate = project(args.tensor, q_feats[:1])
+    if "qk_norm" in flags:
+        qry = norm(args(qry, ["rms", "scale"]), q_feats)
         key = norm(args(key, ["rms", "scale"]), kv_feats)
     lead_dims = [d for d in args.tensor.dims if d not in [dim] + feats]
-    canonical = lead_dims + [dim] + feats
+    canonical = lead_dims + [dim] + q_feats
     lead = 1
     for d in lead_dims:
         lead *= d.size
     q = transpose_to(qry, canonical).data.reshape(
-        lead, dim.size, params.head_dim.size, params.key_dim.size)
+        lead, dim.size, q_feats[0].size, params.key_dim.size)
     k, v = (transpose_to(t, lead_dims + [dim] + kv_feats).data.reshape(
         lead, dim.size, kv_heads, params.key_dim.size) for t in (key, val))
-    if "rope" in args.name_extras:
+    if "nope" not in flags:
+        theta = float(flags.get("theta", params.rope_theta))
+        rope_args: typing.Tuple = ()
+        if "rotary_pct" in flags or "yarn" in flags:
+            width, rest = divmod(
+                params.key_dim.size * flags.get("rotary_pct", 100), 100)
+            if rest or width < 2 or width % 2 or width > params.key_dim.size:
+                raise ValueError(
+                    f"rotary_pct{flags.get('rotary_pct')}: an even number "
+                    f"of features_per_head {params.key_dim.size}'s features")
+            rope_args = (width,)
+            if "yarn" in flags:
+                import math
+                factor = params.rope_yarn_attention_factor \
+                    or 0.1 * math.log(params.rope_yarn_factor) + 1.0
+                rope_args = (width, yarn_inv_freq(
+                    theta, width, params.rope_yarn_factor,
+                    params.rope_yarn_original_positions,
+                    params.rope_yarn_beta_fast, params.rope_yarn_beta_slow),
+                    float(factor))
         with jax.named_scope("rope"):
-            q = rotary(q, params.rope_theta)
-            k = rotary(k, params.rope_theta)
+            q = rotary(q, theta, *rope_args)
+            k = rotary(k, theta, *rope_args)
     scale = params.attention_scale or params.key_dim.size ** -0.5
     if grouped:
         # grouped queries: each K/V head repeated over its group before the
         # kernel, autodiff sums dk and dv over it (the flash kernels are
         # multi-head only; PERF.md section 6, PR 30 has the A/B against K/V
         # index maps)
-        import jax.numpy as jnp
-        k, v = (jnp.repeat(t, params.query_group, axis=2) for t in (k, v))
+        k, v = (jnp.repeat(t, group, axis=2) for t in (k, v))
+    window = flags.get("window")
     if params.use_flash_attention:
-        out = _flash(ctx, q, k, v, scale)
+        out = _flash(ctx, q, k, v, scale, window)
     else:
         from ..parallel.flash_attention import _xla_reference
         with jax.named_scope("attention_dense"):
-            out = _xla_reference(q, k, v, scale, True)
-    out_nt = transpose_to(nt(out.reshape([d.size for d in canonical]),
-                             canonical), args.tensor.dims)
-    return project(out_nt)
+            out = _xla_reference(q, k, v, scale, True, window)
+    out_nt = nt(out.reshape([d.size for d in canonical]), canonical)
+    if gate is not None:
+        with jax.named_scope("gate"):
+            out_nt = out_nt * nt(jax.nn.sigmoid(
+                gate.data.astype(jnp.float32)).astype(out.dtype), gate.dims)
+    return project(transpose_to(
+        out_nt, [d for d in args.tensor.dims if d not in feats] + q_feats),
+        feats, q_feats)
 
 
 def attention(args: BlockArgs) -> NamedTensor:
     params = args.params
     params.attention_idx += 1
-    if "rope" in args.name_extras or "nope" in args.name_extras:
+    if any(f in args.name_extras for f in _STANDARD_POSITION):
         return _standard_attention(args)
     base = None
     if "dot_product" in args.name_extras or "input_as_value" not in args.name_extras:

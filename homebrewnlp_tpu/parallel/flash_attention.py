@@ -42,19 +42,23 @@ _NEG_INF = -1e30
 _KERNEL_VMEM_BUDGET = 64 * 1024 * 1024
 
 
-def _xla_reference(q, k, v, scale, causal):
+def _xla_reference(q, k, v, scale, causal, window=None):
     # XLA dead-code-eliminates the unused lse
-    return _xla_reference_with_lse(q, k, v, scale, causal)[0]
+    return _xla_reference_with_lse(q, k, v, scale, causal, window)[0]
 
 
-def _xla_reference_with_lse(q, k, v, scale, causal):
+def _xla_reference_with_lse(q, k, v, scale, causal, window=None):
     """(out, lse [b*h, s]) — the fused XLA form for stash COLLECTION off-TPU
-    (the pallas kernels' residual contract, without interpret-mode cost)."""
+    (the pallas kernels' residual contract, without interpret-mode cost).
+    ``window``: key ``t`` is visible to query ``i`` iff ``0 <= i - t <
+    window`` (a band below the diagonal; HF's ``sliding_window``)."""
     b, s, h, d = q.shape
     scores = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32) * scale,
                         k.astype(jnp.float32))
     if causal:
         mask = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
+        if window is not None:
+            mask &= jnp.arange(s)[:, None] - jnp.arange(s)[None, :] < window
         scores = jnp.where(mask[None, None], scores, _NEG_INF)
     m = scores.max(-1)
     p = jnp.exp(scores - m[..., None])
@@ -80,8 +84,53 @@ def _causal_split(qi, ki, block_q: int, block_k: int):
     return live, full
 
 
+def _window_k_range(qi, block_q: int, block_k: int, window: int):
+    """(first, last) k block a q block's band touches: keys ``qi * block_q
+    - (window - 1) .. qi * block_q + block_q - 1``, clipped at position 0.
+    Works on python ints and on traced indices alike."""
+    first = (qi * block_q - (window - 1)) // block_k
+    first = max(first, 0) if isinstance(first, int) else jnp.maximum(first, 0)
+    return first, (qi * block_q + block_q - 1) // block_k
+
+
+def _window_q_range(ki, block_q: int, block_k: int, window: int, num_q: int):
+    """(first, last) q block whose band touches k block ``ki``: queries ``ki
+    * block_k .. ki * block_k + block_k - 1 + window - 1``, clipped at the
+    sequence's end."""
+    last = (ki * block_k + block_k - 1 + window - 1) // block_q
+    last = min(last, num_q - 1) if isinstance(last, int) \
+        else jnp.minimum(last, num_q - 1)
+    return (ki * block_k) // block_q, last
+
+
+def _window_q_index(ki, jj, block_q: int, block_k: int, window, seq_q: int):
+    """``(q block, valid)`` of inner step ``jj`` of a k-outer grid: the step
+    itself without a window; under one the inner dimension walks k block
+    ``ki``'s band only, and ``valid`` is false past the band's last block
+    (``seq_q`` the sequence's q blocks)."""
+    if window is None:
+        return jj, None
+    first, last = _window_q_range(ki, block_q, block_k, window, seq_q)
+    return first + jj, first + jj <= last
+
+
+def _window_inner(num_outer: int, rng) -> int:
+    """Length of a windowed grid's inner dimension: the most blocks any
+    outer block's band touches (``rng(i) -> (first, last)``)."""
+    return max(last - first + 1 for first, last in map(rng, range(num_outer)))
+
+
+def _window_split(qi, ki, block_q: int, block_k: int, window: int):
+    """``_causal_split`` under a window: a block is live when it holds a
+    pair with ``0 <= i - t < window``, full when all its pairs are."""
+    live, full = _causal_split(qi, ki, block_q, block_k)
+    live &= ki * block_k + block_k - 1 >= qi * block_q - (window - 1)
+    full &= qi * block_q + block_q - 1 - ki * block_k <= window - 1
+    return live, full
+
+
 def _masked_step(qi, ki, block_q: int, block_k: int, causal: bool, score,
-                 accumulate, dead=None):
+                 accumulate, dead=None, window=None, valid=None):
     """Shared causal dispatch for the kernels: the mask-free interior
     branch, the masked diagonal branch (mutually exclusive ``pl.when``s —
     the FLOP counter relies on that, utils/flops.py), or the unconditional
@@ -89,13 +138,22 @@ def _masked_step(qi, ki, block_q: int, block_k: int, causal: bool, score,
     ``accumulate(s)`` folds them into the kernel's state.  ``dead`` (fused
     backward only) runs on causally-dead cells — it zero-fills the cell's
     dq-partial slot so the caller's sum over partials never reads
-    uninitialised memory."""
+    uninitialised memory.  ``window`` (static; None = the whole causal
+    triangle): blocks wholly behind the window are dead too, and the blocks
+    its far edge crosses take the per-element mask like the diagonal's.
+    ``valid`` (windowed k-outer grids): false where the inner index ran past
+    the sequence's last q block."""
     from jax.experimental import pallas as pl
 
     if not causal:
         accumulate(score())
         return
-    live, full = _causal_split(qi, ki, block_q, block_k)
+    if window is None:
+        live, full = _causal_split(qi, ki, block_q, block_k)
+    else:
+        live, full = _window_split(qi, ki, block_q, block_k, window)
+        if valid is not None:
+            live, full = live & valid, full & valid
 
     @pl.when(full)
     def _step_interior():
@@ -107,7 +165,10 @@ def _masked_step(qi, ki, block_q: int, block_k: int, causal: bool, score,
             jnp.int32, (block_q, 1), 0)
         k_pos = ki * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_k), 1)
-        accumulate(jnp.where(q_pos >= k_pos, score(), _NEG_INF))
+        seen = q_pos >= k_pos
+        if window is not None:
+            seen &= q_pos - k_pos < window
+        accumulate(jnp.where(seen, score(), _NEG_INF))
 
     if dead is not None:
         @pl.when(jnp.logical_not(live))
@@ -115,13 +176,19 @@ def _masked_step(qi, ki, block_q: int, block_k: int, causal: bool, score,
             dead()
 
 
-def _frontier_kv_map(block_q: int, block_k: int, causal: bool):
+def _frontier_kv_map(block_q: int, block_k: int, causal: bool, window=None):
     """K/V BlockSpec index map with dead cells clamped to the causal
     frontier (grid order (i, q, k) — k innermost): the repeated block index
     makes the pipeline skip the dead HBM fetch, so dead cells cost
     iteration overhead only.  The clamp bound is the last live k block of
-    ``_causal_split``'s liveness predicate; forward and dq share it."""
-    if causal:
+    ``_causal_split``'s liveness predicate; forward and dq share it.  Under
+    a ``window`` the inner index counts from the band's first k block (the
+    grid's inner dimension is ``_window_inner`` long, not ``num_k``)."""
+    if window is not None:
+        def kv_map(i, j, kk):
+            first, last = _window_k_range(j, block_q, block_k, window)
+            return (i, jnp.minimum(first + kk, last), 0)
+    elif causal:
         def kv_map(i, j, kk):
             return (i, jnp.minimum(kk, (j * block_q + block_q - 1) // block_k),
                     0)
@@ -131,12 +198,18 @@ def _frontier_kv_map(block_q: int, block_k: int, causal: bool):
     return kv_map
 
 
-def _frontier_q_map(block_q: int, block_k: int, causal: bool):
+def _frontier_q_map(block_q: int, block_k: int, causal: bool, window=None,
+                    num_q: int = 0):
     """Q-side twin of ``_frontier_kv_map`` for the k-outer backward grids
     (grid (i, k, q) — q innermost): causally-dead q blocks BEFORE the first
     live one ((kk*bk)//bq, the ``_causal_split`` liveness bound) repeat its
-    index so the pipeline skips the dead HBM fetch."""
-    if causal:
+    index so the pipeline skips the dead HBM fetch.  Under a ``window`` the
+    inner index counts from the band's first q block."""
+    if window is not None:
+        def q_map(i, kk, j):
+            first, last = _window_q_range(kk, block_q, block_k, window, num_q)
+            return (i, jnp.minimum(first + j, last), 0)
+    elif causal:
         def q_map(i, kk, j):
             return (i, jnp.maximum(j, (kk * block_k) // block_q), 0)
     else:
@@ -159,7 +232,7 @@ def _make_score(q_ref, k_ref, scale):
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
                   *, block_q: int, block_k: int, num_k: int, scale: float,
-                  causal: bool):
+                  causal: bool, window=None):
     """3-D grid (batch*heads, q blocks, k blocks): one K/V block resident in
     VMEM at a time, online-softmax state carried in VMEM scratch across the
     innermost k dimension — VMEM use is O(block) regardless of sequence
@@ -167,9 +240,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    kk = ki = pl.program_id(2)
+    if window is not None:
+        # the inner dimension walks the band only (``num_k`` is its length)
+        ki = _window_k_range(qi, block_q, block_k, window)[0] + kk
 
-    @pl.when(ki == 0)
+    @pl.when(kk == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -189,9 +265,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
         m_ref[...] = m_new
 
     _masked_step(qi, ki, block_q, block_k, causal,
-                 _make_score(q_ref, k_ref, scale), _accumulate)
+                 _make_score(q_ref, k_ref, scale), _accumulate, window=window)
 
-    @pl.when(ki == num_k - 1)
+    @pl.when(kk == num_k - 1)
     def _finish():
         o_ref[...] = (acc_ref[...]
                       / jnp.maximum(l_ref[...], 1e-30)[:, None]).astype(o_ref.dtype)
@@ -200,6 +276,16 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
         # a [bh, s] row block of (1, block_q) cannot satisfy
         lse_ref[...] = (m_ref[...]
                         + jnp.log(jnp.maximum(l_ref[...], 1e-30)))[:, None]
+
+
+def _kernel_name(base: str, causal: bool, window) -> str:
+    """``base`` + ``_causal`` (lets the FLOP counter subtract the skipped
+    dead cells, utils/flops.py count_matmul_flops_split) or ``_window`` (a
+    windowed call's grid holds its band only; the trace tells the two
+    apart by it)."""
+    if window is not None:
+        return base + "_window"
+    return base + "_causal" if causal else base
 
 
 def kernel_block(s: int, cap: int = 1024) -> int:
@@ -214,7 +300,7 @@ def kernel_block(s: int, cap: int = 1024) -> int:
 
 
 def _fwd_flat(qt, kt, vt, scale, causal, block_q, block_k, interpret,
-              out_dtype=None):
+              out_dtype=None, window=None):
     """Flat-core forward: q/k/v [bh, s, d] -> (out [bh, s, d], lse [bh, s]).
 
     The flat layout is shared with the ring-attention hop path
@@ -232,9 +318,14 @@ def _fwd_flat(qt, kt, vt, scale, causal, block_q, block_k, interpret,
     num_k = sk // block_k
     out_dtype = qt.dtype if out_dtype is None else out_dtype
 
+    if window is not None:
+        # the inner dimension walks the band only
+        num_k = _window_inner(s // block_q, lambda j: _window_k_range(
+            j, block_q, block_k, window))
     kernel = functools.partial(_flash_kernel, block_q=block_q, block_k=block_k,
-                               num_k=num_k, scale=scale, causal=causal)
-    _kmap = _frontier_kv_map(block_q, block_k, causal)
+                               num_k=num_k, scale=scale, causal=causal,
+                               window=window)
+    _kmap = _frontier_kv_map(block_q, block_k, causal, window)
     out, lse = pl.pallas_call(
         kernel,
         grid=(bh, s // block_q, num_k),
@@ -257,15 +348,14 @@ def _fwd_flat(qt, kt, vt, scale, causal, block_q, block_k, interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_KERNEL_VMEM_BUDGET),
-        # "causal" in the name lets the FLOP counter subtract the skipped
-        # dead cells (utils/flops.py count_matmul_flops_split)
-        name="flash_fwd_causal" if causal else "flash_fwd",
+        name=_kernel_name("flash_fwd", causal, window),
         interpret=interpret,
     )(qt, kt, vt)
     return out, lse[..., 0]
 
 
-def _flash_fwd_impl(q, k, v, scale, causal, block_q, block_k, interpret):
+def _flash_fwd_impl(q, k, v, scale, causal, block_q, block_k, interpret,
+                    window=None):
     """Returns (out [b, s, h, d], lse [b*h, s]) — lse is the backward's
     softmax residual (flash-2: p is recomputed per block as exp(s - lse))."""
     b, s, h, d = q.shape
@@ -274,21 +364,23 @@ def _flash_fwd_impl(q, k, v, scale, causal, block_q, block_k, interpret):
     kt = k.transpose(0, 2, 1, 3).reshape(b * h, s, d)
     vt = v.transpose(0, 2, 1, 3).reshape(b * h, s, d)
     out, lse = _fwd_flat(qt, kt, vt, scale, causal, block_q, block_k,
-                         interpret)
+                         interpret, window=window)
     return out.reshape(b, h, s, d).transpose(0, 2, 1, 3), lse
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, dq_ref,
                    acc_ref, *, block_q: int, block_k: int, num_k: int,
-                   scale: float, causal: bool):
+                   scale: float, causal: bool, window=None):
     """dq: grid (b*h, q blocks, k blocks), k innermost; dq accumulates in
     VMEM scratch; causally-dead k blocks are skipped."""
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    kk = ki = pl.program_id(2)
+    if window is not None:
+        ki = _window_k_range(qi, block_q, block_k, window)[0] + kk
 
-    @pl.when(ki == 0)
+    @pl.when(kk == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
@@ -305,25 +397,27 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, dq_ref,
             preferred_element_type=jnp.float32)
 
     _masked_step(qi, ki, block_q, block_k, causal,
-                 _make_score(q_ref, k_ref, scale), _accumulate)
+                 _make_score(q_ref, k_ref, scale), _accumulate, window=window)
 
-    @pl.when(ki == num_k - 1)
+    @pl.when(kk == num_k - 1)
     def _finish():
         dq_ref[...] = acc_ref[...].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, dk_ref,
                     dv_ref, dk_acc, dv_acc, *, block_q: int, block_k: int,
-                    num_q: int, scale: float, causal: bool):
+                    num_q: int, scale: float, causal: bool, window=None,
+                    seq_q: int = 0):
     """dk/dv: grid (b*h, k blocks, q blocks), q innermost; for a fixed K/V
     block only q blocks at-or-after it contribute — strictly-earlier
     (causally dead) q blocks are skipped."""
     from jax.experimental import pallas as pl
 
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    jj = pl.program_id(2)
+    qi, valid = _window_q_index(ki, jj, block_q, block_k, window, seq_q)
 
-    @pl.when(qi == 0)
+    @pl.when(jj == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -343,9 +437,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, dk_ref,
             preferred_element_type=jnp.float32)
 
     _masked_step(qi, ki, block_q, block_k, causal,
-                 _make_score(q_ref, k_ref, scale), _accumulate)
+                 _make_score(q_ref, k_ref, scale), _accumulate, window=window,
+                 valid=valid)
 
-    @pl.when(qi == num_q - 1)
+    @pl.when(jj == num_q - 1)
     def _finish():
         dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
@@ -353,7 +448,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, dk_ref,
 
 def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, dqp_ref,
                       dk_ref, dv_ref, dk_acc, dv_acc, *, block_q: int,
-                      block_k: int, num_q: int, scale: float, causal: bool):
+                      block_k: int, num_q: int, scale: float, causal: bool,
+                      window=None, seq_q: int = 0):
     """Fused backward: grid (b*h, k blocks, q blocks), q innermost.
 
     The split dq and dk/dv kernels EACH recompute the two shared
@@ -371,9 +467,10 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, dqp_ref,
     from jax.experimental import pallas as pl
 
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    jj = pl.program_id(2)
+    qi, valid = _window_q_index(ki, jj, block_q, block_k, window, seq_q)
 
-    @pl.when(qi == 0)
+    @pl.when(jj == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -401,9 +498,10 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, dqp_ref,
         dqp_ref[...] = jnp.zeros_like(dqp_ref)
 
     _masked_step(qi, ki, block_q, block_k, causal,
-                 _make_score(q_ref, k_ref, scale), _accumulate, dead=_dead)
+                 _make_score(q_ref, k_ref, scale), _accumulate, dead=_dead,
+                 window=window, valid=valid)
 
-    @pl.when(qi == num_q - 1)
+    @pl.when(jj == num_q - 1)
     def _finish():
         dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
@@ -440,8 +538,16 @@ def _use_fused_bwd(bh: int, s: int, sk: int, d: int, bk: int) -> bool:
 
 
 def _bwd_flat_fused(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
-                    interpret, out_dtype=None):
-    """One-pass fused backward (see ``_bwd_fused_kernel``)."""
+                    interpret, out_dtype=None, window=None):
+    """One-pass fused backward (see ``_bwd_fused_kernel``).
+
+    Under a ``window`` the grid's inner dimension walks a k block's band of
+    q blocks only, and a q block's dq partials lie in ``_window_inner``
+    slots (slot = k block - the band's first k block) instead of one per k
+    block: ``[bh, slots, s + bq, d]``, the last q block a scrap one that
+    cells past the band's end write to.  A q block near position 0 touches
+    fewer k blocks than there are slots; the slots it never wrote are left
+    out of the sum by index."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -454,22 +560,39 @@ def _bwd_flat_fused(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
     dk_dtype = kt.dtype if out_dtype is None else out_dtype
     dv_dtype = vt.dtype if out_dtype is None else out_dtype
 
-    _q_map = _frontier_q_map(bq, bk, causal)
+    inner, slots, rows = nq, nk, s
+
+    def dqp_map(i, kk, j):
+        return (i, kk, j, 0)
+
+    if window is not None:
+        inner = _window_inner(nk, lambda kk: _window_q_range(kk, bq, bk,
+                                                             window, nq))
+        slots = _window_inner(nq, lambda j: _window_k_range(j, bq, bk,
+                                                            window))
+        rows = s + bq
+
+        def dqp_map(i, kk, j):
+            qi, live = _window_q_index(kk, j, bq, bk, window, nq)
+            slot = kk - _window_k_range(qi, bq, bk, window)[0]
+            return (i, jnp.where(live, slot, 0), jnp.where(live, qi, nq), 0)
+
+    _q_map = _frontier_q_map(bq, bk, causal, window, nq)
     qrow_spec = pl.BlockSpec((None, bq, 1), _q_map)
     dqp, dk, dv = pl.pallas_call(
         functools.partial(_bwd_fused_kernel, block_q=bq, block_k=bk,
-                          num_q=nq, scale=scale, causal=causal),
-        grid=(bh, nk, nq),
+                          num_q=inner, scale=scale, causal=causal,
+                          window=window, seq_q=nq),
+        grid=(bh, nk, inner),
         in_specs=[pl.BlockSpec((None, bq, d), _q_map),
                   pl.BlockSpec((None, bk, d), lambda i, kk, j: (i, kk, 0)),
                   pl.BlockSpec((None, bk, d), lambda i, kk, j: (i, kk, 0)),
                   pl.BlockSpec((None, bq, d), _q_map),
                   qrow_spec, qrow_spec],
-        out_specs=[pl.BlockSpec((None, None, bq, d),
-                                lambda i, kk, j: (i, kk, j, 0)),
+        out_specs=[pl.BlockSpec((None, None, bq, d), dqp_map),
                    pl.BlockSpec((None, bk, d), lambda i, kk, j: (i, kk, 0)),
                    pl.BlockSpec((None, bk, d), lambda i, kk, j: (i, kk, 0))],
-        out_shape=[jax.ShapeDtypeStruct((bh, nk, s, d), jnp.float32),
+        out_shape=[jax.ShapeDtypeStruct((bh, slots, rows, d), jnp.float32),
                    jax.ShapeDtypeStruct((bh, sk, d), dk_dtype),
                    jax.ShapeDtypeStruct((bh, sk, d), dv_dtype)],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
@@ -477,15 +600,22 @@ def _bwd_flat_fused(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_KERNEL_VMEM_BUDGET),
-        name="flash_bwd_fused_causal" if causal else "flash_bwd_fused",
+        name=_kernel_name("flash_bwd_fused", causal, window),
         interpret=interpret,
     )(qt, kt, vt, dot, lse3, delta)
-    dq = dqp.sum(axis=1).astype(dq_dtype)
-    return dq, dk, dv
+    if window is None:
+        dq = dqp.sum(axis=1)
+    else:
+        first, last = _window_k_range(jnp.arange(s, dtype=jnp.int32) // bq,
+                                      bq, bk, window)
+        wrote = jnp.arange(slots, dtype=jnp.int32)[:, None] \
+            <= (last - first)[None]
+        dq = jnp.where(wrote[None, :, :, None], dqp[:, :, :s], 0.0).sum(axis=1)
+    return dq.astype(dq_dtype), dk, dv
 
 
 def _bwd_flat(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
-              interpret, out_dtype=None):
+              interpret, out_dtype=None, window=None):
     """Flat-core backward: operands [bh, s, d], lse/delta [bh, s, 1] ->
     (dq, dk, dv) [bh, s, d].  ``lse``/``delta`` are the GLOBAL softmax
     residuals — flash-2's decomposition makes per-block contributions
@@ -502,22 +632,32 @@ def _bwd_flat(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
 
     bh, s, d = qt.shape
     sk = kt.shape[1]
-    if _use_fused_bwd(bh, s, sk, d, bk):
-        return _bwd_flat_fused(qt, kt, vt, dot, lse3, delta, scale, causal,
-                               bq, bk, interpret, out_dtype)
     nq, nk = s // bq, sk // bk
+    inner_k, inner_q = nk, nq
+    if window is not None:
+        # the grids' inner dimensions walk the band only
+        inner_k = _window_inner(nq, lambda j: _window_k_range(j, bq, bk,
+                                                              window))
+        inner_q = _window_inner(nk, lambda kk: _window_q_range(kk, bq, bk,
+                                                               window, nq))
+    # the fused form's dq-partial buffer: one slot a k block, or the band's
+    if _use_fused_bwd(bh, s, sk, d, bk) if window is None \
+            else _use_fused_bwd(bh, s + bq, inner_k * bk, d, bk):
+        return _bwd_flat_fused(qt, kt, vt, dot, lse3, delta, scale, causal,
+                               bq, bk, interpret, out_dtype, window)
     dq_dtype = qt.dtype if out_dtype is None else out_dtype
     dk_dtype = kt.dtype if out_dtype is None else out_dtype
     dv_dtype = vt.dtype if out_dtype is None else out_dtype
 
-    _kv_map = _frontier_kv_map(bq, bk, causal)
-    _q_map_dkv = _frontier_q_map(bq, bk, causal)
+    _kv_map = _frontier_kv_map(bq, bk, causal, window)
+    _q_map_dkv = _frontier_q_map(bq, bk, causal, window, nq)
 
     row_spec = pl.BlockSpec((None, bq, 1), lambda i, j, kk: (i, j, 0))
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, block_q=bq, block_k=bk, num_k=nk,
-                          scale=scale, causal=causal),
-        grid=(bh, nq, nk),
+        functools.partial(_bwd_dq_kernel, block_q=bq, block_k=bk,
+                          num_k=inner_k, scale=scale, causal=causal,
+                          window=window),
+        grid=(bh, nq, inner_k),
         in_specs=[pl.BlockSpec((None, bq, d), lambda i, j, kk: (i, j, 0)),
                   pl.BlockSpec((None, bk, d), _kv_map),
                   pl.BlockSpec((None, bk, d), _kv_map),
@@ -529,15 +669,16 @@ def _bwd_flat(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_KERNEL_VMEM_BUDGET),
-        name="flash_bwd_dq_causal" if causal else "flash_bwd_dq",
+        name=_kernel_name("flash_bwd_dq", causal, window),
         interpret=interpret,
     )(qt, kt, vt, dot, lse3, delta)
 
     qrow_spec = pl.BlockSpec((None, bq, 1), _q_map_dkv)
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, block_q=bq, block_k=bk, num_q=nq,
-                          scale=scale, causal=causal),
-        grid=(bh, nk, nq),
+        functools.partial(_bwd_dkv_kernel, block_q=bq, block_k=bk,
+                          num_q=inner_q, scale=scale, causal=causal,
+                          window=window, seq_q=nq),
+        grid=(bh, nk, inner_q),
         in_specs=[pl.BlockSpec((None, bq, d), _q_map_dkv),
                   pl.BlockSpec((None, bk, d), lambda i, kk, j: (i, kk, 0)),
                   pl.BlockSpec((None, bk, d), lambda i, kk, j: (i, kk, 0)),
@@ -552,14 +693,14 @@ def _bwd_flat(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_KERNEL_VMEM_BUDGET),
-        name="flash_bwd_dkv_causal" if causal else "flash_bwd_dkv",
+        name=_kernel_name("flash_bwd_dkv", causal, window),
         interpret=interpret,
     )(qt, kt, vt, dot, lse3, delta)
     return dq, dk, dv
 
 
 def _flash_bwd_pallas(q, k, v, out, lse, dout, scale, causal, block_q,
-                      block_k, interpret):
+                      block_k, interpret, window=None):
     """Flash-2 pallas backward over [b, s, h, d] operands; every kernel
     skips the causally-dead blocks."""
     b, s, h, d = q.shape
@@ -578,7 +719,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, scale, causal, block_q,
     delta = jnp.sum(dot.astype(jnp.float32) * ot.astype(jnp.float32), -1,
                     keepdims=True)
     dq, dk, dv = _bwd_flat(qt, kt, vt, dot, lse[..., None], delta, scale,
-                           causal, bq, bk, interpret)
+                           causal, bq, bk, interpret, window=window)
 
     def back(x):
         return x.reshape(b, h, s, d).transpose(0, 2, 1, 3)
@@ -586,44 +727,47 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, scale, causal, block_q,
     return back(dq), back(dk), back(dv)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
 def flash_attention(q, k, v, scale: float = None, causal: bool = True,
                     block_q: int = 128, block_k: int = 128,
                     interpret: bool = False, bwd_block_q: int = None,
-                    bwd_block_k: int = None):
+                    bwd_block_k: int = None, window: int = None):
     """q, k, v: [batch, seq, heads, d] -> [batch, seq, heads, d].
 
     ``bwd_block_q``/``bwd_block_k`` override the backward kernels' tiles
     (None = same as forward): the forward profits from a wider k tile
     (fewer online-softmax rescale steps) that pushes the dq kernel past the
-    scoped-VMEM limit in the full model."""
+    scoped-VMEM limit in the full model.  ``window`` (with ``causal``):
+    query ``i`` sees keys ``i - window + 1 .. i`` only, and every kernel's
+    grid holds the blocks that band touches, not the triangle."""
     out, _ = _flash_fwd_impl(q, k, v, scale, causal, block_q, block_k,
-                             interpret)
+                             interpret, window)
     return out
 
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
-               bwd_block_q, bwd_block_k):
+               bwd_block_q, bwd_block_k, window):
     out, lse = _flash_fwd_impl(q, k, v, scale, causal, block_q, block_k,
-                               interpret)
+                               interpret, window)
     return out, (q, k, v, out, lse)
 
 
 def _flash_bwd(scale, causal, block_q, block_k, interpret, bwd_block_q,
-               bwd_block_k, res, dout):
+               bwd_block_k, window, res, dout):
     bq = block_q if bwd_block_q is None else bwd_block_q
     bk = block_k if bwd_block_k is None else bwd_block_k
     q, k, v, out, lse = res
     return _flash_bwd_pallas(q, k, v, out, lse, dout, scale, causal,
-                             bq, bk, interpret)
+                             bq, bk, interpret, window)
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
 def flash_precomputed(q, k, v, out, lse, scale, causal, block_q, block_k,
-                      interpret):
+                      interpret, window=None):
     """Flash attention whose forward is the PROVIDED (out, lse) — no kernel
     run — while the backward is the full flash-2 pallas pass.
 
@@ -639,14 +783,15 @@ def flash_precomputed(q, k, v, out, lse, scale, causal, block_q, block_k,
 
 
 def _flash_pre_fwd(q, k, v, out, lse, scale, causal, block_q, block_k,
-                   interpret):
+                   interpret, window):
     return out, (q, k, v, out, lse)
 
 
-def _flash_pre_bwd(scale, causal, block_q, block_k, interpret, res, dout):
+def _flash_pre_bwd(scale, causal, block_q, block_k, interpret, window, res,
+                   dout):
     q, k, v, out, lse = res
     dq, dk, dv = _flash_bwd_pallas(q, k, v, out, lse, dout, scale, causal,
-                                   block_q, block_k, interpret)
+                                   block_q, block_k, interpret, window)
     # out/lse are stashed residual constants of the OUTER custom_vjp; their
     # cotangents are discarded upstream
     return dq, dk, dv, jnp.zeros_like(out), jnp.zeros_like(lse)
@@ -655,10 +800,34 @@ def _flash_pre_bwd(scale, causal, block_q, block_k, interpret, res, dout):
 flash_precomputed.defvjp(_flash_pre_fwd, _flash_pre_bwd)
 
 
+#: tile cap of a windowed call, both passes and both sides.  A q tile's band
+#: is ``tile + window - 1`` keys wide whatever the tile, so tiles much wider
+#: than the window compute mostly masked pairs; tiles much narrower pay the
+#: per-cell state work the causal kernels' 1024 tiles amortise
+_WINDOW_BLOCK_CAP = 512
+
+
+def window_block(s: int, window: int) -> int:
+    """Tile of a windowed call: ``kernel_block`` capped at the window
+    rounded up to a power of two (128 at least, ``_WINDOW_BLOCK_CAP`` at
+    most)."""
+    cap = 128
+    while cap < min(window, _WINDOW_BLOCK_CAP):
+        cap *= 2
+    return kernel_block(s, cap=cap)
+
+
 def attention(q, k, v, scale: typing.Optional[float] = None,
               causal: bool = True, interpret: typing.Optional[bool] = None,
-              stash: typing.Optional[dict] = None):
+              stash: typing.Optional[dict] = None,
+              window: typing.Optional[int] = None):
     """Dispatch: pallas kernel on TPU, fused XLA elsewhere.
+
+    ``window`` (None = the whole causal triangle, today's kernels bit for
+    bit): query ``i`` sees keys ``i - window + 1 .. i``.  A windowed call
+    runs the ``flash_*_window`` kernels at ``window_block`` tiles, their
+    grids as long as the band; a window that covers the sequence is the
+    causal call.
 
     ``stash``: attention-output stash channel (model/blocks.py): mode
     "collect" computes (out, lse) and appends them to ``stash["items"]``
@@ -684,7 +853,15 @@ def attention(q, k, v, scale: typing.Optional[float] = None,
     if interpret is None:
         interpret = not on_tpu
     s = q.shape[1]
-    blk = kernel_block(s)
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError(f"window {window}: a positive width, under "
+                             "causal attention only")
+        if window >= s:
+            window = None
+    # tiles: (both passes' q and the backward's k, the forward's k)
+    blk, fwd_k = (kernel_block(s), kernel_block(s, cap=2048)) \
+        if window is None else (window_block(s, window),) * 2
     # named-scope regions (docs/OBSERVABILITY.md 'Cost attribution'): which
     # attention implementation actually ran — flash kernel vs the dense XLA
     # fallback — is visible per-op in HLO metadata and profiler traces
@@ -694,21 +871,22 @@ def attention(q, k, v, scale: typing.Optional[float] = None,
             if on_tpu:
                 with jax.named_scope("flash_attention"):
                     out, lse = _flash_fwd_impl(q, k, v, scale, causal, blk,
-                                               kernel_block(s, cap=2048),
-                                               interpret)
+                                               fwd_k, interpret, window)
             else:
                 with jax.named_scope("attention_dense"):
-                    out, lse = _xla_reference_with_lse(q, k, v, scale, causal)
+                    out, lse = _xla_reference_with_lse(q, k, v, scale, causal,
+                                                       window)
             stash_push(stash, (out, lse))
             return out
         out_s, lse_s = stash_pop(stash)
         with jax.named_scope("flash_attention"):
             return flash_precomputed(q, k, v, out_s, lse_s, scale, causal,
-                                     blk, blk, interpret)
+                                     blk, blk, interpret, window)
     if not on_tpu or s % 128 != 0:
         with jax.named_scope("attention_dense"):
-            return _xla_reference(q, k, v, scale, causal)
+            return _xla_reference(q, k, v, scale, causal, window)
     with jax.named_scope("flash_attention"):
-        return flash_attention(q, k, v, scale, causal, blk,
-                               kernel_block(s, cap=2048), interpret,
-                               bwd_block_q=blk, bwd_block_k=blk)
+        return flash_attention(q, k, v, scale, causal, blk, fwd_k, interpret,
+                               bwd_block_q=blk, bwd_block_k=blk,
+                               window=window)
+

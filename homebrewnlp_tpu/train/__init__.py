@@ -76,6 +76,15 @@ def _info_metrics(info) -> typing.Dict[str, jax.Array]:
         extra = {"moe_load_max_over_mean":
                  jnp.max(stats["moe_load_max_over_mean"]),
                  "moe_routed_pairs": jnp.sum(stats["moe_routed_pairs"])}
+    if "moe_held_pairs" in stats:
+        # layers that hold a share of the experts: the pairs routed to the
+        # held ones, and their share of the pairs routed, over all such
+        # layers and in the layer where it is largest
+        extra["moe_held_pairs"] = jnp.sum(stats["moe_held_pairs"])
+        extra["moe_held_pair_share"] = extra["moe_held_pairs"] \
+            / extra["moe_routed_pairs"]
+        extra["moe_held_pair_share_max"] = jnp.max(
+            stats["moe_held_pairs"] / stats["moe_routed_pairs"])
     if "ssd_log_decay_min" in stats:
         extra["ssd_log_decay_min"] = jnp.min(stats["ssd_log_decay_min"])
     if "delta_transform_abs_max" in stats:
@@ -115,6 +124,19 @@ _LAYER_STATS = {
     "moe_routed_pairs": (
         "counter", "hbnlp_moe_routed_pairs_total",
         "(token, choice) pairs routed to an expert, all moe layers"),
+    "moe_held_pairs": (
+        "counter", "hbnlp_moe_held_pairs_total",
+        "(token, choice) pairs routed to an expert this rank holds, all moe "
+        "layers that hold a share of the experts"),
+    "moe_held_pair_share": (
+        "gauge", "hbnlp_moe_held_pair_share",
+        "pairs routed to held experts over pairs routed, all moe layers of "
+        "the newest finished step (experts_held / experts when balanced)"),
+    "moe_held_pair_share_max": (
+        "gauge", "hbnlp_moe_held_pair_share_max",
+        "the same share in the moe layer where it is largest: how far the "
+        "static row buffer (hbnlp_moe_held_rows_bound) is filled is this "
+        "times moe_top_k / min(moe_top_k, experts_held)"),
     "ssd_log_decay_min": (
         "gauge", "hbnlp_ssd_log_decay_min",
         "most negative within-chunk cumulative dt * A of the newest finished "
@@ -621,10 +643,17 @@ class Trainer:
         ``hbnlp_mamba_conv_kernel_layers``: how many of those layers took
         the Pallas conv (``conv_kernel_layers``).  Set when the step is
         built; returns the start-up line that says the same."""
-        from ..model.remat import (conv_kernel_layers, ssd_state_bytes,
-                                   stash_line, stash_plan)
+        from ..model.remat import (conv_kernel_layers, moe_held_rows,
+                                   ssd_state_bytes, stash_line, stash_plan)
         plan = stash_plan(self.params, self.mesh)
         r = telemetry.registry()
+        held_rows = moe_held_rows(self.params)
+        if held_rows:
+            r.gauge("hbnlp_moe_held_rows_bound",
+                    "rows of the static dispatch buffer of a moe layer that "
+                    "holds a share of the experts: tokens x min(moe_top_k, "
+                    "experts_held), which no routing overflows"
+                    ).set(held_rows)
         states = ssd_state_bytes(self.params, self.mesh)
         r.gauge("hbnlp_ssd_state_bytes",
                 "per-device bytes of the recurrent mixers' (mamba, "
@@ -646,7 +675,8 @@ class Trainer:
             nlayers.labels(kind).set(layers)
         return stash_line(plan) + (
             f"; ssd chunk states {states} bytes a device; conv kernel "
-            f"{conv_layers} layers" if states else "")
+            f"{conv_layers} layers" if states else "") + (
+            f"; moe held rows bound {held_rows}" if held_rows else "")
 
     def lowered(self, state: TrainState, batch: typing.Dict[str, jax.Array]):
         """Lowered (StableHLO) train step for ``save_graph`` dumps — the
@@ -726,7 +756,8 @@ class Trainer:
 
     def _publish_layer_stats(self, metrics) -> None:
         """``hbnlp_moe_load_max_over_mean``, ``hbnlp_moe_routed_pairs_total``,
-        ``hbnlp_ssd_log_decay_min`` and ``hbnlp_delta_transform_abs_max`` (under ``telemetry_enabled``: only
+        ``hbnlp_moe_held_pairs_total``, ``hbnlp_moe_held_pair_share`` (and
+        ``_max``), ``hbnlp_ssd_log_decay_min`` and ``hbnlp_delta_transform_abs_max`` (under ``telemetry_enabled``: only
         then does the step report them) from the scalars of EARLIER steps
         the device has finished; a step still running is left for a later
         call, so this never waits.  The last steps of a run stay unread."""

@@ -253,3 +253,117 @@ def backward_path_follows_the_buffer_test(bh, s, d, fused, monkeypatch):
     bk = fa.kernel_block(s)
     assert bk == 1024
     assert fa._use_fused_bwd(bh, s, s, d, bk) is fused
+
+
+# ---- a window (ISSUE 36): key t visible to query i iff 0 <= i - t < window --
+
+#: (sequence, window, q tile, k tile): tiles smaller than, equal to and
+#: larger than the window, windows that end inside a tile, a window of one,
+#: a window one short of the sequence, and uneven tiles both ways
+WINDOW_CASES = [(128, 32, 16, 16), (128, 32, 32, 32), (128, 32, 64, 64),
+                (128, 1, 16, 16), (128, 50, 16, 32), (128, 50, 32, 16),
+                (128, 127, 32, 32), (96, 33, 8, 8), (64, 16, 64, 64)]
+
+
+def _window_inputs(s, seed=3):
+    rng = np.random.default_rng(seed)
+    return tuple(jnp.asarray(rng.standard_normal((1, s, 2, 16))
+                             .astype(np.float32)) for _ in range(4))
+
+
+@pytest.mark.parametrize("s,window,bq,bk", WINDOW_CASES)
+def window_forward_matches_the_band_mask_test(s, window, bq, bk):
+    q, k, v, _ = _window_inputs(s)
+    out = flash_attention(q, k, v, 0.25, True, bq, bk, True, None, None,
+                          window)
+    ref = _xla_reference(q, k, v, 0.25, True, window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    # the reference's own mask, written out once more
+    i, t = np.arange(s)[:, None], np.arange(s)[None, :]
+    score = np.einsum("bqhd,bkhd->bhqk", np.asarray(q), np.asarray(k)) * 0.25
+    score = np.where((t <= i) & (i - t < window), score, -np.inf)
+    weight = np.exp(score - score.max(-1, keepdims=True))
+    weight /= weight.sum(-1, keepdims=True)
+    np.testing.assert_allclose(
+        np.asarray(ref), np.einsum("bhqk,bkhd->bqhd", weight, np.asarray(v)),
+        rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("fused", [True, False], ids=["fused", "split"])
+@pytest.mark.parametrize("s,window,bq,bk", WINDOW_CASES)
+def window_backward_matches_the_band_mask_test(s, window, bq, bk, fused,
+                                               monkeypatch):
+    """The fused backward (dq partials in the band's slots, summed by index)
+    and the split dq / dk-dv pair, both on grids as long as the band."""
+    q, k, v, do = _window_inputs(s)
+    monkeypatch.setattr(fa, "_fused_dqp_cap",
+                        (lambda: 1 << 40) if fused else (lambda: 0))
+    jax.clear_caches()
+    got = jax.vjp(lambda q, k, v: flash_attention(
+        q, k, v, 0.25, True, bq, bk, True, None, None, window), q, k, v)[1](do)
+    monkeypatch.undo()
+    jax.clear_caches()
+    want = jax.vjp(lambda q, k, v: _xla_reference(q, k, v, 0.25, True, window),
+                   q, k, v)[1](do)
+    for a, b_ in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=GRAD_RTOL, atol=GRAD_ATOL)
+
+
+@pytest.mark.parametrize("s,window,bq,bk,inner", [
+    (8192, 512, 512, 512, 2), (8192, 512, 1024, 1024, 2),
+    (8192, 512, 256, 256, 3), (8192, 512, 128, 128, 5),
+    (128, 1, 16, 16, 1)])
+def windowed_grids_are_as_long_as_the_band_test(s, window, bq, bk, inner):
+    """The inner grid dimension of a windowed call holds the blocks one
+    outer block's band touches, both ways round, whatever the sequence."""
+    assert fa._window_inner(s // bq, lambda j: fa._window_k_range(
+        j, bq, bk, window)) == inner
+    assert fa._window_inner(s // bk, lambda kk: fa._window_q_range(
+        kk, bq, bk, window, s // bq)) == inner
+    assert fa.window_block(8192, 512) == 512
+    assert fa.window_block(8192, 100) == 128
+    assert fa.window_block(8192, 4096) == fa._WINDOW_BLOCK_CAP
+
+
+def _normalised_jaxpr_digest(fn, *args) -> str:
+    import hashlib
+    import re
+    text = re.sub(r" at \S+:\d+", "", str(jax.make_jaxpr(fn)(*args)))
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("fused,digest", [(True, "e8c973467ff66f11"),
+                                          (False, "9626d241fbf329fd")])
+def no_window_is_the_parents_call_test(fused, digest, monkeypatch):
+    """``window=None`` traces to what the parent commit of ISSUE 36
+    (5f633c2) traced: the digests are of the parent's jaxpr of this call —
+    kernel bodies, grids, block maps and names, source positions stripped —
+    for the fused and the split backward."""
+    monkeypatch.setattr(fa, "_fused_dqp_cap",
+                        (lambda: 1 << 40) if fused else (lambda: 0))
+    q = jax.ShapeDtypeStruct((1, 2048, 2, 128), jnp.bfloat16)
+
+    def loss(q, k, v, *window):
+        return flash_attention(q, k, v, 128 ** -0.5, True, 1024, 2048, False,
+                               1024, 1024, *window).astype(jnp.float32).sum()
+
+    grad = jax.grad(loss, (0, 1, 2))
+    assert _normalised_jaxpr_digest(grad, q, q, q) == digest
+    assert _normalised_jaxpr_digest(
+        lambda q, k, v: grad(q, k, v, None), q, q, q) == digest
+    names = str(jax.make_jaxpr(lambda q, k, v: jax.grad(
+        lambda *a: loss(*a, 512), (0, 1, 2))(q, k, v))(q, q, q))
+    assert "flash_fwd_window" in names and "_causal" not in names
+    assert ("flash_bwd_fused_window" in names) == fused
+    assert ("flash_bwd_dq_window" in names) == (not fused)
+
+
+def a_window_as_long_as_the_sequence_is_the_causal_call_test():
+    q, k, v, _ = _window_inputs(64)
+    np.testing.assert_array_equal(
+        np.asarray(fa.attention(q, k, v, window=64)),
+        np.asarray(fa.attention(q, k, v)))
+    with pytest.raises(ValueError, match="window"):
+        fa.attention(q, k, v, causal=False, window=8)

@@ -1,0 +1,479 @@
+"""Laguna-S-2.1's layers through the normal path (ISSUE 36): the program
+against the plain reference ``benchmark/reference/laguna_s_2_1.py`` in
+logits, loss and gradients at toy widths; the SHARE test (all expert-parallel
+ranks' routed parts plus the shared expert once = the uncut layer); the
+window below, at and above the sequence; YaRN's frequencies and the partial
+rotation against a transcription of HF's; the held-expert dispatch at its
+extremes; the new flags' and keys' refusals; scopes and gauges."""
+import importlib
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from homebrewnlp_tpu.analysis.cost_ledger import scope_key
+from homebrewnlp_tpu.config import BlockArgs, ModelParameter
+from homebrewnlp_tpu.core import scope
+from homebrewnlp_tpu.core.tensor import nt
+from homebrewnlp_tpu.model import Model, moe as moe_mod, remat
+from homebrewnlp_tpu.model.spatial import (_standard_flags, rotary,
+                                           yarn_inv_freq)
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+FULL = "attention-yarn-q_heads4-kv_heads2-gate-rotary_pct50-theta500000"
+SLIDE = "attention-rope-q_heads6-kv_heads2-gate-window32"
+MOE = "moe-silu-shared_expert"
+
+
+def _block(*layers):
+    return {"skip": True, "layer": list(layers)}
+
+
+# head counts 4 and 6 over 2 K/V heads on a stream of 2 x 16, 16 routed
+# experts of which 4 are held, 4 a token, a window of 32 on 128 positions
+TINY = {"depth": 1, "heads": 2, "features_per_head": 16,
+        "sequence_length": 128, "train_batch_size": 2, "vocab_size": 384,
+        "experts": 16, "experts_held": 4, "moe_top_k": 4, "expert_width": 24,
+        "rope_yarn_original_positions": 64, "tpu_size": 1,
+        "use_checkpointing": False,
+        "input_block_config": [_block("norm-rms-scale", FULL),
+                               _block("norm-rms-scale", "mlp-silu")],
+        "block_config": [_block("norm-rms-scale", SLIDE),
+                         _block("norm-rms-scale", MOE)] * 3
+        + [_block("norm-rms-scale", FULL), _block("norm-rms-scale", MOE)],
+        "output_block_config": [{"layer": ["norm-rms-scale"]}]}
+
+
+def _reference():
+    return importlib.import_module("benchmark.reference.laguna_s_2_1")
+
+
+def _config(dtype: str = "float32", **extra) -> dict:
+    with open(os.path.join(REPO, "configs", "laguna_s_2_1.json")) as f:
+        return {**json.load(f), **TINY, "calculation_dtype": dtype, **extra}
+
+
+def _build(dtype: str = "float32", **extra):
+    config = _config(dtype, **extra)
+    params = ModelParameter(config)
+    assert not params.unknown_config_keys
+    model = Model(params)
+    rng = np.random.default_rng(5)
+    shape = (config["train_batch_size"], config["sequence_length"], 1)
+    tokens = rng.integers(0, 256, shape).astype(np.int32)
+    batch = {"token_x": tokens, "token_y": np.roll(tokens, -1, axis=1)}
+    return config, params, model, batch, model.init(batch, seed=13)
+
+
+def _logits_and_loss(model, variables, batch):
+    info = jax.jit(lambda v, b: model.apply(v, b))(variables, batch)
+    return (np.asarray(info.token_out.data.astype(jnp.float32))[:, :, 0, :],
+            float(info.total_loss.data))
+
+
+def _error(got, want) -> float:
+    return float(np.max(np.abs(want - got)) / np.max(np.abs(want)))
+
+
+# ---- the program against the reference ---------------------------------------
+
+@pytest.mark.parametrize("dtype,tolerance,extra", [
+    # float32 against float32: only the order of sums differs, so this pins
+    # the EQUATIONS: a missing gate, plain top-k weights, a window off by
+    # one, rotate-half over the wrong width are off by orders of magnitude
+    ("float32", 2e-5, {}),
+    # the sequence BELOW the window (32 of it on 16 positions: every layer
+    # sees the whole triangle), AT it, and above (the default: 128)
+    ("float32", 2e-5, {"sequence_length": 16}),
+    ("float32", 2e-5, {"sequence_length": 32}),
+    # every expert held (the uncut layer, OLMoE's dispatch) and a share
+    # that is not the first
+    ("float32", 2e-5, {"experts_held": 0}),
+    ("float32", 2e-5, {"experts_held": 4, "experts_first": 8}),
+    # fewer held than a token's choices: two slots a token
+    ("float32", 2e-5, {"experts_held": 2, "experts_first": 3}),
+    # two periods: the second one's layers have weights of their own (the
+    # shared expert's flag is not the DSL's cross-layer ``shared``)
+    ("float32", 2e-5, {"depth": 2}),
+    # the configuration's bfloat16, at the cells' bound
+    ("bfloat16", 2 ** -4, {})],
+    ids=["float32", "below_window", "at_window", "all_held", "third_share",
+         "two_slots", "two_periods", "bfloat16"])
+def program_matches_reference_test(dtype, tolerance, extra):
+    config, _, model, batch, variables = _build(dtype, **extra)
+    assert sum("moe_0/normal_var4" in name for name in variables) \
+        == 4 * config["depth"]
+    got, loss = _logits_and_loss(model, variables, batch)
+    want = np.asarray(_reference().forward(variables, batch["token_x"][..., 0],
+                                           config))
+    assert got.shape == want.shape
+    assert _error(got, want) < tolerance
+    from benchmark.reference import common
+    want_loss = float(common.loss_of(want, batch["token_y"][..., 0], 0.0))
+    assert abs(want_loss - loss) <= (2.0 ** -18 if dtype == "float32"
+                                     else 2.0 ** -5)
+
+
+def loss_and_gradients_match_reference_test():
+    config, _, model, batch, variables = _build()
+    ref = _reference()
+    tokens, targets = batch["token_x"][..., 0], batch["token_y"][..., 0]
+    got = jax.jit(jax.grad(lambda v: model.apply(v, batch).total_loss.data))(
+        variables)
+    want = jax.grad(lambda v: ref.train_loss(v, tokens, targets, config))(
+        variables)
+    assert set(got) == set(want)
+    for name in got:
+        scale = float(jnp.max(jnp.abs(want[name]))) or 1.0
+        assert float(jnp.max(jnp.abs(got[name] - want[name]))) / scale < 2e-4, \
+            name
+
+
+def reference_at_the_next_precision_below_fails_test():
+    """The reference with a float8 (e4m3) residual stream misses the bound
+    that the program in bfloat16 holds: a lower precision than the
+    configuration states comes out as not correct."""
+    config, _, model, batch, variables = _build("bfloat16")
+    ref = _reference()
+    tokens = batch["token_x"][..., 0]
+    want = np.asarray(ref.forward(variables, tokens, config))
+    low = np.asarray(ref.forward(variables, tokens, config,
+                                 stream_dtype=jnp.float8_e4m3fn))
+    got, _ = _logits_and_loss(model, variables, batch)
+    assert _error(got, want) < 2 ** -4 < _error(low, want)
+
+
+# ---- the share test ------------------------------------------------------------
+
+def _moe_layer(params, weights, x):
+    """Layer ``moe-silu-shared_expert`` of ``params`` on ``x [b, s, heads,
+    features]`` with the given weights (the reference's short names)."""
+    ref = _reference()
+    ctx = scope.Context("apply", params={
+        path + "/var0": jnp.asarray(weights[short])
+        for short, path in ref.SPARSE.items()})
+    with scope.context(ctx):
+        return scope.scoped("moe_", moe_mod.moe, BlockArgs(
+            params, nt(x, [params.batch_dim, params.sequence_dim]
+                       + list(params.feature_dims)), ["silu", "shared_expert"])).data
+
+
+def the_shares_add_up_to_the_uncut_layer_test():
+    """Four expert-parallel ranks of four experts each: their routed parts,
+    with the shared expert that every rank computes alike counted once, add
+    up to what the uncut reference gives for the whole layer — and so do
+    the reference's own shares."""
+    ref = _reference()
+    rng = np.random.default_rng(2)
+    heads, width, n_exp, inter = 2, 16, 16, 24
+
+    def normal(*shape):
+        return jnp.asarray(rng.normal(size=shape).astype(np.float32) * 0.3)
+
+    whole = {"w_router": normal(heads, width, n_exp),
+             "w_gate": normal(n_exp, heads, width, inter),
+             "w_up": normal(n_exp, heads, width, inter),
+             "w_down": normal(n_exp, inter, heads, width),
+             "s_gate": normal(heads, width, inter),
+             "s_up": normal(heads, width, inter),
+             "s_down": normal(inter, heads, width)}
+    m = normal(2, 128, heads, width)
+    weights = ref.route({**whole, "w_norm": jnp.ones((heads, width))}, m, 4,
+                        True, 2.5, 1e-6)[1]
+    # the uncut layer on the already-normed input: rms's scale is one and
+    # its input has unit mean square only roughly, so route() above and the
+    # layer below must see the SAME m: give the layer the normed m
+    m = ref.rms(m, jnp.ones((heads, width)), 1e-6)
+    shared = ref.swiglu(m, whole["s_gate"], whole["s_up"], whole["s_down"])
+    uncut = shared + ref.routed_part(whole, m, weights, 0, n_exp)
+
+    parts, ref_parts = [], []
+    for rank in range(4):
+        first = 4 * rank
+        params = ModelParameter(_config(experts_held=4, experts_first=first))
+        share = dict(whole, **{k: whole[k][first:first + 4]
+                               for k in ("w_gate", "w_up", "w_down")})
+        parts.append(_moe_layer(params, share, m) - shared)
+        ref_parts.append(ref.routed_part(share, m, weights, first, 4))
+        np.testing.assert_allclose(np.asarray(parts[-1]),
+                                   np.asarray(ref_parts[-1]), atol=2e-5)
+        assert float(jnp.max(jnp.abs(parts[-1]))) > 1e-3
+    np.testing.assert_allclose(np.asarray(shared + sum(parts)),
+                               np.asarray(uncut), atol=5e-5)
+    np.testing.assert_allclose(np.asarray(shared + sum(ref_parts)),
+                               np.asarray(uncut), atol=5e-5)
+    # and the program's own uncut layer
+    np.testing.assert_allclose(
+        np.asarray(_moe_layer(ModelParameter(_config(experts_held=0)), whole,
+                              m)), np.asarray(uncut), atol=5e-5)
+
+
+# ---- the held-expert dispatch ----------------------------------------------------
+
+@pytest.mark.parametrize("first,held", [(0, 4), (5, 3), (14, 2), (0, 16)])
+def held_slots_keep_every_held_choice_test(first, held):
+    rng = np.random.default_rng(held)
+    t, k, n = 64, 4, 16
+    logits = jnp.asarray(rng.normal(size=(t, n)).astype(np.float32))
+    weights, experts = moe_mod.route(logits, k, True, 2.5)
+    np.testing.assert_allclose(np.asarray(weights).sum(-1), 2.5, rtol=1e-5)
+    w, local, real = (np.asarray(a) for a in moe_mod.held_slots(
+        weights, experts, first, held))
+    assert w.shape == (t, min(k, held))
+    for row in range(t):
+        want = {int(e) - first: float(p) for e, p in zip(
+            np.asarray(experts)[row], np.asarray(weights)[row])
+            if first <= e < first + held}
+        got = {int(e): float(p) for e, p, r in zip(local[row], w[row],
+                                                   real[row]) if r}
+        assert got == pytest.approx(want)
+        assert np.all(local[row][~real[row]] == held)
+        assert np.all(w[row][~real[row]] == 0)
+    assert moe_mod.held_rows_bound(t, k, held) == t * min(k, held)
+
+
+def every_held_pair_is_computed_when_all_land_here_test():
+    """The router sends EVERY token's every choice to the held experts: the
+    static buffer is full to its last row and nothing is dropped; with none
+    landing here the routed part is exactly zero (and finite, whatever the
+    unwritten rows hold) and so are the held experts' gradients."""
+    ref = _reference()
+    config, params, model, batch, variables = _build(experts_held=4)
+    tokens, targets = batch["token_x"][..., 0], batch["token_y"][..., 0]
+    for bias, share in ((+40.0, 1.0), (-40.0, 0.0)):
+        skewed = dict(variables)
+        for name in variables:
+            if name.endswith("moe_0/normal_var0/var0"):
+                w = np.array(variables[name])
+                w[..., :4] += bias / w.shape[0] / w.shape[1] * np.sign(
+                    np.asarray(variables[name.replace(
+                        "moe_0/normal_var0", "norm_0/normal_var0")]))[..., None]
+                skewed[name] = jnp.asarray(w)
+        # a positive input to the router is not guaranteed: read the share
+        info = model.apply(skewed, batch, layer_stats=True)
+        held = np.asarray(info.layer_stats["moe_held_pairs"])
+        routed = np.asarray(info.layer_stats["moe_routed_pairs"])
+        assert routed.tolist() == [2 * 128 * 4] * 4
+        got, _ = _logits_and_loss(model, skewed, batch)
+        want = np.asarray(ref.forward(skewed, tokens, config))
+        assert _error(got, want) < 2e-5, (bias, held / routed)
+        grads = jax.grad(lambda v: model.apply(v, batch).total_loss.data)(
+            skewed)
+        assert all(np.all(np.isfinite(np.asarray(g))) for g in grads.values())
+        del share
+
+
+def routing_without_the_new_keys_is_olmoes_test():
+    """``route`` without renormalisation or scale traces to the softmax and
+    the top-k alone, as before ISSUE 36."""
+    logits = jax.ShapeDtypeStruct((8, 16), jnp.float32)
+    plain = str(jax.make_jaxpr(lambda x: moe_mod.route(x, 4))(logits))
+    assert plain == str(jax.make_jaxpr(
+        lambda x: moe_mod.route(x, 4, False, 1.0))(logits))
+    assert "div" in str(jax.make_jaxpr(
+        lambda x: moe_mod.route(x, 4, True, 1.0))(logits)).split("top_k")[1]
+    assert "div" not in plain.split("top_k")[1]
+    assert "mul" not in plain.split("top_k")[1]
+
+
+def the_step_reports_the_held_share_test():
+    """At seeded initialisation the held experts get about ``held /
+    experts`` of the pairs; the trainer's metrics carry the counter and both
+    gauges, and the start-up line the static buffer's rows."""
+    config, params, model, batch, variables = _build()
+    info = model.apply(variables, batch, layer_stats=True)
+    held = np.asarray(info.layer_stats["moe_held_pairs"])
+    routed = np.asarray(info.layer_stats["moe_routed_pairs"])
+    assert routed.tolist() == [1024.0] * 4
+    assert np.all((held > 0.15 * routed) & (held < 0.35 * routed))
+    assert np.all(np.asarray(
+        info.layer_stats["moe_load_max_over_mean"]) >= 1.0)
+    from homebrewnlp_tpu.train import _LAYER_STATS, _info_metrics
+    metrics = _info_metrics(info)
+    assert float(metrics["moe_held_pairs"]) == held.sum()
+    assert float(metrics["moe_held_pair_share"]) == pytest.approx(
+        held.sum() / routed.sum())
+    assert float(metrics["moe_held_pair_share_max"]) == pytest.approx(
+        (held / routed).max())
+    assert {"moe_held_pairs", "moe_held_pair_share",
+            "moe_held_pair_share_max"} <= set(_LAYER_STATS)
+    assert remat.moe_held_rows(params) == 2 * 128 * 4
+    assert remat.moe_held_rows(ModelParameter(_config(experts_held=0))) == 0
+    from homebrewnlp_tpu import telemetry
+    from homebrewnlp_tpu.train import Trainer
+    line = Trainer(params, model).publish_stash_plan()
+    assert line.startswith("remat stash:")
+    assert line.endswith("moe held rows bound 1024")
+    assert telemetry.snapshot()["hbnlp_moe_held_rows_bound"]["series"][()] \
+        == 1024
+
+
+def the_experts_stash_counts_the_bounds_rows_test():
+    """model/remat.py's ``experts`` kind: the saved outputs of a layer that
+    holds a share are its whole static buffer."""
+    params = ModelParameter(_config("bfloat16"))
+    layers, nbytes = remat._experts_stash(params, 1)
+    rows = 2 * 128 * 4
+    assert layers == 4
+    assert nbytes == 4 * (rows * (2 * 24 + 32) * 2 + (2 * rows + 5) * 4)
+
+
+# ---- rotary positions ------------------------------------------------------------
+
+@pytest.mark.parametrize("theta,width,factor,original,fast,slow", [
+    (500000.0, 64, 128.0, 8192, 32.0, 1.0),       # the published global layers
+    (500000.0, 8, 128.0, 64, 32.0, 1.0),          # the toy size
+    (10000.0, 128, 4.0, 4096, 32.0, 1.0),
+    (10000.0, 32, 1.0, 2048, 16.0, 2.0)])
+def yarn_frequencies_are_hfs_test(theta, width, factor, original, fast, slow):
+    """Against the transcription of HF's ``_compute_yarn_parameters`` in the
+    reference, and its landmarks: the fastest frequencies stay, the slowest
+    are divided by the factor."""
+    got = yarn_inv_freq(theta, width, factor, original, fast, slow)
+    want = _reference().yarn_inv_freq(theta, width, factor, original, fast,
+                                      slow)
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    plain = theta ** (-np.arange(0, width, 2) / width)
+    assert got[0] == pytest.approx(plain[0])
+    assert got[-1] == pytest.approx(plain[-1] / factor, rel=1e-6)
+    assert np.all(np.diff(got) < 0)
+
+
+@pytest.mark.parametrize("width", [None, 8, 16])
+def partial_rotation_is_hfs_test(width):
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.normal(size=(2, 24, 3, 16)).astype(np.float32))
+    inv_freq = yarn_inv_freq(500000.0, width or 16, 128.0, 8, 32.0, 1.0)
+    got = rotary(x, 500000.0, width, inv_freq, 1.4852030263919618)
+    want = _reference().rope(x, inv_freq, 1.4852030263919618)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
+    if width == 8:
+        np.testing.assert_array_equal(np.asarray(got)[..., 8:],
+                                      np.asarray(x)[..., 8:])
+    # the plain call is what it was
+    np.testing.assert_allclose(
+        np.asarray(rotary(x, 10000.0)),
+        np.asarray(_reference().rope(
+            x, _reference().default_inv_freq(10000.0, 16), 1.0)), atol=2e-6)
+
+
+# ---- refusals ----------------------------------------------------------------------
+
+@pytest.mark.parametrize("flags,match", [
+    (["rope", "sliding512"], "sliding512"),
+    (["rope", "window"], "window"),
+    (["rope", "yarn"], "exactly one"),
+    (["gate"], "exactly one"),
+    (["nope", "theta10000"], "nope"),
+    (["rope", "q_heads8"], "come together"),
+    (["rope", "q_heads6", "kv_heads4"], "must divide")])
+def unknown_attention_flags_refuse_by_name_test(flags, match):
+    with pytest.raises(ValueError, match=match):
+        _standard_flags(flags)
+
+
+def known_attention_flags_parse_test():
+    assert _standard_flags(FULL.split("-")[1:]) == {
+        "yarn": True, "q_heads": 4, "kv_heads": 2, "gate": True,
+        "rotary_pct": 50, "theta": 500000}
+    assert _standard_flags(["rope", "qk_norm"]) == {"rope": True,
+                                                    "qk_norm": True}
+
+
+@pytest.mark.parametrize("extra,match", [
+    ({"experts_held": 17}, "exceeds experts"),
+    ({"experts_held": 4, "experts_first": 13}, "exceeds experts"),
+    ({"experts_held": 0, "experts_first": 2}, "without experts_held"),
+    ({"experts_held": -1}, "whole number"),
+    ({"expert_width": 1.5}, "whole number"),
+    ({"moe_route_scale": 0}, "moe_route_scale"),
+    ({"rope_yarn_factor": 0.5}, "rope_yarn"),
+    ({"rope_yarn_beta_fast": 1, "rope_yarn_beta_slow": 1}, "rope_yarn")])
+def bad_keys_refuse_by_name_test(extra, match):
+    with pytest.raises(ValueError, match=match):
+        ModelParameter(_config(**extra))
+
+
+@pytest.mark.parametrize("layer,match", [
+    ("moe-silu-shared_expert-capacity2", "capacity2"),
+    (FULL.replace("rotary_pct50", "rotary_pct30"), "rotary_pct30")])
+def unknown_layer_flags_refuse_at_init_test(layer, match):
+    config = _config()
+    config["block_config"] = [_block("norm-rms-scale", layer)]
+    model = Model(ModelParameter(config))
+    tokens = np.zeros((2, 128, 1), np.int32)
+    with pytest.raises(ValueError, match=match):
+        model.init({"token_x": tokens, "token_y": tokens}, seed=1)
+
+
+# ---- the repo's config, scopes ---------------------------------------------------
+
+def the_repos_config_is_the_published_model_test():
+    """``configs/laguna_s_2_1.json`` against the catalog's published keys
+    that the benchmark's file repeats."""
+    with open(os.path.join(REPO, "configs", "laguna_s_2_1.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "laguna_s_2_1.json")) as f:
+        doc = json.load(f)
+    params = ModelParameter(config)
+    assert not params.unknown_config_keys
+    assert params.heads * params.features_per_head == doc["hidden_size"]
+    assert params.features_per_head == doc["head_dim"]
+    assert params.intermediate[0].size == doc["intermediate_size"]
+    assert params.expert_intermediate[0].size == doc["moe_intermediate_size"] \
+        == doc["shared_expert_intermediate_size"]
+    assert params.expert_dim.size == doc["published"]["num_experts"]
+    assert params.moe_top_k == doc["num_experts_per_tok"]
+    assert params.moe_route_scale == doc["moe_routed_scaling_factor"]
+    assert params.moe_norm_topk == doc["norm_topk_prob"]
+    assert params.vocab_size == doc["published"]["vocab_size"]
+    layers = [b["layer"][1] for cfgs, times in (
+        (config["input_block_config"], 1), (config["block_config"],
+                                            config["depth"]),
+        (config["output_block_config"][:-1], 1))
+        for _ in range(times) for b in cfgs]
+    attention = [l for l in layers if l.startswith("attention")]
+    assert len(attention) == doc["published"]["num_hidden_layers"]
+    for layer, kind, heads in zip(attention, doc["layer_types"],
+                                  doc["num_attention_heads_per_layer"]):
+        flags = _standard_flags(layer.split("-")[1:])
+        assert flags["q_heads"] == heads
+        assert flags["kv_heads"] == doc["num_key_value_heads"]
+        assert flags.get("window") == (doc["sliding_window"]
+                                       if kind == "sliding_attention"
+                                       else None)
+        assert "gate" in flags
+        assert ("yarn" in flags) == (kind == "full_attention")
+    rope = doc["rope_parameters"]["full_attention"]
+    assert (params.rope_yarn_factor, params.rope_yarn_original_positions,
+            params.rope_yarn_beta_fast, params.rope_yarn_beta_slow,
+            params.rope_yarn_attention_factor) == (
+        rope["factor"], rope["original_max_position_embeddings"],
+        rope["beta_fast"], rope["beta_slow"], rope["attention_factor"])
+    mlps = [l.split("-")[0] for l in layers if not l.startswith("attention")]
+    assert mlps == ["mlp" if k == "dense" else "moe"
+                    for k in doc["mlp_layer_types"]]
+    assert doc["config"]["experts_held"] == doc["num_experts"] == 8
+
+
+@pytest.mark.parametrize("path,scope_name", [
+    ("jit(step_fn)/jvp(gpt0)/body0/checkpoint/block0_1_0/moe_0/shared/dot_general",
+     "body/moe/shared"),
+    ("jit(step_fn)/transpose(jvp(gpt0))/body0/block0_0_0/attention_0/gate/logistic",
+     "body/attention/gate"),
+    ("jit(step_fn)/jvp(gpt0)/body0/block0_0_0/attention_0/rope/mul",
+     "body/attention"),
+    # a leading block is a body layer that runs once
+    ("jit(step_fn)/jvp(gpt0)/input0/lang_inp0_0/attention_0/flash_attention/x",
+     "body/attention"),
+    ("jit(step_fn)/jvp(gpt0)/input0/lang_inp1_0/mlp_0/dot_general",
+     "body/mlp"),
+    ("jit(step_fn)/jvp(gpt0)/input0/gather0/embed0/gather", "input/embed"),
+    ("jit(step_fn)/jvp(gpt0)/body0/block0_1_0/mamba_0/gate_norm/mul",
+     "body/mamba/gate_norm")])
+def the_new_scopes_fold_test(path, scope_name):
+    assert scope_key(path) == scope_name
