@@ -8,7 +8,8 @@ full width of the flagship (``configs/32big_mixer.json``: d4096 = 8 heads x
 chips present, steps, paths; ``save_graph`` on so the trainer reports what it
 compiled; the continuous engine pinned so a serving fallback is a failure):
 
-  kernels  scripts/kernel_parity.py — compiled flash and map-mixer kernels
+  kernels  scripts/kernel_parity.py — compiled flash, map-mixer and
+           delta-solve kernels
            against their XLA references at float32 "highest"
   train    main.py --run_mode train, 10 steps on TFRecords written by
            scripts/text2records.py from a seeded corpus; writes a checkpoint
@@ -256,14 +257,15 @@ def leg_kernels(ctx):
     log = os.path.join(REPORT_DIR, "kernels.log")
     cmd = [sys.executable, "scripts/kernel_parity.py"]
     if ctx["rehearsal"]:
-        cmd += ["--flash-seq", "256", "--mixer-batch", "2"]
+        cmd += ["--flash-seq", "256", "--mixer-batch", "2",
+                "--solve-chunks", "2"]
     rc, wall = run_to_end("kernels", cmd, log, 420)
     rows = []
     with open(log, errors="replace") as f:
         for line in f:
             if line.startswith('{"kernel"'):
                 rows.append(json.loads(line))
-    check(rc == 0 and len(rows) == 2,
+    check(rc == 0 and len(rows) == 3,
           f"kernel parity failed (rc {rc}):\n{tail(log)}")
     if not ctx["rehearsal"]:
         check(all(r["implementation"] == "pallas" for r in rows),

@@ -51,10 +51,17 @@ hands out ``V'`` and the entering states in the calculation dtype, as the
 matmuls take them (``inter_chunk``); the products that need no state
 (``intra_chunk``) and ``O`` (``state_out``) are batched over all chunks.
 
-The unit lower triangular system is solved on the MXU by blocked forward
-substitution with a doubling block (``_inverse_unit_lower``): twelve 64 x 64
-matmuls a chunk a head at chunk 64, in float32 at ``highest`` precision, no
-serial loop, and an autodiff backward of the same shape.  (The product ``(I -
+The unit lower triangular system (``_inverse_unit_lower``, a
+``jax.custom_vjp`` whose only residual is the inverse) is solved by the
+Pallas pair of parallel/delta_solve.py where ``solve_kernel_applies`` (a TPU,
+a power-of-two chunk from 16 to 128, whole tiles of 128 systems a group of
+heads): plain substitution in exact float32 on the VPU with the system's
+index on the lanes, every level in VMEM, and the inverse's own backward ``-X^T
+dX X^T`` as two ``highest`` matmuls in one kernel.  Elsewhere — the CPU, odd
+chunks, and as the kernels' oracle — XLA runs blocked forward substitution
+with a doubling block (``_blocked_inverse``): twelve 64 x 64 matmuls a chunk
+a head at chunk 64, in float32 at ``highest`` precision, no serial loop, and
+two more for the backward.  (The product ``(I -
 N)(I + N^2)(I + N^4) ..`` of the nilpotent ``N`` needs as many matmuls and
 is NOT used: with correlated keys the powers of ``N`` grow to 1e7 and beyond
 before they cancel, and float32 returns garbage.)  Decays, cumulative
@@ -86,6 +93,8 @@ from ..core import scope
 from ..core.dims import Dim
 from ..core.tensor import NamedTensor, nt, transpose_to
 from ..parallel.causal_conv import causal_conv_silu, kernel_applies
+from ..parallel.delta_solve import (inverse_unit_lower, inverse_unit_lower_bwd,
+                                    solve_kernel_applies)
 from .backend import ConstantInit, UniformInit, normal_var
 from .loss import _matmul
 from .normalization import _norm_core
@@ -113,16 +122,13 @@ def _dot(a, b):
     return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
 
 
-@jax.custom_vjp
-def _inverse_unit_lower(strict):
-    """``(I + strict)^-1`` for strictly lower triangular ``strict [..., l,
-    l]`` (float32) by blocked forward substitution, doubling the block: with
-    ``D`` the inverse of the diagonal blocks of width ``n`` (``n = 1``: the
-    identity) and ``L`` the part of ``strict`` that joins two neighbouring
-    blocks into one of width ``2 n``, ``[[A, 0], [C, B]]^-1 = [[A^-1, 0],
-    [-B^-1 C A^-1, B^-1]]`` is ``D - D L D`` for all blocks at once.  The
-    backward is the inverse's own, ``d strict = -X^T dX X^T`` below the
-    diagonal: two matmuls from ``X`` alone, no level's intermediate kept."""
+def _blocked_inverse(strict):
+    """``(I + strict)^-1`` by blocked forward substitution, doubling the
+    block — XLA's form: with ``D`` the inverse of the diagonal blocks of
+    width ``n`` (``n = 1``: the identity) and ``L`` the part of ``strict``
+    that joins two neighbouring blocks into one of width ``2 n``, ``[[A, 0],
+    [C, B]]^-1 = [[A^-1, 0], [-B^-1 C A^-1, B^-1]]`` is ``D - D L D`` for all
+    blocks at once."""
     l = strict.shape[-1]
     size = 1 << max(0, l - 1).bit_length()
     if size != l:           # the inverse of the leading block is unchanged
@@ -138,16 +144,40 @@ def _inverse_unit_lower(strict):
     return inv[..., :l, :l]
 
 
+def _takes_kernel(t) -> bool:
+    return solve_kernel_applies(t.shape[-1], math.prod(t.shape[:-2]))
+
+
+@jax.custom_vjp
+def _inverse_unit_lower(strict):
+    """``(I + strict)^-1`` for strictly lower triangular ``strict [..., l,
+    l]`` (float32): the Pallas pair of parallel/delta_solve.py where
+    ``solve_kernel_applies``, else ``_blocked_inverse`` (the CPU, odd chunks;
+    the kernels' oracle).  The backward is the inverse's own, ``d strict =
+    -X^T dX X^T`` below the diagonal: two matmuls from ``X`` alone, no
+    level's intermediate kept."""
+    if _takes_kernel(strict):
+        return inverse_unit_lower(strict)
+    return _blocked_inverse(strict)
+
+
 def _inverse_fwd(strict):
     inv = _inverse_unit_lower(strict)
     return inv, inv
 
 
-def _inverse_bwd(inv, g):
+def _xla_inverse_bwd(inv, g):
+    """``-X^T dX X^T`` below the diagonal, as two ``highest`` matmuls."""
     inv_t = jnp.swapaxes(inv, -1, -2)
     l = inv.shape[-1]
     below = jnp.arange(l)[:, None] > jnp.arange(l)[None, :]
-    return (jnp.where(below, -_dot(inv_t, _dot(g, inv_t)), 0.0),)
+    return jnp.where(below, -_dot(inv_t, _dot(g, inv_t)), 0.0)
+
+
+def _inverse_bwd(inv, g):
+    if _takes_kernel(inv):
+        return (inverse_unit_lower_bwd(inv, g),)
+    return (_xla_inverse_bwd(inv, g),)
 
 
 _inverse_unit_lower.defvjp(_inverse_fwd, _inverse_bwd)
@@ -370,5 +400,14 @@ def _output_bytes(params: ModelParameter) -> int:
         * jnp.dtype(params.calculation_dtype).itemsize
 
 
+def _solve(params: ModelParameter):
+    """``(chunk, systems)`` of one call of ``_inverse_unit_lower``: a chunk
+    and a head each, over one group of heads (``grouped_rule``)."""
+    bsz, s = params.batch_dim.size, params.sequence_dim.size
+    chunk = min(params.delta_chunk, s)
+    return chunk, bsz * max(1, s // chunk) \
+        * _group_heads(bsz, s, params.delta_heads, chunk)
+
+
 gated_delta.recurrent = Recurrent(_state_bytes, _conv, SAVED_NAMES,
-                                  _output_bytes)
+                                  _output_bytes, _solve)

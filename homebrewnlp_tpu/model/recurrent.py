@@ -2,7 +2,7 @@
 what they refuse and the layout they take, the per-channel float32
 parameters and their initialisers, the causal depthwise conv's XLA form, and what a layer declares of itself for
 ``model/remat.py`` (its chunk states, its conv, the output it offers to save
-across the block's replay)."""
+across the block's replay, the triangular systems it solves)."""
 from __future__ import annotations
 
 import typing
@@ -32,12 +32,19 @@ class Recurrent(typing.NamedTuple):
     sequence x delta_heads x delta_value_features`` in the calculation
     dtype).  ``mamba`` offers nothing: its scan has no inner
     ``jax.checkpoint``, so the replay's forward IS the pass that makes the
-    backward's residuals, and a saved output would skip none of it."""
+    backward's residuals, and a saved output would skip none of it.
+
+    A layer that solves a unit triangular system a chunk declares it:
+    ``solve(params)`` — ``(chunk, matrices a call)`` as
+    ``parallel/delta_solve.solve_kernel_applies`` takes them
+    (``gated_delta``: the systems of one group of heads); None = none."""
     state_bytes: typing.Callable[[ModelParameter], int]
     conv: typing.Callable[[ModelParameter], typing.Tuple[int, int, int]]
     saved_names: typing.Tuple[str, ...] = ()
     saved_bytes: typing.Optional[
         typing.Callable[[ModelParameter], int]] = None
+    solve: typing.Optional[
+        typing.Callable[[ModelParameter], typing.Tuple[int, int]]] = None
 
 
 def token_layout(args: BlockArgs, layer: str, chunk: int):

@@ -451,6 +451,22 @@ def conv_kernel_layers(params: ModelParameter, backend=None) -> int:
     return layers * params.depth
 
 
+def solve_kernel_layers(params: ModelParameter, backend=None
+                        ) -> typing.Optional[int]:
+    """How many recurrent mixers of the step take the Pallas pair for their
+    triangular solve (``parallel/delta_solve.py``), by the predicate the
+    layer itself calls on the systems it declares; None where no layer
+    declares a solve.  ``Trainer`` publishes it as
+    ``hbnlp_delta_solve_kernel_layers``."""
+    from ..parallel.delta_solve import solve_kernel_applies
+    solves = [spec.solve(params) for spec in _recurrent_layers(params)
+              if spec.solve is not None]
+    if not solves:
+        return None
+    return params.depth * sum(solve_kernel_applies(chunk, matrices, backend)
+                              for chunk, matrices in solves)
+
+
 def stash_line(plan: typing.Dict[str, typing.Tuple[int, int]]) -> str:
     """The start-up line beside ``placement_report``'s."""
     return "remat stash: " + "; ".join(

@@ -641,10 +641,14 @@ class Trainer:
         mixers' (``mamba``, ``gated_delta``) chunk states alive at once for
         the backward (``ssd_state_bytes``), and
         ``hbnlp_mamba_conv_kernel_layers``: how many of those layers took
-        the Pallas conv (``conv_kernel_layers``).  Set when the step is
-        built; returns the start-up line that says the same."""
+        the Pallas conv (``conv_kernel_layers``), and
+        ``hbnlp_delta_solve_kernel_layers``: how many took the Pallas pair
+        for their triangular solve (``solve_kernel_layers``; 0 where no
+        layer has one).  Set when the step is built; returns the start-up
+        line that says the same."""
         from ..model.remat import (conv_kernel_layers, moe_held_rows,
-                                   ssd_state_bytes, stash_line, stash_plan)
+                                   solve_kernel_layers, ssd_state_bytes,
+                                   stash_line, stash_plan)
         plan = stash_plan(self.params, self.mesh)
         r = telemetry.registry()
         held_rows = moe_held_rows(self.params)
@@ -664,6 +668,11 @@ class Trainer:
                 "recurrent mixers (mamba, gated_delta) of the built step "
                 "whose conv is the Pallas kernel pair (0 on the XLA "
                 "fallback)").set(conv_layers)
+        solve_layers = solve_kernel_layers(self.params)
+        r.gauge("hbnlp_delta_solve_kernel_layers",
+                "gated_delta layers of the built step whose triangular solve "
+                "is the Pallas kernel pair (0 on the XLA blocked form, and "
+                "without such a layer)").set(solve_layers or 0)
         nbytes = r.gauge("hbnlp_remat_stash_bytes",
                          "per-device bytes riding the memory strategy's "
                          "residuals instead of being replayed", ("kind",))
@@ -676,6 +685,8 @@ class Trainer:
         return stash_line(plan) + (
             f"; ssd chunk states {states} bytes a device; conv kernel "
             f"{conv_layers} layers" if states else "") + (
+            f"; solve kernel {solve_layers} layers"
+            if solve_layers is not None else "") + (
             f"; moe held rows bound {held_rows}" if held_rows else "")
 
     def lowered(self, state: TrainState, batch: typing.Dict[str, jax.Array]):

@@ -14,12 +14,22 @@ Prints one JSON line per kernel and a final ``{"ok": ...}`` line; exits 1 if
 any comparison exceeds :data:`TOLERANCE`, or if a dispatcher took the dense
 path on an accelerator (no ``tpu_custom_call`` in the compiled module).
 
+A third leg holds the gated delta rule's triangular solve
+(parallel/delta_solve.py, float32) and its backward, at the Olmo-Hybrid cell's
+shape ``[1, 256, 10, 64, 64]`` on near-coincident keys, to the host's float64
+inverse: no system further off than :data:`SOLVE_TOLERANCE` of its largest
+entry AND, in the mean over the systems, than twice what XLA's blocked form
+(``model/gated_delta.py _blocked_inverse``, twelve float32 ``highest``
+matmuls) is off on the same chip — a product with a dropped bfloat16 term
+fails the second.
+
 Shapes: flash at the long-context recipe's per-chip shape (seq 16,384, head
 dim 128; two heads so the dense reference's [s, s] scores fit beside it);
 the mixer at the flagship's (8 heads, seq 512, 512 features/head, batch 32).
 Operands are bfloat16, the dtype both recipes compute in.
 """
 import argparse
+import functools
 import json
 import os
 import sys
@@ -35,6 +45,11 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 #: factor of two; a wrong block index, mask or scale is an error of order
 #: one (>= 2^-2), forty times the bound.
 TOLERANCE = 2.0 ** -6
+
+
+#: the solve's bound against float64, as a share of the largest entry: what
+#: tests/delta_solve_test.py holds the kernels and the XLA form to on the CPU
+SOLVE_TOLERANCE = 2e-5
 
 
 def _errors(got, want):
@@ -94,10 +109,76 @@ def _run(name, fn, ref_fn, operands, cotangent_seed):
     return ok
 
 
+def _solve_leg(shape=(1, 256, 10, 64, 64)) -> bool:
+    """The solve's kernel pair against float64 on the host, beside XLA's
+    blocked form on the same device."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from homebrewnlp_tpu.model import gated_delta
+    from homebrewnlp_tpu.parallel import delta_solve
+
+    l, matrices = shape[-1], int(np.prod(shape[:-2]))
+    rng = np.random.default_rng(37)
+    # keys of a chunk nearly alike: entries of N = 2 k_i . k_j near 1.8
+    keys = rng.normal(size=(matrices, 1, 8)) \
+        + 0.3 * rng.normal(size=(matrices, l, 8))
+    keys /= np.linalg.norm(keys, axis=-1, keepdims=True)
+    strict = np.tril(2.0 * np.einsum("nid,njd->nij", keys, keys), -1
+                     ).astype(np.float32)
+    ct = rng.normal(size=strict.shape).astype(np.float32)
+    inv64 = np.linalg.inv(np.eye(l) + strict.astype(np.float64))
+    inv_t = np.swapaxes(inv64, -1, -2)
+    d64 = np.tril(-(inv_t @ ct.astype(np.float64) @ inv_t), -1)
+
+    def both(inverse, backward):
+        def run(n, g):
+            inv = inverse(n)
+            return inv, backward(inv, g)
+        return jax.jit(run)
+
+    platform = jax.devices()[0].platform
+    applies = delta_solve.solve_kernel_applies(l, matrices)
+    interpret = platform == "cpu"
+    kernel = both(functools.partial(delta_solve.inverse_unit_lower,
+                                    interpret=interpret),
+                  functools.partial(delta_solve.inverse_unit_lower_bwd,
+                                    interpret=interpret))
+    blocked = both(gated_delta._blocked_inverse, gated_delta._xla_inverse_bwd)
+    args = (jnp.asarray(strict.reshape(shape)), jnp.asarray(ct.reshape(shape)))
+
+    def off(got, want):      # a system's largest error over its largest entry
+        return np.abs(got - want).max((-1, -2)) / np.abs(want).max((-1, -2))
+
+    errs = {}
+    for name, fn in (("kernel", kernel), ("xla", blocked)):
+        inv, d = (np.asarray(t, np.float64).reshape(strict.shape)
+                  for t in fn(*args))
+        errs[name] = {"upper": float(np.abs(np.triu(inv, 1)).max())}
+        for part, got, want in (("inverse", inv, inv64),
+                                ("backward", d, d64)):
+            errs[name][part] = float(off(got, want).max())
+            errs[name][part + "_mean"] = float(off(got, want).mean())
+    ok = (applies or platform == "cpu") and errs["kernel"]["upper"] == 0.0
+    for part in ("inverse", "backward"):
+        ok = ok and errs["kernel"][part] <= SOLVE_TOLERANCE \
+            and errs["kernel"][part + "_mean"] \
+            <= 2 * errs["xla"][part + "_mean"]
+    print(json.dumps({"kernel": "delta_solve", "ok": bool(ok),
+                      "implementation": "pallas" if applies else
+                      "pallas (interpret)", "max_err_over_max_ref": errs,
+                      "tolerance": SOLVE_TOLERANCE, "shapes": [list(shape)],
+                      "dtype": "float32"}), flush=True)
+    return bool(ok)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--flash-seq", type=int, default=16384)
     ap.add_argument("--mixer-batch", type=int, default=32)
+    ap.add_argument("--solve-chunks", type=int, default=256,
+                    help="chunks of the solve's [1, chunks, 10, 64, 64]")
     args = ap.parse_args(argv)
 
     import jax
@@ -134,6 +215,8 @@ def main(argv=None) -> int:
         bias_, v_, True))
     ok &= _run("map_mixer", lambda bias_, v_: map_mixer.mix(
         bias_, v_, causal=True), mixer_ref, [bias, val], cotangent_seed=13)
+
+    ok &= _solve_leg((1, args.solve_chunks, 10, 64, 64))
 
     print(json.dumps({"ok": bool(ok)}), flush=True)
     return 0 if ok else 1

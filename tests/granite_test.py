@@ -406,12 +406,20 @@ def conv_kernels_keep_their_scope_and_names_test(v5e, monkeypatch):
         and "mamba_conv_bwd" in forms[1]
 
 
-def delta_layers_conv_is_the_same_kernel_pair_test(v5e, monkeypatch):
+def delta_layers_kernels_keep_their_scopes_test(v5e, monkeypatch):
     """One ``gated_delta`` layer at Olmo-Hybrid's published widths, 1 x 8,192
-    tokens (half the cell's), compiled for a v5e as a TPU process traces it: the bias-free conv
-    over 11,520 channels is the same Pallas pair in its three forms, every
-    one folds into ``body/gated_delta/conv``, and the rule brings no custom
-    call of its own."""
+    tokens (half the cell's), compiled for a v5e as a TPU process traces it:
+    the bias-free conv over 11,520 channels is the same Pallas pair in its
+    three forms, every one folds into ``body/gated_delta/conv``; the rule's
+    triangular solve (PR 37) is the pair of ``parallel/delta_solve.py`` —
+    the step's forward, the group's own re-materialisation and the backward,
+    on the 1,920 systems of a group of 15 heads, operands ``[systems * 64,
+    64]`` (a bitcast of XLA's ``[.., 64, 64]``: no transposing copy of an
+    operand) — Mosaic accepts both, their ops carry
+    ``gated_delta_0/delta_rule/../solve/`` and fold into
+    ``body/gated_delta/delta_rule``, which ``delta_rule_time_share`` and
+    ``delta_rule_roofline`` read, and none bears a name another metric's
+    reader takes."""
     import re
     from benchmark.lib.cell import load_cell
     from homebrewnlp_tpu.model import remat
@@ -422,6 +430,7 @@ def delta_layers_conv_is_the_same_kernel_pair_test(v5e, monkeypatch):
                              "vocab_size": 512, "sequence_length": 8192,
                              "model_path": "/tmp/olmo"})
     assert remat.conv_kernel_layers(params) == 1
+    assert remat.solve_kernel_layers(params) == 1
     model = Model(params)
     batch = {k: np.zeros((1, 8192, 1), np.int32)
              for k in ("token_x", "token_y")}
@@ -431,12 +440,31 @@ def delta_layers_conv_is_the_same_kernel_pair_test(v5e, monkeypatch):
     hlo = jax.jit(jax.value_and_grad(
         lambda v, b: model.apply(v, b).total_loss.data)).lower(
         *avals).compile().as_text()
-    calls = re.findall(r'%([\w.-]+) = [^\n]*?custom_call_target='
+    calls = re.findall(r'%([\w.-]+) = ([^\n]*?)custom_call_target='
                        r'"tpu_custom_call"[^\n]*?op_name="([^"]+)"', hlo)
-    assert sorted(re.sub(r"\.\d+$", "", name) for name, _ in calls) \
-        == ["mamba_conv_bwd", "mamba_conv_fwd", "mamba_conv_fwd"]
-    for _, op_name in calls:
-        assert scope_key(op_name) == "body/gated_delta/conv", op_name
+    assert sorted(re.sub(r"\.\d+$", "", name) for name, _, _ in calls) \
+        == ["delta_solve_bwd", "delta_solve_fwd", "delta_solve_fwd",
+            "mamba_conv_bwd", "mamba_conv_fwd", "mamba_conv_fwd"]
+    solves = []
+    for name, line, op_name in calls:
+        assert not re.match(r"flash_|map_mixer_", name)
+        if name.startswith("mamba_conv"):
+            assert scope_key(op_name) == "body/gated_delta/conv", op_name
+            continue
+        assert scope_key(op_name) == "body/gated_delta/delta_rule", op_name
+        assert re.search(r"gated_delta_0/delta_rule/.*/solve/", op_name), \
+            op_name
+        # 128 chunks x 15 heads x 64 rows, and no operand laid out again
+        assert line.startswith("f32[122880,64]{1,0"), line
+        assert not re.search(r"custom-call\([^)]*%(copy|transpose)", line), \
+            line
+        solves.append(op_name)
+    # the step's forward; the group's replay and the backward, both inside
+    # the transposed program
+    assert sorted(("delta_solve_bwd" in op_name,
+                   "rematted_computation" in op_name,
+                   "/transpose(jvp(" in op_name) for op_name in solves) \
+        == [(False, False, False), (False, True, True), (True, False, True)]
 
 
 def experts_rule_declines_without_a_moe_layer_test():

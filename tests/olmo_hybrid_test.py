@@ -459,7 +459,8 @@ def remat_rules_count_the_new_layer_test():
     (and under ``"recompute"`` nothing does); the chunk states' gauge counts
     one layer's (the largest of the declared), every layer's under ``none``;
     the conv gauge counts the three bias-free convs where the kernel takes
-    them."""
+    them, the solve gauge (PR 37) the three triangular solves where theirs
+    does — through what the layer declares, the predicate it calls."""
     from homebrewnlp_tpu import telemetry
     from homebrewnlp_tpu.model.blocks import _checkpoint_policy
     from homebrewnlp_tpu.train import Trainer
@@ -478,9 +479,14 @@ def remat_rules_count_the_new_layer_test():
     line = Trainer(params, model).publish_stash_plan()
     assert line.endswith("experts 0 layers, 0 bytes a device; recurrent 0 "
                          "layers, 0 bytes a device; ssd chunk states 12288 "
-                         "bytes a device; conv kernel 0 layers")
+                         "bytes a device; conv kernel 0 layers; solve kernel "
+                         "0 layers")
     snap = telemetry.registry().snapshot()
     assert snap["hbnlp_ssd_state_bytes"]["series"][()] == 12288
+    assert snap["hbnlp_delta_solve_kernel_layers"]["series"][()] == 0
+    # 2 x 4 chunks x 3 heads = 24 systems of 16 x 16 a call: no whole tile
+    assert delta_mod.gated_delta.recurrent.solve(params) == (16, 24)
+    assert remat.solve_kernel_layers(params, "tpu") == 0
     _, none, _, _, _ = _build("float32", memory_reduction_strategy="none")
     assert remat.ssd_state_bytes(none) == 3 * 12288
     # 3 x (2 x 32 + 64) = 384 channels from channel 0 of proj on
@@ -489,6 +495,15 @@ def remat_rules_count_the_new_layer_test():
     assert delta_mod.gated_delta.recurrent.conv(wide) == (384, 4, 0)
     assert remat.conv_kernel_layers(wide, "tpu") == 3
     assert remat.conv_kernel_layers(wide) == 0
+    # 2 x 16 chunks x 4 heads = 128 systems a call: one tile of the kernel
+    _, tiled, _, _, _ = _build("float32", sequence_length=256, delta_heads=4)
+    assert delta_mod.gated_delta.recurrent.solve(tiled) == (16, 128)
+    assert remat.solve_kernel_layers(tiled, "tpu") == 3
+    assert remat.solve_kernel_layers(tiled) == 0            # the CPU
+    odd = _build("float32", sequence_length=192, delta_heads=4,
+                 delta_chunk=48)[1]
+    assert delta_mod.gated_delta.recurrent.solve(odd) == (48, 32)
+    assert remat.solve_kernel_layers(odd, "tpu") == 0       # no tile takes 48
     with open(os.path.join(REPO, "benchmark", "configs",
                            "olmo_hybrid_7b.json")) as f:
         cell = ModelParameter(dict(json.load(f)["config"],
@@ -497,6 +512,10 @@ def remat_rules_count_the_new_layer_test():
     assert remat.ssd_state_bytes(cell) == 256 * 10 * 192 * 96 * 2 \
         == 94_371_840
     assert remat.conv_kernel_layers(cell, "tpu") == 3
+    # 256 chunks x the 10 heads of a group, chunk 64; 3 layers x depth 1
+    assert delta_mod.gated_delta.recurrent.solve(cell) == (64, 2560)
+    assert remat.solve_kernel_layers(cell, "tpu") == 3 * cell.depth == 3
+    assert remat.solve_kernel_layers(cell) == 0
 
 
 def step_with_the_conv_kernel_test(monkeypatch):
@@ -524,6 +543,44 @@ def step_with_the_conv_kernel_test(monkeypatch):
     text = str(jax.make_jaxpr(
         lambda v: model.apply(v, batch).total_loss.data)(variables))
     assert text.count("name=_fwd_impl") == 1 and "mamba_conv_fwd" in text
+    loss, got = loss_and_grads()
+    assert abs(float(loss) - float(want_loss)) <= 2e-5
+    assert set(got) == set(want)
+    for name in want:
+        a, r = (np.asarray(t[name], np.float32) for t in (got, want))
+        assert np.max(np.abs(a - r)) <= 2e-5 * max(np.max(np.abs(r)), 1e-3), \
+            name
+
+
+def step_with_the_solve_kernel_test(monkeypatch):
+    """The toy step under ``jax.checkpoint`` + ``jax.grad`` as a TPU process
+    traces it at a size whose systems fill one tile of the solve's kernel
+    pair (2 x 16 chunks x 4 heads of 16 x 16; the kernels interpreted): the
+    rule traces the Pallas forward where the blocked form stood — once, the
+    ``jax.jit`` around it — and loss and every gradient equal the blocked
+    form's."""
+    import functools
+    from homebrewnlp_tpu.parallel import delta_solve as ds
+    _, params, model, batch, variables = _build(
+        "float32", sequence_length=256, delta_heads=4,
+        block_config=_ONE["gated_delta"])
+    assert remat.solve_kernel_layers(params, "tpu") == 1
+
+    def loss_and_grads():
+        v = {k: jnp.asarray(a) for k, a in variables.items()}
+        return jax.jit(jax.value_and_grad(
+            lambda v: model.apply(v, batch).total_loss.data))(v)
+
+    want_loss, want = loss_and_grads()
+    monkeypatch.setattr(delta_mod, "solve_kernel_applies", functools.partial(
+        ds.solve_kernel_applies, backend="tpu"))
+    monkeypatch.setattr(delta_mod, "inverse_unit_lower", functools.partial(
+        ds.inverse_unit_lower, interpret=True))
+    monkeypatch.setattr(delta_mod, "inverse_unit_lower_bwd", functools.partial(
+        ds.inverse_unit_lower_bwd, interpret=True))
+    text = str(jax.make_jaxpr(
+        lambda v: model.apply(v, batch).total_loss.data)(variables))
+    assert text.count("name=_fwd_impl") == 1 and "delta_solve_fwd" in text
     loss, got = loss_and_grads()
     assert abs(float(loss) - float(want_loss)) <= 2e-5
     assert set(got) == set(want)

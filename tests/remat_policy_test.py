@@ -430,6 +430,27 @@ def conv_kernel_moves_no_other_cell_test(cell, layers):
     assert any(name == "mamba" for name, _ in _layers(p)) is bool(layers)
 
 
+@pytest.mark.parametrize("cell,layers", [
+    ("train_32big_mixer_b32", None), ("train_32big_mixer_dp2tp2", None),
+    ("train_1b_long_context_s16k", None), ("train_olmoe_1b_7b_s4k", None),
+    ("train_granite_4_0_h_micro_long", None),
+    ("train_laguna_s_2_1_ep32_s8k", None),
+    ("train_olmo_hybrid_7b_long", 3)])
+def solve_kernel_moves_no_other_cell_test(cell, layers):
+    """The Pallas pair for the triangular solve is chosen inside layer
+    ``gated_delta`` alone, by what it DECLARES (``Recurrent.solve``): on a
+    TPU the Olmo-Hybrid cell's three layers take it, the six other cells
+    have no layer that declares a solve (``mamba`` declares none) and trace
+    nothing new (their train steps' jaxprs hash equal to the parent's,
+    ``PERF.md`` section 6, PR 37); off the TPU nobody does."""
+    from homebrewnlp_tpu.model.remat import _layers, solve_kernel_layers
+    p = _cell_params(cell)
+    assert solve_kernel_layers(p, "tpu") == layers
+    assert solve_kernel_layers(p) == (None if layers is None else 0)
+    assert any(name == "gated_delta" for name, _ in _layers(p)) \
+        is (layers is not None)
+
+
 def experts_stash_line_and_policy_test():
     """The start-up line names the kind, and ``_checkpoint_policy`` saves
     layer moe's names exactly where the plan says the kind rides: today's

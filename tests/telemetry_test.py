@@ -486,6 +486,8 @@ _RARE_SERIES = {telemetry.SPAN_METRIC, "hbnlp_init_values_seconds_total",
                 "hbnlp_remat_stash_bytes", "hbnlp_remat_stash_layers",
                 # likewise (PR 30, PR 31; 0 without a mamba layer)
                 "hbnlp_ssd_state_bytes", "hbnlp_mamba_conv_kernel_layers",
+                # likewise (PR 37; 0 without a gated_delta layer)
+                "hbnlp_delta_solve_kernel_layers",
                 # set at the marks of telemetry/memory.py, where the backend
                 # reports memory (PR 34; XLA:CPU: no series)
                 "hbnlp_hbm_bytes", "hbnlp_train_state_bytes"}
