@@ -66,11 +66,21 @@ _LAYER_NAMES = frozenset((
     "feed_forward_product_key_memory", "product_key_memory",
     "reduced_half_linear", "transpose_sequence_features",
     "bottleneck_group_linear", "sum_heads", "moe", "mamba", "gated_delta",
-    "mlp"))
+    "mlp", "cca",
+    # no layer function: a block part's scaled residual merge
+    # (model/frontend.py scaled_merge) opens a scope of its own beside them
+    "merge"))
 #: the parts of layer ``moe`` (model/moe.py), each a scope of its own below
 #: ``body/moe``
 _MOE_PARTS = frozenset(("router", "dispatch", "experts", "combine",
                         "shared"))
+#: the parts of ZAYA1's router (flag ``router_mlp``) below
+#: ``body/moe/router``; the one-matrix router has none
+_ROUTER_PARTS = frozenset(("down", "carry", "mlp"))
+#: the parts of layer ``cca`` (model/cca.py) below ``body/cca``; the flash
+#: kernels stay in ``body/cca`` itself
+_CCA_PARTS = frozenset(("in_proj", "qk_mean", "conv", "qk_norm", "rope",
+                        "value_shift", "out_proj"))
 #: the standard attention's per-head output gate (model/spatial.py), a
 #: scope of its own below ``body/attention``
 _ATTENTION_PARTS = frozenset(("gate",))
@@ -107,7 +117,9 @@ def scope_key(path: str) -> str:
     Keys: ``decode/cache_read|cache_write|sampling``, ``optimizer``,
     ``head_loss``, ``input/embed``, ``input``, ``body/<layer>``,
     ``body/moe/router|dispatch|experts|combine|shared``,
-    ``body/attention/gate``,
+    ``body/moe/router/down|carry|mlp``, ``body/attention/gate``,
+    ``body/cca/in_proj|qk_mean|conv|qk_norm|rope|value_shift|out_proj``,
+    ``body/merge``,
     ``body/mamba/in_proj|conv|ssd|gate_norm|out_proj``,
     ``body/gated_delta/in_proj|conv|delta_rule|gate_norm|out_proj``,
     ``output/unembed``,
@@ -117,6 +129,7 @@ def scope_key(path: str) -> str:
     attribution, not per-pass."""
     phase = None
     layer = None
+    router = False
     bases = []
     for comp in str(path).split("/"):
         base = _basename(_unwrap(comp))
@@ -127,14 +140,22 @@ def scope_key(path: str) -> str:
             phase = base
         elif phase is not None and layer is None and base in _LAYER_NAMES:
             layer = base
-        elif layer == "moe" and base in _MOE_PARTS:
+        elif router and base in _ROUTER_PARTS:
+            return f"body/moe/router/{base}"
+        elif layer == "moe" and base == "router":
+            router = True
+        elif layer == "moe" and base in _MOE_PARTS and not router:
             return f"body/moe/{base}"
+        elif layer == "cca" and base in _CCA_PARTS:
+            return f"body/cca/{base}"
         elif layer == "attention" and base in _ATTENTION_PARTS:
             return f"body/attention/{base}"
         elif layer == "mamba" and base in _MAMBA_PARTS:
             return f"body/mamba/{base}"
         elif layer == "gated_delta" and base in _DELTA_PARTS:
             return f"body/gated_delta/{base}"
+    if router:
+        return "body/moe/router"
     # a leading block (input_block_config) is a body layer that runs once
     if phase in ("body", "input") and layer is not None:
         return f"body/{layer}"

@@ -32,15 +32,27 @@ _CACHE_DTYPES = {**_DTYPES, "int8": jnp.int8}
 
 
 class BlockConfig:
-    """One block part: list of layer strings + skip flag (reference :12-19)."""
+    """One block part: list of layer strings + skip flag (reference :12-19).
+    ``merge`` says how a ``skip`` part joins the stream: ``"add"`` is ``x +
+    f(x)``, ``"scaled"`` ZAYA1's ``(x * a_r + b_r) + (f(x) * a_o + b_o)``
+    with four learned vectors over the features (model/frontend.py
+    ``scaled_merge``)."""
+
+    MERGES = ("add", "scaled")
 
     def __init__(self, config, memory_reduction_strategy: str):
         if isinstance(config, BlockConfig):
             config = config.__dict__
         self.layer: typing.List[str] = []
         self.skip = False
+        self.merge = "add"
         self.memory_reduction_strategy = memory_reduction_strategy
         self.__dict__.update(config)
+        if self.merge not in self.MERGES:
+            raise ValueError(f"block part merge {self.merge!r}: one of "
+                             f"{self.MERGES}")
+        if self.merge != "add" and not self.skip:
+            raise ValueError(f"block part merge {self.merge!r} without skip")
 
 
 class LearningRateConfig:
@@ -219,6 +231,19 @@ class ModelParameter:
         self.experts_first = 0
         self.moe_norm_topk = False
         self.moe_route_scale = 1.0
+        # layer "moe" with flag "router_mlp" (ZAYA1's router): the width of
+        # the router's own stream, which one layer's router hands the next
+        self.moe_router_width = 256
+        # layer "cca" (compressed convolutional attention, model/cca.py):
+        # taps of the depthwise and of the grouped causal conv over the
+        # packed q-k latent (the published cca_time0 / cca_time1)
+        self.cca_time0 = 2
+        self.cca_time1 = 2
+        # standard deviation of the matrices that WRITE into the residual
+        # stream in layers "cca" (the output projection) and "moe" (the
+        # experts' down-projection); 0 = normal(0.02) like every other
+        # matrix.  Megatron's scaled initialisation is 0.02 / sqrt(2 x layers)
+        self.residual_out_stddev = 0.0
         # layer "mamba" (Mamba-2, model/mamba.py): heads x width of the inner
         # stream, the state's size, the causal depthwise conv's width, and
         # the chunk of the state-space-duality scan
@@ -922,6 +947,15 @@ class ModelParameter:
                     or getattr(self, key) < 0:
                 raise ValueError(f"{key} {getattr(self, key)!r} must be a "
                                  "whole number >= 0")
+        for key in ("moe_router_width", "cca_time0", "cca_time1"):
+            if not isinstance(getattr(self, key), int) \
+                    or getattr(self, key) < 1:
+                raise ValueError(f"{key} {getattr(self, key)!r} must be a "
+                                 "positive whole number")
+        if not self.residual_out_stddev >= 0:
+            raise ValueError(f"residual_out_stddev "
+                             f"{self.residual_out_stddev!r} must be >= 0 "
+                             "(0 = 0.02)")
         if self.experts_first + self.experts_held > self.experts:
             raise ValueError(
                 f"experts_first {self.experts_first} + experts_held "

@@ -84,6 +84,13 @@ class Context:
         # returns out of its checkpoint / scan regions as explicit outputs
         # (model/blocks.py); the plain residual strategies only
         self.layer_stats: typing.Optional[list] = None
+        # when not None, the CARRIED SIDE VALUES: ``{name: array}`` that one
+        # layer leaves for a later one beside the stream (layer moe's
+        # router state under ``router_mlp``).  The block machinery hands the
+        # dict in and out of every block's region as an explicit input and
+        # output (model/blocks.py); None under the modes that carry none,
+        # where a layer that needs one refuses by name
+        self.side: typing.Optional[dict] = None
         # matmul-accumulation policy for bf16 einsums ("auto"/"f32"/"bf16",
         # config.matmul_accumulation); consumed by core.tensor.einsum and
         # propagated by ReplayBlock like quant_scales

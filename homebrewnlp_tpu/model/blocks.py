@@ -60,7 +60,11 @@ class ReplayBlock:
     def __call__(self, subset: Subset, x: NamedTensor,
                  it: typing.Optional[jax.Array] = None,
                  stash: typing.Optional[dict] = None,
-                 layer_stats: typing.Optional[list] = None) -> NamedTensor:
+                 layer_stats: typing.Optional[list] = None,
+                 side: typing.Optional[dict] = None):
+        """The block's output; with ``side`` (the carried side values that
+        enter the block, ``Context.side``) ``(output, the side values that
+        leave it)``."""
         outer_rng = None
         outer_mesh = None
         outer_decode = None
@@ -93,6 +97,10 @@ class ReplayBlock:
         # per-step layer statistics (core/scope.py Context.layer_stats): the
         # caller's own list, so that it can return them out of its region
         ctx.layer_stats = layer_stats
+        # carried side values: a copy, so that what the block's layers leave
+        # there is this call's OUTPUT and never the caller's dict mutated
+        # inside a checkpoint region
+        ctx.side = None if side is None else dict(side)
         if outer_rng is not None:
             # `it` is the (possibly traced) depth index under scan-over-layers
             idx = self.depth_idx if it is None else it
@@ -112,7 +120,7 @@ class ReplayBlock:
                     # batch on 'data' / heads on 'model' through the stack
                     from ..core.sharding import with_constraint
                     out = with_constraint(out, self.params, outer_mesh)
-                return out
+                return out if side is None else (out, ctx.side)
         finally:
             self.params.attention_idx = saved
 
@@ -454,12 +462,16 @@ def _merge_stats(parts) -> dict:
 def _block_with_stats(f, collect: bool):
     """``(subset, x, it) -> (out, {name: [n] array})``: the block, and the
     statistics its layers reported (``Context.layer_stats``) as an explicit
-    output, so that they can leave a checkpoint or scan region."""
-    def call(subset, x, it=None):
+    output, so that they can leave a checkpoint or scan region.  With
+    ``side`` (the carried side values, ``Context.side``) ``out`` is
+    ``(stream, side)``: the values enter and leave the region as explicit
+    operands too, so the backward holds their cotangents."""
+    def call(subset, x, it=None, side=None):
+        kwargs = {} if side is None else {"side": side}
         if not collect:
-            return f(subset, x, it=it), {}
+            return f(subset, x, it=it, **kwargs), {}
         sink: list = []
-        out = f(subset, x, it=it, layer_stats=sink)
+        out = f(subset, x, it=it, layer_stats=sink, **kwargs)
         return out, _merge_stats(sink)
     return call
 
@@ -928,12 +940,13 @@ def run_body_blocks(params: ModelParameter, src: NamedTensor,
     if ctx.mode == "init" or plan is None:
         specs: typing.List[BlockSpec] = []
         out = src
-        prev_touched = ctx.touched
+        prev_touched, prev_side = ctx.touched, ctx.side
+        ctx.side = {}       # init only makes parameters: every mode carries
         for i, c, bc in blocks:
             ctx.touched = []
             out = block_part_fn(params, bc, out, _block_scope_name(i, c))
             specs.append((i, c, tuple(ctx.touched)))
-        ctx.touched = prev_touched
+        ctx.touched, ctx.side = prev_touched, prev_side
         if strategy in ("revnet", "momentum"):
             # init forward ran the plain composition; the strategies compute
             # x+f stacks whose *values* differ from the plain stack, but init
@@ -1024,14 +1037,18 @@ def run_body_blocks(params: ModelParameter, src: NamedTensor,
                                  tuple(subsets), src, src, stash)
         return x + v, plan
     # checkpoint / none: the plain stream, each block's layer statistics
-    # an explicit output of its region
-    out, parts = src, []
+    # an explicit output of its region, and beside the stream the CARRIED
+    # SIDE VALUES (Context.side: what one layer leaves for a later one, layer
+    # moe's router state under router_mlp): a dict that enters and leaves
+    # every block's region as an operand, empty — no operand at all — in a
+    # model whose layers carry nothing
+    out, parts, side = src, [], {}
     checkpoint_policy = _checkpoint_policy(params, ctx.mesh)
     for f, s in zip(fns, subsets):
         call = _block_with_stats(f, ctx.layer_stats is not None)
         if strategy == "checkpoint":
             call = jax.checkpoint(call, policy=checkpoint_policy)
-        out, stats = call(s, out)
+        (out, side), stats = call(s, out, None, side)
         parts.append(stats)
     if any(parts):
         ctx.layer_stats.append(_merge_stats(parts))

@@ -11,10 +11,12 @@ from ..config import BlockArgs, BlockConfig, ModelParameter
 from ..core import scope
 from ..core.tensor import NamedTensor, add, multiply
 from .activation import activate
+from .backend import ConstantInit
 from .basic import (bottleneck_group_linear, dropout, feed_forward,
                     feed_forward_product_key_memory, group_linear, mlp,
                     product_key_memory, reduced_half_linear, rezero, sum_heads,
                     transpose_sequence_features)
+from .cca import cca
 from .gated_delta import gated_delta
 from .mamba import mamba
 from .moe import moe
@@ -92,11 +94,43 @@ def _get_block_part(block_part_config: BlockConfig, params: ModelParameter,
         name, *extras = layer.split('-')
         args = BlockArgs(params, out, extras, idx == len(block_part_config.layer))
         out = scope.scoped(name + '_', LAYER_FUNCTIONS[name], args)
-    if block_part_config.skip and block_part_config.memory_reduction_strategy in ("none", "checkpoint"):
+    if block_part_config.skip and block_part_config.merge == "scaled":
+        if block_part_config.memory_reduction_strategy not in ("none",
+                                                               "checkpoint"):
+            raise NotImplementedError(
+                "a block part's merge \"scaled\" under the revnet / momentum "
+                "strategies (they own the residual themselves)")
+        out = scope.scoped("merge_", scaled_merge, params, block_input, out)
+    elif block_part_config.skip and block_part_config.memory_reduction_strategy in ("none", "checkpoint"):
         if params.residual_multiplier != 1:
             out = out * params.residual_multiplier
         out = out + block_input
     return out
+
+
+def scaled_merge(params: ModelParameter, residual: NamedTensor,
+                 out: NamedTensor) -> NamedTensor:
+    """ZAYA1's scaled residual merge, ``(residual * a_r + b_r) + (out * a_o +
+    b_o)``: four learned vectors over the features (``a`` = 1, ``b`` = 0 at
+    initialisation, so it starts as ``residual + out``), created in the
+    order a_r, b_r, a_o, b_o and read in float32; the sum is made in
+    float32 and returned in the stream's dtype."""
+    import jax
+    import jax.numpy as jnp
+    from ..core.tensor import nt, transpose_to
+    from .recurrent import _small_var
+    feats = list(params.feature_dims)
+    args = BlockArgs(params, out, [])
+    a_r, b_r, a_o, b_o = (
+        _small_var(args, "constant_var", feats, ConstantInit(value))
+        for value in (1.0, 0.0, 1.0, 0.0))
+    dims = [d for d in residual.dims if d not in feats] + feats
+    with jax.named_scope("merge"):
+        merged = (transpose_to(residual, dims).data.astype(jnp.float32) * a_r
+                  + b_r) \
+            + (transpose_to(out, dims).data.astype(jnp.float32) * a_o + b_o)
+        return transpose_to(nt(merged.astype(residual.dtype), dims),
+                            residual.dims)
 
 
 def block_part_fn(params: ModelParameter, block_part_config: BlockConfig,
@@ -142,4 +176,5 @@ LAYER_FUNCTIONS = {'feed_forward': feed_forward,
                    'mamba': mamba,
                    'gated_delta': gated_delta,
                    'mlp': mlp,
+                   'cca': cca,
                    }

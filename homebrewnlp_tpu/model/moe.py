@@ -30,6 +30,17 @@ reads a sentinel slot's row.
 SwiGLU of the experts' width that every token passes through, weight 1,
 scope ``shared``) to the routed sum.
 
+Flag ``router_mlp`` replaces the one matrix of logits by ZAYA1's router
+(arXiv:2511.17127): a down-projection to ``moe_router_width`` with a bias,
+plus the learned per-channel scale ``g`` times the router state ``r`` of the
+PREVIOUS ``router_mlp`` layer (after that layer's own addition: depth
+averaging; zero before the first), an RMSNorm with a learned scale, and a
+three-layer GELU (erf) MLP to the ``experts`` logits, all in float32.  ``r``
+leaves the layer beside the stream as a CARRIED SIDE VALUE
+(``Context.side["router_state"]``, model/blocks.py): an explicit input and
+output of every block's region, with its cotangent in the backward.  Only the
+strategies that carry one (``checkpoint`` / ``none``, unrolled) run it.
+
 ``basic.routed_mixture_of_experts`` (one routed linear, capacity-padded
 one-hot dispatch) stays beside it until ROADMAP D7 merges the two.
 """
@@ -47,8 +58,9 @@ from ..core import scope
 from ..core.dims import Dim
 from ..core.tensor import NamedTensor, nt, transpose_to
 from .activation import ACTIVATIONS
-from .backend import normal_var
+from .backend import ConstantInit, NormalInit, normal_var
 from .basic import _router_aux_inject
+from .recurrent import _small_var
 from .utils import anonymize_dim
 
 
@@ -127,15 +139,23 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 #: the names layer ``moe`` gives, by ``checkpoint_name``, to what is dear to
-#: replay per byte: the three grouped matmuls' outputs and the routing triple.
-#: Free where no policy names them; under the ``checkpoint`` strategy the
-#: block's ``jax.checkpoint`` saves them where model/remat.py's ``experts``
-#: kind rides (model/blocks.py ``_checkpoint_policy``), and the replay then
-#: runs neither the three forward kernels (megablox keeps only its INPUTS as
-#: residuals) nor the pairs' sort; the router's softmax and top-k stay in
-#: it, their ``weights`` feed ``_combine``'s backward.
+#: replay per byte: the three grouped matmuls' outputs, the routing triple and
+#: the router's CHOICE.  Free where no policy names them; under the
+#: ``checkpoint`` strategy the block's ``jax.checkpoint`` saves them where
+#: model/remat.py's ``experts`` kind rides (model/blocks.py
+#: ``_checkpoint_policy``), and the replay then runs neither the three
+#: forward kernels (megablox keeps only its INPUTS as residuals) nor the
+#: pairs' sort; the router's softmax and top-k stay in it, their ``weights``
+#: feed ``_combine``'s backward.  The choice (``moe_experts``, which experts each
+#: token took) is saved WITH the sort it made: a replay that chose again
+#: could choose otherwise where two probabilities lie within a rounding of
+#: each other (its fusions are not the forward's), and then read, by the
+#: saved ``inverse``, a row that belongs to another choice — or, in a layer
+#: that holds a share, a row no kernel wrote (my chip runs, PR 39: one NaN
+#: in 16,384 x 2,048 cotangents a step at ZAYA1's top-1 router, whose
+#: logits lie ~0.01 apart at initialisation).
 SAVED_NAMES = ("moe_gate", "moe_up", "moe_down",
-               "moe_order", "moe_inverse", "moe_sizes")
+               "moe_order", "moe_inverse", "moe_sizes", "moe_experts")
 
 
 #: rows, contraction and columns of one tile of the grouped-matmul kernel;
@@ -174,11 +194,77 @@ def route(logits, top_k: int, norm_topk: bool = False, scale: float = 1.0):
     (``w_e = scale * p_e / sum_{top-k} p``)."""
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     weights, experts = jax.lax.top_k(probs, top_k)
+    # the choice by its name (SAVED_NAMES): a replay that holds the saved
+    # choice sorts and selects by it; its own top-k still gives the weights
+    # (at a near-tie the j-th largest of two equal probabilities).  Gathering
+    # the weights from the saved choice instead cost the Laguna cell 3.7% and
+    # the OLMoE cell 1.3-4.4% (my chip runs, PR 39: a [tokens, experts]
+    # gather and its scatter a layer)
+    experts = checkpoint_name(experts, "moe_experts")
     if norm_topk:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
     if scale != 1.0:
         weights = weights * scale
     return weights, experts
+
+
+#: the key of the router state in ``Context.side``
+ROUTER_STATE = "router_state"
+
+
+def _router_mlp(args: BlockArgs, xf, anon, ctx):
+    """ZAYA1's router on the rows ``xf [t, f]`` (module docstring): the
+    ``experts`` logits ``[t, experts]`` in float32.  Reads the previous
+    layer's router state from ``ctx.side`` and leaves its own there.
+    Parameters in creation order: ``Wd [features, width]`` and the three
+    MLP matrices ``[width, width]``, ``[width, width]``, ``[width,
+    experts]`` normal(0.02), each followed by what goes with it: ``bd`` (0),
+    ``g`` (1), the norm's scale (1) after ``Wd``; ``b1``, ``b2`` (0) after
+    ``W1``, ``W2``; ``W3`` has no bias."""
+    params = args.params
+    if ctx.side is None:
+        raise NotImplementedError(
+            "layer moe's router_mlp hands its router state to the next "
+            "layer's router, a carried side value that only the unrolled "
+            "checkpoint / none strategies hold: scan_layers, revnet, "
+            "momentum, a pipe mesh, decode, prefill and the stats probe "
+            "have none yet")
+    width = Dim("router_width", params.moe_router_width)
+    hidden = Dim("_router_width", width.size)
+    w_sz, f_sz = width.size, xf.shape[-1]
+    f32 = jnp.float32
+
+    def vector(value: float, dim=width):
+        return _small_var(args, "constant_var", [dim], ConstantInit(value))
+
+    def matrix(shape):
+        # the MLP in float32: the master, not a rounding of it
+        return _small_var(args, "normal_var", shape, NormalInit())
+
+    with jax.named_scope("down"):
+        # the one wide matrix: read in the calculation dtype with float32
+        # accumulation, as the one-matrix router's
+        w_down, b_down = normal_var(args, anon + [width]), vector(0.0)
+        state = jnp.dot(
+            xf, w_down.data.reshape(f_sz, w_sz),
+            preferred_element_type=None if jax.default_backend() == "cpu"
+            else f32).astype(f32) + b_down
+    with jax.named_scope("carry"):
+        gain = vector(1.0)
+        previous = ctx.side.get(ROUTER_STATE)
+        if previous is not None:
+            state = state + gain * previous.reshape(state.shape)
+        ctx.side[ROUTER_STATE] = state
+    with jax.named_scope("mlp"):
+        scale = vector(1.0)
+        u = state * jax.lax.rsqrt(jnp.mean(jnp.square(state), axis=-1,
+                                           keepdims=True)
+                                  + params.norm_epsilon) * scale
+        w1, b1 = matrix([hidden, width]), vector(0.0)
+        u = jax.nn.gelu(jnp.dot(u, w1) + b1, approximate=False)
+        w2, b2 = matrix([hidden, width]), vector(0.0)
+        u = jax.nn.gelu(jnp.dot(u, w2) + b2, approximate=False)
+        return jnp.dot(u, matrix([hidden, params.expert_dim]))
 
 
 def held_rows_bound(tokens: int, top_k: int, held: int) -> int:
@@ -251,10 +337,12 @@ def moe(args: BlockArgs) -> NamedTensor:
         raise NotImplementedError(
             "layer moe on a mesh (expert-parallel dispatch) is a later issue")
     unknown = [a for a in args.name_extras
-               if a not in ACTIVATIONS and a != "shared_expert"]
+               if a not in ACTIVATIONS
+               and a not in ("shared_expert", "router_mlp")]
     if unknown:
         raise ValueError(f"layer moe does not know flag(s) {unknown} (known: "
-                         "an activation's name, shared_expert)")
+                         "an activation's name, shared_expert, router_mlp)")
+    router_mlp = "router_mlp" in args.name_extras
     n_exp = params.expert_dim.size
     top_k = min(params.moe_top_k, n_exp)
     held, first = params.experts_held or n_exp, params.experts_first
@@ -272,14 +360,16 @@ def moe(args: BlockArgs) -> NamedTensor:
     f_sz = math.prod(d.size for d in feats)
     i_sz = math.prod(d.size for d in inter)
 
-    w_router = normal_var(args, anon + [params.expert_dim])
+    if not router_mlp:
+        w_router = normal_var(args, anon + [params.expert_dim])
     w_gate = normal_var(args, [held_dim] + anon + inter)
     w_up = normal_var(args, [held_dim] + anon + inter)
-    w_down = normal_var(args, [held_dim] + inter + feats)
+    w_down = normal_var(args, [held_dim] + inter + feats,
+                        stddev=params.residual_out_stddev or 0.02)
 
     xf = transpose_to(x, token_dims + feats).data.reshape(t_sz, f_sz)
     with jax.named_scope("router"):
-        logits = jnp.dot(
+        logits = _router_mlp(args, xf, anon, ctx) if router_mlp else jnp.dot(
             xf, w_router.data.reshape(f_sz, n_exp),
             preferred_element_type=None if jax.default_backend() == "cpu"
             else jnp.float32).astype(jnp.float32)
@@ -289,6 +379,10 @@ def moe(args: BlockArgs) -> NamedTensor:
             logits = _router_aux_inject(wb, wz, top_k, logits[None])[0]
         weights, experts = route(logits, top_k, params.moe_norm_topk,
                                  float(params.moe_route_scale))
+    if ctx.layer_stats is not None and top_k == 1:
+        # the chosen expert's probability, the mean over the step's tokens:
+        # 1 / experts = a router that says nothing
+        ctx.layer_stats.append({"moe_top1_weight_mean": jnp.mean(weights)})
     # a layer that holds a share sorts SLOTS (held_slots), not choices, into
     # held + 1 groups, the sentinel last; its kernels see the held groups
     real, slots, groups = None, top_k, n_exp

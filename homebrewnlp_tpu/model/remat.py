@@ -193,21 +193,22 @@ def _experts_stash(params: ModelParameter, shards: int
     """``(layers, per-device bytes)`` of the experts kind over the whole
     depth: per ``moe`` layer the three grouped matmuls' outputs — gate and
     up ``[pairs, intermediate]``, down ``[pairs, features]``, in the
-    calculation dtype — and the routing triple (``order`` and ``inverse``
-    ``[pairs]``, ``sizes`` ``[experts]``, int32), ``pairs = tokens x
+    calculation dtype — the routing triple (``order`` and ``inverse``
+    ``[pairs]``, ``sizes`` ``[experts]``, int32) and the router's choice
+    (``experts`` ``[tokens, moe_top_k]``, int32), ``pairs = tokens x
     min(moe_top_k, experts)``: model/moe.py ``SAVED_NAMES``.  A layer that
     holds a share of the experts saves its whole static buffer:
     ``moe_held_rows`` rows, ``experts_held + 1`` sizes."""
     layers = sum(name == "moe" for name, _ in _layers(params)) * params.depth
     held_rows = moe_held_rows(params)
-    pairs = held_rows or (
-        params.batch_dim.size * params.sequence_dim.size
-        * min(params.moe_top_k, params.expert_dim.size))
+    choices = params.batch_dim.size * params.sequence_dim.size \
+        * min(params.moe_top_k, params.expert_dim.size)
+    pairs = held_rows or choices
     width = 2 * int(np.prod([d.size for d in params.expert_intermediate])) \
         + int(np.prod([d.size for d in params.feature_dims]))
     groups = params.experts_held + 1 if held_rows else params.expert_dim.size
     per_layer = pairs * width * np.dtype(params.calculation_dtype).itemsize \
-        + (2 * pairs + groups) * 4
+        + (2 * pairs + groups + choices) * 4
     return layers, -(-per_layer * layers * max(1, params.macro_batching)
                      // shards)
 
@@ -223,6 +224,20 @@ def moe_held_rows(params: ModelParameter) -> int:
     return held_rows_bound(
         params.batch_dim.size * params.sequence_dim.size,
         min(params.moe_top_k, params.expert_dim.size), params.experts_held)
+
+
+def router_carry_bytes(params: ModelParameter) -> int:
+    """Bytes of the router states alive between blocks for the backward: one
+    float32 ``[batch, sequence, moe_router_width]`` for every ``moe`` layer
+    with flag ``router_mlp`` that hands its state to a later one (all but
+    the last; model/moe.py).  It passes the blocks in between unchanged, so
+    it is held once however many regions it crosses.  0 where no layer
+    carries one."""
+    carrying = sum(name == "moe" and "router_mlp" in extras
+                   for name, extras in _layers(params)) * params.depth
+    return max(0, carrying - 1) * params.batch_dim.size \
+        * params.sequence_dim.size * params.moe_router_width * 4 \
+        * max(1, params.macro_batching)
 
 
 def _recurrent_stash(params: ModelParameter, shards: int

@@ -402,26 +402,23 @@ _STANDARD_PLAIN = ("qk_norm", "gate")
 _STANDARD_NUMBERED = ("q_heads", "kv_heads", "window", "rotary_pct", "theta")
 
 
-def _standard_flags(extras) -> typing.Dict[str, typing.Any]:
-    """The standard attention's flags as ``{name: True | int}``; a flag it
-    does not know, or two ways of placing positions, refuse by name."""
+def numbered_flags(extras, plain, numbered, what: str
+                   ) -> typing.Dict[str, typing.Any]:
+    """A layer's flags as ``{name: True | int}``: the ``plain`` ones, and the
+    ``numbered`` ones followed by a whole number; ``what`` (the layer, in
+    words) refuses any other by name, with the flags it knows."""
     out: typing.Dict[str, typing.Any] = {}
     for extra in extras:
-        if extra in _STANDARD_POSITION + _STANDARD_PLAIN:
+        if extra in plain:
             out[extra] = True
             continue
         name = extra.rstrip("0123456789")
-        if name not in _STANDARD_NUMBERED or name == extra:
+        if name not in numbered or name == extra:
             raise ValueError(
-                f"the standard attention does not know flag {extra!r} "
-                f"(known: {_STANDARD_POSITION + _STANDARD_PLAIN}, and "
-                f"{_STANDARD_NUMBERED} followed by a whole number)")
+                f"{what} does not know flag {extra!r} (known: "
+                f"{tuple(plain)}, and {tuple(numbered)} followed by a whole "
+                "number)")
         out[name] = int(extra[len(name):])
-    if sum(f in out for f in _STANDARD_POSITION) != 1:
-        raise ValueError("the standard attention takes exactly one of "
-                         f"{_STANDARD_POSITION}, got {list(extras)}")
-    if "nope" in out and any(f in out for f in ("rotary_pct", "theta")):
-        raise ValueError("nope (no rotary positions) with rotary_pct / theta")
     if ("q_heads" in out) != ("kv_heads" in out):
         raise ValueError("q_heads<n> and kv_heads<n> come together")
     if "q_heads" in out and (out["kv_heads"] < 1
@@ -429,6 +426,64 @@ def _standard_flags(extras) -> typing.Dict[str, typing.Any]:
         raise ValueError(f"kv_heads{out['kv_heads']} must divide "
                          f"q_heads{out['q_heads']}")
     return out
+
+
+def _standard_flags(extras) -> typing.Dict[str, typing.Any]:
+    """The standard attention's flags as ``{name: True | int}``; a flag it
+    does not know, or two ways of placing positions, refuse by name.
+    (Compressed convolutional attention is a layer of its own, ``cca``,
+    model/cca.py, with its own flags.)"""
+    out = numbered_flags(extras, _STANDARD_POSITION + _STANDARD_PLAIN,
+                         _STANDARD_NUMBERED, "the standard attention")
+    if sum(f in out for f in _STANDARD_POSITION) != 1:
+        raise ValueError("the standard attention takes exactly one of "
+                         f"{_STANDARD_POSITION}, got {list(extras)}")
+    if "nope" in out and any(f in out for f in ("rotary_pct", "theta")):
+        raise ValueError("nope (no rotary positions) with rotary_pct / theta")
+    return out
+
+
+def project(args: BlockArgs, x: NamedTensor, new, old,
+            stddev: float = 0.02) -> NamedTensor:
+    """A bias-free projection ``old`` features -> ``new``, normal(``stddev``):
+    the input's ``old`` dims renamed so that the einsum contracts them."""
+    from ..core.tensor import rename_dim
+    from .backend import normal_var
+    from .utils import anonymize_dim
+    hidden = [anonymize_dim(d) for d in old]
+    for d, a in zip(old, hidden):
+        x = rename_dim(x, d.name, a.name)
+    return einsum([x, normal_var(args, hidden + new, stddev=stddev)],
+                  shape_sub(x.dims, hidden) + new)
+
+
+def rotary_width(features: int, pct: typing.Optional[int]) -> int:
+    """The first ``pct`` percent of a head's ``features`` (flag
+    ``rotary_pct<p>``; None = all): an even count, or it refuses."""
+    width, rest = divmod(features * (100 if pct is None else pct), 100)
+    if rest or width < 2 or width % 2 or width > features:
+        raise ValueError(f"rotary_pct{pct}: an even number of "
+                         f"features_per_head {features}'s features")
+    return width
+
+
+def causal_heads(ctx, params, q, k, v, group: int, scale: float,
+                 window=None):
+    """Causal ``softmax(scale q k^T) v`` on ``q [lead, seq, heads, f]`` and
+    ``k``, ``v`` ``[lead, seq, heads / group, f]``: each K/V head repeated
+    over its ``group`` of query heads before the kernel, autodiff sums dk
+    and dv over it (the flash kernels are multi-head only; PERF.md section
+    6, PR 30 has the A/B against K/V index maps); the flash kernel, or
+    under ``use_flash_attention`` false XLA's dense form."""
+    import jax
+    import jax.numpy as jnp
+    if group > 1:
+        k, v = (jnp.repeat(t, group, axis=2) for t in (k, v))
+    if params.use_flash_attention:
+        return _flash(ctx, q, k, v, scale, window)
+    from ..parallel.flash_attention import _xla_reference
+    with jax.named_scope("attention_dense"):
+        return _xla_reference(q, k, v, scale, True, window)
 
 
 def _standard_attention(args: BlockArgs) -> NamedTensor:
@@ -462,16 +517,18 @@ def _standard_attention(args: BlockArgs) -> NamedTensor:
     ``yarn`` is ``rope`` at YaRN's frequencies (``rope_yarn_factor``,
     ``rope_yarn_original_positions``, ``rope_yarn_beta_fast`` /
     ``_beta_slow``) with cos and sin times ``rope_yarn_attention_factor``
-    (0 = ``0.1 ln(factor) + 1``).  Any other flag refuses by name.
+    (0 = ``0.1 ln(factor) + 1``).  Any other flag refuses by name
+    (``_standard_flags``, whose message lists the ones it knows).
+    Compressed convolutional attention is NOT a flag of this function: it is
+    layer ``cca`` (model/cca.py), which shares ``project``, ``rotary``,
+    ``rotary_width`` and ``causal_heads`` with it.
     Training and full-sequence forward only: a decode step for it is a later
     issue."""
     import jax
     import jax.numpy as jnp
     from ..core import scope as scope_mod
-    from ..core.tensor import nt, rename_dim, transpose_to
-    from .backend import normal_var
+    from ..core.tensor import nt, transpose_to
     from .normalization import norm
-    from .utils import anonymize_dim
     params = args.params
     flags = _standard_flags(args.name_extras)
     ctx = scope_mod.current()
@@ -488,7 +545,6 @@ def _standard_attention(args: BlockArgs) -> NamedTensor:
             "the standard attention (attention-rope / attention-nope) on a "
             "sequence- or pipe-sharded mesh")
     feats = list(params.feature_dims)
-    anon = [anonymize_dim(d) for d in feats]
     own_heads = "q_heads" in flags
     if own_heads:
         group = flags["q_heads"] // flags["kv_heads"]
@@ -506,24 +562,15 @@ def _standard_attention(args: BlockArgs) -> NamedTensor:
             "grouped key / value heads, or a layer's own head counts, on a "
             "mesh")
 
-    def project(x: NamedTensor, new=q_feats, old=feats) -> NamedTensor:
-        """``old`` features -> ``new``, the input's ``old`` dims renamed so
-        that the einsum contracts them."""
-        hidden = [anonymize_dim(d) for d in old]
-        for d, a in zip(old, hidden):
-            x = rename_dim(x, d.name, a.name)
-        return einsum([x, normal_var(args, hidden + new)],
-                      shape_sub(x.dims, hidden) + new)
-
     # creation order: key, query, value (as the dense path), the gate, the
     # two norms, the output projection
-    key = project(args.tensor, kv_feats)
-    qry = project(args.tensor)
-    val = project(args.tensor, kv_feats)
+    key = project(args, args.tensor, kv_feats, feats)
+    qry = project(args, args.tensor, q_feats, feats)
+    val = project(args, args.tensor, kv_feats, feats)
     gate = None
     if "gate" in flags:
         with jax.named_scope("gate"):
-            gate = project(args.tensor, q_feats[:1])
+            gate = project(args, args.tensor, q_feats[:1], feats)
     if "qk_norm" in flags:
         qry = norm(args(qry, ["rms", "scale"]), q_feats)
         key = norm(args(key, ["rms", "scale"]), kv_feats)
@@ -540,12 +587,8 @@ def _standard_attention(args: BlockArgs) -> NamedTensor:
         theta = float(flags.get("theta", params.rope_theta))
         rope_args: typing.Tuple = ()
         if "rotary_pct" in flags or "yarn" in flags:
-            width, rest = divmod(
-                params.key_dim.size * flags.get("rotary_pct", 100), 100)
-            if rest or width < 2 or width % 2 or width > params.key_dim.size:
-                raise ValueError(
-                    f"rotary_pct{flags.get('rotary_pct')}: an even number "
-                    f"of features_per_head {params.key_dim.size}'s features")
+            width = rotary_width(params.key_dim.size,
+                                 flags.get("rotary_pct"))
             rope_args = (width,)
             if "yarn" in flags:
                 import math
@@ -560,25 +603,14 @@ def _standard_attention(args: BlockArgs) -> NamedTensor:
             q = rotary(q, theta, *rope_args)
             k = rotary(k, theta, *rope_args)
     scale = params.attention_scale or params.key_dim.size ** -0.5
-    if grouped:
-        # grouped queries: each K/V head repeated over its group before the
-        # kernel, autodiff sums dk and dv over it (the flash kernels are
-        # multi-head only; PERF.md section 6, PR 30 has the A/B against K/V
-        # index maps)
-        k, v = (jnp.repeat(t, group, axis=2) for t in (k, v))
-    window = flags.get("window")
-    if params.use_flash_attention:
-        out = _flash(ctx, q, k, v, scale, window)
-    else:
-        from ..parallel.flash_attention import _xla_reference
-        with jax.named_scope("attention_dense"):
-            out = _xla_reference(q, k, v, scale, True, window)
+    out = causal_heads(ctx, params, q, k, v, group, scale,
+                       flags.get("window"))
     out_nt = nt(out.reshape([d.size for d in canonical]), canonical)
     if gate is not None:
         with jax.named_scope("gate"):
             out_nt = out_nt * nt(jax.nn.sigmoid(
                 gate.data.astype(jnp.float32)).astype(out.dtype), gate.dims)
-    return project(transpose_to(
+    return project(args, transpose_to(
         out_nt, [d for d in args.tensor.dims if d not in feats] + q_feats),
         feats, q_feats)
 

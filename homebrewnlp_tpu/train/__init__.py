@@ -67,7 +67,8 @@ def _local_batch_dims(p: ModelParameter, local: int):
 def _info_metrics(info) -> typing.Dict[str, jax.Array]:
     """Loss/accuracy metrics from a model BuildInfo (None -> 0), and what
     its layers reported of themselves (``LossInfo.layer_stats``): layer
-    moe's worst expert load and the (token, choice) pairs it routed, layer
+    moe's worst expert load and the (token, choice) pairs it routed (and
+    under top-1 its chosen probability), layer cca's logit bound, layer
     mamba's most negative within-chunk cumulative log-decay, layer
     gated_delta's largest solved transform."""
     stats = getattr(info, "layer_stats", None) or {}
@@ -85,6 +86,11 @@ def _info_metrics(info) -> typing.Dict[str, jax.Array]:
             / extra["moe_routed_pairs"]
         extra["moe_held_pair_share_max"] = jnp.max(
             stats["moe_held_pairs"] / stats["moe_routed_pairs"])
+    if "moe_top1_weight_mean" in stats:
+        # the layer whose router says least
+        extra["moe_top1_weight_mean"] = jnp.min(stats["moe_top1_weight_mean"])
+    if "cca_logit_scale" in stats:
+        extra["cca_logit_scale_max"] = jnp.max(stats["cca_logit_scale"])
     if "ssd_log_decay_min" in stats:
         extra["ssd_log_decay_min"] = jnp.min(stats["ssd_log_decay_min"])
     if "delta_transform_abs_max" in stats:
@@ -137,6 +143,16 @@ _LAYER_STATS = {
         "the same share in the moe layer where it is largest: how far the "
         "static row buffer (hbnlp_moe_held_rows_bound) is filled is this "
         "times moe_top_k / min(moe_top_k, experts_held)"),
+    "moe_top1_weight_mean": (
+        "gauge", "hbnlp_moe_top1_weight_mean",
+        "mean probability of the chosen expert over the tokens of the newest "
+        "finished step, in the top-1 moe layer where it is smallest "
+        "(1 / experts = a router that says nothing)"),
+    "cca_logit_scale_max": (
+        "gauge", "hbnlp_cca_logit_scale_max",
+        "largest sqrt(features_per_head) * |tau| over the cca layers of the "
+        "newest finished step: q and k have unit direction, so no attention "
+        "logit passes it"),
     "ssd_log_decay_min": (
         "gauge", "hbnlp_ssd_log_decay_min",
         "most negative within-chunk cumulative dt * A of the newest finished "
@@ -644,11 +660,13 @@ class Trainer:
         the Pallas conv (``conv_kernel_layers``), and
         ``hbnlp_delta_solve_kernel_layers``: how many took the Pallas pair
         for their triangular solve (``solve_kernel_layers``; 0 where no
-        layer has one).  Set when the step is built; returns the start-up
+        layer has one), and ``hbnlp_router_carry_bytes``: the router states
+        carried between blocks (``router_carry_bytes``; no series where no
+        layer carries one).  Set when the step is built; returns the start-up
         line that says the same."""
         from ..model.remat import (conv_kernel_layers, moe_held_rows,
-                                   solve_kernel_layers, ssd_state_bytes,
-                                   stash_line, stash_plan)
+                                   router_carry_bytes, solve_kernel_layers,
+                                   ssd_state_bytes, stash_line, stash_plan)
         plan = stash_plan(self.params, self.mesh)
         r = telemetry.registry()
         held_rows = moe_held_rows(self.params)
@@ -658,6 +676,14 @@ class Trainer:
                     "holds a share of the experts: tokens x min(moe_top_k, "
                     "experts_held), which no routing overflows"
                     ).set(held_rows)
+        carry = router_carry_bytes(self.params)
+        if carry:
+            r.gauge("hbnlp_router_carry_bytes",
+                    "bytes of the router states (layer moe, router_mlp) "
+                    "alive between blocks for the backward: the carried "
+                    "side value, float32 [batch, sequence, "
+                    "moe_router_width] a carrying layer but the last"
+                    ).set(carry)
         states = ssd_state_bytes(self.params, self.mesh)
         r.gauge("hbnlp_ssd_state_bytes",
                 "per-device bytes of the recurrent mixers' (mamba, "
@@ -687,7 +713,8 @@ class Trainer:
             f"{conv_layers} layers" if states else "") + (
             f"; solve kernel {solve_layers} layers"
             if solve_layers is not None else "") + (
-            f"; moe held rows bound {held_rows}" if held_rows else "")
+            f"; moe held rows bound {held_rows}" if held_rows else "") + (
+            f"; router carry {carry} bytes" if carry else "")
 
     def lowered(self, state: TrainState, batch: typing.Dict[str, jax.Array]):
         """Lowered (StableHLO) train step for ``save_graph`` dumps — the
@@ -768,7 +795,8 @@ class Trainer:
     def _publish_layer_stats(self, metrics) -> None:
         """``hbnlp_moe_load_max_over_mean``, ``hbnlp_moe_routed_pairs_total``,
         ``hbnlp_moe_held_pairs_total``, ``hbnlp_moe_held_pair_share`` (and
-        ``_max``), ``hbnlp_ssd_log_decay_min`` and ``hbnlp_delta_transform_abs_max`` (under ``telemetry_enabled``: only
+        ``_max``), ``hbnlp_moe_top1_weight_mean``,
+        ``hbnlp_cca_logit_scale_max``, ``hbnlp_ssd_log_decay_min`` and ``hbnlp_delta_transform_abs_max`` (under ``telemetry_enabled``: only
         then does the step report them) from the scalars of EARLIER steps
         the device has finished; a step still running is left for a later
         call, so this never waits.  The last steps of a run stay unread."""

@@ -313,12 +313,14 @@ def the_step_reports_the_held_share_test():
 
 def the_experts_stash_counts_the_bounds_rows_test():
     """model/remat.py's ``experts`` kind: the saved outputs of a layer that
-    holds a share are its whole static buffer."""
+    holds a share are its whole static buffer, and (PR 39) the router's
+    choice, ``[tokens, moe_top_k]`` int32, beside the routing triple."""
     params = ModelParameter(_config("bfloat16"))
     layers, nbytes = remat._experts_stash(params, 1)
     rows = 2 * 128 * 4
     assert layers == 4
-    assert nbytes == 4 * (rows * (2 * 24 + 32) * 2 + (2 * rows + 5) * 4)
+    assert nbytes == 4 * (rows * (2 * 24 + 32) * 2
+                          + (2 * rows + 5 + 2 * 128 * 4) * 4)
 
 
 # ---- rotary positions ------------------------------------------------------------

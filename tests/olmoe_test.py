@@ -39,7 +39,9 @@ def _build(experts: int, top_k: int, dtype: str, **extra):
     return config, params, model, batch, model.init(batch, seed=11)
 
 
-CASES = [(8, 2), (64, 8)]
+# (16, 1): top-1 with every expert held (ZAYA1's count and choice, PR 39): one
+# slot a token, against the dense loop over experts
+CASES = [(8, 2), (64, 8), (16, 1)]
 
 
 @pytest.mark.parametrize("experts,top_k", CASES)
@@ -230,8 +232,9 @@ def saved_expert_outputs_change_no_bit_test(scan):
         counts[policy] = _count_primitives(
             jax.make_jaxpr(step)(variables).jaxpr, ("ragged_dot", "sort"))
         results[policy] = jax.jit(step)(variables)
-        # 256 pairs x (2 x 32 + 64) x float32 + (2 x 256 + 8) x int32, twice
-        engaged = (2, 266304) if policy == "auto" else (0, 0)
+        # 256 pairs x (2 x 32 + 64) x float32 + (2 x 256 + 8) x int32 and
+        # (PR 39) the router's choice [128, 2] x int32, twice
+        engaged = (2, 268352) if policy == "auto" else (0, 0)
         line = Trainer(params, model).publish_stash_plan()
         assert line.endswith(f"experts {engaged[0]} layers, {engaged[1]} "
                              "bytes a device; recurrent 0 layers, 0 bytes a "

@@ -311,8 +311,8 @@ def _cell_params(cell: str, **kw):
 
 
 #: 65,536 pairs x (2 x 1,024 + 2,048) x bfloat16, and order + inverse + the
-#: 64 sizes in int32, a layer
-_OLMOE_LAYER = 65536 * (2 * 1024 + 2048) * 2 + (2 * 65536 + 64) * 4
+#: 64 sizes + (PR 39) the router's choice [8,192, 8] in int32, a layer
+_OLMOE_LAYER = 65536 * (2 * 1024 + 2048) * 2 + (3 * 65536 + 64) * 4
 
 
 @pytest.mark.parametrize("case", [
@@ -326,7 +326,7 @@ def experts_stash_resolver_test(case):
         p = _cell_params("train_olmoe_1b_7b_s4k")
         rep = remat_report(p)
         assert rep["experts_stash_bytes_per_device"] == 2 * _OLMOE_LAYER \
-            == 1073741824 + 1049088 <= rep["stash_budget_bytes"]
+            == 1073741824 + 1573376 <= rep["stash_budget_bytes"]
         assert "experts" in stash_kinds(p)
         # the attention kind is named by its sequence rule and rides
         # nothing under "checkpoint": it took nothing from the budget
@@ -382,9 +382,9 @@ def experts_stash_resolver_test(case):
     ("train_1b_long_context_s16k", {"attention"}, "stash",
      {"attention": (8, 2155872256)}, ()),
     ("train_olmoe_1b_7b_s4k", {"attention", "experts"}, "stash",
-     {"experts": (2, 1074790912)},
+     {"experts": (2, 1075315200)},
      ("moe_gate", "moe_up", "moe_down", "moe_order", "moe_inverse",
-      "moe_sizes")),
+      "moe_sizes", "moe_experts")),
     ("train_granite_4_0_h_micro_long", {"attention"}, "stash", {}, ())])
 def experts_kind_moves_no_other_cell_test(cell, kinds, policy, plan, names):
     """What the three cells without a ``moe`` layer resolved to before the
@@ -493,7 +493,7 @@ def _with_moe(top_k: int):
     blocks[at]["layer"][0] = "moe"
     p = _cell_params(_OLMO, block_config=blocks, experts=64, moe_top_k=top_k)
     pairs = 16384 * top_k
-    return p, pairs * (2 * 11008 + 3840) * 2 + (2 * pairs + 64) * 4
+    return p, pairs * (2 * 11008 + 3840) * 2 + (3 * pairs + 64) * 4
 
 
 @pytest.mark.parametrize("case", [
@@ -570,7 +570,7 @@ def recurrent_stash_resolver_test(case):
                                  "recurrent": (3, 3 * _OLMO_LAYER)}
         assert stash_names(p) == (
             "moe_gate", "moe_up", "moe_down", "moe_order", "moe_inverse",
-            "moe_sizes", "gated_delta_out")
+            "moe_sizes", "moe_experts", "gated_delta_out")
     elif case == "experts_leave_none":
         p, experts = _with_moe(3)
         budget = remat_report(p)["stash_budget_bytes"]
@@ -580,7 +580,7 @@ def recurrent_stash_resolver_test(case):
         assert stash_plan(p) == {**idle, "experts": (1, experts)}
         assert stash_names(p) == (
             "moe_gate", "moe_up", "moe_down", "moe_order", "moe_inverse",
-            "moe_sizes")
+            "moe_sizes", "moe_experts")
     elif case == "experts_decline":
         # experts over the budget take none of it
         p, experts = _with_moe(8)
