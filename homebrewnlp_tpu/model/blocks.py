@@ -154,6 +154,13 @@ def _call_block(f, subset, x, it=None, chan=None):
 # in push order, whatever their kind, so a consumer's gate (kind, shapes,
 # mesh) must be a function of what BOTH traces see — it must push in
 # collect mode exactly when it pops in provide mode.
+#
+# The ``checkpoint`` strategy has no residuals of its own to ride: where the
+# attention kind rides each block's ``jax.checkpoint`` instead
+# (model/remat.py), the blocks get a third, stateless mode, "name"
+# (``_name_chan``): a flash layer whose queries see at least ``min_keys``
+# keys NAMES its (out, lse) for the block's policy to save
+# (parallel/flash_attention.py ``SAVED_NAMES``) and pushes nothing.
 
 def _collect_chan(stash: typing.FrozenSet[str]):
     return {"mode": "collect", "items": [], "kinds": stash} if stash else None
@@ -166,6 +173,18 @@ def _provide_chan(stash: typing.FrozenSet[str], items):
     if not stash or not items:
         return None
     return {"mode": "provide", "items": list(items), "i": 0, "kinds": stash}
+
+
+def _name_chan(params: ModelParameter, mesh):
+    """The channel every block of the ``checkpoint`` strategy gets where the
+    attention kind rides its ``jax.checkpoint``
+    (model/remat.py ``saved_attention_keys``), else None."""
+    from .remat import saved_attention_keys
+    keys = saved_attention_keys(params, mesh)
+    if keys is None:
+        return None
+    return {"mode": "name", "kinds": frozenset({"attention"}),
+            "min_keys": keys}
 
 
 def _chan_items(chan):
@@ -196,6 +215,10 @@ def stash_pop(chan):
 
 def stash_collecting(chan) -> bool:
     return chan is not None and chan["mode"] == "collect"
+
+
+def stash_naming(chan) -> bool:
+    return chan is not None and chan["mode"] == "name"
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 4))
@@ -439,8 +462,9 @@ def _checkpoint_policy(params: ModelParameter, mesh=None):
     "nothing_saveable" is jax.checkpoint's own default, so reference
     configs are unchanged) and, where a kind of model/remat.py rides it
     (``stash_names``: ``experts`` — layer ``moe``'s named outputs —
-    ``recurrent`` — the output a recurrent mixer offers), also those
-    names."""
+    ``recurrent`` — the output a recurrent mixer offers — ``attention`` —
+    the (out, lse) every flash layer names under the blocks' "name" channel,
+    ``_name_chan``), also those names."""
     named = getattr(jax.checkpoint_policies,
                     params.gradient_checkpointing_policy)
     from .remat import stash_names
@@ -459,15 +483,18 @@ def _merge_stats(parts) -> dict:
             for k in sorted({k for p in parts for k in p})}
 
 
-def _block_with_stats(f, collect: bool):
+def _block_with_stats(f, collect: bool, chan=None):
     """``(subset, x, it) -> (out, {name: [n] array})``: the block, and the
     statistics its layers reported (``Context.layer_stats``) as an explicit
     output, so that they can leave a checkpoint or scan region.  With
     ``side`` (the carried side values, ``Context.side``) ``out`` is
     ``(stream, side)``: the values enter and leave the region as explicit
-    operands too, so the backward holds their cotangents."""
+    operands too, so the backward holds their cotangents.  ``chan``: the
+    block's stash channel (``_name_chan``), handed on where there is one."""
     def call(subset, x, it=None, side=None):
         kwargs = {} if side is None else {"side": side}
+        if chan is not None:
+            kwargs["stash"] = chan
         if not collect:
             return f(subset, x, it=it, **kwargs), {}
         sink: list = []
@@ -477,7 +504,8 @@ def _block_with_stats(f, collect: bool):
 
 
 def _plain_scan(fns, stacked, shared, x, use_checkpoint: bool,
-                unroll: int = 1, ckpt_policy=None, collect: bool = False):
+                unroll: int = 1, ckpt_policy=None, collect: bool = False,
+                chan=None):
     """Scanned 'checkpoint' / 'none' strategies: O(depth) carries saved by
     scan AD; with use_checkpoint each block recomputes its interior.
     Returns the output and the layers' statistics (empty unless
@@ -486,7 +514,7 @@ def _plain_scan(fns, stacked, shared, x, use_checkpoint: bool,
         x, it = carry
         parts = []
         for f, stk, shr in zip(fns, sl, shared):
-            call = _block_with_stats(f, collect)
+            call = _block_with_stats(f, collect, chan)
             if use_checkpoint:
                 call = jax.checkpoint(call, policy=ckpt_policy)
             x, stats = call({**stk, **shr}, x, it)
@@ -638,7 +666,8 @@ def _try_scan(params: ModelParameter, ctx, plan, src: NamedTensor,
     out, stats = _plain_scan(fns, stacked, shared, src,
                              strategy == "checkpoint", params.scan_unroll,
                              _checkpoint_policy(params, ctx.mesh),
-                             collect=ctx.layer_stats is not None)
+                             collect=ctx.layer_stats is not None,
+                             chan=_name_chan(params, ctx.mesh))
     if stats:
         ctx.layer_stats.append(_merge_stats([stats]))
     return out
@@ -1044,8 +1073,9 @@ def run_body_blocks(params: ModelParameter, src: NamedTensor,
     # model whose layers carry nothing
     out, parts, side = src, [], {}
     checkpoint_policy = _checkpoint_policy(params, ctx.mesh)
+    chan = _name_chan(params, ctx.mesh)
     for f, s in zip(fns, subsets):
-        call = _block_with_stats(f, ctx.layer_stats is not None)
+        call = _block_with_stats(f, ctx.layer_stats is not None, chan)
         if strategy == "checkpoint":
             call = jax.checkpoint(call, policy=checkpoint_policy)
         (out, side), stats = call(s, out, None, side)

@@ -196,3 +196,13 @@ def cca(args: BlockArgs) -> NamedTensor:
             out_nt, [dm for dm in args.tensor.dims if dm not in feats]
             + q_feats), feats, q_feats,
             stddev=params.residual_out_stddev or 0.02)
+
+
+def _declared_flash(params, extras):
+    """Set as ``cca.flash`` (model/remat.py's attention kind under
+    ``checkpoint``; model/spatial.py ``attention.flash``): the one causal
+    call over ``q_heads`` latent heads, no window."""
+    return numbered_flags(extras, (), _NUMBERED, "layer cca")["q_heads"], None
+
+
+cca.flash = _declared_flash

@@ -21,18 +21,29 @@ policy          behavior
                 ``[b, s, intermediate]``, so the replay runs neither that
                 matmul nor, where it contracts a mesh-sharded axis, its
                 tensor-parallel all-reduce (PR 27).  Under the
-                ``checkpoint`` strategy there is no channel: the third
-                kind, ``experts`` — layer ``moe``'s gate, up and down
-                outputs and its routing triple — is SAVED by each block's
-                ``jax.checkpoint`` through a policy over named values
-                (``_checkpoint_policy``), so the replay runs none of the
-                three forward grouped matmuls, 3 of a layer's 12 (PR 29).
+                ``checkpoint`` strategy there is no channel of residuals:
+                what rides is SAVED by each block's ``jax.checkpoint``
+                through a policy over named values
+                (``_checkpoint_policy``).  The third kind, ``experts`` —
+                layer ``moe``'s gate, up and down outputs and its routing
+                triple — rides it so: the replay runs none of the three
+                forward grouped matmuls, 3 of a layer's 12 (PR 29).
                 The fourth, ``recurrent``, rides the same policy: the
                 OUTPUT a recurrent mixer offers because it re-materialises
                 its own interior (``model/recurrent.py`` ``Recurrent``:
                 layer ``gated_delta``'s rule, a group of heads at a time) —
                 the replay then runs no forward of the recurrence, two
-                forwards of the rule a step instead of three (PR 33)
+                forwards of the rule a step instead of three (PR 33).
+                ``attention`` rides it too (PR 40): the blocks get a
+                stateless "name" channel (``model/blocks.py``
+                ``_name_chan``) under which a flash call computes ``(out,
+                lse)`` once, names both (``parallel/flash_attention.py``
+                ``SAVED_NAMES``) and returns ``flash_precomputed`` on
+                them; the replay finds both outputs of the forward kernel
+                saved, so the call is dead code there and the backward is
+                the flash-2 pass on the replayed ``q, k, v`` and the
+                forward's own ``(out, lse)``: one ``flash_fwd_*`` call a
+                layer a step instead of two
 ``save``        NO ``custom_vjp``: the identical primal recurrence under
                 native scan AD; every linearization residual is saved —
                 zero recompute, O(depth) residual memory
@@ -56,31 +67,44 @@ classification this resolver keys on.  ``auto`` therefore picks:
 1. the explicit ``remat_policy`` value when set;
 2. the legacy ``stash_attention_outputs`` boolean when the user set one
    (``true`` → ``stash``, ``false`` → ``recompute``);
-3. ``stash`` when a kind's own rule engages (:func:`stash_kinds`):
-   ``attention`` when the long-context rule pays and fits (seq >= 2048,
-   % 128 == 0, per-device stash <= 15% of HBM — the measured +23% at 16k);
+3. ``stash`` when a kind's own rule engages (:func:`stash_kinds`).
+   Under revnet / momentum (the channel's kinds): ``attention`` when the
+   long-context rule pays and fits (seq >= 2048, % 128 == 0, per-device
+   stash <= 15% of HBM — the measured +23% at 16k);
    ``bottleneck`` when the in-projection's contraction crosses a ``model``
    mesh axis > 1 (each chip then holds a partial sum and the replay would
    all-reduce it a second time — one of three exposed collectives a layer
    on the {data: 2, model: 2} flagship, PERF.md PR 27) and its per-device
-   bytes fit what the attention stash leaves of the same 15%;
-   ``experts``, decided LAST, when the strategy is ``checkpoint``, the
-   model has a ``moe`` layer and the saved outputs of the WHOLE depth —
-   ``pairs x (2 x intermediate + features) x itemsize`` a layer, ``pairs
-   = tokens x top-k``, plus the routing triple — fit the same 15% (the
-   other two kinds ride the revnet / momentum residuals and take nothing
-   of it under ``checkpoint``).  All layers or none: OLMoE-1B-7B at depth
-   2 on 8,192 tokens is 1.07 GB of ~2.5 and rides, at its published depth
-   16 it is 8.6 GB and the rule declines; saving some layers only is a
-   later issue.  ``recurrent`` is decided AFTER it, from what ``experts``
-   leaves of that 15%: strategy ``checkpoint``, at least one layer that
+   bytes fit what the attention stash leaves of the same 15%.
+   Under ``checkpoint`` (the policy's kinds; the two above ride the revnet
+   / momentum residuals and take nothing of the 15% there), in this order:
+   ``experts`` when the model has a ``moe`` layer and the saved outputs of
+   the WHOLE depth — ``pairs x (2 x intermediate + features) x itemsize``
+   a layer, ``pairs = tokens x top-k``, plus the routing triple — fit the
+   15%.  All layers or none: OLMoE-1B-7B at depth 2 on 8,192 tokens is
+   1.07 GB of ~2.5 and rides, at its published depth 16 it is 8.6 GB and
+   the rule declines; saving some layers only is a later issue.
+   ``recurrent`` is decided AFTER it, from what ``experts``
+   leaves of that 15%: at least one layer that
    DECLARES an output to save (``Recurrent.saved_names`` / ``saved_bytes``;
    the resolver tests no layer's and no model's name) and the whole
    depth's declared bytes within what is left — all layers or none.
    Olmo-Hybrid-7B's period of four layers on 16,384 tokens is 3 x 189 MB =
    566 MB of ~2.5 GB and rides; layer ``mamba`` declares nothing (its scan
    has no inner ``jax.checkpoint``: the replay's forward is the pass that
-   makes its backward's residuals).  The legacy boolean ``true`` forces
+   makes its backward's residuals).  ``attention`` is decided LAST there
+   (PR 40), from what both leave, so it moves neither: the ``(out, lse)``
+   of every layer whose flash call engages (the call a layer DECLARES,
+   ``<layer function>.flash``: its OWN query heads and window — layer
+   ``cca``'s 8 in a 16-head stream, 72 beside 48 in one model — on one
+   device, under ``use_flash_attention``, at a sequence of whole
+   128-tiles) and in which a query sees at least 2,048 keys,
+   ``min(sequence, window)`` (:data:`ATTENTION_MIN_KEYS`: the historical
+   ``seq >= 2048`` read a layer), all such layers or none — and it
+   declines where an earlier kind had bytes to save and declined for size:
+   a step whose expert buffers alone pass the budget regenerates them live
+   inside each block's backward and has no room to hold more across
+   blocks.  The legacy boolean ``true`` forces
    the attention kind only (its name; the other kinds still resolve by
    their rules), ``false`` is "recompute";
 4. else ``recompute``.  The save modes stay measured OPT-INS: the A/B
@@ -112,12 +136,18 @@ SAVE_HBM_FRACTION = 0.35
 #: operands) — calibrated against the measured flagship step
 SAVE_RESIDUALS_PER_BLOCK = 16
 
+#: keys a query of a flash layer must see (``min(sequence, window)``) before
+#: the layer's ``(out, lse)`` is held across a ``checkpoint`` block's replay:
+#: the historical ``seq >= 2048``, read a layer (at its floor a window-512
+#: forward is 1.5 ms for 302 MB saved)
+ATTENTION_MIN_KEYS = 2048
+
 POLICIES = ("recompute", "stash", "save", "save_dots")
 #: what a memory strategy can keep for its backward under "stash":
 #: ``attention`` and ``bottleneck`` ride the revnet / momentum residuals (the
-#: channel's kinds, model/blocks.py ``stash_channel``), ``experts`` and
-#: ``recurrent`` the ``checkpoint`` strategy's ``jax.checkpoint``
-#: (``_checkpoint_policy``)
+#: channel's kinds, model/blocks.py ``stash_channel``), ``experts``,
+#: ``recurrent`` and, there, ``attention`` too the ``checkpoint`` strategy's
+#: ``jax.checkpoint`` (``_checkpoint_policy``)
 STASH_KINDS = ("attention", "bottleneck", "experts", "recurrent")
 
 
@@ -251,6 +281,48 @@ def _recurrent_stash(params: ModelParameter, shards: int
         -sum(offers) * params.depth * max(1, params.macro_batching) // shards)
 
 
+def _forced_attention(params: ModelParameter) -> bool:
+    """The configuration itself names the attention kind: an explicit
+    ``"stash"``, or the legacy boolean's ``true``."""
+    return _explicit_policy(params) == "stash" \
+        or getattr(params, "stash_attention_outputs", "auto") is True
+
+
+def _attention_min_keys(params: ModelParameter) -> int:
+    """The fewest keys a query of a flash call sees where, under
+    ``checkpoint``, the call's ``(out, lse)`` are saved:
+    :data:`ATTENTION_MIN_KEYS` by the rule, 0 — every engaged call — where
+    the configuration names the kind itself."""
+    return 0 if _forced_attention(params) else ATTENTION_MIN_KEYS
+
+
+def _saved_attention(params: ModelParameter, mesh, min_keys: int
+                     ) -> typing.Tuple[int, int]:
+    """``(layers, per-device bytes)`` of the attention kind under
+    ``checkpoint`` over the whole depth: ``out`` ``[batch, sequence, heads,
+    features_per_head]`` in the calculation dtype and ``lse`` ``[batch x
+    heads, sequence]`` float32 of every layer whose flash call engages — the
+    call a layer DECLARES (``<layer function>.flash``: its OWN query heads
+    and window), on one device, under ``use_flash_attention``, at a sequence
+    of whole 128-tiles: model/spatial.py ``_flash`` — and in which a query
+    sees at least ``min_keys`` keys (parallel/flash_attention.py
+    ``attention``'s "name" mode, the same test)."""
+    seq = params.sequence_dim.size
+    if mesh is not None or not params.use_flash_attention or seq % 128:
+        return 0, 0
+    from .frontend import LAYER_FUNCTIONS
+    heads = []
+    for name, extras in _layers(params):
+        declare = getattr(LAYER_FUNCTIONS.get(name), "flash", None)
+        call = declare(params, extras) if declare is not None else None
+        if call is not None and min(seq, call[1] or seq) >= min_keys:
+            heads.append(call[0])
+    per_head = params.batch_dim.size * seq * (
+        params.key_dim.size * np.dtype(params.calculation_dtype).itemsize + 4)
+    return len(heads) * params.depth, sum(heads) * per_head * params.depth \
+        * max(1, params.macro_batching)
+
+
 def _save_residual_bytes(params: ModelParameter) -> int:
     """Global estimate of the native-AD linearization residuals the save
     policy keeps: f32 activation-sized intermediates per block part,
@@ -285,8 +357,12 @@ def remat_report(params: ModelParameter, mesh=None) -> typing.Dict[str, typing.A
     layers, bottleneck_bytes, crosses = _bottleneck_stash(params, mesh)
     experts_layers, experts_bytes = _experts_stash(params, shards)
     recurrent_layers, recurrent_bytes = _recurrent_stash(params, shards)
+    saved_layers, saved_bytes = _saved_attention(params, mesh,
+                                                 ATTENTION_MIN_KEYS)
     return {
         "stash_bytes_per_device": -(-_stash_bytes(params) // shards),
+        "saved_attention_layers": saved_layers,
+        "saved_attention_bytes_per_device": saved_bytes,
         "bottleneck_stash_layers": layers,
         "bottleneck_stash_bytes_per_device": bottleneck_bytes,
         "bottleneck_crosses_model_axis": crosses,
@@ -320,34 +396,48 @@ def stash_kinds(params: ModelParameter, mesh=None) -> typing.FrozenSet[str]:
     """Which :data:`STASH_KINDS` the strategy keeps for its backward for
     this (config, mesh): all under an explicit ``"stash"``, none under any
     other explicit policy, else each kind by its own rule (the module
-    docstring's item 3).  The attention rule is the historical one and is
-    decided FIRST: the bottleneck kind only gets what it leaves of the
-    budget, so adding that kind moved no configuration's attention
-    decision; the experts kind is decided after them and moves neither;
-    the recurrent kind is decided LAST, from what experts leaves."""
+    docstring's item 3).  Under revnet / momentum the attention rule is the
+    historical one and is decided FIRST: the bottleneck kind only gets what
+    it leaves of the budget, so adding that kind moved no configuration's
+    attention decision.  Under ``checkpoint`` the experts kind is decided
+    first, the recurrent kind from what it leaves, and the attention kind
+    LAST, from what both leave — so it moves neither — and not at all where
+    one of them had bytes to save and declined for size."""
     explicit = _explicit_policy(params)
     if explicit is not None:
         return frozenset(STASH_KINDS if explicit == "stash" else ())
     rep = remat_report(params, mesh)
     budget = rep["stash_budget_bytes"]
+    forced = _forced_attention(params)
     kinds = set()
-    if getattr(params, "stash_attention_outputs", "auto") is True or (
-            rep["seq"] >= 2048 and rep["seq"] % 128 == 0
-            and rep["stash_bytes_per_device"] <= budget):
+    if forced or (rep["seq"] >= 2048 and rep["seq"] % 128 == 0
+                  and rep["stash_bytes_per_device"] <= budget):
         kinds.add("attention")
         budget -= rep["stash_bytes_per_device"]
     if rep["bottleneck_crosses_model_axis"] \
             and 0 < rep["bottleneck_stash_bytes_per_device"] <= budget:
         kinds.add("bottleneck")
+    if params.memory_reduction_strategy != "checkpoint":
+        return frozenset(kinds)
     # the whole budget: what the two kinds above name rides the revnet /
-    # momentum residuals, so under "checkpoint" they hold no byte of it
-    if params.memory_reduction_strategy == "checkpoint":
-        budget = rep["stash_budget_bytes"]
-        if 0 < rep["experts_stash_bytes_per_device"] <= budget:
-            kinds.add("experts")
-            budget -= rep["experts_stash_bytes_per_device"]
-        if 0 < rep["recurrent_stash_bytes_per_device"] <= budget:
-            kinds.add("recurrent")
+    # momentum residuals, so under "checkpoint" they hold no byte of it, and
+    # the attention kind is decided again by what each layer really saves
+    kinds.discard("attention")
+    budget = rep["stash_budget_bytes"]
+    fitted = True
+    for kind in ("experts", "recurrent"):
+        nbytes = rep[f"{kind}_stash_bytes_per_device"]
+        if 0 < nbytes <= budget:
+            kinds.add(kind)
+            budget -= nbytes
+        elif nbytes:
+            # a step whose expert buffers alone pass the budget regenerates
+            # them live inside each block's backward: no room to hold more
+            fitted = False
+    layers, nbytes = _saved_attention(params, mesh,
+                                      _attention_min_keys(params))
+    if layers and (forced or (fitted and nbytes <= budget)):
+        kinds.add("attention")
     return frozenset(kinds)
 
 
@@ -362,10 +452,9 @@ def _attention_sites(params: ModelParameter, mesh) -> int:
     if not ring and (has_mesh or not params.use_flash_attention
                      or params.sequence_dim.size % 128):
         return 0
-    dense_only = {"biased_softmax", "biased_attention_map",
-                  "scale_attention_map", "shared_key_value"}
+    from .spatial import _DENSE_ONLY
     return sum(name == "attention" and "dot_product" in extras
-               and not dense_only & extras
+               and not extras.intersection(_DENSE_ONLY)
                for name, extras in _layers(params))
 
 
@@ -377,9 +466,9 @@ def stash_plan(params: ModelParameter, mesh=None
     no way to keep it, a pipeline mesh, an explicit policy, a rule that
     declined, no such layer).  ``Trainer`` publishes it as
     ``hbnlp_remat_stash_bytes{kind}`` / ``hbnlp_remat_stash_layers{kind}``
-    (docs/OBSERVABILITY.md); ``_checkpoint_policy`` saves the experts and
-    the recurrent kind's names exactly where this says they ride
-    (:func:`stash_names`)."""
+    (docs/OBSERVABILITY.md); ``_checkpoint_policy`` saves the experts, the
+    recurrent and the attention kind's names exactly where this says they
+    ride (:func:`stash_names`)."""
     from ..core.sharding import PIPE_AXIS
     plan = {kind: (0, 0) for kind in STASH_KINDS}
     strategy = params.memory_reduction_strategy
@@ -393,6 +482,9 @@ def stash_plan(params: ModelParameter, mesh=None
             if kind in kinds and rep[f"{kind}_stash_layers"]:
                 plan[kind] = (rep[f"{kind}_stash_layers"],
                               rep[f"{kind}_stash_bytes_per_device"])
+        if "attention" in kinds:
+            plan["attention"] = _saved_attention(
+                params, mesh, _attention_min_keys(params))
         return plan
     if "attention" in kinds:
         layers = _attention_sites(params, mesh) * params.depth
@@ -415,12 +507,27 @@ def _recurrent_layers(params: ModelParameter):
     return [spec for spec in found if spec is not None]
 
 
+def saved_attention_keys(params: ModelParameter, mesh=None
+                         ) -> typing.Optional[int]:
+    """Where the attention kind rides the ``checkpoint`` strategy's
+    ``jax.checkpoint`` (:func:`stash_plan`): the fewest keys a query of a
+    flash call sees where the call names its ``(out, lse)`` — what the
+    blocks' "name" channel carries (model/blocks.py ``_name_chan``;
+    :data:`ATTENTION_MIN_KEYS`, 0 where the configuration names the kind
+    itself).  None where it does not ride."""
+    if params.memory_reduction_strategy != "checkpoint" \
+            or not stash_plan(params, mesh)["attention"][0]:
+        return None
+    return _attention_min_keys(params)
+
+
 def stash_names(params: ModelParameter, mesh=None) -> typing.Tuple[str, ...]:
     """The ``checkpoint_name``s the ``checkpoint`` strategy's
     ``jax.checkpoint`` saves beside its named policy: those of every kind
     :func:`stash_plan` says rides it — layer ``moe``'s (model/moe.py
     ``SAVED_NAMES``), then what the recurrent mixers declare, in execution
-    order.  Empty where none does."""
+    order, then the flash layers' (parallel/flash_attention.py
+    ``SAVED_NAMES``).  Empty where none does."""
     plan = stash_plan(params, mesh)
     names = []
     if plan["experts"][0]:
@@ -429,6 +536,9 @@ def stash_names(params: ModelParameter, mesh=None) -> typing.Tuple[str, ...]:
     if plan["recurrent"][0]:
         names += [name for spec in _recurrent_layers(params)
                   for name in spec.saved_names]
+    if saved_attention_keys(params, mesh) is not None:
+        from ..parallel.flash_attention import SAVED_NAMES
+        names += SAVED_NAMES
     return tuple(dict.fromkeys(names))     # a name once, in order
 
 

@@ -43,6 +43,13 @@ def _anonymize_kv(x: NamedTensor, dim: Dim) -> NamedTensor:
     return anonymize(x, dim)
 
 
+#: flags under which only the dense einsum reproduces the reference: the
+#: map-bias flags need the dense [s, s] map, shared_key_value leaves the value
+#: on the query dim
+_DENSE_ONLY = ("biased_softmax", "biased_attention_map",
+               "scale_attention_map", "shared_key_value")
+
+
 def _plain_softmax_qkv(args: BlockArgs, dim: Dim, qry: NamedTensor,
                        key: typing.Union[NamedTensor, int], base: BlockArgs):
     """Shared gate + extraction for the ring/flash kernel routes.
@@ -56,9 +63,7 @@ def _plain_softmax_qkv(args: BlockArgs, dim: Dim, qry: NamedTensor,
     init (meshless) and kernel-routed apply resolve identical names."""
     from ..core.tensor import transpose_to
     params = args.params
-    if any(f in args.name_extras for f in
-           ("biased_softmax", "biased_attention_map", "scale_attention_map",
-            "shared_key_value")):
+    if any(f in args.name_extras for f in _DENSE_ONLY):
         return None
     if not isinstance(key, NamedTensor):
         return None
@@ -181,9 +186,10 @@ def _flash(ctx, q, k, v, scale: float, window=None):
     mesh = ctx.mesh
     if mesh is None:
         # causal=True always: the dense softmax branch masks unconditionally.
-        # stash: the strategy machinery's replay stash channel
-        # (model/blocks.py) — single-device path only; the shard_map branch
-        # keeps the plain kernel
+        # stash: the strategy machinery's stash channel (model/blocks.py:
+        # collect / provide under revnet and momentum, "name" under
+        # checkpoint) — single-device path only; the shard_map branch keeps
+        # the plain kernel
         from .blocks import stash_channel
         return flash(q, k, v, scale=scale, causal=True,
                      stash=stash_channel(ctx, "attention"), window=window)
@@ -615,6 +621,24 @@ def _standard_attention(args: BlockArgs) -> NamedTensor:
         feats, q_feats)
 
 
+def _declared_flash(params, extras
+                    ) -> typing.Optional[typing.Tuple[int, typing.Optional[int]]]:
+    """Set as ``attention.flash``, what ``model/remat.py`` sizes the attention
+    kind from under ``checkpoint``: ``(query heads, window)`` of the call
+    this layer makes through ``_flash`` under training — the standard
+    attention its own head count (``q_heads<n>``, else the stream's) and its
+    ``window<w>``, the generic one the stream's heads where it has a key and
+    no flag that keeps the dense map — or None where it makes none."""
+    if any(f in extras for f in _STANDARD_POSITION):
+        flags = _standard_flags(extras)
+        return flags.get("q_heads", params.head_dim.size), flags.get("window")
+    if "dot_product" not in extras or any(f in extras for f in _DENSE_ONLY) \
+            or not any(f in extras for f in ("embedded", "context",
+                                              "positional")):
+        return None
+    return params.head_dim.size, None
+
+
 def attention(args: BlockArgs) -> NamedTensor:
     params = args.params
     params.attention_idx += 1
@@ -672,3 +696,6 @@ def attention(args: BlockArgs) -> NamedTensor:
     if not isinstance(logit, NamedTensor):
         raise UserWarning(f"no spatial mixing with attention parameters: {args.name_extras}")
     return einsum([logit, val], shape)
+
+
+attention.flash = _declared_flash

@@ -32,8 +32,17 @@ import typing
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 _NEG_INF = -1e30
+
+#: the names ``attention`` gives a call's ``out`` ``[b, s, heads, d]`` and
+#: ``lse`` ``[b * heads, s]`` float32 under a "name" channel (free where no
+#: policy names them): where model/remat.py's ``attention`` kind rides the
+#: ``checkpoint`` strategy, each block's ``jax.checkpoint`` saves them
+#: (model/blocks.py ``_checkpoint_policy``) and the replay runs no forward
+#: attention kernel
+SAVED_NAMES = ("flash_out", "flash_lse")
 
 # scoped-VMEM budget for the flash kernels: the compiler default (16M)
 # fits the d128-tuned tiles exactly; wider head dims scale the operand
@@ -835,7 +844,12 @@ def attention(q, k, v, scale: typing.Optional[float] = None,
     consumes the next stashed pair and returns ``flash_precomputed`` so the
     recompute-forward inside the strategy backward never runs the kernel.
     The gate (s %% 128) is identical in both modes, keeping collect/provide
-    counts symmetric.
+    counts symmetric.  Mode "name" (the ``checkpoint`` strategy, where the
+    attention kind rides each block's ``jax.checkpoint``): a call whose
+    queries see at least the channel's ``min_keys`` keys computes (out, lse)
+    once, names both (``SAVED_NAMES``; the block's policy saves them) and
+    returns ``flash_precomputed`` on them — the block's replay finds both
+    outputs of the forward kernel saved and the call is dead code there.
 
     Block sizes (both passes): the largest power-of-two divisors of the
     sequence up to 1024 for q and 2048 for k (always terminating at 128
@@ -865,20 +879,35 @@ def attention(q, k, v, scale: typing.Optional[float] = None,
     # named-scope regions (docs/OBSERVABILITY.md 'Cost attribution'): which
     # attention implementation actually ran — flash kernel vs the dense XLA
     # fallback — is visible per-op in HLO metadata and profiler traces
-    if stash is not None and s % 128 == 0:
-        from ..model.blocks import stash_collecting, stash_pop, stash_push
-        if stash_collecting(stash):
+    keys = s if window is None else window
+    if stash is not None and s % 128 == 0 \
+            and keys >= stash.get("min_keys", 0):
+        from ..model.blocks import (stash_collecting, stash_naming,
+                                    stash_pop, stash_push)
+
+        def forward(q, k, v):
             if on_tpu:
                 with jax.named_scope("flash_attention"):
-                    out, lse = _flash_fwd_impl(q, k, v, scale, causal, blk,
-                                               fwd_k, interpret, window)
-            else:
-                with jax.named_scope("attention_dense"):
-                    out, lse = _xla_reference_with_lse(q, k, v, scale, causal,
-                                                       window)
+                    return _flash_fwd_impl(q, k, v, scale, causal, blk,
+                                           fwd_k, interpret, window)
+            with jax.named_scope("attention_dense"):
+                return _xla_reference_with_lse(q, k, v, scale, causal,
+                                               window)
+
+        if stash_naming(stash):
+            # the gradient stops on the INPUTS: a pallas_call under
+            # differentiation is traced for its JVP before a stop_gradient
+            # on its results is seen
+            out_s, lse_s = forward(*(jax.lax.stop_gradient(t)
+                                     for t in (q, k, v)))
+            out_s = checkpoint_name(out_s, SAVED_NAMES[0])
+            lse_s = checkpoint_name(lse_s, SAVED_NAMES[1])
+        elif stash_collecting(stash):
+            out, lse = forward(q, k, v)
             stash_push(stash, (out, lse))
             return out
-        out_s, lse_s = stash_pop(stash)
+        else:
+            out_s, lse_s = stash_pop(stash)
         with jax.named_scope("flash_attention"):
             return flash_precomputed(q, k, v, out_s, lse_s, scale, causal,
                                      blk, blk, interpret, window)

@@ -467,6 +467,61 @@ def delta_layers_kernels_keep_their_scopes_test(v5e, monkeypatch):
         == [(False, False, False), (False, True, True), (True, False, True)]
 
 
+@pytest.mark.parametrize("cell,layer,scope", [
+    ("train_granite_4_0_h_micro_long", "attention-nope", "body/attention"),
+    ("train_olmo_hybrid_7b_long", "attention-nope-qk_norm", "body/attention"),
+    ("train_zaya1_8b_ep2_s16k",
+     "cca-q_heads8-kv_heads2-rotary_pct50-theta5000000", "body/cca")])
+def saved_flash_outputs_keep_their_scope_test(v5e, monkeypatch, cell, layer,
+                                              scope):
+    """One flash layer of a ``checkpoint`` cell at its published widths and
+    the cell's sequence, compiled for a v5e as a TPU process traces it, with
+    the attention kind riding the block's ``jax.checkpoint`` (PR 40): ONE
+    forward kernel — the step's, outside ``flash_attention``'s
+    ``custom_vjp``, none in the replay — and one fused backward, both still
+    named ``flash_*`` (the ``^flash_`` readers) and folded into the layer's
+    scope (``scope_mixing_time_share`` / ``scope_cca_time_share``); under
+    ``"recompute"`` the same layer runs the forward twice."""
+    import re
+    from benchmark.lib.cell import load_cell
+    from homebrewnlp_tpu.model import remat
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    config = load_cell(cell).model_config()
+    block = next(b for b in config["block_config"] if layer in b["layer"])
+    calls = {}
+    for policy in ("auto", "recompute"):
+        params = ModelParameter({**config, "block_config": [block],
+                                 "depth": 1, "vocab_size": 512,
+                                 "remat_policy": policy,
+                                 "model_path": "/tmp/granite"})
+        assert (remat.stash_plan(params)["attention"][0] == 1) \
+            == (policy == "auto")
+        model = Model(params)
+        batch = {k: np.zeros((1, params.sequence_length, 1), np.int32)
+                 for k in ("token_x", "token_y")}
+        variables = model.init(batch, seed=1)
+        avals = [{k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=v5e)
+                  for k, v in tree.items()} for tree in (variables, batch)]
+        hlo = jax.jit(jax.value_and_grad(
+            lambda v, b: model.apply(v, b).total_loss.data)).lower(
+            *avals).compile().as_text()
+        calls[policy] = re.findall(
+            r'%([\w.-]+) = [^\n]*?custom_call_target="tpu_custom_call"'
+            r'[^\n]*?op_name="([^"]+)"', hlo)
+        for name, op_name in calls[policy]:
+            assert name.startswith("flash_") and scope_key(op_name) == scope, \
+                (name, op_name)
+    kinds = {policy: sorted(re.sub(r"\.\d+$", "", name) for name, _ in found)
+             for policy, found in calls.items()}
+    assert kinds["auto"] == ["flash_bwd_fused_causal", "flash_fwd_causal"]
+    assert kinds["recompute"] == ["flash_bwd_fused_causal",
+                                  "flash_fwd_causal", "flash_fwd_causal"]
+    assert not any("rematted_computation" in op_name and "flash_fwd" in name
+                   for name, op_name in calls["auto"])
+    assert sum("rematted_computation" in op_name and "flash_fwd" in name
+               for name, op_name in calls["recompute"]) == 1
+
+
 def experts_rule_declines_without_a_moe_layer_test():
     """``checkpoint`` with no ``moe`` layer: nothing rides, the policy stays
     the named one, and the chunk states' gauge counts one layer's."""

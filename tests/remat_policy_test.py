@@ -328,9 +328,10 @@ def experts_stash_resolver_test(case):
         assert rep["experts_stash_bytes_per_device"] == 2 * _OLMOE_LAYER \
             == 1073741824 + 1573376 <= rep["stash_budget_bytes"]
         assert "experts" in stash_kinds(p)
-        # the attention kind is named by its sequence rule and rides
-        # nothing under "checkpoint": it took nothing from the budget
-        assert stash_plan(p) == {**idle, "experts": (2, 2 * _OLMOE_LAYER)}
+        # the attention kind is decided after it, from what it leaves
+        # (PR 40): it moved nothing here
+        assert stash_plan(p) == {**idle, "experts": (2, 2 * _OLMOE_LAYER),
+                                 "attention": (2, 68157440)}
     elif case == "depth_16":
         # the published depth: 8.6 GB, all layers or none
         p = _cell_params("train_olmoe_1b_7b_s4k", depth=16)
@@ -346,7 +347,8 @@ def experts_stash_resolver_test(case):
         # explicit: on whatever the bytes
         p = _cell_params("train_olmoe_1b_7b_s4k", remat_policy="stash",
                          depth=16)
-        assert stash_plan(p) == {**idle, "experts": (16, 16 * _OLMOE_LAYER)}
+        assert stash_plan(p) == {**idle, "experts": (16, 16 * _OLMOE_LAYER),
+                                 "attention": (16, 8 * 68157440)}
     elif case == "legacy_false":
         p = _cell_params("train_olmoe_1b_7b_s4k",
                          stash_attention_outputs=False)
@@ -375,28 +377,54 @@ def experts_stash_resolver_test(case):
         assert stash_plan(p) == idle
 
 
+_MOE_NAMES = ("moe_gate", "moe_up", "moe_down", "moe_order", "moe_inverse",
+              "moe_sizes", "moe_experts")
+_FLASH_NAMES = ("flash_out", "flash_lse")
+
+
 @pytest.mark.parametrize("cell,kinds,policy,plan,names", [
     ("train_32big_mixer_b32", set(), "recompute", {}, ()),
     ("train_32big_mixer_dp2tp2", {"bottleneck"}, "stash",
      {"bottleneck": (32, 2147483648)}, ()),
     ("train_1b_long_context_s16k", {"attention"}, "stash",
      {"attention": (8, 2155872256)}, ()),
+    # 2 x (out [2, 4096, 16, 128] bfloat16 + lse [32, 4096] float32)
     ("train_olmoe_1b_7b_s4k", {"attention", "experts"}, "stash",
-     {"experts": (2, 1075315200)},
-     ("moe_gate", "moe_up", "moe_down", "moe_order", "moe_inverse",
-      "moe_sizes", "moe_experts")),
-    ("train_granite_4_0_h_micro_long", {"attention"}, "stash", {}, ())])
+     {"experts": (2, 1075315200), "attention": (2, 68157440)},
+     _MOE_NAMES + _FLASH_NAMES),
+    # out [1, 8192, 32, 64] + lse [32, 8192]
+    ("train_granite_4_0_h_micro_long", {"attention"}, "stash",
+     {"attention": (1, 34603008)}, _FLASH_NAMES),
+    # out [1, 16384, 30, 128] + lse [30, 16384]
+    ("train_olmo_hybrid_7b_long", {"attention", "recurrent"}, "stash",
+     {"recurrent": (3, 566231040), "attention": (1, 127795200)},
+     ("gated_delta_out",) + _FLASH_NAMES),
+    # the experts kind has 5,375,525,008 bytes to save and declined for
+    # size: the step as it was (the parent read a vestigial {"attention"}
+    # that nothing carried)
+    ("train_laguna_s_2_1_ep32_s8k", set(), "recompute", {}, ()),
+    # layer cca's OWN 8 query heads in a 16-head stream: 8 x (out [1, 16384,
+    # 8, 128] + lse [8, 16384])
+    ("train_zaya1_8b_ep2_s16k", {"attention", "experts"}, "stash",
+     {"experts": (8, 1612185888), "attention": (8, 272629760)},
+     _MOE_NAMES + _FLASH_NAMES)])
 def experts_kind_moves_no_other_cell_test(cell, kinds, policy, plan, names):
     """What the three cells without a ``moe`` layer resolved to before the
     experts kind existed, and (PR 33) what the five cells without a layer
     that offers its output resolved to before the recurrent kind existed
     (read off the parent commit), kind for kind and byte for byte; the
     ``jax.checkpoint`` policy is the named object itself where the parent's
-    was, and saves layer ``moe``'s names alone where the parent's did."""
+    was, and saves layer ``moe``'s names alone where the parent's did.
+    Since PR 40 all eight cells: the attention kind rides the four
+    ``checkpoint`` cells whose earlier kinds fitted — each layer's own query
+    heads, ISSUE 40's table to the byte — after the kinds the parent
+    resolved, which it moved in none; Laguna's and the three revnet cells'
+    plan and names are the parent's."""
     from benchmark.lib.cell import load_cell
     from homebrewnlp_tpu.core import sharding as shardlib
-    from homebrewnlp_tpu.model.blocks import _checkpoint_policy
-    from homebrewnlp_tpu.model.remat import (stash_kinds, stash_names,
+    from homebrewnlp_tpu.model.blocks import _checkpoint_policy, _name_chan
+    from homebrewnlp_tpu.model.remat import (saved_attention_keys,
+                                             stash_kinds, stash_names,
                                              stash_plan)
     p = _cell_params(cell)
     mesh = None
@@ -412,6 +440,10 @@ def experts_kind_moves_no_other_cell_test(cell, kinds, policy, plan, names):
     assert stash_names(p, mesh) == names
     assert (_checkpoint_policy(p, mesh)
             is jax.checkpoint_policies.nothing_saveable) == (not names)
+    # the blocks' "name" channel exactly where the two names are saved
+    rides = "flash_out" in names
+    assert saved_attention_keys(p, mesh) == (2048 if rides else None)
+    assert (_name_chan(p, mesh) is not None) == rides
 
 
 @pytest.mark.parametrize("cell,layers", [
@@ -459,9 +491,10 @@ def experts_stash_line_and_policy_test():
     from homebrewnlp_tpu.model.remat import stash_line, stash_plan
     p = _cell_params("train_olmoe_1b_7b_s4k")
     assert stash_line(stash_plan(p)) == (
-        "remat stash: attention 0 layers, 0 bytes a device; bottleneck 0 "
-        f"layers, 0 bytes a device; experts 2 layers, {2 * _OLMOE_LAYER} "
-        "bytes a device; recurrent 0 layers, 0 bytes a device")
+        "remat stash: attention 2 layers, 68157440 bytes a device; "
+        "bottleneck 0 layers, 0 bytes a device; experts 2 layers, "
+        f"{2 * _OLMOE_LAYER} bytes a device; recurrent 0 layers, 0 bytes a "
+        "device")
     nothing = jax.checkpoint_policies.nothing_saveable
     assert _checkpoint_policy(p) is not nothing
     for kw in ({"remat_policy": "recompute"}, {"depth": 16}):
@@ -481,6 +514,9 @@ def experts_stash_line_and_policy_test():
 #: the rule's output [1, 16384, 30, 192] in bfloat16, a layer
 _OLMO_LAYER = 16384 * 30 * 192 * 2
 _OLMO = "train_olmo_hybrid_7b_long"
+#: its one flash layer's out [1, 16384, 30, 128] in bfloat16 and lse [30,
+#: 16384] in float32 (PR 40)
+_OLMO_FLASH = 16384 * 30 * (128 * 2 + 4)
 
 
 def _with_moe(top_k: int):
@@ -515,8 +551,9 @@ def recurrent_stash_resolver_test(case):
             == (3, 3 * _OLMO_LAYER) == (3, 566231040)
         assert 3 * _OLMO_LAYER <= rep["stash_budget_bytes"]
         assert stash_kinds(p) == {"attention", "recurrent"}
-        assert stash_plan(p) == {**idle, "recurrent": (3, 566231040)}
-        assert stash_names(p) == ("gated_delta_out",)
+        assert stash_plan(p) == {**idle, "recurrent": (3, 566231040),
+                                 "attention": (1, _OLMO_FLASH)}
+        assert stash_names(p) == ("gated_delta_out",) + _FLASH_NAMES
         assert _checkpoint_policy(p) is not nothing
     elif case == "over_budget":
         # the published depth, eight periods: 4.5 GB, all layers or none
@@ -533,7 +570,8 @@ def recurrent_stash_resolver_test(case):
     elif case == "stash":
         # explicit: on whatever the bytes
         p = _cell_params(_OLMO, remat_policy="stash", depth=8)
-        assert stash_plan(p) == {**idle, "recurrent": (24, 24 * _OLMO_LAYER)}
+        assert stash_plan(p) == {**idle, "recurrent": (24, 24 * _OLMO_LAYER),
+                                 "attention": (8, 8 * _OLMO_FLASH)}
     elif case == "legacy_false":
         p = _cell_params(_OLMO, stash_attention_outputs=False)
         assert stash_plan(p) == idle and _checkpoint_policy(p) is nothing
@@ -565,18 +603,20 @@ def recurrent_stash_resolver_test(case):
         # decided AFTER experts, from what experts leaves of the same 15%
         p, experts = _with_moe(2)
         budget = remat_report(p)["stash_budget_bytes"]
-        assert experts + 3 * _OLMO_LAYER <= budget
+        assert experts + 3 * _OLMO_LAYER + _OLMO_FLASH <= budget
         assert stash_plan(p) == {**idle, "experts": (1, experts),
-                                 "recurrent": (3, 3 * _OLMO_LAYER)}
-        assert stash_names(p) == (
-            "moe_gate", "moe_up", "moe_down", "moe_order", "moe_inverse",
-            "moe_sizes", "moe_experts", "gated_delta_out")
+                                 "recurrent": (3, 3 * _OLMO_LAYER),
+                                 "attention": (1, _OLMO_FLASH)}
+        assert stash_names(p) == _MOE_NAMES + ("gated_delta_out",) \
+            + _FLASH_NAMES
     elif case == "experts_leave_none":
         p, experts = _with_moe(3)
         budget = remat_report(p)["stash_budget_bytes"]
         assert experts <= budget and 3 * _OLMO_LAYER <= budget \
             < experts + 3 * _OLMO_LAYER
-        assert stash_kinds(p) == {"attention", "experts"}
+        # the attention kind, decided after both (PR 40), finds the budget
+        # exhausted and a kind that declined for size
+        assert stash_kinds(p) == {"experts"}
         assert stash_plan(p) == {**idle, "experts": (1, experts)}
         assert stash_names(p) == (
             "moe_gate", "moe_up", "moe_down", "moe_order", "moe_inverse",
@@ -598,13 +638,170 @@ def recurrent_stash_resolver_test(case):
             assert all(s.saved_names == () and s.saved_bytes is None
                        for s in specs)
             assert remat_report(p)["recurrent_stash_layers"] == 0
-            assert stash_plan(p) == idle and _checkpoint_policy(p) is nothing
+            # what rides there is the attention kind alone (PR 40)
+            assert stash_plan(p) == {**idle, "attention": (1, 34603008)}
+            assert stash_names(p) == _FLASH_NAMES
 
 
 def recurrent_stash_line_test():
     """The start-up line names the kind last, before the chunk states."""
     from homebrewnlp_tpu.model.remat import stash_line, stash_plan
     assert stash_line(stash_plan(_cell_params(_OLMO))) == (
-        "remat stash: attention 0 layers, 0 bytes a device; bottleneck 0 "
-        "layers, 0 bytes a device; experts 0 layers, 0 bytes a device; "
-        "recurrent 3 layers, 566231040 bytes a device")
+        "remat stash: attention 1 layers, 127795200 bytes a device; "
+        "bottleneck 0 layers, 0 bytes a device; experts 0 layers, 0 bytes a "
+        "device; recurrent 3 layers, 566231040 bytes a device")
+
+
+# ---- the attention kind under checkpoint (PR 40): every flash layer's (out,
+# lse) rides the block's jax.checkpoint as named values where a query sees at
+# least 2,048 keys, decided LAST, from what experts and recurrent leave, and
+# not at all where one of them declined for size --------------------------------
+
+_GRANITE = "train_granite_4_0_h_micro_long"
+_LAGUNA = "train_laguna_s_2_1_ep32_s8k"
+_ZAYA = "train_zaya1_8b_ep2_s16k"
+
+
+def _relayered(cell: str, change, **kw):
+    """``cell`` with ``change(layer name) -> layer name`` over its period."""
+    blocks = [{"layer": [change(layer) for layer in b.layer], "skip": b.skip}
+              for b in _cell_params(cell).block_config]
+    return _cell_params(cell, block_config=blocks, **kw)
+
+
+@pytest.mark.parametrize("case", [
+    "own_heads", "window_under_2048_keys", "window_of_2048_keys",
+    "sequence_under_2048", "sequence_off_the_tile", "flash_off", "a_mesh",
+    "macro_batching", "earlier_kinds_exhaust_the_budget",
+    "earlier_kind_declined_for_size", "depth_over_budget", "recompute",
+    "stash", "legacy_true", "legacy_false", "revnet", "momentum", "none",
+    "pipe_mesh"])
+def attention_saved_resolver_test(case):
+    from homebrewnlp_tpu.model.blocks import _checkpoint_policy, _name_chan
+    from homebrewnlp_tpu.model.remat import (saved_attention_keys,
+                                             stash_kinds, stash_names,
+                                             stash_plan)
+    idle = {kind: (0, 0) for kind in
+            ("attention", "bottleneck", "experts", "recurrent")}
+    nothing = jax.checkpoint_policies.nothing_saveable
+
+    def declines(p, mesh=None):
+        # (an explicit "stash" names every kind, whatever can ride)
+        assert p.remat_policy == "stash" \
+            or "attention" not in stash_kinds(p, mesh)
+        assert stash_plan(p, mesh)["attention"] == (0, 0)
+        assert not set(_FLASH_NAMES) & set(stash_names(p, mesh))
+        assert saved_attention_keys(p, mesh) is None
+        assert _name_chan(p, mesh) is None
+
+    if case == "own_heads":
+        # sized from each layer's OWN query heads, not the stream's: cca's 8
+        # of 16, and 72 / 48 where the stream has 24
+        rep = remat_report(_cell_params(_ZAYA))
+        assert rep["saved_attention_bytes_per_device"] == 272629760 \
+            == rep["stash_bytes_per_device"] // 2
+        p = _cell_params(_LAGUNA, experts_held=0, experts=8, moe_top_k=1)
+        rep = remat_report(p)
+        # its one global layer of the period: out [2, 8192, 48, 128] +
+        # lse [96, 8192]; the window-512 layers do not count
+        assert (rep["saved_attention_layers"],
+                rep["saved_attention_bytes_per_device"]) == (1, 204472320)
+        assert rep["stash_bytes_per_device"] == 102236160
+    elif case == "window_under_2048_keys":
+        # a window of 512 keys on 8,192 positions: granite's one layer
+        p = _relayered(_GRANITE, lambda l: l + "-window512"
+                       if l.startswith("attention") else l)
+        assert remat_report(p)["saved_attention_layers"] == 0
+        declines(p)
+        assert stash_plan(p) == idle and _checkpoint_policy(p) is nothing
+    elif case == "window_of_2048_keys":
+        p = _relayered(_GRANITE, lambda l: l + "-window2048"
+                       if l.startswith("attention") else l)
+        assert stash_plan(p) == {**idle, "attention": (1, 34603008)}
+        assert saved_attention_keys(p) == 2048
+    elif case == "sequence_under_2048":
+        declines(_cell_params(_GRANITE, sequence_length=1024))
+    elif case == "sequence_off_the_tile":
+        # 8,256 = 64.5 tiles of 128: the flash route does not engage
+        declines(_cell_params(_GRANITE, sequence_length=8256))
+    elif case == "flash_off":
+        for kw in ({}, {"remat_policy": "stash"}):
+            declines(_cell_params(_GRANITE, use_flash_attention=False, **kw))
+    elif case == "a_mesh":
+        # _flash's shard_map branch keeps the plain kernel
+        from homebrewnlp_tpu.core import sharding as shardlib
+        p = _cell_params(_GRANITE)
+        declines(p, shardlib.build_mesh(p, jax.devices()[:1]))
+    elif case == "macro_batching":
+        # three micro-batches hold three sets: the experts kind passes the
+        # budget and declines, and the attention kind with it
+        declines(_cell_params("train_olmoe_1b_7b_s4k", macro_batching=3))
+        # alone it still fits, three times the bytes
+        p = _cell_params(_GRANITE, macro_batching=3)
+        assert stash_plan(p) == {**idle, "attention": (1, 3 * 34603008)}
+    elif case == "earlier_kinds_exhaust_the_budget":
+        # experts at top-3 fit and leave 34.6 MB; the rule's 566 MB does not
+        # fit that and the layer's 127.8 MB would not either
+        p, experts = _with_moe(3)
+        assert 0 < remat_report(p)["stash_budget_bytes"] - experts \
+            < _OLMO_FLASH
+        declines(p)
+    elif case == "earlier_kind_declined_for_size":
+        # Laguna: the experts kind has 5.4 GB to save and declined; the
+        # global layer's 204 MB alone would fit the whole budget
+        p = _cell_params(_LAGUNA)
+        rep = remat_report(p)
+        assert rep["experts_stash_bytes_per_device"] == 5375525008 \
+            > rep["stash_budget_bytes"] \
+            > rep["saved_attention_bytes_per_device"] == 204472320
+        declines(p)
+        assert stash_kinds(p) == frozenset() and stash_plan(p) == idle
+        assert _checkpoint_policy(p) is nothing
+        # ... and rides as soon as the experts' bytes fit (8 experts at top-1
+        # in a buffer of their own size), by the same rule
+        p = _cell_params(_LAGUNA, experts_held=0, experts=8, moe_top_k=1)
+        assert stash_kinds(p) == {"experts", "attention"}
+        assert stash_plan(p)["attention"] == (1, 204472320)
+    elif case == "depth_over_budget":
+        # all qualifying layers or none: 80 periods of granite's one layer
+        p = _cell_params(_GRANITE, depth=80)
+        assert remat_report(p)["saved_attention_bytes_per_device"] \
+            == 80 * 34603008 > remat_report(p)["stash_budget_bytes"]
+        declines(p)
+    elif case == "recompute":
+        declines(_cell_params(_ZAYA, remat_policy="recompute"))
+    elif case == "stash":
+        # explicit: every engaged flash layer, whatever the keys and bytes
+        p = _cell_params(_LAGUNA, remat_policy="stash")
+        # 3 x (out [2, 8192, 72, 128] + lse [144, 8192]) and the global one
+        assert stash_plan(p)["attention"] == (4, 3 * 306708480 + 204472320)
+        assert saved_attention_keys(p) == 0
+        assert _name_chan(p, None)["min_keys"] == 0
+    elif case == "legacy_true":
+        p = _cell_params(_GRANITE, stash_attention_outputs=True,
+                         sequence_length=1024)
+        assert stash_plan(p) == {**idle, "attention": (1, 34603008 // 8)}
+        assert saved_attention_keys(p) == 0
+    elif case == "legacy_false":
+        declines(_cell_params(_ZAYA, stash_attention_outputs=False))
+    elif case in ("revnet", "momentum", "none"):
+        # nothing is carried through the policy: the channel's kinds keep
+        # their historical rule, and "none" has no replay
+        for kw in ({}, {"remat_policy": "stash"}):
+            p = _cell_params(_OLMO, memory_reduction_strategy=case, **kw)
+            assert stash_names(p) == () and _checkpoint_policy(p) is nothing
+            assert saved_attention_keys(p) is None
+            assert _name_chan(p, None) is None
+            assert "attention" in stash_kinds(p)    # the sequence rule's
+    else:
+        from homebrewnlp_tpu.core.sharding import PIPE_AXIS
+
+        class Piped:
+            devices = None
+            shape = {PIPE_AXIS: 2}
+
+        for kw in ({}, {"remat_policy": "stash"}):
+            p = _cell_params(_GRANITE, **kw)
+            assert stash_plan(p, Piped()) == idle
+            assert _name_chan(p, Piped()) is None
+            assert _checkpoint_policy(p, Piped()) is nothing
