@@ -10,7 +10,8 @@ compiled; the continuous engine pinned so a serving fallback is a failure):
 
   kernels  scripts/kernel_parity.py — compiled flash, map-mixer and
            delta-solve kernels
-           against their XLA references at float32 "highest"
+           against their XLA references at float32 "highest", and the
+           windowed flash forward's two forms against float64
   train    main.py --run_mode train, 10 steps on TFRecords written by
            scripts/text2records.py from a seeded corpus; writes a checkpoint
   resume   a SECOND process restores it and trains 10 more steps; must add
@@ -258,14 +259,15 @@ def leg_kernels(ctx):
     cmd = [sys.executable, "scripts/kernel_parity.py"]
     if ctx["rehearsal"]:
         cmd += ["--flash-seq", "256", "--mixer-batch", "2",
-                "--solve-chunks", "2"]
+                "--solve-chunks", "2", "--band-heads", "1",
+                "--band-seq", "1024"]
     rc, wall = run_to_end("kernels", cmd, log, 420)
     rows = []
     with open(log, errors="replace") as f:
         for line in f:
             if line.startswith('{"kernel"'):
                 rows.append(json.loads(line))
-    check(rc == 0 and len(rows) == 3,
+    check(rc == 0 and len(rows) == 4,
           f"kernel parity failed (rc {rc}):\n{tail(log)}")
     if not ctx["rehearsal"]:
         check(all(r["implementation"] == "pallas" for r in rows),

@@ -296,6 +296,14 @@ def _attention_min_keys(params: ModelParameter) -> int:
     return 0 if _forced_attention(params) else ATTENTION_MIN_KEYS
 
 
+def _flash_call(params: ModelParameter, name: str, extras: set):
+    """``(query heads, window or None)`` of the flash call a layer DECLARES
+    (``<layer function>.flash``), or None where it declares none."""
+    from .frontend import LAYER_FUNCTIONS
+    declare = getattr(LAYER_FUNCTIONS.get(name), "flash", None)
+    return declare(params, extras) if declare is not None else None
+
+
 def _saved_attention(params: ModelParameter, mesh, min_keys: int
                      ) -> typing.Tuple[int, int]:
     """``(layers, per-device bytes)`` of the attention kind under
@@ -310,11 +318,9 @@ def _saved_attention(params: ModelParameter, mesh, min_keys: int
     seq = params.sequence_dim.size
     if mesh is not None or not params.use_flash_attention or seq % 128:
         return 0, 0
-    from .frontend import LAYER_FUNCTIONS
     heads = []
     for name, extras in _layers(params):
-        declare = getattr(LAYER_FUNCTIONS.get(name), "flash", None)
-        call = declare(params, extras) if declare is not None else None
+        call = _flash_call(params, name, extras)
         if call is not None and min(seq, call[1] or seq) >= min_keys:
             heads.append(call[0])
     per_head = params.batch_dim.size * seq * (
@@ -590,6 +596,42 @@ def solve_kernel_layers(params: ModelParameter, backend=None
         return None
     return params.depth * sum(solve_kernel_applies(chunk, matrices, backend)
                               for chunk, matrices in solves)
+
+
+def flash_band_layers(params: ModelParameter, backend=None
+                      ) -> typing.Optional[int]:
+    """How many attention layers of the step run their windowed flash
+    FORWARD as the band kernel (``parallel/flash_attention.py _fwd_band``):
+    of the layers that DECLARE a flash call with a window shorter than the
+    sequence (``<layer function>.flash``, as ``_saved_attention`` reads it;
+    the leading and trailing blocks run once, the body ``depth`` times),
+    those the predicate ``attention`` itself calls admits, where that call
+    reaches the kernels at all (``use_flash_attention``, off the CPU, a
+    sequence of whole 128-tiles); None where no layer declares such a
+    window.  ``Trainer`` publishes it as ``hbnlp_flash_band_layers``."""
+    import jax
+
+    from ..parallel.flash_attention import band_applies
+    seq = params.sequence_dim.size
+    windows = []
+    for blocks, times in ((params.input_block_config, 1),
+                          (params.block_config, params.depth),
+                          (params.output_block_config, 1)):
+        for block in blocks:
+            for layer in block.layer:
+                name, *extras = layer.split("-")
+                call = _flash_call(params, name, set(extras))
+                if call is not None and call[1] is not None and call[1] < seq:
+                    windows += [call[1]] * times
+    if not windows:
+        return None
+    if backend is None:
+        backend = jax.default_backend()
+    if backend == "cpu" or not params.use_flash_attention or seq % 128:
+        return 0
+    itemsize = np.dtype(params.calculation_dtype).itemsize
+    return sum(band_applies(seq, params.key_dim.size, window, itemsize)
+               for window in windows)
 
 
 def stash_line(plan: typing.Dict[str, typing.Tuple[int, int]]) -> str:

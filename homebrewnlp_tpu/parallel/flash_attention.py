@@ -22,12 +22,21 @@ residual nor an O(s²) recompute buffer.  One fused pass gives dq, dk and dv
 while its dq-partial buffer fits the chip (``_use_fused_bwd``); a dq and a
 dk/dv kernel above that.
 
+A WINDOWED call's forward (``window``: query ``i`` sees keys ``i - window + 1
+.. i``) is a band kernel instead (``_fwd_band``, PR 41) wherever a cell's band
+fits VMEM (``band_applies``): grid (batch·heads, q tiles) with no k
+dimension, the head-sequence's K and V resident, the softmax of a sub-block's
+``sub + window`` keys in one pass with no carried state; the tiled forward
+above, its inner dimension as long as the band, where it does not.  The
+backward is the tiled flash-2 pass either way.
+
 Off the TPU ``attention`` runs the dense XLA form (``_xla_reference``, also
 the tests' reference; tests run the kernels in interpret mode).
 """
 from __future__ import annotations
 
 import functools
+import math
 import typing
 
 import jax
@@ -363,6 +372,130 @@ def _fwd_flat(qt, kt, vt, scale, causal, block_q, block_k, interpret,
     return out, lse[..., 0]
 
 
+#: query rows of one static sub-block of a band cell, each against its own
+#: ``sub + reach`` keys.  At a window of 512, 256 rows score 768 keys, 1.5 x
+#: the band's pairs; 128 rows score 640, 1.25 x, and ran SLOWER on a v5e
+#: (PERF.md section 6, PR 41: ~0.23 us a sub-block beside 4.4 ps a pair — a
+#: key tile the MXU loads serves only ``sub`` query rows)
+_BAND_SUB = 256
+#: q tile cap of the band forward (``band_block``): the cell's sub-blocks are
+#: a static loop, so the tile only amortises the grid step and the q / out
+#: DMAs (measured 5.38 / 4.99 / 4.92 ms a call at 512 / 1024 / 2048 and the
+#: Laguna cell's shape; 2048 doubles the lowering time for 1.4%)
+_BAND_BLOCK_CAP = 1024
+
+
+def band_block(s: int) -> int:
+    """Q tile of the band forward: ``kernel_block`` under ``_BAND_BLOCK_CAP``."""
+    return kernel_block(s, cap=_BAND_BLOCK_CAP)
+
+
+def _band_geometry(s: int, window: int, block_q: int):
+    """``(sub, reach, span)`` of a band cell: a sub-block of ``sub`` query
+    rows (``_BAND_SUB``, or what of it divides the tile) starting at ``q0``
+    takes keys ``q0 - reach .. q0 + sub - 1``, its
+    start clamped at position 0: ``reach`` is ``window - 1`` rounded up to
+    whole sub-blocks (so every start is a multiple of ``sub``), ``span`` the
+    keys a sub-block scores (the whole sequence at most)."""
+    sub = math.gcd(_BAND_SUB, block_q)
+    reach = -(-(window - 1) // sub) * sub
+    return sub, reach, min(sub + reach, s)
+
+
+def band_applies(s: int, d: int, window: int, itemsize: int) -> bool:
+    """Whether a windowed call's FORWARD is the band kernel (``_fwd_band``):
+    a cell's VMEM at ``band_block``'s q tile — the head-sequence's K and V
+    resident (two pipeline buffers each), the q, out and lse tiles (two
+    each; an lse row pads to a lane tile), one sub-block's float32 scores,
+    their exponentials and those in the operand dtype — inside the kernels'
+    budget.  A window (or a sequence) too long for that keeps the tiled
+    forward (``_fwd_flat``).  Pure in its arguments: the one predicate
+    ``_flash_fwd_impl``, ``attention`` and the ``hbnlp_flash_band_layers``
+    gauge (model/remat.py) read."""
+    block_q = band_block(s)
+    sub, _, span = _band_geometry(s, window, block_q)
+    resident = 2 * 2 * s * d * itemsize
+    tiles = 2 * (2 * block_q * d * itemsize + block_q * 128 * 4)
+    scores = sub * span * (4 + 4 + itemsize)
+    return resident + tiles + scores <= _KERNEL_VMEM_BUDGET
+
+
+def _band_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int,
+                 sub: int, reach: int, span: int, window: int, scale: float):
+    """Grid (batch*heads, q tiles), no k dimension: a cell holds a q tile
+    and — resident across the head-sequence's cells, fetched once — its whole
+    K and V ``[s, d]``.  Each static sub-block of ``sub`` rows scores its own
+    ``span`` keys once and takes the softmax in ONE pass: row maximum, exp,
+    row sum, one P V matmul, ``out`` and ``lse = m + log l`` written at once;
+    no state is carried between cells, and no cell branches.  The mask is by
+    position relative to the span's first key, which the first sub-blocks
+    clamp at position 0: one form for every sub-block (masking the interior
+    ones by constants, and only the columns the band's edges cross, measured
+    no faster: the kernel is not bound by its elementwise passes)."""
+    from jax.experimental import pallas as pl
+
+    qi = pl.program_id(1)
+    # query row - key column of a span's pair, before the span's offset
+    rel = jax.lax.broadcasted_iota(jnp.int32, (sub, span), 0) \
+        - jax.lax.broadcasted_iota(jnp.int32, (sub, span), 1)
+    for r in range(block_q // sub):
+        q0 = qi * block_q + r * sub
+        start = jnp.maximum(q0 - reach, 0)
+        if sub % 8 == 0:
+            start = pl.multiple_of(start, sub)
+        rows, keys = pl.ds(r * sub, sub), pl.ds(start, span)
+        s = jax.lax.dot_general(q_ref[rows, :], k_ref[keys, :],
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        # pair (a, c) is q0 - start + a - c keys back
+        back = q0 - start
+        s = jnp.where((rel >= -back) & (rel < window - back), s, _NEG_INF)
+        m = s.max(-1, keepdims=True)
+        p = jnp.exp(s - m)
+        # a row sees its own key: l >= 1
+        l = p.sum(-1, keepdims=True)
+        # p rounds to the input dtype for the MXU, as in ``_flash_kernel``
+        acc = jax.lax.dot_general(p.astype(v_ref.dtype), v_ref[keys, :],
+                                  (((1,), (0,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        o_ref[rows, :] = (acc / l).astype(o_ref.dtype)
+        lse_ref[rows, :] = m + jnp.log(l)
+
+
+def _fwd_band(qt, kt, vt, scale, block_q, window, interpret):
+    """Flat-core forward of a windowed call as a band kernel: q/k/v ``[bh,
+    s, d]`` -> ``(out [bh, s, d], lse [bh, s])``, the contract of
+    ``_fwd_flat``.  K and V are read as they arrive (no padded copy): a
+    head-sequence's whole K and V are one block whose index does not change
+    across its q tiles, so the pipeline fetches each once."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bh, s, d = qt.shape
+    block_q = min(block_q, s)
+    sub, reach, span = _band_geometry(s, window, block_q)
+    kernel = functools.partial(_band_kernel, block_q=block_q, sub=sub,
+                               reach=reach, span=span, window=window,
+                               scale=scale)
+    whole = pl.BlockSpec((None, s, d), lambda i, j: (i, 0, 0))
+    out, lse = pl.pallas_call(
+        kernel,
+        grid=(bh, s // block_q),
+        in_specs=[pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
+                  whole, whole],
+        out_specs=[pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
+                   pl.BlockSpec((None, block_q, 1), lambda i, j: (i, j, 0))],
+        out_shape=[jax.ShapeDtypeStruct((bh, s, d), qt.dtype),
+                   jax.ShapeDtypeStruct((bh, s, 1), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_KERNEL_VMEM_BUDGET),
+        name=_kernel_name("flash_fwd", True, window),
+        interpret=interpret,
+    )(qt, kt, vt)
+    return out, lse[..., 0]
+
+
 def _flash_fwd_impl(q, k, v, scale, causal, block_q, block_k, interpret,
                     window=None):
     """Returns (out [b, s, h, d], lse [b*h, s]) — lse is the backward's
@@ -372,8 +505,11 @@ def _flash_fwd_impl(q, k, v, scale, causal, block_q, block_k, interpret,
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, s, d)
     kt = k.transpose(0, 2, 1, 3).reshape(b * h, s, d)
     vt = v.transpose(0, 2, 1, 3).reshape(b * h, s, d)
-    out, lse = _fwd_flat(qt, kt, vt, scale, causal, block_q, block_k,
-                         interpret, window=window)
+    if window is not None and band_applies(s, d, window, q.dtype.itemsize):
+        out, lse = _fwd_band(qt, kt, vt, scale, block_q, window, interpret)
+    else:
+        out, lse = _fwd_flat(qt, kt, vt, scale, causal, block_q, block_k,
+                             interpret, window=window)
     return out.reshape(b, h, s, d).transpose(0, 2, 1, 3), lse
 
 
@@ -748,8 +884,10 @@ def flash_attention(q, k, v, scale: float = None, causal: bool = True,
     (None = same as forward): the forward profits from a wider k tile
     (fewer online-softmax rescale steps) that pushes the dq kernel past the
     scoped-VMEM limit in the full model.  ``window`` (with ``causal``):
-    query ``i`` sees keys ``i - window + 1 .. i`` only, and every kernel's
-    grid holds the blocks that band touches, not the triangle."""
+    query ``i`` sees keys ``i - window + 1 .. i`` only; the backward's grids
+    hold the blocks that band touches, not the triangle, and the forward is
+    the band kernel at q tile ``block_q`` (``block_k`` unused) where
+    ``band_applies``, else the tiled kernel on such a grid."""
     out, _ = _flash_fwd_impl(q, k, v, scale, causal, block_q, block_k,
                              interpret, window)
     return out
@@ -809,17 +947,20 @@ def _flash_pre_bwd(scale, causal, block_q, block_k, interpret, window, res,
 flash_precomputed.defvjp(_flash_pre_fwd, _flash_pre_bwd)
 
 
-#: tile cap of a windowed call, both passes and both sides.  A q tile's band
-#: is ``tile + window - 1`` keys wide whatever the tile, so tiles much wider
-#: than the window compute mostly masked pairs; tiles much narrower pay the
-#: per-cell state work the causal kernels' 1024 tiles amortise
+#: tile cap of a windowed call's TILED kernels, both sides: the backward
+#: (fused, or the dq / dk-dv pair) always, the forward only where the band
+#: kernel declines (``band_applies``; its own tiles are ``band_block`` and
+#: ``_BAND_SUB``).  A q tile's band is ``tile + window - 1`` keys wide
+#: whatever the tile, so tiles much wider than the window compute mostly
+#: masked pairs; tiles much narrower pay the per-cell state work the causal
+#: kernels' 1024 tiles amortise
 _WINDOW_BLOCK_CAP = 512
 
 
 def window_block(s: int, window: int) -> int:
-    """Tile of a windowed call: ``kernel_block`` capped at the window
-    rounded up to a power of two (128 at least, ``_WINDOW_BLOCK_CAP`` at
-    most)."""
+    """Tile of a windowed call's tiled kernels: ``kernel_block`` capped at
+    the window rounded up to a power of two (128 at least,
+    ``_WINDOW_BLOCK_CAP`` at most)."""
     cap = 128
     while cap < min(window, _WINDOW_BLOCK_CAP):
         cap *= 2
@@ -834,9 +975,13 @@ def attention(q, k, v, scale: typing.Optional[float] = None,
 
     ``window`` (None = the whole causal triangle, today's kernels bit for
     bit): query ``i`` sees keys ``i - window + 1 .. i``.  A windowed call
-    runs the ``flash_*_window`` kernels at ``window_block`` tiles, their
-    grids as long as the band; a window that covers the sequence is the
-    causal call.
+    runs the ``flash_*_window`` kernels: the BACKWARD at ``window_block``
+    tiles (512 x 512 at most), its grids as long as the band; the FORWARD
+    as the band kernel — q tiles of ``band_block`` (1024 at most) walked in
+    sub-blocks of ``_BAND_SUB`` rows, each against its own ``sub + window``
+    keys of the resident K and V, no k tiles at all — where ``band_applies``
+    (a cell's band fits VMEM), else tiled like the backward.  A window that
+    covers the sequence is the causal call.
 
     ``stash``: attention-output stash channel (model/blocks.py): mode
     "collect" computes (out, lse) and appends them to ``stash["items"]``
@@ -851,9 +996,9 @@ def attention(q, k, v, scale: typing.Optional[float] = None,
     returns ``flash_precomputed`` on them — the block's replay finds both
     outputs of the forward kernel saved and the call is dead code there.
 
-    Block sizes (both passes): the largest power-of-two divisors of the
-    sequence up to 1024 for q and 2048 for k (always terminating at 128
-    given the s % 128 gate).  Measured on v5e at s=16384, d=128 (in-jit
+    Block sizes (both passes, no window): the largest power-of-two divisors
+    of the sequence up to 1024 for q and 2048 for k (always terminating at
+    128 given the s % 128 gate).  Measured on v5e at s=16384, d=128 (in-jit
     loop): 128x128 tiles are grid-overhead/HBM-read bound (round-4 fix,
     27x); with the diagonal-split kernels the forward is VPU-bound on
     softmax bookkeeping, so bigger tiles amortise the per-cell state ops —
@@ -873,9 +1018,14 @@ def attention(q, k, v, scale: typing.Optional[float] = None,
                              "causal attention only")
         if window >= s:
             window = None
-    # tiles: (both passes' q and the backward's k, the forward's k)
-    blk, fwd_k = (kernel_block(s), kernel_block(s, cap=2048)) \
-        if window is None else (window_block(s, window),) * 2
+    # tiles: (the backward's q and k, the forward's q, the forward's k)
+    if window is None:
+        blk = fwd_q = kernel_block(s)
+        fwd_k = kernel_block(s, cap=2048)
+    else:
+        blk = fwd_q = fwd_k = window_block(s, window)
+        if band_applies(s, q.shape[-1], window, q.dtype.itemsize):
+            fwd_q = band_block(s)
     # named-scope regions (docs/OBSERVABILITY.md 'Cost attribution'): which
     # attention implementation actually ran — flash kernel vs the dense XLA
     # fallback — is visible per-op in HLO metadata and profiler traces
@@ -888,7 +1038,7 @@ def attention(q, k, v, scale: typing.Optional[float] = None,
         def forward(q, k, v):
             if on_tpu:
                 with jax.named_scope("flash_attention"):
-                    return _flash_fwd_impl(q, k, v, scale, causal, blk,
+                    return _flash_fwd_impl(q, k, v, scale, causal, fwd_q,
                                            fwd_k, interpret, window)
             with jax.named_scope("attention_dense"):
                 return _xla_reference_with_lse(q, k, v, scale, causal,
@@ -915,7 +1065,7 @@ def attention(q, k, v, scale: typing.Optional[float] = None,
         with jax.named_scope("attention_dense"):
             return _xla_reference(q, k, v, scale, causal, window)
     with jax.named_scope("flash_attention"):
-        return flash_attention(q, k, v, scale, causal, blk, fwd_k, interpret,
-                               bwd_block_q=blk, bwd_block_k=blk,
+        return flash_attention(q, k, v, scale, causal, fwd_q, fwd_k,
+                               interpret, bwd_block_q=blk, bwd_block_k=blk,
                                window=window)
 

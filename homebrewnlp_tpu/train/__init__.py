@@ -662,9 +662,12 @@ class Trainer:
         for their triangular solve (``solve_kernel_layers``; 0 where no
         layer has one), and ``hbnlp_router_carry_bytes``: the router states
         carried between blocks (``router_carry_bytes``; no series where no
-        layer carries one).  Set when the step is built; returns the start-up
-        line that says the same."""
-        from ..model.remat import (conv_kernel_layers, moe_held_rows,
+        layer carries one), and ``hbnlp_flash_band_layers``: the attention
+        layers whose windowed flash forward is the band kernel
+        (``flash_band_layers``).  Set when the step is built; returns the
+        start-up line that says the same."""
+        from ..model.remat import (conv_kernel_layers, flash_band_layers,
+                                   moe_held_rows,
                                    router_carry_bytes, solve_kernel_layers,
                                    ssd_state_bytes, stash_line, stash_plan)
         plan = stash_plan(self.params, self.mesh)
@@ -699,6 +702,11 @@ class Trainer:
                 "gated_delta layers of the built step whose triangular solve "
                 "is the Pallas kernel pair (0 on the XLA blocked form, and "
                 "without such a layer)").set(solve_layers or 0)
+        band_layers = flash_band_layers(self.params)
+        r.gauge("hbnlp_flash_band_layers",
+                "attention layers of the built step whose windowed flash "
+                "forward is the band kernel (0 on the tiled forward, on the "
+                "CPU and without a windowed layer)").set(band_layers or 0)
         nbytes = r.gauge("hbnlp_remat_stash_bytes",
                          "per-device bytes riding the memory strategy's "
                          "residuals instead of being replayed", ("kind",))
@@ -714,7 +722,9 @@ class Trainer:
             f"; solve kernel {solve_layers} layers"
             if solve_layers is not None else "") + (
             f"; moe held rows bound {held_rows}" if held_rows else "") + (
-            f"; router carry {carry} bytes" if carry else "")
+            f"; router carry {carry} bytes" if carry else "") + (
+            f"; flash band {band_layers} layers"
+            if band_layers is not None else "")
 
     def lowered(self, state: TrainState, batch: typing.Dict[str, jax.Array]):
         """Lowered (StableHLO) train step for ``save_graph`` dumps — the

@@ -23,6 +23,14 @@ entry AND, in the mean over the systems, than twice what XLA's blocked form
 matmuls) is off on the same chip — a product with a dropped bfloat16 term
 fails the second.
 
+A fourth leg holds the windowed forward's two forms — the band kernel
+(``_fwd_band``: one-pass softmax over a q tile's whole band) and the tiled
+one the predicate falls back to (``_fwd_flat`` at ``window_block`` tiles,
+online softmax) — at the Laguna cell's shape ``[2, 8192, 72, 128]``, window
+512, to the host's float64 band: ``out`` inside :data:`TOLERANCE`, ``lse``
+inside :data:`LSE_TOLERANCE`, and the band form no further off than twice
+the tiled one.
+
 Shapes: flash at the long-context recipe's per-chip shape (seq 16,384, head
 dim 128; two heads so the dense reference's [s, s] scores fit beside it);
 the mixer at the flagship's (8 heads, seq 512, 512 features/head, batch 32).
@@ -173,12 +181,80 @@ def _solve_leg(shape=(1, 256, 10, 64, 64)) -> bool:
     return bool(ok)
 
 
+#: bound on max|lse - float64|: both forms keep the statistics in float32
+#: over scores the MXU accumulates from 128 exact bfloat16 products — on a
+#: v5e that accumulation is off by 1.1e-4 at most at this shape, in both
+#: forms alike (my chip run, PR 41) — while one key of 512 dropped or let in
+#: moves a row's lse by about 2e-3
+LSE_TOLERANCE = 5e-4
+
+
+def _band_leg(shape=(2, 8192, 72, 128), window: int = 512) -> bool:
+    """The windowed forward as a band kernel and as the tiled kernel, both
+    against float64 on the host, at the Laguna cell's shape."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from homebrewnlp_tpu.parallel import flash_attention as flash
+
+    b, s, heads, d = shape
+    bh, scale = b * heads, d ** -0.5
+    interpret = jax.devices()[0].platform == "cpu"
+    q, k, v = (jax.random.normal(jax.random.PRNGKey(seed), (bh, s, d),
+                                 jnp.float32).astype(jnp.bfloat16)
+               for seed in (41, 42, 43))
+    q64, k64, v64 = (np.asarray(t, np.float64) for t in (q, k, v))
+    out64, lse64 = np.empty((bh, s, d)), np.empty((bh, s))
+    step = min(512, s)
+    for q0 in range(0, s, step):       # the band, a strip of queries at a time
+        k0 = max(q0 - window + 1, 0)
+        score = scale * q64[:, q0:q0 + step] @ k64[:, k0:q0 + step
+                                                   ].transpose(0, 2, 1)
+        back = np.arange(q0, q0 + step)[:, None] - np.arange(k0, q0 + step)
+        score = np.where((back >= 0) & (back < window), score, -np.inf)
+        m = score.max(-1, keepdims=True)
+        p = np.exp(score - m)
+        l = p.sum(-1, keepdims=True)
+        out64[:, q0:q0 + step] = (p / l) @ v64[:, k0:q0 + step]
+        lse64[:, q0:q0 + step] = (m + np.log(l))[..., 0]
+
+    applies = flash.band_applies(s, d, window, q.dtype.itemsize)
+    blk = flash.window_block(s, window)
+    forms = {"band": jax.jit(lambda q, k, v: flash._fwd_band(
+                 q, k, v, scale, flash.band_block(s), window, interpret)),
+             "tiled": jax.jit(lambda q, k, v: flash._fwd_flat(
+                 q, k, v, scale, True, blk, blk, interpret, window=window))}
+    errs = {}
+    for name, fn in forms.items():
+        out, lse = fn(q, k, v)
+        errs[name] = {
+            "out": float(np.abs(np.asarray(out, np.float64) - out64).max()
+                         / np.abs(out64).max()),
+            "lse": float(np.abs(np.asarray(lse, np.float64) - lse64).max())}
+    ok = applies and errs["band"]["out"] <= TOLERANCE \
+        and errs["band"]["lse"] <= LSE_TOLERANCE \
+        and errs["band"]["out"] <= 2 * errs["tiled"]["out"] \
+        and errs["band"]["lse"] <= 2 * max(errs["tiled"]["lse"], 1e-6)
+    print(json.dumps({"kernel": "flash_fwd_window", "ok": bool(ok),
+                      "implementation": "pallas (interpret)" if interpret
+                      else "pallas", "band_applies": bool(applies),
+                      "max_err_over_max_ref": errs, "tolerance": TOLERANCE,
+                      "lse_tolerance": LSE_TOLERANCE,
+                      "shapes": [list(shape)], "window": window,
+                      "dtype": "bfloat16"}), flush=True)
+    return bool(ok)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--flash-seq", type=int, default=16384)
     ap.add_argument("--mixer-batch", type=int, default=32)
     ap.add_argument("--solve-chunks", type=int, default=256,
                     help="chunks of the solve's [1, chunks, 10, 64, 64]")
+    ap.add_argument("--band-heads", type=int, default=72,
+                    help="heads of the band leg's [2, seq, heads, 128]")
+    ap.add_argument("--band-seq", type=int, default=8192)
     args = ap.parse_args(argv)
 
     import jax
@@ -217,6 +293,8 @@ def main(argv=None) -> int:
         bias_, v_, causal=True), mixer_ref, [bias, val], cotangent_seed=13)
 
     ok &= _solve_leg((1, args.solve_chunks, 10, 64, 64))
+
+    ok &= _band_leg((2, args.band_seq, args.band_heads, 128))
 
     print(json.dumps({"ok": bool(ok)}), flush=True)
     return 0 if ok else 1

@@ -271,13 +271,52 @@ def _window_inputs(s, seed=3):
                              .astype(np.float32)) for _ in range(4))
 
 
+@pytest.fixture
+def band_form(request, monkeypatch):
+    """The windowed FORWARD's form: ``band`` (``_fwd_band``, sub-blocks of 16
+    rows so that a toy tile holds one, two or four) or ``tiled``, the
+    ``_fwd_flat`` grid the predicate falls back to."""
+    form = getattr(request, "param", "band")
+    monkeypatch.setattr(fa, "_BAND_SUB", 16)
+    if form == "tiled":
+        monkeypatch.setattr(fa, "band_applies", lambda *a, **kw: False)
+    jax.clear_caches()
+    yield form
+    monkeypatch.undo()
+    jax.clear_caches()
+
+
+def _forward_kernels(fn, *args):
+    """``(name, grid)`` of every ``pallas_call`` ``fn`` traces to."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append((eqn.params["name"],
+                              tuple(eqn.params["grid_mapping"].grid)))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+@pytest.mark.parametrize("band_form", ["band", "tiled"], indirect=True)
 @pytest.mark.parametrize("s,window,bq,bk", WINDOW_CASES)
-def window_forward_matches_the_band_mask_test(s, window, bq, bk):
+def window_forward_matches_the_band_mask_test(s, window, bq, bk, band_form):
+    """``out`` and ``lse`` of both forms, the first tiles (whose band is
+    clipped at position 0) included, against the dense form."""
     q, k, v, _ = _window_inputs(s)
-    out = flash_attention(q, k, v, 0.25, True, bq, bk, True, None, None,
-                          window)
-    ref = _xla_reference(q, k, v, 0.25, True, window)
+    assert fa.band_applies(s, 16, window, 4) == (band_form == "band")
+    out, lse = fa._flash_fwd_impl(q, k, v, 0.25, True, bq, bk, True, window)
+    (_, grid), = _forward_kernels(lambda *a: fa._flash_fwd_impl(
+        *a, 0.25, True, bq, bk, True, window), q, k, v)
+    assert len(grid) == (2 if band_form == "band" else 3)
+    ref, ref_lse = fa._xla_reference_with_lse(q, k, v, 0.25, True, window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse),
                                rtol=2e-5, atol=2e-5)
     # the reference's own mask, written out once more
     i, t = np.arange(s)[:, None], np.arange(s)[None, :]
@@ -293,13 +332,15 @@ def window_forward_matches_the_band_mask_test(s, window, bq, bk):
 @pytest.mark.parametrize("fused", [True, False], ids=["fused", "split"])
 @pytest.mark.parametrize("s,window,bq,bk", WINDOW_CASES)
 def window_backward_matches_the_band_mask_test(s, window, bq, bk, fused,
-                                               monkeypatch):
+                                               monkeypatch, band_form):
     """The fused backward (dq partials in the band's slots, summed by index)
-    and the split dq / dk-dv pair, both on grids as long as the band."""
+    and the split dq / dk-dv pair, both on grids as long as the band, both
+    on the ``lse`` the band forward wrote."""
     q, k, v, do = _window_inputs(s)
     monkeypatch.setattr(fa, "_fused_dqp_cap",
                         (lambda: 1 << 40) if fused else (lambda: 0))
     jax.clear_caches()
+    assert band_form == "band" and fa.band_applies(s, 16, window, 4)
     got = jax.vjp(lambda q, k, v: flash_attention(
         q, k, v, 0.25, True, bq, bk, True, None, None, window), q, k, v)[1](do)
     monkeypatch.undo()
@@ -325,6 +366,38 @@ def windowed_grids_are_as_long_as_the_band_test(s, window, bq, bk, inner):
     assert fa.window_block(8192, 512) == 512
     assert fa.window_block(8192, 100) == 128
     assert fa.window_block(8192, 4096) == fa._WINDOW_BLOCK_CAP
+
+
+def the_band_forward_is_the_windowed_call_test(monkeypatch):
+    """At the Laguna cell's geometry (window 512, head width 128, bfloat16)
+    the windowed forward is still named ``flash_fwd_window`` (the trace's
+    readers cost it by that name), on a grid of (head-sequences, q tiles)
+    with no k dimension; the backward keeps ``window_block``'s grid; and the
+    predicate declines what does not fit a cell."""
+    q = jax.ShapeDtypeStruct((1, 8192, 2, 128), jnp.bfloat16)
+
+    def grad(q, k, v):
+        return jax.grad(lambda *a: fa.attention(
+            *a, interpret=False, window=512).astype(jnp.float32).sum(),
+            (0, 1, 2))(q, k, v)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    kernels = dict(_forward_kernels(grad, q, q, q))
+    tile = fa.band_block(8192)
+    assert kernels == {"flash_fwd_window": (2, 8192 // tile),
+                       "flash_bwd_fused_window": (2, 16, 2)}
+    assert fa.band_applies(8192, 128, 512, 2)
+    # K and V of one head-sequence, resident: 2 x 2 x s x d x 2 bytes
+    assert fa.band_applies(32768, 128, 512, 2)
+    assert not fa.band_applies(65536, 128, 512, 2)
+    assert not fa.band_applies(16384, 512, 512, 2)
+    # a sub-block's scores over window + sub keys, float32 twice and bfloat16
+    assert fa.band_applies(32768, 128, 8192, 2)
+    assert not fa.band_applies(32768, 128, 16384, 2)
+    monkeypatch.setattr(fa, "band_applies", lambda *a, **kw: False)
+    jax.clear_caches()
+    assert dict(_forward_kernels(grad, q, q, q))["flash_fwd_window"] \
+        == (2, 16, 2)
 
 
 def _normalised_jaxpr_digest(fn, *args) -> str:

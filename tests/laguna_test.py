@@ -306,9 +306,46 @@ def the_step_reports_the_held_share_test():
     from homebrewnlp_tpu.train import Trainer
     line = Trainer(params, model).publish_stash_plan()
     assert line.startswith("remat stash:")
-    assert line.endswith("moe held rows bound 1024")
+    assert line.endswith("moe held rows bound 1024; flash band 0 layers")
     assert telemetry.snapshot()["hbnlp_moe_held_rows_bound"]["series"][()] \
         == 1024
+
+
+def the_band_gauge_counts_the_window_layers_test():
+    """``hbnlp_flash_band_layers`` (PR 41): the layers whose windowed flash
+    forward is the band kernel, by ``parallel/flash_attention.band_applies``
+    — the cell's three window-512 layers on a TPU (the full model's 36: the
+    body's three an eleventh of the depth, three more trailing), none on the
+    CPU, none where no layer declares a window, none where the kernels are
+    not reached."""
+    from homebrewnlp_tpu import telemetry
+    from homebrewnlp_tpu.train import Trainer
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "laguna_s_2_1.json")) as f:
+        cell = json.load(f)["config"]
+    params = ModelParameter(cell)
+    assert remat.flash_band_layers(params, "tpu") == 3
+    assert remat.flash_band_layers(params) == 0
+    with open(os.path.join(REPO, "configs", "laguna_s_2_1.json")) as f:
+        full = ModelParameter(json.load(f))
+    assert remat.flash_band_layers(full, "tpu") == 3 * full.depth + 3 == 36
+    tiny = ModelParameter(_config("bfloat16"))
+    assert remat.flash_band_layers(tiny, "tpu") == 3
+    assert remat.flash_band_layers(tiny) == 0
+    assert remat.flash_band_layers(
+        ModelParameter(_config(use_flash_attention=False)), "tpu") == 0
+    assert remat.flash_band_layers(
+        ModelParameter(_config(sequence_length=96)), "tpu") == 0
+    # a window as long as the sequence is the causal call; no window, no line
+    assert remat.flash_band_layers(
+        ModelParameter(_config(sequence_length=32)), "tpu") is None
+    with open(os.path.join(REPO, "configs", "olmoe_1b_7b.json")) as f:
+        assert remat.flash_band_layers(ModelParameter(json.load(f)),
+                                       "tpu") is None
+    model = Model(tiny)
+    line = Trainer(tiny, model).publish_stash_plan()
+    assert line.endswith("; flash band 0 layers")
+    assert telemetry.snapshot()["hbnlp_flash_band_layers"]["series"][()] == 0
 
 
 def the_experts_stash_counts_the_bounds_rows_test():
@@ -471,6 +508,12 @@ def the_repos_config_is_the_published_model_test():
      "body/attention"),
     # a leading block is a body layer that runs once
     ("jit(step_fn)/jvp(gpt0)/input0/lang_inp0_0/attention_0/flash_attention/x",
+     "body/attention"),
+    # the band forward (PR 41), in a block's forward and in its replay
+    ("jit(step_fn)/jvp(gpt0)/body0/checkpoint/block0_0_0/attention_0/"
+     "flash_attention/flash_fwd_window", "body/attention"),
+    ("jit(step_fn)/transpose(jvp(gpt0))/body0/checkpoint/rematted_computation/"
+     "block0_2_0/attention_0/flash_attention/flash_fwd_window",
      "body/attention"),
     ("jit(step_fn)/jvp(gpt0)/input0/lang_inp1_0/mlp_0/dot_general",
      "body/mlp"),

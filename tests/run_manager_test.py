@@ -40,7 +40,11 @@ def preemption_recovery_test(tmp_path, monkeypatch):
     args = types.SimpleNamespace(
         run_command=run_cmd, model_path=d, create_cmd=create,
         health_cmd=health, delete_cmd=delete, poll_interval=0,
-        poll_jitter=0, stall_timeout=0, max_restarts=5)
+        poll_jitter=0, stall_timeout=0, max_restarts=5,
+        # ``Manager.kill`` waits this long after its SIGTERM before the
+        # SIGKILL: where the parked launch inherits an ignored SIGTERM, the
+        # default of 600 s holds a test worker for ten minutes
+        term_grace=1)
     rm.Manager(args).run()
 
     log = open(os.path.join(d, "run.log")).read()

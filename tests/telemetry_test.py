@@ -488,6 +488,8 @@ _RARE_SERIES = {telemetry.SPAN_METRIC, "hbnlp_init_values_seconds_total",
                 "hbnlp_ssd_state_bytes", "hbnlp_mamba_conv_kernel_layers",
                 # likewise (PR 37; 0 without a gated_delta layer)
                 "hbnlp_delta_solve_kernel_layers",
+                # likewise (PR 41; 0 without a windowed attention layer)
+                "hbnlp_flash_band_layers",
                 # set at the marks of telemetry/memory.py, where the backend
                 # reports memory (PR 34; XLA:CPU: no series)
                 "hbnlp_hbm_bytes", "hbnlp_train_state_bytes"}
