@@ -13,8 +13,8 @@ mechanically for every jitted entry point instead of one-off per PR:
   needed only to produce the HLO you feed it.
 - ``ast_lint``   — repo-specific source rules (wall-clock discipline,
   unseeded rngs, donated-jit registration, config-docs coverage).
-  Stdlib-only and importable standalone (scripts/check_config_docs.py
-  loads it without the package).
+  Stdlib-only and importable standalone (by file path, without the
+  package).
 - ``entry_points`` — builds a small audit model on the current backend and
   lowers the registered jitted entry points (train step, decode chunk
   step, prefill-entry step, eval fn, engine chunk step) for the HLO

@@ -1,8 +1,8 @@
 """Repo-specific AST lint rules (graft-lint half b).
 
 Source-level discipline over ``homebrewnlp_tpu/`` and ``scripts/`` —
-stdlib-only and importable WITHOUT the package (scripts/check_config_docs.py
-loads this file by path; nothing here may import numpy, jax, or siblings):
+stdlib-only and importable WITHOUT the package (by file path; nothing here
+may import numpy, jax, or siblings):
 
 ==============  ============================================================
 rule            invariant
@@ -33,9 +33,7 @@ mesh-axis-literal  hardcoded mesh-axis name strings ("data", "model",
                 ``core/sharding.py``, ``config.py``).  Use the
                 ``core.sharding`` constants (``DATA_AXIS`` ...) so an axis
                 rename cannot silently strand a PartitionSpec.
-config-docs     every ModelParameter knob has a docs/CONFIG.md table row
-                (absorbed from scripts/check_config_docs.py, which now
-                shims onto this rule).
+config-docs     every ModelParameter knob has a docs/CONFIG.md table row.
 env-knob        no ``os.environ`` / ``os.getenv`` read (``.get``, subscript,
                 ``in``, ``setdefault``, ``pop``) under
                 ``homebrewnlp_tpu/{model,parallel,train,optim,core}``: what
@@ -43,6 +41,14 @@ env-knob        no ``os.environ`` / ``os.getenv`` read (``.get``, subscript,
                 what the code observes, never the shell it was started
                 from.  ``ENV_KNOB_ALLOWED`` names the reads that are left,
                 each with the debt that removes it.
+layering        imports point downwards: no module under
+                ``homebrewnlp_tpu/{core,parallel,telemetry,optim}`` imports
+                ``model/``, ``train/``, ``infer/`` or ``run/``
+                (``LAYERING_EXCEPTIONS``: the two pipeline schedules, with
+                their debt), and the declaration readers
+                (``model/remat.py``, ``train/__init__.py``) import no layer
+                and no kernel module — they read what a layer DECLARES
+                (``model/declare.py``), so a new layer edits neither.
 metric-docs     every ``hbnlp_*`` metric name registered via a registry
                 ``counter()``/``gauge()``/``histogram()`` call must have a
                 row in docs/OBSERVABILITY.md's catalog (mirrors the
@@ -138,6 +144,26 @@ ENV_KNOB_ALLOWED: typing.Dict[str, str] = {
         "with the kernel (S1) or when its tests pass interpret themselves",
 }
 
+#: the layering rule: the lower layers, what they must not import, and the
+#: modules that still do -> the debt (ROADMAP.md) that removes the entry
+LOWER_DIRS = tuple(f"homebrewnlp_tpu/{d}/" for d in
+                   ("core", "parallel", "telemetry", "optim"))
+UPPER_PACKAGES = tuple(f"homebrewnlp_tpu.{d}" for d in
+                       ("model", "train", "infer", "run"))
+LAYERING_EXCEPTIONS: typing.Dict[str, str] = {
+    "homebrewnlp_tpu/parallel/pipeline.py":
+        "runs the model's revnet / momentum sequences a stage (D0(c))",
+    "homebrewnlp_tpu/parallel/pipeline_1f1b.py":
+        "runs the model's revnet / momentum sequences a stage (D0(c))",
+}
+#: the declaration readers, and all they may import of ``model/`` and
+#: ``parallel/``: the package (``Model``), the declarations, the rule
+DECLARATION_READERS = ("homebrewnlp_tpu/model/remat.py",
+                       "homebrewnlp_tpu/train/__init__.py")
+DECLARATION_READERS_MAY = frozenset((
+    "homebrewnlp_tpu.model", "homebrewnlp_tpu.model.declare",
+    "homebrewnlp_tpu.model.remat"))
+
 _ENV_MAPPINGS = ("os.environ", "environ")
 _ENV_READ_CALLS = ("os.getenv", "getenv") + tuple(
     f"{m}.{f}" for m in _ENV_MAPPINGS for f in ("get", "setdefault", "pop"))
@@ -199,6 +225,7 @@ class _FileVisitor(ast.NodeVisitor):
         for alias in node.names:
             if alias.name == "time":
                 self.time_modules.add(alias.asname or "time")
+        self._layering(node, [alias.name for alias in node.names])
         self.generic_visit(node)
 
     def visit_ImportFrom(self, node: ast.ImportFrom):
@@ -206,7 +233,47 @@ class _FileVisitor(ast.NodeVisitor):
             for alias in node.names:
                 if alias.name == "time":
                     self.time_funcs.add(alias.asname or "time")
+        # the absolute name of what a relative import names
+        package = self.rel[:-3].split("/")[:-1]
+        base = package[:len(package) - node.level + 1] if node.level else []
+        base = ".".join(base + ([node.module] if node.module else []))
+        self._layering(node, [base] + [f"{base}.{alias.name}"
+                                       for alias in node.names])
         self.generic_visit(node)
+
+    # -- layering ------------------------------------------------------------
+
+    def _layering(self, node: ast.AST, imported: typing.Sequence[str]):
+        """``imported``: absolute dotted names, modules or attributes of
+        modules (an attribute matches no package and no file)."""
+        def under(name, packages):
+            return any(name == p or name.startswith(p + ".")
+                       for p in packages)
+
+        def is_module(name):
+            path = os.path.join(REPO, *name.split("."))
+            return os.path.isfile(path + ".py") or os.path.isdir(path)
+
+        if self.rel.startswith(LOWER_DIRS) \
+                and self.rel not in LAYERING_EXCEPTIONS:
+            for name in imported:
+                if under(name, UPPER_PACKAGES):
+                    self._add("layering", node,
+                              f"{name} imported from a lower layer — move "
+                              "what is shared down (core/), or name the "
+                              "module with its debt in analysis/ast_lint.py "
+                              "LAYERING_EXCEPTIONS")
+                    break
+        if self.rel in DECLARATION_READERS:
+            for name in imported:
+                if under(name, ("homebrewnlp_tpu.model",
+                                "homebrewnlp_tpu.parallel")) \
+                        and name not in DECLARATION_READERS_MAY \
+                        and is_module(name):
+                    self._add("layering", node,
+                              f"{name} imported by a declaration reader — "
+                              "read what the layer declares "
+                              "(model/declare.py) instead of naming it")
 
     def _add(self, rule: str, node: ast.AST, message: str):
         if not _suppressed(self.lines, node.lineno, rule):
@@ -331,7 +398,7 @@ def lint_source(rel: str, source: str) -> typing.List[Finding]:
     return visitor.findings
 
 
-# ---- config-docs rule (absorbed from scripts/check_config_docs.py) ---------
+# ---- config-docs rule ----------------------------------------------------
 
 #: internal bookkeeping assigned in the defaults section that is NOT a
 #: config knob (everything else there is)
@@ -403,6 +470,9 @@ OBSERVABILITY_MD = os.path.join(REPO, "docs", "OBSERVABILITY.md")
 #: registry factory method names whose first string argument is a metric
 #: name (telemetry/registry.py Registry API)
 _METRIC_METHODS = frozenset(("counter", "gauge", "histogram"))
+#: what a layer declares a metric with (model/declare.py): the name is any
+#: literal argument, the trainer registers it from the declaration
+_METRIC_DECLARATIONS = frozenset(("Stat", "Fact"))
 _METRIC_PREFIX = "hbnlp_"
 
 
@@ -410,9 +480,10 @@ def registered_metrics(root: str = REPO,
                        subdirs: typing.Sequence[str] = LINT_SUBDIRS
                        ) -> typing.List[typing.Tuple[str, str, int]]:
     """Every ``hbnlp_*`` metric registered through a literal first argument
-    of a ``counter``/``gauge``/``histogram`` call: ``(name, rel, lineno)``.
-    Names passed through variables (e.g. ``SPAN_METRIC``) are out of scope
-    — the rule polices the literal-registration idiom every layer uses."""
+    of a ``counter``/``gauge``/``histogram`` call, or declared by a layer as
+    a literal argument of ``Stat(...)`` / ``Fact(...)``: ``(name, rel,
+    lineno)``.  Names passed through variables (e.g. ``SPAN_METRIC``) are
+    out of scope — the rule polices the literal idioms every layer uses."""
     out: typing.List[typing.Tuple[str, str, int]] = []
     for path, rel in iter_source_files(root, subdirs):
         with open(path) as f:
@@ -423,15 +494,20 @@ def registered_metrics(root: str = REPO,
             continue
         lines = src.splitlines()
         for node in ast.walk(tree):
-            if (isinstance(node, ast.Call)
-                    and isinstance(node.func, ast.Attribute)
-                    and node.func.attr in _METRIC_METHODS
-                    and node.args
-                    and isinstance(node.args[0], ast.Constant)
-                    and isinstance(node.args[0].value, str)
-                    and node.args[0].value.startswith(_METRIC_PREFIX)
-                    and not _suppressed(lines, node.lineno, "metric-docs")):
-                out.append((node.args[0].value, rel, node.lineno))
+            if not isinstance(node, ast.Call) \
+                    or _suppressed(lines, node.lineno, "metric-docs"):
+                continue
+            if isinstance(node.func, ast.Attribute) \
+                    and node.func.attr in _METRIC_METHODS:
+                names = node.args[:1]
+            elif _dotted(node.func).split(".")[-1] in _METRIC_DECLARATIONS:
+                names = node.args
+            else:
+                continue
+            out += [(arg.value, rel, node.lineno) for arg in names
+                    if isinstance(arg, ast.Constant)
+                    and isinstance(arg.value, str)
+                    and arg.value.startswith(_METRIC_PREFIX)]
     return out
 
 

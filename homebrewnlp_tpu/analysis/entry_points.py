@@ -1,7 +1,7 @@
 """Lowering registry: every jitted entry point the HLO passes audit.
 
-``infer/hlo_check.py`` audited ONE entry point (the decode chunk step);
-the train step (``train/__init__.py`` ``donate_argnums=(0,)``), the
+The first audit covered ONE entry point (the decode chunk step); the train
+step (``train/__init__.py`` ``donate_argnums=(0,)``), the
 cache-initialising first decode chunk ("prefill entry"), and the eval fn
 were on the honor system.  This module builds a small audit model and
 lowers + compiles all four on the CURRENT backend — on TPU that audits the
@@ -269,21 +269,21 @@ def lower_engine_step(model, variables, token_x, mesh=None):
     full-pool-shaped copy, per-slot position vector and all
     (infer/engine.py; docs/SERVING.md).
 
-    Audits the steady-state ``engine_plain`` variant — the program every
+    Audits the steady-state ``plain`` phase — the program every
     decode chunk between admissions runs; abstract avals throughout, same
     OOM-safety argument as ``lower_decode_step``.
     """
     import jax
     import jax.numpy as jnp
 
-    from ..infer.engine import _engine_jit
+    from ..infer.engine import _chunk_jit
     from ..infer.sampler import decode_cache_shapes
 
     aval = jax.ShapeDtypeStruct
     batch = token_x.shape[0]
     shapes = decode_cache_shapes(model, variables, token_x)
     caches = {k: aval(v.shape, v.dtype) for k, v in shapes.items()}
-    step = _engine_jit(model, mesh, "engine_plain")
+    step = _chunk_jit(model, mesh, "plain")
     vec_i = aval((batch,), jnp.int32)
     vec_f = aval((batch,), jnp.float32)
     scalar = aval((), jnp.int32)
@@ -309,9 +309,9 @@ def lower_engine_step(model, variables, token_x, mesh=None):
 
 
 def lower_paged_step(model, variables, token_x, mesh=None):
-    """Compiled donated PAGED engine chunk step (``infer/paged.py``
-    ``_paged_jit`` kind ``paged_plain``): the donated carry holds the KV
-    BLOCK POOLS (per-leaf ``[num_blocks, block_tokens, ...]`` layouts plus
+    """Compiled donated PAGED engine chunk step (``infer/engine.py``
+    ``_chunk_jit`` phase ``plain`` with the ``paged`` component): the
+    donated carry holds the KV BLOCK POOLS (per-leaf ``[num_blocks, block_tokens, ...]`` layouts plus
     any resident recurrent leaves), and the chunk gathers per-slot views
     through the read table, runs the shared engine loop, and scatters back
     through the write table.  The audit pins every pool leaf aliased
@@ -323,7 +323,8 @@ def lower_paged_step(model, variables, token_x, mesh=None):
     import jax
     import jax.numpy as jnp
 
-    from ..infer.paged import _paged_jit, classify_cache_leaves
+    from ..infer.engine import _chunk_jit
+    from ..infer.paged import classify_cache_leaves
     from ..infer.sampler import decode_cache_shapes
 
     aval = jax.ShapeDtypeStruct
@@ -343,7 +344,7 @@ def lower_paged_step(model, variables, token_x, mesh=None):
             ps = list(s.shape)
             ps[baxis], ps[sax] = num_blocks, bt
             pools[n] = aval(tuple(ps), s.dtype)
-    step = _paged_jit(model, mesh, "paged_plain", bt, num_blocks)
+    step = _chunk_jit(model, mesh, "plain", paged=(bt, num_blocks))
     vec_i = aval((batch,), jnp.int32)
     vec_f = aval((batch,), jnp.float32)
     scalar = aval((), jnp.int32)
@@ -373,8 +374,8 @@ def lower_paged_step(model, variables, token_x, mesh=None):
 def lower_spec_step(model, variables, token_x, draft_model=None,
                     draft_variables=None, mesh=None):
     """Compiled donated SPECULATIVE chunk step (``infer/engine.py``
-    ``_spec_jit`` kind ``spec_plain`` — k+1 draft steps + one width-(k+1)
-    verify in a single program): the donated carry holds BOTH cache pools
+    ``_chunk_jit`` phase ``plain`` with a draft model — k+1 draft steps +
+    one width-(k+1) verify in a single program): the donated carry holds BOTH cache pools
     — the target's slot pool AND the quarter-width draft's — and the audit
     pins every leaf of both aliased input->output with no full-pool-shaped
     copy.  The verify's sampled-token readback is the only fresh output.
@@ -386,7 +387,7 @@ def lower_spec_step(model, variables, token_x, draft_model=None,
     import jax
     import jax.numpy as jnp
 
-    from ..infer.engine import _spec_jit
+    from ..infer.engine import _chunk_jit
     from ..infer.sampler import decode_cache_shapes
 
     if draft_model is None:
@@ -399,8 +400,8 @@ def lower_spec_step(model, variables, token_x, draft_model=None,
     dshapes = decode_cache_shapes(draft_model, draft_variables, token_x)
     caches = {k: aval(v.shape, v.dtype) for k, v in tshapes.items()}
     dcaches = {k: aval(v.shape, v.dtype) for k, v in dshapes.items()}
-    step = _spec_jit(model, draft_model, mesh, "spec_plain",
-                     model.params.spec_draft_tokens)
+    step = _chunk_jit(model, mesh, "plain", draft_model=draft_model,
+                      k=model.params.spec_draft_tokens)
     vec_i = aval((batch,), jnp.int32)
     vec_f = aval((batch,), jnp.float32)
     vec_b = aval((batch,), jnp.bool_)

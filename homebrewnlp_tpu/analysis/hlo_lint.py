@@ -3,8 +3,7 @@
 Every pass takes post-optimization HLO text (``jitted.lower(...).compile()
 .as_text()``) plus the caller's expectations and returns ``Finding``s —
 nothing raises, so one run can report every violation at once (the CLI and
-the tier-1 test decide severity).  The passes generalize
-``infer/hlo_check.py`` (which now delegates here):
+the tier-1 test decide severity):
 
 =====================  ====================================================
 pass                   invariant
@@ -283,10 +282,9 @@ def int8_promotion_audit(entry: str, hlo_text: str,
     dequant scope.
 
     The quantized paths promise int8 reaches float exactly once, inside a
-    named fused-dequant region: weights (``serve_quantized_weights``,
-    ``train_quantized_matmuls``) under ``named_scope("dequant")``
-    (``core.scope.materialize_param`` / ``core.quant.ste_dequantize``),
-    and int8 KV caches (``decode_cache_dtype: "int8"``) under the decode
+    named fused-dequant region: weights (``serve_quantized_weights``) under
+    ``named_scope("dequant")`` (``core.scope.materialize_param``), and int8
+    KV caches (``decode_cache_dtype: "int8"``) under the decode
     path's ``named_scope("cache_read")`` (model/decode.py) — both are
     allowed by default.  Any OTHER s8 -> float convert is an accidental
     full-precision materialization of a quantized buffer: it silently
@@ -541,8 +539,8 @@ def audit(entry: str, hlo_text: str, *,
     if check_host_sync:
         findings += host_sync_audit(entry, hlo_text)
     # always on: vacuously clean on int8-free modules, and the quantized
-    # paths (serve_quantized_weights / train_quantized_matmuls) get their
-    # no-promotion-outside-dequant invariant audited for free the moment
-    # an entry point compiles with int8 weights
+    # path (serve_quantized_weights) gets its no-promotion-outside-dequant
+    # invariant audited for free the moment an entry point compiles with
+    # int8 weights
     findings += int8_promotion_audit(entry, hlo_text)
     return findings

@@ -118,8 +118,8 @@ MESH_STRATEGIES: typing.Dict[str, MeshStrategy] = {
     "dp_tp": MeshStrategy(
         "dp_tp",
         {"mesh_shape_override": {"data": 4, "model": 2}},
-        entries=("train_step", "train_step_bucketed", "decode_chunk_step",
-                 "engine_chunk_step", "spec_chunk_step", "paged_chunk_step",
+        entries=("train_step", "decode_chunk_step", "engine_chunk_step",
+                 "spec_chunk_step", "paged_chunk_step",
                  "spec_paged_chunk_step"),
         sharded_dims={"heads": "model"},
         collective_axes=frozenset({"data", "model"}),
@@ -400,20 +400,12 @@ def _cache_protected(cache_shapes: typing.Mapping[str, typing.Any]
             for name, v in cache_shapes.items()}
 
 
-def lower_train_under_mesh(strategy: MeshStrategy, devices=None,
-                           bucketed: bool = False):
+def lower_train_under_mesh(strategy: MeshStrategy, devices=None):
     """``(hlo_text, context)`` of the donated train step compiled under
-    the strategy's mesh from avals.  ``bucketed`` audits the SAME step
-    with ``grad_allreduce="bucketed"`` (the overlap-aware per-bucket
-    gradient reduction, budgets key ``train_step_bucketed``) so the two
-    collective schedules are both regression-pinned."""
+    the strategy's mesh from avals."""
     from ..core import sharding as shardlib
     from ..train import Trainer
 
-    if bucketed:
-        strategy = dataclasses.replace(
-            strategy, overrides={**dict(strategy.overrides),
-                                 "grad_allreduce": "bucketed"})
     params, model = _strategy_params_model(strategy)
     devices = audit_devices() if devices is None else devices
     mesh = shardlib.build_mesh(params, devices)
@@ -549,9 +541,6 @@ def lower_strategy(strategy: MeshStrategy, devices=None
         try:
             if entry == "train_step":
                 out[entry] = lower_train_under_mesh(strategy, devices)
-            elif entry == "train_step_bucketed":
-                out[entry] = lower_train_under_mesh(strategy, devices,
-                                                    bucketed=True)
             else:
                 out[entry] = lower_serving_under_mesh(strategy, entry,
                                                       devices)

@@ -129,7 +129,7 @@ class ModelParameter:
         # a host loop over a jitted CHUNK of decode steps whose carry
         # (token_x, caches, rng, position) is DONATED — input_output_aliases
         # then pins every cache update in place, a property asserted on the
-        # compiled HLO (infer/hlo_check.py).  "auto": stepped when the cache
+        # compiled HLO (analysis/hlo_lint.py).  "auto": stepped when the cache
         # pytree exceeds decode_stepped_min_cache_gb, fused below it.
         self.decode_loop = "auto"
         # tokens per jitted chunk dispatch on the stepped path; amortises
@@ -387,25 +387,6 @@ class ModelParameter:
         # All four execute the SAME primal recurrence (identical losses;
         # gradients agree to reconstruction ulps — tests/remat_policy_test).
         self.remat_policy = "auto"
-        # matmul accumulation policy for bf16 GEMMs ("auto"/"f32"/"bf16"):
-        # "auto" keeps the established behavior (f32 MXU accumulation
-        # requested on TPU backends, backend default elsewhere); "bf16"
-        # drops the f32 request — faster MXU path whose quality cost must
-        # clear the same harness as train_quantized_matmuls; "f32" insists
-        # where supported (CPU keeps backend default — its DotThunk cannot
-        # emit mixed bf16->f32 dots).  Consumed by core/tensor.einsum via
-        # the scope context.
-        self.matmul_accumulation = "auto"
-        # quantize the training forward's largest GEMM weights to int8 each
-        # step (core/quant.py quantize_for_training): one on-device amax
-        # pass over the live master weights, the forward reads the
-        # depth-shared per-channel int8 grid through a straight-through-
-        # estimator dequant (masters/optimizer stay full precision).
-        # Quality-guarded like serve_quantized_weights: losses bit-identical
-        # when off; >= 99% argmax agreement + in-noise val loss when on
-        # (tests/train_quant_test.py); graft-lint audits that the step emits
-        # no float promotion of int8 operands outside the fused dequant
-        self.train_quantized_matmuls = False
         # lax.scan unroll factor for the depth scan (XLA overlap vs memory)
         self.scan_unroll = 1
         self.gradient_checkpointing_policy = "nothing_saveable"
@@ -675,24 +656,6 @@ class ModelParameter:
         # lease lapses (a wedged main thread keeps heartbeating forever;
         # this is the only signal that catches it).  0 = off
         self.elastic_straggler_factor = 4.0
-        # ---- gradient all-reduce policy (docs/DISTRIBUTED.md) ----
-        # "fused" = the historical GSPMD lowering (per-leaf all-reduces at
-        # the compiler's discretion; bit-identical to every earlier round).
-        # "bucketed" = the train step computes per-data-shard gradients
-        # under a partial-manual shard_map and issues ONE multi-operand
-        # all-reduce per size-targeted bucket of grad leaves, in reverse-
-        # topological order (output-side leaves first — the ones whose
-        # backward contributions complete first), so the collectives can
-        # overlap the remaining backward compute.  Losses match fused
-        # within float reduction-order tolerance (mean-of-shard-means vs
-        # global mean); configs the policy cannot carry (pipeline/sequence
-        # meshes, pcgrad/mgda, grad accumulation, video) fall back to
-        # fused with a loud warning
-        self.grad_allreduce = "fused"
-        # bucket size target in MiB: smaller = more, earlier collectives
-        # (better overlap, more per-op latency); larger = fewer, bigger
-        # ones.  A single leaf above the target gets its own bucket
-        self.grad_bucket_mb = 4.0
 
         self.unknown_config_keys: typing.List[str] = []
         for k, v in config.items():
@@ -754,14 +717,6 @@ class ModelParameter:
         if self.elastic_exit_grace_s < 0:
             raise ValueError("elastic_exit_grace_s must be >= 0, got "
                              f"{self.elastic_exit_grace_s}")
-        # tri-state-style gate like serve_engine: a typo would silently
-        # train through the wrong collective schedule
-        if self.grad_allreduce not in ("fused", "bucketed"):
-            raise ValueError("grad_allreduce must be \"fused\" or "
-                             f"\"bucketed\", got {self.grad_allreduce!r}")
-        if self.grad_bucket_mb <= 0:
-            raise ValueError("grad_bucket_mb must be > 0, got "
-                             f"{self.grad_bucket_mb}")
         if self.serve_request_deadline_s <= 0:
             raise ValueError("serve_request_deadline_s must be > 0 (it is "
                              "the default deadline, not just a cap), got "
@@ -860,10 +815,6 @@ class ModelParameter:
             raise ValueError("remat_policy must be \"auto\", \"recompute\", "
                              "\"stash\", \"save\" or \"save_dots\", got "
                              f"{self.remat_policy!r}")
-        if self.matmul_accumulation not in ("auto", "f32", "bf16"):
-            raise ValueError("matmul_accumulation must be \"auto\", \"f32\" "
-                             f"or \"bf16\", got "
-                             f"{self.matmul_accumulation!r}")
         # the checkpoint-strategy jax.checkpoint sites consume this name
         # via getattr (model/blocks.py _checkpoint_policy); validate here so
         # a typo is a clear config error, not an AttributeError mid-trace

@@ -1,39 +1,18 @@
-"""Weight-only int8 quantization — shared by serving AND training.
+"""Weight-only int8 quantization for serving (``serve_quantized_weights``).
 
-The eligibility rules, per-channel scale-axis selection and the
-``quantize_variables`` entry of the serving path live in ``core`` next to
-the scope/materialize machinery that consumes the scales, with a TRAINING
-entry point beside them:
+The eligibility rules, the per-channel scale-axis selection and
+``quantize_variables`` live in ``core`` next to the scope / materialize
+machinery that consumes the scales: a loaded checkpoint is quantized ONCE on
+the host; ``core.scope.materialize_param`` dequantizes at use so the
+convert+scale chain fuses into the consuming dot's operand read (batch-1
+decode streams half the weight bytes, measured 99.3% argmax agreement on a
+trained checkpoint — docs/PERFORMANCE.md 'Decoding').
 
-* Serving (``serve_quantized_weights``, unchanged semantics): quantize a
-  loaded checkpoint ONCE on the host; ``core.scope.materialize_param``
-  dequantizes at use so the convert+scale chain fuses into the consuming
-  dot's operand read (batch-1 decode streams half the weight bytes,
-  measured 99.3% argmax agreement on a trained checkpoint —
-  docs/PERFORMANCE.md 'Decoding').
-
-* Training (``train_quantized_matmuls``, PR 11): the jitted step
-  re-quantizes the LIVE master weights every step on-device
-  (:func:`quantize_for_training`) and the forward's largest GEMMs consume
-  the int8 grid through :func:`ste_dequantize` — a straight-through
-  estimator whose forward is the exact serving dequant chain (int8 ->
-  convert -> scale, under ``jax.named_scope("dequant")`` so graft-lint can
-  audit that no OTHER float promotion of an int8 operand exists) and whose
-  backward passes the cotangent to the master weight unchanged (the
-  round/clip grid has zero gradient a.e.; STE is the standard
-  quantization-aware-training rule).  Master weights, the optimizer, and
-  every update stay full precision — only what the matmuls READ is
-  quantized, so the step's quality is measured exactly like serving
-  quantization: >= 99% teacher-forcing argmax agreement, val loss within
-  noise (tests/train_quant_test.py), and bit-identical losses when the
-  knob is off.
-
-Granularity (both paths): per-channel symmetric scales over every axis the
-consuming einsum does NOT contract (``Model.param_fan_in``, recorded at
-init); sibling depths of a block config share ONE scale (joint amax) so
-the scan-over-layers replay resolves the same scale array under depth-0
-canonical names — see the measured-quality discussion in the original
-docstring, preserved below at :func:`quantize_variables`.
+Granularity: per-channel symmetric scales over every axis the consuming
+einsum does NOT contract (``Model.param_fan_in``, recorded at init); sibling
+depths of a block config share ONE scale (joint amax) so the
+scan-over-layers replay resolves the same scale array under depth-0
+canonical names — the measured quality is at :func:`quantize_variables`.
 """
 from __future__ import annotations
 
@@ -42,6 +21,8 @@ import typing
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from .scope import depth0_name
 
 # quantize only tensors with at least this many elements AND >= 2 dims:
 # the big matmul weights are the bandwidth term; norms/biases/rezero
@@ -82,12 +63,6 @@ def _scale_axes(dims, fan_in_names, ndim: int) -> typing.Tuple[int, ...]:
     return tuple(range(ndim - 1))
 
 
-def _canonical(name: str) -> str:
-    from ..model.backend import _BLOCK_RE
-    return _BLOCK_RE.sub(
-        lambda m: f"{m.group(1)}block0_{m.group(3)}_{m.group(4)}/", name)
-
-
 def _scale_groups(variables: typing.Dict[str, typing.Any],
                   param_dims: typing.Optional[dict],
                   param_fan_in: typing.Optional[dict]
@@ -101,7 +76,7 @@ def _scale_groups(variables: typing.Dict[str, typing.Any],
     for name, value in variables.items():
         dims = (param_dims or {}).get(name, ())
         if eligible(name, value, dims):
-            groups.setdefault(_canonical(name), []).append(name)
+            groups.setdefault(depth0_name(name), []).append(name)
     out = {}
     for canon, names in groups.items():
         dims = (param_dims or {}).get(names[0], ())
@@ -113,19 +88,13 @@ def _scale_groups(variables: typing.Dict[str, typing.Any],
 
 def _quantize_group(variables: typing.Dict[str, typing.Any],
                     names: typing.Sequence[str],
-                    axes: typing.Tuple[int, ...],
-                    stop_grad: bool = False
+                    axes: typing.Tuple[int, ...]
                     ) -> typing.Tuple[typing.Dict[str, jax.Array],
                                       jax.Array]:
-    """``({name: int8 weight}, shared scale)`` for ONE depth-shared group —
-    the single definition of the grid (joint amax over the group,
-    ``amax/127`` symmetric scale, clip to ±127) serving AND training share,
-    so the two paths cannot silently desynchronize.  ``stop_grad`` stops
-    the amax/round chain for the in-step training path (the scale follows
-    the weights; it is not a gradient path)."""
+    """``({name: int8 weight}, shared scale)`` for ONE depth-shared group:
+    joint amax over the group, ``amax/127`` symmetric scale, clip to ±127."""
     def _w(name):
-        w = jnp.asarray(variables[name], jnp.float32)
-        return jax.lax.stop_gradient(w) if stop_grad else w
+        return jnp.asarray(variables[name], jnp.float32)
 
     amax = None
     for name in names:
@@ -167,66 +136,3 @@ def quantize_variables(variables: typing.Dict[str, typing.Any],
             scales[name] = scale
         scales[canon] = scale
     return qvars, scales
-
-
-# ---- training path (train_quantized_matmuls) -------------------------------
-
-@jax.custom_vjp
-def ste_dequantize(master: jax.Array, qdata: jax.Array,
-                   scale: jax.Array) -> jax.Array:
-    """Dequantized weight with a straight-through gradient to ``master``.
-
-    Forward VALUE is exactly the serving dequant chain — ``qdata`` (int8)
-    converted and multiplied by ``scale`` — so the compiled step reads the
-    quantized grid, not the master; backward passes the output cotangent
-    to ``master`` unchanged (round/clip has zero gradient a.e.; the
-    straight-through estimator is the standard QAT rule) and zero to
-    ``scale`` (scales follow the master's amax, they are re-derived each
-    step, not learned)."""
-    del master
-    return (qdata.astype(jnp.float32) * scale)
-
-
-def _ste_fwd(master, qdata, scale):
-    # residuals carry the live master/scale only for their dtype/shape —
-    # both are step inputs, so nothing extra stays resident
-    return ste_dequantize(master, qdata, scale), (master, scale)
-
-
-def _ste_bwd(res, ct):
-    master, scale = res
-    # int8 qdata gets a symbolic-zero (float0) cotangent automatically
-    return (ct.astype(master.dtype), None, jnp.zeros_like(scale))
-
-
-ste_dequantize.defvjp(_ste_fwd, _ste_bwd)
-
-
-def quantize_for_training(variables: typing.Dict[str, jax.Array],
-                          param_dims: typing.Optional[dict],
-                          param_fan_in: typing.Optional[dict],
-                          calc_dtype) -> typing.Dict[str, jax.Array]:
-    """Per-step fake-quantized view of the live master weights.
-
-    Runs INSIDE the jitted train step: one amax pass per eligible weight
-    group (depth-shared, per-channel — identical grid to the serving
-    path), then each eligible weight is replaced by its
-    :func:`ste_dequantize` value in ``calc_dtype``.  Ineligible leaves
-    pass through untouched, so the returned dict is a drop-in for
-    ``model.apply``.  The quantize lives under ``named_scope("quantize_
-    weights")`` and the dequant under ``named_scope("dequant")`` — the
-    join keys graft-lint's int8-promotion audit checks, and the scopes the
-    cost ledger attributes the (small) extra work to."""
-    out = dict(variables)
-    for canon, (names, axes) in _scale_groups(variables, param_dims,
-                                              param_fan_in).items():
-        with jax.named_scope("quantize_weights"):
-            # stop_grad: the scale follows the weights, it is not a
-            # gradient path (matches _ste_bwd's zero scale cotangent)
-            qdata, scale = _quantize_group(variables, names, axes,
-                                           stop_grad=True)
-        with jax.named_scope("dequant"):
-            for name in names:
-                out[name] = ste_dequantize(
-                    variables[name], qdata[name], scale).astype(calc_dtype)
-    return out

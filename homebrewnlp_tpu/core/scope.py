@@ -22,6 +22,7 @@ import contextlib
 import dataclasses
 import functools
 import math
+import re
 import typing
 import zlib
 
@@ -39,6 +40,17 @@ Params = typing.Dict[str, jax.Array]
 class _Frame:
     name: str
     counters: typing.Dict[str, int] = dataclasses.field(default_factory=dict)
+
+
+_BLOCK_RE = re.compile(r"(body\d+/)block(\d+)_(\d+)_(\d+)/")
+
+
+def depth0_name(name: str) -> str:
+    """A body block's parameter name at depth 0: sibling depths of one block
+    config share it (cross-layer weight sharing, model/backend.py; the int8
+    scale groups, core/quant.py)."""
+    return _BLOCK_RE.sub(
+        lambda m: f"{m.group(1)}block0_{m.group(3)}_{m.group(4)}/", name)
 
 
 class Context:
@@ -91,10 +103,6 @@ class Context:
         # output (model/blocks.py); None under the modes that carry none,
         # where a layer that needs one refuses by name
         self.side: typing.Optional[dict] = None
-        # matmul-accumulation policy for bf16 einsums ("auto"/"f32"/"bf16",
-        # config.matmul_accumulation); consumed by core.tensor.einsum and
-        # propagated by ReplayBlock like quant_scales
-        self.matmul_accumulation: typing.Optional[str] = None
         # init mode under Model.init: the core.value_pool.ValuePool that
         # makes the values while the walk goes on (new_param); None = each
         # value is made where the walk meets it
@@ -263,9 +271,7 @@ def materialize_param(ctx: Context, name: str, data, calc_dtype):
     scales = getattr(ctx, "quant_scales", None)
     if scales and data.dtype == jnp.int8 and name in scales:
         # named region: graft-lint's int8-promotion audit allows s8->float
-        # converts ONLY inside dequant-tagged scopes (hlo_lint.py), so the
-        # serving dequant must carry the same tag the training-side
-        # ste_dequantize does (core/quant.py)
+        # converts ONLY inside dequant-tagged scopes (hlo_lint.py)
         with jax.named_scope("dequant"):
             scaled = data.astype(jnp.float32) * scales[name]
             return scaled.astype(calc_dtype)

@@ -73,6 +73,19 @@ def build_mesh(params: ModelParameter,
     return Mesh(np.asarray(devices).reshape(sizes), tuple(axes))
 
 
+def shard_geometry(mesh) -> typing.Tuple[int, typing.Any]:
+    """``(per-device shard divisor, a device)`` for capacity estimates —
+    activation-sized arrays shard over every data / model / sequence axis;
+    ``(1, None)`` without a mesh."""
+    shards = 1
+    device = None
+    if mesh is not None and getattr(mesh, "devices", None) is not None:
+        for axis in (DATA_AXIS, MODEL_AXIS, SEQUENCE_AXIS):
+            shards *= mesh.shape.get(axis, 1)
+        device = np.asarray(mesh.devices).flat[0]
+    return shards, device
+
+
 def placement_report(variables: typing.Mapping[str, jax.Array],
                      mesh: typing.Optional[Mesh]) -> str:
     """One start-up line saying where the parameters actually are: the mesh,

@@ -153,17 +153,10 @@ def einsum(inputs: typing.Sequence[NT], output_shape: SHAPE) -> NT:
     out_spec = "".join(sym[d] for d in output_shape)
     dtype = jnp.result_type(*[t.dtype for t in inputs])
     # bf16 matmuls accumulate in f32 on the MXU; CPU's DotThunk can't emit
-    # mixed bf16->f32 dots, so only request it on TPU backends.  The
-    # ``matmul_accumulation`` config knob rides the scope context: "bf16"
-    # drops the f32 request (faster MXU accumulation, quality-guarded —
-    # config.py), "f32"/"auto" keep it where the backend supports it
+    # mixed bf16->f32 dots, so only request it on TPU backends
     prefer = None
     if dtype == jnp.bfloat16 and jax.default_backend() not in ("cpu",):
-        from . import scope  # function-level: scope imports this module
-        policy = (getattr(scope.current(), "matmul_accumulation", None)
-                  if scope.in_context() else None)
-        if policy != "bf16":
-            prefer = jnp.float32
+        prefer = jnp.float32
     data = jnp.einsum(f"{in_specs}->{out_spec}",
                       *[t.data for t in inputs],
                       preferred_element_type=prefer)

@@ -146,7 +146,7 @@ def _engine_loop(model: Model, mesh, variables, ipb, tb, end_pos, steps,
     """The engine's decode while-loop: up to ``steps`` live iterations of
     (read token at q -> apply_decode -> sample -> write q+1 past the prompt
     boundary).  ONE definition shared by the plain slot engine
-    (``_engine_jit``) and the paged engine (``infer/paged.py``) — the
+    (``_chunk_jit``) and the paged engine (``infer/paged.py``) — the
     paged-vs-plain greedy bit-parity contract cannot drift between copies
     because there are no copies.  ``caches`` is whatever cache pytree the
     caller carries (the fixed-slot pool, or the paged engine's gathered
@@ -548,19 +548,6 @@ class Engine:
         return _chunk_jit(self.model, self.mesh, phase,
                           draft_model=self.draft_model, k=self.k,
                           paged=self.paged)
-
-
-def _engine_jit(model: Model, mesh, kind: str):
-    """Compat shim: the retired ``engine_init``/``engine_admit``/
-    ``engine_plain`` kind names onto the Engine's single builder."""
-    return _chunk_jit(model, mesh, kind.split("_", 1)[1])
-
-
-def _spec_jit(model: Model, draft_model: Model, mesh, kind: str, k: int):
-    """Compat shim: the retired ``spec_*`` kind names onto the Engine's
-    single builder (the spec composition)."""
-    return _chunk_jit(model, mesh, kind.split("_", 1)[1],
-                      draft_model=draft_model, k=k)
 
 
 class EngineExecutor:

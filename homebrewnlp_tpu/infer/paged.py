@@ -284,20 +284,6 @@ def classify_cache_leaves(shapes: typing.Mapping[str, typing.Any],
     return info
 
 
-# -------------------------------------------------------- paged chunk step
-
-def _paged_jit(model, mesh, kind: str, block_tokens: int, num_blocks: int):
-    """Compat shim: the retired ``paged_init``/``paged_admit``/
-    ``paged_plain`` kind names onto the Engine's single builder
-    (``engine._chunk_jit`` with the ``paged`` component — the gather /
-    shared-loop / scatter body now lives there, once, for both the paged
-    and the spec-on-paged compositions)."""
-    from .engine import _chunk_jit
-
-    return _chunk_jit(model, mesh, kind.split("_", 1)[1],
-                      paged=(int(block_tokens), int(num_blocks)))
-
-
 # ------------------------------------------------------------- the executor
 
 class PagedEngineExecutor(EngineExecutor):

@@ -432,7 +432,7 @@ def make_kv_step(model: Model, mesh=None, logits_filter: bool = False,
     provably updates in place instead of copying the multi-GB cache — the
     property the fused single-while_loop sampler loses at large cache sizes
     (BASELINE.md round 5: 60.1 ms/token at 32k vs the ~8 ms read bound) and
-    the one `infer/hlo_check.py` asserts on the compiled module.
+    the one `analysis/hlo_lint.py` asserts on the compiled module.
 
     The body is ``_kv_body`` — the same step the fused sampler runs — so
     greedy outputs are bit-identical between the two loop structures.
@@ -629,7 +629,7 @@ def _jit_sampler(model: Model, mesh, kind: str):
         elif base == "kv_step":
             # the stepped path's chunk: carry (argument 6) DONATED so XLA
             # aliases every cache buffer input->output — the in-place
-            # property infer/hlo_check.py asserts on the compiled module
+            # property analysis/hlo_lint.py asserts on the compiled module
             fn = jax.jit(make_kv_step(model, mesh=mesh, logits_filter=filt),
                          donate_argnums=(6,))
         elif base == "kv_step_init":

@@ -380,7 +380,6 @@ class Model:
         assert self.plan is not None, "call init() first (or assign .plan)"
         ctx = scope.Context("apply", params=variables, rng_key=rng, mesh=mesh)
         ctx.quant_scales = getattr(self, "quant_scales", None)
-        ctx.matmul_accumulation = self.params.matmul_accumulation
         ctx.stats_sink = stats_sink
         if layer_stats:
             ctx.layer_stats = []
@@ -537,7 +536,6 @@ class Model:
                             width=width)
         ctx = scope.Context("apply", params=variables, mesh=mesh, decode=state)
         ctx.quant_scales = getattr(self, "quant_scales", None)
-        ctx.matmul_accumulation = p.matmul_accumulation
         decode_dims = [Dim(d.name, width)
                        if d.name == p.sequence_dim.name else d
                        for d in p.token_dim_shape]
@@ -576,7 +574,6 @@ class Model:
                              cache_dtype=p.decode_cache_dtype, model_params=p)
         ctx = scope.Context("apply", params=variables, mesh=mesh)
         ctx.quant_scales = getattr(self, "quant_scales", None)
-        ctx.matmul_accumulation = p.matmul_accumulation
         ctx.prefill = state
 
         def _output_blocks(params, out):

@@ -17,7 +17,6 @@ Mirrors /root/reference/src/model/backend.py semantics on the jax substrate:
 """
 from __future__ import annotations
 
-import re
 import typing
 
 import numpy as np
@@ -26,8 +25,6 @@ from ..config import BlockArgs, ModelParameter
 from ..core import scope
 from ..core.dims import Dim, SHAPE, deduplicate, shape_size
 from ..core.tensor import NamedTensor, einsum
-
-_BLOCK_RE = re.compile(r"(body\d+/)block(\d+)_(\d+)_(\d+)/")
 
 
 class OrthogonalInit:
@@ -99,8 +96,7 @@ def get_var(args: BlockArgs, shape: SHAPE, initializer) -> NamedTensor:
     # (reference keys its cache on block-part index + fn call order,
     # backend.py:53-94 — hierarchical naming gives us the same identity).
     name = ctx.full_name("var")
-    canonical = _BLOCK_RE.sub(lambda m: f"{m.group(1)}block0_{m.group(3)}_{m.group(4)}/",
-                              name)
+    canonical = scope.depth0_name(name)
     if ctx.mode == "init" and canonical not in ctx.params:
         scope.new_param(ctx, canonical, shape, initializer, params.slice_dtype)
     return scope.param_tensor(ctx, canonical, shape, params.calculation_dtype)

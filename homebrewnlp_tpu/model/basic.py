@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import functools
 import math
+import typing
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +18,8 @@ import jax.numpy as jnp
 from ..config import BlockArgs
 from ..core import scope
 from ..core.dims import Dim, shape_sub
+from ..core.stash import (stash_channel, stash_collecting, stash_pop,
+                          stash_push)
 from ..core.tensor import (NamedTensor, cast, dropout as tensor_dropout, nt,
                            einsum, exp, multiply, reduce_max, reduce_sum,
                            reciprocal, rename_dim, reshape, sigmoid,
@@ -24,6 +27,7 @@ from ..core.tensor import (NamedTensor, cast, dropout as tensor_dropout, nt,
 from .activation import ACTIVATIONS, activate
 from .backend import (ConstantInit, get_var, linear, normal_var,
                       orthogonal_var)
+from .declare import Layer, Offer
 from .embedding import gather_embed
 from .normalization import norm
 from .utils import anonymize_dim, anonymize_shape, linear_shapes
@@ -271,7 +275,6 @@ def replay_stashed_linear(args: BlockArgs, chan: dict) -> NamedTensor:
     again.  The layout is pinned (batch on 'data', replicated over
     'model') on both sides so that GSPMD does not re-shard the stack."""
     from ..core.sharding import with_constraint
-    from .blocks import stash_collecting, stash_pop, stash_push
     params, mesh = args.params, scope.current().mesh
     if stash_collecting(chan):
         out = with_constraint(wrapped_linear(args), params, mesh)
@@ -411,7 +414,6 @@ def feed_forward_product_key_memory(args: BlockArgs) -> NamedTensor:
 def bottleneck_group_linear(args: BlockArgs) -> NamedTensor:
     """features -> bottleneck(intermediate) -> widened grouped mid -> grouped
     out (basic.py:122-126); the workhorse of the flagship mixer configs."""
-    from .blocks import stash_channel
     chan = stash_channel(scope.current(), "bottleneck")
     linear_in = wrapped_linear if chan is None \
         else functools.partial(replay_stashed_linear, chan=chan)
@@ -419,3 +421,20 @@ def bottleneck_group_linear(args: BlockArgs) -> NamedTensor:
     args.name_extras.extend(["group", "mid:group", "out:group"])
     args = args(activated_linear(args, "mid:"))
     return activated_linear_out(args)
+
+
+def _bottleneck_offer(params, extras) -> typing.Optional[Offer]:
+    """The in-projection outputs ``[batch, sequence, intermediate]`` the
+    layer pushes: every ``in:`` linear (one, plus the glu branches; an expert
+    in-projection is not a plain linear and is left alone)."""
+    if "in:mixture_of_experts" in extras:
+        return None
+    glu_add = "in:glu_add" in extras
+    sites = 1 + ("in:glu" in extras or glu_add) + glu_add
+    out_dims = [params.batch_dim, params.sequence_dim, *params.intermediate]
+    return Offer("bottleneck", (),
+                 sites * math.prod(d.size for d in out_dims)
+                 * jnp.dtype(params.calculation_dtype).itemsize, count=sites)
+
+
+bottleneck_group_linear.declares = Layer(offer=_bottleneck_offer)

@@ -71,7 +71,6 @@ class ReplayBlock:
         outer_prefill = None
         outer_sink = None
         outer_quant = None
-        outer_acc = None
         if scope.in_context():
             outer_rng = scope.current().rng_key
             outer_mesh = scope.current().mesh
@@ -79,7 +78,6 @@ class ReplayBlock:
             outer_prefill = scope.current().prefill
             outer_sink = scope.current().stats_sink
             outer_quant = getattr(scope.current(), "quant_scales", None)
-            outer_acc = getattr(scope.current(), "matmul_accumulation", None)
         ctx = scope.Context("apply", params=subset, rng_key=None,
                             mesh=outer_mesh, decode=outer_decode)
         ctx.prefill = outer_prefill
@@ -89,7 +87,6 @@ class ReplayBlock:
         # scan/decode/prefill paths, i.e. every real serving path) would
         # consume raw -127..127 integers
         ctx.quant_scales = outer_quant
-        ctx.matmul_accumulation = outer_acc
         # the replay stash channel (collect/provide, below), handed EXPLICITLY
         # by the strategy code — never inherited from the outer context, so
         # a mode can't leak across custom_vjp replay boundaries
@@ -189,36 +186,6 @@ def _name_chan(params: ModelParameter, mesh):
 
 def _chan_items(chan):
     return tuple(chan["items"]) if chan is not None else ()
-
-
-def stash_channel(ctx, kind: str) -> typing.Optional[dict]:
-    """The scope context's channel if it carries ``kind``, else None —
-    how a layer asks whether its output rides the residuals."""
-    chan = getattr(ctx, "replay_stash", None)
-    return chan if chan is not None and kind in chan["kinds"] else None
-
-
-def stash_push(chan, item) -> None:
-    """Consumer-side half of the stash-channel contract (collect mode) —
-    the single definition every consumer shares (flash and ring attention,
-    the bottleneck in-projection)."""
-    chan["items"].append(item)
-
-
-def stash_pop(chan):
-    """Consumer-side half of the stash-channel contract (provide mode):
-    the next item in push order."""
-    item = chan["items"][chan["i"]]
-    chan["i"] += 1
-    return item
-
-
-def stash_collecting(chan) -> bool:
-    return chan is not None and chan["mode"] == "collect"
-
-
-def stash_naming(chan) -> bool:
-    return chan is not None and chan["mode"] == "name"
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 4))

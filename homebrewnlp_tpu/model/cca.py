@@ -53,8 +53,9 @@ from ..core.tensor import NamedTensor, nt, transpose_to
 from . import decode as decode_mod
 from .backend import ConstantInit, NormalInit, normal_var
 from .recurrent import _small_var, causal_depthwise_conv
-from .spatial import (causal_heads, numbered_flags, project, rotary,
-                      rotary_width)
+from .declare import Layer, Stat
+from .spatial import (causal_heads, flash_offer, numbered_flags, project,
+                      rotary, rotary_width)
 
 _NUMBERED = ("q_heads", "kv_heads", "rotary_pct", "theta")
 #: added to a head's sum of squares before the root: ``F.normalize``'s
@@ -198,11 +199,15 @@ def cca(args: BlockArgs) -> NamedTensor:
             stddev=params.residual_out_stddev or 0.02)
 
 
-def _declared_flash(params, extras):
-    """Set as ``cca.flash`` (model/remat.py's attention kind under
-    ``checkpoint``; model/spatial.py ``attention.flash``): the one causal
-    call over ``q_heads`` latent heads, no window."""
-    return numbered_flags(extras, (), _NUMBERED, "layer cca")["q_heads"], None
+def _offer(params, extras):
+    """The one causal flash call over ``q_heads`` latent heads, no window."""
+    return flash_offer(
+        params, numbered_flags(extras, (), _NUMBERED, "layer cca")["q_heads"])
 
 
-cca.flash = _declared_flash
+cca.declares = Layer(
+    stats=(Stat("cca_logit_scale_max", "gauge", "hbnlp_cca_logit_scale_max",
+                "largest sqrt(features_per_head) * |tau| over the cca layers "
+                "of the newest finished step: q and k have unit direction, so "
+                "no attention logit passes it", "max", "cca_logit_scale"),),
+    offer=_offer)

@@ -98,8 +98,9 @@ from ..parallel.delta_solve import (inverse_unit_lower, inverse_unit_lower_bwd,
 from .backend import ConstantInit, UniformInit, normal_var
 from .loss import _matmul
 from .normalization import _norm_core
-from .recurrent import (Recurrent, _inverse_softplus_of_exp, _small_var,
-                        causal_depthwise_conv, token_layout)
+from .declare import Layer, Offer, Stat
+from .recurrent import (FACTS, Recurrent, _inverse_softplus_of_exp,
+                        _small_var, causal_depthwise_conv, token_layout)
 from .utils import anonymize_dim
 
 L2_EPS = 1e-6
@@ -392,12 +393,13 @@ def _conv(params: ModelParameter):
             params.delta_conv_size, 0)
 
 
-def _output_bytes(params: ModelParameter) -> int:
+def _offer(params: ModelParameter, extras) -> Offer:
     """The rule's output ``[batch, sequence, delta_heads,
     delta_value_features]`` in the calculation dtype: ``SAVED_NAMES``."""
-    return params.batch_dim.size * params.sequence_dim.size \
-        * params.delta_heads * params.delta_value_features \
-        * jnp.dtype(params.calculation_dtype).itemsize
+    return Offer("recurrent", SAVED_NAMES,
+                 params.batch_dim.size * params.sequence_dim.size
+                 * params.delta_heads * params.delta_value_features
+                 * jnp.dtype(params.calculation_dtype).itemsize)
 
 
 def _solve(params: ModelParameter):
@@ -409,5 +411,12 @@ def _solve(params: ModelParameter):
         * _group_heads(bsz, s, params.delta_heads, chunk)
 
 
-gated_delta.recurrent = Recurrent(_state_bytes, _conv, SAVED_NAMES,
-                                  _output_bytes, _solve)
+gated_delta.declares = Layer(
+    stats=(Stat("delta_transform_abs_max", "gauge",
+                "hbnlp_delta_transform_abs_max",
+                "largest magnitude in any chunk's solved transform T = (I + "
+                "strict_tril(diag(beta) (K K^T o Gamma)))^-1 diag(beta) of "
+                "the newest finished step, all gated_delta layers: what its "
+                "lower-precision matmul operands have to carry", "max"),),
+    offer=_offer, facts=FACTS,
+    recurrent=Recurrent(_state_bytes, _conv, _solve))

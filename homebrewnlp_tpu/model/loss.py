@@ -25,7 +25,6 @@ import typing
 import jax
 import jax.numpy as jnp
 
-from ..core import scope
 
 #: float32 bytes of one chunk's logits the walk aims to stay under.  Every
 #: chunk adds its part to the head's whole float32 gradient (412 MB at 2048 x
@@ -49,10 +48,7 @@ def _matmul(spec: str, a, b):
     accumulation for bfloat16 operands where the backend has it."""
     prefer = None
     if a.dtype == jnp.bfloat16 and jax.default_backend() != "cpu":
-        policy = (getattr(scope.current(), "matmul_accumulation", None)
-                  if scope.in_context() else None)
-        if policy != "bf16":
-            prefer = jnp.float32
+        prefer = jnp.float32
     return jnp.einsum(spec, a, b, preferred_element_type=prefer)
 
 

@@ -52,8 +52,9 @@ from ..parallel.causal_conv import causal_conv_silu, kernel_applies
 from .backend import ConstantInit, UniformInit, normal_var
 from .loss import _matmul
 from .normalization import _norm_core
-from .recurrent import (Recurrent, _inverse_softplus_of_exp, _small_var,
-                        causal_depthwise_conv, token_layout)
+from .declare import Layer, Stat
+from .recurrent import (FACTS, Recurrent, _inverse_softplus_of_exp,
+                        _small_var, causal_depthwise_conv, token_layout)
 from .utils import anonymize_dim
 
 
@@ -192,4 +193,9 @@ def _conv(params: ModelParameter):
     return inner + 2 * params.mamba_state, params.mamba_conv_size, inner
 
 
-mamba.recurrent = Recurrent(_state_bytes, _conv)
+mamba.declares = Layer(
+    stats=(Stat("ssd_log_decay_min", "gauge", "hbnlp_ssd_log_decay_min",
+                "most negative within-chunk cumulative dt * A of the newest "
+                "finished step, all mamba layers: exp of it is the smallest "
+                "decay the chunked scan formed", "min"),),
+    facts=FACTS, recurrent=Recurrent(_state_bytes, _conv))

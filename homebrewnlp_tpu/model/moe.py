@@ -60,6 +60,7 @@ from ..core.tensor import NamedTensor, nt, transpose_to
 from .activation import ACTIVATIONS
 from .backend import ConstantInit, NormalInit, normal_var
 from .basic import _router_aux_inject
+from .declare import Fact, Layer, Offer, Stat, layers
 from .recurrent import _small_var
 from .utils import anonymize_dim
 
@@ -428,3 +429,102 @@ def moe(args: BlockArgs) -> NamedTensor:
             out = out + _shared_expert(args, act, xf, anon, inter, feats)
     out = out.reshape([d.size for d in token_dims + feats])
     return transpose_to(nt(out, token_dims + feats), x.dims)
+
+
+def moe_held_rows(params) -> int:
+    """Rows of the static dispatch buffer of a ``moe`` layer that holds a
+    share of the experts (``held_rows_bound``) for one micro batch; 0 where
+    no layer holds a share."""
+    if not 0 < params.experts_held < params.expert_dim.size \
+            or not any(spec is moe.declares for _, _, spec in layers(params)):
+        return 0
+    return held_rows_bound(
+        params.batch_dim.size * params.sequence_dim.size,
+        min(params.moe_top_k, params.expert_dim.size), params.experts_held)
+
+
+def router_carry_bytes(params) -> int:
+    """Bytes of the router states alive between blocks for the backward: one
+    float32 ``[batch, sequence, moe_router_width]`` for every ``moe`` layer
+    with flag ``router_mlp`` that hands its state to a later one (all but
+    the last).  It passes the blocks in between unchanged, so it is held
+    once however many regions it crosses.  0 where no layer carries one."""
+    carrying = sum(spec is moe.declares and "router_mlp" in extras
+                   for _, extras, spec in layers(params)) * params.depth
+    return max(0, carrying - 1) * params.batch_dim.size \
+        * params.sequence_dim.size * params.moe_router_width * 4 \
+        * max(1, params.macro_batching)
+
+
+def _offer(params, extras) -> Offer:
+    """The experts kind, a layer: the three grouped matmuls' outputs — gate
+    and up ``[pairs, intermediate]``, down ``[pairs, features]``, in the
+    calculation dtype — the routing triple (``order`` and ``inverse``
+    ``[pairs]``, ``sizes`` ``[experts]``, int32) and the router's choice
+    (``experts`` ``[tokens, moe_top_k]``, int32), ``pairs = tokens x
+    min(moe_top_k, experts)``: ``SAVED_NAMES``.  A layer that holds a share
+    of the experts saves its whole static buffer: ``moe_held_rows`` rows,
+    ``experts_held + 1`` sizes."""
+    held_rows = moe_held_rows(params)
+    choices = params.batch_dim.size * params.sequence_dim.size \
+        * min(params.moe_top_k, params.expert_dim.size)
+    pairs = held_rows or choices
+    width = 2 * math.prod(d.size for d in params.expert_intermediate) \
+        + math.prod(d.size for d in params.feature_dims)
+    groups = params.experts_held + 1 if held_rows else params.expert_dim.size
+    return Offer("experts", SAVED_NAMES,
+                 pairs * width * jnp.dtype(params.calculation_dtype).itemsize
+                 + (2 * pairs + groups + choices) * 4)
+
+
+moe.declares = Layer(
+    stats=(
+        Stat("moe_load_max_over_mean", "gauge",
+             "hbnlp_moe_load_max_over_mean",
+             "pairs of the busiest expert over the mean, worst moe layer of "
+             "the newest finished step", "max"),
+        Stat("moe_routed_pairs", "counter", "hbnlp_moe_routed_pairs_total",
+             "(token, choice) pairs routed to an expert, all moe layers",
+             "sum"),
+        # layers that hold a share of the experts: the pairs routed to the
+        # held ones, and their share of the pairs routed, over all such
+        # layers and in the layer where it is largest
+        Stat("moe_held_pairs", "counter", "hbnlp_moe_held_pairs_total",
+             "(token, choice) pairs routed to an expert this rank holds, all "
+             "moe layers that hold a share of the experts", "sum"),
+        Stat("moe_held_pair_share", "gauge", "hbnlp_moe_held_pair_share",
+             "pairs routed to held experts over pairs routed, all moe layers "
+             "of the newest finished step (experts_held / experts when "
+             "balanced)",
+             lambda stats, done: done["moe_held_pairs"]
+             / done["moe_routed_pairs"], "moe_held_pairs"),
+        Stat("moe_held_pair_share_max", "gauge",
+             "hbnlp_moe_held_pair_share_max",
+             "the same share in the moe layer where it is largest: how far "
+             "the static row buffer (hbnlp_moe_held_rows_bound) is filled is "
+             "this times moe_top_k / min(moe_top_k, experts_held)",
+             lambda stats, done: jnp.max(stats["moe_held_pairs"]
+                                         / stats["moe_routed_pairs"]),
+             "moe_held_pairs"),
+        # the layer whose router says least
+        Stat("moe_top1_weight_mean", "gauge", "hbnlp_moe_top1_weight_mean",
+             "mean probability of the chosen expert over the tokens of the "
+             "newest finished step, in the top-1 moe layer where it is "
+             "smallest (1 / experts = a router that says nothing)", "min"),
+    ),
+    offer=_offer,
+    facts=(
+        Fact(40, "hbnlp_moe_held_rows_bound",
+             "rows of the static dispatch buffer of a moe layer that holds a "
+             "share of the experts: tokens x min(moe_top_k, experts_held), "
+             "which no routing overflows",
+             lambda params, mesh, backend: moe_held_rows(params) or None,
+             "moe held rows bound {}", zero=False),
+        Fact(50, "hbnlp_router_carry_bytes",
+             "bytes of the router states (layer moe, router_mlp) alive "
+             "between blocks for the backward: the carried side value, "
+             "float32 [batch, sequence, moe_router_width] a carrying layer "
+             "but the last",
+             lambda params, mesh, backend: router_carry_bytes(params) or None,
+             "router carry {} bytes", zero=False),
+    ))

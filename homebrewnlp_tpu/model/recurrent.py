@@ -1,8 +1,9 @@
 """What the recurrent mixers (layers ``mamba`` and ``gated_delta``) share:
 what they refuse and the layout they take, the per-channel float32
-parameters and their initialisers, the causal depthwise conv's XLA form, and what a layer declares of itself for
-``model/remat.py`` (its chunk states, its conv, the output it offers to save
-across the block's replay, the triangular systems it solves)."""
+parameters and their initialisers, the causal depthwise conv's XLA form, what
+a layer declares of its recurrence (:class:`Recurrent`) and the start-up
+facts made of it (:data:`FACTS`: the chunk states alive for the backward, the
+layers whose conv and whose triangular solve are the Pallas pairs)."""
 from __future__ import annotations
 
 import typing
@@ -13,38 +14,108 @@ import numpy as np
 
 from ..config import BlockArgs, ModelParameter
 from ..core import scope
+from ..core.sharding import shard_geometry
+from ..parallel.causal_conv import kernel_applies
+from ..parallel.delta_solve import solve_kernel_applies
+from .declare import Fact, layers
 
 
 class Recurrent(typing.NamedTuple):
-    """Set as ``<layer function>.recurrent``: ``state_bytes(params)`` — the
-    bytes of chunk states one layer keeps for its backward, for the whole
-    batch — and ``conv(params)`` — ``(channels, taps, offset)`` of its causal
-    depthwise conv, as ``parallel/causal_conv.kernel_applies`` takes them.
-
-    A layer that re-materialises its own interior in the backward also
-    OFFERS ITS OUTPUT to the ``checkpoint`` strategy (``model/remat.py``'s
-    ``recurrent`` kind): ``saved_names`` — what it tags with
-    ``jax.ad_checkpoint.checkpoint_name`` — and ``saved_bytes(params)`` —
-    their bytes for the whole batch.  Where the block's ``jax.checkpoint``
-    saves them, the replay runs no forward of the recurrence: everything its
-    backward needs the layer's own ``jax.checkpoint`` makes again from the
-    recurrence's inputs.  ``gated_delta`` offers the rule's output (``batch x
-    sequence x delta_heads x delta_value_features`` in the calculation
-    dtype).  ``mamba`` offers nothing: its scan has no inner
-    ``jax.checkpoint``, so the replay's forward IS the pass that makes the
-    backward's residuals, and a saved output would skip none of it.
-
-    A layer that solves a unit triangular system a chunk declares it:
+    """Set as ``<layer function>.declares.recurrent``: ``state_bytes(params)``
+    — the bytes of chunk states one layer keeps for its backward, for the
+    whole batch — and ``conv(params)`` — ``(channels, taps, offset)`` of its
+    causal depthwise conv, as ``parallel/causal_conv.kernel_applies`` takes
+    them.  A layer that solves a unit triangular system a chunk declares it:
     ``solve(params)`` — ``(chunk, matrices a call)`` as
     ``parallel/delta_solve.solve_kernel_applies`` takes them
-    (``gated_delta``: the systems of one group of heads); None = none."""
+    (``gated_delta``: the systems of one group of heads); None = none.
+
+    A layer that re-materialises its own interior in the backward also OFFERS
+    ITS OUTPUT to the ``checkpoint`` strategy (its ``declares.offer``, kind
+    ``recurrent``): where the block's ``jax.checkpoint`` saves it, the replay
+    runs no forward of the recurrence.  ``mamba`` offers nothing: its scan
+    has no inner ``jax.checkpoint``, so the replay's forward IS the pass that
+    makes the backward's residuals, and a saved output would skip none of
+    it."""
     state_bytes: typing.Callable[[ModelParameter], int]
     conv: typing.Callable[[ModelParameter], typing.Tuple[int, int, int]]
-    saved_names: typing.Tuple[str, ...] = ()
-    saved_bytes: typing.Optional[
-        typing.Callable[[ModelParameter], int]] = None
     solve: typing.Optional[
         typing.Callable[[ModelParameter], typing.Tuple[int, int]]] = None
+
+
+def recurrent_layers(params: ModelParameter) -> typing.List[Recurrent]:
+    """What each recurrent mixer of one depth unit declares of its
+    recurrence, in execution order."""
+    return [spec.recurrent for _, _, spec in layers(params)
+            if spec.recurrent is not None]
+
+
+def ssd_state_bytes(params: ModelParameter, mesh=None) -> int:
+    """Per-device bytes of the recurrent mixers' chunk states — what a layer
+    declares (``mamba``: ``[batch, sequence / mamba_chunk, mamba_heads,
+    mamba_head_features, mamba_state]`` float32, ``gated_delta``: ``[batch,
+    sequence / delta_chunk, delta_heads, delta_value_features,
+    delta_key_features]`` in the calculation dtype), what the inter-chunk
+    scan's backward reads — that are alive at once for the backward: ONE
+    layer's (the largest) under ``checkpoint`` / ``revnet`` / ``momentum``
+    (no policy saves them across the forward; the block's replay makes them
+    again and drops them with the block), every layer's under ``none``.  0
+    without such a layer."""
+    sizes = [spec.state_bytes(params) for spec in recurrent_layers(params)]
+    if not sizes:
+        return 0
+    shards, _ = shard_geometry(mesh)
+    alive = sum(sizes) * params.depth \
+        if params.memory_reduction_strategy == "none" else max(sizes)
+    return -(-alive // shards)
+
+
+def conv_kernel_layers(params: ModelParameter, backend=None) -> int:
+    """How many recurrent mixers of the step take the Pallas conv kernel
+    pair (``parallel/causal_conv.py``), by the predicate the layers
+    themselves call on the conv each declares."""
+    count = 0
+    for spec in recurrent_layers(params):
+        channels, taps, offset = spec.conv(params)
+        count += kernel_applies(channels, params.sequence_dim.size, taps,
+                                offset, backend)
+    return count * params.depth
+
+
+def solve_kernel_layers(params: ModelParameter, backend=None
+                        ) -> typing.Optional[int]:
+    """How many recurrent mixers of the step take the Pallas pair for their
+    triangular solve (``parallel/delta_solve.py``), by the predicate the
+    layer itself calls on the systems it declares; None where no layer
+    declares a solve."""
+    solves = [spec.solve(params) for spec in recurrent_layers(params)
+              if spec.solve is not None]
+    if not solves:
+        return None
+    return params.depth * sum(solve_kernel_applies(chunk, matrices, backend)
+                              for chunk, matrices in solves)
+
+
+#: every recurrent mixer's ``declares.facts``
+FACTS = (
+    Fact(10, "hbnlp_ssd_state_bytes",
+         "per-device bytes of the recurrent mixers' (mamba, gated_delta) "
+         "chunk states alive at once for the backward",
+         lambda params, mesh, backend: ssd_state_bytes(params, mesh) or None,
+         "ssd chunk states {} bytes a device"),
+    Fact(20, "hbnlp_mamba_conv_kernel_layers",
+         "recurrent mixers (mamba, gated_delta) of the built step whose conv "
+         "is the Pallas kernel pair (0 on the XLA fallback)",
+         lambda params, mesh, backend: conv_kernel_layers(params, backend)
+         if recurrent_layers(params) else None,
+         "conv kernel {} layers"),
+    Fact(30, "hbnlp_delta_solve_kernel_layers",
+         "gated_delta layers of the built step whose triangular solve is the "
+         "Pallas kernel pair (0 on the XLA blocked form, and without such a "
+         "layer)",
+         lambda params, mesh, backend: solve_kernel_layers(params, backend),
+         "solve kernel {} layers"),
+)
 
 
 def token_layout(args: BlockArgs, layer: str, chunk: int):

@@ -12,14 +12,20 @@ from __future__ import annotations
 
 import typing
 
+import jax
+import numpy as np
+
 from ..config import BlockArgs
 from ..core.dims import Dim, shape_sub
 from ..core import sharding as shardlib
+from ..core.stash import stash_channel
 from ..core.tensor import (NamedTensor, cumsum as tensor_cumsum, einsum, exp,
                            less, multiply, range_, reduce_max, reduce_sum,
                            stop_gradient, greater_equal)
+from ..parallel.flash_attention import SAVED_NAMES, band_applies
 from . import decode as decode_mod
 from .basic import activated_linear_in, activated_linear_out
+from .declare import Fact, Layer, Offer, step_offers
 from .embedding import embed
 from .utils import (anonymize, compare_range, get_attention_dim,
                     is_masked, linear_shapes)
@@ -122,7 +128,6 @@ def _maybe_ring_attention(args: BlockArgs, dim: Dim, qry: NamedTensor,
     # stash: the strategy machinery's replay stash channel
     # (model/blocks.py) — the zigzag ring collects/provides (out, lse) so
     # the strategy backward's recompute skips the whole ring
-    from .blocks import stash_channel
     out = ring_attention(q, k, v, mesh, causal=True,
                          scale=1.0,  # qry already carries the reference scale
                          stash=stash_channel(ctx, "attention"))
@@ -190,7 +195,6 @@ def _flash(ctx, q, k, v, scale: float, window=None):
         # collect / provide under revnet and momentum, "name" under
         # checkpoint) — single-device path only; the shard_map branch keeps
         # the plain kernel
-        from .blocks import stash_channel
         return flash(q, k, v, scale=scale, causal=True,
                      stash=stash_channel(ctx, "attention"), window=window)
     from jax.sharding import PartitionSpec as P
@@ -621,22 +625,70 @@ def _standard_attention(args: BlockArgs) -> NamedTensor:
         feats, q_feats)
 
 
-def _declared_flash(params, extras
-                    ) -> typing.Optional[typing.Tuple[int, typing.Optional[int]]]:
-    """Set as ``attention.flash``, what ``model/remat.py`` sizes the attention
-    kind from under ``checkpoint``: ``(query heads, window)`` of the call
-    this layer makes through ``_flash`` under training — the standard
-    attention its own head count (``q_heads<n>``, else the stream's) and its
-    ``window<w>``, the generic one the stream's heads where it has a key and
-    no flag that keeps the dense map — or None where it makes none."""
+def flash_offer(params, heads: int, window: typing.Optional[int] = None
+                ) -> Offer:
+    """What one flash call over ``heads`` query heads offers the attention
+    kind: ``out`` ``[batch, sequence, heads, features_per_head]`` in the
+    calculation dtype and ``lse`` ``[batch x heads, sequence]`` float32, and
+    the keys a query sees."""
+    seq = params.sequence_dim.size
+    return Offer("attention", SAVED_NAMES,
+                 heads * params.batch_dim.size * seq
+                 * (params.key_dim.size
+                    * np.dtype(params.calculation_dtype).itemsize + 4),
+                 keys=min(seq, window or seq))
+
+
+def _offer(params, extras) -> typing.Optional[Offer]:
+    """The call this layer makes through ``_flash`` under training — the
+    standard attention its own head count (``q_heads<n>``, else the
+    stream's) and its ``window<w>``, the generic one the stream's heads where
+    it has a key and no flag that keeps the dense map — or None where it
+    makes none."""
     if any(f in extras for f in _STANDARD_POSITION):
         flags = _standard_flags(extras)
-        return flags.get("q_heads", params.head_dim.size), flags.get("window")
+        return flash_offer(params, flags.get("q_heads", params.head_dim.size),
+                           flags.get("window"))
     if "dot_product" not in extras or any(f in extras for f in _DENSE_ONLY) \
             or not any(f in extras for f in ("embedded", "context",
                                               "positional")):
         return None
-    return params.head_dim.size, None
+    return flash_offer(params, params.head_dim.size)
+
+
+def flash_band_layers(params, backend=None) -> typing.Optional[int]:
+    """How many attention layers of the step run their windowed flash
+    FORWARD as the band kernel (``parallel/flash_attention.py _fwd_band``):
+    of the layers that offer a flash call with a window shorter than the
+    sequence, those the predicate ``attention`` itself calls admits, where
+    that call reaches the kernels at all (``use_flash_attention``, off the
+    CPU, a sequence of whole 128-tiles); None where no layer declares such a
+    window."""
+    seq = params.sequence_dim.size
+    windows = []
+    for offer, times in step_offers(params, "attention"):
+        if offer.keys < seq:
+            windows += [offer.keys] * times
+    if not windows:
+        return None
+    if backend is None:
+        backend = jax.default_backend()
+    if backend == "cpu" or not params.use_flash_attention or seq % 128:
+        return 0
+    itemsize = np.dtype(params.calculation_dtype).itemsize
+    return sum(band_applies(seq, params.key_dim.size, window, itemsize)
+               for window in windows)
+
+
+#: layer ``attention``'s ``declares.facts``
+FACTS = (
+    Fact(60, "hbnlp_flash_band_layers",
+         "attention layers of the built step whose windowed flash forward is "
+         "the band kernel (0 on the tiled forward, on the CPU and without a "
+         "windowed layer)",
+         lambda params, mesh, backend: flash_band_layers(params, backend),
+         "flash band {} layers"),
+)
 
 
 def attention(args: BlockArgs) -> NamedTensor:
@@ -698,4 +750,4 @@ def attention(args: BlockArgs) -> NamedTensor:
     return einsum([logit, val], shape)
 
 
-attention.flash = _declared_flash
+attention.declares = Layer(offer=_offer, facts=FACTS)

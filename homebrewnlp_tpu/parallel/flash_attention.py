@@ -43,6 +43,9 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from ..core.stash import (stash_collecting, stash_naming, stash_pop,
+                          stash_push)
+
 _NEG_INF = -1e30
 
 #: the names ``attention`` gives a call's ``out`` ``[b, s, heads, d]`` and
@@ -411,7 +414,7 @@ def band_applies(s: int, d: int, window: int, itemsize: int) -> bool:
     budget.  A window (or a sequence) too long for that keeps the tiled
     forward (``_fwd_flat``).  Pure in its arguments: the one predicate
     ``_flash_fwd_impl``, ``attention`` and the ``hbnlp_flash_band_layers``
-    gauge (model/remat.py) read."""
+    gauge (model/spatial.py) read."""
     block_q = band_block(s)
     sub, _, span = _band_geometry(s, window, block_q)
     resident = 2 * 2 * s * d * itemsize
@@ -1032,9 +1035,6 @@ def attention(q, k, v, scale: typing.Optional[float] = None,
     keys = s if window is None else window
     if stash is not None and s % 128 == 0 \
             and keys >= stash.get("min_keys", 0):
-        from ..model.blocks import (stash_collecting, stash_naming,
-                                    stash_pop, stash_push)
-
         def forward(q, k, v):
             if on_tpu:
                 with jax.named_scope("flash_attention"):

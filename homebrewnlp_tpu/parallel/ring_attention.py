@@ -60,6 +60,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from jax import shard_map
 
+from ..core.stash import stash_collecting, stash_pop, stash_push
+
 _NEG_INF = -1e30
 
 
@@ -656,9 +658,6 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, mesh: Mesh,
                     _to_zigzag(k, axis_name, n_shards),
                     _to_zigzag(v, axis_name, n_shards))
 
-        if stash is not None:
-            from ..model.blocks import (stash_collecting, stash_pop,
-                                        stash_push)
         if stash is not None and stash_collecting(stash):
             def zz_collect(q, k, v):
                 qz, kz, vz = to_zz3(q, k, v)

@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 from homebrewnlp_tpu.model import mamba as mamba_mod
-from homebrewnlp_tpu.model import remat
+from homebrewnlp_tpu.model import recurrent
 from homebrewnlp_tpu.parallel import causal_conv as cc
 
 from granite_test import _build
@@ -202,7 +202,7 @@ def declining_layer_traces_the_parents_ops_test(monkeypatch, tiles):
     """64 channels: with the backend steered to the TPU the layer still
     traces the shifted multiplies, the very jaxpr it traces here."""
     _, params, model, batch, variables = _build("bfloat16")
-    assert remat.conv_kernel_layers(params, "tpu") == 0
+    assert recurrent.conv_kernel_layers(params, "tpu") == 0
     trace = lambda: str(jax.make_jaxpr(  # noqa: E731
         lambda v: model.apply(v, batch).total_loss.data)(variables))
     plain = trace()
@@ -218,8 +218,8 @@ def granite_step_with_the_kernel_test(monkeypatch, tiles, dtype, tolerance):
     every gradient with the kernel pair equal the fallback's."""
     _, params, model, batch, variables = _build(dtype, **_KERNEL_SIZE)
     assert params.memory_reduction_strategy == "checkpoint"
-    assert remat.conv_kernel_layers(params, "tpu") == 9
-    assert remat.conv_kernel_layers(params) == 0
+    assert recurrent.conv_kernel_layers(params, "tpu") == 9
+    assert recurrent.conv_kernel_layers(params) == 0
     want_loss, want = _loss_and_grads(model, variables, batch)
     _steer(monkeypatch, tiles)
     text = str(jax.make_jaxpr(

@@ -1,12 +1,7 @@
 """Tier-1 lint: every ModelParameter knob has a docs/CONFIG.md row
-(scripts/check_config_docs.py — PRs 1-3 hand-maintained this invariant;
-now it is mechanical)."""
-import os
-import sys
-
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "scripts"))
-
-import check_config_docs as ccd  # noqa: E402
+(analysis/ast_lint.py's config-docs rule — PRs 1-3 hand-maintained this
+invariant; now it is mechanical)."""
+from homebrewnlp_tpu.analysis import ast_lint as ccd
 
 
 def config_docs_complete_test():

@@ -1,0 +1,264 @@
+"""The declaration seam (PR 42): a layer declares what it reports, offers and
+runs (``model/declare.py``); the trainer and the memory rule read
+declarations.  What the eight cells print and publish at start-up, and how
+each statistic folds, are WRITTEN DOWN HERE FROM THE PARENT (commit 357d03a,
+before the refactor): nothing below builds a model or compiles a step."""
+import glob
+import hashlib
+import json
+import os
+import types
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from homebrewnlp_tpu import telemetry
+from homebrewnlp_tpu.config import ModelParameter
+from homebrewnlp_tpu.core import sharding as shardlib
+from homebrewnlp_tpu.model import declare
+from homebrewnlp_tpu.train import _LAYER_STATS, Trainer, _info_metrics
+
+from remat_policy_test import _cell_params
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _kinds(attention=(0, 0), bottleneck=(0, 0), experts=(0, 0),
+           recurrent=(0, 0)):
+    plan = {"attention": attention, "bottleneck": bottleneck,
+            "experts": experts, "recurrent": recurrent}
+    return ("remat stash: " + "; ".join(
+        f"{kind} {layers} layers, {nbytes} bytes a device"
+        for kind, (layers, nbytes) in plan.items()), plan)
+
+
+#: cell -> (the kinds' part of the line and the plan, the rest of the line,
+#: the start-up gauges beside the stash's) on a TPU, from the parent
+_CELLS = {
+    "train_32big_mixer_b32": (_kinds(), "", {}),
+    "train_32big_mixer_dp2tp2": (
+        _kinds(bottleneck=(32, 2147483648)), "", {}),
+    "train_1b_long_context_s16k": (
+        _kinds(attention=(8, 2155872256)), "", {}),
+    "train_olmoe_1b_7b_s4k": (
+        _kinds(attention=(2, 68157440), experts=(2, 1075315200)), "", {}),
+    "train_granite_4_0_h_micro_long": (
+        _kinds(attention=(1, 34603008)),
+        "; ssd chunk states 67108864 bytes a device; conv kernel 9 layers",
+        {"hbnlp_ssd_state_bytes": 67108864,
+         "hbnlp_mamba_conv_kernel_layers": 9}),
+    "train_olmo_hybrid_7b_long": (
+        _kinds(attention=(1, 127795200), recurrent=(3, 566231040)),
+        "; ssd chunk states 94371840 bytes a device; conv kernel 3 layers; "
+        "solve kernel 3 layers",
+        {"hbnlp_ssd_state_bytes": 94371840,
+         "hbnlp_mamba_conv_kernel_layers": 3,
+         "hbnlp_delta_solve_kernel_layers": 3}),
+    "train_laguna_s_2_1_ep32_s8k": (
+        _kinds(), "; moe held rows bound 131072; flash band 3 layers",
+        {"hbnlp_moe_held_rows_bound": 131072, "hbnlp_flash_band_layers": 3}),
+    "train_zaya1_8b_ep2_s16k": (
+        _kinds(attention=(8, 272629760), experts=(8, 1612185888)),
+        "; moe held rows bound 16384; router carry 117440512 bytes",
+        {"hbnlp_moe_held_rows_bound": 16384,
+         "hbnlp_router_carry_bytes": 117440512}),
+}
+#: the facts that read 0 where no layer has the mechanism; the others have no
+#: series there
+_ALWAYS = ("hbnlp_ssd_state_bytes", "hbnlp_mamba_conv_kernel_layers",
+           "hbnlp_delta_solve_kernel_layers", "hbnlp_flash_band_layers")
+_SPARSE = ("hbnlp_moe_held_rows_bound", "hbnlp_router_carry_bytes")
+
+
+@pytest.fixture
+def fresh_registry():
+    prev = telemetry.set_registry(telemetry.Registry())
+    yield telemetry.registry()
+    telemetry.set_registry(prev)
+
+
+def _startup(params, mesh=None):
+    """``(the line, {metric: {labels: value}})`` of a trainer's start-up."""
+    line = Trainer(params, None, mesh).publish_stash_plan()
+    snap = telemetry.snapshot()
+    return line, {name: dict(entry["series"]) for name, entry in snap.items()}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELLS))
+def cell_startup_is_the_parents_test(cell, monkeypatch, fresh_registry):
+    """Each cell's ``remat stash:`` line, to the byte, and every start-up
+    series with its value, as a TPU process reads them."""
+    from benchmark.lib.cell import load_cell
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    params = _cell_params(cell)
+    mesh = None
+    if load_cell(cell).chips > 1:
+        if len(jax.devices()) < 4:
+            pytest.skip("needs 4 virtual devices")
+        mesh = shardlib.build_mesh(params, jax.devices()[:4])
+    (head, plan), tail, gauges = _CELLS[cell]
+    line, series = _startup(params, mesh)
+    assert line == head + tail
+    assert series.pop("hbnlp_remat_stash_layers") == {
+        (kind,): layers for kind, (layers, _) in plan.items()}
+    assert series.pop("hbnlp_remat_stash_bytes") == {
+        (kind,): nbytes for kind, (_, nbytes) in plan.items()}
+    want = {name: {(): 0} for name in _ALWAYS}
+    want.update({name: {(): value} for name, value in gauges.items()})
+    assert series == want
+
+
+def _config_files():
+    return sorted(glob.glob(os.path.join(REPO, "configs", "*.json"))
+                  + glob.glob(os.path.join(REPO, "benchmark", "configs",
+                                           "*.json")))
+
+
+#: sha1 of the line + the start-up series of every configuration file as it
+#: stands, TPU then CPU, from the parent
+_FILE_DIGEST = "14424d9e0db6655b911c55f5cf6f76f5b6505273"
+
+
+def every_configuration_file_starts_as_on_the_parent_test(monkeypatch):
+    """Every file under ``configs/`` and ``benchmark/configs/``: the same
+    line and the same series on a TPU and on the CPU (one digest; the cells'
+    own test above says which line moved)."""
+    real = jax.default_backend
+    seen = []
+    for path in _config_files():
+        with open(path) as f:
+            config = json.load(f)
+        config = config.get("config", config)
+        for backend in ("tpu", "cpu"):
+            monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+            prev = telemetry.set_registry(telemetry.Registry())
+            try:
+                line, series = _startup(ModelParameter(
+                    {**config, "model_path": "/tmp/declare_test"}))
+            finally:
+                telemetry.set_registry(prev)
+            seen.append([os.path.relpath(path, REPO), backend, line, sorted(
+                (name, sorted((list(k), v) for k, v in values.items()))
+                for name, values in series.items())])
+    monkeypatch.setattr(jax, "default_backend", real)
+    digest = hashlib.sha1(json.dumps(seen).encode()).hexdigest()
+    assert digest == _FILE_DIGEST, seen
+
+
+# ---- statistics ------------------------------------------------------------
+
+#: what three moe layers, two top-1 routers, two cca, two mamba and two
+#: gated_delta layers appended, merged as ``Model.apply`` merges them
+_LAYER_STATS_IN = {
+    "moe_load_max_over_mean": [1.5, 2.5, 1.25],
+    "moe_routed_pairs": [1024.0, 1024.0, 2048.0],
+    "moe_held_pairs": [256.0, 512.0, 256.0],
+    "moe_top1_weight_mean": [0.3, 0.2],
+    "cca_logit_scale": [3.0, 7.0],
+    "ssd_log_decay_min": [-3.0, -9.0],
+    "delta_transform_abs_max": [2.0, 11.0]}
+
+
+def _info(layer_stats):
+    zero = types.SimpleNamespace(data=jnp.float32(0))
+    return types.SimpleNamespace(
+        layer_stats=layer_stats and {k: jnp.asarray(v, jnp.float32)
+                                     for k, v in layer_stats.items()},
+        total_loss=zero, token_loss=None, video_loss=None, accuracy=None)
+
+
+@pytest.mark.parametrize("name,kind,metric,text,value", [
+    ("moe_load_max_over_mean", "gauge", "hbnlp_moe_load_max_over_mean",
+     "88106c01a6f0", 2.5),
+    ("moe_routed_pairs", "counter", "hbnlp_moe_routed_pairs_total",
+     "4f9956787e42", 4096.0),
+    ("moe_held_pairs", "counter", "hbnlp_moe_held_pairs_total",
+     "1894dc887eb0", 1024.0),
+    ("moe_held_pair_share", "gauge", "hbnlp_moe_held_pair_share",
+     "180250f2524a", 0.25),
+    ("moe_held_pair_share_max", "gauge", "hbnlp_moe_held_pair_share_max",
+     "4eb530aac659", 0.5),
+    ("moe_top1_weight_mean", "gauge", "hbnlp_moe_top1_weight_mean",
+     "8f9d99ebcdda", 0.20000000298023224),
+    ("cca_logit_scale_max", "gauge", "hbnlp_cca_logit_scale_max",
+     "926f7565e35d", 7.0),
+    ("ssd_log_decay_min", "gauge", "hbnlp_ssd_log_decay_min",
+     "c1d31e0cfa01", -9.0),
+    ("delta_transform_abs_max", "gauge", "hbnlp_delta_transform_abs_max",
+     "2dce3a99aa4b", 11.0)])
+def declared_statistic_folds_as_on_the_parent_test(name, kind, metric, text,
+                                                   value):
+    """One declared statistic: the parent's fold over the layers, its kind,
+    its metric name and (by digest) its help text."""
+    assert float(_info_metrics(_info(_LAYER_STATS_IN))[name]) == value
+    stat = _LAYER_STATS[name]
+    assert (stat.kind, stat.metric) == (kind, metric)
+    assert hashlib.sha1(stat.help.encode()).hexdigest()[:12] == text
+
+
+def statistics_are_all_declared_test():
+    """The trainer's table is the declarations': nine statistics, and a step
+    whose layers report nothing (or only some) has only those."""
+    assert len(_LAYER_STATS) == 9 == len(declare.stats())
+    base = {"loss", "token_loss", "video_loss", "accuracy"}
+    assert set(_info_metrics(_info(None))) == base
+    some = {"ssd_log_decay_min": [-1.0]}
+    assert set(_info_metrics(_info(some))) == base | {"ssd_log_decay_min"}
+    # a layer that holds no share reports no held pairs: no share either
+    routed = {k: _LAYER_STATS_IN[k]
+              for k in ("moe_load_max_over_mean", "moe_routed_pairs")}
+    assert set(_info_metrics(_info(routed))) == base | set(routed)
+
+
+def publish_layer_stats_reads_the_declarations_test(fresh_registry):
+    """``Trainer._publish_layer_stats``: gauges set, counters added, by the
+    declared names, from steps the device has finished."""
+    params = _cell_params("train_32big_mixer_b32")
+    trainer = Trainer(params, None)
+    metrics = _info_metrics(_info(_LAYER_STATS_IN))
+    trainer._publish_layer_stats(metrics)
+    trainer._publish_layer_stats(metrics)
+    snap = telemetry.snapshot()
+    assert snap["hbnlp_moe_routed_pairs_total"]["series"][()] == 2 * 4096.0
+    assert snap["hbnlp_moe_held_pair_share"]["series"][()] == 0.25
+    assert snap["hbnlp_cca_logit_scale_max"]["series"][()] == 7.0
+    assert not trainer._pending_layer_stats
+
+
+# ---- the registry of declarations -----------------------------------------
+
+def facts_are_declared_once_in_line_order_test():
+    facts = declare.facts()
+    assert [fact.metric for fact in facts] == [
+        "hbnlp_ssd_state_bytes", "hbnlp_mamba_conv_kernel_layers",
+        "hbnlp_delta_solve_kernel_layers", "hbnlp_moe_held_rows_bound",
+        "hbnlp_router_carry_bytes", "hbnlp_flash_band_layers"]
+    assert [fact.metric for fact in facts if fact.zero] == list(_ALWAYS)
+    assert [fact.metric for fact in facts if not fact.zero] == list(_SPARSE)
+    assert len({fact.place for fact in facts}) == len(facts)
+
+
+@pytest.mark.parametrize("layer,kind,names", [
+    ("moe", "experts", ("moe_gate", "moe_up", "moe_down", "moe_order",
+                        "moe_inverse", "moe_sizes", "moe_experts")),
+    ("gated_delta", "recurrent", ("gated_delta_out",)),
+    ("attention-nope", "attention", ("flash_out", "flash_lse")),
+    ("cca-q_heads8-kv_heads2", "attention", ("flash_out", "flash_lse")),
+    ("bottleneck_group_linear-in:relu", "bottleneck", ()),
+    ("mamba", None, None), ("norm-shift-scale", None, None),
+    ("attention-biased_attention_map-absolute-input_as_value", None, None),
+    ("bottleneck_group_linear-in:mixture_of_experts", None, None)])
+def layer_offers_its_kind_test(layer, kind, names):
+    """What a layer offers a memory strategy, by the layer's own
+    declaration: the kind, the names it tags."""
+    from homebrewnlp_tpu.model.frontend import LAYER_FUNCTIONS
+    params = _cell_params("train_olmo_hybrid_7b_long")
+    name, *extras = layer.split("-")
+    spec = getattr(LAYER_FUNCTIONS[name], "declares", declare.Layer())
+    offer = spec.offer(params, set(extras)) if spec.offer else None
+    if kind is None:
+        assert offer is None
+    else:
+        assert (offer.kind, offer.names) == (kind, names)
+        assert offer.nbytes > 0 and offer.count >= 1

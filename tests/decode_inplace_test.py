@@ -6,7 +6,7 @@ Three properties of the stepped (donated chunked) decode loop:
      full-forward reference sampler — the loop restructure must not change
      one sampled token;
   2. the COMPILED per-token step contains no full-KV-cache-shaped copy and
-     aliases every donated cache leaf input->output (infer/hlo_check.py) —
+     aliases every donated cache leaf input->output (analysis/hlo_lint.py) —
      the property whose loss made 32k decode cost 7.5x its read bound
      (BASELINE.md round 5); this asserts the fix at the artifact level, not
      the source level;
@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 
 from backend import MIXER_BLOCKS, make_params
-from homebrewnlp_tpu.infer import hlo_check
+from homebrewnlp_tpu.analysis import entry_points, hlo_lint
 from homebrewnlp_tpu.infer.sampler import (_sample_kv_stepped,
                                            decode_cache_bytes,
                                            init_decode_caches,
@@ -129,6 +129,34 @@ def sample_text_stepped_routing_test():
     assert _use_stepped_loop(model, variables, token_x)
 
 
+def _assert_no_full_cache_copy(hlo_text, cache_shapes, min_aliases=None):
+    """AssertionError if the compiled module contains a ``copy`` whose result
+    is exactly a full KV-cache buffer (the aliaser inserts such copies when
+    it cannot keep the carry update in place; the small recurrence caches
+    legitimately rewrite their whole buffer and are left out), or if fewer
+    than ``min_aliases`` input/output aliases were established.  Strict
+    (``max_copied_bytes=0``): ANY full-cache copy of live state is the
+    round-5 regression."""
+    targets = hlo_lint.shape_strings(cache_shapes, key_filter="/kv")
+    assert targets, f"no KV cache leaves in {list(cache_shapes)[:5]}"
+    findings = hlo_lint.big_copy_audit("decode_chunk_step", hlo_text,
+                                       targets, max_copied_bytes=0)
+    if min_aliases is not None:
+        findings = findings + hlo_lint.donation_audit(
+            "decode_chunk_step", hlo_text, min_aliases)
+    assert not findings, "\n".join(str(f) for f in findings)
+
+
+def _assert_decode_step_inplace(model, variables, token_x,
+                                logits_filter=False):
+    """The per-token decode step's compiled module keeps every cache update
+    in place (no full-cache copy, caches all aliased)."""
+    hlo, ctx = entry_points.lower_decode_step(model, variables, token_x,
+                                              logits_filter=logits_filter)
+    _assert_no_full_cache_copy(hlo, ctx["cache_shapes"],
+                               min_aliases=ctx["donated_leaves"])
+
+
 def decode_step_inplace_hlo_test():
     """The compiled donated step: no full-cache-shaped copy, every cache
     leaf aliased input->output.  Revnet is the flagship strategy (the
@@ -137,8 +165,7 @@ def decode_step_inplace_hlo_test():
     dtypes are covered at one compile each."""
     _, model, variables, token_x = _build(
         {"block_config": MIXER_BLOCKS, "memory_reduction_strategy": "revnet"})
-    hlo_check.assert_decode_step_inplace(model, variables,
-                                         jnp.asarray(token_x))
+    _assert_decode_step_inplace(model, variables, jnp.asarray(token_x))
 
 
 def decode_step_int8_inplace_hlo_test():
@@ -148,8 +175,7 @@ def decode_step_int8_inplace_hlo_test():
     _, model, variables, token_x = _build(
         {"block_config": MIXER_BLOCKS, "memory_reduction_strategy": "revnet",
          "decode_cache_dtype": "int8"})
-    hlo_check.assert_decode_step_inplace(model, variables,
-                                         jnp.asarray(token_x))
+    _assert_decode_step_inplace(model, variables, jnp.asarray(token_x))
 
 
 def decode_step_filter_inplace_hlo_test():
@@ -157,9 +183,8 @@ def decode_step_filter_inplace_hlo_test():
     cache aliasing property."""
     _, model, variables, token_x = _build(
         {"block_config": MIXER_BLOCKS, "memory_reduction_strategy": "none"})
-    hlo_check.assert_decode_step_inplace(model, variables,
-                                         jnp.asarray(token_x),
-                                         logits_filter=True)
+    _assert_decode_step_inplace(model, variables, jnp.asarray(token_x),
+                                logits_filter=True)
 
 
 def hlo_checker_detects_full_cache_copy_test():
@@ -174,9 +199,9 @@ def hlo_checker_detects_full_cache_copy_test():
     ok = ("%copy.9 = f32[4,16,2,16]{3,2,1,0} "
           "copy(f32[4,16,2,16]{2,0,3,1} %transpose.1)")
     with pytest.raises(AssertionError, match="NOT aliased"):
-        hlo_check.assert_no_full_cache_copy(bad, shapes)
-    hlo_check.assert_no_full_cache_copy(ok, shapes)
-    assert hlo_check.input_output_alias_count(
+        _assert_no_full_cache_copy(bad, shapes)
+    _assert_no_full_cache_copy(ok, shapes)
+    assert hlo_lint.input_output_alias_count(
         "input_output_alias={ {0}: (31, {}, may-alias), "
         "{1}: (32, {}, may-alias) }") == 2
 

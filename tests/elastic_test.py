@@ -1,16 +1,12 @@
 """Elastic pod training tests (marker ``elastic``; docs/DISTRIBUTED.md
 'Elasticity', ROADMAP item 5 / ISSUE 14).
 
-Three tiers:
+Two tiers:
 
 - **Agent state machine** (device-free, injected KV/clock): lease lapse
   detection, missing-peer startup grace, coordinator loss, the
   grace-then-force-exit path with the pre-exit hook, exit-code
   classification, and the controller's jax-free checkpoint probe.
-- **Gradient all-reduce policy** (8 virtual devices): bucket-plan shape
-  (reverse-topological, size-targeted, dtype-homogeneous), eligibility
-  gates, loud fused fallback, and — marked slow — the fused-vs-bucketed
-  loss tolerance on a real data-parallel step.
 - **Controller e2e** (marked slow; real ``run_manager.py --elastic``
   subprocess fleets): SIGKILL one of 4 ranks mid-training → the survivors
   re-form at world size 3 from the freshest complete checkpoint with no
@@ -190,139 +186,6 @@ def latest_complete_step_test(tmp_path):
     for name in ("ckpt_5", "ckpt_12", "ckpt_40.tmp", "elastic", "pids"):
         os.makedirs(tmp_path / name)
     assert latest_complete_step(str(tmp_path)) == 12
-
-
-# ---- gradient all-reduce policy --------------------------------------------
-
-def _ga_cfg(model_path, **over):
-    cfg = {"model_mode": "gpt", "use_video": False, "use_language": True,
-           "sequence_length": 32, "features_per_head": 16, "heads": 8,
-           "depth": 1, "train_batch_size": 8, "vocab_size": 32,
-           "tpu_size": 8,
-           "block_config": [{"layer": ["norm-shift-scale-features-group",
-                                       "feed_forward-in:relu"]}],
-           "memory_reduction_strategy": "none",
-           "optimizer": "adam-learning_rate", "learning_rate": 1e-3,
-           "weight_decay": 0.0, "mesh_shape_override": {"data": 8},
-           "model_path": str(model_path)}
-    cfg.update(over)
-    return cfg
-
-
-def _ga_trainer(model_path, **over):
-    from homebrewnlp_tpu.config import ModelParameter
-    from homebrewnlp_tpu.core import sharding as shardlib
-    from homebrewnlp_tpu.model import Model
-    from homebrewnlp_tpu.train import Trainer
-    params = ModelParameter(_ga_cfg(model_path, **over))
-    mesh = shardlib.build_mesh(params)
-    return params, Trainer(params, Model(params), mesh=mesh)
-
-
-def _ga_batch(params):
-    rng = np.random.default_rng(42)
-    x = rng.integers(0, params.vocab_size,
-                     (params.train_batch_size, params.sequence_length, 1))
-    return {"token_x": np.asarray(x, np.int32),
-            "token_y": np.asarray((x + 1) % params.vocab_size, np.int32)}
-
-
-def bucket_plan_test(tmp_path):
-    """Buckets cover every grad leaf exactly once in REVERSE creation
-    order (output-side leaves first — the ones whose backward
-    contributions complete first), stay under the size target unless a
-    single leaf exceeds it, and never mix dtypes in one flat buffer."""
-    params, trainer = _ga_trainer(tmp_path / "r", grad_allreduce="bucketed",
-                                  grad_bucket_mb=0.015625)  # 16 KiB
-    variables = trainer.model.init(_ga_batch(params))
-    buckets = trainer._bucket_plan(variables)
-    flat = [k for b in buckets for k in b]
-    assert flat == list(reversed(list(variables))), (flat[:4], buckets[:2])
-    target = 16 * 1024
-    for b in buckets:
-        dtypes = {np.dtype(np.asarray(variables[k]).dtype) for k in b}
-        assert len(dtypes) == 1, b
-        size = sum(np.asarray(variables[k]).nbytes for k in b)
-        assert len(b) == 1 or size <= target, (b, size)
-    # a larger target coalesces harder
-    params2, trainer2 = _ga_trainer(tmp_path / "r2",
-                                    grad_allreduce="bucketed",
-                                    grad_bucket_mb=64.0)
-    assert len(trainer2._bucket_plan(variables)) < len(buckets)
-
-
-def grad_allreduce_eligibility_test(tmp_path):
-    """The policy refuses loudly instead of silently changing the
-    program: every gate names its reason, the eligible config returns
-    None, and the resolved fallback warns once."""
-    from homebrewnlp_tpu.model import Model
-    from homebrewnlp_tpu.train import Trainer
-
-    _, ok = _ga_trainer(tmp_path / "a", grad_allreduce="bucketed")
-    assert ok.grad_allreduce_fallback() is None
-
-    _, fused = _ga_trainer(tmp_path / "b")
-    assert fused.grad_allreduce_fallback() is None  # fused: nothing to gate
-
-    _, ga = _ga_trainer(tmp_path / "c", grad_allreduce="bucketed",
-                        grad_accumulation=2)
-    assert "accumulation" in ga.grad_allreduce_fallback()
-
-    _, ml = _ga_trainer(tmp_path / "d", grad_allreduce="bucketed",
-                        multi_loss_strategy="pcgrad")
-    assert "pcgrad" in ml.grad_allreduce_fallback()
-
-    from homebrewnlp_tpu.config import ModelParameter
-    params = ModelParameter(_ga_cfg(tmp_path / "e",
-                                    grad_allreduce="bucketed"))
-    single = Trainer(params, Model(params), mesh=None)
-    assert "single-device" in single.grad_allreduce_fallback()
-
-    # the resolved fallback is LOUD (warns) and lands on fused
-    import types
-
-    import jax.numpy as jnp
-    _, warned = _ga_trainer(tmp_path / "f", grad_allreduce="bucketed",
-                            grad_accumulation=2)
-    fake_info = types.SimpleNamespace(
-        total_loss=types.SimpleNamespace(data=jnp.float32(0)),
-        token_loss=None, video_loss=None, accuracy=None)
-    warned._grads = lambda v, b, r: ({}, fake_info)  # no compile needed
-    with pytest.warns(UserWarning, match="falling back"):
-        warned._grads_with_policy({}, {}, None)
-    assert warned._grad_allreduce_resolved == "fused"
-
-    # config validation rejects typos outright
-    with pytest.raises(ValueError, match="grad_allreduce"):
-        ModelParameter(_ga_cfg(tmp_path / "g", grad_allreduce="buckted"))
-
-
-@pytest.mark.slow
-def bucketed_matches_fused_within_tolerance_test(tmp_path):
-    """The acceptance pin: at the ``fused`` default the policy layer is
-    bit-identical to the historical path (same ``_grads`` seam, asserted
-    bit-for-bit against an explicit ``fused``); ``bucketed`` matches
-    within float reduction-order tolerance (mean-of-shard-means vs global
-    mean; measured ~7e-8 relative) while every bucket reduces once."""
-    import jax
-
-    losses = {}
-    for name, over in (("default", {}), ("fused", {"grad_allreduce": "fused"}),
-                       ("bucketed", {"grad_allreduce": "bucketed"})):
-        params, trainer = _ga_trainer(tmp_path / name, **over)
-        batch = _ga_batch(params)
-        state = trainer.init_state(batch)
-        seq = []
-        for i in range(3):
-            state, metrics = trainer.step(state, batch,
-                                          rng=jax.random.PRNGKey(100 + i))
-            seq.append(float(np.asarray(jax.device_get(metrics["loss"]))))
-        losses[name] = seq
-        assert trainer._grad_allreduce_resolved == (
-            "bucketed" if name == "bucketed" else "fused")
-    assert losses["default"] == losses["fused"], losses  # bit-identical
-    np.testing.assert_allclose(losses["bucketed"], losses["fused"],
-                               rtol=1e-5)
 
 
 # ---- controller e2e --------------------------------------------------------

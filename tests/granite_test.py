@@ -376,13 +376,13 @@ def conv_kernels_keep_their_scope_and_names_test(v5e, monkeypatch):
     (``^flash_``, ``^map_mixer_``)."""
     import re
     from benchmark.lib.cell import load_cell
-    from homebrewnlp_tpu.model import remat
+    from homebrewnlp_tpu.model import recurrent
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cell = load_cell("train_granite_4_0_h_micro_long").model_config()
     assert cell["block_config"][0]["layer"][-1] == "mamba"
     params = ModelParameter({**cell, "block_config": cell["block_config"][:1],
                              "vocab_size": 512, "model_path": "/tmp/granite"})
-    assert remat.conv_kernel_layers(params) == 1
+    assert recurrent.conv_kernel_layers(params) == 1
     model = Model(params)
     batch = {k: np.zeros((1, 8192, 1), np.int32)
              for k in ("token_x", "token_y")}
@@ -422,15 +422,15 @@ def delta_layers_kernels_keep_their_scopes_test(v5e, monkeypatch):
     reader takes."""
     import re
     from benchmark.lib.cell import load_cell
-    from homebrewnlp_tpu.model import remat
+    from homebrewnlp_tpu.model import recurrent
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cell = load_cell("train_olmo_hybrid_7b_long").model_config()
     assert cell["block_config"][0]["layer"][0] == "gated_delta"
     params = ModelParameter({**cell, "block_config": cell["block_config"][:1],
                              "vocab_size": 512, "sequence_length": 8192,
                              "model_path": "/tmp/olmo"})
-    assert remat.conv_kernel_layers(params) == 1
-    assert remat.solve_kernel_layers(params) == 1
+    assert recurrent.conv_kernel_layers(params) == 1
+    assert recurrent.solve_kernel_layers(params) == 1
     model = Model(params)
     batch = {k: np.zeros((1, 8192, 1), np.int32)
              for k in ("token_x", "token_y")}
@@ -526,7 +526,7 @@ def experts_rule_declines_without_a_moe_layer_test():
     """``checkpoint`` with no ``moe`` layer: nothing rides, the policy stays
     the named one, and the chunk states' gauge counts one layer's."""
     from homebrewnlp_tpu import telemetry
-    from homebrewnlp_tpu.model import remat
+    from homebrewnlp_tpu.model import recurrent, remat
     from homebrewnlp_tpu.model.blocks import _checkpoint_policy
     from homebrewnlp_tpu.train import Trainer
     _, params, model, _, _ = _build("float32")
@@ -539,7 +539,7 @@ def experts_rule_declines_without_a_moe_layer_test():
     assert _checkpoint_policy(params) \
         is jax.checkpoint_policies.nothing_saveable
     # [2, 64 / 16, 4, 8, 16] float32
-    assert remat.ssd_state_bytes(params) == 2 * 4 * 4 * 8 * 16 * 4
+    assert recurrent.ssd_state_bytes(params) == 2 * 4 * 4 * 8 * 16 * 4
     line = Trainer(params, model).publish_stash_plan()
     assert line.endswith("experts 0 layers, 0 bytes a device; recurrent 0 "
                          "layers, 0 bytes a device; ssd chunk states 16384 "
@@ -548,7 +548,7 @@ def experts_rule_declines_without_a_moe_layer_test():
     assert snap["hbnlp_ssd_state_bytes"]["series"][()] == 16384
     assert snap["hbnlp_mamba_conv_kernel_layers"]["series"][()] == 0
     _, none, _, _, _ = _build("float32", memory_reduction_strategy="none")
-    assert remat.ssd_state_bytes(none) == 9 * 16384
+    assert recurrent.ssd_state_bytes(none) == 9 * 16384
 
 
 def step_reports_the_log_decay_watch_test():

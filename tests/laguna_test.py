@@ -18,7 +18,7 @@ from homebrewnlp_tpu.analysis.cost_ledger import scope_key
 from homebrewnlp_tpu.config import BlockArgs, ModelParameter
 from homebrewnlp_tpu.core import scope
 from homebrewnlp_tpu.core.tensor import nt
-from homebrewnlp_tpu.model import Model, moe as moe_mod, remat
+from homebrewnlp_tpu.model import Model, moe as moe_mod, remat, spatial
 from homebrewnlp_tpu.model.spatial import (_standard_flags, rotary,
                                            yarn_inv_freq)
 
@@ -300,8 +300,8 @@ def the_step_reports_the_held_share_test():
         (held / routed).max())
     assert {"moe_held_pairs", "moe_held_pair_share",
             "moe_held_pair_share_max"} <= set(_LAYER_STATS)
-    assert remat.moe_held_rows(params) == 2 * 128 * 4
-    assert remat.moe_held_rows(ModelParameter(_config(experts_held=0))) == 0
+    assert moe_mod.moe_held_rows(params) == 2 * 128 * 4
+    assert moe_mod.moe_held_rows(ModelParameter(_config(experts_held=0))) == 0
     from homebrewnlp_tpu import telemetry
     from homebrewnlp_tpu.train import Trainer
     line = Trainer(params, model).publish_stash_plan()
@@ -324,23 +324,23 @@ def the_band_gauge_counts_the_window_layers_test():
                            "laguna_s_2_1.json")) as f:
         cell = json.load(f)["config"]
     params = ModelParameter(cell)
-    assert remat.flash_band_layers(params, "tpu") == 3
-    assert remat.flash_band_layers(params) == 0
+    assert spatial.flash_band_layers(params, "tpu") == 3
+    assert spatial.flash_band_layers(params) == 0
     with open(os.path.join(REPO, "configs", "laguna_s_2_1.json")) as f:
         full = ModelParameter(json.load(f))
-    assert remat.flash_band_layers(full, "tpu") == 3 * full.depth + 3 == 36
+    assert spatial.flash_band_layers(full, "tpu") == 3 * full.depth + 3 == 36
     tiny = ModelParameter(_config("bfloat16"))
-    assert remat.flash_band_layers(tiny, "tpu") == 3
-    assert remat.flash_band_layers(tiny) == 0
-    assert remat.flash_band_layers(
+    assert spatial.flash_band_layers(tiny, "tpu") == 3
+    assert spatial.flash_band_layers(tiny) == 0
+    assert spatial.flash_band_layers(
         ModelParameter(_config(use_flash_attention=False)), "tpu") == 0
-    assert remat.flash_band_layers(
+    assert spatial.flash_band_layers(
         ModelParameter(_config(sequence_length=96)), "tpu") == 0
     # a window as long as the sequence is the causal call; no window, no line
-    assert remat.flash_band_layers(
+    assert spatial.flash_band_layers(
         ModelParameter(_config(sequence_length=32)), "tpu") is None
     with open(os.path.join(REPO, "configs", "olmoe_1b_7b.json")) as f:
-        assert remat.flash_band_layers(ModelParameter(json.load(f)),
+        assert spatial.flash_band_layers(ModelParameter(json.load(f)),
                                        "tpu") is None
     model = Model(tiny)
     line = Trainer(tiny, model).publish_stash_plan()
@@ -353,7 +353,7 @@ def the_experts_stash_counts_the_bounds_rows_test():
     holds a share are its whole static buffer, and (PR 39) the router's
     choice, ``[tokens, moe_top_k]`` int32, beside the routing triple."""
     params = ModelParameter(_config("bfloat16"))
-    layers, nbytes = remat._experts_stash(params, 1)
+    layers, nbytes = remat._offered_stash(params, "experts", 1)
     rows = 2 * 128 * 4
     assert layers == 4
     assert nbytes == 4 * (rows * (2 * 24 + 32) * 2

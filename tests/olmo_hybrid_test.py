@@ -474,8 +474,8 @@ def remat_rules_count_the_new_layer_test():
     assert _checkpoint_policy(params) \
         is jax.checkpoint_policies.nothing_saveable
     # [2, 64 / 16, 3, 16, 8] in the calculation dtype, here float32
-    assert remat.ssd_state_bytes(params) == 2 * 4 * 3 * 16 * 8 * 4 == 12288
-    assert remat.conv_kernel_layers(params, "tpu") == 0     # 96 channels
+    assert recurrent.ssd_state_bytes(params) == 2 * 4 * 3 * 16 * 8 * 4 == 12288
+    assert recurrent.conv_kernel_layers(params, "tpu") == 0     # 96 channels
     line = Trainer(params, model).publish_stash_plan()
     assert line.endswith("experts 0 layers, 0 bytes a device; recurrent 0 "
                          "layers, 0 bytes a device; ssd chunk states 12288 "
@@ -485,37 +485,37 @@ def remat_rules_count_the_new_layer_test():
     assert snap["hbnlp_ssd_state_bytes"]["series"][()] == 12288
     assert snap["hbnlp_delta_solve_kernel_layers"]["series"][()] == 0
     # 2 x 4 chunks x 3 heads = 24 systems of 16 x 16 a call: no whole tile
-    assert delta_mod.gated_delta.recurrent.solve(params) == (16, 24)
-    assert remat.solve_kernel_layers(params, "tpu") == 0
+    assert delta_mod.gated_delta.declares.recurrent.solve(params) == (16, 24)
+    assert recurrent.solve_kernel_layers(params, "tpu") == 0
     _, none, _, _, _ = _build("float32", memory_reduction_strategy="none")
-    assert remat.ssd_state_bytes(none) == 3 * 12288
+    assert recurrent.ssd_state_bytes(none) == 3 * 12288
     # 3 x (2 x 32 + 64) = 384 channels from channel 0 of proj on
     _, wide, _, _, _ = _build("float32", delta_key_features=32,
                               delta_value_features=64, sequence_length=256)
-    assert delta_mod.gated_delta.recurrent.conv(wide) == (384, 4, 0)
-    assert remat.conv_kernel_layers(wide, "tpu") == 3
-    assert remat.conv_kernel_layers(wide) == 0
+    assert delta_mod.gated_delta.declares.recurrent.conv(wide) == (384, 4, 0)
+    assert recurrent.conv_kernel_layers(wide, "tpu") == 3
+    assert recurrent.conv_kernel_layers(wide) == 0
     # 2 x 16 chunks x 4 heads = 128 systems a call: one tile of the kernel
     _, tiled, _, _, _ = _build("float32", sequence_length=256, delta_heads=4)
-    assert delta_mod.gated_delta.recurrent.solve(tiled) == (16, 128)
-    assert remat.solve_kernel_layers(tiled, "tpu") == 3
-    assert remat.solve_kernel_layers(tiled) == 0            # the CPU
+    assert delta_mod.gated_delta.declares.recurrent.solve(tiled) == (16, 128)
+    assert recurrent.solve_kernel_layers(tiled, "tpu") == 3
+    assert recurrent.solve_kernel_layers(tiled) == 0            # the CPU
     odd = _build("float32", sequence_length=192, delta_heads=4,
                  delta_chunk=48)[1]
-    assert delta_mod.gated_delta.recurrent.solve(odd) == (48, 32)
-    assert remat.solve_kernel_layers(odd, "tpu") == 0       # no tile takes 48
+    assert delta_mod.gated_delta.declares.recurrent.solve(odd) == (48, 32)
+    assert recurrent.solve_kernel_layers(odd, "tpu") == 0       # no tile takes 48
     with open(os.path.join(REPO, "benchmark", "configs",
                            "olmo_hybrid_7b.json")) as f:
         cell = ModelParameter(dict(json.load(f)["config"],
                                    model_path="/tmp/olmo_cell"))
     # 256 chunks' entering states of one group of 10 heads in bfloat16
-    assert remat.ssd_state_bytes(cell) == 256 * 10 * 192 * 96 * 2 \
+    assert recurrent.ssd_state_bytes(cell) == 256 * 10 * 192 * 96 * 2 \
         == 94_371_840
-    assert remat.conv_kernel_layers(cell, "tpu") == 3
+    assert recurrent.conv_kernel_layers(cell, "tpu") == 3
     # 256 chunks x the 10 heads of a group, chunk 64; 3 layers x depth 1
-    assert delta_mod.gated_delta.recurrent.solve(cell) == (64, 2560)
-    assert remat.solve_kernel_layers(cell, "tpu") == 3 * cell.depth == 3
-    assert remat.solve_kernel_layers(cell) == 0
+    assert delta_mod.gated_delta.declares.recurrent.solve(cell) == (64, 2560)
+    assert recurrent.solve_kernel_layers(cell, "tpu") == 3 * cell.depth == 3
+    assert recurrent.solve_kernel_layers(cell) == 0
 
 
 def step_with_the_conv_kernel_test(monkeypatch):
@@ -564,7 +564,7 @@ def step_with_the_solve_kernel_test(monkeypatch):
     _, params, model, batch, variables = _build(
         "float32", sequence_length=256, delta_heads=4,
         block_config=_ONE["gated_delta"])
-    assert remat.solve_kernel_layers(params, "tpu") == 1
+    assert recurrent.solve_kernel_layers(params, "tpu") == 1
 
     def loss_and_grads():
         v = {k: jnp.asarray(a) for k, a in variables.items()}
@@ -675,7 +675,8 @@ def saved_rule_output_changes_no_bit_test(monkeypatch, scan_layers, groups):
                                       np.asarray(want[name]), err_msg=name)
     # [2, 64, 3, 16] float32 a layer, three linear layers a period
     saved = 2 * 64 * 3 * 16 * 4 * 3 * depth
-    assert delta_mod.gated_delta.recurrent.saved_names == ("gated_delta_out",)
+    assert delta_mod.gated_delta.declares.offer(params, set()).names \
+        == ("gated_delta_out",)
     assert remat.stash_plan(params)["recurrent"] == (3 * depth, saved)
     assert remat.stash_names(params) == ("gated_delta_out",)
     assert _checkpoint_policy(params) \

@@ -455,11 +455,12 @@ def conv_kernel_moves_no_other_cell_test(cell, layers):
     the granite cell's nine layers take it, the four other cells have no
     such layer to trace anything new (their lowered steps hashed equal to
     the parent's, ``PERF.md`` section 6, PR 31); off the TPU nobody does."""
-    from homebrewnlp_tpu.model.remat import _layers, conv_kernel_layers
+    from homebrewnlp_tpu.model.declare import layers as _layers
+    from homebrewnlp_tpu.model.recurrent import conv_kernel_layers
     p = _cell_params(cell)
     assert conv_kernel_layers(p, "tpu") == layers
     assert conv_kernel_layers(p) == 0
-    assert any(name == "mamba" for name, _ in _layers(p)) is bool(layers)
+    assert any(name == "mamba" for name, *_ in _layers(p)) is bool(layers)
 
 
 @pytest.mark.parametrize("cell,layers", [
@@ -475,11 +476,12 @@ def solve_kernel_moves_no_other_cell_test(cell, layers):
     have no layer that declares a solve (``mamba`` declares none) and trace
     nothing new (their train steps' jaxprs hash equal to the parent's,
     ``PERF.md`` section 6, PR 37); off the TPU nobody does."""
-    from homebrewnlp_tpu.model.remat import _layers, solve_kernel_layers
+    from homebrewnlp_tpu.model.declare import layers as _layers
+    from homebrewnlp_tpu.model.recurrent import solve_kernel_layers
     p = _cell_params(cell)
     assert solve_kernel_layers(p, "tpu") == layers
     assert solve_kernel_layers(p) == (None if layers is None else 0)
-    assert any(name == "gated_delta" for name, _ in _layers(p)) \
+    assert any(name == "gated_delta" for name, *_ in _layers(p)) \
         is (layers is not None)
 
 
@@ -538,8 +540,11 @@ def _with_moe(top_k: int):
     "experts_leave_none", "experts_decline", "mamba_declares_nothing"])
 def recurrent_stash_resolver_test(case):
     from homebrewnlp_tpu.model.blocks import _checkpoint_policy
-    from homebrewnlp_tpu.model.remat import (_recurrent_layers, stash_kinds,
-                                             stash_names, stash_plan)
+    from homebrewnlp_tpu.model.declare import offers
+    from homebrewnlp_tpu.model.recurrent import \
+        recurrent_layers as _recurrent_layers
+    from homebrewnlp_tpu.model.remat import (stash_kinds, stash_names,
+                                             stash_plan)
     idle = {kind: (0, 0) for kind in
             ("attention", "bottleneck", "experts", "recurrent")}
     nothing = jax.checkpoint_policies.nothing_saveable
@@ -635,8 +640,7 @@ def recurrent_stash_resolver_test(case):
             p = _cell_params("train_granite_4_0_h_micro_long", **kw)
             specs = _recurrent_layers(p)
             assert len(specs) == 9
-            assert all(s.saved_names == () and s.saved_bytes is None
-                       for s in specs)
+            assert offers(p, "recurrent") == []
             assert remat_report(p)["recurrent_stash_layers"] == 0
             # what rides there is the attention kind alone (PR 40)
             assert stash_plan(p) == {**idle, "attention": (1, 34603008)}

@@ -367,6 +367,61 @@ def env_knob_rule_negative_control_test():
                                 'import os\nx = os.environ["HOST"]\n') == []
 
 
+def layering_rule_clean_tree_test():
+    """Imports point downwards in the whole tree; the declaration readers
+    name no layer; and every excepted module still imports upwards (an entry
+    whose debt was paid must leave the list)."""
+    upward = set()
+    for path, rel in ast_lint.iter_source_files():
+        with open(path) as f:
+            src = f.read()
+        found = [f_ for f_ in ast_lint.lint_source(rel, src)
+                 if f_.rule == "layering"]
+        assert found == [], "\n".join(str(f_) for f_ in found)
+        if rel in ast_lint.LAYERING_EXCEPTIONS and "from ..model" in src:
+            upward.add(rel)
+    assert upward == set(ast_lint.LAYERING_EXCEPTIONS)
+
+
+@pytest.mark.parametrize("rel,source,findings", [
+    # a lower layer reaching up, in every spelling
+    ("homebrewnlp_tpu/parallel/new_kernel.py",
+     "from ..model.blocks import stash_push\n", 1),
+    ("homebrewnlp_tpu/core/quant.py",
+     "def f():\n    from ..model.backend import _BLOCK_RE\n", 1),
+    ("homebrewnlp_tpu/core/x.py", "from .. import model\n", 1),
+    ("homebrewnlp_tpu/telemetry/x.py",
+     "import homebrewnlp_tpu.train as t\n", 1),
+    ("homebrewnlp_tpu/optim/x.py", "from ..run.train_loop import train\n", 1),
+    ("homebrewnlp_tpu/parallel/x.py", "from ..infer import engine\n", 1),
+    # downwards and sideways are fine, and so are the named exceptions
+    ("homebrewnlp_tpu/parallel/new_kernel.py",
+     "from ..core.stash import stash_push\nfrom .flash_attention import "
+     "kernel_block\nfrom ..config import ModelParameter\n", 0),
+    ("homebrewnlp_tpu/parallel/pipeline.py",
+     "from ..model.blocks import rev_sequence\n", 0),
+    ("homebrewnlp_tpu/model/spatial.py",
+     "from ..parallel.flash_attention import attention\n", 0),
+    # the declaration readers name no layer and no kernel
+    ("homebrewnlp_tpu/model/remat.py",
+     "from .moe import SAVED_NAMES\nfrom . import cca\n", 2),
+    ("homebrewnlp_tpu/model/remat.py",
+     "def f():\n    from ..parallel.flash_attention import band_applies\n",
+     1),
+    ("homebrewnlp_tpu/train/__init__.py",
+     "from ..model.spatial import _DENSE_ONLY\nfrom ..parallel.causal_conv "
+     "import kernel_applies\n", 2),
+    ("homebrewnlp_tpu/train/__init__.py",
+     "from ..model import Model, declare\nfrom ..model.remat import "
+     "stash_plan\nfrom ..core import sharding\n", 0),
+    ("homebrewnlp_tpu/model/remat.py",
+     "from .declare import offers\nfrom ..utils.flops import peak_flops\n",
+     0)])
+def layering_rule_negative_control_test(rel, source, findings):
+    assert [f.rule for f in ast_lint.lint_source(rel, source)] \
+        == ["layering"] * findings, source
+
+
 def config_docs_rule_negative_control_test(tmp_path):
     cfg = tmp_path / "config.py"
     lines = ["class ModelParameter:",
@@ -411,3 +466,14 @@ def graft_lint_cli_reports_findings_test(monkeypatch):
     assert graft_lint.main(["--ast"]) == 1
     monkeypatch.setattr(graft_lint, "run_ast", lambda: [])
     assert graft_lint.main(["--ast"]) == 0
+
+
+def int8_promotion_audit_negative_control_test():
+    """A synthetic dequant-scope-less int8 promotion IS flagged (the pass
+    has teeth), while the same line under a dequant scope is not."""
+    bad = ('  %evil = f32[4,256,128]{2,1,0} convert(s8[4,256,128]{2,1,0} '
+           '%w), metadata={op_name="jit(step_fn)/gpt0/body0/somewhere/'
+           'convert_element_type"}')
+    good = bad.replace("body0/somewhere", "body0/dequant")
+    assert hlo_lint.int8_promotion_audit("t", bad)
+    assert not hlo_lint.int8_promotion_audit("t", good)

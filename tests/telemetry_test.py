@@ -28,6 +28,7 @@ import pytest
 
 from homebrewnlp_tpu import telemetry
 from homebrewnlp_tpu.config import ModelParameter
+from homebrewnlp_tpu.model import declare
 
 pytestmark = pytest.mark.telemetry
 
@@ -484,12 +485,9 @@ _RARE_SERIES = {telemetry.SPAN_METRIC, "hbnlp_init_values_seconds_total",
                 "hbnlp_compile_seconds_total", "hbnlp_compiles_total",
                 # set once, when the step is built (PR 27)
                 "hbnlp_remat_stash_bytes", "hbnlp_remat_stash_layers",
-                # likewise (PR 30, PR 31; 0 without a mamba layer)
-                "hbnlp_ssd_state_bytes", "hbnlp_mamba_conv_kernel_layers",
-                # likewise (PR 37; 0 without a gated_delta layer)
-                "hbnlp_delta_solve_kernel_layers",
-                # likewise (PR 41; 0 without a windowed attention layer)
-                "hbnlp_flash_band_layers",
+                # likewise: the start-up facts the layers declare that read
+                # 0 without their layer (model/declare.py Fact)
+                *(fact.metric for fact in declare.facts() if fact.zero),
                 # set at the marks of telemetry/memory.py, where the backend
                 # reports memory (PR 34; XLA:CPU: no series)
                 "hbnlp_hbm_bytes", "hbnlp_train_state_bytes"}

@@ -690,8 +690,8 @@ def the_step_reports_the_router_and_the_logit_bound_test():
         float(np.sum(info.layer_stats["moe_held_pairs"])) / (3 * 128))
     assert {"moe_top1_weight_mean", "cca_logit_scale_max"} <= set(_LAYER_STATS)
     # one float32 [2, 64, 16] state for every carrying layer but the last
-    assert remat.router_carry_bytes(params) == 2 * 2 * 64 * 16 * 4
-    assert remat.router_carry_bytes(ModelParameter(_config(depth=1))) == 0
+    assert moe_mod.router_carry_bytes(params) == 2 * 2 * 64 * 16 * 4
+    assert moe_mod.router_carry_bytes(ModelParameter(_config(depth=1))) == 0
     from homebrewnlp_tpu import telemetry
     from homebrewnlp_tpu.train import Trainer
     line = Trainer(params, model).publish_stash_plan()
