@@ -66,7 +66,7 @@ _LAYER_NAMES = frozenset((
     "feed_forward_product_key_memory", "product_key_memory",
     "reduced_half_linear", "transpose_sequence_features",
     "bottleneck_group_linear", "sum_heads", "moe", "mamba", "gated_delta",
-    "mlp", "cca",
+    "mlp", "cca", "lightning",
     # no layer function: a block part's scaled residual merge
     # (model/frontend.py scaled_merge) opens a scope of its own beside them
     "merge"))
@@ -84,6 +84,15 @@ _CCA_PARTS = frozenset(("in_proj", "qk_mean", "conv", "qk_norm", "rope",
 #: the standard attention's per-head output gate (model/spatial.py), a
 #: scope of its own below ``body/attention``
 _ATTENTION_PARTS = frozenset(("gate",))
+#: the steps of attention flag ``sparse`` (model/sparse.py; ``attend`` holds
+#: the selected kernels) below ``body/attention/sparse_attention``
+_SPARSE_PARTS = frozenset(("compress", "index", "select", "attend"))
+#: the parts of layer ``lightning`` (model/lightning.py) below
+#: ``body/lightning``; the rule's own steps (``intra_chunk``,
+#: ``chunk_states``, ``inter_chunk``, ``state_out``) stay inside
+#: ``body/lightning/rule``
+_LIGHTNING_PARTS = frozenset(("in_proj", "qk_norm", "rope", "rule",
+                              "gate_norm", "out_proj"))
 #: the parts of layer ``mamba`` (model/mamba.py) below ``body/mamba``; the
 #: scan's own steps (``intra_chunk``, ``chunk_states``, ``inter_chunk``,
 #: ``state_out``) stay inside ``body/mamba/ssd``
@@ -118,6 +127,8 @@ def scope_key(path: str) -> str:
     ``head_loss``, ``input/embed``, ``input``, ``body/<layer>``,
     ``body/moe/router|dispatch|experts|combine|shared``,
     ``body/moe/router/down|carry|mlp``, ``body/attention/gate``,
+    ``body/attention/sparse_attention/compress|index|select|attend``,
+    ``body/lightning/in_proj|qk_norm|rope|rule|gate_norm|out_proj``,
     ``body/cca/in_proj|qk_mean|conv|qk_norm|rope|value_shift|out_proj``,
     ``body/merge``,
     ``body/mamba/in_proj|conv|ssd|gate_norm|out_proj``,
@@ -129,7 +140,7 @@ def scope_key(path: str) -> str:
     attribution, not per-pass."""
     phase = None
     layer = None
-    router = False
+    router = sparse = False
     bases = []
     for comp in str(path).split("/"):
         base = _basename(_unwrap(comp))
@@ -148,14 +159,22 @@ def scope_key(path: str) -> str:
             return f"body/moe/{base}"
         elif layer == "cca" and base in _CCA_PARTS:
             return f"body/cca/{base}"
+        elif sparse and base in _SPARSE_PARTS:
+            return f"body/attention/sparse_attention/{base}"
+        elif layer == "attention" and base == "sparse_attention":
+            sparse = True
         elif layer == "attention" and base in _ATTENTION_PARTS:
             return f"body/attention/{base}"
+        elif layer == "lightning" and base in _LIGHTNING_PARTS:
+            return f"body/lightning/{base}"
         elif layer == "mamba" and base in _MAMBA_PARTS:
             return f"body/mamba/{base}"
         elif layer == "gated_delta" and base in _DELTA_PARTS:
             return f"body/gated_delta/{base}"
     if router:
         return "body/moe/router"
+    if sparse:
+        return "body/attention/sparse_attention"
     # a leading block (input_block_config) is a body layer that runs once
     if phase in ("body", "input") and layer is not None:
         return f"body/{layer}"

@@ -263,6 +263,31 @@ class ModelParameter:
         self.delta_conv_size = 4
         self.delta_chunk = 64
         self.delta_allow_neg_eigval = True
+        # layer "lightning" (decayed linear attention with per-head keys,
+        # model/lightning.py): the heads of the WHOLE layer (a head's decay
+        # follows its index among them), which of them THIS layer holds —
+        # lightning_heads_held consecutive ones from lightning_heads_first
+        # (0 held = all) —, a head's width, the chunk of the chunked form, and
+        # the groups of heads the output norm normalises together (a rank
+        # holds whole groups, so it normalises without an exchange)
+        self.lightning_heads = 32
+        self.lightning_heads_held = 0
+        self.lightning_heads_first = 0
+        self.lightning_head_features = 128
+        self.lightning_chunk = 256
+        self.lightning_norm_groups = 2
+        # attention flag "sparse" (block-selected attention, model/sparse.py;
+        # MiniCPM4's sparse_config): the pooled keys' window and stride, the
+        # keys a block, the blocks a query keeps a K/V group, the leading
+        # blocks and the trailing keys it always keeps, and the sequence
+        # length up to which the layer is dense
+        self.sparse_kernel_size = 32
+        self.sparse_kernel_stride = 16
+        self.sparse_block_size = 64
+        self.sparse_topk = 64
+        self.sparse_init_blocks = 1
+        self.sparse_window = 2048
+        self.sparse_dense_length = 8192
         # the eps of layer "norm" and of gated_delta's gated norm (a
         # published config's rms_norm_eps / layer_norm_eps)
         self.norm_epsilon = 1e-5
@@ -929,6 +954,46 @@ class ModelParameter:
                     or getattr(self, key) < 1:
                 raise ValueError(f"{key} {getattr(self, key)!r} must be a "
                                  "positive whole number")
+        for key in ("lightning_heads", "lightning_head_features",
+                    "lightning_chunk", "lightning_norm_groups",
+                    "sparse_kernel_size",
+                    "sparse_kernel_stride", "sparse_block_size",
+                    "sparse_topk"):
+            if not isinstance(getattr(self, key), int) \
+                    or getattr(self, key) < 1:
+                raise ValueError(f"{key} {getattr(self, key)!r} must be a "
+                                 "positive whole number")
+        for key in ("lightning_heads_held", "lightning_heads_first",
+                    "sparse_init_blocks", "sparse_window",
+                    "sparse_dense_length"):
+            if not isinstance(getattr(self, key), int) \
+                    or getattr(self, key) < 0:
+                raise ValueError(f"{key} {getattr(self, key)!r} must be a "
+                                 "whole number >= 0")
+        if self.lightning_heads_first + self.lightning_heads_held \
+                > self.lightning_heads:
+            raise ValueError(
+                f"lightning_heads_first {self.lightning_heads_first} + "
+                f"lightning_heads_held {self.lightning_heads_held} exceeds "
+                f"lightning_heads {self.lightning_heads}")
+        if self.lightning_heads_first and not self.lightning_heads_held:
+            raise ValueError("lightning_heads_first without "
+                             "lightning_heads_held")
+        if self.lightning_heads % self.lightning_norm_groups or any(
+                count % (self.lightning_heads // self.lightning_norm_groups)
+                for count in (self.lightning_heads_held,
+                              self.lightning_heads_first)):
+            raise ValueError(
+                f"lightning_norm_groups {self.lightning_norm_groups} divides "
+                f"lightning_heads {self.lightning_heads}, and a rank holds "
+                "whole groups (lightning_heads_held, lightning_heads_first)")
+        if self.sparse_kernel_size % self.sparse_kernel_stride \
+                or self.sparse_block_size % self.sparse_kernel_stride \
+                or self.sparse_window % self.sparse_block_size:
+            raise ValueError(
+                "sparse_*: sparse_kernel_stride divides sparse_kernel_size "
+                "and sparse_block_size, sparse_block_size divides "
+                "sparse_window")
         if not isinstance(self.delta_conv_size, int) \
                 or not 1 <= self.delta_conv_size <= 128:
             raise ValueError(f"delta_conv_size {self.delta_conv_size!r} must "

@@ -18,6 +18,7 @@ from .basic import (bottleneck_group_linear, dropout, feed_forward,
                     transpose_sequence_features)
 from .cca import cca
 from .gated_delta import gated_delta
+from .lightning import lightning
 from .mamba import mamba
 from .moe import moe
 from .normalization import norm
@@ -177,4 +178,5 @@ LAYER_FUNCTIONS = {'feed_forward': feed_forward,
                    'gated_delta': gated_delta,
                    'mlp': mlp,
                    'cca': cca,
+                   'lightning': lightning,
                    }

@@ -1,4 +1,5 @@
-"""What the recurrent mixers (layers ``mamba`` and ``gated_delta``) share:
+"""What the recurrent mixers (layers ``mamba``, ``gated_delta`` and
+``lightning``) share:
 what they refuse and the layout they take, the per-channel float32
 parameters and their initialisers, the causal depthwise conv's XLA form, what
 a layer declares of its recurrence (:class:`Recurrent`) and the start-up
@@ -25,7 +26,7 @@ class Recurrent(typing.NamedTuple):
     — the bytes of chunk states one layer keeps for its backward, for the
     whole batch — and ``conv(params)`` — ``(channels, taps, offset)`` of its
     causal depthwise conv, as ``parallel/causal_conv.kernel_applies`` takes
-    them.  A layer that solves a unit triangular system a chunk declares it:
+    them (None: the layer has no conv, ``lightning``).  A layer that solves a unit triangular system a chunk declares it:
     ``solve(params)`` — ``(chunk, matrices a call)`` as
     ``parallel/delta_solve.solve_kernel_applies`` takes them
     (``gated_delta``: the systems of one group of heads); None = none.
@@ -38,7 +39,8 @@ class Recurrent(typing.NamedTuple):
     makes the backward's residuals, and a saved output would skip none of
     it."""
     state_bytes: typing.Callable[[ModelParameter], int]
-    conv: typing.Callable[[ModelParameter], typing.Tuple[int, int, int]]
+    conv: typing.Optional[
+        typing.Callable[[ModelParameter], typing.Tuple[int, int, int]]]
     solve: typing.Optional[
         typing.Callable[[ModelParameter], typing.Tuple[int, int]]] = None
 
@@ -76,6 +78,8 @@ def conv_kernel_layers(params: ModelParameter, backend=None) -> int:
     themselves call on the conv each declares."""
     count = 0
     for spec in recurrent_layers(params):
+        if spec.conv is None:
+            continue
         channels, taps, offset = spec.conv(params)
         count += kernel_applies(channels, params.sequence_dim.size, taps,
                                 offset, backend)
@@ -107,7 +111,8 @@ FACTS = (
          "recurrent mixers (mamba, gated_delta) of the built step whose conv "
          "is the Pallas kernel pair (0 on the XLA fallback)",
          lambda params, mesh, backend: conv_kernel_layers(params, backend)
-         if recurrent_layers(params) else None,
+         if any(spec.conv is not None for spec in recurrent_layers(params))
+         else None,
          "conv kernel {} layers"),
     Fact(30, "hbnlp_delta_solve_kernel_layers",
          "gated_delta layers of the built step whose triangular solve is the "

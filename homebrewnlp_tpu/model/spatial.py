@@ -22,10 +22,11 @@ from ..core.stash import stash_channel
 from ..core.tensor import (NamedTensor, cumsum as tensor_cumsum, einsum, exp,
                            less, multiply, range_, reduce_max, reduce_sum,
                            stop_gradient, greater_equal)
-from ..parallel.flash_attention import SAVED_NAMES, band_applies
+from ..parallel.flash_attention import (SAVED_NAMES, SELECT_NAME,
+                                        band_applies)
 from . import decode as decode_mod
 from .basic import activated_linear_in, activated_linear_out
-from .declare import Fact, Layer, Offer, step_offers
+from .declare import Fact, Layer, Offer, Stat, step_offers
 from .embedding import embed
 from .utils import (anonymize, compare_range, get_attention_dim,
                     is_masked, linear_shapes)
@@ -408,7 +409,8 @@ def rotary(x, theta: float, width: typing.Optional[int] = None,
 #: the standard attention's flags: the three that select it (how positions
 #: enter), the plain ones, and the ones that carry a whole number
 _STANDARD_POSITION = ("rope", "nope", "yarn")
-_STANDARD_PLAIN = ("qk_norm", "gate")
+_STANDARD_PLAIN = ("qk_norm", "qk_norm_head", "gate", "gate_features",
+                   "sparse")
 _STANDARD_NUMBERED = ("q_heads", "kv_heads", "window", "rotary_pct", "theta")
 
 
@@ -450,6 +452,11 @@ def _standard_flags(extras) -> typing.Dict[str, typing.Any]:
                          f"{_STANDARD_POSITION}, got {list(extras)}")
     if "nope" in out and any(f in out for f in ("rotary_pct", "theta")):
         raise ValueError("nope (no rotary positions) with rotary_pct / theta")
+    for one, other in (("qk_norm", "qk_norm_head"), ("gate", "gate_features"),
+                       ("sparse", "window")):
+        if one in out and other in out:
+            raise ValueError(f"the standard attention takes {one} or "
+                             f"{other}, not both")
     return out
 
 
@@ -496,6 +503,40 @@ def causal_heads(ctx, params, q, k, v, group: int, scale: float,
         return _xla_reference(q, k, v, scale, True, window)
 
 
+def sparse_heads(ctx, params, q, k, v, scale: float):
+    """Attention flag ``sparse`` on ``q [lead, seq, heads, f]`` and ``k``,
+    ``v`` ``[lead, seq, kv heads, f]``: the plain causal attention up to
+    ``sparse_dense_length`` keys; past it the indexer's choice (no gradient;
+    named ``SELECT_NAME``) and the selected kernels on it.  Reports the kept
+    share of the visible keys and the share of queries that chose."""
+    import jax
+    import jax.numpy as jnp
+    from jax.ad_checkpoint import checkpoint_name
+    from ..parallel.flash_attention import select_attention
+    from . import sparse
+    sizes = sparse.sizes_of(params)
+    if not sparse.selects(sizes, q.shape[1]):
+        if ctx.layer_stats is not None:
+            ctx.layer_stats.append(
+                {"sparse_kept_key_share": jnp.float32(1.0),
+                 "sparse_choosing_query_share": jnp.float32(0.0)})
+        return causal_heads(ctx, params, q, k, v, q.shape[2] // k.shape[2],
+                            scale)
+    if ctx.mesh is not None and ctx.mesh.size > 1:
+        raise NotImplementedError("attention flag sparse on a mesh")
+    with jax.named_scope("sparse_attention"):
+        keep = checkpoint_name(sparse.select_blocks(
+            jax.lax.stop_gradient(q), jax.lax.stop_gradient(k), sizes,
+            scale), SELECT_NAME)
+        if ctx.layer_stats is not None:
+            share, chose = sparse.kept_shares(keep, sizes.block)
+            ctx.layer_stats.append({"sparse_kept_key_share": share,
+                                    "sparse_choosing_query_share": chose})
+        with jax.named_scope("attend"):
+            return select_attention(q, k, v, keep, sizes.block, scale,
+                                    stash=stash_channel(ctx, "attention"))
+
+
 def _standard_attention(args: BlockArgs) -> NamedTensor:
     """The standard pre-norm transformer's attention (flag ``rope``, ``nope``
     or ``yarn``): query, key and value are three bias-free projections of the
@@ -521,7 +562,17 @@ def _standard_attention(args: BlockArgs) -> NamedTensor:
     sigmoid gate a query head on the attention's output, ``o * sigmoid(a
     Wg)`` with ``Wg [features, heads]`` from the block's (normed) input,
     created after the value projection (Qiu et al., arXiv:2505.06708,
-    head-wise).  ``theta<t>`` replaces ``rope_theta`` for this layer,
+    head-wise); ``gate_features``: a gate a FEATURE, ``Wg [features, heads x
+    features_per_head]`` (MiniCPM-SALA's ``attn_use_output_gate``).
+    ``qk_norm_head``: the RMSNorm over each head's own features, one learned
+    ``[features_per_head]`` scale for the query's heads and one for the
+    key's.  ``sparse``: past ``sparse_dense_length`` keys a query attends the
+    blocks of keys the indexer of model/sparse.py keeps for its K/V group
+    (steps ``compress``, ``index``, ``select`` under scope
+    ``sparse_attention``, then ``attend``: the ``flash_*_select`` kernels of
+    parallel/flash_attention.py); the choice carries no gradient and is
+    named (``SELECT_NAME``) beside ``(out, lse)``, so that where those are
+    saved a replay chooses nothing.  ``theta<t>`` replaces ``rope_theta`` for this layer,
     ``rotary_pct<p>`` turns only the first ``p`` percent of each head's
     features (HF's ``partial_rotary_factor``; an even count), and
     ``yarn`` is ``rope`` at YaRN's frequencies (``rope_yarn_factor``,
@@ -578,12 +629,17 @@ def _standard_attention(args: BlockArgs) -> NamedTensor:
     qry = project(args, args.tensor, q_feats, feats)
     val = project(args, args.tensor, kv_feats, feats)
     gate = None
-    if "gate" in flags:
+    if "gate" in flags or "gate_features" in flags:
         with jax.named_scope("gate"):
-            gate = project(args, args.tensor, q_feats[:1], feats)
+            gate = project(args, args.tensor,
+                           q_feats if "gate_features" in flags
+                           else q_feats[:1], feats)
     if "qk_norm" in flags:
         qry = norm(args(qry, ["rms", "scale"]), q_feats)
         key = norm(args(key, ["rms", "scale"]), kv_feats)
+    if "qk_norm_head" in flags:
+        qry = norm(args(qry, ["rms", "scale"]), q_feats[1:])
+        key = norm(args(key, ["rms", "scale"]), kv_feats[1:])
     lead_dims = [d for d in args.tensor.dims if d not in [dim] + feats]
     canonical = lead_dims + [dim] + q_feats
     lead = 1
@@ -613,8 +669,11 @@ def _standard_attention(args: BlockArgs) -> NamedTensor:
             q = rotary(q, theta, *rope_args)
             k = rotary(k, theta, *rope_args)
     scale = params.attention_scale or params.key_dim.size ** -0.5
-    out = causal_heads(ctx, params, q, k, v, group, scale,
-                       flags.get("window"))
+    if "sparse" in flags:
+        out = sparse_heads(ctx, params, q, k, v, scale)
+    else:
+        out = causal_heads(ctx, params, q, k, v, group, scale,
+                           flags.get("window"))
     out_nt = nt(out.reshape([d.size for d in canonical]), canonical)
     if gate is not None:
         with jax.named_scope("gate"):
@@ -647,8 +706,18 @@ def _offer(params, extras) -> typing.Optional[Offer]:
     makes none."""
     if any(f in extras for f in _STANDARD_POSITION):
         flags = _standard_flags(extras)
-        return flash_offer(params, flags.get("q_heads", params.head_dim.size),
-                           flags.get("window"))
+        heads = flags.get("q_heads", params.head_dim.size)
+        offer = flash_offer(params, heads, flags.get("window"))
+        seq = params.sequence_dim.size
+        if "sparse" in flags and seq > params.sparse_dense_length:
+            # the choice rides with the pair: a bool a query, a block and a
+            # K/V head
+            kv = flags.get("kv_heads", heads // params.query_group)
+            offer = offer._replace(
+                names=SAVED_NAMES + (SELECT_NAME,),
+                nbytes=offer.nbytes + kv * params.batch_dim.size * seq
+                * (seq // params.sparse_block_size))
+        return offer
     if "dot_product" not in extras or any(f in extras for f in _DENSE_ONLY) \
             or not any(f in extras for f in ("embedded", "context",
                                               "positional")):
@@ -750,4 +819,16 @@ def attention(args: BlockArgs) -> NamedTensor:
     return einsum([logit, val], shape)
 
 
-attention.declares = Layer(offer=_offer, facts=FACTS)
+attention.declares = Layer(
+    stats=(Stat("sparse_kept_key_share", "gauge",
+                "hbnlp_sparse_kept_key_share",
+                "keys a query of a sparse attention layer kept over the keys "
+                "it may see, mean over the queries of the newest finished "
+                "step, in the layer where it is smallest (1 = dense)",
+                "min"),
+           Stat("sparse_choosing_query_share", "gauge",
+                "hbnlp_sparse_choosing_query_share",
+                "share of a sparse attention layer's queries that left a "
+                "visible block out, newest finished step, the layer where it "
+                "is largest", "max")),
+    offer=_offer, facts=FACTS)

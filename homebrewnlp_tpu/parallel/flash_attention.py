@@ -1069,3 +1069,452 @@ def attention(q, k, v, scale: typing.Optional[float] = None,
                                interpret, bwd_block_q=blk, bwd_block_k=blk,
                                window=window)
 
+
+
+# ---- block-selected attention ------------------------------------------------
+#
+# A query row keeps whole BLOCKS of ``block`` keys, the same for the ``group``
+# query heads of its K/V head and another set for the next row (model/sparse.py
+# makes the choice; nothing here scores anything): ``keep [b, kv heads, s, s /
+# block]`` bool.  The kernels are the tiled flash-2 ones with two more
+# operands: the rows' choice, a ``[tile, 128]`` window of it a cell, widened to
+# the cell's ``[tile, tile]`` pairs by one matmul with a 0/1 matrix made of
+# iotas (a lane-dimension repeat that Mosaic lowers everywhere); and a table
+# of the tiles some row of a q tile kept, prefetched to SMEM, through which
+# the index maps and the cells skip every K/V tile no row chose — its fetch
+# as well as its matmuls.  K and V come a K/V HEAD each (index ``head //
+# group``), never repeated; the dk/dv pass writes a query head's part, the
+# caller sums a group's.
+
+#: the name of a sparse layer's choice, beside ``SAVED_NAMES``: saved with
+#: ``(out, lse)`` wherever those are, so that a replay chooses nothing
+SELECT_NAME = "sparse_keep"
+#: the selected kernels' tile, q and k alike (the tables' diagonal is then
+#: always live: a row keeps its own block)
+_SELECT_TILE = 512
+#: lanes of the window of the rows' choice a cell reads
+_KEEP_LANES = 128
+
+
+def select_tile(s: int, block: int) -> int:
+    """Tile of the selected kernels: ``kernel_block`` under ``_SELECT_TILE``,
+    in whole blocks."""
+    tile = kernel_block(s, cap=_SELECT_TILE)
+    if tile % block or _KEEP_LANES % (tile // block):
+        raise ValueError(f"a selected kernel's tile of {tile} keys holds no "
+                         f"whole power-of-two number of blocks of {block}")
+    return tile
+
+
+def _keep_mask(keep, block: int, s: int):
+    """``keep [b, g, s, s / block]`` as pairs ``[b, g, s, s]``, causal."""
+    pairs = jnp.repeat(keep, block, axis=-1)[..., :s]
+    return pairs & (jnp.arange(s)[:, None] >= jnp.arange(s)[None, :])
+
+
+def _xla_select_with_lse(q, k, v, keep, scale, block):
+    """The dense masked form off the TPU (and the kernels' reference): ``q
+    [b, s, h, d]``, ``k`` / ``v`` ``[b, s, g, d]`` -> ``(out [b, s, h, d],
+    lse [b * h, s])``, the softmax over exactly the kept keys ``<= t``."""
+    b, s, h, d = q.shape
+    g = k.shape[2]
+    qg = q.reshape(b, s, g, h // g, d).astype(jnp.float32) * scale
+    scores = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k.astype(jnp.float32))
+    mask = _keep_mask(keep, block, s)[:, :, None]
+    scores = jnp.where(mask, scores, _NEG_INF)
+    m = scores.max(-1)
+    p = jnp.where(mask, jnp.exp(scores - m[..., None]), 0.0)
+    l = jnp.maximum(p.sum(-1), 1e-30)
+    out = jnp.einsum("bgrqk,bkgd->bqgrd", p / l[..., None],
+                     v.astype(jnp.float32)).reshape(b, s, h, d)
+    return out.astype(q.dtype), (m + jnp.log(l)).reshape(b * h, s)
+
+
+def _select_tables(keep, tile: int, block: int):
+    """``(keep_rows [b * g, s, lanes] in bfloat16, fetch_k, fetch_q)`` of a
+    choice ``keep [b, g, s, nb]``.  ``fetch_k [b * g * nq * nk]`` int32: for
+    q tile ``j`` and step ``kk`` the k tile to hold — ``kk`` itself where
+    some row of the q tile kept a block of it (and it is not above the
+    diagonal), else the last such tile before it (the first one, before
+    any): a repeated index fetches nothing.  ``fetch_q`` the same for the
+    k-outer grid, ``[.., nk, nq]``."""
+    b, g, s, nb = keep.shape
+    per = tile // block
+    nt = s // tile
+    live = keep.reshape(b * g, nt, tile, nt, per).any(axis=(2, 4))
+    live &= jnp.arange(nt)[:, None] >= jnp.arange(nt)[None, :]
+
+    def fetch(alive):
+        idx = jnp.arange(nt, dtype=jnp.int32)
+        last = jax.lax.cummax(jnp.where(alive, idx, -1), axis=2)
+        first = jnp.argmax(alive, axis=2).astype(jnp.int32)[..., None]
+        return jnp.where(last >= 0, last, first).reshape(-1)
+
+    lanes = -(-nb // _KEEP_LANES) * _KEEP_LANES
+    rows = jnp.pad(keep.reshape(b * g, s, nb).astype(jnp.bfloat16),
+                   ((0, 0), (0, 0), (0, lanes - nb)))
+    return rows, fetch(live), fetch(jnp.swapaxes(live, 1, 2))
+
+
+def _select_seen(keep_ref, qi, ki, tile: int, block: int):
+    """The pairs ``[tile, tile]`` of q tile ``qi`` and k tile ``ki`` a row
+    kept and may see: the window of the rows' choice times the 0/1 matrix
+    that repeats a block's lane over its keys, and the diagonal."""
+    per = tile // block
+    first = jax.lax.rem(ki * per, _KEEP_LANES)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (_KEEP_LANES, tile), 0)
+    key = jax.lax.broadcasted_iota(jnp.int32, (_KEEP_LANES, tile), 1)
+    widen = (lane == first + jax.lax.div(key, block)).astype(keep_ref.dtype)
+    kept = jax.lax.dot_general(keep_ref[...], widen, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32) > 0.5
+    q_pos = qi * tile + jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
+    k_pos = ki * tile + jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
+    return kept & (q_pos >= k_pos)
+
+
+def _select_fwd_kernel(fetch_ref, q_ref, k_ref, v_ref, keep_ref, o_ref,
+                       lse_ref, m_ref, l_ref, acc_ref, *, tile: int,
+                       block: int, num: int, group: int, scale: float):
+    """Grid (batch * heads, q tiles, k tiles), k innermost: ``_flash_kernel``
+    over the tiles some row of the q tile kept, each pair under the rows'
+    own mask."""
+    from jax.experimental import pallas as pl
+
+    i, qi, kk = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+
+    @pl.when(kk == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(fetch_ref[((i // group) * num + qi) * num + kk] == kk)
+    def _step():
+        seen = _select_seen(keep_ref, qi, kk, tile, block)
+        s = jnp.where(seen, _make_score(q_ref, k_ref, scale)(), _NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, s.max(-1))
+        alpha = jnp.exp(m_prev - m_new)
+        # a row that kept nothing of this tile, and nothing before it, has
+        # m_new = _NEG_INF: exp(s - m_new) would read 1 on its masked pairs
+        p = jnp.where(seen, jnp.exp(s - m_new[:, None]), 0.0)
+        l_ref[...] = l_ref[...] * alpha + p.sum(-1)
+        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[...], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    @pl.when(kk == num - 1)
+    def _finish():
+        l = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[...] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        lse_ref[...] = (m_ref[...] + jnp.log(l))[:, None]
+
+
+def _select_pair(seen, q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, scale):
+    """``(p, ds)`` of one cell of the backward, as the causal kernels form
+    them, under the rows' mask."""
+    s = jnp.where(seen, _make_score(q_ref, k_ref, scale)(), _NEG_INF)
+    p = jnp.exp(s - lse_ref[...])
+    dp = jax.lax.dot_general(do_ref[...], v_ref[...],
+                             (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    return p, (p * (dp - d_ref[...]) * scale).astype(q_ref.dtype)
+
+
+def _select_dq_kernel(fetch_ref, q_ref, k_ref, v_ref, keep_ref, do_ref,
+                      lse_ref, d_ref, dq_ref, acc_ref, *, tile: int,
+                      block: int, num: int, group: int, scale: float):
+    """dq: the forward's grid and table."""
+    from jax.experimental import pallas as pl
+
+    i, qi, kk = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+
+    @pl.when(kk == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(fetch_ref[((i // group) * num + qi) * num + kk] == kk)
+    def _step():
+        _, ds = _select_pair(_select_seen(keep_ref, qi, kk, tile, block),
+                             q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref,
+                             scale)
+        acc_ref[...] += jax.lax.dot_general(
+            ds, k_ref[...], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(kk == num - 1)
+    def _finish():
+        dq_ref[...] = acc_ref[...].astype(dq_ref.dtype)
+
+
+def _select_dkv_kernel(fetch_ref, q_ref, k_ref, v_ref, keep_ref, do_ref,
+                       lse_ref, d_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
+                       tile: int, block: int, num: int, group: int,
+                       scale: float):
+    """dk/dv of ONE query head: grid (batch * heads, k tiles, q tiles), q
+    innermost, over the q tiles a row of which kept a block of the k tile."""
+    from jax.experimental import pallas as pl
+
+    i, ki, jj = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+
+    @pl.when(jj == 0)
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    @pl.when(fetch_ref[((i // group) * num + ki) * num + jj] == jj)
+    def _step():
+        p, ds = _select_pair(_select_seen(keep_ref, jj, ki, tile, block),
+                             q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref,
+                             scale)
+        dk_acc[...] += jax.lax.dot_general(
+            ds, q_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dv_acc[...] += jax.lax.dot_general(
+            p.astype(do_ref.dtype), do_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(jj == num - 1)
+    def _finish():
+        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _flat(x):
+    """``[b, s, heads, d]`` -> ``[b * heads, s, d]``."""
+    b, s, h, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+
+
+def _select_call(kernel, name, grid, in_specs, out_specs, out_shape, scratch,
+                 interpret, operands):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_KERNEL_VMEM_BUDGET),
+        name=name, interpret=interpret)(*operands)
+
+
+def _select_specs(tile: int, block: int, d: int, num: int, group: int,
+                  k_outer: bool):
+    """``(q-side spec of width w, k-side spec, the rows' choice's spec)`` of
+    a selected grid: the OUTER tile by its index, the inner one through the
+    table.  ``k_outer``: grid (i, k tile, q step), else (i, q tile, k
+    step)."""
+    from jax.experimental import pallas as pl
+    per = tile // block
+
+    def inner(i, outer, step, fetch_ref):
+        return fetch_ref[((i // group) * num + outer) * num + step]
+
+    if k_outer:
+        def q_map(i, ki, jj, fetch_ref):
+            return (i, inner(i, ki, jj, fetch_ref), 0)
+
+        def k_map(i, ki, jj, fetch_ref):
+            return (i // group, ki, 0)
+
+        def keep_map(i, ki, jj, fetch_ref):
+            return (i // group, inner(i, ki, jj, fetch_ref),
+                    (ki * per) // _KEEP_LANES)
+    else:
+        def q_map(i, qi, kk, fetch_ref):
+            return (i, qi, 0)
+
+        def k_map(i, qi, kk, fetch_ref):
+            return (i // group, inner(i, qi, kk, fetch_ref), 0)
+
+        def keep_map(i, qi, kk, fetch_ref):
+            return (i // group, qi,
+                    (inner(i, qi, kk, fetch_ref) * per) // _KEEP_LANES)
+
+    return (lambda w: pl.BlockSpec((None, tile, w), q_map),
+            pl.BlockSpec((None, tile, d), k_map),
+            pl.BlockSpec((None, tile, _KEEP_LANES), keep_map))
+
+
+def _select_fwd_impl(q, k, v, keep, scale, block, interpret):
+    """``(out [b, s, h, d], lse [b * h, s])`` of the selected forward."""
+    from jax.experimental.pallas import tpu as pltpu
+    b, s, h, d = q.shape
+    group = h // k.shape[2]
+    tile = select_tile(s, block)
+    num = s // tile
+    rows, fetch_k, _ = _select_tables(keep, tile, block)
+    q_spec, k_spec, keep_spec = _select_specs(tile, block, d, num, group,
+                                              False)
+    out, lse = _select_call(
+        functools.partial(_select_fwd_kernel, tile=tile, block=block,
+                          num=num, group=group, scale=scale),
+        "flash_fwd_select", (b * h, num, num),
+        [q_spec(d), k_spec, k_spec, keep_spec], [q_spec(d), q_spec(1)],
+        [jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
+         jax.ShapeDtypeStruct((b * h, s, 1), jnp.float32)],
+        [pltpu.VMEM((tile,), jnp.float32), pltpu.VMEM((tile,), jnp.float32),
+         pltpu.VMEM((tile, d), jnp.float32)],
+        interpret, (fetch_k, _flat(q), _flat(k), _flat(v), rows))
+    return out.reshape(b, h, s, d).transpose(0, 2, 1, 3), lse[..., 0]
+
+
+def _select_bwd_impl(q, k, v, keep, out, lse, dout, scale, block, interpret):
+    """``(dq, dk, dv)`` of the selected attention: a dq pass on the
+    forward's grid and a dk/dv pass on the k-outer one (no fused form: its
+    dq partials are a slot a k tile, 2 GB at 16 heads of 16,384 x 128), each
+    over the kept tiles only; dk and dv summed over a K/V head's group."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    b, s, h, d = q.shape
+    g = k.shape[2]
+    group = h // g
+    tile = select_tile(s, block)
+    num = s // tile
+    rows, fetch_k, fetch_q = _select_tables(keep, tile, block)
+    qt, kt, vt, dot = _flat(q), _flat(k), _flat(v), _flat(dout)
+    delta = jnp.sum(dot.astype(jnp.float32) * _flat(out).astype(jnp.float32),
+                    -1, keepdims=True)
+    lse3 = lse[..., None]
+    q_spec, k_spec, keep_spec = _select_specs(tile, block, d, num, group,
+                                              False)
+    dq = _select_call(
+        functools.partial(_select_dq_kernel, tile=tile, block=block, num=num,
+                          group=group, scale=scale),
+        "flash_bwd_dq_select", (b * h, num, num),
+        [q_spec(d), k_spec, k_spec, keep_spec, q_spec(d), q_spec(1),
+         q_spec(1)], q_spec(d),
+        jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
+        [pltpu.VMEM((tile, d), jnp.float32)],
+        interpret, (fetch_k, qt, kt, vt, rows, dot, lse3, delta))
+    q_spec, k_spec, keep_spec = _select_specs(tile, block, d, num, group,
+                                              True)
+    own = pl.BlockSpec((None, tile, d), lambda i, ki, jj, fetch_ref:
+                       (i, ki, 0))
+    dk, dv = _select_call(
+        functools.partial(_select_dkv_kernel, tile=tile, block=block,
+                          num=num, group=group, scale=scale),
+        "flash_bwd_dkv_select", (b * h, num, num),
+        [q_spec(d), k_spec, k_spec, keep_spec, q_spec(d), q_spec(1),
+         q_spec(1)], [own, own],
+        [jax.ShapeDtypeStruct((b * h, s, d), jnp.float32),
+         jax.ShapeDtypeStruct((b * h, s, d), jnp.float32)],
+        [pltpu.VMEM((tile, d), jnp.float32),
+         pltpu.VMEM((tile, d), jnp.float32)],
+        interpret, (fetch_q, qt, kt, vt, rows, dot, lse3, delta))
+
+    def heads(x, n, dtype):
+        return x.reshape(b, n, s, d).transpose(0, 2, 1, 3).astype(dtype)
+
+    def grouped(x, dtype):
+        return heads(x.reshape(b * g, group, s, d).sum(axis=1), g, dtype)
+
+    return heads(dq, h, q.dtype), grouped(dk, k.dtype), grouped(dv, v.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def flash_select(q, k, v, keep, scale, block, interpret):
+    """Block-selected causal attention: ``q [b, s, h, d]``, ``k`` / ``v``
+    ``[b, s, g, d]`` (``g`` divides ``h``), ``keep [b, g, s, s / block]``
+    bool -> ``[b, s, h, d]``.  No gradient reaches ``keep``."""
+    return _select_fwd_impl(q, k, v, keep, scale, block, interpret)[0]
+
+
+def _flash_select_fwd(q, k, v, keep, scale, block, interpret):
+    out, lse = _select_fwd_impl(q, k, v, keep, scale, block, interpret)
+    return out, (q, k, v, keep, out, lse)
+
+
+def _flash_select_bwd(scale, block, interpret, res, dout):
+    q, k, v, keep, out, lse = res
+    return _select_bwd_impl(q, k, v, keep, out, lse, dout, scale, block,
+                            interpret) + (None,)
+
+
+flash_select.defvjp(_flash_select_fwd, _flash_select_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def flash_select_precomputed(q, k, v, keep, out, lse, scale, block,
+                             interpret):
+    """``flash_precomputed`` for a selected call: the forward is the PROVIDED
+    ``(out, lse)``, the backward the selected pass under the provided
+    ``keep``."""
+    return out
+
+
+def _flash_select_pre_fwd(q, k, v, keep, out, lse, scale, block, interpret):
+    return out, (q, k, v, keep, out, lse)
+
+
+def _flash_select_pre_bwd(scale, block, interpret, res, dout):
+    q, k, v, keep, out, lse = res
+    return _select_bwd_impl(q, k, v, keep, out, lse, dout, scale, block,
+                            interpret) \
+        + (None, jnp.zeros_like(out), jnp.zeros_like(lse))
+
+
+flash_select_precomputed.defvjp(_flash_select_pre_fwd, _flash_select_pre_bwd)
+
+
+def _xla_select(q, k, v, keep, scale, block):
+    return _xla_select_with_lse(q, k, v, keep, scale, block)[0]
+
+
+def select_attention(q, k, v, keep, block: int,
+                     scale: typing.Optional[float] = None,
+                     interpret: typing.Optional[bool] = None,
+                     stash: typing.Optional[dict] = None):
+    """Dispatch of a block-selected call, as ``attention`` is of a causal
+    one: the ``flash_*_select`` kernels on a TPU at a sequence of whole
+    128-tiles, the dense masked XLA form elsewhere.  ``keep`` is a constant
+    of the call (the caller stops its gradient).  ``stash``: the channel of
+    ``attention`` — "name" names ``(out, lse)`` (``SAVED_NAMES``; the caller
+    names ``keep``, ``SELECT_NAME``) and returns the precomputed form, so a
+    block's replay runs no forward kernel and reads the saved choice.  The
+    revnet / momentum channel's "collect" / "provide" are not taken: there
+    the replay runs the plain call (and chooses again)."""
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
+    on_tpu = jax.default_backend() not in ("cpu",)
+    if interpret is None:
+        interpret = not on_tpu
+    s = q.shape[1]
+    kernels = on_tpu and s % 128 == 0
+
+    def forward(q, k, v, keep):
+        if kernels:
+            with jax.named_scope("flash_attention"):
+                return _select_fwd_impl(q, k, v, keep, scale, block,
+                                        interpret)
+        with jax.named_scope("attention_dense"):
+            return _xla_select_with_lse(q, k, v, keep, scale, block)
+
+    def backward_of(q, k, v, keep, out_s, lse_s):
+        if kernels:
+            with jax.named_scope("flash_attention"):
+                return flash_select_precomputed(q, k, v, keep, out_s, lse_s,
+                                                scale, block, interpret)
+        # off the TPU the dense form is its own backward: the saved pair is
+        # the value, the gradient flows through the recomputed one
+        with jax.named_scope("attention_dense"):
+            live = _xla_select(q, k, v, keep, scale, block)
+        return live + jax.lax.stop_gradient(out_s - live)
+
+    if stash_naming(stash) and s % 128 == 0 \
+            and s >= stash.get("min_keys", 0):
+        out_s, lse_s = forward(*(jax.lax.stop_gradient(t)
+                                 for t in (q, k, v)), keep)
+        out_s = checkpoint_name(out_s, SAVED_NAMES[0])
+        lse_s = checkpoint_name(lse_s, SAVED_NAMES[1])
+        return backward_of(q, k, v, keep, out_s, lse_s)
+    if not kernels:
+        with jax.named_scope("attention_dense"):
+            return _xla_select(q, k, v, keep, scale, block)
+    with jax.named_scope("flash_attention"):
+        return flash_select(q, k, v, keep, scale, block, interpret)

@@ -63,6 +63,12 @@ _CELLS = {
         "; moe held rows bound 16384; router carry 117440512 bytes",
         {"hbnlp_moe_held_rows_bound": 16384,
          "hbnlp_router_carry_bytes": 117440512}),
+    # PR 46: the sparse layer's (out, lse) and its choice (a bool a query and
+    # a block); three lightning layers' chunk states, and no conv of theirs
+    "train_minicpm_sala_tp2_long": (
+        _kinds(attention=(1, 72351744)),
+        "; ssd chunk states 67108864 bytes a device",
+        {"hbnlp_ssd_state_bytes": 67108864}),
 }
 #: the facts that read 0 where no layer has the mechanism; the others have no
 #: series there
@@ -116,8 +122,10 @@ def _config_files():
 
 
 #: sha1 of the line + the start-up series of every configuration file as it
-#: stands, TPU then CPU, from the parent
-_FILE_DIGEST = "14424d9e0db6655b911c55f5cf6f76f5b6505273"
+#: stands, TPU then CPU, from the parent (PR 46 added the two MiniCPM-SALA
+#: files; without them the digest is PR 42's
+#: 14424d9e0db6655b911c55f5cf6f76f5b6505273)
+_FILE_DIGEST = "0120375caf4cdf6e013cbb72aedfbae4ab651566"
 
 
 def every_configuration_file_starts_as_on_the_parent_test(monkeypatch):
@@ -157,7 +165,10 @@ _LAYER_STATS_IN = {
     "moe_top1_weight_mean": [0.3, 0.2],
     "cca_logit_scale": [3.0, 7.0],
     "ssd_log_decay_min": [-3.0, -9.0],
-    "delta_transform_abs_max": [2.0, 11.0]}
+    "delta_transform_abs_max": [2.0, 11.0],
+    "sparse_kept_key_share": [0.75, 0.5],
+    "sparse_choosing_query_share": [0.5, 0.75],
+    "lightning_state_abs_max": [4.0, 9.0]}
 
 
 def _info(layer_stats):
@@ -186,7 +197,13 @@ def _info(layer_stats):
     ("ssd_log_decay_min", "gauge", "hbnlp_ssd_log_decay_min",
      "c1d31e0cfa01", -9.0),
     ("delta_transform_abs_max", "gauge", "hbnlp_delta_transform_abs_max",
-     "2dce3a99aa4b", 11.0)])
+     "2dce3a99aa4b", 11.0),
+    ("sparse_kept_key_share", "gauge", "hbnlp_sparse_kept_key_share",
+     "b2ef4e49f658", 0.5),
+    ("sparse_choosing_query_share", "gauge",
+     "hbnlp_sparse_choosing_query_share", "62dca63d7d24", 0.75),
+    ("lightning_state_abs_max", "gauge", "hbnlp_lightning_state_abs_max",
+     "49c68f690d74", 9.0)])
 def declared_statistic_folds_as_on_the_parent_test(name, kind, metric, text,
                                                    value):
     """One declared statistic: the parent's fold over the layers, its kind,
@@ -198,9 +215,9 @@ def declared_statistic_folds_as_on_the_parent_test(name, kind, metric, text,
 
 
 def statistics_are_all_declared_test():
-    """The trainer's table is the declarations': nine statistics, and a step
-    whose layers report nothing (or only some) has only those."""
-    assert len(_LAYER_STATS) == 9 == len(declare.stats())
+    """The trainer's table is the declarations': twelve statistics, and a
+    step whose layers report nothing (or only some) has only those."""
+    assert len(_LAYER_STATS) == 12 == len(declare.stats())
     base = {"loss", "token_loss", "video_loss", "accuracy"}
     assert set(_info_metrics(_info(None))) == base
     some = {"ssd_log_decay_min": [-1.0]}

@@ -522,6 +522,58 @@ def saved_flash_outputs_keep_their_scope_test(v5e, monkeypatch, cell, layer,
                for name, op_name in calls["recompute"]) == 1
 
 
+def selected_kernels_keep_their_scope_and_names_test(v5e, monkeypatch):
+    """MiniCPM-SALA's sparse layer at its published widths and the cell's
+    16,384 tokens, compiled for a v5e as a TPU process traces it (PR 46):
+    Mosaic accepts the three selected kernels, each runs ONCE — the forward
+    outside the block's replay, which reads the saved ``(out, lse)`` and the
+    saved choice — all are named ``flash_*_select`` (the
+    ``sala_sparse_flash_*`` readers) and fold into
+    ``body/attention/sparse_attention/attend``; no causal kernel runs, and
+    the indexer's ops carry their steps.  Under ``"recompute"`` the replay
+    runs the forward kernel again."""
+    import re
+    from benchmark.lib.cell import load_cell
+    from homebrewnlp_tpu.model import remat
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    config = load_cell("train_minicpm_sala_tp2_long").model_config()
+    block = config["block_config"][0]
+    assert "sparse" in block["layer"][1]
+    kinds = {}
+    for policy in ("auto", "recompute"):
+        params = ModelParameter({**config, "block_config": [block],
+                                 "vocab_size": 512, "remat_policy": policy,
+                                 "model_path": "/tmp/granite"})
+        assert (remat.stash_plan(params)["attention"][0] == 1) \
+            == (policy == "auto")
+        model = Model(params)
+        batch = {k: np.zeros((1, params.sequence_length, 1), np.int32)
+                 for k in ("token_x", "token_y")}
+        variables = model.init(batch, seed=1)
+        avals = [{k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=v5e)
+                  for k, v in tree.items()} for tree in (variables, batch)]
+        hlo = jax.jit(jax.value_and_grad(
+            lambda v, b: model.apply(v, b).total_loss.data)).lower(
+            *avals).compile().as_text()
+        calls = re.findall(
+            r'%([\w.-]+) = [^\n]*?custom_call_target="tpu_custom_call"'
+            r'[^\n]*?op_name="([^"]+)"', hlo)
+        for name, op_name in calls:
+            assert re.match(r"flash_.*_select", name), name
+            assert scope_key(op_name) \
+                == "body/attention/sparse_attention/attend", op_name
+        kinds[policy] = sorted(re.sub(r"\.\d+$", "", name)
+                               for name, _ in calls)
+        names = set(re.findall(r'op_name="([^"]*)"', hlo))
+        for step in ("compress", "index", "select"):
+            assert any(scope_key(n)
+                       == f"body/attention/sparse_attention/{step}"
+                       for n in names), step
+    assert kinds["auto"] == ["flash_bwd_dkv_select", "flash_bwd_dq_select",
+                             "flash_fwd_select"]
+    assert kinds["recompute"] == kinds["auto"] + ["flash_fwd_select"]
+
+
 def experts_rule_declines_without_a_moe_layer_test():
     """``checkpoint`` with no ``moe`` layer: nothing rides, the policy stays
     the named one, and the chunk states' gauge counts one layer's."""
