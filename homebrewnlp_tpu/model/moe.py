@@ -19,10 +19,27 @@ Still dropless, with static shapes: a token's choices are distinct, so at
 most ``min(moe_top_k, experts_held)`` of them are held, and the row buffer
 has that many SLOTS a token — ``held_rows_bound`` rows, which no routing can
 overflow.  Each token's held choices fill its slots from the left, the rest
-hold a sentinel group that sorts last; the grouped matmuls run over the held
-groups' rows only (megablox visits tiles of real rows, the rows past them
-are never written), and neither ``_combine`` nor the dispatch's backward
-reads a sentinel slot's row.
+hold a sentinel group that sorts last, so the held pairs are the first
+``n_real`` rows of the sorted buffer; megablox visits the held groups' row
+tiles, the rows past them are never written, and nothing reads a sentinel
+slot's row.  What surrounds the kernels takes one of two forms, by the fill
+the configuration fixes (``walks_real_rows``: ``experts_held / experts x
+moe_top_k / slots`` of the buffer when the router is balanced):
+
+* at most an eighth full (Laguna's rank: 3.9%), everything WALKS THE REAL
+  ROWS: dispatch, the fan-out to the gate and the up matmul, the gated
+  activation and combine — each a ``custom_vjp`` with both directions by
+  hand — visit ``ceil(n_real / tile)`` tiles of 512 rows in a ``lax`` loop
+  whose trip count is known only on the device (forward, a block's replay
+  and backward alike, on every backend).  The buffers keep the bound's
+  rows; what lies past the last visited tile is never written (the buffers
+  start uninitialised on a TPU, ``_fresh``) and never read, and the last
+  tile's rows past ``n_real`` may hold anything: sums drop them by index;
+* fuller than that (ZAYA1's rank: half), the whole buffer is gathered both
+  ways as where every expert is held (``_dispatch`` / ``_combine``), the
+  unreal slots selected away (``real``): a walked row's sum is XLA's row
+  scatter-add, one row after another, and loses to the gather above ~10%
+  real rows.
 
 ``moe_norm_topk`` renormalises the chosen probabilities to sum to one,
 ``moe_route_scale`` multiplies them; flag ``shared_expert`` (the DSL's
@@ -137,6 +154,214 @@ def _combine_bwd(k, res, g):
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+# ---- a layer that holds a small share: the same passes over the real rows ----
+#
+# The held pairs are the first ``n_real`` rows of the sorted buffer (the
+# sentinel group sorts last).  Everything round the grouped matmuls walks
+# ``ceil(n_real / tile)`` row tiles of it in a ``lax`` loop whose trip count
+# only the device knows, and never reads a row past them: the buffer starts
+# uninitialised (``_fresh``), the last tile's rows past ``n_real`` are
+# dropped by index, not multiplied away (they may hold anything).  A sum over
+# a token's slots is the scatter direction here — a loop over the real rows
+# adds each into its token — where the all-held forms above gather every
+# slot; each pass is a ``custom_vjp`` with the backward's loop by hand, so
+# nothing differentiates through a loop.
+
+def _row_tile(rows: int) -> int:
+    """Rows of one tile of the held path's loops: the grouped matmul's own
+    row tile, or the largest part of it that divides the buffer."""
+    return math.gcd(rows, _GMM_TILE[0])
+
+
+def _real_tiles(n_real, rows: int):
+    """How many tiles of a ``rows``-row buffer hold one of its ``n_real``
+    real rows (they come first): the trip count of every loop below."""
+    tile = _row_tile(rows)
+    return (n_real + tile - 1) // tile
+
+
+def _over_real_tiles(n_real, rows: int, body, init):
+    """``body(first row, rows of the tile, carry) -> carry`` over those
+    tiles."""
+    tile = _row_tile(rows)
+    return jax.lax.fori_loop(
+        0, _real_tiles(n_real, rows),
+        lambda i, carry: body(i * tile, tile, carry), init)
+
+
+def _cut(buffer, at, tile: int):
+    """Rows ``at .. at + tile - 1`` of ``buffer [rows, width]``."""
+    return jax.lax.dynamic_slice(buffer, (at, 0), (tile, buffer.shape[1]))
+
+
+def _fresh(after, shape, dtype, name: str = "moe_held_rows_alloc"):
+    """An uninitialised ``shape`` buffer for a loop to fill, which exists
+    only once ``after`` does.  ``lax.empty`` alone is an operand-less
+    ``AllocateBuffer`` on a TPU, and XLA schedules every one of a step's at
+    its start: all layers' row buffers alive at once, 19 GB for the Laguna
+    cell's step (compiled for a described v5e, PR 47).  A Pallas call that
+    does nothing is a custom call like any other: scheduled where its
+    operand is ready, it hands out its never-written output.  Two of one
+    shape after one array take two names, or XLA makes them one call and
+    copies its output."""
+    if jax.default_backend() != "tpu":
+        return jax.lax.empty(shape, dtype)
+    from jax.experimental import pallas as pl
+    return pl.pallas_call(
+        lambda after_ref, out_ref: None,
+        out_shape=jax.ShapeDtypeStruct(shape, dtype),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        name=name)(after)
+
+
+def _real_pairs(order, n_real, at, tile: int, k: int):
+    """The tile's pairs and their tokens, each ``[tile]``; a row past the
+    real ones gets the first index out of range, which a scatter drops (a
+    gather clips it)."""
+    pairs = jax.lax.dynamic_slice(order, (at,), (tile,))
+    live = at + jnp.arange(tile, dtype=jnp.int32) < n_real
+    return jnp.where(live, pairs, order.shape[0]), \
+        jnp.where(live, pairs // k, order.shape[0] // k)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch_held(x, order, n_real, k: int):
+    """Rows of ``x [t, f]`` for the real prefix of the sorted pairs, in a
+    ``[t * k, f]`` buffer whose other tiles are never written."""
+    def body(at, tile, rows):
+        _, tokens = _real_pairs(order, n_real, at, tile, k)
+        return jax.lax.dynamic_update_slice(
+            rows, x.at[tokens].get(mode="clip"), (at, 0))
+    return _over_real_tiles(
+        n_real, order.shape[0], body,
+        _fresh(x, (order.shape[0], x.shape[1]), x.dtype))
+
+
+def _dispatch_held_fwd(x, order, n_real, k):
+    return _dispatch_held(x, order, n_real, k), (order, n_real)
+
+
+def _dispatch_held_bwd(k, res, g):
+    order, n_real = res
+
+    def body(at, tile, d_x):
+        _, tokens = _real_pairs(order, n_real, at, tile, k)
+        return d_x.at[tokens].add(_cut(g, at, tile).astype(jnp.float32),
+                                  mode="drop")
+    d_x = _over_real_tiles(
+        n_real, order.shape[0], body,
+        jnp.zeros((order.shape[0] // k, g.shape[1]), jnp.float32))
+    return d_x.astype(g.dtype), None, None
+
+
+_dispatch_held.defvjp(_dispatch_held_fwd, _dispatch_held_bwd)
+
+
+@jax.custom_vjp
+def _twice_held(rows, n_real):
+    """``(rows, rows)`` for the gate and the up matmul: their two cotangents
+    are added over the tiles that hold real rows, where autodiff's own sum
+    passes over the whole buffer."""
+    return rows, rows
+
+
+def _twice_held_fwd(rows, n_real):
+    return (rows, rows), n_real
+
+
+def _twice_held_bwd(n_real, grads):
+    def body(at, tile, total):
+        # in place: the first cotangent's buffer takes the sum
+        return jax.lax.dynamic_update_slice(
+            total, _cut(total, at, tile) + _cut(grads[1], at, tile), (at, 0))
+    return _over_real_tiles(n_real, grads[0].shape[0], body, grads[0]), None
+
+
+_twice_held.defvjp(_twice_held_fwd, _twice_held_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _gated_held(gated, gate, up, n_real):
+    """``gated(gate, up)`` (``act(gate) * up``, a function of two row tiles)
+    on the tiles that hold real rows of ``gate`` and ``up [rows, width]``."""
+    def body(at, tile, hidden):
+        return jax.lax.dynamic_update_slice(
+            hidden, gated(_cut(gate, at, tile), _cut(up, at, tile)), (at, 0))
+    return _over_real_tiles(n_real, gate.shape[0], body,
+                            _fresh(gate, gate.shape, gate.dtype))
+
+
+def _gated_held_fwd(gated, gate, up, n_real):
+    return _gated_held(gated, gate, up, n_real), (gate, up, n_real)
+
+
+def _gated_held_bwd(gated, res, g):
+    gate, up, n_real = res
+
+    def body(at, tile, grads):
+        parts = jax.vjp(gated, _cut(gate, at, tile), _cut(up, at, tile))[1](
+            _cut(g, at, tile))
+        return tuple(jax.lax.dynamic_update_slice(d, part, (at, 0))
+                     for d, part in zip(grads, parts))
+    return (*_over_real_tiles(
+        n_real, gate.shape[0], body,
+        (_fresh(g, gate.shape, gate.dtype),
+         _fresh(g, up.shape, up.dtype, "moe_held_rows_alloc_up"))), None)
+
+
+_gated_held.defvjp(_gated_held_fwd, _gated_held_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _combine_held(rows, weights, order, n_real, k: int):
+    """``out[t] = sum of weights[t, j] * rows[position of (t, j)]`` over the
+    token's real slots: each real row of ``rows [t * k, f]`` weighted and
+    added into its token, float32 sums returned in ``rows``' dtype."""
+    flat = weights.reshape(-1)
+
+    def body(at, tile, out):
+        pairs, tokens = _real_pairs(order, n_real, at, tile, k)
+        return out.at[tokens].add(
+            _cut(rows, at, tile).astype(jnp.float32)
+            * flat.at[pairs].get(mode="clip")[:, None], mode="drop")
+    return _over_real_tiles(
+        n_real, rows.shape[0], body,
+        jnp.zeros((rows.shape[0] // k, rows.shape[1]), jnp.float32)
+    ).astype(rows.dtype)
+
+
+def _combine_held_fwd(rows, weights, order, n_real, k):
+    return _combine_held(rows, weights, order, n_real, k), \
+        (rows, weights, order, n_real)
+
+
+def _combine_held_bwd(k, res, g):
+    rows, weights, order, n_real = res
+    flat = weights.reshape(-1)
+
+    def body(at, tile, grads):
+        d_rows, d_flat = grads
+        pairs, tokens = _real_pairs(order, n_real, at, tile, k)
+        # every real pair's row of g, a gather from [t, f]
+        spread = g.at[tokens].get(mode="clip").astype(jnp.float32)
+        d_rows = jax.lax.dynamic_update_slice(
+            d_rows, (spread * flat.at[pairs].get(mode="clip")[:, None]
+                     ).astype(rows.dtype), (at, 0))
+        return d_rows, d_flat.at[pairs].set(
+            jnp.sum(_cut(rows, at, tile).astype(jnp.float32) * spread,
+                    axis=-1), mode="drop")
+    d_rows, d_flat = _over_real_tiles(
+        n_real, rows.shape[0], body,
+        (_fresh(g, rows.shape, rows.dtype),
+         jnp.zeros(flat.shape, jnp.float32)))
+    return d_rows, d_flat.reshape(weights.shape).astype(weights.dtype), \
+        None, None
+
+
+_combine_held.defvjp(_combine_held_fwd, _combine_held_bwd)
 
 
 #: the names layer ``moe`` gives, by ``checkpoint_name``, to what is dear to
@@ -275,20 +500,40 @@ def held_rows_bound(tokens: int, top_k: int, held: int) -> int:
     return tokens * min(top_k, held)
 
 
+def walks_real_rows(experts: int, held: int, top_k: int) -> bool:
+    """Whether a layer that holds ``held`` of ``experts`` experts walks the
+    real rows of its static buffer (``_dispatch_held`` and the passes after
+    it) or moves the whole buffer (``_dispatch`` / ``_combine`` with
+    ``real``): by the fill the configuration fixes, ``held / experts x top_k
+    / slots`` of the buffer when the router is balanced, at most an eighth.
+    A walked row costs a sum 0.25-0.33 us (XLA's row scatter-add, one row
+    after another), a gathered slot 0.04-0.06: on a v5e the passes meet at
+    9-14% real rows (my chip runs, PR 47: the Laguna cell, 3.9% by this rule,
+    +22% with the walk; the ZAYA1 cell, 50%, -8.4% with it)."""
+    return 0 < held < experts \
+        and 8 * held * top_k <= experts * min(top_k, held)
+
+
 def held_slots(weights, experts, first: int, held: int):
     """A token's choices among experts ``first .. first + held - 1``, moved
-    to the left of ``min(top_k, held)`` slots: ``(weights, local expert,
-    real)``, each ``[t, slots]``; a slot without a held choice has weight 0,
-    expert ``held`` (the sentinel group, which sorts last) and ``real``
-    false."""
+    to the left of ``min(top_k, held)`` slots in the order it made them:
+    ``(weights, local expert, real)``, each ``[t, slots]``; a slot without a
+    held choice has weight 0, expert ``held`` (the sentinel group, which
+    sorts last) and ``real`` false.  By counting — a held choice's slot is
+    the number of held choices before it — and selecting: no sort and no
+    gather over ``[t, top_k]`` (two ``take_along_axis`` here were 24 ms of
+    the Laguna cell's step; my chip run, PR 47)."""
     local = experts - first
-    local = jnp.where((local >= 0) & (local < held), local, held)
+    inside = (local >= 0) & (local < held)
     slots = min(experts.shape[-1], held)
-    pick = jnp.argsort(local, axis=-1, stable=True)[:, :slots]
-    local = jnp.take_along_axis(local, pick, axis=-1)
-    real = local < held
-    return jnp.where(real, jnp.take_along_axis(weights, pick, axis=-1), 0.0), \
-        local, real
+    # [t, slots, top_k]: choice c of the token sits in slot j
+    sits = inside[:, None, :] & (
+        (jnp.cumsum(inside, axis=-1, dtype=jnp.int32) - 1)[:, None, :]
+        == jnp.arange(slots, dtype=jnp.int32)[:, None])
+    real = jnp.any(sits, axis=-1)
+    return jnp.sum(jnp.where(sits, weights[:, None, :], 0.0), axis=-1), \
+        jnp.where(real, jnp.sum(jnp.where(sits, local[:, None, :], 0),
+                                axis=-1), held), real
 
 
 def sort_pairs(experts, n_experts: int):
@@ -301,6 +546,21 @@ def sort_pairs(experts, n_experts: int):
         jnp.arange(order.shape[0], dtype=jnp.int32))
     sizes = jnp.zeros((n_experts,), jnp.int32).at[flat].add(1)
     return order, inverse, sizes
+
+
+def sort_held(local, held: int):
+    """The slots of ``held_slots`` sorted by expert, stably: ``order``
+    (sorted position -> slot ``t * slots + j``; the held pairs come first,
+    the sentinel slots after them, where nothing reads it) and the pair
+    counts of the ``held`` experts, the sentinel group's last.  The counts
+    by comparing, not by a scatter-add over the bound (1.15 ms a call on a
+    v5e, the sort 0.1; my chip run, PR 47); no ``inverse``: every sum over a
+    token's slots walks the real rows and adds (``_combine_held``)."""
+    flat = local.reshape(-1)
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    sizes = jnp.sum(flat[:, None] == jnp.arange(held + 1, dtype=flat.dtype),
+                    axis=0, dtype=jnp.int32)
+    return order, sizes
 
 
 def _shared_expert(args: BlockArgs, act, xf, anon, inter, feats):
@@ -385,17 +645,25 @@ def moe(args: BlockArgs) -> NamedTensor:
         # 1 / experts = a router that says nothing
         ctx.layer_stats.append({"moe_top1_weight_mean": jnp.mean(weights)})
     # a layer that holds a share sorts SLOTS (held_slots), not choices, into
-    # held + 1 groups, the sentinel last; its kernels see the held groups
-    real, slots, groups = None, top_k, n_exp
+    # held + 1 groups, the sentinel last; its kernels see the held groups,
+    # and where the buffer is mostly empty so does everything round them
+    tiled = walks_real_rows(n_exp, held, top_k)
+    real, slots, groups, n_real = None, top_k, n_exp, None
     with jax.named_scope("dispatch"):
         if partial:
             weights, experts, real = held_slots(weights, experts, first, held)
             slots, groups = weights.shape[-1], held + 1
-        order, inverse, sizes = sort_pairs(experts, groups)
-        order = checkpoint_name(order, "moe_order")
-        inverse = checkpoint_name(inverse, "moe_inverse")
-        sizes = checkpoint_name(sizes, "moe_sizes")
-        rows = _dispatch(xf, order, inverse, slots, real)
+        if tiled:
+            order, sizes = (checkpoint_name(a, name) for a, name in zip(
+                sort_held(experts, held), ("moe_order", "moe_sizes")))
+            n_real = jnp.sum(sizes[:held])
+            rows = _dispatch_held(xf, order, n_real, slots)
+        else:
+            order, inverse, sizes = sort_pairs(experts, groups)
+            order = checkpoint_name(order, "moe_order")
+            inverse = checkpoint_name(inverse, "moe_inverse")
+            sizes = checkpoint_name(sizes, "moe_sizes")
+            rows = _dispatch(xf, order, inverse, slots, real)
         if partial:
             sizes = sizes[:held]
     if ctx.layer_stats is not None and partial:
@@ -406,24 +674,37 @@ def moe(args: BlockArgs) -> NamedTensor:
                 jnp.max(sizes).astype(jnp.float32) * held
                 / jnp.maximum(held_pairs, 1.0),
             "moe_routed_pairs": jnp.float32(t_sz * top_k),
-            "moe_held_pairs": held_pairs})
+            "moe_held_pairs": held_pairs,
+            # the tiled passes' trip count, and the tiles of the bound
+            **({"moe_held_row_tiles":
+                _real_tiles(n_real, t_sz * slots).astype(jnp.float32),
+                "moe_held_bound_tiles":
+                jnp.float32(t_sz * slots // _row_tile(t_sz * slots))}
+               if tiled else {})})
     elif ctx.layer_stats is not None:
         ctx.layer_stats.append({
             # the largest expert's pair count over the mean: 1.0 = balanced
             "moe_load_max_over_mean":
                 jnp.max(sizes).astype(jnp.float32) * n_exp / (t_sz * top_k),
             "moe_routed_pairs": jnp.sum(sizes).astype(jnp.float32)})
+
+    def gated(gate, up):
+        return act(args(nt(gate, [Dim("_pairs", gate.shape[0]),
+                                  Dim("_width", i_sz)]))).data * up
+
     with jax.named_scope("experts"):
+        rows, rows_up = _twice_held(rows, n_real) if tiled else (rows, rows)
         gate = checkpoint_name(grouped_dot(
             rows, w_gate.data.reshape(held, f_sz, i_sz), sizes), "moe_gate")
         up = checkpoint_name(grouped_dot(
-            rows, w_up.data.reshape(held, f_sz, i_sz), sizes), "moe_up")
-        hidden = act(args(nt(gate, [Dim("_pairs", t_sz * slots),
-                                    Dim("_width", i_sz)]))).data * up
+            rows_up, w_up.data.reshape(held, f_sz, i_sz), sizes), "moe_up")
+        hidden = _gated_held(gated, gate, up, n_real) if tiled \
+            else gated(gate, up)
         out = checkpoint_name(grouped_dot(
             hidden, w_down.data.reshape(held, i_sz, f_sz), sizes), "moe_down")
     with jax.named_scope("combine"):
-        out = _combine(out, weights, order, inverse, slots, real)
+        out = _combine_held(out, weights, order, n_real, slots) if tiled \
+            else _combine(out, weights, order, inverse, slots, real)
     if "shared_expert" in args.name_extras:
         with jax.named_scope("shared"):
             out = out + _shared_expert(args, act, xf, anon, inter, feats)
@@ -464,7 +745,10 @@ def _offer(params, extras) -> Offer:
     (``experts`` ``[tokens, moe_top_k]``, int32), ``pairs = tokens x
     min(moe_top_k, experts)``: ``SAVED_NAMES``.  A layer that holds a share
     of the experts saves its whole static buffer: ``moe_held_rows`` rows,
-    ``experts_held + 1`` sizes."""
+    ``experts_held + 1`` sizes; where it walks the real rows it builds no
+    ``inverse`` (``sort_held``), whose 4 bytes a row of ~10 KB the offer
+    still counts, so that no decision of model/remat.py moved with ISSUE
+    47."""
     held_rows = moe_held_rows(params)
     choices = params.batch_dim.size * params.sequence_dim.size \
         * min(params.moe_top_k, params.expert_dim.size)
@@ -506,6 +790,19 @@ moe.declares = Layer(
              lambda stats, done: jnp.max(stats["moe_held_pairs"]
                                          / stats["moe_routed_pairs"]),
              "moe_held_pairs"),
+        # how often the held path's loops engage: the row tiles dispatch,
+        # the activation and combine visit, and their share of the buffer's
+        Stat("moe_held_row_tiles", "counter", "hbnlp_moe_held_row_tiles_total",
+             "row tiles of the static dispatch buffer that hold a real row "
+             "(ceil(held pairs / tile), the tiles every pass over the buffer "
+             "visits), all moe layers that hold a share of the experts",
+             "sum"),
+        Stat("moe_held_tile_share", "gauge", "hbnlp_moe_held_tile_share",
+             "row tiles visited over the row tiles of the static dispatch "
+             "buffer (hbnlp_moe_held_rows_bound / tile), all moe layers of "
+             "the newest finished step that hold a share of the experts",
+             lambda stats, done: done["moe_held_row_tiles"]
+             / jnp.sum(stats["moe_held_bound_tiles"]), "moe_held_row_tiles"),
         # the layer whose router says least
         Stat("moe_top1_weight_mean", "gauge", "hbnlp_moe_top1_weight_mean",
              "mean probability of the chosen expert over the tokens of the "

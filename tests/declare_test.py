@@ -162,6 +162,8 @@ _LAYER_STATS_IN = {
     "moe_load_max_over_mean": [1.5, 2.5, 1.25],
     "moe_routed_pairs": [1024.0, 1024.0, 2048.0],
     "moe_held_pairs": [256.0, 512.0, 256.0],
+    "moe_held_row_tiles": [1.0, 1.0, 1.0],
+    "moe_held_bound_tiles": [2.0, 2.0, 8.0],
     "moe_top1_weight_mean": [0.3, 0.2],
     "cca_logit_scale": [3.0, 7.0],
     "ssd_log_decay_min": [-3.0, -9.0],
@@ -190,6 +192,10 @@ def _info(layer_stats):
      "180250f2524a", 0.25),
     ("moe_held_pair_share_max", "gauge", "hbnlp_moe_held_pair_share_max",
      "4eb530aac659", 0.5),
+    ("moe_held_row_tiles", "counter", "hbnlp_moe_held_row_tiles_total",
+     "30245da96d7a", 3.0),
+    ("moe_held_tile_share", "gauge", "hbnlp_moe_held_tile_share",
+     "f1450e20cc47", 0.25),
     ("moe_top1_weight_mean", "gauge", "hbnlp_moe_top1_weight_mean",
      "8f9d99ebcdda", 0.20000000298023224),
     ("cca_logit_scale_max", "gauge", "hbnlp_cca_logit_scale_max",
@@ -215,9 +221,9 @@ def declared_statistic_folds_as_on_the_parent_test(name, kind, metric, text,
 
 
 def statistics_are_all_declared_test():
-    """The trainer's table is the declarations': twelve statistics, and a
+    """The trainer's table is the declarations': fourteen statistics, and a
     step whose layers report nothing (or only some) has only those."""
-    assert len(_LAYER_STATS) == 12 == len(declare.stats())
+    assert len(_LAYER_STATS) == 14 == len(declare.stats())
     base = {"loss", "token_loss", "video_loss", "accuracy"}
     assert set(_info_metrics(_info(None))) == base
     some = {"ssd_log_decay_min": [-1.0]}

@@ -467,6 +467,59 @@ def delta_layers_kernels_keep_their_scopes_test(v5e, monkeypatch):
         == [(False, False, False), (False, True, True), (True, False, True)]
 
 
+def held_row_buffers_are_allocated_where_they_are_filled_test(v5e,
+                                                              monkeypatch):
+    """One ``moe`` layer of the Laguna cell (8 of 256 experts held, eight
+    slots a token) at 1 x 8,192 tokens, compiled for a v5e as a TPU process
+    traces it (ISSUE 47): the held path's loops are ``while`` ops in the
+    layer's three scopes; their row buffers come from ``moe_held_rows_alloc``
+    calls — a custom call with an operand, scheduled where it is filled — and
+    not from operand-less ``AllocateBuffer``s, which XLA schedules at the
+    step's start (every layer's buffers alive at once: the cell's step then
+    needs 19 GB); no buffer of the bound's rows is copied."""
+    import re
+    from benchmark.lib.cell import load_cell
+    from homebrewnlp_tpu.model import moe as moe_mod
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cell = load_cell("train_laguna_s_2_1_ep32_s8k").model_config()
+    block = cell["block_config"][1]
+    assert block["layer"][-1].startswith("moe")
+    params = ModelParameter({**cell, "block_config": [block],
+                             "input_block_config": [], "vocab_size": 512,
+                             "train_batch_size": 1,
+                             "model_path": "/tmp/laguna"})
+    rows = moe_mod.moe_held_rows(params)
+    assert rows == 8192 * 8
+    model = Model(params)
+    batch = {k: np.zeros((1, 8192, 1), np.int32)
+             for k in ("token_x", "token_y")}
+    variables = model.init(batch, seed=1)
+    avals = [{k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=v5e)
+              for k, v in tree.items()} for tree in (variables, batch)]
+    hlo = jax.jit(jax.value_and_grad(
+        lambda v, b: model.apply(v, b).total_loss.data)).lower(
+        *avals).compile().as_text()
+    buffer = rf"bf16\[{rows},(?:3072|1024)\]"
+    assert not re.search(rf"= {buffer}\S* custom-call\(\)", hlo)
+    assert not re.search(rf"= {buffer}\S* copy\(", hlo)
+    allocs = re.findall(rf'= {buffer}[^\n]*?custom_call_target='
+                        r'"tpu_custom_call"[^\n]*?op_name="([^"]+'
+                        r'moe_held_rows_alloc[^"]*)"', hlo)
+    # dispatch forward and replay; the activation forward, replay and its
+    # backward's two; combine's backward
+    assert sorted(scope_key(op) for op in allocs) \
+        == ["body/moe/combine"] + ["body/moe/dispatch"] * 2 \
+        + ["body/moe/experts"] * 4
+    loops = re.findall(r'= [^\n]*? while\([^\n]*?op_name="([^"]+moe_0[^"]+)"',
+                       hlo)
+    held = [op for op in loops if "searchsorted" not in op]
+    assert {scope_key(op) for op in held} == {
+        "body/moe/dispatch", "body/moe/experts", "body/moe/combine"}
+    # dispatch 3 (forward, replay, backward), the fan-out's backward 1, the
+    # activation 3, combine 2 (its forward is not replayed)
+    assert len(held) == 9
+
+
 @pytest.mark.parametrize("cell,layer,scope", [
     ("train_granite_4_0_h_micro_long", "attention-nope", "body/attention"),
     ("train_olmo_hybrid_7b_long", "attention-nope-qk_norm", "body/attention"),

@@ -95,13 +95,18 @@ def _error(got, want) -> float:
     ("float32", 2e-5, {"experts_held": 4, "experts_first": 8}),
     # fewer held than a token's choices: two slots a token
     ("float32", 2e-5, {"experts_held": 2, "experts_first": 3}),
+    # a buffer an eighth full or less when balanced (4 of 64 held, as the
+    # cell's 8 of 256): dispatch, the activation and combine walk the real
+    # rows' tiles (moe.walks_real_rows); the cases above move the buffer
+    ("float32", 2e-5, {"experts": 64}),
+    ("bfloat16", 2 ** -4, {"experts": 64, "experts_first": 30}),
     # two periods: the second one's layers have weights of their own (the
     # shared expert's flag is not the DSL's cross-layer ``shared``)
     ("float32", 2e-5, {"depth": 2}),
     # the configuration's bfloat16, at the cells' bound
     ("bfloat16", 2 ** -4, {})],
     ids=["float32", "below_window", "at_window", "all_held", "third_share",
-         "two_slots", "two_periods", "bfloat16"])
+         "two_slots", "walked", "walked_bfloat16", "two_periods", "bfloat16"])
 def program_matches_reference_test(dtype, tolerance, extra):
     config, _, model, batch, variables = _build(dtype, **extra)
     assert sum("moe_0/normal_var4" in name for name in variables) \
@@ -117,8 +122,13 @@ def program_matches_reference_test(dtype, tolerance, extra):
                                      else 2.0 ** -5)
 
 
-def loss_and_gradients_match_reference_test():
-    config, _, model, batch, variables = _build()
+@pytest.mark.parametrize("extra", [{}, {"experts": 64}],
+                         ids=["whole_buffer", "walked"])
+def loss_and_gradients_match_reference_test(extra):
+    config, params, model, batch, variables = _build(**extra)
+    assert moe_mod.walks_real_rows(
+        config["experts"], config["experts_held"], config["moe_top_k"]) \
+        == bool(extra)
     ref = _reference()
     tokens, targets = batch["token_x"][..., 0], batch["token_y"][..., 0]
     got = jax.jit(jax.grad(lambda v: model.apply(v, batch).total_loss.data))(
@@ -235,13 +245,16 @@ def held_slots_keep_every_held_choice_test(first, held):
     assert moe_mod.held_rows_bound(t, k, held) == t * min(k, held)
 
 
-def every_held_pair_is_computed_when_all_land_here_test():
+@pytest.mark.parametrize("experts", [16, 64], ids=["whole_buffer", "walked"])
+def every_held_pair_is_computed_when_all_land_here_test(experts):
     """The router sends EVERY token's every choice to the held experts: the
     static buffer is full to its last row and nothing is dropped; with none
     landing here the routed part is exactly zero (and finite, whatever the
-    unwritten rows hold) and so are the held experts' gradients."""
+    unwritten rows hold) and so are the held experts' gradients — where the
+    layer moves the whole buffer and where it walks the real rows."""
     ref = _reference()
-    config, params, model, batch, variables = _build(experts_held=4)
+    config, params, model, batch, variables = _build(experts_held=4,
+                                                     experts=experts)
     tokens, targets = batch["token_x"][..., 0], batch["token_y"][..., 0]
     for bias, share in ((+40.0, 1.0), (-40.0, 0.0)):
         skewed = dict(variables)
@@ -264,6 +277,120 @@ def every_held_pair_is_computed_when_all_land_here_test():
             skewed)
         assert all(np.all(np.isfinite(np.asarray(g))) for g in grads.values())
         del share
+
+
+def _choices(rng, tokens, n_exp, top_k, first, held, n_held):
+    """``[tokens, top_k]`` distinct experts a token of which exactly
+    ``n_held`` pairs in all lie in ``first .. first + held - 1``, spread
+    over the tokens at random (at most ``min(top_k, held)`` a token)."""
+    slots = min(top_k, held)
+    counts = np.zeros(tokens, np.int64)
+    for spot in rng.permutation(tokens * slots)[:n_held]:
+        counts[spot // slots] += 1
+    inside = np.arange(first, first + held)
+    outside = np.setdiff1d(np.arange(n_exp), inside)
+    rows = [rng.permutation(np.concatenate([
+        rng.permutation(inside)[:c], rng.permutation(outside)[:top_k - c]]))
+        for c in counts]
+    return jnp.asarray(np.stack(rows).astype(np.int32))
+
+
+def _held_part(x, weights, experts, mats, first, held, dense: bool):
+    """The routed part of a layer that holds a share, through the shipped
+    tiled passes or — ``dense`` — the parent's forms: every slot's row
+    gathered, activated and summed, the unreal ones selected away, plain
+    ``jax.numpy`` under autodiff."""
+    w, local, real = moe_mod.held_slots(weights, experts, first, held)
+    slots = w.shape[-1]
+
+    def gated(gate, up):
+        return jax.nn.silu(gate) * up
+
+    if dense:
+        order, inverse, sizes = moe_mod.sort_pairs(local, held + 1)
+        rows, n_real = x[order // slots], None
+    else:
+        order, sizes = moe_mod.sort_held(local, held)
+        n_real = jnp.sum(sizes[:held])
+        rows = moe_mod._twice_held(
+            moe_mod._dispatch_held(x, order, n_real, slots), n_real)
+    sizes = sizes[:held]
+    gate = moe_mod.grouped_dot(rows if dense else rows[0], mats[0], sizes)
+    up = moe_mod.grouped_dot(rows if dense else rows[1], mats[1], sizes)
+    hidden = gated(gate, up) if dense \
+        else moe_mod._gated_held(gated, gate, up, n_real)
+    out = moe_mod.grouped_dot(hidden, mats[2], sizes)
+    if not dense:
+        return moe_mod._combine_held(out, w, order, n_real, slots)
+    pairs = out[inverse].reshape(-1, slots, out.shape[-1]).astype(
+        jnp.float32) * w[..., None]
+    return jnp.sum(jnp.where(real[..., None], pairs, 0.0), axis=1).astype(
+        out.dtype)
+
+
+@pytest.mark.parametrize("dtype,tolerance", [
+    # the same products at the same rounding points: what differs is the
+    # order of a token's float32 sum over its slots
+    ("float32", 1e-5), ("bfloat16", 2 ** -7)])
+@pytest.mark.parametrize("fill", ["none", "one", "tile", "tile+1", "bound"])
+@pytest.mark.parametrize("n_exp,top_k,first,held", [
+    (16, 4, 4, 4),      # Laguna's class: 4 of 16 held, four slots a token
+    (16, 4, 14, 2),     # two held, two slots
+    (16, 1, 0, 8)])     # ZAYA1's: top-1, half held, one slot
+def the_tiled_passes_are_the_dense_ones_test(n_exp, top_k, first, held, fill,
+                                             dtype, tolerance, monkeypatch):
+    """Dispatch, the gated activation and combine over ``ceil(real rows /
+    tile)`` tiles against the parent's dense forms: the value and the
+    gradients of the input, the weights and the three expert matrices, at
+    the fills where a loop's trip count or its last tile's mask could be
+    off by one.  The buffer starts as NaN here (``lax.empty`` hands out
+    zeros on the CPU, anything on a TPU): whatever reads a row past the
+    real ones fails."""
+    tokens, features, width = 1536 // min(top_k, held), 16, 24
+    bound = moe_mod.held_rows_bound(tokens, top_k, held)
+    tile = moe_mod._row_tile(bound)
+    assert (bound, tile) == (1536, 512)
+    n_held = {"none": 0, "one": 1, "tile": tile, "tile+1": tile + 1,
+              "bound": bound}[fill]
+    rng = np.random.default_rng(n_held + held)
+    experts = _choices(rng, tokens, n_exp, top_k, first, held, n_held)
+
+    def normal(*shape, scale=1.0):
+        return jnp.asarray(rng.normal(size=shape).astype(np.float32) * scale
+                           ).astype(dtype)
+
+    x = normal(tokens, features)
+    weights = jnp.asarray(rng.uniform(0.1, 1.0, (tokens, top_k)).astype(
+        np.float32))
+    mats = (normal(held, features, width, scale=0.3),
+            normal(held, features, width, scale=0.3),
+            normal(held, width, features, scale=0.3))
+    probe = normal(tokens, features)
+
+    def run(dense):
+        def loss(x, weights, mats):
+            out = _held_part(x, weights, experts, mats, first, held, dense)
+            return jnp.sum(out.astype(jnp.float32)
+                           * probe.astype(jnp.float32)), out
+        return jax.jit(jax.value_and_grad(loss, (0, 1, 2), has_aux=True))(
+            x, weights, mats)
+
+    (_, want), want_grads = run(dense=True)
+    monkeypatch.setattr(jax.lax, "empty", lambda shape, dtype: jnp.full(
+        shape, jnp.nan, dtype))
+    (_, got), grads = run(dense=False)
+    sizes = moe_mod.sort_held(moe_mod.held_slots(
+        weights, experts, first, held)[1], held)[1]
+    assert int(jnp.sum(sizes[:held])) == n_held == bound - int(sizes[held])
+    for name, a, b in zip(
+            ("out", "x", "weights", "gate", "up", "down"),
+            (got, grads[0], grads[1], *grads[2]),
+            (want, want_grads[0], want_grads[1], *want_grads[2])):
+        a, b = (np.asarray(v.astype(jnp.float32)) for v in (a, b))
+        assert np.all(np.isfinite(a)), name
+        scale = max(float(np.max(np.abs(b))), 1e-6)
+        assert float(np.max(np.abs(a - b))) <= tolerance * scale, name
+        assert (n_held == 0) == (not np.any(b)), name
 
 
 def routing_without_the_new_keys_is_olmoes_test():
@@ -300,6 +427,9 @@ def the_step_reports_the_held_share_test():
         (held / routed).max())
     assert {"moe_held_pairs", "moe_held_pair_share",
             "moe_held_pair_share_max"} <= set(_LAYER_STATS)
+    # a buffer this full (a quarter) is moved whole: no tiles to report
+    assert "moe_held_row_tiles" not in info.layer_stats
+    assert "moe_held_tile_share" not in metrics
     assert moe_mod.moe_held_rows(params) == 2 * 128 * 4
     assert moe_mod.moe_held_rows(ModelParameter(_config(experts_held=0))) == 0
     from homebrewnlp_tpu import telemetry
@@ -309,6 +439,48 @@ def the_step_reports_the_held_share_test():
     assert line.endswith("moe held rows bound 1024; flash band 0 layers")
     assert telemetry.snapshot()["hbnlp_moe_held_rows_bound"]["series"][()] \
         == 1024
+
+
+def the_step_reports_the_tiles_it_walks_test():
+    """``moe_held_row_tiles`` / ``hbnlp_moe_held_tile_share`` (PR 47): where
+    the layer walks the real rows (4 of 64 experts held), ``ceil(held pairs
+    / tile)`` tiles a layer — the trip count of every pass — over the
+    bound's tiles."""
+    from homebrewnlp_tpu.train import _LAYER_STATS, _info_metrics
+    config, params, model, batch, variables = _build(experts=64)
+    info = model.apply(variables, batch, layer_stats=True)
+    held = np.asarray(info.layer_stats["moe_held_pairs"])
+    assert np.all((held > 0) & (held < 0.15 * 1024))
+    tile = moe_mod._row_tile(moe_mod.moe_held_rows(params))
+    assert tile == 512
+    visited = np.ceil(held / tile)
+    assert np.asarray(info.layer_stats["moe_held_row_tiles"]).tolist() \
+        == visited.tolist() == [1.0] * 4
+    metrics = _info_metrics(info)
+    assert float(metrics["moe_held_row_tiles"]) == 4
+    assert float(metrics["moe_held_tile_share"]) == 4 / (4 * 2)
+    assert (_LAYER_STATS["moe_held_row_tiles"].metric,
+            _LAYER_STATS["moe_held_tile_share"].metric) == (
+        "hbnlp_moe_held_row_tiles_total", "hbnlp_moe_held_tile_share")
+
+
+@pytest.mark.parametrize("config,walks", [
+    ("laguna_s_2_1", True),     # 8 / 256 x 10 / 8 = 3.9% of the buffer
+    ("zaya1_8b", False),        # 8 / 16 x 1 / 1 = 50%
+    ("olmoe_1b_7b", False)])    # every expert held: no buffer of slots
+def the_form_follows_the_fill_test(config, walks):
+    """The held path's form is decided by the fill the configuration fixes
+    (at most an eighth of the static buffer when balanced), per cell."""
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           config + ".json")) as f:
+        cell = json.load(f)["config"]
+    assert moe_mod.walks_real_rows(
+        cell["experts"], cell.get("experts_held", 0),
+        min(cell["moe_top_k"], cell["experts"])) == walks
+    # the edge: an eighth walks, a quarter does not
+    assert moe_mod.walks_real_rows(16, 1, 2)
+    assert not moe_mod.walks_real_rows(16, 2, 4)
+    assert not moe_mod.walks_real_rows(16, 4, 4)
 
 
 def the_band_gauge_counts_the_window_layers_test():
@@ -515,6 +687,27 @@ def the_repos_config_is_the_published_model_test():
     ("jit(step_fn)/transpose(jvp(gpt0))/body0/checkpoint/rematted_computation/"
      "block0_2_0/attention_0/flash_attention/flash_fwd_window",
      "body/attention"),
+    # the held path's loops (PR 47), as a lowered step names them: forward,
+    # the block's replay and the backward's own
+    ("jit(step_fn)/jvp(gpt0)/body0/checkpoint/block0_1_0/moe_0/dispatch/"
+     "while/body/gather", "body/moe/dispatch"),
+    ("jit(step_fn)/transpose(jvp(gpt0))/body0/jvp(gpt0)/body0/checkpoint/"
+     "rematted_computation/block0_1_0/moe_0/dispatch/while/body/"
+     "dynamic_update_slice", "body/moe/dispatch"),
+    ("jit(step_fn)/transpose(jvp(gpt0))/body0/jvp(gpt0)/body0/checkpoint/"
+     "block0_1_0/moe_0/dispatch/while/body/scatter-add", "body/moe/dispatch"),
+    ("jit(step_fn)/jvp(gpt0)/body0/checkpoint/block0_3_0/moe_0/experts/"
+     "while/body/exp", "body/moe/experts"),
+    ("jit(step_fn)/transpose(jvp(gpt0))/body0/jvp(gpt0)/body0/checkpoint/"
+     "rematted_computation/block0_3_0/moe_0/experts/while/cond/lt",
+     "body/moe/experts"),
+    ("jit(step_fn)/transpose(jvp(gpt0))/body0/jvp(gpt0)/body0/checkpoint/"
+     "block0_3_0/moe_0/experts/while/body/transpose(jvp())/mul",
+     "body/moe/experts"),
+    ("jit(step_fn)/jvp(gpt0)/body0/checkpoint/block0_1_0/moe_0/combine/"
+     "while/body/scatter-add", "body/moe/combine"),
+    ("jit(step_fn)/transpose(jvp(gpt0))/body0/jvp(gpt0)/body0/checkpoint/"
+     "block0_1_0/moe_0/combine/while/body/scatter", "body/moe/combine"),
     ("jit(step_fn)/jvp(gpt0)/input0/lang_inp1_0/mlp_0/dot_general",
      "body/mlp"),
     ("jit(step_fn)/jvp(gpt0)/input0/gather0/embed0/gather", "input/embed"),
