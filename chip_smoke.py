@@ -8,8 +8,8 @@ full width of the flagship (``configs/32big_mixer.json``: d4096 = 8 heads x
 chips present, steps, paths; ``save_graph`` on so the trainer reports what it
 compiled; the continuous engine pinned so a serving fallback is a failure):
 
-  kernels  scripts/kernel_parity.py — compiled flash, map-mixer and
-           delta-solve kernels
+  kernels  scripts/kernel_parity.py — compiled flash, map-mixer,
+           delta-solve and chunked-scan (layer mamba) kernels
            against their XLA references at float32 "highest", and the
            windowed flash forward's two forms against float64
   train    main.py --run_mode train, 10 steps on TFRecords written by
@@ -260,14 +260,14 @@ def leg_kernels(ctx):
     if ctx["rehearsal"]:
         cmd += ["--flash-seq", "256", "--mixer-batch", "2",
                 "--solve-chunks", "2", "--band-heads", "1",
-                "--band-seq", "1024"]
-    rc, wall = run_to_end("kernels", cmd, log, 420)
+                "--band-seq", "1024", "--scan-seq", "512"]
+    rc, wall = run_to_end("kernels", cmd, log, 540)
     rows = []
     with open(log, errors="replace") as f:
         for line in f:
             if line.startswith('{"kernel"'):
                 rows.append(json.loads(line))
-    check(rc == 0 and len(rows) == 4,
+    check(rc == 0 and len(rows) == 5,
           f"kernel parity failed (rc {rc}):\n{tail(log)}")
     if not ctx["rehearsal"]:
         check(all(r["implementation"] == "pallas" for r in rows),

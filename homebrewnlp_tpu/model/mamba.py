@@ -24,14 +24,19 @@ shared by all heads, state size ``n = mamba_state``:
 
 The recurrence is never run position by position: inside a chunk of
 ``mamba_chunk`` positions it is the masked ``C B^T`` product against ``x``
-(``intra_chunk``), every chunk leaves one state (``chunk_states``), a serial
-``lax.scan`` over the chunks carries the states across (``inter_chunk``), and
+(``intra_chunk``), every chunk leaves one state (``chunk_states``), the
+states are carried across the chunks in order (``inter_chunk``), and
 the state entering a chunk adds its part to that chunk's outputs
 (``state_out``).  Decays are ``exp`` of DIFFERENCES of a float32 cumulative
 sum of ``dt A`` within the chunk — masked before the ``exp``, never a product
 or quotient of exponentials, so nothing under- or overflows on the way —
 and matmul operands are the calculation dtype with float32 accumulation.
-Autodiff gives the backward.
+Two implementations of that one arithmetic (``ssd``): on a TPU at whole
+tiles (``parallel/ssd_scan.py ssd_kernel_applies``) a Pallas kernel pair
+walks the chunks with the decay matrices and the carried state in VMEM,
+forward and hand-written backward (PR 48); elsewhere ``ssd_xla`` runs the
+four steps as XLA einsums and a ``lax.scan`` and autodiff gives the backward
+— the CPU's path and the kernels' oracle.
 
 Training and full-sequence forward on one device; a decode / prefill form
 (a state and a conv window per sequence) is ROADMAP R3's serving half.
@@ -49,6 +54,7 @@ from ..core import scope
 from ..core.dims import Dim
 from ..core.tensor import NamedTensor, nt, transpose_to
 from ..parallel.causal_conv import causal_conv_silu, kernel_applies
+from ..parallel.ssd_scan import log_decay, ssd_kernel_applies, ssd_scan
 from .backend import ConstantInit, UniformInit, normal_var
 from .loss import _matmul
 from .normalization import _norm_core
@@ -63,7 +69,19 @@ def ssd(x, dt, a, b_mat, c_mat, chunk: int):
     s, h]`` and ``a [h]`` float32 (``a`` negative), ``b_mat`` / ``c_mat``
     ``[b, s, n]``; ``s`` a multiple of ``chunk``.  Returns ``(y [b, s, h,
     p]`` in float32 WITHOUT the ``D x`` skip, the most negative within-chunk
-    cumulative ``dt a``)``."""
+    cumulative ``dt a``)``: the Pallas pair of ``parallel/ssd_scan.py`` where
+    ``ssd_kernel_applies``, else ``ssd_xla``."""
+    _, s, h, p = x.shape
+    if not ssd_kernel_applies(s, chunk, h, p, b_mat.shape[-1]):
+        return ssd_xla(x, dt, a, b_mat, c_mat, chunk)
+    a_cum = log_decay(dt, a, chunk)
+    return ssd_scan(x, dt, a_cum, b_mat, c_mat, chunk), jnp.min(a_cum)
+
+
+def ssd_xla(x, dt, a, b_mat, c_mat, chunk: int):
+    """``ssd`` as XLA's einsums and a ``lax.scan`` over the chunk states,
+    autodiff its backward: the path off the TPU and at shapes the kernels
+    decline, and their oracle."""
     bsz, s, h, p = x.shape
     n = b_mat.shape[-1]
     c, l = s // chunk, chunk
@@ -193,9 +211,15 @@ def _conv(params: ModelParameter):
     return inner + 2 * params.mamba_state, params.mamba_conv_size, inner
 
 
+def _scan(params: ModelParameter):
+    s = params.sequence_dim.size
+    return (s, min(params.mamba_chunk, s), params.mamba_heads,
+            params.mamba_head_features, params.mamba_state)
+
+
 mamba.declares = Layer(
     stats=(Stat("ssd_log_decay_min", "gauge", "hbnlp_ssd_log_decay_min",
                 "most negative within-chunk cumulative dt * A of the newest "
                 "finished step, all mamba layers: exp of it is the smallest "
                 "decay the chunked scan formed", "min"),),
-    facts=FACTS, recurrent=Recurrent(_state_bytes, _conv))
+    facts=FACTS, recurrent=Recurrent(_state_bytes, _conv, scan=_scan))

@@ -4,7 +4,8 @@ what they refuse and the layout they take, the per-channel float32
 parameters and their initialisers, the causal depthwise conv's XLA form, what
 a layer declares of its recurrence (:class:`Recurrent`) and the start-up
 facts made of it (:data:`FACTS`: the chunk states alive for the backward, the
-layers whose conv and whose triangular solve are the Pallas pairs)."""
+layers whose conv, whose triangular solve and whose chunked scan are the
+Pallas pairs)."""
 from __future__ import annotations
 
 import typing
@@ -18,6 +19,7 @@ from ..core import scope
 from ..core.sharding import shard_geometry
 from ..parallel.causal_conv import kernel_applies
 from ..parallel.delta_solve import solve_kernel_applies
+from ..parallel.ssd_scan import ssd_kernel_applies
 from .declare import Fact, layers
 
 
@@ -30,19 +32,25 @@ class Recurrent(typing.NamedTuple):
     ``solve(params)`` — ``(chunk, matrices a call)`` as
     ``parallel/delta_solve.solve_kernel_applies`` takes them
     (``gated_delta``: the systems of one group of heads); None = none.
+    A layer whose chunked scan can be ``parallel/ssd_scan.py``'s pair declares
+    its shapes: ``scan(params)`` — ``(sequence, chunk, heads, head features,
+    state)`` as ``ssd_kernel_applies`` takes them (``mamba``); None = none.
 
     A layer that re-materialises its own interior in the backward also OFFERS
     ITS OUTPUT to the ``checkpoint`` strategy (its ``declares.offer``, kind
     ``recurrent``): where the block's ``jax.checkpoint`` saves it, the replay
-    runs no forward of the recurrence.  ``mamba`` offers nothing: its scan
-    has no inner ``jax.checkpoint``, so the replay's forward IS the pass that
-    makes the backward's residuals, and a saved output would skip none of
-    it."""
+    runs no forward of the recurrence.  ``mamba`` offers nothing YET: since
+    PR 48 its scan's backward reads the call's inputs, its output ``y`` and
+    the entering chunk states the forward kernel writes, so a saved ``y``
+    (and states) would let the replay skip the forward kernel — the next
+    issue (ROADMAP S9b); today the replay runs it again."""
     state_bytes: typing.Callable[[ModelParameter], int]
     conv: typing.Optional[
         typing.Callable[[ModelParameter], typing.Tuple[int, int, int]]]
     solve: typing.Optional[
         typing.Callable[[ModelParameter], typing.Tuple[int, int]]] = None
+    scan: typing.Optional[
+        typing.Callable[[ModelParameter], typing.Tuple[int, ...]]] = None
 
 
 def recurrent_layers(params: ModelParameter) -> typing.List[Recurrent]:
@@ -100,6 +108,20 @@ def solve_kernel_layers(params: ModelParameter, backend=None
                               for chunk, matrices in solves)
 
 
+def scan_kernel_layers(params: ModelParameter, backend=None
+                       ) -> typing.Optional[int]:
+    """How many recurrent mixers of the step take the Pallas pair for their
+    chunked scan (``parallel/ssd_scan.py``), by the predicate the layer
+    itself calls on the shapes it declares; None where no layer declares a
+    scan."""
+    scans = [spec.scan(params) for spec in recurrent_layers(params)
+             if spec.scan is not None]
+    if not scans:
+        return None
+    return params.depth * sum(ssd_kernel_applies(*shapes, backend)
+                              for shapes in scans)
+
+
 #: every recurrent mixer's ``declares.facts``
 FACTS = (
     Fact(10, "hbnlp_ssd_state_bytes",
@@ -120,6 +142,11 @@ FACTS = (
          "layer)",
          lambda params, mesh, backend: solve_kernel_layers(params, backend),
          "solve kernel {} layers"),
+    Fact(35, "hbnlp_ssd_scan_kernel_layers",
+         "mamba layers of the built step whose chunked scan is the Pallas "
+         "kernel pair (0 on the XLA einsums, and without such a layer)",
+         lambda params, mesh, backend: scan_kernel_layers(params, backend),
+         "scan kernel {} layers"),
 )
 
 

@@ -31,6 +31,13 @@ online softmax) — at the Laguna cell's shape ``[2, 8192, 72, 128]``, window
 inside :data:`LSE_TOLERANCE`, and the band form no further off than twice
 the tiled one.
 
+A fifth leg holds layer ``mamba``'s chunked scan (parallel/ssd_scan.py,
+through ``model/mamba.py ssd``) at the Granite cell's shapes — ``x [1, 8192,
+64, 64]``, state 128, chunk 256, decays as strong as the cell's — to the XLA
+form ``ssd_xla`` in float32 under ``highest``: ``y`` and the five gradients
+inside :data:`TOLERANCE`, beside what the XLA form in bfloat16 (the parent's
+path) is off against the same reference, and each path's time a call.
+
 Shapes: flash at the long-context recipe's per-chip shape (seq 16,384, head
 dim 128; two heads so the dense reference's [s, s] scores fit beside it);
 the mixer at the flagship's (8 heads, seq 512, 512 features/head, batch 32).
@@ -64,7 +71,7 @@ def _errors(got, want):
     """{name: max|got - want| / max|want|} over matching tuples of arrays."""
     import numpy as np
     out = {}
-    for name, g, w in zip(("out", "d0", "d1", "d2"), got, want):
+    for name, g, w in zip(("out", "d0", "d1", "d2", "d3", "d4"), got, want):
         g = np.asarray(g, np.float32)
         w = np.asarray(w, np.float32)
         assert g.shape == w.shape, (name, g.shape, w.shape)
@@ -246,6 +253,68 @@ def _band_leg(shape=(2, 8192, 72, 128), window: int = 512) -> bool:
     return bool(ok)
 
 
+def _scan_leg(s: int = 8192, heads: int = 64, p: int = 64, n: int = 128,
+              chunk: int = 256) -> bool:
+    """The scan's kernel pair and the XLA form in bfloat16, both against the
+    XLA form in float32 ``highest`` on the same device."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from homebrewnlp_tpu.model import mamba
+    from homebrewnlp_tpu.parallel import ssd_scan
+
+    rng = np.random.default_rng(48)
+    # the layer's own ranges: dt = softplus(.) log-uniform about [1e-3, 0.1]
+    # with a tail, A = -U[1, 16]: a chunk's cumulative dt A reaches -400
+    x, b_mat, c_mat = (jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+                       for shape in ((1, s, heads, p), (1, s, n), (1, s, n)))
+    dt = jnp.asarray(np.exp(rng.uniform(np.log(1e-3), np.log(0.2),
+                                        (1, s, heads))), jnp.float32)
+    a = jnp.asarray(-rng.uniform(1.0, 16.0, (heads,)), jnp.float32)
+    ct = jnp.asarray(rng.normal(size=(1, s, heads, p)), jnp.float32)
+    operands = (x, dt, a, b_mat, c_mat)
+    platform = jax.devices()[0].platform
+    applies = ssd_scan.ssd_kernel_applies(s, chunk, heads, p, n)
+
+    def kernel(*args):
+        if platform != "cpu":
+            return mamba.ssd(*args, chunk)[0]       # the layer's dispatcher
+        return ssd_scan.ssd_scan(
+            args[0], args[1], ssd_scan.log_decay(args[1], args[2], chunk),
+            *args[3:], chunk, None, True)
+
+    def xla(*args):
+        return mamba.ssd_xla(*args, chunk)[0]
+
+    low = float(jnp.min(ssd_scan.log_decay(dt, a, chunk)))
+    with jax.default_matmul_precision("highest"):
+        want = _with_grads(xla)(ct, *(t.astype(jnp.float32)
+                                      for t in operands))
+    errs, ms = {}, {}
+    for name, fn in (("kernel", kernel), ("xla", xla)):
+        compiled = _with_grads(fn).lower(ct, *operands).compile()
+        got = jax.block_until_ready(compiled(ct, *operands))
+        errs[name] = {k: round(v, 6) for k, v in _errors(got, want).items()}
+        start = time.perf_counter()
+        for _ in range(5):
+            got = compiled(ct, *operands)
+        jax.block_until_ready(got)
+        ms[name] = round((time.perf_counter() - start) * 200, 3)
+    ok = (applies or platform == "cpu") and all(
+        e <= TOLERANCE for e in errs["kernel"].values())
+    print(json.dumps({"kernel": "ssd_scan", "ok": bool(ok),
+                      "implementation": "pallas" if applies else
+                      "pallas (interpret)", "max_err_over_max_ref": errs,
+                      "tolerance": TOLERANCE, "log_decay_min": low,
+                      "ms_a_call_forward_and_backward": ms,
+                      "shapes": [list(t.shape) for t in operands],
+                      "chunk": chunk, "dtype": "bfloat16"}), flush=True)
+    return bool(ok)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--flash-seq", type=int, default=16384)
@@ -255,6 +324,9 @@ def main(argv=None) -> int:
     ap.add_argument("--band-heads", type=int, default=72,
                     help="heads of the band leg's [2, seq, heads, 128]")
     ap.add_argument("--band-seq", type=int, default=8192)
+    ap.add_argument("--scan-seq", type=int, default=8192)
+    ap.add_argument("--only-scan", action="store_true",
+                    help="run the chunked scan's leg alone")
     args = ap.parse_args(argv)
 
     import jax
@@ -262,6 +334,11 @@ def main(argv=None) -> int:
 
     from homebrewnlp_tpu.parallel import flash_attention as flash
     from homebrewnlp_tpu.parallel import map_mixer
+
+    if args.only_scan:
+        ok = _scan_leg(args.scan_seq)
+        print(json.dumps({"ok": bool(ok)}), flush=True)
+        return 0 if ok else 1
 
     def rand(seed, shape, scale=1.0):
         return (scale * jax.random.normal(jax.random.PRNGKey(seed), shape,
@@ -295,6 +372,8 @@ def main(argv=None) -> int:
     ok &= _solve_leg((1, args.solve_chunks, 10, 64, 64))
 
     ok &= _band_leg((2, args.band_seq, args.band_heads, 128))
+
+    ok &= _scan_leg(args.scan_seq)
 
     print(json.dumps({"ok": bool(ok)}), flush=True)
     return 0 if ok else 1

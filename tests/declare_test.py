@@ -45,9 +45,11 @@ _CELLS = {
         _kinds(attention=(2, 68157440), experts=(2, 1075315200)), "", {}),
     "train_granite_4_0_h_micro_long": (
         _kinds(attention=(1, 34603008)),
-        "; ssd chunk states 67108864 bytes a device; conv kernel 9 layers",
+        "; ssd chunk states 67108864 bytes a device; conv kernel 9 layers; "
+        "scan kernel 9 layers",                                  # PR 48
         {"hbnlp_ssd_state_bytes": 67108864,
-         "hbnlp_mamba_conv_kernel_layers": 9}),
+         "hbnlp_mamba_conv_kernel_layers": 9,
+         "hbnlp_ssd_scan_kernel_layers": 9}),
     "train_olmo_hybrid_7b_long": (
         _kinds(attention=(1, 127795200), recurrent=(3, 566231040)),
         "; ssd chunk states 94371840 bytes a device; conv kernel 3 layers; "
@@ -73,7 +75,8 @@ _CELLS = {
 #: the facts that read 0 where no layer has the mechanism; the others have no
 #: series there
 _ALWAYS = ("hbnlp_ssd_state_bytes", "hbnlp_mamba_conv_kernel_layers",
-           "hbnlp_delta_solve_kernel_layers", "hbnlp_flash_band_layers")
+           "hbnlp_delta_solve_kernel_layers", "hbnlp_ssd_scan_kernel_layers",
+           "hbnlp_flash_band_layers")
 _SPARSE = ("hbnlp_moe_held_rows_bound", "hbnlp_router_carry_bytes")
 
 
@@ -124,8 +127,11 @@ def _config_files():
 #: sha1 of the line + the start-up series of every configuration file as it
 #: stands, TPU then CPU, from the parent (PR 46 added the two MiniCPM-SALA
 #: files; without them the digest is PR 42's
-#: 14424d9e0db6655b911c55f5cf6f76f5b6505273)
-_FILE_DIGEST = "0120375caf4cdf6e013cbb72aedfbae4ab651566"
+#: 14424d9e0db6655b911c55f5cf6f76f5b6505273; PR 48 added the series
+#: ``hbnlp_ssd_scan_kernel_layers`` to every file and ``; scan kernel N
+#: layers`` to the lines of the two granite files: before it
+#: 0120375caf4cdf6e013cbb72aedfbae4ab651566)
+_FILE_DIGEST = "1ae9e7a6b258ab7ceafda5cfe9cd9ec99860c294"
 
 
 def every_configuration_file_starts_as_on_the_parent_test(monkeypatch):
@@ -255,7 +261,8 @@ def facts_are_declared_once_in_line_order_test():
     facts = declare.facts()
     assert [fact.metric for fact in facts] == [
         "hbnlp_ssd_state_bytes", "hbnlp_mamba_conv_kernel_layers",
-        "hbnlp_delta_solve_kernel_layers", "hbnlp_moe_held_rows_bound",
+        "hbnlp_delta_solve_kernel_layers", "hbnlp_ssd_scan_kernel_layers",
+        "hbnlp_moe_held_rows_bound",
         "hbnlp_router_carry_bytes", "hbnlp_flash_band_layers"]
     assert [fact.metric for fact in facts if fact.zero] == list(_ALWAYS)
     assert [fact.metric for fact in facts if not fact.zero] == list(_SPARSE)

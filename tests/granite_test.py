@@ -367,43 +367,84 @@ def v5e():
     return jax.sharding.SingleDeviceSharding(topo.devices[0])
 
 
-def conv_kernels_keep_their_scope_and_names_test(v5e, monkeypatch):
+_MAMBA_HLO = []
+
+
+def _mamba_layer_hlo(v5e, monkeypatch) -> str:
     """One ``mamba`` layer at the published widths, 1 x 8,192 tokens, loss
-    and gradients compiled for a v5e as a TPU process traces them: the conv
-    is the Pallas pair in its three forms (forward, ``checkpoint``'s replay,
-    backward), Mosaic accepts both kernels, every one folds into
-    ``body/mamba/conv`` and none bears a name another metric's reader takes
-    (``^flash_``, ``^map_mixer_``)."""
-    import re
+    and gradients compiled for a v5e as a TPU process traces them (once a
+    module: both kernel tests read the same text)."""
     from benchmark.lib.cell import load_cell
     from homebrewnlp_tpu.model import recurrent
+    if _MAMBA_HLO:
+        return _MAMBA_HLO[0]
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cell = load_cell("train_granite_4_0_h_micro_long").model_config()
     assert cell["block_config"][0]["layer"][-1] == "mamba"
     params = ModelParameter({**cell, "block_config": cell["block_config"][:1],
                              "vocab_size": 512, "model_path": "/tmp/granite"})
     assert recurrent.conv_kernel_layers(params) == 1
+    assert recurrent.scan_kernel_layers(params) == 1
     model = Model(params)
     batch = {k: np.zeros((1, 8192, 1), np.int32)
              for k in ("token_x", "token_y")}
     variables = model.init(batch, seed=1)
     avals = [{k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=v5e)
               for k, v in tree.items()} for tree in (variables, batch)]
-    hlo = jax.jit(jax.value_and_grad(
+    _MAMBA_HLO.append(jax.jit(jax.value_and_grad(
         lambda v, b: model.apply(v, b).total_loss.data)).lower(
-        *avals).compile().as_text()
-    calls = re.findall(r'%([\w.-]+) = [^\n]*?custom_call_target='
-                       r'"tpu_custom_call"[^\n]*?op_name="([^"]+)"', hlo)
-    assert sorted(re.sub(r"\.\d+$", "", name) for name, _ in calls) \
-        == ["mamba_conv_bwd", "mamba_conv_fwd", "mamba_conv_fwd"]
-    for name, op_name in calls:
-        assert scope_key(op_name) == "body/mamba/conv", op_name
-        assert not re.match(r"flash_|map_mixer_", name)
+        *avals).compile().as_text())
+    return _MAMBA_HLO[0]
+
+
+def _kernel_calls(hlo: str, prefix: str):
+    """``(name, op_name)`` of the Pallas calls named ``prefix*``, each in
+    its three forms: forward, ``checkpoint``'s replay, backward."""
+    import re
+    calls = [(re.sub(r"\.\d+$", "", name), op_name) for name, op_name in
+             re.findall(r'%([\w.-]+) = [^\n]*?custom_call_target='
+                        r'"tpu_custom_call"[^\n]*?op_name="([^"]+)"', hlo)
+             if name.startswith(prefix)]
+    assert sorted(name for name, _ in calls) \
+        == [prefix + "bwd", prefix + "fwd", prefix + "fwd"]
     forms = sorted(op_name for _, op_name in calls)
     assert forms[0].split("/")[1].startswith("jvp(")
-    assert "rematted_computation" in forms[2] and "mamba_conv_fwd" in forms[2]
+    assert "rematted_computation" in forms[2] and prefix + "fwd" in forms[2]
     assert forms[1].split("/")[1].startswith("transpose(jvp(") \
-        and "mamba_conv_bwd" in forms[1]
+        and prefix + "bwd" in forms[1]
+    return calls
+
+
+def conv_kernels_keep_their_scope_and_names_test(v5e, monkeypatch):
+    """The conv is the Pallas pair in its three forms (forward,
+    ``checkpoint``'s replay, backward), Mosaic accepts both kernels, every
+    one folds into ``body/mamba/conv`` and none bears a name another metric's
+    reader takes (``^flash_``, ``^map_mixer_``)."""
+    import re
+    for name, op_name in _kernel_calls(_mamba_layer_hlo(v5e, monkeypatch),
+                                       "mamba_conv_"):
+        assert scope_key(op_name) == "body/mamba/conv", op_name
+        assert not re.match(r"flash_|map_mixer_", name)
+
+
+def scan_kernels_keep_their_scope_and_names_test(v5e, monkeypatch):
+    """PR 48: the chunked scan is the Pallas pair ``ssd_scan_fwd`` /
+    ``ssd_scan_bwd`` in the same three forms, Mosaic accepts both at the
+    cell's shapes, every one folds into ``body/mamba/ssd`` (what both
+    ``ssd_scan_*`` readers take), and no op of that scope outside the Pallas
+    calls is shaped ``[.., 256, 256]``: the decay matrices stay in VMEM."""
+    import re
+    hlo = _mamba_layer_hlo(v5e, monkeypatch)
+    for _, op_name in _kernel_calls(hlo, "ssd_scan_"):
+        assert scope_key(op_name) == "body/mamba/ssd", op_name
+    in_scope = [line for line in hlo.splitlines()
+                if (m := re.search(r'op_name="([^"]+)"', line))
+                and scope_key(m.group(1)) == "body/mamba/ssd"
+                and "tpu_custom_call" not in line]
+    assert in_scope
+    for line in in_scope:
+        assert not re.search(r"\[[\d,]*256,256\]", line.split(" = ")[1]
+                             .split("(")[0]), line
 
 
 def delta_layers_kernels_keep_their_scopes_test(v5e, monkeypatch):
@@ -648,19 +689,30 @@ def experts_rule_declines_without_a_moe_layer_test():
     line = Trainer(params, model).publish_stash_plan()
     assert line.endswith("experts 0 layers, 0 bytes a device; recurrent 0 "
                          "layers, 0 bytes a device; ssd chunk states 16384 "
-                         "bytes a device; conv kernel 0 layers")
+                         "bytes a device; conv kernel 0 layers; scan kernel "
+                         "0 layers")
     snap = telemetry.registry().snapshot()
     assert snap["hbnlp_ssd_state_bytes"]["series"][()] == 16384
     assert snap["hbnlp_mamba_conv_kernel_layers"]["series"][()] == 0
+    assert snap["hbnlp_ssd_scan_kernel_layers"]["series"][()] == 0
     _, none, _, _, _ = _build("float32", memory_reduction_strategy="none")
     assert recurrent.ssd_state_bytes(none) == 9 * 16384
 
 
-def step_reports_the_log_decay_watch_test():
+@pytest.mark.parametrize("kernel", [False, True], ids=["xla", "kernel"])
+def step_reports_the_log_decay_watch_test(kernel, monkeypatch):
     """Two steps of the trainer: the loss is finite and falls, the second
-    call publishes the first step's ``hbnlp_ssd_log_decay_min``."""
+    call publishes the first step's ``hbnlp_ssd_log_decay_min`` — the same
+    number with the scan's Pallas pair (PR 48, interpreted) as with XLA's
+    einsums: the first step's is the minimum of the same cumulative sum."""
+    import functools
     from homebrewnlp_tpu import telemetry
+    from homebrewnlp_tpu.parallel import ssd_scan
     from homebrewnlp_tpu.train import Trainer
+    if kernel:
+        monkeypatch.setattr(mamba_mod, "ssd_kernel_applies", lambda *_: True)
+        monkeypatch.setattr(mamba_mod, "ssd_scan", functools.partial(
+            ssd_scan.ssd_scan, interpret=True))
     _, params, model, batch, _ = _build(
         "float32", telemetry_enabled=True, sequence_length=32,
         learning_rate=0.01,
@@ -668,15 +720,21 @@ def step_reports_the_log_decay_watch_test():
     batch = {k: v[:, :32] for k, v in batch.items()}
     trainer = Trainer(params, model)
     state = trainer.init_state(batch)
-    losses = []
+    losses, lows = [], []
     for _ in range(3):
         state, metrics = trainer.step(state, batch)
         jax.block_until_ready(metrics["loss"])
         losses.append(float(metrics["loss"]))
-        assert float(metrics["ssd_log_decay_min"]) < 0
+        lows.append(float(metrics["ssd_log_decay_min"]))
+        assert lows[-1] < 0
     assert all(np.isfinite(losses)) and losses[-1] < losses[0]
     snap = telemetry.registry().snapshot()
     assert snap["hbnlp_ssd_log_decay_min"]["series"][()] < 0
+    _LOG_DECAY_MIN.append(lows[0])
+    assert _LOG_DECAY_MIN[0] == lows[0]
+
+
+_LOG_DECAY_MIN = []
 
 
 @pytest.mark.parametrize("batch,heads", [(32, 8), (128, 4)],
