@@ -18,6 +18,19 @@ EXPECTED_FAILURES = {
         "tests/olmo_hybrid_test.py holds the toy size to a bound that float8 "
         "misses.  A per-configuration bound in reference_test.py is a "
         "benchmark PR's",
+    **{"benchmark/tests/reference_test.py::reference_matches_program_test"
+       f"[{case}-ouro_2_6b]":
+       "the accepted test holds the program's reported loss to the "
+       "cross-entropy of the logits the reference returns.  A looped model "
+       "(PR 49) reports what it trains on, sum_t p_t CE_t - 0.1 H(p) over "
+       "its four passes, and its reference returns the LAST pass's logits "
+       "(what the train driver compares at all positions): the two differ "
+       "by construction (at this test's size by 0.11-0.14), whatever the "
+       "program does.  The logits part of the case holds (float32 1e-6); "
+       "tests/ouro_test.py holds every pass's logits, p, the loss and the "
+       "gradients to the reference's own.  A loss the reference names "
+       "itself in reference_test.py is a benchmark PR's"
+       for case in ("float32-2e-05", "bfloat16-0.0625")},
 }
 
 

@@ -57,7 +57,10 @@ _SPECIAL = {"cache_read": "decode/cache_read",
             "sampling": "decode/sampling",
             "optimizer": "optimizer",
             # the head matmul with its cross-entropy (model/__init__.py)
-            "head_loss": "head_loss"}
+            "head_loss": "head_loss",
+            # a looped model's exit gate, its distribution and the weighting
+            # of the passes' losses (model/loop.py)
+            "exit_gate": "exit_gate"}
 #: model/frontend.py LAYER_FUNCTIONS keys (mirrored, not imported — this
 #: module must stay importable without jax); update together
 _LAYER_NAMES = frozenset((

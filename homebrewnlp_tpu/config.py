@@ -299,6 +299,14 @@ class ModelParameter:
         self.logits_scaling = 1.0
         # the head is the (direct) token embedding itself: one parameter
         self.tie_word_embeddings = False
+        # a looped (weight-tied-depth) model, model/loop.py: the whole body
+        # and the output blocks run loop_steps times over the SAME weights,
+        # each pass's output the next one's input and the head's; an exit
+        # gate a token spreads the loss over the passes, less
+        # loop_exit_entropy times the entropy of that distribution (only
+        # read where loop_steps > 1).  1 = the body runs once
+        self.loop_steps = 1
+        self.loop_exit_entropy = 0.1
         self.pkm_axes = 2
         self.use_bit_fold_input_pipeline = False
         self.bit_fold_value = 4
@@ -1001,6 +1009,33 @@ class ModelParameter:
         if not self.norm_epsilon > 0:
             raise ValueError(f"norm_epsilon {self.norm_epsilon!r} must be "
                              "positive")
+        if not isinstance(self.loop_steps, int) \
+                or isinstance(self.loop_steps, bool) or self.loop_steps < 1:
+            raise ValueError(f"loop_steps {self.loop_steps!r} must be a "
+                             "whole number >= 1")
+        if self.loop_steps > 1:
+            entropy = self.loop_exit_entropy
+            if isinstance(entropy, bool) \
+                    or not isinstance(entropy, (int, float)) or entropy < 0:
+                raise ValueError(f"loop_exit_entropy {entropy!r} must be a "
+                                 "number >= 0")
+            refused = [why for why, hit in (
+                (f"memory_reduction_strategy "
+                 f"{self.memory_reduction_strategy!r} (the revnet and "
+                 "momentum streams are not re-entered: \"checkpoint\" or "
+                 "\"none\")",
+                 self.memory_reduction_strategy not in ("none",
+                                                        "checkpoint")),
+                ("use_video", self.use_video),
+                ("a contrastive loss", self.contrastive_across_samples
+                 or self.contrastive_across_token_embeddings),
+                (f"multi_loss_strategy {self.multi_loss_strategy!r}",
+                 self.multi_loss_strategy != "linear"),
+                ("calc_accuracy", self.calc_accuracy)) if hit]
+            if refused:
+                raise ValueError(f"loop_steps {self.loop_steps} (a looped "
+                                 "model, model/loop.py) refuses "
+                                 + "; ".join(refused))
         if self.tie_word_embeddings and (self.vocab_weight_factorization
                                          or self.token_patch_size != 1
                                          or self.use_video):
@@ -1067,6 +1102,12 @@ class ModelParameter:
             raise ValueError(
                 "pipeline_stages > 1 requires a 'pipe' axis in mesh_shape_override")
         self.pipeline_stages = self.mesh_shape.get("pipe", 1)
+        if self.pipeline_stages > 1 and self.loop_steps > 1:
+            raise ValueError(
+                f"loop_steps {self.loop_steps} (a looped model, "
+                "model/loop.py) refuses a pipeline mesh: pipeline_stages "
+                f"{self.pipeline_stages} (a stage's blocks are not "
+                "re-entered)")
         if self.pipeline_stages > 1 and self.depth % self.pipeline_stages:
             raise ValueError(
                 f"depth={self.depth} must divide into pipe={self.pipeline_stages} stages")

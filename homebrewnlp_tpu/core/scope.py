@@ -110,9 +110,15 @@ class Context:
         self._rng_count = 0
 
     # -- naming ------------------------------------------------------------
-    def enter(self, name: str) -> str:
+    def enter(self, name: str, again: bool = False) -> str:
+        """Open scope ``name`` under the next index of the frame — or, with
+        ``again``, under the index it was opened with LAST, with counters of
+        its own: whatever runs inside resolves the names it resolved then (a
+        looped model's passes, model/loop.py; apply mode only, since init
+        would make every parameter a second time)."""
         frame = self.stack[-1]
-        idx = frame.counters.get(name, 0)
+        idx = frame.counters.get(name, 0) - bool(again)
+        assert idx >= 0, f"scope {name!r} entered again before it was opened"
         frame.counters[name] = idx + 1
         scoped_name = f"{name}{idx}"
         self.stack.append(_Frame(scoped_name))
@@ -162,9 +168,9 @@ def context(ctx: Context):
 
 
 @contextlib.contextmanager
-def name_scope(name: str):
+def name_scope(name: str, again: bool = False):
     ctx = current()
-    scoped_name = ctx.enter(name)
+    scoped_name = ctx.enter(name, again)
     try:
         # mirror the scope frame into jax's name stack: every op traced
         # inside lands in compiled-HLO ``metadata={op_name=...}`` and in

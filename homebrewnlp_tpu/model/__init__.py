@@ -155,6 +155,10 @@ def _output(params: ModelParameter, out: NamedTensor, spatial_ctx: Dim,
         if not contrastive:
             for config_idx, config in enumerate(params.output_block_config):
                 token_out = block_part_fn(params, config, token_out, f'lang_out{config_idx}')
+            if storage is not None:
+                # what the output blocks leave: a looped model's next pass
+                # starts from it (_build_looped)
+                storage["stream"] = token_out
             new = [params.token_patch_dim, params.vocab_dim]
             old = list(params.feature_dims)
             if params.tie_word_embeddings:
@@ -267,6 +271,52 @@ def _loss(params: ModelParameter, frame_out, token_out, txt_tgt, loss_list,
     return loss_list, token_loss, accuracy, video_loss
 
 
+def _build_looped(params: ModelParameter, src: NamedTensor, txt_tgt,
+                  spatial_ctx: Dim, storage: dict, plan):
+    """A looped model (``loop_steps`` > 1; model/loop.py): ``h_t =
+    output blocks(body(h_(t-1)))`` for ``t = 1 .. loop_steps`` from ``h_0`` the
+    embedding — the SAME blocks, parameters and positions every pass, the
+    output blocks' result (the final norm's) the next pass's input and the
+    head's — then the gate and the loss over all passes.
+
+    The passes are a Python loop over the unrolled blocks: every pass opens
+    scopes ``body`` and ``output`` AGAIN (core/scope.py), so the blocks
+    resolve the parameters of the first, each block is a ``jax.checkpoint``
+    region of its own a pass under ``checkpoint``, and autodiff adds a
+    parameter's gradients over its uses.  A pass is one region ``loop/pass<t>``
+    of the device trace (a scan over passes would fold the four into one).
+    Init mode runs ONE pass — it makes each parameter once and records the
+    one plan — and stands it in for the others, since init reads no value."""
+    from .loop import gated_loss
+    ctx = scope.current()
+    init = ctx.mode == "init" or plan is None
+    streams, token_out, first_plan = [], None, plan
+    for step in range(params.loop_steps):
+        if init and step:
+            streams.append(streams[0])
+            continue
+        again = step > 0
+        params.attention_idx = 0
+        with jax.named_scope("loop"), jax.named_scope(f"pass{step}"):
+            with scope.name_scope("body", again):
+                out, made = _body(params, src, plan)
+            with scope.name_scope("output", again):
+                _, token_out = _output(params, out, spatial_ctx, storage)
+        if not step:
+            first_plan = made
+        src = storage["stream"]
+        streams.append(src)
+    with scope.name_scope("loss"):
+        total, cross, stats = gated_loss(params, streams, storage["head"][1],
+                                         txt_tgt)
+    if ctx.layer_stats is not None:
+        ctx.layer_stats.append(stats)
+    params.attention_idx = 0
+    total, cross = nt(total, ()), nt(cross, ())
+    return LossInfo(total, [total], None, None, cross, None,
+                    token_out), first_plan
+
+
 def _build(params: ModelParameter, vid, cat_msk_src, cat_msk_tgt, txt_src,
            txt_tgt, vid_msk_src, vid_msk_tgt, txt_msk, plan):
     cat_msk_src = _default_ones(params, cat_msk_src) if params.use_video else cat_msk_src
@@ -280,6 +330,8 @@ def _build(params: ModelParameter, vid, cat_msk_src, cat_msk_tgt, txt_src,
 
     src, vid_tgt = scope.scoped("input", _input, params, vid, cat_msk_src,
                                 txt_src, vid_msk_src, spatial_ctx, storage)
+    if params.loop_steps > 1:
+        return _build_looped(params, src, txt_tgt, spatial_ctx, storage, plan)
     out, plan = scope.scoped("body", _body, params, src, plan)
     frame_out, token_out = scope.scoped("output", _output, params, out,
                                         spatial_ctx, storage)
@@ -297,6 +349,14 @@ def build(params: ModelParameter, vid, cat_msk_src, cat_msk_tgt, txt_src,
     return scope.scoped(params.model_mode, _build, params, vid, cat_msk_src,
                         cat_msk_tgt, txt_src, txt_tgt, vid_msk_src,
                         vid_msk_tgt, txt_msk, plan)
+
+
+def _refuse_looped(params: ModelParameter, what: str) -> None:
+    if params.loop_steps > 1:
+        raise NotImplementedError(
+            f"{what} of a looped model (loop_steps {params.loop_steps}): the "
+            "passes' KV caches and the exit by threshold are not built; a "
+            "looped model trains and runs its full forward only")
 
 
 class Model:
@@ -525,6 +585,7 @@ class Model:
         from .decode import DecodeState
         assert self.plan is not None, "call init() first (or assign .plan)"
         p = self.params
+        _refuse_looped(p, "incremental decode")
         assert not p.use_video and p.use_language, \
             "incremental decode supports text (gpt) mode only"
         width = int(token_slice.shape[1])
@@ -562,6 +623,7 @@ class Model:
         from .decode import PrefillState
         assert self.plan is not None, "call init() first (or assign .plan)"
         p = self.params
+        _refuse_looped(p, "prefill")
         assert not p.use_video and p.use_language, \
             "prefill supports text (gpt) mode only"
         from ..core import sharding as shardlib

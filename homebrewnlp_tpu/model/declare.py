@@ -13,22 +13,29 @@ import jax.numpy as jnp
 
 from ..config import ModelParameter
 
-_FOLDS = {"max": jnp.max, "min": jnp.min, "sum": jnp.sum}
+#: ``"each"``: no fold, the layers' (a looped model's passes') values as they
+#: are — a statistic with a ``label``
+_FOLDS = {"max": jnp.max, "min": jnp.min, "sum": jnp.sum,
+          "each": lambda values: values}
 
 
 class Stat(typing.NamedTuple):
     """One step metric made of what a layer appends to ``ctx.layer_stats``
     under ``key`` (``name`` where empty; ``Model.apply`` merges the layers'
     scalars into one array a key).  ``fold``: ``"max"`` / ``"min"`` /
-    ``"sum"`` over the layers, or a function of ``(the merged dict, this
+    ``"sum"`` over the layers (``"each"``: none), or a function of ``(the merged dict, this
     layer's metrics folded before it)``.  ``Trainer._publish_layer_stats``
-    publishes it as the ``kind`` (``"gauge"`` / ``"counter"``) ``metric``."""
+    publishes it as the ``kind`` (``"gauge"`` / ``"counter"``) ``metric``.
+    With a ``label`` the fold gives a vector, and the step reports one metric
+    ``<name>/<index>`` an entry, published under ``metric{label="<index>"}``
+    (a looped model's statistics a pass, model/loop.py)."""
     name: str
     kind: str
     metric: str
     help: str
     fold: typing.Union[str, typing.Callable[[dict, dict], typing.Any]]
     key: str = ""
+    label: str = ""
 
 
 class Offer(typing.NamedTuple):
@@ -118,8 +125,9 @@ def step_offers(params: ModelParameter, kind: str):
 
 
 def _registered() -> typing.List[Layer]:
-    from .frontend import LAYER_FUNCTIONS
-    found = (getattr(fn, "declares", None) for fn in LAYER_FUNCTIONS.values())
+    from .frontend import DECLARING, LAYER_FUNCTIONS
+    found = (getattr(fn, "declares", None)
+             for fn in (*LAYER_FUNCTIONS.values(), *DECLARING))
     return [spec for spec in found if spec is not None]
 
 
@@ -149,5 +157,9 @@ def fold_stats(layer_stats: typing.Optional[dict]) -> typing.Dict[str, typing.An
                 continue
             done[stat.name] = _FOLDS[stat.fold](layer_stats[key]) \
                 if isinstance(stat.fold, str) else stat.fold(layer_stats, done)
+        for stat in spec.stats:
+            if stat.label and stat.name in done:
+                done.update({f"{stat.name}/{i}": value for i, value
+                             in enumerate(done.pop(stat.name))})
         out.update(done)
     return out

@@ -19,6 +19,7 @@ from .basic import (bottleneck_group_linear, dropout, feed_forward,
 from .cca import cca
 from .gated_delta import gated_delta
 from .lightning import lightning
+from .loop import gated_loss
 from .mamba import mamba
 from .moe import moe
 from .normalization import norm
@@ -180,3 +181,7 @@ LAYER_FUNCTIONS = {'feed_forward': feed_forward,
                    'cca': cca,
                    'lightning': lightning,
                    }
+
+#: what declares itself (model/declare.py) beside the layers of the DSL: a
+#: looped model's loss (model/loop.py)
+DECLARING = (gated_loss,)

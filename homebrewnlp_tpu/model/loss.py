@@ -52,10 +52,9 @@ def _matmul(spec: str, a, b):
     return jnp.einsum(spec, a, b, preferred_element_type=prefer)
 
 
-def _chunk(x, w, targets, z_loss: float, count: int, with_grads: bool):
-    """One chunk: ``(sum of log_z - picked, sum of log_z^2, dx, dw)``; the
-    gradients are of the MEAN over ``count`` targets, None without
-    ``with_grads``.  ``w`` None: ``x`` holds the logits themselves."""
+def _softmax_parts(x, w, targets):
+    """One chunk's ``(logits, the same in float32, exp(logits - max), its sum
+    over the vocabulary, log_z, the target's logit)``."""
     logits = x if w is None else _matmul("bshk,hkpv->bspv", x, w
                                          ).astype(x.dtype)
     lf = logits.astype(jnp.float32)
@@ -64,6 +63,14 @@ def _chunk(x, w, targets, z_loss: float, count: int, with_grads: bool):
     total = jnp.sum(ex, axis=-1, keepdims=True)
     log_z = (jnp.log(total) + top)[..., 0]
     picked = jnp.take_along_axis(lf, targets[..., None], axis=-1)[..., 0]
+    return logits, lf, ex, total, log_z, picked
+
+
+def _chunk(x, w, targets, z_loss: float, count: int, with_grads: bool):
+    """One chunk: ``(sum of log_z - picked, sum of log_z^2, dx, dw)``; the
+    gradients are of the MEAN over ``count`` targets, None without
+    ``with_grads``.  ``w`` None: ``x`` holds the logits themselves."""
+    logits, lf, ex, total, log_z, picked = _softmax_parts(x, w, targets)
     sums = jnp.sum(log_z - picked), jnp.sum(jnp.square(log_z))
     if not with_grads:
         return sums + (None, None)
@@ -78,17 +85,20 @@ def _chunk(x, w, targets, z_loss: float, count: int, with_grads: bool):
                    _matmul("bshk,bspv->hkpv", x, grad).astype(jnp.float32))
 
 
+def _split(n_chunks: int, t):
+    """``t [b, s, ...]`` as ``[n_chunks, b, s / n_chunks, ...]``: what a walk
+    scans over."""
+    return jnp.moveaxis(t.reshape(
+        (t.shape[0], n_chunks, t.shape[1] // n_chunks) + t.shape[2:]), 1, 0)
+
+
 def _walk(x, w, targets, z_loss: float, n_chunks: int, with_grads: bool):
     """The loss (float32) and, with ``with_grads``, ``(dx, dw)``."""
     count = targets.size
     if n_chunks == 1:
         a, z, dx, dw = _chunk(x, w, targets, z_loss, count, with_grads)
     else:
-        def split(t):
-            return jnp.moveaxis(t.reshape(
-                (t.shape[0], n_chunks, t.shape[1] // n_chunks) + t.shape[2:]),
-                1, 0)
-
+        split = functools.partial(_split, n_chunks)
         keep_dw = with_grads and w is not None
 
         def step(carry, chunk):
@@ -137,3 +147,99 @@ def head_xent(x, w: typing.Optional[jax.Array], targets, z_loss: float):
     v = x.shape[-1] if w is None else w.shape[-1]
     with jax.named_scope("head_loss"):
         return _xent(x, w, targets, float(z_loss), chunks_for(b, s, p, v))
+
+
+# ---- the walk a token: a loss and a weight each ----------------------------
+#
+# A looped model (model/loop.py) weighs every token's cross-entropy at every
+# pass by that token's exit probability, and the gate that makes the
+# probability needs the token's loss back.  The gate reads the passes'
+# outputs, not their logits, so the weights are known before the first chunk
+# is walked: they go IN, each chunk's gradient is made with them at once
+# (as ``_chunk`` makes its with ``1 / count``), and the backward rule scales
+# the residuals by a scalar like ``_xent_bwd`` — no chunk's logits are made
+# twice, which a walk that waited for a ``[b, s]`` cotangent would have to.
+# What comes OUT beside the weighted sum is the losses a token, which are the
+# weighted sum's gradient by the weights.  All passes go through ONE walk
+# (stacked on the lead axis), so one float32 head gradient takes every
+# chunk's part.
+
+def _chunk_tokens(x, w, targets, weights, z_loss: float, with_grads: bool):
+    """One chunk: ``(token losses, dx, dw)``, the gradients of ``sum(weights
+    * token losses)``, None without ``with_grads``."""
+    logits, lf, ex, total, log_z, picked = _softmax_parts(x, w, targets)
+    token = log_z - picked
+    if z_loss:
+        token = token + z_loss * jnp.square(log_z)
+    if not with_grads:
+        return token, None, None
+    grad = ex * (((1.0 + 2.0 * z_loss * log_z) * weights)[..., None] / total)
+    hit = jax.lax.broadcasted_iota(jnp.int32, lf.shape, lf.ndim - 1) \
+        == targets[..., None]
+    grad = jnp.where(hit, grad - weights[..., None], grad).astype(logits.dtype)
+    return (token, _matmul("bspv,hkpv->bshk", grad, w).astype(x.dtype),
+            _matmul("bshk,bspv->hkpv", x, grad).astype(jnp.float32))
+
+
+def _walk_tokens(x, w, targets, weights, z_loss: float, n_chunks: int,
+                 with_grads: bool):
+    """The token losses ``[b, s, p]`` (float32) and, with ``with_grads``,
+    ``(dx, dw)`` of their weighted sum."""
+    if n_chunks == 1:
+        token, dx, dw = _chunk_tokens(x, w, targets, weights, z_loss,
+                                      with_grads)
+    else:
+        split = functools.partial(_split, n_chunks)
+
+        def join(t):
+            return jnp.moveaxis(t, 0, 1).reshape(
+                (t.shape[1], n_chunks * t.shape[2]) + t.shape[3:])
+
+        def step(dw, chunk):
+            token, dx, part = _chunk_tokens(chunk[0], w, chunk[1], chunk[2],
+                                            z_loss, with_grads)
+            return (dw + part if with_grads else dw), (token, dx)
+
+        dw, (token, dx) = jax.lax.scan(
+            step, jnp.zeros(w.shape if with_grads else (), jnp.float32),
+            (split(x), split(targets), split(weights)))
+        token = join(token)
+        dx = join(dx) if with_grads else None
+    return token, dx, (dw.astype(w.dtype) if with_grads else None)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _xent_tokens(x, w, targets, weights, z_loss: float, n_chunks: int):
+    token = _walk_tokens(x, w, targets, weights, z_loss, n_chunks, False)[0]
+    return jnp.sum(weights * token), token
+
+
+def _xent_tokens_fwd(x, w, targets, weights, z_loss, n_chunks):
+    token, dx, dw = _walk_tokens(x, w, targets, weights, z_loss, n_chunks,
+                                 True)
+    return (jnp.sum(weights * token), token), (dx, dw, token)
+
+
+def _xent_tokens_bwd(z_loss, n_chunks, res, g):
+    # the token losses leave as plain values (head_xent_tokens stops their
+    # gradient), so only the weighted sum's cotangent arrives
+    dx, dw, token = res
+    return (dx * g[0].astype(dx.dtype), dw * g[0].astype(dw.dtype), None,
+            token * g[0])
+
+
+_xent_tokens.defvjp(_xent_tokens_fwd, _xent_tokens_bwd)
+
+
+def head_xent_tokens(x, w, targets, weights, z_loss: float):
+    """``(sum over the tokens of weights * loss, the losses [b, s, p])`` of
+    ``targets [b, s, p]`` under the logits ``x [b, s, h, k] . w [h, k, p,
+    v]``, float32: each token's cross-entropy (+ ``z_loss`` times its squared
+    log-partition) and a float32 weight a token.  The sum carries gradients
+    to ``x``, ``w`` and ``weights``; the losses beside it are values only.
+    With ``weights`` all ``1 / targets.size`` the sum is ``head_xent``."""
+    b, s, p = targets.shape
+    with jax.named_scope("head_loss"):
+        loss, token = _xent_tokens(x, w, targets, weights, float(z_loss),
+                                   chunks_for(b, s, p, w.shape[-1]))
+    return loss, jax.lax.stop_gradient(token)

@@ -138,14 +138,21 @@ def _bottleneck_stash(params: ModelParameter, mesh) -> typing.Tuple[int, int, bo
     return layers, nbytes, crosses
 
 
+def _executions(params: ModelParameter) -> int:
+    """How often a step runs each layer of one depth-unit: ``depth`` times,
+    and a looped model (model/loop.py) every pass again — each execution
+    leaves outputs of its own for the backward."""
+    return params.depth * params.loop_steps
+
+
 def _offered_stash(params: ModelParameter, kind: str, shards: int
                    ) -> typing.Tuple[int, int]:
-    """``(layers, per-device bytes)`` of the experts or the recurrent kind
-    over the whole depth: what every layer that offers the kind DECLARES
-    (``Offer.nbytes``, for the whole batch)."""
+    """``(executions, per-device bytes)`` of the experts or the recurrent
+    kind over the whole step: what every layer that offers the kind DECLARES
+    (``Offer.nbytes``, for the whole batch), each time it runs."""
     offered = [offer.nbytes for offer in offers(params, kind)]
-    return len(offered) * params.depth, -(
-        -sum(offered) * params.depth * max(1, params.macro_batching)
+    return len(offered) * _executions(params), -(
+        -sum(offered) * _executions(params) * max(1, params.macro_batching)
         // shards)
 
 
@@ -166,9 +173,9 @@ def _attention_min_keys(params: ModelParameter) -> int:
 
 def _saved_attention(params: ModelParameter, mesh, min_keys: int
                      ) -> typing.Tuple[int, int]:
-    """``(layers, per-device bytes)`` of the attention kind under
-    ``checkpoint`` over the whole depth: the ``(out, lse)`` every layer
-    OFFERS whose flash call engages — on one device, under
+    """``(executions, per-device bytes)`` of the attention kind under
+    ``checkpoint`` over the whole step: the ``(out, lse)`` every layer
+    OFFERS, each time it runs, whose flash call engages — on one device, under
     ``use_flash_attention``, at a sequence of whole 128-tiles: model/spatial.py
     ``_flash`` — and in which a query sees at least ``min_keys`` keys
     (parallel/flash_attention.py ``attention``'s "name" mode, the same
@@ -178,8 +185,8 @@ def _saved_attention(params: ModelParameter, mesh, min_keys: int
         return 0, 0
     saved = [offer.nbytes for offer in offers(params, "attention")
              if offer.keys >= min_keys]
-    return len(saved) * params.depth, sum(saved) * params.depth \
-        * max(1, params.macro_batching)
+    return len(saved) * _executions(params), sum(saved) \
+        * _executions(params) * max(1, params.macro_batching)
 
 
 def _save_residual_bytes(params: ModelParameter) -> int:
@@ -317,9 +324,11 @@ def _attention_sites(params: ModelParameter, mesh) -> int:
 
 def stash_plan(params: ModelParameter, mesh=None
                ) -> typing.Dict[str, typing.Tuple[int, int]]:
-    """``{kind: (layers, per-device bytes)}`` of what the memory strategy
-    of the step this (config, mesh) builds keeps for its backward, from its
-    shapes; ``(0, 0)`` for a kind that is not engaged (a strategy that has
+    """``{kind: (layer executions, per-device bytes)}`` of what the memory
+    strategy of the step this (config, mesh) builds keeps for its backward,
+    from its shapes — a layer counts once each time the step runs it, which
+    is once everywhere but in a looped model (``loop_steps``) —; ``(0, 0)``
+    for a kind that is not engaged (a strategy that has
     no way to keep it, a pipeline mesh, an explicit policy, a rule that
     declined, no such layer).  ``Trainer`` publishes it as
     ``hbnlp_remat_stash_bytes{kind}`` / ``hbnlp_remat_stash_layers{kind}``
@@ -381,11 +390,14 @@ def stash_names(params: ModelParameter, mesh=None) -> typing.Tuple[str, ...]:
     return tuple(dict.fromkeys(names))     # a name once, in order
 
 
-def stash_line(plan: typing.Dict[str, typing.Tuple[int, int]]) -> str:
-    """The start-up line beside ``placement_report``'s."""
+def stash_line(plan: typing.Dict[str, typing.Tuple[int, int]],
+               looped: bool = False) -> str:
+    """The start-up line beside ``placement_report``'s; ``looped``: a layer
+    runs more than once a step, and the counts are its executions."""
+    unit = "executions" if looped else "layers"
     return "remat stash: " + "; ".join(
-        f"{kind} {layers} layers, {nbytes} bytes a device"
-        for kind, (layers, nbytes) in plan.items())
+        f"{kind} {count} {unit}, {nbytes} bytes a device"
+        for kind, (count, nbytes) in plan.items())
 
 
 def resolve_remat(params: ModelParameter, mesh=None) -> str:

@@ -69,6 +69,14 @@ def _grad_norm_metrics(grads: Params, debug: bool) -> typing.Dict[str, jax.Array
 #: publishes
 _LAYER_STATS = declare.stats()
 
+
+
+def _stat_of(metric: str) -> str:
+    """The declared statistic behind a step metric: itself, or what stands
+    before the ``/<index>`` of a labelled one."""
+    return metric.partition("/")[0]
+
+
 #: ``Trainer._loaded_probe`` before the first step is dispatched
 _FIRST_STEP = object()
 
@@ -341,7 +349,7 @@ class Trainer:
         and its part of the line).  Set when the step is built; returns the
         start-up line that says the same."""
         plan = stash_plan(self.params, self.mesh)
-        line = stash_line(plan)
+        line = stash_line(plan, self.params.loop_steps > 1)
         r = telemetry.registry()
         for fact in declare.facts():
             value = fact.value(self.params, self.mesh, None)
@@ -354,7 +362,8 @@ class Trainer:
                          "residuals instead of being replayed", ("kind",))
         nlayers = r.gauge("hbnlp_remat_stash_layers",
                           "layer outputs riding the memory strategy's "
-                          "residuals", ("kind",))
+                          "residuals, one each time the step runs the layer "
+                          "(a looped model: every pass)", ("kind",))
         for kind, (layers, size) in plan.items():
             nbytes.labels(kind).set(size)
             nlayers.labels(kind).set(layers)
@@ -418,7 +427,7 @@ class Trainer:
             state, metrics = self._step_fn(state, batch, rng)
             if self._loaded_probe is not None:
                 self._mark_step_loaded(metrics["loss"])
-            if any(k in metrics for k in _LAYER_STATS):
+            if any(_stat_of(k) in _LAYER_STATS for k in metrics):
                 self._publish_layer_stats(metrics)
             return state, metrics
 
@@ -443,15 +452,19 @@ class Trainer:
         running is left for a later call, so this never waits.  The last
         steps of a run stay unread."""
         pending = self._pending_layer_stats
-        pending.append({k: metrics[k] for k in _LAYER_STATS if k in metrics})
+        pending.append({k: v for k, v in metrics.items()
+                        if _stat_of(k) in _LAYER_STATS})
         r = telemetry.registry()
         while pending and all(v.is_ready() for v in pending[0].values()):
             for key, value in pending.popleft().items():
-                stat = _LAYER_STATS[key]
-                if stat.kind == "gauge":
-                    r.gauge(stat.metric, stat.help).set(float(value))
-                else:
+                stat = _LAYER_STATS[_stat_of(key)]
+                if stat.kind == "counter":
                     r.counter(stat.metric, stat.help).inc(float(value))
+                elif stat.label:
+                    r.gauge(stat.metric, stat.help, (stat.label,)).labels(
+                        key.partition("/")[2]).set(float(value))
+                else:
+                    r.gauge(stat.metric, stat.help).set(float(value))
 
     def eval_loss(self, state: TrainState,
                   batch: typing.Dict[str, jax.Array]

@@ -25,11 +25,11 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _kinds(attention=(0, 0), bottleneck=(0, 0), experts=(0, 0),
-           recurrent=(0, 0)):
+           recurrent=(0, 0), unit="layers"):
     plan = {"attention": attention, "bottleneck": bottleneck,
             "experts": experts, "recurrent": recurrent}
     return ("remat stash: " + "; ".join(
-        f"{kind} {layers} layers, {nbytes} bytes a device"
+        f"{kind} {layers} {unit}, {nbytes} bytes a device"
         for kind, (layers, nbytes) in plan.items()), plan)
 
 
@@ -71,6 +71,11 @@ _CELLS = {
         _kinds(attention=(1, 72351744)),
         "; ssd chunk states 67108864 bytes a device",
         {"hbnlp_ssd_state_bytes": 67108864}),
+    # PR 49: a looped model counts a layer each time the step runs it, and
+    # says so: 12 layers x 4 passes of (out [2, 4096, 16, 128] bfloat16, lse
+    # [2, 16, 4096] float32).  The nine lines above stand as they were
+    "train_ouro_2_6b_loop4_s4k": (
+        _kinds(attention=(48, 1635778560), unit="executions"), "", {}),
 }
 #: the facts that read 0 where no layer has the mechanism; the others have no
 #: series there
@@ -130,8 +135,10 @@ def _config_files():
 #: 14424d9e0db6655b911c55f5cf6f76f5b6505273; PR 48 added the series
 #: ``hbnlp_ssd_scan_kernel_layers`` to every file and ``; scan kernel N
 #: layers`` to the lines of the two granite files: before it
-#: 0120375caf4cdf6e013cbb72aedfbae4ab651566)
-_FILE_DIGEST = "1ae9e7a6b258ab7ceafda5cfe9cd9ec99860c294"
+#: 0120375caf4cdf6e013cbb72aedfbae4ab651566; PR 49 added the two Ouro files,
+#: whose lines count executions: without them the digest is PR 48's
+#: 1ae9e7a6b258ab7ceafda5cfe9cd9ec99860c294, every other line as it was)
+_FILE_DIGEST = "cab4c9c0a1c8793f2e07b4e41810430d7e27b83b"
 
 
 def every_configuration_file_starts_as_on_the_parent_test(monkeypatch):
@@ -227,9 +234,12 @@ def declared_statistic_folds_as_on_the_parent_test(name, kind, metric, text,
 
 
 def statistics_are_all_declared_test():
-    """The trainer's table is the declarations': fourteen statistics, and a
-    step whose layers report nothing (or only some) has only those."""
-    assert len(_LAYER_STATS) == 14 == len(declare.stats())
+    """The trainer's table is the declarations': fourteen statistics of
+    layers and (PR 49) three of a looped model's loss, and a step whose
+    layers report nothing (or only some) has only those."""
+    assert len(_LAYER_STATS) == 17 == len(declare.stats())
+    assert {name for name in _LAYER_STATS if name.startswith("loop_")} == {
+        "loop_pass_loss", "loop_exit_share", "loop_exit_entropy"}
     base = {"loss", "token_loss", "video_loss", "accuracy"}
     assert set(_info_metrics(_info(None))) == base
     some = {"ssd_log_decay_min": [-1.0]}
