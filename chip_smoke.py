@@ -260,14 +260,15 @@ def leg_kernels(ctx):
     if ctx["rehearsal"]:
         cmd += ["--flash-seq", "256", "--mixer-batch", "2",
                 "--solve-chunks", "2", "--band-heads", "1",
-                "--band-seq", "1024", "--scan-seq", "512"]
+                "--band-seq", "1024", "--scan-seq", "512",
+                "--rule-seq", "256"]
     rc, wall = run_to_end("kernels", cmd, log, 540)
     rows = []
     with open(log, errors="replace") as f:
         for line in f:
             if line.startswith('{"kernel"'):
                 rows.append(json.loads(line))
-    check(rc == 0 and len(rows) == 5,
+    check(rc == 0 and len(rows) == 6,
           f"kernel parity failed (rc {rc}):\n{tail(log)}")
     if not ctx["rehearsal"]:
         check(all(r["implementation"] == "pallas" for r in rows),

@@ -66,15 +66,41 @@ N)(I + N^2)(I + N^4) ..`` of the nilpotent ``N`` needs as many matmuls and
 is NOT used: with correlated keys the powers of ``N`` grow to 1e7 and beyond
 before they cancel, and float32 returns garbage.)  Decays, cumulative
 sums, ``beta``, the solve and the carried state are float32; the other
-matmuls take the calculation dtype with float32 accumulation.  Autodiff gives
-the backward, a group of heads at a time (``grouped_rule``): what it keeps of
-the rule over all heads at once does not fit a chip at 16,384 tokens.  Under
-the ``checkpoint`` strategy a step therefore runs the rule forward TWICE and
-backward once — the step's forward and the group's own re-materialisation —
-where the block's ``jax.checkpoint`` saves the rule's output (``SAVED_NAMES``;
-model/remat.py's ``recurrent`` kind, 189 MB a layer at 16,384 tokens), and
-three times where it does not: the block's replay then runs it once more, for
-nothing but ``o``.
+matmuls take the calculation dtype with float32 accumulation.
+
+Two implementations of that one arithmetic, chosen by
+parallel/delta_rule.py's ``rule_kernel_applies`` on the backend and the
+shapes (no knob).  On a TPU at a chunk the solve's kernel takes, whole lane
+tiles of positions and head widths in whole sublane tiles (``kernel_rule``):
+all heads at once.  The solve's input ``strict_tril(diag(beta) (K~ K~^T o
+Gamma))`` is the Pallas pair ``delta_strict_fwd`` / ``delta_strict_bwd``,
+``_inverse_unit_lower`` is called ONCE a layer, XLA keeps ``gamma``'s
+cumulative sum, ``T = X diag(beta)`` and ``max|T|``, and everything that
+touches the state is the Pallas pair ``delta_rule_fwd`` / ``delta_rule_bwd``
+under one ``jax.custom_vjp``: the float32 state of ALL heads stays in VMEM
+along a sequential walk over the chunks, the backward is one reverse walk
+with ``dS`` carried the same way, and its residuals are the inputs, ``T`` and
+the states entering every chunk in the calculation dtype
+(``hbnlp_ssd_state_bytes``).  Nothing shaped ``[.., l, l]`` but ``strict``,
+the inverse, ``T`` and their cotangents reaches HBM.
+Elsewhere — the CPU, odd chunks, toy widths, and as the kernels' oracle —
+autodiff gives the backward of the XLA form above, a group of heads at a time
+(``grouped_rule``): what it keeps of the rule over all heads at once does not
+fit a chip at 16,384 tokens, so each group is rematerialised in its own
+backward.
+
+How often the rule runs forward in a step under the ``checkpoint`` strategy:
+TWICE on either path, backward once.  The block's ``jax.checkpoint`` saves
+the rule's output (``SAVED_NAMES``; model/remat.py's ``recurrent`` kind, 189
+MB a layer at 16,384 tokens), so the gate norm and the out-projection
+differentiate through the saved ``o``; the second forward is the block's
+replay on the kernel path (the three forward kernels run again, for ``T`` and
+the entering states the backward reads: neither is offered to the
+``recurrent`` kind — 1.0 GB more over the cell's three layers at the step's
+peak, which is the attention layer's backward), the group's own
+re-materialisation on
+the XLA path.  Where the kind does not ride, the XLA path runs forward three
+times: the block's replay once more, for nothing but ``o``.
 
 Training and full-sequence forward on one device; a decode / prefill form (a
 state and a conv window per sequence) is a later issue.
@@ -93,6 +119,8 @@ from ..core import scope
 from ..core.dims import Dim
 from ..core.tensor import NamedTensor, nt, transpose_to
 from ..parallel.causal_conv import causal_conv_silu, kernel_applies
+from ..parallel.delta_rule import (delta_rule_pair, delta_strict,
+                                   rule_kernel_applies)
 from ..parallel.delta_solve import (inverse_unit_lower, inverse_unit_lower_bwd,
                                     solve_kernel_applies)
 from .backend import ConstantInit, UniformInit, normal_var
@@ -112,10 +140,12 @@ GROUP_BYTES = 48 << 20
 #: ``checkpoint`` strategy the block's ``jax.checkpoint`` saves it where
 #: model/remat.py's ``recurrent`` kind rides (model/blocks.py
 #: ``_checkpoint_policy``): the block's replay then runs no forward of the
-#: rule — the gate norm and the out-projection differentiate through the
-#: saved ``o``, and the rule's own backward makes everything it needs again
-#: from ``q, k, v, beta, g`` inside ``grouped_rule``'s ``jax.checkpoint``.
-#: ``transform_max`` is not named: the replay needs no statistic.
+#: rule on the XLA path — the gate norm and the out-projection differentiate
+#: through the saved ``o``, and the rule's own backward makes everything it
+#: needs again from ``q, k, v, beta, g`` inside ``grouped_rule``'s
+#: ``jax.checkpoint`` — and, on the kernel path, the forward kernels for the
+#: pair's residuals alone.  ``transform_max`` is not named: the replay
+#: needs no statistic.
 SAVED_NAMES = ("gated_delta_out",)
 
 
@@ -288,6 +318,28 @@ def grouped_rule(q, k, v, beta, g, chunk: int):
     return (jnp.moveaxis(o, 0, 2).reshape(v.shape), jnp.max(transform_max))
 
 
+def kernel_rule(q, k, v, beta, g, chunk: int):
+    """``delta_rule`` over all heads at once in the Pallas pairs of
+    parallel/delta_rule.py (shapes as ``rule_kernel_applies`` accepts them):
+    ``delta_strict`` makes the solve's input from ``k``, ``gamma`` and
+    ``beta``, ``_inverse_unit_lower`` solves every chunk and head of the
+    layer in ONE call, XLA scales the inverse to ``T`` and takes its largest
+    magnitude, and ``delta_rule_pair`` does everything that touches the
+    state.  The pairs keep their inputs, ``T`` and the entering states of
+    all heads for the backward and nothing else shaped ``[.., l, l]``, so no
+    group of heads is run or rematerialised alone."""
+    bsz, s, h, _ = q.shape
+    with jax.named_scope("decay"):
+        gamma = jnp.cumsum(g.reshape(bsz, s // chunk, chunk, h),
+                           axis=2).reshape(bsz, s, h)
+    with jax.named_scope("solve"):
+        scale = jnp.moveaxis(beta.reshape(bsz, s // chunk, chunk, h), 2, 3)
+        transform = _inverse_unit_lower(delta_strict(k, gamma, beta, chunk)) \
+            * scale[..., None, :]
+        transform_max = jax.lax.stop_gradient(jnp.max(jnp.abs(transform)))
+    return delta_rule_pair(q, k, v, gamma, transform, chunk), transform_max
+
+
 def gated_delta(args: BlockArgs) -> NamedTensor:
     """Layer ``gated_delta`` (module docstring).  Parameters in creation
     order: ``W_qkv``, ``W_gate``, ``W_ba`` normal(0.02); the conv's weight
@@ -352,7 +404,9 @@ def gated_delta(args: BlockArgs) -> NamedTensor:
             * (2.0 if params.delta_allow_neg_eigval else 1.0)
         g = -jnp.exp(a_log) * jax.nn.softplus(a_raw.astype(jnp.float32)
                                               + dt_bias)
-        o, transform_max = grouped_rule(
+        rule = kernel_rule if rule_kernel_applies(chunk, h, dk, dv, s) \
+            else grouped_rule
+        o, transform_max = rule(
             q, key, qkv[..., 2 * d_key:].reshape(bsz, s, h, dv), beta, g,
             chunk)
         o = checkpoint_name(o, SAVED_NAMES[0])
@@ -372,17 +426,35 @@ def gated_delta(args: BlockArgs) -> NamedTensor:
     return transpose_to(nt(out, token_dims + feats), x.dims)
 
 
+def _rule(params: ModelParameter):
+    """``(chunk, heads, d_k, d_v, sequence)`` as ``rule_kernel_applies``
+    takes them."""
+    s = params.sequence_dim.size
+    return (min(params.delta_chunk, s), params.delta_heads,
+            params.delta_key_features, params.delta_value_features, s)
+
+
+def _heads_a_call(params: ModelParameter, backend=None) -> int:
+    """The heads whose rule runs, and keeps its states, at once: all of them
+    where the rule is the Pallas pair, else one group (``grouped_rule``)."""
+    if rule_kernel_applies(*_rule(params), backend):
+        return params.delta_heads
+    bsz, s = params.batch_dim.size, params.sequence_dim.size
+    return _group_heads(bsz, s, params.delta_heads, min(params.delta_chunk, s))
+
+
 def _state_bytes(params: ModelParameter) -> int:
-    """``[batch, sequence / delta_chunk, heads of a group,
-    delta_value_features, delta_key_features]`` in the calculation dtype: the
-    states entering every chunk of ONE group of heads (``grouped_rule``
-    rematerialises a group at a time), which ``state_out`` and the
-    inter-chunk scan's backward read (the carried state itself is float32,
-    one chunk's)."""
+    """``[batch, sequence / delta_chunk, heads a call, delta_value_features,
+    delta_key_features]`` in the calculation dtype: the states entering every
+    chunk that are alive at once for the backward — of ALL heads where the
+    rule is the Pallas pair of parallel/delta_rule.py (its forward writes
+    them, its backward reads them), of ONE group of heads on the XLA form
+    (``grouped_rule`` rematerialises a group at a time; ``state_out`` and the
+    inter-chunk scan's backward read them).  The carried state itself is
+    float32, one chunk's."""
     bsz, s = params.batch_dim.size, params.sequence_dim.size
     chunk = min(params.delta_chunk, s)
-    return bsz * max(1, s // chunk) \
-        * _group_heads(bsz, s, params.delta_heads, chunk) \
+    return bsz * max(1, s // chunk) * _heads_a_call(params) \
         * params.delta_value_features * params.delta_key_features \
         * jnp.dtype(params.calculation_dtype).itemsize
 
@@ -402,13 +474,13 @@ def _offer(params: ModelParameter, extras) -> Offer:
                  * jnp.dtype(params.calculation_dtype).itemsize)
 
 
-def _solve(params: ModelParameter):
+def _solve(params: ModelParameter, backend=None):
     """``(chunk, systems)`` of one call of ``_inverse_unit_lower``: a chunk
-    and a head each, over one group of heads (``grouped_rule``)."""
+    and a head each, over the heads of a call (``_heads_a_call``: every head
+    under ``kernel_rule``, one group under ``grouped_rule``)."""
     bsz, s = params.batch_dim.size, params.sequence_dim.size
     chunk = min(params.delta_chunk, s)
-    return chunk, bsz * max(1, s // chunk) \
-        * _group_heads(bsz, s, params.delta_heads, chunk)
+    return chunk, bsz * max(1, s // chunk) * _heads_a_call(params, backend)
 
 
 gated_delta.declares = Layer(
@@ -419,4 +491,4 @@ gated_delta.declares = Layer(
                 "the newest finished step, all gated_delta layers: what its "
                 "lower-precision matmul operands have to carry", "max"),),
     offer=_offer, facts=FACTS,
-    recurrent=Recurrent(_state_bytes, _conv, _solve))
+    recurrent=Recurrent(_state_bytes, _conv, _solve, rule=_rule))

@@ -4,8 +4,8 @@ what they refuse and the layout they take, the per-channel float32
 parameters and their initialisers, the causal depthwise conv's XLA form, what
 a layer declares of its recurrence (:class:`Recurrent`) and the start-up
 facts made of it (:data:`FACTS`: the chunk states alive for the backward, the
-layers whose conv, whose triangular solve and whose chunked scan are the
-Pallas pairs)."""
+layers whose conv, whose triangular solve, whose chunked rule and whose
+chunked scan are the Pallas pairs)."""
 from __future__ import annotations
 
 import typing
@@ -18,6 +18,7 @@ from ..config import BlockArgs, ModelParameter
 from ..core import scope
 from ..core.sharding import shard_geometry
 from ..parallel.causal_conv import kernel_applies
+from ..parallel.delta_rule import rule_kernel_applies
 from ..parallel.delta_solve import solve_kernel_applies
 from ..parallel.ssd_scan import ssd_kernel_applies
 from .declare import Fact, layers
@@ -29,9 +30,14 @@ class Recurrent(typing.NamedTuple):
     whole batch — and ``conv(params)`` — ``(channels, taps, offset)`` of its
     causal depthwise conv, as ``parallel/causal_conv.kernel_applies`` takes
     them (None: the layer has no conv, ``lightning``).  A layer that solves a unit triangular system a chunk declares it:
-    ``solve(params)`` — ``(chunk, matrices a call)`` as
+    ``solve(params, backend)`` — ``(chunk, matrices a call)`` as
     ``parallel/delta_solve.solve_kernel_applies`` takes them
-    (``gated_delta``: the systems of one group of heads); None = none.
+    (``gated_delta``: the systems of every head where its rule is the Pallas
+    pair on that backend, else of one group of heads); None = none.
+    A layer whose chunked rule can be ``parallel/delta_rule.py``'s pair
+    declares its shapes: ``rule(params)`` — ``(chunk, heads, d_k, d_v,
+    sequence)`` as ``rule_kernel_applies`` takes them (``gated_delta``);
+    None = none.
     A layer whose chunked scan can be ``parallel/ssd_scan.py``'s pair declares
     its shapes: ``scan(params)`` — ``(sequence, chunk, heads, head features,
     state)`` as ``ssd_kernel_applies`` takes them (``mamba``); None = none.
@@ -47,9 +53,11 @@ class Recurrent(typing.NamedTuple):
     state_bytes: typing.Callable[[ModelParameter], int]
     conv: typing.Optional[
         typing.Callable[[ModelParameter], typing.Tuple[int, int, int]]]
-    solve: typing.Optional[
-        typing.Callable[[ModelParameter], typing.Tuple[int, int]]] = None
+    solve: typing.Optional[typing.Callable[
+        [ModelParameter, typing.Optional[str]], typing.Tuple[int, int]]] = None
     scan: typing.Optional[
+        typing.Callable[[ModelParameter], typing.Tuple[int, ...]]] = None
+    rule: typing.Optional[
         typing.Callable[[ModelParameter], typing.Tuple[int, ...]]] = None
 
 
@@ -100,12 +108,21 @@ def solve_kernel_layers(params: ModelParameter, backend=None
     triangular solve (``parallel/delta_solve.py``), by the predicate the
     layer itself calls on the systems it declares; None where no layer
     declares a solve."""
-    solves = [spec.solve(params) for spec in recurrent_layers(params)
-              if spec.solve is not None]
+    solves = [spec.solve(params, backend)
+              for spec in recurrent_layers(params) if spec.solve is not None]
     if not solves:
         return None
     return params.depth * sum(solve_kernel_applies(chunk, matrices, backend)
                               for chunk, matrices in solves)
+
+
+def _shape_kernel_layers(params: ModelParameter, field: str, applies,
+                         backend) -> typing.Optional[int]:
+    shapes = [getattr(spec, field)(params) for spec in recurrent_layers(params)
+              if getattr(spec, field) is not None]
+    if not shapes:
+        return None
+    return params.depth * sum(applies(*each, backend) for each in shapes)
 
 
 def scan_kernel_layers(params: ModelParameter, backend=None
@@ -114,12 +131,14 @@ def scan_kernel_layers(params: ModelParameter, backend=None
     chunked scan (``parallel/ssd_scan.py``), by the predicate the layer
     itself calls on the shapes it declares; None where no layer declares a
     scan."""
-    scans = [spec.scan(params) for spec in recurrent_layers(params)
-             if spec.scan is not None]
-    if not scans:
-        return None
-    return params.depth * sum(ssd_kernel_applies(*shapes, backend)
-                              for shapes in scans)
+    return _shape_kernel_layers(params, "scan", ssd_kernel_applies, backend)
+
+
+def rule_kernel_layers(params: ModelParameter, backend=None
+                       ) -> typing.Optional[int]:
+    """The same for the chunked delta rule (``parallel/delta_rule.py``);
+    None where no layer declares a rule."""
+    return _shape_kernel_layers(params, "rule", rule_kernel_applies, backend)
 
 
 #: every recurrent mixer's ``declares.facts``
@@ -142,6 +161,12 @@ FACTS = (
          "layer)",
          lambda params, mesh, backend: solve_kernel_layers(params, backend),
          "solve kernel {} layers"),
+    Fact(32, "hbnlp_delta_rule_kernel_layers",
+         "gated_delta layers of the built step whose chunked rule is the "
+         "Pallas kernel pair (0 on the XLA form over groups of heads, and "
+         "without such a layer)",
+         lambda params, mesh, backend: rule_kernel_layers(params, backend),
+         "rule kernel {} layers"),
     Fact(35, "hbnlp_ssd_scan_kernel_layers",
          "mamba layers of the built step whose chunked scan is the Pallas "
          "kernel pair (0 on the XLA einsums, and without such a layer)",

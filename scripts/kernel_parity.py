@@ -38,6 +38,19 @@ form ``ssd_xla`` in float32 under ``highest``: ``y`` and the five gradients
 inside :data:`TOLERANCE`, beside what the XLA form in bfloat16 (the parent's
 path) is off against the same reference, and each path's time a call.
 
+A sixth leg holds layer ``gated_delta``'s chunked rule
+(parallel/delta_rule.py, through ``model/gated_delta.py kernel_rule``: XLA's
+``T`` around the solve's pair, then the rule's pair) at the Olmo-Hybrid
+cell's shapes — ``q`` / ``k [1, 16384, 30, 96]``, ``v [.., 192]``, chunk 64,
+``beta`` up to 2, the layer's own decays — to the XLA form ``grouped_rule``
+in float32 under ``highest``: ``o`` and the five gradients no further off
+than half as much again as the XLA form in bfloat16 (the parent's path) is
+off against the same reference on the same chip (or :data:`TOLERANCE`, if
+that is more: with keys that share a direction and ``beta`` up to 2 the
+solved transform reaches 3, ``W``, ``U``, ``V'`` and the carried state's
+bfloat16 copy each round once more, and the XLA form itself is 2-8% of the
+largest entry off the float32 one); each path's time a call.
+
 Shapes: flash at the long-context recipe's per-chip shape (seq 16,384, head
 dim 128; two heads so the dense reference's [s, s] scores fit beside it);
 the mixer at the flagship's (8 heads, seq 512, 512 features/head, batch 32).
@@ -253,12 +266,31 @@ def _band_leg(shape=(2, 8192, 72, 128), window: int = 512) -> bool:
     return bool(ok)
 
 
+def _errors_and_ms(forms, ct, operands, want, calls: int):
+    """``({name: errors against want}, {name: ms a call forward + backward})``
+    of each ``(name, fn)``, compiled for the local device."""
+    import time
+
+    import jax
+
+    errs, ms = {}, {}
+    for name, fn in forms:
+        compiled = _with_grads(fn).lower(ct, *operands).compile()
+        got = jax.block_until_ready(compiled(ct, *operands))
+        errs[name] = {k: round(v, 6) for k, v in _errors(got, want).items()}
+        start = time.perf_counter()
+        for _ in range(calls):
+            got = compiled(ct, *operands)
+        jax.block_until_ready(got)
+        ms[name] = round((time.perf_counter() - start) * 1000 / calls, 3)
+        del got, compiled
+    return errs, ms
+
+
 def _scan_leg(s: int = 8192, heads: int = 64, p: int = 64, n: int = 128,
               chunk: int = 256) -> bool:
     """The scan's kernel pair and the XLA form in bfloat16, both against the
     XLA form in float32 ``highest`` on the same device."""
-    import time
-
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -293,22 +325,74 @@ def _scan_leg(s: int = 8192, heads: int = 64, p: int = 64, n: int = 128,
     with jax.default_matmul_precision("highest"):
         want = _with_grads(xla)(ct, *(t.astype(jnp.float32)
                                       for t in operands))
-    errs, ms = {}, {}
-    for name, fn in (("kernel", kernel), ("xla", xla)):
-        compiled = _with_grads(fn).lower(ct, *operands).compile()
-        got = jax.block_until_ready(compiled(ct, *operands))
-        errs[name] = {k: round(v, 6) for k, v in _errors(got, want).items()}
-        start = time.perf_counter()
-        for _ in range(5):
-            got = compiled(ct, *operands)
-        jax.block_until_ready(got)
-        ms[name] = round((time.perf_counter() - start) * 200, 3)
+    errs, ms = _errors_and_ms((("kernel", kernel), ("xla", xla)), ct,
+                              operands, want, 5)
     ok = (applies or platform == "cpu") and all(
         e <= TOLERANCE for e in errs["kernel"].values())
     print(json.dumps({"kernel": "ssd_scan", "ok": bool(ok),
                       "implementation": "pallas" if applies else
                       "pallas (interpret)", "max_err_over_max_ref": errs,
                       "tolerance": TOLERANCE, "log_decay_min": low,
+                      "ms_a_call_forward_and_backward": ms,
+                      "shapes": [list(t.shape) for t in operands],
+                      "chunk": chunk, "dtype": "bfloat16"}), flush=True)
+    return bool(ok)
+
+
+def _rule_leg(s: int = 16384, heads: int = 30, dk: int = 96, dv: int = 192,
+              chunk: int = 64) -> bool:
+    """The rule's kernel pair and the XLA form in bfloat16, both against the
+    XLA form in float32 ``highest`` on the same device."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from homebrewnlp_tpu.model import gated_delta
+    from homebrewnlp_tpu.parallel import delta_rule
+
+    rng = np.random.default_rng(50)
+    shared = rng.normal(size=(1, 1, heads, dk))
+
+    def unit(x):
+        return x / np.linalg.norm(x, axis=-1, keepdims=True)
+
+    # the layer's own ranges: unit keys that share a direction, beta = 2
+    # sigmoid(.), g = -A softplus(.) with A = U(0, 16) a head and softplus(.)
+    # log-uniform in [1e-3, 1e-1]
+    q, k, v = (jnp.asarray(t, jnp.bfloat16) for t in (
+        unit(rng.normal(size=(1, s, heads, dk)) + shared) * dk ** -0.5,
+        unit(rng.normal(size=(1, s, heads, dk)) + 2 * shared),
+        rng.normal(size=(1, s, heads, dv))))
+    beta = jnp.asarray(rng.uniform(0.0, 2.0, (1, s, heads)), jnp.float32)
+    g = jnp.asarray(-rng.uniform(0.0, 16.0, (heads,)) * np.exp(rng.uniform(
+        np.log(1e-3), np.log(1e-1), (1, s, heads))), jnp.float32)
+    ct = jnp.asarray(rng.normal(size=(1, s, heads, dv)), jnp.float32)
+    operands = (q, k, v, beta, g)
+    platform = jax.devices()[0].platform
+    applies = delta_rule.rule_kernel_applies(chunk, heads, dk, dv, s)
+    if platform == "cpu":
+        for name in ("delta_rule_pair", "delta_strict"):
+            setattr(gated_delta, name, functools.partial(
+                getattr(delta_rule, name), interpret=True))
+
+    def kernel(*args):
+        return gated_delta.kernel_rule(*args, chunk)[0].astype(jnp.float32)
+
+    def xla(*args):
+        return gated_delta.grouped_rule(*args, chunk)[0].astype(jnp.float32)
+
+    with jax.default_matmul_precision("highest"):
+        want = jax.block_until_ready(_with_grads(xla)(
+            ct, *(t.astype(jnp.float32) for t in operands)))
+    errs, ms = _errors_and_ms((("kernel", kernel), ("xla", xla)), ct,
+                              operands, want, 3)
+    ok = (applies or platform == "cpu") and all(
+        e <= max(TOLERANCE, 1.5 * errs["xla"][name])
+        for name, e in errs["kernel"].items())
+    print(json.dumps({"kernel": "delta_rule", "ok": bool(ok),
+                      "implementation": "pallas" if applies else
+                      "pallas (interpret)", "max_err_over_max_ref": errs,
+                      "tolerance": "1.5 x the XLA form's",
                       "ms_a_call_forward_and_backward": ms,
                       "shapes": [list(t.shape) for t in operands],
                       "chunk": chunk, "dtype": "bfloat16"}), flush=True)
@@ -327,6 +411,9 @@ def main(argv=None) -> int:
     ap.add_argument("--scan-seq", type=int, default=8192)
     ap.add_argument("--only-scan", action="store_true",
                     help="run the chunked scan's leg alone")
+    ap.add_argument("--rule-seq", type=int, default=16384)
+    ap.add_argument("--only-rule", action="store_true",
+                    help="run the chunked delta rule's leg alone")
     args = ap.parse_args(argv)
 
     import jax
@@ -335,8 +422,9 @@ def main(argv=None) -> int:
     from homebrewnlp_tpu.parallel import flash_attention as flash
     from homebrewnlp_tpu.parallel import map_mixer
 
-    if args.only_scan:
-        ok = _scan_leg(args.scan_seq)
+    if args.only_scan or args.only_rule:
+        ok = _scan_leg(args.scan_seq) if args.only_scan \
+            else _rule_leg(args.rule_seq)
         print(json.dumps({"ok": bool(ok)}), flush=True)
         return 0 if ok else 1
 
@@ -374,6 +462,8 @@ def main(argv=None) -> int:
     ok &= _band_leg((2, args.band_seq, args.band_heads, 128))
 
     ok &= _scan_leg(args.scan_seq)
+
+    ok &= _rule_leg(args.rule_seq)
 
     print(json.dumps({"ok": bool(ok)}), flush=True)
     return 0 if ok else 1

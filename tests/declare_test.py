@@ -52,11 +52,14 @@ _CELLS = {
          "hbnlp_ssd_scan_kernel_layers": 9}),
     "train_olmo_hybrid_7b_long": (
         _kinds(attention=(1, 127795200), recurrent=(3, 566231040)),
-        "; ssd chunk states 94371840 bytes a device; conv kernel 3 layers; "
-        "solve kernel 3 layers",
-        {"hbnlp_ssd_state_bytes": 94371840,
+        # PR 50: the rule is the Pallas pair, which keeps the entering
+        # states of all 30 heads (until then one group's ten: 94371840)
+        "; ssd chunk states 283115520 bytes a device; conv kernel 3 layers; "
+        "solve kernel 3 layers; rule kernel 3 layers",
+        {"hbnlp_ssd_state_bytes": 283115520,
          "hbnlp_mamba_conv_kernel_layers": 3,
-         "hbnlp_delta_solve_kernel_layers": 3}),
+         "hbnlp_delta_solve_kernel_layers": 3,
+         "hbnlp_delta_rule_kernel_layers": 3}),
     "train_laguna_s_2_1_ep32_s8k": (
         _kinds(), "; moe held rows bound 131072; flash band 3 layers",
         {"hbnlp_moe_held_rows_bound": 131072, "hbnlp_flash_band_layers": 3}),
@@ -80,8 +83,8 @@ _CELLS = {
 #: the facts that read 0 where no layer has the mechanism; the others have no
 #: series there
 _ALWAYS = ("hbnlp_ssd_state_bytes", "hbnlp_mamba_conv_kernel_layers",
-           "hbnlp_delta_solve_kernel_layers", "hbnlp_ssd_scan_kernel_layers",
-           "hbnlp_flash_band_layers")
+           "hbnlp_delta_solve_kernel_layers", "hbnlp_delta_rule_kernel_layers",
+           "hbnlp_ssd_scan_kernel_layers", "hbnlp_flash_band_layers")
 _SPARSE = ("hbnlp_moe_held_rows_bound", "hbnlp_router_carry_bytes")
 
 
@@ -137,8 +140,12 @@ def _config_files():
 #: layers`` to the lines of the two granite files: before it
 #: 0120375caf4cdf6e013cbb72aedfbae4ab651566; PR 49 added the two Ouro files,
 #: whose lines count executions: without them the digest is PR 48's
-#: 1ae9e7a6b258ab7ceafda5cfe9cd9ec99860c294, every other line as it was)
-_FILE_DIGEST = "cab4c9c0a1c8793f2e07b4e41810430d7e27b83b"
+#: 1ae9e7a6b258ab7ceafda5cfe9cd9ec99860c294, every other line as it was; PR 50
+#: added the series ``hbnlp_delta_rule_kernel_layers`` to every file and ``;
+#: rule kernel N layers`` to the lines of the two Olmo-Hybrid files, whose
+#: chunk states on a TPU are all 30 heads' where they were one group's: before
+#: it cab4c9c0a1c8793f2e07b4e41810430d7e27b83b)
+_FILE_DIGEST = "5221896c3d024205a9d2196fd56b63c8840405ae"
 
 
 def every_configuration_file_starts_as_on_the_parent_test(monkeypatch):
@@ -271,8 +278,8 @@ def facts_are_declared_once_in_line_order_test():
     facts = declare.facts()
     assert [fact.metric for fact in facts] == [
         "hbnlp_ssd_state_bytes", "hbnlp_mamba_conv_kernel_layers",
-        "hbnlp_delta_solve_kernel_layers", "hbnlp_ssd_scan_kernel_layers",
-        "hbnlp_moe_held_rows_bound",
+        "hbnlp_delta_solve_kernel_layers", "hbnlp_delta_rule_kernel_layers",
+        "hbnlp_ssd_scan_kernel_layers", "hbnlp_moe_held_rows_bound",
         "hbnlp_router_carry_bytes", "hbnlp_flash_band_layers"]
     assert [fact.metric for fact in facts if fact.zero] == list(_ALWAYS)
     assert [fact.metric for fact in facts if not fact.zero] == list(_SPARSE)

@@ -452,18 +452,27 @@ def delta_layers_kernels_keep_their_scopes_test(v5e, monkeypatch):
     tokens (half the cell's), compiled for a v5e as a TPU process traces it:
     the bias-free conv over 11,520 channels is the same Pallas pair in its
     three forms, every one folds into ``body/gated_delta/conv``; the rule's
-    triangular solve (PR 37) is the pair of ``parallel/delta_solve.py`` —
-    the step's forward, the group's own re-materialisation and the backward,
-    on the 1,920 systems of a group of 15 heads, operands ``[systems * 64,
-    64]`` (a bitcast of XLA's ``[.., 64, 64]``: no transposing copy of an
-    operand) — Mosaic accepts both, their ops carry
-    ``gated_delta_0/delta_rule/../solve/`` and fold into
+    triangular solve (PR 37) is the pair of ``parallel/delta_solve.py`` and
+    what is around it (PR 50) the two pairs of ``parallel/delta_rule.py``
+    (``delta_strict_*`` makes the solve's input, ``delta_rule_*`` runs the
+    rule): ONE ``delta_rule_bwd``, ``delta_solve_bwd`` and
+    ``delta_strict_bwd`` a layer and as many of each ``_fwd`` as the memory
+    plan gives — two (the step's forward and the block's replay) where the
+    ``recurrent`` kind saves the rule's output alone, so that the replay
+    makes ``T`` and the entering states again; one if they ride with it —
+    the solve on
+    all 3,840 systems of the layer at once, operands ``[systems * 64, 64]``
+    (a bitcast of XLA's ``[.., 64, 64]``), the rule on the sequence-minor
+    layout the conv's kernels write: no transposing copy of a large operand
+    beside any.  Mosaic accepts all six, their ops carry
+    ``gated_delta_0/delta_rule/`` (the solve's ``../solve/``) and fold into
     ``body/gated_delta/delta_rule``, which ``delta_rule_time_share`` and
     ``delta_rule_roofline`` read, and none bears a name another metric's
     reader takes."""
     import re
     from benchmark.lib.cell import load_cell
-    from homebrewnlp_tpu.model import recurrent
+    from homebrewnlp_tpu.model import gated_delta as delta_mod
+    from homebrewnlp_tpu.model import recurrent, remat
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cell = load_cell("train_olmo_hybrid_7b_long").model_config()
     assert cell["block_config"][0]["layer"][0] == "gated_delta"
@@ -472,6 +481,12 @@ def delta_layers_kernels_keep_their_scopes_test(v5e, monkeypatch):
                              "model_path": "/tmp/olmo"})
     assert recurrent.conv_kernel_layers(params) == 1
     assert recurrent.solve_kernel_layers(params) == 1
+    assert recurrent.rule_kernel_layers(params) == 1
+    # what the plan saves of the rule: its output alone -> the replay runs
+    # both forward kernels again
+    assert remat.stash_plan(params)["recurrent"][0] == 1
+    saved = delta_mod.gated_delta.declares.offer(params, set()).names
+    forwards = 2 if saved == ("gated_delta_out",) else 1
     model = Model(params)
     batch = {k: np.zeros((1, 8192, 1), np.int32)
              for k in ("token_x", "token_y")}
@@ -484,28 +499,52 @@ def delta_layers_kernels_keep_their_scopes_test(v5e, monkeypatch):
     calls = re.findall(r'%([\w.-]+) = ([^\n]*?)custom_call_target='
                        r'"tpu_custom_call"[^\n]*?op_name="([^"]+)"', hlo)
     assert sorted(re.sub(r"\.\d+$", "", name) for name, _, _ in calls) \
-        == ["delta_solve_bwd", "delta_solve_fwd", "delta_solve_fwd",
-            "mamba_conv_bwd", "mamba_conv_fwd", "mamba_conv_fwd"]
-    solves = []
+        == sorted(["delta_rule_bwd", "delta_solve_bwd", "delta_strict_bwd",
+                   "mamba_conv_bwd"]
+                  + ["delta_rule_fwd", "delta_solve_fwd", "delta_strict_fwd"]
+                  * forwards + ["mamba_conv_fwd"] * 2)
+
+    def operand_bytes(name):
+        shape = re.search(rf"%{re.escape(name)} = (\w+)\[([\d,]*)\]", hlo)
+        return np.dtype(shape.group(1).replace("bf16", "float16")
+                        .replace("f32", "float32")).itemsize * int(np.prod(
+            [int(d) for d in shape.group(2).split(",") if d]))
+
+    forms = {"delta_solve": [], "delta_strict": [], "delta_rule": []}
     for name, line, op_name in calls:
         assert not re.match(r"flash_|map_mixer_", name)
         if name.startswith("mamba_conv"):
             assert scope_key(op_name) == "body/gated_delta/conv", op_name
             continue
         assert scope_key(op_name) == "body/gated_delta/delta_rule", op_name
-        assert re.search(r"gated_delta_0/delta_rule/.*/solve/", op_name), \
-            op_name
-        # 128 chunks x 15 heads x 64 rows, and no operand laid out again
-        assert line.startswith("f32[122880,64]{1,0"), line
-        assert not re.search(r"custom-call\([^)]*%(copy|transpose)", line), \
-            line
-        solves.append(op_name)
-    # the step's forward; the group's replay and the backward, both inside
+        assert "gated_delta_0/delta_rule/" in op_name, op_name
+        # nothing laid out again but the float32 [1, 30, 8192] rows of gamma
+        # (``copy-done`` is XLA's move between memory spaces, one layout)
+        for copied in re.findall(r"%((?:copy|transpose)(?:\.\d+)?)(?![\w.-])",
+                                 line.split("custom-call(")[1]):
+            assert operand_bytes(copied) <= 30 * 8192 * 4, (name, copied)
+        if name.startswith(("delta_solve", "delta_strict")):
+            assert re.search(r"gated_delta_0/delta_rule/.*solve/", op_name), \
+                op_name
+        if name.startswith("delta_solve"):
+            # 128 chunks x 30 heads x 64 rows
+            assert line.startswith("f32[245760,64]{1,0"), line
+        elif name.startswith("delta_strict_fwd"):
+            assert line.startswith("f32[1,128,30,64,64]{4,3,2,1,0"), line
+        elif name.startswith("delta_rule_fwd"):
+            # o^T and the entering states of every chunk and head
+            assert line.startswith("(bf16[1,5760,8192]{2,1,0") \
+                and "bf16[1,128,30,192,96]{4,3,2,1,0" in line, line
+        forms[name[:name.index("_", 6)]].append((
+            name.split(".")[0].endswith("bwd"),
+            "rematted_computation" in op_name,
+            "/transpose(jvp(" in op_name))
+    # the step's forward; the block's replay and the backward, both inside
     # the transposed program
-    assert sorted(("delta_solve_bwd" in op_name,
-                   "rematted_computation" in op_name,
-                   "/transpose(jvp(" in op_name) for op_name in solves) \
-        == [(False, False, False), (False, True, True), (True, False, True)]
+    for kernel, seen in forms.items():
+        assert sorted(seen) == sorted(
+            [(False, False, False), (True, False, True)]
+            + [(False, True, True)] * (forwards - 1)), (kernel, seen)
 
 
 def held_row_buffers_are_allocated_where_they_are_filled_test(v5e,

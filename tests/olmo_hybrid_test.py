@@ -454,7 +454,7 @@ def traced_ops_carry_the_rules_steps_test():
         assert any(f"gated_delta_0/{part}/" in n for n in names), part
 
 
-def remat_rules_count_the_new_layer_test():
+def remat_rules_count_the_new_layer_test(monkeypatch):
     """``checkpoint`` with no ``moe`` layer: the experts kind rides nothing
     (and under ``"recompute"`` nothing does); the chunk states' gauge counts
     one layer's (the largest of the declared), every layer's under ``none``;
@@ -480,7 +480,7 @@ def remat_rules_count_the_new_layer_test():
     assert line.endswith("experts 0 layers, 0 bytes a device; recurrent 0 "
                          "layers, 0 bytes a device; ssd chunk states 12288 "
                          "bytes a device; conv kernel 0 layers; solve kernel "
-                         "0 layers")
+                         "0 layers; rule kernel 0 layers")
     snap = telemetry.registry().snapshot()
     assert snap["hbnlp_ssd_state_bytes"]["series"][()] == 12288
     assert snap["hbnlp_delta_solve_kernel_layers"]["series"][()] == 0
@@ -516,6 +516,13 @@ def remat_rules_count_the_new_layer_test():
     assert delta_mod.gated_delta.declares.recurrent.solve(cell) == (64, 2560)
     assert recurrent.solve_kernel_layers(cell, "tpu") == 3 * cell.depth == 3
     assert recurrent.solve_kernel_layers(cell) == 0
+    # PR 50: where the rule is the Pallas pair every head's systems are one
+    # call's (256 x 30) and every head's entering states are alive at once
+    assert delta_mod.gated_delta.declares.recurrent.solve(cell, "tpu") \
+        == (64, 7680)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert recurrent.ssd_state_bytes(cell) == 256 * 30 * 192 * 96 * 2 \
+        == 283_115_520
 
 
 def step_with_the_conv_kernel_test(monkeypatch):
