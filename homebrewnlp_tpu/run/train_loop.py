@@ -777,7 +777,8 @@ def train(params: ModelParameter, train_steps: typing.Optional[int] = None,
             ran_eval = (eval_batches is not None and
                         step_now % params.eval_interval < params.macro_batching)
             if ran_eval:
-                with telemetry.span("train/eval"):
+                with telemetry.span("train/eval",
+                                    listener=trainer.step_clock):
                     vals = [jax.device_get(trainer.eval_loss(state, eb))
                             for eb in eval_batches]
                 metrics = dict(metrics, **{
@@ -786,7 +787,8 @@ def train(params: ModelParameter, train_steps: typing.Optional[int] = None,
             # an eval step always reaches the metric log, so every recorded
             # val/loss point lands in metrics.jsonl/TB even off-cadence
             if ran_eval or step_now % log_every < params.macro_batching:
-                with telemetry.span("train/metric_log"):
+                with telemetry.span("train/metric_log",
+                                    listener=trainer.step_clock):
                     # the float conversions wait for the step: the loop's
                     # one device sync, every log_every steps
                     last_metrics = {**last_metrics, **{
@@ -816,7 +818,8 @@ def train(params: ModelParameter, train_steps: typing.Optional[int] = None,
             # saves are chief-trivially
             if params.use_checkpointing and \
                     step_now % params.steps_per_checkpoint < params.macro_batching:
-                with telemetry.span("train/checkpoint_save"):
+                with telemetry.span("train/checkpoint_save",
+                                    listener=trainer.step_clock):
                     save_state(step_now)
             if should_stop(it_count):
                 # graceful preemption: the in-flight step finished; fall

@@ -42,12 +42,25 @@ PHASES = {
 _WRAPPER = re.compile(r"^[a-z_]+\((.*)\)$")
 
 _installed = False
+#: ``hbnlp_compiles_total{phase="backend"}`` summed over ``fun``, as a plain
+#: number: what the step clock reads at every step, where a registry call
+#: has no place
+_backend_compiles = 0
+
+
+def backend_compiles() -> int:
+    """Backend compiles (or loads of a cached executable) this process has
+    made since the listener was installed."""
+    return _backend_compiles
 
 
 def _on_duration(event: str, seconds: float, **kw) -> None:
     phase = PHASES.get(event)
     if phase is None:
         return
+    if phase == "backend":
+        global _backend_compiles
+        _backend_compiles += 1
     fun = str(kw.get("fun_name") or "")
     wrapped = _WRAPPER.match(fun)
     if wrapped:

@@ -77,10 +77,6 @@ def _stat_of(metric: str) -> str:
     return metric.partition("/")[0]
 
 
-#: ``Trainer._loaded_probe`` before the first step is dispatched
-_FIRST_STEP = object()
-
-
 class TrainState(typing.NamedTuple):
     variables: Params
     opt_state: typing.Dict[str, typing.Dict[str, jax.Array]]
@@ -105,14 +101,18 @@ class Trainer:
         # the layers' own statistics of steps already dispatched, waiting
         # for the device to finish them (_publish_layer_stats)
         self._pending_layer_stats: collections.deque = collections.deque()
+        # one entry a call of ``step``, always on (telemetry/step_clock.py):
+        # the listener of this trainer's span sites and, as the trainer
+        # built last, of the prefetcher's ``data/next`` and the collector
+        self.step_clock = telemetry.step_clock.install(
+            telemetry.StepClock(record=self._record_steps))
         # the chip's memory (telemetry/memory.py): the start-up line of
         # init_state's marks, and the one ``step`` leaves here at its mark
         # (None until then, and on a backend that reports nothing);
-        # ``train()`` prints both.  ``_loaded_probe``: the first step's
-        # loss once that step is dispatched, None once the mark is made
+        # ``train()`` prints both.  ``_step_loaded``: that mark is made
         self.state_memory_line: str = memory.NOT_REPORTED
         self.step_memory_line: typing.Optional[str] = None
-        self._loaded_probe: typing.Any = _FIRST_STEP
+        self._step_loaded = False
 
     # -- state -------------------------------------------------------------
     def init_state(self, batch: typing.Dict[str, jax.Array],
@@ -387,7 +387,8 @@ class Trainer:
         arrays and skips re-sharding — the seam the train loop's
         double-buffered input overlap uses (run/train_loop.py
         ``_AsyncFeeder``; ``async_input_transfer``)."""
-        with telemetry.span("data/place", record=self._record_steps):
+        with telemetry.span("data/place", record=self._record_steps,
+                            listener=self.step_clock):
             if self.mesh is not None:
                 return shardlib.shard_batch(self.params, batch, self.mesh)
             return {k: (jax.device_put(v) if v is not None else v)
@@ -409,8 +410,12 @@ class Trainer:
         # the host's whole part of a step — key build, placement check, the
         # jitted call's enqueue (and, the first time, its trace + compile) —
         # under one span, here and not around the call, so every caller of
-        # step() has it
-        with telemetry.span("train/step_dispatch", record=self._record_steps):
+        # step() has it.  Its annotation carries the step's number in the
+        # step clock's ring, which joins a captured window's executions of
+        # the step to their entries
+        clock = self.step_clock
+        with telemetry.span("train/step_dispatch", record=self._record_steps,
+                            listener=clock, step=clock.steps):
             if self._step_fn is None:
                 self._step_fn = self._build_step(state=state)
                 self._rng_counter = 0
@@ -425,25 +430,23 @@ class Trainer:
             if self.mesh is not None and not self._batch_placed(batch):
                 batch = shardlib.shard_batch(self.params, batch, self.mesh)
             state, metrics = self._step_fn(state, batch, rng)
-            if self._loaded_probe is not None:
-                self._mark_step_loaded(metrics["loss"])
+            clock.dispatched(metrics["loss"])
+            if not self._step_loaded and clock.completed:
+                self._mark_step_loaded()
             if any(_stat_of(k) in _LAYER_STATS for k in metrics):
                 self._publish_layer_stats(metrics)
             return state, metrics
 
-    def _mark_step_loaded(self, loss: jax.Array) -> None:
+    def _mark_step_loaded(self) -> None:
         """Point ``step_loaded`` of telemetry/memory.py, at the first call
-        that finds the loss of the FIRST step (the one that traced,
-        compiled and loaded the program) ready: the program has run once,
-        so the runtime's reservation is the step's scratch and ``in_use``
-        what the loop keeps.  Like ``_publish_layer_stats`` it never waits;
-        once made, a step pays one ``is not None``."""
-        if self._loaded_probe is _FIRST_STEP:
-            self._loaded_probe = loss
-        elif self._loaded_probe.is_ready():
-            self._loaded_probe = None
-            self.step_memory_line = memory.loaded_line(
-                memory.mark("step_loaded"))
+        whose enter the step clock found the FIRST step (the one that
+        traced, compiled and loaded the program) done: the program has run
+        once, so the runtime's reservation is the step's scratch and
+        ``in_use`` what the loop keeps.  The clock's poll never waits, and
+        this reads its ring and polls nothing itself; once made, a step
+        pays one ``not``."""
+        self._step_loaded = True
+        self.step_memory_line = memory.loaded_line(memory.mark("step_loaded"))
 
     def _publish_layer_stats(self, metrics) -> None:
         """The statistics the layers declare (``_LAYER_STATS``; under

@@ -265,22 +265,35 @@ class _Loss:
 
 def step_loaded_waits_for_the_building_steps_loss_test(tmp_path, fresh,
                                                        monkeypatch):
-    """The mark is made at the first call that finds the FIRST step's loss
-    ready, exactly once, without a wait; from then on a step does not ask."""
+    """The mark is made at the first call whose enter the step clock found
+    the FIRST step's loss ready, exactly once, without a wait and without a
+    poll of its own: the clock's ring is what it reads (the clock goes on
+    asking the steps after it, once each enter)."""
     registry, recorder = fresh
     _report_memory(monkeypatch)
+    trainer, batch = _toy_trainer(tmp_path)
+    state = trainer.init_state(batch())
     monkeypatch.setattr(jax, "block_until_ready", _Loss.block_until_ready)
-    trainer, _ = _toy_trainer(tmp_path)
     first, later = _Loss(), _Loss()
-    trainer._mark_step_loaded(first)            # the step that built
-    for _ in range(3):                          # still on the device
-        trainer._mark_step_loaded(later)
-    assert first.asked == 3 and recorder.events("memory") == []
-    assert trainer.step_memory_line is None and not _hbm(registry)
+    real_step = []
+
+    def step_fn(state, batch, rng):
+        # the jitted step's place: what it returns is what the clock polls
+        real_step.append(1)
+        return state, {"loss": first if len(real_step) == 1 else later}
+
+    trainer._step_fn = step_fn
+    for _ in range(4):                          # the step that built, and
+        trainer.step(state, batch())            # three while it still runs
+    assert first.asked == 3 and recorder.events("memory")[2:] == []
+    assert trainer.step_memory_line is None and not trainer._step_loaded
+    assert not any(k[0] == "step_loaded" for k in _hbm(registry))
     first.ready = True
-    trainer._mark_step_loaded(later)
-    assert [e["point"] for e in recorder.events("memory")] == ["step_loaded"]
-    assert trainer._loaded_probe is None and later.asked == 0
+    trainer.step(state, batch())
+    assert first.asked == 4 and trainer.step_clock.completed == 1
+    assert [e["point"] for e in recorder.events("memory")][2:] == \
+        ["step_loaded"]
+    assert trainer._step_loaded and later.asked == 1
     assert ("step_loaded", "reserved") in _hbm(registry)
     line = trainer.step_memory_line
     assert line.startswith("memory: at step_loaded device ")
@@ -306,11 +319,12 @@ def trainer_marks_each_point_once_test(tmp_path, fresh, monkeypatch, reported):
     state = trainer.init_state(batch())
     points = ["params_placed", "state_ready"]
     state, metrics = trainer.step(state, batch())
-    assert trainer._loaded_probe is metrics["loss"]
+    assert not trainer._step_loaded
+    assert trainer.step_clock._pending[0][1] is metrics["loss"]
     jax.block_until_ready(metrics["loss"])      # the test's wait, not the mark's
     for _ in range(3):
         state, _ = trainer.step(state, batch())
-    assert trainer._loaded_probe is None
+    assert trainer._step_loaded
     spans = registry.snapshot()[telemetry.SPAN_METRIC]["series"]
     for point in points + ["step_loaded"]:
         assert sum(spans[(f"memory/{point}",)]["counts"]) == 1, point

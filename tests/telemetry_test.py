@@ -490,7 +490,10 @@ _RARE_SERIES = {telemetry.SPAN_METRIC, "hbnlp_init_values_seconds_total",
                 *(fact.metric for fact in declare.facts() if fact.zero),
                 # set at the marks of telemetry/memory.py, where the backend
                 # reports memory (PR 34; XLA:CPU: no series)
-                "hbnlp_hbm_bytes", "hbnlp_train_state_bytes"}
+                "hbnlp_hbm_bytes", "hbnlp_train_state_bytes",
+                # a stalled step, when the step clock names one (PR 51):
+                # rare by its rule, so unconditional; nothing per step
+                "hbnlp_step_stalls_total", "hbnlp_step_stall_seconds_total"}
 _RARE_SPANS = {"setup/data_first_batch", "setup/model_init",
                "setup/place_params", "setup/opt_init", "setup/init_wait",
                "train/metric_log", "train/checkpoint_save", "train/eval",
@@ -748,7 +751,10 @@ def telemetry_off_step_makes_no_registry_call_test(tmp_path, monkeypatch):
     three sites each make one (the control that the counter can see them).
     Steady state begins once ``step_loaded`` is marked (telemetry/memory.py:
     the devices report memory here, so that mark does set its gauges): the
-    step after it pays one ``is not None``."""
+    step after it pays one ``not``.  The step clock runs either way: its
+    ring holds every one of these steps, dispatch, placement and queue wait
+    timed, at no registry call (with telemetry on its intervals reach
+    ``hbnlp_step_seconds`` through a child bound at the first)."""
     import jax
     from memory_marks_test import _report_memory
     from homebrewnlp_tpu.data.inputs import Prefetcher
@@ -768,7 +774,7 @@ def telemetry_off_step_makes_no_registry_call_test(tmp_path, monkeypatch):
                     state, metrics = trainer.step(
                         state, trainer.place_batch(next(feed)))
                     jax.block_until_ready(metrics["loss"])
-                assert trainer._loaded_probe is None
+                assert trainer._step_loaded
                 before = counting.lookups
                 snap = counting.snapshot()
                 assert ("step_loaded", "in_use") in \
@@ -779,6 +785,17 @@ def telemetry_off_step_makes_no_registry_call_test(tmp_path, monkeypatch):
                 assert counting.lookups - before == expected, enabled
                 if not enabled:
                     assert counting.snapshot() == snap
+                ring = list(trainer.step_clock.ring)
+                assert [e.index for e in ring] == list(range(5))
+                for e in ring[2:]:
+                    assert e.exit_ns > e.enter_ns and e.cpu_ns > 0
+                for e in ring[1:4]:
+                    # the turn a step opens holds the fetch and placement
+                    # of the batch for the next
+                    assert e.data_place_ns > 0 and e.data_next_ns > 0
+                assert trainer.step_clock.completed >= 2
+                steps = counting.snapshot().get("hbnlp_step_seconds")
+                assert (steps is not None) == enabled
             finally:
                 feed.close()
         finally:
