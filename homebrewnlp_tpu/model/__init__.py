@@ -129,13 +129,13 @@ def _input(params: ModelParameter, vid, cat_msk_src, txt_src, vid_msk_src,
 
 
 def _body(params: ModelParameter, src: NamedTensor,
-          plan) -> typing.Tuple[NamedTensor, tuple]:
+          plan, loop_pass: int = 0) -> typing.Tuple[NamedTensor, tuple]:
     base_args = BlockArgs(params, src, [''])
     if params.use_initial_position_embedding:
         for dim in shape_sub(src.dims, params.feature_dims)[1:]:
             src = src + embed(base_args(list(params.position_embedding)),
                               [dim] + list(params.feature_dims))
-    return run_body_blocks(params, src, plan)
+    return run_body_blocks(params, src, plan, loop_pass)
 
 
 def _output(params: ModelParameter, out: NamedTensor, spatial_ctx: Dim,
@@ -299,7 +299,7 @@ def _build_looped(params: ModelParameter, src: NamedTensor, txt_tgt,
         params.attention_idx = 0
         with jax.named_scope("loop"), jax.named_scope(f"pass{step}"):
             with scope.name_scope("body", again):
-                out, made = _body(params, src, plan)
+                out, made = _body(params, src, plan, step)
             with scope.name_scope("output", again):
                 _, token_out = _output(params, out, spatial_ctx, storage)
         if not step:

@@ -14,6 +14,7 @@ import typing
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..config import BlockArgs
 from ..core import scope
@@ -315,6 +316,13 @@ def activated_linear_out(args: BlockArgs) -> NamedTensor:
     return activated_linear(args, "out:")
 
 
+#: the names layer ``mlp`` gives its two matmul outputs ``[batch, sequence,
+#: intermediate]`` (``checkpoint_name``; free where no policy names them).
+#: With both saved a ``checkpoint`` block's replay runs the activation and the
+#: product alone, no matmul; the down matmul's input is made again from them
+MLP_SAVED_NAMES = ("mlp_gate", "mlp_up")
+
+
 def mlp(args: BlockArgs) -> NamedTensor:
     """Layer ``mlp``: the dense gated MLP of today's transformers,
     ``down(act(gate(x)) * up(x))``, all features -> ``intermediate`` -> all
@@ -332,10 +340,24 @@ def mlp(args: BlockArgs) -> NamedTensor:
     for d, a in zip(feats, anon):
         x = rename_dim(x, d.name, a.name)
     hidden_dims = shape_sub(x.dims, anon) + inter
-    gate = einsum([x, normal_var(args, anon + inter)], hidden_dims)
-    up = einsum([x, normal_var(args, anon + inter)], hidden_dims)
+
+    def hidden(name: str) -> NamedTensor:
+        out = einsum([x, normal_var(args, anon + inter)], hidden_dims)
+        return nt(checkpoint_name(out.data, name), hidden_dims)
+
+    gate, up = (hidden(name) for name in MLP_SAVED_NAMES)
     return einsum([act(args(gate)) * up, normal_var(args, inter + feats)],
                   list(args.tensor.dims))
+
+
+def _mlp_offer(params, extras) -> Offer:
+    out_dims = [params.batch_dim, params.sequence_dim, *params.intermediate]
+    return Offer("dense", MLP_SAVED_NAMES,
+                 2 * math.prod(d.size for d in out_dims)
+                 * jnp.dtype(params.calculation_dtype).itemsize, count=2)
+
+
+mlp.declares = Layer(offer=_mlp_offer)
 
 
 def feed_forward(args: BlockArgs) -> NamedTensor:

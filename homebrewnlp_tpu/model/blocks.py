@@ -423,7 +423,8 @@ def _mom_scan_bwd(fns, alpha, unroll, stash, res, cot):
 momentum_scan.defvjp(_mom_scan_fwd, _mom_scan_bwd)
 
 
-def _checkpoint_policy(params: ModelParameter, mesh=None):
+def _checkpoint_policy(params: ModelParameter, mesh=None,
+                       names: typing.Optional[typing.Tuple[str, ...]] = None):
     """The ``jax.checkpoint`` policy for the 'checkpoint' strategy: the
     named one (``gradient_checkpointing_policy``; the default
     "nothing_saveable" is jax.checkpoint's own default, so reference
@@ -431,15 +432,47 @@ def _checkpoint_policy(params: ModelParameter, mesh=None):
     (``stash_names``: ``experts`` — layer ``moe``'s named outputs —
     ``recurrent`` — the output a recurrent mixer offers — ``attention`` —
     the (out, lse) every flash layer names under the blocks' "name" channel,
-    ``_name_chan``), also those names."""
-    named = getattr(jax.checkpoint_policies,
-                    params.gradient_checkpointing_policy)
-    from .remat import stash_names
-    names = stash_names(params, mesh)
+    ``_name_chan``), also those names: what every region of the step saves.
+    The ``dense`` kind — layer ``mlp``'s gate and up outputs — is admitted
+    an execution at a time from the step's end, so what it adds is a
+    region's own: ``names``, what one region saves (``_region_policies``)."""
+    if names is None:
+        from .remat import stash_names
+        names = stash_names(params, mesh)
+    return _named_policy(params.gradient_checkpointing_policy, names)
+
+
+def _region_policies(params: ModelParameter, mesh=None) -> list:
+    """The ``jax.checkpoint`` policy of every region of the step, by its
+    place (``_region``): ``_checkpoint_policy``, and in the regions that hold
+    an admitted execution of the ``dense`` kind also its names (model/remat.py
+    ``region_names``)."""
+    from .remat import region_names
+    return [_checkpoint_policy(params, mesh, names)
+            for names in region_names(params, mesh)]
+
+
+@functools.lru_cache(maxsize=None)
+def _named_policy(named: str, names: typing.Tuple[str, ...]):
+    """``jax.checkpoint_policies.<named>`` and, beside it, ``names`` saved:
+    ONE object for the regions that save the same, so that a step's jaxpr
+    reads the same wherever its regions do."""
+    named = getattr(jax.checkpoint_policies, named)
     if not names:
         return named
     return jax.checkpoint_policies.save_from_both_policies(
         named, jax.checkpoint_policies.save_only_these_names(*names))
+
+
+def _region(params: ModelParameter, loop_pass: int, depth_idx: int,
+            cfg_idx: int) -> int:
+    """A body block's ``jax.checkpoint`` region's place among the step's, in
+    execution order: a looped model's passes (model/loop.py) outermost.  A
+    scanned body traces ONE block a ``cfg_idx`` for all its iterations
+    (``depth_idx`` 0): model/remat.py admits a scanned body's executions all
+    together or not at all, so the one policy is every iteration's."""
+    return (loop_pass * params.depth + depth_idx) \
+        * len(params.block_config) + cfg_idx
 
 
 def _merge_stats(parts) -> dict:
@@ -471,19 +504,19 @@ def _block_with_stats(f, collect: bool, chan=None):
 
 
 def _plain_scan(fns, stacked, shared, x, use_checkpoint: bool,
-                unroll: int = 1, ckpt_policy=None, collect: bool = False,
+                unroll: int = 1, ckpt_policies=None, collect: bool = False,
                 chan=None):
     """Scanned 'checkpoint' / 'none' strategies: O(depth) carries saved by
-    scan AD; with use_checkpoint each block recomputes its interior.
-    Returns the output and the layers' statistics (empty unless
-    ``collect``)."""
+    scan AD; with use_checkpoint each block recomputes its interior under
+    its own of ``ckpt_policies`` (one a traced block).  Returns the output
+    and the layers' statistics (empty unless ``collect``)."""
     def step(carry, sl):
         x, it = carry
         parts = []
-        for f, stk, shr in zip(fns, sl, shared):
+        for c, (f, stk, shr) in enumerate(zip(fns, sl, shared)):
             call = _block_with_stats(f, collect, chan)
             if use_checkpoint:
-                call = jax.checkpoint(call, policy=ckpt_policy)
+                call = jax.checkpoint(call, policy=ckpt_policies[c])
             x, stats = call({**stk, **shr}, x, it)
             parts.append(stats)
         return (x, it + 1), _merge_stats(parts)
@@ -611,7 +644,8 @@ def resolve_stash(params: ModelParameter, mesh=None) -> bool:
 
 
 def _try_scan(params: ModelParameter, ctx, plan, src: NamedTensor,
-              strategy: str, attn_base: int) -> typing.Optional[NamedTensor]:
+              strategy: str, attn_base: int, loop_pass: int = 0
+              ) -> typing.Optional[NamedTensor]:
     pro = _scan_prologue(params, ctx, plan, src, attn_base)
     if pro is None:
         return None
@@ -630,9 +664,11 @@ def _try_scan(params: ModelParameter, ctx, plan, src: NamedTensor,
         x, v = momentum_scan(fns, params.momentumnet_alpha, params.scan_unroll,
                              stacked, shared, src, src, stash)
         return x + v
+    policies = _region_policies(params, ctx.mesh)
     out, stats = _plain_scan(fns, stacked, shared, src,
                              strategy == "checkpoint", params.scan_unroll,
-                             _checkpoint_policy(params, ctx.mesh),
+                             [policies[_region(params, loop_pass, 0, c)]
+                              for c in range(len(fns))],
                              collect=ctx.layer_stats is not None,
                              chan=_name_chan(params, ctx.mesh))
     if stats:
@@ -920,13 +956,16 @@ def _try_prefill_scan(params: ModelParameter, ctx, plan, src: NamedTensor,
 # ---- body assembly -------------------------------------------------------
 
 def run_body_blocks(params: ModelParameter, src: NamedTensor,
-                    plan: typing.Optional[typing.Tuple[BlockSpec, ...]]
+                    plan: typing.Optional[typing.Tuple[BlockSpec, ...]],
+                    loop_pass: int = 0
                     ) -> typing.Tuple[NamedTensor, typing.Tuple[BlockSpec, ...]]:
     """Run depth × block_config with the configured memory strategy.
 
     In init mode (plan None) blocks run plainly in the outer context and the
     per-block touched-parameter plan is recorded.  In apply mode the plan
     feeds explicit parameter subsets into the custom-vjp sequences.
+    ``loop_pass``: which pass of a looped model this is (model/loop.py): the
+    ``checkpoint`` strategy's regions count on over the passes (``_region``).
     """
     ctx = scope.current()
     strategy = params.memory_reduction_strategy
@@ -1009,7 +1048,8 @@ def run_body_blocks(params: ModelParameter, src: NamedTensor,
     if params.scan_layers:
         # attention_idx was already advanced to its post-body value by the
         # builder above; the scanned blocks replay from the captured base
-        scanned = _try_scan(params, ctx, plan, src, strategy, attn_base)
+        scanned = _try_scan(params, ctx, plan, src, strategy, attn_base,
+                            loop_pass)
         if scanned is not None:
             return scanned, plan
 
@@ -1039,12 +1079,13 @@ def run_body_blocks(params: ModelParameter, src: NamedTensor,
     # every block's region as an operand, empty — no operand at all — in a
     # model whose layers carry nothing
     out, parts, side = src, [], {}
-    checkpoint_policy = _checkpoint_policy(params, ctx.mesh)
     chan = _name_chan(params, ctx.mesh)
-    for f, s in zip(fns, subsets):
+    policies = _region_policies(params, ctx.mesh)
+    for (i, c, _), f, s in zip(blocks, fns, subsets):
         call = _block_with_stats(f, ctx.layer_stats is not None, chan)
         if strategy == "checkpoint":
-            call = jax.checkpoint(call, policy=checkpoint_policy)
+            call = jax.checkpoint(
+                call, policy=policies[_region(params, loop_pass, i, c)])
         (out, side), stats = call(s, out, None, side)
         parts.append(stats)
     if any(parts):

@@ -113,6 +113,14 @@ def offers(params: ModelParameter, kind: str) -> typing.List[Offer]:
     return list(_offers(params, kind, params.block_config))
 
 
+def block_offers(params: ModelParameter, kind: str
+                 ) -> typing.List[typing.List[Offer]]:
+    """What each block of one depth-unit offers of ``kind``: a list a block,
+    in execution order."""
+    return [list(_offers(params, kind, (block,)))
+            for block in params.block_config]
+
+
 def step_offers(params: ModelParameter, kind: str):
     """``(offer, times it runs)`` of every layer of the step that offers
     ``kind``: the leading and trailing blocks once, the body ``depth``

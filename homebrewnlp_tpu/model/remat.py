@@ -34,7 +34,10 @@ the revnet / momentum residuals through the stash channel
 (``core/stash.py``, ``model/blocks.py``).  ``experts``: a routed layer's
 grouped-matmul outputs, routing triple and choice.  ``recurrent``: the
 output a recurrent mixer offers because it re-materialises its own interior.
-The ``checkpoint`` strategy has no residuals of its own: there these two,
+``dense``: layer ``mlp``'s two matmul outputs, gate and up ``[batch,
+sequence, intermediate]`` — the replay runs the activation and the product
+alone, no dense matmul (2 of an MLP's 11-12 matmul units a step).
+The ``checkpoint`` strategy has no residuals of its own: there these three,
 and ``attention`` again, are SAVED by each block's ``jax.checkpoint``
 through a policy over their names (``model/blocks.py _checkpoint_policy``;
 for ``attention`` the blocks get a stateless "name" channel under which a
@@ -60,7 +63,20 @@ flash call names its outputs).
    layers or none — and not at all where an earlier kind had bytes to save
    and declined for size: a step whose expert buffers alone pass the budget
    regenerates them live inside each block's backward and has no room to
-   hold more across blocks;
+   hold more across blocks; ``dense`` after all of them, so that it moves
+   none of their decisions, and the one kind admitted a PART at a time: the
+   ``mlp`` executions of the step ONE BY ONE FROM THE LAST backwards (a
+   looped model's passes outermost) while they fit what is left — of the
+   same 15% less every kind already admitted AND less the block inputs
+   ``checkpoint`` itself keeps, one ``[batch, sequence, features]`` for
+   every ``jax.checkpoint`` region of the step: the 15% bounds what the step
+   holds ACROSS its backward, and those inputs are such bytes that no other
+   kind counts.  From the end because the last block's backward comes first:
+   its outputs are held for the shortest time, and through its own backward
+   they stand where the replay would have put them anyway.  Not at all where
+   an earlier kind declined for size; all the step's executions or none under
+   ``scan_layers`` (one traced block for all iterations); every execution
+   under an explicit ``"stash"``;
 4. else ``recompute``.  The save modes stay measured OPT-INS
    (docs/PERFORMANCE.md 'Round 11': ``recompute`` 204 ms/step, ``save`` 280,
    ``save_dots`` 249 on an hbm-bound rig).
@@ -75,7 +91,7 @@ import numpy as np
 
 from ..config import ModelParameter
 from ..core import sharding as shardlib
-from .declare import offers
+from .declare import block_offers, offers
 
 #: fraction of per-chip HBM the attention stash may claim (the historical
 #: resolve_stash gate)
@@ -98,9 +114,9 @@ POLICIES = ("recompute", "stash", "save", "save_dots")
 #: what a memory strategy can keep for its backward under "stash":
 #: ``attention`` and ``bottleneck`` ride the revnet / momentum residuals (the
 #: channel's kinds, model/blocks.py ``stash_channel``), ``experts``,
-#: ``recurrent`` and, there, ``attention`` too the ``checkpoint`` strategy's
-#: ``jax.checkpoint`` (``_checkpoint_policy``)
-STASH_KINDS = ("attention", "bottleneck", "experts", "recurrent")
+#: ``recurrent``, ``dense`` and, there, ``attention`` too the ``checkpoint``
+#: strategy's ``jax.checkpoint`` (``_checkpoint_policy``)
+STASH_KINDS = ("attention", "bottleneck", "experts", "recurrent", "dense")
 
 
 def _stash_bytes(params: ModelParameter) -> int:
@@ -189,6 +205,61 @@ def _saved_attention(params: ModelParameter, mesh, min_keys: int
         * _executions(params) * max(1, params.macro_batching)
 
 
+def _block_input_bytes(params: ModelParameter, shards: int) -> int:
+    """Per-device bytes of the block inputs the ``checkpoint`` strategy
+    itself keeps across the step's backward: one ``[batch, sequence,
+    features]`` in the calculation dtype for every ``jax.checkpoint`` region
+    of the step — each block of the body, each time it runs.  (The input and
+    output blocks run outside any region, model/__init__.py: they keep no
+    block input, replay nothing and offer nothing.)"""
+    one = params.batch_dim.size * params.sequence_dim.size \
+        * int(np.prod([d.size for d in params.feature_dims])) \
+        * np.dtype(params.calculation_dtype).itemsize
+    return -(-one * len(params.block_config) * _executions(params)
+             * max(1, params.macro_batching) // shards)
+
+
+def _dense_regions(params: ModelParameter, shards: int
+                   ) -> typing.List[typing.Tuple[int, int]]:
+    """``(layer executions, per-device bytes)`` of the dense kind in every
+    ``jax.checkpoint`` region of the step, in execution order (a looped
+    model's passes outermost): what the region's block OFFERS.  A block's
+    layers share their names, so a region's executions go together."""
+    unit = [(len(offered), -(-sum(offer.nbytes for offer in offered)
+                             * max(1, params.macro_batching) // shards))
+            for offered in block_offers(params, "dense")]
+    return unit * _executions(params)
+
+
+def _admit_dense(params: ModelParameter, shards: int,
+                 budget: typing.Optional[int]) -> typing.Tuple[int, int, int]:
+    """``(layer executions, per-device bytes, first region)`` of the dense
+    kind admitted into ``budget`` bytes (None: all of it, an explicit
+    ``"stash"``; 0: none): region by region from the step's LAST backwards while the
+    next fits — the last region's backward comes first, so its outputs are
+    held for the shortest time, and through its own backward they stand where
+    the replay would have put them anyway.  Every region from ``first
+    region`` on saves the names (:func:`region_names`).  A scanned body
+    (``scan_layers``) traces ONE block for all its iterations: there the
+    step's executions are admitted all together or not at all."""
+    regions = _dense_regions(params, shards)
+    total = sum(nbytes for _, nbytes in regions)
+    if budget is None:
+        budget = total
+    if params.scan_layers and total > budget:
+        budget = 0
+    executions = admitted = 0
+    first = len(regions)
+    for region in reversed(range(len(regions))):
+        count, nbytes = regions[region]
+        if admitted + nbytes > budget:
+            break
+        if count:
+            first = region
+        executions, admitted = executions + count, admitted + nbytes
+    return executions, admitted, first
+
+
 def _save_residual_bytes(params: ModelParameter) -> int:
     """Global estimate of the native-AD linearization residuals the save
     policy keeps: f32 activation-sized intermediates per block part,
@@ -268,12 +339,25 @@ def stash_kinds(params: ModelParameter, mesh=None) -> typing.FrozenSet[str]:
     historical one and is decided FIRST: the bottleneck kind only gets what
     it leaves of the budget, so adding that kind moved no configuration's
     attention decision.  Under ``checkpoint`` the experts kind is decided
-    first, the recurrent kind from what it leaves, and the attention kind
-    LAST, from what both leave — so it moves neither — and not at all where
-    one of them had bytes to save and declined for size."""
+    first, the recurrent kind from what it leaves, the attention kind from
+    what both leave — so it moves neither — and not at all where one of them
+    had bytes to save and declined for size; the dense kind LAST, an
+    execution at a time (:func:`_decide`)."""
+    return _decide(params, mesh)[0]
+
+
+def _decide(params: ModelParameter, mesh
+            ) -> typing.Tuple[typing.FrozenSet[str],
+                              typing.Tuple[int, int, int]]:
+    """``(the kinds, the dense kind's (layer executions, per-device bytes,
+    first region))`` — :func:`stash_kinds`, and how far the one kind that is
+    admitted a part at a time got (:func:`_admit_dense`)."""
+    shards, _ = shardlib.shard_geometry(mesh)
     explicit = _explicit_policy(params)
     if explicit is not None:
-        return frozenset(STASH_KINDS if explicit == "stash" else ())
+        stash = explicit == "stash"
+        return frozenset(STASH_KINDS if stash else ()), _admit_dense(
+            params, shards, None if stash else 0)
     rep = remat_report(params, mesh)
     budget = rep["stash_budget_bytes"]
     forced = _forced_attention(params)
@@ -286,7 +370,7 @@ def stash_kinds(params: ModelParameter, mesh=None) -> typing.FrozenSet[str]:
             and 0 < rep["bottleneck_stash_bytes_per_device"] <= budget:
         kinds.add("bottleneck")
     if params.memory_reduction_strategy != "checkpoint":
-        return frozenset(kinds)
+        return frozenset(kinds), _admit_dense(params, shards, 0)
     # the whole budget: what the two kinds above name rides the revnet /
     # momentum residuals, so under "checkpoint" they hold no byte of it, and
     # the attention kind is decided again by what each layer really saves
@@ -306,7 +390,14 @@ def stash_kinds(params: ModelParameter, mesh=None) -> typing.FrozenSet[str]:
                                       _attention_min_keys(params))
     if layers and (forced or (fitted and nbytes <= budget)):
         kinds.add("attention")
-    return frozenset(kinds)
+        budget -= nbytes
+    # what is left of a bound on what the step holds ACROSS its backward:
+    # the block inputs ``checkpoint`` itself keeps are such bytes too
+    dense = _admit_dense(params, shards, budget - _block_input_bytes(
+        params, shards) if fitted else 0)
+    if dense[0]:
+        kinds.add("dense")
+    return frozenset(kinds), dense
 
 
 def _attention_sites(params: ModelParameter, mesh) -> int:
@@ -334,13 +425,14 @@ def stash_plan(params: ModelParameter, mesh=None
     ``hbnlp_remat_stash_bytes{kind}`` / ``hbnlp_remat_stash_layers{kind}``
     (docs/OBSERVABILITY.md); ``_checkpoint_policy`` saves the experts, the
     recurrent and the attention kind's names exactly where this says they
-    ride (:func:`stash_names`)."""
+    ride (:func:`stash_names`), and the dense kind's in the regions of the
+    executions it counts (:func:`region_names`)."""
     plan = {kind: (0, 0) for kind in STASH_KINDS}
     strategy = params.memory_reduction_strategy
     piped = mesh is not None and mesh.shape.get(shardlib.PIPE_AXIS, 1) > 1
     if strategy not in ("revnet", "momentum", "checkpoint") or piped:
         return plan
-    kinds = stash_kinds(params, mesh)
+    kinds, dense = _decide(params, mesh)
     rep = remat_report(params, mesh)
     if strategy == "checkpoint":
         for kind in ("experts", "recurrent"):
@@ -350,6 +442,7 @@ def stash_plan(params: ModelParameter, mesh=None
         if "attention" in kinds:
             plan["attention"] = _saved_attention(
                 params, mesh, _attention_min_keys(params))
+        plan["dense"] = dense[:2]
         return plan
     if "attention" in kinds:
         layers = _attention_sites(params, mesh) * params.depth
@@ -388,6 +481,32 @@ def stash_names(params: ModelParameter, mesh=None) -> typing.Tuple[str, ...]:
     names = [name for kind in riding for offer in offers(params, kind)
              for name in offer.names]
     return tuple(dict.fromkeys(names))     # a name once, in order
+
+
+def dense_executions(params: ModelParameter, mesh=None) -> int:
+    """How many ``mlp`` executions of the step save their gate and up outputs
+    (:func:`stash_plan`'s ``dense``): the LAST so many of the body, a looped
+    model's passes outermost."""
+    return stash_plan(params, mesh)["dense"][0]
+
+
+def region_names(params: ModelParameter, mesh=None
+                 ) -> typing.List[typing.Tuple[str, ...]]:
+    """The names the ``jax.checkpoint`` of every region of the step saves, a
+    tuple a region (the body's blocks in execution order, a looped model's
+    passes outermost; a scanned body's one traced block stands for all its
+    iterations): :func:`stash_names`, and the dense kind's where the region
+    holds an admitted execution."""
+    names = stash_names(params, mesh)
+    regions = _dense_regions(params, shardlib.shard_geometry(mesh)[0])
+    # (the plan's count is 0 where the strategy has no region to ride)
+    first = _decide(params, mesh)[1][2] \
+        if dense_executions(params, mesh) else len(regions)
+    dense = tuple(dict.fromkeys(
+        (*names, *(name for offer in offers(params, "dense")
+                   for name in offer.names))))
+    return [dense if region >= first and count else names
+            for region, (count, _) in enumerate(regions)]
 
 
 def stash_line(plan: typing.Dict[str, typing.Tuple[int, int]],

@@ -25,16 +25,18 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _kinds(attention=(0, 0), bottleneck=(0, 0), experts=(0, 0),
-           recurrent=(0, 0), unit="layers"):
+           recurrent=(0, 0), dense=(0, 0), unit="layers"):
     plan = {"attention": attention, "bottleneck": bottleneck,
-            "experts": experts, "recurrent": recurrent}
+            "experts": experts, "recurrent": recurrent, "dense": dense}
     return ("remat stash: " + "; ".join(
         f"{kind} {layers} {unit}, {nbytes} bytes a device"
         for kind, (layers, nbytes) in plan.items()), plan)
 
 
 #: cell -> (the kinds' part of the line and the plan, the rest of the line,
-#: the start-up gauges beside the stash's) on a TPU, from the parent
+#: the start-up gauges beside the stash's) on a TPU, from the parent; PR 52
+#: added the fifth kind to every line and the two gauges, ``dense``: 0 / 0
+#: but in the three cells named below
 _CELLS = {
     "train_32big_mixer_b32": (_kinds(), "", {}),
     "train_32big_mixer_dp2tp2": (
@@ -44,14 +46,17 @@ _CELLS = {
     "train_olmoe_1b_7b_s4k": (
         _kinds(attention=(2, 68157440), experts=(2, 1075315200)), "", {}),
     "train_granite_4_0_h_micro_long": (
-        _kinds(attention=(1, 34603008)),
+        # PR 52: six of the ten MLPs' gate and up [1, 8192, 8192] bfloat16
+        _kinds(attention=(1, 34603008), dense=(6, 1610612736)),
         "; ssd chunk states 67108864 bytes a device; conv kernel 9 layers; "
         "scan kernel 9 layers",                                  # PR 48
         {"hbnlp_ssd_state_bytes": 67108864,
          "hbnlp_mamba_conv_kernel_layers": 9,
          "hbnlp_ssd_scan_kernel_layers": 9}),
     "train_olmo_hybrid_7b_long": (
-        _kinds(attention=(1, 127795200), recurrent=(3, 566231040)),
+        # PR 52: the last MLP's gate and up [1, 16384, 11008]
+        _kinds(attention=(1, 127795200), recurrent=(3, 566231040),
+               dense=(1, 721420288)),
         # PR 50: the rule is the Pallas pair, which keeps the entering
         # states of all 30 heads (until then one group's ten: 94371840)
         "; ssd chunk states 283115520 bytes a device; conv kernel 3 layers; "
@@ -71,7 +76,8 @@ _CELLS = {
     # PR 46: the sparse layer's (out, lse) and its choice (a bool a query and
     # a block); three lightning layers' chunk states, and no conv of theirs
     "train_minicpm_sala_tp2_long": (
-        _kinds(attention=(1, 72351744)),
+        # PR 52: the last MLP's gate and up [1, 16384, 16384]
+        _kinds(attention=(1, 72351744), dense=(1, 1073741824)),
         "; ssd chunk states 67108864 bytes a device",
         {"hbnlp_ssd_state_bytes": 67108864}),
     # PR 49: a looped model counts a layer each time the step runs it, and
@@ -144,8 +150,12 @@ def _config_files():
 #: added the series ``hbnlp_delta_rule_kernel_layers`` to every file and ``;
 #: rule kernel N layers`` to the lines of the two Olmo-Hybrid files, whose
 #: chunk states on a TPU are all 30 heads' where they were one group's: before
-#: it cab4c9c0a1c8793f2e07b4e41810430d7e27b83b)
-_FILE_DIGEST = "5221896c3d024205a9d2196fd56b63c8840405ae"
+#: it cab4c9c0a1c8793f2e07b4e41810430d7e27b83b; PR 52 added the fifth kind,
+#: ``dense``, to every file's line and two gauges — 0 layers and 0 bytes in
+#: every file but the three cells' ``benchmark/configs/`` files of granite (6,
+#: 1610612736), MiniCPM-SALA (1, 1073741824) and Olmo-Hybrid (1, 721420288):
+#: before it 5221896c3d024205a9d2196fd56b63c8840405ae)
+_FILE_DIGEST = "822c534a7685509a587d4690f8021ccd1e6099de"
 
 
 def every_configuration_file_starts_as_on_the_parent_test(monkeypatch):
@@ -293,6 +303,7 @@ def facts_are_declared_once_in_line_order_test():
     ("attention-nope", "attention", ("flash_out", "flash_lse")),
     ("cca-q_heads8-kv_heads2", "attention", ("flash_out", "flash_lse")),
     ("bottleneck_group_linear-in:relu", "bottleneck", ()),
+    ("mlp-silu", "dense", ("mlp_gate", "mlp_up")),
     ("mamba", None, None), ("norm-shift-scale", None, None),
     ("attention-biased_attention_map-absolute-input_as_value", None, None),
     ("bottleneck_group_linear-in:mixture_of_experts", None, None)])
@@ -309,3 +320,6 @@ def layer_offers_its_kind_test(layer, kind, names):
     else:
         assert (offer.kind, offer.names) == (kind, names)
         assert offer.nbytes > 0 and offer.count >= 1
+    if kind == "dense":
+        # gate and up, [1, 16384, 11008] in bfloat16 each
+        assert (offer.nbytes, offer.count) == (2 * 16384 * 11008 * 2, 2)

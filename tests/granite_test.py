@@ -708,8 +708,9 @@ def selected_kernels_keep_their_scope_and_names_test(v5e, monkeypatch):
 
 
 def experts_rule_declines_without_a_moe_layer_test():
-    """``checkpoint`` with no ``moe`` layer: nothing rides, the policy stays
-    the named one, and the chunk states' gauge counts one layer's."""
+    """``checkpoint`` with no ``moe`` layer: neither the experts nor the
+    recurrent kind rides, the policy of a region without an ``mlp`` stays the
+    named one, and the chunk states' gauge counts one layer's."""
     from homebrewnlp_tpu import telemetry
     from homebrewnlp_tpu.model import recurrent, remat
     from homebrewnlp_tpu.model.blocks import _checkpoint_policy
@@ -726,8 +727,11 @@ def experts_rule_declines_without_a_moe_layer_test():
     # [2, 64 / 16, 4, 8, 16] float32
     assert recurrent.ssd_state_bytes(params) == 2 * 4 * 4 * 8 * 16 * 4
     line = Trainer(params, model).publish_stash_plan()
+    # (PR 52: the ten toy MLPs' gate and up [2, 64, 256] float32 fit, and
+    # ride the regions' own policies: tests/remat_policy_test.py)
     assert line.endswith("experts 0 layers, 0 bytes a device; recurrent 0 "
-                         "layers, 0 bytes a device; ssd chunk states 16384 "
+                         "layers, 0 bytes a device; dense 10 layers, 2621440 "
+                         "bytes a device; ssd chunk states 16384 "
                          "bytes a device; conv kernel 0 layers; scan kernel "
                          "0 layers")
     snap = telemetry.registry().snapshot()

@@ -715,3 +715,30 @@ def the_repos_config_is_the_published_model_test():
      "body/mamba/gate_norm")])
 def the_new_scopes_fold_test(path, scope_name):
     assert scope_key(path) == scope_name
+
+
+def dense_kind_leaves_the_cells_step_alone_test(monkeypatch):
+    """PR 52: the cell's one ``mlp`` (the published dense layer 0) is an
+    INPUT block — outside every ``jax.checkpoint`` region, so it offers
+    nothing — and the experts kind declined for size, so nothing is admitted
+    whatever a body held: every region's policy is the parent's, the named
+    one itself."""
+    from homebrewnlp_tpu.model.blocks import _region_policies
+    from homebrewnlp_tpu.model.declare import step_offers
+    from homebrewnlp_tpu.utils import flops
+    from remat_policy_test import _cell_params
+    monkeypatch.setattr(flops, "hbm_capacity",
+                        lambda device=None: (16911433728, "memory_stats"))
+    params = _cell_params("train_laguna_s_2_1_ep32_s8k")
+    assert any("mlp" in layer for block in params.input_block_config
+               for layer in block.layer)
+    assert remat.offers(params, "dense") == []
+    assert len(list(step_offers(params, "dense"))) == 1
+    assert remat.stash_plan(params)["dense"] == (0, 0)
+    assert "dense" not in remat.stash_kinds(params)
+    regions = len(params.block_config) * params.depth
+    assert remat.region_names(params) == [()] * regions
+    policies = _region_policies(params)
+    assert len(policies) == regions
+    assert all(policy is jax.checkpoint_policies.nothing_saveable
+               for policy in policies)

@@ -478,7 +478,8 @@ def remat_rules_count_the_new_layer_test(monkeypatch):
     assert recurrent.conv_kernel_layers(params, "tpu") == 0     # 96 channels
     line = Trainer(params, model).publish_stash_plan()
     assert line.endswith("experts 0 layers, 0 bytes a device; recurrent 0 "
-                         "layers, 0 bytes a device; ssd chunk states 12288 "
+                         "layers, 0 bytes a device; dense 0 layers, 0 bytes "
+                         "a device; ssd chunk states 12288 "
                          "bytes a device; conv kernel 0 layers; solve kernel "
                          "0 layers; rule kernel 0 layers")
     snap = telemetry.registry().snapshot()
@@ -698,3 +699,30 @@ def saved_rule_output_changes_no_bit_test(monkeypatch, scan_layers, groups):
     assert remat.stash_plan(off)["recurrent"] == (0, 0)
     assert remat.stash_names(off) == ()
     assert _checkpoint_policy(off) is jax.checkpoint_policies.nothing_saveable
+
+
+def dense_kind_rides_the_last_region_alone_test(monkeypatch):
+    """PR 52: of the cell's four MLPs the rule admits ONE, the step's last
+    block's — 0.836 GB are left of the 15% after the flash pair, the rule's
+    three outputs and the eight block inputs, and gate + up of [1, 16384,
+    11008] are 0.721 GB — so only the last region's policy holds the two new
+    names; the seven before it get the parent's policy, the same object."""
+    from homebrewnlp_tpu.model.blocks import (_checkpoint_policy,
+                                              _region_policies)
+    from homebrewnlp_tpu.utils import flops
+    from remat_policy_test import _cell_params
+    monkeypatch.setattr(flops, "hbm_capacity",
+                        lambda device=None: (16911433728, "memory_stats"))
+    params = _cell_params("train_olmo_hybrid_7b_long")
+    assert len(params.block_config) * params.depth == 8
+    left = int(0.15 * 16911433728) - 127795200 - 566231040 \
+        - 8 * 16384 * 3840 * 2
+    assert 721420288 <= left < 2 * 721420288
+    assert remat.stash_plan(params)["dense"] == (1, 721420288)
+    assert remat.dense_executions(params) == 1
+    parents = ("gated_delta_out", "flash_out", "flash_lse")
+    assert remat.region_names(params) == [parents] * 7 \
+        + [parents + ("mlp_gate", "mlp_up")]
+    policies = _region_policies(params)
+    assert [policy is _checkpoint_policy(params) for policy in policies] \
+        == [True] * 7 + [False]

@@ -238,7 +238,7 @@ def saved_expert_outputs_change_no_bit_test(scan):
         line = Trainer(params, model).publish_stash_plan()
         assert line.endswith(f"experts {engaged[0]} layers, {engaged[1]} "
                              "bytes a device; recurrent 0 layers, 0 bytes a "
-                             "device")
+                             "device; dense 0 layers, 0 bytes a device")
         snap = telemetry.registry().snapshot()
         assert snap["hbnlp_remat_stash_layers"]["series"][("experts",)] \
             == engaged[0]

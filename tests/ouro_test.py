@@ -614,3 +614,30 @@ def the_cells_parameters_test():
         == pytest.approx(6.55, abs=0.01)
     assert ouro_costs.train_flops_per_token(config) \
         == 3 * ouro_costs.forward_flops_per_token(config)
+
+
+def dense_kind_leaves_the_cells_step_alone_test(monkeypatch):
+    """PR 52: layer ``mlp`` offers its gate and up, and the cell admits none
+    — 96 block inputs and 48 ``(out, lse)`` leave nothing of the 15% at the
+    chip's own limit — so every one of the 96 regions (2 blocks x 12 x 4 passes)
+    gets the parent's policy, the one object that saves the flash pair."""
+    from homebrewnlp_tpu.model import remat
+    from homebrewnlp_tpu.model.blocks import (_checkpoint_policy,
+                                              _region_policies)
+    from homebrewnlp_tpu.utils import flops
+    from remat_policy_test import _cell_params
+    monkeypatch.setattr(flops, "hbm_capacity",
+                        lambda device=None: (16911433728, "memory_stats"))
+    params = _cell_params("train_ouro_2_6b_loop4_s4k")
+    # one MLP a period, 12 periods, 4 passes: 48 executions of 184.5 MB
+    assert [o.nbytes for o in remat.offers(params, "dense")] == [184549376]
+    assert params.depth * params.loop_steps == 48
+    assert remat.stash_plan(params)["dense"] == (0, 0)
+    assert remat.dense_executions(params) == 0
+    regions = len(params.block_config) * params.depth * params.loop_steps
+    assert regions == 96
+    assert remat.stash_names(params) == ("flash_out", "flash_lse")
+    assert remat.region_names(params) == [("flash_out", "flash_lse")] * 96
+    policies = _region_policies(params)
+    assert len(policies) == 96
+    assert all(policy is _checkpoint_policy(params) for policy in policies)

@@ -8,6 +8,10 @@ stash/recompute, the long-context stash rule still fires, and short-context
 default resolves to recompute (the round-11 A/B measured the save modes
 SLOWER on the memory-bound rig — auto must not silently adopt them).
 """
+import contextlib
+import functools
+import typing
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,8 +19,12 @@ import pytest
 
 from backend import make_params
 from homebrewnlp_tpu.model import Model
-from homebrewnlp_tpu.model.remat import remat_report, resolve_remat
+from homebrewnlp_tpu.model.remat import (STASH_KINDS, remat_report,
+                                         resolve_remat)
 from homebrewnlp_tpu.train import Trainer
+
+#: no kind engaged
+_IDLE = {kind: (0, 0) for kind in STASH_KINDS}
 
 _CFG = dict(sequence_length=32, features_per_head=16, heads=2, depth=2,
             train_batch_size=4, vocab_size=64,
@@ -208,8 +216,7 @@ def bottleneck_stash_resolver_test(case):
         assert resolve_remat(p, mesh) == "stash"
         # 128 sequences a chip x 512 x 512 x bfloat16 x 32 layers
         assert stash_plan(p, mesh) == {
-            "attention": (0, 0), "bottleneck": (32, 128 * 512 * 512 * 2 * 32),
-            "experts": (0, 0), "recurrent": (0, 0)}
+            **_IDLE, "bottleneck": (32, 128 * 512 * 512 * 2 * 32)}
     elif case == "over_budget":
         # the published deployment's share, 256 sequences a chip: 4.3 GB
         # against 15% of the planning figure
@@ -226,8 +233,7 @@ def bottleneck_stash_resolver_test(case):
     elif case == "checkpoint_strategy":
         p = _flagship(memory_reduction_strategy="checkpoint")
         plan = stash_plan(p, shardlib.build_mesh(p, devices))
-        assert plan == {"attention": (0, 0), "bottleneck": (0, 0),
-                        "experts": (0, 0), "recurrent": (0, 0)}
+        assert plan == _IDLE
     else:
         # a long-context configuration's attention decision is the same
         # with and without a bottleneck in the block, on one device and on
@@ -273,10 +279,10 @@ def remat_stash_gauges_test(engaged):
     assert got["hbnlp_remat_stash_bytes"] == {
         ("attention",): 0,
         ("bottleneck",): item * params.depth if engaged else 0,
-        ("experts",): 0, ("recurrent",): 0}
+        ("experts",): 0, ("recurrent",): 0, ("dense",): 0}
     assert got["hbnlp_remat_stash_layers"] == {
         ("attention",): 0, ("bottleneck",): params.depth if engaged else 0,
-        ("experts",): 0, ("recurrent",): 0}
+        ("experts",): 0, ("recurrent",): 0, ("dense",): 0}
     assert trainer.publish_stash_plan().startswith("remat stash: attention 0")
 
 
@@ -320,8 +326,7 @@ _OLMOE_LAYER = 65536 * (2 * 1024 + 2048) * 2 + (3 * 65536 + 64) * 4
     "no_moe_layer", "revnet", "none", "macro_batching"])
 def experts_stash_resolver_test(case):
     from homebrewnlp_tpu.model.remat import stash_kinds, stash_plan
-    idle = {"attention": (0, 0), "bottleneck": (0, 0), "experts": (0, 0),
-            "recurrent": (0, 0)}
+    idle = _IDLE
     if case == "engaged":
         p = _cell_params("train_olmoe_1b_7b_s4k")
         rep = remat_report(p)
@@ -382,33 +387,67 @@ _MOE_NAMES = ("moe_gate", "moe_up", "moe_down", "moe_order", "moe_inverse",
 _FLASH_NAMES = ("flash_out", "flash_lse")
 
 
-@pytest.mark.parametrize("cell,kinds,policy,plan,names", [
-    ("train_32big_mixer_b32", set(), "recompute", {}, ()),
+#: a v5e's memory: the table's 15.75 GiB (``utils/flops.py HBM_BYTES``, what
+#: a described chip reads and ISSUE 52 counted with; the budget is 15% of it,
+#: 2,536,715,059 bytes) and what a live chip reports as its limit
+#: (``memory_stats()['bytes_limit']``: 2 MiB less; my chip runs, PR 50)
+_CHIP_LIMITS = (16911433728, 16909336064)
+_MLP_NAMES = ("mlp_gate", "mlp_up")
+
+
+@pytest.fixture(params=_CHIP_LIMITS, ids=["table", "reported"])
+def chip_limit(request, monkeypatch):
+    """The rules read the chip's own limit, as a run on the chip does (the
+    CPU's table row is 16 GiB); the limit."""
+    from homebrewnlp_tpu.utils import flops
+    monkeypatch.setattr(flops, "hbm_capacity",
+                        lambda device=None: (request.param, "memory_stats"))
+    return request.param
+
+
+@pytest.mark.parametrize("cell,kinds,policy,plan,names,dense", [
+    ("train_32big_mixer_b32", set(), "recompute", {}, (), (0, 0, ())),
     ("train_32big_mixer_dp2tp2", {"bottleneck"}, "stash",
-     {"bottleneck": (32, 2147483648)}, ()),
+     {"bottleneck": (32, 2147483648)}, (), (0, 0, ())),
     ("train_1b_long_context_s16k", {"attention"}, "stash",
-     {"attention": (8, 2155872256)}, ()),
+     {"attention": (8, 2155872256)}, (), (0, 0, ())),
     # 2 x (out [2, 4096, 16, 128] bfloat16 + lse [32, 4096] float32)
     ("train_olmoe_1b_7b_s4k", {"attention", "experts"}, "stash",
      {"experts": (2, 1075315200), "attention": (2, 68157440)},
-     _MOE_NAMES + _FLASH_NAMES),
-    # out [1, 8192, 32, 64] + lse [32, 8192]
-    ("train_granite_4_0_h_micro_long", {"attention"}, "stash",
-     {"attention": (1, 34603008)}, _FLASH_NAMES),
-    # out [1, 16384, 30, 128] + lse [30, 16384]
-    ("train_olmo_hybrid_7b_long", {"attention", "recurrent"}, "stash",
-     {"recurrent": (3, 566231040), "attention": (1, 127795200)},
-     ("gated_delta_out",) + _FLASH_NAMES),
+     _MOE_NAMES + _FLASH_NAMES, (0, 0, ())),
+    # out [1, 8192, 32, 64] + lse [32, 8192]; PR 52: 2 x [1, 8192, 8192]
+    # bfloat16 an execution, six of ten inside 2.537 GB - 34.6 MB - 20 block
+    # inputs of [1, 8192, 2048] = 1.831 GB, from the last block backwards
+    ("train_granite_4_0_h_micro_long", {"attention", "dense"}, "stash",
+     {"attention": (1, 34603008)}, _FLASH_NAMES,
+     (6, 1610612736, (9, 11, 13, 15, 17, 19))),
+    # out [1, 16384, 30, 128] + lse [30, 16384]; PR 52: 2 x [1, 16384, 11008]
+    # an execution, one of four inside 2.537 GB - 127.8 MB - 566.2 MB - 8
+    # block inputs of [1, 16384, 3840] = 0.836 GB: the step's last block
+    ("train_olmo_hybrid_7b_long", {"attention", "recurrent", "dense"},
+     "stash", {"recurrent": (3, 566231040), "attention": (1, 127795200)},
+     ("gated_delta_out",) + _FLASH_NAMES, (1, 721420288, (7,))),
     # the experts kind has 5,375,525,008 bytes to save and declined for
     # size: the step as it was (the parent read a vestigial {"attention"}
-    # that nothing carried)
-    ("train_laguna_s_2_1_ep32_s8k", set(), "recompute", {}, ()),
+    # that nothing carried), and (PR 52) no room for the dense kind either;
+    # its one ``mlp`` is an input block, outside every region
+    ("train_laguna_s_2_1_ep32_s8k", set(), "recompute", {}, (), (0, 0, ())),
     # layer cca's OWN 8 query heads in a 16-head stream: 8 x (out [1, 16384,
     # 8, 128] + lse [8, 16384])
     ("train_zaya1_8b_ep2_s16k", {"attention", "experts"}, "stash",
      {"experts": (8, 1612185888), "attention": (8, 272629760)},
-     _MOE_NAMES + _FLASH_NAMES)])
-def experts_kind_moves_no_other_cell_test(cell, kinds, policy, plan, names):
+     _MOE_NAMES + _FLASH_NAMES, (0, 0, ())),
+    # PR 52: 2 x [1, 16384, 16384] an execution, one of four inside 2.537 GB
+    # - 72.4 MB - 8 block inputs of [1, 16384, 4096] = 1.391 GB
+    ("train_minicpm_sala_tp2_long", {"attention", "dense"}, "stash",
+     {"attention": (1, 72351744)}, _FLASH_NAMES + ("sparse_keep",),
+     (1, 1073741824, (7,))),
+    # 96 regions x [2, 4096, 2048] block inputs = 3.2 GB beside the 1.6 GB of
+    # (out, lse): nothing is left (PR 52)
+    ("train_ouro_2_6b_loop4_s4k", {"attention"}, "stash",
+     {"attention": (48, 1635778560)}, _FLASH_NAMES, (0, 0, ()))])
+def experts_kind_moves_no_other_cell_test(cell, kinds, policy, plan, names,
+                                          dense, chip_limit):
     """What the three cells without a ``moe`` layer resolved to before the
     experts kind existed, and (PR 33) what the five cells without a layer
     that offers its output resolved to before the recurrent kind existed
@@ -419,11 +458,18 @@ def experts_kind_moves_no_other_cell_test(cell, kinds, policy, plan, names):
     ``checkpoint`` cells whose earlier kinds fitted — each layer's own query
     heads, ISSUE 40's table to the byte — after the kinds the parent
     resolved, which it moved in none; Laguna's and the three revnet cells'
-    plan and names are the parent's."""
+    plan and names are the parent's.  Since PR 52 all ten cells, at the
+    chip's own limit: the ``dense`` kind, decided last, admits ``dense`` =
+    (executions, bytes, the regions that save layer ``mlp``'s two names) —
+    ISSUE 52's table to the byte — and moves no other kind's numbers; every
+    other region's policy names what the parent's named."""
     from benchmark.lib.cell import load_cell
     from homebrewnlp_tpu.core import sharding as shardlib
-    from homebrewnlp_tpu.model.blocks import _checkpoint_policy, _name_chan
-    from homebrewnlp_tpu.model.remat import (saved_attention_keys,
+    from homebrewnlp_tpu.model.blocks import (_checkpoint_policy, _name_chan,
+                                              _named_policy,
+                                              _region_policies)
+    from homebrewnlp_tpu.model.remat import (dense_executions, region_names,
+                                             saved_attention_keys,
                                              stash_kinds, stash_names,
                                              stash_plan)
     p = _cell_params(cell)
@@ -434,12 +480,19 @@ def experts_kind_moves_no_other_cell_test(cell, kinds, policy, plan, names):
         mesh = shardlib.build_mesh(p, jax.devices()[:4])
     assert stash_kinds(p, mesh) == kinds
     assert resolve_remat(p, mesh) == policy
-    assert stash_plan(p, mesh) == {
-        "attention": (0, 0), "bottleneck": (0, 0), "experts": (0, 0),
-        "recurrent": (0, 0), **plan}
+    assert stash_plan(p, mesh) == {**_IDLE, **plan, "dense": dense[:2]}
+    assert dense_executions(p, mesh) == dense[0]
     assert stash_names(p, mesh) == names
     assert (_checkpoint_policy(p, mesh)
             is jax.checkpoint_policies.nothing_saveable) == (not names)
+    # region by region: the parent's names, and the two new ones only where
+    # an admitted execution is
+    regions = len(p.block_config) * p.depth * p.loop_steps
+    saved = [names + (_MLP_NAMES if region in dense[2] else ())
+             for region in range(regions)]
+    assert region_names(p, mesh) == saved
+    assert all(policy is _named_policy(p.gradient_checkpointing_policy, held)
+               for policy, held in zip(_region_policies(p, mesh), saved))
     # the blocks' "name" channel exactly where the two names are saved
     rides = "flash_out" in names
     assert saved_attention_keys(p, mesh) == (2048 if rides else None)
@@ -496,7 +549,7 @@ def experts_stash_line_and_policy_test():
         "remat stash: attention 2 layers, 68157440 bytes a device; "
         "bottleneck 0 layers, 0 bytes a device; experts 2 layers, "
         f"{2 * _OLMOE_LAYER} bytes a device; recurrent 0 layers, 0 bytes a "
-        "device")
+        "device; dense 0 layers, 0 bytes a device")
     nothing = jax.checkpoint_policies.nothing_saveable
     assert _checkpoint_policy(p) is not nothing
     for kw in ({"remat_policy": "recompute"}, {"depth": 16}):
@@ -519,6 +572,10 @@ _OLMO = "train_olmo_hybrid_7b_long"
 #: its one flash layer's out [1, 16384, 30, 128] in bfloat16 and lse [30,
 #: 16384] in float32 (PR 40)
 _OLMO_FLASH = 16384 * 30 * (128 * 2 + 4)
+#: one MLP's gate and up [1, 16384, 11008] in bfloat16 (PR 52); granite's
+#: [1, 8192, 8192]
+_OLMO_MLP = 2 * 16384 * 11008 * 2
+_GRANITE_MLP = 2 * 8192 * 8192 * 2
 
 
 def _with_moe(top_k: int):
@@ -545,8 +602,7 @@ def recurrent_stash_resolver_test(case):
         recurrent_layers as _recurrent_layers
     from homebrewnlp_tpu.model.remat import (stash_kinds, stash_names,
                                              stash_plan)
-    idle = {kind: (0, 0) for kind in
-            ("attention", "bottleneck", "experts", "recurrent")}
+    idle = _IDLE
     nothing = jax.checkpoint_policies.nothing_saveable
     if case == "engaged":
         p = _cell_params(_OLMO)
@@ -555,9 +611,12 @@ def recurrent_stash_resolver_test(case):
                 rep["recurrent_stash_bytes_per_device"]) \
             == (3, 3 * _OLMO_LAYER) == (3, 566231040)
         assert 3 * _OLMO_LAYER <= rep["stash_budget_bytes"]
-        assert stash_kinds(p) == {"attention", "recurrent"}
+        # (PR 52: the dense kind, decided after all three, takes the last
+        # MLP's gate and up from what they and the block inputs leave)
+        assert stash_kinds(p) == {"attention", "recurrent", "dense"}
         assert stash_plan(p) == {**idle, "recurrent": (3, 566231040),
-                                 "attention": (1, _OLMO_FLASH)}
+                                 "attention": (1, _OLMO_FLASH),
+                                 "dense": (1, _OLMO_MLP)}
         assert stash_names(p) == ("gated_delta_out",) + _FLASH_NAMES
         assert _checkpoint_policy(p) is not nothing
     elif case == "over_budget":
@@ -576,7 +635,8 @@ def recurrent_stash_resolver_test(case):
         # explicit: on whatever the bytes
         p = _cell_params(_OLMO, remat_policy="stash", depth=8)
         assert stash_plan(p) == {**idle, "recurrent": (24, 24 * _OLMO_LAYER),
-                                 "attention": (8, 8 * _OLMO_FLASH)}
+                                 "attention": (8, 8 * _OLMO_FLASH),
+                                 "dense": (32, 32 * _OLMO_MLP)}
     elif case == "legacy_false":
         p = _cell_params(_OLMO, stash_attention_outputs=False)
         assert stash_plan(p) == idle and _checkpoint_policy(p) is nothing
@@ -642,8 +702,11 @@ def recurrent_stash_resolver_test(case):
             assert len(specs) == 9
             assert offers(p, "recurrent") == []
             assert remat_report(p)["recurrent_stash_layers"] == 0
-            # what rides there is the attention kind alone (PR 40)
-            assert stash_plan(p) == {**idle, "attention": (1, 34603008)}
+            # what rides there is the attention kind (PR 40) and six of
+            # the ten MLPs' gate and up (PR 52; all ten where forced)
+            assert stash_plan(p) == {
+                **idle, "attention": (1, 34603008),
+                "dense": (10 if kw else 6, (10 if kw else 6) * _GRANITE_MLP)}
             assert stash_names(p) == _FLASH_NAMES
 
 
@@ -653,7 +716,8 @@ def recurrent_stash_line_test():
     assert stash_line(stash_plan(_cell_params(_OLMO))) == (
         "remat stash: attention 1 layers, 127795200 bytes a device; "
         "bottleneck 0 layers, 0 bytes a device; experts 0 layers, 0 bytes a "
-        "device; recurrent 3 layers, 566231040 bytes a device")
+        "device; recurrent 3 layers, 566231040 bytes a device; dense 1 "
+        "layers, 721420288 bytes a device")
 
 
 # ---- the attention kind under checkpoint (PR 40): every flash layer's (out,
@@ -685,8 +749,7 @@ def attention_saved_resolver_test(case):
     from homebrewnlp_tpu.model.remat import (saved_attention_keys,
                                              stash_kinds, stash_names,
                                              stash_plan)
-    idle = {kind: (0, 0) for kind in
-            ("attention", "bottleneck", "experts", "recurrent")}
+    idle = _IDLE
     nothing = jax.checkpoint_policies.nothing_saveable
 
     def declines(p, mesh=None):
@@ -717,11 +780,15 @@ def attention_saved_resolver_test(case):
                        if l.startswith("attention") else l)
         assert remat_report(p)["saved_attention_layers"] == 0
         declines(p)
-        assert stash_plan(p) == idle and _checkpoint_policy(p) is nothing
+        # (PR 52: the 34.6 MB the layer does not hold are room for a seventh
+        # MLP's gate and up, at the CPU's 16 GiB)
+        assert stash_plan(p) == {**idle, "dense": (7, 7 * _GRANITE_MLP)}
+        assert _checkpoint_policy(p) is nothing
     elif case == "window_of_2048_keys":
         p = _relayered(_GRANITE, lambda l: l + "-window2048"
                        if l.startswith("attention") else l)
-        assert stash_plan(p) == {**idle, "attention": (1, 34603008)}
+        assert stash_plan(p) == {**idle, "attention": (1, 34603008),
+                                 "dense": (6, 6 * _GRANITE_MLP)}
         assert saved_attention_keys(p) == 2048
     elif case == "sequence_under_2048":
         declines(_cell_params(_GRANITE, sequence_length=1024))
@@ -784,7 +851,8 @@ def attention_saved_resolver_test(case):
     elif case == "legacy_true":
         p = _cell_params(_GRANITE, stash_attention_outputs=True,
                          sequence_length=1024)
-        assert stash_plan(p) == {**idle, "attention": (1, 34603008 // 8)}
+        assert stash_plan(p) == {**idle, "attention": (1, 34603008 // 8),
+                                 "dense": (10, 10 * _GRANITE_MLP // 8)}
         assert saved_attention_keys(p) == 0
     elif case == "legacy_false":
         declines(_cell_params(_ZAYA, stash_attention_outputs=False))
@@ -809,3 +877,275 @@ def attention_saved_resolver_test(case):
             assert stash_plan(p, Piped()) == idle
             assert _name_chan(p, Piped()) is None
             assert _checkpoint_policy(p, Piped()) is nothing
+
+
+# ---- the dense kind (PR 52): layer mlp's gate and up outputs ride the
+# jax.checkpoint of the regions that hold an admitted execution — admitted one
+# execution at a time from the step's LAST backwards, into what the earlier
+# kinds AND the block inputs ``checkpoint`` itself keeps leave of the 15% -----
+
+_SALA = "train_minicpm_sala_tp2_long"
+_OURO = "train_ouro_2_6b_loop4_s4k"
+_TOY = {"depth": 2, "heads": 4, "features_per_head": 16,
+        "sequence_length": 64, "train_batch_size": 2, "vocab_size": 384,
+        "tpu_size": 1, "use_checkpointing": False, "slice_dtype": "float32",
+        "calculation_dtype": "float32", "model_path": "/tmp/remat_policy_test"}
+#: the toy's gate and up [2, 64, 176] float32 an execution, and a block input
+#: [2, 64, 4 x 16]
+_TOY_MLP = 2 * 2 * 64 * 176 * 4
+_TOY_INPUT = 2 * 64 * 64 * 4
+
+
+@functools.lru_cache(maxsize=None)
+def _dense_toy(loops: int, policy: str, limit: int = 0, scan: bool = False):
+    """Ouro's period (an attention block, then an MLP block, each between two
+    norms) at toy widths, two periods deep, ``loops`` passes: ``(params, the
+    gradient's jaxpr, (loss, gradients))`` under ``policy``; ``limit``: the
+    bytes the chip reports, where the rule is to admit a part."""
+    import json
+    import os
+    from unittest import mock
+    from homebrewnlp_tpu.config import ModelParameter
+    from homebrewnlp_tpu.utils import flops
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "configs", "ouro_2_6b.json")) as f:
+        config = {**json.load(f), **_TOY, "loop_steps": loops,
+                  "remat_policy": policy, "scan_layers": scan}
+    params = ModelParameter(config)
+    model = Model(params)
+    tokens = np.random.default_rng(0).integers(0, 256, (2, 64, 1)).astype(
+        np.int32)
+    batch = {"token_x": tokens, "token_y": np.roll(tokens, -1, axis=1)}
+    variables = {k: jnp.asarray(v)
+                 for k, v in model.init(batch, seed=11).items()}
+    fn = jax.value_and_grad(lambda v: model.apply(v, batch).total_loss.data)
+    with mock.patch.object(flops, "hbm_capacity",
+                           lambda device=None: (limit, "test")) \
+            if limit else contextlib.nullcontext():
+        from homebrewnlp_tpu.model.remat import stash_plan
+        return (params, stash_plan(params)["dense"],
+                jax.make_jaxpr(fn)(variables).jaxpr, jax.jit(fn)(variables))
+
+
+def _limit_admitting(executions: int, loops: int) -> int:
+    """The chip limit whose 15% holds the toy's block inputs (one a region)
+    and ``executions`` and a half of its MLPs' gate and up."""
+    from homebrewnlp_tpu.model.remat import STASH_HBM_FRACTION
+    regions = 2 * _TOY["depth"] * loops
+    return int((regions * _TOY_INPUT + executions * _TOY_MLP + _TOY_MLP // 2)
+               / STASH_HBM_FRACTION)
+
+
+def _replayed_dense_dots(jaxpr) -> typing.List[int]:
+    """The matmuls of the gate / up shapes — ``[2, 64, 4, 16] x [4, 16, 176]
+    -> [2, 64, 176]`` — in the BACKWARD of every ``jax.checkpoint`` region of
+    a gradient's jaxpr (where the region's replay is), in execution order of
+    the regions: the backward holds them last region first."""
+    counts = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name != "remat2":
+            continue
+        counts.append(sum(
+            e.primitive.name == "dot_general"
+            and tuple(e.invars[1].aval.shape) == (4, 16, 176)
+            and tuple(e.outvars[0].aval.shape) == (2, 64, 176)
+            for e in eqn.params["jaxpr"].eqns))
+    return counts[::-1]
+
+
+@pytest.mark.parametrize("loops,admit,scan", [
+    (1, 0, False), (1, 1, False), (1, "all", False), (2, 0, False),
+    (2, 1, False), (2, 3, False), (2, "all", False), (1, "all", True),
+    (1, 1, True)])
+def dense_admission_changes_no_value_test(loops, admit, scan):
+    """A toy ``checkpoint`` model with none, some and all of its ``mlp``
+    executions admitted — unrolled, looped (the admission runs on over the
+    passes: three of four reach into the first pass) and scanned (all or
+    none) — against ``"recompute"``: the loss bit for bit, the gradients to
+    the file's tolerance; the replay of an admitted block runs no matmul of
+    the gate / up shapes, that of a block not admitted both; admitted are the
+    LAST executions of the step."""
+    _, _, base_jaxpr, (want_loss, want) = _dense_toy(loops, "recompute",
+                                                     scan=scan)
+    executions = _TOY["depth"] * loops
+    if admit == "all":
+        # the toy's bytes fit the CPU's 16 GiB many times over
+        params, plan, jaxpr, (loss, grads) = _dense_toy(loops, "auto",
+                                                        scan=scan)
+        admitted = executions
+    else:
+        params, plan, jaxpr, (loss, grads) = _dense_toy(
+            loops, "auto", _limit_admitting(admit, loops), scan)
+        # a scanned body traces one block for all its iterations: all its
+        # executions or none, and one of two does not fit
+        admitted = 0 if scan else admit
+    assert plan == (admitted, admitted * _TOY_MLP)
+    assert float(loss) == float(want_loss) and np.isfinite(float(loss))
+    assert set(grads) == set(want)
+    for name in want:
+        np.testing.assert_allclose(np.asarray(grads[name], np.float32),
+                                   np.asarray(want[name], np.float32),
+                                   rtol=2e-4, atol=1e-5, err_msg=name)
+    if scan:
+        # one region a block of the period, inside the scan's backward
+        return
+    # the regions alternate: an attention block, an MLP block
+    regions = 2 * executions
+    assert _replayed_dense_dots(base_jaxpr) == [0, 2] * executions
+    assert _replayed_dense_dots(jaxpr) == [
+        0 if region % 2 == 0 or region >= regions - 2 * admitted else 2
+        for region in range(regions)]
+
+
+@pytest.mark.parametrize("case", [
+    "from_the_end", "block_inputs_count", "earlier_kind_declined_for_size",
+    "earlier_kinds_leave_less", "stash", "recompute", "legacy_false",
+    "revnet", "none", "pipe_mesh", "scan_layers", "macro_batching",
+    "input_block_offers_nothing", "two_layers_a_block"])
+def dense_stash_resolver_test(case, chip_limit):
+    from homebrewnlp_tpu.model.blocks import (_checkpoint_policy,
+                                              _region_policies)
+    from homebrewnlp_tpu.model.declare import offers
+    from homebrewnlp_tpu.model.remat import (dense_executions, region_names,
+                                             stash_kinds, stash_plan)
+    budget = int(0.15 * chip_limit)
+
+    def saving(p, mesh=None):
+        """The regions whose policy saves the two names."""
+        names, policies = region_names(p, mesh), _region_policies(p, mesh)
+        assert len(names) == len(policies) \
+            == len(p.block_config) * p.depth * p.loop_steps
+        found = [r for r, held in enumerate(names)
+                 if set(_MLP_NAMES) & set(held)]
+        assert all((policy is not _checkpoint_policy(p, mesh)) == (r in found)
+                   for r, policy in enumerate(policies))
+        return found
+
+    def declines(p, mesh=None, kinds=True):
+        # (an explicit "stash" names every kind, whatever can ride)
+        assert p.remat_policy == "stash" or not kinds \
+            or "dense" not in stash_kinds(p, mesh)
+        assert stash_plan(p, mesh)["dense"] == (0, 0)
+        assert dense_executions(p, mesh) == 0 and saving(p, mesh) == []
+
+    if case == "from_the_end":
+        # SALA's four MLPs at 1,073,741,824 bytes each: as the chip grows the
+        # rule takes block 7, then 5, then 3, then 1 — never an earlier one
+        # before a later
+        p = _cell_params(_SALA)
+        assert [o.nbytes for o in offers(p, "dense")] == [_SALA_MLP] * 4
+        held = 72351744 + 8 * 16384 * 4096 * 2
+        from homebrewnlp_tpu.utils import flops
+        for executions, blocks in enumerate(([], [7], [5, 7], [3, 5, 7],
+                                             [1, 3, 5, 7])):
+            limit = int((held + executions * _SALA_MLP + 1000) / 0.15) + 7
+            with pytest.MonkeyPatch.context() as patch:
+                patch.setattr(flops, "hbm_capacity",
+                              lambda device=None, v=limit: (v, "test"))
+                assert stash_plan(p)["dense"] == (executions,
+                                                  executions * _SALA_MLP)
+                assert saving(p) == blocks
+                assert stash_plan(p)["attention"] == (1, 72351744)
+    elif case == "block_inputs_count":
+        # Ouro: 2.537 GB - 1.636 GB of (out, lse) would hold four executions
+        # of 184.5 MB by the kinds alone; the 96 block inputs [2, 4096, 2048]
+        # the strategy itself keeps are 3.2 GB: nothing is left
+        p = _cell_params(_OURO)
+        one = offers(p, "dense")[0].nbytes
+        assert one == 2 * 2 * 4096 * 5632 * 2
+        assert (budget - 1635778560) // one == 4
+        assert 96 * 2 * 4096 * 2048 * 2 == 3221225472 > budget
+        declines(p)
+        # granite: 20 inputs of [1, 8192, 2048] take 0.671 GB of the 2.502 GB
+        # the attention kind leaves: six executions where nine would fit
+        p = _cell_params(_GRANITE)
+        assert (budget - 34603008) // _GRANITE_MLP == 9
+        assert (budget - 34603008 - 20 * 8192 * 2048 * 2) // _GRANITE_MLP == 6
+        assert stash_plan(p)["dense"] == (6, 6 * _GRANITE_MLP)
+        assert saving(p) == [9, 11, 13, 15, 17, 19]
+    elif case == "earlier_kind_declined_for_size":
+        # the experts kind over the budget: the step regenerates its buffers
+        # live inside each block's backward and has no room to hold more
+        p, experts = _with_moe(8)
+        assert experts > budget
+        assert stash_plan(p)["recurrent"] == (3, 3 * _OLMO_LAYER)
+        declines(p)
+        # Laguna: declined by the same test; its one ``mlp`` is an input
+        # block, which no region holds
+        p = _cell_params(_LAGUNA)
+        assert offers(p, "dense") == []
+        declines(p)
+    elif case == "earlier_kinds_leave_less":
+        # decided LAST: experts at top-2 (the parent's plan, unmoved), the
+        # rule's outputs and the flash pair leave 0.15 GB, less than the
+        # eight block inputs [1, 16384, 3840] alone
+        p, experts = _with_moe(2)
+        left = budget - experts - 3 * _OLMO_LAYER - _OLMO_FLASH
+        assert 0 < left < 8 * 16384 * 3840 * 2
+        assert stash_plan(p) == {**_IDLE, "experts": (1, experts),
+                                 "recurrent": (3, 3 * _OLMO_LAYER),
+                                 "attention": (1, _OLMO_FLASH)}
+        declines(p)
+    elif case == "stash":
+        # explicit: every execution, whatever the bytes — 12 x 4 passes
+        p = _cell_params(_OURO, remat_policy="stash")
+        one = offers(p, "dense")[0].nbytes
+        assert stash_plan(p)["dense"] == (48, 48 * one)
+        assert saving(p) == list(range(1, 96, 2))
+    elif case in ("recompute", "legacy_false"):
+        declines(_cell_params(_GRANITE, **(
+            {"remat_policy": "recompute"} if case == "recompute"
+            else {"stash_attention_outputs": False})))
+    elif case in ("revnet", "none"):
+        # no jax.checkpoint to ride ("none" has no replay)
+        for kw in ({}, {"remat_policy": "stash"}):
+            declines(_cell_params(_OLMO, memory_reduction_strategy=case,
+                                  **kw))
+    elif case == "pipe_mesh":
+        from homebrewnlp_tpu.core.sharding import PIPE_AXIS
+
+        class Piped:
+            devices = None
+            shape = {PIPE_AXIS: 2}
+
+        for kw in ({}, {"remat_policy": "stash"}):
+            # (the kinds are the mesh-blind rules'; the plan is the step's)
+            declines(_cell_params(_GRANITE, **kw), Piped(), kinds=False)
+    elif case == "scan_layers":
+        # a scanned body traces ONE block for all its iterations: all the
+        # step's executions or none.  Two periods of granite are 20 MLPs
+        p = _cell_params(_GRANITE, depth=2)
+        assert stash_plan(p)["dense"][0] == 4       # 40 block inputs now
+        declines(_cell_params(_GRANITE, depth=2, scan_layers=True))
+        p = _cell_params(_GRANITE, depth=2, scan_layers=True,
+                         sequence_length=1024)
+        assert stash_plan(p)["dense"] == (20, 20 * _GRANITE_MLP // 8)
+        assert saving(p) == list(range(1, 40, 2))
+    elif case == "macro_batching":
+        # two micro-batches hold two sets of everything
+        p = _cell_params(_GRANITE, macro_batching=2)
+        assert (budget - 2 * 34603008 - 2 * 20 * 8192 * 2048 * 2) \
+            // (2 * _GRANITE_MLP) == 2
+        assert stash_plan(p)["dense"] == (2, 2 * 2 * _GRANITE_MLP)
+        assert saving(p) == [17, 19]
+    elif case == "input_block_offers_nothing":
+        # the input and output blocks run outside any region: an ``mlp``
+        # there is neither offered nor counted
+        blocks = [{"layer": list(b.layer), "skip": b.skip}
+                  for b in _cell_params(_GRANITE).block_config]
+        p = _cell_params(_GRANITE, input_block_config=blocks[1:2])
+        assert stash_plan(p)["dense"] == (6, 6 * _GRANITE_MLP)
+    else:
+        # a block's layers share their names: its executions go together.
+        # Granite's last two MLPs in one block: 19 regions, and the budget
+        # 1.848 GB holds the pair (0.537 GB) three times ... but the blocks
+        # before the pair hold one each: 2 + 4 x 1
+        blocks = [{"layer": list(b.layer), "skip": b.skip}
+                  for b in _cell_params(_GRANITE).block_config]
+        blocks[17]["layer"] += blocks[19]["layer"]
+        p = _cell_params(_GRANITE, block_config=blocks[:19])
+        assert stash_plan(p)["dense"] == (6, 6 * _GRANITE_MLP)
+        assert saving(p) == [9, 11, 13, 15, 17]
+
+
+_SALA_MLP = 2 * 16384 * 16384 * 2
