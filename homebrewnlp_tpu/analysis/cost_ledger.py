@@ -76,7 +76,7 @@ _LAYER_NAMES = frozenset((
 #: the parts of layer ``moe`` (model/moe.py), each a scope of its own below
 #: ``body/moe``
 _MOE_PARTS = frozenset(("router", "dispatch", "experts", "combine",
-                        "shared"))
+                        "shared", "latent_down", "latent_up"))
 #: the parts of ZAYA1's router (flag ``router_mlp``) below
 #: ``body/moe/router``; the one-matrix router has none
 _ROUTER_PARTS = frozenset(("down", "carry", "mlp"))
@@ -128,7 +128,8 @@ def scope_key(path: str) -> str:
 
     Keys: ``decode/cache_read|cache_write|sampling``, ``optimizer``,
     ``head_loss``, ``input/embed``, ``input``, ``body/<layer>``,
-    ``body/moe/router|dispatch|experts|combine|shared``,
+    ``body/moe/router|dispatch|experts|combine|shared|latent_down|
+    latent_up``,
     ``body/moe/router/down|carry|mlp``, ``body/attention/gate``,
     ``body/attention/sparse_attention/compress|index|select|attend``,
     ``body/lightning/in_proj|qk_norm|rope|rule|gate_norm|out_proj``,

@@ -234,6 +234,15 @@ class ModelParameter:
         # layer "moe" with flag "router_mlp" (ZAYA1's router): the width of
         # the router's own stream, which one layer's router hands the next
         self.moe_router_width = 256
+        # layer "moe" with flag "latent" (LatentMoE): the width of the latent
+        # the routed experts read and write, between a projection down before
+        # dispatch and one up after combine; with flag "shared_expert": the
+        # shared expert's own width (0 = the experts'); with flag
+        # "sigmoid_bias": the step of the selection bias's update (its rule:
+        # optim/__init__.py selection_bias_rule; 0 = the bias stays)
+        self.moe_latent_width = 0
+        self.shared_expert_width = 0
+        self.moe_bias_rate = 1e-3
         # layer "cca" (compressed convolutional attention, model/cca.py):
         # taps of the depthwise and of the grouped causal conv over the
         # packed q-k latent (the published cca_time0 / cca_time1)
@@ -252,6 +261,9 @@ class ModelParameter:
         self.mamba_state = 128
         self.mamba_conv_size = 4
         self.mamba_chunk = 256
+        # groups of B / C: head j reads group j // (mamba_heads /
+        # mamba_groups), and the gated RMSNorm runs over each group's columns
+        self.mamba_groups = 1
         # layer "gated_delta" (gated delta-rule linear attention,
         # model/gated_delta.py): heads, the width of a head's key / query and
         # of its value, the causal depthwise conv's taps, the chunk of the
@@ -926,7 +938,8 @@ class ModelParameter:
         if self.query_group < 1 or self.heads % self.query_group:
             raise ValueError(f"query_group {self.query_group} must divide "
                              f"heads {self.heads}")
-        for key in ("expert_width", "experts_held", "experts_first"):
+        for key in ("expert_width", "experts_held", "experts_first",
+                    "moe_latent_width", "shared_expert_width"):
             if not isinstance(getattr(self, key), int) \
                     or getattr(self, key) < 0:
                 raise ValueError(f"{key} {getattr(self, key)!r} must be a "
@@ -940,6 +953,14 @@ class ModelParameter:
             raise ValueError(f"residual_out_stddev "
                              f"{self.residual_out_stddev!r} must be >= 0 "
                              "(0 = 0.02)")
+        if not self.moe_bias_rate >= 0:
+            raise ValueError(f"moe_bias_rate {self.moe_bias_rate!r} must be "
+                             ">= 0")
+        if not isinstance(self.mamba_groups, int) or self.mamba_groups < 1 \
+                or self.mamba_heads % self.mamba_groups:
+            raise ValueError(f"mamba_groups {self.mamba_groups!r} must be a "
+                             f"positive divisor of mamba_heads "
+                             f"{self.mamba_heads}")
         if self.experts_first + self.experts_held > self.experts:
             raise ValueError(
                 f"experts_first {self.experts_first} + experts_held "

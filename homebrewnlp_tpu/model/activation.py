@@ -45,6 +45,11 @@ def _relu(args):
     return unary(jax.nn.relu, args.tensor)
 
 
+def _relu2(args):
+    # relu(x)^2 (Primer's squared ReLU; Nemotron's ungated experts)
+    return unary(lambda x: jnp.square(jax.nn.relu(x)), args.tensor)
+
+
 def _sigmoid_fn(args):
     return _sigmoid(args.tensor)
 
@@ -76,6 +81,7 @@ def _exp(args):
 
 
 ACTIVATIONS = {'relu': _relu,
+               'relu2': _relu2,
                'sigmoid': _sigmoid_fn,
                'tanh': _tanh_fn,
                'gelu': _gelu,

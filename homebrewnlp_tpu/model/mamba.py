@@ -3,10 +3,11 @@ chunked state-space-duality form (Dao & Gu, arXiv:2405.21060, section 6 and
 listing 1).
 
 On the block's (already normalised) input ``u [b, s, features]``, with
-``d_inner = mamba_heads x mamba_head_features``, one group of ``B`` / ``C``
-shared by all heads, state size ``n = mamba_state``:
+``d_inner = mamba_heads x mamba_head_features``, ``g = mamba_groups`` groups
+of ``B`` / ``C`` (head ``j`` reads group ``G(j) = j // (heads / g)``; 1 = one
+group shared by all heads, Granite's), state size ``n = mamba_state``:
 
-    z, xBC, dt = split(u W_in)            W_in: features x (2 d_inner + 2 n
+    z, xBC, dt = split(u W_in)            W_in: features x (2 d_inner + 2 g n
                                           + heads), no bias
     xBC = silu(conv(xBC))                 causal depthwise conv over the
                                           sequence, width mamba_conv_size,
@@ -14,12 +15,13 @@ shared by all heads, state size ``n = mamba_state``:
                                           where parallel/causal_conv.py
                                           ``kernel_applies``, else K shifted
                                           multiplies in XLA
-    x, B, C = split(xBC)                  d_inner, n, n
+    x, B, C = split(xBC)                  d_inner, g n, g n
     dt = softplus(dt + dt_bias);  A = -exp(A_log)          per head, float32
-    S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T             S: [width, n]
-    y_t = S_t C_t + D x_t
-    y = rms(y * silu(z)) * w_norm         gate FIRST, then RMSNorm over all
-                                          d_inner columns, eps 1e-5
+    S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_{t,G}^T         S: [width, n]
+    y_t = S_t C_{t,G} + D x_t
+    y = rms(y * silu(z)) * w_norm         gate FIRST, then RMSNorm over each
+                                          group's d_inner / g columns
+                                          (g = 1: all of them), eps 1e-5
     out = y W_out                         d_inner x features, no bias
 
 The recurrence is never run position by position: inside a chunk of
@@ -43,6 +45,7 @@ Training and full-sequence forward on one device; a decode / prefill form
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -67,12 +70,14 @@ from .utils import anonymize_dim
 def ssd(x, dt, a, b_mat, c_mat, chunk: int):
     """The chunked scan.  ``x [b, s, h, p]`` (calculation dtype), ``dt [b,
     s, h]`` and ``a [h]`` float32 (``a`` negative), ``b_mat`` / ``c_mat``
-    ``[b, s, n]``; ``s`` a multiple of ``chunk``.  Returns ``(y [b, s, h,
+    ``[b, s, n]`` (one group) or ``[b, s, groups, n]``; ``s`` a multiple of
+    ``chunk``.  Returns ``(y [b, s, h,
     p]`` in float32 WITHOUT the ``D x`` skip, the most negative within-chunk
     cumulative ``dt a``)``: the Pallas pair of ``parallel/ssd_scan.py`` where
     ``ssd_kernel_applies``, else ``ssd_xla``."""
     _, s, h, p = x.shape
-    if not ssd_kernel_applies(s, chunk, h, p, b_mat.shape[-1]):
+    groups = b_mat.shape[2] if b_mat.ndim == 4 else 1
+    if not ssd_kernel_applies(s, chunk, h, p, b_mat.shape[-1], groups=groups):
         return ssd_xla(x, dt, a, b_mat, c_mat, chunk)
     a_cum = log_decay(dt, a, chunk)
     return ssd_scan(x, dt, a_cum, b_mat, c_mat, chunk), jnp.min(a_cum)
@@ -81,8 +86,16 @@ def ssd(x, dt, a, b_mat, c_mat, chunk: int):
 def ssd_xla(x, dt, a, b_mat, c_mat, chunk: int):
     """``ssd`` as XLA's einsums and a ``lax.scan`` over the chunk states,
     autodiff its backward: the path off the TPU and at shapes the kernels
-    decline, and their oracle."""
+    decline, and their oracle.  Groups of ``B`` / ``C`` are one ``vmap`` of
+    the one-group form over each group with its heads."""
     bsz, s, h, p = x.shape
+    if b_mat.ndim == 4:
+        g = b_mat.shape[2]
+        y, lowest = jax.vmap(
+            functools.partial(ssd_xla, chunk=chunk), (2, 2, 0, 2, 2), (2, 0))(
+            x.reshape(bsz, s, g, h // g, p), dt.reshape(bsz, s, g, h // g),
+            a.reshape(g, h // g), b_mat, c_mat)
+        return y.reshape(x.shape), jnp.min(lowest)
     n = b_mat.shape[-1]
     c, l = s // chunk, chunk
     dtype = x.dtype
@@ -131,14 +144,14 @@ def mamba(args: BlockArgs) -> NamedTensor:
     U(-1/sqrt(K), 1/sqrt(K)) (torch's Conv1d default, as the Mamba-2 code
     leaves it); ``dt_bias`` with ``softplus`` log-uniform in [1e-3, 1e-1],
     ``A_log = log U[1, 16]``, ``D = 1``, the norm's scale 1; ``W_out``
-    normal(0.02)."""
+    normal(``residual_out_stddev`` or 0.02): it writes into the stream."""
     params = args.params
     ctx = scope.current()
     token_dims, bsz, s, chunk = token_layout(args, "mamba",
                                              params.mamba_chunk)
     h, p, n = params.mamba_heads, params.mamba_head_features, params.mamba_state
-    k = params.mamba_conv_size
-    d_inner, conv_dim = h * p, h * p + 2 * n
+    k, g = params.mamba_conv_size, params.mamba_groups
+    d_inner, conv_dim = h * p, h * p + 2 * g * n
     feats = list(params.feature_dims)
     anon = [anonymize_dim(d) for d in feats]
     x = args.tensor
@@ -177,19 +190,27 @@ def mamba(args: BlockArgs) -> NamedTensor:
     with jax.named_scope("ssd"):
         xs = xbc[..., :d_inner].reshape(bsz, s, h, p)
         dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
-        y, log_decay_min = ssd(xs, dt, -jnp.exp(a_log),
-                               xbc[..., d_inner:d_inner + n],
-                               xbc[..., d_inner + n:], chunk)
+        a = -jnp.exp(a_log)
+        b_mat = xbc[..., d_inner:d_inner + g * n]
+        c_mat = xbc[..., d_inner + g * n:]
+        if g > 1:
+            b_mat, c_mat = (m.reshape(bsz, s, g, n) for m in (b_mat, c_mat))
+        y, log_decay_min = ssd(xs, dt, a, b_mat, c_mat, chunk)
         y = y + xs.astype(jnp.float32) * skip[:, None]
     if ctx.layer_stats is not None:
         ctx.layer_stats.append({"ssd_log_decay_min": log_decay_min})
     with jax.named_scope("gate_norm"):
         gated = (y.reshape(bsz, s, d_inner)
                  * jax.nn.silu(z.astype(jnp.float32))).astype(dtype)
-        gated = _norm_core(gated, w_norm.reshape(1, 1, d_inner),
-                           jnp.ones((1, 1, 1), jnp.float32), (2,), 1e-5,
-                           True, False, False)
-    w_out = normal_var(args, [inner] + feats)
+        # each group's columns normalised apart: [b, s, g, d_inner / g]
+        grouped = (g, d_inner // g) if g > 1 else (d_inner,)
+        gated = _norm_core(gated.reshape((bsz, s) + grouped),
+                           w_norm.reshape((1, 1) + grouped),
+                           jnp.ones((1,) * (2 + len(grouped)), jnp.float32),
+                           (1 + len(grouped),), 1e-5, True, False, False
+                           ).reshape(bsz, s, d_inner)
+    w_out = normal_var(args, [inner] + feats,
+                       stddev=params.residual_out_stddev or 0.02)
     with jax.named_scope("out_proj"):
         out = _matmul("bsi,if->bsf", gated, w_out.data.reshape(d_inner, f_sz)
                       ).astype(dtype)
@@ -208,13 +229,15 @@ def _state_bytes(params: ModelParameter) -> int:
 
 def _conv(params: ModelParameter):
     inner = params.mamba_heads * params.mamba_head_features
-    return inner + 2 * params.mamba_state, params.mamba_conv_size, inner
+    return inner + 2 * params.mamba_groups * params.mamba_state, \
+        params.mamba_conv_size, inner
 
 
 def _scan(params: ModelParameter):
     s = params.sequence_dim.size
     return (s, min(params.mamba_chunk, s), params.mamba_heads,
-            params.mamba_head_features, params.mamba_state)
+            params.mamba_head_features, params.mamba_state,
+            params.mamba_groups)
 
 
 mamba.declares = Layer(

@@ -58,6 +58,27 @@ leaves the layer beside the stream as a CARRIED SIDE VALUE
 output of every block's region, with its cotangent in the backward.  Only the
 strategies that carry one (``checkpoint`` / ``none``, unrolled) run it.
 
+Flag ``sigmoid_bias`` replaces the softmax by DeepSeek-V3's scoring
+(arXiv:2412.19437 section 2.1.2): ``s = sigmoid(x W_r)`` in float32, the
+choice ``T = top-k(s + b)`` with ``b [experts]`` the SELECTION BIAS, which
+chooses only — the weights are ``moe_route_scale x s_e / (sum_T s + 1e-20)``
+under ``moe_norm_topk``.  ``b`` is a parameter with NO gradient: the
+backward hands the optimizer the step's pair counts of ALL experts as its
+cotangent (``_balance_tap``), and ``optim/__init__.py selection_bias_rule``
+moves it by ``moe_bias_rate x sign(mean - count)``, outside the chain.
+``moe_balance_loss`` then weighs ``experts x sum_e f_e mean_t(s_e / sum s)``
+(``f``: the choice's pair shares), injected into the scores' cotangent by the
+same tap.  Flag ``plain`` makes an expert ``down(act(up x))``: two grouped
+matmuls, no gate (``relu2``: Nemotron's).  Flag ``latent`` (LatentMoE) puts a
+projection to ``moe_latent_width`` columns before dispatch (scope
+``latent_down``) and one back after combine (``latent_up``): the rows, the
+buffers, the gathers and the scatter-add are that wide, the experts are
+``latent x width x latent``; the router and the shared expert read the
+full-width input, and the shared expert (``shared_expert_width`` wide where
+set) is added after the projection up.  Such a layer offers the memory
+strategy the combined sum a TOKEN (``LATENT_SUM``) where the others offer
+their grouped matmuls' outputs a pair.
+
 ``basic.routed_mixture_of_experts`` (one routed linear, capacity-padded
 one-hot dispatch) stays beside it until ROADMAP D7 merges the two.
 """
@@ -74,6 +95,7 @@ from ..config import BlockArgs
 from ..core import scope
 from ..core.dims import Dim
 from ..core.tensor import NamedTensor, nt, transpose_to
+from ..optim import SELECTION_BIAS
 from .activation import ACTIVATIONS
 from .backend import ConstantInit, NormalInit, normal_var
 from .basic import _router_aux_inject
@@ -284,32 +306,36 @@ _twice_held.defvjp(_twice_held_fwd, _twice_held_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _gated_held(gated, gate, up, n_real):
-    """``gated(gate, up)`` (``act(gate) * up``, a function of two row tiles)
-    on the tiles that hold real rows of ``gate`` and ``up [rows, width]``."""
+def _gated_held(gated, operands, n_real):
+    """``gated(*operands)`` (``act(gate) * up`` of two row tiles; a ``plain``
+    expert's ``act(up)`` of one) on the tiles that hold real rows of the
+    ``operands``, each ``[rows, width]``."""
+    first = operands[0]
+
     def body(at, tile, hidden):
         return jax.lax.dynamic_update_slice(
-            hidden, gated(_cut(gate, at, tile), _cut(up, at, tile)), (at, 0))
-    return _over_real_tiles(n_real, gate.shape[0], body,
-                            _fresh(gate, gate.shape, gate.dtype))
+            hidden, gated(*(_cut(o, at, tile) for o in operands)), (at, 0))
+    return _over_real_tiles(n_real, first.shape[0], body,
+                            _fresh(first, first.shape, first.dtype))
 
 
-def _gated_held_fwd(gated, gate, up, n_real):
-    return _gated_held(gated, gate, up, n_real), (gate, up, n_real)
+def _gated_held_fwd(gated, operands, n_real):
+    return _gated_held(gated, operands, n_real), (operands, n_real)
 
 
 def _gated_held_bwd(gated, res, g):
-    gate, up, n_real = res
+    operands, n_real = res
 
     def body(at, tile, grads):
-        parts = jax.vjp(gated, _cut(gate, at, tile), _cut(up, at, tile))[1](
+        parts = jax.vjp(gated, *(_cut(o, at, tile) for o in operands))[1](
             _cut(g, at, tile))
         return tuple(jax.lax.dynamic_update_slice(d, part, (at, 0))
                      for d, part in zip(grads, parts))
-    return (*_over_real_tiles(
-        n_real, gate.shape[0], body,
-        (_fresh(g, gate.shape, gate.dtype),
-         _fresh(g, up.shape, up.dtype, "moe_held_rows_alloc_up"))), None)
+    return _over_real_tiles(
+        n_real, operands[0].shape[0], body,
+        tuple(_fresh(g, o.shape, o.dtype, name) for o, name in zip(
+            operands, ("moe_held_rows_alloc", "moe_held_rows_alloc_up")))
+    ), None
 
 
 _gated_held.defvjp(_gated_held_fwd, _gated_held_bwd)
@@ -383,6 +409,15 @@ _combine_held.defvjp(_combine_held_fwd, _combine_held_bwd)
 SAVED_NAMES = ("moe_gate", "moe_up", "moe_down",
                "moe_order", "moe_inverse", "moe_sizes", "moe_experts")
 
+#: what a ``latent`` layer offers in the place of the grouped matmuls'
+#: outputs: the combined sum ``[tokens, moe_latent_width]``, the projection
+#: up's operand.  A pair's row costs ``intermediate + latent`` columns to keep
+#: and two grouped matmuls at the latent's width to make again; the sum is
+#: ``latent`` columns a TOKEN, and the replay that holds it runs no combine:
+#: one scatter-add a real row less (0.08 us a row at 1,024 columns; my chip
+#: runs, PR 54)
+LATENT_SUM = "moe_latent_sum"
+
 
 #: rows, contraction and columns of one tile of the grouped-matmul kernel;
 #: measured on a v5e at [65536, 2048] x [64, 2048, 1024], forward and
@@ -434,8 +469,71 @@ def route(logits, top_k: int, norm_topk: bool = False, scale: float = 1.0):
     return weights, experts
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _balance_tap(wb: float, scores, bias, counts):
+    """The scores as they are; the backward hands ``bias`` the step's pair
+    counts ``[experts]`` as its cotangent — what
+    ``optim/__init__.py selection_bias_rule`` reads; the bias has no gradient
+    — and, where ``wb``, adds the balance term's gradient ``wb x experts x
+    sum_e f_e mean_t(s_e / sum s)`` (``f = counts / sum counts``, constant) to
+    the scores'.  Like ``_router_aux_inject``: nothing has to leave the block
+    stack, so a block's replay and gradient accumulation need no care (the
+    counts of the micro batches add up)."""
+    return scores
+
+
+def _balance_tap_fwd(wb, scores, bias, counts):
+    return scores, (scores, counts)
+
+
+def _balance_tap_bwd(wb, res, g):
+    scores, counts = res
+    if wb:
+        share = counts / jnp.maximum(jnp.sum(counts), 1.0)
+        g = g + jax.grad(lambda s: wb * s.shape[-1] * jnp.sum(share * jnp.mean(
+            s / jnp.sum(s, axis=-1, keepdims=True), axis=0)))(scores
+                                                              ).astype(g.dtype)
+    return g, counts, jnp.zeros_like(counts)
+
+
+_balance_tap.defvjp(_balance_tap_fwd, _balance_tap_bwd)
+
+
+def route_sigmoid(logits, bias, top_k: int, norm_topk: bool = True,
+                  scale: float = 1.0, balance: float = 0.0,
+                  train: bool = False):
+    """Float32 sigmoid scores, the ``top_k`` largest of ``scores + bias``
+    chosen and the SCORES of the chosen as weights (``norm_topk``: over their
+    sum + 1e-20; times ``scale``): ``(weights [t, k], experts [t, k], pair
+    counts [experts])``.  The chosen scores by comparing and selecting, no
+    gather over ``[t, experts]`` (``held_slots``); a replay that holds the
+    saved choice weighs exactly what the forward chose.  ``train``: the
+    scores pass ``_balance_tap``."""
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, experts = jax.lax.top_k(scores + bias, top_k)
+    experts = checkpoint_name(experts, "moe_experts")
+    chose = experts[..., None] == jnp.arange(scores.shape[-1],
+                                             dtype=experts.dtype)
+    counts = jnp.sum(chose, axis=(0, 1), dtype=jnp.float32)
+    if train:
+        scores = _balance_tap(balance, scores, bias, counts)
+    weights = jnp.sum(jnp.where(chose, scores[:, None, :], 0.0), axis=-1)
+    if norm_topk:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    if scale != 1.0:
+        weights = weights * scale
+    return weights, experts, counts
+
+
 #: the key of the router state in ``Context.side``
 ROUTER_STATE = "router_state"
+
+#: the flags layer ``moe`` knows beside an activation's name
+_FLAGS = ("shared_expert", "router_mlp", "plain", "latent", "sigmoid_bias")
+
+# ``SELECTION_BIAS``: the scope the selection bias is made under — its name
+# holds it, which is how ``optim/__init__.py OWN_RULES`` knows the leaf its
+# own rule moves
 
 
 def _router_mlp(args: BlockArgs, xf, anon, ctx):
@@ -563,21 +661,36 @@ def sort_held(local, held: int):
     return order, sizes
 
 
-def _shared_expert(args: BlockArgs, act, xf, anon, inter, feats):
+def _dense(lhs, weight, shape):
+    """``lhs [t, a]`` times the parameter ``weight`` read as ``shape [a,
+    b]``, float32 accumulation off the CPU, in ``lhs``' dtype."""
+    return jnp.dot(lhs, weight.data.reshape(shape).astype(lhs.dtype),
+                   preferred_element_type=None
+                   if jax.default_backend() == "cpu" else jnp.float32
+                   ).astype(lhs.dtype)
+
+
+def _shared_expert(args: BlockArgs, act, xf, anon, inter, feats,
+                   plain: bool = False):
     """The shared expert: ``down(act(gate x) * up x)`` on every token's row
-    of ``xf [t, f]``, three matrices created in the order gate, up, down."""
+    of ``xf [t, f]``, three matrices created in the order gate, up, down;
+    ``plain``: ``down(act(up x))``, two."""
     f_sz, i_sz = xf.shape[-1], math.prod(d.size for d in inter)
-    prefer = None if jax.default_backend() == "cpu" else jnp.float32
 
-    def dot(lhs, weight, shape):
-        return jnp.dot(lhs, weight.data.reshape(shape).astype(lhs.dtype),
-                       preferred_element_type=prefer).astype(lhs.dtype)
+    def activated(rows):
+        return act(args(nt(rows, [Dim("_tokens", xf.shape[0]),
+                                  Dim("_width", i_sz)]))).data
 
-    gate = dot(xf, normal_var(args, anon + inter), (f_sz, i_sz))
-    up = dot(xf, normal_var(args, anon + inter), (f_sz, i_sz))
-    hidden = act(args(nt(gate, [Dim("_tokens", xf.shape[0]),
-                                Dim("_width", i_sz)]))).data * up
-    return dot(hidden, normal_var(args, inter + feats), (i_sz, f_sz))
+    if plain:
+        hidden = activated(_dense(xf, normal_var(args, anon + inter),
+                                  (f_sz, i_sz)))
+    else:
+        gate = _dense(xf, normal_var(args, anon + inter), (f_sz, i_sz))
+        up = _dense(xf, normal_var(args, anon + inter), (f_sz, i_sz))
+        hidden = activated(gate) * up
+    return _dense(hidden, normal_var(
+        args, inter + feats,
+        stddev=args.params.residual_out_stddev or 0.02), (i_sz, f_sz))
 
 
 def moe(args: BlockArgs) -> NamedTensor:
@@ -589,7 +702,14 @@ def moe(args: BlockArgs) -> NamedTensor:
     all, OLMoE's form).  Parameters, normal(0.02), in creation order: router
     ``[features, experts]``, gate and up ``[held, features, width]``, down
     ``[held, width, features]``, then the shared expert's gate, up
-    ``[features, width]`` and down ``[width, features]``."""
+    ``[features, width]`` and down ``[width, features]``.  Flags
+    ``sigmoid_bias``, ``plain`` and ``latent`` (module docstring) add the
+    selection bias ``[experts]`` (0) after the router and the latent's
+    projection down ``[features, moe_latent_width]`` after it, drop every
+    gate, and put the projection up ``[moe_latent_width, features]`` after
+    the experts' down.  The three that write towards the stream — the
+    experts' down, the latent's projection up and the shared expert's down —
+    are normal(``residual_out_stddev``) where that key is set."""
     params = args.params
     ctx = scope.current()
     if ctx.decode is not None:
@@ -599,11 +719,22 @@ def moe(args: BlockArgs) -> NamedTensor:
             "layer moe on a mesh (expert-parallel dispatch) is a later issue")
     unknown = [a for a in args.name_extras
                if a not in ACTIVATIONS
-               and a not in ("shared_expert", "router_mlp")]
+               and a not in _FLAGS]
     if unknown:
         raise ValueError(f"layer moe does not know flag(s) {unknown} (known: "
-                         "an activation's name, shared_expert, router_mlp)")
-    router_mlp = "router_mlp" in args.name_extras
+                         f"an activation's name, {', '.join(_FLAGS)})")
+    router_mlp, plain, latent, biased = (
+        flag in args.name_extras
+        for flag in ("router_mlp", "plain", "latent", "sigmoid_bias"))
+    if biased and (router_mlp or params.scan_layers
+                   or params.pipeline_stages > 1):
+        raise NotImplementedError(
+            "layer moe's sigmoid_bias (a selection bias the optimizer moves "
+            "by the step's pair counts) runs with the one-matrix router on "
+            "the unrolled strategies: router_mlp, scan_layers and "
+            "pipeline_stages > 1 have no form of it yet")
+    if latent and not params.moe_latent_width:
+        raise ValueError("layer moe's flag latent needs moe_latent_width")
     n_exp = params.expert_dim.size
     top_k = min(params.moe_top_k, n_exp)
     held, first = params.experts_held or n_exp, params.experts_first
@@ -621,29 +752,63 @@ def moe(args: BlockArgs) -> NamedTensor:
     f_sz = math.prod(d.size for d in feats)
     i_sz = math.prod(d.size for d in inter)
 
+    # what the experts read and write: the stream, or the latent
+    row_dims, rows_anon, r_sz = feats, anon, f_sz
     if not router_mlp:
         w_router = normal_var(args, anon + [params.expert_dim])
-    w_gate = normal_var(args, [held_dim] + anon + inter)
-    w_up = normal_var(args, [held_dim] + anon + inter)
-    w_down = normal_var(args, [held_dim] + inter + feats,
+    if biased:
+        bias = _small_var(args, SELECTION_BIAS, [params.expert_dim],
+                          ConstantInit(0.0))
+    if latent:
+        row_dims = [Dim("moe_latent", params.moe_latent_width)]
+        rows_anon, r_sz = [anonymize_dim(row_dims[0])], row_dims[0].size
+        w_latent_down = normal_var(args, anon + row_dims)
+    if not plain:
+        w_gate = normal_var(args, [held_dim] + rows_anon + inter)
+    w_up = normal_var(args, [held_dim] + rows_anon + inter)
+    w_down = normal_var(args, [held_dim] + inter + row_dims,
                         stddev=params.residual_out_stddev or 0.02)
+    if latent:
+        w_latent_up = normal_var(args, rows_anon + feats,
+                                 stddev=params.residual_out_stddev or 0.02)
 
     xf = transpose_to(x, token_dims + feats).data.reshape(t_sz, f_sz)
+    counts = None
     with jax.named_scope("router"):
         logits = _router_mlp(args, xf, anon, ctx) if router_mlp else jnp.dot(
             xf, w_router.data.reshape(f_sz, n_exp),
             preferred_element_type=None if jax.default_backend() == "cpu"
             else jnp.float32).astype(jnp.float32)
         wb, wz = float(params.moe_balance_loss), float(params.moe_router_z_loss)
-        if params.train and (wb or wz):
-            # one routing group: the balance term is over the step's tokens
-            logits = _router_aux_inject(wb, wz, top_k, logits[None])[0]
-        weights, experts = route(logits, top_k, params.moe_norm_topk,
-                                 float(params.moe_route_scale))
+        if biased:
+            if wz:
+                raise ValueError("moe_router_z_loss has no form on sigmoid "
+                                 "scores (layer moe, flag sigmoid_bias)")
+            weights, experts, counts = route_sigmoid(
+                logits, bias, top_k, params.moe_norm_topk,
+                float(params.moe_route_scale), wb, params.train)
+        else:
+            if params.train and (wb or wz):
+                # one routing group: the balance term is over the step's
+                # tokens
+                logits = _router_aux_inject(wb, wz, top_k, logits[None])[0]
+            weights, experts = route(logits, top_k, params.moe_norm_topk,
+                                     float(params.moe_route_scale))
     if ctx.layer_stats is not None and top_k == 1:
         # the chosen expert's probability, the mean over the step's tokens:
         # 1 / experts = a router that says nothing
         ctx.layer_stats.append({"moe_top1_weight_mean": jnp.mean(weights)})
+    if ctx.layer_stats is not None and biased:
+        ctx.layer_stats.append({
+            "moe_bias_abs_max": jnp.max(jnp.abs(bias)),
+            # over ALL the experts, from the counts the bias's rule reads
+            "moe_all_load_max_over_mean":
+                jnp.max(counts) * n_exp / jnp.maximum(jnp.sum(counts), 1.0)})
+    if latent:
+        with jax.named_scope("latent_down"):
+            xr = _dense(xf, w_latent_down, (f_sz, r_sz))
+    else:
+        xr = xf
     # a layer that holds a share sorts SLOTS (held_slots), not choices, into
     # held + 1 groups, the sentinel last; its kernels see the held groups,
     # and where the buffer is mostly empty so does everything round them
@@ -657,13 +822,13 @@ def moe(args: BlockArgs) -> NamedTensor:
             order, sizes = (checkpoint_name(a, name) for a, name in zip(
                 sort_held(experts, held), ("moe_order", "moe_sizes")))
             n_real = jnp.sum(sizes[:held])
-            rows = _dispatch_held(xf, order, n_real, slots)
+            rows = _dispatch_held(xr, order, n_real, slots)
         else:
             order, inverse, sizes = sort_pairs(experts, groups)
             order = checkpoint_name(order, "moe_order")
             inverse = checkpoint_name(inverse, "moe_inverse")
             sizes = checkpoint_name(sizes, "moe_sizes")
-            rows = _dispatch(xf, order, inverse, slots, real)
+            rows = _dispatch(xr, order, inverse, slots, real)
         if partial:
             sizes = sizes[:held]
     if ctx.layer_stats is not None and partial:
@@ -688,26 +853,45 @@ def moe(args: BlockArgs) -> NamedTensor:
                 jnp.max(sizes).astype(jnp.float32) * n_exp / (t_sz * top_k),
             "moe_routed_pairs": jnp.sum(sizes).astype(jnp.float32)})
 
+    def activated(rows):
+        return act(args(nt(rows, [Dim("_pairs", rows.shape[0]),
+                                  Dim("_width", i_sz)]))).data
+
     def gated(gate, up):
-        return act(args(nt(gate, [Dim("_pairs", gate.shape[0]),
-                                  Dim("_width", i_sz)]))).data * up
+        return activated(gate) * up
 
     with jax.named_scope("experts"):
-        rows, rows_up = _twice_held(rows, n_real) if tiled else (rows, rows)
-        gate = checkpoint_name(grouped_dot(
-            rows, w_gate.data.reshape(held, f_sz, i_sz), sizes), "moe_gate")
-        up = checkpoint_name(grouped_dot(
-            rows_up, w_up.data.reshape(held, f_sz, i_sz), sizes), "moe_up")
-        hidden = _gated_held(gated, gate, up, n_real) if tiled \
-            else gated(gate, up)
+        if plain:
+            up = checkpoint_name(grouped_dot(
+                rows, w_up.data.reshape(held, r_sz, i_sz), sizes), "moe_up")
+            hidden = _gated_held(activated, (up,), n_real) if tiled \
+                else activated(up)
+        else:
+            rows, rows_up = _twice_held(rows, n_real) if tiled \
+                else (rows, rows)
+            gate = checkpoint_name(grouped_dot(
+                rows, w_gate.data.reshape(held, r_sz, i_sz), sizes),
+                "moe_gate")
+            up = checkpoint_name(grouped_dot(
+                rows_up, w_up.data.reshape(held, r_sz, i_sz), sizes),
+                "moe_up")
+            hidden = _gated_held(gated, (gate, up), n_real) if tiled \
+                else gated(gate, up)
         out = checkpoint_name(grouped_dot(
-            hidden, w_down.data.reshape(held, i_sz, f_sz), sizes), "moe_down")
+            hidden, w_down.data.reshape(held, i_sz, r_sz), sizes), "moe_down")
     with jax.named_scope("combine"):
         out = _combine_held(out, weights, order, n_real, slots) if tiled \
             else _combine(out, weights, order, inverse, slots, real)
+    if latent:
+        out = checkpoint_name(out, LATENT_SUM)
+        with jax.named_scope("latent_up"):
+            out = _dense(out, w_latent_up, (r_sz, f_sz))
     if "shared_expert" in args.name_extras:
         with jax.named_scope("shared"):
-            out = out + _shared_expert(args, act, xf, anon, inter, feats)
+            wide = [Dim("shared_intermediate", params.shared_expert_width)] \
+                if params.shared_expert_width else inter
+            out = out + _shared_expert(args, act, xf, anon, wide, feats,
+                                       plain)
     out = out.reshape([d.size for d in token_dims + feats])
     return transpose_to(nt(out, token_dims + feats), x.dims)
 
@@ -738,9 +922,11 @@ def router_carry_bytes(params) -> int:
 
 
 def _offer(params, extras) -> Offer:
-    """The experts kind, a layer: the three grouped matmuls' outputs — gate
-    and up ``[pairs, intermediate]``, down ``[pairs, features]``, in the
-    calculation dtype — the routing triple (``order`` and ``inverse``
+    """The experts kind, a layer: the grouped matmuls' outputs — gate (none
+    under flag ``plain``) and up ``[pairs, intermediate]``, down ``[pairs,
+    features]``, in the calculation dtype; under flag ``latent`` the
+    combined sum ``[tokens, moe_latent_width]`` in their place
+    (``LATENT_SUM``) — the routing triple (``order`` and ``inverse``
     ``[pairs]``, ``sizes`` ``[experts]``, int32) and the router's choice
     (``experts`` ``[tokens, moe_top_k]``, int32), ``pairs = tokens x
     min(moe_top_k, experts)``: ``SAVED_NAMES``.  A layer that holds a share
@@ -753,12 +939,19 @@ def _offer(params, extras) -> Offer:
     choices = params.batch_dim.size * params.sequence_dim.size \
         * min(params.moe_top_k, params.expert_dim.size)
     pairs = held_rows or choices
-    width = 2 * math.prod(d.size for d in params.expert_intermediate) \
-        + math.prod(d.size for d in params.feature_dims)
     groups = params.experts_held + 1 if held_rows else params.expert_dim.size
-    return Offer("experts", SAVED_NAMES,
-                 pairs * width * jnp.dtype(params.calculation_dtype).itemsize
-                 + (2 * pairs + groups + choices) * 4)
+    routing = (2 * pairs + groups + choices) * 4
+    itemsize = jnp.dtype(params.calculation_dtype).itemsize
+    if "latent" in extras:
+        return Offer("experts", SAVED_NAMES[3:] + (LATENT_SUM,),
+                     params.batch_dim.size * params.sequence_dim.size
+                     * params.moe_latent_width * itemsize + routing)
+    # a plain expert has no gate
+    width = (1 if "plain" in extras else 2) \
+        * math.prod(d.size for d in params.expert_intermediate) \
+        + math.prod(d.size for d in params.feature_dims)
+    return Offer("experts", SAVED_NAMES["plain" in extras:],
+                 pairs * width * itemsize + routing)
 
 
 moe.declares = Layer(
@@ -803,6 +996,17 @@ moe.declares = Layer(
              "the newest finished step that hold a share of the experts",
              lambda stats, done: done["moe_held_row_tiles"]
              / jnp.sum(stats["moe_held_bound_tiles"]), "moe_held_row_tiles"),
+        # flag sigmoid_bias: how far the selection bias has moved, and the
+        # load it answers — over ALL the experts, not the held ones
+        Stat("moe_bias_abs_max", "gauge", "hbnlp_moe_bias_abs_max",
+             "largest |selection bias| of a sigmoid_bias moe layer, the "
+             "layer where it is largest (moe_bias_rate x steps at most)",
+             "max"),
+        Stat("moe_all_load_max_over_mean", "gauge",
+             "hbnlp_moe_all_load_max_over_mean",
+             "pairs of the busiest of ALL the experts over their mean, worst "
+             "sigmoid_bias moe layer of the newest finished step: what the "
+             "selection bias's rule pulls towards 1", "max"),
         # the layer whose router says least
         Stat("moe_top1_weight_mean", "gauge", "hbnlp_moe_top1_weight_mean",
              "mean probability of the chosen expert over the tokens of the "

@@ -40,7 +40,8 @@ class Recurrent(typing.NamedTuple):
     None = none.
     A layer whose chunked scan can be ``parallel/ssd_scan.py``'s pair declares
     its shapes: ``scan(params)`` — ``(sequence, chunk, heads, head features,
-    state)`` as ``ssd_kernel_applies`` takes them (``mamba``); None = none.
+    state, groups)`` as ``ssd_kernel_applies`` takes them round its backend
+    (``mamba``); None = none.
 
     A layer that re-materialises its own interior in the backward also OFFERS
     ITS OUTPUT to the ``checkpoint`` strategy (its ``declares.offer``, kind
@@ -122,7 +123,9 @@ def _shape_kernel_layers(params: ModelParameter, field: str, applies,
               if getattr(spec, field) is not None]
     if not shapes:
         return None
-    return params.depth * sum(applies(*each, backend) for each in shapes)
+    # what a layer declares past the five shapes follows the backend
+    return params.depth * sum(applies(*each[:5], backend, *each[5:])
+                              for each in shapes)
 
 
 def scan_kernel_layers(params: ModelParameter, backend=None

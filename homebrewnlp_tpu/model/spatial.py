@@ -550,7 +550,8 @@ def _standard_attention(args: BlockArgs) -> NamedTensor:
     ``rope`` (``rope_theta``) and none under ``nope`` (Granite 4.0-H: the
     Mamba layers carry the order), causal ``softmax(scale q k^T) v`` with
     ``scale = attention_scale`` (0 = ``features_per_head ** -0.5``) through
-    the flash kernel, and an output projection.  Weights are normal(0.02).
+    the flash kernel, and an output projection.  Weights are normal(0.02),
+    the output projection's normal(``residual_out_stddev``) where that is set.
 
     A layer's own head counts: ``q_heads<n>-kv_heads<m>`` project the query
     to ``n`` heads and key and value to ``m`` of ``features_per_head`` each,
@@ -681,7 +682,7 @@ def _standard_attention(args: BlockArgs) -> NamedTensor:
                 gate.data.astype(jnp.float32)).astype(out.dtype), gate.dims)
     return project(args, transpose_to(
         out_nt, [d for d in args.tensor.dims if d not in feats] + q_feats),
-        feats, q_feats)
+        feats, q_feats, stddev=args.params.residual_out_stddev or 0.02)
 
 
 def flash_offer(params, heads: int, window: typing.Optional[int] = None

@@ -50,6 +50,38 @@ def is_large_tensor(params: ModelParameter, name: str,
     return bool(large)
 
 
+def selection_bias_rule(bias, counts, rate: float):
+    """DeepSeek-V3's auxiliary-loss-free balancing (arXiv:2412.19437 section
+    2.1.2): ``b_e <- b_e + rate x sign(mean(c) - c_e)`` with ``counts`` the
+    step's (token, choice) pairs of every expert of the layer (the last
+    axis), as the backward of model/moe.py ``_balance_tap`` hands them over:
+    an expert above the mean load is chosen less next step, one below it
+    more."""
+    counts = counts.astype(jnp.float32)
+    return (bias.astype(jnp.float32) + rate * jnp.sign(
+        jnp.mean(counts, axis=-1, keepdims=True) - counts)).astype(bias.dtype)
+
+
+#: the scope layer ``moe`` makes its selection bias under (model/moe.py
+#: imports it from here: ``optim/`` lies below ``model/``)
+SELECTION_BIAS = "selection_bias"
+
+#: the mark in its name -> the rule ``(params, value, handed) -> value`` of a
+#: leaf that is no weight of the loss: the step moves it from what the
+#: backward hands it in the place of a gradient — outside the chain, the
+#: clip's global norm, the weight decay and the slots
+OWN_RULES = {
+    SELECTION_BIAS: lambda params, bias, counts: selection_bias_rule(
+        bias, counts, params.moe_bias_rate),
+}
+
+
+def own_rule(name: str):
+    """The rule of its own that moves the leaf ``name``, or None."""
+    return next((rule for mark, rule in OWN_RULES.items() if mark in name),
+                None)
+
+
 def parse_chain(optimizer: str) -> typing.List[typing.Tuple[str, typing.Tuple[str, ...]]]:
     chain = []
     for member in optimizer.split("-"):
@@ -91,6 +123,10 @@ class Optimizer:
         opt_dtype = self.params.optimizer_slice_dtype
         calc = self.params.optimizer_calculation_dtype
         for name, value in variables.items():
+            if own_rule(name):
+                state[name] = {}
+                continue
+
             def _shapes(shape=value.shape):
                 ctx = VarCtx(name=name,
                              grad=jnp.zeros(shape, calc),
@@ -126,12 +162,18 @@ class Optimizer:
         global_norm_recip = None
         if self._needs_global_norm:
             clip = next(float(a[0]) for n, a in self.chain if n == "global_l2norm_clip")
-            total = sum(jnp.sum(jnp.square(g.astype(calc))) for g in grads.values())
+            total = sum(jnp.sum(jnp.square(g.astype(calc)))
+                        for name, g in grads.items() if not own_rule(name))
             global_norm_recip = jax_rsqrt(jnp.maximum(total, clip ** -2))
 
         new_vars: Params = {}
         new_state: OptState = {}
         for name, value in variables.items():
+            rule = own_rule(name)
+            if rule is not None:
+                new_vars[name] = rule(p, value, grads[name])
+                new_state[name] = {}
+                continue
             grad = grads[name].astype(calc)
             ctx = VarCtx(name=name, grad=grad, value=value.astype(calc),
                          slots=state.get(name, {}), new_slots={},

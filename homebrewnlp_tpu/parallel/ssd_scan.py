@@ -55,6 +55,16 @@ Precision is the XLA form's: matmul operands in the calculation dtype with
 float32 accumulation, everything else float32; ``dM`` and ``dscores`` are not
 rounded on their way (autodiff rounds both).
 
+Groups of ``B`` / ``C`` (``[b, s, groups, n]``; ``[b, s, n]`` = one group,
+today's graph): head ``h`` reads group ``h // (heads / groups)``.  A head
+block lies inside ONE group (``head_block`` of the group's heads), so the
+``B`` / ``C`` blocks of a grid step are its group's ``n`` columns of ``[b, s,
+groups * n]``; ``scores`` are made once a GROUP and chunk, at the group's first
+head block — not once a head —, the ``dscores`` accumulator starts there and
+that group's ``dB`` / ``dC`` blocks, summed over its heads in float32 and
+revisited on its consecutive steps, are finished at its last block.  The
+state scratch stays ``[heads, p, n]`` for all heads.
+
 Dispatch (``ssd_kernel_applies``): the one predicate the layer and the
 ``hbnlp_ssd_scan_kernel_layers`` gauge both read.  Off the TPU and at shapes
 it declines ``model/mamba.py ssd``'s XLA form runs: the kernels' oracle.
@@ -73,10 +83,11 @@ _BLOCK_ROWS = 512      # heads x head features a grid step
 _VMEM_LIMIT = 64 << 20
 
 
-def head_block(heads: int, head_features: int) -> int:
-    """Heads a grid step: the largest divisor of ``heads`` whose rows fit
-    ``_BLOCK_ROWS`` and fill whole sublane tiles of the float32 ``dt`` / ``a``
-    rows (a multiple of 8), else all the heads."""
+def head_block(heads: int, head_features: int, groups: int = 1) -> int:
+    """Heads a grid step: the largest divisor of ONE GROUP's heads whose rows
+    fit ``_BLOCK_ROWS`` and fill whole sublane tiles of the float32 ``dt`` /
+    ``a`` rows (a multiple of 8), else all the group's heads."""
+    heads //= groups
     for hb in range(min(heads, max(1, _BLOCK_ROWS // head_features)), 0, -1):
         if heads % hb == 0 and hb % 8 == 0:
             return hb
@@ -85,17 +96,22 @@ def head_block(heads: int, head_features: int) -> int:
 
 def ssd_kernel_applies(sequence: int, chunk: int, heads: int,
                        head_features: int, state: int,
-                       backend: typing.Optional[str] = None) -> bool:
+                       backend: typing.Optional[str] = None,
+                       groups: int = 1) -> bool:
     """Whether ``ssd_scan`` runs these shapes here: a TPU backend, whole
     chunks of whole lane tiles (the sequence is on the lanes) no longer than
     ``_MAX_CHUNK``, a state of whole lane tiles, head features in whole
-    sublane tiles of a 16-bit operand (16).  Pure in its arguments but for
-    the backend's default."""
+    sublane tiles of a 16-bit operand (16); with more than one group of
+    ``B`` / ``C``, whole groups whose head blocks fill whole sublane tiles of
+    the ``dt`` / ``a`` rows (8).  Pure in its arguments but for the backend's
+    default."""
     if backend is None:
         backend = jax.default_backend()
     return (backend == "tpu" and 0 < chunk <= _MAX_CHUNK
             and chunk % _LANE == 0 and sequence % chunk == 0
-            and state % _LANE == 0 and head_features % 16 == 0 and heads > 0)
+            and state % _LANE == 0 and head_features % 16 == 0 and heads > 0
+            and (groups == 1 or (heads % groups == 0 and head_block(
+                heads, head_features, groups) % 8 == 0)))
 
 
 def log_decay(dt, a, chunk: int):
@@ -126,16 +142,37 @@ def _head(i, k, hb: int, p: int, a_ref, dt_ref, acols, lanes):
     a_row, dt_row = a_ref[pl.ds(i, 1), :], dt_ref[pl.ds(i, 1), :]
     a_col = jnp.sum(jnp.where(lanes == index, acols, 0.0), axis=1,
                     keepdims=True)
-    return index, rows, a_row, dt_row, a_col, a_row[:, -1:]
+    l = a_row.shape[1]
+    if l > _LANE:
+        return index, rows, a_row, dt_row, a_col, a_row[:, -1:]
+    # a chunk of ONE lane tile: Mosaic cannot broadcast the [1, 1] slice at
+    # lane 127 over sublanes and lanes ("Broadcast in both sublanes and
+    # lanes", compiled for a described v5e); a masked lane sum leaves the
+    # same value at lane 0
+    last = jax.lax.broadcasted_iota(jnp.int32, (1, l), 1) == l - 1
+    return index, rows, a_row, dt_row, a_col, jnp.sum(
+        jnp.where(last, a_row, 0.0), axis=1, keepdims=True)
+
+
+def _first(k, per_group: int):
+    """Whether head block ``k`` is the first of its group of ``per_group``
+    blocks (0: one group, all the layer's blocks)."""
+    return k % per_group == 0 if per_group else k == 0
+
+
+def _last(k, per_group: int):
+    from jax.experimental import pallas as pl
+    return k % per_group == per_group - 1 if per_group \
+        else k == pl.num_programs(2) - 1
 
 
 def _fwd_kernel(x_ref, dt_ref, a_ref, acol_ref, b_ref, ct_ref, y_ref, st_ref,
-                state, scores_t, *, hb: int, p: int):
+                state, scores_t, *, hb: int, p: int, per_group: int):
     from jax.experimental import pallas as pl
     c, k = pl.program_id(1), pl.program_id(2)
     l, dtype = x_ref.shape[1], x_ref.dtype
 
-    @pl.when(k == 0)
+    @pl.when(_first(k, per_group))
     def _shared():
         scores_t[...] = _dot(b_ref[...], ct_ref[...])           # [j, i]
 
@@ -168,16 +205,17 @@ def _fwd_kernel(x_ref, dt_ref, a_ref, acol_ref, b_ref, ct_ref, y_ref, st_ref,
 
 def _bwd_kernel(x_ref, dt_ref, a_ref, acol_ref, b_ref, bt_ref, c_ref, ct_ref,
                 y_ref, g_ref, st_ref, dx_ref, ddt_ref, da_ref, db_ref,
-                dbt_ref, dc_ref, dstate, scores, dscores, *, hb: int, p: int):
+                dbt_ref, dc_ref, dstate, scores, dscores, *, hb: int, p: int,
+                per_group: int):
     """Grid step ``(b, c, k)`` holds chunk ``chunks - 1 - c``.  ``db_ref [l,
-    n]`` takes the state terms of ``dB``, ``dbt_ref [n, l]`` the ``dscores``
-    one (transposed: ``C^T dscores`` is a plain matmul); the caller adds
-    them."""
+    n]`` takes the state terms of the group's ``dB``, ``dbt_ref [n, l]`` the
+    ``dscores`` one (transposed: ``C^T dscores`` is a plain matmul); the
+    caller adds them."""
     from jax.experimental import pallas as pl
     c, k = pl.program_id(1), pl.program_id(2)
     l, dtype = x_ref.shape[1], x_ref.dtype
 
-    @pl.when(k == 0)
+    @pl.when(_first(k, per_group))
     def _shared():
         scores[...] = _dot(c_ref[...], bt_ref[...])              # [i, j]
         dscores[...] = jnp.zeros_like(dscores)
@@ -231,7 +269,7 @@ def _bwd_kernel(x_ref, dt_ref, a_ref, acol_ref, b_ref, bt_ref, c_ref, ct_ref,
 
     jax.lax.fori_loop(0, hb, head, None)
 
-    @pl.when(k == pl.num_programs(2) - 1)
+    @pl.when(_last(k, per_group))
     def _shared_out():
         d = dscores[...].astype(dtype)
         dc_ref[...] += _dot(d, b_ref[...])
@@ -239,21 +277,26 @@ def _bwd_kernel(x_ref, dt_ref, a_ref, acol_ref, b_ref, bt_ref, c_ref, ct_ref,
 
 
 def _specs(hb: int, p: int, l: int, h: int, n: int, chunks: int,
-           reverse: bool):
+           reverse: bool, per_group: int = 0):
     """Block specs on grid (batch, chunk step, head block): the ``[b, heads *
     p, s]`` tile, the ``[b, heads, s]`` rows, ``[b, s, heads]`` /  ``[b, s,
-    n]`` columns, ``[b, n, s]`` rows and the ``[b, chunks, heads, p, n]``
-    states; ``reverse`` walks the chunks from the last."""
+    groups * n]`` columns, ``[b, groups * n, s]`` rows — the ``n`` of the head
+    block's group: ``per_group`` blocks a group, 0 = one group — and the
+    ``[b, chunks, heads, p, n]`` states; ``reverse`` walks the chunks from
+    the last."""
     from jax.experimental import pallas as pl
 
     def at(c):
         return chunks - 1 - c if reverse else c
 
+    def group(k):
+        return k // per_group if per_group else 0
+
     tile = pl.BlockSpec((None, hb * p, l), lambda b, c, k: (b, k, at(c)))
     rows = pl.BlockSpec((None, hb, l), lambda b, c, k: (b, k, at(c)))
     heads = pl.BlockSpec((None, l, h), lambda b, c, k: (b, at(c), 0))
-    cols = pl.BlockSpec((None, l, n), lambda b, c, k: (b, at(c), 0))
-    cols_t = pl.BlockSpec((None, n, l), lambda b, c, k: (b, 0, at(c)))
+    cols = pl.BlockSpec((None, l, n), lambda b, c, k: (b, at(c), group(k)))
+    cols_t = pl.BlockSpec((None, n, l), lambda b, c, k: (b, group(k), at(c)))
     states = pl.BlockSpec((None, None, hb, p, n),
                           lambda b, c, k: (b, at(c), k, 0, 0))
     return tile, rows, heads, cols, cols_t, states
@@ -267,20 +310,32 @@ def _params():
         vmem_limit_bytes=_VMEM_LIMIT)
 
 
+def _blocks_a_group(heads: int, hb: int, groups: int) -> int:
+    """Head blocks a group of ``B`` / ``C``; 0 stands for one group."""
+    if groups == 1:
+        return 0
+    if heads % groups or (heads // groups) % hb:
+        raise ValueError(f"a block of {hb} heads does not lie inside one of "
+                         f"{groups} groups of {heads} heads")
+    return heads // groups // hb
+
+
 # jitted so that a model traces each kernel once, not once a layer and pass
-@functools.partial(jax.jit, static_argnums=(6, 7, 8))
-def _fwd_impl(xt, dt, a, a_cols, b_mat, ct, chunk, hb, interpret):
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9))
+def _fwd_impl(xt, dt, a, a_cols, b_mat, ct, chunk, hb, interpret, groups=1):
     """``xt [b, heads * p, s]``, ``dt`` / ``a [b, heads, s]``, ``a_cols [b,
-    s, heads]``, ``b_mat [b, s, n]``, ``ct [b, n, s]`` -> ``(y^T [b, heads *
-    p, s]`` float32, entering states ``[b, chunks, heads, p, n]``)``."""
+    s, heads]``, ``b_mat [b, s, groups * n]``, ``ct [b, groups * n, s]`` ->
+    ``(y^T [b, heads * p, s]`` float32, entering states ``[b, chunks, heads,
+    p, n]``)``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    (bsz, rows_, s), h, n = xt.shape, dt.shape[1], b_mat.shape[-1]
+    (bsz, rows_, s), h, n = xt.shape, dt.shape[1], b_mat.shape[-1] // groups
     p, chunks = rows_ // h, s // chunk
-    tile, rows, heads, cols, cols_t, states = _specs(hb, p, chunk, h, n,
-                                                     chunks, False)
+    per_group = _blocks_a_group(h, hb, groups)
+    tile, rows, heads, cols, cols_t, states = _specs(
+        hb, p, chunk, h, n, chunks, False, per_group)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, hb=hb, p=p),
+        functools.partial(_fwd_kernel, hb=hb, p=p, per_group=per_group),
         grid=(bsz, chunks, h // hb),
         in_specs=[tile, rows, rows, heads, cols, cols_t],
         out_specs=[tile, states],
@@ -294,21 +349,22 @@ def _fwd_impl(xt, dt, a, a_cols, b_mat, ct, chunk, hb, interpret):
     )(xt, dt, a, a_cols, b_mat, ct)
 
 
-@functools.partial(jax.jit, static_argnums=(11, 12, 13))
+@functools.partial(jax.jit, static_argnums=(11, 12, 13, 14))
 def _bwd_impl(xt, dt, a, a_cols, b_mat, bt, c_mat, ct, yt, gt, entering,
-              chunk, hb, interpret):
+              chunk, hb, interpret, groups=1):
     """-> ``(dx^T`` in ``xt``'s dtype, ``ddt`` and ``da [b, heads, s]``
-    float32, ``dB``'s state terms ``[b, s, n]``, its ``dscores`` term ``[b,
-    n, s]``, ``dC [b, s, n]``, float32)``."""
+    float32, ``dB``'s state terms ``[b, s, groups * n]``, its ``dscores``
+    term ``[b, groups * n, s]``, ``dC [b, s, groups * n]``, float32)``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    (bsz, _, s), h, n = xt.shape, dt.shape[1], b_mat.shape[-1]
+    (bsz, _, s), h, n = xt.shape, dt.shape[1], b_mat.shape[-1] // groups
     p, chunks = xt.shape[1] // h, s // chunk
-    tile, rows, heads, cols, cols_t, states = _specs(hb, p, chunk, h, n,
-                                                     chunks, True)
+    per_group = _blocks_a_group(h, hb, groups)
+    tile, rows, heads, cols, cols_t, states = _specs(
+        hb, p, chunk, h, n, chunks, True, per_group)
     f32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32)
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, hb=hb, p=p),
+        functools.partial(_bwd_kernel, hb=hb, p=p, per_group=per_group),
         grid=(bsz, chunks, h // hb),
         in_specs=[tile, rows, rows, heads, cols, cols_t, cols, cols_t, tile,
                   tile, states],
@@ -330,11 +386,24 @@ def _sequence_minor(x):
     return jnp.swapaxes(x.reshape(x.shape[:2] + (-1,)), 1, 2)
 
 
+def _groups(b_mat) -> int:
+    """Groups of a ``B`` / ``C`` operand: ``[b, s, n]`` is one."""
+    return b_mat.shape[2] if b_mat.ndim == 4 else 1
+
+
+def _flat(mat):
+    """``[b, s, groups, n]`` -> ``[b, s, groups * n]``; ``[b, s, n]`` as it
+    is."""
+    return mat.reshape(mat.shape[:2] + (-1,)) if mat.ndim == 4 else mat
+
+
 def _forward(x, dt, a_cum, b_mat, c_mat, chunk, hb, interpret):
-    hb = hb or head_block(*x.shape[2:])
+    groups = _groups(b_mat)
+    hb = hb or head_block(*x.shape[2:], groups)
     yt, entering = _fwd_impl(
         _sequence_minor(x), jnp.swapaxes(dt, 1, 2), jnp.swapaxes(a_cum, 1, 2),
-        a_cum, b_mat, jnp.swapaxes(c_mat, 1, 2), chunk, hb, interpret)
+        a_cum, _flat(b_mat), jnp.swapaxes(_flat(c_mat), 1, 2), chunk, hb,
+        interpret, groups)
     return jnp.swapaxes(yt, 1, 2).reshape(x.shape), entering
 
 
@@ -346,8 +415,8 @@ def ssd_scan(x, dt, a_cum, b_mat, c_mat, chunk: int,
     x`` skip) from ``x [b, s, heads, p]``, float32 ``dt`` and ``a_cum [b, s,
     heads]`` (``a_cum``: ``log_decay``, the cumulative ``dt A`` from each
     chunk's start),
-    ``b_mat`` / ``c_mat [b, s, n]``; shapes as ``ssd_kernel_applies``
-    accepts them."""
+    ``b_mat`` / ``c_mat [b, s, n]`` (one group for all heads) or ``[b, s,
+    groups, n]``; shapes as ``ssd_kernel_applies`` accepts them."""
     return _forward(x, dt, a_cum, b_mat, c_mat, chunk, heads_a_block,
                     interpret)[0]
 
@@ -359,18 +428,21 @@ def _vjp_fwd(x, dt, a_cum, b_mat, c_mat, chunk, hb, interpret):
 
 def _vjp_bwd(chunk, hb, interpret, res, g):
     x, dt, a_cum, b_mat, c_mat, y, entering = res
-    hb = hb or head_block(*x.shape[2:])
+    groups = _groups(b_mat)
+    hb = hb or head_block(*x.shape[2:], groups)
+    b_flat, c_flat = _flat(b_mat), _flat(c_mat)
     dxt, ddt, da, db, dbt, dc = _bwd_impl(
         _sequence_minor(x), jnp.swapaxes(dt, 1, 2), jnp.swapaxes(a_cum, 1, 2),
-        a_cum, b_mat, jnp.swapaxes(b_mat, 1, 2), c_mat,
-        jnp.swapaxes(c_mat, 1, 2), _sequence_minor(y),
+        a_cum, b_flat, jnp.swapaxes(b_flat, 1, 2), c_flat,
+        jnp.swapaxes(c_flat, 1, 2), _sequence_minor(y),
         _sequence_minor(g.astype(jnp.float32)), entering, chunk, hb,
-        interpret)
+        interpret, groups)
     return (jnp.swapaxes(dxt, 1, 2).reshape(x.shape),
             jnp.swapaxes(ddt, 1, 2).astype(dt.dtype),
             jnp.swapaxes(da, 1, 2).astype(a_cum.dtype),
-            (db + jnp.swapaxes(dbt, 1, 2)).astype(b_mat.dtype),
-            dc.astype(c_mat.dtype))
+            (db + jnp.swapaxes(dbt, 1, 2)).astype(b_mat.dtype
+                                                  ).reshape(b_mat.shape),
+            dc.astype(c_mat.dtype).reshape(c_mat.shape))
 
 
 ssd_scan.defvjp(_vjp_fwd, _vjp_bwd)

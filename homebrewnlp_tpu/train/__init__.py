@@ -28,7 +28,7 @@ from ..config import ModelParameter
 from ..core import sharding as shardlib
 from ..model import Model, declare
 from ..model.remat import stash_line, stash_plan
-from ..optim import Optimizer
+from ..optim import Optimizer, own_rule
 from ..optim.gradients import MULTI_LOSS_GRADIENTS
 from ..telemetry import memory
 
@@ -53,6 +53,8 @@ def _info_metrics(info) -> typing.Dict[str, jax.Array]:
 
 
 def _grad_norm_metrics(grads: Params, debug: bool) -> typing.Dict[str, jax.Array]:
+    # a leaf with a rule of its own (optim/__init__.py) holds no gradient
+    grads = {k: g for k, g in grads.items() if not own_rule(k)}
     extra = {}
     if debug:
         # per-variable gradient norms (the reference's --debug_grad

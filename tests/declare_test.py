@@ -85,6 +85,21 @@ _CELLS = {
     # [2, 16, 4096] float32).  The nine lines above stand as they were
     "train_ouro_2_6b_loop4_s4k": (
         _kinds(attention=(48, 1635778560), unit="executions"), "", {}),
+    # PR 54: five grouped Mamba-2 layers' chunk states ([1, 128, 64, 64, 128]
+    # float32 a layer) and the static row buffer of five LatentMoE layers,
+    # whose offer is the combined sum a token ([16384, 1024] bfloat16) with
+    # the routing triple and the choice, 36,044,836 bytes a layer (the pairs'
+    # rows, 131,072 x (2,688 + 1,024) a layer, would pass the budget); the
+    # one attention layer's (out, lse) rides after it.  The ten lines above
+    # stand
+    "train_nemotron_3_super_tp2_ep64_s16k": (
+        _kinds(attention=(1, 68157440), experts=(5, 180224180)),
+        "; ssd chunk states 268435456 bytes a device; conv kernel 5 layers; "
+        "scan kernel 5 layers; moe held rows bound 131072",
+        {"hbnlp_ssd_state_bytes": 268435456,
+         "hbnlp_mamba_conv_kernel_layers": 5,
+         "hbnlp_ssd_scan_kernel_layers": 5,
+         "hbnlp_moe_held_rows_bound": 131072}),
 }
 #: the facts that read 0 where no layer has the mechanism; the others have no
 #: series there
@@ -154,8 +169,10 @@ def _config_files():
 #: ``dense``, to every file's line and two gauges — 0 layers and 0 bytes in
 #: every file but the three cells' ``benchmark/configs/`` files of granite (6,
 #: 1610612736), MiniCPM-SALA (1, 1073741824) and Olmo-Hybrid (1, 721420288):
-#: before it 5221896c3d024205a9d2196fd56b63c8840405ae)
-_FILE_DIGEST = "822c534a7685509a587d4690f8021ccd1e6099de"
+#: before it 5221896c3d024205a9d2196fd56b63c8840405ae; PR 54 added the two
+#: Nemotron-3-Super files: without them the digest is PR 52's
+#: 822c534a7685509a587d4690f8021ccd1e6099de, every other line as it was)
+_FILE_DIGEST = "8b03ac467bb6c441d9ab0b271ec8f57775930b9f"
 
 
 def every_configuration_file_starts_as_on_the_parent_test(monkeypatch):
@@ -251,10 +268,13 @@ def declared_statistic_folds_as_on_the_parent_test(name, kind, metric, text,
 
 
 def statistics_are_all_declared_test():
-    """The trainer's table is the declarations': fourteen statistics of
-    layers and (PR 49) three of a looped model's loss, and a step whose
-    layers report nothing (or only some) has only those."""
-    assert len(_LAYER_STATS) == 17 == len(declare.stats())
+    """The trainer's table is the declarations': sixteen statistics of
+    layers (PR 54: the selection bias's two) and (PR 49) three of a looped
+    model's loss, and a step whose layers report nothing (or only some) has
+    only those."""
+    assert len(_LAYER_STATS) == 19 == len(declare.stats())
+    assert {"moe_bias_abs_max", "moe_all_load_max_over_mean"} \
+        <= set(_LAYER_STATS)
     assert {name for name in _LAYER_STATS if name.startswith("loop_")} == {
         "loop_pass_loss", "loop_exit_share", "loop_exit_entropy"}
     base = {"loss", "token_loss", "video_loss", "accuracy"}
@@ -299,6 +319,14 @@ def facts_are_declared_once_in_line_order_test():
 @pytest.mark.parametrize("layer,kind,names", [
     ("moe", "experts", ("moe_gate", "moe_up", "moe_down", "moe_order",
                         "moe_inverse", "moe_sizes", "moe_experts")),
+    # PR 54: a plain expert has no gate to save; a latent layer offers its
+    # combined sum a token in the place of the pairs' rows
+    ("moe-relu2-plain", "experts",
+     ("moe_up", "moe_down", "moe_order", "moe_inverse", "moe_sizes",
+      "moe_experts")),
+    ("moe-relu2-plain-latent-sigmoid_bias-shared_expert", "experts",
+     ("moe_order", "moe_inverse", "moe_sizes", "moe_experts",
+      "moe_latent_sum")),
     ("gated_delta", "recurrent", ("gated_delta_out",)),
     ("attention-nope", "attention", ("flash_out", "flash_lse")),
     ("cca-q_heads8-kv_heads2", "attention", ("flash_out", "flash_lse")),

@@ -447,6 +447,28 @@ def scan_kernels_keep_their_scope_and_names_test(v5e, monkeypatch):
                              .split("(")[0]), line
 
 
+@pytest.mark.parametrize("groups", [1, 4])
+def scan_pair_compiles_at_a_chunk_of_one_lane_tile_test(v5e, groups):
+    """PR 54: Mosaic accepts the scan pair at Nemotron-3's shapes — 64 heads
+    of 64, a state of 128, a chunk of ONE lane tile (the last lane's decay is
+    a masked lane sum there, ``_head``), and ``B`` / ``C`` in 4 groups whose
+    two head blocks each share one ``scores`` tile — as at one group."""
+    from homebrewnlp_tpu.parallel import ssd_scan as sk
+    b, s, h, p, n, chunk = 1, 1024, 64, 64, 128, 128
+    assert sk.ssd_kernel_applies(s, chunk, h, p, n, "tpu", groups)
+    assert sk.head_block(h, p, groups) == 8
+    cols = (b, s, groups, n) if groups > 1 else (b, s, n)
+    avals = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+             for shape, dtype in (((b, s, h, p), jnp.bfloat16),
+                                  ((b, s, h), jnp.float32),
+                                  ((b, s, h), jnp.float32),
+                                  (cols, jnp.bfloat16), (cols, jnp.bfloat16))]
+    hlo = jax.jit(jax.value_and_grad(
+        lambda *a: jnp.sum(sk.ssd_scan(*a, chunk)), argnums=(0, 1, 2, 3, 4))
+    ).lower(*avals).compile().as_text()
+    assert "ssd_scan_fwd" in hlo and "ssd_scan_bwd" in hlo
+
+
 def delta_layers_kernels_keep_their_scopes_test(v5e, monkeypatch):
     """One ``gated_delta`` layer at Olmo-Hybrid's published widths, 1 x 8,192
     tokens (half the cell's), compiled for a v5e as a TPU process traces it:
@@ -753,7 +775,8 @@ def step_reports_the_log_decay_watch_test(kernel, monkeypatch):
     from homebrewnlp_tpu.parallel import ssd_scan
     from homebrewnlp_tpu.train import Trainer
     if kernel:
-        monkeypatch.setattr(mamba_mod, "ssd_kernel_applies", lambda *_: True)
+        monkeypatch.setattr(mamba_mod, "ssd_kernel_applies",
+                            lambda *_, **__: True)
         monkeypatch.setattr(mamba_mod, "ssd_scan", functools.partial(
             ssd_scan.ssd_scan, interpret=True))
     _, params, model, batch, _ = _build(

@@ -318,7 +318,7 @@ def _held_part(x, weights, experts, mats, first, held, dense: bool):
     gate = moe_mod.grouped_dot(rows if dense else rows[0], mats[0], sizes)
     up = moe_mod.grouped_dot(rows if dense else rows[1], mats[1], sizes)
     hidden = gated(gate, up) if dense \
-        else moe_mod._gated_held(gated, gate, up, n_real)
+        else moe_mod._gated_held(gated, (gate, up), n_real)
     out = moe_mod.grouped_dot(hidden, mats[2], sizes)
     if not dense:
         return moe_mod._combine_held(out, w, order, n_real, slots)

@@ -143,7 +143,8 @@ def head_block_divides_the_heads_test(heads, p, block):
 def _steer(monkeypatch):
     """The layer as a TPU process at kernel shapes would trace it, the
     kernels interpreted."""
-    monkeypatch.setattr(mamba_mod, "ssd_kernel_applies", lambda *_: True)
+    monkeypatch.setattr(mamba_mod, "ssd_kernel_applies",
+                        lambda *_, **__: True)
     monkeypatch.setattr(mamba_mod, "ssd_scan", functools.partial(
         sk.ssd_scan, heads_a_block=2, interpret=True))
 
@@ -174,7 +175,7 @@ def scan_fact_counts_the_layers_test():
             "sequence_length": 256}
     _, params, _, _, _ = _build("bfloat16", **wide)
     assert mamba_mod.mamba.declares.recurrent.scan(params) \
-        == (256, 128, 4, 64, 128)
+        == (256, 128, 4, 64, 128, 1)
     assert recurrent.scan_kernel_layers(params, "tpu") == 9
     assert recurrent.scan_kernel_layers(params) == 0
     short = _build("bfloat16", **{**wide, "sequence_length": 128,
