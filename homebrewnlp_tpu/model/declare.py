@@ -58,15 +58,18 @@ class Fact(typing.NamedTuple):
     the predicate the layer itself calls; None where no layer of the
     configuration has the mechanism — ``fragment`` — its part of the ``remat
     stash:`` line, a format of the value, printed where the value is not None
-    — ``place`` — where on that line — and ``zero`` — whether the gauge reads
-    0 where the value is None (else it has no series)."""
+    — ``place`` — where on that line — ``zero`` — whether the gauge reads
+    0 where the value is None (else it has no series) — and ``label``: where
+    set, the gauge has that one label and the value is ``{label value:
+    number}``, printed as ``<label value> <number>`` pairs."""
     place: int
     metric: str
     help: str
     value: typing.Callable[[ModelParameter, typing.Any, typing.Optional[str]],
-                           typing.Optional[int]]
+                           typing.Union[None, int, typing.Dict[str, float]]]
     fragment: str
     zero: bool = True
+    label: str = ""
 
 
 class Layer(typing.NamedTuple):

@@ -23,7 +23,7 @@ from ..core.tensor import (NamedTensor, cumsum as tensor_cumsum, einsum, exp,
                            less, multiply, range_, reduce_max, reduce_sum,
                            stop_gradient, greater_equal)
 from ..parallel.flash_attention import (SAVED_NAMES, SELECT_NAME,
-                                        band_applies)
+                                        band_applies, scored_over_live)
 from . import decode as decode_mod
 from .basic import activated_linear_in, activated_linear_out
 from .declare import Fact, Layer, Offer, Stat, step_offers
@@ -726,6 +726,16 @@ def _offer(params, extras) -> typing.Optional[Offer]:
     return flash_offer(params, params.head_dim.size)
 
 
+def _reaches_flash_kernels(params, backend=None) -> bool:
+    """Whether ``attention``'s dispatch hands this step's flash calls to the
+    Pallas kernels: ``use_flash_attention``, off the CPU, a sequence of whole
+    128-tiles."""
+    if backend is None:
+        backend = jax.default_backend()
+    return backend != "cpu" and params.use_flash_attention \
+        and params.sequence_dim.size % 128 == 0
+
+
 def flash_band_layers(params, backend=None) -> typing.Optional[int]:
     """How many attention layers of the step run their windowed flash
     FORWARD as the band kernel (``parallel/flash_attention.py _fwd_band``):
@@ -741,13 +751,38 @@ def flash_band_layers(params, backend=None) -> typing.Optional[int]:
             windows += [offer.keys] * times
     if not windows:
         return None
-    if backend is None:
-        backend = jax.default_backend()
-    if backend == "cpu" or not params.use_flash_attention or seq % 128:
+    if not _reaches_flash_kernels(params, backend):
         return 0
     itemsize = np.dtype(params.calculation_dtype).itemsize
     return sum(band_applies(seq, params.key_dim.size, window, itemsize)
                for window in windows)
+
+
+def flash_scored_over_live(params, backend=None
+                           ) -> typing.Optional[typing.Dict[str, float]]:
+    """``{"fwd": .., "bwd": ..}``: the pairs the step's tiled causal flash
+    kernels score over the pairs its calls have to (``parallel/
+    flash_attention.py scored_over_live``: an edge cell is scored as its live
+    part, and what of it is still dead shows here), the WORST layer of each
+    pass — a windowed layer's backward counts, its band forward, which has no
+    such cells, does not.  None where no call reaches those kernels: no layer
+    offers a flash call, the CPU, ``use_flash_attention`` off, a sequence of
+    no whole 128-tiles, a sparse layer past its dense length (the
+    ``flash_*_select`` kernels have their own tables)."""
+    if not _reaches_flash_kernels(params, backend):
+        return None
+    itemsize = np.dtype(params.calculation_dtype).itemsize
+    worst: typing.Dict[str, float] = {}
+    for offer, _ in step_offers(params, "attention"):
+        if SELECT_NAME in offer.names:
+            continue
+        # ``offer.keys``: the window, or the sequence where there is none
+        for name, share in scored_over_live(
+                params.sequence_dim.size, params.key_dim.size, offer.keys,
+                itemsize).items():
+            if share is not None:
+                worst[name] = max(worst.get(name, 0.0), share)
+    return worst or None
 
 
 #: layer ``attention``'s ``declares.facts``
@@ -758,6 +793,12 @@ FACTS = (
          "windowed layer)",
          lambda params, mesh, backend: flash_band_layers(params, backend),
          "flash band {} layers"),
+    Fact(61, "hbnlp_flash_scored_over_live_pairs",
+         "pairs the step's tiled causal flash kernels score over the pairs "
+         "its calls have to, the worst layer of each pass (1.0: only the "
+         "band; no series where no call reaches those kernels)",
+         lambda params, mesh, backend: flash_scored_over_live(params, backend),
+         "flash scored over live pairs {}", zero=False, label="pass"),
 )
 
 

@@ -16,7 +16,13 @@ blockwise pass.  Grid: (batch·heads, q blocks, k blocks) with the
 online-softmax state (m, l, acc) carried in VMEM scratch across the
 innermost k dimension, so VMEM use is O(block) regardless of sequence
 length; causal blocks above the diagonal are skipped via a pl.when
-predicate.  Backward is flash-2's, in pallas under ``jax.custom_vjp``: p is
+predicate, and a block the diagonal CROSSES scores only its live part
+(``_masked_step`` / ``_cell_parts``, PR 55: the forward's cell that starts
+where its k tile of twice the q tile's length starts is one online-softmax
+step over the first half of the keys; a square backward cell is its
+lower-left quadrant mask-free, the two on the diagonal masked, the
+upper-right not scored; a windowed cell's far edge likewise).  Backward is
+flash-2's, in pallas under ``jax.custom_vjp``: p is
 recomputed per block from the saved lse, so training needs neither the O(s²)
 residual nor an O(s²) recompute buffer.  One fused pass gives dq, dk and dv
 while its dq-partial buffer fits the chip (``_use_fused_bwd``); a dq and a
@@ -150,24 +156,171 @@ def _window_split(qi, ki, block_q: int, block_k: int, window: int):
     return live, full
 
 
-def _masked_step(qi, ki, block_q: int, block_k: int, causal: bool, score,
-                 accumulate, dead=None, window=None, valid=None):
+class _Part(typing.NamedTuple):
+    """One rectangle of a cell that a kernel scores: rows ``rows[0] ..
+    rows[1]`` of the q tile against keys ``cols[0] .. cols[1]`` of the k
+    tile; ``causal`` / ``far``: whether the diagonal / the window's far edge
+    crosses it (the comparisons its mask needs; neither = mask-free)."""
+    rows: typing.Tuple[int, int]
+    cols: typing.Tuple[int, int]
+    causal: bool
+    far: bool
+
+    @property
+    def pairs(self) -> int:
+        return (self.rows[1] - self.rows[0]) * (self.cols[1] - self.cols[0])
+
+
+def _half_tile(block_q: int, block_k: int) -> int:
+    """Side of the sub-squares a cell is read as: half the smaller tile's."""
+    return max(min(block_q, block_k) // 2, 1)
+
+
+def _rect_state(off: int, rows, cols, window) -> typing.Tuple[bool, bool, bool]:
+    """``(live, causal, far)`` of a rectangle of a cell whose q tile starts
+    ``off`` positions after its k tile: its pairs are ``off + a - c`` keys
+    back (``a`` a row, ``c`` a column), seen iff ``0 <= back < window``.
+    ``causal`` / ``far``: some pair is ahead of the diagonal / behind the
+    window.  Python ints only."""
+    low = off + rows[0] - (cols[1] - 1)
+    high = off + rows[1] - 1 - cols[0]
+    live = high >= 0 and (window is None or low <= window - 1)
+    return live, low < 0, window is not None and high > window - 1
+
+
+@functools.lru_cache(maxsize=None)
+def _edge_offsets(block_q: int, block_k: int, window) -> typing.Tuple[int, ...]:
+    """Every ``qi * block_q - ki * block_k`` of a live cell that an edge (the
+    diagonal, a window's far side) crosses: the cells ``_causal_split`` /
+    ``_window_split`` call live and not full.  A short static list — the
+    diagonal's offsets lie within a tile of 0, the far edge's within a tile
+    of ``window`` — so a kernel can give each a branch whose slices are
+    static."""
+    step = math.gcd(block_q, block_k)
+    last = block_k if window is None else window + block_k
+    found = []
+    for off in range(-(block_q - step), last, step):
+        live, causal, far = _rect_state(off, (0, block_q), (0, block_k),
+                                        window)
+        if live and (causal or far):
+            found.append(off)
+    return tuple(found)
+
+
+@functools.lru_cache(maxsize=None)
+def _cell_parts(block_q: int, block_k: int, off: int, window,
+                carried: bool) -> typing.Tuple[_Part, ...]:
+    """What a cell at offset ``off`` (``_edge_offsets``) scores, as static
+    rectangles.  The cell is read as sub-squares of half the smaller tile
+    side: each is wholly live, wholly dead, or crossed by an edge.
+
+    A kernel that carries NO softmax state (the three backward kernels: ``p
+    = exp(s - lse)``) walks each band of sub-square rows as runs of like
+    columns — a mask-free run, a masked run, nothing for a dead one — so a
+    quadrant costs its pairs and nothing else: a square diagonal cell is its
+    lower-left quadrant mask-free, the two on the diagonal masked, and the
+    upper-right not scored.
+
+    The forward (``carried``) pays a fixed cost for every online-softmax
+    step a row takes (the rescale of ``m`` / ``l`` / ``acc``; measured in
+    ``attention``'s docstring), so its cell stays ONE masked step, over the
+    bounding rectangle of the sub-squares that are not dead: where the q
+    tile starts where a k tile of twice its length starts, that is the first
+    half of the keys."""
+    half = _half_tile(block_q, block_k)
+    bands = []
+    for r0 in range(0, block_q, half):
+        rows, runs = (r0, r0 + half), []
+        for c0 in range(0, block_k, half):
+            live, causal, far = _rect_state(off, rows, (c0, c0 + half),
+                                            window)
+            if not live:
+                continue
+            last = runs[-1] if runs else None
+            if last is not None and last.cols[1] == c0 \
+                    and (last.causal or last.far) == (causal or far):
+                runs[-1] = last._replace(cols=(last.cols[0], c0 + half),
+                                         causal=last.causal or causal,
+                                         far=last.far or far)
+            else:
+                runs.append(_Part(rows, (c0, c0 + half), causal, far))
+        bands += runs
+    if not carried or not bands:
+        return tuple(bands)
+    rows = (min(p.rows[0] for p in bands), max(p.rows[1] for p in bands))
+    cols = (min(p.cols[0] for p in bands), max(p.cols[1] for p in bands))
+    _, causal, far = _rect_state(off, rows, cols, window)
+    return (_Part(rows, cols, causal, far),)
+
+
+def _part_mask(part: _Part, off, window):
+    """``s -> s`` with the pairs of ``part`` no query sees at ``_NEG_INF``
+    (None for a mask-free part).  ``off`` is the cell's offset: static on an
+    edge branch.  The positions are built where the mask is APPLIED, inside
+    the caller's branch (at the body's top level every grid step would pay
+    for them, the dead cells too), as a column of query positions against a
+    row of key positions: one compare a pair and edge."""
+    if not (part.causal or part.far):
+        return None
+
+    def mask(s):
+        nrows = part.rows[1] - part.rows[0]
+        ncols = part.cols[1] - part.cols[0]
+        # a pair is ``q_pos - k_pos`` keys back
+        q_pos = off + part.rows[0] - part.cols[0] \
+            + jax.lax.broadcasted_iota(jnp.int32, (nrows, 1), 0)
+        k_pos = jax.lax.broadcasted_iota(jnp.int32, (1, ncols), 1)
+        seen = q_pos >= k_pos if part.causal else None
+        if part.far:
+            near = q_pos - (window - 1) <= k_pos
+            seen = near if seen is None else seen & near
+        return jnp.where(seen, s, _NEG_INF)
+    return mask
+
+
+#: the most matmul volume (rows x keys x head width, summed over a body's
+#: branches) a FORWARD body may hold with a branch of its own for every edge
+#: offset: the parent's two whole 1,024 x 2,048 tiles at head width 512, the
+#: largest body measured to run at speed.  Two and a half of them (the third
+#: branch, the first-half cell's) ran the long-context forward 2.17 x SLOWER
+#: on a v5e — 32.78 -> 71.04 ms a call, whatever the VMEM limit — while the
+#: same three branches at width 256 and 128 ran 5.6% and 14.8% faster than
+#: two (PERF.md section 6, PR 55).  Past the cap the whole-tile edge cells
+#: share the interior's branch (``_masked_step``)
+_FORWARD_BODY_CAP = 2 * 1024 * 2048 * 512
+
+
+def _masked_step(qi, ki, block_q: int, block_k: int, causal: bool, step,
+                 dead=None, window=None, valid=None, carried: bool = False,
+                 width: int = 0):
     """Shared causal dispatch for the kernels: the mask-free interior
-    branch, the masked diagonal branch (mutually exclusive ``pl.when``s —
-    the FLOP counter relies on that, utils/flops.py), or the unconditional
-    non-causal form.  ``score()`` returns the scaled [bq, bk] logits;
-    ``accumulate(s)`` folds them into the kernel's state.  ``dead`` (fused
-    backward only) runs on causally-dead cells — it zero-fills the cell's
-    dq-partial slot so the caller's sum over partials never reads
-    uninitialised memory.  ``window`` (static; None = the whole causal
-    triangle): blocks wholly behind the window are dead too, and the blocks
-    its far edge crosses take the per-element mask like the diagonal's.
-    ``valid`` (windowed k-outer grids): false where the inner index ran past
-    the sequence's last q block."""
+    branch, one branch for each offset at which an edge crosses the cell
+    (``_edge_offsets``; all mutually exclusive ``pl.when``s — the FLOP
+    counter relies on that, utils/flops.py), or the unconditional non-causal
+    form.  ``step(rows, cols, mask, fresh)`` scores rows ``rows`` of the q
+    tile against keys ``cols`` of the k tile (static ``(start, stop)``
+    pairs), masks the logits with ``mask`` where it is not None and folds
+    them into the kernel's state; ``fresh``: no earlier part of this cell
+    touched these rows (the fused backward assigns its dq-partial rows
+    there, and adds after).  An edge branch scores only the LIVE part of its
+    cell, as ``_cell_parts`` cuts it (``carried``: the kernel carries
+    softmax state across its steps; ``width``: its head width).  Where the
+    forward's branches together pass ``_FORWARD_BODY_CAP``, the edge cells
+    whose part is the whole tile run in the interior's branch, which then
+    masks by position (one body for both, a select a pair on a kernel the
+    MXU bounds at such widths); the others keep their own.  ``dead(rows)``
+    (fused backward only) runs on causally-dead cells, and on the rows of an
+    edge cell that no part scores — it zero-fills the cell's dq-partial rows
+    so the caller's sum over partials never reads uninitialised memory.
+    ``window`` (static; None = the whole causal triangle): blocks wholly
+    behind the window are dead too, and the blocks its far edge crosses are
+    edge cells like the diagonal's.  ``valid`` (windowed k-outer grids):
+    false where the inner index ran past the sequence's last q block."""
     from jax.experimental import pallas as pl
 
+    whole = _Part((0, block_q), (0, block_k), False, False)
     if not causal:
-        accumulate(score())
+        step(whole.rows, whole.cols, None, True)
         return
     if window is None:
         live, full = _causal_split(qi, ki, block_q, block_k)
@@ -175,26 +328,45 @@ def _masked_step(qi, ki, block_q: int, block_k: int, causal: bool, score,
         live, full = _window_split(qi, ki, block_q, block_k, window)
         if valid is not None:
             live, full = live & valid, full & valid
+    edge = live & jnp.logical_not(full)
+    off = qi * block_q - ki * block_k
+    cells = {value: _cell_parts(block_q, block_k, value, window, carried)
+             for value in _edge_offsets(block_q, block_k, window)}
 
-    @pl.when(full)
+    shared, shared_mask = full, None
+    if carried and width * (whole.pairs + sum(
+            p.pairs for parts in cells.values() for p in parts)) \
+            > _FORWARD_BODY_CAP:
+        for value, (part,) in list(cells.items()):
+            if (part.rows, part.cols) == (whole.rows, whole.cols):
+                shared |= edge & (off == value)
+                del cells[value]
+        shared_mask = _part_mask(whole._replace(
+            causal=True, far=window is not None), off, window)
+
+    @pl.when(shared)
     def _step_interior():
-        accumulate(score())
+        step(whole.rows, whole.cols, shared_mask, True)
 
-    @pl.when(live & jnp.logical_not(full))
-    def _step_diagonal():
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, 1), 0)
-        k_pos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1)
-        seen = q_pos >= k_pos
-        if window is not None:
-            seen &= q_pos - k_pos < window
-        accumulate(jnp.where(seen, score(), _NEG_INF))
+    half = _half_tile(block_q, block_k)
+    for value, parts in cells.items():
+        @pl.when(edge & (off == value))
+        def _step_edge(parts=parts, value=value):
+            touched = set()
+            for part in parts:
+                bands = set(range(part.rows[0], part.rows[1], half))
+                step(part.rows, part.cols, _part_mask(part, value, window),
+                     not bands & touched)
+                touched |= bands
+            if dead is not None:
+                for r0 in range(0, block_q, half):
+                    if r0 not in touched:
+                        dead((r0, r0 + half))
 
     if dead is not None:
         @pl.when(jnp.logical_not(live))
         def _step_dead():
-            dead()
+            dead(whole.rows)
 
 
 def _frontier_kv_map(block_q: int, block_k: int, causal: bool, window=None):
@@ -244,11 +416,36 @@ def _make_score(q_ref, k_ref, scale):
     accumulation: for bf16 inputs, bf16 x bf16 -> f32 on the MXU computes
     exact products (the same numerics as an f32 matmul of the upcast
     values) at the native MXU rate; the scale folds in AFTER, in f32."""
-    def score():
-        return jax.lax.dot_general(q_ref[...], k_ref[...],
-                                   (((1,), (1,)), ((), ())),
+    def score(rows=None, cols=None):
+        # static (start, stop) rows of the q tile / keys of the k tile; None
+        # = the whole tile
+        q = q_ref[...] if rows is None else q_ref[slice(*rows), :]
+        k = k_ref[...] if cols is None else k_ref[slice(*cols), :]
+        return jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                    preferred_element_type=jnp.float32) * scale
     return score
+
+
+def _make_pair(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, scale):
+    """The two per-pair tensors the backward kernels share, of rows ``rows``
+    against keys ``cols`` of a cell: ``p = exp(s - lse)`` (the forward's
+    softmax, recomputed from the saved lse — no state, so a part of a cell
+    costs its pairs and nothing else) and ``ds = p (do v^T - delta)
+    scale``, both float32.  Raw-dtype dots with f32 accumulation (see
+    ``_make_score``); ``lse`` / ``delta`` blocks are ``[bq, 1]``."""
+    score = _make_score(q_ref, k_ref, scale)
+
+    def pair(rows, cols, mask):
+        r = slice(*rows)
+        s = score(rows, cols)
+        if mask is not None:
+            s = mask(s)
+        p = jnp.exp(s - lse_ref[r, :])
+        dp = jax.lax.dot_general(do_ref[r, :], v_ref[slice(*cols), :],
+                                 (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        return p, p * (dp - d_ref[r, :]) * scale
+    return pair
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
@@ -272,21 +469,27 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def _accumulate(s):
-        m_prev = m_ref[...]
+    score = _make_score(q_ref, k_ref, scale)
+
+    def _step(rows, cols, mask, fresh):
+        r = slice(*rows)
+        s = score(rows, cols)
+        if mask is not None:
+            s = mask(s)
+        m_prev = m_ref[r]
         m_new = jnp.maximum(m_prev, s.max(-1))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new[:, None])
-        l_ref[...] = l_ref[...] * alpha + p.sum(-1)
+        l_ref[r] = l_ref[r] * alpha + p.sum(-1)
         # p rounds to the input dtype for the MXU (p in [0, 1]; flash-2
         # standard — same precision class as a dense bf16 attention)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[...], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        acc_ref[r, :] = acc_ref[r, :] * alpha[:, None] + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[slice(*cols), :],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        m_ref[r] = m_new
 
-    _masked_step(qi, ki, block_q, block_k, causal,
-                 _make_score(q_ref, k_ref, scale), _accumulate, window=window)
+    _masked_step(qi, ki, block_q, block_k, causal, _step, window=window,
+                 carried=True, width=q_ref.shape[-1])
 
     @pl.when(kk == num_k - 1)
     def _finish():
@@ -532,20 +735,16 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, dq_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def _accumulate(s):
-        # raw-dtype dots with f32 accumulation (see _make_score);
-        # p and ds round to the operand dtype before their MXU dots
-        p = jnp.exp(s - lse_ref[...])        # lse block is [bq, 1]
-        dp = jax.lax.dot_general(do_ref[...], v_ref[...],
-                                 (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - d_ref[...]) * scale).astype(k_ref.dtype)
-        acc_ref[...] += jax.lax.dot_general(
-            ds, k_ref[...], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    pair = _make_pair(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, scale)
 
-    _masked_step(qi, ki, block_q, block_k, causal,
-                 _make_score(q_ref, k_ref, scale), _accumulate, window=window)
+    def _step(rows, cols, mask, fresh):
+        # p and ds round to the operand dtype before their MXU dots
+        _, ds = pair(rows, cols, mask)
+        acc_ref[slice(*rows), :] += jax.lax.dot_general(
+            ds.astype(k_ref.dtype), k_ref[slice(*cols), :],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+    _masked_step(qi, ki, block_q, block_k, causal, _step, window=window)
 
     @pl.when(kk == num_k - 1)
     def _finish():
@@ -570,22 +769,19 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, dk_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def _accumulate(s):
-        # raw-dtype dots with f32 accumulation (see _make_score)
-        p = jnp.exp(s - lse_ref[...])        # lse block is [bq, 1]
-        dp = jax.lax.dot_general(do_ref[...], v_ref[...],
-                                 (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - d_ref[...]) * scale).astype(q_ref.dtype)
-        dk_acc[...] += jax.lax.dot_general(
-            ds, q_ref[...], (((0,), (0,)), ((), ())),
+    pair = _make_pair(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, scale)
+
+    def _step(rows, cols, mask, fresh):
+        r, c = slice(*rows), slice(*cols)
+        p, ds = pair(rows, cols, mask)
+        dk_acc[c, :] += jax.lax.dot_general(
+            ds.astype(q_ref.dtype), q_ref[r, :], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dv_acc[...] += jax.lax.dot_general(
-            p.astype(do_ref.dtype), do_ref[...], (((0,), (0,)), ((), ())),
+        dv_acc[c, :] += jax.lax.dot_general(
+            p.astype(do_ref.dtype), do_ref[r, :], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _masked_step(qi, ki, block_q, block_k, causal,
-                 _make_score(q_ref, k_ref, scale), _accumulate, window=window,
+    _masked_step(qi, ki, block_q, block_k, causal, _step, window=window,
                  valid=valid)
 
     @pl.when(jj == num_q - 1)
@@ -623,30 +819,32 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, dqp_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def _accumulate(s):
+    pair = _make_pair(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, scale)
+
+    def _step(rows, cols, mask, fresh):
         # identical dot/rounding structure to the split kernels (numerics
         # match to f32-accumulation order): p and ds round to the operand
         # dtype before their MXU dots, accumulation stays f32
-        p = jnp.exp(s - lse_ref[...])
-        dp = jax.lax.dot_general(do_ref[...], v_ref[...],
-                                 (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - d_ref[...]) * scale).astype(q_ref.dtype)
-        dqp_ref[...] = jax.lax.dot_general(
-            ds, k_ref[...], (((1,), (0,)), ((), ())),
+        r, c = slice(*rows), slice(*cols)
+        p, ds = pair(rows, cols, mask)
+        ds = ds.astype(q_ref.dtype)
+        dqp = jax.lax.dot_general(
+            ds, k_ref[c, :], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32).astype(dqp_ref.dtype)
-        dk_acc[...] += jax.lax.dot_general(
-            ds, q_ref[...], (((0,), (0,)), ((), ())),
+        # an edge cell's later parts add to the rows an earlier one wrote
+        dqp_ref[r, :] = dqp if fresh else dqp_ref[r, :] + dqp
+        dk_acc[c, :] += jax.lax.dot_general(
+            ds, q_ref[r, :], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dv_acc[...] += jax.lax.dot_general(
-            p.astype(do_ref.dtype), do_ref[...], (((0,), (0,)), ((), ())),
+        dv_acc[c, :] += jax.lax.dot_general(
+            p.astype(do_ref.dtype), do_ref[r, :], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    def _dead():
-        dqp_ref[...] = jnp.zeros_like(dqp_ref)
+    def _dead(rows):
+        r = slice(*rows)
+        dqp_ref[r, :] = jnp.zeros_like(dqp_ref[r, :])
 
-    _masked_step(qi, ki, block_q, block_k, causal,
-                 _make_score(q_ref, k_ref, scale), _accumulate, dead=_dead,
+    _masked_step(qi, ki, block_q, block_k, causal, _step, dead=_dead,
                  window=window, valid=valid)
 
     @pl.when(jj == num_q - 1)
@@ -970,6 +1168,67 @@ def window_block(s: int, window: int) -> int:
     return kernel_block(s, cap=cap)
 
 
+def call_tiles(s: int, d: int, window, itemsize: int
+               ) -> typing.Tuple[int, int, int, bool]:
+    """``(the backward's q and k tile, the forward's q tile, the forward's k
+    tile, whether the forward is the band kernel)`` of a causal call of
+    ``attention`` over ``s`` positions (``window``: None, or shorter than
+    ``s``).  Pure in its arguments: ``attention`` and the
+    ``hbnlp_flash_scored_over_live_pairs`` gauge read the same tiles."""
+    if window is None:
+        return kernel_block(s), kernel_block(s), kernel_block(s, cap=2048), \
+            False
+    blk = window_block(s, window)
+    if band_applies(s, d, window, itemsize):
+        return blk, band_block(s), blk, True
+    return blk, blk, blk, False
+
+
+def scored_pairs(s: int, block_q: int, block_k: int, window=None,
+                 carried: bool = False) -> int:
+    """The (query, key) pairs a tiled causal kernel scores over ``s`` x ``s``
+    positions: its live cells' — an interior cell whole, an edge cell the
+    parts ``_cell_parts`` cuts it into (``carried``: the forward's cut).
+    Python ints from the geometry the kernels branch on."""
+    block_q, block_k = min(block_q, s), min(block_k, s)
+    edges = {off: sum(p.pairs for p in _cell_parts(block_q, block_k, off,
+                                                   window, carried))
+             for off in _edge_offsets(block_q, block_k, window)}
+    total = 0
+    for qi in range(s // block_q):
+        for ki in range(s // block_k):
+            off = qi * block_q - ki * block_k
+            if off in edges:
+                total += edges[off]
+            elif _rect_state(off, (0, block_q), (0, block_k), window)[0]:
+                total += block_q * block_k
+    return total
+
+
+def live_pairs(s: int, window=None) -> float:
+    """The pairs a causal call over ``s`` positions has to score, by AREA:
+    the diagonal's own pairs count half, so that the whole triangle is the
+    ``s^2 / 2`` the rooflines credit (benchmark/roofline)."""
+    w = s if window is None else min(window, s)
+    return w * (w + 1) / 2 + (s - w) * w - s / 2
+
+
+def scored_over_live(s: int, d: int, window, itemsize: int
+                     ) -> typing.Dict[str, typing.Optional[float]]:
+    """``{"fwd": .., "bwd": ..}``: the pairs the tiled kernels of one causal
+    call of ``attention`` score over its live pairs — how much of what an
+    edge cell holds the kernels still pay for (1.0 = only the band itself).
+    ``fwd`` is None where the forward is the band kernel, which has no cells
+    of this kind."""
+    if window is not None and window >= s:
+        window = None
+    blk, fwd_q, fwd_k, band = call_tiles(s, d, window, itemsize)
+    live = live_pairs(s, window)
+    return {"fwd": None if band else scored_pairs(s, fwd_q, fwd_k, window,
+                                                  carried=True) / live,
+            "bwd": scored_pairs(s, blk, blk, window) / live}
+
+
 def attention(q, k, v, scale: typing.Optional[float] = None,
               causal: bool = True, interpret: typing.Optional[bool] = None,
               stash: typing.Optional[dict] = None,
@@ -999,16 +1258,46 @@ def attention(q, k, v, scale: typing.Optional[float] = None,
     returns ``flash_precomputed`` on them — the block's replay finds both
     outputs of the forward kernel saved and the call is dead code there.
 
-    Block sizes (both passes, no window): the largest power-of-two divisors
-    of the sequence up to 1024 for q and 2048 for k (always terminating at
-    128 given the s % 128 gate).  Measured on v5e at s=16384, d=128 (in-jit
+    Block sizes (``call_tiles``; no window): the largest power-of-two
+    divisors of the sequence up to 1024 for q and, in the forward, 2048 for
+    k (always terminating at 128 given the s % 128 gate).  The tile
+    measurements are round 4's, on a v5e at s=16384, d=128 ONLY (in-jit
     loop): 128x128 tiles are grid-overhead/HBM-read bound (round-4 fix,
     27x); with the diagonal-split kernels the forward is VPU-bound on
     softmax bookkeeping, so bigger tiles amortise the per-cell state ops —
     1024x1024 beats 512x512 by 38%, and widening the FORWARD's k tile to
     2048 (fewer online-softmax rescale steps per q row) another 26%; the
     backward keeps 1024x1024 — measured neutral at wider k standalone, and
-    the dq kernel exceeds the in-model scoped-VMEM limit there."""
+    the dq kernel exceeds the in-model scoped-VMEM limit there.
+
+    What a cell the diagonal crosses scores (PR 55; ``_cell_parts``).  The
+    kernels' time follows the pairs they SCORE (the forward runs at 54-56%
+    of the MXU peak on them at 4,096, 8,192 and 16,384 alike), and wide
+    tiles make a diagonal cell mostly dead: the forward cell whose q tile
+    starts where its k tile starts now scores the first 1,024 keys as ONE
+    online-softmax step, the other diagonal cell stays whole; a backward
+    diagonal cell is three of its four 512 x 512 quadrants.  Scored over
+    live pairs (``scored_over_live``; gauge
+    ``hbnlp_flash_scored_over_live_pairs``), forward / backward:
+
+        s        every live cell whole     PR 55
+        4,096    1.500 / 1.250             1.250 / 1.125
+        8,192    1.250 / 1.125             1.125 / 1.0625
+        16,384   1.125 / 1.0625            1.0625 / 1.03125
+
+    Why the forward goes no finer: an online-softmax step has a FIXED cost
+    beside its columns'.  Measured on a v5e (PR 55; non-causal forward, q
+    tile 1,024, k tiles 1,024 against 2,048, in-jit loop): at bh 32, s
+    4,096, d 128 a step costs 3.76 us a 1,024 keys + 2.67 us fixed — 0.71
+    of 1,024 columns; at bh 16, s 16,384, d 512, 12.23 + 1.85 us — 0.15.
+    Walking the shortened cell as three quadrants (one step more for its
+    lower rows) made the d 128 forward 2.6% SLOWER than scoring every cell
+    whole (2.094 against 2.041 ms a call, 1.738 as here); cutting the edge
+    cells' rows into bands of 512, which adds no step to any row and drops
+    the dead quadrants too, measured the same as this form (1.736): a
+    step's fixed cost does not shrink with its rows.  At head width 512 a third branch
+    does not fit the body (``_FORWARD_BODY_CAP``): there the whole-tile
+    diagonal cell shares the interior's branch under the position mask."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     on_tpu = jax.default_backend() not in ("cpu",)
@@ -1021,14 +1310,8 @@ def attention(q, k, v, scale: typing.Optional[float] = None,
                              "causal attention only")
         if window >= s:
             window = None
-    # tiles: (the backward's q and k, the forward's q, the forward's k)
-    if window is None:
-        blk = fwd_q = kernel_block(s)
-        fwd_k = kernel_block(s, cap=2048)
-    else:
-        blk = fwd_q = fwd_k = window_block(s, window)
-        if band_applies(s, q.shape[-1], window, q.dtype.itemsize):
-            fwd_q = band_block(s)
+    blk, fwd_q, fwd_k, _ = call_tiles(s, q.shape[-1], window,
+                                      q.dtype.itemsize)
     # named-scope regions (docs/OBSERVABILITY.md 'Cost attribution'): which
     # attention implementation actually ran — flash kernel vs the dense XLA
     # fallback — is visible per-op in HLO metadata and profiler traces

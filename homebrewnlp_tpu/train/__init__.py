@@ -355,7 +355,13 @@ class Trainer:
         r = telemetry.registry()
         for fact in declare.facts():
             value = fact.value(self.params, self.mesh, None)
-            if value is not None or fact.zero:
+            if fact.label and value is not None:
+                gauge = r.gauge(fact.metric, fact.help, (fact.label,))
+                for key, number in value.items():
+                    gauge.labels(key).set(number)
+                value = " ".join(f"{key} {number:.6g}"
+                                 for key, number in value.items())
+            elif value is not None or fact.zero:
                 r.gauge(fact.metric, fact.help).set(value or 0)
             if value is not None:
                 line += "; " + fact.fragment.format(value)

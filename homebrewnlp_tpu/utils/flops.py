@@ -10,6 +10,7 @@ recompute) does not get credit.  The reference had no FLOP accounting at all
 """
 from __future__ import annotations
 
+import re
 import typing
 
 import jax
@@ -258,23 +259,58 @@ def _count_split(jaxpr) -> typing.Tuple[int, int]:
             # cells in ``dead``: live block pairs are the ones overlapping
             # the lower triangle, sum_j min(b, ceil(j·b/a)) —
             # transpose-symmetric, so the (i, q, k) and (i, k, q) grids
-            # count identically
+            # count identically.  The flash kernels score only the live
+            # PART of a cell the diagonal crosses (``_flash_scored``)
             body_jaxpr, grid, cells = _pallas_grid(eqn)
             if body_jaxpr is not None:
-                body = _pallas_body_flops(body_jaxpr)
-                total += cells * body
                 name = str(eqn.params.get("name", ""))
+                masked = _FLASH_MASKED.match(name) is not None
+                body = _pallas_body_flops(body_jaxpr, exclusive=masked)
+                total += cells * body
                 if "causal" in name and len(grid) >= 3 \
                         and all(isinstance(g, int) for g in grid):
                     a, b = grid[1], grid[2]
-                    live = sum(min(b, (j * b + a - 1) // a)
-                               for j in range(1, a + 1))
-                    dead += cells // (a * b) * (a * b - live) * body
+                    scored = _flash_scored(eqn, name, a, b) if masked \
+                        else None
+                    if scored is None:
+                        live = sum(min(b, (j * b + a - 1) // a)
+                                   for j in range(1, a + 1))
+                        dead += cells // (a * b) * (a * b - live) * body
+                    else:
+                        pairs, tile = scored
+                        dead += cells // (a * b) * (body // tile) \
+                            * (a * b * tile - pairs)
     return total, dead
 
 
-def _pallas_body_flops(jaxpr) -> int:
-    """Per-cell FLOPs of a pallas kernel body.
+#: the kernels of parallel/flash_attention.py ``_masked_step``: the interior
+#: branch and one branch an edge offset, all mutually exclusive
+_FLASH_MASKED = re.compile(r"flash_(fwd|bwd_fused|bwd_dq|bwd_dkv)_(causal|window)$")
+
+
+def _flash_scored(eqn, name: str, a: int, b: int
+                  ) -> typing.Optional[typing.Tuple[int, int]]:
+    """``(pairs scored a head-sequence, pairs of one tile)`` of a causal
+    flash call on a grid of ``a`` x ``b`` cells, from the geometry the
+    kernels themselves branch on (``scored_pairs``: an interior cell whole,
+    an edge cell its live part) — a cell's FLOPs are its interior branch's
+    by the share of the tile it scores, every dot being rows x keys x width.
+    None for a call on unequal lengths, which keeps the count by cells."""
+    from ..parallel.flash_attention import scored_pairs
+    sq, sk = (v.aval.shape[-2] for v in eqn.invars[:2])
+    # grid (b*h, q blocks, k blocks) for the forward and dq, k-outer else
+    nq, nk = (a, b) if "fwd" in name or "bwd_dq" in name else (b, a)
+    if sq != sk or sq % nq or sk % nk:
+        return None
+    bq, bk = sq // nq, sk // nk
+    return scored_pairs(sq, bq, bk, carried="fwd" in name), bq * bk
+
+
+def _pallas_body_flops(jaxpr, exclusive: bool = False) -> int:
+    """Per-cell FLOPs of a pallas kernel body (``exclusive``: a kernel of
+    the ``_masked_step`` family, whose gated branches are ONE cell's
+    alternatives — the interior, and an edge branch for each offset that
+    scores a part of the same dots — so the cell counts as its largest).
 
     ``pl.when`` branches lower to ``cond`` eqns; kernels that split the
     causal mask into interior/diagonal variants (parallel/flash_attention.py
@@ -291,6 +327,8 @@ def _pallas_body_flops(jaxpr) -> int:
     conds = [count_matmul_flops(b.jaxpr)
              for e in jaxpr.eqns if e.primitive.name == "cond"
              for b in e.params.get("branches", ())]
+    if exclusive:
+        return uncond + max(conds, default=0)
     return uncond + sum(set(c for c in conds if c))
 
 
@@ -393,7 +431,9 @@ def _scope_walk(jaxpr, prefix: str, mult: int, out) -> None:
         elif prim == "pallas_call":
             body_jaxpr, _grid, cells = _pallas_grid(eqn)
             if body_jaxpr is not None:
-                flops = cells * _pallas_body_flops(body_jaxpr)
+                flops = cells * _pallas_body_flops(
+                    body_jaxpr, exclusive=_FLASH_MASKED.match(
+                        str(eqn.params.get("name", ""))) is not None)
         ent = out.setdefault(path, [0, 0])
         ent[0] += mult * flops
         ent[1] += mult * _eqn_bytes(eqn)

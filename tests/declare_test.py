@@ -36,23 +36,40 @@ def _kinds(attention=(0, 0), bottleneck=(0, 0), experts=(0, 0),
 #: cell -> (the kinds' part of the line and the plan, the rest of the line,
 #: the start-up gauges beside the stash's) on a TPU, from the parent; PR 52
 #: added the fifth kind to every line and the two gauges, ``dense``: 0 / 0
-#: but in the three cells named below
+#: but in the three cells named below; PR 55 added, at the end of the line of
+#: every cell that runs the tiled causal flash kernels, what they score over
+#: what their calls have to (``_scored``): before it an edge cell was scored
+#: whole and the Ouro shapes read 1.5 / 1.25
+def _scored(fwd, bwd):
+    return (f"; flash scored over live pairs fwd {fwd:.6g} bwd {bwd:.6g}",
+            {"hbnlp_flash_scored_over_live_pairs": {("fwd",): fwd,
+                                                    ("bwd",): bwd}})
+
+
+#: 4,096 positions (forward 1,024 x 2,048 tiles, backward 1,024 x 1,024),
+#: 8,192 and 16,384
+_S4K, _S8K, _S16K = (_scored(1.25, 1.125), _scored(1.125, 1.0625),
+                     _scored(1.0625, 1.03125))
+#: Laguna: the global layers' forward; the windowed layers' backward at 512 x
+#: 512 tiles under a window of 512 (three quadrants of either cell of a band:
+#: 93 x 65,536 x 6 / 4 pairs over 4,059,392), their forward the band kernel
+_LAGUNA = _scored(1.125, 6094848 / 4059392)
 _CELLS = {
     "train_32big_mixer_b32": (_kinds(), "", {}),
     "train_32big_mixer_dp2tp2": (
         _kinds(bottleneck=(32, 2147483648)), "", {}),
     "train_1b_long_context_s16k": (
-        _kinds(attention=(8, 2155872256)), "", {}),
+        _kinds(attention=(8, 2155872256)), _S16K[0], _S16K[1]),
     "train_olmoe_1b_7b_s4k": (
-        _kinds(attention=(2, 68157440), experts=(2, 1075315200)), "", {}),
+        _kinds(attention=(2, 68157440), experts=(2, 1075315200)), *_S4K),
     "train_granite_4_0_h_micro_long": (
         # PR 52: six of the ten MLPs' gate and up [1, 8192, 8192] bfloat16
         _kinds(attention=(1, 34603008), dense=(6, 1610612736)),
         "; ssd chunk states 67108864 bytes a device; conv kernel 9 layers; "
-        "scan kernel 9 layers",                                  # PR 48
+        "scan kernel 9 layers" + _S8K[0],                        # PR 48
         {"hbnlp_ssd_state_bytes": 67108864,
          "hbnlp_mamba_conv_kernel_layers": 9,
-         "hbnlp_ssd_scan_kernel_layers": 9}),
+         "hbnlp_ssd_scan_kernel_layers": 9, **_S8K[1]}),
     "train_olmo_hybrid_7b_long": (
         # PR 52: the last MLP's gate and up [1, 16384, 11008]
         _kinds(attention=(1, 127795200), recurrent=(3, 566231040),
@@ -60,19 +77,22 @@ _CELLS = {
         # PR 50: the rule is the Pallas pair, which keeps the entering
         # states of all 30 heads (until then one group's ten: 94371840)
         "; ssd chunk states 283115520 bytes a device; conv kernel 3 layers; "
-        "solve kernel 3 layers; rule kernel 3 layers",
+        "solve kernel 3 layers; rule kernel 3 layers" + _S16K[0],
         {"hbnlp_ssd_state_bytes": 283115520,
          "hbnlp_mamba_conv_kernel_layers": 3,
          "hbnlp_delta_solve_kernel_layers": 3,
-         "hbnlp_delta_rule_kernel_layers": 3}),
+         "hbnlp_delta_rule_kernel_layers": 3, **_S16K[1]}),
     "train_laguna_s_2_1_ep32_s8k": (
-        _kinds(), "; moe held rows bound 131072; flash band 3 layers",
-        {"hbnlp_moe_held_rows_bound": 131072, "hbnlp_flash_band_layers": 3}),
+        _kinds(), "; moe held rows bound 131072; flash band 3 layers"
+        + _LAGUNA[0],
+        {"hbnlp_moe_held_rows_bound": 131072, "hbnlp_flash_band_layers": 3,
+         **_LAGUNA[1]}),
     "train_zaya1_8b_ep2_s16k": (
         _kinds(attention=(8, 272629760), experts=(8, 1612185888)),
-        "; moe held rows bound 16384; router carry 117440512 bytes",
+        "; moe held rows bound 16384; router carry 117440512 bytes"
+        + _S16K[0],
         {"hbnlp_moe_held_rows_bound": 16384,
-         "hbnlp_router_carry_bytes": 117440512}),
+         "hbnlp_router_carry_bytes": 117440512, **_S16K[1]}),
     # PR 46: the sparse layer's (out, lse) and its choice (a bool a query and
     # a block); three lightning layers' chunk states, and no conv of theirs
     "train_minicpm_sala_tp2_long": (
@@ -84,7 +104,7 @@ _CELLS = {
     # says so: 12 layers x 4 passes of (out [2, 4096, 16, 128] bfloat16, lse
     # [2, 16, 4096] float32).  The nine lines above stand as they were
     "train_ouro_2_6b_loop4_s4k": (
-        _kinds(attention=(48, 1635778560), unit="executions"), "", {}),
+        _kinds(attention=(48, 1635778560), unit="executions"), *_S4K),
     # PR 54: five grouped Mamba-2 layers' chunk states ([1, 128, 64, 64, 128]
     # float32 a layer) and the static row buffer of five LatentMoE layers,
     # whose offer is the combined sum a token ([16384, 1024] bfloat16) with
@@ -95,18 +115,19 @@ _CELLS = {
     "train_nemotron_3_super_tp2_ep64_s16k": (
         _kinds(attention=(1, 68157440), experts=(5, 180224180)),
         "; ssd chunk states 268435456 bytes a device; conv kernel 5 layers; "
-        "scan kernel 5 layers; moe held rows bound 131072",
+        "scan kernel 5 layers; moe held rows bound 131072" + _S16K[0],
         {"hbnlp_ssd_state_bytes": 268435456,
          "hbnlp_mamba_conv_kernel_layers": 5,
          "hbnlp_ssd_scan_kernel_layers": 5,
-         "hbnlp_moe_held_rows_bound": 131072}),
+         "hbnlp_moe_held_rows_bound": 131072, **_S16K[1]}),
 }
 #: the facts that read 0 where no layer has the mechanism; the others have no
 #: series there
 _ALWAYS = ("hbnlp_ssd_state_bytes", "hbnlp_mamba_conv_kernel_layers",
            "hbnlp_delta_solve_kernel_layers", "hbnlp_delta_rule_kernel_layers",
            "hbnlp_ssd_scan_kernel_layers", "hbnlp_flash_band_layers")
-_SPARSE = ("hbnlp_moe_held_rows_bound", "hbnlp_router_carry_bytes")
+_SPARSE = ("hbnlp_moe_held_rows_bound", "hbnlp_router_carry_bytes",
+           "hbnlp_flash_scored_over_live_pairs")
 
 
 @pytest.fixture
@@ -143,7 +164,8 @@ def cell_startup_is_the_parents_test(cell, monkeypatch, fresh_registry):
     assert series.pop("hbnlp_remat_stash_bytes") == {
         (kind,): nbytes for kind, (_, nbytes) in plan.items()}
     want = {name: {(): 0} for name in _ALWAYS}
-    want.update({name: {(): value} for name, value in gauges.items()})
+    want.update({name: value if isinstance(value, dict) else {(): value}
+                 for name, value in gauges.items()})
     assert series == want
 
 
@@ -171,8 +193,12 @@ def _config_files():
 #: 1610612736), MiniCPM-SALA (1, 1073741824) and Olmo-Hybrid (1, 721420288):
 #: before it 5221896c3d024205a9d2196fd56b63c8840405ae; PR 54 added the two
 #: Nemotron-3-Super files: without them the digest is PR 52's
-#: 822c534a7685509a587d4690f8021ccd1e6099de, every other line as it was)
-_FILE_DIGEST = "8b03ac467bb6c441d9ab0b271ec8f57775930b9f"
+#: 822c534a7685509a587d4690f8021ccd1e6099de, every other line as it was; PR 55
+#: added ``; flash scored over live pairs fwd F bwd B`` and the series
+#: ``hbnlp_flash_scored_over_live_pairs{pass}`` to the TPU side of every file
+#: whose step calls the tiled causal flash kernels, and nothing to the others
+#: or to any CPU side: before it 8b03ac467bb6c441d9ab0b271ec8f57775930b9f)
+_FILE_DIGEST = "cf28920b09793a3aa8ba5412654c871541bed16a"
 
 
 def every_configuration_file_starts_as_on_the_parent_test(monkeypatch):
@@ -302,6 +328,30 @@ def publish_layer_stats_reads_the_declarations_test(fresh_registry):
     assert not trainer._pending_layer_stats
 
 
+def flash_scored_over_live_gauge_test(monkeypatch):
+    """PR 55: at the Ouro shapes (4,096 positions: forward 1,024 x 2,048
+    tiles, backward 1,024 x 1,024) the kernels score 1.25 / 1.125 of the live
+    pairs — scored whole, the six forward and ten backward live cells were
+    1.5 / 1.25 of them — and the gauge has no series where no call reaches
+    the tiled causal kernels: the CPU, the map mixer, the selected kernels."""
+    from homebrewnlp_tpu.model import spatial
+    from homebrewnlp_tpu.parallel import flash_attention as fa
+    ouro = _cell_params("train_ouro_2_6b_loop4_s4k")
+    assert spatial.flash_scored_over_live(ouro, "tpu") == {"fwd": 1.25,
+                                                           "bwd": 1.125}
+    live = fa.live_pairs(4096)
+    assert (6 * 1024 * 2048 / live, 10 * 1024 * 1024 / live) == (1.5, 1.25)
+    assert spatial.flash_scored_over_live(ouro, "cpu") is None
+    assert spatial.flash_scored_over_live(ouro) is None
+    for cell in ("train_32big_mixer_b32", "train_minicpm_sala_tp2_long"):
+        assert spatial.flash_scored_over_live(_cell_params(cell),
+                                              "tpu") is None
+    # a windowed layer's backward counts, its band forward does not
+    assert spatial.flash_scored_over_live(
+        _cell_params("train_laguna_s_2_1_ep32_s8k"), "tpu") == {
+        "fwd": 1.125, "bwd": 6094848 / 4059392}
+
+
 # ---- the registry of declarations -----------------------------------------
 
 def facts_are_declared_once_in_line_order_test():
@@ -310,7 +360,8 @@ def facts_are_declared_once_in_line_order_test():
         "hbnlp_ssd_state_bytes", "hbnlp_mamba_conv_kernel_layers",
         "hbnlp_delta_solve_kernel_layers", "hbnlp_delta_rule_kernel_layers",
         "hbnlp_ssd_scan_kernel_layers", "hbnlp_moe_held_rows_bound",
-        "hbnlp_router_carry_bytes", "hbnlp_flash_band_layers"]
+        "hbnlp_router_carry_bytes", "hbnlp_flash_band_layers",
+        "hbnlp_flash_scored_over_live_pairs"]
     assert [fact.metric for fact in facts if fact.zero] == list(_ALWAYS)
     assert [fact.metric for fact in facts if not fact.zero] == list(_SPARSE)
     assert len({fact.place for fact in facts}) == len(facts)

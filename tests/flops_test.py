@@ -2,6 +2,7 @@
 MFU numbers the program reports."""
 import jax
 import jax.numpy as jnp
+import pytest
 
 from backend import make_params  # noqa: F401  (sets up the CPU mesh env)
 
@@ -78,3 +79,66 @@ def flops_split_map_mixer_batch_sweep_grid_test():
         == (150_994_944, 94_371_840)
     assert forward_flops_split(fwd_bwd(False), bias, v) \
         == (150_994_944, 150_994_944)
+
+
+def _pallas_eqns(jaxpr, found=None):
+    found = {} if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found[eqn.params["name"]] = eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _pallas_eqns(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("kernel", ["flash_fwd_causal",
+                                    "flash_bwd_fused_causal",
+                                    "flash_bwd_dq_causal",
+                                    "flash_bwd_dkv_causal"])
+@pytest.mark.parametrize("s", [4096, 8192, 16384])
+def flash_executed_flops_follow_the_scored_pairs_test(s, kernel, monkeypatch):
+    """PR 55: a cell the diagonal crosses scores its live part only, and the
+    counter follows the kernels' own geometry.  At ``attention``'s tiles,
+    with ``n = s / 1024``: the forward (1,024 x 2,048; the cell that starts
+    where its k tile starts scores the first 1,024 keys) executes ``(n + 1)
+    / 2n`` of the full square — 10 / 36 / 136 tiles of 1,024 x 1,024 at
+    4,096 / 8,192 / 16,384, where its whole cells were 12 / 40 / 144 — and
+    each backward kernel (1,024 x 1,024; a diagonal cell is three of its
+    four quadrants) ``(2n + 1) / 4n``: 9 / 34 / 132 tiles for 10 / 36 / 136
+    cells.  ``full`` stays the full-square convention."""
+    from homebrewnlp_tpu.parallel import flash_attention as fa
+    from homebrewnlp_tpu.utils.flops import (_StrippedJaxpr,
+                                             count_matmul_flops_split)
+    d, n = 128, s // 1024
+    fused = kernel == "flash_bwd_fused_causal"
+    monkeypatch.setattr(fa, "_fused_dqp_cap",
+                        (lambda: 1 << 50) if fused else (lambda: 0))
+    x = jax.ShapeDtypeStruct((1, s, 1, d), jnp.bfloat16)
+    blk, fwd_q, fwd_k, band = fa.call_tiles(s, d, None, 2)
+    assert (blk, fwd_q, fwd_k, band) == (1024, 1024, 2048, False)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, 1.0, True, fwd_q, fwd_k, False,
+                                  blk, blk).astype(jnp.float32).sum()
+
+    eqns = _pallas_eqns(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(x, x, x).jaxpr)
+    assert sorted(eqns) == (["flash_bwd_fused_causal", "flash_fwd_causal"]
+                            if fused else
+                            ["flash_bwd_dkv_causal", "flash_bwd_dq_causal",
+                             "flash_fwd_causal"])
+    full, executed = count_matmul_flops_split(_StrippedJaxpr([eqns[kernel]]))
+    dots = {"flash_fwd_causal": 2, "flash_bwd_fused_causal": 5,
+            "flash_bwd_dq_causal": 3, "flash_bwd_dkv_causal": 4}[kernel]
+    assert full == dots * 2 * s * s * d
+    tile = dots * 2 * 1024 * 1024 * d
+    if kernel == "flash_fwd_causal":
+        assert executed == n * (n + 1) // 2 * tile
+        assert fa.scored_pairs(s, fwd_q, fwd_k, carried=True) \
+            == {4096: 10, 8192: 36, 16384: 136}[s] * 1024 * 1024
+    else:
+        assert executed * 4 == n * (2 * n + 1) * tile
+        assert fa.scored_pairs(s, blk, blk) \
+            == {4096: 9, 8192: 34, 16384: 132}[s] * 1024 * 1024
+    # scored over live, as the gauge reads it: 1.25 / 1.125 at 4,096
+    assert fa.scored_over_live(s, d, None, 2) == {
+        "fwd": (n + 1) / n, "bwd": (2 * n + 1) / (2 * n)}
