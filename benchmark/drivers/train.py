@@ -16,6 +16,15 @@ device without a queued step; ``train()`` itself reads the metrics every
 tenth step).  Tokens of all steps between the two fences over the wall time
 between them, per chip.  ``block_until_ready`` is a true fence on this
 attachment (measured in PR 22, PERF.md section 6).
+
+Two seeds make a run.  ``--seed`` is the program's ``data_seed``: the order
+of the record files, and so every batch.  The weights come from the cell's
+own file (``weights_seed``, ``cell_weights_seed`` below) through
+``Trainer.init_state(first_batch, seed=...)``, so that every run of a cell
+trains the same model on other data: a rank that holds a share of the experts
+draws the rows routed to it once, in the file, and not with every seed of a
+check (``benchmark/README.md``, 'What --seed decides').  One ``seeds:`` line a
+run carries the checksums that show it.
 """
 from __future__ import annotations
 
@@ -23,6 +32,7 @@ import math
 import os
 import shutil
 import time
+import zlib
 
 from ..lib import data as data_mod
 from ..lib.result import NoAccelerator, Result, footprint
@@ -31,6 +41,53 @@ from ..trace.reduce import newest_xplane
 RUN_AHEAD = 2
 WARMUP_STEPS = 2
 SPANS = ("data_next", "dispatch", "fence")
+
+
+def cell_weights_seed(cell) -> int:
+    """The seed of the cell's weights, from the cell's own file
+    (``weights_seed``, with ``weights_seed_why``): ``--seed`` deals out the
+    data only, so every run of a cell trains the same router and the same
+    tables on another order of the record files (``benchmark/README.md``,
+    'What --seed decides').  A file without the key is an error, not a
+    default: a cell that drew its weights from ``--seed`` would draw its held
+    experts' rows anew with every pair of the check's runs."""
+    seed, why = cell.spec.get("weights_seed"), cell.spec.get("weights_seed_why")
+    if isinstance(seed, bool) or not isinstance(seed, int) \
+            or not isinstance(why, str) or not why.strip():
+        raise KeyError(
+            f"cell {cell.name}: benchmark/workloads/{cell.name}.json needs an "
+            f"integer \"weights_seed\" and a \"weights_seed_why\" that says "
+            f"how it was chosen (benchmark/README.md, 'Choosing a cell's "
+            f"weights_seed'); found {seed!r} and {why!r}")
+    return seed
+
+
+def weights_seed_and_origin(cell, override=None) -> tuple:
+    """``(seed, where it came from)``: ``--weights-seed`` where a sweep or a
+    control gave one, the cell's file otherwise."""
+    if override is not None:
+        return override, "--weights-seed"
+    return cell_weights_seed(cell), "the cell's file"
+
+
+def seeds_line(seed: int, origin: str, variables, batch) -> tuple:
+    """``(line, counters)``: the weights' seed, a CRC-32 of two named
+    parameters — the token embedding (the first parameter the graph walk
+    makes) and the first parameter of the first sparse layer where the model
+    has one (the router's matrix under the one-matrix routers) — and one of
+    the first batch's tokens.  Two runs of a cell at different ``--seed``
+    show the first two equal and the third different."""
+    import numpy as np
+    names = list(variables)
+    named = names[:1] + [n for n in names if "/moe_" in n][:1]
+    params = {n: zlib.crc32(np.ascontiguousarray(variables[n]))
+              for n in named}
+    tokens = zlib.crc32(np.ascontiguousarray(batch["token_x"]))
+    line = (f"seeds: weights_seed {seed} ({origin}); "
+            + "; ".join(f"{n} crc32 {v:08x}" for n, v in params.items())
+            + f"; the first batch's tokens crc32 {tokens:08x}")
+    return line, {"weights_seed": seed, "param_crc32": params,
+                  "first_batch_crc32": tokens}
 
 
 class _Compiles:
@@ -75,6 +132,7 @@ def run(ctx) -> Result:
 
     traffic = cell.traffic(ctx.rehearsal)
     config = cell.model_config(ctx.rehearsal)
+    weights_seed, origin = weights_seed_and_origin(cell, ctx.weights_seed)
     run_dir = os.path.join(ctx.out_dir, "run")
     shutil.rmtree(run_dir, ignore_errors=True)
     config.update(
@@ -91,9 +149,12 @@ def run(ctx) -> Result:
     data = make_dataset(params, mesh=mesh)
     try:
         first_batch = next(iter(data))
-        state = trainer.init_state(first_batch)
+        state = trainer.init_state(first_batch, seed=weights_seed)
         jax.block_until_ready(state.variables)
         log(shardlib.placement_report(state.variables, mesh))
+        line, seeds = seeds_line(weights_seed, origin, state.variables,
+                                 first_batch)
+        log(line)
         t_init = time.monotonic()
 
         checks = _reference_check(ctx, config, model, trainer, mesh, state,
@@ -144,11 +205,12 @@ def run(ctx) -> Result:
     # one step of the bfloat16 it is reported in
     tail = window[-max(1, len(window) // 4):]
     tail_mean = sum(tail) / len(tail)
+    nonfinite = sum(not math.isfinite(v) for v in window)
     checks.update(
         first_step_loss=first_loss,
         loss_agrees=abs(first_loss - checks["reference_loss"])
         <= checks["loss_tolerance"],
-        losses_finite=all(math.isfinite(v) for v in window),
+        losses_finite=nonfinite == 0,
         loss_drop=first_loss - tail_mean,
         loss_fell=first_loss - tail_mean > checks["loss_tolerance"],
         compiles_in_window=compiles.in_window,
@@ -156,6 +218,16 @@ def run(ctx) -> Result:
     correct = all(checks[k] for k in ("loss_agrees", "logits_agree",
                                       "losses_finite", "loss_fell",
                                       "no_compile_in_window"))
+    # each number compared beside its limit, for the run's last lines
+    compared = {
+        "logit_error": {"value": checks["logit_error"],
+                        "at_most": checks["logit_tolerance"]},
+        "loss_gap": {"value": abs(first_loss - checks["reference_loss"]),
+                     "at_most": checks["loss_tolerance"]},
+        "loss_drop": {"value": checks["loss_drop"],
+                      "above": checks["loss_tolerance"]},
+        "nonfinite_losses": {"value": nonfinite, "at_most": 0},
+        "compiles_in_window": {"value": compiles.in_window, "at_most": 0}}
     log(f"window: {len(window)} steps in {t1 - t0:.4f}s; first step's loss "
         f"{first_loss:.4f}, the window's: "
         + " ".join(f"{v:.4f}" for v in window[:12])
@@ -166,9 +238,8 @@ def run(ctx) -> Result:
     return Result(
         end_to_end={"train_tokens_per_sec_chip": rate,
                     "setup_s": t0 - ctx.t_start},
-        correct=correct, checks=checks,
-        attempted=len(window),
-        failed=sum(not math.isfinite(v) for v in window),
+        correct=correct, checks=checks, compared=compared,
+        attempted=len(window), failed=nonfinite,
         device={"platform": platform, "kind": devices[0].device_kind,
                 "count": len(devices), "memory_peak_bytes": int(peak)},
         spans={"import_s": t_import - ctx.t_start, "data_s": t_data - t_import,
@@ -176,7 +247,7 @@ def run(ctx) -> Result:
                "compile_s": t_warm - t_check, "window_s": t1 - t0},
         counters={"steps": len(window), "tokens_per_step": tokens_per_step,
                   "cache_misses": compiles.misses,
-                  "memory_limit_bytes": int(limit)},
+                  "memory_limit_bytes": int(limit), **seeds},
         trace_path=newest_xplane(trace_dir), trace_window="bench_window",
         trace_spans=SPANS)
 
@@ -206,7 +277,8 @@ def _reference_check(ctx, config, model, trainer, mesh, state, batch) -> dict:
     ctx.log(f"reference: max|logit - reference| / max|reference| = "
             f"{err:.6f} (tolerance {tolerance}), max|reference| = "
             f"{float(np.max(np.abs(want))):.4f}")
-    return {"logit_error": err, "logits_agree": err <= tolerance,
+    return {"logit_error": err, "logit_tolerance": tolerance,
+            "logits_agree": err <= tolerance,
             "reference_loss": float(common.loss_of(want, targets,
                                                    config["z_loss"])),
             "loss_tolerance": float(ctx.cell.spec["correct"]

@@ -38,6 +38,9 @@ class Context:
     t_start: float               # time.monotonic() at process start
     out_dir: str
     log: typing.Callable[[str], None]
+    #: a sweep's override of the cell file's ``weights_seed`` (``run.py
+    #: --weights-seed``); the driver's check never gives it
+    weights_seed: typing.Optional[int] = None
 
 
 @dataclasses.dataclass
@@ -51,6 +54,9 @@ class Result:
     device: dict                 # platform, kind, count, memory_peak_bytes
     spans: typing.Dict[str, float]        # host clocks, seconds
     counters: dict               # program counters and counts of the run
+    #: each number that decided ``correct`` beside its limit: ``{name:
+    #: {"value": v, "at_most" | "above": limit}}``; the result line's last key
+    compared: dict = dataclasses.field(default_factory=dict)
     trace_path: typing.Optional[str] = None
     trace_window: typing.Optional[str] = None
     trace_spans: typing.Sequence[str] = ()

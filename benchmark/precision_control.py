@@ -6,7 +6,11 @@ precision than the configuration states has to come out as NOT correct.
 
 Builds what the ``train`` driver builds, in its order — the cell's
 configuration, the seeded corpus, ``Model``, ``Trainer``, the record
-pipeline's first batch, ``init_state`` — and puts three sets of logits through
+pipeline's first batch, ``init_state`` with the cell file's ``weights_seed``
+(so the logits are of the weights every run of the cell trains; ``--seed``
+deals out the first batch only, as in a run, and ``--weights-seed`` reads
+another draw of the weights, as the tolerances' readings before PR 57 did
+with every seed) — and puts three sets of logits through
 the driver's OWN comparison (``drivers/train.py _reference_check``, the
 cell's ``logit_tolerance``) against the plain float32 reference: the
 program's, as every run of the cell does, and the reference's with its
@@ -56,6 +60,8 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--weights-seed", type=int, default=None,
+                    help="another draw of the weights than the cell's own")
     ap.add_argument("--rehearse-cpu", action="store_true")
     args = ap.parse_args(argv)
 
@@ -75,7 +81,8 @@ def main(argv=None) -> int:
     if cell.chips != 1:
         raise SystemExit("precision_control.py: one-chip cells only")
 
-    from benchmark.drivers.train import _reference_check
+    from benchmark.drivers.train import (_reference_check, seeds_line,
+                                         weights_seed_and_origin)
     from homebrewnlp_tpu.config import ModelParameter
     from homebrewnlp_tpu.model import Model
     from homebrewnlp_tpu.run.train_loop import make_dataset
@@ -100,13 +107,16 @@ def main(argv=None) -> int:
         batch = next(iter(data))
     finally:
         data.close()
-    state = trainer.init_state(batch)
+    weights_seed, origin = weights_seed_and_origin(cell, args.weights_seed)
+    state = trainer.init_state(batch, seed=weights_seed)
     jax.block_until_ready(state.variables)
+    ctx.log(seeds_line(weights_seed, origin, state.variables, batch)[0])
 
     def numbers(checks):
         return {k: checks[k] for k in ("logit_error", "logits_agree")}
 
     out = {"workload": cell.name, "seed": args.seed,
+           "weights_seed": weights_seed,
            "logit_tolerance": float(cell.spec["correct"]["logit_tolerance"]),
            "program": numbers(_reference_check(ctx, config, model, trainer,
                                                None, state, batch))}

@@ -37,6 +37,10 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--weights-seed", type=int, default=None,
+                    help="a sweep's override of the cell file's "
+                         "weights_seed (sweep_weights_seed.py); the "
+                         "driver's check never gives it")
     ap.add_argument("--rehearse-cpu", action="store_true",
                     help="toy size on the CPU; exercises the harness, "
                          "prints no result line, exits 10")
@@ -70,7 +74,8 @@ def main(argv=None) -> int:
            else ""))
     ctx = Context(cell=cell, seed=args.seed, seconds=args.seconds,
                   trace=bool(args.trace), rehearsal=args.rehearse_cpu,
-                  t_start=T_START, out_dir=out_dir, log=log)
+                  t_start=T_START, out_dir=out_dir, log=log,
+                  weights_seed=args.weights_seed)
     try:
         result = cell_mod.load_driver(cell.spec["driver"]).run(ctx)
     except NoAccelerator as exc:
@@ -125,6 +130,9 @@ def main(argv=None) -> int:
             line["metrics"][metric["name"]] = {
                 "value": result.end_to_end[metric["name"]],
                 "unit": metric["unit"]}
+    # what decided ``correct``, each number beside its limit: the line's
+    # last key and the last lines on standard error
+    line["compared"] = result.compared
     with open(os.path.join(out_dir, "result.json"), "w") as f:
         json.dump({"line": line, "spans": result.spans,
                    "counters": result.counters, "checks": result.checks,
@@ -141,6 +149,9 @@ def main(argv=None) -> int:
               flush=True)
         return EXIT_REHEARSAL
     print(json.dumps(line), flush=True)
+    for name, entry in result.compared.items():
+        print(f"compared {name}: " + " ".join(
+            f"{k} {v}" for k, v in entry.items()), file=sys.stderr, flush=True)
     return 0
 
 

@@ -86,7 +86,10 @@ def a_cell_a_configuration_and_a_metric_arrive_as_new_files_test(tmp_path):
     with open(os.path.join(bench_dir, "workloads",
                            "train_32big_mixer_b32.json")) as f:
         cell = json.load(f)
-    cell.update(name="train_throwaway", config="throwaway")
+    # a new cell's file carries its own weights_seed, an addition like any
+    # other: the reader below refuses a file without it
+    cell.update(name="train_throwaway", config="throwaway", weights_seed=3,
+                weights_seed_why="test: a dense toy, nothing to sweep")
     with open(os.path.join(bench_dir, "workloads", "train_throwaway.json"),
               "w") as f:
         json.dump(cell, f)
@@ -127,6 +130,8 @@ def a_cell_a_configuration_and_a_metric_arrive_as_new_files_test(tmp_path):
         result["counters"]["steps"] > 0
     assert result["checks"]["logits_agree"] and \
         result["checks"]["no_compile_in_window"]
+    assert result["counters"]["weights_seed"] == 3
+    assert "seeds: weights_seed 3 (the cell's file)" in done.stdout
     after = _digests(root)
     assert {k: after[k] for k in before} == before, \
         "an existing file of the benchmark was edited"
@@ -135,6 +140,80 @@ def a_cell_a_configuration_and_a_metric_arrive_as_new_files_test(tmp_path):
         "benchmark/metrics/throwaway_steps.py",
         "benchmark/reference/throwaway.py",
         "benchmark/workloads/train_throwaway.json"]
+
+
+# ---- two seeds make a run: the cell's file deals out the weights, --seed
+# ---- the data ---------------------------------------------------------------
+
+def _train_cells():
+    return [w["name"] for w in _bench()["workloads"]
+            if w["name"].startswith("train_")]
+
+
+@pytest.mark.parametrize("cell", _train_cells())
+def every_train_cell_names_its_weights_seed_test(cell):
+    from benchmark.drivers.train import cell_weights_seed
+    from benchmark.lib.cell import load_cell
+    loaded = load_cell(cell)
+    assert isinstance(cell_weights_seed(loaded), int)
+    why = loaded.spec["weights_seed_why"]
+    assert len(why) > 40
+    held = loaded.model_config().get("experts_held", 0)
+    # a cell that holds a share of its experts gives the sweep it was
+    # chosen from; any other cell is 0 and says why nothing was swept
+    assert ("lower median" in why) if held else loaded.spec["weights_seed"] == 0
+
+
+@pytest.mark.parametrize("spec", [
+    {}, {"weights_seed": 3}, {"weights_seed": 3, "weights_seed_why": " "},
+    {"weights_seed": "3", "weights_seed_why": "a string is no seed"},
+    {"weights_seed": True, "weights_seed_why": "nor is a flag"},
+    {"weights_seed": None, "weights_seed_why": "nor is null"}])
+def a_cell_file_without_its_weights_seed_fails_loudly_test(spec):
+    import types
+    from benchmark.drivers.train import cell_weights_seed
+    cell = types.SimpleNamespace(name="train_nameless", spec=spec)
+    with pytest.raises(KeyError) as err:
+        cell_weights_seed(cell)
+    assert "train_nameless" in str(err.value)
+    assert "weights_seed" in str(err.value)
+    assert cell_weights_seed(types.SimpleNamespace(
+        name="train_named", spec={"weights_seed": 5,
+                                  "weights_seed_why": "chosen"})) == 5
+
+
+def the_seed_deals_out_the_data_and_the_cells_file_the_weights_test():
+    """A CPU rehearsal of a held-expert cell at two ``--seed``s: the same
+    weights (the checksums of the embedding and of the first sparse layer's
+    first parameter, the router's matrix), other batches; ``--weights-seed``,
+    the sweep's override, moves the weights and not the batch."""
+    cell = "train_laguna_s_2_1_ep32_s8k"
+    seen = []
+    for extra in (["--seed", "1"], ["--seed", "2"],
+                  ["--seed", "2", "--weights-seed", "12345"]):
+        done = _run(REPO, "--workload", cell, *extra, "--seconds", "1",
+                    "--trace", "0", "--rehearse-cpu")
+        assert done.returncode == 10, done.stdout[-3000:] + done.stderr[-3000:]
+        line = [ln for ln in done.stdout.splitlines()
+                if ln.startswith("seeds: weights_seed ")]
+        assert len(line) == 1 and "crc32" in line[0]
+        with open(os.path.join(REPO, "benchmark", "out", "rehearsal", cell,
+                               "result.json")) as f:
+            counters = json.load(f)["counters"]
+        assert len(counters["param_crc32"]) == 2
+        assert any("/moe_" in name for name in counters["param_crc32"])
+        seen.append(counters)
+    one, two, swept = seen
+    with open(os.path.join(REPO, "benchmark", "workloads",
+                           cell + ".json")) as f:
+        assert one["weights_seed"] == two["weights_seed"] == \
+            json.load(f)["weights_seed"]
+    assert one["param_crc32"] == two["param_crc32"]
+    assert one["first_batch_crc32"] != two["first_batch_crc32"]
+    assert swept["weights_seed"] == 12345
+    assert swept["first_batch_crc32"] == two["first_batch_crc32"]
+    assert set(swept["param_crc32"].values()).isdisjoint(
+        two["param_crc32"].values())
 
 
 def on_the_cpu_without_the_flag_nothing_is_printed_as_a_result_test():

@@ -243,11 +243,14 @@ def every_new_metric_is_listed_by_the_three_train_cells_test():
     entries = {m["name"]: m for m in bench["per_layer"]}
     cells = ["train_32big_mixer_b32", "train_32big_mixer_dp2tp2",
              "train_1b_long_context_s16k"]
-    # appended at the end, in the order PERF.md section 3 lists them
-    assert tuple(m["name"] for m in bench["per_layer"][-15:]) == NEW_METRICS
+    # appended in the order PERF.md section 3 lists them (later PRs appended
+    # theirs after them, so the list's tail is no longer these)
+    assert tuple(m["name"] for m in bench["per_layer"]
+                 if m["name"] in NEW_METRICS) == NEW_METRICS
     for name in NEW_METRICS:
         mod = cell_mod.load_metric(name)
         assert (mod.LAYER, mod.MOVES) == (entries[name]["layer"],
                                           entries[name]["moves"])
-        assert entries[name]["workloads"] == cells
+        # the three cells of PR 23 first; later cells appended their names
+        assert entries[name]["workloads"][:3] == cells
         assert mod.__doc__ and len(mod.__doc__) > 40
