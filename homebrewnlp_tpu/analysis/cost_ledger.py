@@ -69,7 +69,7 @@ _LAYER_NAMES = frozenset((
     "feed_forward_product_key_memory", "product_key_memory",
     "reduced_half_linear", "transpose_sequence_features",
     "bottleneck_group_linear", "sum_heads", "moe", "mamba", "gated_delta",
-    "mlp", "cca", "lightning",
+    "kda", "mlp", "cca", "lightning",
     # no layer function: a block part's scaled residual merge
     # (model/frontend.py scaled_merge) opens a scope of its own beside them
     "merge"))
@@ -84,9 +84,13 @@ _ROUTER_PARTS = frozenset(("down", "carry", "mlp"))
 #: kernels stay in ``body/cca`` itself
 _CCA_PARTS = frozenset(("in_proj", "qk_mean", "conv", "qk_norm", "rope",
                         "value_shift", "out_proj"))
-#: the standard attention's per-head output gate (model/spatial.py), a
-#: scope of its own below ``body/attention``
-_ATTENTION_PARTS = frozenset(("gate",))
+#: the standard attention's per-head output gate and the projections of its
+#: latent form (flag ``kv_latent<n>``; model/spatial.py), each a scope of its
+#: own below ``body/attention``; the latent form's ``attend`` (the flash
+#: kernels and what names their outputs) stays in ``body/attention`` itself,
+#: as ``cca``'s kernels stay in ``body/cca``
+_ATTENTION_PARTS = frozenset(("gate", "q_proj", "kv_down", "kv_norm", "kv_up",
+                              "out_proj"))
 #: the steps of attention flag ``sparse`` (model/sparse.py; ``attend`` holds
 #: the selected kernels) below ``body/attention/sparse_attention``
 _SPARSE_PARTS = frozenset(("compress", "index", "select", "attend"))
@@ -106,6 +110,11 @@ _MAMBA_PARTS = frozenset(("in_proj", "conv", "ssd", "gate_norm", "out_proj"))
 #: ``body/gated_delta/delta_rule``
 _DELTA_PARTS = frozenset(("in_proj", "conv", "delta_rule", "gate_norm",
                           "out_proj"))
+#: the parts of layer ``kda`` (model/kda.py) below ``body/kda``; the rule's
+#: own steps (``decay``, ``solve``, ``intra_chunk``, ``inter_chunk``,
+#: ``state_out``) stay inside ``body/kda/rule``
+_KDA_PARTS = frozenset(("in_proj", "conv", "decay", "rule", "gate_norm",
+                        "out_proj"))
 
 
 def _unwrap(comp: str) -> str:
@@ -130,13 +139,15 @@ def scope_key(path: str) -> str:
     ``head_loss``, ``input/embed``, ``input``, ``body/<layer>``,
     ``body/moe/router|dispatch|experts|combine|shared|latent_down|
     latent_up``,
-    ``body/moe/router/down|carry|mlp``, ``body/attention/gate``,
+    ``body/moe/router/down|carry|mlp``, ``body/attention/gate|q_proj|kv_down|
+    kv_norm|kv_up|out_proj``,
     ``body/attention/sparse_attention/compress|index|select|attend``,
     ``body/lightning/in_proj|qk_norm|rope|rule|gate_norm|out_proj``,
     ``body/cca/in_proj|qk_mean|conv|qk_norm|rope|value_shift|out_proj``,
     ``body/merge``,
     ``body/mamba/in_proj|conv|ssd|gate_norm|out_proj``,
     ``body/gated_delta/in_proj|conv|delta_rule|gate_norm|out_proj``,
+    ``body/kda/in_proj|conv|decay|rule|gate_norm|out_proj``,
     ``output/unembed``,
     ``output``, ``loss``, ``unscoped``.  Transform decorations
     (``jvp``/``transpose``/``jit`` wrappers) are unwrapped, so forward and
@@ -175,6 +186,8 @@ def scope_key(path: str) -> str:
             return f"body/mamba/{base}"
         elif layer == "gated_delta" and base in _DELTA_PARTS:
             return f"body/gated_delta/{base}"
+        elif layer == "kda" and base in _KDA_PARTS:
+            return f"body/kda/{base}"
     if router:
         return "body/moe/router"
     if sparse:

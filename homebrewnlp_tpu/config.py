@@ -275,6 +275,14 @@ class ModelParameter:
         self.delta_conv_size = 4
         self.delta_chunk = 64
         self.delta_allow_neg_eigval = True
+        # layer "kda" (Kimi Delta Attention, model/kda.py: the delta rule
+        # with a decay a channel of the key): heads, the width of a head's
+        # key / query and of its value (also the inner width of the low-rank
+        # decay and gate pairs), the conv's taps
+        self.kda_heads = 32
+        self.kda_key_features = 128
+        self.kda_value_features = 128
+        self.kda_conv_size = 4
         # layer "lightning" (decayed linear attention with per-head keys,
         # model/lightning.py): the heads of the WHOLE layer (a head's decay
         # follows its index among them), which of them THIS layer holds —
@@ -978,7 +986,8 @@ class ModelParameter:
                 "beta_fast > beta_slow > 0, attention_factor >= 0 (0 = "
                 "0.1 ln(factor) + 1)")
         for key in ("delta_heads", "delta_key_features",
-                    "delta_value_features", "delta_chunk"):
+                    "delta_value_features", "delta_chunk", "kda_heads",
+                    "kda_key_features", "kda_value_features"):
             if not isinstance(getattr(self, key), int) \
                     or getattr(self, key) < 1:
                 raise ValueError(f"{key} {getattr(self, key)!r} must be a "
@@ -1023,10 +1032,11 @@ class ModelParameter:
                 "sparse_*: sparse_kernel_stride divides sparse_kernel_size "
                 "and sparse_block_size, sparse_block_size divides "
                 "sparse_window")
-        if not isinstance(self.delta_conv_size, int) \
-                or not 1 <= self.delta_conv_size <= 128:
-            raise ValueError(f"delta_conv_size {self.delta_conv_size!r} must "
-                             "be 1 to 128 taps")
+        for key in ("delta_conv_size", "kda_conv_size"):
+            if not isinstance(getattr(self, key), int) \
+                    or not 1 <= getattr(self, key) <= 128:
+                raise ValueError(f"{key} {getattr(self, key)!r} must be 1 to "
+                                 "128 taps")
         if not self.norm_epsilon > 0:
             raise ValueError(f"norm_epsilon {self.norm_epsilon!r} must be "
                              "positive")

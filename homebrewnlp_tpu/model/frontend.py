@@ -18,6 +18,7 @@ from .basic import (bottleneck_group_linear, dropout, feed_forward,
                     transpose_sequence_features)
 from .cca import cca
 from .gated_delta import gated_delta
+from .kda import kda
 from .lightning import lightning
 from .loop import gated_loss
 from .mamba import mamba
@@ -177,6 +178,7 @@ LAYER_FUNCTIONS = {'feed_forward': feed_forward,
                    'moe': moe,
                    'mamba': mamba,
                    'gated_delta': gated_delta,
+                   'kda': kda,
                    'mlp': mlp,
                    'cca': cca,
                    'lightning': lightning,

@@ -293,29 +293,38 @@ def _group_heads(bsz: int, s: int, h: int, chunk: int) -> int:
                if h % d == 0 and (d * per_head <= GROUP_BYTES or d == 1))
 
 
-def grouped_rule(q, k, v, beta, g, chunk: int):
-    """``delta_rule`` over groups of heads, one after another (``lax.map``),
-    each group rematerialised in the backward (``jax.checkpoint``): autodiff
-    keeps a dozen ``[b, chunks, heads, chunk, chunk]`` float32 matrices and
-    the scan's float32 states of whatever it differentiates at once, 6.6 GiB
-    a layer over 30 heads at 16,384 tokens, and heads are independent.  A
-    group is the most heads (a divisor of all) whose one such matrix stays
-    within ``GROUP_BYTES``; where that is all of them, one group.  The
+def over_groups(rule, group: int, q, k, v, beta, g):
+    """``rule(q, k, v, beta, g) -> (o, *statistics)`` over groups of
+    ``group`` heads (a divisor of all), one after another (``lax.map``),
+    each group rematerialised in the backward (``jax.checkpoint``).  Returns
+    ``o`` over all heads and every statistic a group, ``[groups]``.  The
     group's backward needs nothing of an enclosing forward but ``q, k, v,
     beta, g``: an enclosing ``jax.checkpoint`` that saves the output
-    (``SAVED_NAMES``) replays none of this."""
-    bsz, s, h, _ = q.shape
-    group = _group_heads(bsz, s, h, chunk)
+    replays none of this.  Layer ``kda`` runs its rule through it too."""
+    h = q.shape[2]
 
     def split(t):
         return jnp.moveaxis(t.reshape(t.shape[:2] + (h // group, group)
                                       + t.shape[3:]), 2, 0)
 
-    o, transform_max = jax.lax.map(
-        jax.checkpoint(lambda heads: delta_rule(*heads, chunk),
-                       prevent_cse=False),
+    o, *statistics = jax.lax.map(
+        jax.checkpoint(lambda heads: rule(*heads), prevent_cse=False),
         tuple(split(t) for t in (q, k, v, beta, g)))
-    return (jnp.moveaxis(o, 0, 2).reshape(v.shape), jnp.max(transform_max))
+    return (jnp.moveaxis(o, 0, 2).reshape(v.shape), *statistics)
+
+
+def grouped_rule(q, k, v, beta, g, chunk: int):
+    """``delta_rule`` over groups of heads (``over_groups``): autodiff
+    keeps a dozen ``[b, chunks, heads, chunk, chunk]`` float32 matrices and
+    the scan's float32 states of whatever it differentiates at once, 6.6 GiB
+    a layer over 30 heads at 16,384 tokens, and heads are independent.  A
+    group is the most heads (a divisor of all) whose one such matrix stays
+    within ``GROUP_BYTES``; where that is all of them, one group."""
+    bsz, s, h, _ = q.shape
+    o, transform_max = over_groups(
+        lambda *heads: delta_rule(*heads, chunk),
+        _group_heads(bsz, s, h, chunk), q, k, v, beta, g)
+    return o, jnp.max(transform_max)
 
 
 def kernel_rule(q, k, v, beta, g, chunk: int):

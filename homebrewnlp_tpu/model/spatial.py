@@ -411,7 +411,13 @@ def rotary(x, theta: float, width: typing.Optional[int] = None,
 _STANDARD_POSITION = ("rope", "nope", "yarn")
 _STANDARD_PLAIN = ("qk_norm", "qk_norm_head", "gate", "gate_features",
                    "sparse")
-_STANDARD_NUMBERED = ("q_heads", "kv_heads", "window", "rotary_pct", "theta")
+_STANDARD_NUMBERED = ("q_heads", "kv_heads", "window", "rotary_pct", "theta",
+                      "kv_latent", "shared_key")
+#: what the latent form (``kv_latent<n>``) does not build, each refused by
+#: name
+_LATENT_REFUSES = ("rope", "yarn", "rotary_pct", "theta", "qk_norm",
+                   "qk_norm_head", "gate", "gate_features", "sparse",
+                   "window")
 
 
 def numbered_flags(extras, plain, numbered, what: str
@@ -457,6 +463,21 @@ def _standard_flags(extras) -> typing.Dict[str, typing.Any]:
         if one in out and other in out:
             raise ValueError(f"the standard attention takes {one} or "
                              f"{other}, not both")
+    if "shared_key" in out and "kv_latent" not in out:
+        raise ValueError("shared_key<n> (one key part shared by all heads) "
+                         "comes with kv_latent<n>")
+    if "kv_latent" in out:
+        for flag in _LATENT_REFUSES:
+            if flag in out:
+                raise ValueError(
+                    f"latent attention (kv_latent<n>) does not build {flag}: "
+                    "no rotary on the shared key part (nope only), no query "
+                    "latent, norm, gate, sparse choice or window")
+        if out.get("q_heads") != out.get("kv_heads"):
+            raise ValueError("latent attention expands the latent to a key "
+                             "and a value a query head: kv_heads = q_heads")
+        if min(out["kv_latent"], out.get("shared_key", 1)) < 1:
+            raise ValueError("kv_latent<n> and shared_key<n> are positive")
     return out
 
 
@@ -606,6 +627,11 @@ def _standard_attention(args: BlockArgs) -> NamedTensor:
         raise NotImplementedError(
             "the standard attention (attention-rope / attention-nope) on a "
             "sequence- or pipe-sharded mesh")
+    if "kv_latent" in flags:
+        if mesh is not None and mesh.size > 1:
+            raise NotImplementedError("latent attention (kv_latent<n>) on a "
+                                      "mesh")
+        return _latent_attention(args, flags)
     feats = list(params.feature_dims)
     own_heads = "q_heads" in flags
     if own_heads:
@@ -685,11 +711,84 @@ def _standard_attention(args: BlockArgs) -> NamedTensor:
         feats, q_feats, stddev=args.params.residual_out_stddev or 0.02)
 
 
+def _latent_attention(args: BlockArgs, flags) -> NamedTensor:
+    """Latent attention without positions (DeepSeek-V2's MLA as Kimi Linear
+    runs it, ``mla_use_nope``; flags ``nope-kv_latent<c>-shared_key<r>``,
+    with ``q_heads<n>-kv_heads<n>`` or the stream's heads): on the block's
+    input ``u``, ``n`` heads of ``d = features_per_head``,
+
+        q = u W_q                     features x n x (d + r); a head's q =
+                                      [q_n (d) | q_s (r)]      (no query latent)
+        c | k_s = u W_kvd             features x (c + r); k_s is ONE shared
+                                      r-wide key part for all heads
+        k_n | v = rms(c) w_c W_kvu    c x n x (d + d); RMSNorm over the
+                                      latent, eps ``norm_epsilon``
+        k = [k_n | k_s]               k_s repeated over the heads
+        o = causal softmax(scale q k^T) v      scale = attention_scale, or
+                                               (d + r)^-1/2
+        out = o W_o                   n x d x features
+
+    The flash kernels take the key at ``d + r`` and the value at ``d``
+    (parallel/flash_attention.py: V is not padded).  Parameters in creation
+    order: ``W_q``, ``W_kvd``, the latent norm's scale, ``W_kvu``, ``W_o``;
+    normal(0.02), ``W_o`` normal(``residual_out_stddev``) where set.  Scopes:
+    ``q_proj``, ``kv_down``, ``kv_norm``, ``kv_up``, ``attend``,
+    ``out_proj`` (analysis/cost_ledger.py folds ``attend`` into
+    ``body/attention`` itself, the mixing the readers of that scope mean)."""
+    import jax.numpy as jnp
+    from ..core import scope as scope_mod
+    from ..core.tensor import nt, transpose_to
+    from .normalization import norm
+    params = args.params
+    ctx = scope_mod.current()
+    dim = get_attention_dim(args).dim
+    feats = list(params.feature_dims)
+    heads = flags.get("q_heads", params.head_dim.size)
+    d, r, c = params.key_dim.size, flags.get("shared_key", 0), \
+        flags["kv_latent"]
+    head, latent = Dim("q_heads", heads), Dim("kv_latent", c)
+    lead_dims = [x for x in args.tensor.dims if x not in [dim] + feats]
+    tokens = lead_dims + [dim]
+
+    def projected(x, new, old):
+        """``x`` projected ``old -> new`` as ``[lead, sequence, *new]``."""
+        return transpose_to(project(args, x, new, old), tokens + new
+                            ).data.reshape(-1, dim.size,
+                                           *(n.size for n in new))
+
+    with jax.named_scope("q_proj"):
+        q = projected(args.tensor, [head, Dim("latent_key", d + r)], feats)
+    with jax.named_scope("kv_down"):
+        down = projected(args.tensor, [Dim("kv_latent_shared", c + r)], feats)
+    with jax.named_scope("kv_norm"):
+        normed = norm(args(nt(down[..., :c], tokens + [latent]),
+                           ["rms", "scale"]), [latent])
+    with jax.named_scope("kv_up"):
+        up = projected(normed, [head, Dim("latent_key_value", 2 * d)],
+                       [latent])
+        k, v = up[..., :d], up[..., d:]
+        if r:
+            k = jnp.concatenate([k, jnp.broadcast_to(
+                down[:, :, None, c:], k.shape[:3] + (r,))], axis=-1)
+    scale = params.attention_scale or (d + r) ** -0.5
+    with jax.named_scope("attend"):
+        out = causal_heads(ctx, params, q, k, v, 1, scale)
+    out_feats = [head, params.key_dim]
+    out_nt = nt(out.reshape([x.size for x in tokens + out_feats]),
+                tokens + out_feats)
+    with jax.named_scope("out_proj"):
+        return project(args, transpose_to(
+            out_nt, [x for x in args.tensor.dims if x not in feats]
+            + out_feats), feats, out_feats,
+            stddev=params.residual_out_stddev or 0.02)
+
+
 def flash_offer(params, heads: int, window: typing.Optional[int] = None
                 ) -> Offer:
     """What one flash call over ``heads`` query heads offers the attention
     kind: ``out`` ``[batch, sequence, heads, features_per_head]`` in the
-    calculation dtype and ``lse`` ``[batch x heads, sequence]`` float32, and
+    calculation dtype — the VALUE's width, which under the latent form is
+    not the key's — and ``lse`` ``[batch x heads, sequence]`` float32, and
     the keys a query sees."""
     seq = params.sequence_dim.size
     return Offer("attention", SAVED_NAMES,

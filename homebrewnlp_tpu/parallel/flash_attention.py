@@ -1,7 +1,10 @@
 """Pallas TPU flash attention (single-device causal softmax attention).
 
-Head layout: multi-head attention only, ``q``, ``k``, ``v`` all ``[b, s,
-heads, d]``.  Grouped-query attention reaches these kernels with each K/V
+Head layout: multi-head attention only, ``q``, ``k`` ``[b, s, heads, d_k]``
+and ``v``, ``out`` ``[b, s, heads, d_v]``: the value's width need not be the
+key's (latent attention, model/spatial.py: 192 / 128; every kernel reads the
+two widths from its operands, V is never padded to the key's, and at ``d_k ==
+d_v`` each call is the one it was).  Grouped-query attention reaches these kernels with each K/V
 head already repeated over its group of query heads (``model/spatial.py``
 ``_standard_attention``; autodiff sums dk and dv over the group): K/V index
 maps that read row ``i // group`` with the group's sum inside the dk/dv pass
@@ -525,7 +528,8 @@ def kernel_block(s: int, cap: int = 1024) -> int:
 
 def _fwd_flat(qt, kt, vt, scale, causal, block_q, block_k, interpret,
               out_dtype=None, window=None):
-    """Flat-core forward: q/k/v [bh, s, d] -> (out [bh, s, d], lse [bh, s]).
+    """Flat-core forward: q/k [bh, s, d], v [bh, s, d_v] -> (out [bh, s,
+    d_v], lse [bh, s]).
 
     The flat layout is shared with the ring-attention hop path
     (parallel/ring_attention.py) — each ring hop runs this kernel on one
@@ -536,7 +540,7 @@ def _fwd_flat(qt, kt, vt, scale, causal, block_q, block_k, interpret,
     from jax.experimental.pallas import tpu as pltpu
 
     bh, s, d = qt.shape
-    sk = kt.shape[1]
+    sk, dv = kt.shape[1], vt.shape[2]
     block_q = min(block_q, s)
     block_k = min(block_k, sk)
     num_k = sk // block_k
@@ -555,14 +559,14 @@ def _fwd_flat(qt, kt, vt, scale, causal, block_q, block_k, interpret,
         grid=(bh, s // block_q, num_k),
         in_specs=[pl.BlockSpec((None, block_q, d), lambda i, j, kk: (i, j, 0)),
                   pl.BlockSpec((None, block_k, d), _kmap),
-                  pl.BlockSpec((None, block_k, d), _kmap)],
-        out_specs=[pl.BlockSpec((None, block_q, d), lambda i, j, kk: (i, j, 0)),
+                  pl.BlockSpec((None, block_k, dv), _kmap)],
+        out_specs=[pl.BlockSpec((None, block_q, dv), lambda i, j, kk: (i, j, 0)),
                    pl.BlockSpec((None, block_q, 1), lambda i, j, kk: (i, j, 0))],
-        out_shape=[jax.ShapeDtypeStruct((bh, s, d), out_dtype),
+        out_shape=[jax.ShapeDtypeStruct((bh, s, dv), out_dtype),
                    jax.ShapeDtypeStruct((bh, s, 1), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((block_q,), jnp.float32),
                         pltpu.VMEM((block_q,), jnp.float32),
-                        pltpu.VMEM((block_q, d), jnp.float32)],
+                        pltpu.VMEM((block_q, dv), jnp.float32)],
         # the innermost k dimension carries the online-softmax scratch state
         # and MUST run sequentially ("arbitrary"); the outer two dims are
         # independent and may be partitioned across megacore.  vmem budget:
@@ -608,7 +612,8 @@ def _band_geometry(s: int, window: int, block_q: int):
     return sub, reach, min(sub + reach, s)
 
 
-def band_applies(s: int, d: int, window: int, itemsize: int) -> bool:
+def band_applies(s: int, d: int, window: int, itemsize: int,
+                 d_v: typing.Optional[int] = None) -> bool:
     """Whether a windowed call's FORWARD is the band kernel (``_fwd_band``):
     a cell's VMEM at ``band_block``'s q tile — the head-sequence's K and V
     resident (two pipeline buffers each), the q, out and lse tiles (two
@@ -620,8 +625,9 @@ def band_applies(s: int, d: int, window: int, itemsize: int) -> bool:
     gauge (model/spatial.py) read."""
     block_q = band_block(s)
     sub, _, span = _band_geometry(s, window, block_q)
-    resident = 2 * 2 * s * d * itemsize
-    tiles = 2 * (2 * block_q * d * itemsize + block_q * 128 * 4)
+    d_v = d if d_v is None else d_v
+    resident = 2 * s * (d + d_v) * itemsize
+    tiles = 2 * (block_q * (d + d_v) * itemsize + block_q * 128 * 4)
     scores = sub * span * (4 + 4 + itemsize)
     return resident + tiles + scores <= _KERNEL_VMEM_BUDGET
 
@@ -678,20 +684,21 @@ def _fwd_band(qt, kt, vt, scale, block_q, window, interpret):
     from jax.experimental.pallas import tpu as pltpu
 
     bh, s, d = qt.shape
+    dv = vt.shape[2]
     block_q = min(block_q, s)
     sub, reach, span = _band_geometry(s, window, block_q)
     kernel = functools.partial(_band_kernel, block_q=block_q, sub=sub,
                                reach=reach, span=span, window=window,
                                scale=scale)
-    whole = pl.BlockSpec((None, s, d), lambda i, j: (i, 0, 0))
     out, lse = pl.pallas_call(
         kernel,
         grid=(bh, s // block_q),
         in_specs=[pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
-                  whole, whole],
-        out_specs=[pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
+                  pl.BlockSpec((None, s, d), lambda i, j: (i, 0, 0)),
+                  pl.BlockSpec((None, s, dv), lambda i, j: (i, 0, 0))],
+        out_specs=[pl.BlockSpec((None, block_q, dv), lambda i, j: (i, j, 0)),
                    pl.BlockSpec((None, block_q, 1), lambda i, j: (i, j, 0))],
-        out_shape=[jax.ShapeDtypeStruct((bh, s, d), qt.dtype),
+        out_shape=[jax.ShapeDtypeStruct((bh, s, dv), qt.dtype),
                    jax.ShapeDtypeStruct((bh, s, 1), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
@@ -707,16 +714,18 @@ def _flash_fwd_impl(q, k, v, scale, causal, block_q, block_k, interpret,
     """Returns (out [b, s, h, d], lse [b*h, s]) — lse is the backward's
     softmax residual (flash-2: p is recomputed per block as exp(s - lse))."""
     b, s, h, d = q.shape
+    dv = v.shape[-1]
     # [b, s, h, d] -> [b*h, s, d]
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, s, d)
     kt = k.transpose(0, 2, 1, 3).reshape(b * h, s, d)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * h, s, d)
-    if window is not None and band_applies(s, d, window, q.dtype.itemsize):
+    vt = v.transpose(0, 2, 1, 3).reshape(b * h, s, dv)
+    if window is not None and band_applies(s, d, window, q.dtype.itemsize,
+                                           dv):
         out, lse = _fwd_band(qt, kt, vt, scale, block_q, window, interpret)
     else:
         out, lse = _fwd_flat(qt, kt, vt, scale, causal, block_q, block_k,
                              interpret, window=window)
-    return out.reshape(b, h, s, d).transpose(0, 2, 1, 3), lse
+    return out.reshape(b, h, s, dv).transpose(0, 2, 1, 3), lse
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, dq_ref,
@@ -898,7 +907,7 @@ def _bwd_flat_fused(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
     from jax.experimental.pallas import tpu as pltpu
 
     bh, s, d = qt.shape
-    sk = kt.shape[1]
+    sk, dv = kt.shape[1], vt.shape[2]
     nq, nk = s // bq, sk // bk
     # per-operand output dtypes, matching the split path exactly (which
     # path runs is a size decision and must not change output precision)
@@ -932,17 +941,17 @@ def _bwd_flat_fused(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
         grid=(bh, nk, inner),
         in_specs=[pl.BlockSpec((None, bq, d), _q_map),
                   pl.BlockSpec((None, bk, d), lambda i, kk, j: (i, kk, 0)),
-                  pl.BlockSpec((None, bk, d), lambda i, kk, j: (i, kk, 0)),
-                  pl.BlockSpec((None, bq, d), _q_map),
+                  pl.BlockSpec((None, bk, dv), lambda i, kk, j: (i, kk, 0)),
+                  pl.BlockSpec((None, bq, dv), _q_map),
                   qrow_spec, qrow_spec],
         out_specs=[pl.BlockSpec((None, None, bq, d), dqp_map),
                    pl.BlockSpec((None, bk, d), lambda i, kk, j: (i, kk, 0)),
-                   pl.BlockSpec((None, bk, d), lambda i, kk, j: (i, kk, 0))],
+                   pl.BlockSpec((None, bk, dv), lambda i, kk, j: (i, kk, 0))],
         out_shape=[jax.ShapeDtypeStruct((bh, slots, rows, d), jnp.float32),
                    jax.ShapeDtypeStruct((bh, sk, d), dk_dtype),
-                   jax.ShapeDtypeStruct((bh, sk, d), dv_dtype)],
+                   jax.ShapeDtypeStruct((bh, sk, dv), dv_dtype)],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d), jnp.float32)],
+                        pltpu.VMEM((bk, dv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_KERNEL_VMEM_BUDGET),
@@ -962,8 +971,8 @@ def _bwd_flat_fused(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
 
 def _bwd_flat(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
               interpret, out_dtype=None, window=None):
-    """Flat-core backward: operands [bh, s, d], lse/delta [bh, s, 1] ->
-    (dq, dk, dv) [bh, s, d].  ``lse``/``delta`` are the GLOBAL softmax
+    """Flat-core backward: q/k [bh, s, d], v/dout [bh, s, d_v], lse/delta
+    [bh, s, 1] -> (dq, dk [bh, s, d], dv [bh, s, d_v]).  ``lse``/``delta`` are the GLOBAL softmax
     residuals — flash-2's decomposition makes per-block contributions
     correct under any partitioning of the key space, which is what lets
     the ring-attention backward run this same core per hop pair
@@ -977,7 +986,7 @@ def _bwd_flat(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
     from jax.experimental.pallas import tpu as pltpu
 
     bh, s, d = qt.shape
-    sk = kt.shape[1]
+    sk, dv = kt.shape[1], vt.shape[2]
     nq, nk = s // bq, sk // bk
     inner_k, inner_q = nk, nq
     if window is not None:
@@ -1006,8 +1015,8 @@ def _bwd_flat(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
         grid=(bh, nq, inner_k),
         in_specs=[pl.BlockSpec((None, bq, d), lambda i, j, kk: (i, j, 0)),
                   pl.BlockSpec((None, bk, d), _kv_map),
-                  pl.BlockSpec((None, bk, d), _kv_map),
-                  pl.BlockSpec((None, bq, d), lambda i, j, kk: (i, j, 0)),
+                  pl.BlockSpec((None, bk, dv), _kv_map),
+                  pl.BlockSpec((None, bq, dv), lambda i, j, kk: (i, j, 0)),
                   row_spec, row_spec],
         out_specs=pl.BlockSpec((None, bq, d), lambda i, j, kk: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, s, d), dq_dtype),
@@ -1027,15 +1036,15 @@ def _bwd_flat(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
         grid=(bh, nk, inner_q),
         in_specs=[pl.BlockSpec((None, bq, d), _q_map_dkv),
                   pl.BlockSpec((None, bk, d), lambda i, kk, j: (i, kk, 0)),
-                  pl.BlockSpec((None, bk, d), lambda i, kk, j: (i, kk, 0)),
-                  pl.BlockSpec((None, bq, d), _q_map_dkv),
+                  pl.BlockSpec((None, bk, dv), lambda i, kk, j: (i, kk, 0)),
+                  pl.BlockSpec((None, bq, dv), _q_map_dkv),
                   qrow_spec, qrow_spec],
         out_specs=[pl.BlockSpec((None, bk, d), lambda i, kk, j: (i, kk, 0)),
-                   pl.BlockSpec((None, bk, d), lambda i, kk, j: (i, kk, 0))],
+                   pl.BlockSpec((None, bk, dv), lambda i, kk, j: (i, kk, 0))],
         out_shape=[jax.ShapeDtypeStruct((bh, sk, d), dk_dtype),
-                   jax.ShapeDtypeStruct((bh, sk, d), dv_dtype)],
+                   jax.ShapeDtypeStruct((bh, sk, dv), dv_dtype)],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d), jnp.float32)],
+                        pltpu.VMEM((bk, dv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_KERNEL_VMEM_BUDGET),
@@ -1050,6 +1059,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, scale, causal, block_q,
     """Flash-2 pallas backward over [b, s, h, d] operands; every kernel
     skips the causally-dead blocks."""
     b, s, h, d = q.shape
+    dv = v.shape[-1]
     # caller-chosen block sizes, exactly as in the forward — attention()
     # passes the tuned 1024 tiles for both passes; tests pass small blocks
     # to exercise the multi-block causal-skip and diagonal-frontier paths
@@ -1057,9 +1067,9 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, scale, causal, block_q,
     bk = min(block_k, s)
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, s, d)
     kt = k.transpose(0, 2, 1, 3).reshape(b * h, s, d)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * h, s, d)
-    dot = dout.transpose(0, 2, 1, 3).reshape(b * h, s, d)
-    ot = out.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+    vt = v.transpose(0, 2, 1, 3).reshape(b * h, s, dv)
+    dot = dout.transpose(0, 2, 1, 3).reshape(b * h, s, dv)
+    ot = out.transpose(0, 2, 1, 3).reshape(b * h, s, dv)
     # delta_i = dout_i . out_i (rowwise), the softmax-jacobian correction;
     # lse/delta travel as [bh, s, 1] (TPU block-tiling rule, see forward)
     delta = jnp.sum(dot.astype(jnp.float32) * ot.astype(jnp.float32), -1,
@@ -1068,7 +1078,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, scale, causal, block_q,
                            causal, bq, bk, interpret, window=window)
 
     def back(x):
-        return x.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+        return x.reshape(b, h, s, x.shape[-1]).transpose(0, 2, 1, 3)
 
     return back(dq), back(dk), back(dv)
 
@@ -1079,7 +1089,8 @@ def flash_attention(q, k, v, scale: float = None, causal: bool = True,
                     block_q: int = 128, block_k: int = 128,
                     interpret: bool = False, bwd_block_q: int = None,
                     bwd_block_k: int = None, window: int = None):
-    """q, k, v: [batch, seq, heads, d] -> [batch, seq, heads, d].
+    """q, k: [batch, seq, heads, d_k], v: [batch, seq, heads, d_v] ->
+    [batch, seq, heads, d_v].
 
     ``bwd_block_q``/``bwd_block_k`` override the backward kernels' tiles
     (None = same as forward): the forward profits from a wider k tile
@@ -1168,18 +1179,20 @@ def window_block(s: int, window: int) -> int:
     return kernel_block(s, cap=cap)
 
 
-def call_tiles(s: int, d: int, window, itemsize: int
+def call_tiles(s: int, d: int, window, itemsize: int,
+               d_v: typing.Optional[int] = None
                ) -> typing.Tuple[int, int, int, bool]:
     """``(the backward's q and k tile, the forward's q tile, the forward's k
     tile, whether the forward is the band kernel)`` of a causal call of
     ``attention`` over ``s`` positions (``window``: None, or shorter than
-    ``s``).  Pure in its arguments: ``attention`` and the
+    ``s``; ``d`` the key's width, ``d_v`` the value's where it differs).
+    Pure in its arguments: ``attention`` and the
     ``hbnlp_flash_scored_over_live_pairs`` gauge read the same tiles."""
     if window is None:
         return kernel_block(s), kernel_block(s), kernel_block(s, cap=2048), \
             False
     blk = window_block(s, window)
-    if band_applies(s, d, window, itemsize):
+    if band_applies(s, d, window, itemsize, d_v):
         return blk, band_block(s), blk, True
     return blk, blk, blk, False
 
@@ -1311,7 +1324,7 @@ def attention(q, k, v, scale: typing.Optional[float] = None,
         if window >= s:
             window = None
     blk, fwd_q, fwd_k, _ = call_tiles(s, q.shape[-1], window,
-                                      q.dtype.itemsize)
+                                      q.dtype.itemsize, v.shape[-1])
     # named-scope regions (docs/OBSERVABILITY.md 'Cost attribution'): which
     # attention implementation actually ran — flash kernel vs the dense XLA
     # fallback — is visible per-op in HLO metadata and profiler traces

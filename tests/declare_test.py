@@ -120,6 +120,21 @@ _CELLS = {
          "hbnlp_mamba_conv_kernel_layers": 5,
          "hbnlp_ssd_scan_kernel_layers": 5,
          "hbnlp_moe_held_rows_bound": 131072, **_S16K[1]}),
+    # PR 58: four KDA layers' rule outputs ([1, 16384, 32, 128] bfloat16 a
+    # layer) ride as the recurrent kind and one group's chunk states (8 of 32
+    # heads: [256, 8, 128, 128] bfloat16) are what is alive for the backward;
+    # the four sparse layers' row buffers (131,072 x (2 x 1,024 + 2,304) a
+    # layer) pass the budget, so the latent attention's (out, lse) does not
+    # ride either; the conv and the solve are the Pallas pairs, the rule XLA's
+    # (it declares none).  The eleven lines above stand
+    "train_kimi_linear_ep32_s16k": (
+        _kinds(recurrent=(4, 536870912)),
+        "; ssd chunk states 67108864 bytes a device; conv kernel 4 layers; "
+        "solve kernel 4 layers; moe held rows bound 131072" + _S16K[0],
+        {"hbnlp_ssd_state_bytes": 67108864,
+         "hbnlp_mamba_conv_kernel_layers": 4,
+         "hbnlp_delta_solve_kernel_layers": 4,
+         "hbnlp_moe_held_rows_bound": 131072, **_S16K[1]}),
 }
 #: the facts that read 0 where no layer has the mechanism; the others have no
 #: series there
@@ -197,8 +212,10 @@ def _config_files():
 #: added ``; flash scored over live pairs fwd F bwd B`` and the series
 #: ``hbnlp_flash_scored_over_live_pairs{pass}`` to the TPU side of every file
 #: whose step calls the tiled causal flash kernels, and nothing to the others
-#: or to any CPU side: before it 8b03ac467bb6c441d9ab0b271ec8f57775930b9f)
-_FILE_DIGEST = "cf28920b09793a3aa8ba5412654c871541bed16a"
+#: or to any CPU side: before it 8b03ac467bb6c441d9ab0b271ec8f57775930b9f;
+#: PR 58 added the two Kimi-Linear files: without them the digest is PR 55's
+#: cf28920b09793a3aa8ba5412654c871541bed16a, every other line as it was)
+_FILE_DIGEST = "a5ae0c5439eacddd8671609ed08f3e82c06cec80"
 
 
 def every_configuration_file_starts_as_on_the_parent_test(monkeypatch):
@@ -241,6 +258,7 @@ _LAYER_STATS_IN = {
     "cca_logit_scale": [3.0, 7.0],
     "ssd_log_decay_min": [-3.0, -9.0],
     "delta_transform_abs_max": [2.0, 11.0],
+    "kda_log_decay_min": [-40.0, -144.0],
     "sparse_kept_key_share": [0.75, 0.5],
     "sparse_choosing_query_share": [0.5, 0.75],
     "lightning_state_abs_max": [4.0, 9.0]}
@@ -277,6 +295,9 @@ def _info(layer_stats):
      "c1d31e0cfa01", -9.0),
     ("delta_transform_abs_max", "gauge", "hbnlp_delta_transform_abs_max",
      "2dce3a99aa4b", 11.0),
+    # PR 58: layer kda's own statistic (it shares the one above)
+    ("kda_log_decay_min", "gauge", "hbnlp_kda_log_decay_min",
+     "e0a2d3a5188e", -144.0),
     ("sparse_kept_key_share", "gauge", "hbnlp_sparse_kept_key_share",
      "b2ef4e49f658", 0.5),
     ("sparse_choosing_query_share", "gauge",
@@ -294,11 +315,12 @@ def declared_statistic_folds_as_on_the_parent_test(name, kind, metric, text,
 
 
 def statistics_are_all_declared_test():
-    """The trainer's table is the declarations': sixteen statistics of
-    layers (PR 54: the selection bias's two) and (PR 49) three of a looped
-    model's loss, and a step whose layers report nothing (or only some) has
-    only those."""
-    assert len(_LAYER_STATS) == 19 == len(declare.stats())
+    """The trainer's table is the declarations': seventeen statistics of
+    layers (PR 54: the selection bias's two; PR 58: layer ``kda``'s log-decay,
+    and its transform under ``gated_delta``'s name) and (PR 49) three of a
+    looped model's loss, and a step whose layers report nothing (or only
+    some) has only those."""
+    assert len(_LAYER_STATS) == 20 == len(declare.stats())
     assert {"moe_bias_abs_max", "moe_all_load_max_over_mean"} \
         <= set(_LAYER_STATS)
     assert {name for name in _LAYER_STATS if name.startswith("loop_")} == {

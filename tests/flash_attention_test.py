@@ -630,3 +630,101 @@ def edge_cell_parts_are_the_live_part_test():
             fa._cell_parts(512, 512, 512, 512, False)] == [
         ((0, 256), (0, 256), True), ((0, 256), (256, 512), False),
         ((256, 512), (256, 512), True)]
+
+
+# ---- a value narrower (or wider) than the key (PR 58) ------------------------
+
+def _two_width_inputs(s, d_k, d_v, heads=2, seed=17):
+    rng = np.random.default_rng(seed)
+
+    def normal(d):
+        return jnp.asarray(rng.standard_normal((1, s, heads, d)), jnp.float32)
+
+    return normal(d_k), normal(d_k), normal(d_v), normal(d_v)
+
+
+@pytest.mark.parametrize("fused", [True, False], ids=["fused", "split"])
+@pytest.mark.parametrize("window", [None, 96], ids=["causal", "window"])
+@pytest.mark.parametrize("d_k,d_v", [(192, 128), (64, 128)])
+def two_widths_match_the_dense_form_test(d_k, d_v, window, fused,
+                                         monkeypatch):
+    """``q, k [.., d_k]``, ``v, out [.., d_v]``: the forward (tiled, and the
+    band under a window), the fused backward and the dq / dk-dv pair against
+    ``_xla_reference`` — latent attention's 192 / 128, and the other way
+    round."""
+    monkeypatch.setattr(fa, "_fused_dqp_cap",
+                        (lambda: 1 << 40) if fused else (lambda: 0))
+    q, k, v, do = _two_width_inputs(256, d_k, d_v)
+    scale = d_k ** -0.5
+
+    def kernels(q, k, v):
+        return flash_attention(q, k, v, scale, True, 64, 128, True, 64, 64,
+                               window)
+
+    out, vjp = jax.vjp(kernels, q, k, v)
+    want, want_vjp = jax.vjp(
+        lambda q, k, v: _xla_reference(q, k, v, scale, True, window), q, k, v)
+    assert out.shape == want.shape == (1, 256, 2, d_v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    for got, ref in zip(vjp(do), want_vjp(do)):
+        assert got.shape == ref.shape
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=GRAD_RTOL, atol=GRAD_ATOL)
+    names = str(jax.make_jaxpr(lambda *a: jax.vjp(kernels, *a)[1](do))(
+        q, k, v))
+    assert ("flash_bwd_fused" in names) == fused
+    assert ("flash_bwd_dkv" in names) == (not fused)
+
+
+def a_precomputed_forward_at_two_widths_test():
+    q, k, v, do = _two_width_inputs(256, 192, 128, seed=19)
+    scale = 192 ** -0.5
+    out, lse = fa._xla_reference_with_lse(q, k, v, scale, True)
+    assert out.shape == (1, 256, 2, 128) and lse.shape == (2, 256)
+    got = jax.vjp(lambda q, k, v: fa.flash_precomputed(
+        q, k, v, out, lse, scale, True, 64, 64, True), q, k, v)[1](do)
+    want = jax.vjp(lambda q, k, v: _xla_reference(q, k, v, scale, True),
+                   q, k, v)[1](do)
+    for a, b_ in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=GRAD_RTOL, atol=GRAD_ATOL)
+    # the dispatch: a named pair at the value's width
+    stash = {"mode": "name", "min_keys": 0}
+    named = jax.jit(lambda q, k, v: fa.attention(q, k, v, scale, stash=stash,
+                                                 interpret=True))(q, k, v)
+    np.testing.assert_allclose(np.asarray(named), np.asarray(out),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("heads,s,d,window,digest,precomputed", [
+    (16, 16384, 512, None, "8085b0b458133d61", "537f93e728be5233"),
+    (16, 4096, 128, None, "b7ca317183ec284c", "aca9a0fc8d6e5f85"),
+    (72, 8192, 128, 512, "7f26067a62d06811", "5c106b71441b2e30")],
+    ids=["long_context", "olmoe", "laguna_window"])
+def equal_widths_are_the_parents_calls_test(heads, s, d, window, digest,
+                                            precomputed):
+    """At ``d_k == d_v`` every call is the one it was: the jaxprs — kernel
+    bodies, grids, block maps, names, source positions stripped — of
+    ``flash_attention``'s and ``flash_precomputed``'s gradients at the
+    long-context cell's, OLMoE's and Laguna's window layers' shapes and
+    tiles, digests taken on PR 58's parent (239ac1f)."""
+    q = jax.ShapeDtypeStruct((1, s, heads, d), jnp.bfloat16)
+    blk, fwd_q, fwd_k, _ = fa.call_tiles(s, d, window, 2)
+    assert fa.call_tiles(s, d, window, 2, d) == (blk, fwd_q, fwd_k, _)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, d ** -0.5, True, fwd_q, fwd_k, False,
+                               blk, blk, window).astype(jnp.float32).sum()
+
+    assert _normalised_jaxpr_digest(jax.grad(loss, (0, 1, 2)), q, q, q) \
+        == digest
+
+    def saved(q, k, v, out, lse):
+        return jax.grad(lambda q, k, v: fa.flash_precomputed(
+            q, k, v, out, lse, d ** -0.5, True, blk, blk, False, window
+        ).astype(jnp.float32).sum(), (0, 1, 2))(q, k, v)
+
+    assert _normalised_jaxpr_digest(
+        saved, q, q, q, q, jax.ShapeDtypeStruct((heads, s), jnp.float32)) \
+        == precomputed

@@ -726,3 +726,19 @@ def dense_kind_rides_the_last_region_alone_test(monkeypatch):
     policies = _region_policies(params)
     assert [policy is _checkpoint_policy(params) for policy in policies] \
         == [True] * 7 + [False]
+
+
+def the_scalar_decay_layers_jaxpr_is_the_parents_test():
+    """PR 58 added layer ``kda`` (a decay a channel) BESIDE this one and
+    widened the flash kernels to two widths: the toy model's gradient —
+    ``gated_delta``'s scalar-decay rule, the attention layer, the MLPs —
+    traces to the jaxpr it had on that PR's parent (239ac1f), source
+    positions and object addresses stripped."""
+    import hashlib
+    import re
+    _, _, model, batch, variables = _build()
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda v, b: model.apply(v, b).total_loss.data))(variables, batch))
+    text = re.sub(r" at 0x[0-9a-f]+", "", re.sub(r" at \S+:\d+", "", text))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == "d162ea973ae1124b"
