@@ -9,9 +9,10 @@ chips present, steps, paths; ``save_graph`` on so the trainer reports what it
 compiled; the continuous engine pinned so a serving fallback is a failure):
 
   kernels  scripts/kernel_parity.py — compiled flash, map-mixer,
-           delta-solve and chunked-scan (layer mamba) kernels
-           against their XLA references at float32 "highest", and the
-           windowed flash forward's two forms against float64
+           delta-solve, chunked-scan (layer mamba) and both chunked delta
+           rules' (layers gated_delta and kda) kernels against their XLA
+           references at float32 "highest", and the windowed flash
+           forward's two forms against float64
   train    main.py --run_mode train, 10 steps on TFRecords written by
            scripts/text2records.py from a seeded corpus; writes a checkpoint
   resume   a SECOND process restores it and trains 10 more steps; must add
@@ -261,14 +262,14 @@ def leg_kernels(ctx):
         cmd += ["--flash-seq", "256", "--mixer-batch", "2",
                 "--solve-chunks", "2", "--band-heads", "1",
                 "--band-seq", "1024", "--scan-seq", "512",
-                "--rule-seq", "256"]
-    rc, wall = run_to_end("kernels", cmd, log, 540)
+                "--rule-seq", "256", "--kda-rule-seq", "256"]
+    rc, wall = run_to_end("kernels", cmd, log, 720)
     rows = []
     with open(log, errors="replace") as f:
         for line in f:
             if line.startswith('{"kernel"'):
                 rows.append(json.loads(line))
-    check(rc == 0 and len(rows) == 6,
+    check(rc == 0 and len(rows) == 7,
           f"kernel parity failed (rc {rc}):\n{tail(log)}")
     if not ctx["rehearsal"]:
         check(all(r["implementation"] == "pallas" for r in rows),

@@ -64,22 +64,41 @@ the ``exp``.  ``exp(gamma)``,
 
 The unit triangular system is ``gated_delta._inverse_unit_lower`` as it is
 (the Pallas pair of parallel/delta_solve.py where ``solve_kernel_applies``).
-Everything else is XLA's, a group of heads at a time, each group
-rematerialised in its own backward (``gated_delta.over_groups``: what
-autodiff keeps of the rule over all heads at once does not fit beside the
-train state at 16,384 tokens): the state walk is a ``lax.scan`` over the
-chunks with ``S`` carried in float32.  parallel/delta_rule.py's pair takes
-ONE decay a head (``rule_kernel_applies``' shapes have no decay row), so no
-predicate chooses here: a pair with a ``[d_k]`` decay row is a later issue
-(ROADMAP).  Decays, cumulative sums, ``beta``, the solve and the carried
-state are float32 (``KEPT``); the matmuls take the calculation dtype with
-float32 accumulation.
+Round it the rule has two forms, chosen by ``kda_kernel_applies(chunk, heads,
+d_k, d_v, sequence)`` on what the code observes (the backend and the shapes;
+never a key of the configuration):
+
+* ``kernel_rule`` — a TPU, whole lane tiles of positions, widths in whole
+  sublane tiles, a float32 state of all heads within VMEM: the Pallas pairs
+  of parallel/kda_rule.py over ALL heads a call.  ``kda_scores`` reads
+  ``q`` and ``k`` as the conv left them and ``g``, makes the L2 norms and
+  the running sum ``gamma`` on its way and ``A`` and ``A'`` with
+  ``_decayed_scores``' arithmetic; XLA scales by ``beta`` round ONE solve
+  call over every head's systems; ``kda_rule_pair`` walks the chunks with
+  ``S`` of all heads in VMEM and writes ``o`` and the states entering every
+  chunk; each pair's backward is one kernel.  XLA keeps ``beta``, the turns
+  between the layer's layout and the kernels' and both statistics.
+* ``grouped_rule`` — everywhere else, and the kernels' oracle: XLA's
+  ``kda_rule`` a group of heads at a time, each group rematerialised in its
+  own backward (``gated_delta.over_groups``: what autodiff keeps of the rule
+  over all heads at once does not fit beside the train state at 16,384
+  tokens); the state walk is a ``lax.scan`` over the chunks with ``S``
+  carried in float32.
+
+parallel/delta_rule.py's pair (layer ``gated_delta``) takes ONE decay a head:
+its ``K^T K o Gamma`` pass is what a decay a channel forbids, so the two
+layers have a file and a predicate each.  In both forms decays, cumulative
+sums, ``beta``, the solve and the carried state are float32 (``KEPT``); the
+matmuls take the calculation dtype with float32 accumulation.
 
 Under the ``checkpoint`` strategy the block's ``jax.checkpoint`` saves the
-rule's output (``SAVED_NAMES``, kind ``recurrent``), so the block's replay
-runs no forward of the rule: the gate norm and the out-projection
-differentiate through the saved ``o`` and each group's backward makes what it
-needs again from ``q, k, v, beta, g``.
+rule's output (``SAVED_NAMES``, kind ``recurrent``): the gate norm and the
+out-projection differentiate through the saved ``o``.  On the XLA form the
+block's replay then runs no forward of the rule (each group's backward makes
+what it needs again from ``q, k, v, beta, g``); on the kernels' form the
+replay runs both forward kernels and the solve again, for ``T``, ``A'`` and
+the chunk states their backwards read (alive only from one block's replay to
+its backward).
 
 Training and full-sequence forward on one device; a decode / prefill form (a
 ``[H, d_k, d_v]`` state and a conv window a sequence) is a later issue.
@@ -98,6 +117,8 @@ from ..core import scope
 from ..core.dims import Dim
 from ..core.tensor import NamedTensor, nt, transpose_to
 from ..parallel.causal_conv import causal_conv_silu, kernel_applies
+from ..parallel.kda_rule import (kda_kernel_applies, kda_rule_pair,
+                                 kda_scores, positions_major, sequence_minor)
 from .backend import ConstantInit, UniformInit, normal_var
 from .declare import Layer, Offer, Stat
 from .gated_delta import L2_EPS, _inverse_unit_lower, over_groups
@@ -243,6 +264,47 @@ def grouped_rule(q, k, v, beta, g, chunk: int):
     return o, jnp.max(transform_max), jnp.min(log_decay_min)
 
 
+def unit(t, scale: float):
+    """``t [.., d]`` over its L2 norm along ``d`` (float32, ``L2_EPS`` under
+    the root) times ``scale``, in ``t``'s dtype."""
+    f = t.astype(jnp.float32)
+    return (f * jax.lax.rsqrt(jnp.sum(jnp.square(f), -1, keepdims=True)
+                              + L2_EPS) * scale).astype(t.dtype)
+
+
+def normalised(rule):
+    """``rule`` (``kda_rule``, ``grouped_rule``) behind the layer's norms of
+    ``q`` and ``k``: what ``kernel_rule`` is on the same arguments."""
+    def run(q, k, *rest):
+        return rule(unit(q, q.shape[-1] ** -0.5), unit(k, 1.0), *rest)
+    return run
+
+
+def kernel_rule(q, k, v, beta, g, chunk: int):
+    """``normalised(kda_rule)`` over all heads at once as the Pallas pairs of
+    parallel/kda_rule.py (shapes as ``kda_kernel_applies`` accepts them):
+    ``q`` and ``k`` as the conv left them.  The scores' pair normalises them
+    and sums ``g`` along each chunk on its way; XLA turns the operands
+    sequence-minor, scales by ``beta`` round the solve and reads both
+    statistics."""
+    bsz, s, h, dk = q.shape
+    with jax.named_scope("solve"):
+        strict, mixed, gamma, q_unit, k_unit = kda_scores(
+            sequence_minor(q), sequence_minor(k), sequence_minor(g), h,
+            chunk, math.gcd(chunk, _SUB), dk ** -0.5, L2_EPS, KEPT)
+        scale = jnp.moveaxis(beta.reshape(bsz, s // chunk, chunk, h), 2, 3)
+        strict = strict * scale[..., :, None]
+        transform = _inverse_unit_lower(
+            strict.astype(KEPT).astype(jnp.float32)) * scale[..., None, :]
+        transform_max = jax.lax.stop_gradient(jnp.max(jnp.abs(transform)))
+    with jax.named_scope("decay"):
+        log_decay_min = jax.lax.stop_gradient(jnp.min(
+            gamma.reshape(bsz, h * dk, s // chunk, chunk)[..., -1]))
+    o = kda_rule_pair(q_unit, k_unit, sequence_minor(v), gamma, transform,
+                      mixed, chunk, kept=KEPT)
+    return positions_major(o, v.shape), transform_max, log_decay_min
+
+
 def kda(args: BlockArgs) -> NamedTensor:
     """Layer ``kda`` (module docstring).  Parameters in creation order:
     ``W_qkv``, the decay pair ``W_f1``, ``W_f2``, the gate pair ``W_g1``,
@@ -304,18 +366,13 @@ def kda(args: BlockArgs) -> NamedTensor:
         g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
             (raw.astype(jnp.float32) + dt_bias).reshape(bsz, s, h, dk))
     with jax.named_scope("rule"):
-        def unit(t, scale):
-            t = t.astype(jnp.float32)
-            return (t * jax.lax.rsqrt(jnp.sum(jnp.square(t), -1,
-                                              keepdims=True) + L2_EPS)
-                    * scale).astype(dtype)
-
-        q = unit(qkv[..., :d_key].reshape(bsz, s, h, dk), dk ** -0.5)
-        key = unit(qkv[..., d_key:2 * d_key].reshape(bsz, s, h, dk), 1.0)
-        beta = jax.nn.sigmoid(b_raw.astype(jnp.float32))
-        o, transform_max, log_decay_min = grouped_rule(
-            q, key, qkv[..., 2 * d_key:].reshape(bsz, s, h, dv), beta, g,
-            chunk)
+        rule = kernel_rule if kda_kernel_applies(chunk, h, dk, dv, s) \
+            else normalised(grouped_rule)
+        o, transform_max, log_decay_min = rule(
+            qkv[..., :d_key].reshape(bsz, s, h, dk),
+            qkv[..., d_key:2 * d_key].reshape(bsz, s, h, dk),
+            qkv[..., 2 * d_key:].reshape(bsz, s, h, dv),
+            jax.nn.sigmoid(b_raw.astype(jnp.float32)), g, chunk)
         o = checkpoint_name(o, SAVED_NAMES[0])
     if ctx.layer_stats is not None:
         ctx.layer_stats.append({"delta_transform_abs_max": transform_max,
@@ -336,20 +393,33 @@ def kda(args: BlockArgs) -> NamedTensor:
     return transpose_to(nt(out, token_dims + feats), x.dims)
 
 
-def _group(params: ModelParameter):
-    """``(the chunk as it runs, chunks over the batch, heads a group)``."""
+def _rule(params: ModelParameter):
+    """``(chunk, heads, d_k, d_v, sequence)`` as ``kda_kernel_applies`` takes
+    them."""
+    s = params.sequence_dim.size
+    return (min(CHUNK, s), params.kda_heads, params.kda_key_features,
+            params.kda_value_features, s)
+
+
+def _chunks_and_heads(params: ModelParameter, backend=None):
+    """``(the chunk as it runs, chunks over the batch, the heads whose rule
+    runs and keeps its states at once)``: every head where the rule is the
+    Pallas pairs on that backend, else one group (``grouped_rule``)."""
     bsz, s = params.batch_dim.size, params.sequence_dim.size
     chunk = min(CHUNK, s)
-    return chunk, bsz * max(1, s // chunk), _group_heads(
-        bsz, s, params.kda_heads, params.kda_key_features)
+    heads = params.kda_heads if kda_kernel_applies(*_rule(params), backend) \
+        else _group_heads(bsz, s, params.kda_heads, params.kda_key_features)
+    return chunk, bsz * max(1, s // chunk), heads
 
 
 def _state_bytes(params: ModelParameter) -> int:
-    """``[batch, sequence / CHUNK, heads a group, kda_key_features,
+    """``[batch, sequence / CHUNK, heads a call, kda_key_features,
     kda_value_features]`` in the calculation dtype: the states entering every
-    chunk of ONE group of heads (``grouped_rule`` rematerialises a group at a
-    time)."""
-    _, chunks, heads = _group(params)
+    chunk that are alive at once for the backward — of ALL heads where the
+    rule is the Pallas pair of parallel/kda_rule.py (its forward writes them,
+    its backward reads them), of ONE group of heads on the XLA form
+    (``grouped_rule`` rematerialises a group at a time)."""
+    _, chunks, heads = _chunks_and_heads(params)
     return chunks * heads * params.kda_key_features \
         * params.kda_value_features \
         * jnp.dtype(params.calculation_dtype).itemsize
@@ -372,8 +442,8 @@ def _offer(params: ModelParameter, extras) -> Offer:
 
 def _solve(params: ModelParameter, backend=None):
     """``(chunk, systems)`` of one call of ``_inverse_unit_lower``: a chunk
-    and a head each, over one group's heads."""
-    chunk, chunks, heads = _group(params)
+    and a head each, over the heads of a call (``_chunks_and_heads``)."""
+    chunk, chunks, heads = _chunks_and_heads(params, backend)
     return chunk, chunks * heads
 
 
@@ -384,4 +454,5 @@ kda.declares = Layer(
              "chunk of the newest finished step, all kda layers: how far "
              "the step runs from where exp(gamma) underflows", "min"),),
     offer=_offer, facts=FACTS,
-    recurrent=Recurrent(_state_bytes, _conv, _solve))
+    recurrent=Recurrent(_state_bytes, _conv, _solve, rule=_rule,
+                        rule_applies=kda_kernel_applies))

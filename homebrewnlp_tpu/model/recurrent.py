@@ -1,4 +1,4 @@
-"""What the recurrent mixers (layers ``mamba``, ``gated_delta`` and
+"""What the recurrent mixers (layers ``mamba``, ``gated_delta``, ``kda`` and
 ``lightning``) share:
 what they refuse and the layout they take, the per-channel float32
 parameters and their initialisers, the causal depthwise conv's XLA form, what
@@ -34,10 +34,12 @@ class Recurrent(typing.NamedTuple):
     ``parallel/delta_solve.solve_kernel_applies`` takes them
     (``gated_delta``: the systems of every head where its rule is the Pallas
     pair on that backend, else of one group of heads); None = none.
-    A layer whose chunked rule can be ``parallel/delta_rule.py``'s pair
-    declares its shapes: ``rule(params)`` — ``(chunk, heads, d_k, d_v,
-    sequence)`` as ``rule_kernel_applies`` takes them (``gated_delta``);
-    None = none.
+    A layer whose chunked rule can be a Pallas pair declares its shapes and
+    the predicate it calls on them itself: ``rule(params)`` — ``(chunk,
+    heads, d_k, d_v, sequence)`` — and ``rule_applies(*shapes, backend)``:
+    ``gated_delta`` with ``parallel/delta_rule.py``'s ``rule_kernel_applies``
+    (one decay a head; the default), ``kda`` with ``parallel/kda_rule.py``'s
+    ``kda_kernel_applies`` (a decay a channel); None = none.
     A layer whose chunked scan can be ``parallel/ssd_scan.py``'s pair declares
     its shapes: ``scan(params)`` — ``(sequence, chunk, heads, head features,
     state, groups)`` as ``ssd_kernel_applies`` takes them round its backend
@@ -60,6 +62,7 @@ class Recurrent(typing.NamedTuple):
         typing.Callable[[ModelParameter], typing.Tuple[int, ...]]] = None
     rule: typing.Optional[
         typing.Callable[[ModelParameter], typing.Tuple[int, ...]]] = None
+    rule_applies: typing.Callable[..., bool] = rule_kernel_applies
 
 
 def recurrent_layers(params: ModelParameter) -> typing.List[Recurrent]:
@@ -117,31 +120,33 @@ def solve_kernel_layers(params: ModelParameter, backend=None
                               for chunk, matrices in solves)
 
 
-def _shape_kernel_layers(params: ModelParameter, field: str, applies,
-                         backend) -> typing.Optional[int]:
-    shapes = [getattr(spec, field)(params) for spec in recurrent_layers(params)
-              if getattr(spec, field) is not None]
-    if not shapes:
-        return None
-    # what a layer declares past the five shapes follows the backend
-    return params.depth * sum(applies(*each[:5], backend, *each[5:])
-                              for each in shapes)
-
-
 def scan_kernel_layers(params: ModelParameter, backend=None
                        ) -> typing.Optional[int]:
     """How many recurrent mixers of the step take the Pallas pair for their
     chunked scan (``parallel/ssd_scan.py``), by the predicate the layer
     itself calls on the shapes it declares; None where no layer declares a
     scan."""
-    return _shape_kernel_layers(params, "scan", ssd_kernel_applies, backend)
+    shapes = [spec.scan(params) for spec in recurrent_layers(params)
+              if spec.scan is not None]
+    if not shapes:
+        return None
+    # what a layer declares past the five shapes follows the backend
+    return params.depth * sum(ssd_kernel_applies(*each[:5], backend, *each[5:])
+                              for each in shapes)
 
 
 def rule_kernel_layers(params: ModelParameter, backend=None
                        ) -> typing.Optional[int]:
-    """The same for the chunked delta rule (``parallel/delta_rule.py``);
-    None where no layer declares a rule."""
-    return _shape_kernel_layers(params, "rule", rule_kernel_applies, backend)
+    """The same for the chunked delta rule, each layer by its own predicate
+    (``parallel/delta_rule.py``'s pair under ``gated_delta``,
+    ``parallel/kda_rule.py``'s under ``kda``); None where no layer declares
+    a rule."""
+    rules = [spec for spec in recurrent_layers(params)
+             if spec.rule is not None]
+    if not rules:
+        return None
+    return params.depth * sum(spec.rule_applies(*spec.rule(params), backend)
+                              for spec in rules)
 
 
 #: every recurrent mixer's ``declares.facts``
@@ -165,9 +170,9 @@ FACTS = (
          lambda params, mesh, backend: solve_kernel_layers(params, backend),
          "solve kernel {} layers"),
     Fact(32, "hbnlp_delta_rule_kernel_layers",
-         "gated_delta layers of the built step whose chunked rule is the "
-         "Pallas kernel pair (0 on the XLA form over groups of heads, and "
-         "without such a layer)",
+         "gated_delta and kda layers of the built step whose chunked rule is "
+         "the Pallas kernel pairs (0 on the XLA form over groups of heads, "
+         "and without such a layer)",
          lambda params, mesh, backend: rule_kernel_layers(params, backend),
          "rule kernel {} layers"),
     Fact(35, "hbnlp_ssd_scan_kernel_layers",

@@ -51,6 +51,21 @@ solved transform reaches 3, ``W``, ``U``, ``V'`` and the carried state's
 bfloat16 copy each round once more, and the XLA form itself is 2-8% of the
 largest entry off the float32 one); each path's time a call.
 
+A seventh leg holds layer ``kda``'s chunked rule — a delta rule with a
+log-decay a CHANNEL of the key (parallel/kda_rule.py, through ``model/kda.py
+kernel_rule``: the scores' pair with the norms of ``q`` and ``k`` and the
+running sum of ``g`` inside, XLA's ``diag(beta)`` round the solve's pair, the
+walk's pair) — at the Kimi-Linear cell's shapes — ``q`` / ``k`` / ``v [1,
+16384, 32, 128]``, chunk 64, ``beta = sigmoid(.)``, ``g = -exp(A_log)
+softplus(. + dt_bias)`` a channel with ``A_log`` and ``dt_bias`` seeded as the
+layer draws them (a chunk's cumulative log-decay reaches -100 and beyond) — to
+the XLA form ``grouped_rule`` in float32 under ``highest``: ``o`` and the five
+gradients no further off than :data:`KDA_RULE_ROOM` x what the XLA form in
+bfloat16 (the parent's path) is off against the same reference on the same
+chip (:data:`KDA_RULE_ROOM_REHEARSAL` at a CPU rehearsal's size), or :data:`KDA_RULE_FLOOR` of the largest entry where that is more (an
+output both forms hold to a few 2^-9 says nothing of either); each path's time
+a call.
+
 Shapes: flash at the long-context recipe's per-chip shape (seq 16,384, head
 dim 128; two heads so the dense reference's [s, s] scores fit beside it);
 the mixer at the flagship's (8 heads, seq 512, 512 features/head, batch 32).
@@ -73,6 +88,20 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 #: factor of two; a wrong block index, mask or scale is an error of order
 #: one (>= 2^-2), forty times the bound.
 TOLERANCE = 2.0 ** -6
+
+
+#: the kda rule's kernels against the XLA form in bfloat16, an output's error
+#: over the other's on the same operands: the pairs round where the XLA form
+#: rounds (matmul operands in bfloat16, ``gamma``, the solve's input and the
+#: carried state float32), so the two are one rounding's luck apart: the
+#: largest of 67 M entries at the cell's size, where that luck is a few
+#: percent; a CPU rehearsal's toy size reads a quarter either way
+KDA_RULE_ROOM = 1.1
+KDA_RULE_ROOM_REHEARSAL = 1.5
+#: below this share of the reference's largest entry an output is held by
+#: both forms and the ratio of two roundings is not read (2^-8: one bfloat16
+#: rounding of the largest entry)
+KDA_RULE_FLOOR = 2.0 ** -8
 
 
 #: the solve's bound against float64, as a share of the largest entry: what
@@ -399,6 +428,83 @@ def _rule_leg(s: int = 16384, heads: int = 30, dk: int = 96, dv: int = 192,
     return bool(ok)
 
 
+def _kda_rule_leg(s: int = 16384, heads: int = 32, dk: int = 128,
+                  dv: int = 128) -> bool:
+    """Layer ``kda``'s kernel pairs and the XLA form in bfloat16, both
+    against the XLA form in float32 ``highest`` on the same device."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from homebrewnlp_tpu.model import kda
+    from homebrewnlp_tpu.parallel import kda_rule
+
+    chunk = min(kda.CHUNK, s)
+    rng = np.random.default_rng(59)
+    shared = rng.normal(size=(1, 1, heads, dk))
+
+    def unit(x):
+        return x / np.linalg.norm(x, axis=-1, keepdims=True)
+
+    def some_length(x):
+        return unit(x) * rng.uniform(0.5, 2.0, size=x.shape[:-1] + (1,))
+
+    # the layer's own ranges at its seeded start: keys that share a
+    # direction and, like the queries, are not of unit length (the rule
+    # normalises what the conv left), beta = sigmoid(.), g = -exp(A_log)
+    # softplus(. + dt_bias) with exp(A_log) = U(1, 16) a head,
+    # softplus(dt_bias) log-uniform in [1e-3, 1e-1] a channel and the
+    # low-rank pair's part beside it
+    q, k, v = (jnp.asarray(t, jnp.bfloat16) for t in (
+        some_length(rng.normal(size=(1, s, heads, dk)) + shared),
+        some_length(rng.normal(size=(1, s, heads, dk)) + 2 * shared),
+        rng.normal(size=(1, s, heads, dv))))
+    beta = jnp.asarray(1 / (1 + np.exp(-rng.normal(size=(1, s, heads)))),
+                       jnp.float32)
+    dt = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), (heads, dk)))
+    dt_bias = dt + np.log(-np.expm1(-dt))           # softplus's inverse
+    raw = 0.3 * rng.normal(size=(1, s, heads, dk)) + dt_bias
+    g = jnp.asarray(-rng.uniform(1.0, 16.0, (heads, 1))
+                    * np.logaddexp(raw, 0.0), jnp.float32)
+    ct = jnp.asarray(rng.normal(size=(1, s, heads, dv)), jnp.float32)
+    operands = (q, k, v, beta, g)
+    platform = jax.devices()[0].platform
+    applies = kda_rule.kda_kernel_applies(chunk, heads, dk, dv, s)
+    if platform == "cpu":
+        for name in ("kda_rule_pair", "kda_scores"):
+            setattr(kda, name, functools.partial(
+                getattr(kda_rule, name), interpret=True))
+
+    def kernel(*args):
+        return kda.kernel_rule(*args, chunk)[0].astype(jnp.float32)
+
+    def xla(*args):
+        return kda.normalised(kda.grouped_rule)(*args, chunk)[0].astype(
+            jnp.float32)
+
+    low = float(jnp.min(jnp.sum(g.reshape(1, s // chunk, chunk, heads, dk),
+                                axis=2)))
+    with jax.default_matmul_precision("highest"):
+        want = jax.block_until_ready(_with_grads(xla)(
+            ct, *(t.astype(jnp.float32) for t in operands)))
+    errs, ms = _errors_and_ms((("kernel", kernel), ("xla", xla)), ct,
+                              operands, want, 3)
+    room = KDA_RULE_ROOM_REHEARSAL if platform == "cpu" else KDA_RULE_ROOM
+    ok = (applies or platform == "cpu") and all(
+        e <= max(KDA_RULE_FLOOR, room * errs["xla"][name])
+        for name, e in errs["kernel"].items())
+    print(json.dumps({"kernel": "kda_rule", "ok": bool(ok),
+                      "implementation": "pallas" if applies else
+                      "pallas (interpret)", "max_err_over_max_ref": errs,
+                      "tolerance": f"{room} x the XLA form's, or "
+                                   f"{KDA_RULE_FLOOR}",
+                      "log_decay_min": low,
+                      "ms_a_call_forward_and_backward": ms,
+                      "shapes": [list(t.shape) for t in operands],
+                      "chunk": chunk, "dtype": "bfloat16"}), flush=True)
+    return bool(ok)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--flash-seq", type=int, default=16384)
@@ -414,6 +520,9 @@ def main(argv=None) -> int:
     ap.add_argument("--rule-seq", type=int, default=16384)
     ap.add_argument("--only-rule", action="store_true",
                     help="run the chunked delta rule's leg alone")
+    ap.add_argument("--kda-rule-seq", type=int, default=16384)
+    ap.add_argument("--only-kda-rule", action="store_true",
+                    help="run layer kda's chunked rule's leg alone")
     args = ap.parse_args(argv)
 
     import jax
@@ -422,9 +531,10 @@ def main(argv=None) -> int:
     from homebrewnlp_tpu.parallel import flash_attention as flash
     from homebrewnlp_tpu.parallel import map_mixer
 
-    if args.only_scan or args.only_rule:
+    if args.only_scan or args.only_rule or args.only_kda_rule:
         ok = _scan_leg(args.scan_seq) if args.only_scan \
-            else _rule_leg(args.rule_seq)
+            else _rule_leg(args.rule_seq) if args.only_rule \
+            else _kda_rule_leg(args.kda_rule_seq)
         print(json.dumps({"ok": bool(ok)}), flush=True)
         return 0 if ok else 1
 
@@ -464,6 +574,8 @@ def main(argv=None) -> int:
     ok &= _scan_leg(args.scan_seq)
 
     ok &= _rule_leg(args.rule_seq)
+
+    ok &= _kda_rule_leg(args.kda_rule_seq)
 
     print(json.dumps({"ok": bool(ok)}), flush=True)
     return 0 if ok else 1

@@ -14,12 +14,14 @@ JSON line it prints is the program one precision below the one the
 configuration states.  The float8 stream is kept beside it for scale.  Exit 0
 where the limit refuses that program, 1 where it lets it pass.
 
-``--rule-alone`` leaves the model out: ONE group of the cell's heads (8 of
-128 / 128 over 16,384 positions, the layer's own ranges of ``beta`` and of the
-log-decay a channel, bfloat16 ``q, k, v``) through ``kda_rule`` as it runs and
-with ``KEPT`` at bfloat16, each against the reference's recurrence position by
-position in float32 ``highest`` on the same device: what the logits' limit
-cannot tell apart, the rule's own output does.  One JSON line, exit 0.
+``--rule-alone`` leaves the model out: 8 of the cell's heads (128 / 128 over
+16,384 positions, the layer's own ranges of ``beta`` and of the log-decay a
+channel, bfloat16 ``q, k, v``) through the rule as the layer runs it there —
+the Pallas pairs of parallel/kda_rule.py where ``kda_kernel_applies`` (a
+TPU), else the XLA form — and with ``KEPT`` at bfloat16, each against the
+reference's recurrence position by position in float32 ``highest`` on the
+same device: what the logits' limit cannot tell apart, the rule's own output
+does.  One JSON line that names the form, exit 0.
 
 ``--rehearse-cpu`` runs the same path at the cell's toy size on the CPU
 (exit 10).
@@ -63,18 +65,24 @@ def rule_alone(s: int, heads: int = 8, dk: int = 128, dv: int = 128) -> int:
     beta = jnp.asarray(rng.uniform(0.0, 1.0, (1, s, heads)), jnp.float32)
     g = jnp.asarray(-rng.uniform(1.0, 16.0, (heads, 1)) * np.exp(rng.uniform(
         np.log(1e-3), np.log(1e-1), (1, s, heads, dk))), jnp.float32)
+    # the reference starts from the normalised q and k as the rule rounds
+    # them: what is compared is the rule's float32 parts, not the norms
     with jax.default_matmul_precision("highest"):
-        want = np.asarray(jax.jit(recurrence)(
-            *(t.astype(jnp.float32) for t in (q, k, v)), beta, g))
+        want = np.asarray(jax.jit(recurrence)(*(t.astype(jnp.float32) for t in (
+            kda.unit(q, dk ** -0.5), kda.unit(k, 1.0), v)), beta, g))
     errors = {}
+    chunk = min(kda.CHUNK, s)
+    kernels = kda.kda_kernel_applies(chunk, heads, dk, dv, s)
+    rule = kda.kernel_rule if kernels else kda.normalised(kda.kda_rule)
     for kept in (jnp.float32, jnp.bfloat16):
         kda.KEPT = kept
-        got = np.asarray(jax.jit(lambda *a: kda.kda_rule(
-            *a, min(kda.CHUNK, s))[0].astype(jnp.float32))(q, k, v, beta, g))
+        got = np.asarray(jax.jit(lambda *a: rule(
+            *a, chunk)[0].astype(jnp.float32))(q, k, v, beta, g))
         errors[jnp.dtype(kept).name] = float(
             np.max(np.abs(got - want)) / np.max(np.abs(want)))
     print(json.dumps({"rule_alone": [1, s, heads, dk, dv],
                       "device": jax.devices()[0].device_kind,
+                      "rule": "pallas" if kernels else "xla",
                       "max_err_over_max_recurrence_by_kept": errors}),
           flush=True)
     return 0

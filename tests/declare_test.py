@@ -125,15 +125,20 @@ _CELLS = {
     # heads: [256, 8, 128, 128] bfloat16) are what is alive for the backward;
     # the four sparse layers' row buffers (131,072 x (2 x 1,024 + 2,304) a
     # layer) pass the budget, so the latent attention's (out, lse) does not
-    # ride either; the conv and the solve are the Pallas pairs, the rule XLA's
-    # (it declares none).  The eleven lines above stand
+    # ride either; the conv and the solve are the Pallas pairs.  PR 59: so is
+    # the rule (parallel/kda_rule.py, by the layer's own predicate): ``; rule
+    # kernel 4 layers``, and the chunk states alive for the backward are all
+    # 32 heads' ([256, 32, 128, 128] bfloat16) where they were one group's
+    # 67,108,864.  The eleven lines above stand
     "train_kimi_linear_ep32_s16k": (
         _kinds(recurrent=(4, 536870912)),
-        "; ssd chunk states 67108864 bytes a device; conv kernel 4 layers; "
-        "solve kernel 4 layers; moe held rows bound 131072" + _S16K[0],
-        {"hbnlp_ssd_state_bytes": 67108864,
+        "; ssd chunk states 268435456 bytes a device; conv kernel 4 layers; "
+        "solve kernel 4 layers; rule kernel 4 layers; "
+        "moe held rows bound 131072" + _S16K[0],
+        {"hbnlp_ssd_state_bytes": 268435456,
          "hbnlp_mamba_conv_kernel_layers": 4,
          "hbnlp_delta_solve_kernel_layers": 4,
+         "hbnlp_delta_rule_kernel_layers": 4,
          "hbnlp_moe_held_rows_bound": 131072, **_S16K[1]}),
 }
 #: the facts that read 0 where no layer has the mechanism; the others have no
@@ -214,8 +219,13 @@ def _config_files():
 #: whose step calls the tiled causal flash kernels, and nothing to the others
 #: or to any CPU side: before it 8b03ac467bb6c441d9ab0b271ec8f57775930b9f;
 #: PR 58 added the two Kimi-Linear files: without them the digest is PR 55's
-#: cf28920b09793a3aa8ba5412654c871541bed16a, every other line as it was)
-_FILE_DIGEST = "a5ae0c5439eacddd8671609ed08f3e82c06cec80"
+#: cf28920b09793a3aa8ba5412654c871541bed16a, every other line as it was;
+#: PR 59: layer ``kda`` declares its rule, so the two Kimi-Linear files' lines
+#: gain ``; rule kernel N layers`` (4 and 20 on a TPU, 0 on the CPU) and
+#: their chunk states on a TPU are all 32 heads' where they were one
+#: group's; the other 27 files' lines and series as they were: before it
+#: a5ae0c5439eacddd8671609ed08f3e82c06cec80)
+_FILE_DIGEST = "6fc52f37f21988967d830151219f98f18fbe5817"
 
 
 def every_configuration_file_starts_as_on_the_parent_test(monkeypatch):

@@ -1,0 +1,478 @@
+"""parallel/kda_rule.py: the Pallas pairs of layer ``kda``'s chunked rule — a
+delta rule with a log-decay a CHANNEL of the key — in interpret mode on the
+CPU against the XLA form ``model/kda.py kda_rule`` behind the layer's norms
+(``normalised``) and autodiff's gradients of it, against the recurrence run
+position by position in float32, against ``gated_delta``'s rule where the
+decay is flat; what the scores' kernel hands on to the walk (the norms, the
+running sum of the log-decays); that no ``exp`` in a kernel
+body sees a positive operand; the predicate that chooses between the two
+forms, the layer with and without the kernels, what a step lowered for a TPU
+carries, and the start-up fact."""
+import functools
+import importlib
+import json
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from homebrewnlp_tpu.analysis.cost_ledger import scope_key
+from homebrewnlp_tpu.config import ModelParameter
+from homebrewnlp_tpu.model import gated_delta as delta_mod
+from homebrewnlp_tpu.model import kda as kda_mod
+from homebrewnlp_tpu.model import recurrent
+from homebrewnlp_tpu.parallel import kda_rule as kr
+
+from kimi_linear_test import _block, _build
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_NAMES = "o dq dk dv dbeta dg".split()
+
+
+def _recurrence():
+    return importlib.import_module(
+        "benchmark.reference.kimi_linear_48b_a3b").recurrence
+
+
+def _inputs(s, heads, low, dk=16, dv=16, dtype=jnp.float32, seed=0,
+            batch=1):
+    """Keys that share a direction (the triangular system is then far from
+    the identity) and, like the queries, are of every length between a half
+    and two (the rule normalises them itself), ``beta`` over all of ``(0,
+    1)`` and at its top every fifth position, a log-decay a channel between
+    0 and ``low`` a position."""
+    rng = np.random.default_rng(seed)
+    shared = rng.normal(size=(batch, 1, heads, dk))
+
+    def some_length(x):
+        return x / np.linalg.norm(x, axis=-1, keepdims=True) \
+            * rng.uniform(0.5, 2.0, size=x.shape[:-1] + (1,))
+
+    q = some_length(rng.normal(size=(batch, s, heads, dk)) + shared)
+    k = some_length(rng.normal(size=(batch, s, heads, dk)) + 2 * shared)
+    v = rng.normal(size=(batch, s, heads, dv))
+    beta = rng.uniform(0.0, 1.0, size=(batch, s, heads))
+    beta[:, ::5] = 1.0
+    g = low * rng.uniform(0.0, 1.0, size=(batch, s, heads, dk))
+    weights = jnp.asarray(rng.normal(size=v.shape), jnp.float32)
+    return (*(jnp.asarray(t, dtype) for t in (q, k, v)),
+            *(jnp.asarray(t, jnp.float32) for t in (beta, g))), weights
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    """``kernel_rule`` with both pairs interpreted, a head block of the
+    caller's."""
+    def steer(heads_a_block=None):
+        for name in ("kda_rule_pair", "kda_scores"):
+            monkeypatch.setattr(kda_mod, name, functools.partial(
+                getattr(kr, name), heads_a_block=heads_a_block,
+                interpret=True))
+    return steer
+
+
+def _value_and_grads(rule, inputs, weights, chunk):
+    def loss(*args):
+        o, *statistics = rule(*args, chunk)
+        return jnp.sum(o.astype(jnp.float32) * weights), (o, statistics)
+    (_, (o, statistics)), grads = jax.jit(jax.value_and_grad(
+        loss, argnums=range(5), has_aux=True))(*inputs)
+    return (o, *grads), statistics
+
+
+def _close(got, want, tolerance):
+    for name, g, w in zip(_NAMES, got, want):
+        g, w = (np.asarray(t, np.float32) for t in (g, w))
+        assert g.shape == w.shape and np.all(np.isfinite(g)), name
+        assert np.max(np.abs(g - w)) <= tolerance * max(np.max(np.abs(w)),
+                                                        1e-3), name
+
+
+# (sequence, chunk, heads, heads a block, the steepest log-decay a position):
+# the cell's chunk with a last block of one head of three over two lane
+# tiles (the state crosses a tile); three tiles and a last block of two of
+# five; eight chunks a lane tile (no sub-chunk before another); four; one
+# chunk a tile (seven sub-chunks before the last); a step's log-decay of -20
+# (exp(-gamma) overflows float32 after five positions: no inf, no nan);
+# almost none
+@pytest.mark.parametrize("s,chunk,heads,block,low", [
+    (256, 64, 3, 2, -1.0), (384, 64, 5, 3, -0.3), (256, 16, 3, 3, -1.0),
+    (128, 32, 4, 4, -1.0), (256, 128, 2, 1, -0.2), (256, 64, 2, 1, -20.0),
+    (128, 64, 3, 2, -1e-4)],
+    ids=["c64_h3", "c64_h5_three_tiles", "c16", "c32", "c128", "c64_steep",
+         "c64_slow"])
+def pairs_match_the_xla_form_test(interpreted, s, chunk, heads, block, low):
+    """Output, ``max|T|``, the log-decay watch and all five gradients
+    against autodiff through the XLA form over all heads at once."""
+    inputs, weights = _inputs(s, heads, low)
+    interpreted(block)
+    got, statistics = _value_and_grads(kda_mod.kernel_rule, inputs, weights,
+                                       chunk)
+    want, want_statistics = _value_and_grads(
+        kda_mod.normalised(kda_mod.kda_rule), inputs, weights, chunk)
+    np.testing.assert_allclose(statistics, want_statistics, rtol=1e-6)
+    # at -20 a step gamma reaches -1,280, where float32 is spaced 1.2e-4:
+    # the kernel's running sum (doubling steps) and XLA's round it apart
+    _close(got, want, 1e-4 if low == -20.0 else 2e-5)
+
+
+@pytest.mark.parametrize("chunk,s,low", [(16, 128, -1.0), (64, 256, -1.0),
+                                         (64, 128, -20.0), (128, 256, -0.2)],
+                         ids=["c16", "c64_two_tiles", "c64_steep", "c128"])
+def pairs_are_the_recurrence_test(interpreted, chunk, s, low):
+    """``o`` and all five gradients against the recurrence of the module
+    docstring run position by position in float32 (``lax.scan``'s own
+    reverse mode)."""
+    inputs, weights = _inputs(s, 3, low, dk=16, dv=16)
+    interpreted(2)
+    got, (_, log_decay_min) = _value_and_grads(kda_mod.kernel_rule, inputs,
+                                               weights, chunk)
+    recurrence = _recurrence()
+    want, _ = _value_and_grads(kda_mod.normalised(
+        lambda *args: (recurrence(*args[:5]),)), inputs, weights, chunk)
+    _close(got, want, 1e-4)
+    if low == -20.0:
+        assert float(log_decay_min) < -88 * 2        # exp(-gamma) = inf
+
+
+@pytest.mark.parametrize("chunk,kept", [(64, jnp.float32), (16, jnp.float32),
+                                        (128, jnp.float32),
+                                        (64, jnp.bfloat16)],
+                         ids=["c64", "c16", "c128", "c64_kept_bfloat16"])
+def scores_hand_on_the_norms_and_the_running_sum_test(chunk, kept):
+    """``kda_scores``' last three outputs are XLA's: ``gamma`` the cumulative
+    sum of ``g`` along each chunk (through ``kept``: at bfloat16 a value
+    bfloat16 holds, near the float32 sum), ``q`` and ``k`` over their norms
+    as ``model/kda.py unit`` rounds them."""
+    (q, k, _, _, g), _ = _inputs(256, 3, -1.0, dtype=jnp.bfloat16)
+    bsz, s, h, dk = q.shape
+    _, _, gamma, q_unit, k_unit = kr.kda_scores(
+        kr.sequence_minor(q), kr.sequence_minor(k), kr.sequence_minor(g), h,
+        chunk, min(chunk, 16), dk ** -0.5, delta_mod.L2_EPS, kept, 2, True)
+    want = jnp.cumsum(g.reshape(bsz, s // chunk, chunk, h, dk), axis=2)
+    got = kr.positions_major(gamma, g.shape).reshape(want.shape)
+    if kept == jnp.float32:
+        np.testing.assert_allclose(got, want, rtol=2e-6, atol=1e-6)
+    else:
+        assert bool(jnp.all(got.astype(kept).astype(jnp.float32) == got))
+        np.testing.assert_allclose(got, want, rtol=0.05, atol=0.05)
+    for got, raw, scale in ((q_unit, q, dk ** -0.5), (k_unit, k, 1.0)):
+        np.testing.assert_allclose(
+            np.asarray(kr.positions_major(got, raw.shape), np.float32),
+            np.asarray(kda_mod.unit(raw, scale), np.float32),
+            rtol=2.0 ** -7, atol=1e-6)
+
+
+def a_flat_decay_is_gated_deltas_rule_test(interpreted):
+    """With ``g`` equal over a head's channels the kernels' rule is
+    ``gated_delta``'s XLA rule (its state transposed): outputs and
+    gradients."""
+    (q, k, v, beta, g), weights = _inputs(128, 3, -0.5, dv=32)
+    flat = g[..., 0]
+    interpreted(2)
+
+    def ours(q, k, v, beta, flat, chunk):
+        return kda_mod.kernel_rule(
+            q, k, v, beta, jnp.broadcast_to(flat[..., None], g.shape), chunk)
+
+    got, _ = _value_and_grads(ours, (q, k, v, beta, flat), weights, 64)
+    want, _ = _value_and_grads(kda_mod.normalised(delta_mod.delta_rule),
+                               (q, k, v, beta, flat), weights, 64)
+    _close(got, want, 2e-5)
+
+
+def pairs_round_no_lower_than_the_xla_form_test(interpreted):
+    """bfloat16 operands against the recurrence in float32: the pairs are,
+    in the mean over four draws, no further off than the XLA form in
+    bfloat16 (half as much again, for the rounding's luck)."""
+    interpreted(2)
+    recurrence = _recurrence()
+    off = {"kernel": [], "xla": []}
+    for seed in range(4):
+        inputs, weights = _inputs(128, 3, -0.3, dk=16, dv=32,
+                                  dtype=jnp.bfloat16, seed=seed)
+        exact, _ = _value_and_grads(
+            kda_mod.normalised(lambda *args: (recurrence(*args[:5]),)),
+            tuple(t.astype(jnp.float32) for t in inputs), weights, 64)
+        for name, rule in (("kernel", kda_mod.kernel_rule),
+                           ("xla", kda_mod.normalised(kda_mod.kda_rule))):
+            got, _ = _value_and_grads(rule, inputs, weights, 64)
+            off[name].append([
+                float(np.max(np.abs(np.asarray(g, np.float32) - np.asarray(w)))
+                      / np.max(np.abs(np.asarray(w))))
+                for g, w in zip(got, exact)])
+    assert np.max(off["kernel"]) <= 2.0 ** -4
+    assert np.all(np.mean(off["kernel"], 0) <= 1.5 * np.mean(off["xla"], 0)), \
+        (np.mean(off["kernel"], 0), np.mean(off["xla"], 0))
+
+
+def what_the_kernels_keep_in_float32_is_felt_test(interpreted, monkeypatch):
+    """``KEPT`` reaches the kernels' path too: at bfloat16 the cumulative
+    log-decays, the solve's input and the state the walk carries are rounded
+    and the rule moves away from the recurrence by orders of magnitude."""
+    (q, k, v, beta, g), _ = _inputs(256, 3, -0.3)
+    want = kda_mod.normalised(_recurrence())(q, k, v, beta, g)
+    interpreted(2)
+
+    def off():
+        got = kda_mod.kernel_rule(q, k, v, beta, g, 64)[0]
+        return float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+
+    kept = off()
+    monkeypatch.setattr(kda_mod, "KEPT", jnp.bfloat16)
+    assert kept < 1e-5 and off() > 100 * kept
+
+
+@pytest.mark.parametrize("rule", ["kernels", "xla"])
+def no_exp_of_a_positive_decay_difference_is_formed_test(
+        rule, interpreted, monkeypatch):
+    """Every ``exp`` of the rule, forward and backward, is given values <= 0
+    (or -inf) on log-decays of -20 a step: the jaxpr is walked with every
+    ``exp``'s operand recorded, INTO the ``pallas_call`` bodies (their own
+    ``exp``s report through a callback while the call is interpreted)."""
+    from jax.extend.core import Literal
+    inside, seen = [], []
+    if rule == "kernels":
+        class Spied:
+            """``jax.numpy`` as the kernels' module sees it, ``exp``
+            reporting its operand's largest entry."""
+            def __getattr__(self, name):
+                return getattr(jnp, name)
+
+            @staticmethod
+            def exp(x):
+                jax.debug.callback(lambda top: inside.append(float(top)),
+                                   jnp.max(x))
+                return jnp.exp(x)
+
+        monkeypatch.setattr(kr, "jnp", Spied())
+        jax.clear_caches()
+        interpreted(1)
+    (q, k, v, beta, g), _ = _inputs(128, 2, -20.0)
+    fn = kda_mod.kernel_rule if rule == "kernels" \
+        else kda_mod.normalised(kda_mod.kda_rule)
+    closed = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(fn(*a, 32)[0]), argnums=(0, 1, 2, 3, 4)))(
+        q, k, v, beta, g)
+
+    def has_exp(eqn):
+        inner = [getattr(p, "jaxpr", p) for p in eqn.params.values()]
+        return eqn.primitive.name == "exp" or any(
+            has_exp(e) for j in inner if hasattr(j, "eqns") for e in j.eqns)
+
+    def walk(jaxpr, consts, args):
+        env = dict(zip(jaxpr.constvars, consts))
+        env.update(zip(jaxpr.invars, args))
+
+        def read(var):
+            return var.val if isinstance(var, Literal) else env[var]
+
+        for eqn in jaxpr.eqns:
+            values = [read(v) for v in eqn.invars]
+            if eqn.primitive.name == "exp":
+                seen.append(float(jnp.max(values[0])))
+            inner = eqn.params.get("jaxpr", eqn.params.get("call_jaxpr"))
+            if hasattr(inner, "consts") and eqn.primitive.name != "scan":
+                out = walk(inner.jaxpr, inner.consts, values)
+            else:
+                # the state walk, the solve: no exp of their own; a Pallas
+                # call: its body's report through the callback
+                assert eqn.primitive.name in ("exp", "pallas_call") \
+                    or not has_exp(eqn), eqn
+                out = eqn.primitive.bind(*values, **eqn.params)
+                out = out if eqn.primitive.multiple_results else [out]
+            env.update(zip(eqn.outvars, out))
+        return [read(v) for v in jaxpr.outvars]
+
+    out = walk(closed.jaxpr, closed.consts, [q, k, v, beta, g])
+    jax.effects_barrier()
+    assert all(bool(jnp.all(jnp.isfinite(t))) for t in out)
+    if rule == "kernels":
+        # four kernels, each with the rows', the columns' and the diagonal
+        # blocks' or the walk's three decays
+        assert len(inside) >= 40 and max(inside) <= 0.0
+        jax.clear_caches()
+    else:
+        assert len(seen) >= 8
+    assert max(seen, default=0.0) <= 0.0
+
+
+@pytest.mark.parametrize(
+    "chunk,heads,d_k,d_v,sequence,backend,takes", [
+        (64, 32, 128, 128, 16384, "tpu", True),    # the published widths
+        (16, 1, 16, 16, 128, "tpu", True),         # one tile, one head
+        (128, 4, 128, 64, 256, "tpu", True),
+        (64, 32, 128, 128, 16384, "cpu", False),
+        (64, 32, 128, 128, 16384, "gpu", False),
+        (48, 32, 128, 128, 16320, "tpu", False),   # no chunk the solve takes
+        (256, 32, 128, 128, 16384, "tpu", False),  # beyond a lane tile
+        (8, 32, 128, 128, 16384, "tpu", False),
+        (64, 32, 128, 128, 16384 + 64, "tpu", False),   # no whole lane tiles
+        (64, 3, 16, 8, 128, "tpu", False),         # the toy value width
+        (64, 3, 24, 16, 128, "tpu", False),        # an odd key width
+        (64, 0, 128, 128, 16384, "tpu", False),
+        (64, 512, 128, 128, 16384, "tpu", False)])  # a state beyond VMEM
+def predicate_test(chunk, heads, d_k, d_v, sequence, backend, takes):
+    assert kr.kda_kernel_applies(chunk, heads, d_k, d_v, sequence,
+                                 backend) is takes
+
+
+def predicate_reads_the_backend_test():
+    assert jax.default_backend() == "cpu"
+    assert not kr.kda_kernel_applies(64, 32, 128, 128, 16384)
+
+
+_WIDE = {"kda_key_features": 16, "kda_value_features": 16,
+         "sequence_length": 256, "train_batch_size": 1,
+         "block_config": [_block("kda")]}
+
+
+def _as_a_tpu_process(monkeypatch):
+    monkeypatch.setattr(kda_mod, "kda_kernel_applies", functools.partial(
+        kr.kda_kernel_applies, backend="tpu"))
+
+
+def _loss_and_grads(model, variables, batch):
+    v = {k: jnp.asarray(a) for k, a in variables.items()}
+    return jax.jit(jax.value_and_grad(
+        lambda v: model.apply(v, batch).total_loss.data))(v)
+
+
+@pytest.mark.parametrize("extra", [
+    {}, {"kda_key_features": 24}, {"sequence_length": 64},
+    {"sequence_length": 192}],
+    ids=["value_width_8", "key_width_24", "half_a_lane_tile",
+         "a_tile_and_a_half"])
+def declining_layer_traces_the_xla_form_test(monkeypatch, extra):
+    """Widths in no whole sublane tiles, a sequence of no whole lane tiles:
+    with the backend steered to the TPU the layer still traces
+    ``grouped_rule``'s ops, and no Pallas call."""
+    _, params, model, batch, variables = _build(
+        "bfloat16", block_config=[_block("kda")], **extra)
+    assert recurrent.rule_kernel_layers(params, "tpu") == 0
+    trace = lambda: re.sub(r" at 0x[0-9a-f]+", "", str(jax.make_jaxpr(  # noqa: E731
+        lambda v: model.apply(v, batch).total_loss.data)(variables)))
+    plain = trace()
+    _as_a_tpu_process(monkeypatch)
+    assert trace() == plain
+    assert "kda_rule_fwd" not in plain and "pallas_call" not in plain
+
+
+@pytest.mark.parametrize("dtype,tolerance", [("float32", 2e-5),
+                                             ("bfloat16", 2.0 ** -5)])
+def step_with_the_kernels_test(monkeypatch, interpreted, dtype, tolerance):
+    """The toy step under ``jax.checkpoint`` + ``jax.grad`` as a TPU process
+    at kernel shapes traces it: the rule is the two pairs where ``lax.map``
+    over groups stood — each traced once, the ``jax.jit`` around it —, and
+    loss and every gradient equal the XLA form's."""
+    _, params, model, batch, variables = _build(dtype, **_WIDE)
+    assert params.memory_reduction_strategy == "checkpoint"
+    assert recurrent.rule_kernel_layers(params, "tpu") == 1
+    want_loss, want = _loss_and_grads(model, variables, batch)
+    _as_a_tpu_process(monkeypatch)
+    interpreted()
+    text = str(jax.make_jaxpr(
+        lambda v: model.apply(v, batch).total_loss.data)(variables))
+    assert text.count("name=_fwd_impl") == 1 \
+        and text.count("name=_scores_fwd_impl") == 1
+    assert "kda_rule_fwd" in text and "kda_scores_fwd" in text
+    loss, got = _loss_and_grads(model, variables, batch)
+    assert abs(float(loss) - float(want_loss)) <= tolerance
+    assert set(got) == set(want)
+    for name in want:
+        a, r = (np.asarray(t[name], np.float32) for t in (got, want))
+        assert np.max(np.abs(a - r)) <= tolerance * max(
+            np.max(np.abs(r)), 1e-3), name
+
+
+def step_lowered_for_a_tpu_carries_the_kernels_test(monkeypatch):
+    """The toy step at kernel shapes lowered for a TPU (no chip, no
+    compile): four kinds of ``tpu_custom_call`` — each pair's forward twice,
+    the step's and the block's replay, its backward once — every one under
+    scope ``body/kda/rule``, which ``kimi_kda_rule_roofline`` and
+    ``scope_kda_time_share`` read, and no loop left there (the XLA form has
+    the groups' ``lax.map`` and a ``while`` of a trip a chunk)."""
+    _, _, model, batch, variables = _build("bfloat16", **_WIDE)
+
+    def lowered():
+        return jax.jit(jax.grad(
+            lambda v, b: model.apply(v, b).total_loss.data)).trace(
+            variables, batch).lower(lowering_platforms=("tpu",)).as_text(
+            debug_info=True)
+
+    def loops(text):
+        return [line for line in text.split("\n") if "stablehlo.while" in line]
+
+    assert "tpu_custom_call" not in lowered()
+    xla_loops = loops(lowered())
+    _as_a_tpu_process(monkeypatch)
+    text = lowered()
+    named = dict(re.findall(r'(#loc\d+) = loc\("([^"]+)"', text))
+
+    def paths(line):
+        return [named.get(ref, "")
+                for ref in re.findall(r"loc\((#loc\d+)\)", line)]
+
+    # a kernel's ``jax.jit`` is a function of the module, called from the
+    # layer's scope: the compiled op's name is the two joined
+    sites = [(re.search(r"call @_(\w*?(?:fwd|bwd))_impl", line), line)
+             for line in text.split("\n")]
+    sites = [(found.group(1), paths(line)[0]) for found, line in sites
+             if found]
+    assert sorted(name for name, _ in sites) == [
+        "bwd", "fwd", "fwd", "scores_bwd", "scores_fwd", "scores_fwd"]
+    for _, path in sites:
+        assert scope_key(path + "/kda_rule_fwd/pallas_call") \
+            == "body/kda/rule", path
+    calls = [paths(line)[0].split("/")[0] for line in text.split("\n")
+             if "stablehlo.custom_call @tpu_custom_call" in line]
+    assert sorted(set(calls)) == ["kda_rule_bwd", "kda_rule_fwd",
+                                  "kda_scores_bwd", "kda_scores_fwd"]
+    assert len(xla_loops) >= 2 and len(loops(text)) < len(xla_loops)
+    assert not any("kda_0/rule" in path for line in loops(text)
+                   for path in paths(line))
+
+
+def rule_fact_counts_the_layers_test(monkeypatch):
+    """``hbnlp_delta_rule_kernel_layers``: layer ``kda`` by its OWN
+    predicate on the shapes it declares.  Where the rule is the pairs the
+    solve's one call holds every head's systems and the chunk states alive
+    are every head's."""
+    _, params, _, _, _ = _build("bfloat16", **_WIDE)
+    declared = kda_mod.kda.declares.recurrent
+    assert declared.rule(params) == (64, 3, 16, 16, 256)
+    assert declared.rule_applies is kr.kda_kernel_applies
+    assert delta_mod.gated_delta.declares.recurrent.rule_applies \
+        is recurrent.rule_kernel_applies
+    assert recurrent.rule_kernel_layers(params, "tpu") == 1
+    assert recurrent.rule_kernel_layers(params) == 0
+    assert declared.solve(params, "tpu") == (64, 1 * 4 * 3)
+    all_heads = recurrent.ssd_state_bytes(params)
+    assert all_heads == 1 * 4 * 3 * 16 * 16 * 2       # one group holds three
+    monkeypatch.setattr(kda_mod, "GROUP_BYTES", 256 * kda_mod._SUB * 16 * 4)
+    assert recurrent.ssd_state_bytes(params) == all_heads // 3
+    assert declared.solve(params) == (64, 4)
+    assert declared.solve(params, "tpu") == (64, 12)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert recurrent.ssd_state_bytes(params) == all_heads
+    assert declared.solve(params) == (64, 12)
+
+
+@pytest.mark.parametrize("config,layers", [("kimi_linear_48b_a3b", 4),
+                                           ("olmo_hybrid_7b", 3)])
+def the_cells_rule_layers_test(config, layers):
+    """The repo's Kimi-Linear cell: 4 ``kda`` layers take the pairs on a TPU,
+    none on the CPU; Olmo-Hybrid's 3 / 0 stand."""
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           config + ".json")) as f:
+        cell = json.load(f)["config"]
+    params = ModelParameter({**cell, "model_path": "/tmp/" + config})
+    assert recurrent.rule_kernel_layers(params, "tpu") == layers
+    assert recurrent.rule_kernel_layers(params, "cpu") == 0
+    assert recurrent.solve_kernel_layers(params, "tpu") == layers
+    fact = next(f for f in recurrent.FACTS
+                if f.metric == "hbnlp_delta_rule_kernel_layers")
+    assert fact.value(params, None, "tpu") == layers

@@ -528,7 +528,10 @@ def the_layers_offer_their_outputs_test():
     assert spec.conv(params) == (3 * (16 + 16 + 8), 4, 0)
     assert spec.solve(params, None) == (64, 2 * 1 * 3)
     assert spec.state_bytes(params) == 2 * 1 * 3 * 16 * 8 * 4
-    assert spec.rule is None
+    # the rule's shapes and its own predicate (PR 59): the toy's value width
+    # of 8 and its half lane tile of positions decline on any backend
+    assert spec.rule(params) == (64, 3, 16, 8, 64)
+    assert not spec.rule_applies(*spec.rule(params), "tpu")
 
 
 # ---- the configurations ----------------------------------------------------------
