@@ -13,6 +13,7 @@ from homebrewnlp_tpu.model import mamba as mamba_mod
 from homebrewnlp_tpu.model import recurrent
 from homebrewnlp_tpu.parallel import causal_conv as cc
 
+import harness
 from granite_test import _build
 
 
@@ -20,7 +21,12 @@ from granite_test import _build
 def tiles(monkeypatch):
     """Set the kernels' tile constants for one test.  ``_fwd_impl`` /
     ``_bwd_impl`` are ``jax.jit``s, whose traces do not see a module
-    constant change: drop them before and after."""
+    constant change: drop THEIRS before and after (``jax.clear_caches()``
+    would drop every program of the worker with them)."""
+    def drop():
+        cc._fwd_impl.clear_cache()
+        cc._bwd_impl.clear_cache()
+
     def set_tiles(seq_tile, piece_lanes=None):
         monkeypatch.setattr(cc, "_SEQ_TILE", seq_tile)
         if piece_lanes is not None:
@@ -28,10 +34,10 @@ def tiles(monkeypatch):
                                 (cc._FWD_PIECE[0], piece_lanes))
             monkeypatch.setattr(cc, "_BWD_PIECE",
                                 (cc._BWD_PIECE[0], piece_lanes))
-        jax.clear_caches()
+        drop()
     yield set_tiles
     monkeypatch.undo()
-    jax.clear_caches()
+    drop()
 
 
 def _reference(x, weight, bias):
@@ -176,26 +182,16 @@ def predicate_reads_the_backend_test():
     assert not cc.kernel_applies(4352, 8192, 4, 4096)
 
 
-# 4 heads x 32 = 128 channels of x, + 2 x 64 of B and C = 256 for the conv,
-# read from channel 128 of proj on; two sequence tiles of 128
-_KERNEL_SIZE = {"mamba_head_features": 32, "mamba_state": 64,
-                "sequence_length": 256}
-
-
-def _steer(monkeypatch, tiles):
-    """The layer as a TPU process would trace it, the kernels interpreted."""
-    tiles(128)
-    monkeypatch.setattr(mamba_mod, "kernel_applies", functools.partial(
-        cc.kernel_applies, backend="tpu"))
-    monkeypatch.setattr(
-        mamba_mod, "causal_conv_silu",
-        lambda x, w, b, offset: cc.causal_conv_silu(x, w, b, offset, True))
-
-
-def _loss_and_grads(model, variables, batch):
-    v = {k: jnp.asarray(a) for k, a in variables.items()}
-    return jax.value_and_grad(
-        lambda v: model.apply(v, batch).total_loss.data)(v)
+def steer_to_the_kernel(monkeypatch, tiles=None, module=mamba_mod):
+    """``module``'s layer as a TPU process would trace it, the kernels
+    interpreted (with ``tiles``: at sequence tiles of 128)."""
+    if tiles is not None:
+        tiles(128)
+    harness.steer(
+        monkeypatch, module,
+        kernel_applies=functools.partial(cc.kernel_applies, backend="tpu"),
+        causal_conv_silu=lambda x, w, b, offset: cc.causal_conv_silu(
+            x, w, b, offset, True))
 
 
 def declining_layer_traces_the_parents_ops_test(monkeypatch, tiles):
@@ -203,33 +199,8 @@ def declining_layer_traces_the_parents_ops_test(monkeypatch, tiles):
     traces the shifted multiplies, the very jaxpr it traces here."""
     _, params, model, batch, variables = _build("bfloat16")
     assert recurrent.conv_kernel_layers(params, "tpu") == 0
-    trace = lambda: str(jax.make_jaxpr(  # noqa: E731
-        lambda v: model.apply(v, batch).total_loss.data)(variables))
-    plain = trace()
-    _steer(monkeypatch, tiles)
-    assert trace() == plain and "mamba_conv" not in plain
+    plain = harness.step_jaxpr(model, variables, batch)
+    steer_to_the_kernel(monkeypatch, tiles)
+    assert harness.step_jaxpr(model, variables, batch) == plain
+    assert "mamba_conv" not in plain
     assert "pad" in plain
-
-
-@pytest.mark.parametrize("dtype,tolerance", [("float32", 2e-5),
-                                             ("bfloat16", 2.0 ** -5)])
-def granite_step_with_the_kernel_test(monkeypatch, tiles, dtype, tolerance):
-    """The toy granite step under ``jax.checkpoint`` + ``jax.grad``: loss and
-    every gradient with the kernel pair equal the fallback's."""
-    _, params, model, batch, variables = _build(dtype, **_KERNEL_SIZE)
-    assert params.memory_reduction_strategy == "checkpoint"
-    assert recurrent.conv_kernel_layers(params, "tpu") == 9
-    assert recurrent.conv_kernel_layers(params) == 0
-    want_loss, want = _loss_and_grads(model, variables, batch)
-    _steer(monkeypatch, tiles)
-    text = str(jax.make_jaxpr(
-        lambda v: model.apply(v, batch).total_loss.data)(variables))
-    # one jitted kernel call a layer, one trace of the kernel for them all
-    assert text.count("name=_fwd_impl") == 9 and "mamba_conv_fwd" in text
-    loss, got = _loss_and_grads(model, variables, batch)
-    assert abs(float(loss) - float(want_loss)) <= tolerance
-    assert set(got) == set(want)
-    for name in want:
-        a, r = (np.asarray(t[name], np.float32) for t in (got, want))
-        assert np.max(np.abs(a - r)) <= tolerance * max(
-            np.max(np.abs(r)), 1e-3), name

@@ -4,7 +4,6 @@ delta_rule`` and autodiff's gradients of it, against the recurrence run
 position by position, the predicate that chooses between them, and the layer
 with and without the kernels."""
 import functools
-import re
 
 import jax
 import jax.numpy as jnp
@@ -15,7 +14,8 @@ from homebrewnlp_tpu.model import gated_delta as delta_mod
 from homebrewnlp_tpu.model import recurrent
 from homebrewnlp_tpu.parallel import delta_rule as dr
 
-from olmo_hybrid_test import _ONE, _build, _reference
+import harness
+from olmo_hybrid_test import _build, _reference
 
 
 def _inputs(s, heads, decay, dk=8, dv=16, dtype=jnp.float32, batch=2, seed=0,
@@ -40,36 +40,21 @@ def _inputs(s, heads, decay, dk=8, dv=16, dtype=jnp.float32, batch=2, seed=0,
             *(jnp.asarray(t, jnp.float32) for t in (beta, g))), weights
 
 
-@pytest.fixture
-def interpreted(monkeypatch):
+def steer_interpreted(monkeypatch, heads_a_block=None):
     """``kernel_rule`` with both pairs interpreted, a head block of the
     caller's."""
-    def steer(heads_a_block=None):
-        for name in ("delta_rule_pair", "delta_strict"):
-            monkeypatch.setattr(delta_mod, name, functools.partial(
-                getattr(dr, name), heads_a_block=heads_a_block,
-                interpret=True))
-    return steer
+    harness.steer_interpreted(monkeypatch, delta_mod, dr, "delta_rule_pair", "delta_strict",
+                              heads_a_block=heads_a_block)
 
 
-def _value_and_grads(rule, inputs, weights, chunk):
-    def loss(*args):
-        o, biggest = rule(*args, chunk)
-        return jnp.sum(o.astype(jnp.float32) * weights), (o, biggest)
-    (_, (o, biggest)), grads = jax.jit(jax.value_and_grad(
-        loss, argnums=range(5), has_aux=True))(*inputs)
-    return (o, *grads), biggest
+@pytest.fixture
+def interpreted(monkeypatch):
+    return functools.partial(steer_interpreted, monkeypatch)
 
 
-_NAMES = "o dq dk dv dbeta dg".split()
-
-
-def _close(got, want, tolerance):
-    for name, g, w in zip(_NAMES, got, want):
-        g, w = (np.asarray(t, np.float32) for t in (g, w))
-        assert g.shape == w.shape and np.all(np.isfinite(g)), name
-        assert np.max(np.abs(g - w)) <= tolerance * max(np.max(np.abs(w)),
-                                                        1e-3), name
+_value_and_grads = harness.rule_value_and_grads
+_close = functools.partial(harness.assert_close_each,
+                           names="o dq dk dv dbeta dg".split())
 
 
 # (sequence, chunk, heads, heads a block, decay a position): eight chunks a
@@ -164,31 +149,10 @@ def _recurrence(q, k, v, beta, g):
     return jnp.moveaxis(o, 0, 1)
 
 
-def pair_rounds_no_lower_than_the_xla_form_test(interpreted):
-    """bfloat16 operands against the recurrence in float64: the pair is, in
-    the mean over six draws, no further off than the XLA form in bfloat16
-    (half as much again, for the rounding's luck) and never past the bound
-    ``scripts/kernel_parity.py`` holds it to on the chip."""
-    interpreted(2)
-    off = {"kernel": [], "xla": []}
-    for seed in range(6):
-        inputs, weights = _inputs(128, 3, 0.3, dk=16, dv=32,
-                                  dtype=jnp.bfloat16, seed=seed, batch=1)
-        with jax.enable_x64(True):
-            exact, _ = _value_and_grads(
-                lambda *args: (_recurrence(*args[:5]), 0.0),
-                tuple(jnp.asarray(np.asarray(t, np.float64)) for t in inputs),
-                jnp.asarray(np.asarray(weights, np.float64)), 64)
-            exact = [np.asarray(t) for t in exact]
-        for name, rule in (("kernel", delta_mod.kernel_rule),
-                           ("xla", delta_mod.delta_rule)):
-            got, _ = _value_and_grads(rule, inputs, weights, 64)
-            off[name].append([
-                float(np.max(np.abs(np.asarray(g, np.float64) - w))
-                      / np.max(np.abs(w))) for g, w in zip(got, exact)])
-    assert np.max(off["kernel"]) <= 2.0 ** -5
-    assert np.all(np.mean(off["kernel"], 0) <= 1.5 * np.mean(off["xla"], 0)), \
-        (np.mean(off["kernel"], 0), np.mean(off["xla"], 0))
+def _recurrence_rule(*args):
+    """``_recurrence`` as a rule: what bfloat16 operands are held against,
+    in float64 (``kernel_steps_test.py``)."""
+    return _recurrence(*args[:5]), 0.0
 
 
 @pytest.mark.parametrize(
@@ -225,24 +189,16 @@ _WIDE = {"delta_key_features": 16, "delta_value_features": 16,
          "sequence_length": 128, "delta_chunk": 32, "train_batch_size": 1}
 
 
-def _loss_and_grads(model, variables, batch):
-    v = {k: jnp.asarray(a) for k, a in variables.items()}
-    return jax.jit(jax.value_and_grad(
-        lambda v: model.apply(v, batch).total_loss.data))(v)
-
-
 def declining_layer_traces_the_parents_ops_test(monkeypatch):
     """The toy widths (8 key features a head): with the backend steered to
     the TPU the layer still traces ``grouped_rule``'s ops, and no Pallas
     call."""
     _, params, model, batch, variables = _build("bfloat16")
     assert recurrent.rule_kernel_layers(params, "tpu") == 0
-    trace = lambda: re.sub(r" at 0x[0-9a-f]+", "", str(jax.make_jaxpr(  # noqa: E731
-        lambda v: model.apply(v, batch).total_loss.data)(variables)))
-    plain = trace()
+    plain = harness.step_jaxpr(model, variables, batch)
     monkeypatch.setattr(delta_mod, "rule_kernel_applies", functools.partial(
         dr.rule_kernel_applies, backend="tpu"))
-    assert trace() == plain
+    assert harness.step_jaxpr(model, variables, batch) == plain
     assert "delta_rule_fwd" not in plain and "pallas_call" not in plain
 
 
@@ -269,30 +225,3 @@ def rule_fact_counts_the_layers_test(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert recurrent.ssd_state_bytes(params) == one_group
     assert declared.solve(params) == (32, 12)
-
-
-@pytest.mark.parametrize("dtype,tolerance", [("float32", 2e-5),
-                                             ("bfloat16", 2.0 ** -5)])
-def step_with_the_kernel_test(monkeypatch, interpreted, dtype, tolerance):
-    """The toy step under ``jax.checkpoint`` + ``jax.grad`` as a TPU process
-    at kernel shapes traces it: the rule is the pair where ``lax.map`` over
-    groups stood — traced once, the ``jax.jit`` around it —, and loss, every
-    gradient and the transform watch equal the XLA form's."""
-    _, params, model, batch, variables = _build(
-        dtype, **_WIDE, block_config=_ONE["gated_delta"])
-    assert params.memory_reduction_strategy == "checkpoint"
-    assert recurrent.rule_kernel_layers(params, "tpu") == 1
-    want_loss, want = _loss_and_grads(model, variables, batch)
-    monkeypatch.setattr(delta_mod, "rule_kernel_applies", functools.partial(
-        dr.rule_kernel_applies, backend="tpu"))
-    interpreted()
-    text = str(jax.make_jaxpr(
-        lambda v: model.apply(v, batch).total_loss.data)(variables))
-    assert text.count("name=_fwd_impl") == 1 and "delta_rule_fwd" in text
-    loss, got = _loss_and_grads(model, variables, batch)
-    assert abs(float(loss) - float(want_loss)) <= tolerance
-    assert set(got) == set(want)
-    for name in want:
-        a, r = (np.asarray(t[name], np.float32) for t in (got, want))
-        assert np.max(np.abs(a - r)) <= tolerance * max(
-            np.max(np.abs(r)), 1e-3), name

@@ -14,8 +14,7 @@ admission takes the ordinary prefix-hit path (prefill skipped over the
 injected span) — plus the two-replica REST round trip over the real
 ``/kv/blocks`` seam.
 
-Standalone-runnable (tier-1 truncates at 870s on this box;
-``scripts/run_late_markers.sh`` runs this suite in the late-marker set):
+Standalone-runnable:
 ``python -m pytest tests/disagg_test.py -q``
 """
 import base64

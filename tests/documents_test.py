@@ -4,8 +4,7 @@ One case per document: every repo path it spells (``scripts/x.py``,
 ``homebrewnlp_tpu/a/b.py``, ``infer/engine.py``, ``configs/x.json``, a bare
 ``main.py`` or ``BASELINE.json``) is a file of this checkout — whole, or as
 the tail of one file's path.  History documents (``CHANGES.md``, ``PERF.md``,
-``ROADMAP.md``, ``BASELINE.md``, ``VERDICT.md``, ``ADVICE.md``,
-``SURVEY.md``) tell what was and are out of scope; a document in scope that
+``ROADMAP.md``, ``BASELINE.md``, ``SURVEY.md``) tell what was and are out of scope; a document in scope that
 is history until its rewrite says so in its head (``HISTORY_HEADER``).
 """
 import functools
@@ -83,3 +82,16 @@ def named_paths_negative_control_test():
     text = ("run `scripts/gone.py`, then infer/engine.py; see "
             "https://example.org/x/config.json and <run>/report.json")
     assert named_paths(text) == ["infer/engine.py", "scripts/gone.py"]
+
+
+def a_runs_seconds_fold_by_file_test():
+    """What the end of a run's log says (``tests/conftest.py``, README
+    'Tests'): a pair a phase of a test, summed by file, the longest first."""
+    from durations import longest_files
+    total, files = longest_files([
+        ("tests/a_test.py::x_test[1]", 2.0), ("tests/b_test.py::y_test", 4.5),
+        ("tests/a_test.py::x_test[1]", 0.5), ("tests/a_test.py::x_test[2]", 1),
+        ("tests/c_test.py::z_test", 0.25)], top=2)
+    assert total == 8.25
+    assert files == [(4.5, 1, "tests/b_test.py"), (3.5, 2, "tests/a_test.py")]
+    assert longest_files([]) == (0, [])

@@ -3,7 +3,6 @@ chunked scan against the recurrence run position by position, the causal
 conv, grouped-query flash attention, the tied head's gradient, the program
 against the plain reference ``benchmark/reference/granite_4_0_h_micro.py``,
 the twenty-block period, and the new scopes and gauges."""
-import importlib
 import json
 import os
 
@@ -12,37 +11,37 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import harness
+from harness import REPO
 from homebrewnlp_tpu.analysis.cost_ledger import scope_key
 from homebrewnlp_tpu.config import ModelParameter
-from homebrewnlp_tpu.model import Model, mamba as mamba_mod
+from homebrewnlp_tpu.model import mamba as mamba_mod
 from homebrewnlp_tpu.parallel import flash_attention as fa
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TINY = {"depth": 1, "heads": 4, "features_per_head": 16,
         "sequence_length": 64, "train_batch_size": 2, "vocab_size": 384,
         "mamba_heads": 4, "mamba_head_features": 8, "mamba_state": 16,
         "mamba_chunk": 16, "tpu_size": 1, "use_checkpointing": False}
 
 
+CELL = "train_granite_4_0_h_micro_long"
+#: one short period that holds each layer kind of the published twenty
+#: blocks, for what is not about the pattern (a step's compile is the
+#: blocks' count: PR 60)
+SHORT = [{"skip": True, "layer": ["norm-rms-scale", layer]} for layer in (
+    "mamba", "mlp-silu", "attention-nope", "mlp-silu", "mamba")]
+
+
 def _reference():
-    return importlib.import_module("benchmark.reference.granite_4_0_h_micro")
+    return harness.reference("granite_4_0_h_micro")
 
 
 def _config(dtype: str = "float32", **extra) -> dict:
-    with open(os.path.join(REPO, "configs", "granite_4_0_h_micro.json")) as f:
-        return {**json.load(f), **TINY, "calculation_dtype": dtype, **extra}
+    return harness.config_of("granite_4_0_h_micro", TINY, dtype, **extra)
 
 
 def _build(dtype: str = "float32", **extra):
-    config = _config(dtype, **extra)
-    params = ModelParameter(config)
-    assert not params.unknown_config_keys
-    model = Model(params)
-    rng = np.random.default_rng(5)
-    shape = (config["train_batch_size"], config["sequence_length"], 1)
-    tokens = rng.integers(0, 256, shape).astype(np.int32)
-    batch = {"token_x": tokens, "token_y": np.roll(tokens, -1, axis=1)}
-    return config, params, model, batch, model.init(batch, seed=13)
+    return harness.build(_config(dtype, **extra))
 
 
 # ---- the chunked scan --------------------------------------------------------
@@ -77,19 +76,20 @@ def chunked_scan_is_the_recurrence_test(chunk, s, decay):
     def plain(*args):
         return jnp.sum(recurrence(*args) * weights)
 
-    got = mamba_mod.ssd(*inputs, chunk)[0]
+    got = jax.jit(lambda *a: mamba_mod.ssd(*a, chunk)[0])(*inputs)
     want = recurrence(*inputs)
     assert np.all(np.isfinite(np.asarray(got)))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
-    got_g = jax.grad(chunked, argnums=(0, 1, 2, 3, 4))(*inputs)
+    got_g = jax.jit(jax.grad(chunked, argnums=(0, 1, 2, 3, 4)))(*inputs)
     # the reference loops with fori_loop, which reverse mode cannot cross:
     # the same recurrence under scan's rule
-    want_g = jax.grad(lambda *a: jnp.sum(_scan_recurrence(*a) * weights),
-                      argnums=(0, 1, 2, 3, 4))(*inputs)
-    np.testing.assert_allclose(float(plain(*inputs)),
-                               float(jnp.sum(_scan_recurrence(*inputs)
-                                             * weights)), rtol=1e-5)
+    want_g = jax.jit(jax.grad(
+        lambda *a: jnp.sum(_scan_recurrence(*a) * weights),
+        argnums=(0, 1, 2, 3, 4)))(*inputs)
+    np.testing.assert_allclose(
+        float(plain(*inputs)), float(jax.jit(lambda *a: jnp.sum(
+            _scan_recurrence(*a) * weights))(*inputs)), rtol=1e-5)
     for g, w in zip(got_g, want_g):
         assert np.all(np.isfinite(np.asarray(g)))
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
@@ -169,10 +169,10 @@ def grouped_flash_matches_repeated_reference_test(heads, kv_heads, fused,
     kernel = grouped(lambda q, k, v: fa.flash_attention(
         q, k, v, 0.2, True, 64, 128, True, 64, 64))
     dense = grouped(lambda q, k, v: fa._xla_reference(q, k, v, 0.2, True))
+    want_out, *want = harness.with_input_grads(dense, (q, k, v), do)
     np.testing.assert_allclose(np.asarray(kernel(q, k, v)),
-                               np.asarray(dense(q, k, v)), atol=2e-5)
+                               np.asarray(want_out), atol=2e-5)
     got = jax.grad(lambda *a: jnp.sum(kernel(*a) * do), (0, 1, 2))(q, k, v)
-    want = jax.grad(lambda *a: jnp.sum(dense(*a) * do), (0, 1, 2))(q, k, v)
     for g, w in zip(got, want):
         assert g.shape == w.shape
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=5e-5)
@@ -221,17 +221,9 @@ def grouped_attention_layer_matches_its_equation_test():
     # the configuration's bfloat16 at the chip runs' bound
     ("bfloat16", 2 ** -5)])
 def program_matches_reference_test(dtype, tolerance):
-    config, params, model, batch, variables = _build(dtype)
-    info = model.apply(variables, batch)
-    got = np.asarray(info.token_out.data.astype(np.float32))[:, :, 0, :]
-    want = _reference().forward(variables, batch["token_x"][..., 0], config)
-    assert want.shape == got.shape == (2, 64, 384)
-    err = np.max(np.abs(want - got)) / np.max(np.abs(want))
-    assert err < tolerance, err
-    from benchmark.reference import common
-    loss = float(common.loss_of(want, batch["token_y"][..., 0], 0.0))
-    assert abs(loss - float(info.total_loss.data)) \
-        <= (2.0 ** -18 if dtype == "float32" else 2.0 ** -5)
+    got = harness.assert_program_matches_reference(
+        _reference(), _build(dtype), dtype, tolerance)
+    assert got.shape == (2, 64, 384)
 
 
 def reference_at_the_next_precision_below_fails_test():
@@ -252,20 +244,17 @@ def reference_at_the_next_precision_below_fails_test():
 def tied_head_gradient_is_the_sum_of_both_uses_test():
     """One parameter, read by the gather and by the head: its gradient is
     the untied twin's embedding gradient plus its head gradient."""
-    _, _, tied, batch, variables = _build("float32")
-    _, _, untied, _, twin = _build("float32", tie_word_embeddings=False)
+    _, _, tied, batch, variables = _build("float32", block_config=SHORT)
+    _, _, untied, _, twin = _build("float32", block_config=SHORT,
+                                   tie_word_embeddings=False)
     table = "gpt0/input0/gather0/embed0/normal_var0/var0"
     head = "gpt0/output0/embed0/normal_var0/var0"
     assert head not in variables and set(twin) == set(variables) | {head}
     twin = dict(variables, **{head: np.transpose(
         np.asarray(variables[table]), (1, 2, 0))[:, :, None, :]})
 
-    def grads(model, v):
-        v = {k: jnp.asarray(a) for k, a in v.items()}
-        return jax.value_and_grad(
-            lambda v: model.apply(v, batch).total_loss.data)(v)
-
-    (loss, got), (want_loss, want) = grads(tied, variables), grads(untied, twin)
+    loss, got = harness.loss_and_grads(tied, variables, batch)
+    want_loss, want = harness.loss_and_grads(untied, twin, batch)
     assert abs(float(loss) - float(want_loss)) < 1e-6
     both = np.asarray(want[table]) + np.transpose(
         np.asarray(want[head])[:, :, 0, :], (2, 0, 1))
@@ -353,58 +342,24 @@ def new_layers_fold_into_their_scopes_test(path, scope):
     assert scope_key(path) == scope
 
 
-@pytest.fixture(scope="module")
-def v5e():
-    """One chip of a described (not attached) v5e; libtpu is loaded here,
-    inside a test of this file, never while a module is imported."""
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    from jax.experimental import topologies
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return jax.sharding.SingleDeviceSharding(topo.devices[0])
-
-
-_MAMBA_HLO = []
-
-
 def _mamba_layer_hlo(v5e, monkeypatch) -> str:
     """One ``mamba`` layer at the published widths, 1 x 8,192 tokens, loss
     and gradients compiled for a v5e as a TPU process traces them (once a
     module: both kernel tests read the same text)."""
-    from benchmark.lib.cell import load_cell
     from homebrewnlp_tpu.model import recurrent
-    if _MAMBA_HLO:
-        return _MAMBA_HLO[0]
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cell = load_cell("train_granite_4_0_h_micro_long").model_config()
-    assert cell["block_config"][0]["layer"][-1] == "mamba"
-    params = ModelParameter({**cell, "block_config": cell["block_config"][:1],
-                             "vocab_size": 512, "model_path": "/tmp/granite"})
+    params, hlo = harness.cell_layer_hlo(v5e, monkeypatch, CELL, 0)
+    assert params.block_config[0].layer[-1] == "mamba"
+    assert params.sequence_length == 8192
     assert recurrent.conv_kernel_layers(params) == 1
     assert recurrent.scan_kernel_layers(params) == 1
-    model = Model(params)
-    batch = {k: np.zeros((1, 8192, 1), np.int32)
-             for k in ("token_x", "token_y")}
-    variables = model.init(batch, seed=1)
-    avals = [{k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=v5e)
-              for k, v in tree.items()} for tree in (variables, batch)]
-    _MAMBA_HLO.append(jax.jit(jax.value_and_grad(
-        lambda v, b: model.apply(v, b).total_loss.data)).lower(
-        *avals).compile().as_text())
-    return _MAMBA_HLO[0]
+    return hlo
 
 
 def _kernel_calls(hlo: str, prefix: str):
     """``(name, op_name)`` of the Pallas calls named ``prefix*``, each in
     its three forms: forward, ``checkpoint``'s replay, backward."""
-    import re
-    calls = [(re.sub(r"\.\d+$", "", name), op_name) for name, op_name in
-             re.findall(r'%([\w.-]+) = [^\n]*?custom_call_target='
-                        r'"tpu_custom_call"[^\n]*?op_name="([^"]+)"', hlo)
-             if name.startswith(prefix)]
+    calls = [call for call in harness.kernel_calls(hlo)
+             if call[0].startswith(prefix)]
     assert sorted(name for name, _ in calls) \
         == [prefix + "bwd", prefix + "fwd", prefix + "fwd"]
     forms = sorted(op_name for _, op_name in calls)
@@ -447,286 +402,11 @@ def scan_kernels_keep_their_scope_and_names_test(v5e, monkeypatch):
                              .split("(")[0]), line
 
 
-@pytest.mark.parametrize("groups", [1, 4])
-def scan_pair_compiles_at_a_chunk_of_one_lane_tile_test(v5e, groups):
-    """PR 54: Mosaic accepts the scan pair at Nemotron-3's shapes — 64 heads
-    of 64, a state of 128, a chunk of ONE lane tile (the last lane's decay is
-    a masked lane sum there, ``_head``), and ``B`` / ``C`` in 4 groups whose
-    two head blocks each share one ``scores`` tile — as at one group."""
-    from homebrewnlp_tpu.parallel import ssd_scan as sk
-    b, s, h, p, n, chunk = 1, 1024, 64, 64, 128, 128
-    assert sk.ssd_kernel_applies(s, chunk, h, p, n, "tpu", groups)
-    assert sk.head_block(h, p, groups) == 8
-    cols = (b, s, groups, n) if groups > 1 else (b, s, n)
-    avals = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
-             for shape, dtype in (((b, s, h, p), jnp.bfloat16),
-                                  ((b, s, h), jnp.float32),
-                                  ((b, s, h), jnp.float32),
-                                  (cols, jnp.bfloat16), (cols, jnp.bfloat16))]
-    hlo = jax.jit(jax.value_and_grad(
-        lambda *a: jnp.sum(sk.ssd_scan(*a, chunk)), argnums=(0, 1, 2, 3, 4))
-    ).lower(*avals).compile().as_text()
-    assert "ssd_scan_fwd" in hlo and "ssd_scan_bwd" in hlo
-
-
-def delta_layers_kernels_keep_their_scopes_test(v5e, monkeypatch):
-    """One ``gated_delta`` layer at Olmo-Hybrid's published widths, 1 x 8,192
-    tokens (half the cell's), compiled for a v5e as a TPU process traces it:
-    the bias-free conv over 11,520 channels is the same Pallas pair in its
-    three forms, every one folds into ``body/gated_delta/conv``; the rule's
-    triangular solve (PR 37) is the pair of ``parallel/delta_solve.py`` and
-    what is around it (PR 50) the two pairs of ``parallel/delta_rule.py``
-    (``delta_strict_*`` makes the solve's input, ``delta_rule_*`` runs the
-    rule): ONE ``delta_rule_bwd``, ``delta_solve_bwd`` and
-    ``delta_strict_bwd`` a layer and as many of each ``_fwd`` as the memory
-    plan gives — two (the step's forward and the block's replay) where the
-    ``recurrent`` kind saves the rule's output alone, so that the replay
-    makes ``T`` and the entering states again; one if they ride with it —
-    the solve on
-    all 3,840 systems of the layer at once, operands ``[systems * 64, 64]``
-    (a bitcast of XLA's ``[.., 64, 64]``), the rule on the sequence-minor
-    layout the conv's kernels write: no transposing copy of a large operand
-    beside any.  Mosaic accepts all six, their ops carry
-    ``gated_delta_0/delta_rule/`` (the solve's ``../solve/``) and fold into
-    ``body/gated_delta/delta_rule``, which ``delta_rule_time_share`` and
-    ``delta_rule_roofline`` read, and none bears a name another metric's
-    reader takes."""
-    import re
-    from benchmark.lib.cell import load_cell
-    from homebrewnlp_tpu.model import gated_delta as delta_mod
-    from homebrewnlp_tpu.model import recurrent, remat
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cell = load_cell("train_olmo_hybrid_7b_long").model_config()
-    assert cell["block_config"][0]["layer"][0] == "gated_delta"
-    params = ModelParameter({**cell, "block_config": cell["block_config"][:1],
-                             "vocab_size": 512, "sequence_length": 8192,
-                             "model_path": "/tmp/olmo"})
-    assert recurrent.conv_kernel_layers(params) == 1
-    assert recurrent.solve_kernel_layers(params) == 1
-    assert recurrent.rule_kernel_layers(params) == 1
-    # what the plan saves of the rule: its output alone -> the replay runs
-    # both forward kernels again
-    assert remat.stash_plan(params)["recurrent"][0] == 1
-    saved = delta_mod.gated_delta.declares.offer(params, set()).names
-    forwards = 2 if saved == ("gated_delta_out",) else 1
-    model = Model(params)
-    batch = {k: np.zeros((1, 8192, 1), np.int32)
-             for k in ("token_x", "token_y")}
-    variables = model.init(batch, seed=1)
-    avals = [{k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=v5e)
-              for k, v in tree.items()} for tree in (variables, batch)]
-    hlo = jax.jit(jax.value_and_grad(
-        lambda v, b: model.apply(v, b).total_loss.data)).lower(
-        *avals).compile().as_text()
-    calls = re.findall(r'%([\w.-]+) = ([^\n]*?)custom_call_target='
-                       r'"tpu_custom_call"[^\n]*?op_name="([^"]+)"', hlo)
-    assert sorted(re.sub(r"\.\d+$", "", name) for name, _, _ in calls) \
-        == sorted(["delta_rule_bwd", "delta_solve_bwd", "delta_strict_bwd",
-                   "mamba_conv_bwd"]
-                  + ["delta_rule_fwd", "delta_solve_fwd", "delta_strict_fwd"]
-                  * forwards + ["mamba_conv_fwd"] * 2)
-
-    def operand_bytes(name):
-        shape = re.search(rf"%{re.escape(name)} = (\w+)\[([\d,]*)\]", hlo)
-        return np.dtype(shape.group(1).replace("bf16", "float16")
-                        .replace("f32", "float32")).itemsize * int(np.prod(
-            [int(d) for d in shape.group(2).split(",") if d]))
-
-    forms = {"delta_solve": [], "delta_strict": [], "delta_rule": []}
-    for name, line, op_name in calls:
-        assert not re.match(r"flash_|map_mixer_", name)
-        if name.startswith("mamba_conv"):
-            assert scope_key(op_name) == "body/gated_delta/conv", op_name
-            continue
-        assert scope_key(op_name) == "body/gated_delta/delta_rule", op_name
-        assert "gated_delta_0/delta_rule/" in op_name, op_name
-        # nothing laid out again but the float32 [1, 30, 8192] rows of gamma
-        # (``copy-done`` is XLA's move between memory spaces, one layout)
-        for copied in re.findall(r"%((?:copy|transpose)(?:\.\d+)?)(?![\w.-])",
-                                 line.split("custom-call(")[1]):
-            assert operand_bytes(copied) <= 30 * 8192 * 4, (name, copied)
-        if name.startswith(("delta_solve", "delta_strict")):
-            assert re.search(r"gated_delta_0/delta_rule/.*solve/", op_name), \
-                op_name
-        if name.startswith("delta_solve"):
-            # 128 chunks x 30 heads x 64 rows
-            assert line.startswith("f32[245760,64]{1,0"), line
-        elif name.startswith("delta_strict_fwd"):
-            assert line.startswith("f32[1,128,30,64,64]{4,3,2,1,0"), line
-        elif name.startswith("delta_rule_fwd"):
-            # o^T and the entering states of every chunk and head
-            assert line.startswith("(bf16[1,5760,8192]{2,1,0") \
-                and "bf16[1,128,30,192,96]{4,3,2,1,0" in line, line
-        forms[name[:name.index("_", 6)]].append((
-            name.split(".")[0].endswith("bwd"),
-            "rematted_computation" in op_name,
-            "/transpose(jvp(" in op_name))
-    # the step's forward; the block's replay and the backward, both inside
-    # the transposed program
-    for kernel, seen in forms.items():
-        assert sorted(seen) == sorted(
-            [(False, False, False), (True, False, True)]
-            + [(False, True, True)] * (forwards - 1)), (kernel, seen)
-
-
-def held_row_buffers_are_allocated_where_they_are_filled_test(v5e,
-                                                              monkeypatch):
-    """One ``moe`` layer of the Laguna cell (8 of 256 experts held, eight
-    slots a token) at 1 x 8,192 tokens, compiled for a v5e as a TPU process
-    traces it (ISSUE 47): the held path's loops are ``while`` ops in the
-    layer's three scopes; their row buffers come from ``moe_held_rows_alloc``
-    calls — a custom call with an operand, scheduled where it is filled — and
-    not from operand-less ``AllocateBuffer``s, which XLA schedules at the
-    step's start (every layer's buffers alive at once: the cell's step then
-    needs 19 GB); no buffer of the bound's rows is copied."""
-    import re
-    from benchmark.lib.cell import load_cell
-    from homebrewnlp_tpu.model import moe as moe_mod
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cell = load_cell("train_laguna_s_2_1_ep32_s8k").model_config()
-    block = cell["block_config"][1]
-    assert block["layer"][-1].startswith("moe")
-    params = ModelParameter({**cell, "block_config": [block],
-                             "input_block_config": [], "vocab_size": 512,
-                             "train_batch_size": 1,
-                             "model_path": "/tmp/laguna"})
-    rows = moe_mod.moe_held_rows(params)
-    assert rows == 8192 * 8
-    model = Model(params)
-    batch = {k: np.zeros((1, 8192, 1), np.int32)
-             for k in ("token_x", "token_y")}
-    variables = model.init(batch, seed=1)
-    avals = [{k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=v5e)
-              for k, v in tree.items()} for tree in (variables, batch)]
-    hlo = jax.jit(jax.value_and_grad(
-        lambda v, b: model.apply(v, b).total_loss.data)).lower(
-        *avals).compile().as_text()
-    buffer = rf"bf16\[{rows},(?:3072|1024)\]"
-    assert not re.search(rf"= {buffer}\S* custom-call\(\)", hlo)
-    assert not re.search(rf"= {buffer}\S* copy\(", hlo)
-    allocs = re.findall(rf'= {buffer}[^\n]*?custom_call_target='
-                        r'"tpu_custom_call"[^\n]*?op_name="([^"]+'
-                        r'moe_held_rows_alloc[^"]*)"', hlo)
-    # dispatch forward and replay; the activation forward, replay and its
-    # backward's two; combine's backward
-    assert sorted(scope_key(op) for op in allocs) \
-        == ["body/moe/combine"] + ["body/moe/dispatch"] * 2 \
-        + ["body/moe/experts"] * 4
-    loops = re.findall(r'= [^\n]*? while\([^\n]*?op_name="([^"]+moe_0[^"]+)"',
-                       hlo)
-    held = [op for op in loops if "searchsorted" not in op]
-    assert {scope_key(op) for op in held} == {
-        "body/moe/dispatch", "body/moe/experts", "body/moe/combine"}
-    # dispatch 3 (forward, replay, backward), the fan-out's backward 1, the
-    # activation 3, combine 2 (its forward is not replayed)
-    assert len(held) == 9
-
-
-@pytest.mark.parametrize("cell,layer,scope", [
-    ("train_granite_4_0_h_micro_long", "attention-nope", "body/attention"),
-    ("train_olmo_hybrid_7b_long", "attention-nope-qk_norm", "body/attention"),
-    ("train_zaya1_8b_ep2_s16k",
-     "cca-q_heads8-kv_heads2-rotary_pct50-theta5000000", "body/cca")])
-def saved_flash_outputs_keep_their_scope_test(v5e, monkeypatch, cell, layer,
-                                              scope):
-    """One flash layer of a ``checkpoint`` cell at its published widths and
-    the cell's sequence, compiled for a v5e as a TPU process traces it, with
-    the attention kind riding the block's ``jax.checkpoint`` (PR 40): ONE
-    forward kernel — the step's, outside ``flash_attention``'s
-    ``custom_vjp``, none in the replay — and one fused backward, both still
-    named ``flash_*`` (the ``^flash_`` readers) and folded into the layer's
-    scope (``scope_mixing_time_share`` / ``scope_cca_time_share``); under
-    ``"recompute"`` the same layer runs the forward twice."""
-    import re
-    from benchmark.lib.cell import load_cell
-    from homebrewnlp_tpu.model import remat
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    config = load_cell(cell).model_config()
-    block = next(b for b in config["block_config"] if layer in b["layer"])
-    calls = {}
-    for policy in ("auto", "recompute"):
-        params = ModelParameter({**config, "block_config": [block],
-                                 "depth": 1, "vocab_size": 512,
-                                 "remat_policy": policy,
-                                 "model_path": "/tmp/granite"})
-        assert (remat.stash_plan(params)["attention"][0] == 1) \
-            == (policy == "auto")
-        model = Model(params)
-        batch = {k: np.zeros((1, params.sequence_length, 1), np.int32)
-                 for k in ("token_x", "token_y")}
-        variables = model.init(batch, seed=1)
-        avals = [{k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=v5e)
-                  for k, v in tree.items()} for tree in (variables, batch)]
-        hlo = jax.jit(jax.value_and_grad(
-            lambda v, b: model.apply(v, b).total_loss.data)).lower(
-            *avals).compile().as_text()
-        calls[policy] = re.findall(
-            r'%([\w.-]+) = [^\n]*?custom_call_target="tpu_custom_call"'
-            r'[^\n]*?op_name="([^"]+)"', hlo)
-        for name, op_name in calls[policy]:
-            assert name.startswith("flash_") and scope_key(op_name) == scope, \
-                (name, op_name)
-    kinds = {policy: sorted(re.sub(r"\.\d+$", "", name) for name, _ in found)
-             for policy, found in calls.items()}
-    assert kinds["auto"] == ["flash_bwd_fused_causal", "flash_fwd_causal"]
-    assert kinds["recompute"] == ["flash_bwd_fused_causal",
-                                  "flash_fwd_causal", "flash_fwd_causal"]
-    assert not any("rematted_computation" in op_name and "flash_fwd" in name
-                   for name, op_name in calls["auto"])
-    assert sum("rematted_computation" in op_name and "flash_fwd" in name
-               for name, op_name in calls["recompute"]) == 1
-
-
-def selected_kernels_keep_their_scope_and_names_test(v5e, monkeypatch):
-    """MiniCPM-SALA's sparse layer at its published widths and the cell's
-    16,384 tokens, compiled for a v5e as a TPU process traces it (PR 46):
-    Mosaic accepts the three selected kernels, each runs ONCE — the forward
-    outside the block's replay, which reads the saved ``(out, lse)`` and the
-    saved choice — all are named ``flash_*_select`` (the
-    ``sala_sparse_flash_*`` readers) and fold into
-    ``body/attention/sparse_attention/attend``; no causal kernel runs, and
-    the indexer's ops carry their steps.  Under ``"recompute"`` the replay
-    runs the forward kernel again."""
-    import re
-    from benchmark.lib.cell import load_cell
-    from homebrewnlp_tpu.model import remat
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    config = load_cell("train_minicpm_sala_tp2_long").model_config()
-    block = config["block_config"][0]
-    assert "sparse" in block["layer"][1]
-    kinds = {}
-    for policy in ("auto", "recompute"):
-        params = ModelParameter({**config, "block_config": [block],
-                                 "vocab_size": 512, "remat_policy": policy,
-                                 "model_path": "/tmp/granite"})
-        assert (remat.stash_plan(params)["attention"][0] == 1) \
-            == (policy == "auto")
-        model = Model(params)
-        batch = {k: np.zeros((1, params.sequence_length, 1), np.int32)
-                 for k in ("token_x", "token_y")}
-        variables = model.init(batch, seed=1)
-        avals = [{k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=v5e)
-                  for k, v in tree.items()} for tree in (variables, batch)]
-        hlo = jax.jit(jax.value_and_grad(
-            lambda v, b: model.apply(v, b).total_loss.data)).lower(
-            *avals).compile().as_text()
-        calls = re.findall(
-            r'%([\w.-]+) = [^\n]*?custom_call_target="tpu_custom_call"'
-            r'[^\n]*?op_name="([^"]+)"', hlo)
-        for name, op_name in calls:
-            assert re.match(r"flash_.*_select", name), name
-            assert scope_key(op_name) \
-                == "body/attention/sparse_attention/attend", op_name
-        kinds[policy] = sorted(re.sub(r"\.\d+$", "", name)
-                               for name, _ in calls)
-        names = set(re.findall(r'op_name="([^"]*)"', hlo))
-        for step in ("compress", "index", "select"):
-            assert any(scope_key(n)
-                       == f"body/attention/sparse_attention/{step}"
-                       for n in names), step
-    assert kinds["auto"] == ["flash_bwd_dkv_select", "flash_bwd_dq_select",
-                             "flash_fwd_select"]
-    assert kinds["recompute"] == kinds["auto"] + ["flash_fwd_select"]
+def saved_flash_outputs_keep_their_scope_test(v5e, monkeypatch):
+    """The cell's ``attention-nope`` layer (``harness.py
+    saved_flash_outputs_keep_their_scope``)."""
+    harness.saved_flash_outputs_keep_their_scope(
+        v5e, monkeypatch, CELL, "attention-nope", "body/attention")
 
 
 def experts_rule_declines_without_a_moe_layer_test():
@@ -770,17 +450,13 @@ def step_reports_the_log_decay_watch_test(kernel, monkeypatch):
     call publishes the first step's ``hbnlp_ssd_log_decay_min`` — the same
     number with the scan's Pallas pair (PR 48, interpreted) as with XLA's
     einsums: the first step's is the minimum of the same cumulative sum."""
-    import functools
     from homebrewnlp_tpu import telemetry
-    from homebrewnlp_tpu.parallel import ssd_scan
     from homebrewnlp_tpu.train import Trainer
     if kernel:
-        monkeypatch.setattr(mamba_mod, "ssd_kernel_applies",
-                            lambda *_, **__: True)
-        monkeypatch.setattr(mamba_mod, "ssd_scan", functools.partial(
-            ssd_scan.ssd_scan, interpret=True))
+        harness.steer_mamba_scan(monkeypatch)
     _, params, model, batch, _ = _build(
-        "float32", telemetry_enabled=True, sequence_length=32,
+        "float32", block_config=SHORT, telemetry_enabled=True,
+        sequence_length=32,
         learning_rate=0.01,
         learning_rate_config={"linear_warmup": {"final_step": 1}})
     batch = {k: v[:, :32] for k, v in batch.items()}
@@ -803,29 +479,3 @@ def step_reports_the_log_decay_watch_test(kernel, monkeypatch):
 _LOG_DECAY_MIN = []
 
 
-@pytest.mark.parametrize("batch,heads", [(32, 8), (128, 4)],
-                         ids=["flagship_chip", "dp2tp2_chip"])
-def map_mixer_backward_compiles_to_one_map_test(v5e, batch, heads):
-    """The map mixer's backward at one chip's share of the two flagship
-    cells (sequence 512, 512 features a head, bfloat16), compiled for a v5e:
-    Mosaic accepts the batch-sweeping dbias kernel, it writes ONE float32
-    ``[heads, 512, 512]`` map, and no per-(batch, head) map is left in the
-    program.  (This file holds the described topology: a second file's
-    fixture would find libtpu taken.)"""
-    import re
-    from homebrewnlp_tpu.parallel import map_mixer as mm
-    s = f = 512
-    bias = jax.ShapeDtypeStruct((heads, s, s), jnp.bfloat16, sharding=v5e)
-    act = jax.ShapeDtypeStruct((batch * heads, s, f), jnp.bfloat16,
-                               sharding=v5e)
-    block = mm.kernel_block(s, cap=512)
-    hlo = jax.jit(lambda bias_, v, g: mm._bwd_impl(
-        bias_, v, g, True, block, block, False)).lower(
-        bias, act, act).compile().as_text()
-    calls = dict(re.findall(r"%(map_mixer_\w+?)(?:\.\d+)? = (\w+\[[\d,]*\])",
-                            hlo))
-    assert calls == {
-        "map_mixer_bwd_dbias_causal": f"f32[{heads},512,512]",
-        "map_mixer_bwd_dval_causal": f"bf16[{batch * heads},512,512]"}, calls
-    assert f"f32[{batch * heads},512,512]" not in hlo
-    assert f"f32[{batch},{heads},512,512]" not in hlo

@@ -9,7 +9,6 @@ body sees a positive operand; the predicate that chooses between the two
 forms, the layer with and without the kernels, what a step lowered for a TPU
 carries, and the start-up fact."""
 import functools
-import importlib
 import json
 import os
 import re
@@ -26,15 +25,20 @@ from homebrewnlp_tpu.model import kda as kda_mod
 from homebrewnlp_tpu.model import recurrent
 from homebrewnlp_tpu.parallel import kda_rule as kr
 
+import harness
 from kimi_linear_test import _block, _build
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_NAMES = "o dq dk dv dbeta dg".split()
-
-
 def _recurrence():
-    return importlib.import_module(
-        "benchmark.reference.kimi_linear_48b_a3b").recurrence
+    return harness.reference("kimi_linear_48b_a3b").recurrence
+
+
+@functools.lru_cache(maxsize=None)
+def recurrence_rule():
+    """The recurrence behind the layer's norms, as a rule: what the pairs and
+    bfloat16 operands (``kernel_steps_test.py``) are held against."""
+    recurrence = _recurrence()
+    return kda_mod.normalised(lambda *args: (recurrence(*args[:5]),))
 
 
 def _inputs(s, heads, low, dk=16, dv=16, dtype=jnp.float32, seed=0,
@@ -62,33 +66,21 @@ def _inputs(s, heads, low, dk=16, dv=16, dtype=jnp.float32, seed=0,
             *(jnp.asarray(t, jnp.float32) for t in (beta, g))), weights
 
 
-@pytest.fixture
-def interpreted(monkeypatch):
+def steer_interpreted(monkeypatch, heads_a_block=None):
     """``kernel_rule`` with both pairs interpreted, a head block of the
     caller's."""
-    def steer(heads_a_block=None):
-        for name in ("kda_rule_pair", "kda_scores"):
-            monkeypatch.setattr(kda_mod, name, functools.partial(
-                getattr(kr, name), heads_a_block=heads_a_block,
-                interpret=True))
-    return steer
+    harness.steer_interpreted(monkeypatch, kda_mod, kr, "kda_rule_pair", "kda_scores",
+                              heads_a_block=heads_a_block)
 
 
-def _value_and_grads(rule, inputs, weights, chunk):
-    def loss(*args):
-        o, *statistics = rule(*args, chunk)
-        return jnp.sum(o.astype(jnp.float32) * weights), (o, statistics)
-    (_, (o, statistics)), grads = jax.jit(jax.value_and_grad(
-        loss, argnums=range(5), has_aux=True))(*inputs)
-    return (o, *grads), statistics
+@pytest.fixture
+def interpreted(monkeypatch):
+    return functools.partial(steer_interpreted, monkeypatch)
 
 
-def _close(got, want, tolerance):
-    for name, g, w in zip(_NAMES, got, want):
-        g, w = (np.asarray(t, np.float32) for t in (g, w))
-        assert g.shape == w.shape and np.all(np.isfinite(g)), name
-        assert np.max(np.abs(g - w)) <= tolerance * max(np.max(np.abs(w)),
-                                                        1e-3), name
+_value_and_grads = harness.rule_value_and_grads
+_close = functools.partial(harness.assert_close_each,
+                           names="o dq dk dv dbeta dg".split())
 
 
 # (sequence, chunk, heads, heads a block, the steepest log-decay a position):
@@ -184,31 +176,6 @@ def a_flat_decay_is_gated_deltas_rule_test(interpreted):
     _close(got, want, 2e-5)
 
 
-def pairs_round_no_lower_than_the_xla_form_test(interpreted):
-    """bfloat16 operands against the recurrence in float32: the pairs are,
-    in the mean over four draws, no further off than the XLA form in
-    bfloat16 (half as much again, for the rounding's luck)."""
-    interpreted(2)
-    recurrence = _recurrence()
-    off = {"kernel": [], "xla": []}
-    for seed in range(4):
-        inputs, weights = _inputs(128, 3, -0.3, dk=16, dv=32,
-                                  dtype=jnp.bfloat16, seed=seed)
-        exact, _ = _value_and_grads(
-            kda_mod.normalised(lambda *args: (recurrence(*args[:5]),)),
-            tuple(t.astype(jnp.float32) for t in inputs), weights, 64)
-        for name, rule in (("kernel", kda_mod.kernel_rule),
-                           ("xla", kda_mod.normalised(kda_mod.kda_rule))):
-            got, _ = _value_and_grads(rule, inputs, weights, 64)
-            off[name].append([
-                float(np.max(np.abs(np.asarray(g, np.float32) - np.asarray(w)))
-                      / np.max(np.abs(np.asarray(w))))
-                for g, w in zip(got, exact)])
-    assert np.max(off["kernel"]) <= 2.0 ** -4
-    assert np.all(np.mean(off["kernel"], 0) <= 1.5 * np.mean(off["xla"], 0)), \
-        (np.mean(off["kernel"], 0), np.mean(off["xla"], 0))
-
-
 def what_the_kernels_keep_in_float32_is_felt_test(interpreted, monkeypatch):
     """``KEPT`` reaches the kernels' path too: at bfloat16 the cumulative
     log-decays, the solve's input and the state the walk carries are rounded
@@ -233,8 +200,7 @@ def no_exp_of_a_positive_decay_difference_is_formed_test(
     (or -inf) on log-decays of -20 a step: the jaxpr is walked with every
     ``exp``'s operand recorded, INTO the ``pallas_call`` bodies (their own
     ``exp``s report through a callback while the call is interpreted)."""
-    from jax.extend.core import Literal
-    inside, seen = [], []
+    inside = []
     if rule == "kernels":
         class Spied:
             """``jax.numpy`` as the kernels' module sees it, ``exp``
@@ -258,36 +224,8 @@ def no_exp_of_a_positive_decay_difference_is_formed_test(
         lambda *a: jnp.sum(fn(*a, 32)[0]), argnums=(0, 1, 2, 3, 4)))(
         q, k, v, beta, g)
 
-    def has_exp(eqn):
-        inner = [getattr(p, "jaxpr", p) for p in eqn.params.values()]
-        return eqn.primitive.name == "exp" or any(
-            has_exp(e) for j in inner if hasattr(j, "eqns") for e in j.eqns)
-
-    def walk(jaxpr, consts, args):
-        env = dict(zip(jaxpr.constvars, consts))
-        env.update(zip(jaxpr.invars, args))
-
-        def read(var):
-            return var.val if isinstance(var, Literal) else env[var]
-
-        for eqn in jaxpr.eqns:
-            values = [read(v) for v in eqn.invars]
-            if eqn.primitive.name == "exp":
-                seen.append(float(jnp.max(values[0])))
-            inner = eqn.params.get("jaxpr", eqn.params.get("call_jaxpr"))
-            if hasattr(inner, "consts") and eqn.primitive.name != "scan":
-                out = walk(inner.jaxpr, inner.consts, values)
-            else:
-                # the state walk, the solve: no exp of their own; a Pallas
-                # call: its body's report through the callback
-                assert eqn.primitive.name in ("exp", "pallas_call") \
-                    or not has_exp(eqn), eqn
-                out = eqn.primitive.bind(*values, **eqn.params)
-                out = out if eqn.primitive.multiple_results else [out]
-            env.update(zip(eqn.outvars, out))
-        return [read(v) for v in jaxpr.outvars]
-
-    out = walk(closed.jaxpr, closed.consts, [q, k, v, beta, g])
+    out, seen = harness.exp_operands(closed, [q, k, v, beta, g],
+                                     ("exp", "pallas_call"))
     jax.effects_barrier()
     assert all(bool(jnp.all(jnp.isfinite(t))) for t in out)
     if rule == "kernels":
@@ -335,12 +273,6 @@ def _as_a_tpu_process(monkeypatch):
         kr.kda_kernel_applies, backend="tpu"))
 
 
-def _loss_and_grads(model, variables, batch):
-    v = {k: jnp.asarray(a) for k, a in variables.items()}
-    return jax.jit(jax.value_and_grad(
-        lambda v: model.apply(v, batch).total_loss.data))(v)
-
-
 @pytest.mark.parametrize("extra", [
     {}, {"kda_key_features": 24}, {"sequence_length": 64},
     {"sequence_length": 192}],
@@ -353,39 +285,10 @@ def declining_layer_traces_the_xla_form_test(monkeypatch, extra):
     _, params, model, batch, variables = _build(
         "bfloat16", block_config=[_block("kda")], **extra)
     assert recurrent.rule_kernel_layers(params, "tpu") == 0
-    trace = lambda: re.sub(r" at 0x[0-9a-f]+", "", str(jax.make_jaxpr(  # noqa: E731
-        lambda v: model.apply(v, batch).total_loss.data)(variables)))
-    plain = trace()
+    plain = harness.step_jaxpr(model, variables, batch)
     _as_a_tpu_process(monkeypatch)
-    assert trace() == plain
+    assert harness.step_jaxpr(model, variables, batch) == plain
     assert "kda_rule_fwd" not in plain and "pallas_call" not in plain
-
-
-@pytest.mark.parametrize("dtype,tolerance", [("float32", 2e-5),
-                                             ("bfloat16", 2.0 ** -5)])
-def step_with_the_kernels_test(monkeypatch, interpreted, dtype, tolerance):
-    """The toy step under ``jax.checkpoint`` + ``jax.grad`` as a TPU process
-    at kernel shapes traces it: the rule is the two pairs where ``lax.map``
-    over groups stood — each traced once, the ``jax.jit`` around it —, and
-    loss and every gradient equal the XLA form's."""
-    _, params, model, batch, variables = _build(dtype, **_WIDE)
-    assert params.memory_reduction_strategy == "checkpoint"
-    assert recurrent.rule_kernel_layers(params, "tpu") == 1
-    want_loss, want = _loss_and_grads(model, variables, batch)
-    _as_a_tpu_process(monkeypatch)
-    interpreted()
-    text = str(jax.make_jaxpr(
-        lambda v: model.apply(v, batch).total_loss.data)(variables))
-    assert text.count("name=_fwd_impl") == 1 \
-        and text.count("name=_scores_fwd_impl") == 1
-    assert "kda_rule_fwd" in text and "kda_scores_fwd" in text
-    loss, got = _loss_and_grads(model, variables, batch)
-    assert abs(float(loss) - float(want_loss)) <= tolerance
-    assert set(got) == set(want)
-    for name in want:
-        a, r = (np.asarray(t[name], np.float32) for t in (got, want))
-        assert np.max(np.abs(a - r)) <= tolerance * max(
-            np.max(np.abs(r)), 1e-3), name
 
 
 def step_lowered_for_a_tpu_carries_the_kernels_test(monkeypatch):

@@ -7,16 +7,16 @@ and against ``gated_delta``'s rule where the decay is flat over a head's
 channels; that no ``exp`` of a positive log-decay difference is formed; the
 32 expert shares (the shared expert counted once) add up to the uncut layer;
 refusals, scopes, statistics, the repo's configuration."""
-import importlib
 import json
 import os
-import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import harness
+from harness import REPO
 from homebrewnlp_tpu import telemetry
 from homebrewnlp_tpu.analysis.cost_ledger import scope_key
 from homebrewnlp_tpu.config import BlockArgs, ModelParameter
@@ -27,7 +27,6 @@ from homebrewnlp_tpu.model import (Model, gated_delta as delta_mod,
 from homebrewnlp_tpu.optim import own_rule
 from homebrewnlp_tpu.train import Trainer
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 MOE = "moe-sigmoid_bias-shared_expert"
 MLA = "attention-nope-q_heads4-kv_heads4-kv_latent24-shared_key8"
 
@@ -50,12 +49,11 @@ TINY = {"depth": 1, "heads": 2, "features_per_head": 16,
 
 
 def _reference():
-    return importlib.import_module("benchmark.reference.kimi_linear_48b_a3b")
+    return harness.reference("kimi_linear_48b_a3b")
 
 
 def _config(dtype: str = "float32", **extra) -> dict:
-    with open(os.path.join(REPO, "configs", "kimi_linear_48b_a3b.json")) as f:
-        return {**json.load(f), **TINY, "calculation_dtype": dtype, **extra}
+    return harness.config_of("kimi_linear_48b_a3b", TINY, dtype, **extra)
 
 
 def _lively(variables, bias: float = 0.05):
@@ -83,26 +81,7 @@ def chunk(monkeypatch):
 
 
 def _build(dtype: str = "float32", **extra):
-    config = _config(dtype, **extra)
-    params = ModelParameter(config)
-    assert not params.unknown_config_keys
-    model = Model(params)
-    rng = np.random.default_rng(5)
-    shape = (config["train_batch_size"], config["sequence_length"], 1)
-    tokens = rng.integers(0, 256, shape).astype(np.int32)
-    batch = {"token_x": tokens, "token_y": np.roll(tokens, -1, axis=1)}
-    return config, params, model, batch, _lively(model.init(batch, seed=13))
-
-
-def _logits_and_loss(model, variables, batch):
-    info = jax.jit(lambda v, b: model.apply(v, b))(variables, batch)
-    return (np.asarray(info.token_out.data.astype(jnp.float32))[:, :, 0, :],
-            float(info.total_loss.data))
-
-
-def _error(got, want) -> float:
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    return float(np.max(np.abs(want - got)) / max(np.max(np.abs(want)), 1e-12))
+    return harness.build(_config(dtype, **extra), lively=_lively)
 
 
 def _biases(variables):
@@ -130,15 +109,8 @@ def program_matches_reference_test(dtype, tolerance, extra, chunk):
     if "chunk" in extra:
         chunk(extra.pop("chunk"))
     config, _, model, batch, variables = _build(dtype, **extra)
-    got, loss = _logits_and_loss(model, variables, batch)
-    want = np.asarray(_reference().forward(variables, batch["token_x"][..., 0],
-                                           config))
-    assert got.shape == want.shape
-    assert _error(got, want) < tolerance
-    from benchmark.reference import common
-    want_loss = float(common.loss_of(want, batch["token_y"][..., 0], 0.0))
-    assert abs(want_loss - loss) <= (2.0 ** -18 if dtype == "float32"
-                                     else 2.0 ** -5)
+    got = harness.assert_program_matches_reference(
+        _reference(), (config, _, model, batch, variables), dtype, tolerance)
 
 
 @pytest.mark.parametrize("extra", [
@@ -150,16 +122,15 @@ def loss_and_every_gradient_match_reference_test(extra):
     tokens, targets = batch["token_x"][..., 0], batch["token_y"][..., 0]
     trainer = Trainer(params, model)
     got, _ = jax.jit(lambda v, b: trainer._grads(v, b, None))(variables, batch)
-    as_arrays = {k: jnp.asarray(v) for k, v in variables.items()}
-    want = jax.grad(lambda v: ref.train_loss(v, tokens, targets, config))(
-        as_arrays)
+    _, want = harness.reference_loss_and_grads(ref, variables, tokens,
+                                               targets, config)
     counts = ref.pair_counts(variables, tokens, config)
     assert set(got) == set(want) and len(_biases(got)) == len(counts) == 2
     for name in got:
         if own_rule(name):
             continue
         assert float(jnp.max(jnp.abs(want[name]))) > 0, name
-        assert _error(got[name], want[name]) < 2e-4, name
+        assert harness.error(got[name], want[name]) < 2e-4, name
     # the selection bias has no gradient: the program hands the optimizer
     # the step's pair counts in its place
     for name, layer_counts in zip(_biases(got), counts):
@@ -169,14 +140,8 @@ def loss_and_every_gradient_match_reference_test(extra):
 
 
 def reference_at_the_next_precision_below_fails_test():
-    config, _, model, batch, variables = _build("bfloat16")
-    ref = _reference()
-    tokens = batch["token_x"][..., 0]
-    want = np.asarray(ref.forward(variables, tokens, config))
-    low = np.asarray(ref.forward(variables, tokens, config,
-                                 stream_dtype=jnp.float8_e4m3fn))
-    got, _ = _logits_and_loss(model, variables, batch)
-    assert _error(got, want) < 2 ** -4 < _error(low, want)
+    """``harness.assert_float8_stream_misses``."""
+    harness.assert_float8_stream_misses(_reference(), _build("bfloat16"))
 
 
 # ---- the chunked rule ------------------------------------------------------------
@@ -194,9 +159,7 @@ def _rule_inputs(seed, s=128, h=3, dk=16, dv=8, low=-1.0, batch=1):
 
 def _with_gradients(fn, inputs):
     weights = jax.random.normal(jax.random.PRNGKey(9), inputs[2].shape)
-    return (fn(*inputs), *jax.grad(
-        lambda *args: jnp.sum(fn(*args) * weights),
-        argnums=(0, 1, 2, 3, 4))(*inputs))
+    return harness.with_input_grads(fn, inputs, weights)
 
 
 @pytest.mark.parametrize("chunk,heads,low", [
@@ -213,8 +176,9 @@ def chunked_rule_is_the_recurrence_test(chunk, heads, low):
     for mine, theirs in zip(got, want):
         assert mine.shape == theirs.shape
         assert bool(jnp.all(jnp.isfinite(mine)))
-        assert _error(mine, theirs) < 1e-4
-    _, transform_max, log_decay_min = kda_mod.grouped_rule(*inputs, chunk)
+        assert harness.error(mine, theirs) < 1e-4
+    _, transform_max, log_decay_min = jax.jit(
+        lambda *a: kda_mod.grouped_rule(*a, chunk))(*inputs)
     assert float(transform_max) >= float(jnp.max(inputs[3])) - 1e-6
     gamma = jnp.cumsum(inputs[4].reshape(1, -1, chunk, heads, 16), axis=2)
     assert float(log_decay_min) == pytest.approx(float(jnp.min(gamma)),
@@ -225,10 +189,10 @@ def chunked_rule_is_the_recurrence_test(chunk, heads, low):
 
 def groups_of_heads_are_the_rule_over_all_test(monkeypatch):
     inputs = _rule_inputs(3, s=64, h=6)
-    whole = kda_mod.kda_rule(*inputs, 32)
+    whole = jax.jit(lambda *a: kda_mod.kda_rule(*a, 32))(*inputs)
     monkeypatch.setattr(kda_mod, "GROUP_BYTES", 2 * 64 * kda_mod._SUB * 16 * 4)
     assert kda_mod._group_heads(1, 64, 6, 16) == 2
-    grouped = kda_mod.grouped_rule(*inputs, 32)
+    grouped = jax.jit(lambda *a: kda_mod.grouped_rule(*a, 32))(*inputs)
     for got, want in zip(grouped, whole):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-6, atol=1e-7)
@@ -241,9 +205,11 @@ def what_the_rule_keeps_in_float32_is_felt_in_bfloat16_test(monkeypatch):
     orders of magnitude; inputs already rounded are not what moves it."""
     inputs = _rule_inputs(5, s=256, low=-0.3)
     want = _reference().recurrence(*inputs)
-    kept = _error(kda_mod.kda_rule(*inputs, 64)[0], want)
+    kept = harness.error(
+        jax.jit(lambda *a: kda_mod.kda_rule(*a, 64)[0])(*inputs), want)
     monkeypatch.setattr(kda_mod, "KEPT", jnp.bfloat16)
-    rounded = _error(kda_mod.kda_rule(*inputs, 64)[0], want)
+    rounded = harness.error(
+        jax.jit(lambda *a: kda_mod.kda_rule(*a, 64)[0])(*inputs), want)
     assert kept < 1e-5 and rounded > 100 * kept
 
 
@@ -273,7 +239,7 @@ def a_flat_decay_is_gated_deltas_rule_test():
     want = _with_gradients(
         lambda *a: delta_mod.grouped_rule(*a, 32)[0], (q, k, v, beta, flat))
     for mine, theirs in zip(got, want):
-        assert _error(mine, theirs) < 2e-5
+        assert harness.error(mine, theirs) < 2e-5
 
 
 def no_exp_of_a_positive_decay_difference_is_formed_test():
@@ -284,54 +250,16 @@ def no_exp_of_a_positive_decay_difference_is_formed_test():
     closed = jax.make_jaxpr(jax.grad(
         lambda *a: jnp.sum(kda_mod.kda_rule(*a, 32)[0]),
         argnums=(0, 1, 2, 3, 4)))(*inputs)
-    from jax.extend.core import Literal
-    seen = []
-
-    def has_exp(eqn):
-        inner = [getattr(p, "jaxpr", p) for p in eqn.params.values()]
-        return eqn.primitive.name == "exp" or any(
-            has_exp(e) for j in inner if hasattr(j, "eqns") for e in j.eqns)
-
-    def walk(jaxpr, consts, args):
-        env = dict(zip(jaxpr.constvars, consts))
-        env.update(zip(jaxpr.invars, args))
-
-        def read(var):
-            return var.val if isinstance(var, Literal) else env[var]
-
-        for eqn in jaxpr.eqns:
-            values = [read(v) for v in eqn.invars]
-            if eqn.primitive.name == "exp":
-                seen.append(float(jnp.max(values[0])))
-            inner = eqn.params.get("jaxpr", eqn.params.get("call_jaxpr"))
-            if hasattr(inner, "consts") and eqn.primitive.name != "scan":
-                out = walk(inner.jaxpr, inner.consts, values)
-            else:
-                # the state walk, the solve: no exp of their own
-                assert eqn.primitive.name == "exp" or not has_exp(eqn), eqn
-                out = eqn.primitive.bind(*values, **eqn.params)
-                out = out if eqn.primitive.multiple_results else [out]
-            env.update(zip(eqn.outvars, out))
-        return [read(v) for v in jaxpr.outvars]
-
-    walk(closed.jaxpr, closed.consts, inputs)
+    _, seen = harness.exp_operands(closed, inputs)
     assert len(seen) >= 8
     assert max(seen) <= 0.0
 
 
-# ---- the share test --------------------------------------------------------------
+def _layer_on(*args, **kwargs):
+    return harness.layer_on(*args, **kwargs)[0]
 
-def _layer(params, fn, names, weights, x, flags=()):
-    """One layer function of the program on ``x [b, s, heads, features]``
-    with the given weights (the reference's short names)."""
-    ctx = scope.Context("apply", params={
-        path + "/var0": jnp.asarray(weights[short])
-        for short, path in names.items() if short in weights})
-    base = next(iter(names.values())).split("_0/")[0]
-    with scope.context(ctx):
-        return scope.scoped(base + "_", fn, BlockArgs(
-            params, nt(x, [params.batch_dim, params.sequence_dim]
-                       + list(params.feature_dims)), list(flags))).data
+
+# ---- the share test --------------------------------------------------------------
 
 
 def the_32_expert_shares_add_up_to_the_uncut_layer_test():
@@ -370,12 +298,12 @@ def the_32_expert_shares_add_up_to_the_uncut_layer_test():
                                for k in ("w_gate", "w_up", "w_down")})
         want, _, _ = ref.sparse_block(share, h, cut)
         if rank in (0, 13, 31):
-            got = _layer(ModelParameter(cut), moe_mod.moe, ref.SPARSE, share,
+            got = _layer_on(ModelParameter(cut), moe_mod.moe, ref.SPARSE, share,
                          x, flags)
-            assert _error(got, want) < 2e-5
+            assert harness.error(got, want) < 2e-5
         total = total + np.asarray(want - shared_part)
-    assert _error(total, uncut) < 2e-5
-    assert _error(_layer(ModelParameter(config), moe_mod.moe, ref.SPARSE,
+    assert harness.error(total, uncut) < 2e-5
+    assert harness.error(_layer_on(ModelParameter(config), moe_mod.moe, ref.SPARSE,
                          whole, x, flags), uncut) < 2e-5
 
 
@@ -477,10 +405,8 @@ def the_new_scopes_fold_test(path, scope_name):
 
 def traced_ops_carry_the_new_scopes_test():
     _, _, model, batch, variables = _build()
-    text = jax.jit(jax.grad(
-        lambda v, b: model.apply(v, b).total_loss.data)).lower(
-        variables, batch).as_text(debug_info=True)
-    found = {scope_key(name) for name in re.findall(r'loc\("([^"]+)"', text)}
+    found = {scope_key(name) for name in harness.traced_op_names(
+        model, variables, batch, compiled=False)}
     assert {"body/kda/in_proj", "body/kda/conv", "body/kda/decay",
             "body/kda/rule", "body/kda/gate_norm", "body/kda/out_proj",
             "body/attention/q_proj", "body/attention/kv_down",

@@ -5,7 +5,6 @@ ranks' routed parts plus the shared expert once = the uncut layer); the
 window below, at and above the sequence; YaRN's frequencies and the partial
 rotation against a transcription of HF's; the held-expert dispatch at its
 extremes; the new flags' and keys' refusals; scopes and gauges."""
-import importlib
 import json
 import os
 
@@ -14,15 +13,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import harness
+from harness import REPO
 from homebrewnlp_tpu.analysis.cost_ledger import scope_key
-from homebrewnlp_tpu.config import BlockArgs, ModelParameter
-from homebrewnlp_tpu.core import scope
-from homebrewnlp_tpu.core.tensor import nt
+from homebrewnlp_tpu.config import ModelParameter
 from homebrewnlp_tpu.model import Model, moe as moe_mod, remat, spatial
 from homebrewnlp_tpu.model.spatial import (_standard_flags, rotary,
                                            yarn_inv_freq)
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FULL = "attention-yarn-q_heads4-kv_heads2-gate-rotary_pct50-theta500000"
 SLIDE = "attention-rope-q_heads6-kv_heads2-gate-window32"
 MOE = "moe-silu-shared_expert"
@@ -48,34 +46,15 @@ TINY = {"depth": 1, "heads": 2, "features_per_head": 16,
 
 
 def _reference():
-    return importlib.import_module("benchmark.reference.laguna_s_2_1")
+    return harness.reference("laguna_s_2_1")
 
 
 def _config(dtype: str = "float32", **extra) -> dict:
-    with open(os.path.join(REPO, "configs", "laguna_s_2_1.json")) as f:
-        return {**json.load(f), **TINY, "calculation_dtype": dtype, **extra}
+    return harness.config_of("laguna_s_2_1", TINY, dtype, **extra)
 
 
 def _build(dtype: str = "float32", **extra):
-    config = _config(dtype, **extra)
-    params = ModelParameter(config)
-    assert not params.unknown_config_keys
-    model = Model(params)
-    rng = np.random.default_rng(5)
-    shape = (config["train_batch_size"], config["sequence_length"], 1)
-    tokens = rng.integers(0, 256, shape).astype(np.int32)
-    batch = {"token_x": tokens, "token_y": np.roll(tokens, -1, axis=1)}
-    return config, params, model, batch, model.init(batch, seed=13)
-
-
-def _logits_and_loss(model, variables, batch):
-    info = jax.jit(lambda v, b: model.apply(v, b))(variables, batch)
-    return (np.asarray(info.token_out.data.astype(jnp.float32))[:, :, 0, :],
-            float(info.total_loss.data))
-
-
-def _error(got, want) -> float:
-    return float(np.max(np.abs(want - got)) / np.max(np.abs(want)))
+    return harness.build(_config(dtype, **extra))
 
 
 # ---- the program against the reference ---------------------------------------
@@ -111,15 +90,8 @@ def program_matches_reference_test(dtype, tolerance, extra):
     config, _, model, batch, variables = _build(dtype, **extra)
     assert sum("moe_0/normal_var4" in name for name in variables) \
         == 4 * config["depth"]
-    got, loss = _logits_and_loss(model, variables, batch)
-    want = np.asarray(_reference().forward(variables, batch["token_x"][..., 0],
-                                           config))
-    assert got.shape == want.shape
-    assert _error(got, want) < tolerance
-    from benchmark.reference import common
-    want_loss = float(common.loss_of(want, batch["token_y"][..., 0], 0.0))
-    assert abs(want_loss - loss) <= (2.0 ** -18 if dtype == "float32"
-                                     else 2.0 ** -5)
+    got = harness.assert_program_matches_reference(
+        _reference(), (config, _, model, batch, variables), dtype, tolerance)
 
 
 @pytest.mark.parametrize("extra", [{}, {"experts": 64}],
@@ -133,27 +105,14 @@ def loss_and_gradients_match_reference_test(extra):
     tokens, targets = batch["token_x"][..., 0], batch["token_y"][..., 0]
     got = jax.jit(jax.grad(lambda v: model.apply(v, batch).total_loss.data))(
         variables)
-    want = jax.grad(lambda v: ref.train_loss(v, tokens, targets, config))(
-        variables)
-    assert set(got) == set(want)
-    for name in got:
-        scale = float(jnp.max(jnp.abs(want[name]))) or 1.0
-        assert float(jnp.max(jnp.abs(got[name] - want[name]))) / scale < 2e-4, \
-            name
+    _, want = harness.reference_loss_and_grads(ref, variables, tokens,
+                                               targets, config)
+    harness.assert_grads_match(got, want, 2e-4)
 
 
 def reference_at_the_next_precision_below_fails_test():
-    """The reference with a float8 (e4m3) residual stream misses the bound
-    that the program in bfloat16 holds: a lower precision than the
-    configuration states comes out as not correct."""
-    config, _, model, batch, variables = _build("bfloat16")
-    ref = _reference()
-    tokens = batch["token_x"][..., 0]
-    want = np.asarray(ref.forward(variables, tokens, config))
-    low = np.asarray(ref.forward(variables, tokens, config,
-                                 stream_dtype=jnp.float8_e4m3fn))
-    got, _ = _logits_and_loss(model, variables, batch)
-    assert _error(got, want) < 2 ** -4 < _error(low, want)
+    """``harness.assert_float8_stream_misses``."""
+    harness.assert_float8_stream_misses(_reference(), _build("bfloat16"))
 
 
 # ---- the share test ------------------------------------------------------------
@@ -161,14 +120,8 @@ def reference_at_the_next_precision_below_fails_test():
 def _moe_layer(params, weights, x):
     """Layer ``moe-silu-shared_expert`` of ``params`` on ``x [b, s, heads,
     features]`` with the given weights (the reference's short names)."""
-    ref = _reference()
-    ctx = scope.Context("apply", params={
-        path + "/var0": jnp.asarray(weights[short])
-        for short, path in ref.SPARSE.items()})
-    with scope.context(ctx):
-        return scope.scoped("moe_", moe_mod.moe, BlockArgs(
-            params, nt(x, [params.batch_dim, params.sequence_dim]
-                       + list(params.feature_dims)), ["silu", "shared_expert"])).data
+    return harness.layer_on(params, moe_mod.moe, _reference().SPARSE, weights,
+                            x, ["silu", "shared_expert"])[0]
 
 
 def the_shares_add_up_to_the_uncut_layer_test():
@@ -255,8 +208,11 @@ def every_held_pair_is_computed_when_all_land_here_test(experts):
     ref = _reference()
     config, params, model, batch, variables = _build(experts_held=4,
                                                      experts=experts)
-    tokens, targets = batch["token_x"][..., 0], batch["token_y"][..., 0]
-    for bias, share in ((+40.0, 1.0), (-40.0, 0.0)):
+    tokens = batch["token_x"][..., 0]
+    # one program each for both biases
+    run = jax.jit(lambda v: model.apply(v, batch, layer_stats=True))
+    grad = jax.jit(jax.grad(lambda v: harness.loss_of(model)(v, batch)))
+    for bias in (+40.0, -40.0):
         skewed = dict(variables)
         for name in variables:
             if name.endswith("moe_0/normal_var0/var0"):
@@ -266,17 +222,15 @@ def every_held_pair_is_computed_when_all_land_here_test(experts):
                         "moe_0/normal_var0", "norm_0/normal_var0")]))[..., None]
                 skewed[name] = jnp.asarray(w)
         # a positive input to the router is not guaranteed: read the share
-        info = model.apply(skewed, batch, layer_stats=True)
+        info = run(skewed)
         held = np.asarray(info.layer_stats["moe_held_pairs"])
         routed = np.asarray(info.layer_stats["moe_routed_pairs"])
         assert routed.tolist() == [2 * 128 * 4] * 4
-        got, _ = _logits_and_loss(model, skewed, batch)
+        got = np.asarray(info.token_out.data.astype(jnp.float32))[:, :, 0, :]
         want = np.asarray(ref.forward(skewed, tokens, config))
-        assert _error(got, want) < 2e-5, (bias, held / routed)
-        grads = jax.grad(lambda v: model.apply(v, batch).total_loss.data)(
-            skewed)
-        assert all(np.all(np.isfinite(np.asarray(g))) for g in grads.values())
-        del share
+        assert harness.error(got, want) < 2e-5, (bias, held / routed)
+        assert all(np.all(np.isfinite(np.asarray(g)))
+                   for g in grad(skewed).values())
 
 
 def _choices(rng, tokens, n_exp, top_k, first, held, n_held):
@@ -411,7 +365,7 @@ def the_step_reports_the_held_share_test():
     experts`` of the pairs; the trainer's metrics carry the counter and both
     gauges, and the start-up line the static buffer's rows."""
     config, params, model, batch, variables = _build()
-    info = model.apply(variables, batch, layer_stats=True)
+    info = harness.apply_with_stats(model, variables, batch)
     held = np.asarray(info.layer_stats["moe_held_pairs"])
     routed = np.asarray(info.layer_stats["moe_routed_pairs"])
     assert routed.tolist() == [1024.0] * 4
@@ -448,7 +402,7 @@ def the_step_reports_the_tiles_it_walks_test():
     bound's tiles."""
     from homebrewnlp_tpu.train import _LAYER_STATS, _info_metrics
     config, params, model, batch, variables = _build(experts=64)
-    info = model.apply(variables, batch, layer_stats=True)
+    info = harness.apply_with_stats(model, variables, batch)
     held = np.asarray(info.layer_stats["moe_held_pairs"])
     assert np.all((held > 0) & (held < 0.15 * 1024))
     tile = moe_mod._row_tile(moe_mod.moe_held_rows(params))
@@ -742,3 +696,44 @@ def dense_kind_leaves_the_cells_step_alone_test(monkeypatch):
     assert len(policies) == regions
     assert all(policy is jax.checkpoint_policies.nothing_saveable
                for policy in policies)
+
+
+# ---- compiled for a described v5e ---------------------------------------------
+
+def held_row_buffers_are_allocated_where_they_are_filled_test(v5e,
+                                                              monkeypatch):
+    """One ``moe`` layer of the Laguna cell (8 of 256 experts held, eight
+    slots a token) at 1 x 8,192 tokens, compiled for a v5e as a TPU process
+    traces it (ISSUE 47): the held path's loops are ``while`` ops in the
+    layer's three scopes; their row buffers come from ``moe_held_rows_alloc``
+    calls — a custom call with an operand, scheduled where it is filled — and
+    not from operand-less ``AllocateBuffer``s, which XLA schedules at the
+    step's start (every layer's buffers alive at once: the cell's step then
+    needs 19 GB); no buffer of the bound's rows is copied."""
+    import re
+    params, hlo = harness.cell_layer_hlo(
+        v5e, monkeypatch, "train_laguna_s_2_1_ep32_s8k", 1,
+        input_block_config=[], train_batch_size=1)
+    assert params.block_config[0].layer[-1].startswith("moe")
+    assert params.sequence_length == 8192
+    rows = moe_mod.moe_held_rows(params)
+    assert rows == 8192 * 8
+    buffer = rf"bf16\[{rows},(?:3072|1024)\]"
+    assert not re.search(rf"= {buffer}\S* custom-call\(\)", hlo)
+    assert not re.search(rf"= {buffer}\S* copy\(", hlo)
+    allocs = re.findall(rf'= {buffer}[^\n]*?custom_call_target='
+                        r'"tpu_custom_call"[^\n]*?op_name="([^"]+'
+                        r'moe_held_rows_alloc[^"]*)"', hlo)
+    # dispatch forward and replay; the activation forward, replay and its
+    # backward's two; combine's backward
+    assert sorted(scope_key(op) for op in allocs) \
+        == ["body/moe/combine"] + ["body/moe/dispatch"] * 2 \
+        + ["body/moe/experts"] * 4
+    loops = re.findall(r'= [^\n]*? while\([^\n]*?op_name="([^"]+moe_0[^"]+)"',
+                       hlo)
+    held = [op for op in loops if "searchsorted" not in op]
+    assert {scope_key(op) for op in held} == {
+        "body/moe/dispatch", "body/moe/experts", "body/moe/combine"}
+    # dispatch 3 (forward, replay, backward), the fan-out's backward 1, the
+    # activation 3, combine 2 (its forward is not replayed)
+    assert len(held) == 9

@@ -7,6 +7,8 @@ real kernel bodies in interpret mode), must skip causally-dead blocks
 correctly at multi-block shapes, and must decline LOUDLY (naming why) at
 unsupported shapes while keeping the dense result.
 """
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -220,3 +222,30 @@ def map_mixer_backward_holds_no_per_batch_map_test():
     assert (b * h, s, s) not in shapes and (b, h, s, s) not in shapes, shapes
     after = eqns[eqns.index(dbias) + 1:]
     assert not [e for e in after if e.primitive.name == "reduce_sum"], after
+
+
+@pytest.mark.parametrize("batch,heads", [(32, 8), (128, 4)],
+                         ids=["flagship_chip", "dp2tp2_chip"])
+def map_mixer_backward_compiles_to_one_map_test(v5e, batch, heads):
+    """The map mixer's backward at one chip's share of the two flagship
+    cells (sequence 512, 512 features a head, bfloat16), compiled for a v5e:
+    Mosaic accepts the batch-sweeping dbias kernel, it writes ONE float32
+    ``[heads, 512, 512]`` map, and no per-(batch, head) map is left in the
+    program."""
+    import re
+    from homebrewnlp_tpu.parallel import map_mixer as mm
+    s = f = 512
+    bias = jax.ShapeDtypeStruct((heads, s, s), jnp.bfloat16, sharding=v5e)
+    act = jax.ShapeDtypeStruct((batch * heads, s, f), jnp.bfloat16,
+                               sharding=v5e)
+    block = mm.kernel_block(s, cap=512)
+    hlo = jax.jit(lambda bias_, v, g: mm._bwd_impl(
+        bias_, v, g, True, block, block, False)).lower(
+        bias, act, act).compile().as_text()
+    calls = dict(re.findall(r"%(map_mixer_\w+?)(?:\.\d+)? = (\w+\[[\d,]*\])",
+                            hlo))
+    assert calls == {
+        "map_mixer_bwd_dbias_causal": f"f32[{heads},512,512]",
+        "map_mixer_bwd_dval_causal": f"bf16[{batch * heads},512,512]"}, calls
+    assert f"f32[{batch * heads},512,512]" not in hlo
+    assert f"f32[{batch},{heads},512,512]" not in hlo

@@ -10,7 +10,6 @@ counted once, and both tensor-parallel halves of a Mamba-2 and an attention
 layer, add up to the uncut layer); refusals, scopes, statistics, the repo's
 configuration."""
 import hashlib
-import importlib
 import json
 import os
 import re
@@ -20,10 +19,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import harness
+from harness import REPO
 from homebrewnlp_tpu import telemetry
 from homebrewnlp_tpu.analysis.cost_ledger import scope_key
 from homebrewnlp_tpu.config import BlockArgs, ModelParameter
-from homebrewnlp_tpu.core import scope
 from homebrewnlp_tpu.core.tensor import nt
 from homebrewnlp_tpu.model import Model, mamba as mamba_mod, moe as moe_mod
 from homebrewnlp_tpu.model.activation import ACTIVATIONS
@@ -31,7 +31,6 @@ from homebrewnlp_tpu.optim import Optimizer, own_rule, selection_bias_rule
 from homebrewnlp_tpu.parallel import ssd_scan as sk
 from homebrewnlp_tpu.train import Trainer
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 MOE = "moe-relu2-plain-latent-sigmoid_bias-shared_expert"
 ATTENTION = "attention-nope-q_heads4-kv_heads1"
 
@@ -56,13 +55,11 @@ TINY = {"depth": 1, "heads": 2, "features_per_head": 16,
 
 
 def _reference():
-    return importlib.import_module("benchmark.reference.nemotron_3_super_120b")
+    return harness.reference("nemotron_3_super_120b")
 
 
 def _config(dtype: str = "float32", **extra) -> dict:
-    with open(os.path.join(REPO, "configs",
-                           "nemotron_3_super_120b.json")) as f:
-        return {**json.load(f), **TINY, "calculation_dtype": dtype, **extra}
+    return harness.config_of("nemotron_3_super_120b", TINY, dtype, **extra)
 
 
 def _lively(variables, bias: float = 0.05):
@@ -81,26 +78,7 @@ def _lively(variables, bias: float = 0.05):
 
 
 def _build(dtype: str = "float32", **extra):
-    config = _config(dtype, **extra)
-    params = ModelParameter(config)
-    assert not params.unknown_config_keys
-    model = Model(params)
-    rng = np.random.default_rng(5)
-    shape = (config["train_batch_size"], config["sequence_length"], 1)
-    tokens = rng.integers(0, 256, shape).astype(np.int32)
-    batch = {"token_x": tokens, "token_y": np.roll(tokens, -1, axis=1)}
-    return config, params, model, batch, _lively(model.init(batch, seed=13))
-
-
-def _logits_and_loss(model, variables, batch):
-    info = jax.jit(lambda v, b: model.apply(v, b))(variables, batch)
-    return (np.asarray(info.token_out.data.astype(jnp.float32))[:, :, 0, :],
-            float(info.total_loss.data))
-
-
-def _error(got, want) -> float:
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    return float(np.max(np.abs(want - got)) / max(np.max(np.abs(want)), 1e-12))
+    return harness.build(_config(dtype, **extra), lively=_lively)
 
 
 def _biases(variables):
@@ -134,15 +112,8 @@ def program_matches_reference_test(dtype, tolerance, extra):
     assert moe_mod.walks_real_rows(
         config["experts"], config["experts_held"] or config["experts"],
         config["moe_top_k"]) == (config["experts"] == 64)
-    got, loss = _logits_and_loss(model, variables, batch)
-    want = np.asarray(_reference().forward(variables, batch["token_x"][..., 0],
-                                           config))
-    assert got.shape == want.shape
-    assert _error(got, want) < tolerance
-    from benchmark.reference import common
-    want_loss = float(common.loss_of(want, batch["token_y"][..., 0], 0.0))
-    assert abs(want_loss - loss) <= (2.0 ** -18 if dtype == "float32"
-                                     else 2.0 ** -5)
+    got = harness.assert_program_matches_reference(
+        _reference(), (config, _, model, batch, variables), dtype, tolerance)
 
 
 @pytest.mark.parametrize("extra", [
@@ -160,15 +131,14 @@ def loss_and_every_gradient_match_reference_test(extra):
     tokens, targets = batch["token_x"][..., 0], batch["token_y"][..., 0]
     trainer = Trainer(params, model)
     got, _ = jax.jit(lambda v, b: trainer._grads(v, b, None))(variables, batch)
-    as_arrays = {k: jnp.asarray(v) for k, v in variables.items()}
-    want = jax.grad(lambda v: ref.train_loss(v, tokens, targets, config))(
-        as_arrays)
+    _, want = harness.reference_loss_and_grads(ref, variables, tokens,
+                                               targets, config)
     counts = ref.pair_counts(variables, tokens, config)
     assert set(got) == set(want) and len(_biases(got)) == len(counts) == 2
     for name in got:
         if own_rule(name):
             continue
-        assert _error(got[name], want[name]) < 2e-4, name
+        assert harness.error(got[name], want[name]) < 2e-4, name
     # the selection bias has no gradient: the reference's is zero, and the
     # program hands the optimizer the step's pair counts in its place
     for name, layer_counts in zip(_biases(got), counts):
@@ -179,14 +149,8 @@ def loss_and_every_gradient_match_reference_test(extra):
 
 
 def reference_at_the_next_precision_below_fails_test():
-    config, _, model, batch, variables = _build("bfloat16")
-    ref = _reference()
-    tokens = batch["token_x"][..., 0]
-    want = np.asarray(ref.forward(variables, tokens, config))
-    low = np.asarray(ref.forward(variables, tokens, config,
-                                 stream_dtype=jnp.float8_e4m3fn))
-    got, _ = _logits_and_loss(model, variables, batch)
-    assert _error(got, want) < 2 ** -4 < _error(low, want)
+    """``harness.assert_float8_stream_misses``."""
+    harness.assert_float8_stream_misses(_reference(), _build("bfloat16"))
 
 
 # ---- the selection bias --------------------------------------------------------
@@ -302,21 +266,19 @@ def grouped_scan_is_the_recurrence_test(groups, heads_a_block):
              "sequential": _reference().recurrence}
     out = {}
     for name, fn in forms.items():
-        out[name] = (fn(*inputs), *jax.grad(
-            lambda *args: jnp.sum(fn(*args) * weights),
-            argnums=(0, 1, 2, 3, 4))(*inputs))
+        out[name] = harness.with_input_grads(fn, inputs, weights)
     for name in ("kernel", "xla"):
         for got, want in zip(out[name], out["sequential"]):
             assert got.shape == want.shape
-            assert _error(got, want) < 2e-5, name
+            assert harness.error(got, want) < 2e-5, name
     # the groups are not one another's: with one group's B doubled only
     # its own heads move
     x, dt, a, b_mat, c_mat = inputs
     if groups > 1:
         moved = kernel(x, dt, a, b_mat.at[:, :, 0].multiply(2.0), c_mat)
         per = 8 // groups
-        assert _error(moved[:, :, per:], out["kernel"][0][:, :, per:]) == 0.0
-        assert _error(moved[:, :, :per], out["kernel"][0][:, :, :per]) > 0.1
+        assert harness.error(moved[:, :, per:], out["kernel"][0][:, :, per:]) == 0.0
+        assert harness.error(moved[:, :, :per], out["kernel"][0][:, :, :per]) > 0.1
 
 
 def three_dimensional_b_and_c_are_one_group_test():
@@ -360,15 +322,10 @@ def the_layer_runs_the_grouped_kernels_test(monkeypatch):
     """Layer ``mamba`` with its scan steered to the interpreted Pallas pair
     is the layer with XLA's form: the same logits and loss."""
     config, _, model, batch, variables = _build()
-    want = _logits_and_loss(model, variables, batch)
-    import functools
-    monkeypatch.setattr(mamba_mod, "ssd_kernel_applies",
-                        lambda *_, **__: True)
-    monkeypatch.setattr(mamba_mod, "ssd_scan", functools.partial(
-        sk.ssd_scan, interpret=True))
-    jax.clear_caches()
-    got = _logits_and_loss(model, variables, batch)
-    assert _error(got[0], want[0]) < 2e-5 and abs(got[1] - want[1]) < 1e-5
+    want = harness.logits_and_loss(model, variables, batch)
+    harness.steer_mamba_scan(monkeypatch)
+    got = harness.logits_and_loss(model, variables, batch)
+    assert harness.error(got[0], want[0]) < 2e-5 and abs(got[1] - want[1]) < 1e-5
 
 
 #: sha1 of the StableHLO of loss and gradients of ONE ``mamba`` block at toy
@@ -404,19 +361,11 @@ def one_group_lowers_to_the_parents_graph_test():
     assert seen == [(2, 64, 16)]
 
 
-# ---- the share tests -----------------------------------------------------------
+def _layer_on(*args, **kwargs):
+    return harness.layer_on(*args, **kwargs)[0]
 
-def _layer(params, fn, names, weights, x, flags=()):
-    """One layer function of the program on ``x [b, s, heads, features]``
-    with the given weights (the reference's short names)."""
-    ctx = scope.Context("apply", params={
-        path + "/var0": jnp.asarray(weights[short])
-        for short, path in names.items() if short in weights})
-    base = next(iter(names.values())).split("_0/")[0]
-    with scope.context(ctx):
-        return scope.scoped(base + "_", fn, BlockArgs(
-            params, nt(x, [params.batch_dim, params.sequence_dim]
-                       + list(params.feature_dims)), list(flags))).data
+
+# ---- the share tests -----------------------------------------------------------
 
 
 def the_expert_shares_add_up_to_the_uncut_layer_test():
@@ -455,14 +404,14 @@ def the_expert_shares_add_up_to_the_uncut_layer_test():
         cut = _config(experts_held=4, experts_first=first)
         share = dict(whole, **{k: whole[k][first:first + 4]
                                for k in ("w_up", "w_down")})
-        got = _layer(ModelParameter(cut), moe_mod.moe, ref.SPARSE, share, x,
+        got = _layer_on(ModelParameter(cut), moe_mod.moe, ref.SPARSE, share, x,
                      flags)
         want, _, _ = ref.sparse_block(share, h, cut)
-        assert _error(got, want) < 2e-5
+        assert harness.error(got, want) < 2e-5
         parts.append(np.asarray(got - shared_part))
         assert np.max(np.abs(parts[-1])) > 1e-3
-    assert _error(np.asarray(shared_part) + sum(parts), uncut) < 2e-5
-    assert _error(_layer(ModelParameter(config), moe_mod.moe, ref.SPARSE,
+    assert harness.error(np.asarray(shared_part) + sum(parts), uncut) < 2e-5
+    assert harness.error(_layer_on(ModelParameter(config), moe_mod.moe, ref.SPARSE,
                          whole, x, flags), uncut) < 2e-5
 
 
@@ -517,11 +466,11 @@ def both_tensor_parallel_halves_add_up_to_the_uncut_layers_test():
     for rank in (0, 1):
         share = _mamba_half(whole, rank, heads, p, groups, n)
         want = ref.mamba_block(share, h, heads // 2, n, groups // 2, 1e-5)
-        got = _layer(cut, mamba_mod.mamba, ref.MAMBA, share, x)
-        assert _error(got, want) < 2e-5
+        got = _layer_on(cut, mamba_mod.mamba, ref.MAMBA, share, x)
+        assert harness.error(got, want) < 2e-5
         halves.append(np.asarray(got))
-    assert _error(halves[0] + halves[1], uncut) < 2e-5
-    assert _error(halves[0], uncut) > 0.1
+    assert harness.error(halves[0] + halves[1], uncut) < 2e-5
+    assert harness.error(halves[0], uncut) > 0.1
 
     from homebrewnlp_tpu.model import spatial
     whole = {"w_norm_in": jnp.ones(f), "w_key": normal(*f, 2, 16),
@@ -536,13 +485,13 @@ def both_tensor_parallel_halves_add_up_to_the_uncut_layers_test():
                  "w_query": whole["w_query"][:, :, 2 * rank:2 * rank + 2],
                  "w_out": whole["w_out"][2 * rank:2 * rank + 2]}
         want = ref.attention_block(share, h, 1e-5)
-        got = _layer(ModelParameter(_config()), spatial.attention,
+        got = _layer_on(ModelParameter(_config()), spatial.attention,
                      ref.ATTENTION, share, x,
                      ["nope", "q_heads2", "kv_heads1"])
-        assert _error(got, want) < 2e-5
+        assert harness.error(got, want) < 2e-5
         halves.append(np.asarray(got))
-    assert _error(halves[0] + halves[1], uncut) < 2e-5
-    assert _error(halves[0], uncut) > 0.1
+    assert harness.error(halves[0] + halves[1], uncut) < 2e-5
+    assert harness.error(halves[0], uncut) > 0.1
 
 
 # ---- refusals, scopes, statistics ----------------------------------------------
@@ -589,10 +538,8 @@ def the_new_scopes_fold_test(path, scope_name):
 
 def traced_ops_carry_the_latent_scopes_test():
     config, _, model, batch, variables = _build()
-    text = jax.jit(jax.grad(
-        lambda v, b: model.apply(v, b).total_loss.data)).lower(
-        variables, batch).as_text(debug_info=True)
-    found = {scope_key(name) for name in re.findall(r'loc\("([^"]+)"', text)}
+    found = {scope_key(name) for name in harness.traced_op_names(
+        model, variables, batch, compiled=False)}
     assert {"body/moe/latent_down", "body/moe/latent_up", "body/moe/shared",
             "body/moe/router", "body/moe/experts", "body/mamba/ssd",
             "body/mamba/gate_norm"} <= found
@@ -717,3 +664,26 @@ def the_repos_config_is_the_published_model_test():
     for key, value in doc["published"].items():
         assert doc[key] == doc["reduced"][key]["to"] != value \
             == doc["reduced"][key]["from"]
+
+
+# ---- compiled for a described v5e ---------------------------------------------
+
+@pytest.mark.parametrize("groups", [1, 4])
+def scan_pair_compiles_at_a_chunk_of_one_lane_tile_test(v5e, groups):
+    """PR 54: Mosaic accepts the scan pair at Nemotron-3's shapes — 64 heads
+    of 64, a state of 128, a chunk of ONE lane tile (the last lane's decay is
+    a masked lane sum there, ``_head``), and ``B`` / ``C`` in 4 groups whose
+    two head blocks each share one ``scores`` tile — as at one group."""
+    b, s, h, p, n, chunk = 1, 1024, 64, 64, 128, 128
+    assert sk.ssd_kernel_applies(s, chunk, h, p, n, "tpu", groups)
+    assert sk.head_block(h, p, groups) == 8
+    cols = (b, s, groups, n) if groups > 1 else (b, s, n)
+    avals = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+             for shape, dtype in (((b, s, h, p), jnp.bfloat16),
+                                  ((b, s, h), jnp.float32),
+                                  ((b, s, h), jnp.float32),
+                                  (cols, jnp.bfloat16), (cols, jnp.bfloat16))]
+    hlo = jax.jit(jax.value_and_grad(
+        lambda *a: jnp.sum(sk.ssd_scan(*a, chunk)), argnums=(0, 1, 2, 3, 4))
+    ).lower(*avals).compile().as_text()
+    assert "ssd_scan_fwd" in hlo and "ssd_scan_bwd" in hlo

@@ -4,7 +4,6 @@ blocked triangular solve, post-norm blocks and NoPE QK-norm attention at a
 head count that is no power of two, the program against the plain reference
 ``benchmark/reference/olmo_hybrid_7b.py``, the eight-block period, and the
 new scopes and gauges."""
-import importlib
 import json
 import os
 import re
@@ -14,13 +13,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import harness
+from harness import REPO
 from homebrewnlp_tpu.analysis.cost_ledger import scope_key
 from homebrewnlp_tpu.config import ModelParameter
 from homebrewnlp_tpu.model import Model, gated_delta as delta_mod
 from homebrewnlp_tpu.model import mamba as mamba_mod
 from homebrewnlp_tpu.model import recurrent, remat
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # three heads: no power of two, as the published thirty
 TINY = {"depth": 1, "heads": 3, "features_per_head": 16,
         "sequence_length": 64, "train_batch_size": 2, "vocab_size": 384,
@@ -30,38 +30,19 @@ TINY = {"depth": 1, "heads": 3, "features_per_head": 16,
 
 
 def _reference():
-    return importlib.import_module("benchmark.reference.olmo_hybrid_7b")
+    return harness.reference("olmo_hybrid_7b")
 
 
 def _config(dtype: str = "float32", **extra) -> dict:
-    with open(os.path.join(REPO, "configs", "olmo_hybrid_7b.json")) as f:
-        return {**json.load(f), **TINY, "calculation_dtype": dtype, **extra}
+    return harness.config_of("olmo_hybrid_7b", TINY, dtype, **extra)
 
 
 def _build(dtype: str = "float32", **extra):
-    config = _config(dtype, **extra)
-    params = ModelParameter(config)
-    assert not params.unknown_config_keys
-    model = Model(params)
-    rng = np.random.default_rng(5)
-    shape = (config["train_batch_size"], config["sequence_length"], 1)
-    tokens = rng.integers(0, 256, shape).astype(np.int32)
-    batch = {"token_x": tokens, "token_y": np.roll(tokens, -1, axis=1)}
-    return config, params, model, batch, model.init(batch, seed=13)
-
-
-def _logits_and_loss(model, variables, batch):
-    info = jax.jit(lambda v, b: model.apply(v, b))(variables, batch)
-    return (np.asarray(info.token_out.data.astype(jnp.float32))[:, :, 0, :],
-            float(info.total_loss.data))
+    return harness.build(_config(dtype, **extra))
 
 
 def _logits(model, variables, batch):
-    return _logits_and_loss(model, variables, batch)[0]
-
-
-def _error(got, want) -> float:
-    return float(np.max(np.abs(want - got)) / np.max(np.abs(want)))
+    return harness.logits_and_loss(model, variables, batch)[0]
 
 
 # ---- the chunked rule --------------------------------------------------------
@@ -100,7 +81,7 @@ def chunked_rule_is_the_recurrence_test(chunk, s, decay):
     got = jax.jit(lambda *a: delta_mod.delta_rule(*a, chunk)[0])(*inputs)
     want = jax.jit(_reference().recurrence)(*inputs)
     assert got.dtype == jnp.float32
-    assert _error(np.asarray(got), np.asarray(want)) < 2e-5
+    assert harness.error(np.asarray(got), np.asarray(want)) < 2e-5
 
 
 @pytest.mark.parametrize("decay", [0.05, 6.0])
@@ -123,7 +104,7 @@ def chunked_rules_gradients_are_the_recurrences_test(chunk, s, decay):
     wants = jax.jit(jax.grad(stepped, argnums=range(5)))(*inputs)
     for name, a, r in zip("q k v beta g".split(), grads, wants):
         assert np.all(np.isfinite(a)), name
-        assert _error(np.asarray(a), np.asarray(r)) < 1e-4, name
+        assert harness.error(np.asarray(a), np.asarray(r)) < 1e-4, name
 
 
 @pytest.mark.parametrize("budget,groups", [(48 << 20, 1), (2 * 32 * 8 * 4, 3),
@@ -205,8 +186,8 @@ def float32_program_is_the_reference_test(blocks):
     extra = {} if blocks is None else {"block_config": blocks}
     config, _, model, batch, variables = _build("float32", **extra)
     want = _reference().forward(variables, batch["token_x"][..., 0], config)
-    logits, loss = _logits_and_loss(model, variables, batch)
-    assert _error(logits, want) < 2e-5
+    logits, loss = harness.logits_and_loss(model, variables, batch)
+    assert harness.error(logits, want) < 2e-5
     from benchmark.reference import common
     want_loss = float(common.loss_of(want, batch["token_y"][..., 0], 0.0))
     assert abs(want_loss - loss) <= 2.0 ** -18 * want_loss
@@ -241,10 +222,10 @@ def bfloat16_program_meets_a_bound_float8_misses_test():
     tokens = batch["token_x"][..., 0]
     ref = _reference()
     want = ref.forward(variables, tokens, config)
-    assert _error(_logits(model, variables, batch), want) < 0.4
-    assert _error(ref.forward(variables, tokens, config,
+    assert harness.error(_logits(model, variables, batch), want) < 0.4
+    assert harness.error(ref.forward(variables, tokens, config,
                               stream_dtype=jnp.bfloat16), want) < 0.4
-    assert _error(ref.forward(variables, tokens, config,
+    assert harness.error(ref.forward(variables, tokens, config,
                               stream_dtype=jnp.float8_e4m3fn), want) > 0.6
 
 
@@ -272,9 +253,9 @@ def rounded_weights_alone_miss_the_benchmarks_toy_bound_test():
                if "input0/gather0/embed0/normal_var0" in k]
     ref = _reference()
     want = ref.forward(variables, tokens[..., 0], config)
-    assert _error(ref.forward(rounded, tokens[..., 0], config), want) \
+    assert harness.error(ref.forward(rounded, tokens[..., 0], config), want) \
         > 1.5 * 2 ** -4
-    assert _error(ref.forward({**variables, name: rounded[name]},
+    assert harness.error(ref.forward({**variables, name: rounded[name]},
                               tokens[..., 0], config), want) > 2 ** -4
 
 
@@ -311,7 +292,7 @@ def a_short_sequence_is_one_chunk_test():
     config, _, model, batch, variables = _build(
         "float32", sequence_length=48, delta_chunk=64)
     want = _reference().forward(variables, batch["token_x"][..., 0], config)
-    assert _error(_logits(model, variables, batch), want) < 2e-5
+    assert harness.error(_logits(model, variables, batch), want) < 2e-5
 
 
 # ---- the configuration -------------------------------------------------------
@@ -439,10 +420,7 @@ def traced_ops_carry_the_rules_steps_test():
     ``jax.checkpoint``, whose bodies are lowered as functions of their own:
     the compiled ops carry the whole path, which is what the trace reads."""
     _, _, model, batch, variables = _build("float32")
-    text = jax.jit(jax.grad(
-        lambda v: model.apply(v, batch).total_loss.data)).lower(
-        variables).compile().as_text()
-    names = set(re.findall(r'op_name="([^"]*)"', text))
+    names = harness.traced_op_names(model, variables, batch)
     for step in ("decay", "solve", "intra_chunk", "inter_chunk",
                  "state_out"):
         inside = [n for n in names if re.search(
@@ -526,78 +504,6 @@ def remat_rules_count_the_new_layer_test(monkeypatch):
         == 283_115_520
 
 
-def step_with_the_conv_kernel_test(monkeypatch):
-    """The toy step under ``jax.checkpoint`` + ``jax.grad`` as a TPU process
-    traces it (the kernels interpreted): loss and every gradient with the
-    bias-free kernel pair equal the fallback's."""
-    import functools
-    from homebrewnlp_tpu.parallel import causal_conv as cc
-    _, params, model, batch, variables = _build(
-        "float32", delta_key_features=32, delta_value_features=64,
-        sequence_length=128, delta_chunk=32, train_batch_size=1,
-        block_config=_ONE["gated_delta"])
-
-    def loss_and_grads():
-        v = {k: jnp.asarray(a) for k, a in variables.items()}
-        return jax.jit(jax.value_and_grad(
-            lambda v: model.apply(v, batch).total_loss.data))(v)
-
-    want_loss, want = loss_and_grads()
-    monkeypatch.setattr(delta_mod, "kernel_applies", functools.partial(
-        cc.kernel_applies, backend="tpu"))
-    monkeypatch.setattr(
-        delta_mod, "causal_conv_silu",
-        lambda x, w, b, offset: cc.causal_conv_silu(x, w, b, offset, True))
-    text = str(jax.make_jaxpr(
-        lambda v: model.apply(v, batch).total_loss.data)(variables))
-    assert text.count("name=_fwd_impl") == 1 and "mamba_conv_fwd" in text
-    loss, got = loss_and_grads()
-    assert abs(float(loss) - float(want_loss)) <= 2e-5
-    assert set(got) == set(want)
-    for name in want:
-        a, r = (np.asarray(t[name], np.float32) for t in (got, want))
-        assert np.max(np.abs(a - r)) <= 2e-5 * max(np.max(np.abs(r)), 1e-3), \
-            name
-
-
-def step_with_the_solve_kernel_test(monkeypatch):
-    """The toy step under ``jax.checkpoint`` + ``jax.grad`` as a TPU process
-    traces it at a size whose systems fill one tile of the solve's kernel
-    pair (2 x 16 chunks x 4 heads of 16 x 16; the kernels interpreted): the
-    rule traces the Pallas forward where the blocked form stood — once, the
-    ``jax.jit`` around it — and loss and every gradient equal the blocked
-    form's."""
-    import functools
-    from homebrewnlp_tpu.parallel import delta_solve as ds
-    _, params, model, batch, variables = _build(
-        "float32", sequence_length=256, delta_heads=4,
-        block_config=_ONE["gated_delta"])
-    assert recurrent.solve_kernel_layers(params, "tpu") == 1
-
-    def loss_and_grads():
-        v = {k: jnp.asarray(a) for k, a in variables.items()}
-        return jax.jit(jax.value_and_grad(
-            lambda v: model.apply(v, batch).total_loss.data))(v)
-
-    want_loss, want = loss_and_grads()
-    monkeypatch.setattr(delta_mod, "solve_kernel_applies", functools.partial(
-        ds.solve_kernel_applies, backend="tpu"))
-    monkeypatch.setattr(delta_mod, "inverse_unit_lower", functools.partial(
-        ds.inverse_unit_lower, interpret=True))
-    monkeypatch.setattr(delta_mod, "inverse_unit_lower_bwd", functools.partial(
-        ds.inverse_unit_lower_bwd, interpret=True))
-    text = str(jax.make_jaxpr(
-        lambda v: model.apply(v, batch).total_loss.data)(variables))
-    assert text.count("name=_fwd_impl") == 1 and "delta_solve_fwd" in text
-    loss, got = loss_and_grads()
-    assert abs(float(loss) - float(want_loss)) <= 2e-5
-    assert set(got) == set(want)
-    for name in want:
-        a, r = (np.asarray(t[name], np.float32) for t in (got, want))
-        assert np.max(np.abs(a - r)) <= 2e-5 * max(np.max(np.abs(r)), 1e-3), \
-            name
-
-
 def step_reports_the_transform_watch_test():
     """Five steps of the trainer: the loss is finite and falls, a later call
     publishes an earlier step's ``hbnlp_delta_transform_abs_max``."""
@@ -646,7 +552,10 @@ def _forward_carries(jaxpr, found=None, path=""):
 @pytest.mark.parametrize("scan_layers", [False, True],
                          ids=["unrolled", "scan_layers"])
 def saved_rule_output_changes_no_bit_test(monkeypatch, scan_layers, groups):
-    """Two periods under ``checkpoint`` with the rule's output saved by each
+    """Two periods — of ONE linear layer and the full one here, not the
+    published three and one: a step's compile is its blocks' count, and the
+    layers' counts below follow the toy (PR 60) — under ``checkpoint`` with
+    the rule's output saved by each
     block's ``jax.checkpoint`` (``"auto"``: the bytes fit): the loss and every
     gradient are bit for bit those of ``remat_policy: "recompute"``, the
     gradient's jaxpr runs the rule forward twice a ``gated_delta`` layer
@@ -660,16 +569,17 @@ def saved_rule_output_changes_no_bit_test(monkeypatch, scan_layers, groups):
         # [2, 64] tokens x chunk 16 x float32: one head's matrix
         monkeypatch.setattr(delta_mod, "GROUP_BYTES", 2 * 64 * 16 * 4)
     assert 3 // delta_mod._group_heads(2, 64, 3, 16) == groups
-    depth = 2
+    depth, linear = 2, 1
     results = {}
     for policy in ("recompute", "auto"):
         _, params, model, batch, variables = _build(
             "float32", depth=depth, scan_layers=scan_layers,
-            remat_policy=policy)
+            remat_policy=policy, block_config=_ONE["gated_delta"]
+            + _ONE["attention-nope-qk_norm"])
         fn = jax.value_and_grad(
             lambda v: model.apply(v, batch).total_loss.data)
-        # the depth's scan traces its three linear layers once
-        layers = 3 if scan_layers else 3 * depth
+        # the depth's scan traces its linear layers once
+        layers = linear if scan_layers else linear * depth
         forwards = len(_forward_carries(jax.make_jaxpr(fn)(variables).jaxpr))
         results[policy] = (params, model, forwards / layers,
                            jax.jit(fn)(variables))
@@ -681,20 +591,21 @@ def saved_rule_output_changes_no_bit_test(monkeypatch, scan_layers, groups):
     for name in want:
         np.testing.assert_array_equal(np.asarray(grads[name]),
                                       np.asarray(want[name]), err_msg=name)
-    # [2, 64, 3, 16] float32 a layer, three linear layers a period
-    saved = 2 * 64 * 3 * 16 * 4 * 3 * depth
+    # [2, 64, 3, 16] float32 a layer
+    saved = 2 * 64 * 3 * 16 * 4 * linear * depth
     assert delta_mod.gated_delta.declares.offer(params, set()).names \
         == ("gated_delta_out",)
-    assert remat.stash_plan(params)["recurrent"] == (3 * depth, saved)
+    assert remat.stash_plan(params)["recurrent"] == (linear * depth, saved)
     assert remat.stash_names(params) == ("gated_delta_out",)
     assert _checkpoint_policy(params) \
         is not jax.checkpoint_policies.nothing_saveable
     line = Trainer(params, model).publish_stash_plan()
-    assert f"recurrent {3 * depth} layers, {saved} bytes a device" in line
+    assert f"recurrent {linear * depth} layers, {saved} bytes a device" \
+        in line
     snap = telemetry.registry().snapshot()
     assert snap["hbnlp_remat_stash_bytes"]["series"][("recurrent",)] == saved
     assert snap["hbnlp_remat_stash_layers"]["series"][("recurrent",)] \
-        == 3 * depth
+        == linear * depth
     off = results["recompute"][0]
     assert remat.stash_plan(off)["recurrent"] == (0, 0)
     assert remat.stash_names(off) == ()
@@ -742,3 +653,98 @@ def the_scalar_decay_layers_jaxpr_is_the_parents_test():
     text = re.sub(r" at 0x[0-9a-f]+", "", re.sub(r" at \S+:\d+", "", text))
     assert hashlib.sha256(text.encode()).hexdigest()[:16] \
         == "d162ea973ae1124b"
+
+
+# ---- compiled for a described v5e ---------------------------------------------
+
+def delta_layers_kernels_keep_their_scopes_test(v5e, monkeypatch):
+    """One ``gated_delta`` layer at Olmo-Hybrid's published widths, 1 x 8,192
+    tokens (half the cell's), compiled for a v5e as a TPU process traces it:
+    the bias-free conv over 11,520 channels is the same Pallas pair in its
+    three forms, every one folds into ``body/gated_delta/conv``; the rule's
+    triangular solve (PR 37) is the pair of ``parallel/delta_solve.py`` and
+    what is around it (PR 50) the two pairs of ``parallel/delta_rule.py``
+    (``delta_strict_*`` makes the solve's input, ``delta_rule_*`` runs the
+    rule): ONE ``delta_rule_bwd``, ``delta_solve_bwd`` and
+    ``delta_strict_bwd`` a layer and as many of each ``_fwd`` as the memory
+    plan gives — two (the step's forward and the block's replay) where the
+    ``recurrent`` kind saves the rule's output alone, so that the replay
+    makes ``T`` and the entering states again; one if they ride with it —
+    the solve on
+    all 3,840 systems of the layer at once, operands ``[systems * 64, 64]``
+    (a bitcast of XLA's ``[.., 64, 64]``), the rule on the sequence-minor
+    layout the conv's kernels write: no transposing copy of a large operand
+    beside any.  Mosaic accepts all six, their ops carry
+    ``gated_delta_0/delta_rule/`` (the solve's ``../solve/``) and fold into
+    ``body/gated_delta/delta_rule``, which ``delta_rule_time_share`` and
+    ``delta_rule_roofline`` read, and none bears a name another metric's
+    reader takes."""
+    params, hlo = harness.cell_layer_hlo(
+        v5e, monkeypatch, "train_olmo_hybrid_7b_long", 0,
+        sequence_length=8192)
+    assert params.block_config[0].layer[0] == "gated_delta"
+    assert recurrent.conv_kernel_layers(params) == 1
+    assert recurrent.solve_kernel_layers(params) == 1
+    assert recurrent.rule_kernel_layers(params) == 1
+    # what the plan saves of the rule: its output alone -> the replay runs
+    # both forward kernels again
+    assert remat.stash_plan(params)["recurrent"][0] == 1
+    saved = delta_mod.gated_delta.declares.offer(params, set()).names
+    forwards = 2 if saved == ("gated_delta_out",) else 1
+    calls = re.findall(r'%([\w.-]+) = ([^\n]*?)custom_call_target='
+                       r'"tpu_custom_call"[^\n]*?op_name="([^"]+)"', hlo)
+    assert sorted(re.sub(r"\.\d+$", "", name) for name, _, _ in calls) \
+        == sorted(["delta_rule_bwd", "delta_solve_bwd", "delta_strict_bwd",
+                   "mamba_conv_bwd"]
+                  + ["delta_rule_fwd", "delta_solve_fwd", "delta_strict_fwd"]
+                  * forwards + ["mamba_conv_fwd"] * 2)
+
+    def operand_bytes(name):
+        shape = re.search(rf"%{re.escape(name)} = (\w+)\[([\d,]*)\]", hlo)
+        return np.dtype(shape.group(1).replace("bf16", "float16")
+                        .replace("f32", "float32")).itemsize * int(np.prod(
+            [int(d) for d in shape.group(2).split(",") if d]))
+
+    forms = {"delta_solve": [], "delta_strict": [], "delta_rule": []}
+    for name, line, op_name in calls:
+        assert not re.match(r"flash_|map_mixer_", name)
+        if name.startswith("mamba_conv"):
+            assert scope_key(op_name) == "body/gated_delta/conv", op_name
+            continue
+        assert scope_key(op_name) == "body/gated_delta/delta_rule", op_name
+        assert "gated_delta_0/delta_rule/" in op_name, op_name
+        # nothing laid out again but the float32 [1, 30, 8192] rows of gamma
+        # (``copy-done`` is XLA's move between memory spaces, one layout)
+        for copied in re.findall(r"%((?:copy|transpose)(?:\.\d+)?)(?![\w.-])",
+                                 line.split("custom-call(")[1]):
+            assert operand_bytes(copied) <= 30 * 8192 * 4, (name, copied)
+        if name.startswith(("delta_solve", "delta_strict")):
+            assert re.search(r"gated_delta_0/delta_rule/.*solve/", op_name), \
+                op_name
+        if name.startswith("delta_solve"):
+            # 128 chunks x 30 heads x 64 rows
+            assert line.startswith("f32[245760,64]{1,0"), line
+        elif name.startswith("delta_strict_fwd"):
+            assert line.startswith("f32[1,128,30,64,64]{4,3,2,1,0"), line
+        elif name.startswith("delta_rule_fwd"):
+            # o^T and the entering states of every chunk and head
+            assert line.startswith("(bf16[1,5760,8192]{2,1,0") \
+                and "bf16[1,128,30,192,96]{4,3,2,1,0" in line, line
+        forms[name[:name.index("_", 6)]].append((
+            name.split(".")[0].endswith("bwd"),
+            "rematted_computation" in op_name,
+            "/transpose(jvp(" in op_name))
+    # the step's forward; the block's replay and the backward, both inside
+    # the transposed program
+    for kernel, seen in forms.items():
+        assert sorted(seen) == sorted(
+            [(False, False, False), (True, False, True)]
+            + [(False, True, True)] * (forwards - 1)), (kernel, seen)
+
+
+def saved_flash_outputs_keep_their_scope_test(v5e, monkeypatch):
+    """The cell's full-attention layer (``harness.py
+    saved_flash_outputs_keep_their_scope``)."""
+    harness.saved_flash_outputs_keep_their_scope(
+        v5e, monkeypatch, "train_olmo_hybrid_7b_long",
+        "attention-nope-qk_norm", "body/attention")

@@ -2,41 +2,29 @@
 plain reference ``benchmark/reference/olmoe_1b_7b.py`` in logits, loss and
 gradients at tiny widths, the dropless dispatch at its extreme, the chunked
 head loss against the old one-hot form, and rotary positions' invariants."""
-import json
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import harness
 from homebrewnlp_tpu.analysis.cost_ledger import scope_key
-from homebrewnlp_tpu.config import ModelParameter
-from homebrewnlp_tpu.model import Model, loss as loss_mod, moe as moe_mod
+from homebrewnlp_tpu.model import loss as loss_mod, moe as moe_mod
 from homebrewnlp_tpu.model.spatial import rotary
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TINY = {"depth": 2, "heads": 4, "features_per_head": 16,
         "sequence_length": 64, "train_batch_size": 2, "vocab_size": 384,
         "tpu_size": 1, "use_checkpointing": False, "slice_dtype": "float32"}
 
 
 def _reference():
-    import importlib
-    return importlib.import_module("benchmark.reference.olmoe_1b_7b")
+    return harness.reference("olmoe_1b_7b")
 
 
 def _build(experts: int, top_k: int, dtype: str, **extra):
-    with open(os.path.join(REPO, "configs", "olmoe_1b_7b.json")) as f:
-        config = dict(json.load(f), **TINY, experts=experts, moe_top_k=top_k,
-                      calculation_dtype=dtype, **extra)
-    params = ModelParameter(config)
-    assert not params.unknown_config_keys
-    model = Model(params)
-    rng = np.random.default_rng(experts)
-    tokens = rng.integers(0, 256, (2, 64, 1)).astype(np.int32)
-    batch = {"token_x": tokens, "token_y": np.roll(tokens, -1, axis=1)}
-    return config, params, model, batch, model.init(batch, seed=11)
+    return harness.build(harness.config_of(
+        "olmoe_1b_7b", TINY, dtype, experts=experts, moe_top_k=top_k,
+        **extra), data_seed=experts, init_seed=11)
 
 
 # (16, 1): top-1 with every expert held (ZAYA1's count and choice, PR 39): one
@@ -59,20 +47,11 @@ CASES = [(8, 2), (64, 8), (16, 1)]
     # (reference_at_the_next_precision_below_fails_test)
     ("bfloat16", 2 ** -4)])
 def program_matches_reference_test(experts, top_k, dtype, tolerance):
-    config, params, model, batch, variables = _build(experts, top_k, dtype)
-    from benchmark.reference import common
-    info = model.apply(variables, batch)
-    got = np.asarray(info.token_out.data.astype(jnp.float32))[:, :, 0, :]
-    want = np.asarray(_reference().forward(
-        variables, batch["token_x"][..., 0], config))
-    assert got.shape == want.shape == (2, 64, 384)
-    err = np.max(np.abs(want - got)) / np.max(np.abs(want))
-    assert err <= tolerance, (experts, dtype, err)
-    want_loss = float(common.loss_of(want, batch["token_y"][..., 0], 0.0))
     # the loss is reported in the calculation dtype: bfloat16's spacing
     # between 4 and 8 is 2^-5, float32 sums 128 terms
-    ulp = 2.0 ** -18 if dtype == "float32" else 2.0 ** -5
-    assert abs(want_loss - float(info.total_loss.data)) <= ulp
+    got = harness.assert_program_matches_reference(
+        _reference(), _build(experts, top_k, dtype), dtype, tolerance)
+    assert got.shape == (2, 64, 384)
 
 
 @pytest.mark.parametrize("experts,top_k", CASES)
@@ -88,27 +67,21 @@ def loss_and_gradients_match_reference_test(experts, top_k):
     variables = {k: jnp.asarray(v) for k, v in variables.items()}
     assert params.train and params.moe_balance_loss and \
         params.moe_router_z_loss
-    got_loss, got = jax.value_and_grad(
-        lambda v: model.apply(v, batch).total_loss.data)(variables)
-    want = jax.grad(lambda v: ref.train_loss(v, tokens, targets, config))(
-        variables)
+    got_loss, got = harness.loss_and_grads(model, variables, batch)
+    _, want = harness.reference_loss_and_grads(ref, variables, tokens,
+                                               targets, config)
     from benchmark.reference import common
     want_loss = common.loss_of(ref.forward(variables, tokens, config),
                                targets, 0.0)
     assert abs(float(got_loss) - float(want_loss)) <= 2.0 ** -18
-    assert set(got) == set(want)
-    for name in sorted(got):
-        scale = float(jnp.max(jnp.abs(want[name])))
-        assert scale > 0, name
-        # float32 both sides; sums of up to 128 tokens x 64 features in
-        # another order, and a softmax's gradient through exp: 1e-4 of the
-        # parameter's largest gradient
-        err = float(jnp.max(jnp.abs(got[name] - want[name]))) / scale
-        assert err <= 1e-4, (name, err)
+    # float32 both sides; sums of up to 128 tokens x 64 features in another
+    # order, and a softmax's gradient through exp: 1e-4 of the parameter's
+    # largest gradient
+    harness.assert_grads_match(got, want, 1e-4, alive=True)
     # the router terms are in those gradients: without them the router's
     # differ by far more than the tolerance
-    plain = jax.grad(lambda v: common.loss_of(
-        ref.forward(v, tokens, config), targets, 0.0))(variables)
+    plain = jax.jit(jax.grad(lambda v: common.loss_of(
+        ref.forward(v, tokens, config), targets, 0.0)))(variables)
     router = "gpt0/body0/block0_1_0/moe_0/normal_var0/var0"
     assert float(jnp.max(jnp.abs(plain[router] - want[router]))) \
         > 1e-2 * float(jnp.max(jnp.abs(want[router])))
@@ -185,15 +158,16 @@ def the_step_reports_the_routed_layers_load_test():
     config, params, model, batch, variables = _build(8, 2, "float32")
     zeroed = {k: (np.zeros_like(v) if "moe_0/normal_var0" in k else v)
               for k, v in variables.items()}
-    info = model.apply(zeroed, batch, layer_stats=True)
+    info = harness.apply_with_stats(model, zeroed, batch)
     np.testing.assert_allclose(
         np.asarray(info.layer_stats["moe_load_max_over_mean"]), [4.0, 4.0])
     np.testing.assert_allclose(
         np.asarray(info.layer_stats["moe_routed_pairs"]), [256.0, 256.0])
-    assert model.apply(zeroed, batch).layer_stats is None
-    grads = jax.grad(lambda v: model.apply(
+    assert jax.eval_shape(lambda v: model.apply(v, batch),
+                          zeroed).layer_stats is None
+    grads = jax.jit(jax.grad(lambda v: model.apply(
         {k: jnp.asarray(a) for k, a in v.items()}, batch,
-        layer_stats=True).total_loss.data)(zeroed)
+        layer_stats=True).total_loss.data))(zeroed)
     assert all(np.all(np.isfinite(np.asarray(g))) for g in grads.values())
 
 

@@ -4,7 +4,6 @@ The chain members' exact formulas (SM3 min-bucket, AGC, Nesterov momentum,
 debiased Adam, grafting) are the reference's loss-parity-critical parts
 (SURVEY.md §7 hard part 1); each is locked down numerically here.
 """
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest

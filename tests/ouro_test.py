@@ -4,7 +4,6 @@ pass's logits, the exit distribution, the loss and the gradients at tiny
 widths; one set of parameters re-entered by every pass; the weighted walk of
 the head loss; what the memory rule, the trace's scopes and the step's gauges
 read of the passes; and what refuses a looped model by name."""
-import importlib
 import json
 import os
 import re
@@ -14,13 +13,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import harness
+from harness import REPO
 from homebrewnlp_tpu import telemetry
 from homebrewnlp_tpu.analysis.cost_ledger import scope_key
 from homebrewnlp_tpu.config import ModelParameter
 from homebrewnlp_tpu.model import Model, loop as loop_mod, loss as loss_mod
 from homebrewnlp_tpu.model.remat import stash_line, stash_plan
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TINY = {"depth": 2, "heads": 4, "features_per_head": 16,
         "sequence_length": 64, "train_batch_size": 2, "vocab_size": 384,
         "tpu_size": 1, "use_checkpointing": False, "slice_dtype": "float32",
@@ -30,35 +30,33 @@ GATE_BIAS = "gpt0/loss0/exit_gate0/constant_var0/var0"
 
 
 def _reference():
-    return importlib.import_module("benchmark.reference.ouro_2_6b")
+    return harness.reference("ouro_2_6b")
 
 
 def _config(dtype="float32", **extra):
-    with open(os.path.join(REPO, "configs", "ouro_2_6b.json")) as f:
-        return {**json.load(f), **TINY, "calculation_dtype": dtype, **extra}
+    return harness.config_of("ouro_2_6b", TINY, dtype, **extra)
 
 
 def _batch(batch=2, seq=64):
-    rng = np.random.default_rng(0)
-    tokens = rng.integers(0, 256, (batch, seq, 1)).astype(np.int32)
-    return {"token_x": tokens, "token_y": np.roll(tokens, -1, axis=1)}
+    return harness.token_batch(batch, seq, seed=0)
+
+
+def _lively(variables):
+    """At normal(0.02) and 64 features the gate's logits are ~0.1: a gate
+    twenty times as steep makes p differ a token, so a wrong product of the
+    (1 - lambda)s cannot hide."""
+    variables = {k: jnp.asarray(v) for k, v in variables.items()}
+    if GATE in variables:
+        variables[GATE] = variables[GATE] * 20.0
+        variables[GATE_BIAS] = variables[GATE_BIAS] + 0.3
+    return variables
 
 
 def _build(dtype="float32", lively=True, **extra):
-    config = _config(dtype, **extra)
-    params = ModelParameter(config)
-    assert not params.unknown_config_keys
-    model = Model(params)
-    batch = _batch(params.train_batch_size, params.sequence_length)
-    variables = {k: jnp.asarray(v)
-                 for k, v in model.init(batch, seed=11).items()}
-    if lively and GATE in variables:
-        # at normal(0.02) and 64 features the gate's logits are ~0.1: a
-        # gate twenty times as steep makes p differ a token, so a wrong
-        # product of the (1 - lambda)s cannot hide
-        variables[GATE] = variables[GATE] * 20.0
-        variables[GATE_BIAS] = variables[GATE_BIAS] + 0.3
-    return config, params, model, batch, variables
+    return harness.build(
+        _config(dtype, **extra), data_seed=0, init_seed=11,
+        lively=_lively if lively else
+        lambda v: {k: jnp.asarray(a) for k, a in v.items()})
 
 
 def _sides(batch):
@@ -74,8 +72,7 @@ def _pass_logits(config, variables, batch, steps):
     params = ModelParameter(dict(config, loop_steps=steps))
     model = Model(params)
     model.init(batch, seed=11)
-    info = model.apply(variables, batch)
-    return np.asarray(info.token_out.data.astype(jnp.float32))[:, :, 0, :]
+    return harness.logits_and_loss(model, variables, batch)[0]
 
 
 @pytest.mark.parametrize("dtype,tolerance,loss_tolerance", [
@@ -99,7 +96,8 @@ def every_pass_matches_the_reference_test(dtype, tolerance, loss_tolerance):
         ref = np.asarray(want["logits"][step])
         err = np.max(np.abs(ref - got)) / np.max(np.abs(ref))
         assert err <= tolerance, (dtype, step, err)
-    info = model.apply(variables, batch, layer_stats=True)
+    info = jax.jit(lambda v, b: model.apply(v, b, layer_stats=True))(
+        variables, batch)
     assert abs(float(info.total_loss.data) - float(want["loss"])) \
         <= loss_tolerance
     stats = info.layer_stats
@@ -126,19 +124,13 @@ def gradients_match_the_reference_test():
     config, params, model, batch, variables = _build()
     tokens, targets = _sides(batch)
     ref = _reference()
-    got_loss, got = jax.value_and_grad(
-        lambda v: model.apply(v, batch).total_loss.data)(variables)
-    want_loss, want = jax.value_and_grad(
-        lambda v: ref.train_loss(v, tokens, targets, config))(variables)
+    got_loss, got = harness.loss_and_grads(model, variables, batch)
+    want_loss, want = harness.reference_loss_and_grads(ref, variables, tokens,
+                                                       targets, config)
     assert abs(float(got_loss) - float(want_loss)) <= 2e-5
-    assert set(got) == set(want)
-    for name in sorted(got):
-        scale = float(jnp.max(jnp.abs(want[name])))
-        assert scale > 0, name
-        # float32 both sides, sums in another order: 1e-4 of the
-        # parameter's largest gradient
-        err = float(jnp.max(jnp.abs(got[name] - want[name]))) / scale
-        assert err <= 1e-4, (name, err)
+    # float32 both sides, sums in another order: 1e-4 of the parameter's
+    # largest gradient
+    harness.assert_grads_match(got, want, 1e-4, alive=True)
 
 
 def a_shared_gradient_is_the_sum_of_the_passes_test():
@@ -148,7 +140,7 @@ def a_shared_gradient_is_the_sum_of_the_passes_test():
     config, params, model, batch, variables = _build()
     tokens, targets = _sides(batch)
     ref = _reference()
-    got = jax.grad(lambda v: model.apply(v, batch).total_loss.data)(variables)
+    _, got = harness.loss_and_grads(model, variables, batch)
     body = {k: v for k, v in variables.items()
             if "/body0/" in k or "/lang_out0_0/" in k}
     copies = jax.grad(lambda own: ref.train_loss(
@@ -214,6 +206,7 @@ def a_zero_gate_weighs_the_passes_by_halves_test():
     cross-entropies weighed so, less beta H."""
     config, params, model, batch, variables = _build(lively=False)
     variables = {**variables, GATE: jnp.zeros_like(variables[GATE])}
+    # op by op: compiled as one program the entropy's sum rounds 1e-6 apart
     info = model.apply(variables, batch, layer_stats=True)
     stats = info.layer_stats
     np.testing.assert_allclose(np.asarray(stats["loop_exit_share"]),
@@ -361,12 +354,10 @@ def every_form_of_the_body_is_the_same_model_test(strategy, scan):
     """``checkpoint`` and ``none``, unrolled and scanned over depth inside
     each pass: the loss and the gradients of the cell's form."""
     _, _, model, batch, variables = _build()
-    want_loss, want = jax.value_and_grad(
-        lambda v: model.apply(v, batch).total_loss.data)(variables)
+    want_loss, want = harness.loss_and_grads(model, variables, batch)
     _, _, other, _, _ = _build(memory_reduction_strategy=strategy,
                                scan_layers=scan)
-    got_loss, got = jax.value_and_grad(
-        lambda v: other.apply(v, batch).total_loss.data)(variables)
+    got_loss, got = harness.loss_and_grads(other, variables, batch)
     assert float(got_loss) == pytest.approx(float(want_loss), abs=1e-6)
     for name in want:
         np.testing.assert_allclose(np.asarray(got[name]),
@@ -429,10 +420,7 @@ def the_scopes_fold_test():
     ops; the blocks inside still fold to ``body/<layer>``, the final norm to
     ``output``, the gate to ``exit_gate`` and the walk to ``head_loss``."""
     _, _, model, batch, variables = _build()
-    text = jax.jit(jax.grad(
-        lambda v: model.apply(v, batch).total_loss.data)).lower(
-        variables).compile().as_text()
-    names = set(re.findall(r'op_name="([^"]*)"', text))
+    names = harness.traced_op_names(model, variables, batch)
     for step in range(4):
         inside = [n for n in names if f"loop/pass{step}/" in n]
         assert inside, step
@@ -531,8 +519,7 @@ def a_data_and_model_mesh_runs_the_loop_test():
     config, params, model, batch, variables = _build(
         mesh_shape_override={"data": 2, "model": 2}, tpu_size=4)
     mesh = shardlib.build_mesh(params, jax.devices()[:4])
-    want_loss, want = jax.value_and_grad(
-        lambda v: model.apply(v, batch).total_loss.data)(variables)
+    want_loss, want = harness.loss_and_grads(model, variables, batch)
     placed = shardlib.shard_params(params, variables, model.param_dims, mesh)
     placed_batch = shardlib.shard_batch(params, batch, mesh)
     got_loss, got = jax.jit(jax.value_and_grad(

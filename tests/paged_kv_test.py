@@ -15,8 +15,7 @@ chunk step's HLO audit (every pool leaf aliased, no full-pool copy), the
 ``kv_paging`` knob resolution matrix, and the REST path with the
 ``hbnlp_kv_*`` gauges.
 
-Standalone-runnable (tier-1 truncates at 870s on this box;
-``scripts/run_late_markers.sh`` runs this suite in the late-marker set):
+Standalone-runnable:
 ``python -m pytest tests/paged_kv_test.py -q``
 """
 import json

@@ -12,7 +12,7 @@ subprocesses of a tiny paged-engine model behind the router — answering
 completions deterministically, merging /health, and exporting
 replica-labeled block-pool gauges on one scrape.
 
-Standalone-runnable (late-marker set, scripts/run_late_markers.sh):
+Standalone-runnable:
 ``python -m pytest tests/router_test.py -q``
 """
 import json

@@ -7,7 +7,6 @@ the sleeps are patched out, so a full preemption round-trip runs in
 seconds."""
 import importlib.util
 import os
-import sys
 import types
 
 

@@ -7,7 +7,6 @@ interpret mode against a masked softmax, forward and backward; the SHARE test
 (the two tensor-parallel ranks' mixer outputs add up to the uncut layer); a
 replay that would choose otherwise still reads the saved choice; refusals,
 scopes and gauges."""
-import importlib
 import json
 import os
 import re
@@ -18,6 +17,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import harness
+from harness import REPO
 from homebrewnlp_tpu.analysis.cost_ledger import scope_key
 from homebrewnlp_tpu.config import ModelParameter
 from homebrewnlp_tpu.model import Model, remat, sparse
@@ -26,7 +27,6 @@ from homebrewnlp_tpu.model import spatial
 from homebrewnlp_tpu.model.mamba import ssd
 from homebrewnlp_tpu.parallel import flash_attention as fa
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SPARSE = "attention-nope-qk_norm_head-gate_features-sparse"
 
 
@@ -61,19 +61,16 @@ TINY = {"depth": 1, "heads": 4, "features_per_head": 16,
 
 
 def _reference():
-    return importlib.import_module("benchmark.reference.minicpm_sala")
+    return harness.reference("minicpm_sala")
 
 
 def _config(dtype: str = "float32", **extra) -> dict:
-    with open(os.path.join(REPO, "configs", "minicpm_sala.json")) as f:
-        return {**json.load(f), **TINY, "calculation_dtype": dtype, **extra}
+    return harness.config_of("minicpm_sala", TINY, dtype, **extra)
 
 
 def _batch(config, seed: int = 5):
-    rng = np.random.default_rng(seed)
-    shape = (config["train_batch_size"], config["sequence_length"], 1)
-    tokens = rng.integers(0, 256, shape).astype(np.int32)
-    return {"token_x": tokens, "token_y": np.roll(tokens, -1, axis=1)}
+    return harness.token_batch(config["train_batch_size"],
+                               config["sequence_length"], seed)
 
 
 def _lively(variables, seed: int = 3):
@@ -96,22 +93,7 @@ def _lively(variables, seed: int = 3):
 
 
 def _build(dtype: str = "float32", **extra):
-    config = _config(dtype, **extra)
-    params = ModelParameter(config)
-    assert not params.unknown_config_keys
-    model = Model(params)
-    batch = _batch(config)
-    return config, params, model, batch, _lively(model.init(batch, seed=13))
-
-
-def _logits_and_loss(model, variables, batch):
-    info = jax.jit(lambda v, b: model.apply(v, b))(variables, batch)
-    return (np.asarray(info.token_out.data.astype(jnp.float32))[:, :, 0, :],
-            float(info.total_loss.data))
-
-
-def _error(got, want) -> float:
-    return float(np.max(np.abs(want - got)) / np.max(np.abs(want)))
+    return harness.build(_config(dtype, **extra), lively=_lively)
 
 
 # ---- the program against the reference ---------------------------------------
@@ -143,15 +125,9 @@ def _error(got, want) -> float:
          "dense", "other_sizes", "one_chunk", "bfloat16"])
 def program_matches_reference_test(dtype, tolerance, extra):
     config, _, model, batch, variables = _build(dtype, **extra)
-    got, loss = _logits_and_loss(model, variables, batch)
-    want = np.asarray(_reference().forward(variables, batch["token_x"][..., 0],
-                                           config))
-    assert got.shape == want.shape == (2, config["sequence_length"], 272)
-    assert _error(got, want) < tolerance
-    from benchmark.reference import common
-    want_loss = float(common.loss_of(want, batch["token_y"][..., 0], 0.0))
-    assert abs(want_loss - loss) <= (2.0 ** -18 if dtype == "float32"
-                                     else 2.0 ** -5)
+    got = harness.assert_program_matches_reference(
+        _reference(), (config, _, model, batch, variables), dtype, tolerance)
+    assert got.shape == (2, config["sequence_length"], 272)
 
 
 def loss_and_gradients_match_reference_test():
@@ -163,40 +139,27 @@ def loss_and_gradients_match_reference_test():
     tokens, targets = batch["token_x"][..., 0], batch["token_y"][..., 0]
     got = jax.jit(jax.grad(lambda v: model.apply(v, batch).total_loss.data))(
         variables)
-    want = jax.grad(lambda v: ref.train_loss(v, tokens, targets, config))(
-        variables)
-    assert set(got) == set(want)
-    for name in sorted(got):
-        scale = float(jnp.max(jnp.abs(want[name])))
-        assert scale > 0, name
-        assert float(jnp.max(jnp.abs(got[name] - want[name]))) / scale < 2e-4, \
-            name
+    _, want = harness.reference_loss_and_grads(ref, variables, tokens,
+                                               targets, config)
+    harness.assert_grads_match(got, want, 2e-4, alive=True)
 
 
 def reference_at_the_next_precision_below_fails_test():
-    """The reference with a float8 (e4m3) residual stream misses the bound
-    that the program in bfloat16 holds."""
-    config, _, model, batch, variables = _build("bfloat16")
-    ref = _reference()
-    tokens = batch["token_x"][..., 0]
-    want = np.asarray(ref.forward(variables, tokens, config))
-    low = np.asarray(ref.forward(variables, tokens, config,
-                                 stream_dtype=jnp.float8_e4m3fn))
-    got, _ = _logits_and_loss(model, variables, batch)
+    """``harness.assert_float8_stream_misses``."""
     # at this toy depth of two layers both lie under the cells' 2^-4: what
     # separates them is a bound between the two readings, as the cell's is
-    assert _error(got, want) < 0.02 < _error(low, want)
+    harness.assert_float8_stream_misses(_reference(), _build("bfloat16"), 0.02)
 
 
 def nothing_looks_ahead_test():
     """Perturb token ``t``: no logit before ``t`` moves — the pooled windows
     a query scores end at or before it — and those from ``t`` on do."""
     config, _, model, batch, variables = _build()
-    base, _ = _logits_and_loss(model, variables, batch)
+    base, _ = harness.logits_and_loss(model, variables, batch)
     t = 77
     tokens = batch["token_x"].copy()
     tokens[:, t] = (tokens[:, t] + 1) % 256
-    moved, _ = _logits_and_loss(model, variables,
+    moved, _ = harness.logits_and_loss(model, variables,
                                 {**batch, "token_x": tokens})
     assert np.array_equal(base[:, :t], moved[:, :t])
     assert np.all(np.max(np.abs(base[:, t:] - moved[:, t:]), axis=-1) > 0)
@@ -228,7 +191,7 @@ def chunked_rule_is_the_serial_recurrence_test(chunk):
     rates = lightning_mod.decay_rates(4)
     got, state_max = lightning_mod.lightning_rule(q, k, v, rates, chunk)
     want = _serial(q, k, v, rates)
-    assert _error(np.asarray(got), want) < 1e-6
+    assert harness.error(np.asarray(got), want) < 1e-6
     assert float(state_max) > 0 or chunk == 64
 
 
@@ -241,7 +204,7 @@ def rule_is_ssd_at_one_shared_key_test():
     got, _ = lightning_mod.lightning_rule(*shared, v, rates, 16)
     want, _ = ssd(v, jnp.ones(v.shape[:3], jnp.float32), -jnp.asarray(rates),
                   k[:, :, 0], q[:, :, 0], 16)
-    assert _error(np.asarray(got), np.asarray(want)) < 1e-6
+    assert harness.error(np.asarray(got), np.asarray(want)) < 1e-6
 
 
 def a_heads_decay_follows_its_index_in_the_whole_layer_test():
@@ -322,16 +285,16 @@ def at_the_dense_length_the_layer_is_the_plain_attention_test():
              for b in config["block_config"]]
     other = Model(ModelParameter({**config, "block_config": plain}))
     other.init(batch, seed=13)
-    assert np.array_equal(_logits_and_loss(model, variables, batch)[0],
-                          _logits_and_loss(other, variables, batch)[0])
+    assert np.array_equal(harness.logits_and_loss(model, variables, batch)[0],
+                          harness.logits_and_loss(other, variables, batch)[0])
     # and past it the selection changes the result
     config, _, model, batch, variables = _build()
     plain = [dict(b, layer=[name.replace("-sparse", "") for name in b["layer"]])
              for b in config["block_config"]]
     other = Model(ModelParameter({**config, "block_config": plain}))
     other.init(batch, seed=13)
-    assert _error(_logits_and_loss(model, variables, batch)[0],
-                  _logits_and_loss(other, variables, batch)[0]) > 1e-3
+    assert harness.error(harness.logits_and_loss(model, variables, batch)[0],
+                  harness.logits_and_loss(other, variables, batch)[0]) > 1e-3
 
 
 # ---- the selected kernels, interpreted -------------------------------------------
@@ -363,8 +326,9 @@ def selected_kernels_match_a_masked_softmax_test(small_tiles, kind):
     keep = _choice(kind, q, k)
     scale = 32 ** -0.5
     out, lse = fa._select_fwd_impl(q, k, v, keep, scale, 16, True)
-    want, want_lse = fa._xla_select_with_lse(q, k, v, keep, scale, 16)
-    assert _error(np.asarray(out), np.asarray(want)) < 1e-5
+    want, want_lse = jax.jit(lambda *t: fa._xla_select_with_lse(
+        *t, keep, scale, 16))(q, k, v)
+    assert harness.error(np.asarray(out), np.asarray(want)) < 1e-5
     assert float(jnp.max(jnp.abs(lse - want_lse))) < 1e-5
     # against the softmax written out: exactly the kept keys <= t
     mask = np.repeat(np.asarray(keep), 16, axis=-1) \
@@ -376,15 +340,15 @@ def selected_kernels_match_a_masked_softmax_test(small_tiles, kind):
     weight /= weight.sum(-1, keepdims=True)
     plain = np.einsum("bgrqk,bkgd->bqgrd", weight,
                       np.asarray(v, np.float64)).reshape(1, 512, 4, 32)
-    assert _error(np.asarray(out), plain) < 1e-5
+    assert harness.error(np.asarray(out), plain) < 1e-5
     cot = jnp.asarray(np.random.default_rng(9).normal(size=out.shape),
                       jnp.float32)
     got = jax.grad(lambda *t: jnp.sum(fa.flash_select(
         *t, keep, scale, 16, True) * cot), (0, 1, 2))(q, k, v)
-    wanted = jax.grad(lambda *t: jnp.sum(fa._xla_select(
-        *t, keep, scale, 16) * cot), (0, 1, 2))(q, k, v)
+    wanted = jax.jit(jax.grad(lambda *t: jnp.sum(fa._xla_select(
+        *t, keep, scale, 16) * cot), (0, 1, 2)))(q, k, v)
     for g, w in zip(got, wanted):
-        assert _error(np.asarray(g), np.asarray(w)) < 1e-5
+        assert harness.error(np.asarray(g), np.asarray(w)) < 1e-5
 
 
 def tiles_no_row_kept_are_not_visited_test(small_tiles):
@@ -477,11 +441,11 @@ def the_shares_add_up_to_the_uncut_layer_test(kind):
         # and the program at this share is the reference at this share
         cut_model = Model(ModelParameter(cut))
         cut_model.init(batch, seed=13)
-        got, _ = _logits_and_loss(cut_model, share, batch)
+        got, _ = harness.logits_and_loss(cut_model, share, batch)
         want = _reference().forward(share, batch["token_x"][..., 0], cut)
-        assert _error(got, want) < 2e-5
-    assert _error(parts[0] + parts[1], whole) < 1e-5
-    assert _error(parts[0], whole) > 0.1
+        assert harness.error(got, want) < 2e-5
+    assert harness.error(parts[0] + parts[1], whole) < 1e-5
+    assert harness.error(parts[0], whole) > 0.1
 
 
 # ---- the saved choice ----------------------------------------------------------------
@@ -719,10 +683,7 @@ def traced_ops_carry_the_steps_test():
     """Every step of the rule and of the selection is a named scope of the
     compiled program's ops, which is what the trace reads."""
     _, _, model, batch, variables = _build()
-    text = jax.jit(jax.grad(
-        lambda v: model.apply(v, batch).total_loss.data)).lower(
-        variables).compile().as_text()
-    names = set(re.findall(r'op_name="([^"]*)"', text))
+    names = harness.traced_op_names(model, variables, batch)
     for step in ("intra_chunk", "chunk_states", "inter_chunk", "state_out"):
         inside = [n for n in names
                   if re.search(rf"lightning_0/rule/(.*/)?{step}/", n)]
@@ -743,7 +704,7 @@ def the_step_reports_the_selection_and_the_state_test():
     from benchmark.roofline import sala_costs
     config, params, model, batch, variables = _build(
         block_config=_blocks(order="sll"))
-    info = model.apply(variables, batch, layer_stats=True)
+    info = harness.apply_with_stats(model, variables, batch)
     share = np.asarray(info.layer_stats["sparse_kept_key_share"])
     assert share.shape == (1,)
     assert float(share[0]) == pytest.approx(
@@ -787,3 +748,38 @@ def the_trainer_steps_test():
     assert losses[-1] < losses[0] - 0.05
     assert {"sparse_kept_key_share", "sparse_choosing_query_share",
             "lightning_state_abs_max"} <= set(metrics)
+
+
+# ---- compiled for a described v5e ---------------------------------------------
+
+def selected_kernels_keep_their_scope_and_names_test(v5e, monkeypatch):
+    """MiniCPM-SALA's sparse layer at its published widths and the cell's
+    16,384 tokens, compiled for a v5e as a TPU process traces it (PR 46):
+    Mosaic accepts the three selected kernels, each runs ONCE — the forward
+    outside the block's replay, which reads the saved ``(out, lse)`` and the
+    saved choice — all are named ``flash_*_select`` (the
+    ``sala_sparse_flash_*`` readers) and fold into
+    ``body/attention/sparse_attention/attend``; no causal kernel runs, and
+    the indexer's ops carry their steps.  Under ``"recompute"`` the replay
+    runs the forward kernel again."""
+    kinds = {}
+    for policy in ("auto", "recompute"):
+        params, hlo = harness.cell_layer_hlo(
+            v5e, monkeypatch, "train_minicpm_sala_tp2_long", 0, policy)
+        assert "sparse" in params.block_config[0].layer[1]
+        assert (remat.stash_plan(params)["attention"][0] == 1) \
+            == (policy == "auto")
+        calls = harness.kernel_calls(hlo)
+        for name, op_name in calls:
+            assert re.match(r"flash_.*_select", name), name
+            assert scope_key(op_name) \
+                == "body/attention/sparse_attention/attend", op_name
+        kinds[policy] = sorted(name for name, _ in calls)
+        names = set(re.findall(r'op_name="([^"]*)"', hlo))
+        for step in ("compress", "index", "select"):
+            assert any(scope_key(n)
+                       == f"body/attention/sparse_attention/{step}"
+                       for n in names), step
+    assert kinds["auto"] == ["flash_bwd_dkv_select", "flash_bwd_dq_select",
+                             "flash_fwd_select"]
+    assert kinds["recompute"] == kinds["auto"] + ["flash_fwd_select"]

@@ -1,7 +1,6 @@
 """Top-k / nucleus (top-p) sampling filters (beyond-reference serving
 surface: the reference samples the full distribution only,
 /root/reference/src/run/inference.py:88-92)."""
-import jax
 import jax.numpy as jnp
 import numpy as np
 

@@ -14,6 +14,7 @@ from homebrewnlp_tpu.model import mamba as mamba_mod
 from homebrewnlp_tpu.model import recurrent
 from homebrewnlp_tpu.parallel import ssd_scan as sk
 
+import harness
 from granite_test import _build, _scan_recurrence
 
 
@@ -36,22 +37,25 @@ def _kernel(x, dt, a, b_mat, c_mat, chunk, heads_a_block=None):
                        True), jnp.min(a_cum)
 
 
-def _value_and_grads(fn, inputs, weights):
-    out, low = fn(*inputs)
-    grads = jax.grad(lambda *args: jnp.sum(fn(*args)[0] * weights),
-                     argnums=(0, 1, 2, 3, 4))(*inputs)
-    return (out, *grads), low
+def _value_and_grads(fn, inputs, weights, compiled=None):
+    """``compiled``: where a caller of many draws keeps each form's program."""
+    def run(weights, *inputs):
+        def loss(*args):
+            out, low = fn(*args)
+            return jnp.sum(out * weights), (out, low)
+        (_, (out, low)), grads = jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3, 4), has_aux=True)(*inputs)
+        return (out, *grads), low
+    return ({} if compiled is None else compiled).setdefault(
+        fn, jax.jit(run))(weights, *inputs)
 
 
 def _close(got, want, tolerance):
-    """Each within ``tolerance`` of its largest entry; ``dA``, a sum over
-    every position of terms that cancel, of the terms' size: ``ddt``'s."""
-    scale = {"dA": np.max(np.abs(np.asarray(want[2], np.float32)))}
-    for name, g, w in zip(("y", "dx", "ddt", "dA", "dB", "dC"), got, want):
-        g, w = (np.asarray(t, np.float32) for t in (g, w))
-        assert g.shape == w.shape and np.all(np.isfinite(g)), name
-        assert np.max(np.abs(g - w)) <= tolerance * max(
-            np.max(np.abs(w)), scale.get(name, 1e-3)), name
+    """``dA``, a sum over every position of terms that cancel, is held to
+    the terms' size: ``ddt``'s."""
+    harness.assert_close_each(
+        got, want, tolerance, ("y", "dx", "ddt", "dA", "dB", "dC"),
+        {"dA": np.max(np.abs(np.asarray(want[2], np.float32)))})
 
 
 # (sequence, chunk, heads, heads a block, decay a position): one chunk of one
@@ -87,32 +91,6 @@ def pair_is_the_recurrence_test(chunk, s, decay):
     _close(got, want, 1e-4)
 
 
-def pair_rounds_no_lower_than_the_xla_form_test():
-    """bfloat16 operands: against the XLA form in float32 the pair is, in the
-    mean over six draws, no further off than the XLA form in bfloat16 (half
-    as much again, for the rounding's luck) and never past the bound
-    ``scripts/kernel_parity.py`` holds it to on the chip — the log-decay's
-    gradient, a difference of sums that cancel, included."""
-    off = {"kernel": [], "xla": []}
-    for seed in range(6):
-        inputs, weights = _inputs(64, 4, 0.5, p=16, n=32, dtype=jnp.bfloat16,
-                                  seed=seed)
-        exact, _ = _value_and_grads(
-            functools.partial(mamba_mod.ssd_xla, chunk=32),
-            tuple(t.astype(jnp.float32) for t in inputs), weights)
-        for name, fn in (("kernel", functools.partial(_kernel, chunk=32,
-                                                      heads_a_block=2)),
-                         ("xla", functools.partial(mamba_mod.ssd_xla,
-                                                   chunk=32))):
-            got, _ = _value_and_grads(fn, inputs, weights)
-            off[name].append([
-                float(np.max(np.abs(np.asarray(g, np.float32) - w))
-                      / np.max(np.abs(w)))
-                for g, w in zip(got, (np.asarray(t) for t in exact))])
-    assert np.max(off["kernel"]) <= 2.0 ** -6
-    assert np.all(np.mean(off["kernel"], 0) <= 1.5 * np.mean(off["xla"], 0))
-
-
 @pytest.mark.parametrize("sequence,chunk,heads,p,state,backend,takes", [
     (8192, 256, 64, 64, 128, "tpu", True),     # the published widths
     (8192, 128, 64, 16, 256, "tpu", True),
@@ -140,32 +118,16 @@ def head_block_divides_the_heads_test(heads, p, block):
     assert sk.head_block(heads, p) == block
 
 
-def _steer(monkeypatch):
-    """The layer as a TPU process at kernel shapes would trace it, the
-    kernels interpreted."""
-    monkeypatch.setattr(mamba_mod, "ssd_kernel_applies",
-                        lambda *_, **__: True)
-    monkeypatch.setattr(mamba_mod, "ssd_scan", functools.partial(
-        sk.ssd_scan, heads_a_block=2, interpret=True))
-
-
-def _loss_and_grads(model, variables, batch):
-    v = {k: jnp.asarray(a) for k, a in variables.items()}
-    return jax.value_and_grad(
-        lambda v: model.apply(v, batch).total_loss.data)(v)
-
-
 def declining_layer_traces_the_parents_ops_test(monkeypatch):
     """The toy widths (8 features a head, state 16, chunk 16): with the
     backend steered to the TPU the layer still traces the einsums."""
     _, params, model, batch, variables = _build("bfloat16")
     assert recurrent.scan_kernel_layers(params, "tpu") == 0
-    trace = lambda: str(jax.make_jaxpr(  # noqa: E731
-        lambda v: model.apply(v, batch).total_loss.data)(variables))
-    plain = trace()
-    monkeypatch.setattr(mamba_mod, "ssd_kernel_applies", functools.partial(
+    plain = harness.step_jaxpr(model, variables, batch)
+    harness.steer(monkeypatch, mamba_mod, ssd_kernel_applies=functools.partial(
         sk.ssd_kernel_applies, backend="tpu"))
-    assert trace() == plain and "ssd_scan" not in plain
+    assert harness.step_jaxpr(model, variables, batch) == plain
+    assert "ssd_scan" not in plain
 
 
 def scan_fact_counts_the_layers_test():
@@ -183,25 +145,3 @@ def scan_fact_counts_the_layers_test():
     assert recurrent.scan_kernel_layers(short, "tpu") == 9   # one chunk
     from olmo_hybrid_test import _build as _build_olmo
     assert recurrent.scan_kernel_layers(_build_olmo()[1], "tpu") is None
-
-
-@pytest.mark.parametrize("dtype,tolerance", [("float32", 2e-5),
-                                             ("bfloat16", 2.0 ** -5)])
-def granite_step_with_the_kernel_test(monkeypatch, dtype, tolerance):
-    """The toy granite step under ``jax.checkpoint`` + ``jax.grad``: loss,
-    every gradient and the log-decay watch with the kernel pair equal the
-    fallback's."""
-    _, params, model, batch, variables = _build(dtype)
-    assert params.memory_reduction_strategy == "checkpoint"
-    want_loss, want = _loss_and_grads(model, variables, batch)
-    _steer(monkeypatch)
-    text = str(jax.make_jaxpr(
-        lambda v: model.apply(v, batch).total_loss.data)(variables))
-    assert "ssd_scan_fwd" in text and "intra_chunk" not in text
-    loss, got = _loss_and_grads(model, variables, batch)
-    assert abs(float(loss) - float(want_loss)) <= tolerance
-    assert set(got) == set(want)
-    for name in want:
-        a, r = (np.asarray(t[name], np.float32) for t in (got, want))
-        assert np.max(np.abs(a - r)) <= tolerance * max(
-            np.max(np.abs(r)), 1e-3), name

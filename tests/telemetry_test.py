@@ -15,7 +15,6 @@ device-free, on the serving_robustness_test harness — ``GET /metrics``
 answering valid exposition from the HTTP child while the device loop is
 wedged inside a decode."""
 import json
-import math
 import os
 import re
 import signal

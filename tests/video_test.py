@@ -5,7 +5,6 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from homebrewnlp_tpu.config import ModelParameter
 from homebrewnlp_tpu.model import Model

@@ -6,7 +6,6 @@ boundary; the SHARE test (the two ranks' expert parts, with attention, router
 and merge counted once, add up to the uncut layer); top-1 with every token's
 expert absent; the refusals of the modes that carry no side value; scopes and
 gauges."""
-import importlib
 import json
 import os
 
@@ -15,6 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import harness
+from harness import REPO
 from homebrewnlp_tpu.analysis.cost_ledger import scope_key
 from homebrewnlp_tpu.config import BlockArgs, BlockConfig, ModelParameter
 from homebrewnlp_tpu.core import scope
@@ -22,7 +23,6 @@ from homebrewnlp_tpu.core.tensor import nt
 from homebrewnlp_tpu.model import Model, cca as cca_mod, moe as moe_mod, remat
 from homebrewnlp_tpu.model.spatial import numbered_flags
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CCA = "cca-q_heads4-kv_heads2-rotary_pct50-theta5000000"
 MOE = "moe-silu-router_mlp"
 
@@ -44,19 +44,16 @@ TINY = {"depth": 3, "heads": 4, "features_per_head": 16,
 
 
 def _reference():
-    return importlib.import_module("benchmark.reference.zaya1_8b")
+    return harness.reference("zaya1_8b")
 
 
 def _config(dtype: str = "float32", **extra) -> dict:
-    with open(os.path.join(REPO, "configs", "zaya1_8b.json")) as f:
-        return {**json.load(f), **TINY, "calculation_dtype": dtype, **extra}
+    return harness.config_of("zaya1_8b", TINY, dtype, **extra)
 
 
 def _batch(config, seed: int = 5):
-    rng = np.random.default_rng(seed)
-    shape = (config["train_batch_size"], config["sequence_length"], 1)
-    tokens = rng.integers(0, 256, shape).astype(np.int32)
-    return {"token_x": tokens, "token_y": np.roll(tokens, -1, axis=1)}
+    return harness.token_batch(config["train_batch_size"],
+                               config["sequence_length"], seed)
 
 
 def _lively(variables, seed: int = 3):
@@ -79,22 +76,7 @@ def _lively(variables, seed: int = 3):
 
 
 def _build(dtype: str = "float32", **extra):
-    config = _config(dtype, **extra)
-    params = ModelParameter(config)
-    assert not params.unknown_config_keys
-    model = Model(params)
-    batch = _batch(config)
-    return config, params, model, batch, _lively(model.init(batch, seed=13))
-
-
-def _logits_and_loss(model, variables, batch):
-    info = jax.jit(lambda v, b: model.apply(v, b))(variables, batch)
-    return (np.asarray(info.token_out.data.astype(jnp.float32))[:, :, 0, :],
-            float(info.total_loss.data))
-
-
-def _error(got, want) -> float:
-    return float(np.max(np.abs(want - got)) / np.max(np.abs(want)))
+    return harness.build(_config(dtype, **extra), lively=_lively)
 
 
 # ---- the program against the reference ---------------------------------------
@@ -121,15 +103,9 @@ def _error(got, want) -> float:
          "more_taps", "one_tap", "bfloat16"])
 def program_matches_reference_test(dtype, tolerance, extra):
     config, _, model, batch, variables = _build(dtype, **extra)
-    got, loss = _logits_and_loss(model, variables, batch)
-    want = np.asarray(_reference().forward(variables, batch["token_x"][..., 0],
-                                           config))
-    assert got.shape == want.shape == (2, 64, 272)
-    assert _error(got, want) < tolerance
-    from benchmark.reference import common
-    want_loss = float(common.loss_of(want, batch["token_y"][..., 0], 0.0))
-    assert abs(want_loss - loss) <= (2.0 ** -18 if dtype == "float32"
-                                     else 2.0 ** -5)
+    got = harness.assert_program_matches_reference(
+        _reference(), (config, _, model, batch, variables), dtype, tolerance)
+    assert got.shape == (2, 64, 272)
 
 
 #: one parameter of every kind the issue names, by its path below a block
@@ -161,13 +137,9 @@ def loss_and_gradients_match_reference_test():
     tokens, targets = batch["token_x"][..., 0], batch["token_y"][..., 0]
     got = jax.jit(jax.grad(lambda v: model.apply(v, batch).total_loss.data))(
         variables)
-    want = jax.grad(lambda v: ref.train_loss(v, tokens, targets, config))(
-        variables)
-    assert set(got) == set(want)
-    for name in sorted(got):
-        scale = float(jnp.max(jnp.abs(want[name]))) or 1.0
-        assert float(jnp.max(jnp.abs(got[name] - want[name]))) / scale < 2e-4, \
-            name
+    _, want = harness.reference_loss_and_grads(ref, variables, tokens,
+                                               targets, config)
+    harness.assert_grads_match(got, want, 2e-4)
     for kind, path in KINDS.items():
         name = f"gpt0/body0/{path}/var0"
         assert float(jnp.max(jnp.abs(got[name]))) > 0, kind
@@ -177,16 +149,8 @@ def loss_and_gradients_match_reference_test():
 
 
 def reference_at_the_next_precision_below_fails_test():
-    """The reference with a float8 (e4m3) residual stream misses the bound
-    that the program in bfloat16 holds."""
-    config, _, model, batch, variables = _build("bfloat16")
-    ref = _reference()
-    tokens = batch["token_x"][..., 0]
-    want = np.asarray(ref.forward(variables, tokens, config))
-    low = np.asarray(ref.forward(variables, tokens, config,
-                                 stream_dtype=jnp.float8_e4m3fn))
-    got, _ = _logits_and_loss(model, variables, batch)
-    assert _error(got, want) < 2 ** -4 < _error(low, want)
+    """``harness.assert_float8_stream_misses``."""
+    harness.assert_float8_stream_misses(_reference(), _build("bfloat16"))
 
 
 # ---- causality -------------------------------------------------------------------
@@ -198,11 +162,11 @@ def nothing_looks_ahead_test(extra):
     two convolutions, the value shift and the q-k mean are causal), and the
     logits from ``t`` on do."""
     config, _, model, batch, variables = _build(**extra)
-    base, _ = _logits_and_loss(model, variables, batch)
+    base, _ = harness.logits_and_loss(model, variables, batch)
     t = 37
     other = {k: np.array(v) for k, v in batch.items()}
     other["token_x"][:, t] = (other["token_x"][:, t] + 1) % 256
-    moved, _ = _logits_and_loss(model, variables, other)
+    moved, _ = harness.logits_and_loss(model, variables, other)
     np.testing.assert_array_equal(moved[:, :t], base[:, :t])
     assert np.max(np.abs(moved[:, t] - base[:, t])) > 1e-4
     assert np.max(np.abs(moved[:, t + 1] - base[:, t + 1])) > 1e-5
@@ -325,16 +289,11 @@ def _moe_layer(params, weights, x, state=None):
     """Layer ``moe-silu-router_mlp`` of ``params`` on the normed ``x [b, s,
     heads, features]`` with the given weights (the reference's short names):
     ``(output, the router state it leaves)``."""
-    ref = _reference()
-    ctx = scope.Context("apply", params={
-        path + "/var0": jnp.asarray(weights[short])
-        for short, path in ref.SPARSE.items()})
-    ctx.side = {} if state is None else {moe_mod.ROUTER_STATE: state}
-    with scope.context(ctx):
-        out = scope.scoped("moe_", moe_mod.moe, BlockArgs(
-            params, nt(x, [params.batch_dim, params.sequence_dim]
-                       + list(params.feature_dims)), ["silu", "router_mlp"]))
-    return out.data, ctx.side[moe_mod.ROUTER_STATE]
+    out, ctx = harness.layer_on(
+        params, moe_mod.moe, _reference().SPARSE, weights, x,
+        ["silu", "router_mlp"],
+        {} if state is None else {moe_mod.ROUTER_STATE: state})
+    return out, ctx.side[moe_mod.ROUTER_STATE]
 
 
 def the_shares_add_up_to_the_uncut_layer_test():
@@ -418,20 +377,20 @@ def every_tokens_expert_absent_gives_zero_test():
             skewed[name] = jnp.asarray(w)
         if name.endswith("moe_0/normal_var5/var0"):      # W2 = 0: u = gelu(b2)
             skewed[name] = jnp.zeros_like(variables[name])
-    info = model.apply(skewed, batch, layer_stats=True)
+    info = jax.jit(lambda v: model.apply(v, batch, layer_stats=True))(skewed)
     assert np.asarray(info.layer_stats["moe_held_pairs"]).tolist() == [0.0] * 3
     assert np.asarray(info.layer_stats["moe_routed_pairs"]).tolist() \
         == [128.0] * 3
-    got, _ = _logits_and_loss(model, skewed, batch)
+    got = np.asarray(info.token_out.data.astype(jnp.float32))[:, :, 0, :]
     want = np.asarray(ref.forward(skewed, batch["token_x"][..., 0], config))
-    assert _error(got, want) < 2e-5
+    assert harness.error(got, want) < 2e-5
     params_f = ModelParameter(_config())
     x = jnp.asarray(np.random.default_rng(0).normal(
         size=(2, 64, 4, 16)).astype(np.float32))
     weights = {short: skewed[f"gpt0/body0/block0_1_0/{path}/var0"]
                for short, path in ref.SPARSE.items()}
     assert not np.any(np.asarray(_moe_layer(params_f, weights, x)[0]))
-    grads = jax.grad(lambda v: model.apply(v, batch).total_loss.data)(skewed)
+    _, grads = harness.loss_and_grads(model, skewed, batch)
     assert all(np.all(np.isfinite(np.asarray(g))) for g in grads.values())
     assert not np.any(np.asarray(
         grads["gpt0/body0/block1_1_0/moe_0/normal_var0/var0"]))
@@ -465,8 +424,7 @@ def rows_no_kernel_wrote_reach_no_gradient_test():
     config, _, model, batch, variables = _build()
     moe_mod.grouped_dot = poisoned
     try:
-        loss, grads = jax.value_and_grad(
-            lambda v: model.apply(v, batch).total_loss.data)(variables)
+        loss, grads = harness.loss_and_grads(model, variables, batch)
     finally:
         moe_mod.grouped_dot = orig
     assert np.isfinite(float(loss))
@@ -512,8 +470,8 @@ def the_merge_starts_as_the_plain_residual_test():
     plain_model = Model(ModelParameter(plain))
     plain_vars = plain_model.init(batch, seed=13)
     assert set(plain_vars) == set(variables) - set(merges)
-    got, _ = _logits_and_loss(model, variables, batch)
-    want, _ = _logits_and_loss(plain_model,
+    got, _ = harness.logits_and_loss(model, variables, batch)
+    want, _ = harness.logits_and_loss(plain_model,
                                {k: variables[k] for k in plain_vars}, batch)
     np.testing.assert_allclose(got, want, atol=1e-6)
 
@@ -673,7 +631,7 @@ def the_step_reports_the_router_and_the_logit_bound_test():
     the layer where it is smallest and the largest ``sqrt(d) |tau|``; the
     start-up line ends with the carried router states' bytes."""
     config, params, model, batch, variables = _build()
-    info = model.apply(variables, batch, layer_stats=True)
+    info = harness.apply_with_stats(model, variables, batch)
     top1 = np.asarray(info.layer_stats["moe_top1_weight_mean"])
     assert top1.shape == (3,) and np.all(top1 >= 1 / 8) and np.all(top1 <= 1)
     scales = np.asarray(info.layer_stats["cca_logit_scale"])
@@ -717,3 +675,13 @@ def the_trainer_steps_test():
     assert losses[-1] < losses[0] - 0.05
     assert {"moe_top1_weight_mean", "cca_logit_scale_max",
             "moe_held_pair_share"} <= set(metrics)
+
+
+# ---- compiled for a described v5e ---------------------------------------------
+
+def saved_flash_outputs_keep_their_scope_test(v5e, monkeypatch):
+    """The cell's ``cca`` layer (``harness.py
+    saved_flash_outputs_keep_their_scope``)."""
+    harness.saved_flash_outputs_keep_their_scope(
+        v5e, monkeypatch, "train_zaya1_8b_ep2_s16k",
+        "cca-q_heads8-kv_heads2-rotary_pct50-theta5000000", "body/cca")
