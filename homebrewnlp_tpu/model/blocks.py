@@ -432,10 +432,11 @@ def _checkpoint_policy(params: ModelParameter, mesh=None,
     (``stash_names``: ``experts`` — layer ``moe``'s named outputs —
     ``recurrent`` — the output a recurrent mixer offers — ``attention`` —
     the (out, lse) every flash layer names under the blocks' "name" channel,
-    ``_name_chan``), also those names: what every region of the step saves.
-    The ``dense`` kind — layer ``mlp``'s gate and up outputs — is admitted
-    an execution at a time from the step's end, so what it adds is a
-    region's own: ``names``, what one region saves (``_region_policies``)."""
+    ``_name_chan``), also those names: what a region of the step saves that
+    holds an admitted execution of every kind.  The ``recurrent`` kind and the
+    ``dense`` one — layer ``mlp``'s gate and up outputs — are admitted an
+    execution at a time from the step's end, so what they add is a region's
+    own: ``names``, what one region saves (``_region_policies``)."""
     if names is None:
         from .remat import stash_names
         names = stash_names(params, mesh)
@@ -444,8 +445,8 @@ def _checkpoint_policy(params: ModelParameter, mesh=None,
 
 def _region_policies(params: ModelParameter, mesh=None) -> list:
     """The ``jax.checkpoint`` policy of every region of the step, by its
-    place (``_region``): ``_checkpoint_policy``, and in the regions that hold
-    an admitted execution of the ``dense`` kind also its names (model/remat.py
+    place (``_region``): ``_checkpoint_policy``'s over the names of the kinds
+    whose admitted executions the region holds (model/remat.py
     ``region_names``)."""
     from .remat import region_names
     return [_checkpoint_policy(params, mesh, names)

@@ -44,12 +44,18 @@ class Offer(typing.NamedTuple):
     the ``checkpoint_name``s it tags them with (none for a kind that rides
     the revnet / momentum channel alone), their bytes for the whole batch
     over its ``count`` outputs and, for a flash call, the ``keys`` a query
-    sees (``min(sequence, window)``)."""
+    sees (``min(sequence, window)``).  ``interior_names`` /
+    ``interior_nbytes``: what, kept WITH ``names``, lets the replay skip the
+    layer's own forward kernels (the residuals of a rule run as Pallas
+    pairs) — a second part, admitted on top of the first and never without
+    it."""
     kind: str
     names: typing.Tuple[str, ...]
     nbytes: int
     count: int = 1
     keys: typing.Optional[int] = None
+    interior_names: typing.Tuple[str, ...] = ()
+    interior_nbytes: int = 0
 
 
 class Fact(typing.NamedTuple):

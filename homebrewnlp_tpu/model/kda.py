@@ -91,14 +91,20 @@ layers have a file and a predicate each.  In both forms decays, cumulative
 sums, ``beta``, the solve and the carried state are float32 (``KEPT``); the
 matmuls take the calculation dtype with float32 accumulation.
 
-Under the ``checkpoint`` strategy the block's ``jax.checkpoint`` saves the
-rule's output (``SAVED_NAMES``, kind ``recurrent``): the gate norm and the
-out-projection differentiate through the saved ``o``.  On the XLA form the
-block's replay then runs no forward of the rule (each group's backward makes
-what it needs again from ``q, k, v, beta, g``); on the kernels' form the
-replay runs both forward kernels and the solve again, for ``T``, ``A'`` and
-the chunk states their backwards read (alive only from one block's replay to
-its backward).
+Under the ``checkpoint`` strategy the layer offers the block's
+``jax.checkpoint`` what lets its replay skip the rule (``SAVED_NAMES``, kind
+``recurrent``; model/remat.py admits each part an execution at a time from
+the step's end).  The rule's output ``o`` first: the gate norm and the
+out-projection differentiate through it, and on the XLA form the replay then
+runs no forward of the rule (each group's backward makes what it needs again
+from ``q, k, v, beta, g``).  On the kernels' form ``o`` alone skips no kernel
+(the walk's forward writes it with the states) but the turn of ``o^T`` into
+the layer's layout and its consumers' strided reads; so there the offer has
+an INTERIOR, what the three forwards hand their backwards, each as it is kept
+today — ``gamma``, ``q~``, ``k~``, ``A`` (the solve's input before
+``diag(beta)``) and ``A'`` of ``kda_scores``, the solve's inverse, the walk's
+entering chunk states —, named inside the forward rules: the replay of an
+execution whose interior is admitted runs none of the three forwards.
 
 Training and full-sequence forward on one device; a decode / prefill form (a
 ``[H, d_k, d_v]`` state and a conv window a sequence) is a later issue.
@@ -117,11 +123,13 @@ from ..core import scope
 from ..core.dims import Dim
 from ..core.tensor import NamedTensor, nt, transpose_to
 from ..parallel.causal_conv import causal_conv_silu, kernel_applies
-from ..parallel.kda_rule import (kda_kernel_applies, kda_rule_pair,
+from ..parallel.kda_rule import (SCORES_NAMES, STATES_NAME,
+                                 kda_kernel_applies, kda_rule_pair,
                                  kda_scores, positions_major, sequence_minor)
 from .backend import ConstantInit, UniformInit, normal_var
 from .declare import Layer, Offer, Stat
-from .gated_delta import L2_EPS, _inverse_unit_lower, over_groups
+from .gated_delta import (L2_EPS, _inverse_bwd, _inverse_unit_lower,
+                          over_groups)
 from .gated_delta import gated_delta as _gated_delta
 from .loss import _matmul
 from .normalization import _norm_core
@@ -144,9 +152,15 @@ GROUP_BYTES = 1 << 30
 #: cell with bfloat16 here: the precision below the one the configuration
 #: states, which the cell's ``logit_tolerance`` has to see
 KEPT = jnp.float32
-#: the name layer ``kda`` gives the rule's output ``o [b, s, heads, d_v]``
-#: (``checkpoint_name``; model/remat.py's ``recurrent`` kind)
-SAVED_NAMES = ("kda_out",)
+#: the name ``kernel_rule`` gives the solve's inverse, its backward's one
+#: residual
+SOLVED_NAME = "kda_solved"
+#: what layer ``kda`` offers model/remat.py's ``recurrent`` kind, by
+#: ``checkpoint_name``: the rule's output ``o [b, s, heads, d_v]`` and, where
+#: the rule is the Pallas pairs, as the offer's interior what their forwards
+#: hand their backwards (parallel/kda_rule.py ``SCORES_NAMES``,
+#: ``STATES_NAME``; the inverse)
+SAVED_NAMES = ("kda_out", *SCORES_NAMES, SOLVED_NAME, STATES_NAME)
 
 
 def _decayed_scores(x, k, gamma, strict: bool):
@@ -280,6 +294,22 @@ def normalised(rule):
     return run
 
 
+@jax.custom_vjp
+def _solved(strict):
+    """``gated_delta._inverse_unit_lower`` under a forward rule that NAMES
+    the inverse it hands the backward (``SOLVED_NAME``): saved, the block's
+    replay runs no solve."""
+    return _inverse_unit_lower(strict)
+
+
+def _solved_fwd(strict):
+    inv = checkpoint_name(_inverse_unit_lower(strict), SOLVED_NAME)
+    return inv, inv
+
+
+_solved.defvjp(_solved_fwd, _inverse_bwd)
+
+
 def kernel_rule(q, k, v, beta, g, chunk: int):
     """``normalised(kda_rule)`` over all heads at once as the Pallas pairs of
     parallel/kda_rule.py (shapes as ``kda_kernel_applies`` accepts them):
@@ -294,7 +324,7 @@ def kernel_rule(q, k, v, beta, g, chunk: int):
             chunk, math.gcd(chunk, _SUB), dk ** -0.5, L2_EPS, KEPT)
         scale = jnp.moveaxis(beta.reshape(bsz, s // chunk, chunk, h), 2, 3)
         strict = strict * scale[..., :, None]
-        transform = _inverse_unit_lower(
+        transform = _solved(
             strict.astype(KEPT).astype(jnp.float32)) * scale[..., None, :]
         transform_max = jax.lax.stop_gradient(jnp.max(jnp.abs(transform)))
     with jax.named_scope("decay"):
@@ -433,11 +463,23 @@ def _conv(params: ModelParameter):
 
 def _offer(params: ModelParameter, extras) -> Offer:
     """The rule's output ``[batch, sequence, kda_heads, kda_value_features]``
-    in the calculation dtype: ``SAVED_NAMES``."""
-    return Offer("recurrent", SAVED_NAMES,
-                 params.batch_dim.size * params.sequence_dim.size
-                 * params.kda_heads * params.kda_value_features
-                 * jnp.dtype(params.calculation_dtype).itemsize)
+    in the calculation dtype (``SAVED_NAMES[0]``), and where the rule is the
+    Pallas pairs, as the offer's interior, what they keep for their backwards
+    (the other ``SAVED_NAMES``): ``gamma`` in float32 and the normalised
+    ``q`` and ``k`` ``[batch, sequence, heads, d_k]``, ``A``, the inverse
+    (float32) and ``A'`` ``[batch, chunks, heads, chunk, chunk]``, the
+    entering states ``[batch, chunks, heads, d_v, d_k]``."""
+    chunk, h, dk, dv, s = _rule(params)
+    low = jnp.dtype(params.calculation_dtype).itemsize
+    positions = params.batch_dim.size * s * h
+    offer = Offer("recurrent", SAVED_NAMES[:1], positions * dv * low)
+    if not kda_kernel_applies(chunk, h, dk, dv, s):
+        return offer
+    return offer._replace(
+        interior_names=SAVED_NAMES[1:],
+        interior_nbytes=positions * dk * (4 + 2 * low)
+        + positions * chunk * (4 + 4 + low)
+        + positions // chunk * dk * dv * low)
 
 
 def _solve(params: ModelParameter, backend=None):

@@ -32,8 +32,10 @@ intermediate]`` — the replay runs neither that matmul nor, where it
 contracts a mesh-sharded axis, its tensor-parallel all-reduce.  Both ride
 the revnet / momentum residuals through the stash channel
 (``core/stash.py``, ``model/blocks.py``).  ``experts``: a routed layer's
-grouped-matmul outputs, routing triple and choice.  ``recurrent``: the
-output a recurrent mixer offers because it re-materialises its own interior.
+grouped-matmul outputs, routing triple and choice.  ``recurrent``: what a
+recurrent mixer offers of its rule — its output, and where Pallas pairs run
+the rule, as the offer's interior, what their forwards hand their backwards,
+so that the replay runs none of them.
 ``dense``: layer ``mlp``'s two matmul outputs, gate and up ``[batch,
 sequence, intermediate]`` — the replay runs the activation and the product
 alone, no dense matmul (2 of an MLP's 11-12 matmul units a step).
@@ -54,29 +56,30 @@ flash call names its outputs).
    :data:`STASH_HBM_FRACTION` of a chip's HBM; then ``bottleneck`` where the
    in-projection's contraction crosses a ``model`` mesh axis > 1 and its
    per-device bytes fit what ``attention`` left.  Under ``checkpoint`` (the
-   whole budget again: the two above take nothing of it there):
+   whole budget again: the two above take nothing of it there) ONE rule:
+   each kind is judged on its own bytes against what the kinds before it
+   TOOK, and one that passes it takes nothing and moves no other kind's
+   decision.  In order: ``attention`` — every layer whose flash call engages
+   (one device, ``use_flash_attention``, a sequence of whole 128-tiles) and
+   in which a query sees at least :data:`ATTENTION_MIN_KEYS` keys, all such
+   layers or none; first, because its bytes grow with the sequence and the
+   forward it skips with the square, so no cheaper kind squeezes it out;
    ``experts`` where the WHOLE depth's offered bytes fit — all layers or
-   none; ``recurrent`` likewise from what ``experts`` left; ``attention``
-   LAST, from what both left — every layer whose flash call engages (one
-   device, ``use_flash_attention``, a sequence of whole 128-tiles) and in
-   which a query sees at least :data:`ATTENTION_MIN_KEYS` keys, all such
-   layers or none — and not at all where an earlier kind had bytes to save
-   and declined for size: a step whose expert buffers alone pass the budget
-   regenerates them live inside each block's backward and has no room to
-   hold more across blocks; ``dense`` after all of them, so that it moves
-   none of their decisions, and the one kind admitted a PART at a time: the
-   ``mlp`` executions of the step ONE BY ONE FROM THE LAST backwards (a
-   looped model's passes outermost) while they fit what is left — of the
-   same 15% less every kind already admitted AND less the block inputs
-   ``checkpoint`` itself keeps, one ``[batch, sequence, features]`` for
-   every ``jax.checkpoint`` region of the step: the 15% bounds what the step
-   holds ACROSS its backward, and those inputs are such bytes that no other
-   kind counts.  From the end because the last block's backward comes first:
-   its outputs are held for the shortest time, and through its own backward
-   they stand where the replay would have put them anyway.  Not at all where
-   an earlier kind declined for size; all the step's executions or none under
-   ``scan_layers`` (one traced block for all iterations); every execution
-   under an explicit ``"stash"``;
+   none; then the two kinds admitted a PART at a time, ``recurrent`` and
+   ``dense``: the executions of the step ONE BY ONE FROM THE LAST backwards
+   (a looped model's passes outermost) while the next fits — the offers'
+   first part (a rule's output), then into what that leaves the INTERIOR of
+   those that ride, where a layer declares one (what its kernels keep for
+   their backwards), likewise from the end.  From the end because the last
+   block's backward comes first: its values are held for the shortest time,
+   and through its own backward they stand where the replay would have put
+   them anyway.  ``dense``, last, is charged what every earlier kind took AND
+   the block inputs ``checkpoint`` itself keeps, one ``[batch, sequence,
+   features]`` for every ``jax.checkpoint`` region of the step: the 15%
+   bounds what the step holds ACROSS its backward, and those inputs are such
+   bytes that no other kind counts.  Both: all the step's executions or none
+   under ``scan_layers`` (one traced block for all iterations); every
+   execution under an explicit ``"stash"``;
 4. else ``recompute``.  The save modes stay measured OPT-INS
    (docs/PERFORMANCE.md 'Round 11': ``recompute`` 204 ms/step, ``save`` 280,
    ``save_dots`` 249 on an hbm-bound rig).
@@ -219,45 +222,58 @@ def _block_input_bytes(params: ModelParameter, shards: int) -> int:
              * max(1, params.macro_batching) // shards)
 
 
-def _dense_regions(params: ModelParameter, shards: int
-                   ) -> typing.List[typing.Tuple[int, int]]:
-    """``(layer executions, per-device bytes)`` of the dense kind in every
+def _regions(params: ModelParameter, kind: str, shards: int,
+             interior: bool = False) -> typing.List[typing.Tuple[int, int]]:
+    """``(layer executions, per-device bytes)`` of ``kind`` in every
     ``jax.checkpoint`` region of the step, in execution order (a looped
-    model's passes outermost): what the region's block OFFERS.  A block's
-    layers share their names, so a region's executions go together."""
-    unit = [(len(offered), -(-sum(offer.nbytes for offer in offered)
-                             * max(1, params.macro_batching) // shards))
-            for offered in block_offers(params, "dense")]
+    model's passes outermost): what the region's block OFFERS — of its
+    layers' first part, or of their ``interior`` (the layers that have one).
+    A block's layers share their names, so a region's executions go
+    together."""
+    def part(offer):
+        return offer.interior_nbytes if interior else offer.nbytes
+
+    unit = [(sum(1 for offer in offered if part(offer)),
+             -(-sum(part(offer) for offer in offered)
+               * max(1, params.macro_batching) // shards))
+            for offered in block_offers(params, kind)]
     return unit * _executions(params)
 
 
-def _admit_dense(params: ModelParameter, shards: int,
-                 budget: typing.Optional[int]) -> typing.Tuple[int, int, int]:
-    """``(layer executions, per-device bytes, first region)`` of the dense
-    kind admitted into ``budget`` bytes (None: all of it, an explicit
-    ``"stash"``; 0: none): region by region from the step's LAST backwards while the
-    next fits — the last region's backward comes first, so its outputs are
-    held for the shortest time, and through its own backward they stand where
-    the replay would have put them anyway.  Every region from ``first
-    region`` on saves the names (:func:`region_names`).  A scanned body
-    (``scan_layers``) traces ONE block for all its iterations: there the
-    step's executions are admitted all together or not at all."""
-    regions = _dense_regions(params, shards)
-    total = sum(nbytes for _, nbytes in regions)
-    if budget is None:
-        budget = total
-    if params.scan_layers and total > budget:
-        budget = 0
-    executions = admitted = 0
-    first = len(regions)
-    for region in reversed(range(len(regions))):
-        count, nbytes = regions[region]
-        if admitted + nbytes > budget:
-            break
-        if count:
-            first = region
-        executions, admitted = executions + count, admitted + nbytes
-    return executions, admitted, first
+def _admit(params: ModelParameter, kind: str, shards: int,
+           budget: typing.Optional[int]) -> typing.Tuple[int, int, int, int]:
+    """``(layer executions, per-device bytes, first region, first region of
+    the interior)`` of ``kind`` (``recurrent``, ``dense``) admitted into
+    ``budget`` bytes (None: all of it, an explicit ``"stash"``; 0: none):
+    region by region from the step's LAST backwards while the next fits — the
+    last region's backward comes first, so its outputs are held for the
+    shortest time, and through its own backward they stand where the replay
+    would have put them anyway.  The offers' first part, then into what that
+    leaves the interior of the executions that ride, likewise from the end.
+    The regions from ``first region`` on save the part's names
+    (:func:`region_names`).  A scanned body (``scan_layers``) traces ONE
+    block for all its iterations: there a part's executions are admitted all
+    together or not at all."""
+    executions = admitted = stop = 0
+    firsts = []
+    for interior in (False, True):
+        regions = _regions(params, kind, shards, interior)
+        total = sum(nbytes for _, nbytes in regions)
+        left = total if budget is None else budget - admitted
+        if params.scan_layers and total > left:
+            left = 0
+        first, taken = len(regions), 0
+        for region in reversed(range(stop, len(regions))):
+            count, nbytes = regions[region]
+            if taken + nbytes > left:
+                break
+            if count:
+                first = region
+            taken += nbytes
+            executions += 0 if interior else count
+        firsts.append(first)
+        admitted, stop = admitted + taken, first
+    return (executions, admitted, *firsts)
 
 
 def _save_residual_bytes(params: ModelParameter) -> int:
@@ -338,26 +354,36 @@ def stash_kinds(params: ModelParameter, mesh=None) -> typing.FrozenSet[str]:
     docstring's item 3).  Under revnet / momentum the attention rule is the
     historical one and is decided FIRST: the bottleneck kind only gets what
     it leaves of the budget, so adding that kind moved no configuration's
-    attention decision.  Under ``checkpoint`` the experts kind is decided
-    first, the recurrent kind from what it leaves, the attention kind from
-    what both leave — so it moves neither — and not at all where one of them
-    had bytes to save and declined for size; the dense kind LAST, an
-    execution at a time (:func:`_decide`)."""
+    attention decision.  Under ``checkpoint`` each kind is judged on its own
+    bytes against what the kinds before it TOOK — attention, experts,
+    recurrent, dense — and one that passes it takes nothing and moves no
+    other's decision; the last two an execution at a time (:func:`_decide`)."""
     return _decide(params, mesh)[0]
+
+
+#: the kinds the ``checkpoint`` strategy admits an execution at a time
+#: (:func:`_admit`), in the order they are decided
+BY_EXECUTION = ("recurrent", "dense")
 
 
 def _decide(params: ModelParameter, mesh
             ) -> typing.Tuple[typing.FrozenSet[str],
-                              typing.Tuple[int, int, int]]:
-    """``(the kinds, the dense kind's (layer executions, per-device bytes,
-    first region))`` — :func:`stash_kinds`, and how far the one kind that is
-    admitted a part at a time got (:func:`_admit_dense`)."""
+                              typing.Dict[str, typing.Tuple[int, ...]]]:
+    """``(the kinds, {kind: (layer executions, per-device bytes, first
+    region, first region of the interior)})`` — :func:`stash_kinds`, and how
+    far each of the kinds that are admitted a part at a time got
+    (:data:`BY_EXECUTION`, :func:`_admit`)."""
     shards, _ = shardlib.shard_geometry(mesh)
+
+    def each(budget):
+        return {kind: _admit(params, kind, shards, budget)
+                for kind in BY_EXECUTION}
+
     explicit = _explicit_policy(params)
     if explicit is not None:
         stash = explicit == "stash"
-        return frozenset(STASH_KINDS if stash else ()), _admit_dense(
-            params, shards, None if stash else 0)
+        return frozenset(STASH_KINDS if stash else ()), each(
+            None if stash else 0)
     rep = remat_report(params, mesh)
     budget = rep["stash_budget_bytes"]
     forced = _forced_attention(params)
@@ -370,34 +396,30 @@ def _decide(params: ModelParameter, mesh
             and 0 < rep["bottleneck_stash_bytes_per_device"] <= budget:
         kinds.add("bottleneck")
     if params.memory_reduction_strategy != "checkpoint":
-        return frozenset(kinds), _admit_dense(params, shards, 0)
+        return frozenset(kinds), each(0)
     # the whole budget: what the two kinds above name rides the revnet /
     # momentum residuals, so under "checkpoint" they hold no byte of it, and
     # the attention kind is decided again by what each layer really saves
     kinds.discard("attention")
     budget = rep["stash_budget_bytes"]
-    fitted = True
-    for kind in ("experts", "recurrent"):
-        nbytes = rep[f"{kind}_stash_bytes_per_device"]
-        if 0 < nbytes <= budget:
-            kinds.add(kind)
-            budget -= nbytes
-        elif nbytes:
-            # a step whose expert buffers alone pass the budget regenerates
-            # them live inside each block's backward: no room to hold more
-            fitted = False
     layers, nbytes = _saved_attention(params, mesh,
                                       _attention_min_keys(params))
-    if layers and (forced or (fitted and nbytes <= budget)):
+    if layers and (forced or nbytes <= budget):
         kinds.add("attention")
         budget -= nbytes
-    # what is left of a bound on what the step holds ACROSS its backward:
-    # the block inputs ``checkpoint`` itself keeps are such bytes too
-    dense = _admit_dense(params, shards, budget - _block_input_bytes(
-        params, shards) if fitted else 0)
-    if dense[0]:
-        kinds.add("dense")
-    return frozenset(kinds), dense
+    nbytes = rep["experts_stash_bytes_per_device"]
+    if 0 < nbytes <= budget:
+        kinds.add("experts")
+        budget -= nbytes
+    recurrent = _admit(params, "recurrent", shards, budget)
+    # what is left of a bound on what the step holds ACROSS its backward: the
+    # block inputs ``checkpoint`` itself keeps are such bytes too, charged to
+    # the last kind alone
+    dense = _admit(params, "dense", shards, budget - recurrent[1]
+                   - _block_input_bytes(params, shards))
+    parts = {"recurrent": recurrent, "dense": dense}
+    kinds.update(kind for kind, part in parts.items() if part[0])
+    return frozenset(kinds), parts
 
 
 def _attention_sites(params: ModelParameter, mesh) -> int:
@@ -423,26 +445,25 @@ def stash_plan(params: ModelParameter, mesh=None
     no way to keep it, a pipeline mesh, an explicit policy, a rule that
     declined, no such layer).  ``Trainer`` publishes it as
     ``hbnlp_remat_stash_bytes{kind}`` / ``hbnlp_remat_stash_layers{kind}``
-    (docs/OBSERVABILITY.md); ``_checkpoint_policy`` saves the experts, the
-    recurrent and the attention kind's names exactly where this says they
-    ride (:func:`stash_names`), and the dense kind's in the regions of the
-    executions it counts (:func:`region_names`)."""
+    (docs/OBSERVABILITY.md); ``_checkpoint_policy`` saves the experts and the
+    attention kind's names exactly where this says they ride
+    (:func:`stash_names`), and the recurrent and the dense kind's in the
+    regions of the executions it counts (:func:`region_names`)."""
     plan = {kind: (0, 0) for kind in STASH_KINDS}
     strategy = params.memory_reduction_strategy
     piped = mesh is not None and mesh.shape.get(shardlib.PIPE_AXIS, 1) > 1
     if strategy not in ("revnet", "momentum", "checkpoint") or piped:
         return plan
-    kinds, dense = _decide(params, mesh)
+    kinds, parts = _decide(params, mesh)
     rep = remat_report(params, mesh)
     if strategy == "checkpoint":
-        for kind in ("experts", "recurrent"):
-            if kind in kinds and rep[f"{kind}_stash_layers"]:
-                plan[kind] = (rep[f"{kind}_stash_layers"],
-                              rep[f"{kind}_stash_bytes_per_device"])
+        if "experts" in kinds and rep["experts_stash_layers"]:
+            plan["experts"] = (rep["experts_stash_layers"],
+                               rep["experts_stash_bytes_per_device"])
         if "attention" in kinds:
             plan["attention"] = _saved_attention(
                 params, mesh, _attention_min_keys(params))
-        plan["dense"] = dense[:2]
+        plan.update({kind: part[:2] for kind, part in parts.items()})
         return plan
     if "attention" in kinds:
         layers = _attention_sites(params, mesh) * params.depth
@@ -469,17 +490,30 @@ def saved_attention_keys(params: ModelParameter, mesh=None
     return _attention_min_keys(params)
 
 
+def _names(params: ModelParameter, kind: str, interior: bool = False
+           ) -> typing.Tuple[str, ...]:
+    """The ``checkpoint_name``s one depth-unit's layers declare for ``kind``
+    (or for its ``interior``), in execution order."""
+    return tuple(name for offer in offers(params, kind) for name in (
+        offer.interior_names if interior else offer.names))
+
+
 def stash_names(params: ModelParameter, mesh=None) -> typing.Tuple[str, ...]:
     """The ``checkpoint_name``s the ``checkpoint`` strategy's
-    ``jax.checkpoint`` saves beside its named policy: those the layers
-    declare for every kind :func:`stash_plan` says rides it — experts, then
+    ``jax.checkpoint`` saves beside its named policy in a region that holds
+    an admitted execution of the recurrent kind, interior and all (every
+    region, where all its executions are admitted): those the layers declare
+    for every kind :func:`stash_plan` says rides it — experts, then
     recurrent, in execution order, then attention.  Empty where none does."""
     plan = stash_plan(params, mesh)
-    riding = [kind for kind in ("experts", "recurrent") if plan[kind][0]]
+    names = _names(params, "experts") if plan["experts"][0] else ()
+    if plan["recurrent"][0]:
+        regions = len(params.block_config) * _executions(params)
+        inside = _decide(params, mesh)[1]["recurrent"][3] < regions
+        names += _names(params, "recurrent") \
+            + (_names(params, "recurrent", True) if inside else ())
     if saved_attention_keys(params, mesh) is not None:
-        riding.append("attention")
-    names = [name for kind in riding for offer in offers(params, kind)
-             for name in offer.names]
+        names += _names(params, "attention")
     return tuple(dict.fromkeys(names))     # a name once, in order
 
 
@@ -495,18 +529,25 @@ def region_names(params: ModelParameter, mesh=None
     """The names the ``jax.checkpoint`` of every region of the step saves, a
     tuple a region (the body's blocks in execution order, a looped model's
     passes outermost; a scanned body's one traced block stands for all its
-    iterations): :func:`stash_names`, and the dense kind's where the region
-    holds an admitted execution."""
+    iterations): :func:`stash_names` — the recurrent kind's from the first
+    region that holds an admitted execution of it on, its interior's from
+    theirs — and the dense kind's where the region holds one of its own."""
     names = stash_names(params, mesh)
-    regions = _dense_regions(params, shardlib.shard_geometry(mesh)[0])
+    plan, parts = stash_plan(params, mesh), _decide(params, mesh)[1]
+    regions = _regions(params, "dense", shardlib.shard_geometry(mesh)[0])
     # (the plan's count is 0 where the strategy has no region to ride)
-    first = _decide(params, mesh)[1][2] \
-        if dense_executions(params, mesh) else len(regions)
-    dense = tuple(dict.fromkeys(
-        (*names, *(name for offer in offers(params, "dense")
-                   for name in offer.names))))
-    return [dense if region >= first and count else names
-            for region, (count, _) in enumerate(regions)]
+    first, inside = parts["recurrent"][2:] if plan["recurrent"][0] \
+        else (len(regions),) * 2
+    dense_first = parts["dense"][2] if plan["dense"][0] else len(regions)
+    outer, inner = (_names(params, "recurrent", interior)
+                    for interior in (False, True))
+    dense = _names(params, "dense")
+    return [tuple(dict.fromkeys(
+        (*(name for name in names
+           if (name not in outer or region >= first)
+           and (name not in inner or region >= inside)),
+         *(dense if region >= dense_first and count else ()))))
+        for region, (count, _) in enumerate(regions)]
 
 
 def stash_line(plan: typing.Dict[str, typing.Tuple[int, int]],

@@ -90,11 +90,22 @@ import typing
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from .delta_rule import (_CHUNKS, _LANE, _NT, _STATE_BYTES, _TN, _dot,
                          _heads_here, _lay_diagonal, _padded_heads, _pairs,
                          _params, _rows, _sequence_minor, _take_diagonal,
                          head_block)
+
+
+#: the names the forward rules give what a kernel hands the backwards (free
+#: where no policy names them): ``kda_scores``' five outputs — ``A``, ``A'^T``,
+#: ``gamma``, the normalised ``q`` and ``k`` — and the states entering every
+#: chunk of ``kda_rule_pair``'s walk.  A ``jax.checkpoint`` that saves them,
+#: the walk's ``o`` and the solve's inverse replays neither forward kernel
+SCORES_NAMES = ("kda_strict", "kda_mixed", "kda_gamma", "kda_q_unit",
+                "kda_k_unit")
+STATES_NAME = "kda_states"
 
 
 def kda_kernel_applies(chunk: int, heads: int, d_k: int, d_v: int,
@@ -447,7 +458,8 @@ def kda_rule_pair(qt, kt, vt, gt, transform, mixed, chunk: int,
 def _vjp_fwd(qt, kt, vt, gt, transform, mixed, chunk, hb, kept, interpret):
     ot, low, entering = _forward(qt, kt, vt, gt, transform, mixed, chunk, hb,
                                  kept, interpret)
-    return ot, (qt, kt, vt, gt, low, mixed, entering)
+    return ot, (qt, kt, vt, gt, low, mixed,
+                checkpoint_name(entering, STATES_NAME))
 
 
 def _vjp_bwd(chunk, hb, kept, interpret, res, g):
@@ -797,7 +809,8 @@ def kda_scores(qt, kt, gt, heads: int, chunk: int, sub: int, q_scale: float,
 
 
 def _scores_vjp_fwd(qt, kt, gt, *static):
-    return kda_scores(qt, kt, gt, *static), (qt, kt, gt)
+    return tuple(checkpoint_name(out, name) for out, name in zip(
+        kda_scores(qt, kt, gt, *static), SCORES_NAMES)), (qt, kt, gt)
 
 
 def _scores_vjp_bwd(*args):

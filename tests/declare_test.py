@@ -82,8 +82,11 @@ _CELLS = {
          "hbnlp_mamba_conv_kernel_layers": 3,
          "hbnlp_delta_solve_kernel_layers": 3,
          "hbnlp_delta_rule_kernel_layers": 3, **_S16K[1]}),
+    # PR 61: the global layer's (out [2, 8192, 48, 128], lse [96, 8192]) rides
+    # on its own bytes (until then nothing: the experts' decline kept it out)
     "train_laguna_s_2_1_ep32_s8k": (
-        _kinds(), "; moe held rows bound 131072; flash band 3 layers"
+        _kinds(attention=(1, 204472320)),
+        "; moe held rows bound 131072; flash band 3 layers"
         + _LAGUNA[0],
         {"hbnlp_moe_held_rows_bound": 131072, "hbnlp_flash_band_layers": 3,
          **_LAGUNA[1]}),
@@ -129,9 +132,13 @@ _CELLS = {
     # the rule (parallel/kda_rule.py, by the layer's own predicate): ``; rule
     # kernel 4 layers``, and the chunk states alive for the backward are all
     # 32 heads' ([256, 32, 128, 128] bfloat16) where they were one group's
-    # 67,108,864.  The eleven lines above stand
+    # 67,108,864.  The eleven lines above stand.  PR 61: each kind on its own
+    # bytes, so the latent attention's (out, lse) rides, and the recurrent
+    # kind an execution at a time, the pairs' interior (1,140,850,688 bytes a
+    # layer on a TPU) a second part of the offer: beside the four outputs
+    # (536,870,912 bytes, as before) the LAST layer's interior
     "train_kimi_linear_ep32_s16k": (
-        _kinds(recurrent=(4, 536870912)),
+        _kinds(attention=(1, 136314880), recurrent=(4, 1677721600)),
         "; ssd chunk states 268435456 bytes a device; conv kernel 4 layers; "
         "solve kernel 4 layers; rule kernel 4 layers; "
         "moe held rows bound 131072" + _S16K[0],
@@ -224,8 +231,14 @@ def _config_files():
 #: gain ``; rule kernel N layers`` (4 and 20 on a TPU, 0 on the CPU) and
 #: their chunk states on a TPU are all 32 heads' where they were one
 #: group's; the other 27 files' lines and series as they were: before it
-#: a5ae0c5439eacddd8671609ed08f3e82c06cec80)
-_FILE_DIGEST = "6fc52f37f21988967d830151219f98f18fbe5817"
+#: a5ae0c5439eacddd8671609ed08f3e82c06cec80; PR 61: the two cells' files
+#: alone — ``benchmark/configs/laguna_s_2_1.json`` reads ``attention 1 layers,
+#: 204472320`` on both sides, ``benchmark/configs/kimi_linear_48b_a3b.json``
+#: ``attention 1 layers, 136314880`` with ``recurrent 4 layers, 1677721600``
+#: on a TPU and, on the CPU, the four outputs as before and ``dense 1 layers,
+#: 603979776``; the other 27 files as they were: before it
+#: 6fc52f37f21988967d830151219f98f18fbe5817)
+_FILE_DIGEST = "9ecdcf46a27f8dec5a5319d33aeb28b42ab64363"
 
 
 def every_configuration_file_starts_as_on_the_parent_test(monkeypatch):

@@ -291,14 +291,20 @@ def declining_layer_traces_the_xla_form_test(monkeypatch, extra):
     assert "kda_rule_fwd" not in plain and "pallas_call" not in plain
 
 
-def step_lowered_for_a_tpu_carries_the_kernels_test(monkeypatch):
+@pytest.mark.parametrize("policy,forwards", [("recompute", 2), ("auto", 1)])
+def step_lowered_for_a_tpu_carries_the_kernels_test(monkeypatch, policy,
+                                                    forwards):
     """The toy step at kernel shapes lowered for a TPU (no chip, no
-    compile): four kinds of ``tpu_custom_call`` — each pair's forward twice,
-    the step's and the block's replay, its backward once — every one under
-    scope ``body/kda/rule``, which ``kimi_kda_rule_roofline`` and
-    ``scope_kda_time_share`` read, and no loop left there (the XLA form has
-    the groups' ``lax.map`` and a ``while`` of a trip a chunk)."""
-    _, _, model, batch, variables = _build("bfloat16", **_WIDE)
+    compile): four kinds of ``tpu_custom_call`` — each pair's backward once,
+    its forward twice, the step's and the block's replay, where nothing is
+    kept (``"recompute"``) and once where the execution's interior rides the
+    block's ``jax.checkpoint`` (PR 61: the toy's bytes fit under ``"auto"``)
+    — every one under scope ``body/kda/rule``, which
+    ``kimi_kda_rule_roofline`` and ``scope_kda_time_share`` read, and no loop
+    left there (the XLA form has the groups' ``lax.map`` and a ``while`` of a
+    trip a chunk)."""
+    _, _, model, batch, variables = _build("bfloat16", remat_policy=policy,
+                                           **_WIDE)
 
     def lowered():
         return jax.jit(jax.grad(
@@ -325,8 +331,8 @@ def step_lowered_for_a_tpu_carries_the_kernels_test(monkeypatch):
              for line in text.split("\n")]
     sites = [(found.group(1), paths(line)[0]) for found, line in sites
              if found]
-    assert sorted(name for name, _ in sites) == [
-        "bwd", "fwd", "fwd", "scores_bwd", "scores_fwd", "scores_fwd"]
+    assert sorted(name for name, _ in sites) == sorted(
+        ["bwd", "scores_bwd"] + ["fwd", "scores_fwd"] * forwards)
     for _, path in sites:
         assert scope_key(path + "/kda_rule_fwd/pallas_call") \
             == "body/kda/rule", path
@@ -337,6 +343,84 @@ def step_lowered_for_a_tpu_carries_the_kernels_test(monkeypatch):
     assert len(xla_loops) >= 2 and len(loops(text)) < len(xla_loops)
     assert not any("kda_0/rule" in path for line in loops(text)
                    for path in paths(line))
+
+
+#: two ``kda`` blocks of 8 heads at chunk 16: 128 systems, a tile of the
+#: solve's kernel
+_TWO_BLOCKS = {"kda_heads": 8, "kda_key_features": 16,
+               "kda_value_features": 16, "sequence_length": 256,
+               "train_batch_size": 1,
+               "block_config": [_block("kda"), _block("kda")]}
+_FORWARDS = ("kda_scores_fwd", "delta_solve_fwd", "kda_rule_fwd")
+_BACKWARDS = ("kda_scores_bwd", "delta_solve_bwd", "kda_rule_bwd")
+_REPLAYED = {}
+
+
+def _two_blocks(monkeypatch, policy: str, admit=None):
+    """The toy of two ``kda`` blocks in float32 as a TPU process traces it,
+    all three pairs interpreted, under ``policy``; ``admit``: the executions
+    the chip's limit admits.  ``(the plan's recurrent kind, the kernels named
+    in every region's backward in execution order, (loss, gradients))``."""
+    from homebrewnlp_tpu.model import remat
+    from homebrewnlp_tpu.parallel import delta_solve as ds
+    from homebrewnlp_tpu.utils import flops
+    monkeypatch.setattr(kda_mod, "CHUNK", 16)
+    _as_a_tpu_process(monkeypatch)
+    steer_interpreted(monkeypatch)
+    harness.steer(monkeypatch, delta_mod,
+                  solve_kernel_applies=functools.partial(
+                      ds.solve_kernel_applies, backend="tpu"))
+    harness.steer_interpreted(monkeypatch, delta_mod, ds,
+                              "inverse_unit_lower", "inverse_unit_lower_bwd")
+    _, params, model, batch, variables = _build(
+        "float32", remat_policy=policy, **_TWO_BLOCKS)
+    offer = kda_mod.kda.declares.offer(params, set())
+    unit = (offer.nbytes, offer.interior_nbytes)
+    if admit is not None:
+        # both outputs and ``admit`` and a half interiors
+        monkeypatch.setattr(flops, "hbm_capacity", lambda device=None: (
+            int((2 * unit[0] + (admit + 0.5) * unit[1])
+                / remat.STASH_HBM_FRACTION), "test"))
+    fn = jax.value_and_grad(lambda v: harness.loss_of(model)(v, batch))
+    regions = [str(eqn.params["jaxpr"])
+               for eqn in jax.make_jaxpr(fn)(variables).jaxpr.eqns
+               if eqn.primitive.name == "remat2"][::-1]
+    return (remat.stash_plan(params)["recurrent"], unit,
+            [{name: text.count(name) for name in _FORWARDS + _BACKWARDS}
+             for text in regions], jax.jit(fn)(variables))
+
+
+@pytest.mark.parametrize("admit", [0, 1, 2])
+def saved_interior_is_the_replayed_one_test(monkeypatch, admit):
+    """PR 61: a ``kda`` block whose interior is admitted saves what the
+    three pairs' forwards hand their backwards, and its replay — the
+    ``jax.checkpoint`` region's backward — names no forward call of the
+    scores, the solve or the walk; one whose output alone rides names all
+    three; admitted are the LAST executions.  Under ``jax.jit`` the loss and
+    every gradient are the replayed ones' (``"recompute"``) bit for bit: a
+    saved value is the bits its replay would have made."""
+    if not _REPLAYED:
+        with pytest.MonkeyPatch.context() as patch:
+            _REPLAYED["all"] = _two_blocks(patch, "recompute")
+    plan, _, regions, (want_loss, want) = _REPLAYED["all"]
+    assert plan == (0, 0)
+    assert regions == [dict.fromkeys(_FORWARDS + _BACKWARDS, 1)] * 2
+    plan, unit, regions, (loss, grads) = _two_blocks(monkeypatch, "auto",
+                                                     admit)
+    # o [1, 256, 8, 16]; q~, k~ and gamma the same, A, A' and the inverse
+    # [1, 16, 8, 16, 16], the states [1, 16, 8, 16, 16], all float32 here
+    assert unit == (4 * 256 * 8 * 16,
+                    4 * (3 * 256 * 8 * 16 + 4 * 16 * 8 * 16 * 16))
+    assert plan == (2, 2 * unit[0] + admit * unit[1])
+    for region, names in enumerate(regions):
+        kept = region >= 2 - admit
+        assert names == {**dict.fromkeys(_FORWARDS, 0 if kept else 1),
+                         **dict.fromkeys(_BACKWARDS, 1)}, region
+    assert float(loss) == float(want_loss) and np.isfinite(float(loss))
+    assert set(grads) == set(want)
+    for name in want:
+        np.testing.assert_array_equal(np.asarray(grads[name]),
+                                      np.asarray(want[name]), err_msg=name)
 
 
 def rule_fact_counts_the_layers_test(monkeypatch):

@@ -440,7 +440,31 @@ def the_step_reports_the_decay_and_the_transform_test():
         telemetry.set_registry(prev)
 
 
-def the_layers_offer_their_outputs_test():
+@pytest.mark.parametrize("kernels", [False, True], ids=["xla", "pairs"])
+def the_layers_offer_their_outputs_test(kernels, monkeypatch):
+    """Layer ``kda`` offers the rule's output and — PR 61, where the rule is
+    the Pallas pairs, by the layer's own predicate on its shapes (a toy of
+    kernel widths: the file's declines on any backend) — what the pairs'
+    forwards hand their backwards, each in the precision it is kept in."""
+    from homebrewnlp_tpu.parallel import kda_rule as kr
+    if kernels:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    wide = ModelParameter(_config(
+        "bfloat16", sequence_length=128, kda_key_features=32,
+        kda_value_features=16))
+    offer = kda_mod.kda.declares.offer(wide, set())
+    assert (offer.kind, offer.names, offer.nbytes) \
+        == ("recurrent", ("kda_out",), 2 * 128 * 3 * 16 * 2)
+    if kernels:
+        assert ("kda_out",) + offer.interior_names == (
+            "kda_out", *kr.SCORES_NAMES, "kda_solved", kr.STATES_NAME) \
+            == kda_mod.SAVED_NAMES
+        # gamma in float32 and q~, k~ [2, 128, 3, 32]; A and the inverse in
+        # float32, A' [2, 2, 3, 64, 64]; the states [2, 2, 3, 16, 32]
+        assert offer.interior_nbytes == 2 * 128 * 3 * 32 * (4 + 2 + 2) \
+            + 2 * 2 * 3 * 64 * 64 * (4 + 4 + 2) + 2 * 2 * 3 * 16 * 32 * 2
+    else:
+        assert (offer.interior_names, offer.interior_nbytes) == ((), 0)
     params = ModelParameter(_config())
     offer = kda_mod.kda.declares.offer(params, set())
     assert (offer.kind, offer.names) == ("recurrent", ("kda_out",))
@@ -511,3 +535,35 @@ def the_repos_config_is_the_published_model_test():
                                  "train_batch_size", "tpu_size"})
     for key, value in doc["published"].items():
         assert doc["reduced"][key]["from"] == value
+
+
+# ---- compiled for a described v5e ---------------------------------------------
+
+@pytest.mark.parametrize("policy,forwards", [("auto", 1), ("recompute", 2)])
+def kda_block_compiled_for_a_v5e_test(v5e, monkeypatch, policy, forwards):
+    """One ``kda`` block of the cell at its published widths and 8,192 tokens,
+    loss and gradients compiled for a v5e as a TPU process traces them.  PR
+    61: where the block's interior is admitted (one block: its 0.64 GB fit)
+    the ``jax.checkpoint`` holds what the three pairs' forwards hand their
+    backwards and the compiled step runs each forward ONCE — the replay none;
+    under ``"recompute"`` twice.  Mosaic accepts every call; the rule's ops
+    still fold into ``body/kda/rule``, which ``kimi_kda_rule_roofline`` and
+    ``scope_kda_time_share`` read."""
+    from homebrewnlp_tpu.model import remat
+    params, hlo = harness.cell_layer_hlo(
+        v5e, monkeypatch, "train_kimi_linear_ep32_s16k", "kda", policy,
+        sequence_length=8192)
+    assert params.block_config[0].layer[-1] == "kda"
+    offer = kda_mod.kda.declares.offer(params, set())
+    assert offer.names + offer.interior_names == kda_mod.SAVED_NAMES
+    assert remat.stash_plan(params)["recurrent"] == (
+        (1, offer.nbytes + offer.interior_nbytes) if policy == "auto"
+        else (0, 0))
+    calls = harness.kernel_calls(hlo)
+    assert sorted(name for name, _ in calls) == sorted(
+        ["kda_scores_bwd", "delta_solve_bwd", "kda_rule_bwd",
+         "mamba_conv_bwd"] + ["mamba_conv_fwd"] * 2
+        + ["kda_scores_fwd", "delta_solve_fwd", "kda_rule_fwd"] * forwards)
+    for name, op_name in calls:
+        assert scope_key(op_name) == ("body/kda/conv" if name.startswith(
+            "mamba_conv") else "body/kda/rule"), (name, op_name)

@@ -674,10 +674,13 @@ def the_new_scopes_fold_test(path, scope_name):
 def dense_kind_leaves_the_cells_step_alone_test(monkeypatch):
     """PR 52: the cell's one ``mlp`` (the published dense layer 0) is an
     INPUT block — outside every ``jax.checkpoint`` region, so it offers
-    nothing — and the experts kind declined for size, so nothing is admitted
-    whatever a body held: every region's policy is the parent's, the named
-    one itself."""
-    from homebrewnlp_tpu.model.blocks import _region_policies
+    nothing, whatever the budget.  PR 61: the experts kind passes the budget
+    and takes none of it, so the global layer's flash pair rides on its own
+    204,472,320 bytes — every region's policy names the pair and nothing of
+    layer ``mlp`` (until then every region's was the named policy itself:
+    the experts' decline kept every later kind out)."""
+    from homebrewnlp_tpu.model.blocks import (_named_policy,
+                                              _region_policies)
     from homebrewnlp_tpu.model.declare import step_offers
     from homebrewnlp_tpu.utils import flops
     from remat_policy_test import _cell_params
@@ -689,13 +692,19 @@ def dense_kind_leaves_the_cells_step_alone_test(monkeypatch):
     assert remat.offers(params, "dense") == []
     assert len(list(step_offers(params, "dense"))) == 1
     assert remat.stash_plan(params)["dense"] == (0, 0)
-    assert "dense" not in remat.stash_kinds(params)
+    assert remat.stash_kinds(params) == {"attention"}
+    assert remat.stash_plan(params)["attention"] == (1, 204472320)
+    assert remat.stash_plan(params)["experts"] == (0, 0)
     regions = len(params.block_config) * params.depth
-    assert remat.region_names(params) == [()] * regions
+    pair = ("flash_out", "flash_lse")
+    assert remat.region_names(params) == [pair] * regions
     policies = _region_policies(params)
     assert len(policies) == regions
-    assert all(policy is jax.checkpoint_policies.nothing_saveable
+    assert all(policy is _named_policy("nothing_saveable", pair)
                for policy in policies)
+    # the three window-512 layers' queries see fewer than 2,048 keys: the
+    # blocks' channel names the global layer's call alone
+    assert remat.saved_attention_keys(params) == 2048
 
 
 # ---- compiled for a described v5e ---------------------------------------------

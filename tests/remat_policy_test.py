@@ -333,8 +333,8 @@ def experts_stash_resolver_test(case):
         assert rep["experts_stash_bytes_per_device"] == 2 * _OLMOE_LAYER \
             == 1073741824 + 1573376 <= rep["stash_budget_bytes"]
         assert "experts" in stash_kinds(p)
-        # the attention kind is decided after it, from what it leaves
-        # (PR 40): it moved nothing here
+        # the attention kind is decided before it (PR 61; after it since PR
+        # 40): both fit, so neither order moves the other
         assert stash_plan(p) == {**idle, "experts": (2, 2 * _OLMOE_LAYER),
                                  "attention": (2, 68157440)}
     elif case == "depth_16":
@@ -343,8 +343,10 @@ def experts_stash_resolver_test(case):
         rep = remat_report(p)
         assert rep["experts_stash_bytes_per_device"] == 16 * _OLMOE_LAYER \
             > rep["stash_budget_bytes"]
-        assert "experts" not in stash_kinds(p)
-        assert stash_plan(p) == idle
+        # a kind that passes the budget takes none of it and moves no other
+        # kind's decision (PR 61): the sixteen layers' (out, lse) ride
+        assert stash_kinds(p) == {"attention"}
+        assert stash_plan(p) == {**idle, "attention": (16, 8 * 68157440)}
     elif case == "recompute":
         p = _cell_params("train_olmoe_1b_7b_s4k", remat_policy="recompute")
         assert stash_kinds(p) == frozenset() and stash_plan(p) == idle
@@ -379,7 +381,8 @@ def experts_stash_resolver_test(case):
         p = _cell_params("train_olmoe_1b_7b_s4k", macro_batching=3)
         assert remat_report(p)["experts_stash_bytes_per_device"] \
             == 6 * _OLMOE_LAYER
-        assert stash_plan(p) == idle
+        # ... and take none of it (PR 61): three sets of (out, lse) ride
+        assert stash_plan(p) == {**idle, "attention": (2, 3 * 68157440)}
 
 
 _MOE_NAMES = ("moe_gate", "moe_up", "moe_down", "moe_order", "moe_inverse",
@@ -427,11 +430,13 @@ def chip_limit(request, monkeypatch):
     ("train_olmo_hybrid_7b_long", {"attention", "recurrent", "dense"},
      "stash", {"recurrent": (3, 566231040), "attention": (1, 127795200)},
      ("gated_delta_out",) + _FLASH_NAMES, (1, 721420288, (7,))),
-    # the experts kind has 5,375,525,008 bytes to save and declined for
-    # size: the step as it was (the parent read a vestigial {"attention"}
-    # that nothing carried), and (PR 52) no room for the dense kind either;
-    # its one ``mlp`` is an input block, outside every region
-    ("train_laguna_s_2_1_ep32_s8k", set(), "recompute", {}, (), (0, 0, ())),
+    # the experts kind has 5,375,525,008 bytes to save, passes the budget and
+    # takes none of it: since PR 61 the global layer's (out [2, 8192, 48, 128]
+    # + lse [96, 8192]) rides on its own bytes (the three window-512 layers
+    # are under 2,048 keys); its one ``mlp`` is an input block, outside every
+    # region
+    ("train_laguna_s_2_1_ep32_s8k", {"attention"}, "stash",
+     {"attention": (1, 204472320)}, _FLASH_NAMES, (0, 0, ())),
     # layer cca's OWN 8 query heads in a 16-head stream: 8 x (out [1, 16384,
     # 8, 128] + lse [8, 16384])
     ("train_zaya1_8b_ep2_s16k", {"attention", "experts"}, "stash",
@@ -458,7 +463,8 @@ def experts_kind_moves_no_other_cell_test(cell, kinds, policy, plan, names,
     ``checkpoint`` cells whose earlier kinds fitted — each layer's own query
     heads, ISSUE 40's table to the byte — after the kinds the parent
     resolved, which it moved in none; Laguna's and the three revnet cells'
-    plan and names are the parent's.  Since PR 52 all ten cells, at the
+    plan and names are the parent's (until PR 61: Laguna's global layer rides
+    now, the one row that moved).  Since PR 52 all ten cells, at the
     chip's own limit: the ``dense`` kind, decided last, admits ``dense`` =
     (executions, bytes, the regions that save layer ``mlp``'s two names) —
     ISSUE 52's table to the byte — and moves no other kind's numbers; every
@@ -552,11 +558,14 @@ def experts_stash_line_and_policy_test():
         "device; dense 0 layers, 0 bytes a device")
     nothing = jax.checkpoint_policies.nothing_saveable
     assert _checkpoint_policy(p) is not nothing
-    for kw in ({"remat_policy": "recompute"}, {"depth": 16}):
+    # (at the published depth the experts pass the budget; since PR 61 the
+    # sixteen flash pairs ride there on their own bytes: flash off, nothing)
+    for kw in ({"remat_policy": "recompute"},
+               {"depth": 16, "use_flash_attention": False}):
         assert _checkpoint_policy(
             _cell_params("train_olmoe_1b_7b_s4k", **kw)) is nothing
     assert _checkpoint_policy(_cell_params(
-        "train_olmoe_1b_7b_s4k", depth=16,
+        "train_olmoe_1b_7b_s4k", depth=16, use_flash_attention=False,
         gradient_checkpointing_policy="dots_saveable")) \
         is jax.checkpoint_policies.dots_saveable
 
@@ -600,8 +609,8 @@ def recurrent_stash_resolver_test(case):
     from homebrewnlp_tpu.model.declare import offers
     from homebrewnlp_tpu.model.recurrent import \
         recurrent_layers as _recurrent_layers
-    from homebrewnlp_tpu.model.remat import (stash_kinds, stash_names,
-                                             stash_plan)
+    from homebrewnlp_tpu.model.remat import (region_names, stash_kinds,
+                                             stash_names, stash_plan)
     idle = _IDLE
     nothing = jax.checkpoint_policies.nothing_saveable
     if case == "engaged":
@@ -620,13 +629,22 @@ def recurrent_stash_resolver_test(case):
         assert stash_names(p) == ("gated_delta_out",) + _FLASH_NAMES
         assert _checkpoint_policy(p) is not nothing
     elif case == "over_budget":
-        # the published depth, eight periods: 4.5 GB, all layers or none
+        # the published depth, eight periods, offers 4.5 GB: an execution at
+        # a time from the step's end (PR 61; all layers or none before it,
+        # so none) — the eight that fit what the eight flash pairs leave
         p = _cell_params(_OLMO, depth=8)
         rep = remat_report(p)
         assert rep["recurrent_stash_bytes_per_device"] == 24 * _OLMO_LAYER \
             > rep["stash_budget_bytes"]
-        assert "recurrent" not in stash_kinds(p) and stash_plan(p) == idle
-        assert _checkpoint_policy(p) is nothing
+        left = rep["stash_budget_bytes"] - 8 * _OLMO_FLASH
+        assert 8 * _OLMO_LAYER <= left < 9 * _OLMO_LAYER
+        assert stash_plan(p) == {**idle, "attention": (8, 8 * _OLMO_FLASH),
+                                 "recurrent": (8, 8 * _OLMO_LAYER)}
+        # the last eight: the last two periods' three and two of the sixth's
+        held = [r for r, names in enumerate(region_names(p))
+                if "gated_delta_out" in names]
+        assert held == list(range(42, 64))
+        assert _checkpoint_policy(p) is not nothing
     elif case == "recompute":
         p = _cell_params(_OLMO, remat_policy="recompute")
         assert stash_kinds(p) == frozenset() and stash_plan(p) == idle
@@ -658,14 +676,16 @@ def recurrent_stash_resolver_test(case):
             assert stash_plan(p, Piped()) == idle
             assert _checkpoint_policy(p, Piped()) is nothing
     elif case == "macro_batching":
-        # five micro-batches hold five sets of outputs: over the budget
+        # five micro-batches hold five sets of outputs: 2.83 GB offered, and
+        # (PR 61) the last two layers' 1.89 GB admitted beside the flash pair
         p = _cell_params(_OLMO, macro_batching=5)
         rep = remat_report(p)
         assert rep["recurrent_stash_bytes_per_device"] == 15 * _OLMO_LAYER \
             > rep["stash_budget_bytes"]
-        assert stash_plan(p) == idle
+        assert stash_plan(p) == {**idle, "attention": (1, 5 * _OLMO_FLASH),
+                                 "recurrent": (2, 10 * _OLMO_LAYER)}
     elif case == "experts_leave_room":
-        # decided AFTER experts, from what experts leaves of the same 15%
+        # decided AFTER experts, from what it and attention took of the 15%
         p, experts = _with_moe(2)
         budget = remat_report(p)["stash_budget_bytes"]
         assert experts + 3 * _OLMO_LAYER + _OLMO_FLASH <= budget
@@ -679,19 +699,25 @@ def recurrent_stash_resolver_test(case):
         budget = remat_report(p)["stash_budget_bytes"]
         assert experts <= budget and 3 * _OLMO_LAYER <= budget \
             < experts + 3 * _OLMO_LAYER
-        # the attention kind, decided after both (PR 40), finds the budget
-        # exhausted and a kind that declined for size
-        assert stash_kinds(p) == {"experts"}
-        assert stash_plan(p) == {**idle, "experts": (1, experts)}
-        assert stash_names(p) == (
-            "moe_gate", "moe_up", "moe_down", "moe_order", "moe_inverse",
-            "moe_sizes", "moe_experts")
+        # the attention kind is taken FIRST (PR 61; last since PR 40, when
+        # the experts took the budget and the two others rode nowhere): the
+        # experts, all layers or none, no longer fit what it leaves and take
+        # nothing; the rule's outputs and the last MLP are judged on their own
+        assert experts + _OLMO_FLASH > budget
+        assert stash_kinds(p) == {"attention", "recurrent", "dense"}
+        assert stash_plan(p) == {**idle, "attention": (1, _OLMO_FLASH),
+                                 "recurrent": (3, 3 * _OLMO_LAYER),
+                                 "dense": (1, _OLMO_MLP)}
+        assert stash_names(p) == ("gated_delta_out",) + _FLASH_NAMES
     elif case == "experts_decline":
-        # experts over the budget take none of it
+        # experts over the budget take none of it and (PR 61) move no other
+        # kind's decision: the cell's own plan, its first MLP a ``moe`` layer
         p, experts = _with_moe(8)
         assert experts > remat_report(p)["stash_budget_bytes"]
-        assert stash_plan(p) == {**idle, "recurrent": (3, 3 * _OLMO_LAYER)}
-        assert stash_names(p) == ("gated_delta_out",)
+        assert stash_plan(p) == {**idle, "attention": (1, _OLMO_FLASH),
+                                 "recurrent": (3, 3 * _OLMO_LAYER),
+                                 "dense": (1, _OLMO_MLP)}
+        assert stash_names(p) == ("gated_delta_out",) + _FLASH_NAMES
     else:
         # nine mamba layers under "checkpoint": recurrent mixers that offer
         # nothing (no inner jax.checkpoint: a saved output would skip none
@@ -722,8 +748,8 @@ def recurrent_stash_line_test():
 
 # ---- the attention kind under checkpoint (PR 40): every flash layer's (out,
 # lse) rides the block's jax.checkpoint as named values where a query sees at
-# least 2,048 keys, decided LAST, from what experts and recurrent leave, and
-# not at all where one of them declined for size --------------------------------
+# least 2,048 keys; since PR 61 decided FIRST and on its own bytes (PR 40: last,
+# and not at all where an earlier kind declined for size) -----------------------
 
 _GRANITE = "train_granite_4_0_h_micro_long"
 _LAGUNA = "train_laguna_s_2_1_ep32_s8k"
@@ -805,31 +831,39 @@ def attention_saved_resolver_test(case):
         declines(p, shardlib.build_mesh(p, jax.devices()[:1]))
     elif case == "macro_batching":
         # three micro-batches hold three sets: the experts kind passes the
-        # budget and declines, and the attention kind with it
-        declines(_cell_params("train_olmoe_1b_7b_s4k", macro_batching=3))
-        # alone it still fits, three times the bytes
+        # budget and takes none of it; the attention kind is judged on its own
+        p = _cell_params("train_olmoe_1b_7b_s4k", macro_batching=3)
+        assert stash_plan(p) == {**idle, "attention": (2, 3 * 68157440)}
+        assert saved_attention_keys(p) == 2048
+        # three times the bytes
         p = _cell_params(_GRANITE, macro_batching=3)
         assert stash_plan(p) == {**idle, "attention": (1, 3 * 34603008)}
     elif case == "earlier_kinds_exhaust_the_budget":
-        # experts at top-3 fit and leave 34.6 MB; the rule's 566 MB does not
-        # fit that and the layer's 127.8 MB would not either
+        # experts at top-3 alone would leave 34.6 MB, less than the layer's
+        # 127.8 MB: taken first, the one kind whose forward grows with the
+        # square of the sequence is never squeezed out by a cheaper one
         p, experts = _with_moe(3)
         assert 0 < remat_report(p)["stash_budget_bytes"] - experts \
             < _OLMO_FLASH
-        declines(p)
+        assert stash_plan(p)["attention"] == (1, _OLMO_FLASH)
+        assert stash_plan(p)["experts"] == (0, 0)
+        assert saved_attention_keys(p) == 2048
     elif case == "earlier_kind_declined_for_size":
         # Laguna: the experts kind has 5.4 GB to save and declined; the
-        # global layer's 204 MB alone would fit the whole budget
+        # global layer's 204 MB fit the budget twelve times over and (PR 61)
+        # ride: each kind is judged on its own bytes
         p = _cell_params(_LAGUNA)
         rep = remat_report(p)
         assert rep["experts_stash_bytes_per_device"] == 5375525008 \
             > rep["stash_budget_bytes"] \
             > rep["saved_attention_bytes_per_device"] == 204472320
-        declines(p)
-        assert stash_kinds(p) == frozenset() and stash_plan(p) == idle
-        assert _checkpoint_policy(p) is nothing
-        # ... and rides as soon as the experts' bytes fit (8 experts at top-1
-        # in a buffer of their own size), by the same rule
+        assert stash_kinds(p) == {"attention"}
+        assert stash_plan(p) == {**idle, "attention": (1, 204472320)}
+        assert stash_names(p) == _FLASH_NAMES
+        assert saved_attention_keys(p) == 2048
+        assert _checkpoint_policy(p) is not nothing
+        # ... beside the experts where their bytes fit too (8 experts at
+        # top-1 in a buffer of their own size)
         p = _cell_params(_LAGUNA, experts_held=0, experts=8, moe_top_k=1)
         assert stash_kinds(p) == {"experts", "attention"}
         assert stash_plan(p)["attention"] == (1, 204472320)
@@ -1064,14 +1098,14 @@ def dense_stash_resolver_test(case, chip_limit):
         assert stash_plan(p)["dense"] == (6, 6 * _GRANITE_MLP)
         assert saving(p) == [9, 11, 13, 15, 17, 19]
     elif case == "earlier_kind_declined_for_size":
-        # the experts kind over the budget: the step regenerates its buffers
-        # live inside each block's backward and has no room to hold more
+        # the experts kind over the budget takes none of it and (PR 61)
+        # moves no other kind's decision: the cell's last MLP, as in the cell
         p, experts = _with_moe(8)
         assert experts > budget
         assert stash_plan(p)["recurrent"] == (3, 3 * _OLMO_LAYER)
-        declines(p)
-        # Laguna: declined by the same test; its one ``mlp`` is an input
-        # block, which no region holds
+        assert stash_plan(p)["dense"] == (1, _OLMO_MLP)
+        assert saving(p) == [7]
+        # Laguna: its one ``mlp`` is an input block, which no region holds
         p = _cell_params(_LAGUNA)
         assert offers(p, "dense") == []
         declines(p)
@@ -1149,3 +1183,221 @@ def dense_stash_resolver_test(case, chip_limit):
 
 
 _SALA_MLP = 2 * 16384 * 16384 * 2
+
+
+# ---- one rule for the ``checkpoint`` strategy's kinds (PR 61): each judged on
+# its own bytes in the order attention, experts, recurrent, dense; the
+# recurrent kind, like the dense one, an execution at a time from the step's
+# end — an offer's first part, then the interior of those that ride ------------
+
+_KIMI = "train_kimi_linear_ep32_s16k"
+#: the Kimi-Linear cell's latent attention: out [1, 16384, 32, 128] bfloat16 +
+#: lse [32, 16384] float32
+_KIMI_FLASH = 16384 * 32 * (128 * 2 + 4)
+#: one ``kda`` layer's offer: the rule's output o [1, 16384, 32, 128] bfloat16
+#: and, where its rule is the Pallas pairs, the interior: q~, k~ the same,
+#: gamma the same in float32, A and the inverse [1, 256, 32, 64, 64] float32,
+#: A' the same in bfloat16, the entering states [1, 256, 32, 128, 128] bfloat16
+_KIMI_OUT = 16384 * 32 * 128 * 2
+_KIMI_INSIDE = 2 * _KIMI_OUT + 2 * _KIMI_OUT \
+    + 256 * 32 * 64 * 64 * (4 + 4 + 2) + 256 * 32 * 128 * 128 * 2
+#: its four sparse layers' row buffers and routing
+_KIMI_EXPERTS = 4569694352
+_KDA_INSIDE = ("kda_strict", "kda_mixed", "kda_gamma", "kda_q_unit",
+               "kda_k_unit", "kda_solved", "kda_states")
+
+
+@pytest.fixture
+def kda_kernels(monkeypatch):
+    """Layer ``kda`` reads its predicate as a TPU process does: its offer has
+    the interior of its Pallas pairs."""
+    from homebrewnlp_tpu.model import kda
+    from homebrewnlp_tpu.parallel.kda_rule import kda_kernel_applies
+    monkeypatch.setattr(kda, "kda_kernel_applies", functools.partial(
+        kda_kernel_applies, backend="tpu"))
+
+
+def _at_limit(monkeypatch, limit: int):
+    from homebrewnlp_tpu.utils import flops
+    monkeypatch.setattr(flops, "hbm_capacity",
+                        lambda device=None: (limit, "memory_stats"))
+
+
+@pytest.mark.parametrize("cell,plan", [
+    ("train_32big_mixer_b32", {}),
+    ("train_32big_mixer_dp2tp2", {"bottleneck": (32, 2147483648)}),
+    ("train_1b_long_context_s16k", {"attention": (8, 2155872256)}),
+    ("train_olmoe_1b_7b_s4k", {"attention": (2, 68157440),
+                               "experts": (2, 1075315200)}),
+    ("train_granite_4_0_h_micro_long", {"attention": (1, 34603008),
+                                        "dense": (6, 1610612736)}),
+    ("train_olmo_hybrid_7b_long", {"attention": (1, 127795200),
+                                   "recurrent": (3, 566231040),
+                                   "dense": (1, 721420288)}),
+    # the parent: nothing (the experts' 5.38 GB declined, and all with them)
+    ("train_laguna_s_2_1_ep32_s8k", {"attention": (1, 204472320)}),
+    ("train_zaya1_8b_ep2_s16k", {"attention": (8, 272629760),
+                                 "experts": (8, 1612185888)}),
+    ("train_minicpm_sala_tp2_long", {"attention": (1, 72351744),
+                                     "dense": (1, 1073741824)}),
+    ("train_ouro_2_6b_loop4_s4k", {"attention": (48, 1635778560)}),
+    ("train_nemotron_3_super_tp2_ep64_s16k", {"attention": (1, 68157440),
+                                              "experts": (5, 180224180)}),
+    # the parent: recurrent (4, 536870912), the four outputs, and nothing
+    # else (the experts' 4.57 GB declined); now the flash pair and, beside
+    # the four outputs, the LAST layer's interior of 1,140,850,688 bytes
+    (_KIMI, {"attention": (1, 136314880), "recurrent": (4, 1677721600)})])
+def every_cells_plan_test(cell, plan, monkeypatch, kda_kernels):
+    """``stash_plan`` of all twelve cells' configurations at the table's
+    16,911,433,728 bytes a chip: ten as the parent of PR 61 read them (ISSUE
+    61's table), Laguna's global flash pair and the Kimi-Linear cell's pair
+    and last ``kda`` interior as the one rule admits them."""
+    from benchmark.lib.cell import load_cell
+    from homebrewnlp_tpu.core import sharding as shardlib
+    from homebrewnlp_tpu.model.remat import stash_plan
+    _at_limit(monkeypatch, _CHIP_LIMITS[0])
+    p = _cell_params(cell)
+    mesh = None
+    if load_cell(cell).chips > 1:
+        if len(jax.devices()) < 4:
+            pytest.skip("needs 4 virtual devices")
+        mesh = shardlib.build_mesh(p, jax.devices()[:4])
+    assert stash_plan(p, mesh) == {**_IDLE, **plan}
+
+
+def _holding(p, name: str) -> typing.List[int]:
+    """The regions whose policy saves ``name``."""
+    from homebrewnlp_tpu.model.blocks import (_named_policy,
+                                              _region_policies)
+    from homebrewnlp_tpu.model.remat import region_names
+    names, policies = region_names(p), _region_policies(p)
+    assert len(names) == len(policies) \
+        == len(p.block_config) * p.depth * p.loop_steps
+    assert all(policy is _named_policy(p.gradient_checkpointing_policy, held)
+               for policy, held in zip(policies, names))
+    return [r for r, held in enumerate(names) if name in held]
+
+
+@pytest.mark.parametrize("case", [
+    "no_output", "two_outputs", "no_interior", "one_interior",
+    "two_interiors", "all", "off_the_tpu", "dense_takes_what_is_left",
+    "scan_layers", "stash", "recompute"])
+def recurrent_admission_test(case, monkeypatch, kda_kernels):
+    """The recurrent kind an execution at a time from the step's LAST
+    backwards (the Kimi-Linear cell's ``kda`` blocks are regions 0, 2, 4 and
+    8 of ten): the offers' first part — none, some and all of the rule's
+    outputs as the chip grows, never an earlier one before a later — then the
+    interior of the executions that ride, likewise; no part of a part; all or
+    none under ``scan_layers``; every execution under ``"stash"``."""
+    from homebrewnlp_tpu.model import kda
+    from homebrewnlp_tpu.model.declare import offers
+    from homebrewnlp_tpu.model.remat import (STASH_HBM_FRACTION, region_names,
+                                             stash_kinds, stash_plan)
+
+    def limit_for(nbytes: int) -> int:
+        return int((nbytes + 1000) / STASH_HBM_FRACTION) + 7
+
+    def rides(outputs: int, interiors: int, first: int, inside: int):
+        assert stash_plan(p)["recurrent"] == (
+            outputs, outputs * _KIMI_OUT + interiors * _KIMI_INSIDE)
+        assert _holding(p, "kda_out") == list(range(first, 10))
+        for name in _KDA_INSIDE:
+            assert _holding(p, name) == list(range(inside, 10))
+
+    p = _cell_params(_KIMI)
+    if case not in ("off_the_tpu", "scan_layers"):
+        offered = offers(p, "recurrent")
+        assert [(o.names, o.nbytes, o.interior_names, o.interior_nbytes)
+                for o in offered] == [(("kda_out",), _KIMI_OUT, _KDA_INSIDE,
+                                       _KIMI_INSIDE)] * 4
+        assert _KIMI_OUT + _KIMI_INSIDE == 1275068416
+        assert ("kda_out",) + _KDA_INSIDE == kda.SAVED_NAMES
+    if case == "no_output":
+        # an output less a byte beside the flash pair: no part of a part
+        _at_limit(monkeypatch, limit_for(_KIMI_FLASH + _KIMI_OUT - 2000))
+        assert stash_kinds(p) == {"attention"}
+        assert stash_plan(p) == {**_IDLE, "attention": (1, _KIMI_FLASH)}
+        rides(0, 0, 10, 10)
+        assert _holding(p, "flash_out") == list(range(10))
+    elif case == "two_outputs":
+        _at_limit(monkeypatch, limit_for(_KIMI_FLASH + 2 * _KIMI_OUT))
+        rides(2, 0, 4, 10)
+    elif case == "no_interior":
+        # the four outputs and an interior less a byte
+        _at_limit(monkeypatch, limit_for(_KIMI_FLASH + 4 * _KIMI_OUT
+                                         + _KIMI_INSIDE - 2000))
+        rides(4, 0, 0, 10)
+        assert stash_kinds(p) == {"attention", "recurrent"}
+    elif case == "one_interior":
+        # the chip as it is: from the last ``kda`` block on (a block without
+        # the layer names nothing by them)
+        _at_limit(monkeypatch, _CHIP_LIMITS[0])
+        rides(4, 1, 0, 8)
+        assert stash_plan(p) == {**_IDLE, "attention": (1, _KIMI_FLASH),
+                                 "recurrent": (4, 1677721600)}
+        assert region_names(p)[7] == ("kda_out",) + _FLASH_NAMES
+        assert region_names(p)[9] == ("kda_out",) + _KDA_INSIDE \
+            + _FLASH_NAMES
+    elif case == "two_interiors":
+        _at_limit(monkeypatch, limit_for(_KIMI_FLASH + 4 * _KIMI_OUT
+                                         + 2 * _KIMI_INSIDE))
+        rides(4, 2, 0, 4)
+    elif case == "all":
+        # (a chip that holds all four holds the experts' row buffers, decided
+        # before them, too)
+        _at_limit(monkeypatch, limit_for(
+            _KIMI_FLASH + _KIMI_EXPERTS + 4 * (_KIMI_OUT + _KIMI_INSIDE)))
+        rides(4, 4, 0, 0)
+        assert stash_plan(p)["experts"] == (4, _KIMI_EXPERTS)
+        assert region_names(p)[0] == _MOE_NAMES + ("kda_out",) \
+            + _KDA_INSIDE + _FLASH_NAMES
+    elif case == "off_the_tpu":
+        # the XLA form's offer has no interior: the four outputs, and the
+        # MLP's gate and up [1, 16384, 9216] x 2 after the ten block inputs
+        # [1, 16384, 2304]
+        monkeypatch.undo()
+        _at_limit(monkeypatch, _CHIP_LIMITS[0])
+        assert [(o.names, o.nbytes, o.interior_names, o.interior_nbytes)
+                for o in offers(p, "recurrent")] \
+            == [(("kda_out",), _KIMI_OUT, (), 0)] * 4
+        assert stash_plan(p) == {**_IDLE, "attention": (1, _KIMI_FLASH),
+                                 "recurrent": (4, 4 * _KIMI_OUT),
+                                 "dense": (1, 2 * 16384 * 9216 * 2)}
+        assert _holding(p, "kda_out") == list(range(10))
+        assert _holding(p, "mlp_gate") == [1]
+    elif case == "dense_takes_what_is_left":
+        # decided after it, from what it took: while an interior more fits,
+        # the ten block inputs and the MLP's gate and up (1.36 GB) do not; a
+        # chip whose 15% holds every earlier kind whole, the inputs and the
+        # MLP admits it
+        inputs = 10 * 16384 * 2304 * 2
+        mlp = 2 * 16384 * 9216 * 2
+        assert _KIMI_INSIDE < inputs + mlp
+        for short, dense in ((2000, (0, 0)), (0, (1, mlp))):
+            _at_limit(monkeypatch, limit_for(
+                _KIMI_FLASH + _KIMI_EXPERTS + 4 * (_KIMI_OUT + _KIMI_INSIDE)
+                + inputs + mlp - short))
+            rides(4, 4, 0, 0)
+            assert stash_plan(p)["dense"] == dense
+        assert _holding(p, "mlp_gate") == [1]
+    elif case == "scan_layers":
+        # a scanned body traces ONE block for all its iterations: Olmo-Hybrid
+        # at its published depth offers 24 outputs, 4.5 GB — unrolled the
+        # last eight ride, scanned none; at an eighth of the sequence all
+        p = _cell_params(_OLMO, depth=8)
+        assert stash_plan(p)["recurrent"] == (8, 8 * _OLMO_LAYER)
+        p = _cell_params(_OLMO, depth=8, scan_layers=True)
+        assert stash_plan(p)["recurrent"] == (0, 0)
+        assert _holding(p, "gated_delta_out") == []
+        p = _cell_params(_OLMO, depth=8, scan_layers=True,
+                         sequence_length=2048)
+        assert stash_plan(p)["recurrent"] == (24, 24 * _OLMO_LAYER // 8)
+        assert _holding(p, "gated_delta_out") == list(range(64))
+    elif case == "stash":
+        # explicit: every execution and its interior, whatever the bytes
+        _at_limit(monkeypatch, limit_for(0))
+        p = _cell_params(_KIMI, remat_policy="stash")
+        rides(4, 4, 0, 0)
+    else:
+        p = _cell_params(_KIMI, remat_policy="recompute")
+        assert stash_plan(p) == _IDLE and region_names(p) == [()] * 10
