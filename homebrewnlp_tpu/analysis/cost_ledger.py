@@ -92,8 +92,11 @@ _CCA_PARTS = frozenset(("in_proj", "qk_mean", "conv", "qk_norm", "rope",
 _ATTENTION_PARTS = frozenset(("gate", "q_proj", "kv_down", "kv_norm", "kv_up",
                               "out_proj"))
 #: the steps of attention flag ``sparse`` (model/sparse.py; ``attend`` holds
-#: the selected kernels) below ``body/attention/sparse_attention``
-_SPARSE_PARTS = frozenset(("compress", "index", "select", "attend"))
+#: the selected kernels) and of flag ``indexed`` (model/indexer.py: ``index``,
+#: ``select``, ``attend`` and its own ``index_loss``) below
+#: ``body/attention/sparse_attention``
+_SPARSE_PARTS = frozenset(("compress", "index", "select", "attend",
+                           "index_loss"))
 #: the parts of layer ``lightning`` (model/lightning.py) below
 #: ``body/lightning``; the rule's own steps (``intra_chunk``,
 #: ``chunk_states``, ``inter_chunk``, ``state_out``) stay inside
@@ -141,7 +144,8 @@ def scope_key(path: str) -> str:
     latent_up``,
     ``body/moe/router/down|carry|mlp``, ``body/attention/gate|q_proj|kv_down|
     kv_norm|kv_up|out_proj``,
-    ``body/attention/sparse_attention/compress|index|select|attend``,
+    ``body/attention/sparse_attention/compress|index|select|attend|
+    index_loss``,
     ``body/lightning/in_proj|qk_norm|rope|rule|gate_norm|out_proj``,
     ``body/cca/in_proj|qk_mean|conv|qk_norm|rope|value_shift|out_proj``,
     ``body/merge``,

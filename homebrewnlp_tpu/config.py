@@ -308,6 +308,14 @@ class ModelParameter:
         self.sparse_init_blocks = 1
         self.sparse_window = 2048
         self.sparse_dense_length = 8192
+        # attention flag "indexed" (token-level sparse attention with a
+        # learned indexer, model/indexer.py; Keye-VL-2.0's sa_config): the
+        # indexer's heads, the features of an index query and of the one
+        # index key, and the single keys a query keeps (up to so many keys
+        # the layer is dense)
+        self.index_heads = 16
+        self.index_features = 64
+        self.index_topk = 2048
         # the eps of layer "norm" and of gated_delta's gated norm (a
         # published config's rms_norm_eps / layer_norm_eps)
         self.norm_epsilon = 1e-5
@@ -996,7 +1004,8 @@ class ModelParameter:
                     "lightning_chunk", "lightning_norm_groups",
                     "sparse_kernel_size",
                     "sparse_kernel_stride", "sparse_block_size",
-                    "sparse_topk"):
+                    "sparse_topk", "index_heads", "index_features",
+                    "index_topk"):
             if not isinstance(getattr(self, key), int) \
                     or getattr(self, key) < 1:
                 raise ValueError(f"{key} {getattr(self, key)!r} must be a "
@@ -1032,6 +1041,9 @@ class ModelParameter:
                 "sparse_*: sparse_kernel_stride divides sparse_kernel_size "
                 "and sparse_block_size, sparse_block_size divides "
                 "sparse_window")
+        if self.index_features % 2:
+            raise ValueError(f"index_features {self.index_features}: rotary "
+                             "positions turn pairs of features")
         for key in ("delta_conv_size", "kda_conv_size"):
             if not isinstance(getattr(self, key), int) \
                     or not 1 <= getattr(self, key) <= 128:

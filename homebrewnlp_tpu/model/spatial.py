@@ -410,14 +410,14 @@ def rotary(x, theta: float, width: typing.Optional[int] = None,
 #: enter), the plain ones, and the ones that carry a whole number
 _STANDARD_POSITION = ("rope", "nope", "yarn")
 _STANDARD_PLAIN = ("qk_norm", "qk_norm_head", "gate", "gate_features",
-                   "sparse")
+                   "sparse", "indexed")
 _STANDARD_NUMBERED = ("q_heads", "kv_heads", "window", "rotary_pct", "theta",
                       "kv_latent", "shared_key")
 #: what the latent form (``kv_latent<n>``) does not build, each refused by
 #: name
 _LATENT_REFUSES = ("rope", "yarn", "rotary_pct", "theta", "qk_norm",
                    "qk_norm_head", "gate", "gate_features", "sparse",
-                   "window")
+                   "indexed", "window")
 
 
 def numbered_flags(extras, plain, numbered, what: str
@@ -459,7 +459,8 @@ def _standard_flags(extras) -> typing.Dict[str, typing.Any]:
     if "nope" in out and any(f in out for f in ("rotary_pct", "theta")):
         raise ValueError("nope (no rotary positions) with rotary_pct / theta")
     for one, other in (("qk_norm", "qk_norm_head"), ("gate", "gate_features"),
-                       ("sparse", "window")):
+                       ("sparse", "window"), ("indexed", "window"),
+                       ("sparse", "indexed")):
         if one in out and other in out:
             raise ValueError(f"the standard attention takes {one} or "
                              f"{other}, not both")
@@ -472,7 +473,7 @@ def _standard_flags(extras) -> typing.Dict[str, typing.Any]:
                 raise ValueError(
                     f"latent attention (kv_latent<n>) does not build {flag}: "
                     "no rotary on the shared key part (nope only), no query "
-                    "latent, norm, gate, sparse choice or window")
+                    "latent, norm, gate, sparse or indexed choice or window")
         if out.get("q_heads") != out.get("kv_heads"):
             raise ValueError("latent attention expands the latent to a key "
                              "and a value a query head: kv_heads = q_heads")
@@ -524,6 +525,13 @@ def causal_heads(ctx, params, q, k, v, group: int, scale: float,
         return _xla_reference(q, k, v, scale, True, window)
 
 
+def _one_device(ctx, flag: str) -> None:
+    """A choice of keys is made on one device: ``flag`` refuses a mesh."""
+    if ctx.mesh is not None and ctx.mesh.size > 1:
+        raise NotImplementedError(f"attention flag {flag} on a mesh: the "
+                                  "choice of keys is made on one device")
+
+
 def sparse_heads(ctx, params, q, k, v, scale: float):
     """Attention flag ``sparse`` on ``q [lead, seq, heads, f]`` and ``k``,
     ``v`` ``[lead, seq, kv heads, f]``: the plain causal attention up to
@@ -543,8 +551,7 @@ def sparse_heads(ctx, params, q, k, v, scale: float):
                  "sparse_choosing_query_share": jnp.float32(0.0)})
         return causal_heads(ctx, params, q, k, v, q.shape[2] // k.shape[2],
                             scale)
-    if ctx.mesh is not None and ctx.mesh.size > 1:
-        raise NotImplementedError("attention flag sparse on a mesh")
+    _one_device(ctx, "sparse")
     with jax.named_scope("sparse_attention"):
         keep = checkpoint_name(sparse.select_blocks(
             jax.lax.stop_gradient(q), jax.lax.stop_gradient(k), sizes,
@@ -556,6 +563,87 @@ def sparse_heads(ctx, params, q, k, v, scale: float):
         with jax.named_scope("attend"):
             return select_attention(q, k, v, keep, sizes.block, scale,
                                     stash=stash_channel(ctx, "attention"))
+
+
+def index_inputs(args: BlockArgs, feats, lead_dims, dim, theta: float):
+    """Attention flag ``indexed``'s own parameters, on ``stop_gradient`` of
+    the block's input: ``(qI [lead, seq, index_heads, index_features], kI
+    [lead, seq, index_features], w [lead, seq, index_heads] float32)`` of
+    model/indexer.py — a projection to the index queries, one to the single
+    index key under a LayerNorm (learned scale and shift, ``norm_epsilon``),
+    one to a weight a head times ``index_heads ** -0.5``; rotary at ``theta``
+    over all the index features of queries and key."""
+    import jax
+    import jax.numpy as jnp
+    from ..core.tensor import nt, transpose_to
+    from .normalization import norm
+    params = args.params
+    heads = Dim("index_heads", params.index_heads)
+    width = Dim("index_features", params.index_features)
+    x = nt(jax.lax.stop_gradient(args.tensor.data), args.tensor.dims)
+    qry = project(args, x, [heads, width], feats)
+    key = norm(args(project(args, x, [width], feats), ["scale", "shift"]),
+               [width])
+    weight = project(args, x, [heads], feats)
+    lead = 1
+    for d in lead_dims:
+        lead *= d.size
+
+    def flat(t, tail):
+        return transpose_to(t, lead_dims + [dim] + tail).data.reshape(
+            lead, dim.size, *(d.size for d in tail))
+
+    with jax.named_scope("rope"):
+        q_index = rotary(flat(qry, [heads, width]), theta)
+        k_index = rotary(flat(key, [width])[:, :, None], theta)[:, :, 0]
+    return q_index, k_index, flat(weight, [heads]).astype(jnp.float32) \
+        * params.index_heads ** -0.5
+
+
+def indexed_heads(ctx, params, q, k, v, scale: float, index):
+    """Attention flag ``indexed`` on ``q [lead, seq, heads, f]`` and ``k``,
+    ``v`` ``[lead, seq, kv heads, f]`` with ``index_inputs``' three: the
+    plain causal attention up to ``index_topk`` keys; past it the indexer's
+    choice of single keys, one for all heads (no gradient; named
+    ``SELECT_NAME``), and the selected kernels on it.  Either way the
+    indexer trains on its own loss (model/indexer.py ``index_loss`` /
+    ``inject``).  Reports the kept share of the visible keys, the share of
+    queries that chose, the index loss and the largest kept |score|."""
+    import jax
+    import jax.numpy as jnp
+    from jax.ad_checkpoint import checkpoint_name
+    from ..parallel.flash_attention import key_select_attention
+    from . import indexer
+    _one_device(ctx, "indexed")
+    detached = tuple(jax.lax.stop_gradient(x) for x in index)
+    stats = ctx.layer_stats is not None
+    keep = lse = None
+    with jax.named_scope("sparse_attention"):
+        if indexer.selects(params.index_topk, q.shape[1]):
+            keep = checkpoint_name(indexer.select_keys(
+                *detached, params.index_topk), SELECT_NAME)
+            with jax.named_scope("attend"):
+                out, lse = key_select_attention(
+                    q, k, v, keep, scale,
+                    stash=stash_channel(ctx, "attention"))
+    if keep is None:
+        out = causal_heads(ctx, params, q, k, v, q.shape[2] // k.shape[2],
+                           scale)
+    if not (params.train or stats):
+        return out
+    with jax.named_scope("sparse_attention"):
+        value, top, *grads = indexer.named_index_loss(
+            *detached, q, k, lse, keep, scale=scale)
+        if params.train:
+            out = indexer.inject(out, *index, *grads)
+        if stats:
+            share, chose = (jnp.float32(1.0), jnp.float32(0.0)) \
+                if keep is None else indexer.kept_shares(keep)
+            ctx.layer_stats.append({
+                "sparse_kept_key_share": share,
+                "sparse_choosing_query_share": chose,
+                "index_loss": value, "index_score_abs_max": top})
+    return out
 
 
 def _standard_attention(args: BlockArgs) -> NamedTensor:
@@ -594,7 +682,17 @@ def _standard_attention(args: BlockArgs) -> NamedTensor:
     ``sparse_attention``, then ``attend``: the ``flash_*_select`` kernels of
     parallel/flash_attention.py); the choice carries no gradient and is
     named (``SELECT_NAME``) beside ``(out, lse)``, so that where those are
-    saved a replay chooses nothing.  ``theta<t>`` replaces ``rope_theta`` for this layer,
+    saved a replay chooses nothing.  ``indexed``: past ``index_topk`` keys a
+    query attends the ``index_topk`` single keys that a learned indexer of
+    its own parameters (``index_inputs``: ``index_heads`` index queries of
+    ``index_features``, one index key, a weight a head, all from
+    ``stop_gradient`` of the block's input; model/indexer.py) scores highest,
+    ONE choice for all the layer's heads (steps ``index``, ``select``,
+    ``attend``, ``index_loss`` under scope ``sparse_attention``; the
+    ``flash_*_select`` kernels' key-at-a-time form); the choice carries no
+    gradient and is named like ``sparse``'s; the indexer learns from a KL
+    loss to the attention's own head-mean probabilities, whose gradient
+    reaches only it.  ``theta<t>`` replaces ``rope_theta`` for this layer,
     ``rotary_pct<p>`` turns only the first ``p`` percent of each head's
     features (HF's ``partial_rotary_factor``; an even count), and
     ``yarn`` is ``rope`` at YaRN's frequencies (``rope_yarn_factor``,
@@ -668,6 +766,10 @@ def _standard_attention(args: BlockArgs) -> NamedTensor:
         qry = norm(args(qry, ["rms", "scale"]), q_feats[1:])
         key = norm(args(key, ["rms", "scale"]), kv_feats[1:])
     lead_dims = [d for d in args.tensor.dims if d not in [dim] + feats]
+    if "indexed" in flags:
+        with jax.named_scope("sparse_attention"), jax.named_scope("index"):
+            index = index_inputs(args, feats, lead_dims, dim, float(
+                flags.get("theta", params.rope_theta)))
     canonical = lead_dims + [dim] + q_feats
     lead = 1
     for d in lead_dims:
@@ -698,6 +800,8 @@ def _standard_attention(args: BlockArgs) -> NamedTensor:
     scale = params.attention_scale or params.key_dim.size ** -0.5
     if "sparse" in flags:
         out = sparse_heads(ctx, params, q, k, v, scale)
+    elif "indexed" in flags:
+        out = indexed_heads(ctx, params, q, k, v, scale, index)
     else:
         out = causal_heads(ctx, params, q, k, v, group, scale,
                            flags.get("window"))
@@ -817,6 +921,19 @@ def _offer(params, extras) -> typing.Optional[Offer]:
                 names=SAVED_NAMES + (SELECT_NAME,),
                 nbytes=offer.nbytes + kv * params.batch_dim.size * seq
                 * (seq // params.sparse_block_size))
+        if "indexed" in flags:
+            # the index loss's value and three gradients; past index_topk
+            # keys the choice too: a bit a query and a key
+            from .indexer import INDEX_LOSS_NAMES
+            rows = params.batch_dim.size * seq
+            offer = offer._replace(
+                names=offer.names + INDEX_LOSS_NAMES,
+                nbytes=offer.nbytes + 4 * (1 + rows * (
+                    params.index_heads * (params.index_features + 1)
+                    + params.index_features)))
+            if seq > params.index_topk:
+                offer = offer._replace(names=offer.names + (SELECT_NAME,),
+                                       nbytes=offer.nbytes + rows * seq // 8)
         return offer
     if "dot_product" not in extras or any(f in extras for f in _DENSE_ONLY) \
             or not any(f in extras for f in ("embedded", "context",
@@ -971,5 +1088,14 @@ attention.declares = Layer(
                 "hbnlp_sparse_choosing_query_share",
                 "share of a sparse attention layer's queries that left a "
                 "visible block out, newest finished step, the layer where it "
-                "is largest", "max")),
+                "is largest", "max"),
+           Stat("index_loss", "gauge", "hbnlp_index_loss",
+                "attention flag indexed: the indexer's KL loss to the "
+                "attention's head-mean probabilities over the kept keys, "
+                "nat, mean over the layers of the newest finished step",
+                lambda stats, done: stats["index_loss"].mean()),
+           Stat("index_score_abs_max", "gauge", "hbnlp_index_score_abs_max",
+                "attention flag indexed: the largest |index score| among the "
+                "kept (query, key) pairs, newest finished step, the layer "
+                "where it is largest", "max")),
     offer=_offer, facts=FACTS)

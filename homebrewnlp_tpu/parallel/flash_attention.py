@@ -1381,6 +1381,13 @@ def attention(q, k, v, scale: typing.Optional[float] = None,
 # as well as its matmuls.  K and V come a K/V HEAD each (index ``head //
 # group``), never repeated; the dk/dv pass writes a query head's part, the
 # caller sums a group's.
+#
+# A second form keeps single KEYS (``block`` 1; model/indexer.py makes that
+# choice, one for ALL the query heads of a layer): ``keep [b, 1, s / 32, s]``
+# int32, bit ``r`` of word ``[i, u]`` saying whether query ``32 i + r`` kept
+# key ``u`` — a bit a pair.  A cell reads its ``[tile / 32, tile]`` window of
+# the words directly and shifts each row of words out over its 32 queries (no
+# widening matmul); the tables of live tiles are made from the same words.
 
 #: the name of a sparse layer's choice, beside ``SAVED_NAMES``: saved with
 #: ``(out, lse)`` wherever those are, so that a replay chooses nothing
@@ -1392,19 +1399,54 @@ _SELECT_TILE = 512
 _KEEP_LANES = 128
 
 
+#: queries a word of the key-at-a-time choice holds a bit each
+KEEP_WORD = 32
+
+
 def select_tile(s: int, block: int) -> int:
     """Tile of the selected kernels: ``kernel_block`` under ``_SELECT_TILE``,
-    in whole blocks."""
+    in whole blocks (``block`` 1, the key-at-a-time form: in whole words of
+    ``KEEP_WORD`` queries)."""
     tile = kernel_block(s, cap=_SELECT_TILE)
+    if block == 1:
+        if tile % KEEP_WORD:
+            raise ValueError(
+                f"the key-at-a-time selected kernels' tile of {tile} queries "
+                f"holds no whole words of {KEEP_WORD}")
+        return tile
     if tile % block or _KEEP_LANES % (tile // block):
-        raise ValueError(f"a selected kernel's tile of {tile} keys holds no "
-                         f"whole power-of-two number of blocks of {block}")
+        raise ValueError(
+            f"a selected kernel's tile of {tile} keys holds no whole "
+            f"power-of-two number of blocks of {block}: the forms are blocks "
+            f"of keys (a power of two of them a tile, at most {_KEEP_LANES}) "
+            "and single keys (block 1, the choice as bits)")
     return tile
 
 
+def pack_keep(keep):
+    """``keep [.., n, s]`` bool -> ``[.., n / KEEP_WORD, s]`` int32: bit ``r``
+    of word ``[i, u]`` is ``keep[KEEP_WORD i + r, u]``."""
+    *lead, n, s = keep.shape
+    bits = keep.reshape(*lead, n // KEEP_WORD, KEEP_WORD, s).astype(
+        jnp.uint32) << jnp.arange(KEEP_WORD, dtype=jnp.uint32)[:, None]
+    return jax.lax.bitcast_convert_type(jnp.sum(bits, axis=-2,
+                                                dtype=jnp.uint32), jnp.int32)
+
+
+def unpack_keep(words):
+    """``pack_keep``'s inverse: ``[.., n / KEEP_WORD, s]`` int32 -> ``[.., n,
+    s]`` bool."""
+    *lead, rows, s = words.shape
+    bits = (words[..., :, None, :] >> jnp.arange(
+        KEEP_WORD, dtype=jnp.int32)[:, None]) & 1
+    return bits.reshape(*lead, rows * KEEP_WORD, s) != 0
+
+
 def _keep_mask(keep, block: int, s: int):
-    """``keep [b, g, s, s / block]`` as pairs ``[b, g, s, s]``, causal."""
-    pairs = jnp.repeat(keep, block, axis=-1)[..., :s]
+    """``keep [b, g, s, s / block]`` (``block`` 1: the packed words) as
+    pairs ``[b, g, s, s]``, causal."""
+    pairs = unpack_keep(keep) if block == 1 \
+        else jnp.repeat(keep, block, axis=-1)[..., :s]
     return pairs & (jnp.arange(s)[:, None] >= jnp.arange(s)[None, :])
 
 
@@ -1433,11 +1475,19 @@ def _select_tables(keep, tile: int, block: int):
     some row of the q tile kept a block of it (and it is not above the
     diagonal), else the last such tile before it (the first one, before
     any): a repeated index fetches nothing.  ``fetch_q`` the same for the
-    k-outer grid, ``[.., nk, nq]``."""
-    b, g, s, nb = keep.shape
-    per = tile // block
-    nt = s // tile
-    live = keep.reshape(b * g, nt, tile, nt, per).any(axis=(2, 4))
+    k-outer grid, ``[.., nk, nq]``.  ``block`` 1: ``keep`` is the packed
+    words ``[b, g, s / KEEP_WORD, s]`` and goes to the kernels as it is."""
+    b, g = keep.shape[:2]
+    if block == 1:
+        s = keep.shape[3]
+        nt = s // tile
+        live = (keep.reshape(b * g, nt, tile // KEEP_WORD, nt, tile)
+                != 0).any(axis=(2, 4))
+    else:
+        s, nb = keep.shape[2:]
+        per = tile // block
+        nt = s // tile
+        live = keep.reshape(b * g, nt, tile, nt, per).any(axis=(2, 4))
     live &= jnp.arange(nt)[:, None] >= jnp.arange(nt)[None, :]
 
     def fetch(alive):
@@ -1446,6 +1496,9 @@ def _select_tables(keep, tile: int, block: int):
         first = jnp.argmax(alive, axis=2).astype(jnp.int32)[..., None]
         return jnp.where(last >= 0, last, first).reshape(-1)
 
+    if block == 1:
+        return keep.reshape(b * g, s // KEEP_WORD, s), fetch(live), \
+            fetch(jnp.swapaxes(live, 1, 2))
     lanes = -(-nb // _KEEP_LANES) * _KEEP_LANES
     rows = jnp.pad(keep.reshape(b * g, s, nb).astype(jnp.bfloat16),
                    ((0, 0), (0, 0), (0, lanes - nb)))
@@ -1455,22 +1508,38 @@ def _select_tables(keep, tile: int, block: int):
 def _select_seen(keep_ref, qi, ki, tile: int, block: int):
     """The pairs ``[tile, tile]`` of q tile ``qi`` and k tile ``ki`` a row
     kept and may see: the window of the rows' choice times the 0/1 matrix
-    that repeats a block's lane over its keys, and the diagonal."""
-    per = tile // block
-    first = jax.lax.rem(ki * per, _KEEP_LANES)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (_KEEP_LANES, tile), 0)
-    key = jax.lax.broadcasted_iota(jnp.int32, (_KEEP_LANES, tile), 1)
-    widen = (lane == first + jax.lax.div(key, block)).astype(keep_ref.dtype)
-    kept = jax.lax.dot_general(keep_ref[...], widen, (((1,), (0,)), ((), ())),
-                               preferred_element_type=jnp.float32) > 0.5
+    that repeats a block's lane over its keys, and the diagonal.  ``block``
+    1: the cell's ``[tile / KEEP_WORD, tile]`` words, each row of them
+    shifted out over its ``KEEP_WORD`` queries."""
+    if block == 1:
+        bit = jax.lax.broadcasted_iota(jnp.int32, (KEEP_WORD, tile), 0)
+        words = keep_ref[...]
+        kept = jnp.concatenate([
+            (jnp.broadcast_to(words[r:r + 1], (KEEP_WORD, tile)) >> bit) & 1
+            for r in range(tile // KEEP_WORD)], axis=0) != 0
+    else:
+        kept = _widened(keep_ref, ki, tile, block)
     q_pos = qi * tile + jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
     k_pos = ki * tile + jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
     return kept & (q_pos >= k_pos)
 
 
+def _widened(keep_ref, ki, tile: int, block: int):
+    """The block form's ``[tile, tile]`` pairs a row kept: its window of the
+    rows' choice, a block's lane repeated over its keys."""
+    per = tile // block
+    first = jax.lax.rem(ki * per, _KEEP_LANES)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (_KEEP_LANES, tile), 0)
+    key = jax.lax.broadcasted_iota(jnp.int32, (_KEEP_LANES, tile), 1)
+    widen = (lane == first + jax.lax.div(key, block)).astype(keep_ref.dtype)
+    return jax.lax.dot_general(keep_ref[...], widen, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32) > 0.5
+
+
 def _select_fwd_kernel(fetch_ref, q_ref, k_ref, v_ref, keep_ref, o_ref,
                        lse_ref, m_ref, l_ref, acc_ref, *, tile: int,
-                       block: int, num: int, group: int, scale: float):
+                       block: int, num: int, group: int, keep_group: int,
+                       scale: float):
     """Grid (batch * heads, q tiles, k tiles), k innermost: ``_flash_kernel``
     over the tiles some row of the q tile kept, each pair under the rows'
     own mask."""
@@ -1484,7 +1553,7 @@ def _select_fwd_kernel(fetch_ref, q_ref, k_ref, v_ref, keep_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(fetch_ref[((i // group) * num + qi) * num + kk] == kk)
+    @pl.when(fetch_ref[((i // keep_group) * num + qi) * num + kk] == kk)
     def _step():
         seen = _select_seen(keep_ref, qi, kk, tile, block)
         s = jnp.where(seen, _make_score(q_ref, k_ref, scale)(), _NEG_INF)
@@ -1520,7 +1589,8 @@ def _select_pair(seen, q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, scale):
 
 def _select_dq_kernel(fetch_ref, q_ref, k_ref, v_ref, keep_ref, do_ref,
                       lse_ref, d_ref, dq_ref, acc_ref, *, tile: int,
-                      block: int, num: int, group: int, scale: float):
+                      block: int, num: int, group: int, keep_group: int,
+                      scale: float):
     """dq: the forward's grid and table."""
     from jax.experimental import pallas as pl
 
@@ -1530,7 +1600,7 @@ def _select_dq_kernel(fetch_ref, q_ref, k_ref, v_ref, keep_ref, do_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(fetch_ref[((i // group) * num + qi) * num + kk] == kk)
+    @pl.when(fetch_ref[((i // keep_group) * num + qi) * num + kk] == kk)
     def _step():
         _, ds = _select_pair(_select_seen(keep_ref, qi, kk, tile, block),
                              q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref,
@@ -1547,7 +1617,7 @@ def _select_dq_kernel(fetch_ref, q_ref, k_ref, v_ref, keep_ref, do_ref,
 def _select_dkv_kernel(fetch_ref, q_ref, k_ref, v_ref, keep_ref, do_ref,
                        lse_ref, d_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
                        tile: int, block: int, num: int, group: int,
-                       scale: float):
+                       keep_group: int, scale: float):
     """dk/dv of ONE query head: grid (batch * heads, k tiles, q tiles), q
     innermost, over the q tiles a row of which kept a block of the k tile."""
     from jax.experimental import pallas as pl
@@ -1559,7 +1629,7 @@ def _select_dkv_kernel(fetch_ref, q_ref, k_ref, v_ref, keep_ref, do_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    @pl.when(fetch_ref[((i // group) * num + ki) * num + jj] == jj)
+    @pl.when(fetch_ref[((i // keep_group) * num + ki) * num + jj] == jj)
     def _step():
         p, ds = _select_pair(_select_seen(keep_ref, jj, ki, tile, block),
                              q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref,
@@ -1600,16 +1670,27 @@ def _select_call(kernel, name, grid, in_specs, out_specs, out_shape, scratch,
 
 
 def _select_specs(tile: int, block: int, d: int, num: int, group: int,
-                  k_outer: bool):
+                  k_outer: bool, keep_group: int):
     """``(q-side spec of width w, k-side spec, the rows' choice's spec)`` of
     a selected grid: the OUTER tile by its index, the inner one through the
     table.  ``k_outer``: grid (i, k tile, q step), else (i, q tile, k
-    step)."""
+    step).  ``keep_group``: the query heads that share one choice (the
+    block form: a K/V head's ``group``); ``block`` 1 reads the words' ``[tile
+    / KEEP_WORD, tile]`` window of the cell itself."""
     from jax.experimental import pallas as pl
     per = tile // block
 
     def inner(i, outer, step, fetch_ref):
-        return fetch_ref[((i // group) * num + outer) * num + step]
+        return fetch_ref[((i // keep_group) * num + outer) * num + step]
+
+    if block == 1:
+        def key_map(i, outer, step, fetch_ref):
+            q, k = (inner(i, outer, step, fetch_ref), outer) if k_outer \
+                else (outer, inner(i, outer, step, fetch_ref))
+            return (i // keep_group, q, k)
+        keep_spec = pl.BlockSpec((None, tile // KEEP_WORD, tile), key_map)
+    else:
+        keep_spec = None
 
     if k_outer:
         def q_map(i, ki, jj, fetch_ref):
@@ -1634,7 +1715,7 @@ def _select_specs(tile: int, block: int, d: int, num: int, group: int,
 
     return (lambda w: pl.BlockSpec((None, tile, w), q_map),
             pl.BlockSpec((None, tile, d), k_map),
-            pl.BlockSpec((None, tile, _KEEP_LANES), keep_map))
+            keep_spec or pl.BlockSpec((None, tile, _KEEP_LANES), keep_map))
 
 
 def _select_fwd_impl(q, k, v, keep, scale, block, interpret):
@@ -1642,14 +1723,16 @@ def _select_fwd_impl(q, k, v, keep, scale, block, interpret):
     from jax.experimental.pallas import tpu as pltpu
     b, s, h, d = q.shape
     group = h // k.shape[2]
+    keep_group = h // keep.shape[1]
     tile = select_tile(s, block)
     num = s // tile
     rows, fetch_k, _ = _select_tables(keep, tile, block)
     q_spec, k_spec, keep_spec = _select_specs(tile, block, d, num, group,
-                                              False)
+                                              False, keep_group)
     out, lse = _select_call(
         functools.partial(_select_fwd_kernel, tile=tile, block=block,
-                          num=num, group=group, scale=scale),
+                          num=num, group=group, keep_group=keep_group,
+                          scale=scale),
         "flash_fwd_select", (b * h, num, num),
         [q_spec(d), k_spec, k_spec, keep_spec], [q_spec(d), q_spec(1)],
         [jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
@@ -1670,6 +1753,7 @@ def _select_bwd_impl(q, k, v, keep, out, lse, dout, scale, block, interpret):
     b, s, h, d = q.shape
     g = k.shape[2]
     group = h // g
+    keep_group = h // keep.shape[1]
     tile = select_tile(s, block)
     num = s // tile
     rows, fetch_k, fetch_q = _select_tables(keep, tile, block)
@@ -1678,10 +1762,10 @@ def _select_bwd_impl(q, k, v, keep, out, lse, dout, scale, block, interpret):
                     -1, keepdims=True)
     lse3 = lse[..., None]
     q_spec, k_spec, keep_spec = _select_specs(tile, block, d, num, group,
-                                              False)
+                                              False, keep_group)
     dq = _select_call(
         functools.partial(_select_dq_kernel, tile=tile, block=block, num=num,
-                          group=group, scale=scale),
+                          group=group, keep_group=keep_group, scale=scale),
         "flash_bwd_dq_select", (b * h, num, num),
         [q_spec(d), k_spec, k_spec, keep_spec, q_spec(d), q_spec(1),
          q_spec(1)], q_spec(d),
@@ -1689,12 +1773,13 @@ def _select_bwd_impl(q, k, v, keep, out, lse, dout, scale, block, interpret):
         [pltpu.VMEM((tile, d), jnp.float32)],
         interpret, (fetch_k, qt, kt, vt, rows, dot, lse3, delta))
     q_spec, k_spec, keep_spec = _select_specs(tile, block, d, num, group,
-                                              True)
+                                              True, keep_group)
     own = pl.BlockSpec((None, tile, d), lambda i, ki, jj, fetch_ref:
                        (i, ki, 0))
     dk, dv = _select_call(
         functools.partial(_select_dkv_kernel, tile=tile, block=block,
-                          num=num, group=group, scale=scale),
+                          num=num, group=group, keep_group=keep_group,
+                          scale=scale),
         "flash_bwd_dkv_select", (b * h, num, num),
         [q_spec(d), k_spec, k_spec, keep_spec, q_spec(d), q_spec(1),
          q_spec(1)], [own, own],
@@ -1814,3 +1899,38 @@ def select_attention(q, k, v, keep, block: int,
             return _xla_select(q, k, v, keep, scale, block)
     with jax.named_scope("flash_attention"):
         return flash_select(q, k, v, keep, scale, block, interpret)
+
+
+def key_select_attention(q, k, v, keep, scale: float,
+                         stash: typing.Optional[dict] = None):
+    """Dispatch of a key-at-a-time selected call (the module comment's second
+    form): ``keep [b, 1, s / KEEP_WORD, s]`` int32, one choice for all of
+    ``q``'s heads -> ``(out [b, s, h, d], lse [b * h, s])``, ``lse`` without a
+    gradient (the caller's index loss reads it).  The forward runs ONCE, on
+    detached operands, and the differentiable value is the precomputed form
+    over it — the ``flash_*_select`` kernels at ``block`` 1 on a TPU at a
+    sequence of whole 256-tiles, the dense masked XLA form elsewhere.
+    ``stash``: under ``attention``'s "name" channel ``(out, lse)`` are named
+    (``SAVED_NAMES``; the caller names ``keep``), so a block's replay runs no
+    forward kernel."""
+    s = q.shape[1]
+    kernels = jax.default_backend() != "cpu" and s % 256 == 0
+    detached = tuple(jax.lax.stop_gradient(t) for t in (q, k, v))
+    if kernels:
+        with jax.named_scope("flash_attention"):
+            out_s, lse_s = _select_fwd_impl(*detached, keep, scale, 1, False)
+    else:
+        with jax.named_scope("attention_dense"):
+            out_s, lse_s = _xla_select_with_lse(*detached, keep, scale, 1)
+    if stash_naming(stash) and s >= stash.get("min_keys", 0):
+        out_s = checkpoint_name(out_s, SAVED_NAMES[0])
+        lse_s = checkpoint_name(lse_s, SAVED_NAMES[1])
+    if kernels:
+        with jax.named_scope("flash_attention"):
+            return flash_select_precomputed(q, k, v, keep, out_s, lse_s,
+                                            scale, 1, False), lse_s
+    # off the TPU the dense form is its own backward: the saved pair is the
+    # value, the gradient flows through the recomputed one
+    with jax.named_scope("attention_dense"):
+        live = _xla_select(q, k, v, keep, scale, 1)
+    return live + jax.lax.stop_gradient(out_s - live), lse_s

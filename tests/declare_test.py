@@ -237,8 +237,12 @@ def _config_files():
 #: ``attention 1 layers, 136314880`` with ``recurrent 4 layers, 1677721600``
 #: on a TPU and, on the CPU, the four outputs as before and ``dense 1 layers,
 #: 603979776``; the other 27 files as they were: before it
-#: 6fc52f37f21988967d830151219f98f18fbe5817)
-_FILE_DIGEST = "9ecdcf46a27f8dec5a5319d33aeb28b42ab64363"
+#: 6fc52f37f21988967d830151219f98f18fbe5817; PR 62 added the two Keye-VL-2.0
+#: files — the cell's reads ``attention 7 layers, 1695547420`` on both sides:
+#: a layer's ``(out, lse)``, its choice as bits and the index loss's
+#: gradients —: without them the digest is PR 61's
+#: 9ecdcf46a27f8dec5a5319d33aeb28b42ab64363, every other line as it was)
+_FILE_DIGEST = "71a59d30fb05fedc6feb56ee36b3684de7f15891"
 
 
 def every_configuration_file_starts_as_on_the_parent_test(monkeypatch):
@@ -284,6 +288,8 @@ _LAYER_STATS_IN = {
     "kda_log_decay_min": [-40.0, -144.0],
     "sparse_kept_key_share": [0.75, 0.5],
     "sparse_choosing_query_share": [0.5, 0.75],
+    "index_loss": [0.25, 0.75],
+    "index_score_abs_max": [3.0, 5.0],
     "lightning_state_abs_max": [4.0, 9.0]}
 
 
@@ -326,7 +332,12 @@ def _info(layer_stats):
     ("sparse_choosing_query_share", "gauge",
      "hbnlp_sparse_choosing_query_share", "62dca63d7d24", 0.75),
     ("lightning_state_abs_max", "gauge", "hbnlp_lightning_state_abs_max",
-     "49c68f690d74", 9.0)])
+     "49c68f690d74", 9.0),
+    # PR 62: attention flag indexed's own two (the MEAN over the layers, and
+    # the largest)
+    ("index_loss", "gauge", "hbnlp_index_loss", "f8211bece1a4", 0.5),
+    ("index_score_abs_max", "gauge", "hbnlp_index_score_abs_max",
+     "db2b64e379d3", 5.0)])
 def declared_statistic_folds_as_on_the_parent_test(name, kind, metric, text,
                                                    value):
     """One declared statistic: the parent's fold over the layers, its kind,
@@ -338,12 +349,13 @@ def declared_statistic_folds_as_on_the_parent_test(name, kind, metric, text,
 
 
 def statistics_are_all_declared_test():
-    """The trainer's table is the declarations': seventeen statistics of
+    """The trainer's table is the declarations': nineteen statistics of
     layers (PR 54: the selection bias's two; PR 58: layer ``kda``'s log-decay,
-    and its transform under ``gated_delta``'s name) and (PR 49) three of a
+    and its transform under ``gated_delta``'s name; PR 62: attention flag
+    ``indexed``'s index loss and largest kept score) and (PR 49) three of a
     looped model's loss, and a step whose layers report nothing (or only
     some) has only those."""
-    assert len(_LAYER_STATS) == 20 == len(declare.stats())
+    assert len(_LAYER_STATS) == 22 == len(declare.stats())
     assert {"moe_bias_abs_max", "moe_all_load_max_over_mean"} \
         <= set(_LAYER_STATS)
     assert {name for name in _LAYER_STATS if name.startswith("loop_")} == {
