@@ -1392,35 +1392,45 @@ def attention(q, k, v, scale: typing.Optional[float] = None,
 #: the name of a sparse layer's choice, beside ``SAVED_NAMES``: saved with
 #: ``(out, lse)`` wherever those are, so that a replay chooses nothing
 SELECT_NAME = "sparse_keep"
-#: the selected kernels' tile, q and k alike (the tables' diagonal is then
-#: always live: a row keeps its own block)
+#: the block form's tile, q and k alike (the tables' diagonal is then always
+#: live: a row keeps its own block); the key-at-a-time form's is twice that
+#: (``select_tile``)
 _SELECT_TILE = 512
 #: lanes of the window of the rows' choice a cell reads
 _KEEP_LANES = 128
+#: lanes of the selected forward's row statistics (a vreg's)
+_STAT_LANES = 128
 
 
 #: queries a word of the key-at-a-time choice holds a bit each
 KEEP_WORD = 32
 
 
-def select_tile(s: int, block: int) -> int:
-    """Tile of the selected kernels: ``kernel_block`` under ``_SELECT_TILE``,
-    in whole blocks (``block`` 1, the key-at-a-time form: in whole words of
-    ``KEEP_WORD`` queries)."""
-    tile = kernel_block(s, cap=_SELECT_TILE)
+def select_tile(s: int, block: int) -> typing.Tuple[int, int]:
+    """``(q tile, k tile)`` of the three selected kernels: ``kernel_block``
+    under ``_SELECT_TILE``, in whole blocks — the block form's tables skip
+    the tiles no row kept, and a wider tile skips fewer.  ``block`` 1, the
+    key-at-a-time form: whole words of ``KEEP_WORD`` queries, and twice the
+    tile both ways — scattered keys empty no tile of either size, and at
+    1,024 x 1,024 a call runs a quarter of the cells, grid steps and rescales
+    (PR 63, on the chip at 32 / 4 heads x 16,384 x 128: forward 20.2 -> 17.2
+    ms, dq 26.2 -> 23.0, dk/dv 37.0 -> 28.5; 512 x 1,024 and 1,024 x 2,048
+    lie between).  The kernels, tables and specs take any pair."""
     if block == 1:
+        tile = kernel_block(s, cap=2 * _SELECT_TILE)
         if tile % KEEP_WORD:
             raise ValueError(
                 f"the key-at-a-time selected kernels' tile of {tile} queries "
                 f"holds no whole words of {KEEP_WORD}")
-        return tile
+        return tile, tile
+    tile = kernel_block(s, cap=_SELECT_TILE)
     if tile % block or _KEEP_LANES % (tile // block):
         raise ValueError(
             f"a selected kernel's tile of {tile} keys holds no whole "
             f"power-of-two number of blocks of {block}: the forms are blocks "
             f"of keys (a power of two of them a tile, at most {_KEEP_LANES}) "
             "and single keys (block 1, the choice as bits)")
-    return tile
+    return tile, tile
 
 
 def pack_keep(keep):
@@ -1468,30 +1478,33 @@ def _xla_select_with_lse(q, k, v, keep, scale, block):
     return out.astype(q.dtype), (m + jnp.log(l)).reshape(b * h, s)
 
 
-def _select_tables(keep, tile: int, block: int):
+def _select_tables(keep, tq: int, tk: int, block: int):
     """``(keep_rows [b * g, s, lanes] in bfloat16, fetch_k, fetch_q)`` of a
-    choice ``keep [b, g, s, nb]``.  ``fetch_k [b * g * nq * nk]`` int32: for
-    q tile ``j`` and step ``kk`` the k tile to hold — ``kk`` itself where
-    some row of the q tile kept a block of it (and it is not above the
-    diagonal), else the last such tile before it (the first one, before
-    any): a repeated index fetches nothing.  ``fetch_q`` the same for the
-    k-outer grid, ``[.., nk, nq]``.  ``block`` 1: ``keep`` is the packed
-    words ``[b, g, s / KEEP_WORD, s]`` and goes to the kernels as it is."""
+    choice ``keep [b, g, s, nb]`` at q tiles of ``tq`` and k tiles of ``tk``.
+    ``fetch_k [b * g * nq * nk]`` int32: for q tile ``j`` and step ``kk`` the
+    k tile to hold — ``kk`` itself where some row of the q tile kept a block
+    of it (and not all of it is above the diagonal), else the last such tile
+    before it (the first one, before any): a repeated index fetches nothing.
+    ``fetch_q`` the same for the k-outer grid, ``[.., nk, nq]``.  ``block``
+    1: ``keep`` is the packed words ``[b, g, s / KEEP_WORD, s]`` and goes to
+    the kernels as it is."""
     b, g = keep.shape[:2]
     if block == 1:
         s = keep.shape[3]
-        nt = s // tile
-        live = (keep.reshape(b * g, nt, tile // KEEP_WORD, nt, tile)
+        nq, nk = s // tq, s // tk
+        live = (keep.reshape(b * g, nq, tq // KEEP_WORD, nk, tk)
                 != 0).any(axis=(2, 4))
     else:
         s, nb = keep.shape[2:]
-        per = tile // block
-        nt = s // tile
-        live = keep.reshape(b * g, nt, tile, nt, per).any(axis=(2, 4))
-    live &= jnp.arange(nt)[:, None] >= jnp.arange(nt)[None, :]
+        nq, nk = s // tq, s // tk
+        live = keep.reshape(b * g, nq, tq, nk, tk // block).any(axis=(2, 4))
+    # a k tile whose first key is past the q tile's last row is dead whatever
+    # the choice says
+    live &= (jnp.arange(nq) * tq + tq - 1)[:, None] \
+        >= (jnp.arange(nk) * tk)[None, :]
 
     def fetch(alive):
-        idx = jnp.arange(nt, dtype=jnp.int32)
+        idx = jnp.arange(alive.shape[2], dtype=jnp.int32)
         last = jax.lax.cummax(jnp.where(alive, idx, -1), axis=2)
         first = jnp.argmax(alive, axis=2).astype(jnp.int32)[..., None]
         return jnp.where(last >= 0, last, first).reshape(-1)
@@ -1505,27 +1518,31 @@ def _select_tables(keep, tile: int, block: int):
     return rows, fetch(live), fetch(jnp.swapaxes(live, 1, 2))
 
 
-def _select_seen(keep_ref, qi, ki, tile: int, block: int):
-    """The pairs ``[tile, tile]`` of q tile ``qi`` and k tile ``ki`` a row
-    kept and may see: the window of the rows' choice times the 0/1 matrix
-    that repeats a block's lane over its keys, and the diagonal.  ``block``
-    1: the cell's ``[tile / KEEP_WORD, tile]`` words, each row of them
-    shifted out over its ``KEEP_WORD`` queries."""
+def _select_kept(keep_ref, ki, tq: int, tk: int, block: int):
+    """The pairs ``[tq, tk]`` of a cell on k tile ``ki`` a row kept: the
+    window of the rows' choice times the 0/1 matrix that repeats a block's
+    lane over its keys.  ``block`` 1: the cell's ``[tq / KEEP_WORD, tk]``
+    words, each row of them shifted out over its ``KEEP_WORD`` queries."""
     if block == 1:
-        bit = jax.lax.broadcasted_iota(jnp.int32, (KEEP_WORD, tile), 0)
+        bit = jax.lax.broadcasted_iota(jnp.int32, (KEEP_WORD, tk), 0)
         words = keep_ref[...]
-        kept = jnp.concatenate([
-            (jnp.broadcast_to(words[r:r + 1], (KEEP_WORD, tile)) >> bit) & 1
-            for r in range(tile // KEEP_WORD)], axis=0) != 0
-    else:
-        kept = _widened(keep_ref, ki, tile, block)
-    q_pos = qi * tile + jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
-    k_pos = ki * tile + jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
+        return jnp.concatenate([
+            (jnp.broadcast_to(words[r:r + 1], (KEEP_WORD, tk)) >> bit) & 1
+            for r in range(tq // KEEP_WORD)], axis=0) != 0
+    return _widened(keep_ref, ki, tk, block)
+
+
+def _select_seen(keep_ref, qi, ki, tq: int, tk: int, block: int):
+    """``_select_kept`` and the diagonal: the pairs of q tile ``qi`` and k
+    tile ``ki`` a row kept and may see."""
+    kept = _select_kept(keep_ref, ki, tq, tk, block)
+    q_pos = qi * tq + jax.lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
+    k_pos = ki * tk + jax.lax.broadcasted_iota(jnp.int32, (1, tk), 1)
     return kept & (q_pos >= k_pos)
 
 
 def _widened(keep_ref, ki, tile: int, block: int):
-    """The block form's ``[tile, tile]`` pairs a row kept: its window of the
+    """The block form's ``[rows, tile]`` pairs a row kept: its window of the
     rows' choice, a block's lane repeated over its keys."""
     per = tile // block
     first = jax.lax.rem(ki * per, _KEEP_LANES)
@@ -1536,13 +1553,38 @@ def _widened(keep_ref, ki, tile: int, block: int):
                                preferred_element_type=jnp.float32) > 0.5
 
 
+def _select_live(fetch_ref, i, outer, step, num_outer: int, num_inner: int,
+                 keep_group: int):
+    """The table's entry of grid step ``(i, outer, step)``: the inner tile to
+    hold — ``step`` itself where the cell is live."""
+    return fetch_ref[((i // keep_group) * num_outer + outer) * num_inner
+                     + step]
+
+
+def _lanes(x, width: int):
+    """A row statistic ``[rows, _STAT_LANES]``, every lane of a row the same
+    value, over ``width`` lanes: whole copies of its vregs where ``width``
+    is whole lane tiles (none at ``_STAT_LANES``), else a broadcast."""
+    from jax.experimental.pallas import tpu as pltpu
+    if width % _STAT_LANES:
+        return jnp.broadcast_to(x[:, :1], (x.shape[0], width))
+    return pltpu.repeat(x, width // _STAT_LANES, axis=1)
+
+
 def _select_fwd_kernel(fetch_ref, q_ref, k_ref, v_ref, keep_ref, o_ref,
-                       lse_ref, m_ref, l_ref, acc_ref, *, tile: int,
-                       block: int, num: int, group: int, keep_group: int,
+                       lse_ref, m_ref, l_ref, acc_ref, *, tq: int, tk: int,
+                       block: int, num_q: int, num_k: int, keep_group: int,
                        scale: float):
     """Grid (batch * heads, q tiles, k tiles), k innermost: ``_flash_kernel``
     over the tiles some row of the q tile kept, each pair under the rows'
-    own mask."""
+    own mask.  The row statistics are ``[tq, _STAT_LANES]``, a row's value in
+    every lane, from the reduction to the rescale: the scores' tile and the
+    accumulator read them as whole vregs and nothing turns between lanes and
+    sublanes (as 1-D ``(tq,)`` scratch they cost 1.9 of a cell's 3.1 us, PR
+    63); a masked score is ``-inf`` under a FINITE first maximum, so a row
+    that has kept nothing yet reads ``p = 0`` with no second select.  Every
+    cell compares positions: a second body for the cells the diagonal does
+    not cross is 0.3% of the Keye step and 3 s of every set-up (PR 63)."""
     from jax.experimental import pallas as pl
 
     i, qi, kk = pl.program_id(0), pl.program_id(1), pl.program_id(2)
@@ -1553,27 +1595,28 @@ def _select_fwd_kernel(fetch_ref, q_ref, k_ref, v_ref, keep_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(fetch_ref[((i // keep_group) * num + qi) * num + kk] == kk)
+    @pl.when(_select_live(fetch_ref, i, qi, kk, num_q, num_k, keep_group)
+             == kk)
     def _step():
-        seen = _select_seen(keep_ref, qi, kk, tile, block)
-        s = jnp.where(seen, _make_score(q_ref, k_ref, scale)(), _NEG_INF)
+        seen = _select_seen(keep_ref, qi, kk, tq, tk, block)
+        s = jnp.where(seen, _make_score(q_ref, k_ref, scale)(), -jnp.inf)
         m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(-1))
+        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        # a row that kept nothing of this tile, and nothing before it, has
-        # m_new = _NEG_INF: exp(s - m_new) would read 1 on its masked pairs
-        p = jnp.where(seen, jnp.exp(s - m_new[:, None]), 0.0)
-        l_ref[...] = l_ref[...] * alpha + p.sum(-1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[...], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        p = jnp.exp(s - _lanes(m_new, tk))
+        l_ref[...] = l_ref[...] * alpha + p.sum(-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * _lanes(alpha, acc_ref.shape[-1]) \
+            + jax.lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[...], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
-    @pl.when(kk == num - 1)
+    @pl.when(kk == num_k - 1)
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[...] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[...] = (m_ref[...] + jnp.log(l))[:, None]
+        o_ref[...] = (acc_ref[...] / _lanes(l, acc_ref.shape[-1])
+                      ).astype(o_ref.dtype)
+        lse_ref[...] = (m_ref[...] + jnp.log(l))[:, :1]
 
 
 def _select_pair(seen, q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, scale):
@@ -1588,8 +1631,8 @@ def _select_pair(seen, q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, scale):
 
 
 def _select_dq_kernel(fetch_ref, q_ref, k_ref, v_ref, keep_ref, do_ref,
-                      lse_ref, d_ref, dq_ref, acc_ref, *, tile: int,
-                      block: int, num: int, group: int, keep_group: int,
+                      lse_ref, d_ref, dq_ref, acc_ref, *, tq: int, tk: int,
+                      block: int, num_q: int, num_k: int, keep_group: int,
                       scale: float):
     """dq: the forward's grid and table."""
     from jax.experimental import pallas as pl
@@ -1600,23 +1643,24 @@ def _select_dq_kernel(fetch_ref, q_ref, k_ref, v_ref, keep_ref, do_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(fetch_ref[((i // keep_group) * num + qi) * num + kk] == kk)
+    @pl.when(_select_live(fetch_ref, i, qi, kk, num_q, num_k, keep_group)
+             == kk)
     def _step():
-        _, ds = _select_pair(_select_seen(keep_ref, qi, kk, tile, block),
+        _, ds = _select_pair(_select_seen(keep_ref, qi, kk, tq, tk, block),
                              q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref,
                              scale)
         acc_ref[...] += jax.lax.dot_general(
             ds, k_ref[...], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(kk == num - 1)
+    @pl.when(kk == num_k - 1)
     def _finish():
         dq_ref[...] = acc_ref[...].astype(dq_ref.dtype)
 
 
 def _select_dkv_kernel(fetch_ref, q_ref, k_ref, v_ref, keep_ref, do_ref,
                        lse_ref, d_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
-                       tile: int, block: int, num: int, group: int,
+                       tq: int, tk: int, block: int, num_q: int, num_k: int,
                        keep_group: int, scale: float):
     """dk/dv of ONE query head: grid (batch * heads, k tiles, q tiles), q
     innermost, over the q tiles a row of which kept a block of the k tile."""
@@ -1629,9 +1673,10 @@ def _select_dkv_kernel(fetch_ref, q_ref, k_ref, v_ref, keep_ref, do_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    @pl.when(fetch_ref[((i // keep_group) * num + ki) * num + jj] == jj)
+    @pl.when(_select_live(fetch_ref, i, ki, jj, num_k, num_q, keep_group)
+             == jj)
     def _step():
-        p, ds = _select_pair(_select_seen(keep_ref, jj, ki, tile, block),
+        p, ds = _select_pair(_select_seen(keep_ref, jj, ki, tq, tk, block),
                              q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref,
                              scale)
         dk_acc[...] += jax.lax.dot_general(
@@ -1641,7 +1686,7 @@ def _select_dkv_kernel(fetch_ref, q_ref, k_ref, v_ref, keep_ref, do_ref,
             p.astype(do_ref.dtype), do_ref[...], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(jj == num - 1)
+    @pl.when(jj == num_q - 1)
     def _finish():
         dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
@@ -1669,26 +1714,27 @@ def _select_call(kernel, name, grid, in_specs, out_specs, out_shape, scratch,
         name=name, interpret=interpret)(*operands)
 
 
-def _select_specs(tile: int, block: int, d: int, num: int, group: int,
-                  k_outer: bool, keep_group: int):
+def _select_specs(tq: int, tk: int, block: int, d: int, num_q: int,
+                  num_k: int, group: int, k_outer: bool, keep_group: int):
     """``(q-side spec of width w, k-side spec, the rows' choice's spec)`` of
     a selected grid: the OUTER tile by its index, the inner one through the
     table.  ``k_outer``: grid (i, k tile, q step), else (i, q tile, k
     step).  ``keep_group``: the query heads that share one choice (the
-    block form: a K/V head's ``group``); ``block`` 1 reads the words' ``[tile
-    / KEEP_WORD, tile]`` window of the cell itself."""
+    block form: a K/V head's ``group``); ``block`` 1 reads the words' ``[tq
+    / KEEP_WORD, tk]`` window of the cell itself."""
     from jax.experimental import pallas as pl
-    per = tile // block
+    per = tk // block
+    nums = (num_k, num_q) if k_outer else (num_q, num_k)
 
     def inner(i, outer, step, fetch_ref):
-        return fetch_ref[((i // keep_group) * num + outer) * num + step]
+        return _select_live(fetch_ref, i, outer, step, *nums, keep_group)
 
     if block == 1:
         def key_map(i, outer, step, fetch_ref):
             q, k = (inner(i, outer, step, fetch_ref), outer) if k_outer \
                 else (outer, inner(i, outer, step, fetch_ref))
             return (i // keep_group, q, k)
-        keep_spec = pl.BlockSpec((None, tile // KEEP_WORD, tile), key_map)
+        keep_spec = pl.BlockSpec((None, tq // KEEP_WORD, tk), key_map)
     else:
         keep_spec = None
 
@@ -1713,9 +1759,9 @@ def _select_specs(tile: int, block: int, d: int, num: int, group: int,
             return (i // group, qi,
                     (inner(i, qi, kk, fetch_ref) * per) // _KEEP_LANES)
 
-    return (lambda w: pl.BlockSpec((None, tile, w), q_map),
-            pl.BlockSpec((None, tile, d), k_map),
-            keep_spec or pl.BlockSpec((None, tile, _KEEP_LANES), keep_map))
+    return (lambda w: pl.BlockSpec((None, tq, w), q_map),
+            pl.BlockSpec((None, tk, d), k_map),
+            keep_spec or pl.BlockSpec((None, tq, _KEEP_LANES), keep_map))
 
 
 def _select_fwd_impl(q, k, v, keep, scale, block, interpret):
@@ -1724,21 +1770,22 @@ def _select_fwd_impl(q, k, v, keep, scale, block, interpret):
     b, s, h, d = q.shape
     group = h // k.shape[2]
     keep_group = h // keep.shape[1]
-    tile = select_tile(s, block)
-    num = s // tile
-    rows, fetch_k, _ = _select_tables(keep, tile, block)
-    q_spec, k_spec, keep_spec = _select_specs(tile, block, d, num, group,
-                                              False, keep_group)
+    tq, tk = select_tile(s, block)
+    num_q, num_k = s // tq, s // tk
+    rows, fetch_k, _ = _select_tables(keep, tq, tk, block)
+    q_spec, k_spec, keep_spec = _select_specs(tq, tk, block, d, num_q, num_k,
+                                              group, False, keep_group)
     out, lse = _select_call(
-        functools.partial(_select_fwd_kernel, tile=tile, block=block,
-                          num=num, group=group, keep_group=keep_group,
+        functools.partial(_select_fwd_kernel, tq=tq, tk=tk, block=block,
+                          num_q=num_q, num_k=num_k, keep_group=keep_group,
                           scale=scale),
-        "flash_fwd_select", (b * h, num, num),
+        "flash_fwd_select", (b * h, num_q, num_k),
         [q_spec(d), k_spec, k_spec, keep_spec], [q_spec(d), q_spec(1)],
         [jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
          jax.ShapeDtypeStruct((b * h, s, 1), jnp.float32)],
-        [pltpu.VMEM((tile,), jnp.float32), pltpu.VMEM((tile,), jnp.float32),
-         pltpu.VMEM((tile, d), jnp.float32)],
+        [pltpu.VMEM((tq, _STAT_LANES), jnp.float32),
+         pltpu.VMEM((tq, _STAT_LANES), jnp.float32),
+         pltpu.VMEM((tq, d), jnp.float32)],
         interpret, (fetch_k, _flat(q), _flat(k), _flat(v), rows))
     return out.reshape(b, h, s, d).transpose(0, 2, 1, 3), lse[..., 0]
 
@@ -1754,39 +1801,38 @@ def _select_bwd_impl(q, k, v, keep, out, lse, dout, scale, block, interpret):
     g = k.shape[2]
     group = h // g
     keep_group = h // keep.shape[1]
-    tile = select_tile(s, block)
-    num = s // tile
-    rows, fetch_k, fetch_q = _select_tables(keep, tile, block)
+    tq, tk = select_tile(s, block)
+    num_q, num_k = s // tq, s // tk
+    rows, fetch_k, fetch_q = _select_tables(keep, tq, tk, block)
     qt, kt, vt, dot = _flat(q), _flat(k), _flat(v), _flat(dout)
     delta = jnp.sum(dot.astype(jnp.float32) * _flat(out).astype(jnp.float32),
                     -1, keepdims=True)
     lse3 = lse[..., None]
-    q_spec, k_spec, keep_spec = _select_specs(tile, block, d, num, group,
-                                              False, keep_group)
+    sizes = dict(tq=tq, tk=tk, block=block, num_q=num_q, num_k=num_k,
+                 keep_group=keep_group, scale=scale)
+    q_spec, k_spec, keep_spec = _select_specs(tq, tk, block, d, num_q, num_k,
+                                              group, False, keep_group)
     dq = _select_call(
-        functools.partial(_select_dq_kernel, tile=tile, block=block, num=num,
-                          group=group, keep_group=keep_group, scale=scale),
-        "flash_bwd_dq_select", (b * h, num, num),
+        functools.partial(_select_dq_kernel, **sizes),
+        "flash_bwd_dq_select", (b * h, num_q, num_k),
         [q_spec(d), k_spec, k_spec, keep_spec, q_spec(d), q_spec(1),
          q_spec(1)], q_spec(d),
         jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
-        [pltpu.VMEM((tile, d), jnp.float32)],
+        [pltpu.VMEM((tq, d), jnp.float32)],
         interpret, (fetch_k, qt, kt, vt, rows, dot, lse3, delta))
-    q_spec, k_spec, keep_spec = _select_specs(tile, block, d, num, group,
-                                              True, keep_group)
-    own = pl.BlockSpec((None, tile, d), lambda i, ki, jj, fetch_ref:
+    q_spec, k_spec, keep_spec = _select_specs(tq, tk, block, d, num_q, num_k,
+                                              group, True, keep_group)
+    own = pl.BlockSpec((None, tk, d), lambda i, ki, jj, fetch_ref:
                        (i, ki, 0))
     dk, dv = _select_call(
-        functools.partial(_select_dkv_kernel, tile=tile, block=block,
-                          num=num, group=group, keep_group=keep_group,
-                          scale=scale),
-        "flash_bwd_dkv_select", (b * h, num, num),
+        functools.partial(_select_dkv_kernel, **sizes),
+        "flash_bwd_dkv_select", (b * h, num_k, num_q),
         [q_spec(d), k_spec, k_spec, keep_spec, q_spec(d), q_spec(1),
          q_spec(1)], [own, own],
         [jax.ShapeDtypeStruct((b * h, s, d), jnp.float32),
          jax.ShapeDtypeStruct((b * h, s, d), jnp.float32)],
-        [pltpu.VMEM((tile, d), jnp.float32),
-         pltpu.VMEM((tile, d), jnp.float32)],
+        [pltpu.VMEM((tk, d), jnp.float32),
+         pltpu.VMEM((tk, d), jnp.float32)],
         interpret, (fetch_q, qt, kt, vt, rows, dot, lse3, delta))
 
     def heads(x, n, dtype):

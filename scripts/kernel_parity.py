@@ -66,6 +66,18 @@ chip (:data:`KDA_RULE_ROOM_REHEARSAL` at a CPU rehearsal's size), or :data:`KDA_
 output both forms hold to a few 2^-9 says nothing of either); each path's time
 a call.
 
+An eighth leg, run only by ``--only-select``, holds the selected flash kernels
+(``flash_fwd_select``, ``flash_bwd_dq_select``, ``flash_bwd_dkv_select`` of
+parallel/flash_attention.py) in both forms at their cells' shapes: ``block`` 1
+(Keye-VL-2.0: 32 query over 4 K/V heads x 16,384 x 128, a seeded top-2,048
+choice of keys a query, one for all heads) and ``block`` 64 (MiniCPM-SALA: 16
+over 1, ``model/sparse.py``'s own selection on the seeded operands).  Each
+kernel's ms a call from the device trace, its us a live cell and a 512 x 512
+cell's worth of pairs, and ``out`` / ``lse`` / dq / dk / dv of ONE query head
+against ``_xla_select_with_lse`` in float32 ``highest``.  ``--select-bisect``
+adds the forward with parts of its cell body swapped or taken out
+(:func:`_select_variant`; ISSUE 63's bisect: which part of a cell costs what).
+
 Shapes: flash at the long-context recipe's per-chip shape (seq 16,384, head
 dim 128; two heads so the dense reference's [s, s] scores fit beside it);
 the mixer at the flagship's (8 heads, seq 512, 512 features/head, batch 32).
@@ -505,6 +517,341 @@ def _kda_rule_leg(s: int = 16384, heads: int = 32, dk: int = 128,
     return bool(ok)
 
 
+def _kernel_ms(run, pattern: str, calls: int = 3):
+    """``{kernel: ms a call}`` of the device operations named ``pattern``
+    over ``calls`` runs of ``run()`` under the profiler, and under ``wall``
+    the host's clock round the same runs with the profiler off (all a CPU
+    rehearsal has: its trace holds no device)."""
+    import re
+    import tempfile
+    import time
+
+    import jax
+
+    from benchmark.trace import reduce
+
+    jax.block_until_ready(run())
+    start = time.perf_counter()
+    for _ in range(calls):
+        out = run()
+    jax.block_until_ready(out)
+    total = {"wall": (time.perf_counter() - start) * 1000 / calls}
+    if jax.devices()[0].platform != "cpu":
+        with tempfile.TemporaryDirectory() as trace_dir:
+            with jax.profiler.trace(trace_dir):
+                for _ in range(calls):
+                    out = run()
+                jax.block_until_ready(out)
+            ops = reduce.load(reduce.newest_xplane(trace_dir)).devices[0].ops
+        for op in ops:
+            # under a vjp the op is ``transpose_jvp_<kernel>__.1``
+            found = re.search(pattern, reduce.short_name(op.name))
+            if found:
+                total[found.group(0)] = total.get(found.group(0), 0.0) \
+                    + op.seconds * 1000 / calls
+    return {k: round(v, 3) for k, v in total.items()}
+
+
+#: the forward's cell body as ISSUE 63's bisect varies it.  ``stats``: the
+#: row statistics as 1-D ``(tile,)`` scratch (the parent's), ``[tile, 1]``
+#: columns, lane-replicated ``[tile, 128]``, or none (``p = exp(s)``: a
+#: time, not a result); ``mask``: a select on the scores and one on ``p``
+#: (the parent's), or one select to ``-inf`` under a finite first maximum;
+#: ``unpack``: each word shifted out and masked (the parent's), each word
+#: against its row's bit, or every pair kept (a time, not a result);
+#: ``compare``: positions compared in every cell (the parent's), in the
+#: cells the diagonal crosses, or never (a time)
+SELECT_PARENT = {"stats": "1d", "mask": "two", "unpack": "shift",
+                 "compare": "every"}
+
+
+def _select_variant(name, tiles, stats, mask, unpack, compare):
+    """``(q, k, v, keep) -> out`` of the selected forward with the named
+    parts: the library's tables, specs and call round a cell body made
+    here."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from homebrewnlp_tpu.parallel import flash_attention as fa
+
+    def forward(q, k, v, keep, scale, block):
+        b, s, h, d = q.shape
+        group, keep_group = h // k.shape[2], h // keep.shape[1]
+        tq, tk = tiles
+        num_q, num_k = s // tq, s // tk
+
+        def kept_of(keep_ref, kk):
+            if unpack == "none":
+                return None
+            if unpack == "and" and block == 1:
+                bit = jnp.left_shift(1, jax.lax.broadcasted_iota(
+                    jnp.int32, (fa.KEEP_WORD, tk), 0))
+                words = keep_ref[...]
+                return jnp.concatenate([
+                    jnp.broadcast_to(words[r:r + 1], (fa.KEEP_WORD, tk)) & bit
+                    for r in range(tq // fa.KEEP_WORD)], axis=0) != 0
+            return fa._select_kept(keep_ref, kk, tq, tk, block)
+
+        def kernel(fetch_ref, q_ref, k_ref, v_ref, keep_ref, o_ref, lse_ref,
+                   m_ref, l_ref, acc_ref):
+            i, qi, kk = (pl.program_id(n) for n in range(3))
+
+            @pl.when(kk == 0)
+            def _init():
+                m_ref[...] = jnp.full_like(m_ref, fa._NEG_INF)
+                l_ref[...] = jnp.zeros_like(l_ref)
+                acc_ref[...] = jnp.zeros_like(acc_ref)
+
+            def pv(p):
+                return jax.lax.dot_general(
+                    p.astype(v_ref.dtype), v_ref[...],
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+
+            def step(positions):
+                kept = kept_of(keep_ref, kk)
+                if positions:
+                    seen = (qi * tq + jax.lax.broadcasted_iota(
+                        jnp.int32, (tq, 1), 0)) >= (
+                        kk * tk + jax.lax.broadcasted_iota(
+                            jnp.int32, (1, tk), 1))
+                    kept = seen if kept is None else kept & seen
+                s_ = fa._make_score(q_ref, k_ref, scale)()
+                if kept is not None:
+                    s_ = jnp.where(kept, s_, -jnp.inf if mask == "inf"
+                                   else fa._NEG_INF)
+                if stats == "none":
+                    acc_ref[...] += pv(jnp.exp(s_))
+                    return
+                keepdims = stats != "1d"
+                m_prev = m_ref[...]
+                m_new = jnp.maximum(m_prev, s_.max(-1, keepdims=keepdims))
+                alpha = jnp.exp(m_prev - m_new)
+                p = jnp.exp(s_ - (m_new[:, None] if stats == "1d" else m_new
+                                  if stats == "col" else fa._lanes(m_new, tk)))
+                if mask == "two" and kept is not None:
+                    p = jnp.where(kept, p, 0.0)
+                l_ref[...] = l_ref[...] * alpha + p.sum(-1, keepdims=keepdims)
+                if stats == "1d":
+                    alpha = alpha[:, None]
+                elif stats == "lanes":
+                    alpha = fa._lanes(alpha, d)
+                acc_ref[...] = acc_ref[...] * alpha + pv(p)
+                m_ref[...] = m_new
+
+            live = fa._select_live(fetch_ref, i, qi, kk, num_q, num_k,
+                                   keep_group) == kk
+            if compare == "diagonal":
+                below = fa._causal_split(qi, kk, tq, tk)[1]
+                pl.when(live & below)(lambda: step(False))
+                pl.when(live & jnp.logical_not(below))(lambda: step(True))
+            else:
+                pl.when(live)(lambda: step(compare == "every"))
+
+            @pl.when(kk == num_k - 1)
+            def _finish():
+                m, l = m_ref[...], jnp.maximum(l_ref[...], 1e-30)
+                if stats == "1d":
+                    m, l = m[:, None], l[:, None]
+                elif stats == "lanes":
+                    m, l = m[:, :1], l[:, :1]
+                o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
+                lse_ref[...] = m + jnp.log(l)
+
+        rows, fetch_k, _ = fa._select_tables(keep, tq, tk, block)
+        q_spec, k_spec, keep_spec = fa._select_specs(
+            tq, tk, block, d, num_q, num_k, group, False, keep_group)
+        stat = {"1d": (tq,), "lanes": (tq, fa._STAT_LANES)}.get(stats,
+                                                                (tq, 1))
+        out, _ = fa._select_call(
+            kernel, name, (b * h, num_q, num_k),
+            [q_spec(d), k_spec, k_spec, keep_spec], [q_spec(d), q_spec(1)],
+            [jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
+             jax.ShapeDtypeStruct((b * h, s, 1), jnp.float32)],
+            [pltpu.VMEM(stat, jnp.float32), pltpu.VMEM(stat, jnp.float32),
+             pltpu.VMEM((tq, d), jnp.float32)],
+            jax.devices()[0].platform == "cpu",
+            (fetch_k, fa._flat(q), fa._flat(k), fa._flat(v), rows))
+        return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+    return forward
+
+
+def _select_choice(block: int, q, k, topk: int, seed: int):
+    """The choice a cell's layer would hand the kernels on seeded operands:
+    ``block`` 1, ``model/indexer.py top_keys`` over seeded scores (exactly
+    ``topk`` scattered keys a query past ``topk``), packed to bits; else
+    ``model/sparse.py``'s own selection at MiniCPM-SALA's sizes."""
+    import jax
+    import jax.numpy as jnp
+
+    from homebrewnlp_tpu.model import indexer, sparse
+    from homebrewnlp_tpu.parallel import flash_attention as fa
+
+    s = q.shape[1]
+    if block != 1:
+        sizes = sparse.Sizes(32, 16, block, min(64, s // block // 2), 1,
+                             min(2048, s // 4), 0)
+        return jax.jit(lambda q, k: sparse.select_blocks(
+            q, k, sizes, q.shape[-1] ** -0.5))(q, k)
+    rows = min(1024, s)
+    pick = jax.jit(lambda key, first: fa.pack_keep(indexer.top_keys(
+        jax.random.uniform(key, (1, rows, s)), first, topk)))
+    keys = jax.random.split(jax.random.PRNGKey(seed), s // rows)
+    return jnp.concatenate([pick(keys[n], n * rows)
+                            for n in range(s // rows)], axis=1)[:, None]
+
+
+def _live_cells(keep, tiles, block: int, heads: int) -> int:
+    """The cells of a selected grid over all ``heads`` that run their body."""
+    import numpy as np
+
+    from homebrewnlp_tpu.parallel import flash_attention as fa
+    num_k = keep.shape[2] * (fa.KEEP_WORD if block == 1 else 1) // tiles[1]
+    fetch_k = np.asarray(fa._select_tables(keep, *tiles, block)[1])
+    return int((fetch_k.reshape(-1, num_k) == np.arange(num_k)).sum()) \
+        * heads // keep.shape[1]
+
+
+def _select_leg(block: int, s: int, heads: int, kv_heads: int, d: int = 128,
+                topk: int = 2048, bisect: bool = False,
+                other_tiles=()) -> bool:
+    """The three selected kernels of one form at its cell's shape: ms a call
+    by the device trace, us a live cell, and one query head against the
+    dense masked form in float32."""
+    import jax
+    import jax.numpy as jnp
+
+    from homebrewnlp_tpu.parallel import flash_attention as fa
+
+    interpret = jax.devices()[0].platform == "cpu"
+    scale = d ** -0.5
+    q, k, v, ct = (jax.random.normal(jax.random.PRNGKey(63 + n),
+                                     (1, s, h, d), jnp.float32
+                                     ).astype(jnp.bfloat16)
+                   for n, h in enumerate((heads, kv_heads, kv_heads, heads)))
+    keep = _select_choice(block, q, k, min(topk, s // 4), 63)
+    tq, tk = fa.select_tile(s, block)
+    live = _live_cells(keep, (tq, tk), block, heads)
+
+    def per_cell(ms):
+        return {name: {"ms_a_call": t,
+                       "us_a_live_cell": round(t * 1000 / live, 3),
+                       "us_a_512x512_of_pairs": round(
+                           t * 1000 / (live * tq * tk / 512 ** 2), 3)}
+                for name, t in ms.items()}
+
+    def library_ms():
+        both = jax.jit(lambda q, k, v, ct: jax.vjp(
+            lambda *t: fa.flash_select(*t, keep, scale, block, interpret),
+            q, k, v)[1](ct))
+        return _kernel_ms(lambda: both(q, k, v, ct),
+                          r"flash_[a-z_]+?_select")
+
+    ms = library_ms()
+    # ONE query head and its K/V head against the dense masked form
+    one = (q[:, :, :1], k[:, :, :1], v[:, :, :1], ct[:, :, :1])
+    keep1 = keep[:, :1]
+    out, lse = jax.jit(lambda q, k, v: fa._select_fwd_impl(
+        q, k, v, keep1, scale, block, interpret))(*one[:3])
+    got = (out, lse) + jax.jit(lambda q, k, v, ct: jax.vjp(
+        lambda *t: fa.flash_select(*t, keep1, scale, block, interpret),
+        q, k, v)[1](ct))(*one)
+    with jax.default_matmul_precision("highest"):
+        def dense(q, k, v):
+            o, e = fa._xla_select_with_lse(q, k, v, keep1, scale, block)
+            return o, jax.lax.stop_gradient(e)
+        (want_out, want_lse), pull = jax.vjp(
+            dense, *(t.astype(jnp.float32) for t in one[:3]))
+        want = (want_out, want_lse) + pull((one[3].astype(jnp.float32),
+                                            jnp.zeros_like(want_lse)))
+    errs = dict(zip(("out", "lse", "dq", "dk", "dv"),
+                    _errors(got, want).values()))
+    ok = all(e <= TOLERANCE for e in errs.values())
+    row = {"kernel": f"flash_select_block{block}", "ok": bool(ok),
+           "implementation": "pallas (interpret)" if interpret else "pallas",
+           "tiles": [tq, tk], "live_cells": live,
+           "kernels": per_cell(ms),
+           "max_err_over_max_ref": {n: round(e, 6) for n, e in errs.items()},
+           "tolerance": TOLERANCE,
+           "shapes": [list(t.shape) for t in (q, k, v, keep)],
+           "dtype": "bfloat16"}
+    print(json.dumps(row), flush=True)
+    # the library's three kernels at other tiles than ``select_tile``'s
+    chosen = fa.select_tile
+    for tiles in other_tiles:
+        if tiles == (tq, tk) or s % tiles[1] or (
+                block != 1 and fa._KEEP_LANES % (tiles[1] // block)):
+            continue
+        fa.select_tile = lambda s, block: tiles
+        try:
+            cells = _live_cells(keep, tiles, block, heads)
+            print(json.dumps({"library_at_tiles": list(tiles), "block": block,
+                              "live_cells": cells, "ms_a_call": library_ms()}),
+                  flush=True)
+        except Exception as e:
+            print(json.dumps({"library_at_tiles": list(tiles),
+                              "refused": repr(e)[:400]}), flush=True)
+        finally:
+            fa.select_tile = chosen
+    if not bisect:
+        return bool(ok)
+    # the forward with parts of its body swapped or taken out, as PR 63 ran
+    # them (PERF.md section 6 has the table); the library's own body is
+    # lane statistics, one mask, the compare in every cell
+    parent = SELECT_PARENT
+    final = {"stats": "col", "mask": "inf", "unpack": "shift",
+             "compare": "diagonal"}
+    fwd = jax.jit(lambda q, k, v: fa._select_fwd_impl(
+        q, k, v, keep, scale, block, interpret))
+    tq = min(tq, 512)
+    square, wide = (tq, tq), (tq, min(2 * tq, s))
+    variants = [
+        ("parent", square, parent),
+        ("no_compare", square, {**parent, "compare": "none"}),
+        ("no_stats", square, {**parent, "stats": "none"}),
+        ("no_unpack_no_mask", square, {**parent, "unpack": "none",
+                                       "compare": "none"}),
+        ("col_stats", square, {**parent, "stats": "col"}),
+        ("lane_stats", square, {**parent, "stats": "lanes"}),
+        ("one_mask", square, {**parent, "stats": "col", "mask": "inf"}),
+        ("diagonal_compare", square, final),
+        ("and_unpack", square, {**final, "unpack": "and"}),
+        ("wide", wide, final),
+        ("wide_lane_stats", wide, {**final, "stats": "lanes"}),
+        ("wide_and_unpack", wide, {**final, "unpack": "and"}),
+        ("wide_parent_body", wide, parent),
+        ("wider", (tq, 4 * tq), final),
+        ("tall_wide", (2 * tq, 2 * tq), final),
+        ("short_wider", (tq // 2, 4 * tq), final),
+    ]
+    want_out = jax.block_until_ready(fwd(q, k, v))[0].astype(jnp.float32)
+    for name, tiles, parts in variants:
+        if s % tiles[1] or (block != 1 and (
+                tiles != square or parts["unpack"] == "and")):
+            continue
+        kernel_name = f"select_fwd_{name}"
+        try:
+            run = jax.jit(functools.partial(_select_variant(
+                kernel_name, tiles, **parts), scale=scale, block=block))
+            ms = _kernel_ms(lambda: run(q, k, v, keep), kernel_name)
+            err = float(jnp.max(jnp.abs(run(q, k, v, keep).astype(
+                jnp.float32) - want_out)))
+        except Exception as e:  # Mosaic refuses a layout: a finding
+            print(json.dumps({"variant": name, "refused": repr(e)[:400]}),
+                  flush=True)
+            continue
+        cells = _live_cells(keep, tiles, block, heads)
+        t_ms = ms.get(kernel_name, ms["wall"])
+        print(json.dumps({
+            "variant": name, "block": block, "tiles": list(tiles), **parts,
+            "ms_a_call": t_ms, "us_a_512x512_of_pairs": round(
+                t_ms * 1000 / (cells * tiles[0] * tiles[1] / 512 ** 2), 3),
+            "max_abs_diff_to_the_library": err}), flush=True)
+    return bool(ok)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--flash-seq", type=int, default=16384)
@@ -523,6 +870,16 @@ def main(argv=None) -> int:
     ap.add_argument("--kda-rule-seq", type=int, default=16384)
     ap.add_argument("--only-kda-rule", action="store_true",
                     help="run layer kda's chunked rule's leg alone")
+    ap.add_argument("--select-seq", type=int, default=16384)
+    ap.add_argument("--only-select", action="store_true",
+                    help="run the selected flash kernels' leg alone, both "
+                         "forms at their cells' shapes")
+    ap.add_argument("--select-tiles", default="",
+                    help="with --only-select: also time the library's three "
+                         "kernels at these tiles, e.g. 512x512,1024x1024")
+    ap.add_argument("--select-bisect", action="store_true",
+                    help="with --only-select: also time the forward with "
+                         "parts of its cell body swapped or taken out")
     args = ap.parse_args(argv)
 
     import jax
@@ -531,6 +888,15 @@ def main(argv=None) -> int:
     from homebrewnlp_tpu.parallel import flash_attention as flash
     from homebrewnlp_tpu.parallel import map_mixer
 
+    if args.only_select:
+        tiles = [tuple(int(n) for n in pair.split("x"))
+                 for pair in args.select_tiles.split(",") if pair]
+        ok = _select_leg(1, args.select_seq, 32, 4,
+                         bisect=args.select_bisect, other_tiles=tiles)
+        ok &= _select_leg(64, args.select_seq, 16, 1,
+                          bisect=args.select_bisect, other_tiles=tiles)
+        print(json.dumps({"ok": bool(ok)}), flush=True)
+        return 0 if ok else 1
     if args.only_scan or args.only_rule or args.only_kda_rule:
         ok = _scan_leg(args.scan_seq) if args.only_scan \
             else _rule_leg(args.rule_seq) if args.only_rule \

@@ -267,7 +267,8 @@ def at_index_topk_keys_the_layer_is_the_plain_attention_test():
 
 @pytest.fixture
 def small_tiles(monkeypatch):
-    """Four tiles of 128 keys a sequence of 512, so that tiles die."""
+    """The block form's tile at 128 (the key-at-a-time form's at 256), so
+    that tiles of a sequence of 512 die."""
     monkeypatch.setattr(fa, "_SELECT_TILE", 128)
     jax.clear_caches()
     yield
@@ -281,28 +282,61 @@ def _qkv(seed, b, s, h, d, g):
 
 
 def _choice(kind: str):
+    """``(the packed words, the pairs a row keeps AND may see)``."""
     rng = np.random.default_rng(2)
-    if kind == "scattered":
+    if kind in ("scattered", "above"):
         keep = rng.random((1, 1, 512, 512)) < 0.1
         # the last q tile keeps nothing of the first two k tiles: dead tiles
         keep[:, :, 384:, :256] = False
+    elif kind == "late":
+        # query 400 keeps its own key and no other while its neighbours keep
+        # key 0: its q tile's first cells run with nothing kept in its row
+        keep = np.zeros((1, 1, 512, 512), bool)
+        keep[..., 0] = True
+        keep[:, :, 400] = False
     else:
         # a row keeps key 0 and its own: every tile between is dead for every
         # row of a q tile
         keep = np.zeros((1, 1, 512, 512), bool)
         keep[..., 0] = True
     keep |= np.eye(512, dtype=bool)
-    keep &= np.tril(np.ones((512, 512), bool))
-    return fa.pack_keep(jnp.asarray(keep)), keep
+    seen = keep & np.tril(np.ones((512, 512), bool))
+    # "above": the words hold bits past the diagonal, inside the cells it
+    # crosses (the kernels mask those by position) and in whole tiles above
+    # it (the tables kill those)
+    return fa.pack_keep(jnp.asarray(keep if kind == "above" else seen)), seen
 
 
-@pytest.mark.parametrize("kind", ["scattered", "local"])
-def key_select_kernels_match_the_dense_form_test(small_tiles, kind):
+def _live_steps(fetch, nq: int, nk: int, k_outer: bool = False):
+    """``[nq, nk]`` bool: the cells whose table entry is their own step."""
+    shape, own = ((nk, nq), np.arange(nq)) if k_outer \
+        else ((nq, nk), np.arange(nk))
+    live = np.asarray(fetch).reshape(shape) == own[None, :]
+    return live.T if k_outer else live
+
+
+@pytest.mark.parametrize("kind,tiles", [
+    ("scattered", None), ("local", None), ("late", None), ("above", None),
+    ("scattered", (128, 256)), ("above", (128, 256)),
+    ("local", (128, 128)), ("late", (128, 128))])
+def key_select_kernels_match_the_dense_form_test(small_tiles, monkeypatch,
+                                                 kind, tiles):
     """Forward, ``lse``, dq, dk and dv of the ``flash_*_select`` kernels at
     ``block`` 1 (8 query heads over 2 K/V heads, ONE choice for all) against
-    the dense masked XLA form, and the tables skip the dead tiles."""
+    the dense masked XLA form, at the form's own tile (256 x 256 under
+    ``small_tiles``), a rectangular and a smaller one, and the tables skip
+    the dead tiles: a k tile no row kept, a
+    tile above the diagonal whatever its bits say.  ``late``: a row that has
+    kept nothing when its q tile's first cells run (the finite first maximum:
+    ``p`` 0 there, ``out`` and ``lse`` finite); ``above``: bits past the
+    diagonal, masked by position."""
+    if tiles is None:
+        tiles = fa.select_tile(512, 1)
+        assert tiles == (256, 256)
+    else:
+        monkeypatch.setattr(fa, "select_tile", lambda s, block: tiles)
     q, k, v = _qkv(4, 1, 512, 8, 32, 2)
-    words, keep = _choice(kind)
+    words, seen = _choice(kind)
     scale = 32 ** -0.5
     weights = jnp.asarray(np.random.default_rng(5).normal(
         size=(1, 512, 8, 32)), jnp.float32)
@@ -311,17 +345,37 @@ def key_select_kernels_match_the_dense_form_test(small_tiles, kind):
         weights)
     want = harness.with_input_grads(
         lambda *t: fa._xla_select(*t, words, scale, 1), (q, k, v), weights)
+    assert all(bool(jnp.isfinite(x).all()) for x in got)
     harness.assert_close_each(got, want, 2e-5, ("out", "dq", "dk", "dv"))
     _, lse = fa._select_fwd_impl(q, k, v, words, scale, 1, True)
     _, want_lse = fa._xla_select_with_lse(q, k, v, words, scale, 1)
+    assert bool(jnp.isfinite(lse).all())
     assert harness.error(lse, want_lse) < 1e-5
-    _, fetch_k, fetch_q = fa._select_tables(words, 128, 1)
-    live = keep.reshape(4, 128, 4, 128).any(axis=(1, 3))
-    assert int((np.asarray(fetch_k).reshape(4, 4)
-                == np.arange(4)[None, :]).sum()) == int(live.sum())
-    assert int((np.asarray(fetch_q).reshape(4, 4)
-                == np.arange(4)[None, :]).sum()) == int(live.sum())
-    assert int(live.sum()) < 10
+    tq, tk = tiles
+    nq, nk = 512 // tq, 512 // tk
+    _, fetch_k, fetch_q = fa._select_tables(words, tq, tk, 1)
+    live = seen.reshape(nq, tq, nk, tk).any(axis=(1, 3))
+    assert np.array_equal(_live_steps(fetch_k, nq, nk), live)
+    assert np.array_equal(_live_steps(fetch_q, nq, nk, k_outer=True), live)
+    # whatever the words' bits say, no live cell lies above the diagonal: a
+    # cell it does not cross holds no pair a row may not see
+    above = np.arange(nk)[None, :] * tk > np.arange(nq)[:, None] * tq + tq - 1
+    if tiles == (128, 128):
+        assert int(live.sum()) < int((~above).sum()) == 10
+    bits = np.asarray(fa.unpack_keep(words)).reshape(nq, tq, nk, tk).any(
+        axis=(1, 3))
+    assert (bits & above).any() == (kind == "above")
+    assert not (_live_steps(fetch_k, nq, nk) & above).any()
+
+
+@pytest.mark.parametrize("s,block,tiles", [
+    (16384, 1, (1024, 1024)), (1024, 1, (1024, 1024)), (512, 1, (512, 512)),
+    (768, 1, (256, 256)), (16384, 64, (512, 512)), (16384, 16, (512, 512)),
+    (256, 16, (256, 256))])
+def select_tile_by_form_test(s, block, tiles):
+    """The key-at-a-time form takes twice the block form's tile where the
+    sequence holds one."""
+    assert fa.select_tile(s, block) == tiles
 
 
 def dispatch_returns_the_dense_forms_value_and_gradients_test():
@@ -380,11 +434,19 @@ def mrope_on_equal_streams_is_rope_test():
 
 # ---- what the parent traced still traces ---------------------------------------
 
-#: sha1 of the jaxpr (addresses stripped) that commit 233230e (PR 61) traces:
-#: SALA's block-selected call, forward and backward, and the forward of every
-#: other one-chip train cell at its rehearsal size
+#: sha1 of the jaxpr (addresses stripped) that commit cf244b8 (PR 62) traces:
+#: the block-selected call's two backward kernels, and the forward of every
+#: one-chip train cell at its rehearsal size — off the TPU the two cells with
+#: a selected call run the dense masked form, so PR 63 moves none of them.
+#: ``select`` (SALA's block-selected call whole, forward and backward) is
+#: PR 63's own: it moved from the parent's 77b432de... by the forward
+#: kernel's body (lane-replicated statistics, one select a pair) and by the
+#: tables' rectangular diagonal, and by nothing else — ``select_dq`` and ``select_dkv`` are the
+#: parent's, letter for letter
 _PARENT = {
-    "select": "77b432de42d5eeb0a1170fbe88fe72b4344d7edc",
+    "select": "ab4ea813094509e3835c587a40d64787f5d91bbe",
+    "select_dq": "821f74ffb0e6b4069ff0f4fbd51fdb687cf05cdf",
+    "select_dkv": "f34b72f595c21121767ecb7ce6a9b91974a8f020",
     "train_32big_mixer_b32": "80cc1d5df38d1036908a3ffc0d498fed970a112f",
     "train_1b_long_context_s16k": "93c267300f6762eac60b1ad8c26b779106cdd89f",
     "train_olmoe_1b_7b_s4k": "c762ce67313660f1d9c7a0699dfc40731993a9a1",
@@ -398,6 +460,7 @@ _PARENT = {
     "train_nemotron_3_super_tp2_ep64_s16k":
         "f4323140a48161e1787ff0735c309f29125e2a74",
     "train_kimi_linear_ep32_s16k": "816fab234da3b7a594e5e971555744ab986c38dc",
+    "train_keye_vl_2_0_ep8_s16k": "a3379a7a8f6c66f8bc446b44fa9ac9afe2223779",
 }
 
 
@@ -406,9 +469,25 @@ def _sha1(text: str) -> str:
                         ).hexdigest()
 
 
+def _pallas_calls(jaxpr, found):
+    """``{kernel name: its equation's text}`` of every Pallas call under
+    ``jaxpr``."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found[eqn.params["name"]] = str(eqn)
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) \
+                    else [value]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    _pallas_calls(inner, found)
+    return found
+
+
 def block_selected_call_traces_as_on_the_parent_test():
     """SALA's form of the select kernels — blocks of keys, a choice a K/V
-    group — traces to the parent's jaxpr, forward and backward."""
+    group: the whole call traces to PR 63's pinned jaxpr, and its two
+    backward kernels (body, grid, index maps) to the PARENT's."""
     q = jnp.zeros((1, 512, 4, 32), jnp.float32)
     k = v = jnp.zeros((1, 512, 2, 32), jnp.float32)
     keep = jnp.zeros((1, 2, 512, 32), bool)
@@ -417,9 +496,15 @@ def block_selected_call_traces_as_on_the_parent_test():
                                                 True)),
         argnums=(0, 1, 2)))(q, k, v)
     assert _sha1(str(jaxpr)) == _PARENT["select"]
+    calls = _pallas_calls(jaxpr.jaxpr, {})
+    assert sorted(calls) == ["flash_bwd_dkv_select", "flash_bwd_dq_select",
+                             "flash_fwd_select"]
+    assert _sha1(calls["flash_bwd_dq_select"]) == _PARENT["select_dq"]
+    assert _sha1(calls["flash_bwd_dkv_select"]) == _PARENT["select_dkv"]
 
 
-@pytest.mark.parametrize("cell", [c for c in _PARENT if c != "select"])
+@pytest.mark.parametrize("cell", [c for c in _PARENT
+                                  if not c.startswith("select")])
 def other_cells_step_traces_as_on_the_parent_test(cell):
     from benchmark.lib.cell import load_cell
     config = {**load_cell(cell).model_config(rehearsal=True),
@@ -468,7 +553,7 @@ def a_mesh_and_a_tile_without_words_refuse_by_name_test():
             spatial._one_device(ctx, flag)
     with pytest.raises(ValueError, match="the forms are blocks of keys"):
         fa.select_tile(512, 48)
-    assert fa.select_tile(16384, 1) == 512
+    assert fa.select_tile(16384, 1) == (1024, 1024)
     with pytest.raises(ValueError, match="whole words of 32"):
         indexer.selects(32, 48)
     with pytest.raises(ValueError, match="index_features 7"):

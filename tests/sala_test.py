@@ -317,17 +317,34 @@ def _choice(kind: str, q, k):
     idx = np.arange(32)[None, :]
     own = (np.arange(512) // 16)[:, None]
     keep = (idx == 0) | (idx == own)
+    if kind == "late":
+        # query 400 keeps its own block and no other while its neighbours
+        # keep block 0: its q tile's first cell runs with nothing kept in
+        # its row (the finite first maximum)
+        keep[400, 0] = False
+    if kind == "above":
+        # blocks past a row's own: masked by position where the diagonal
+        # crosses a cell, in tiles the tables kill above it
+        keep = keep | (idx > own)
     return jnp.asarray(np.broadcast_to(keep, (1, 2, 512, 32)))
 
 
-@pytest.mark.parametrize("kind", ["indexer", "local"])
-def selected_kernels_match_a_masked_softmax_test(small_tiles, kind):
+@pytest.mark.parametrize("kind,tiles", [
+    ("indexer", None), ("local", None), ("late", None), ("above", None),
+    ("indexer", (128, 256)), ("above", (128, 256))])
+def selected_kernels_match_a_masked_softmax_test(small_tiles, monkeypatch,
+                                                 kind, tiles):
+    if tiles is None:
+        assert fa.select_tile(512, 16) == (128, 128)
+    else:
+        monkeypatch.setattr(fa, "select_tile", lambda s, block: tiles)
     q, k, v = _qkv(4, 1, 512, 4, 32, 2)
     keep = _choice(kind, q, k)
     scale = 32 ** -0.5
     out, lse = fa._select_fwd_impl(q, k, v, keep, scale, 16, True)
     want, want_lse = jax.jit(lambda *t: fa._xla_select_with_lse(
         *t, keep, scale, 16))(q, k, v)
+    assert bool(jnp.isfinite(out).all() & jnp.isfinite(lse).all())
     assert harness.error(np.asarray(out), np.asarray(want)) < 1e-5
     assert float(jnp.max(jnp.abs(lse - want_lse))) < 1e-5
     # against the softmax written out: exactly the kept keys <= t
@@ -357,7 +374,7 @@ def tiles_no_row_kept_are_not_visited_test(small_tiles):
     does nothing); the k-outer grid's likewise."""
     q, k, _ = _qkv(4, 1, 512, 4, 32, 2)
     rows, fetch_k, fetch_q = fa._select_tables(_choice("local", q, k), 128,
-                                               16)
+                                               128, 16)
     assert rows.shape == (2, 512, 128) and rows.dtype == jnp.bfloat16
     assert np.asarray(fetch_k).reshape(2, 4, 4)[0].tolist() == [
         [0, 0, 0, 0], [0, 1, 1, 1], [0, 0, 2, 2], [0, 0, 0, 3]]
