@@ -22,11 +22,23 @@ the scores' ordered bit pattern (32 counting passes), ties at it go to the
 lower positions; no sort, no ``lax.top_k`` over the keys.  The choice is held
 as bits, ``[b, 1, s / 32, s]`` int32 (``parallel/flash_attention.py
 pack_keep``): a bit a (query, key) pair a layer, never a float ``[s, s]``.
-Every pass walks the queries ``QUERY_CHUNK`` at a time (``lax.map`` /
+Every XLA pass walks the queries ``QUERY_CHUNK`` at a time (``lax.map`` /
 ``lax.scan``), one index head — or one attention head — at a time inside a
 chunk, so that one ``[b, chunk, s]`` float32 plane a live value is what a
 pass holds; and in ``BANDS`` bands of chunks, a band against the keys up to
 its last query only (what lies past them no row of it may see).
+
+Which pass runs where: ``select_keys`` (``index``, ``select``) is XLA's
+everywhere.  ``index_loss`` is ONE Pallas kernel a layer,
+``parallel/index_loss.py index_loss_pass``, where the call can see that it
+applies (``kernel_applies``: a TPU, the choice held as bits with the
+attention's ``lse`` over it, a sequence of whole tiles) — its ``[q tile, k
+tile]`` planes stay in VMEM and it walks the tiles at or under the diagonal,
+so chunks and bands mean nothing to it — and ``xla_index_loss`` everywhere
+else: off the TPU, up to ``index_topk`` keys (``keep is None``), a sequence
+of no whole tiles.  The XLA form is the kernel's reference
+(``tests/index_loss_kernel_test.py``, ``scripts/kernel_parity.py
+--only-index-loss``); no option chooses between them.
 
 ``L_I``'s gradient reaches ``qI``, ``kI`` and ``w`` only, and is made BY HAND
 in the pass that makes the loss (``d L_I / d I = (softmax_S(I) - pbar) /
@@ -47,6 +59,9 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ..parallel.flash_attention import KEEP_WORD, pack_keep, unpack_keep
+from ..parallel.index_loss import (index_loss_pass, index_loss_tile,
+                                   kernel_applies,
+                                   walked_over_visible as kernel_walk)
 from .loss import _matmul
 
 #: queries a chunk of every pass (Keye-VL-2.0's ``q_chunk_size``: a tiling,
@@ -196,9 +211,31 @@ def index_loss(q_index, k_index, weight, q, k, lse, keep, scale: float):
     ``k [b, s, g, f]`` the attention's own (rotated, normalised) queries and
     keys, ``lse [b * h, s]`` its log-normalisers over the kept keys (None:
     made here), ``keep`` the choice as bits (None: every key ``u <= t``).
-    Nothing here carries a gradient; a chunk of queries at a time, an
-    attention head at a time for ``pbar``, an index head at a time for the
-    scores and their backward."""
+    Nothing here carries a gradient.  By what the call can see
+    (``kernel_applies``): one Pallas kernel on a TPU, ``xla_index_loss``
+    everywhere else."""
+    if kernel_applies(q.shape[1], keep is not None and lse is not None):
+        return index_loss_pass(q_index, k_index, weight, q, k, lse, keep,
+                               scale)
+    return xla_index_loss(q_index, k_index, weight, q, k, lse, keep, scale)
+
+
+def walked_over_visible(s: int, kernel: bool) -> float:
+    """The (query, key) pairs ``index_loss`` walks over the ``s (s + 1) / 2``
+    a query may see: the kernel's tiles at or under the diagonal, the XLA
+    form's bands."""
+    if kernel:
+        return kernel_walk(s, index_loss_tile(s))
+    return sum(rows * keys for _, rows, keys in _bands(s, _chunk(s))) \
+        / (s * (s + 1) / 2)
+
+
+def xla_index_loss(q_index, k_index, weight, q, k, lse, keep, scale: float):
+    """``index_loss`` as XLA's own passes — the path off the TPU, of the
+    ``keep is None`` case and of a sequence of no whole kernel tiles, and the
+    kernel's reference: a chunk of queries at a time, an attention head at a
+    time for ``pbar``, an index head at a time for the scores and their
+    backward."""
     b, s, h, f = q.shape
     g = k.shape[2]
     chunk = _chunk(s)

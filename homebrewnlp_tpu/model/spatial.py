@@ -1001,6 +1001,35 @@ def flash_scored_over_live(params, backend=None
     return worst or None
 
 
+def index_loss_kernel_layers(params, backend=None) -> typing.Optional[int]:
+    """How many attention layers of the step (flag ``indexed``) run their
+    index loss as the kernel of ``parallel/index_loss.py``, by the predicate
+    ``model/indexer.py index_loss`` itself calls: a TPU, a sequence past
+    ``index_topk`` (the choice as bits and the ``lse`` over it) of whole
+    tiles; None where no layer has the flag."""
+    from ..parallel.index_loss import kernel_applies
+    from .indexer import INDEX_LOSS_NAMES
+    layers = sum(times for offer, times in step_offers(params, "attention")
+                 if INDEX_LOSS_NAMES[0] in offer.names)
+    if not layers:
+        return None
+    seq = params.sequence_dim.size
+    return layers * kernel_applies(seq, seq > params.index_topk, backend)
+
+
+def index_loss_walked_over_visible(params, backend=None
+                                   ) -> typing.Optional[float]:
+    """The (query, key) pairs an ``indexed`` layer's index loss walks over
+    the ``s (s + 1) / 2`` a query may see (``model/indexer.py
+    walked_over_visible``: the kernel's tiles under the diagonal or the XLA
+    form's bands); None where no layer has the flag."""
+    from .indexer import walked_over_visible
+    layers = index_loss_kernel_layers(params, backend)
+    if layers is None:
+        return None
+    return walked_over_visible(params.sequence_dim.size, layers > 0)
+
+
 #: layer ``attention``'s ``declares.facts``
 FACTS = (
     Fact(60, "hbnlp_flash_band_layers",
@@ -1015,6 +1044,20 @@ FACTS = (
          "band; no series where no call reaches those kernels)",
          lambda params, mesh, backend: flash_scored_over_live(params, backend),
          "flash scored over live pairs {}", zero=False, label="pass"),
+    Fact(62, "hbnlp_index_loss_kernel_layers",
+         "attention layers of the built step (flag indexed) whose index loss "
+         "is the Pallas kernel (0 off the TPU, at or under index_topk keys "
+         "and at a sequence of no whole tiles; no series without the flag)",
+         lambda params, mesh, backend: index_loss_kernel_layers(params,
+                                                                backend),
+         "index loss kernel {} layers", zero=False),
+    Fact(63, "hbnlp_index_loss_walked_over_visible_pairs",
+         "pairs an indexed layer's index loss walks over the pairs a query "
+         "may see (the kernel: its tiles at or under the diagonal; the XLA "
+         "form: its bands of chunks; no series without the flag)",
+         lambda params, mesh, backend: index_loss_walked_over_visible(
+             params, backend),
+         "index loss walked over visible pairs {:.6g}", zero=False),
 )
 
 

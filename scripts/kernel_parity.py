@@ -78,6 +78,21 @@ against ``_xla_select_with_lse`` in float32 ``highest``.  ``--select-bisect``
 adds the forward with parts of its cell body swapped or taken out
 (:func:`_select_variant`; ISSUE 63's bisect: which part of a cell costs what).
 
+A ninth leg, run only by ``--only-index-loss``, holds the learned indexer's
+index-loss kernel (``index_loss_pass`` of parallel/index_loss.py) ALONE at
+the Keye-VL-2.0 cell's shape — 1 x 16,384, 32 query over 4 K/V heads of 128,
+16 index heads of 64, ``model/indexer.py top_keys``' choice of 2,048 seeded
+keys a query as bits, the selected forward's own ``lse`` — beside the XLA
+form ``model/indexer.py xla_index_loss``: each's ms a call from the device
+trace, and the value, the largest kept score and the three gradients of both
+against the XLA form in float32 ``highest``, the kernel no further off than
+:data:`INDEX_LOSS_ROOM` x what the XLA form is (or :data:`INDEX_LOSS_FLOOR`
+of the largest entry); and what a float32 ``dot`` at the default precision
+carries on the device (the parent's two gradient contractions).
+``--index-loss-bisect`` adds the kernel with parts of its cell body taken
+out, its other forms and other tiles (:func:`_index_loss_variant`; ISSUE 64's
+bisect: which unit binds).
+
 Shapes: flash at the long-context recipe's per-chip shape (seq 16,384, head
 dim 128; two heads so the dense reference's [s, s] scores fit beside it);
 the mixer at the flagship's (8 heads, seq 512, 512 features/head, batch 32).
@@ -114,6 +129,16 @@ KDA_RULE_ROOM_REHEARSAL = 1.5
 #: both forms and the ratio of two roundings is not read (2^-8: one bfloat16
 #: rounding of the largest entry)
 KDA_RULE_FLOOR = 2.0 ** -8
+
+
+#: the index-loss kernel against the XLA form, an output's error over the
+#: other's against float32 ``highest`` on the same operands (PR 59's rule for
+#: the kda rule): both round the matmuls' operands to bfloat16 and keep every
+#: plane float32, so they are an order of summation apart
+INDEX_LOSS_ROOM = 1.1
+#: below this share of the reference's largest entry both forms hold an
+#: output and the ratio of two roundings is not read
+INDEX_LOSS_FLOOR = 2.0 ** -12
 
 
 #: the solve's bound against float64, as a share of the largest entry: what
@@ -852,6 +877,400 @@ def _select_leg(block: int, s: int, heads: int, kv_heads: int, d: int = 128,
     return bool(ok)
 
 
+#: the index-loss kernel's cell body as ISSUE 64's bisect varies it.
+#: ``pbar``: the 32 heads' probabilities whole, without their ``exp`` (a
+#: time, not a result), or not at all; ``backward``: the index heads'
+#: backward whole, without its two matmuls, or not at all; ``normaliser``:
+#: the scores' ``logsumexp`` by a sweep of its own (the library's form (a)),
+#: by a sweep that also leaves the q tile's whole score ROW in VMEM for the
+#: second to read (form (b)), or none (a time); ``stack``: one matmul a K/V
+#: group and one for all index heads, or one a head; ``rows``: the
+#: elementwise passes over a cell's planes whole (0), or so many query rows
+#: at a time with the running sums of a block of rows carried through the
+#: heads (the same operations in another order: a block's accumulator is 32
+#: vregs at 64 rows)
+INDEX_LOSS_PARTS = {"pbar": "full", "backward": "full", "normaliser": "sweep",
+                    "stack": True, "rows": 0}
+
+
+def _index_loss_variant(name, tiles, pbar, backward, normaliser, stack,
+                        rows=0, interpret=None):
+    """``parallel/index_loss.py index_loss_pass`` with parts of its cell body
+    swapped or taken out (``INDEX_LOSS_PARTS``), as a kernel named ``name``:
+    the same operands, grid, table and results."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from homebrewnlp_tpu.parallel import flash_attention as fa
+    from homebrewnlp_tpu.parallel import index_loss as il
+
+    tq, tk = tiles
+    lanes = min(128, tk)
+    row = normaliser == "row"
+
+    def kernel(qi_ref, sweep_ref, kk_ref, q_ref, k_ref, qx_ref, kx_ref, w_ref,
+               lse_ref, keep_ref, val_ref, top_ref, dq_ref, gk_ref, dw_ref,
+               lse_rep, w_rep, m_ref, l_ref, norm_ref, dw_acc, val_acc,
+               top_acc, dq_acc, *row_ref, heads, group, index_heads, scale,
+               i_scale, inv_rows, **_):
+        t = pl.program_id(1)
+        qi, sweep, kk = qi_ref[t], sweep_ref[t], kk_ref[t]
+        last = (qi * tq + tq - 1) // tk
+        f = q_ref.shape[1] // heads
+        d = kx_ref.shape[1]
+
+        @pl.when(t == 0)
+        def _first():
+            gk_ref[...] = jnp.zeros_like(gk_ref)
+
+        @pl.when((sweep == 0) & (kk == 0))
+        def _start():
+            for h in range(heads):
+                lse_rep[h] = jnp.broadcast_to(lse_ref[h:h + 1, :],
+                                              (lanes, tq)).T
+            for j in range(index_heads):
+                w_rep[j] = jnp.broadcast_to(w_ref[j:j + 1, :] * i_scale,
+                                            (lanes, tq)).T
+            m_ref[...] = jnp.full_like(m_ref, fa._NEG_INF)
+            for ref in (l_ref, dw_acc, val_acc, top_acc, dq_acc, norm_ref):
+                ref[...] = jnp.zeros_like(ref)
+
+        seen = fa._select_seen(keep_ref, qi, kk, tq, tk, 1)
+        nt = (((1,), (1,)), ((), ()))
+
+        def raw_scores():
+            if stack:
+                return jax.lax.dot_general(
+                    qx_ref[...].reshape(index_heads * tq, d), kx_ref[...],
+                    nt, preferred_element_type=jnp.float32)
+            return jnp.concatenate([jax.lax.dot_general(
+                qx_ref[j], kx_ref[...], nt,
+                preferred_element_type=jnp.float32)
+                for j in range(index_heads)], axis=0)
+
+        blocks = [(r0, rows or tq) for r0 in range(0, tq, rows or tq)]
+
+        def weighted(raw):
+            parts = []
+            for r0, n in blocks:
+                total = jnp.zeros((n, tk), jnp.float32)
+                for j in range(index_heads):
+                    total = total + il._lanes(w_rep[j, r0:r0 + n], tk) \
+                        * jnp.maximum(raw[j * tq + r0:j * tq + r0 + n], 0.0)
+                parts.append(total)
+            return parts[0] if len(parts) == 1 else jnp.concatenate(parts, 0)
+
+        if normaliser != "none":
+            @pl.when(sweep == 0)
+            def _normaliser():
+                score = weighted(raw_scores())
+                if row:
+                    row_ref[0][:, pl.ds(pl.multiple_of(kk * tk, tk), tk)] \
+                        = score
+                score = jnp.where(seen, score, -jnp.inf)
+                m_prev = m_ref[...]
+                m_new = jnp.maximum(m_prev, il._fold(score, lanes,
+                                                     jnp.maximum))
+                l_new = l_ref[...] * jnp.exp(m_prev - m_new) + il._fold(
+                    jnp.exp(score - il._lanes(m_new, tk)), lanes, jnp.add)
+                m_ref[...] = m_new
+                l_ref[...] = l_new
+
+                @pl.when(kk == last)
+                def _merge():
+                    big = jnp.broadcast_to(m_new.max(-1, keepdims=True),
+                                           (tq, lanes))
+                    total = jnp.sum(l_new * jnp.exp(m_new - big), -1,
+                                    keepdims=True)
+                    norm_ref[...] = big + jnp.log(jnp.maximum(
+                        jnp.broadcast_to(total, (tq, lanes)), 1e-30))
+
+        @pl.when(sweep == 1)
+        def _loss():
+            logits = {}
+            for kv in range(heads // group if pbar != "none" else 0):
+                members = range(kv * group, (kv + 1) * group)
+                k_head = k_ref[:, kv * f:(kv + 1) * f]
+                if stack:
+                    whole = jax.lax.dot_general(
+                        jnp.concatenate([q_ref[:, h * f:(h + 1) * f]
+                                         for h in members], axis=0),
+                        k_head, nt, preferred_element_type=jnp.float32)
+                for r, h in enumerate(members):
+                    logits[h] = whole[r * tq:(r + 1) * tq] if stack else \
+                        jax.lax.dot_general(
+                            q_ref[:, h * f:(h + 1) * f], k_head, nt,
+                            preferred_element_type=jnp.float32)
+            parts = []
+            for r0, n in blocks:
+                total = jnp.zeros((n, tk), jnp.float32)
+                for h, plane in logits.items():
+                    part = plane[r0:r0 + n] * scale - il._lanes(
+                        lse_rep[h, r0:r0 + n], tk)
+                    total = total + (jnp.exp(part) if pbar == "full"
+                                     else part)
+                parts.append(total)
+            mean = parts[0] if len(parts) == 1 else jnp.concatenate(parts, 0)
+            mean = jnp.where(seen, mean, 0.0) * (1.0 / heads)
+            raw = raw_scores()
+            score = row_ref[0][:, pl.ds(pl.multiple_of(kk * tk, tk), tk)] \
+                if row else weighted(raw)
+            log_index = score - il._lanes(norm_ref[...], tk)
+            val_acc[...] += il._fold(jnp.where(mean > 0, mean * (jnp.log(
+                jnp.maximum(mean, 1e-38)) - log_index), 0.0), lanes, jnp.add)
+            top_acc[...] = jnp.maximum(top_acc[...], il._fold(
+                jnp.where(seen, jnp.abs(score), 0.0), lanes, jnp.maximum))
+            d_score = jnp.where(seen, jnp.exp(log_index) - mean, 0.0) \
+                * inv_rows
+            if backward != "none":
+                planes = [[] for _ in range(index_heads)]
+                for r0, n in blocks:
+                    d_block = d_score[r0:r0 + n]
+                    for j in range(index_heads):
+                        raw_j = raw[j * tq + r0:j * tq + r0 + n]
+                        dw_acc[j, r0:r0 + n] += il._fold(
+                            d_block * jnp.maximum(raw_j, 0.0), lanes, jnp.add)
+                        planes[j].append(jnp.where(
+                            raw_j > 0, d_block * il._lanes(
+                                w_rep[j, r0:r0 + n], tk), 0.0
+                        ).astype(kx_ref.dtype))
+                d_logits = [x[0] if len(x) == 1 else jnp.concatenate(x, 0)
+                            for x in planes]
+                keys = pl.ds(pl.multiple_of(kk * tk, tk), tk)
+                if backward == "no_matmul":
+                    # the planes are made and read, no matmul eats them
+                    dw_acc[0] += il._fold(sum(
+                        x.astype(jnp.float32) for x in d_logits), lanes,
+                        jnp.add)
+                elif stack:
+                    d_logits = jnp.concatenate(d_logits, axis=0)
+                    dq_acc[...] += jax.lax.dot_general(
+                        d_logits, kx_ref[...], (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32
+                    ).reshape(index_heads, tq, d)
+                    gk_ref[keys, :] += jax.lax.dot_general(
+                        d_logits, qx_ref[...].reshape(index_heads * tq, d),
+                        (((0,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                else:
+                    for j, plane in enumerate(d_logits):
+                        dq_acc[j] += jax.lax.dot_general(
+                            plane, kx_ref[...], (((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+                        gk_ref[keys, :] += jax.lax.dot_general(
+                            plane, qx_ref[j], (((0,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+            else:
+                dw_acc[0] += il._fold(d_score, lanes, jnp.add)
+
+            @pl.when(kk == last)
+            def _finish():
+                fold = (tq // val_ref.shape[0], val_ref.shape[0], lanes)
+                val_ref[...] = val_acc[...].reshape(fold).sum(0)
+                top_ref[...] = top_acc[...].reshape(fold).max(0)
+                for j in range(index_heads):
+                    dw_ref[j:j + 1, :] = jnp.sum(
+                        dw_acc[j].T, axis=0, keepdims=True) * i_scale
+                    dq_ref[:, j * d:(j + 1) * d] = dq_acc[j]
+
+    def run(*operands, scale):
+        s = operands[3].shape[1]
+        return il._call(
+            kernel, name, *operands, scale, tiles,
+            jax.devices()[0].platform == "cpu" if interpret is None
+            else interpret,
+            more_scratch=((tq, s),) if row else (),
+            vmem_limit=100 * 1024 * 1024)
+    return run
+
+
+def _module_ms(run, calls: int = 3) -> float:
+    """ms a call of ``run()``'s whole device program by the device trace's
+    module line (the host's clock on the CPU, whose trace holds no device)."""
+    import tempfile
+    import time
+
+    import jax
+
+    from benchmark.trace import reduce
+
+    jax.block_until_ready(run())
+    if jax.devices()[0].platform == "cpu":
+        start = time.perf_counter()
+        for _ in range(calls):
+            out = run()
+        jax.block_until_ready(out)
+        return round((time.perf_counter() - start) * 1000 / calls, 3)
+    with tempfile.TemporaryDirectory() as trace_dir:
+        with jax.profiler.trace(trace_dir):
+            for _ in range(calls):
+                out = run()
+            jax.block_until_ready(out)
+        modules = reduce.load(reduce.newest_xplane(trace_dir)
+                              ).devices[0].modules
+    return round(sum(m.seconds for m in modules) * 1000 / calls, 3)
+
+
+def _index_loss_operands(s: int, heads: int, kv_heads: int, f: int,
+                         index_heads: int, d: int, topk: int):
+    """The Keye-VL-2.0 cell's layer at its seeded start: bfloat16 attention
+    and index operands, float32 weights times ``index_heads ** -0.5``, a
+    seeded top-``topk`` choice of scattered keys as bits, and the selected
+    forward's own ``lse`` over it."""
+    import jax
+    import jax.numpy as jnp
+
+    from homebrewnlp_tpu.parallel import flash_attention as fa
+
+    def normal(n, shape, dtype=jnp.bfloat16):
+        return jax.random.normal(jax.random.PRNGKey(64 + n), shape,
+                                 jnp.float32).astype(dtype)
+
+    q, k, v = (normal(n, (1, s, h, f))
+               for n, h in enumerate((heads, kv_heads, kv_heads)))
+    q_index = normal(3, (1, s, index_heads, d))
+    k_index = normal(4, (1, s, d))
+    weight = normal(5, (1, s, index_heads), jnp.float32) * index_heads ** -0.5
+    keep = _select_choice(1, q, k, min(topk, s // 4), 64)
+    scale = f ** -0.5
+    if jax.devices()[0].platform == "cpu":
+        lse = jax.jit(lambda q, k, v: fa._xla_select_with_lse(
+            q, k, v, keep, scale, 1))(q, k, v)[1]
+    else:
+        lse = jax.jit(lambda q, k, v: fa._select_fwd_impl(
+            q, k, v, keep, scale, 1, False))(q, k, v)[1]
+    return (q_index, k_index, weight, q, k, lse, keep), scale
+
+
+def _dot_precision_probe(d_logits, k_index):
+    """What a float32 ``dot`` at the default precision carries on this device
+    (``model/indexer.py xla_index_loss``'s two gradient contractions): its
+    result against the same contraction of operands rounded to bfloat16
+    first, and against ``highest``, each over the largest entry."""
+    import jax
+    import jax.numpy as jnp
+
+    a = d_logits.astype(jnp.float32)
+    b = k_index.astype(jnp.float32)
+    default = jax.jit(lambda a, b: jnp.einsum("ns,sd->nd", a, b))(a, b)
+    rounded = jax.jit(lambda a, b: jnp.einsum(
+        "ns,sd->nd", a.astype(jnp.bfloat16), b.astype(jnp.bfloat16),
+        preferred_element_type=jnp.float32))(a, b)
+    with jax.default_matmul_precision("highest"):
+        highest = jax.jit(lambda a, b: jnp.einsum("ns,sd->nd", a, b))(a, b)
+    top = float(jnp.max(jnp.abs(highest)))
+    return {"default_to_bfloat16_operands":
+            float(jnp.max(jnp.abs(default - rounded))) / top,
+            "default_to_highest":
+            float(jnp.max(jnp.abs(default - highest))) / top}
+
+
+def _index_loss_leg(s: int = 16384, heads: int = 32, kv_heads: int = 4,
+                    f: int = 128, index_heads: int = 16, d: int = 64,
+                    topk: int = 2048, bisect: bool = False) -> bool:
+    """The index-loss kernel ALONE at the Keye-VL-2.0 cell's shape beside the
+    XLA form: each's ms a call by the device trace, and the five outputs of
+    both against the XLA form in float32 ``highest``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from homebrewnlp_tpu.model import indexer
+    from homebrewnlp_tpu.parallel import index_loss as il
+
+    platform = jax.devices()[0].platform
+    interpret = platform == "cpu"
+    operands, scale = _index_loss_operands(s, heads, kv_heads, f, index_heads,
+                                           d, topk)
+    tiles = il.index_loss_tile(s)
+    if interpret and tiles is None:
+        tiles = (min(64, s), min(128, s))
+    names = ("value", "top", "d_q", "d_k", "d_w")
+    kernel = jax.jit(lambda *t: il.index_loss_pass(
+        *t, scale, tiles=tiles, interpret=interpret))
+    xla = jax.jit(lambda *t: indexer.xla_index_loss(*t, scale))
+    with jax.default_matmul_precision("highest"):
+        want = jax.block_until_ready(jax.jit(
+            lambda *t: indexer.xla_index_loss(*t, scale))(*(
+                t.astype(jnp.float32) if t.dtype == jnp.bfloat16 else t
+                for t in operands)))
+    errs = {name: dict(zip(names, (round(e, 7) for e in _errors(
+        jax.block_until_ready(fn(*operands)), want).values())))
+        for name, fn in (("kernel", kernel), ("xla", xla))}
+    ms = {"kernel": _kernel_ms(lambda: kernel(*operands), "index_loss_pass"),
+          "xla_module": _module_ms(lambda: xla(*operands)),
+          "kernel_module": _module_ms(lambda: kernel(*operands))}
+    ok = (platform == "cpu" or il.kernel_applies(s, True)
+          ) and all(e <= max(INDEX_LOSS_FLOOR,
+                             INDEX_LOSS_ROOM * errs["xla"][name])
+                    for name, e in errs["kernel"].items())
+    probe = _dot_precision_probe(
+        jax.random.normal(jax.random.PRNGKey(7), (512, min(s, 4096))) * 1e-4,
+        operands[1][0, :min(s, 4096)])
+    print(json.dumps({
+        "kernel": "index_loss_pass", "ok": bool(ok),
+        "implementation": "pallas (interpret)" if interpret else "pallas",
+        "tiles": list(tiles),
+        "walked_over_visible_pairs": {
+            "kernel": round(il.walked_over_visible(s, tiles), 4),
+            "xla": round(indexer.walked_over_visible(s, False), 4)},
+        "max_err_over_max_ref": errs,
+        "tolerance": f"{INDEX_LOSS_ROOM} x the XLA form's, or "
+                     f"{INDEX_LOSS_FLOOR}",
+        "ms_a_call": ms, "float32_dot_at_default_precision": probe,
+        "shapes": [list(t.shape) for t in operands],
+        "dtype": "bfloat16"}), flush=True)
+    if not bisect:
+        return bool(ok)
+    # the kernel with parts of its body taken out or swapped, and at other
+    # tiles (PERF.md section 6, PR 64, has the table)
+    parts = INDEX_LOSS_PARTS
+    tq, tk = tiles
+    variants = [
+        ("library_body", tiles, parts),
+        ("no_pbar_exp", tiles, {**parts, "pbar": "no_exp"}),
+        ("no_pbar", tiles, {**parts, "pbar": "none"}),
+        ("no_backward_matmuls", tiles, {**parts, "backward": "no_matmul"}),
+        ("no_backward", tiles, {**parts, "backward": "none"}),
+        ("no_normaliser", tiles, {**parts, "normaliser": "none"}),
+        ("score_row_in_vmem", tiles, {**parts, "normaliser": "row"}),
+        ("a_matmul_a_head", tiles, {**parts, "stack": False}),
+        ("wide", (tq, 2 * tk), parts),
+        ("tall", (2 * tq, tk), parts),
+        ("tall_a_matmul_a_head", (2 * tq, tk), {**parts, "stack": False}),
+        ("narrow", (tq, tk // 2), parts),
+        ("rows_64", tiles, {**parts, "rows": min(64, tq)}),
+        ("rows_32", tiles, {**parts, "rows": min(32, tq)}),
+        ("rows_64_a_matmul_a_head", tiles, {**parts, "rows": min(64, tq),
+                                            "stack": False}),
+        ("tall_rows_64", (2 * tq, tk), {**parts, "rows": min(64, tq)}),
+    ]
+    library = [np.asarray(x, np.float32) for x in kernel(*operands)]
+    for name, shape, chosen in variants:
+        if s % shape[0] or s % shape[1]:
+            continue
+        kernel_name = f"index_loss_{name}"
+        try:
+            run = jax.jit(functools.partial(_index_loss_variant(
+                kernel_name, shape, **chosen), scale=scale))
+            ms = _kernel_ms(lambda: run(*operands), kernel_name)
+            diff = {n: float(np.max(np.abs(np.asarray(x, np.float32) - w))
+                             / max(np.max(np.abs(w)), 1e-30))
+                    for n, x, w in zip(names, run(*operands), library)}
+        except Exception as e:  # Mosaic refuses a shape: a finding
+            print(json.dumps({"variant": name, "refused": repr(e)[:400]}),
+                  flush=True)
+            continue
+        print(json.dumps({
+            "variant": name, "tiles": list(shape), **chosen,
+            "ms_a_call": ms.get(kernel_name, ms["wall"]),
+            "max_diff_to_the_library_over_its_max": {
+                n: round(v, 7) for n, v in diff.items()}}), flush=True)
+    return bool(ok)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--flash-seq", type=int, default=16384)
@@ -870,6 +1289,14 @@ def main(argv=None) -> int:
     ap.add_argument("--kda-rule-seq", type=int, default=16384)
     ap.add_argument("--only-kda-rule", action="store_true",
                     help="run layer kda's chunked rule's leg alone")
+    ap.add_argument("--index-loss-seq", type=int, default=16384)
+    ap.add_argument("--only-index-loss", action="store_true",
+                    help="run the index-loss kernel's leg alone, at the "
+                         "Keye-VL-2.0 cell's shape")
+    ap.add_argument("--index-loss-bisect", action="store_true",
+                    help="with --only-index-loss: also time the kernel with "
+                         "parts of its cell body taken out and its other "
+                         "forms")
     ap.add_argument("--select-seq", type=int, default=16384)
     ap.add_argument("--only-select", action="store_true",
                     help="run the selected flash kernels' leg alone, both "
@@ -888,6 +1315,11 @@ def main(argv=None) -> int:
     from homebrewnlp_tpu.parallel import flash_attention as flash
     from homebrewnlp_tpu.parallel import map_mixer
 
+    if args.only_index_loss:
+        ok = _index_loss_leg(args.index_loss_seq,
+                             bisect=args.index_loss_bisect)
+        print(json.dumps({"ok": bool(ok)}), flush=True)
+        return 0 if ok else 1
     if args.only_select:
         tiles = [tuple(int(n) for n in pair.split("x"))
                  for pair in args.select_tiles.split(",") if pair]

@@ -154,7 +154,9 @@ _ALWAYS = ("hbnlp_ssd_state_bytes", "hbnlp_mamba_conv_kernel_layers",
            "hbnlp_delta_solve_kernel_layers", "hbnlp_delta_rule_kernel_layers",
            "hbnlp_ssd_scan_kernel_layers", "hbnlp_flash_band_layers")
 _SPARSE = ("hbnlp_moe_held_rows_bound", "hbnlp_router_carry_bytes",
-           "hbnlp_flash_scored_over_live_pairs")
+           "hbnlp_flash_scored_over_live_pairs",
+           "hbnlp_index_loss_kernel_layers",
+           "hbnlp_index_loss_walked_over_visible_pairs")
 
 
 @pytest.fixture
@@ -241,8 +243,14 @@ def _config_files():
 #: files — the cell's reads ``attention 7 layers, 1695547420`` on both sides:
 #: a layer's ``(out, lse)``, its choice as bits and the index loss's
 #: gradients —: without them the digest is PR 61's
-#: 9ecdcf46a27f8dec5a5319d33aeb28b42ab64363, every other line as it was)
-_FILE_DIGEST = "71a59d30fb05fedc6feb56ee36b3684de7f15891"
+#: 9ecdcf46a27f8dec5a5319d33aeb28b42ab64363, every other line as it was;
+#: PR 64: the two Keye-VL-2.0 files alone gain ``; index loss kernel N
+#: layers; index loss walked over visible pairs X`` and the two series
+#: ``hbnlp_index_loss_kernel_layers`` / ``..._walked_over_visible_pairs`` — 7
+#: (the cell's file) and 48 layers at 1.03119 on a TPU, 0 at 1.24992 on the
+#: CPU —, the other 29 files as they were: before it
+#: 71a59d30fb05fedc6feb56ee36b3684de7f15891)
+_FILE_DIGEST = "fc574cb9973bd6b1ce062da7b715e870e530095f"
 
 
 def every_configuration_file_starts_as_on_the_parent_test(monkeypatch):
@@ -418,7 +426,9 @@ def facts_are_declared_once_in_line_order_test():
         "hbnlp_delta_solve_kernel_layers", "hbnlp_delta_rule_kernel_layers",
         "hbnlp_ssd_scan_kernel_layers", "hbnlp_moe_held_rows_bound",
         "hbnlp_router_carry_bytes", "hbnlp_flash_band_layers",
-        "hbnlp_flash_scored_over_live_pairs"]
+        "hbnlp_flash_scored_over_live_pairs",
+        "hbnlp_index_loss_kernel_layers",
+        "hbnlp_index_loss_walked_over_visible_pairs"]
     assert [fact.metric for fact in facts if fact.zero] == list(_ALWAYS)
     assert [fact.metric for fact in facts if not fact.zero] == list(_SPARSE)
     assert len({fact.place for fact in facts}) == len(facts)

@@ -571,7 +571,8 @@ def compiled_for_a_v5e_the_layer_runs_the_key_kernels_once_test(
     dq and one dk/dv call, all named ``flash_*_select`` under ``attend``; and
     the compiled program's ops name ``index``, ``select``, ``attend`` and
     ``index_loss`` under ``sparse_attention``, each a scope of the cost
-    ledger's."""
+    ledger's.  The index loss is ``index_loss_pass``, once, under scope
+    ``index_loss`` (ISSUE 64)."""
     params, hlo = harness.cell_layer_hlo(v5e, monkeypatch, CELL, 0, depth=1)
     plan = remat.stash_plan(params)
     assert plan["attention"][0] == 1
@@ -584,9 +585,19 @@ def compiled_for_a_v5e_the_layer_runs_the_key_kernels_once_test(
         + 4 * (1 + 16384 * (16 * 65 + 64))
     calls = harness.kernel_calls(hlo)
     assert sorted(name for name, _ in calls) == [
-        "flash_bwd_dkv_select", "flash_bwd_dq_select", "flash_fwd_select"]
-    for _, op_name in calls:
-        assert scope_key(op_name) == "body/attention/sparse_attention/attend"
+        "flash_bwd_dkv_select", "flash_bwd_dq_select", "flash_fwd_select",
+        "index_loss_pass"]
+    for name, op_name in calls:
+        assert scope_key(op_name) == "body/attention/sparse_attention/" + (
+            "index_loss" if name == "index_loss_pass" else "attend")
     found = {scope_key(name) for name in re.findall(r'op_name="([^"]*)"', hlo)}
     for step in ("index", "select", "attend", "index_loss"):
         assert f"body/attention/sparse_attention/{step}" in found, step
+    # ISSUE 64: the index loss is ONE kernel a layer — its results are named
+    # and kept, so the replay runs none — and no ``[512, keys]`` float32
+    # plane of the XLA form is left under its scope
+    assert spatial.index_loss_kernel_layers(params, "tpu") == 1
+    planes = [line for line in hlo.splitlines()
+              if "sparse_attention/index_loss" in line
+              and re.search(r"= f32\[(1,)?512,(4096|8192|12288|16384)\]", line)]
+    assert not planes, planes[:3]
