@@ -84,13 +84,14 @@ _ROUTER_PARTS = frozenset(("down", "carry", "mlp"))
 #: kernels stay in ``body/cca`` itself
 _CCA_PARTS = frozenset(("in_proj", "qk_mean", "conv", "qk_norm", "rope",
                         "value_shift", "out_proj"))
-#: the standard attention's per-head output gate and the projections of its
-#: latent form (flag ``kv_latent<n>``; model/spatial.py), each a scope of its
-#: own below ``body/attention``; the latent form's ``attend`` (the flash
+#: the standard attention's per-head output gate, and the projections and
+#: the rotary of its latent form (flags ``kv_latent<n>``, ``q_latent<n>``,
+#: ``rope``; model/spatial.py), each a scope of its own below
+#: ``body/attention``; the latent form's ``attend`` (the flash
 #: kernels and what names their outputs) stays in ``body/attention`` itself,
 #: as ``cca``'s kernels stay in ``body/cca``
-_ATTENTION_PARTS = frozenset(("gate", "q_proj", "kv_down", "kv_norm", "kv_up",
-                              "out_proj"))
+_ATTENTION_PARTS = frozenset(("gate", "q_down", "q_norm", "q_proj", "kv_down",
+                              "kv_norm", "kv_up", "latent_rope", "out_proj"))
 #: the steps of attention flag ``sparse`` (model/sparse.py; ``attend`` holds
 #: the selected kernels) and of flag ``indexed`` (model/indexer.py: ``index``,
 #: ``select``, ``attend`` and its own ``index_loss``) below
@@ -135,15 +136,41 @@ def _basename(comp: str) -> str:
     return comp.rstrip("0123456789").rstrip("_")
 
 
+#: a multi-token-prediction module (model/mtp.py): everything below scope
+#: ``mtp`` folds as it would in the main model, under ``mtp/`` — ``mtp/join``,
+#: ``mtp/body/<layer>[/<part>]``, ``mtp/output``, ``mtp/head_loss``
+_MTP = "mtp"
+_MTP_PARTS = frozenset(("join",))
+
+
 def scope_key(path: str) -> str:
-    """Fold a name-stack / HLO ``op_name`` path into a coarse model scope.
+    """Fold a name-stack / HLO ``op_name`` path into a coarse model scope:
+    :func:`_model_scope_key`, and what lies below scope ``mtp`` (a
+    multi-token-prediction module, model/mtp.py) folded the same way under
+    ``mtp/`` (``mtp/join``, ``mtp/body/attention/q_proj``, ``mtp/head_loss``,
+    ..; ``mtp`` itself for what names nothing below it).  The optimizer's
+    update of the module's parameters is ``optimizer``'s."""
+    comps = str(path).split("/")
+    bases = [_basename(_unwrap(comp)) for comp in comps]
+    if _MTP not in bases or "optimizer" in bases:
+        return _model_scope_key(path)
+    below = bases[bases.index(_MTP) + 1:]
+    part = next((base for base in below if base in _MTP_PARTS), None)
+    if part is not None:
+        return f"{_MTP}/{part}"
+    key = _model_scope_key("/".join(below))
+    return _MTP if key == "unscoped" else f"{_MTP}/{key}"
+
+
+def _model_scope_key(path: str) -> str:
+    """The main model's fold of a name-stack path (see :func:`scope_key`).
 
     Keys: ``decode/cache_read|cache_write|sampling``, ``optimizer``,
     ``head_loss``, ``input/embed``, ``input``, ``body/<layer>``,
     ``body/moe/router|dispatch|experts|combine|shared|latent_down|
     latent_up``,
-    ``body/moe/router/down|carry|mlp``, ``body/attention/gate|q_proj|kv_down|
-    kv_norm|kv_up|out_proj``,
+    ``body/moe/router/down|carry|mlp``, ``body/attention/gate|q_down|q_norm|
+    q_proj|kv_down|kv_norm|kv_up|latent_rope|out_proj``,
     ``body/attention/sparse_attention/compress|index|select|attend|
     index_loss``,
     ``body/lightning/in_proj|qk_norm|rope|rule|gate_norm|out_proj``,

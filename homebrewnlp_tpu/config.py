@@ -335,6 +335,15 @@ class ModelParameter:
         # read where loop_steps > 1).  1 = the body runs once
         self.loop_steps = 1
         self.loop_exit_entropy = 0.1
+        # a multi-token-prediction module, model/mtp.py (DeepSeek-V3,
+        # arXiv:2412.19437 section 2.2): mtp_depth more passes of the SAME
+        # head, each over the last output joined to the next token's
+        # embedding and through the blocks of mtp_block_config (own
+        # weights), with its own cross-entropy added to the step's objective
+        # at mtp_loss_weight.  0 = no module, and nothing of it is read
+        self.mtp_depth = 0
+        self.mtp_loss_weight = 0.3
+        self.mtp_block_config: typing.Any = []
         self.pkm_axes = 2
         self.use_bit_fold_input_pipeline = False
         self.bit_fold_value = 4
@@ -1079,6 +1088,44 @@ class ModelParameter:
                 raise ValueError(f"loop_steps {self.loop_steps} (a looped "
                                  "model, model/loop.py) refuses "
                                  + "; ".join(refused))
+        if not isinstance(self.mtp_depth, int) \
+                or isinstance(self.mtp_depth, bool) or self.mtp_depth < 0:
+            raise ValueError(f"mtp_depth {self.mtp_depth!r} must be a whole "
+                             "number >= 0")
+        if self.mtp_depth:
+            weight = self.mtp_loss_weight
+            if isinstance(weight, bool) \
+                    or not isinstance(weight, (int, float)) or weight < 0:
+                raise ValueError(f"mtp_loss_weight {weight!r} must be a "
+                                 "number >= 0")
+            refused = [why for why, hit in (
+                ("no mtp_block_config (the module's blocks)",
+                 not self.mtp_block_config),
+                (f"loop_steps {self.loop_steps} (a looped model)",
+                 self.loop_steps > 1),
+                (f"memory_reduction_strategy "
+                 f"{self.memory_reduction_strategy!r} (the module's blocks "
+                 "join no revnet or momentum stream: \"checkpoint\" or "
+                 "\"none\")",
+                 self.memory_reduction_strategy not in ("none",
+                                                        "checkpoint")),
+                ("scan_layers", self.scan_layers),
+                ("use_video", self.use_video),
+                ("a contrastive loss", self.contrastive_across_samples
+                 or self.contrastive_across_token_embeddings),
+                (f"multi_loss_strategy {self.multi_loss_strategy!r}",
+                 self.multi_loss_strategy != "linear"),
+                ("a factorized or patched token embedding",
+                 bool(self.vocab_weight_factorization)
+                 or self.token_patch_size != 1),
+                (f"sequence_length {self.sequence_length} under "
+                 f"{self.mtp_depth + 1}",
+                 self.sequence_length <= self.mtp_depth)) if hit]
+            if refused:
+                raise ValueError(f"mtp_depth {self.mtp_depth} (a "
+                                 "multi-token-prediction module, "
+                                 "model/mtp.py) refuses "
+                                 + "; ".join(refused))
         if self.tie_word_embeddings and (self.vocab_weight_factorization
                                          or self.token_patch_size != 1
                                          or self.use_video):
@@ -1151,6 +1198,12 @@ class ModelParameter:
                 "model/loop.py) refuses a pipeline mesh: pipeline_stages "
                 f"{self.pipeline_stages} (a stage's blocks are not "
                 "re-entered)")
+        if self.pipeline_stages > 1 and self.mtp_depth:
+            raise ValueError(
+                f"mtp_depth {self.mtp_depth} (a multi-token-prediction "
+                "module, model/mtp.py) refuses a pipeline mesh: "
+                f"pipeline_stages {self.pipeline_stages} (the module reads "
+                "the last stage's output and the first stage's table)")
         if self.pipeline_stages > 1 and self.depth % self.pipeline_stages:
             raise ValueError(
                 f"depth={self.depth} must divide into pipe={self.pipeline_stages} stages")
@@ -1190,6 +1243,8 @@ class ModelParameter:
                              for c in self.block_config]
         self.input_block_config = [BlockConfig(c, "checkpoint") for c in self.input_block_config]
         self.output_block_config = [BlockConfig(c, "checkpoint") for c in self.output_block_config]
+        self.mtp_block_config = [BlockConfig(c, self.memory_reduction_strategy)
+                                 for c in self.mtp_block_config]
 
         self.time_patch_size = self.sequence_length // self.time_patch
         self.frame_height_patch = self.frame_height // self.patch_size

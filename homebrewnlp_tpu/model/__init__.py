@@ -45,6 +45,10 @@ class LossInfo(typing.NamedTuple):
     #: ``Model.apply(layer_stats=True)`` ran the plain residual stream
     #: (layer moe's expert load; model/blocks.py), else None
     layer_stats: typing.Optional[dict] = None
+    #: what the step differentiates where that is more than ``total_loss``
+    #: (a multi-token-prediction module's loss at its weight on top,
+    #: model/mtp.py: float32), else None
+    objective: typing.Any = None
 
 
 def _default_ones(params: ModelParameter, inp) -> NamedTensor:
@@ -332,16 +336,29 @@ def _build(params: ModelParameter, vid, cat_msk_src, cat_msk_tgt, txt_src,
                                 txt_src, vid_msk_src, spatial_ctx, storage)
     if params.loop_steps > 1:
         return _build_looped(params, src, txt_tgt, spatial_ctx, storage, plan)
-    out, plan = scope.scoped("body", _body, params, src, plan)
+    # a multi-token-prediction module's blocks follow the body's in the plan
+    blocks = params.depth * len(params.block_config)
+    out, body_plan = scope.scoped("body", _body, params, src,
+                                  None if plan is None else plan[:blocks])
     frame_out, token_out = scope.scoped("output", _output, params, out,
                                         spatial_ctx, storage)
     loss_list, token_loss, accuracy, video_loss = scope.scoped(
         "loss", _loss, params, frame_out, token_out, txt_tgt, loss_list,
         vid_msk_tgt, cat_msk_tgt, vid_tgt, storage)
+    info = LossInfo(add_n(loss_list), loss_list, video_loss, accuracy,
+                    token_loss, frame_out, token_out)
+    if params.mtp_depth:
+        from .mtp import module_loss
+        mtp_loss, mtp_plan = scope.scoped(
+            "mtp", module_loss, params, storage, txt_tgt, token_loss,
+            None if plan is None else plan[blocks:])
+        body_plan += mtp_plan
+        info = info._replace(objective=nt(
+            info.total_loss.data.astype(jnp.float32)
+            + params.mtp_loss_weight * mtp_loss, ()))
 
     params.attention_idx = 0
-    return LossInfo(add_n(loss_list), loss_list, video_loss, accuracy,
-                    token_loss, frame_out, token_out), plan
+    return info, body_plan
 
 
 def build(params: ModelParameter, vid, cat_msk_src, cat_msk_tgt, txt_src,
@@ -357,6 +374,12 @@ def _refuse_looped(params: ModelParameter, what: str) -> None:
             f"{what} of a looped model (loop_steps {params.loop_steps}): the "
             "passes' KV caches and the exit by threshold are not built; a "
             "looped model trains and runs its full forward only")
+    if params.mtp_depth:
+        raise NotImplementedError(
+            f"{what} of a model with a multi-token-prediction module "
+            f"(mtp_depth {params.mtp_depth}, model/mtp.py): the module as a "
+            "self-drafting head is not built; it trains and runs its full "
+            "forward only")
 
 
 class Model:

@@ -466,12 +466,17 @@ def _named_policy(named: str, names: typing.Tuple[str, ...]):
 
 
 def _region(params: ModelParameter, loop_pass: int, depth_idx: int,
-            cfg_idx: int) -> int:
+            cfg_idx: int, module: bool = False) -> int:
     """A body block's ``jax.checkpoint`` region's place among the step's, in
     execution order: a looped model's passes (model/loop.py) outermost.  A
     scanned body traces ONE block a ``cfg_idx`` for all its iterations
     (``depth_idx`` 0): model/remat.py admits a scanned body's executions all
-    together or not at all, so the one policy is every iteration's."""
+    together or not at all, so the one policy is every iteration's.
+    ``module``: a block of the multi-token-prediction module (model/mtp.py),
+    whose regions follow the body's (``depth_idx``: the module's pass)."""
+    if module:
+        return params.loop_steps * params.depth * len(params.block_config) \
+            + depth_idx * len(params.mtp_block_config) + cfg_idx
     return (loop_pass * params.depth + depth_idx) \
         * len(params.block_config) + cfg_idx
 
@@ -958,7 +963,8 @@ def _try_prefill_scan(params: ModelParameter, ctx, plan, src: NamedTensor,
 
 def run_body_blocks(params: ModelParameter, src: NamedTensor,
                     plan: typing.Optional[typing.Tuple[BlockSpec, ...]],
-                    loop_pass: int = 0
+                    loop_pass: int = 0,
+                    module: typing.Optional[int] = None
                     ) -> typing.Tuple[NamedTensor, typing.Tuple[BlockSpec, ...]]:
     """Run depth × block_config with the configured memory strategy.
 
@@ -967,11 +973,19 @@ def run_body_blocks(params: ModelParameter, src: NamedTensor,
     feeds explicit parameter subsets into the custom-vjp sequences.
     ``loop_pass``: which pass of a looped model this is (model/loop.py): the
     ``checkpoint`` strategy's regions count on over the passes (``_region``).
+    ``module``: the pass of the multi-token-prediction module (model/mtp.py)
+    whose blocks these are in the body's place — ``mtp_block_config`` once,
+    named ``block<module>_<c>`` under the caller's scope, ``plan`` theirs;
+    the configuration admits the module under ``checkpoint`` / ``none``
+    without ``scan_layers`` or a pipeline mesh, and its caller refuses decode
+    and prefill.
     """
     ctx = scope.current()
     strategy = params.memory_reduction_strategy
     blocks = [(i, c, bc) for i in range(params.depth)
-              for c, bc in enumerate(params.block_config)]
+              for c, bc in enumerate(params.block_config)] \
+        if module is None else [(module, c, bc) for c, bc
+                                in enumerate(params.mtp_block_config)]
 
     if ctx.mode == "init" or plan is None:
         specs: typing.List[BlockSpec] = []
@@ -1085,8 +1099,8 @@ def run_body_blocks(params: ModelParameter, src: NamedTensor,
     for (i, c, _), f, s in zip(blocks, fns, subsets):
         call = _block_with_stats(f, ctx.layer_stats is not None, chan)
         if strategy == "checkpoint":
-            call = jax.checkpoint(
-                call, policy=policies[_region(params, loop_pass, i, c)])
+            call = jax.checkpoint(call, policy=policies[_region(
+                params, loop_pass, i, c, module is not None)])
         (out, side), stats = call(s, out, None, side)
         parts.append(stats)
     if any(parts):

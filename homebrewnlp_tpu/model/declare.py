@@ -15,7 +15,7 @@ from ..config import ModelParameter
 
 #: ``"each"``: no fold, the layers' (a looped model's passes') values as they
 #: are — a statistic with a ``label``
-_FOLDS = {"max": jnp.max, "min": jnp.min, "sum": jnp.sum,
+_FOLDS = {"max": jnp.max, "min": jnp.min, "sum": jnp.sum, "mean": jnp.mean,
           "each": lambda values: values}
 
 
@@ -130,13 +130,27 @@ def block_offers(params: ModelParameter, kind: str
             for block in params.block_config]
 
 
+def region_offers(params: ModelParameter, kind: str
+                  ) -> typing.List[typing.List[Offer]]:
+    """What the block of every ``jax.checkpoint`` region of the step offers
+    of ``kind``, a list a region in execution order: the body's depth-unit
+    each time the step runs it (``depth`` x ``loop_steps``, a looped model's
+    passes outermost), then the blocks of the multi-token-prediction module
+    (``mtp_depth`` x ``mtp_block_config``, model/mtp.py)."""
+    module = [list(_offers(params, kind, (block,)))
+              for block in params.mtp_block_config]
+    return block_offers(params, kind) * (params.depth * params.loop_steps) \
+        + module * params.mtp_depth
+
+
 def step_offers(params: ModelParameter, kind: str):
     """``(offer, times it runs)`` of every layer of the step that offers
     ``kind``: the leading and trailing blocks once, the body ``depth``
-    times."""
+    times, a multi-token-prediction module's blocks ``mtp_depth`` times."""
     for blocks, times in ((params.input_block_config, 1),
                           (params.block_config, params.depth),
-                          (params.output_block_config, 1)):
+                          (params.output_block_config, 1),
+                          (params.mtp_block_config, params.mtp_depth)):
         for offer in _offers(params, kind, blocks):
             yield offer, times
 

@@ -21,6 +21,7 @@ from .gated_delta import gated_delta
 from .kda import kda
 from .lightning import lightning
 from .loop import gated_loss
+from .mtp import module_loss
 from .mamba import mamba
 from .moe import moe
 from .normalization import norm
@@ -185,5 +186,6 @@ LAYER_FUNCTIONS = {'feed_forward': feed_forward,
                    }
 
 #: what declares itself (model/declare.py) beside the layers of the DSL: a
-#: looped model's loss (model/loop.py)
-DECLARING = (gated_loss,)
+#: looped model's loss (model/loop.py), a multi-token-prediction module's
+#: (model/mtp.py)
+DECLARING = (gated_loss, module_loss)

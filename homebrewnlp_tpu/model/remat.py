@@ -94,7 +94,7 @@ import numpy as np
 
 from ..config import ModelParameter
 from ..core import sharding as shardlib
-from .declare import block_offers, offers
+from .declare import offers, region_offers
 
 #: fraction of per-chip HBM the attention stash may claim (the historical
 #: resolve_stash gate)
@@ -164,15 +164,22 @@ def _executions(params: ModelParameter) -> int:
     return params.depth * params.loop_steps
 
 
+def region_count(params: ModelParameter) -> int:
+    """The ``jax.checkpoint`` regions of the step: a block of the body each
+    time it runs, then the blocks of a multi-token-prediction module
+    (model/declare.py ``region_offers``, model/blocks.py ``_region``)."""
+    return len(region_offers(params, "attention"))
+
+
 def _offered_stash(params: ModelParameter, kind: str, shards: int
                    ) -> typing.Tuple[int, int]:
     """``(executions, per-device bytes)`` of the experts or the recurrent
     kind over the whole step: what every layer that offers the kind DECLARES
     (``Offer.nbytes``, for the whole batch), each time it runs."""
-    offered = [offer.nbytes for offer in offers(params, kind)]
-    return len(offered) * _executions(params), -(
-        -sum(offered) * _executions(params) * max(1, params.macro_batching)
-        // shards)
+    offered = [offer.nbytes for region in region_offers(params, kind)
+               for offer in region]
+    return len(offered), -(
+        -sum(offered) * max(1, params.macro_batching) // shards)
 
 
 def _forced_attention(params: ModelParameter) -> bool:
@@ -202,23 +209,23 @@ def _saved_attention(params: ModelParameter, mesh, min_keys: int
     if mesh is not None or not params.use_flash_attention \
             or params.sequence_dim.size % 128:
         return 0, 0
-    saved = [offer.nbytes for offer in offers(params, "attention")
-             if offer.keys >= min_keys]
-    return len(saved) * _executions(params), sum(saved) \
-        * _executions(params) * max(1, params.macro_batching)
+    saved = [offer.nbytes for region in region_offers(params, "attention")
+             for offer in region if offer.keys >= min_keys]
+    return len(saved), sum(saved) * max(1, params.macro_batching)
 
 
 def _block_input_bytes(params: ModelParameter, shards: int) -> int:
     """Per-device bytes of the block inputs the ``checkpoint`` strategy
     itself keeps across the step's backward: one ``[batch, sequence,
     features]`` in the calculation dtype for every ``jax.checkpoint`` region
-    of the step — each block of the body, each time it runs.  (The input and
-    output blocks run outside any region, model/__init__.py: they keep no
-    block input, replay nothing and offer nothing.)"""
+    of the step — each block of the body, each time it runs, and each block
+    of a multi-token-prediction module.  (The input and output blocks run
+    outside any region, model/__init__.py: they keep no block input, replay
+    nothing and offer nothing.)"""
     one = params.batch_dim.size * params.sequence_dim.size \
         * int(np.prod([d.size for d in params.feature_dims])) \
         * np.dtype(params.calculation_dtype).itemsize
-    return -(-one * len(params.block_config) * _executions(params)
+    return -(-one * region_count(params)
              * max(1, params.macro_batching) // shards)
 
 
@@ -233,11 +240,10 @@ def _regions(params: ModelParameter, kind: str, shards: int,
     def part(offer):
         return offer.interior_nbytes if interior else offer.nbytes
 
-    unit = [(sum(1 for offer in offered if part(offer)),
+    return [(sum(1 for offer in offered if part(offer)),
              -(-sum(part(offer) for offer in offered)
                * max(1, params.macro_batching) // shards))
-            for offered in block_offers(params, kind)]
-    return unit * _executions(params)
+            for offered in region_offers(params, kind)]
 
 
 def _admit(params: ModelParameter, kind: str, shards: int,
@@ -492,10 +498,11 @@ def saved_attention_keys(params: ModelParameter, mesh=None
 
 def _names(params: ModelParameter, kind: str, interior: bool = False
            ) -> typing.Tuple[str, ...]:
-    """The ``checkpoint_name``s one depth-unit's layers declare for ``kind``
-    (or for its ``interior``), in execution order."""
-    return tuple(name for offer in offers(params, kind) for name in (
-        offer.interior_names if interior else offer.names))
+    """The ``checkpoint_name``s the step's layers declare for ``kind`` (or
+    for its ``interior``), each once, in execution order."""
+    return tuple(dict.fromkeys(
+        name for region in region_offers(params, kind) for offer in region
+        for name in (offer.interior_names if interior else offer.names)))
 
 
 def stash_names(params: ModelParameter, mesh=None) -> typing.Tuple[str, ...]:
@@ -508,8 +515,8 @@ def stash_names(params: ModelParameter, mesh=None) -> typing.Tuple[str, ...]:
     plan = stash_plan(params, mesh)
     names = _names(params, "experts") if plan["experts"][0] else ()
     if plan["recurrent"][0]:
-        regions = len(params.block_config) * _executions(params)
-        inside = _decide(params, mesh)[1]["recurrent"][3] < regions
+        inside = _decide(params, mesh)[1]["recurrent"][3] \
+            < region_count(params)
         names += _names(params, "recurrent") \
             + (_names(params, "recurrent", True) if inside else ())
     if saved_attention_keys(params, mesh) is not None:

@@ -412,12 +412,11 @@ _STANDARD_POSITION = ("rope", "nope", "yarn")
 _STANDARD_PLAIN = ("qk_norm", "qk_norm_head", "gate", "gate_features",
                    "sparse", "indexed")
 _STANDARD_NUMBERED = ("q_heads", "kv_heads", "window", "rotary_pct", "theta",
-                      "kv_latent", "shared_key")
+                      "kv_latent", "shared_key", "q_latent")
 #: what the latent form (``kv_latent<n>``) does not build, each refused by
 #: name
-_LATENT_REFUSES = ("rope", "yarn", "rotary_pct", "theta", "qk_norm",
-                   "qk_norm_head", "gate", "gate_features", "sparse",
-                   "indexed", "window")
+_LATENT_REFUSES = ("yarn", "rotary_pct", "qk_norm", "qk_norm_head", "gate",
+                   "gate_features", "sparse", "indexed", "window")
 
 
 def numbered_flags(extras, plain, numbered, what: str
@@ -464,21 +463,34 @@ def _standard_flags(extras) -> typing.Dict[str, typing.Any]:
         if one in out and other in out:
             raise ValueError(f"the standard attention takes {one} or "
                              f"{other}, not both")
-    if "shared_key" in out and "kv_latent" not in out:
-        raise ValueError("shared_key<n> (one key part shared by all heads) "
-                         "comes with kv_latent<n>")
+    for flag in ("shared_key", "q_latent"):
+        if flag in out and "kv_latent" not in out:
+            raise ValueError(
+                f"{flag}<n> (one key part shared by all heads / a query "
+                "latent) comes with kv_latent<n>")
     if "kv_latent" in out:
         for flag in _LATENT_REFUSES:
             if flag in out:
                 raise ValueError(
                     f"latent attention (kv_latent<n>) does not build {flag}: "
-                    "no rotary on the shared key part (nope only), no query "
-                    "latent, norm, gate, sparse or indexed choice or window")
+                    "rotary turns the shared key part whole at the plain "
+                    "frequencies (rope, theta<t>; no yarn, no rotary_pct), "
+                    "and there is no query / key norm beside the latents' "
+                    "own, no gate, no sparse or indexed choice, no window")
         if out.get("q_heads") != out.get("kv_heads"):
             raise ValueError("latent attention expands the latent to a key "
                              "and a value a query head: kv_heads = q_heads")
-        if min(out["kv_latent"], out.get("shared_key", 1)) < 1:
-            raise ValueError("kv_latent<n> and shared_key<n> are positive")
+        if min(out["kv_latent"], out.get("shared_key", 1),
+               out.get("q_latent", 1)) < 1:
+            raise ValueError("kv_latent<n>, shared_key<n> and q_latent<n> "
+                             "are positive")
+        if "rope" in out and (out.get("shared_key", 0) < 2
+                              or out["shared_key"] % 2):
+            raise ValueError(
+                "latent attention under rope turns the shared key part "
+                "(and the last shared_key features of every query head): "
+                "shared_key<n> with n even and positive, got "
+                f"{out.get('shared_key', 0)}")
     return out
 
 
@@ -816,17 +828,25 @@ def _standard_attention(args: BlockArgs) -> NamedTensor:
 
 
 def _latent_attention(args: BlockArgs, flags) -> NamedTensor:
-    """Latent attention without positions (DeepSeek-V2's MLA as Kimi Linear
-    runs it, ``mla_use_nope``; flags ``nope-kv_latent<c>-shared_key<r>``,
-    with ``q_heads<n>-kv_heads<n>`` or the stream's heads): on the block's
-    input ``u``, ``n`` heads of ``d = features_per_head``,
+    """Latent attention (DeepSeek-V2 / -V3's MLA, arXiv:2412.19437 section
+    2.1.1; flags ``kv_latent<c>-shared_key<r>`` with ``q_heads<n>-kv_heads<n>``
+    or the stream's heads, ``nope`` or ``rope``, and ``q_latent<cq>`` or
+    none): on the block's input ``u``, ``n`` heads of ``d =
+    features_per_head``,
 
-        q = u W_q                     features x n x (d + r); a head's q =
-                                      [q_n (d) | q_s (r)]      (no query latent)
+        c_q = rms(u W_qa) w_q         features x cq, RMSNorm over the latent
+        q = c_q W_qb                  cq x n x (d + r)       (``q_latent<cq>``)
+        q = u W_q                     features x n x (d + r)  (without it)
+                                      a head's q = [q_n (d) | q_s (r)]
         c | k_s = u W_kvd             features x (c + r); k_s is ONE shared
                                       r-wide key part for all heads
         k_n | v = rms(c) w_c W_kvu    c x n x (d + d); RMSNorm over the
                                       latent, eps ``norm_epsilon``
+        q_s, k_s = rotary(q_s), rotary(k_s)    under ``rope`` only: all r
+                                      features, pairs (i, i + r/2), theta
+                                      ``theta<t>`` or ``rope_theta``,
+                                      position = index; ``nope`` turns nothing
+                                      (Kimi Linear's ``mla_use_nope``)
         k = [k_n | k_s]               k_s repeated over the heads
         o = causal softmax(scale q k^T) v      scale = attention_scale, or
                                                (d + r)^-1/2
@@ -834,11 +854,18 @@ def _latent_attention(args: BlockArgs, flags) -> NamedTensor:
 
     The flash kernels take the key at ``d + r`` and the value at ``d``
     (parallel/flash_attention.py: V is not padded).  Parameters in creation
-    order: ``W_q``, ``W_kvd``, the latent norm's scale, ``W_kvu``, ``W_o``;
-    normal(0.02), ``W_o`` normal(``residual_out_stddev``) where set.  Scopes:
-    ``q_proj``, ``kv_down``, ``kv_norm``, ``kv_up``, ``attend``,
-    ``out_proj`` (analysis/cost_ledger.py folds ``attend`` into
-    ``body/attention`` itself, the mixing the readers of that scope mean)."""
+    order: (``W_qa``, the query latent's scale, ``W_qb``) or ``W_q``,
+    ``W_kvd``, the latent norm's scale, ``W_kvu``, ``W_o``; normal(0.02),
+    ``W_o`` normal(``residual_out_stddev``) where set.  Scopes: ``q_down``,
+    ``q_norm`` (the query latent's), ``q_proj``, ``kv_down``, ``kv_norm``,
+    ``kv_up``, ``latent_rope``, ``attend``, ``out_proj`` (analysis/cost_ledger.py
+    folds ``attend`` into ``body/attention`` itself, the mixing the readers
+    of that scope mean).  Without ``rope`` and ``q_latent`` the function
+    traces what it traced before it knew them.  Not built (refused by name,
+    ``_LATENT_REFUSES``): YaRN or a partial turn of the shared part, a norm
+    on the queries or keys beside the latents' own, a gate, a sparse or
+    indexed choice, a window; decode and prefill (the latent cache and the
+    absorbed form are serving's) and a mesh."""
     import jax.numpy as jnp
     from ..core import scope as scope_mod
     from ..core.tensor import nt, transpose_to
@@ -860,20 +887,36 @@ def _latent_attention(args: BlockArgs, flags) -> NamedTensor:
                             ).data.reshape(-1, dim.size,
                                            *(n.size for n in new))
 
+    q_in, q_old = args.tensor, feats
+    if "q_latent" in flags:
+        q_old = [Dim("q_latent", flags["q_latent"])]
+        with jax.named_scope("q_down"):
+            q_in = nt(projected(args.tensor, q_old, feats), tokens + q_old)
+        with jax.named_scope("q_norm"):
+            q_in = norm(args(q_in, ["rms", "scale"]), q_old)
     with jax.named_scope("q_proj"):
-        q = projected(args.tensor, [head, Dim("latent_key", d + r)], feats)
+        q = projected(q_in, [head, Dim("latent_key", d + r)], q_old)
     with jax.named_scope("kv_down"):
         down = projected(args.tensor, [Dim("kv_latent_shared", c + r)], feats)
     with jax.named_scope("kv_norm"):
         normed = norm(args(nt(down[..., :c], tokens + [latent]),
                            ["rms", "scale"]), [latent])
+    shared = None
+    if "rope" in flags:
+        theta = float(flags.get("theta", params.rope_theta))
+        with jax.named_scope("latent_rope"):
+            q = jnp.concatenate([q[..., :d], rotary(q[..., d:], theta)],
+                                axis=-1)
+            shared = rotary(down[:, :, None, c:], theta)
     with jax.named_scope("kv_up"):
         up = projected(normed, [head, Dim("latent_key_value", 2 * d)],
                        [latent])
         k, v = up[..., :d], up[..., d:]
         if r:
+            if shared is None:
+                shared = down[:, :, None, c:]
             k = jnp.concatenate([k, jnp.broadcast_to(
-                down[:, :, None, c:], k.shape[:3] + (r,))], axis=-1)
+                shared, k.shape[:3] + (r,))], axis=-1)
     scale = params.attention_scale or (d + r) ** -0.5
     with jax.named_scope("attend"):
         out = causal_heads(ctx, params, q, k, v, 1, scale)

@@ -203,7 +203,12 @@ class Trainer:
         def loss_of(v, idx=None):
             info = self.model.apply(v, batch, rng, mesh=mesh,
                                     layer_stats=self._record_steps)
-            return (info.total_loss.data if idx is None
+            # what the step differentiates: the reported loss, or where a
+            # multi-token-prediction module adds its own at a weight
+            # (model/mtp.py) the sum, which the step does not report
+            whole = info.total_loss if info.objective is None \
+                else info.objective
+            return (whole.data if idx is None
                     else info.loss_list[idx].data), info
 
         # the strategy backwards (revnet/momentum custom_vjp) re-trace

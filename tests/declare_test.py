@@ -249,8 +249,13 @@ def _config_files():
 #: ``hbnlp_index_loss_kernel_layers`` / ``..._walked_over_visible_pairs`` — 7
 #: (the cell's file) and 48 layers at 1.03119 on a TPU, 0 at 1.24992 on the
 #: CPU —, the other 29 files as they were: before it
-#: 71a59d30fb05fedc6feb56ee36b3684de7f15891)
-_FILE_DIGEST = "fc574cb9973bd6b1ce062da7b715e870e530095f"
+#: 71a59d30fb05fedc6feb56ee36b3684de7f15891; PR 65 added the two
+#: JoyAI-LLM-Flash files — the cell's reads ``attention 6 layers, 817889280``
+#: on both sides (five body layers' and the multi-token-prediction module's
+#: ``(out, lse)``; layer 0 runs outside any region) with ``moe held rows bound
+#: 131072`` —: without them the digest is PR 64's
+#: fc574cb9973bd6b1ce062da7b715e870e530095f, every other line as it was)
+_FILE_DIGEST = "9e0fcbd609c6d4328deeeeb2142f9de7f2bb983e"
 
 
 def every_configuration_file_starts_as_on_the_parent_test(monkeypatch):
@@ -360,10 +365,13 @@ def statistics_are_all_declared_test():
     """The trainer's table is the declarations': nineteen statistics of
     layers (PR 54: the selection bias's two; PR 58: layer ``kda``'s log-decay,
     and its transform under ``gated_delta``'s name; PR 62: attention flag
-    ``indexed``'s index loss and largest kept score) and (PR 49) three of a
-    looped model's loss, and a step whose layers report nothing (or only
-    some) has only those."""
-    assert len(_LAYER_STATS) == 22 == len(declare.stats())
+    ``indexed``'s index loss and largest kept score), (PR 49) three of a
+    looped model's loss and (PR 65) two of a multi-token-prediction
+    module's, and a step whose layers report nothing (or only some) has only
+    those."""
+    assert len(_LAYER_STATS) == 24 == len(declare.stats())
+    assert {name for name in _LAYER_STATS if name.startswith("mtp_")} == {
+        "mtp_loss", "mtp_loss_over_main"}
     assert {"moe_bias_abs_max", "moe_all_load_max_over_mean"} \
         <= set(_LAYER_STATS)
     assert {name for name in _LAYER_STATS if name.startswith("loop_")} == {

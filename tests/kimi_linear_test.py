@@ -311,14 +311,14 @@ def the_32_expert_shares_add_up_to_the_uncut_layer_test():
 
 @pytest.mark.parametrize("layer,error,match", [
     (MLA + "-rms", ValueError, "does not know flag"),
-    (MLA.replace("nope", "rope"), ValueError, "does not build rope"),
+    (MLA.replace("nope", "yarn"), ValueError, "does not build yarn"),
     (MLA + "-window32", ValueError, "does not build window"),
     (MLA + "-qk_norm", ValueError, "does not build qk_norm"),
     (MLA + "-gate", ValueError, "does not build gate"),
     (MLA.replace("kv_heads4", "kv_heads2"), ValueError, "kv_heads = q_heads"),
     ("attention-nope-shared_key8", ValueError, "comes with kv_latent"),
     ("kda-anything", None, None)],
-    ids=["unknown", "rope", "window", "qk_norm", "gate", "grouped",
+    ids=["unknown", "yarn", "window", "qk_norm", "gate", "grouped",
          "shared_alone", "kda_flag"])
 def what_is_not_built_refuses_by_name_test(layer, error, match):
     config = _config(block_config=[_block(layer)])
