@@ -224,12 +224,12 @@ def _cell_parts(block_q: int, block_k: int, off: int, window,
     lower-left quadrant mask-free, the two on the diagonal masked, and the
     upper-right not scored.
 
-    The forward (``carried``) pays a fixed cost for every online-softmax
-    step a row takes (the rescale of ``m`` / ``l`` / ``acc``; measured in
-    ``attention``'s docstring), so its cell stays ONE masked step, over the
-    bounding rectangle of the sub-squares that are not dead: where the q
-    tile starts where a k tile of twice its length starts, that is the first
-    half of the keys."""
+    The forward (``carried``) folds every part into ``m`` / ``l`` / ``acc``
+    (an online-softmax step; what one costs is in ``attention``'s
+    docstring), so its cell stays ONE masked step, over the bounding
+    rectangle of the sub-squares that are not dead: where the q tile starts
+    where a k tile of twice its length starts, that is the first half of the
+    keys."""
     half = _half_tile(block_q, block_k)
     bands = []
     for r0 in range(0, block_q, half):
@@ -451,6 +451,73 @@ def _make_pair(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, scale):
     return pair
 
 
+#: lanes of a forward's row statistics (a vreg's)
+_STAT_LANES = 128
+
+
+def _lanes(x, width: int):
+    """A row statistic ``[rows, _STAT_LANES]``, every lane of a row the same
+    value, over ``width`` lanes: whole copies of its vregs where ``width``
+    is whole lane tiles (none at ``_STAT_LANES``), else a broadcast."""
+    from jax.experimental.pallas import tpu as pltpu
+    if width % _STAT_LANES:
+        return jnp.broadcast_to(x[:, :1], (x.shape[0], width))
+    return pltpu.repeat(x, width // _STAT_LANES, axis=1)
+
+
+def _stat_scratch(rows: int, d_v: int):
+    """The online softmax's carried state as VMEM scratch: ``m`` and ``l``
+    ``[rows, _STAT_LANES]`` float32, a row's value in every lane, and the
+    accumulator ``[rows, d_v]``."""
+    from jax.experimental.pallas import tpu as pltpu
+    return [pltpu.VMEM((rows, _STAT_LANES), jnp.float32),
+            pltpu.VMEM((rows, _STAT_LANES), jnp.float32),
+            pltpu.VMEM((rows, d_v), jnp.float32)]
+
+
+def _softmax_init(m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def _softmax_step(s, v_ref, m_ref, l_ref, acc_ref, rows=..., cols=...):
+    """One online-softmax step of BOTH forwards (``_flash_kernel``,
+    ``_select_fwd_kernel``): the MASKED float32 scores ``s`` of rows ``rows``
+    of the q tile against keys ``cols`` of the k tile (static slices; the
+    whole tile where left out) folded into the state.  The row statistics
+    are ``[rows, _STAT_LANES]``, a row's value in every lane, from the
+    reduction to the rescale: the scores' tile and the accumulator read them
+    as whole vregs and nothing turns between lanes and sublanes (as 1-D
+    ``(rows,)`` scratch that turning was 1.9 of a 512 x 512 selected cell's
+    3.1 us, PR 63, and the causal forward's fixed cost a step, PR 66:
+    ``attention``'s docstring).  A masked score is the caller's: the causal
+    parts' finite ``_NEG_INF``, the selected form's ``-inf`` under the
+    FINITE first maximum of ``_softmax_init``."""
+    m_prev = m_ref[rows]
+    m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - _lanes(m_new, s.shape[-1]))
+    l_ref[rows] = l_ref[rows] * alpha + p.sum(-1, keepdims=True)
+    # p rounds to the input dtype for the MXU (p in [0, 1]; flash-2
+    # standard — same precision class as a dense bf16 attention)
+    acc_ref[rows] = acc_ref[rows] * _lanes(alpha, acc_ref.shape[-1]) \
+        + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[cols], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    m_ref[rows] = m_new
+
+
+def _softmax_finish(o_ref, lse_ref, m_ref, l_ref, acc_ref):
+    l = jnp.maximum(l_ref[...], 1e-30)
+    o_ref[...] = (acc_ref[...] / _lanes(l, acc_ref.shape[-1])
+                  ).astype(o_ref.dtype)
+    # lse rides a [bh, s, 1] buffer: TPU lowering requires the last two
+    # block dims divisible by (8, 128) or equal to the array dims, which
+    # a [bh, s] row block of (1, block_q) cannot satisfy
+    lse_ref[...] = (m_ref[...] + jnp.log(l))[:, :1]
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
                   *, block_q: int, block_k: int, num_k: int, scale: float,
                   causal: bool, window=None):
@@ -468,41 +535,23 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
 
     @pl.when(kk == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _softmax_init(m_ref, l_ref, acc_ref)
 
     score = _make_score(q_ref, k_ref, scale)
 
     def _step(rows, cols, mask, fresh):
-        r = slice(*rows)
         s = score(rows, cols)
         if mask is not None:
             s = mask(s)
-        m_prev = m_ref[r]
-        m_new = jnp.maximum(m_prev, s.max(-1))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_ref[r] = l_ref[r] * alpha + p.sum(-1)
-        # p rounds to the input dtype for the MXU (p in [0, 1]; flash-2
-        # standard — same precision class as a dense bf16 attention)
-        acc_ref[r, :] = acc_ref[r, :] * alpha[:, None] + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[slice(*cols), :],
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        m_ref[r] = m_new
+        _softmax_step(s, v_ref, m_ref, l_ref, acc_ref, slice(*rows),
+                      slice(*cols))
 
     _masked_step(qi, ki, block_q, block_k, causal, _step, window=window,
                  carried=True, width=q_ref.shape[-1])
 
     @pl.when(kk == num_k - 1)
     def _finish():
-        o_ref[...] = (acc_ref[...]
-                      / jnp.maximum(l_ref[...], 1e-30)[:, None]).astype(o_ref.dtype)
-        # lse rides a [bh, s, 1] buffer: TPU lowering requires the last two
-        # block dims divisible by (8, 128) or equal to the array dims, which
-        # a [bh, s] row block of (1, block_q) cannot satisfy
-        lse_ref[...] = (m_ref[...]
-                        + jnp.log(jnp.maximum(l_ref[...], 1e-30)))[:, None]
+        _softmax_finish(o_ref, lse_ref, m_ref, l_ref, acc_ref)
 
 
 def _kernel_name(base: str, causal: bool, window) -> str:
@@ -564,9 +613,7 @@ def _fwd_flat(qt, kt, vt, scale, causal, block_q, block_k, interpret,
                    pl.BlockSpec((None, block_q, 1), lambda i, j, kk: (i, j, 0))],
         out_shape=[jax.ShapeDtypeStruct((bh, s, dv), out_dtype),
                    jax.ShapeDtypeStruct((bh, s, 1), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((block_q,), jnp.float32),
-                        pltpu.VMEM((block_q,), jnp.float32),
-                        pltpu.VMEM((block_q, dv), jnp.float32)],
+        scratch_shapes=_stat_scratch(block_q, dv),
         # the innermost k dimension carries the online-softmax scratch state
         # and MUST run sequentially ("arbitrary"); the outer two dims are
         # independent and may be partitioned across megacore.  vmem budget:
@@ -1298,19 +1345,31 @@ def attention(q, k, v, scale: typing.Optional[float] = None,
         8,192    1.250 / 1.125             1.125 / 1.0625
         16,384   1.125 / 1.0625            1.0625 / 1.03125
 
-    Why the forward goes no finer: an online-softmax step has a FIXED cost
-    beside its columns'.  Measured on a v5e (PR 55; non-causal forward, q
-    tile 1,024, k tiles 1,024 against 2,048, in-jit loop): at bh 32, s
-    4,096, d 128 a step costs 3.76 us a 1,024 keys + 2.67 us fixed — 0.71
-    of 1,024 columns; at bh 16, s 16,384, d 512, 12.23 + 1.85 us — 0.15.
-    Walking the shortened cell as three quadrants (one step more for its
-    lower rows) made the d 128 forward 2.6% SLOWER than scoring every cell
-    whole (2.094 against 2.041 ms a call, 1.738 as here); cutting the edge
-    cells' rows into bands of 512, which adds no step to any row and drops
-    the dead quadrants too, measured the same as this form (1.736): a
-    step's fixed cost does not shrink with its rows.  At head width 512 a third branch
-    does not fit the body (``_FORWARD_BODY_CAP``): there the whole-tile
-    diagonal cell shares the interior's branch under the position mask."""
+    Why the forward goes no finer, and what a step costs.  Until PR 66 an
+    online-softmax step had a FIXED cost beside its columns': measured on a
+    v5e (PR 55, taken again by PR 66 on the parent's body; non-causal
+    forward, q tile 1,024, k tiles 1,024 against 2,048), at bh 32, s 4,096,
+    d 128 a step cost 3.51 us a 1,024 keys + 2.70 us fixed, at bh 16, s
+    16,384, d 512 12.01 + 1.85 us.  Walking the shortened cell as three
+    quadrants (one step more for its lower rows) made the d 128 forward 2.6%
+    SLOWER than scoring every cell whole; cutting the edge cells' rows into
+    bands of 512, which adds no step to any row, measured the same as this
+    form: the fixed cost did not shrink with a step's rows.  It was the row
+    statistics' layout: ``m`` and ``l`` as 1-D ``(rows,)`` scratch turn
+    between lanes and sublanes at every use.  Held ``[rows, 128]`` with a
+    row's value in every lane (``_softmax_step``, PR 66) the same fit reads
+    4.18 us a 1,024 keys - 0.17 at d 128 and 12.23 - 0.09 at d 512: no fixed
+    cost is left, and a 1,024 x 2,048 step is 1.34-1.89 us shorter at every
+    width (``flash_fwd_causal`` 27.84 -> 24.75 ms a call at bh 32 x 16,384 x
+    192 / 128, 5.32 -> 4.53 at 8 x 16,384 x 128, 1.63 -> 1.28 at 32 x 4,096
+    x 128, 30.25 -> 28.07 at 16 x 16,384 x 512, 5.83 -> 4.83 at 32 x 8,192 x
+    64; ``scripts/kernel_parity.py --only-flash-forward --flash-bisect``
+    takes them again).  What a step's statistics still cost is 1.4-1.9 us
+    beside ``p = exp(s)`` alone, now by its columns; whether a finer cut of
+    the edge cells gains without the fixed cost has not been measured.  At
+    head width 512 a third branch does not fit the body
+    (``_FORWARD_BODY_CAP``): there the whole-tile diagonal cell shares the
+    interior's branch under the position mask."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     on_tpu = jax.default_backend() not in ("cpu",)
@@ -1398,9 +1457,6 @@ SELECT_NAME = "sparse_keep"
 _SELECT_TILE = 512
 #: lanes of the window of the rows' choice a cell reads
 _KEEP_LANES = 128
-#: lanes of the selected forward's row statistics (a vreg's)
-_STAT_LANES = 128
-
 
 #: queries a word of the key-at-a-time choice holds a bit each
 KEEP_WORD = 32
@@ -1561,28 +1617,15 @@ def _select_live(fetch_ref, i, outer, step, num_outer: int, num_inner: int,
                      + step]
 
 
-def _lanes(x, width: int):
-    """A row statistic ``[rows, _STAT_LANES]``, every lane of a row the same
-    value, over ``width`` lanes: whole copies of its vregs where ``width``
-    is whole lane tiles (none at ``_STAT_LANES``), else a broadcast."""
-    from jax.experimental.pallas import tpu as pltpu
-    if width % _STAT_LANES:
-        return jnp.broadcast_to(x[:, :1], (x.shape[0], width))
-    return pltpu.repeat(x, width // _STAT_LANES, axis=1)
-
-
 def _select_fwd_kernel(fetch_ref, q_ref, k_ref, v_ref, keep_ref, o_ref,
                        lse_ref, m_ref, l_ref, acc_ref, *, tq: int, tk: int,
                        block: int, num_q: int, num_k: int, keep_group: int,
                        scale: float):
     """Grid (batch * heads, q tiles, k tiles), k innermost: ``_flash_kernel``
     over the tiles some row of the q tile kept, each pair under the rows'
-    own mask.  The row statistics are ``[tq, _STAT_LANES]``, a row's value in
-    every lane, from the reduction to the rescale: the scores' tile and the
-    accumulator read them as whole vregs and nothing turns between lanes and
-    sublanes (as 1-D ``(tq,)`` scratch they cost 1.9 of a cell's 3.1 us, PR
-    63); a masked score is ``-inf`` under a FINITE first maximum, so a row
-    that has kept nothing yet reads ``p = 0`` with no second select.  Every
+    own mask, the online softmax the causal forward's (``_softmax_step``).
+    A masked score is ``-inf`` under a FINITE first maximum, so a row that
+    has kept nothing yet reads ``p = 0`` with no second select.  Every
     cell compares positions: a second body for the cells the diagonal does
     not cross is 0.3% of the Keye step and 3 s of every set-up (PR 63)."""
     from jax.experimental import pallas as pl
@@ -1591,32 +1634,18 @@ def _select_fwd_kernel(fetch_ref, q_ref, k_ref, v_ref, keep_ref, o_ref,
 
     @pl.when(kk == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _softmax_init(m_ref, l_ref, acc_ref)
 
     @pl.when(_select_live(fetch_ref, i, qi, kk, num_q, num_k, keep_group)
              == kk)
     def _step():
         seen = _select_seen(keep_ref, qi, kk, tq, tk, block)
         s = jnp.where(seen, _make_score(q_ref, k_ref, scale)(), -jnp.inf)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - _lanes(m_new, tk))
-        l_ref[...] = l_ref[...] * alpha + p.sum(-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * _lanes(alpha, acc_ref.shape[-1]) \
-            + jax.lax.dot_general(
-                p.astype(v_ref.dtype), v_ref[...], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        _softmax_step(s, v_ref, m_ref, l_ref, acc_ref)
 
     @pl.when(kk == num_k - 1)
     def _finish():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[...] = (acc_ref[...] / _lanes(l, acc_ref.shape[-1])
-                      ).astype(o_ref.dtype)
-        lse_ref[...] = (m_ref[...] + jnp.log(l))[:, :1]
+        _softmax_finish(o_ref, lse_ref, m_ref, l_ref, acc_ref)
 
 
 def _select_pair(seen, q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, scale):
@@ -1766,7 +1795,6 @@ def _select_specs(tq: int, tk: int, block: int, d: int, num_q: int,
 
 def _select_fwd_impl(q, k, v, keep, scale, block, interpret):
     """``(out [b, s, h, d], lse [b * h, s])`` of the selected forward."""
-    from jax.experimental.pallas import tpu as pltpu
     b, s, h, d = q.shape
     group = h // k.shape[2]
     keep_group = h // keep.shape[1]
@@ -1783,10 +1811,8 @@ def _select_fwd_impl(q, k, v, keep, scale, block, interpret):
         [q_spec(d), k_spec, k_spec, keep_spec], [q_spec(d), q_spec(1)],
         [jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
          jax.ShapeDtypeStruct((b * h, s, 1), jnp.float32)],
-        [pltpu.VMEM((tq, _STAT_LANES), jnp.float32),
-         pltpu.VMEM((tq, _STAT_LANES), jnp.float32),
-         pltpu.VMEM((tq, d), jnp.float32)],
-        interpret, (fetch_k, _flat(q), _flat(k), _flat(v), rows))
+        _stat_scratch(tq, d), interpret,
+        (fetch_k, _flat(q), _flat(k), _flat(v), rows))
     return out.reshape(b, h, s, d).transpose(0, 2, 1, 3), lse[..., 0]
 
 

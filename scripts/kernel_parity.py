@@ -93,6 +93,18 @@ carries on the device (the parent's two gradient contractions).
 out, its other forms and other tiles (:func:`_index_loss_variant`; ISSUE 64's
 bisect: which unit binds).
 
+A tenth leg, run only by ``--only-flash-forward``, holds the causal flash
+FORWARD (``flash_fwd_causal``: ``_fwd_flat`` at ``call_tiles``' 1,024 x 2,048
+tiles) ALONE at the six shapes the train cells hand it
+(:data:`FLASH_FORWARD_SHAPES`): its ms a call from the device trace, the
+online-softmax steps of a call and its us a step, and ``out`` / ``lse`` of one
+head against ``_xla_reference_with_lse`` in float32 ``highest`` inside
+:data:`TOLERANCE` / :data:`LSE_TOLERANCE`.  ``--flash-bisect`` adds the forward
+with the row statistics of its step held otherwise
+(:func:`_flash_forward_variant`; ISSUE 66's bisect: what a step's fixed cost
+is) and, from the non-causal forward at k tiles of 1,024 against 2,048, a
+step's cost as ``us a 1,024 keys + us fixed``.
+
 Shapes: flash at the long-context recipe's per-chip shape (seq 16,384, head
 dim 128; two heads so the dense reference's [s, s] scores fit beside it);
 the mixer at the flagship's (8 heads, seq 512, 512 features/head, batch 32).
@@ -877,6 +889,227 @@ def _select_leg(block: int, s: int, heads: int, kv_heads: int, d: int = 128,
     return bool(ok)
 
 
+#: ``(the cells, batch x heads, positions, key width, value width)`` of the
+#: causal forward's calls in the train cells
+FLASH_FORWARD_SHAPES = (
+    ("joyai_llm_flash, kimi_linear", 32, 16384, 192, 128),
+    ("zaya1", 8, 16384, 128, 128),
+    ("laguna (global layers)", 96, 8192, 128, 128),
+    ("ouro", 32, 4096, 128, 128),
+    ("1b_long_context", 16, 16384, 512, 512),
+    ("granite", 32, 8192, 64, 64))
+#: the row statistics of the forward's step as ISSUE 66's bisect varies them:
+#: 1-D ``(rows,)`` scratch widened at every use (the parent's), ``[rows, 1]``
+#: columns, lane-replicated ``[rows, 128]`` (the library's), or none (``p =
+#: exp(s)``: a time, not a result)
+FLASH_FORWARD_STATS = ("1d", "col", "lanes", "none")
+
+
+def _flash_forward_variant(name, stats, causal=True, block_k=None):
+    """``(q, k, v [bh, s, d], scale) -> (out, lse)`` of the tiled forward
+    with the named statistics: the library's tiles, grid, maps, branches
+    (``_masked_step``) and scores round an online-softmax step made here."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from homebrewnlp_tpu.parallel import flash_attention as fa
+
+    keepdims = stats != "1d"
+
+    def wide(x, width):
+        return x[:, None] if stats == "1d" else fa._lanes(x, width) \
+            if stats == "lanes" else x
+
+    def forward(q, k, v, scale):
+        bh, s, d = q.shape
+        dv = v.shape[-1]
+        _, bq, bk, _ = fa.call_tiles(s, d, None, q.dtype.itemsize, dv)
+        bk = min(block_k or bk, s)
+        num_k = s // bk
+
+        def kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
+                   acc_ref):
+            qi, kk = pl.program_id(1), pl.program_id(2)
+
+            @pl.when(kk == 0)
+            def _init():
+                fa._softmax_init(m_ref, l_ref, acc_ref)
+
+            score = fa._make_score(q_ref, k_ref, scale)
+
+            def step(rows, cols, mask, fresh):
+                r = slice(*rows)
+                s_ = score(rows, cols)
+                if mask is not None:
+                    s_ = mask(s_)
+
+                def pv(p):
+                    return jax.lax.dot_general(
+                        p.astype(v_ref.dtype), v_ref[slice(*cols)],
+                        (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+
+                if stats == "none":
+                    acc_ref[r] += pv(jnp.exp(s_))
+                    return
+                m_prev = m_ref[r]
+                m_new = jnp.maximum(m_prev, s_.max(-1, keepdims=keepdims))
+                alpha = jnp.exp(m_prev - m_new)
+                p = jnp.exp(s_ - wide(m_new, s_.shape[-1]))
+                l_ref[r] = l_ref[r] * alpha + p.sum(-1, keepdims=keepdims)
+                acc_ref[r] = acc_ref[r] * wide(alpha, dv) + pv(p)
+                m_ref[r] = m_new
+
+            fa._masked_step(qi, kk, bq, bk, causal, step, carried=True,
+                            width=d)
+
+            @pl.when(kk == num_k - 1)
+            def _finish():
+                if stats == "none":
+                    o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+                    lse_ref[...] = jnp.zeros_like(lse_ref)
+                    return
+                m, l = m_ref[...], jnp.maximum(l_ref[...], 1e-30)
+                o_ref[...] = (acc_ref[...] / wide(l, dv)).astype(o_ref.dtype)
+                lse = m + jnp.log(l)
+                lse_ref[...] = lse[:, None] if stats == "1d" else lse[:, :1]
+
+        stat = {"1d": (bq,), "lanes": (bq, fa._STAT_LANES)}.get(stats,
+                                                                 (bq, 1))
+        kmap = fa._frontier_kv_map(bq, bk, causal)
+        out, lse = pl.pallas_call(
+            kernel, grid=(bh, s // bq, num_k),
+            in_specs=[pl.BlockSpec((None, bq, d), lambda i, j, kk: (i, j, 0)),
+                      pl.BlockSpec((None, bk, d), kmap),
+                      pl.BlockSpec((None, bk, dv), kmap)],
+            out_specs=[pl.BlockSpec((None, bq, dv),
+                                    lambda i, j, kk: (i, j, 0)),
+                       pl.BlockSpec((None, bq, 1),
+                                    lambda i, j, kk: (i, j, 0))],
+            out_shape=[jax.ShapeDtypeStruct((bh, s, dv), q.dtype),
+                       jax.ShapeDtypeStruct((bh, s, 1), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM(stat, jnp.float32),
+                            pltpu.VMEM(stat, jnp.float32),
+                            pltpu.VMEM((bq, dv), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                vmem_limit_bytes=fa._KERNEL_VMEM_BUDGET),
+            name=name, interpret=jax.devices()[0].platform == "cpu",
+        )(q, k, v)
+        return out, lse[..., 0]
+    return forward
+
+
+def _forward_steps(bh: int, s: int, bq: int, bk: int, causal=True) -> int:
+    """The online-softmax steps of a call: its live cells."""
+    bq, bk = min(bq, s), min(bk, s)
+    return bh * sum(1 for qi in range(s // bq) for ki in range(s // bk)
+                    if not causal or ki * bk <= qi * bq + bq - 1)
+
+
+def _flash_forward_leg(seq: int = 0, bisect: bool = False) -> bool:
+    """The causal forward alone at its cells' shapes (``seq``: every shape
+    at that many positions and two heads, a CPU rehearsal's size)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from homebrewnlp_tpu.parallel import flash_attention as fa
+
+    interpret = jax.devices()[0].platform == "cpu"
+    ok = True
+
+    def operands(bh, s, d, dv, seed=66):
+        return tuple(jax.random.normal(jax.random.PRNGKey(seed + n),
+                                       (bh, s, w), jnp.float32
+                                       ).astype(jnp.bfloat16)
+                     for n, w in enumerate((d, d, dv)))
+
+    def timed(name, fn, steps, *args):
+        ms = _kernel_ms(lambda: fn(*args), name)
+        t_ms = ms.get(name, ms["wall"])
+        return t_ms, round(t_ms * 1000 / steps, 3)
+
+    for cells, bh, s, d, dv in FLASH_FORWARD_SHAPES:
+        if seq:
+            bh, s = min(bh, 2), seq
+        scale = d ** -0.5
+        q, k, v = operands(bh, s, d, dv)
+        _, bq, bk, _ = fa.call_tiles(s, d, None, 2, dv)
+        steps = _forward_steps(bh, s, bq, bk)
+        library = jax.jit(lambda q, k, v: fa._fwd_flat(
+            q, k, v, scale, True, bq, bk, interpret))
+        t_ms, us = timed("flash_fwd_causal", library, steps, q, k, v)
+        out, lse = library(q, k, v)
+        # ONE head against the dense form in float32
+        with jax.default_matmul_precision("highest"):
+            want, want_lse = jax.jit(lambda q, k, v: (
+                fa._xla_reference_with_lse(q, k, v, scale, True)))(
+                *(t[:1, :, None].astype(jnp.float32) for t in (q, k, v)))
+        want, want_lse = np.asarray(want[0, :, 0]), np.asarray(want_lse[0])
+        errs = {"out": float(np.abs(np.asarray(out[0], np.float32) - want
+                                    ).max() / np.abs(want).max()),
+                "lse": float(np.abs(np.asarray(lse[0]) - want_lse).max())}
+        good = errs["out"] <= TOLERANCE and errs["lse"] <= LSE_TOLERANCE
+        ok &= good
+        print(json.dumps({
+            "kernel": "flash_fwd_causal", "ok": bool(good), "cells": cells,
+            "implementation": "pallas (interpret)" if interpret else "pallas",
+            "shape": {"bh": bh, "s": s, "d_k": d, "d_v": dv},
+            "tiles": [min(bq, s), min(bk, s)], "steps_a_call": steps,
+            "ms_a_call": t_ms, "us_a_step": us,
+            "max_err_over_max_ref": {n: round(e, 7) for n, e in errs.items()},
+            "tolerance": TOLERANCE, "lse_tolerance": LSE_TOLERANCE,
+            "dtype": "bfloat16"}), flush=True)
+        if not bisect:
+            continue
+        for stats in FLASH_FORWARD_STATS:
+            name = f"fwd_stats_{stats}"
+            try:
+                run = jax.jit(functools.partial(_flash_forward_variant(
+                    name, stats), scale=scale))
+                v_ms, v_us = timed(name, run, steps, q, k, v)
+                got, got_lse = run(q, k, v)
+                diff = {"out": float(jnp.max(jnp.abs(
+                    got.astype(jnp.float32) - out.astype(jnp.float32)))),
+                    "lse": float(jnp.max(jnp.abs(got_lse - lse)))}
+            except Exception as e:  # Mosaic refuses a layout: a finding
+                print(json.dumps({"variant": name, "cells": cells,
+                                  "refused": repr(e)[:400]}), flush=True)
+                continue
+            print(json.dumps({
+                "variant": name, "cells": cells, "ms_a_call": v_ms,
+                "us_a_step": v_us,
+                "us_a_step_over_the_library": round(v_us - us, 3),
+                "max_abs_diff_to_the_library": diff}), flush=True)
+    if not bisect:
+        return bool(ok)
+    # a step as ``per x (keys / 1,024) + fixed``, PR 55's way: the NON-causal
+    # forward (every cell whole, no mask) at k tiles of 1,024 against 2,048
+    for bh, s, d in ((32, 4096, 128), (16, 16384, 512)):
+        if seq:
+            bh, s = min(bh, 2), max(seq, 2048)
+        scale = d ** -0.5
+        q, k, v = operands(bh, s, d, d, seed=55)
+        for stats in ("lanes", "1d"):
+            us = {}
+            for bk in (1024, 2048):
+                name = f"fwd_flat_{stats}_k{bk}"
+                run = jax.jit(functools.partial(_flash_forward_variant(
+                    name, stats, causal=False, block_k=bk), scale=scale))
+                us[bk] = timed(name, run, _forward_steps(
+                    bh, s, 1024, bk, causal=False), q, k, v)[1]
+            per = us[2048] - us[1024]
+            print(json.dumps({
+                "step_cost": stats, "shape": {"bh": bh, "s": s, "d": d},
+                "us_a_step_at_k1024": us[1024], "us_a_step_at_k2048": us[2048],
+                "us_a_1024_keys": round(per, 3),
+                "us_fixed": round(us[1024] - per, 3)}), flush=True)
+    return bool(ok)
+
+
 #: the index-loss kernel's cell body as ISSUE 64's bisect varies it.
 #: ``pbar``: the 32 heads' probabilities whole, without their ``exp`` (a
 #: time, not a result), or not at all; ``backward``: the index heads'
@@ -1304,6 +1537,16 @@ def main(argv=None) -> int:
     ap.add_argument("--select-tiles", default="",
                     help="with --only-select: also time the library's three "
                          "kernels at these tiles, e.g. 512x512,1024x1024")
+    ap.add_argument("--only-flash-forward", action="store_true",
+                    help="run the causal flash forward's leg alone, at the "
+                         "six shapes its cells hand it")
+    ap.add_argument("--flash-forward-seq", type=int, default=0,
+                    help="with --only-flash-forward: every shape at this "
+                         "many positions and two heads (a CPU rehearsal)")
+    ap.add_argument("--flash-bisect", action="store_true",
+                    help="with --only-flash-forward: also time the forward "
+                         "with its step's row statistics held otherwise, and "
+                         "a step's cost a 1,024 keys and fixed")
     ap.add_argument("--select-bisect", action="store_true",
                     help="with --only-select: also time the forward with "
                          "parts of its cell body swapped or taken out")
@@ -1318,6 +1561,10 @@ def main(argv=None) -> int:
     if args.only_index_loss:
         ok = _index_loss_leg(args.index_loss_seq,
                              bisect=args.index_loss_bisect)
+        print(json.dumps({"ok": bool(ok)}), flush=True)
+        return 0 if ok else 1
+    if args.only_flash_forward:
+        ok = _flash_forward_leg(args.flash_forward_seq, args.flash_bisect)
         print(json.dumps({"ok": bool(ok)}), flush=True)
         return 0 if ok else 1
     if args.only_select:

@@ -196,8 +196,8 @@ def a_precomputed_forward_at_two_widths_test():
 
 
 @pytest.mark.parametrize("heads,s,d,window,digest,precomputed", [
-    (16, 16384, 512, None, "8085b0b458133d61", "537f93e728be5233"),
-    (16, 4096, 128, None, "b7ca317183ec284c", "aca9a0fc8d6e5f85"),
+    (16, 16384, 512, None, "a1fd20a5d5bb767d", "537f93e728be5233"),
+    (16, 4096, 128, None, "e9a9ebb4a3aae071", "aca9a0fc8d6e5f85"),
     (72, 8192, 128, 512, "7f26067a62d06811", "5c106b71441b2e30")],
     ids=["long_context", "olmoe", "laguna_window"])
 def equal_widths_are_the_parents_calls_test(heads, s, d, window, digest,
@@ -206,7 +206,11 @@ def equal_widths_are_the_parents_calls_test(heads, s, d, window, digest,
     bodies, grids, block maps, names, source positions stripped — of
     ``flash_attention``'s and ``flash_precomputed``'s gradients at the
     long-context cell's, OLMoE's and Laguna's window layers' shapes and
-    tiles, digests taken on PR 58's parent (239ac1f)."""
+    tiles, digests taken on PR 58's parent (239ac1f) — the two whose forward
+    is the tiled one taken again by PR 66, which MEANT to move that body (its
+    row statistics lane-replicated: 8085b0b458133d61 / b7ca317183ec284c
+    before); the backward alone (``precomputed``) and the band forward are
+    the parent's still."""
     q = jax.ShapeDtypeStruct((1, s, heads, d), jnp.bfloat16)
     blk, fwd_q, fwd_k, _ = fa.call_tiles(s, d, window, 2)
     assert fa.call_tiles(s, d, window, 2, d) == (blk, fwd_q, fwd_k, _)

@@ -1,7 +1,7 @@
 """What the flash-attention files share (PR 60): inputs from a seed, the dense
 form's ``out``, ``lse`` and gradients — traced and compiled once a ``(sequence,
 window, seed, ...)`` and taken from here by every case after — the gradient
-tolerances, and two readers of a traced call."""
+tolerances, and three readers of a traced call."""
 import functools
 import hashlib
 import re
@@ -59,19 +59,36 @@ def assert_grads_close(got, want, rtol=GRAD_RTOL, atol=GRAD_ATOL):
                                    atol=atol)
 
 
-def forward_kernels(fn, *args):
-    """``(name, grid)`` of every ``pallas_call`` ``fn`` traces to."""
+def _pallas_calls(fn, *args):
+    """Every ``pallas_call`` equation ``fn`` traces to, nested ones too."""
     found = []
 
     def walk(jaxpr):
         for eqn in jaxpr.eqns:
             if eqn.primitive.name == "pallas_call":
-                found.append((eqn.params["name"],
-                              tuple(eqn.params["grid_mapping"].grid)))
+                found.append(eqn)
             for sub in jax.core.jaxprs_in_params(eqn.params):
                 walk(sub)
 
     walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+def forward_kernels(fn, *args):
+    """``(name, grid)`` of every ``pallas_call`` ``fn`` traces to."""
+    return [(eqn.params["name"], tuple(eqn.params["grid_mapping"].grid))
+            for eqn in _pallas_calls(fn, *args)]
+
+
+def kernel_scratch(fn, *args):
+    """``{name: [(shape, dtype), ..]}`` of the scratch buffers of every
+    ``pallas_call`` ``fn`` traces to."""
+    found = {}
+    for eqn in _pallas_calls(fn, *args):
+        count = eqn.params["grid_mapping"].num_scratch_operands
+        refs = eqn.params["jaxpr"].invars
+        found[eqn.params["name"]] = [(tuple(ref.aval.shape), ref.aval.dtype)
+                                     for ref in refs[len(refs) - count:]]
     return found
 
 

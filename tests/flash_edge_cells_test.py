@@ -180,3 +180,73 @@ def edge_cell_parts_are_the_live_part_test():
         ((256, 512), (256, 512), True)]
 
 
+#: ``(key width, value width, (block_q, block_k), window, the ring hop's
+#: bfloat16 operands and float32 partials)``: the widths the train cells hand
+#: the tiled forward, each at tiles whose diagonal cell is cut to a part — at
+#: 64 / 64 one of 64 keys, no whole lane tile of the row statistics
+STATISTIC_CASES = {
+    "64x64": (64, 64, (64, 128), None, False),
+    "128x128": (128, 128, (128, 256), None, False),
+    "192x128": (192, 128, (128, 256), None, False),
+    "512x512": (512, 512, (128, 256), None, False),
+    "window": (128, 128, (128, 128), 192, False),
+    "ring_hop": (128, 128, (128, 256), None, True),
+}
+
+
+@pytest.mark.parametrize("case", list(STATISTIC_CASES))
+def lane_replicated_statistics_match_the_dense_form_test(case):
+    """``_fwd_flat``'s ``(out, lse)`` with the row statistics held ``[rows,
+    _STAT_LANES]`` (PR 66) against the dense form: every width of a train
+    cell, a windowed call on the tiled grid, a ring hop's partials."""
+    d_k, d_v, (bq, bk), window, hop = STATISTIC_CASES[case]
+    dtype = jnp.bfloat16 if hop else np.float32
+    q, k, v, _ = dense_form.inputs(512, 66, heads=2, d=d_k, d_v=d_v,
+                                   dtype=dtype)
+    scale = d_k ** -0.5
+    out, lse = fa._fwd_flat(
+        *(x[0].transpose(1, 0, 2) for x in (q, k, v)), scale, True, bq, bk,
+        True, out_dtype=jnp.float32 if hop else None, window=window)
+    assert out.dtype == jnp.float32 and lse.dtype == jnp.float32
+    ref, ref_lse = fa._xla_reference_with_lse(
+        *(x.astype(jnp.float32) for x in (q, k, v)), scale, True, window)
+    # a hop's p rounds to bfloat16 before its dot with v
+    tol = 2e-2 if hop else 2e-5
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(ref[0].transpose(1, 0, 2)),
+                               rtol=tol, atol=tol)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("forward", ["causal", "window", "select_blocks",
+                                     "select_keys"])
+def forward_statistics_are_lane_replicated_test(forward, monkeypatch):
+    """Every tiled forward's ``pallas_call`` carries ``m`` and ``l`` as two
+    ``[q tile, _STAT_LANES]`` float32 scratch buffers beside its accumulator,
+    and no 1-D one: as ``(q tile,)`` scratch they turn between lanes and
+    sublanes at every use (PR 63, PR 66)."""
+    s, heads, d_k, d_v = 512, 2, 64, 64
+    q = jax.ShapeDtypeStruct((1, s, heads, d_k), jnp.float32)
+    v = jax.ShapeDtypeStruct((1, s, heads, d_v), jnp.float32)
+    if forward.startswith("select"):
+        block = 32 if forward == "select_blocks" else 1
+        # the key-at-a-time form's tile is twice the block form's
+        monkeypatch.setattr(fa, "_SELECT_TILE", 128 if block == 1 else 256)
+        keep = jnp.ones((1, 1, s, s // block), bool)
+        keep = fa.pack_keep(keep) if block == 1 else keep.astype(q.dtype)
+        tile, name = fa.select_tile(s, block)[0], "flash_fwd_select"
+        scratch = dense_form.kernel_scratch(
+            lambda q, k, v: fa._select_fwd_impl(q, k, v, keep, 0.125, block,
+                                                True), q, q, v)
+    else:
+        window = 192 if forward == "window" else None
+        monkeypatch.setattr(fa, "band_applies", lambda *a, **kw: False)
+        tile, name = 256, "flash_fwd_" + forward
+        scratch = dense_form.kernel_scratch(
+            lambda q, k, v: fa._flash_fwd_impl(q, k, v, 0.125, True, tile,
+                                               tile, True, window), q, q, v)
+    assert tile != fa._STAT_LANES != d_v
+    assert scratch == {name: [((tile, fa._STAT_LANES), jnp.float32)] * 2
+                       + [((tile, d_v), jnp.float32)]}
+

@@ -127,8 +127,8 @@ def the_band_forward_is_the_windowed_call_test(monkeypatch):
         == (2, 16, 2)
 
 
-@pytest.mark.parametrize("fused,digest", [(True, "ff0effe0be83d952"),
-                                          (False, "6c1cc156f207ebf9")])
+@pytest.mark.parametrize("fused,digest", [(True, "d5bbd3f5dd0fee2e"),
+                                          (False, "aacc61b444fdd5fd")])
 def no_window_is_the_parents_call_test(fused, digest, monkeypatch):
     """``window=None`` traces to one call whether the argument is left out
     or given as None: the digests are of this call's jaxpr — kernel bodies,
@@ -136,7 +136,9 @@ def no_window_is_the_parents_call_test(fused, digest, monkeypatch):
     and the split backward.  Until PR 55 they were those of the parent
     commit of ISSUE 36 (5f633c2: e8c973467ff66f11 / 9626d241fbf329fd);
     PR 55 MEANT to move the bodies (an edge cell scores its live part), the
-    grids, maps and names are as they were (``flops_test.py``)."""
+    grids, maps and names are as they were (``flops_test.py``); PR 66 the
+    forward's alone (its row statistics lane-replicated: ff0effe0be83d952 /
+    6c1cc156f207ebf9 before)."""
     monkeypatch.setattr(fa, "_fused_dqp_cap",
                         (lambda: 1 << 40) if fused else (lambda: 0))
     q = jax.ShapeDtypeStruct((1, 2048, 2, 128), jnp.bfloat16)
