@@ -31,6 +31,23 @@ EXPECTED_FAILURES = {
        "gradients to the reference's own.  A loss the reference names "
        "itself in reference_test.py is a benchmark PR's"
        for case in ("float32-2e-05", "bfloat16-0.0625")},
+    **{"benchmark/tests/reference_test.py::reference_matches_program_test"
+       f"[{case}-sdar_30b_a3b]":
+       "the accepted test holds the program's reported loss to the "
+       "NEXT-TOKEN cross-entropy of the logits the reference returns.  A "
+       "block-diffusion model (PR 67) reports what it trains on, (1 / L) "
+       "sum_i m_i / t_b(i) CE(logits_i, x_i) over the masked positions "
+       "against the SAME position's clean token, and its reference returns "
+       "the noised half's logits under PRNGKey(0)'s noise (what the train "
+       "driver compares at all positions): the two are not the same "
+       "quantity (at this test's size 11.30 against 9.87: 32 blocks' weights "
+       "m / t average 1.15, not 1), whatever the program does.  The logits "
+       "part of the case holds (float32 4e-7); tests/sdar_test.py holds the "
+       "logits under three keys, the loss and every gradient to the "
+       "reference's own.  A loss the reference names itself in "
+       "reference_test.py (PERF.md section 7, reported_loss) is a benchmark "
+       "PR's, and drops these two entries with Ouro's"
+       for case in ("float32-2e-05", "bfloat16-0.0625")},
     **{f"tests/{test}":
        f"the HLO audit's [big-copy] rule on XLA:CPU under this jax: "
        f"{finding}.  The aggregate test fails on train_step's 22 copies of "

@@ -91,7 +91,17 @@ _CCA_PARTS = frozenset(("in_proj", "qk_mean", "conv", "qk_norm", "rope",
 #: kernels and what names their outputs) stays in ``body/attention`` itself,
 #: as ``cca``'s kernels stay in ``body/cca``
 _ATTENTION_PARTS = frozenset(("gate", "q_down", "q_norm", "q_proj", "kv_down",
-                              "kv_norm", "kv_up", "latent_rope", "out_proj"))
+                              "kv_norm", "kv_up", "latent_rope", "out_proj",
+                              # what the block-diffusion mask runs round its
+                              # kernels (flag ``block_diffusion``): the clean
+                              # half's keys for both halves, a query's own
+                              # block, the merge by log-sum-exp
+                              "halves", "own_block", "lse_merge"))
+#: block-diffusion training (model/denoise.py): the draws, the two sequences'
+#: ids side by side, the noised half of the body's output — ``denoise/<part>``
+#: wherever in the model they run
+_DENOISE = "denoise"
+_DENOISE_PARTS = frozenset(("noise", "join", "split"))
 #: the steps of attention flag ``sparse`` (model/sparse.py; ``attend`` holds
 #: the selected kernels) and of flag ``indexed`` (model/indexer.py: ``index``,
 #: ``select``, ``attend`` and its own ``index_loss``) below
@@ -170,7 +180,8 @@ def _model_scope_key(path: str) -> str:
     ``body/moe/router|dispatch|experts|combine|shared|latent_down|
     latent_up``,
     ``body/moe/router/down|carry|mlp``, ``body/attention/gate|q_down|q_norm|
-    q_proj|kv_down|kv_norm|kv_up|latent_rope|out_proj``,
+    q_proj|kv_down|kv_norm|kv_up|latent_rope|out_proj|halves|own_block|
+    lse_merge``, ``denoise/noise|join|split``,
     ``body/attention/sparse_attention/compress|index|select|attend|
     index_loss``,
     ``body/lightning/in_proj|qk_norm|rope|rule|gate_norm|out_proj``,
@@ -186,14 +197,18 @@ def _model_scope_key(path: str) -> str:
     attribution, not per-pass."""
     phase = None
     layer = None
-    router = sparse = False
+    router = sparse = denoise = False
     bases = []
     for comp in str(path).split("/"):
         base = _basename(_unwrap(comp))
         bases.append(base)
         if base in _SPECIAL:
             return _SPECIAL[base]
-        if phase is None and base in _PHASES:
+        if denoise and base in _DENOISE_PARTS:
+            return f"{_DENOISE}/{base}"
+        if base == _DENOISE:
+            denoise = True
+        elif phase is None and base in _PHASES:
             phase = base
         elif phase is not None and layer is None and base in _LAYER_NAMES:
             layer = base
@@ -219,6 +234,8 @@ def _model_scope_key(path: str) -> str:
             return f"body/gated_delta/{base}"
         elif layer == "kda" and base in _KDA_PARTS:
             return f"body/kda/{base}"
+    if denoise:
+        return _DENOISE
     if router:
         return "body/moe/router"
     if sparse:

@@ -344,6 +344,17 @@ class ModelParameter:
         self.mtp_depth = 0
         self.mtp_loss_weight = 0.3
         self.mtp_block_config: typing.Any = []
+        # block-diffusion training, model/denoise.py (BD3-LM,
+        # arXiv:2503.09573; SDAR, arXiv:2510.06303): the body runs once over
+        # [noised sequence | clean sequence], 2 x sequence_length positions,
+        # the noise drawn in the step from its key a block of
+        # diffusion_block tokens (a rate t ~ U[diffusion_t_min, 1] a block, a
+        # mask a token, the mask token diffusion_mask_id, -1 = the last row
+        # of the vocabulary); the loss is over the masked positions at 1 / t.
+        # 0 = left to right, and nothing of it is read
+        self.diffusion_block = 0
+        self.diffusion_t_min = 1e-3
+        self.diffusion_mask_id = -1
         self.pkm_axes = 2
         self.use_bit_fold_input_pipeline = False
         self.bit_fold_value = 4
@@ -1126,6 +1137,51 @@ class ModelParameter:
                                  "multi-token-prediction module, "
                                  "model/mtp.py) refuses "
                                  + "; ".join(refused))
+        block = self.diffusion_block
+        if not isinstance(block, int) or isinstance(block, bool) or block < 0:
+            raise ValueError(f"diffusion_block {block!r} must be a whole "
+                             "number >= 0")
+        if block:
+            t_min, mask_id = self.diffusion_t_min, self.diffusion_mask_id
+            if isinstance(t_min, bool) or not isinstance(t_min, (int, float)) \
+                    or not 0 < t_min <= 1:
+                raise ValueError(f"diffusion_t_min {t_min!r} must be a "
+                                 "number in (0, 1]")
+            if not isinstance(mask_id, int) or isinstance(mask_id, bool) \
+                    or not -1 <= mask_id < self.vocab_size:
+                raise ValueError(f"diffusion_mask_id {mask_id!r} must be a "
+                                 "row of the vocabulary, or -1 (the last)")
+            refused = [why for why, hit in (
+                (f"sequence_length {self.sequence_length} of no whole "
+                 f"blocks of {block}", self.sequence_length % block != 0),
+                (f"loop_steps {self.loop_steps} (a looped model)",
+                 self.loop_steps > 1),
+                (f"mtp_depth {self.mtp_depth} (a multi-token-prediction "
+                 "module)", bool(self.mtp_depth)),
+                (f"memory_reduction_strategy "
+                 f"{self.memory_reduction_strategy!r} (revnet and momentum "
+                 "streams: \"checkpoint\" or \"none\")",
+                 self.memory_reduction_strategy not in ("none",
+                                                        "checkpoint")),
+                ("scan_layers", self.scan_layers),
+                ("use_video", self.use_video),
+                ("a contrastive loss", self.contrastive_across_samples
+                 or self.contrastive_across_token_embeddings),
+                (f"multi_loss_strategy {self.multi_loss_strategy!r}",
+                 self.multi_loss_strategy != "linear"),
+                ("calc_accuracy", self.calc_accuracy),
+                ("input_dropout", self.input_dropout > 0),
+                ("use_initial_position_embedding",
+                 self.use_initial_position_embedding),
+                ("a factorized or patched token embedding",
+                 bool(self.vocab_weight_factorization)
+                 or self.token_patch_size != 1),
+                ("a leading block (input_block_config)",
+                 bool(self.input_block_config))) if hit]
+            if refused:
+                raise ValueError(f"diffusion_block {block} (block-diffusion "
+                                 "training, model/denoise.py) refuses "
+                                 + "; ".join(refused))
         if self.tie_word_embeddings and (self.vocab_weight_factorization
                                          or self.token_patch_size != 1
                                          or self.use_video):
@@ -1204,6 +1260,13 @@ class ModelParameter:
                 "module, model/mtp.py) refuses a pipeline mesh: "
                 f"pipeline_stages {self.pipeline_stages} (the module reads "
                 "the last stage's output and the first stage's table)")
+        if self.diffusion_block and (self.pipeline_stages > 1
+                                     or self.mesh_shape.get("sequence", 1) > 1):
+            raise ValueError(
+                f"diffusion_block {self.diffusion_block} (block-diffusion "
+                "training, model/denoise.py) refuses a pipeline mesh and a "
+                f"sequence-sharded one: mesh {self.mesh_shape} (the noised "
+                "half reads the clean half's keys)")
         if self.pipeline_stages > 1 and self.depth % self.pipeline_stages:
             raise ValueError(
                 f"depth={self.depth} must divide into pipe={self.pipeline_stages} stages")
@@ -1247,6 +1310,10 @@ class ModelParameter:
                                  for c in self.mtp_block_config]
 
         self.time_patch_size = self.sequence_length // self.time_patch
+        # positions a sequence of the body's stream (block-diffusion training
+        # runs the noised sequence beside the clean one)
+        self.stream_length = self.sequence_length \
+            * (2 if self.diffusion_block else 1)
         self.frame_height_patch = self.frame_height // self.patch_size
         self.frame_width_patch = self.frame_width // self.patch_size
         self.channel_color_size = self.color_channels * self.time_patch * self.patch_size ** 2
@@ -1288,10 +1355,16 @@ class ModelParameter:
         frame_input_shape += [self.color_channel_dim]
         self.frame_input_shape = frame_input_shape
 
-        self.sequence_dim = Dim("sequence", self.time_patch_size)
+        # the body's stream: the trained tokens, and under block-diffusion
+        # training (diffusion_block > 0) the noised sequence before them;
+        # the batch's tokens are token_sequence_dim long either way
+        self.token_sequence_dim = Dim("sequence", self.time_patch_size)
+        self.sequence_dim = Dim("sequence",
+                                self.stream_length // self.time_patch)
         self.token_patch_dim = Dim("language_token_patch", self.token_patch_size)
-        self.token_dim_shape = [self.batch_dim, self.sequence_dim, self.token_patch_dim]
-        self.frame_mask_shape = [self.batch_dim, self.sequence_dim]
+        self.token_dim_shape = [self.batch_dim, self.token_sequence_dim,
+                                self.token_patch_dim]
+        self.frame_mask_shape = [self.batch_dim, self.token_sequence_dim]
 
         self.input_pipeline_shape: typing.Dict[str, list] = {}
         if self.use_video:

@@ -30,6 +30,7 @@ from ..core.tensor import (NamedTensor, add_n, argmax, cast, concat,
 from ..core.value_pool import ValuePool
 from .backend import linear_from_features, linear_to_features
 from .blocks import BlockSpec, _merge_stats, run_body_blocks
+from .denoise import joined_tokens, masked_loss, noised_half
 from .embedding import batched_gather, embed, gather_embed
 from .frontend import block_part_fn
 
@@ -98,6 +99,10 @@ def _input(params: ModelParameter, vid, cat_msk_src, txt_src, vid_msk_src,
             src = block_part_fn(params, config, src, f'vid_inp{config_idx}')
 
     if params.use_language:
+        if params.diffusion_block:
+            # block-diffusion training: the noised sequence before the clean
+            # one, ONE stream of 2 x sequence_length through the one table
+            txt_src = joined_tokens(params, txt_src, storage)
         base_args = BlockArgs(params, txt_src, [''])
         intermediate = Dim(params.intermediate[0].name,
                            int(params.intermediate[0].size * params.vocab_weight_factorization))
@@ -156,6 +161,9 @@ def _output(params: ModelParameter, out: NamedTensor, spatial_ctx: Dim,
     if params.use_language:
         token_out = slice_(out, 0, params.language_token_patch, spatial_ctx.name) \
             if params.use_video else out
+        if params.diffusion_block:
+            # the head and the loss read the noised half alone
+            token_out = noised_half(params, token_out)
         if not contrastive:
             for config_idx, config in enumerate(params.output_block_config):
                 token_out = block_part_fn(params, config, token_out, f'lang_out{config_idx}')
@@ -246,6 +254,11 @@ def _loss(params: ModelParameter, frame_out, token_out, txt_tgt, loss_list,
             gathered = batched_gather(emb, txt_tgt, [params.head_dim])
             token_loss = token_loss - einsum([token_out, gathered], []) * 2
             token_loss = token_loss / (token_out.size * params.vocab_size)
+        elif "denoise" in storage:
+            # block-diffusion training: the masked positions alone, each at
+            # 1 / its rate, against the SAME position's clean token
+            token_loss = nt(masked_loss(params, *storage["head"], storage
+                                        ).astype(token_out.dtype), ())
         elif "head" in storage:
             token_loss = softmax_cross_entropy_with_logits(
                 params, storage["head"][0], txt_tgt, storage["head"][1])
@@ -380,6 +393,13 @@ def _refuse_looped(params: ModelParameter, what: str) -> None:
             f"(mtp_depth {params.mtp_depth}, model/mtp.py): the module as a "
             "self-drafting head is not built; it trains and runs its full "
             "forward only")
+    if params.diffusion_block:
+        raise NotImplementedError(
+            f"{what} of a block-diffusion model (diffusion_block "
+            f"{params.diffusion_block}, model/denoise.py): a sampler whose "
+            "step fills a block in several passes and a cache written a "
+            "clean block at a time are not built; it trains and runs its "
+            "full forward only")
 
 
 class Model:

@@ -48,7 +48,9 @@ class Offer(typing.NamedTuple):
     ``interior_nbytes``: what, kept WITH ``names``, lets the replay skip the
     layer's own forward kernels (the residuals of a rule run as Pallas
     pairs) — a second part, admitted on top of the first and never without
-    it."""
+    it.  ``block``: a flash call under the block-diffusion mask
+    (``diffusion_block``; 0 = causal), whose ``keys`` are the trained tokens
+    a sequence."""
     kind: str
     names: typing.Tuple[str, ...]
     nbytes: int
@@ -56,6 +58,7 @@ class Offer(typing.NamedTuple):
     keys: typing.Optional[int] = None
     interior_names: typing.Tuple[str, ...] = ()
     interior_nbytes: int = 0
+    block: int = 0
 
 
 class Fact(typing.NamedTuple):

@@ -20,6 +20,7 @@ from .cca import cca
 from .gated_delta import gated_delta
 from .kda import kda
 from .lightning import lightning
+from .denoise import joined_tokens
 from .loop import gated_loss
 from .mtp import module_loss
 from .mamba import mamba
@@ -187,5 +188,5 @@ LAYER_FUNCTIONS = {'feed_forward': feed_forward,
 
 #: what declares itself (model/declare.py) beside the layers of the DSL: a
 #: looped model's loss (model/loop.py), a multi-token-prediction module's
-#: (model/mtp.py)
-DECLARING = (gated_loss, module_loss)
+#: (model/mtp.py), block-diffusion training's noise (model/denoise.py)
+DECLARING = (gated_loss, module_loss, joined_tokens)

@@ -20,10 +20,13 @@ cost two more copies of it a step on the v5e; PERF.md section 6, PR 26.)
 from __future__ import annotations
 
 import functools
+import math
 import typing
 
 import jax
 import jax.numpy as jnp
+
+from ..core.tensor import transpose_to
 
 
 #: float32 bytes of one chunk's logits the walk aims to stay under.  Every
@@ -243,3 +246,20 @@ def head_xent_tokens(x, w, targets, weights, z_loss: float):
         loss, token = _xent_tokens(x, w, targets, weights, float(z_loss),
                                    chunks_for(b, s, p, w.shape[-1]))
     return loss, jax.lax.stop_gradient(token)
+
+
+def named_operands(params, stream, head, targets):
+    """``head_xent_tokens``' operands of named tensors: ``(x [b, s, h, k], w
+    [h, k, p, v], targets [b, s, p])`` of the head's input ``stream``, the
+    output embedding ``head`` and ``targets`` — every lead dim folded into
+    ``b``."""
+    seq = [d for d in targets.dims if d.name == params.sequence_dim.name]
+    last = [params.token_patch_dim]
+    lead = [d for d in targets.dims if d not in seq + last]
+    feats = list(params.feature_dims)
+    shape = (math.prod(d.size for d in lead), math.prod(d.size for d in seq))
+    x = transpose_to(stream, lead + seq + feats).data.reshape(
+        shape + tuple(d.size for d in feats))
+    tgt = transpose_to(targets, lead + seq + last).data.reshape(
+        shape + (last[0].size,))
+    return x, transpose_to(head, feats + last + [params.vocab_dim]).data, tgt

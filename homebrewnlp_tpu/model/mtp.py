@@ -34,7 +34,6 @@ a self-drafting head is serving's).
 """
 from __future__ import annotations
 
-import math
 import typing
 
 import jax
@@ -46,7 +45,7 @@ from ..core.dims import Dim
 from ..core.tensor import NamedTensor, cast, nt, reduce_sum, transpose_to
 from .declare import Layer, Stat
 from .embedding import batched_gather
-from .loss import head_xent_tokens
+from .loss import head_xent_tokens, named_operands
 from .normalization import norm
 from .spatial import project
 
@@ -93,22 +92,14 @@ def _head_loss(params: ModelParameter, stream: NamedTensor,
                ) -> jax.Array:
     """Mean cross-entropy (+ ``z_loss``) of ``targets`` under the head on
     ``stream``, over the positions that have a target ``ahead`` on: float32."""
-    seq = [d for d in targets.dims if d.name == params.sequence_dim.name]
-    last = [params.token_patch_dim]
-    lead = [d for d in targets.dims if d not in seq + last]
-    feats = list(params.feature_dims)
-    shape = (math.prod(d.size for d in lead), math.prod(d.size for d in seq))
     if params.logits_scaling != 1:
         stream = stream * (1 / params.logits_scaling)
-    x = transpose_to(stream, lead + seq + feats).data.reshape(
-        shape + tuple(d.size for d in feats))
-    tgt = transpose_to(targets, lead + seq + last).data.reshape(
-        shape + (last[0].size,))
-    w = transpose_to(head, feats + last + [params.vocab_dim]).data
+    x, w, tgt = named_operands(params, stream, head, targets)
+    shape = tgt.shape
     held = shape[1] - ahead
     weights = jnp.broadcast_to(
         (jnp.arange(shape[1]) < held)[None, :, None].astype(jnp.float32)
-        / (shape[0] * held * last[0].size), tgt.shape)
+        / (shape[0] * held * shape[2]), tgt.shape)
     return head_xent_tokens(x, w, tgt, weights, params.z_loss)[0]
 
 

@@ -125,7 +125,7 @@ STASH_KINDS = ("attention", "bottleneck", "experts", "recurrent", "dense")
 def _stash_bytes(params: ModelParameter) -> int:
     """Global attention-stash estimate: one (out [b,s,h,d], lse [b,h,s])
     pair per block, sized as if every block held one attention layer."""
-    seq = params.sequence_length // max(1, params.token_patch_size)
+    seq = params.stream_length // max(1, params.token_patch_size)
     calc_bytes = np.dtype(params.calculation_dtype).itemsize
     per_layer = (params.train_batch_size * seq * params.heads
                  * params.features_per_head * calc_bytes
@@ -286,7 +286,7 @@ def _save_residual_bytes(params: ModelParameter) -> int:
     """Global estimate of the native-AD linearization residuals the save
     policy keeps: f32 activation-sized intermediates per block part,
     stacked over depth by scan AD."""
-    seq = params.sequence_length // max(1, params.token_patch_size)
+    seq = params.stream_length // max(1, params.token_patch_size)
     act = params.train_batch_size * seq * params.heads \
         * params.features_per_head * 4
     blocks = params.depth * max(1, len(params.block_config))
@@ -303,7 +303,7 @@ def remat_report(params: ModelParameter, mesh=None) -> typing.Dict[str, typing.A
                                peak_hbm_bandwidth)
     shards, device = shardlib.shard_geometry(mesh)
     hbm = device_hbm_bytes(device)
-    seq = params.sequence_length // max(1, params.token_patch_size)
+    seq = params.stream_length // max(1, params.token_patch_size)
     tokens = params.train_batch_size * seq
     d_model = params.heads * params.features_per_head
     # one depth-unit's forward: ~4 d_model^2 GEMMs (the mixer shape) plus

@@ -23,7 +23,9 @@ from ..core.tensor import (NamedTensor, cumsum as tensor_cumsum, einsum, exp,
                            less, multiply, range_, reduce_max, reduce_sum,
                            stop_gradient, greater_equal)
 from ..parallel.flash_attention import (SAVED_NAMES, SELECT_NAME,
-                                        band_applies, scored_over_live)
+                                        band_applies,
+                                        block_diffusion_scored_over_live,
+                                        scored_over_live, stepped_applies)
 from . import decode as decode_mod
 from .basic import activated_linear_in, activated_linear_out
 from .declare import Fact, Layer, Offer, Stat, step_offers
@@ -410,7 +412,10 @@ def rotary(x, theta: float, width: typing.Optional[int] = None,
 #: enter), the plain ones, and the ones that carry a whole number
 _STANDARD_POSITION = ("rope", "nope", "yarn")
 _STANDARD_PLAIN = ("qk_norm", "qk_norm_head", "gate", "gate_features",
-                   "sparse", "indexed")
+                   "sparse", "indexed", "block_diffusion")
+#: what the block-diffusion mask (``block_diffusion``) does not build, each
+#: refused by name
+_BLOCK_DIFFUSION_REFUSES = ("sparse", "indexed", "window", "kv_latent")
 _STANDARD_NUMBERED = ("q_heads", "kv_heads", "window", "rotary_pct", "theta",
                       "kv_latent", "shared_key", "q_latent")
 #: what the latent form (``kv_latent<n>``) does not build, each refused by
@@ -463,6 +468,15 @@ def _standard_flags(extras) -> typing.Dict[str, typing.Any]:
         if one in out and other in out:
             raise ValueError(f"the standard attention takes {one} or "
                              f"{other}, not both")
+    if "block_diffusion" in out:
+        for flag in _BLOCK_DIFFUSION_REFUSES:
+            if flag in out:
+                raise ValueError(
+                    f"the block-diffusion mask (block_diffusion) does not "
+                    f"build {flag}: every key of the clean half's earlier "
+                    "blocks and of the query's own block is seen (no sparse "
+                    "or indexed choice, no window), from plain keys and "
+                    "values (no kv_latent)")
     for flag in ("shared_key", "q_latent"):
         if flag in out and "kv_latent" not in out:
             raise ValueError(
@@ -542,6 +556,42 @@ def _one_device(ctx, flag: str) -> None:
     if ctx.mesh is not None and ctx.mesh.size > 1:
         raise NotImplementedError(f"attention flag {flag} on a mesh: the "
                                   "choice of keys is made on one device")
+
+
+def block_diffusion_heads(ctx, params, q, k, v, group: int, scale: float):
+    """Attention flag ``block_diffusion`` on the stream's halves folded into
+    the lead axis, noised before clean a sequence: ``q [2 lead, L, heads, f]``
+    and ``k``, ``v`` ``[2 lead, L, heads / group, f]`` -> ``[2 lead, L,
+    heads, f]`` under the block-diffusion mask of ``diffusion_block``
+    (parallel/flash_attention.py ``block_diffusion_attention``: a query's far
+    part over the CLEAN half's keys of earlier blocks — the kernels —, its
+    own block in its own half, merged by log-sum-exp).  The CLEAN half's K/V
+    heads are repeated over their group for the kernels, as ``causal_heads``
+    repeats them, in the copy that hands them to both halves; the own blocks
+    read K/V a K/V head each.  ``use_flash_attention`` false, the CPU and
+    shapes the kernels do not tile run the same two parts in XLA.  Scopes
+    ``halves`` (the clean half's keys and values for both), ``own_block``,
+    ``lse_merge``."""
+    import jax
+    import jax.numpy as jnp
+    from ..parallel.flash_attention import block_diffusion_attention
+    _one_device(ctx, "block_diffusion")
+
+    def clean(t):
+        # ONE copy: the clean half's K/V heads over their group of query
+        # heads and over both halves
+        lead, (length, heads, width) = t.shape[0] // 2, t.shape[1:]
+        pair = t.reshape(lead, 2, length, heads, 1, width)[:, 1:]
+        return jnp.broadcast_to(
+            pair, (lead, 2, length, heads, group, width)).reshape(
+                2 * lead, length, heads * group, width)
+
+    with jax.named_scope("halves"):
+        k_clean, v_clean = clean(k), clean(v)
+    return block_diffusion_attention(
+        q, k, v, k_clean, v_clean, params.diffusion_block, scale,
+        stash=stash_channel(ctx, "attention"),
+        kernels=params.use_flash_attention)
 
 
 def sparse_heads(ctx, params, q, k, v, scale: float):
@@ -710,7 +760,17 @@ def _standard_attention(args: BlockArgs) -> NamedTensor:
     ``yarn`` is ``rope`` at YaRN's frequencies (``rope_yarn_factor``,
     ``rope_yarn_original_positions``, ``rope_yarn_beta_fast`` /
     ``_beta_slow``) with cos and sin times ``rope_yarn_attention_factor``
-    (0 = ``0.1 ln(factor) + 1``).  Any other flag refuses by name
+    (0 = ``0.1 ln(factor) + 1``).  ``block_diffusion``: the layer mixes the
+    doubled stream of block-diffusion training (``diffusion_block`` > 0,
+    model/denoise.py: ``[noised | clean]``, ``2 L`` positions) under its mask
+    — a noised query sees the noised keys of its own block of
+    ``diffusion_block`` (both directions) and the clean keys of earlier
+    blocks, a clean query the clean keys of its own and earlier blocks —
+    with rotary by ``index mod L`` (each half turned by its own index);
+    ``block_diffusion_heads``: the far part through the
+    ``flash_*_blockdiff`` kernels, scopes ``halves``, ``own_block``,
+    ``lse_merge``; no sparse or indexed choice, window or latent form with
+    it.  Any other flag refuses by name
     (``_standard_flags``, whose message lists the ones it knows).
     Compressed convolutional attention is NOT a flag of this function: it is
     layer ``cca`` (model/cca.py), which shares ``project``, ``rotary``,
@@ -790,6 +850,17 @@ def _standard_attention(args: BlockArgs) -> NamedTensor:
         lead, dim.size, q_feats[0].size, params.key_dim.size)
     k, v = (transpose_to(t, lead_dims + [dim] + kv_feats).data.reshape(
         lead, dim.size, kv_heads, params.key_dim.size) for t in (key, val))
+    if "block_diffusion" in flags:
+        if not params.diffusion_block or dim.size != params.sequence_dim.size:
+            raise ValueError(
+                "attention flag block_diffusion mixes the doubled stream of "
+                "block-diffusion training (diffusion_block > 0, "
+                f"model/denoise.py) along the sequence; got diffusion_block "
+                f"{params.diffusion_block} and axis {dim}")
+        # the halves fold into the lead axis, noised before clean: rotary
+        # then turns each by its own index, position = index mod L
+        q, k, v = (t.reshape((2 * lead, dim.size // 2) + t.shape[2:])
+                   for t in (q, k, v))
     if "nope" not in flags:
         theta = float(flags.get("theta", params.rope_theta))
         rope_args: typing.Tuple = ()
@@ -814,6 +885,8 @@ def _standard_attention(args: BlockArgs) -> NamedTensor:
         out = sparse_heads(ctx, params, q, k, v, scale)
     elif "indexed" in flags:
         out = indexed_heads(ctx, params, q, k, v, scale, index)
+    elif "block_diffusion" in flags:
+        out = block_diffusion_heads(ctx, params, q, k, v, group, scale)
     else:
         out = causal_heads(ctx, params, q, k, v, group, scale,
                            flags.get("window"))
@@ -956,6 +1029,11 @@ def _offer(params, extras) -> typing.Optional[Offer]:
         heads = flags.get("q_heads", params.head_dim.size)
         offer = flash_offer(params, heads, flags.get("window"))
         seq = params.sequence_dim.size
+        if "block_diffusion" in flags:
+            # the far part's pair over both halves; a query sees at most the
+            # clean half
+            offer = offer._replace(keys=seq // 2,
+                                   block=params.diffusion_block)
         if "sparse" in flags and seq > params.sparse_dense_length:
             # the choice rides with the pair: a bool a query, a block and a
             # K/V head
@@ -1006,7 +1084,7 @@ def flash_band_layers(params, backend=None) -> typing.Optional[int]:
     seq = params.sequence_dim.size
     windows = []
     for offer, times in step_offers(params, "attention"):
-        if offer.keys < seq:
+        if offer.keys < seq and not offer.block:
             windows += [offer.keys] * times
     if not windows:
         return None
@@ -1035,10 +1113,19 @@ def flash_scored_over_live(params, backend=None
     for offer, _ in step_offers(params, "attention"):
         if SELECT_NAME in offer.names:
             continue
-        # ``offer.keys``: the window, or the sequence where there is none
-        for name, share in scored_over_live(
+        if offer.block:
+            # the block-diffusion mask: ``offer.keys`` the trained tokens
+            if not stepped_applies(offer.keys, params.key_dim.size,
+                                   offer.block, itemsize):
+                continue
+            shares = block_diffusion_scored_over_live(
+                offer.keys, params.key_dim.size, offer.block, itemsize)
+        else:
+            # ``offer.keys``: the window, or the sequence where there is none
+            shares = scored_over_live(
                 params.sequence_dim.size, params.key_dim.size, offer.keys,
-                itemsize).items():
+                itemsize)
+        for name, share in shares.items():
             if share is not None:
                 worst[name] = max(worst.get(name, 0.0), share)
     return worst or None

@@ -39,6 +39,18 @@ dimension, the head-sequence's K and V resident, the softmax of a sub-block's
 above, its inner dimension as long as the band, where it does not.  The
 backward is the tiled flash-2 pass either way.
 
+The masks the kernels know: ``causal`` (the lower triangle), a ``window``
+under it, a kept set of BLOCKS of keys or of single KEYS a query
+(``flash_*_select``, further down), and the BLOCK-DIFFUSION mask of
+block-diffusion training (``block_diffusion_attention``, PR 67: over
+``[noised | clean]`` a noised query sees its own noised block both ways and
+the clean keys of earlier blocks, a clean query the clean keys of its own and
+earlier blocks; the halves fold into the batch, the far part is the causal
+grid under a diagonal in steps of the block — ``flash_*_blockdiff``, one more
+compare in the cells the diagonal crosses — and the own blocks and the merge
+by log-sum-exp run in XLA: the dead three quarters of the ``[2 L, 2 L]``
+square are never scored).
+
 Off the TPU ``attention`` runs the dense XLA form (``_xla_reference``, also
 the tests' reference; tests run the kernels in interpret mode).
 """
@@ -256,9 +268,12 @@ def _cell_parts(block_q: int, block_k: int, off: int, window,
     return (_Part(rows, cols, causal, far),)
 
 
-def _part_mask(part: _Part, off, window):
+def _part_mask(part: _Part, off, window, step=None):
     """``s -> s`` with the pairs of ``part`` no query sees at ``_NEG_INF``
-    (None for a mask-free part).  ``off`` is the cell's offset: static on an
+    (None for a mask-free part).  ``step`` (a power of two that divides the
+    q tile; the block-diffusion mask's far part): the diagonal in steps of
+    ``step`` positions, a query sees the keys of EARLIER blocks of ``step``
+    only — its row moved back to the last key before its own block.  ``off`` is the cell's offset: static on an
     edge branch.  The positions are built where the mask is APPLIED, inside
     the caller's branch (at the body's top level every grid step would pay
     for them, the dead cells too), as a column of query positions against a
@@ -272,6 +287,10 @@ def _part_mask(part: _Part, off, window):
         # a pair is ``q_pos - k_pos`` keys back
         q_pos = off + part.rows[0] - part.cols[0] \
             + jax.lax.broadcasted_iota(jnp.int32, (nrows, 1), 0)
+        if step is not None:
+            row = part.rows[0] \
+                + jax.lax.broadcasted_iota(jnp.int32, (nrows, 1), 0)
+            q_pos = q_pos - (row & (step - 1)) - 1
         k_pos = jax.lax.broadcasted_iota(jnp.int32, (1, ncols), 1)
         seen = q_pos >= k_pos if part.causal else None
         if part.far:
@@ -295,7 +314,7 @@ _FORWARD_BODY_CAP = 2 * 1024 * 2048 * 512
 
 def _masked_step(qi, ki, block_q: int, block_k: int, causal: bool, step,
                  dead=None, window=None, valid=None, carried: bool = False,
-                 width: int = 0):
+                 width: int = 0, step_size=None):
     """Shared causal dispatch for the kernels: the mask-free interior
     branch, one branch for each offset at which an edge crosses the cell
     (``_edge_offsets``; all mutually exclusive ``pl.when``s — the FLOP
@@ -318,7 +337,11 @@ def _masked_step(qi, ki, block_q: int, block_k: int, causal: bool, step,
     ``window`` (static; None = the whole causal triangle): blocks wholly
     behind the window are dead too, and the blocks its far edge crosses are
     edge cells like the diagonal's.  ``valid`` (windowed k-outer grids):
-    false where the inner index ran past the sequence's last q block."""
+    false where the inner index ran past the sequence's last q block.
+    ``step_size``: ``_part_mask``'s ``step`` — the stepped diagonal crosses
+    the cells the plain one does and cuts them into the same parts (a sub-
+    square's side is whole blocks: ``stepped_applies``), so only the masks
+    differ."""
     from jax.experimental import pallas as pl
 
     whole = _Part((0, block_q), (0, block_k), False, False)
@@ -345,7 +368,7 @@ def _masked_step(qi, ki, block_q: int, block_k: int, causal: bool, step,
                 shared |= edge & (off == value)
                 del cells[value]
         shared_mask = _part_mask(whole._replace(
-            causal=True, far=window is not None), off, window)
+            causal=True, far=window is not None), off, window, step_size)
 
     @pl.when(shared)
     def _step_interior():
@@ -358,7 +381,8 @@ def _masked_step(qi, ki, block_q: int, block_k: int, causal: bool, step,
             touched = set()
             for part in parts:
                 bands = set(range(part.rows[0], part.rows[1], half))
-                step(part.rows, part.cols, _part_mask(part, value, window),
+                step(part.rows, part.cols,
+                     _part_mask(part, value, window, step_size),
                      not bands & touched)
                 touched |= bands
             if dead is not None:
@@ -520,7 +544,7 @@ def _softmax_finish(o_ref, lse_ref, m_ref, l_ref, acc_ref):
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
                   *, block_q: int, block_k: int, num_k: int, scale: float,
-                  causal: bool, window=None):
+                  causal: bool, window=None, step=None):
     """3-D grid (batch*heads, q blocks, k blocks): one K/V block resident in
     VMEM at a time, online-softmax state carried in VMEM scratch across the
     innermost k dimension — VMEM use is O(block) regardless of sequence
@@ -547,18 +571,21 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
                       slice(*cols))
 
     _masked_step(qi, ki, block_q, block_k, causal, _step, window=window,
-                 carried=True, width=q_ref.shape[-1])
+                 carried=True, width=q_ref.shape[-1], step_size=step)
 
     @pl.when(kk == num_k - 1)
     def _finish():
         _softmax_finish(o_ref, lse_ref, m_ref, l_ref, acc_ref)
 
 
-def _kernel_name(base: str, causal: bool, window) -> str:
+def _kernel_name(base: str, causal: bool, window, step=None) -> str:
     """``base`` + ``_causal`` (lets the FLOP counter subtract the skipped
     dead cells, utils/flops.py count_matmul_flops_split) or ``_window`` (a
     windowed call's grid holds its band only; the trace tells the two
-    apart by it)."""
+    apart by it) or ``_blockdiff`` (the block-diffusion mask's far part: the
+    causal grid under the stepped diagonal)."""
+    if step is not None:
+        return base + "_blockdiff"
     if window is not None:
         return base + "_window"
     return base + "_causal" if causal else base
@@ -576,7 +603,7 @@ def kernel_block(s: int, cap: int = 1024) -> int:
 
 
 def _fwd_flat(qt, kt, vt, scale, causal, block_q, block_k, interpret,
-              out_dtype=None, window=None):
+              out_dtype=None, window=None, step=None):
     """Flat-core forward: q/k [bh, s, d], v [bh, s, d_v] -> (out [bh, s,
     d_v], lse [bh, s]).
 
@@ -601,7 +628,7 @@ def _fwd_flat(qt, kt, vt, scale, causal, block_q, block_k, interpret,
             j, block_q, block_k, window))
     kernel = functools.partial(_flash_kernel, block_q=block_q, block_k=block_k,
                                num_k=num_k, scale=scale, causal=causal,
-                               window=window)
+                               window=window, step=step)
     _kmap = _frontier_kv_map(block_q, block_k, causal, window)
     out, lse = pl.pallas_call(
         kernel,
@@ -623,7 +650,7 @@ def _fwd_flat(qt, kt, vt, scale, causal, block_q, block_k, interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_KERNEL_VMEM_BUDGET),
-        name=_kernel_name("flash_fwd", causal, window),
+        name=_kernel_name("flash_fwd", causal, window, step),
         interpret=interpret,
     )(qt, kt, vt)
     return out, lse[..., 0]
@@ -757,7 +784,7 @@ def _fwd_band(qt, kt, vt, scale, block_q, window, interpret):
 
 
 def _flash_fwd_impl(q, k, v, scale, causal, block_q, block_k, interpret,
-                    window=None):
+                    window=None, step=None):
     """Returns (out [b, s, h, d], lse [b*h, s]) — lse is the backward's
     softmax residual (flash-2: p is recomputed per block as exp(s - lse))."""
     b, s, h, d = q.shape
@@ -771,13 +798,13 @@ def _flash_fwd_impl(q, k, v, scale, causal, block_q, block_k, interpret,
         out, lse = _fwd_band(qt, kt, vt, scale, block_q, window, interpret)
     else:
         out, lse = _fwd_flat(qt, kt, vt, scale, causal, block_q, block_k,
-                             interpret, window=window)
+                             interpret, window=window, step=step)
     return out.reshape(b, h, s, dv).transpose(0, 2, 1, 3), lse
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, dq_ref,
                    acc_ref, *, block_q: int, block_k: int, num_k: int,
-                   scale: float, causal: bool, window=None):
+                   scale: float, causal: bool, window=None, step=None):
     """dq: grid (b*h, q blocks, k blocks), k innermost; dq accumulates in
     VMEM scratch; causally-dead k blocks are skipped."""
     from jax.experimental import pallas as pl
@@ -800,7 +827,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, dq_ref,
             ds.astype(k_ref.dtype), k_ref[slice(*cols), :],
             (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
-    _masked_step(qi, ki, block_q, block_k, causal, _step, window=window)
+    _masked_step(qi, ki, block_q, block_k, causal, _step, window=window,
+                 step_size=step)
 
     @pl.when(kk == num_k - 1)
     def _finish():
@@ -810,7 +838,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, dq_ref,
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, dk_ref,
                     dv_ref, dk_acc, dv_acc, *, block_q: int, block_k: int,
                     num_q: int, scale: float, causal: bool, window=None,
-                    seq_q: int = 0):
+                    seq_q: int = 0, step=None):
     """dk/dv: grid (b*h, k blocks, q blocks), q innermost; for a fixed K/V
     block only q blocks at-or-after it contribute — strictly-earlier
     (causally dead) q blocks are skipped."""
@@ -838,7 +866,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, dk_ref,
             preferred_element_type=jnp.float32)
 
     _masked_step(qi, ki, block_q, block_k, causal, _step, window=window,
-                 valid=valid)
+                 valid=valid, step_size=step)
 
     @pl.when(jj == num_q - 1)
     def _finish():
@@ -849,7 +877,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, dk_ref,
 def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, dqp_ref,
                       dk_ref, dv_ref, dk_acc, dv_acc, *, block_q: int,
                       block_k: int, num_q: int, scale: float, causal: bool,
-                      window=None, seq_q: int = 0):
+                      window=None, seq_q: int = 0, step=None):
     """Fused backward: grid (b*h, k blocks, q blocks), q innermost.
 
     The split dq and dk/dv kernels EACH recompute the two shared
@@ -901,7 +929,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, dqp_ref,
         dqp_ref[r, :] = jnp.zeros_like(dqp_ref[r, :])
 
     _masked_step(qi, ki, block_q, block_k, causal, _step, dead=_dead,
-                 window=window, valid=valid)
+                 window=window, valid=valid, step_size=step)
 
     @pl.when(jj == num_q - 1)
     def _finish():
@@ -940,7 +968,7 @@ def _use_fused_bwd(bh: int, s: int, sk: int, d: int, bk: int) -> bool:
 
 
 def _bwd_flat_fused(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
-                    interpret, out_dtype=None, window=None):
+                    interpret, out_dtype=None, window=None, step=None):
     """One-pass fused backward (see ``_bwd_fused_kernel``).
 
     Under a ``window`` the grid's inner dimension walks a k block's band of
@@ -984,7 +1012,7 @@ def _bwd_flat_fused(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
     dqp, dk, dv = pl.pallas_call(
         functools.partial(_bwd_fused_kernel, block_q=bq, block_k=bk,
                           num_q=inner, scale=scale, causal=causal,
-                          window=window, seq_q=nq),
+                          window=window, seq_q=nq, step=step),
         grid=(bh, nk, inner),
         in_specs=[pl.BlockSpec((None, bq, d), _q_map),
                   pl.BlockSpec((None, bk, d), lambda i, kk, j: (i, kk, 0)),
@@ -1002,7 +1030,7 @@ def _bwd_flat_fused(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_KERNEL_VMEM_BUDGET),
-        name=_kernel_name("flash_bwd_fused", causal, window),
+        name=_kernel_name("flash_bwd_fused", causal, window, step),
         interpret=interpret,
     )(qt, kt, vt, dot, lse3, delta)
     if window is None:
@@ -1017,7 +1045,7 @@ def _bwd_flat_fused(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
 
 
 def _bwd_flat(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
-              interpret, out_dtype=None, window=None):
+              interpret, out_dtype=None, window=None, step=None):
     """Flat-core backward: q/k [bh, s, d], v/dout [bh, s, d_v], lse/delta
     [bh, s, 1] -> (dq, dk [bh, s, d], dv [bh, s, d_v]).  ``lse``/``delta`` are the GLOBAL softmax
     residuals — flash-2's decomposition makes per-block contributions
@@ -1046,7 +1074,7 @@ def _bwd_flat(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
     if _use_fused_bwd(bh, s, sk, d, bk) if window is None \
             else _use_fused_bwd(bh, s + bq, inner_k * bk, d, bk):
         return _bwd_flat_fused(qt, kt, vt, dot, lse3, delta, scale, causal,
-                               bq, bk, interpret, out_dtype, window)
+                               bq, bk, interpret, out_dtype, window, step)
     dq_dtype = qt.dtype if out_dtype is None else out_dtype
     dk_dtype = kt.dtype if out_dtype is None else out_dtype
     dv_dtype = vt.dtype if out_dtype is None else out_dtype
@@ -1058,7 +1086,7 @@ def _bwd_flat(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, block_q=bq, block_k=bk,
                           num_k=inner_k, scale=scale, causal=causal,
-                          window=window),
+                          window=window, step=step),
         grid=(bh, nq, inner_k),
         in_specs=[pl.BlockSpec((None, bq, d), lambda i, j, kk: (i, j, 0)),
                   pl.BlockSpec((None, bk, d), _kv_map),
@@ -1071,7 +1099,7 @@ def _bwd_flat(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_KERNEL_VMEM_BUDGET),
-        name=_kernel_name("flash_bwd_dq", causal, window),
+        name=_kernel_name("flash_bwd_dq", causal, window, step),
         interpret=interpret,
     )(qt, kt, vt, dot, lse3, delta)
 
@@ -1079,7 +1107,7 @@ def _bwd_flat(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, block_q=bq, block_k=bk,
                           num_q=inner_q, scale=scale, causal=causal,
-                          window=window, seq_q=nq),
+                          window=window, seq_q=nq, step=step),
         grid=(bh, nk, inner_q),
         in_specs=[pl.BlockSpec((None, bq, d), _q_map_dkv),
                   pl.BlockSpec((None, bk, d), lambda i, kk, j: (i, kk, 0)),
@@ -1095,16 +1123,18 @@ def _bwd_flat(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_KERNEL_VMEM_BUDGET),
-        name=_kernel_name("flash_bwd_dkv", causal, window),
+        name=_kernel_name("flash_bwd_dkv", causal, window, step),
         interpret=interpret,
     )(qt, kt, vt, dot, lse3, delta)
     return dq, dk, dv
 
 
 def _flash_bwd_pallas(q, k, v, out, lse, dout, scale, causal, block_q,
-                      block_k, interpret, window=None):
+                      block_k, interpret, window=None, step=None, dlse=None):
     """Flash-2 pallas backward over [b, s, h, d] operands; every kernel
-    skips the causally-dead blocks."""
+    skips the causally-dead blocks.  ``dlse`` ``[b * h, s]``: the cotangent
+    of ``lse`` where a caller reads it (``d lse / d s_j = p_j``, so it
+    leaves ``delta``)."""
     b, s, h, d = q.shape
     dv = v.shape[-1]
     # caller-chosen block sizes, exactly as in the forward — attention()
@@ -1121,8 +1151,11 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, scale, causal, block_q,
     # lse/delta travel as [bh, s, 1] (TPU block-tiling rule, see forward)
     delta = jnp.sum(dot.astype(jnp.float32) * ot.astype(jnp.float32), -1,
                     keepdims=True)
+    if dlse is not None:
+        delta = delta - dlse[..., None].astype(jnp.float32)
     dq, dk, dv = _bwd_flat(qt, kt, vt, dot, lse[..., None], delta, scale,
-                           causal, bq, bk, interpret, window=window)
+                           causal, bq, bk, interpret, window=window,
+                           step=step)
 
     def back(x):
         return x.reshape(b, h, s, x.shape[-1]).transpose(0, 2, 1, 3)
@@ -1423,6 +1456,201 @@ def attention(q, k, v, scale: typing.Optional[float] = None,
         return flash_attention(q, k, v, scale, causal, fwd_q, fwd_k,
                                interpret, bwd_block_q=blk, bwd_block_k=blk,
                                window=window)
+
+
+# ---- the block-diffusion mask ------------------------------------------------
+#
+# Block-diffusion training (model/denoise.py; BD3-LM, arXiv:2503.09573) runs
+# the body over ``[noised sequence | clean sequence]``, ``2 L`` positions in
+# blocks of ``B``, ``b(i) = i // B``: a noised query sees the noised keys of
+# its OWN block (both directions) and the clean keys of EARLIER blocks, a
+# clean query the clean keys of its own and earlier blocks.  Read a query at
+# a time, BOTH halves are the same two parts: the clean keys of the blocks
+# BEFORE the query's (the far part: ``L^2 / 2`` pairs a half, the causal
+# triangle under a diagonal in steps of ``B``) and the ``B`` keys of its own
+# block in its own half (the own part: ``L B`` pairs a half, no mask).  So
+# the halves fold into the batch, the far part is the causal kernels on ``L``
+# positions with one more compare in the cells the diagonal crosses
+# (``_part_mask``'s ``step``; kernels ``flash_*_blockdiff``), the own part a
+# ``[L / B, B, B]`` product in XLA, and the two merge by their
+# log-sum-exps, as parallel/ring_attention.py merges its hops.  The dead
+# three quarters of the ``[2 L, 2 L]`` square are never scored.
+
+
+def block_diffusion_mask(length: int, block: int):
+    """The mask from its definition: ``[2 length, 2 length]`` bool, noised
+    half first.  What the forms here are tested against; no program path
+    builds it."""
+    import numpy as np
+    pos = np.arange(2 * length)
+    clean, blk = pos >= length, (pos % length) // block
+    q_clean, k_clean = clean[:, None], clean[None, :]
+    q_blk, k_blk = blk[:, None], blk[None, :]
+    return (~q_clean & ~k_clean & (q_blk == k_blk)) \
+        | (~q_clean & k_clean & (k_blk < q_blk)) \
+        | (q_clean & k_clean & (k_blk <= q_blk))
+
+
+def stepped_applies(s: int, d: int, block: int, itemsize: int,
+                    d_v: typing.Optional[int] = None) -> bool:
+    """Whether the far part of a block-diffusion call over ``s`` positions a
+    half runs the ``flash_*_blockdiff`` kernels (on a TPU): whole 128-tiles,
+    and a sub-square of every tile (``_half_tile``) whole blocks of a power
+    of two, so that the stepped diagonal cuts the cells as the plain one
+    does.  Pure in its arguments."""
+    if s % 128 or block < 1 or block & (block - 1):
+        return False
+    blk, fwd_q, fwd_k, _ = call_tiles(s, d, None, itemsize, d_v)
+    half = min(_half_tile(blk, blk), _half_tile(fwd_q, fwd_k))
+    return half >= 2 and half % block == 0
+
+
+def block_diffusion_live_pairs(length: int, block: int) -> int:
+    """The pairs the mask lets through, a head: two block-causal triangles
+    (``length (length + block) / 2`` clean-to-clean, ``length (length -
+    block) / 2`` noised-to-clean) and the noised blocks' own ``length x
+    block``."""
+    return length * length + length * block
+
+
+def block_diffusion_scored_over_live(length: int, d: int, block: int,
+                                     itemsize: int
+                                     ) -> typing.Dict[str, float]:
+    """``scored_over_live`` of a block-diffusion call of ``length`` positions
+    a half: the far part's tiled kernels over both halves and the own part's
+    ``2 length x block`` pairs, over the mask's live pairs."""
+    blk, fwd_q, fwd_k, _ = call_tiles(length, d, None, itemsize)
+    live = block_diffusion_live_pairs(length, block)
+    own = 2 * length * block
+    return {"fwd": (2 * scored_pairs(length, fwd_q, fwd_k, carried=True)
+                    + own) / live,
+            "bwd": (2 * scored_pairs(length, blk, blk) + own) / live}
+
+
+def _xla_stepped_with_lse(q, k, v, scale, step: int):
+    """The far part's dense form off the TPU (and the kernels' reference):
+    ``softmax(scale q k^T) v`` over the keys of the blocks of ``step``
+    BEFORE the query's, ``(out [b, s, h, d_v], lse [b * h, s])``,
+    differentiable.  A row that sees no key (the first block's) reads a
+    finite ``out`` under an ``lse`` of ``_NEG_INF``: it weighs nothing in a
+    merge."""
+    b, s, h, _ = q.shape
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32) * scale,
+                        k.astype(jnp.float32))
+    blk = jnp.arange(s) // step
+    scores = jnp.where((blk[:, None] > blk[None, :])[None, None], scores,
+                       _NEG_INF)
+    m = scores.max(-1)
+    p = jnp.exp(scores - m[..., None])
+    l = p.sum(-1)
+    out = jnp.einsum("bhqk,bkhd->bqhd", p / l[..., None],
+                     v.astype(jnp.float32))
+    return out.astype(q.dtype), (m + jnp.log(l)).reshape(b * h, s)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def flash_stepped_precomputed(q, k, v, out, lse, scale, step, block_q,
+                              block_k, interpret):
+    """``flash_precomputed`` for the far part of a block-diffusion call: the
+    forward is the PROVIDED ``(out, lse)``, BOTH handed on (the caller merges
+    by ``lse``), the backward the ``flash_*_blockdiff`` pass under both
+    cotangents."""
+    return out, lse
+
+
+def _flash_stepped_pre_fwd(q, k, v, out, lse, scale, step, block_q, block_k,
+                           interpret):
+    return (out, lse), (q, k, v, out, lse)
+
+
+def _flash_stepped_pre_bwd(scale, step, block_q, block_k, interpret, res,
+                           cotangents):
+    q, k, v, out, lse = res
+    dout, dlse = cotangents
+    dq, dk, dv = _flash_bwd_pallas(q, k, v, out, lse, dout, scale, True,
+                                   block_q, block_k, interpret, step=step,
+                                   dlse=dlse)
+    return dq, dk, dv, jnp.zeros_like(out), jnp.zeros_like(lse)
+
+
+flash_stepped_precomputed.defvjp(_flash_stepped_pre_fwd,
+                                 _flash_stepped_pre_bwd)
+
+
+def _own_block(q, k, v, scale, block: int):
+    """The own part: every query against the ``block`` keys of its own block
+    (both directions, no mask), ``(out [n, s, h, d_v] float32, lse [n, s, h]
+    float32)``; ``k``, ``v`` ``[n, s, g, d]`` a K/V head each (``g`` divides
+    ``h``, never repeated).  ``block`` keys a query is no matmul's shape: the
+    scores are a product summed over the features and the values a product
+    summed over the block's keys, float32 — as an einsum XLA pads each ``[B,
+    d] x [d, B]`` to the MXU's tiles and lays the operands out again (14 ms a
+    layer and pass at the SDAR cell's shape for this form's 2: PERF.md
+    section 6, PR 67)."""
+    n, s, h, d = q.shape
+    g, nb, f32 = k.shape[2], s // block, jnp.float32
+    qb = q.reshape(n, nb, block, 1, g, h // g, d).astype(f32)
+    kb = k.reshape(n, nb, 1, block, g, 1, d).astype(f32)
+    vb = v.reshape(n, nb, 1, block, g, 1, v.shape[-1]).astype(f32)
+    scores = jnp.sum(qb * kb, axis=-1) * scale       # [n, nb, B, B, g, r]
+    m = scores.max(3, keepdims=True)
+    p = jnp.exp(scores - m)
+    l = p.sum(3, keepdims=True)
+    out = jnp.sum((p / l)[..., None] * vb, axis=3)   # [n, nb, B, g, r, d_v]
+    return out.reshape(n, s, h, v.shape[-1]), \
+        (m + jnp.log(l)).reshape(n, s, h)
+
+
+def block_diffusion_attention(q, k, v, k_clean, v_clean, block: int,
+                              scale: typing.Optional[float] = None,
+                              stash: typing.Optional[dict] = None,
+                              kernels: bool = True):
+    """Attention under the block-diffusion mask (the section's comment), the
+    halves folded into the batch: ``q [n, s, heads, d]`` and its own half's
+    ``k``, ``v`` ``[n, s, kv heads, d]`` one half a row of ``n`` (``s`` = the
+    trained tokens a sequence; a K/V head each, never repeated), and
+    ``k_clean``, ``v_clean`` ``[n, s, heads, d]`` the CLEAN half's keys and
+    values of the same sequence for every row, a query head each (the
+    kernels are multi-head only) -> ``[n, s, heads, d_v]``.
+
+    The far part's forward runs ONCE, on detached operands — the
+    ``flash_fwd_blockdiff`` kernel on a TPU where ``stepped_applies`` —
+    and its differentiable value is ``flash_stepped_precomputed`` over it
+    (``key_select_attention``'s way); elsewhere, and with ``kernels``
+    false, the dense XLA form, itself differentiated.  ``stash``: under ``attention``'s "name" channel the far
+    part's ``(out, lse)`` are named (``SAVED_NAMES``), so a block's replay
+    runs no forward kernel, only the own part and the merge."""
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
+    n, s, h, d = q.shape
+    if kernels and jax.default_backend() != "cpu" and stepped_applies(
+            s, d, block, q.dtype.itemsize, v.shape[-1]):
+        blk, fwd_q, fwd_k, _ = call_tiles(s, d, None, q.dtype.itemsize,
+                                          v.shape[-1])
+        with jax.named_scope("flash_attention"):
+            out_s, lse_s = _flash_fwd_impl(
+                *(jax.lax.stop_gradient(t) for t in (q, k_clean, v_clean)),
+                scale, True, fwd_q, fwd_k, False, step=block)
+        if stash_naming(stash) and s >= stash.get("min_keys", 0):
+            out_s = checkpoint_name(out_s, SAVED_NAMES[0])
+            lse_s = checkpoint_name(lse_s, SAVED_NAMES[1])
+        with jax.named_scope("flash_attention"):
+            far, far_lse = flash_stepped_precomputed(
+                q, k_clean, v_clean, out_s, lse_s, scale, block, blk, blk,
+                False)
+    else:
+        with jax.named_scope("attention_dense"):
+            far, far_lse = _xla_stepped_with_lse(q, k_clean, v_clean, scale,
+                                                 block)
+    with jax.named_scope("own_block"):
+        own, own_lse = _own_block(q, k, v, scale, block)
+    with jax.named_scope("lse_merge"):
+        far_lse = far_lse.reshape(n, h, s).transpose(0, 2, 1)
+        top = jnp.maximum(far_lse, own_lse)
+        w_far, w_own = jnp.exp(far_lse - top), jnp.exp(own_lse - top)
+        total = w_far + w_own
+        return ((far.astype(jnp.float32) * (w_far / total)[..., None]
+                 + own * (w_own / total)[..., None])).astype(q.dtype)
 
 
 

@@ -54,6 +54,12 @@ _S4K, _S8K, _S16K = (_scored(1.25, 1.125), _scored(1.125, 1.0625),
 #: 512 tiles under a window of 512 (three quadrants of either cell of a band:
 #: 93 x 65,536 x 6 / 4 pairs over 4,059,392), their forward the band kernel
 _LAGUNA = _scored(1.125, 6094848 / 4059392)
+#: the block-diffusion mask at 8,192 trained tokens, block 4: the far part's
+#: causal tiles over both halves and the own blocks' 2 x 8,192 x 4 pairs over
+#: 8,192 x 8,196 live pairs (forward 1,024 x 2,048 tiles, backward 1,024 x
+#: 1,024)
+_SDAR = _scored((2 * 37748736 + 65536) / 67141632,
+                (2 * 35651584 + 65536) / 67141632)
 _CELLS = {
     "train_32big_mixer_b32": (_kinds(), "", {}),
     "train_32big_mixer_dp2tp2": (
@@ -147,6 +153,18 @@ _CELLS = {
          "hbnlp_delta_solve_kernel_layers": 4,
          "hbnlp_delta_rule_kernel_layers": 4,
          "hbnlp_moe_held_rows_bound": 131072, **_S16K[1]}),
+    # PR 67: block-diffusion training: seven layers' (out [1, 16384, 32, 128]
+    # bfloat16, lse [32, 16384] float32) of the mask's far part over BOTH
+    # halves of the doubled stream, the row buffer of a layer that is handed
+    # 16,384 rows, the pairs the blockdiff kernels and the own blocks score
+    # over the mask's 8,192 x 8,196 live ones, and the stream's positions.
+    # The twelve lines above stand
+    "train_sdar_30b_a3b_ep8_s8k": (
+        _kinds(attention=(7, 954204160)),
+        "; moe held rows bound 131072" + _SDAR[0]
+        + "; denoise stream 16384 positions",
+        {"hbnlp_moe_held_rows_bound": 131072, **_SDAR[1],
+         "hbnlp_denoise_stream_positions": 16384}),
 }
 #: the facts that read 0 where no layer has the mechanism; the others have no
 #: series there
@@ -156,7 +174,8 @@ _ALWAYS = ("hbnlp_ssd_state_bytes", "hbnlp_mamba_conv_kernel_layers",
 _SPARSE = ("hbnlp_moe_held_rows_bound", "hbnlp_router_carry_bytes",
            "hbnlp_flash_scored_over_live_pairs",
            "hbnlp_index_loss_kernel_layers",
-           "hbnlp_index_loss_walked_over_visible_pairs")
+           "hbnlp_index_loss_walked_over_visible_pairs",
+           "hbnlp_denoise_stream_positions")
 
 
 @pytest.fixture
@@ -254,8 +273,15 @@ def _config_files():
 #: on both sides (five body layers' and the multi-token-prediction module's
 #: ``(out, lse)``; layer 0 runs outside any region) with ``moe held rows bound
 #: 131072`` —: without them the digest is PR 64's
-#: fc574cb9973bd6b1ce062da7b715e870e530095f, every other line as it was)
-_FILE_DIGEST = "9e0fcbd609c6d4328deeeeb2142f9de7f2bb983e"
+#: fc574cb9973bd6b1ce062da7b715e870e530095f, every other line as it was;
+#: PR 67 added the two SDAR-30B-A3B files — the cell's reads ``attention 7
+#: layers, 954204160`` on both sides with ``moe held rows bound 131072`` and,
+#: new, ``; denoise stream 16384 positions`` (the series
+#: ``hbnlp_denoise_stream_positions``, which no other file has), on a TPU
+#: ``flash scored over live pairs fwd 1.12543 bwd 1.06296`` —: without them
+#: the digest is PR 65's 9e0fcbd609c6d4328deeeeb2142f9de7f2bb983e, every
+#: other line as it was)
+_FILE_DIGEST = "afab9a58e0a13082ca6848573b5819db77f6b067"
 
 
 def every_configuration_file_starts_as_on_the_parent_test(monkeypatch):
@@ -369,7 +395,9 @@ def statistics_are_all_declared_test():
     looped model's loss and (PR 65) two of a multi-token-prediction
     module's, and a step whose layers report nothing (or only some) has only
     those."""
-    assert len(_LAYER_STATS) == 24 == len(declare.stats())
+    assert len(_LAYER_STATS) == 27 == len(declare.stats())
+    assert {name for name in _LAYER_STATS if name.startswith("denoise_")} == {
+        "denoise_masked_share", "denoise_weight_mean", "denoise_loss"}
     assert {name for name in _LAYER_STATS if name.startswith("mtp_")} == {
         "mtp_loss", "mtp_loss_over_main"}
     assert {"moe_bias_abs_max", "moe_all_load_max_over_mean"} \
@@ -436,7 +464,8 @@ def facts_are_declared_once_in_line_order_test():
         "hbnlp_router_carry_bytes", "hbnlp_flash_band_layers",
         "hbnlp_flash_scored_over_live_pairs",
         "hbnlp_index_loss_kernel_layers",
-        "hbnlp_index_loss_walked_over_visible_pairs"]
+        "hbnlp_index_loss_walked_over_visible_pairs",
+        "hbnlp_denoise_stream_positions"]
     assert [fact.metric for fact in facts if fact.zero] == list(_ALWAYS)
     assert [fact.metric for fact in facts if not fact.zero] == list(_SPARSE)
     assert len({fact.place for fact in facts}) == len(facts)

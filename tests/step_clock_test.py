@@ -455,8 +455,8 @@ def readers_take_the_window_by_index_test(monkeypatch, fresh):
 
 def benchmark_lists_the_four_metrics_test():
     """``BENCHMARK.json``: the four entries in PR 51's order (last until
-    PR 54 appended its cell's six), each on all fourteen train cells (eleven
-    until PR 58, twelve until PR 62, thirteen until PR 65), each with its file agreeing on layer and end-to-end
+    PR 54 appended its cell's six), each on all fifteen train cells (eleven
+    until PR 58, twelve until PR 62, thirteen until PR 65, fourteen until PR 67), each with its file agreeing on layer and end-to-end
     metric."""
     import json
     from benchmark.lib import cell as cell_mod
@@ -469,7 +469,7 @@ def benchmark_lists_the_four_metrics_test():
     assert [m["name"] for m in mine] == names
     for entry in mine:
         mod = cell_mod.load_metric(entry["name"])
-        assert entry["workloads"] == cells and len(cells) == 14
+        assert entry["workloads"] == cells and len(cells) == 15
         assert (entry["layer"], entry["moves"], entry["source"],
                 entry["better"]) == (mod.LAYER, mod.MOVES, "program_counter",
                                      "lower")
