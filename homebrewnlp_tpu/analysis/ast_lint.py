@@ -135,10 +135,6 @@ ENV_KNOB_DIRS = tuple(f"homebrewnlp_tpu/{d}/" for d in
 #: environment reads the env-knob rule still admits there -> the debt
 #: (ROADMAP.md) whose payment deletes the entry
 ENV_KNOB_ALLOWED: typing.Dict[str, str] = {
-    "HBNLP_FUSED_DQP_CAP_GB":
-        "scripts/pod_lowering.py pins the fused-backward cap for a chip "
-        "that is not the local client's; goes when the kernel choice takes "
-        "the mesh's device as model/remat.py does",
     "HBNLP_MAP_MIXER_INTERPRET":
         "runs the map-mixer kernel in interpret mode off the TPU; goes "
         "with the kernel (S1) or when its tests pass interpret themselves",

@@ -50,7 +50,8 @@ class Offer(typing.NamedTuple):
     pairs) — a second part, admitted on top of the first and never without
     it.  ``block``: a flash call under the block-diffusion mask
     (``diffusion_block``; 0 = causal), whose ``keys`` are the trained tokens
-    a sequence."""
+    a sequence.  ``key_width``: a flash call's key width where it is not the
+    value's (the latent form's ``d + r``; 0 = the value's)."""
     kind: str
     names: typing.Tuple[str, ...]
     nbytes: int
@@ -59,6 +60,7 @@ class Offer(typing.NamedTuple):
     interior_names: typing.Tuple[str, ...] = ()
     interior_nbytes: int = 0
     block: int = 0
+    key_width: int = 0
 
 
 class Fact(typing.NamedTuple):

@@ -130,7 +130,7 @@ joined_tokens.declares = Layer(stats=(
          "block-diffusion training: the step's loss in float32, before it "
          "is reported in the calculation dtype", "mean")),
     facts=(Fact(
-        64, "hbnlp_denoise_stream_positions",
+        70, "hbnlp_denoise_stream_positions",
         "block-diffusion training: positions a sequence the body of the "
         "built step runs over, noised half and clean half (2 x "
         "sequence_length; no series without diffusion_block)",

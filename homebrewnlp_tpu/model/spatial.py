@@ -25,6 +25,7 @@ from ..core.tensor import (NamedTensor, cumsum as tensor_cumsum, einsum, exp,
 from ..parallel.flash_attention import (SAVED_NAMES, SELECT_NAME,
                                         band_applies,
                                         block_diffusion_scored_over_live,
+                                        call_tiles, one_pass_applies,
                                         scored_over_live, stepped_applies)
 from . import decode as decode_mod
 from .basic import activated_linear_in, activated_linear_out
@@ -1029,6 +1030,10 @@ def _offer(params, extras) -> typing.Optional[Offer]:
         heads = flags.get("q_heads", params.head_dim.size)
         offer = flash_offer(params, heads, flags.get("window"))
         seq = params.sequence_dim.size
+        if "shared_key" in flags:
+            # the latent form: the shared part widens the key alone
+            offer = offer._replace(
+                key_width=params.key_dim.size + flags["shared_key"])
         if "block_diffusion" in flags:
             # the far part's pair over both halves; a query sees at most the
             # clean half
@@ -1131,6 +1136,40 @@ def flash_scored_over_live(params, backend=None
     return worst or None
 
 
+def flash_backward_one_pass_layers(params, backend=None
+                                   ) -> typing.Optional[int]:
+    """How many attention layers of the step run their flash BACKWARD as the
+    one-pass kernel (``parallel/flash_attention.py _bwd_flat_one_pass``: dq,
+    dk and dv from one sweep, nothing partial in HBM), by the predicate
+    ``_bwd_flat`` itself calls on the call's keys, widths and tiles — the
+    other layers that reach the causal, windowed or block-diffusion kernels
+    are on the split dq / dk-dv pair.  None (the gauge reads 0, the line
+    says nothing) where no call reaches them — ``use_flash_attention`` off,
+    the CPU, a sequence of no whole 128-tiles — and where no layer offers
+    such a call (a sparse or indexed layer past its dense length has the
+    ``flash_*_select`` kernels' own backward)."""
+    offers = [(offer, times)
+              for offer, times in step_offers(params, "attention")
+              if SELECT_NAME not in offer.names]
+    if not offers or not _reaches_flash_kernels(params, backend):
+        return None
+    seq, d_v = params.sequence_dim.size, params.key_dim.size
+    itemsize = np.dtype(params.calculation_dtype).itemsize
+    layers = 0
+    for offer, times in offers:
+        d_k = offer.key_width or d_v
+        keys, window = seq, offer.keys if offer.keys < seq else None
+        if offer.block:
+            # the far part: the causal grid over the trained tokens
+            keys, window = offer.keys, None
+            if not stepped_applies(keys, d_k, offer.block, itemsize, d_v):
+                continue
+        blk = call_tiles(keys, d_k, window, itemsize, d_v)[0]
+        layers += times * one_pass_applies(keys, d_k, d_v, blk, blk,
+                                           itemsize)
+    return layers
+
+
 def index_loss_kernel_layers(params, backend=None) -> typing.Optional[int]:
     """How many attention layers of the step (flag ``indexed``) run their
     index loss as the kernel of ``parallel/index_loss.py``, by the predicate
@@ -1188,6 +1227,14 @@ FACTS = (
          lambda params, mesh, backend: index_loss_walked_over_visible(
              params, backend),
          "index loss walked over visible pairs {:.6g}", zero=False),
+    Fact(64, "hbnlp_flash_backward_one_pass_layers",
+         "attention layers of the built step whose flash backward is the "
+         "one-pass kernel (the other layers that reach the flash kernels are "
+         "on the split dq / dk-dv pair; 0 on the CPU, where no call reaches "
+         "the kernels and without such a layer)",
+         lambda params, mesh, backend: flash_backward_one_pass_layers(
+             params, backend),
+         "flash backward one pass {} layers"),
 )
 
 

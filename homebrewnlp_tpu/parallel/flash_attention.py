@@ -27,9 +27,13 @@ lower-left quadrant mask-free, the two on the diagonal masked, the
 upper-right not scored; a windowed cell's far edge likewise).  Backward is
 flash-2's, in pallas under ``jax.custom_vjp``: p is
 recomputed per block from the saved lse, so training needs neither the O(s²)
-residual nor an O(s²) recompute buffer.  One fused pass gives dq, dk and dv
-while its dq-partial buffer fits the chip (``_use_fused_bwd``); a dq and a
-dk/dv kernel above that.
+residual nor an O(s²) recompute buffer.  ONE pass gives dq, dk and dv with
+every accumulation in VMEM (``_bwd_one_pass_kernel``, PR 68: a grid of the
+call's LIVE cells, a q block at a time with its k blocks ascending — dq over
+that walk, a head's whole dk and dv resident for its sweep; nothing partial
+reaches HBM, no grid step for a dead cell) where those accumulators fit the VMEM
+the call asks for (``one_pass_applies``: key width 192 at 16,384 positions
+does, 512 does not); a dq and a dk/dv kernel where they do not.
 
 A WINDOWED call's forward (``window``: query ``i`` sees keys ``i - window + 1
 .. i``) is a band kernel instead (``_fwd_band``, PR 41) wherever a cell's band
@@ -313,27 +317,24 @@ _FORWARD_BODY_CAP = 2 * 1024 * 2048 * 512
 
 
 def _masked_step(qi, ki, block_q: int, block_k: int, causal: bool, step,
-                 dead=None, window=None, valid=None, carried: bool = False,
+                 window=None, valid=None, carried: bool = False,
                  width: int = 0, step_size=None):
     """Shared causal dispatch for the kernels: the mask-free interior
     branch, one branch for each offset at which an edge crosses the cell
     (``_edge_offsets``; all mutually exclusive ``pl.when``s — the FLOP
     counter relies on that, utils/flops.py), or the unconditional non-causal
-    form.  ``step(rows, cols, mask, fresh)`` scores rows ``rows`` of the q
-    tile against keys ``cols`` of the k tile (static ``(start, stop)``
-    pairs), masks the logits with ``mask`` where it is not None and folds
-    them into the kernel's state; ``fresh``: no earlier part of this cell
-    touched these rows (the fused backward assigns its dq-partial rows
-    there, and adds after).  An edge branch scores only the LIVE part of its
-    cell, as ``_cell_parts`` cuts it (``carried``: the kernel carries
-    softmax state across its steps; ``width``: its head width).  Where the
-    forward's branches together pass ``_FORWARD_BODY_CAP``, the edge cells
-    whose part is the whole tile run in the interior's branch, which then
-    masks by position (one body for both, a select a pair on a kernel the
-    MXU bounds at such widths); the others keep their own.  ``dead(rows)``
-    (fused backward only) runs on causally-dead cells, and on the rows of an
-    edge cell that no part scores — it zero-fills the cell's dq-partial rows
-    so the caller's sum over partials never reads uninitialised memory.
+    form.  ``step(rows, cols, mask)`` scores rows ``rows`` of the q tile
+    against keys ``cols`` of the k tile (static ``(start, stop)`` pairs),
+    masks the logits with ``mask`` where it is not None and folds them into
+    the kernel's state (every kernel ACCUMULATES there: a dead cell, and the
+    rows of an edge cell that no part scores, run nothing).  An edge branch
+    scores only the LIVE part of its cell, as ``_cell_parts`` cuts it
+    (``carried``: the kernel carries softmax state across its steps;
+    ``width``: its head width).  Where the forward's branches together pass
+    ``_FORWARD_BODY_CAP``, the edge cells whose part is the whole tile run
+    in the interior's branch, which then masks by position (one body for
+    both, a select a pair on a kernel the MXU bounds at such widths); the
+    others keep their own.
     ``window`` (static; None = the whole causal triangle): blocks wholly
     behind the window are dead too, and the blocks its far edge crosses are
     edge cells like the diagonal's.  ``valid`` (windowed k-outer grids):
@@ -346,7 +347,7 @@ def _masked_step(qi, ki, block_q: int, block_k: int, causal: bool, step,
 
     whole = _Part((0, block_q), (0, block_k), False, False)
     if not causal:
-        step(whole.rows, whole.cols, None, True)
+        step(whole.rows, whole.cols, None)
         return
     if window is None:
         live, full = _causal_split(qi, ki, block_q, block_k)
@@ -372,28 +373,14 @@ def _masked_step(qi, ki, block_q: int, block_k: int, causal: bool, step,
 
     @pl.when(shared)
     def _step_interior():
-        step(whole.rows, whole.cols, shared_mask, True)
+        step(whole.rows, whole.cols, shared_mask)
 
-    half = _half_tile(block_q, block_k)
     for value, parts in cells.items():
         @pl.when(edge & (off == value))
         def _step_edge(parts=parts, value=value):
-            touched = set()
             for part in parts:
-                bands = set(range(part.rows[0], part.rows[1], half))
                 step(part.rows, part.cols,
-                     _part_mask(part, value, window, step_size),
-                     not bands & touched)
-                touched |= bands
-            if dead is not None:
-                for r0 in range(0, block_q, half):
-                    if r0 not in touched:
-                        dead((r0, r0 + half))
-
-    if dead is not None:
-        @pl.when(jnp.logical_not(live))
-        def _step_dead():
-            dead(whole.rows)
+                     _part_mask(part, value, window, step_size))
 
 
 def _frontier_kv_map(block_q: int, block_k: int, causal: bool, window=None):
@@ -563,7 +550,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
 
     score = _make_score(q_ref, k_ref, scale)
 
-    def _step(rows, cols, mask, fresh):
+    def _step(rows, cols, mask):
         s = score(rows, cols)
         if mask is not None:
             s = mask(s)
@@ -820,7 +807,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, dq_ref,
 
     pair = _make_pair(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, scale)
 
-    def _step(rows, cols, mask, fresh):
+    def _step(rows, cols, mask):
         # p and ds round to the operand dtype before their MXU dots
         _, ds = pair(rows, cols, mask)
         acc_ref[slice(*rows), :] += jax.lax.dot_general(
@@ -855,7 +842,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, dk_ref,
 
     pair = _make_pair(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, scale)
 
-    def _step(rows, cols, mask, fresh):
+    def _step(rows, cols, mask):
         r, c = slice(*rows), slice(*cols)
         p, ds = pair(rows, cols, mask)
         dk_acc[c, :] += jax.lax.dot_general(
@@ -874,174 +861,203 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, dk_ref,
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, dqp_ref,
-                      dk_ref, dv_ref, dk_acc, dv_acc, *, block_q: int,
-                      block_k: int, num_q: int, scale: float, causal: bool,
-                      window=None, seq_q: int = 0, step=None):
-    """Fused backward: grid (b*h, k blocks, q blocks), q innermost.
+@functools.lru_cache(maxsize=None)
+def _live_steps(num_q: int, num_k: int, block_q: int, block_k: int,
+                causal: bool, window):
+    """``(qi, ki, edge)`` int32 ``[steps]``: the LIVE cells of a call's
+    ``num_q`` x ``num_k`` rectangle in the order the one-pass backward walks
+    them — a q block at a time, its k blocks ascending — and whether a step
+    is its q block's first (``edge & 1``) and last (``edge & 2``).  A cell
+    is live where ``_causal_split`` / ``_window_split`` say so (the stepped
+    diagonal's cells are the causal ones); every q block has one."""
+    import numpy as np
+    cells = [(j, c) for j in range(num_q) for c in range(num_k)
+             if not causal or _rect_state(j * block_q - c * block_k,
+                                          (0, block_q), (0, block_k),
+                                          window)[0]]
+    qi, ki = (np.asarray(x, np.int32) for x in zip(*cells))
+    assert len(set(qi.tolist())) == num_q
+    turn = qi[1:] != qi[:-1]
+    edge = np.r_[True, turn] + 2 * np.r_[turn, True]
+    return qi, ki, edge.astype(np.int32)
 
-    The split dq and dk/dv kernels EACH recompute the two shared
-    per-pair tensors p = exp(q·kᵀ − lse) and dp = do·vᵀ — 7 dots + 2 exp
-    per live pair across the two passes.  This kernel computes them once
-    and produces all three gradients in one pass — 5 dots + 1 exp — which
-    also lets the dq contribution ride the MXU work that hides the exp
-    (the standalone dq kernel's 3 dots cannot hide its VPU load; the
-    measured symptom was dq ~27% over its MXU ideal while dk/dv ran
-    saturated).  dk/dv accumulate in VMEM scratch across the inner q
-    sweep exactly as in the split kernel; dq cannot (its blocks change
-    every inner step), so each pair writes its contribution to a per-k
-    PARTIAL buffer [bh, nk, sq, d] that the caller sums over nk —
-    causally-dead cells zero-fill their slot so the sum is garbage-free."""
+
+def _bwd_one_pass_kernel(qi_ref, ki_ref, edge_ref, q_ref, k_ref, v_ref,
+                         do_ref, lse_ref, d_ref, dq_ref, dk_ref, dv_ref,
+                         dq_acc, dk_acc, dv_acc, *, block_q: int,
+                         block_k: int, scale: float, causal: bool,
+                         window=None, step=None):
+    """One-pass backward: grid (b*h, live cells) — a head's live cells a q
+    block at a time, k ascending (``_live_steps``, prefetched: the dq
+    kernel's order with no step for a dead cell).
+
+    The split dq and dk/dv kernels EACH recompute the two shared per-pair
+    tensors p = exp(q·kᵀ − lse) and dp = do·vᵀ — 7 dots + 2 exp per live
+    pair across the two passes.  This kernel computes them once and gives
+    all three gradients — 5 dots + 1 exp — which also lets the dq
+    contribution ride the MXU work that hides the exp.  Every gradient
+    accumulates in float32 VMEM and nothing partial reaches HBM: dq in a
+    ``[bq, d]`` scratch over the inner k walk, as in the dq kernel; dk and
+    dv in ``[k tiles, bk, d]`` scratch that stays for the HEAD's whole sweep
+    (zeroed at its first step; at its last, cast into output blocks whose
+    index changes with the head only, so each is written back once).  What
+    that costs in VMEM decides whether the call is this one
+    (``one_pass_applies``)."""
     from jax.experimental import pallas as pl
 
-    ki = pl.program_id(1)
-    jj = pl.program_id(2)
-    qi, valid = _window_q_index(ki, jj, block_q, block_k, window, seq_q)
+    t = pl.program_id(1)
+    qi, ki, edge = qi_ref[t], ki_ref[t], edge_ref[t]
 
-    @pl.when(jj == 0)
+    def _each_k_tile(body):
+        def run(c, carry):
+            body(c)
+            return carry
+        jax.lax.fori_loop(0, dk_acc.shape[0], run, 0)
+
+    @pl.when(t == 0)
+    def _init_head():
+        def zero(c):
+            dk_acc[c] = jnp.zeros(dk_acc.shape[1:], dk_acc.dtype)
+            dv_acc[c] = jnp.zeros(dv_acc.shape[1:], dv_acc.dtype)
+        _each_k_tile(zero)
+
+    @pl.when((edge & 1) == 1)
     def _init():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
+        dq_acc[...] = jnp.zeros_like(dq_acc)
 
     pair = _make_pair(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, scale)
 
-    def _step(rows, cols, mask, fresh):
+    def _step(rows, cols, mask):
         # identical dot/rounding structure to the split kernels (numerics
         # match to f32-accumulation order): p and ds round to the operand
         # dtype before their MXU dots, accumulation stays f32
         r, c = slice(*rows), slice(*cols)
         p, ds = pair(rows, cols, mask)
         ds = ds.astype(q_ref.dtype)
-        dqp = jax.lax.dot_general(
+        dq_acc[r, :] += jax.lax.dot_general(
             ds, k_ref[c, :], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(dqp_ref.dtype)
-        # an edge cell's later parts add to the rows an earlier one wrote
-        dqp_ref[r, :] = dqp if fresh else dqp_ref[r, :] + dqp
-        dk_acc[c, :] += jax.lax.dot_general(
+            preferred_element_type=jnp.float32)
+        dk_acc[ki, c, :] += jax.lax.dot_general(
             ds, q_ref[r, :], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dv_acc[c, :] += jax.lax.dot_general(
+        dv_acc[ki, c, :] += jax.lax.dot_general(
             p.astype(do_ref.dtype), do_ref[r, :], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    def _dead(rows):
-        r = slice(*rows)
-        dqp_ref[r, :] = jnp.zeros_like(dqp_ref[r, :])
+    _masked_step(qi, ki, block_q, block_k, causal, _step, window=window,
+                 step_size=step)
 
-    _masked_step(qi, ki, block_q, block_k, causal, _step, dead=_dead,
-                 window=window, valid=valid, step_size=step)
-
-    @pl.when(jj == num_q - 1)
+    @pl.when((edge & 2) == 2)
     def _finish():
-        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+        dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
+
+    @pl.when(t == pl.num_programs(1) - 1)
+    def _finish_head():
+        def cast(c):
+            dk_ref[c] = dk_acc[c].astype(dk_ref.dtype)
+            dv_ref[c] = dv_acc[c].astype(dv_ref.dtype)
+        _each_k_tile(cast)
 
 
-# dq-partial buffer cap for the fused backward (bytes); above it the split
-# kernels run instead (the buffer is nk x the dq size — negligible for ring
-# hop chunks, ~1GB a layer at 16k and head width 128, and quadratic beyond)
-_FUSED_DQP_CAP = 2 * 1024 ** 3
-# admit dq-partial buffers up to this fraction of per-chip HBM (floored at
-# the old fixed 2GB cap): the 32k-context recipe's 4.3GB buffer fits a
-# 16GB v5e alongside its activations (BASELINE.md '32k context
-# single-chip').  HBNLP_FUSED_DQP_CAP_GB pins the cap (fractional OK) for
-# scripts/pod_lowering.py, which lowers for a chip that is not the local
-# client's (ROADMAP.md: the choice should take the mesh's device)
-_FUSED_DQP_HBM_FRACTION = 0.30
+#: scoped VMEM the one-pass backward asks for, of the 128 MiB a v5e / v5p
+#: core has: its head-resident accumulators are what the call is for
+_ONE_PASS_VMEM_BUDGET = 100 * 1024 * 1024
 
 
-def _fused_dqp_cap() -> int:
-    import os
-    gb = os.environ.get("HBNLP_FUSED_DQP_CAP_GB")
-    if gb:
-        return int(float(gb) * 1024 ** 3)
-    from ..utils.flops import device_hbm_bytes
-    return max(_FUSED_DQP_CAP,
-               int(_FUSED_DQP_HBM_FRACTION * device_hbm_bytes()))
+def _lane_pad(width: int) -> int:
+    """A minor dimension as VMEM holds it: whole tiles of 128 lanes."""
+    return -(-width // 128) * 128
 
 
-def _use_fused_bwd(bh: int, s: int, sk: int, d: int, bk: int) -> bool:
-    """Fused one-pass backward while its float32 dq-partial buffer
-    [bh, sk // bk, s, d] fits under ``_fused_dqp_cap()``; the split dq /
-    dk-dv pair above it."""
-    return bh * max(1, sk // bk) * s * d * 4 <= _fused_dqp_cap()
+def one_pass_applies(sk: int, d: int, d_v: int, block_q: int, block_k: int,
+                     itemsize: int,
+                     out_itemsize: typing.Optional[int] = None) -> bool:
+    """Whether a call's BACKWARD is the one-pass kernel
+    (``_bwd_one_pass_kernel``): its VMEM over ``sk`` keys a head at tiles
+    ``block_q`` x ``block_k`` inside ``_ONE_PASS_VMEM_BUDGET`` — the float32
+    dk and dv accumulators of a whole head, the output blocks they are cast
+    into, the q / k / v / do tiles, the lse / delta columns (a lane tile a
+    row) and the dq tile (two pipeline buffers each), dq's accumulator, and
+    a cell's score planes: four float32 (s, p, dp, ds) and p, ds in the
+    operand dtype.  At 16,384 keys and widths 192 / 128 that is 50 + 7 + 21
+    = 79 MB (a width pads to whole lane tiles: 192 holds 256); head width
+    512 there (2 x 33.5 MB of accumulators and as much again of output
+    blocks) does not fit and keeps the split dq / dk-dv pair.  Pure in its
+    arguments: the one predicate ``_bwd_flat`` and the
+    ``hbnlp_flash_backward_one_pass_layers`` gauge (model/spatial.py) read."""
+    out_itemsize = itemsize if out_itemsize is None else out_itemsize
+    block_q, block_k = min(block_q, sk), min(block_k, sk)
+    wide = _lane_pad(d) + _lane_pad(d_v)
+    resident = sk * wide * (4 + 2 * out_itemsize)
+    tiles = 2 * ((block_q + block_k) * wide * itemsize
+                 + 2 * block_q * 128 * 4
+                 + block_q * _lane_pad(d) * out_itemsize) \
+        + block_q * _lane_pad(d) * 4
+    scores = block_q * block_k * (4 * 4 + 2 * itemsize)
+    return resident + tiles + scores <= _ONE_PASS_VMEM_BUDGET
 
 
-def _bwd_flat_fused(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
-                    interpret, out_dtype=None, window=None, step=None):
-    """One-pass fused backward (see ``_bwd_fused_kernel``).
+def _grad_dtypes(qt, kt, vt, out_dtype):
+    """(dq, dk, dv) dtypes: each operand's own, or ``out_dtype`` for all —
+    the same in both backward forms (which one runs is a size decision and
+    must not change output precision)."""
+    return tuple(x.dtype if out_dtype is None else out_dtype
+                 for x in (qt, kt, vt))
 
-    Under a ``window`` the grid's inner dimension walks a k block's band of
-    q blocks only, and a q block's dq partials lie in ``_window_inner``
-    slots (slot = k block - the band's first k block) instead of one per k
-    block: ``[bh, slots, s + bq, d]``, the last q block a scrap one that
-    cells past the band's end write to.  A q block near position 0 touches
-    fewer k blocks than there are slots; the slots it never wrote are left
-    out of the sum by index."""
+
+def _bwd_flat_one_pass(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
+                       interpret, out_dtype=None, window=None, step=None):
+    """The one-pass backward (see ``_bwd_one_pass_kernel``): the grid's
+    second dimension is the call's live cells, causal, windowed or all."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, s, d = qt.shape
     sk, dv = kt.shape[1], vt.shape[2]
     nq, nk = s // bq, sk // bk
-    # per-operand output dtypes, matching the split path exactly (which
-    # path runs is a size decision and must not change output precision)
-    dq_dtype = qt.dtype if out_dtype is None else out_dtype
-    dk_dtype = kt.dtype if out_dtype is None else out_dtype
-    dv_dtype = vt.dtype if out_dtype is None else out_dtype
+    steps = _live_steps(nq, nk, bq, bk, causal, window)
+    dq_dtype, dk_dtype, dv_dtype = _grad_dtypes(qt, kt, vt, out_dtype)
 
-    inner, slots, rows = nq, nk, s
+    def q_side(width):
+        return pl.BlockSpec((None, bq, width),
+                            lambda i, t, qi, ki, edge: (i, qi[t], 0))
 
-    def dqp_map(i, kk, j):
-        return (i, kk, j, 0)
+    def k_side(width):
+        return pl.BlockSpec((None, bk, width),
+                            lambda i, t, qi, ki, edge: (i, ki[t], 0))
 
-    if window is not None:
-        inner = _window_inner(nk, lambda kk: _window_q_range(kk, bq, bk,
-                                                             window, nq))
-        slots = _window_inner(nq, lambda j: _window_k_range(j, bq, bk,
-                                                            window))
-        rows = s + bq
+    def head_spec(width):
+        # a head's whole dk / dv, [k tiles, bk, width]: the index changes
+        # with the head only, so each is written back once a head — behind
+        # the next head's sweep (two buffers: one measured 0.7-4% slower a
+        # call on a v5e, the write-back waited for; PERF.md section 6, PR 68)
+        return pl.BlockSpec((None, nk, bk, width),
+                            lambda i, t, *_: (i, 0, 0, 0))
 
-        def dqp_map(i, kk, j):
-            qi, live = _window_q_index(kk, j, bq, bk, window, nq)
-            slot = kk - _window_k_range(qi, bq, bk, window)[0]
-            return (i, jnp.where(live, slot, 0), jnp.where(live, qi, nq), 0)
-
-    _q_map = _frontier_q_map(bq, bk, causal, window, nq)
-    qrow_spec = pl.BlockSpec((None, bq, 1), _q_map)
-    dqp, dk, dv = pl.pallas_call(
-        functools.partial(_bwd_fused_kernel, block_q=bq, block_k=bk,
-                          num_q=inner, scale=scale, causal=causal,
-                          window=window, seq_q=nq, step=step),
-        grid=(bh, nk, inner),
-        in_specs=[pl.BlockSpec((None, bq, d), _q_map),
-                  pl.BlockSpec((None, bk, d), lambda i, kk, j: (i, kk, 0)),
-                  pl.BlockSpec((None, bk, dv), lambda i, kk, j: (i, kk, 0)),
-                  pl.BlockSpec((None, bq, dv), _q_map),
-                  qrow_spec, qrow_spec],
-        out_specs=[pl.BlockSpec((None, None, bq, d), dqp_map),
-                   pl.BlockSpec((None, bk, d), lambda i, kk, j: (i, kk, 0)),
-                   pl.BlockSpec((None, bk, dv), lambda i, kk, j: (i, kk, 0))],
-        out_shape=[jax.ShapeDtypeStruct((bh, slots, rows, d), jnp.float32),
-                   jax.ShapeDtypeStruct((bh, sk, d), dk_dtype),
-                   jax.ShapeDtypeStruct((bh, sk, dv), dv_dtype)],
-        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, dv), jnp.float32)],
+    dq, dk, dv_ = pl.pallas_call(
+        functools.partial(_bwd_one_pass_kernel, block_q=bq, block_k=bk,
+                          scale=scale, causal=causal, window=window,
+                          step=step),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(bh, len(steps[0])),
+            in_specs=[q_side(d), k_side(d), k_side(dv), q_side(dv),
+                      q_side(1), q_side(1)],
+            out_specs=[q_side(d), head_spec(d), head_spec(dv)],
+            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),
+                            pltpu.VMEM((nk, bk, d), jnp.float32),
+                            pltpu.VMEM((nk, bk, dv), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((bh, s, d), dq_dtype),
+                   jax.ShapeDtypeStruct((bh, nk, bk, d), dk_dtype),
+                   jax.ShapeDtypeStruct((bh, nk, bk, dv), dv_dtype)],
+        # dk and dv accumulate across a head's whole walk: only the heads
+        # are independent
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=_KERNEL_VMEM_BUDGET),
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_ONE_PASS_VMEM_BUDGET),
         name=_kernel_name("flash_bwd_fused", causal, window, step),
         interpret=interpret,
-    )(qt, kt, vt, dot, lse3, delta)
-    if window is None:
-        dq = dqp.sum(axis=1)
-    else:
-        first, last = _window_k_range(jnp.arange(s, dtype=jnp.int32) // bq,
-                                      bq, bk, window)
-        wrote = jnp.arange(slots, dtype=jnp.int32)[:, None] \
-            <= (last - first)[None]
-        dq = jnp.where(wrote[None, :, :, None], dqp[:, :, :s], 0.0).sum(axis=1)
-    return dq.astype(dq_dtype), dk, dv
+    )(*steps, qt, kt, vt, dot, lse3, delta)
+    return dq, dk.reshape(bh, sk, d), dv_.reshape(bh, sk, dv)
 
 
 def _bwd_flat(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
@@ -1054,14 +1070,27 @@ def _bwd_flat(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
     (``out_dtype=f32`` there: per-hop grad pieces accumulate across P hops
     and must not round per hop).
 
-    The one-pass FUSED kernel (``_bwd_fused_kernel`` — 5 dots + 1 exp per
-    pair instead of the split kernels' 7 + 2) while ``_use_fused_bwd`` says
-    its dq-partial buffer fits; the split dq / dk/dv kernels above that."""
+    The ONE-PASS kernel (``_bwd_one_pass_kernel`` — 5 dots + 1 exp per pair
+    instead of the split kernels' 7 + 2) where ``one_pass_applies`` says a
+    head's dk and dv fit VMEM; the split dq / dk/dv kernels where not."""
+    out_itemsize = jnp.dtype(_grad_dtypes(qt, kt, vt, out_dtype)[0]).itemsize
+    one_pass = one_pass_applies(kt.shape[1], qt.shape[2], vt.shape[2], bq, bk,
+                                qt.dtype.itemsize, out_itemsize)
+    return (_bwd_flat_one_pass if one_pass else _bwd_flat_split)(
+        qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk, interpret,
+        out_dtype, window, step)
+
+
+def _bwd_flat_split(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
+                    interpret, out_dtype=None, window=None, step=None):
+    """The split backward: a dq kernel (k innermost) and a dk/dv kernel (q
+    innermost), each with O(tile) VMEM whatever the sequence's length."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, s, d = qt.shape
     sk, dv = kt.shape[1], vt.shape[2]
+    dq_dtype, dk_dtype, dv_dtype = _grad_dtypes(qt, kt, vt, out_dtype)
     nq, nk = s // bq, sk // bk
     inner_k, inner_q = nk, nq
     if window is not None:
@@ -1070,14 +1099,6 @@ def _bwd_flat(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
                                                               window))
         inner_q = _window_inner(nk, lambda kk: _window_q_range(kk, bq, bk,
                                                                window, nq))
-    # the fused form's dq-partial buffer: one slot a k block, or the band's
-    if _use_fused_bwd(bh, s, sk, d, bk) if window is None \
-            else _use_fused_bwd(bh, s + bq, inner_k * bk, d, bk):
-        return _bwd_flat_fused(qt, kt, vt, dot, lse3, delta, scale, causal,
-                               bq, bk, interpret, out_dtype, window, step)
-    dq_dtype = qt.dtype if out_dtype is None else out_dtype
-    dk_dtype = kt.dtype if out_dtype is None else out_dtype
-    dv_dtype = vt.dtype if out_dtype is None else out_dtype
 
     _kv_map = _frontier_kv_map(bq, bk, causal, window)
     _q_map_dkv = _frontier_q_map(bq, bk, causal, window, nq)
@@ -1240,7 +1261,7 @@ flash_precomputed.defvjp(_flash_pre_fwd, _flash_pre_bwd)
 
 
 #: tile cap of a windowed call's TILED kernels, both sides: the backward
-#: (fused, or the dq / dk-dv pair) always, the forward only where the band
+#: (one pass, or the dq / dk-dv pair) always, the forward only where the band
 #: kernel declines (``band_applies``; its own tiles are ``band_block`` and
 #: ``_BAND_SUB``).  A q tile's band is ``tile + window - 1`` keys wide
 #: whatever the tile, so tiles much wider than the window compute mostly
@@ -1362,6 +1383,24 @@ def attention(q, k, v, scale: typing.Optional[float] = None,
     2048 (fewer online-softmax rescale steps per q row) another 26%; the
     backward keeps 1024x1024 — measured neutral at wider k standalone, and
     the dq kernel exceeds the in-model scoped-VMEM limit there.
+
+    The backward's form (PR 68; ``_bwd_flat``): ONE pass — 5 dots + 1 exp a
+    pair — on a grid of the call's live cells (``_live_steps``: prefetched
+    tables, so a dead cell costs no step: 0.58 us each on the rectangular
+    grid, 120 of a head's 256 at 16,384), dq in VMEM over a q block's k walk
+    and a head's whole dk and dv in float32 VMEM for its sweep, where
+    ``one_pass_applies`` (the call's keys, widths and tiles against the 100
+    MiB it asks for); the split dq / dk-dv pair — 7 + 2 — where a head's
+    accumulators do not fit (width 512 at 16,384).  Until PR 68 the one pass
+    wrote each pair's dq part to a float32 ``[bh, nk, s, d]`` buffer in HBM
+    that XLA summed, and calls whose buffer passed 30% of the chip (width
+    192 at 16,384: 6.4 GB) ran the pair.  Measured on a v5e, ms a call: bh
+    32 x 16,384 x 192 / 128 70.82 (the pair) -> 49.00; 16 x 16,384 x 128
+    20.58 (kernel 16.98 + the sum) -> 16.16 (kernel 15.75); 32 x 4,096 x 128
+    2.89 -> 2.39 (PERF.md section 6, PR 68; ``scripts/kernel_parity.py
+    --only-flash-backward`` takes the two forms again).  Width 192 costs the
+    MXU what 256 does (whole 128-lane tiles): the call runs at ~93% of THAT
+    floor and 74% of the floor its FLOPs alone give.
 
     What a cell the diagonal crosses scores (PR 55; ``_cell_parts``).  The
     kernels' time follows the pairs they SCORE (the forward runs at 54-56%

@@ -217,10 +217,19 @@ def _descend(eqn):
 def _pallas_grid(eqn):
     """``(inner_jaxpr_or_None, grid, cells)`` of a ``pallas_call`` — the
     kernel body runs once per grid cell, so FLOPs are grid product × body
-    FLOPs."""
+    FLOPs.  The flash one-pass backward's grid (batch·heads, steps) walks
+    only the LIVE cells of its ``q blocks x k blocks`` rectangle, by
+    prefetched tables (parallel/flash_attention.py ``_live_steps``): it is
+    counted as that rectangle — the full-square convention — read off its q
+    and k operands' blocks."""
     inner = eqn.params.get("jaxpr")
     gm = eqn.params.get("grid_mapping")
     grid = getattr(gm, "grid", ()) if gm is not None else ()
+    if len(grid) == 2 and gm.num_index_operands \
+            and str(eqn.params.get("name", "")).startswith("flash_bwd_fused"):
+        grid = (grid[0],) + tuple(
+            m.array_aval.shape[-2] // m.block_shape[-2].block_size
+            for m in gm.block_mappings[:2])
     cells = int(np.prod([g for g in grid if isinstance(g, int)],
                         dtype=np.int64)) if grid else 1
     return (getattr(inner, "jaxpr", inner) if inner is not None else None,
@@ -297,9 +306,11 @@ def _flash_scored(eqn, name: str, a: int, b: int
     by the share of the tile it scores, every dot being rows x keys x width.
     None for a call on unequal lengths, which keeps the count by cells."""
     from ..parallel.flash_attention import scored_pairs
-    sq, sk = (v.aval.shape[-2] for v in eqn.invars[:2])
-    # grid (b*h, q blocks, k blocks) for the forward and dq, k-outer else
-    nq, nk = (a, b) if "fwd" in name or "bwd_dq" in name else (b, a)
+    # q and k follow the prefetched step tables, where a call has them
+    first = getattr(eqn.params.get("grid_mapping"), "num_index_operands", 0)
+    sq, sk = (v.aval.shape[-2] for v in eqn.invars[first:first + 2])
+    # grid (b*h, q blocks, k blocks), but for the dk/dv kernel's k-outer one
+    nq, nk = (b, a) if "bwd_dkv" in name else (a, b)
     if sq != sk or sq % nq or sk % nk:
         return None
     bq, bk = sq // nq, sk // nk

@@ -105,6 +105,15 @@ with the row statistics of its step held otherwise
 is) and, from the non-causal forward at k tiles of 1,024 against 2,048, a
 step's cost as ``us a 1,024 keys + us fixed``.
 
+An eleventh leg, run only by ``--only-flash-backward``, holds the flash
+BACKWARD's two forms — the one-pass kernel (``_bwd_flat_one_pass``: dq in VMEM
+over the k walk, dk / dv in head-resident VMEM, no partials in HBM) and the
+split dq / dk-dv pair (``_bwd_flat_split``) — at the shapes the train cells
+hand it (:data:`FLASH_BACKWARD_SHAPES`: causal, block-diffusion and windowed):
+each form's ms a call from the device trace, and dq, dk, dv of ONE head
+against the dense form's gradients in float32 ``highest``: the one pass
+inside :data:`TOLERANCE` and no further off than 1.25 x the split pair.
+
 Shapes: flash at the long-context recipe's per-chip shape (seq 16,384, head
 dim 128; two heads so the dense reference's [s, s] scores fit beside it);
 the mixer at the flagship's (8 heads, seq 512, 512 features/head, batch 32).
@@ -939,7 +948,7 @@ def _flash_forward_variant(name, stats, causal=True, block_k=None):
 
             score = fa._make_score(q_ref, k_ref, scale)
 
-            def step(rows, cols, mask, fresh):
+            def step(rows, cols, mask):
                 r = slice(*rows)
                 s_ = score(rows, cols)
                 if mask is not None:
@@ -1107,6 +1116,112 @@ def _flash_forward_leg(seq: int = 0, bisect: bool = False) -> bool:
                 "us_a_step_at_k1024": us[1024], "us_a_step_at_k2048": us[2048],
                 "us_a_1024_keys": round(per, 3),
                 "us_fixed": round(us[1024] - per, 3)}), flush=True)
+    return bool(ok)
+
+
+#: ``(the cells, batch x heads, positions, key width, value width, window,
+#: block-diffusion step)`` of the backward's calls in the train cells
+FLASH_BACKWARD_SHAPES = (
+    ("joyai_llm_flash, kimi_linear", 32, 16384, 192, 128, None, None),
+    ("nemotron", 16, 16384, 128, 128, None, None),
+    ("ouro", 32, 4096, 128, 128, None, None),
+    ("sdar", 64, 8192, 128, 128, None, 4),
+    ("granite", 32, 8192, 64, 64, None, None),
+    ("laguna (window layers)", 144, 8192, 128, 128, 512, None))
+
+
+def _flash_backward_leg(seq: int = 0) -> bool:
+    """The backward's two forms at its cells' shapes (``seq``: every shape
+    at that many positions and two heads, a CPU rehearsal's size)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from homebrewnlp_tpu.parallel import flash_attention as fa
+
+    interpret = jax.devices()[0].platform == "cpu"
+    ok = True
+    for cells, bh, s, d, dv, window, step in FLASH_BACKWARD_SHAPES:
+        if seq:
+            bh, s = min(bh, 2), seq
+            window = None if window is None else min(window, s // 4)
+        scale = d ** -0.5
+        q, k, v, do = (jax.random.normal(jax.random.PRNGKey(68 + n),
+                                         (bh, s, w), jnp.float32
+                                         ).astype(jnp.bfloat16)
+                       for n, w in enumerate((d, d, dv, dv)))
+        if step is not None:
+            # the first block's rows see no key: the caller's merge hands
+            # them a zero cotangent
+            do = do.at[:, :step].set(0)
+        blk, fwd_q, fwd_k, band = fa.call_tiles(s, d, window, 2, dv)
+        blk = min(blk, s)
+        if band:
+            fwd_q = fwd_k = blk
+        out, lse = jax.jit(lambda q, k, v: fa._fwd_flat(
+            q, k, v, scale, True, fwd_q, fwd_k, interpret, window=window,
+            step=step))(q, k, v)
+        delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), -1,
+                        keepdims=True)
+        args = (q, k, v, do, lse[..., None], delta)
+
+        # ONE head's gradients from the dense form in float32
+        def dense(q, k, v):
+            if step is not None:
+                return fa._xla_stepped_with_lse(q, k, v, scale, step)[0]
+            return fa._xla_reference(q, k, v, scale, True, window)
+
+        with jax.default_matmul_precision("highest"):
+            want = jax.jit(lambda q, k, v, do: jax.vjp(dense, q, k, v)[1](
+                do))(*(t[:1, :, None].astype(jnp.float32)
+                       for t in (q, k, v, do)))
+        want = [np.asarray(w[0, :, 0]) for w in want]
+        applies = fa.one_pass_applies(s, d, dv, blk, blk, 2)
+        line = {"kernel": "flash_bwd", "cells": cells,
+                "implementation": "pallas (interpret)" if interpret
+                else "pallas",
+                "shape": {"bh": bh, "s": s, "d_k": d, "d_v": dv,
+                          "window": window, "step": step},
+                "tiles": [blk, blk], "one_pass_applies": bool(applies)}
+        got, errs, ms = {}, {}, {}
+        for name, form in (("one_pass", fa._bwd_flat_one_pass),
+                           ("split", fa._bwd_flat_split)):
+            run = jax.jit(lambda *a, form=form: form(
+                *a, scale, True, blk, blk, interpret, window=window,
+                step=step))
+            try:
+                timed = _kernel_ms(lambda: run(*args),
+                                   r"flash_bwd_(fused|dq|dkv)_[a-z]+")
+            except Exception as e:  # Mosaic refuses the form: a finding
+                line.setdefault("refused", {})[name] = repr(e)[:400]
+                continue
+            ms[name] = {n: t for n, t in timed.items() if n != "wall"} \
+                or {"wall": timed["wall"]}
+            got[name] = run(*args)
+            errs[name] = {
+                n: float(np.abs(np.asarray(g[0], np.float32) - w).max()
+                         / np.abs(w).max())
+                for n, g, w in zip(("dq", "dk", "dv"), got[name], want)}
+        good = "one_pass" in errs and "split" in errs and all(
+            e <= TOLERANCE and e <= 1.25 * errs["split"][n] + 1e-6
+            for n, e in errs["one_pass"].items())
+        ok &= good
+        if len(got) == 2:
+            line["max_abs_diff_one_pass_to_split"] = {
+                n: float(jnp.max(jnp.abs(a.astype(jnp.float32)
+                                         - b.astype(jnp.float32))))
+                for n, a, b in zip(("dq", "dk", "dv"), got["one_pass"],
+                                   got["split"])}
+        line.update({
+            "ok": bool(good), "ms_a_call": ms,
+            "ms_a_call_total": {n: round(sum(t.values()), 3)
+                                for n, t in ms.items()},
+            "max_err_over_max_ref": {n: {g: round(e, 7) for g, e in
+                                         es.items()}
+                                     for n, es in errs.items()},
+            "tolerance": TOLERANCE, "dtype": "bfloat16"})
+        print(json.dumps(line), flush=True)
+        del got, args, out, lse, delta
     return bool(ok)
 
 
@@ -1543,6 +1658,13 @@ def main(argv=None) -> int:
     ap.add_argument("--flash-forward-seq", type=int, default=0,
                     help="with --only-flash-forward: every shape at this "
                          "many positions and two heads (a CPU rehearsal)")
+    ap.add_argument("--only-flash-backward", action="store_true",
+                    help="run the flash backward's leg alone: the one-pass "
+                         "kernel against the split pair at the six shapes "
+                         "its cells hand it")
+    ap.add_argument("--flash-backward-seq", type=int, default=0,
+                    help="with --only-flash-backward: every shape at this "
+                         "many positions and two heads (a CPU rehearsal)")
     ap.add_argument("--flash-bisect", action="store_true",
                     help="with --only-flash-forward: also time the forward "
                          "with its step's row statistics held otherwise, and "
@@ -1565,6 +1687,10 @@ def main(argv=None) -> int:
         return 0 if ok else 1
     if args.only_flash_forward:
         ok = _flash_forward_leg(args.flash_forward_seq, args.flash_bisect)
+        print(json.dumps({"ok": bool(ok)}), flush=True)
+        return 0 if ok else 1
+    if args.only_flash_backward:
+        ok = _flash_backward_leg(args.flash_backward_seq)
         print(json.dumps({"ok": bool(ok)}), flush=True)
         return 0 if ok else 1
     if args.only_select:

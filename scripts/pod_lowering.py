@@ -81,86 +81,64 @@ def lower_target(config_path: str, topology: str, hbm_key: str = "v5p",
     model = Model(params)
     trainer = Trainer(params, model, mesh)
 
-    # memory-aware kernel/stash heuristics must budget against the TARGET
-    # chips, not the local client (a CPU process lowering for a v5p
-    # pod would otherwise bake a 16GiB-derived dq-partial cap into a 95GiB
-    # chip's executable).  resolve_stash reads the mesh's own devices; the
-    # fused-backward cap has no device argument, so pin it via its env
-    # override for the lowering
-    from homebrewnlp_tpu.utils.flops import device_hbm_bytes
-    target_hbm = device_hbm_bytes(devices[0])
-    cap_key = "HBNLP_FUSED_DQP_CAP_GB"
+    # the memory-aware stash heuristic budgets against the TARGET chips, not
+    # the local client: resolve_stash reads the mesh's own devices (the flash
+    # backward's choice reads VMEM by the call's shapes, no device at all).
+    # ONE aval-construction + lowering path shared with the mesh audit
+    # (analysis/mesh_audit.py train_step_avals): cheap zero-init for the
+    # QR matrices, layout-derived NamedShardings for params, the REAL
+    # Optimizer.init slot discovery for opt-state avals, batch over
+    # 'data' where divisible
+    from homebrewnlp_tpu.analysis import mesh_audit
 
-    def _lower_with_cap():
-        # ONE aval-construction + lowering path shared with the mesh audit
-        # (analysis/mesh_audit.py train_step_avals): cheap zero-init for the
-        # QR matrices, layout-derived NamedShardings for params, the REAL
-        # Optimizer.init slot discovery for opt-state avals, batch over
-        # 'data' where divisible
-        from homebrewnlp_tpu.analysis import mesh_audit
+    state_avals, batch_avals, rng_aval, info = mesh_audit.train_step_avals(
+        params, model, mesh, cheap_init=True)
+    n_params = info["n_params"]
+    trainer.optimizer = info["optimizer"]
 
-        state_avals, batch_avals, rng_aval, info = mesh_audit.train_step_avals(
-            params, model, mesh, cheap_init=True)
-        n_params = info["n_params"]
-        trainer.optimizer = info["optimizer"]
+    step_fn = trainer._build_step(state=state_avals)
+    t_trace = time.monotonic()
+    lowered = step_fn.lower(state_avals, batch_avals, rng_aval)
+    t_lower = time.monotonic()
+    compiled = lowered.compile()
+    t_compile = time.monotonic()
 
-        step_fn = trainer._build_step(state=state_avals)
-        t_trace = time.monotonic()
-        lowered = step_fn.lower(state_avals, batch_avals, rng_aval)
-        t_lower = time.monotonic()
-        compiled = lowered.compile()
-        t_compile = time.monotonic()
+    ma = compiled.memory_analysis()
+    hlo = compiled.as_text()
+    inventory = _collective_inventory(hlo, dict(mesh.shape))
 
-        ma = compiled.memory_analysis()
-        hlo = compiled.as_text()
-        inventory = _collective_inventory(hlo, dict(mesh.shape))
-
-        hbm = HBM_BYTES[hbm_key]
-        # donated state aliases the output, so peak live ≈ arguments (params +
-        # opt state + batch) + XLA temporaries (activations, stash, collective
-        # buffers); generated code is tiny by comparison but counted
-        peak = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
-                + ma.generated_code_size_in_bytes)
-        gib = 1024 ** 3
-        report = {
-            "config": config_path,
-            "topology": topology,
-            "devices": len(devices),
-            "device_kind": str(devices[0].device_kind),
-            "mesh": dict(mesh.shape),
-            "n_params": n_params,
-            "per_chip": {
-                "arguments_gib": round(ma.argument_size_in_bytes / gib, 3),
-                "output_gib": round(ma.output_size_in_bytes / gib, 3),
-                "temp_gib": round(ma.temp_size_in_bytes / gib, 3),
-                "alias_gib": round(ma.alias_size_in_bytes / gib, 3),
-                "code_gib": round(ma.generated_code_size_in_bytes / gib, 3),
-                "peak_estimate_gib": round(peak / gib, 3),
-                "hbm_gib": round(hbm / gib, 2),
-                "fits": bool(peak < hbm),
-            },
-            "collectives": inventory,
-            "timings_s": {"setup": round(t_trace - t0, 1),
-                          "trace_lower": round(t_lower - t_trace, 1),
-                          "compile": round(t_compile - t_lower, 1)},
-        }
-        if keep_hlo_lines:
-            report["hlo_head"] = hlo.splitlines()[:keep_hlo_lines]
-        return report
-
-    cap_prev = os.environ.get(cap_key)
-    os.environ[cap_key] = str(0.30 * target_hbm / 1024 ** 3)
-    # the restore spans EVERYTHING from the assignment on (it used to wrap
-    # only lower()/compile()): an exception in init/aval construction below
-    # would otherwise leak the target-chip cap into the process env,
-    # silently mis-budgeting every later lowering in the same process
-    try:
-        return _lower_with_cap()
-    finally:
-        if cap_prev is None:
-            os.environ.pop(cap_key, None)
-        else:
-            os.environ[cap_key] = cap_prev
+    hbm = HBM_BYTES[hbm_key]
+    # donated state aliases the output, so peak live ≈ arguments (params +
+    # opt state + batch) + XLA temporaries (activations, stash, collective
+    # buffers); generated code is tiny by comparison but counted
+    peak = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
+            + ma.generated_code_size_in_bytes)
+    gib = 1024 ** 3
+    report = {
+        "config": config_path,
+        "topology": topology,
+        "devices": len(devices),
+        "device_kind": str(devices[0].device_kind),
+        "mesh": dict(mesh.shape),
+        "n_params": n_params,
+        "per_chip": {
+            "arguments_gib": round(ma.argument_size_in_bytes / gib, 3),
+            "output_gib": round(ma.output_size_in_bytes / gib, 3),
+            "temp_gib": round(ma.temp_size_in_bytes / gib, 3),
+            "alias_gib": round(ma.alias_size_in_bytes / gib, 3),
+            "code_gib": round(ma.generated_code_size_in_bytes / gib, 3),
+            "peak_estimate_gib": round(peak / gib, 3),
+            "hbm_gib": round(hbm / gib, 2),
+            "fits": bool(peak < hbm),
+        },
+        "collectives": inventory,
+        "timings_s": {"setup": round(t_trace - t0, 1),
+                      "trace_lower": round(t_lower - t_trace, 1),
+                      "compile": round(t_compile - t_lower, 1)},
+    }
+    if keep_hlo_lines:
+        report["hlo_head"] = hlo.splitlines()[:keep_hlo_lines]
+    return report
 
 
 def main() -> int:

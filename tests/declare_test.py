@@ -46,6 +46,15 @@ def _scored(fwd, bwd):
                                                     ("bwd",): bwd}})
 
 
+def _one_pass(scored, layers):
+    """PR 68 added, after ``scored``'s part of the line, how many attention
+    layers run their flash backward as the one-pass kernel
+    (``hbnlp_flash_backward_one_pass_layers``; the long-context cell's
+    eight, at head width 512, are on the split pair: 0)."""
+    return (scored[0] + f"; flash backward one pass {layers} layers",
+            {**scored[1], "hbnlp_flash_backward_one_pass_layers": layers})
+
+
 #: 4,096 positions (forward 1,024 x 2,048 tiles, backward 1,024 x 1,024),
 #: 8,192 and 16,384
 _S4K, _S8K, _S16K = (_scored(1.25, 1.125), _scored(1.125, 1.0625),
@@ -65,17 +74,18 @@ _CELLS = {
     "train_32big_mixer_dp2tp2": (
         _kinds(bottleneck=(32, 2147483648)), "", {}),
     "train_1b_long_context_s16k": (
-        _kinds(attention=(8, 2155872256)), _S16K[0], _S16K[1]),
+        _kinds(attention=(8, 2155872256)), *_one_pass(_S16K, 0)),
     "train_olmoe_1b_7b_s4k": (
-        _kinds(attention=(2, 68157440), experts=(2, 1075315200)), *_S4K),
+        _kinds(attention=(2, 68157440), experts=(2, 1075315200)),
+        *_one_pass(_S4K, 2)),
     "train_granite_4_0_h_micro_long": (
         # PR 52: six of the ten MLPs' gate and up [1, 8192, 8192] bfloat16
         _kinds(attention=(1, 34603008), dense=(6, 1610612736)),
         "; ssd chunk states 67108864 bytes a device; conv kernel 9 layers; "
-        "scan kernel 9 layers" + _S8K[0],                        # PR 48
+        "scan kernel 9 layers" + _one_pass(_S8K, 1)[0],          # PR 48
         {"hbnlp_ssd_state_bytes": 67108864,
          "hbnlp_mamba_conv_kernel_layers": 9,
-         "hbnlp_ssd_scan_kernel_layers": 9, **_S8K[1]}),
+         "hbnlp_ssd_scan_kernel_layers": 9, **_one_pass(_S8K, 1)[1]}),
     "train_olmo_hybrid_7b_long": (
         # PR 52: the last MLP's gate and up [1, 16384, 11008]
         _kinds(attention=(1, 127795200), recurrent=(3, 566231040),
@@ -83,25 +93,26 @@ _CELLS = {
         # PR 50: the rule is the Pallas pair, which keeps the entering
         # states of all 30 heads (until then one group's ten: 94371840)
         "; ssd chunk states 283115520 bytes a device; conv kernel 3 layers; "
-        "solve kernel 3 layers; rule kernel 3 layers" + _S16K[0],
+        "solve kernel 3 layers; rule kernel 3 layers"
+        + _one_pass(_S16K, 1)[0],
         {"hbnlp_ssd_state_bytes": 283115520,
          "hbnlp_mamba_conv_kernel_layers": 3,
          "hbnlp_delta_solve_kernel_layers": 3,
-         "hbnlp_delta_rule_kernel_layers": 3, **_S16K[1]}),
+         "hbnlp_delta_rule_kernel_layers": 3, **_one_pass(_S16K, 1)[1]}),
     # PR 61: the global layer's (out [2, 8192, 48, 128], lse [96, 8192]) rides
     # on its own bytes (until then nothing: the experts' decline kept it out)
     "train_laguna_s_2_1_ep32_s8k": (
         _kinds(attention=(1, 204472320)),
         "; moe held rows bound 131072; flash band 3 layers"
-        + _LAGUNA[0],
+        + _one_pass(_LAGUNA, 5)[0],
         {"hbnlp_moe_held_rows_bound": 131072, "hbnlp_flash_band_layers": 3,
-         **_LAGUNA[1]}),
+         **_one_pass(_LAGUNA, 5)[1]}),
     "train_zaya1_8b_ep2_s16k": (
         _kinds(attention=(8, 272629760), experts=(8, 1612185888)),
         "; moe held rows bound 16384; router carry 117440512 bytes"
-        + _S16K[0],
+        + _one_pass(_S16K, 8)[0],
         {"hbnlp_moe_held_rows_bound": 16384,
-         "hbnlp_router_carry_bytes": 117440512, **_S16K[1]}),
+         "hbnlp_router_carry_bytes": 117440512, **_one_pass(_S16K, 8)[1]}),
     # PR 46: the sparse layer's (out, lse) and its choice (a bool a query and
     # a block); three lightning layers' chunk states, and no conv of theirs
     "train_minicpm_sala_tp2_long": (
@@ -113,7 +124,8 @@ _CELLS = {
     # says so: 12 layers x 4 passes of (out [2, 4096, 16, 128] bfloat16, lse
     # [2, 16, 4096] float32).  The nine lines above stand as they were
     "train_ouro_2_6b_loop4_s4k": (
-        _kinds(attention=(48, 1635778560), unit="executions"), *_S4K),
+        _kinds(attention=(48, 1635778560), unit="executions"),
+        *_one_pass(_S4K, 12)),
     # PR 54: five grouped Mamba-2 layers' chunk states ([1, 128, 64, 64, 128]
     # float32 a layer) and the static row buffer of five LatentMoE layers,
     # whose offer is the combined sum a token ([16384, 1024] bfloat16) with
@@ -124,11 +136,12 @@ _CELLS = {
     "train_nemotron_3_super_tp2_ep64_s16k": (
         _kinds(attention=(1, 68157440), experts=(5, 180224180)),
         "; ssd chunk states 268435456 bytes a device; conv kernel 5 layers; "
-        "scan kernel 5 layers; moe held rows bound 131072" + _S16K[0],
+        "scan kernel 5 layers; moe held rows bound 131072"
+        + _one_pass(_S16K, 1)[0],
         {"hbnlp_ssd_state_bytes": 268435456,
          "hbnlp_mamba_conv_kernel_layers": 5,
          "hbnlp_ssd_scan_kernel_layers": 5,
-         "hbnlp_moe_held_rows_bound": 131072, **_S16K[1]}),
+         "hbnlp_moe_held_rows_bound": 131072, **_one_pass(_S16K, 1)[1]}),
     # PR 58: four KDA layers' rule outputs ([1, 16384, 32, 128] bfloat16 a
     # layer) ride as the recurrent kind and one group's chunk states (8 of 32
     # heads: [256, 8, 128, 128] bfloat16) are what is alive for the backward;
@@ -147,12 +160,12 @@ _CELLS = {
         _kinds(attention=(1, 136314880), recurrent=(4, 1677721600)),
         "; ssd chunk states 268435456 bytes a device; conv kernel 4 layers; "
         "solve kernel 4 layers; rule kernel 4 layers; "
-        "moe held rows bound 131072" + _S16K[0],
+        "moe held rows bound 131072" + _one_pass(_S16K, 1)[0],
         {"hbnlp_ssd_state_bytes": 268435456,
          "hbnlp_mamba_conv_kernel_layers": 4,
          "hbnlp_delta_solve_kernel_layers": 4,
          "hbnlp_delta_rule_kernel_layers": 4,
-         "hbnlp_moe_held_rows_bound": 131072, **_S16K[1]}),
+         "hbnlp_moe_held_rows_bound": 131072, **_one_pass(_S16K, 1)[1]}),
     # PR 67: block-diffusion training: seven layers' (out [1, 16384, 32, 128]
     # bfloat16, lse [32, 16384] float32) of the mask's far part over BOTH
     # halves of the doubled stream, the row buffer of a layer that is handed
@@ -161,16 +174,17 @@ _CELLS = {
     # The twelve lines above stand
     "train_sdar_30b_a3b_ep8_s8k": (
         _kinds(attention=(7, 954204160)),
-        "; moe held rows bound 131072" + _SDAR[0]
+        "; moe held rows bound 131072" + _one_pass(_SDAR, 7)[0]
         + "; denoise stream 16384 positions",
-        {"hbnlp_moe_held_rows_bound": 131072, **_SDAR[1],
+        {"hbnlp_moe_held_rows_bound": 131072, **_one_pass(_SDAR, 7)[1],
          "hbnlp_denoise_stream_positions": 16384}),
 }
 #: the facts that read 0 where no layer has the mechanism; the others have no
 #: series there
 _ALWAYS = ("hbnlp_ssd_state_bytes", "hbnlp_mamba_conv_kernel_layers",
            "hbnlp_delta_solve_kernel_layers", "hbnlp_delta_rule_kernel_layers",
-           "hbnlp_ssd_scan_kernel_layers", "hbnlp_flash_band_layers")
+           "hbnlp_ssd_scan_kernel_layers", "hbnlp_flash_band_layers",
+           "hbnlp_flash_backward_one_pass_layers")
 _SPARSE = ("hbnlp_moe_held_rows_bound", "hbnlp_router_carry_bytes",
            "hbnlp_flash_scored_over_live_pairs",
            "hbnlp_index_loss_kernel_layers",
@@ -280,8 +294,16 @@ def _config_files():
 #: ``hbnlp_denoise_stream_positions``, which no other file has), on a TPU
 #: ``flash scored over live pairs fwd 1.12543 bwd 1.06296`` —: without them
 #: the digest is PR 65's 9e0fcbd609c6d4328deeeeb2142f9de7f2bb983e, every
-#: other line as it was)
-_FILE_DIGEST = "afab9a58e0a13082ca6848573b5819db77f6b067"
+#: other line as it was; PR 68 added the series
+#: ``hbnlp_flash_backward_one_pass_layers`` to every file (0 where no call
+#: reaches the kernels) and ``; flash backward one pass N layers`` to the TPU
+#: side of the 24 files whose step calls the causal, windowed or
+#: block-diffusion flash kernels, and nothing to any CPU side — the cells'
+#: files read JoyAI 7, Kimi-Linear 1, ZAYA1 8, Ouro 12 (layers, not
+#: executions), SDAR 7, Laguna 5, OLMoE 2, granite, Olmo-Hybrid and Nemotron
+#: 1, the long-context file 0 (its eight on the split pair) —: before it
+#: afab9a58e0a13082ca6848573b5819db77f6b067)
+_FILE_DIGEST = "8af285da012d194c1f1f2761c2da8d6dc449c633"
 
 
 def every_configuration_file_starts_as_on_the_parent_test(monkeypatch):
@@ -465,6 +487,7 @@ def facts_are_declared_once_in_line_order_test():
         "hbnlp_flash_scored_over_live_pairs",
         "hbnlp_index_loss_kernel_layers",
         "hbnlp_index_loss_walked_over_visible_pairs",
+        "hbnlp_flash_backward_one_pass_layers",
         "hbnlp_denoise_stream_positions"]
     assert [fact.metric for fact in facts if fact.zero] == list(_ALWAYS)
     assert [fact.metric for fact in facts if not fact.zero] == list(_SPARSE)

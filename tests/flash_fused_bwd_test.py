@@ -1,5 +1,6 @@
-"""The one-pass fused flash backward against the split dq / dk-dv pair and
-dense autodiff, and the buffer that chooses between them."""
+"""The one-pass flash backward (dq in VMEM over the k walk, dk / dv in a head's
+resident accumulators) against the split dq / dk-dv pair and dense autodiff,
+and the fit rule that chooses between them."""
 import functools
 
 import jax
@@ -23,11 +24,11 @@ def _dense_square_grads(causal):
 
 @pytest.mark.parametrize("causal", [True, False])
 @pytest.mark.parametrize("bq,bk", [(16, 16), (16, 32), (32, 16)])
-def fused_bwd_matches_split_test(causal, bq, bk, monkeypatch):
-    """The one-pass fused backward kernel (default) against the split
-    dq / dk/dv kernels and dense autodiff, across uneven tiles (the
-    diagonal frontier crossing block boundaries both ways) and both
-    causal modes."""
+def one_pass_bwd_matches_split_test(causal, bq, bk, monkeypatch):
+    """The one-pass backward kernel (what the fit rule picks at this size)
+    against the split dq / dk/dv kernels and dense autodiff, across uneven
+    tiles (the diagonal frontier crossing block boundaries both ways) and
+    both causal modes."""
     q, k, v, _ = dense_form.inputs(96, 11, d=8)
 
     def grads():
@@ -35,25 +36,37 @@ def fused_bwd_matches_split_test(causal, bq, bk, monkeypatch):
             flash_attention(q, k, v, 0.35, causal, bq, bk, True) ** 2),
             argnums=(0, 1, 2))(q, k, v)
 
-    g_fused = grads()
-    # no buffer fits a cap of 0: the split pair runs (the cap is read at
-    # every call: nothing of jax's is keyed on it)
-    monkeypatch.setattr(fa, "_fused_dqp_cap", lambda: 0)
+    assert fa.one_pass_applies(96, 8, 8, bq, bk, 4)
+    g_one = grads()
+    # the rule is read at every call: nothing of jax's is keyed on it
+    monkeypatch.setattr(fa, "one_pass_applies", lambda *a: False)
     g_split = grads()
     g_ref = _dense_square_grads(causal)(q, k, v)
-    for a, b_, c in zip(g_fused, g_split, g_ref):
-        # fused vs split: same dots/rounding points, only the dq partial-sum
-        # order differs (VMEM sequential vs XLA reduce over nk)
+    for a, b_, c in zip(g_one, g_split, g_ref):
+        # one pass vs split: the same dots, rounding points and float32
+        # accumulation in VMEM; dk / dv add their q blocks in the same order
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    rtol=1e-5, atol=1e-5)
         np.testing.assert_allclose(np.asarray(a), np.asarray(c),
                                    rtol=2e-4, atol=2e-5)
 
 
-def fused_bwd_uneven_lengths_test(monkeypatch):
-    """_bwd_flat with sq != sk (the ring-hop contract allows it): fused vs
-    split parity on a rectangular non-causal pair."""
-    from homebrewnlp_tpu.parallel.flash_attention import _bwd_flat
+def _flat_residuals(qt, kt, vt, dot, scale):
+    """``(lse [bh, s, 1], delta [bh, s, 1])`` of the dense form."""
+    scores = jnp.einsum("zqd,zkd->zqk", qt.astype(jnp.float32),
+                        kt.astype(jnp.float32)) * scale
+    m = scores.max(-1)
+    p_un = jnp.exp(scores - m[..., None])
+    l = p_un.sum(-1)
+    out = jnp.einsum("zqk,zkd->zqd", p_un / l[..., None],
+                     vt.astype(jnp.float32))
+    delta = jnp.sum(dot.astype(jnp.float32) * out, -1, keepdims=True)
+    return (m + jnp.log(l))[..., None], delta
+
+
+def one_pass_bwd_uneven_lengths_test():
+    """_bwd_flat with sq != sk (the ring-hop contract allows it): one pass
+    vs split parity on a rectangular non-causal pair."""
     rng = np.random.default_rng(12)
     bh, sq, sk, d = 2, 32, 64, 8
     f32 = np.float32
@@ -61,30 +74,25 @@ def fused_bwd_uneven_lengths_test(monkeypatch):
     kt = jnp.asarray(rng.standard_normal((bh, sk, d)).astype(f32))
     vt = jnp.asarray(rng.standard_normal((bh, sk, d)).astype(f32))
     dot = jnp.asarray(rng.standard_normal((bh, sq, d)).astype(f32))
-    # consistent (lse, delta) residuals from the dense form
-    scores = jnp.einsum("zqd,zkd->zqk", qt, kt) * 0.35
-    m = scores.max(-1)
-    p_un = jnp.exp(scores - m[..., None])
-    l = p_un.sum(-1)
-    lse = m + jnp.log(l)
-    out = jnp.einsum("zqk,zkd->zqd", p_un / l[..., None], vt)
-    delta = jnp.sum(dot * out, -1, keepdims=True)
-
-    res_fused = _bwd_flat(qt, kt, vt, dot, lse[..., None], delta, 0.35,
-                          False, 16, 16, True)
-    monkeypatch.setattr(fa, "_fused_dqp_cap", lambda: 0)
-    res_split = _bwd_flat(qt, kt, vt, dot, lse[..., None], delta, 0.35,
-                          False, 16, 16, True)
-    for a, b_ in zip(res_fused, res_split):
+    lse, delta = _flat_residuals(qt, kt, vt, dot, 0.35)
+    args = (qt, kt, vt, dot, lse, delta, 0.35, False, 16, 16, True)
+    res_one = fa._bwd_flat_one_pass(*args)
+    res_split = fa._bwd_flat_split(*args)
+    for a, b_ in zip(res_one, res_split):
+        assert a.shape == b_.shape
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    rtol=1e-5, atol=1e-5)
+    # the dispatcher takes the one pass here
+    for a, b_ in zip(fa._bwd_flat(*args), res_one):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b_))
 
 
-def fused_bwd_random_shapes_property_test():
+def one_pass_bwd_random_shapes_property_test():
     """Property sweep: random (seq, tiles, causal, dtype) combinations
-    through the fused backward vs dense autodiff — shape-dependent logic
-    (frontier clamps, dead-cell zero-fill, partial-slice counts, uneven
-    tile ratios) must hold everywhere, not just at the tuned points."""
+    through the one-pass backward vs dense autodiff — shape-dependent logic
+    (frontier clamps, the head's first and last step, the resident
+    accumulators' tile index, uneven tile ratios) must hold everywhere, not
+    just at the tuned points."""
     rng = np.random.default_rng(99)
     for trial in range(6):
         s = int(rng.choice([48, 64, 80, 96, 128]))
@@ -108,26 +116,117 @@ def fused_bwd_random_shapes_property_test():
                 err_msg=f"trial={trial} s={s} bq={bq} bk={bk} causal={causal}")
 
 
-@pytest.mark.parametrize("bh,s,d,fused", [
-    # train_1b_long_context_s16k: 16 heads x 512, 8.6 GB of dq partials
-    (16, 16384, 512, False),
-    # train_olmoe_1b_7b_s4k: batch 2 x 16 heads x 128, 268 MB
-    (32, 4096, 128, True),
-    # one ring hop's chunk pair of configs/1b_long_context.json, 134 MB
-    (16, 2048, 512, True),
-    # BASELINE.md '32k context single-chip': 8 heads x 128, batch 1, 4.3 GB
-    (8, 32768, 128, True),
-])
-def backward_path_follows_the_buffer_test(bh, s, d, fused, monkeypatch):
-    """The one fork the backward keeps is chosen from what the code
-    observes — the dq-partial buffer's bytes against the chip's memory, here
-    a v5e's 16 GiB — and the benchmark has a cell on each side of it."""
-    from homebrewnlp_tpu.utils import flops
-    monkeypatch.delenv("HBNLP_FUSED_DQP_CAP_GB", raising=False)
-    monkeypatch.setattr(flops, "device_hbm_bytes",
-                        lambda device=None: 16 * 1024 ** 3)
-    bk = fa.kernel_block(s)
-    assert bk == 1024
-    assert fa._use_fused_bwd(bh, s, s, d, bk) is fused
+@pytest.mark.parametrize("bq,bk", [(32, 32), (32, 64), (64, 32)])
+def one_pass_bwd_at_unequal_widths_test(bq, bk):
+    """Key width 24, value width 16 (latent attention's 192 / 128 scaled
+    down) over several q and k blocks with the diagonal crossing both ways:
+    the one pass against the split pair, float32 summation order apart, and
+    against the dense form's gradients."""
+    q, k, v, do = (x[0].transpose(1, 0, 2) for x in dense_form.inputs(
+        256, 21, d=24, d_v=16))
+    out, lse = fa._fwd_flat(q, k, v, 0.2, True, bq, bk, True)
+    delta = jnp.sum(do * out, -1, keepdims=True)
+    args = (q, k, v, do, lse[..., None], delta, 0.2, True, bq, bk, True)
+    one, split = fa._bwd_flat_one_pass(*args), fa._bwd_flat_split(*args)
+    want = dense_form.dense(256, 21, scale=0.2, d=24, d_v=16)[2]
+    for a, b_, w, width in zip(one, split, want, (24, 24, 16)):
+        assert a.shape == b_.shape == (2, 256, width)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(np.asarray(a),
+                                   np.asarray(w[0].transpose(1, 0, 2)),
+                                   rtol=dense_form.GRAD_RTOL,
+                                   atol=dense_form.GRAD_ATOL)
 
 
+@pytest.mark.parametrize("step", [4, 16])
+def one_pass_bwd_under_the_stepped_diagonal_test(step):
+    """The block-diffusion mask's far part (``step``: a query sees the keys
+    of EARLIER blocks): the one pass against the split pair and against the
+    dense stepped form, the first block's rows (which see no key) under the
+    zero cotangent the caller's merge hands them."""
+    q, k, v, do = (x[0].transpose(1, 0, 2) for x in dense_form.inputs(
+        256, 23, d=16))
+    do = do.at[:, :step].set(0)
+    out, lse = fa._fwd_flat(q, k, v, 0.25, True, 64, 128, True, step=step)
+    delta = jnp.sum(do * out, -1, keepdims=True)
+    args = (q, k, v, do, lse[..., None], delta, 0.25, True, 64, 64, True)
+    one = fa._bwd_flat_one_pass(*args, step=step)
+    split = fa._bwd_flat_split(*args, step=step)
+
+    def dense(q, k, v):
+        return fa._xla_stepped_with_lse(
+            *(x.transpose(1, 0, 2)[None] for x in (q, k, v)), 0.25, step
+        )[0][0].transpose(1, 0, 2)
+
+    want = jax.vjp(dense, q, k, v)[1](do)
+    for a, b_, w in zip(one, split, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(w),
+                                   rtol=dense_form.GRAD_RTOL,
+                                   atol=dense_form.GRAD_ATOL)
+
+
+def one_pass_bwd_float32_partials_test():
+    """The ring hop's contract: bfloat16 operands, ``out_dtype=float32``
+    gradients, no rounding between the accumulators and the outputs — the
+    one pass hands back the split pair's float32 values."""
+    q, k, v, do = (x[0].transpose(1, 0, 2) for x in dense_form.inputs(
+        256, 25, d=16, dtype=jnp.bfloat16))
+    out, lse = fa._fwd_flat(q, k, v, 0.25, True, 64, 128, True,
+                            out_dtype=jnp.float32)
+    delta = jnp.sum(do.astype(jnp.float32) * out, -1, keepdims=True)
+    args = (q, k, v, do, lse[..., None], delta, 0.25, True, 64, 64, True)
+    one = fa._bwd_flat_one_pass(*args, out_dtype=jnp.float32)
+    split = fa._bwd_flat_split(*args, out_dtype=jnp.float32)
+    for a, b_ in zip(one, split):
+        assert a.dtype == b_.dtype == jnp.float32
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=1e-6, atol=1e-6)
+    # without it each gradient takes its operand's dtype, as the pair's
+    for a, b_ in zip(fa._bwd_flat_one_pass(*args), fa._bwd_flat_split(*args)):
+        assert a.dtype == b_.dtype == jnp.bfloat16
+
+
+#: ``(positions, key width, value width, window, block-diffusion block)`` of
+#: the flash call of every train cell that makes one (the other four — both
+#: mixer cells, MiniCPM-SALA, Keye-VL-2.0 — reach no causal, windowed or
+#: block-diffusion kernel), a ring hop's chunk pair and BASELINE.md's 32k
+#: recipe, and whether the backward is the one pass
+_CALLS = {
+    # 2 x 33.5 MB of float32 accumulators a head: the split pair
+    "train_1b_long_context_s16k": (16384, 512, 512, None, 0, False),
+    "train_olmoe_1b_7b_s4k": (4096, 128, 128, None, 0, True),
+    "train_granite_4_0_h_micro_long": (8192, 64, 64, None, 0, True),
+    "train_olmo_hybrid_7b_long": (16384, 128, 128, None, 0, True),
+    "train_laguna_s_2_1_ep32_s8k-global": (8192, 128, 128, None, 0, True),
+    "train_laguna_s_2_1_ep32_s8k-window": (8192, 128, 128, 512, 0, True),
+    "train_zaya1_8b_ep2_s16k": (16384, 128, 128, None, 0, True),
+    "train_ouro_2_6b_loop4_s4k": (4096, 128, 128, None, 0, True),
+    "train_nemotron_3_super_tp2_ep64_s16k": (16384, 128, 128, None, 0, True),
+    # latent attention: until PR 68 on the split pair (6.4 GB of partials)
+    "train_kimi_linear_ep32_s16k": (16384, 192, 128, None, 0, True),
+    "train_joyai_llm_flash_ep16_s16k": (16384, 192, 128, None, 0, True),
+    "train_sdar_30b_a3b_ep8_s8k": (8192, 128, 128, None, 4, True),
+    "ring-hop-of-1b_long_context": (2048, 512, 512, None, 0, True),
+    "baseline-32k-recipe": (32768, 128, 128, None, 0, True),
+}
+
+
+@pytest.mark.parametrize("call", sorted(_CALLS))
+def backward_form_follows_the_fit_test(call):
+    """The one fork the backward keeps is chosen from what the code observes
+    — a head's accumulators, output blocks, tiles and score planes against
+    the VMEM the call asks for — and the benchmark has cells on each side."""
+    s, d, d_v, window, block, one_pass = _CALLS[call]
+    if block:
+        assert fa.stepped_applies(s, d, block, 2, d_v)
+    blk = fa.call_tiles(s, d, window, 2, d_v)[0]
+    assert blk == (512 if window else 1024)
+    assert fa.one_pass_applies(s, d, d_v, blk, blk, 2) is one_pass
+    # float32 outputs (a ring hop's) double the output blocks: of these
+    # calls the 32k recipe's alone would then pass the budget
+    assert fa.one_pass_applies(s, d, d_v, blk, blk, 2, 4) \
+        is (one_pass and call != "baseline-32k-recipe")
+    assert fa._ONE_PASS_VMEM_BUDGET < 128 * 1024 ** 2

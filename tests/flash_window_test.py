@@ -63,16 +63,15 @@ def window_forward_matches_the_band_mask_test(s, window, bq, bk, band_form):
         rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("fused", [True, False], ids=["fused", "split"])
+@pytest.mark.parametrize("fused", [True, False], ids=["one_pass", "split"])
 @pytest.mark.parametrize("s,window,bq,bk", WINDOW_CASES)
 def window_backward_matches_the_band_mask_test(s, window, bq, bk, fused,
                                                monkeypatch, band_form):
-    """The fused backward (dq partials in the band's slots, summed by index)
-    and the split dq / dk-dv pair, both on grids as long as the band, both
-    on the ``lse`` the band forward wrote."""
+    """The one-pass backward (dq in VMEM over the band's k walk, dk / dv in
+    the head's resident accumulators) and the split dq / dk-dv pair, both on
+    grids as long as the band, both on the ``lse`` the band forward wrote."""
     q, k, v, do = _window_inputs(s)
-    monkeypatch.setattr(fa, "_fused_dqp_cap",
-                        (lambda: 1 << 40) if fused else (lambda: 0))
+    monkeypatch.setattr(fa, "one_pass_applies", lambda *a: fused)
     assert band_form == "band" and fa.band_applies(s, 16, window, 4)
     got = jax.vjp(lambda q, k, v: flash_attention(
         q, k, v, 0.25, True, bq, bk, True, None, None, window), q, k, v)[1](do)
@@ -99,7 +98,9 @@ def the_band_forward_is_the_windowed_call_test(monkeypatch):
     """At the Laguna cell's geometry (window 512, head width 128, bfloat16)
     the windowed forward is still named ``flash_fwd_window`` (the trace's
     readers cost it by that name), on a grid of (head-sequences, q tiles)
-    with no k dimension; the backward keeps ``window_block``'s grid; and the
+    with no k dimension; the backward keeps ``window_block``'s tiles, its
+    grid the band's 31 live cells a head-sequence (16 q blocks of 512, two k
+    blocks each but the first: no step for a dead cell, PR 68); and the
     predicate declines what does not fit a cell."""
     q = jax.ShapeDtypeStruct((1, 8192, 2, 128), jnp.bfloat16)
 
@@ -112,7 +113,7 @@ def the_band_forward_is_the_windowed_call_test(monkeypatch):
     kernels = dict(dense_form.forward_kernels(grad, q, q, q))
     tile = fa.band_block(8192)
     assert kernels == {"flash_fwd_window": (2, 8192 // tile),
-                       "flash_bwd_fused_window": (2, 16, 2)}
+                       "flash_bwd_fused_window": (2, 31)}
     assert fa.band_applies(8192, 128, 512, 2)
     # K and V of one head-sequence, resident: 2 x 2 x s x d x 2 bytes
     assert fa.band_applies(32768, 128, 512, 2)
@@ -127,7 +128,7 @@ def the_band_forward_is_the_windowed_call_test(monkeypatch):
         == (2, 16, 2)
 
 
-@pytest.mark.parametrize("fused,digest", [(True, "d5bbd3f5dd0fee2e"),
+@pytest.mark.parametrize("fused,digest", [(True, "0395705bf82abe9c"),
                                           (False, "aacc61b444fdd5fd")])
 def no_window_is_the_parents_call_test(fused, digest, monkeypatch):
     """``window=None`` traces to one call whether the argument is left out
@@ -138,9 +139,10 @@ def no_window_is_the_parents_call_test(fused, digest, monkeypatch):
     PR 55 MEANT to move the bodies (an edge cell scores its live part), the
     grids, maps and names are as they were (``flops_test.py``); PR 66 the
     forward's alone (its row statistics lane-replicated: ff0effe0be83d952 /
-    6c1cc156f207ebf9 before)."""
-    monkeypatch.setattr(fa, "_fused_dqp_cap",
-                        (lambda: 1 << 40) if fused else (lambda: 0))
+    6c1cc156f207ebf9 before); PR 68 the fused backward's (the one-pass
+    kernel on a grid of the live cells, no dq partials: d5bbd3f5dd0fee2e before),
+    the split pair's as it was."""
+    monkeypatch.setattr(fa, "one_pass_applies", lambda *a: fused)
     q = jax.ShapeDtypeStruct((1, 2048, 2, 128), jnp.bfloat16)
 
     def loss(q, k, v, *window):

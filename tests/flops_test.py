@@ -111,8 +111,7 @@ def flash_executed_flops_follow_the_scored_pairs_test(s, kernel, monkeypatch):
                                              count_matmul_flops_split)
     d, n = 128, s // 1024
     fused = kernel == "flash_bwd_fused_causal"
-    monkeypatch.setattr(fa, "_fused_dqp_cap",
-                        (lambda: 1 << 50) if fused else (lambda: 0))
+    monkeypatch.setattr(fa, "one_pass_applies", lambda *a: fused)
     x = jax.ShapeDtypeStruct((1, s, 1, d), jnp.bfloat16)
     blk, fwd_q, fwd_k, band = fa.call_tiles(s, d, None, 2)
     assert (blk, fwd_q, fwd_k, band) == (1024, 1024, 2048, False)

@@ -153,7 +153,7 @@ def grouped_flash_matches_repeated_reference_test(heads, kv_heads, fused,
     repeated over their group, then the multi-head kernels (interpret mode),
     autodiff summing dk and dv over the group: forward, dQ, dK, dV against
     the dense form, one-pass and split backward."""
-    monkeypatch.setattr(fa, "_use_fused_bwd", lambda *a: fused)
+    monkeypatch.setattr(fa, "one_pass_applies", lambda *a: fused)
     rng = np.random.default_rng(heads * 10 + kv_heads)
     b, s, d = 2, 256, 32
     q, do = (jnp.asarray(rng.normal(size=(b, s, heads, d)).astype(np.float32))

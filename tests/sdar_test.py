@@ -286,7 +286,7 @@ def blockdiff_kernels_match_the_dense_mask_test(monkeypatch, tiles, block,
     merge reads ``lse``) — merged with the own blocks: against ONE softmax
     under the dense mask, value and the three gradients.  1e-5: float32,
     summation order."""
-    monkeypatch.setattr(fa, "_use_fused_bwd", lambda *_: fused)
+    monkeypatch.setattr(fa, "one_pass_applies", lambda *_: fused)
     length, (bq, bk) = 256, tiles
     q2, k2, v2, w = _stream(7 + block, 1, length, 2, 16)
     mask = jnp.asarray(fa.block_diffusion_mask(length, block))
@@ -448,13 +448,15 @@ _PARENT = {
     "train_laguna_s_2_1_ep32_s8k": "c7c8961295c85dbdf5e393e76e908935320b914e",
 }
 #: the causal flash call whole (forward, fused backward) and a windowed one,
-#: by the kernels' equations on the PARENT
+#: by the kernels' equations on the PARENT; PR 68 took the two backwards
+#: again (the one-pass kernel: 688c1baefd5c77e47329d5ab4b87ae7fc48a1802 /
+#: 49dcb72718dac9333ba83cbc851a56fb8ebe3027 before), the forwards stand
 _PARENT_CALLS = {
     "causal": {
-        "flash_bwd_fused_causal": "688c1baefd5c77e47329d5ab4b87ae7fc48a1802",
+        "flash_bwd_fused_causal": "2117c0f17e81bd690e7d4418bb6d73981360f0ff",
         "flash_fwd_causal": "115f8b85e5751943692dee7169296f3b5a5e58a4"},
     "window": {
-        "flash_bwd_fused_window": "49dcb72718dac9333ba83cbc851a56fb8ebe3027",
+        "flash_bwd_fused_window": "54027c10c0f6287dcaca1574b36a05eff303e821",
         "flash_fwd_window": "0a3302965fb307ae6ba4b592bd54848fd11f51fa"},
 }
 

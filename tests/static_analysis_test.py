@@ -360,7 +360,7 @@ def env_knob_rule_negative_control_test():
                 'import os\nx = os.environ.pop("HBNLP_NEW_TILE", None)\n'):
         assert [f.rule for f in ast_lint.lint_source(rel, bad)] \
             == ["env-knob"], bad
-    allowed = 'import os\nx = os.environ.get("HBNLP_FUSED_DQP_CAP_GB")\n'
+    allowed = 'import os\nx = os.environ.get("HBNLP_MAP_MIXER_INTERPRET")\n'
     assert ast_lint.lint_source(rel, allowed) == []
     # the other layers keep their deployment settings (addresses, paths)
     assert ast_lint.lint_source("homebrewnlp_tpu/distributed/x.py",
