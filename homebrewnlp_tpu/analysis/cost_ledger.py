@@ -20,7 +20,9 @@ module turns that into a budgeted artifact:
   --write`` and review the diff, docs/STATIC_ANALYSIS.md).
 * :func:`scope_key` also folds the ``tf_op`` of a TPU trace's device ops
   (``benchmark/lib/program_readers.py``), so measured time and these
-  budgets share their scope names.
+  budgets share their scope names; :func:`pass_key` folds the same path
+  into the PASS that runs the instruction — forward, replay, backward,
+  optimizer (``benchmark/lib/pass_readers.py``).
 
 Import stays cheap: jax only inside functions (the AST-only consumers of
 the package import this module's :func:`scope_key` without jax).
@@ -172,6 +174,62 @@ def scope_key(path: str) -> str:
     return _MTP if key == "unscoped" else f"{_MTP}/{key}"
 
 
+#: the components that say "this forward runs AGAIN, inside a backward":
+#: ``jax.checkpoint``'s own name for the computation it rematerializes
+#: (jax ``ad_checkpoint.py``) and the scope under which the program makes its
+#: own replays (core/scope.py ``REPLAY``; mirrored, not imported — this module
+#: must stay importable without jax; update together)
+_REPLAY_MARKS = frozenset(("rematted_computation", "replay"))
+PASSES = ("forward", "replay", "backward", "optimizer", "unmarked")
+
+
+def pass_key(path: str) -> str:
+    """Fold a name-stack / HLO ``op_name`` path of the train step into the
+    PASS that runs the instruction, one of :data:`PASSES` — the dimension
+    :func:`scope_key` unwraps away:
+
+    * ``optimizer`` where :func:`scope_key` says so;
+    * ``replay``: a component is one of :data:`_REPLAY_MARKS` — a forward
+      made again for a backward — and the marked region was not itself
+      transposed;
+    * ``backward``: a ``transpose(..)`` wrapper stands and no replay mark
+      does; or the LAST mark's region is the transposed one: jax writes that
+      as a ``transpose(..)`` on the mark or below it
+      (``while/body/transpose(replay)/jvp(block0_0_0)``), or, where the
+      region was traced into the enclosing program under its whole stack, as
+      that stack wrapped once more
+      (``transpose(transpose(jvp(gpt0)))/body0/replay/jvp(block0_0_0)``);
+    * ``forward``: no transpose and no mark, under a differentiated region
+      (a ``jvp(..)`` wrapper) or any model scope;
+    * ``unmarked``: the rest — no path, the step's glue, and an ARGUMENT's
+      own name (``state.variables['gpt0/body0/..']``: XLA's copies of a
+      parameter carry it, and :func:`scope_key` reads a scope out of it).
+
+    Recomputation INSIDE a kernel (a flash backward forming ``p`` again) is
+    its instruction's pass, ``backward``."""
+    path = str(path)
+    scope = scope_key(path)
+    if scope == "optimizer":
+        return "optimizer"
+    comps = path.split("/")
+    if "[" in comps[0]:
+        return "unmarked"
+    # a transform decorates the scope it wraps: ``transpose(jvp(gpt0))``
+    transposes = [comp.count("transpose(") for comp in comps]
+    marks = [i for i, comp in enumerate(comps)
+             if _basename(_unwrap(comp)) in _REPLAY_MARKS]
+    if marks:
+        last = marks[-1]
+        transposed = any(transposes[last:]) \
+            or any(count > 1 for count in transposes[:last])
+        return "backward" if transposed else "replay"
+    if any(transposes):
+        return "backward"
+    if scope != "unscoped" or any("jvp(" in comp for comp in comps):
+        return "forward"
+    return "unmarked"
+
+
 def _model_scope_key(path: str) -> str:
     """The main model's fold of a name-stack path (see :func:`scope_key`).
 
@@ -192,9 +250,9 @@ def _model_scope_key(path: str) -> str:
     ``body/kda/in_proj|conv|decay|rule|gate_norm|out_proj``,
     ``output/unembed``,
     ``output``, ``loss``, ``unscoped``.  Transform decorations
-    (``jvp``/``transpose``/``jit`` wrappers) are unwrapped, so forward and
-    backward ops of one block fold into the same scope — per-block
-    attribution, not per-pass."""
+    (``jvp``/``transpose``/``jit`` wrappers) are unwrapped, so forward,
+    replay and backward ops of one block fold into the same scope — the
+    pass is :func:`pass_key`'s to tell."""
     phase = None
     layer = None
     router = sparse = denoise = False

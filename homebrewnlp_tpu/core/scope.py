@@ -35,6 +35,11 @@ from .tensor import NamedTensor, nt
 
 Params = typing.Dict[str, jax.Array]
 
+#: the ``jax.named_scope`` under which the program runs a forward AGAIN for
+#: a backward (:func:`replay_vjp`).  ``jax.checkpoint`` names its own replay
+#: ``rematted_computation``; analysis/cost_ledger.py ``pass_key`` reads both
+REPLAY = "replay"
+
 
 @dataclasses.dataclass
 class _Frame:
@@ -182,6 +187,18 @@ def name_scope(name: str, again: bool = False):
             yield
     finally:
         ctx.exit()
+
+
+def replay_vjp(fn: typing.Callable, *primals):
+    """``jax.vjp`` of a forward that already ran and that a backward runs
+    AGAIN for its residuals (the revnet / momentum strategies' blocks,
+    model/blocks.py; the 1F1B schedule's backward units,
+    parallel/pipeline_1f1b.py): the re-traced forward stands under scope
+    :data:`REPLAY`, which is how a device trace tells the replay from the
+    forward and the backward (analysis/cost_ledger.py ``pass_key``).
+    Metadata only: the compiled program is unchanged."""
+    with jax.named_scope(REPLAY):
+        return jax.vjp(fn, *primals)
 
 
 def scoped(name: str, fn: typing.Callable, *args, **kwargs):

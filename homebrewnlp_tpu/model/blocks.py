@@ -213,7 +213,7 @@ def _rev_bwd(fns, stash, res, cot):
         f, s = fns[i], subsets[i]
         b_prev = a
         chan = _provide_chan(stash, stashes[i])
-        fval, fvjp = jax.vjp(
+        fval, fvjp = scope.replay_vjp(
             lambda s_, x_: _call_block(f, s_, x_, chan=chan), s, b_prev)
         a_prev = b - fval
         ds, db_extra = fvjp(db)
@@ -257,7 +257,7 @@ def _mom_bwd(fns, alpha, stash, res, cot):
         f, s = fns[i], subsets[i]
         x_prev = x - v
         chan = _provide_chan(stash, stashes[i])
-        fval, fvjp = jax.vjp(
+        fval, fvjp = scope.replay_vjp(
             lambda s_, x_: _call_block(f, s_, x_, chan=chan), s, x_prev)
         v_prev = (v - fval * (1 - alpha)) / alpha
         g = dx + dv  # total cotangent on v' (it feeds both outputs)
@@ -330,7 +330,7 @@ def _rev_scan_bwd(fns, unroll, stash, res, cot):
             f, stk, shr = fns[c], sl_params[c], shared[c]
             b_prev = a
             chan = _provide_chan(stash, sl_stash[c])
-            fval, fvjp = jax.vjp(
+            fval, fvjp = scope.replay_vjp(
                 lambda stk_, shr_, x_: _call_block(f, {**stk_, **shr_}, x_,
                                                    it=it, chan=chan),
                 stk, shr, b_prev)
@@ -398,7 +398,7 @@ def _mom_scan_bwd(fns, alpha, unroll, stash, res, cot):
             f, stk, shr = fns[c], sl_params[c], shared[c]
             x_prev = x - v
             chan = _provide_chan(stash, sl_stash[c])
-            fval, fvjp = jax.vjp(
+            fval, fvjp = scope.replay_vjp(
                 lambda stk_, shr_, x_: _call_block(f, {**stk_, **shr_}, x_,
                                                    it=it, chan=chan),
                 stk, shr, x_prev)

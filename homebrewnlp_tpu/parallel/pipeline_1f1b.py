@@ -397,7 +397,10 @@ def pipeline_train_1f1b(params, mesh: Mesh, fns, subsets, plan,
                     tgt_local, jnp.minimum(m, n_micro - 1), 0, keepdims=False)
 
                 def last_loss(p_, x_, h_):
-                    y_ = stage_apply(p_, x_)
+                    # the stage's forward ran in its forward unit; the head
+                    # runs here for the first time
+                    with jax.named_scope(scope.REPLAY):
+                        y_ = stage_apply(p_, x_)
                     loss, aux = head_fn(h_, combine(y_), tgt)
                     return loss, aux
 
@@ -417,7 +420,8 @@ def pipeline_train_1f1b(params, mesh: Mesh, fns, subsets, plan,
                     cot = jax.lax.dynamic_index_in_dim(bstash, slot, 0,
                                                        keepdims=False)
                     _, vjp = with_rng(
-                        m, c, lambda: jax.vjp(stage_apply, params_c, xs))
+                        m, c, lambda: scope.replay_vjp(stage_apply, params_c,
+                                                       xs))
                     dparams, dx = vjp(cot)
                     return (dparams, jax.tree.map(jnp.zeros_like, hgrads),
                             dx, jnp.float32(0),
