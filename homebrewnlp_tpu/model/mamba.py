@@ -40,6 +40,12 @@ forward and hand-written backward (PR 48); elsewhere ``ssd_xla`` runs the
 four steps as XLA einsums and a ``lax.scan`` and autodiff gives the backward
 — the CPU's path and the kernels' oracle.
 
+Under the ``checkpoint`` strategy the layer offers the memory-for-recompute
+rule (model/remat.py, kind ``recurrent``) the in-projection's output ``u
+W_in`` under one name (``SAVED_NAMES``, ``_offer``): where the rule admits
+it, the block's replay runs no in-projection matmul and everything after it
+again from the saved value.
+
 Training and full-sequence forward on one device; a decode / prefill form
 (a state and a conv window per sequence) is ROADMAP R3's serving half.
 """
@@ -51,6 +57,7 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from ..config import BlockArgs, ModelParameter
 from ..core import scope
@@ -61,10 +68,24 @@ from ..parallel.ssd_scan import log_decay, ssd_kernel_applies, ssd_scan
 from .backend import ConstantInit, UniformInit, normal_var
 from .loss import _matmul
 from .normalization import _norm_core
-from .declare import Layer, Stat
+from .declare import Layer, Offer, Stat
 from .recurrent import (FACTS, Recurrent, _inverse_softplus_of_exp,
                         _small_var, causal_depthwise_conv, token_layout)
 from .utils import anonymize_dim
+
+
+#: the name layer ``mamba`` gives its in-projection's output ``proj [b, s,
+#: 2 d_inner + 2 g n + heads]`` (``checkpoint_name``; free where no policy
+#: names it), before the ``z`` / ``xBC`` / ``dt`` slices and before the conv
+#: kernel that reads its channels in place.  Under the ``checkpoint`` strategy
+#: the block's ``jax.checkpoint`` saves it where model/remat.py's
+#: ``recurrent`` kind rides (model/blocks.py ``_checkpoint_policy``): the
+#: block's replay then runs no in-projection matmul — the layer's largest, a
+#: quarter of its forward — and the conv, the scan, the gate norm and the
+#: out-projection replay from the saved ``proj``.  ``W_in``'s own backward
+#: wants the block's normed input, which the replay still makes, and
+#: ``d proj``.
+SAVED_NAMES = ("mamba_in_proj",)
 
 
 def ssd(x, dt, a, b_mat, c_mat, chunk: int):
@@ -175,8 +196,9 @@ def mamba(args: BlockArgs) -> NamedTensor:
     dtype = x.dtype
     u = transpose_to(x, token_dims + feats).data.reshape(bsz, s, f_sz)
     with jax.named_scope("in_proj"):
-        proj = _matmul("bsf,fo->bso", u, w_in.data.reshape(f_sz, -1)
-                       ).astype(dtype)
+        proj = checkpoint_name(
+            _matmul("bsf,fo->bso", u, w_in.data.reshape(f_sz, -1)
+                    ).astype(dtype), SAVED_NAMES[0])
         z = proj[..., :d_inner]
         xbc = proj[..., d_inner:d_inner + conv_dim]
         dt = proj[..., d_inner + conv_dim:]
@@ -240,9 +262,22 @@ def _scan(params: ModelParameter):
             params.mamba_groups)
 
 
+def _offer(params: ModelParameter, extras) -> Offer:
+    """The in-projection's output ``[batch, sequence, 2 d_inner + 2 groups x
+    state + heads]`` in the calculation dtype: ``SAVED_NAMES``.  No interior:
+    the scan's ``y`` and entering states buy a fifth of what these bytes do
+    (ROADMAP S9b(3))."""
+    channels, _, inner = _conv(params)
+    return Offer("recurrent", SAVED_NAMES,
+                 params.batch_dim.size * params.sequence_dim.size
+                 * (inner + channels + params.mamba_heads)
+                 * jnp.dtype(params.calculation_dtype).itemsize)
+
+
 mamba.declares = Layer(
     stats=(Stat("ssd_log_decay_min", "gauge", "hbnlp_ssd_log_decay_min",
                 "most negative within-chunk cumulative dt * A of the newest "
                 "finished step, all mamba layers: exp of it is the smallest "
                 "decay the chunked scan formed", "min"),),
-    facts=FACTS, recurrent=Recurrent(_state_bytes, _conv, scan=_scan))
+    offer=_offer, facts=FACTS,
+    recurrent=Recurrent(_state_bytes, _conv, scan=_scan))

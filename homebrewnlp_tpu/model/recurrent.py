@@ -48,11 +48,15 @@ class Recurrent(typing.NamedTuple):
     A layer that re-materialises its own interior in the backward also OFFERS
     ITS OUTPUT to the ``checkpoint`` strategy (its ``declares.offer``, kind
     ``recurrent``): where the block's ``jax.checkpoint`` saves it, the replay
-    runs no forward of the recurrence.  ``mamba`` offers nothing YET: since
-    PR 48 its scan's backward reads the call's inputs, its output ``y`` and
-    the entering chunk states the forward kernel writes, so a saved ``y``
-    (and states) would let the replay skip the forward kernel — the next
-    issue (ROADMAP S9b); today the replay runs it again."""
+    runs no forward of the recurrence.  ``mamba`` has no such interior — its
+    saved output would skip none of the replay — and offers its
+    IN-PROJECTION's output instead (model/mamba.py ``SAVED_NAMES``): the
+    replay of a region that rides runs no in-projection matmul, the layer's
+    largest, and the conv and the scan again from the saved value.  Its
+    scan's ``y`` and the entering chunk states the forward kernel writes
+    (what the scan's backward reads beside the call's inputs) would be the
+    offer's interior; they buy a fifth of what the in-projection's bytes do,
+    so the layer declares none (ROADMAP S9b(3))."""
     state_bytes: typing.Callable[[ModelParameter], int]
     conv: typing.Optional[
         typing.Callable[[ModelParameter], typing.Tuple[int, int, int]]]

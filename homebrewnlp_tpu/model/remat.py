@@ -35,7 +35,9 @@ the revnet / momentum residuals through the stash channel
 grouped-matmul outputs, routing triple and choice.  ``recurrent``: what a
 recurrent mixer offers of its rule — its output, and where Pallas pairs run
 the rule, as the offer's interior, what their forwards hand their backwards,
-so that the replay runs none of them.
+so that the replay runs none of them — or, of a mixer with no inner
+re-materialisation (layer ``mamba``), its in-projection's output: the replay
+runs no in-projection matmul.
 ``dense``: layer ``mlp``'s two matmul outputs, gate and up ``[batch,
 sequence, intermediate]`` — the replay runs the activation and the product
 alone, no dense matmul (2 of an MLP's 11-12 matmul units a step).

@@ -79,8 +79,11 @@ _CELLS = {
         _kinds(attention=(2, 68157440), experts=(2, 1075315200)),
         *_one_pass(_S4K, 2)),
     "train_granite_4_0_h_micro_long": (
-        # PR 52: six of the ten MLPs' gate and up [1, 8192, 8192] bfloat16
-        _kinds(attention=(1, 34603008), dense=(6, 1610612736)),
+        # PR 52: six of the ten MLPs' gate and up [1, 8192, 8192] bfloat16;
+        # PR 71: the nine mamba layers' in-projection outputs [1, 8192, 8512]
+        # bfloat16, decided before them, leave two
+        _kinds(attention=(1, 34603008), recurrent=(9, 1255145472),
+               dense=(2, 536870912)),
         "; ssd chunk states 67108864 bytes a device; conv kernel 9 layers; "
         "scan kernel 9 layers" + _one_pass(_S8K, 1)[0],          # PR 48
         {"hbnlp_ssd_state_bytes": 67108864,
@@ -132,9 +135,11 @@ _CELLS = {
     # the routing triple and the choice, 36,044,836 bytes a layer (the pairs'
     # rows, 131,072 x (2,688 + 1,024) a layer, would pass the budget); the
     # one attention layer's (out, lse) rides after it.  The ten lines above
-    # stand
+    # stand.  PR 71: the five mamba layers' in-projection outputs [1, 16384,
+    # 9280] bfloat16 ride in what those two kinds leave
     "train_nemotron_3_super_tp2_ep64_s16k": (
-        _kinds(attention=(1, 68157440), experts=(5, 180224180)),
+        _kinds(attention=(1, 68157440), experts=(5, 180224180),
+               recurrent=(5, 1520435200)),
         "; ssd chunk states 268435456 bytes a device; conv kernel 5 layers; "
         "scan kernel 5 layers; moe held rows bound 131072"
         + _one_pass(_S16K, 1)[0],
@@ -302,8 +307,14 @@ def _config_files():
 #: files read JoyAI 7, Kimi-Linear 1, ZAYA1 8, Ouro 12 (layers, not
 #: executions), SDAR 7, Laguna 5, OLMoE 2, granite, Olmo-Hybrid and Nemotron
 #: 1, the long-context file 0 (its eight on the split pair) —: before it
-#: afab9a58e0a13082ca6848573b5819db77f6b067)
-_FILE_DIGEST = "8af285da012d194c1f1f2761c2da8d6dc449c633"
+#: afab9a58e0a13082ca6848573b5819db77f6b067; PR 71: layer ``mamba`` offers its
+#: in-projection's output, so the two cells' files alone moved, on both sides
+#: — ``benchmark/configs/granite_4_0_h_micro.json`` reads ``recurrent 9
+#: layers, 1255145472`` and ``dense 2 layers, 536870912`` (6, 1610612736),
+#: ``benchmark/configs/nemotron_3_super_120b.json`` ``recurrent 5 layers,
+#: 1520435200`` —, the other 33 files as they were: before it
+#: 8af285da012d194c1f1f2761c2da8d6dc449c633)
+_FILE_DIGEST = "abf9c04a11d6e8f83fd126478a6dd66bb2935c3c"
 
 
 def every_configuration_file_starts_as_on_the_parent_test(monkeypatch):
@@ -506,11 +517,13 @@ def facts_are_declared_once_in_line_order_test():
      ("moe_order", "moe_inverse", "moe_sizes", "moe_experts",
       "moe_latent_sum")),
     ("gated_delta", "recurrent", ("gated_delta_out",)),
+    # PR 71: its in-projection's output (nothing until then)
+    ("mamba", "recurrent", ("mamba_in_proj",)),
     ("attention-nope", "attention", ("flash_out", "flash_lse")),
     ("cca-q_heads8-kv_heads2", "attention", ("flash_out", "flash_lse")),
     ("bottleneck_group_linear-in:relu", "bottleneck", ()),
     ("mlp-silu", "dense", ("mlp_gate", "mlp_up")),
-    ("mamba", None, None), ("norm-shift-scale", None, None),
+    ("lightning", None, None), ("norm-shift-scale", None, None),
     ("attention-biased_attention_map-absolute-input_as_value", None, None),
     ("bottleneck_group_linear-in:mixture_of_experts", None, None)])
 def layer_offers_its_kind_test(layer, kind, names):

@@ -410,9 +410,10 @@ def saved_flash_outputs_keep_their_scope_test(v5e, monkeypatch):
 
 
 def experts_rule_declines_without_a_moe_layer_test():
-    """``checkpoint`` with no ``moe`` layer: neither the experts nor the
-    recurrent kind rides, the policy of a region without an ``mlp`` stays the
-    named one, and the chunk states' gauge counts one layer's."""
+    """``checkpoint`` with no ``moe`` layer: the experts kind does not ride;
+    the recurrent kind does (PR 71: layer ``mamba``'s in-projection outputs),
+    so every region's policy saves that name, and the chunk states' gauge
+    counts one layer's."""
     from homebrewnlp_tpu import telemetry
     from homebrewnlp_tpu.model import recurrent, remat
     from homebrewnlp_tpu.model.blocks import _checkpoint_policy
@@ -421,19 +422,21 @@ def experts_rule_declines_without_a_moe_layer_test():
     assert params.memory_reduction_strategy == "checkpoint"
     assert remat.stash_plan(params)["experts"] == (0, 0)
     assert "experts" not in remat.stash_kinds(params)
-    # layer mamba offers no output to save (PR 33): the recurrent kind too
-    assert remat.stash_plan(params)["recurrent"] == (0, 0)
-    assert "recurrent" not in remat.stash_kinds(params)
+    # layer mamba offers its in-projection's output (PR 71; nothing until
+    # then): the nine toy ones [2, 64, 2 x 32 + 2 x 16 + 4] float32 fit
+    assert remat.stash_plan(params)["recurrent"] == (9, 9 * 2 * 64 * 100 * 4)
+    assert "recurrent" in remat.stash_kinds(params)
+    assert remat.stash_names(params)[0] == "mamba_in_proj"
     assert _checkpoint_policy(params) \
-        is jax.checkpoint_policies.nothing_saveable
+        is not jax.checkpoint_policies.nothing_saveable
     # [2, 64 / 16, 4, 8, 16] float32
     assert recurrent.ssd_state_bytes(params) == 2 * 4 * 4 * 8 * 16 * 4
     line = Trainer(params, model).publish_stash_plan()
     # (PR 52: the ten toy MLPs' gate and up [2, 64, 256] float32 fit, and
     # ride the regions' own policies: tests/remat_policy_test.py)
-    assert line.endswith("experts 0 layers, 0 bytes a device; recurrent 0 "
-                         "layers, 0 bytes a device; dense 10 layers, 2621440 "
-                         "bytes a device; ssd chunk states 16384 "
+    assert line.endswith("experts 0 layers, 0 bytes a device; recurrent 9 "
+                         "layers, 460800 bytes a device; dense 10 layers, "
+                         "2621440 bytes a device; ssd chunk states 16384 "
                          "bytes a device; conv kernel 0 layers; scan kernel "
                          "0 layers")
     snap = telemetry.registry().snapshot()
@@ -442,6 +445,57 @@ def experts_rule_declines_without_a_moe_layer_test():
     assert snap["hbnlp_ssd_scan_kernel_layers"]["series"][()] == 0
     _, none, _, _, _ = _build("float32", memory_reduction_strategy="none")
     assert recurrent.ssd_state_bytes(none) == 9 * 16384
+
+
+def _replayed_in_projections(jaxpr) -> list:
+    """The matmuls of the in-projection's shape — ``[2, 64, 64] x [64, 100]
+    -> [2, 64, 100]`` — in the BACKWARD of every ``jax.checkpoint`` region of
+    a gradient's jaxpr (where the region's replay is), in execution order of
+    the regions: the backward holds them last region first."""
+    counts = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name != "remat2":
+            continue
+        counts.append(sum(
+            e.primitive.name == "dot_general"
+            and tuple(e.outvars[0].aval.shape) == (2, 64, 100)
+            for e in eqn.params["jaxpr"].eqns))
+    return counts[::-1]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def saved_in_projection_changes_no_value_test(dtype):
+    """PR 71: a ``checkpoint`` step of mamba blocks with the in-projection's
+    output saved (the rule admits it: the toy's bytes fit) against
+    ``"recompute"``: the loss bit for bit — the saved value is the array the
+    forward made, in the calculation dtype —, the gradients to reconstruction
+    ulps, and the backward of a region that holds a ``mamba`` layer runs ONE
+    matmul of the in-projection's shape fewer: the replay's."""
+    from homebrewnlp_tpu.model import remat
+    got = {}
+    for policy in ("recompute", "auto"):
+        _, params, model, batch, variables = _build(
+            dtype, block_config=SHORT, remat_policy=policy)
+        assert remat.stash_plan(params)["recurrent"] == (
+            (2, 2 * 2 * 64 * 100 * jnp.dtype(dtype).itemsize)
+            if policy == "auto" else (0, 0))
+        variables = {k: jnp.asarray(v) for k, v in variables.items()}
+        fn = jax.value_and_grad(harness.loss_of(model))
+        got[policy] = (_replayed_in_projections(
+            jax.make_jaxpr(fn)(variables, batch).jaxpr),
+            jax.jit(fn)(variables, batch))
+    (replayed, (want_loss, want)), (saved, (loss, grads)) = \
+        got["recompute"], got["auto"]
+    # SHORT: mamba, mlp, attention, mlp, mamba
+    assert replayed == [1, 0, 0, 0, 1] and saved == [0] * 5
+    assert float(loss) == float(want_loss) and np.isfinite(float(loss))
+    assert set(grads) == set(want)
+    tight = dtype == "float32"
+    for name in want:
+        np.testing.assert_allclose(
+            np.asarray(grads[name], np.float32),
+            np.asarray(want[name], np.float32), err_msg=name,
+            rtol=2e-4 if tight else 2e-2, atol=1e-6 if tight else 1e-4)
 
 
 @pytest.mark.parametrize("kernel", [False, True], ids=["xla", "kernel"])

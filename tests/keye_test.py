@@ -442,7 +442,10 @@ def mrope_on_equal_streams_is_rope_test():
 #: PR 63's own: it moved from the parent's 77b432de... by the forward
 #: kernel's body (lane-replicated statistics, one select a pair) and by the
 #: tables' rectangular diagonal, and by nothing else — ``select_dq`` and ``select_dkv`` are the
-#: parent's, letter for letter
+#: parent's, letter for letter.  PR 71 moved the two cells with a ``mamba``
+#: layer and no other: their steps trace the ``name`` layer ``mamba`` gives
+#: its in-projection's output, and their regions' policies save it (granite
+#: f9c4190e..., Nemotron f4323140... until then)
 _PARENT = {
     "select": "ab4ea813094509e3835c587a40d64787f5d91bbe",
     "select_dq": "821f74ffb0e6b4069ff0f4fbd51fdb687cf05cdf",
@@ -451,14 +454,14 @@ _PARENT = {
     "train_1b_long_context_s16k": "93c267300f6762eac60b1ad8c26b779106cdd89f",
     "train_olmoe_1b_7b_s4k": "c762ce67313660f1d9c7a0699dfc40731993a9a1",
     "train_granite_4_0_h_micro_long":
-        "f9c4190e5e13fb4940ae1019ebef930f1eece46e",
+        "b01ba6819dc86178a41f2c0753bde7057dff17d9",
     "train_olmo_hybrid_7b_long": "1fa4aaca6ce9e530c6ffbc8424080f0ada4d7231",
     "train_laguna_s_2_1_ep32_s8k": "c7c8961295c85dbdf5e393e76e908935320b914e",
     "train_zaya1_8b_ep2_s16k": "c9d96234ed75811d1d0c675347a5027042175d00",
     "train_minicpm_sala_tp2_long": "23a2df09e10e61d58895cf748d90e7b175b1f444",
     "train_ouro_2_6b_loop4_s4k": "40862f4338cc8c697d627e077b74820df0f5ad9b",
     "train_nemotron_3_super_tp2_ep64_s16k":
-        "f4323140a48161e1787ff0735c309f29125e2a74",
+        "d9bafa202444c6a2652e323cbb0d38ef98505702",
     "train_kimi_linear_ep32_s16k": "816fab234da3b7a594e5e971555744ab986c38dc",
     "train_keye_vl_2_0_ep8_s16k": "a3379a7a8f6c66f8bc446b44fa9ac9afe2223779",
 }

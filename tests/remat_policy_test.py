@@ -420,10 +420,12 @@ def chip_limit(request, monkeypatch):
      _MOE_NAMES + _FLASH_NAMES, (0, 0, ())),
     # out [1, 8192, 32, 64] + lse [32, 8192]; PR 52: 2 x [1, 8192, 8192]
     # bfloat16 an execution, six of ten inside 2.537 GB - 34.6 MB - 20 block
-    # inputs of [1, 8192, 2048] = 1.831 GB, from the last block backwards
-    ("train_granite_4_0_h_micro_long", {"attention", "dense"}, "stash",
-     {"attention": (1, 34603008)}, _FLASH_NAMES,
-     (6, 1610612736, (9, 11, 13, 15, 17, 19))),
+    # inputs of [1, 8192, 2048] = 1.831 GB, from the last block backwards;
+    # PR 71, the one row that moved: nine in-projection outputs [1, 8192,
+    # 8512] are decided before them and leave 0.576 GB, two of ten
+    ("train_granite_4_0_h_micro_long", {"attention", "recurrent", "dense"},
+     "stash", {"attention": (1, 34603008), "recurrent": (9, 1255145472)},
+     ("mamba_in_proj",) + _FLASH_NAMES, (2, 536870912, (17, 19))),
     # out [1, 16384, 30, 128] + lse [30, 16384]; PR 52: 2 x [1, 16384, 11008]
     # an execution, one of four inside 2.537 GB - 127.8 MB - 566.2 MB - 8
     # block inputs of [1, 16384, 3840] = 0.836 GB: the step's last block
@@ -468,7 +470,8 @@ def experts_kind_moves_no_other_cell_test(cell, kinds, policy, plan, names,
     chip's own limit: the ``dense`` kind, decided last, admits ``dense`` =
     (executions, bytes, the regions that save layer ``mlp``'s two names) —
     ISSUE 52's table to the byte — and moves no other kind's numbers; every
-    other region's policy names what the parent's named."""
+    other region's policy names what the parent's named.  Since PR 71
+    granite's row holds layer ``mamba``'s in-projection outputs."""
     from benchmark.lib.cell import load_cell
     from homebrewnlp_tpu.core import sharding as shardlib
     from homebrewnlp_tpu.model.blocks import (_checkpoint_policy, _name_chan,
@@ -585,6 +588,17 @@ _OLMO_FLASH = 16384 * 30 * (128 * 2 + 4)
 #: [1, 8192, 8192]
 _OLMO_MLP = 2 * 16384 * 11008 * 2
 _GRANITE_MLP = 2 * 8192 * 8192 * 2
+#: layer mamba's in-projection output (PR 71), z | xBC | dt = 2 d_inner + 2
+#: groups x state + heads columns in bfloat16: granite's [1, 8192, 8512], the
+#: Nemotron cell's [1, 16384, 9280] (one tensor-parallel rank's 64 heads and
+#: 4 groups)
+_GRANITE_PROJ = 8192 * (2 * 4096 + 2 * 128 + 64) * 2
+_NEMOTRON_PROJ = 16384 * (2 * 4096 + 2 * 4 * 128 + 64) * 2
+_NEMOTRON = "train_nemotron_3_super_tp2_ep64_s16k"
+_MAMBA_NAMES = ("mamba_in_proj",)
+
+
+_GRANITE = "train_granite_4_0_h_micro_long"
 
 
 def _with_moe(top_k: int):
@@ -603,7 +617,8 @@ def _with_moe(top_k: int):
 @pytest.mark.parametrize("case", [
     "engaged", "over_budget", "recompute", "stash", "legacy_false", "revnet",
     "none", "pipe_mesh", "macro_batching", "experts_leave_room",
-    "experts_leave_none", "experts_decline", "mamba_declares_nothing"])
+    "experts_leave_none", "experts_decline",
+    "mamba_offers_its_in_projection"])
 def recurrent_stash_resolver_test(case):
     from homebrewnlp_tpu.model.blocks import _checkpoint_policy
     from homebrewnlp_tpu.model.declare import offers
@@ -719,21 +734,49 @@ def recurrent_stash_resolver_test(case):
                                  "dense": (1, _OLMO_MLP)}
         assert stash_names(p) == ("gated_delta_out",) + _FLASH_NAMES
     else:
-        # nine mamba layers under "checkpoint": recurrent mixers that offer
-        # nothing (no inner jax.checkpoint: a saved output would skip none
-        # of the replay), whatever the policy says
+        # PR 71: layer mamba has no inner jax.checkpoint, so its OUTPUT saved
+        # would skip none of the replay; what it offers is its in-projection's
+        # output (the layer's largest matmul, which the replay then does not
+        # run), one name and no interior.  Granite's nine layers ride whole
+        # and, decided before the dense kind, leave it two of the ten MLPs
+        # where it had six (all ten where forced)
         for kw in ({}, {"remat_policy": "stash"}):
-            p = _cell_params("train_granite_4_0_h_micro_long", **kw)
-            specs = _recurrent_layers(p)
-            assert len(specs) == 9
-            assert offers(p, "recurrent") == []
-            assert remat_report(p)["recurrent_stash_layers"] == 0
-            # what rides there is the attention kind (PR 40) and six of
-            # the ten MLPs' gate and up (PR 52; all ten where forced)
+            p = _cell_params(_GRANITE, **kw)
+            assert len(_recurrent_layers(p)) == 9
+            assert [(o.names, o.nbytes, o.interior_names)
+                    for o in offers(p, "recurrent")] \
+                == [(_MAMBA_NAMES, _GRANITE_PROJ, ())] * 9
+            rep = remat_report(p)
+            assert (rep["recurrent_stash_layers"],
+                    rep["recurrent_stash_bytes_per_device"]) \
+                == (9, 9 * _GRANITE_PROJ) == (9, 1255145472)
             assert stash_plan(p) == {
                 **idle, "attention": (1, 34603008),
-                "dense": (10 if kw else 6, (10 if kw else 6) * _GRANITE_MLP)}
-            assert stash_names(p) == _FLASH_NAMES
+                "recurrent": (9, 1255145472),
+                "dense": (10 if kw else 2, (10 if kw else 2) * _GRANITE_MLP)}
+            assert stash_names(p) == _MAMBA_NAMES + _FLASH_NAMES
+            # the first region holds an admitted layer: the name from there on
+            assert all("mamba_in_proj" in names for names in region_names(p))
+            assert _checkpoint_policy(p) is not nothing
+        # the Nemotron cell: its five layers beside the attention and the
+        # experts kind as they were, in the 2.33 GB those leave; the name
+        # from the first mamba region (2 of 0-10) on
+        p = _cell_params(_NEMOTRON)
+        assert [o.nbytes for o in offers(p, "recurrent")] \
+            == [_NEMOTRON_PROJ] * 5 == [304087040] * 5
+        assert stash_plan(p) == {**idle, "attention": (1, 68157440),
+                                 "experts": (5, 180224180),
+                                 "recurrent": (5, 1520435200)}
+        assert stash_names(p)[-3:] == _MAMBA_NAMES + _FLASH_NAMES
+        assert _holding(p, "mamba_in_proj") == list(range(2, 11))
+        # three micro-batches hold three sets: from the step's end, the last
+        # two layers' (regions 8 and 10)
+        p = _cell_params(_NEMOTRON, macro_batching=3)
+        assert stash_plan(p)["recurrent"] == (2, 6 * _NEMOTRON_PROJ)
+        assert _holding(p, "mamba_in_proj") == [8, 9, 10]
+        for kw in ({"remat_policy": "recompute"},
+                   {"memory_reduction_strategy": "none"}):
+            assert stash_plan(_cell_params(_NEMOTRON, **kw)) == idle
 
 
 def recurrent_stash_line_test():
@@ -751,7 +794,6 @@ def recurrent_stash_line_test():
 # least 2,048 keys; since PR 61 decided FIRST and on its own bytes (PR 40: last,
 # and not at all where an earlier kind declined for size) -----------------------
 
-_GRANITE = "train_granite_4_0_h_micro_long"
 _LAGUNA = "train_laguna_s_2_1_ep32_s8k"
 _ZAYA = "train_zaya1_8b_ep2_s16k"
 
@@ -806,15 +848,17 @@ def attention_saved_resolver_test(case):
                        if l.startswith("attention") else l)
         assert remat_report(p)["saved_attention_layers"] == 0
         declines(p)
-        # (PR 52: the 34.6 MB the layer does not hold are room for a seventh
-        # MLP's gate and up, at the CPU's 16 GiB)
-        assert stash_plan(p) == {**idle, "dense": (7, 7 * _GRANITE_MLP)}
-        assert _checkpoint_policy(p) is nothing
+        # (PR 71: the nine in-projection outputs ride; the 34.6 MB the layer
+        # does not hold are no room for a third MLP's gate and up)
+        assert stash_plan(p) == {**idle, "recurrent": (9, 9 * _GRANITE_PROJ),
+                                 "dense": (2, 2 * _GRANITE_MLP)}
+        assert stash_names(p) == _MAMBA_NAMES
     elif case == "window_of_2048_keys":
         p = _relayered(_GRANITE, lambda l: l + "-window2048"
                        if l.startswith("attention") else l)
         assert stash_plan(p) == {**idle, "attention": (1, 34603008),
-                                 "dense": (6, 6 * _GRANITE_MLP)}
+                                 "recurrent": (9, 9 * _GRANITE_PROJ),
+                                 "dense": (2, 2 * _GRANITE_MLP)}
         assert saved_attention_keys(p) == 2048
     elif case == "sequence_under_2048":
         declines(_cell_params(_GRANITE, sequence_length=1024))
@@ -835,9 +879,11 @@ def attention_saved_resolver_test(case):
         p = _cell_params("train_olmoe_1b_7b_s4k", macro_batching=3)
         assert stash_plan(p) == {**idle, "attention": (2, 3 * 68157440)}
         assert saved_attention_keys(p) == 2048
-        # three times the bytes
+        # three times the bytes (PR 71: of the in-projection outputs too —
+        # the last five layers' fit what the pair leaves)
         p = _cell_params(_GRANITE, macro_batching=3)
-        assert stash_plan(p) == {**idle, "attention": (1, 3 * 34603008)}
+        assert stash_plan(p) == {**idle, "attention": (1, 3 * 34603008),
+                                 "recurrent": (5, 3 * 5 * _GRANITE_PROJ)}
     elif case == "earlier_kinds_exhaust_the_budget":
         # experts at top-3 alone would leave 34.6 MB, less than the layer's
         # 127.8 MB: taken first, the one kind whose forward grows with the
@@ -886,6 +932,7 @@ def attention_saved_resolver_test(case):
         p = _cell_params(_GRANITE, stash_attention_outputs=True,
                          sequence_length=1024)
         assert stash_plan(p) == {**idle, "attention": (1, 34603008 // 8),
+                                 "recurrent": (9, 9 * _GRANITE_PROJ // 8),
                                  "dense": (10, 10 * _GRANITE_MLP // 8)}
         assert saved_attention_keys(p) == 0
     elif case == "legacy_false":
@@ -1090,13 +1137,15 @@ def dense_stash_resolver_test(case, chip_limit):
         assert (budget - 1635778560) // one == 4
         assert 96 * 2 * 4096 * 2048 * 2 == 3221225472 > budget
         declines(p)
-        # granite: 20 inputs of [1, 8192, 2048] take 0.671 GB of the 2.502 GB
-        # the attention kind leaves: six executions where nine would fit
+        # granite: 20 inputs of [1, 8192, 2048] take 0.671 GB of the 1.247 GB
+        # the attention kind and (PR 71) the nine in-projection outputs
+        # leave: two executions where four would fit
         p = _cell_params(_GRANITE)
-        assert (budget - 34603008) // _GRANITE_MLP == 9
-        assert (budget - 34603008 - 20 * 8192 * 2048 * 2) // _GRANITE_MLP == 6
-        assert stash_plan(p)["dense"] == (6, 6 * _GRANITE_MLP)
-        assert saving(p) == [9, 11, 13, 15, 17, 19]
+        left = budget - 34603008 - 9 * _GRANITE_PROJ
+        assert left // _GRANITE_MLP == 4
+        assert (left - 20 * 8192 * 2048 * 2) // _GRANITE_MLP == 2
+        assert stash_plan(p)["dense"] == (2, 2 * _GRANITE_MLP)
+        assert saving(p) == [17, 19]
     elif case == "earlier_kind_declined_for_size":
         # the experts kind over the budget takes none of it and (PR 61)
         # moves no other kind's decision: the cell's last MLP, as in the cell
@@ -1147,39 +1196,66 @@ def dense_stash_resolver_test(case, chip_limit):
             declines(_cell_params(_GRANITE, **kw), Piped(), kinds=False)
     elif case == "scan_layers":
         # a scanned body traces ONE block for all its iterations: all the
-        # step's executions or none.  Two periods of granite are 20 MLPs
-        p = _cell_params(_GRANITE, depth=2)
-        assert stash_plan(p)["dense"][0] == 4       # 40 block inputs now
-        declines(_cell_params(_GRANITE, depth=2, scan_layers=True))
+        # step's executions or none.  Two periods of granite at 4,096
+        # positions are 20 MLPs, 40 block inputs and (PR 71) 18 in-projection
+        # outputs of half the cell's bytes each
+        half = {"depth": 2, "sequence_length": 4096}
+        p = _cell_params(_GRANITE, **half)
+        assert stash_plan(p)["recurrent"] == (18, 9 * _GRANITE_PROJ)
+        assert stash_plan(p)["dense"] == (4, 2 * _GRANITE_MLP)
+        p = _cell_params(_GRANITE, scan_layers=True, **half)
+        assert stash_plan(p)["recurrent"] == (18, 9 * _GRANITE_PROJ)
+        declines(p)
+        # (the recurrent kind likewise: 17 of the 18 at the cell's sequence,
+        # none of them scanned)
+        assert stash_plan(_cell_params(_GRANITE, depth=2))["recurrent"] \
+            == (17, 17 * _GRANITE_PROJ)
+        assert stash_plan(_cell_params(_GRANITE, depth=2, scan_layers=True)
+                          )["recurrent"] == (0, 0)
         p = _cell_params(_GRANITE, depth=2, scan_layers=True,
                          sequence_length=1024)
         assert stash_plan(p)["dense"] == (20, 20 * _GRANITE_MLP // 8)
         assert saving(p) == list(range(1, 40, 2))
     elif case == "macro_batching":
-        # two micro-batches hold two sets of everything
-        p = _cell_params(_GRANITE, macro_batching=2)
-        assert (budget - 2 * 34603008 - 2 * 20 * 8192 * 2048 * 2) \
-            // (2 * _GRANITE_MLP) == 2
-        assert stash_plan(p)["dense"] == (2, 2 * 2 * _GRANITE_MLP)
+        # two micro-batches hold two sets of everything: at half the cell's
+        # sequence, what the cell holds
+        p = _cell_params(_GRANITE, macro_batching=2, sequence_length=4096)
+        assert (budget - 34603008 - 9 * _GRANITE_PROJ
+                - 20 * 8192 * 2048 * 2) // _GRANITE_MLP == 2
+        assert stash_plan(p)["dense"] == (2, 2 * _GRANITE_MLP)
         assert saving(p) == [17, 19]
+        # at the cell's own (PR 71) eight of the nine in-projection outputs
+        # fit, and leave the dense kind less than the block inputs
+        p = _cell_params(_GRANITE, macro_batching=2)
+        assert stash_plan(p)["recurrent"] == (8, 2 * 8 * _GRANITE_PROJ)
+        assert stash_plan(p)["dense"] == (0, 0)
     elif case == "input_block_offers_nothing":
         # the input and output blocks run outside any region: an ``mlp``
         # there is neither offered nor counted
         blocks = [{"layer": list(b.layer), "skip": b.skip}
                   for b in _cell_params(_GRANITE).block_config]
         p = _cell_params(_GRANITE, input_block_config=blocks[1:2])
-        assert stash_plan(p)["dense"] == (6, 6 * _GRANITE_MLP)
+        assert stash_plan(p)["dense"] == (2, 2 * _GRANITE_MLP)
     else:
         # a block's layers share their names: its executions go together.
-        # Granite's last two MLPs in one block: 19 regions, and the budget
-        # 1.848 GB holds the pair (0.537 GB) three times ... but the blocks
-        # before the pair hold one each: 2 + 4 x 1
+        # Granite's last two MLPs in one block: 19 regions, and what the
+        # nine in-projection outputs leave (PR 71), 0.609 GB, holds the pair
+        # (0.537 GB) ...
         blocks = [{"layer": list(b.layer), "skip": b.skip}
                   for b in _cell_params(_GRANITE).block_config]
         blocks[17]["layer"] += blocks[19]["layer"]
         p = _cell_params(_GRANITE, block_config=blocks[:19])
-        assert stash_plan(p)["dense"] == (6, 6 * _GRANITE_MLP)
-        assert saving(p) == [9, 11, 13, 15, 17]
+        assert stash_plan(p)["dense"] == (2, 2 * _GRANITE_MLP)
+        assert saving(p) == [17]
+        # ... and not the last four in one block of 15 (1.074 GB for the
+        # 1.023 GB seven in-projection outputs leave), of which three would
+        # fit one at a time
+        blocks[13]["layer"] += blocks[15]["layer"] + blocks[17]["layer"]
+        p = _cell_params(_GRANITE, block_config=blocks[:15])
+        assert stash_plan(p)["recurrent"] == (7, 7 * _GRANITE_PROJ)
+        left = budget - 34603008 - 7 * _GRANITE_PROJ - 15 * 8192 * 2048 * 2
+        assert 3 * _GRANITE_MLP <= left < 4 * _GRANITE_MLP
+        declines(p)
 
 
 _SALA_MLP = 2 * 16384 * 16384 * 2
@@ -1229,8 +1305,10 @@ def _at_limit(monkeypatch, limit: int):
     ("train_1b_long_context_s16k", {"attention": (8, 2155872256)}),
     ("train_olmoe_1b_7b_s4k", {"attention": (2, 68157440),
                                "experts": (2, 1075315200)}),
+    # PR 71: the nine in-projection outputs, and two MLPs for six
     ("train_granite_4_0_h_micro_long", {"attention": (1, 34603008),
-                                        "dense": (6, 1610612736)}),
+                                        "recurrent": (9, 1255145472),
+                                        "dense": (2, 536870912)}),
     ("train_olmo_hybrid_7b_long", {"attention": (1, 127795200),
                                    "recurrent": (3, 566231040),
                                    "dense": (1, 721420288)}),
@@ -1241,8 +1319,10 @@ def _at_limit(monkeypatch, limit: int):
     ("train_minicpm_sala_tp2_long", {"attention": (1, 72351744),
                                      "dense": (1, 1073741824)}),
     ("train_ouro_2_6b_loop4_s4k", {"attention": (48, 1635778560)}),
+    # PR 71: the five in-projection outputs [1, 16384, 9280] bfloat16
     ("train_nemotron_3_super_tp2_ep64_s16k", {"attention": (1, 68157440),
-                                              "experts": (5, 180224180)}),
+                                              "experts": (5, 180224180),
+                                              "recurrent": (5, 1520435200)}),
     # the parent: recurrent (4, 536870912), the four outputs, and nothing
     # else (the experts' 4.57 GB declined); now the flash pair and, beside
     # the four outputs, the LAST layer's interior of 1,140,850,688 bytes
@@ -1251,7 +1331,9 @@ def every_cells_plan_test(cell, plan, monkeypatch, kda_kernels):
     """``stash_plan`` of all twelve cells' configurations at the table's
     16,911,433,728 bytes a chip: ten as the parent of PR 61 read them (ISSUE
     61's table), Laguna's global flash pair and the Kimi-Linear cell's pair
-    and last ``kda`` interior as the one rule admits them."""
+    and last ``kda`` interior as the one rule admits them; since PR 71 the
+    two cells with a ``mamba`` layer with its in-projection outputs (ISSUE
+    71's table)."""
     from benchmark.lib.cell import load_cell
     from homebrewnlp_tpu.core import sharding as shardlib
     from homebrewnlp_tpu.model.remat import stash_plan
