@@ -71,7 +71,7 @@ _LAYER_NAMES = frozenset((
     "feed_forward_product_key_memory", "product_key_memory",
     "reduced_half_linear", "transpose_sequence_features",
     "bottleneck_group_linear", "sum_heads", "moe", "mamba", "gated_delta",
-    "kda", "mlp", "cca", "lightning",
+    "kda", "mlp", "cca", "lightning", "route_early",
     # no layer function: a block part's scaled residual merge
     # (model/frontend.py scaled_merge) opens a scope of its own beside them
     "merge"))
@@ -80,8 +80,10 @@ _LAYER_NAMES = frozenset((
 _MOE_PARTS = frozenset(("router", "dispatch", "experts", "combine",
                         "shared", "latent_down", "latent_up"))
 #: the parts of ZAYA1's router (flag ``router_mlp``) below
-#: ``body/moe/router``; the one-matrix router has none
-_ROUTER_PARTS = frozenset(("down", "carry", "mlp"))
+#: ``body/moe/router``, and where flag ``routed_early`` takes the logits an
+#: earlier block's ``route_early`` layer left (``carried``; that layer's own
+#: matmul is ``body/route_early``); the one-matrix router has none
+_ROUTER_PARTS = frozenset(("down", "carry", "mlp", "carried"))
 #: the parts of layer ``cca`` (model/cca.py) below ``body/cca``; the flash
 #: kernels stay in ``body/cca`` itself
 _CCA_PARTS = frozenset(("in_proj", "qk_mean", "conv", "qk_norm", "rope",
@@ -237,7 +239,8 @@ def _model_scope_key(path: str) -> str:
     ``head_loss``, ``input/embed``, ``input``, ``body/<layer>``,
     ``body/moe/router|dispatch|experts|combine|shared|latent_down|
     latent_up``,
-    ``body/moe/router/down|carry|mlp``, ``body/attention/gate|q_down|q_norm|
+    ``body/moe/router/down|carry|mlp|carried``, ``body/route_early``,
+    ``body/attention/gate|q_down|q_norm|
     q_proj|kv_down|kv_norm|kv_up|latent_rope|out_proj|halves|own_block|
     lse_merge``, ``denoise/noise|join|split``,
     ``body/attention/sparse_attention/compress|index|select|attend|

@@ -103,7 +103,8 @@ class Context:
         self.layer_stats: typing.Optional[list] = None
         # when not None, the CARRIED SIDE VALUES: ``{name: array}`` that one
         # layer leaves for a later one beside the stream (layer moe's
-        # router state under ``router_mlp``).  The block machinery hands the
+        # router state under ``router_mlp``; layer ``route_early``'s logits
+        # for a ``routed_early`` sparse layer).  The block machinery hands the
         # dict in and out of every block's region as an explicit input and
         # output (model/blocks.py); None under the modes that carry none,
         # where a layer that needs one refuses by name

@@ -1089,10 +1089,11 @@ def run_body_blocks(params: ModelParameter, src: NamedTensor,
         return x + v, plan
     # checkpoint / none: the plain stream, each block's layer statistics
     # an explicit output of its region, and beside the stream the CARRIED
-    # SIDE VALUES (Context.side: what one layer leaves for a later one, layer
-    # moe's router state under router_mlp): a dict that enters and leaves
-    # every block's region as an operand, empty — no operand at all — in a
-    # model whose layers carry nothing
+    # SIDE VALUES (Context.side: what one layer leaves for a later one — layer
+    # moe's router state under router_mlp, layer route_early's logits for the
+    # routed_early sparse layer of the next block): a dict that enters and
+    # leaves every block's region as an operand, empty — no operand at all —
+    # in a model whose layers carry nothing
     out, parts, side = src, [], {}
     chan = _name_chan(params, ctx.mesh)
     policies = _region_policies(params, ctx.mesh)

@@ -86,12 +86,15 @@ class Fact(typing.NamedTuple):
 class Layer(typing.NamedTuple):
     """``offer(params, extras)`` returns the layer's :class:`Offer` under
     these flags, or None; ``recurrent`` is a recurrent mixer's
-    ``model/recurrent.py Recurrent``."""
+    ``model/recurrent.py Recurrent``; ``carried(params)`` the bytes of the
+    carried side values (``Context.side``, model/blocks.py) that the step's
+    layers of this kind keep alive between blocks for the backward."""
     stats: typing.Tuple[Stat, ...] = ()
     offer: typing.Optional[typing.Callable[
         [ModelParameter, typing.Set[str]], typing.Optional[Offer]]] = None
     facts: typing.Tuple[Fact, ...] = ()
     recurrent: typing.Any = None
+    carried: typing.Optional[typing.Callable[[ModelParameter], int]] = None
 
 
 _NOTHING = Layer()
@@ -177,6 +180,13 @@ def facts() -> typing.List[Fact]:
     found = {fact.metric: fact for spec in _registered()
              for fact in spec.facts}
     return sorted(found.values(), key=lambda fact: fact.place)
+
+
+def carried_bytes(params: ModelParameter) -> int:
+    """Bytes of every carried side value of the step, all declaring layers:
+    held across the backward beside the block inputs (model/remat.py)."""
+    return sum(spec.carried(params) for spec in _registered()
+               if spec.carried is not None)
 
 
 def fold_stats(layer_stats: typing.Optional[dict]) -> typing.Dict[str, typing.Any]:

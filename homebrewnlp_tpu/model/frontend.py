@@ -26,6 +26,7 @@ from .mtp import module_loss
 from .mamba import mamba
 from .moe import moe
 from .normalization import norm
+from .route import route_early
 from .spatial import attention, cummean, cumsum
 
 
@@ -184,6 +185,7 @@ LAYER_FUNCTIONS = {'feed_forward': feed_forward,
                    'mlp': mlp,
                    'cca': cca,
                    'lightning': lightning,
+                   'route_early': route_early,
                    }
 
 #: what declares itself (model/declare.py) beside the layers of the DSL: a

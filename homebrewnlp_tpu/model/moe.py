@@ -2,7 +2,14 @@
 
 ``out = sum_{e in top-k} p_e * down_e(act(gate_e x) * up_e x)`` with ``p`` the
 float32 softmax of the router's logits over ALL experts, the ``k`` largest
-taken as they are (not renormalised; OLMoE's ``norm_topk_prob`` false).  No
+taken as they are (not renormalised; OLMoE's ``norm_topk_prob`` false).  The
+logits are one matrix times the layer's OWN input ``x`` (OLMoE's router) —
+unless a flag says otherwise: ``router_mlp`` (an MLP fed by the previous
+layer's router state), ``sigmoid_bias`` (sigmoid scores and a selection bias)
+and ``routed_early``, which moves the router OUT of the block: the logits were
+made a block earlier, from the attention block's input, by layer
+``route_early`` (model/route.py) and arrive as a carried side value; each has
+its paragraph below.  No
 token is dropped and no expert is padded to a capacity: the (token, choice)
 pairs are sorted by expert, their rows gathered, three grouped matmuls run
 over the ``experts`` groups of whatever sizes the router made, the rows are
@@ -58,6 +65,25 @@ leaves the layer beside the stream as a CARRIED SIDE VALUE
 output of every block's region, with its cotangent in the backward.  Only the
 strategies that carry one (``checkpoint`` / ``none``, unrolled) run it.
 
+Flag ``routed_early`` (SmallThinker, arXiv:2507.20984: pre-attention
+routing) takes the logits ``[tokens, experts]`` that the ``route_early``
+layer of an EARLIER block left in ``Context.side[ROUTER_LOGITS]``
+(model/route.py: one matrix times the attention block's normed input) and
+removes them from there: this layer makes no router matrix and runs no router
+matmul, forward, replay or backward.  Softmax, top-k, ``moe_norm_topk``,
+``moe_route_scale`` and the balance and z terms are the one-matrix router's;
+their gradient leaves the block through the carried value's cotangent.  The
+same strategies as ``router_mlp``; without a ``route_early`` before it the
+layer refuses by name.  Such a layer reports the load over ALL the experts
+(``moe_all_load_max_over_mean``), since its held share sees an eighth of it.
+
+An activation's name as flag (``silu`` where none is given) is what gates an
+expert: ``relu`` makes it ``down(relu(gate x) * up x)`` (SmallThinker's ReGLU),
+on every path — all held, a share held walking the real rows, a share held
+gathered — and such a layer reports the share of the held pairs' gate values
+that ReLU leaves above zero (``moe_gate_live_share``: the zeros are what the
+model's deployment skips).
+
 Flag ``sigmoid_bias`` replaces the softmax by DeepSeek-V3's scoring
 (arXiv:2412.19437 section 2.1.2): ``s = sigmoid(x W_r)`` in float32, the
 choice ``T = top-k(s + b)`` with ``b [experts]`` the SELECTION BIAS, which
@@ -101,6 +127,7 @@ from .backend import ConstantInit, NormalInit, normal_var
 from .basic import _router_aux_inject
 from .declare import Fact, Layer, Offer, Stat, layers
 from .recurrent import _small_var
+from .route import NO_SIDE_VALUES, ROUTER_LOGITS, matrix_logits
 from .utils import anonymize_dim
 
 
@@ -529,7 +556,8 @@ def route_sigmoid(logits, bias, top_k: int, norm_topk: bool = True,
 ROUTER_STATE = "router_state"
 
 #: the flags layer ``moe`` knows beside an activation's name
-_FLAGS = ("shared_expert", "router_mlp", "plain", "latent", "sigmoid_bias")
+_FLAGS = ("shared_expert", "router_mlp", "plain", "latent", "sigmoid_bias",
+          "routed_early")
 
 # ``SELECTION_BIAS``: the scope the selection bias is made under — its name
 # holds it, which is how ``optim/__init__.py OWN_RULES`` knows the leaf its
@@ -550,9 +578,7 @@ def _router_mlp(args: BlockArgs, xf, anon, ctx):
         raise NotImplementedError(
             "layer moe's router_mlp hands its router state to the next "
             "layer's router, a carried side value that only the unrolled "
-            "checkpoint / none strategies hold: scan_layers, revnet, "
-            "momentum, a pipe mesh, decode, prefill and the stats probe "
-            "have none yet")
+            f"checkpoint / none strategies hold: {NO_SIDE_VALUES}")
     width = Dim("router_width", params.moe_router_width)
     hidden = Dim("_router_width", width.size)
     w_sz, f_sz = width.size, xf.shape[-1]
@@ -589,6 +615,47 @@ def _router_mlp(args: BlockArgs, xf, anon, ctx):
         w2, b2 = matrix([hidden, width]), vector(0.0)
         u = jax.nn.gelu(jnp.dot(u, w2) + b2, approximate=False)
         return jnp.dot(u, matrix([hidden, params.expert_dim]))
+
+
+def _early_logits(ctx, tokens: int, experts: int):
+    """The logits ``[tokens, experts]`` that layer ``route_early``
+    (model/route.py) left in ``ctx.side``, taken out of it: they go no
+    further than this layer."""
+    if ctx.side is None:
+        raise NotImplementedError(
+            "layer moe's routed_early takes its router's logits from an "
+            "earlier block's route_early layer, a carried side value that "
+            "only the unrolled checkpoint / none strategies hold: "
+            f"{NO_SIDE_VALUES}")
+    if ROUTER_LOGITS not in ctx.side:
+        raise ValueError(
+            "layer moe's routed_early found no carried side value "
+            f"{ROUTER_LOGITS!r}: it needs a route_early layer in an earlier "
+            "block (['norm-rms-scale', 'route_early', 'attention-...']), one "
+            "for each routed_early layer")
+    logits = ctx.side.pop(ROUTER_LOGITS)
+    if logits.shape != (tokens, experts):
+        raise ValueError(
+            f"layer moe's routed_early got logits {logits.shape} from "
+            f"route_early where it routes [{tokens}, {experts}]")
+    return logits
+
+
+def _load_max_over_mean(counts):
+    """The busiest expert's pairs over the mean of ``counts [experts]``."""
+    return jnp.max(counts) * counts.shape[-1] \
+        / jnp.maximum(jnp.sum(counts), 1.0)
+
+
+def _gate_live(gate, n_real):
+    """How many values of the first ``n_real`` rows of ``gate [rows, width]``
+    lie above zero (float32): the real rows come first on every path, and
+    what lies past them may hold anything."""
+    def body(at, tile, total):
+        live = at + jnp.arange(tile, dtype=jnp.int32) < n_real
+        return total + jnp.sum((_cut(gate, at, tile) > 0) & live[:, None],
+                               dtype=jnp.float32)
+    return _over_real_tiles(n_real, gate.shape[0], body, jnp.float32(0))
 
 
 def held_rows_bound(tokens: int, top_k: int, held: int) -> int:
@@ -723,9 +790,14 @@ def moe(args: BlockArgs) -> NamedTensor:
     if unknown:
         raise ValueError(f"layer moe does not know flag(s) {unknown} (known: "
                          f"an activation's name, {', '.join(_FLAGS)})")
-    router_mlp, plain, latent, biased = (
-        flag in args.name_extras
-        for flag in ("router_mlp", "plain", "latent", "sigmoid_bias"))
+    router_mlp, plain, latent, biased, early = (
+        flag in args.name_extras for flag in (
+            "router_mlp", "plain", "latent", "sigmoid_bias", "routed_early"))
+    if early and (router_mlp or biased):
+        raise NotImplementedError(
+            "layer moe's routed_early takes the one-matrix softmax router's "
+            "logits from a route_early layer: router_mlp and sigmoid_bias "
+            "have no early form yet")
     if biased and (router_mlp or params.scan_layers
                    or params.pipeline_stages > 1):
         raise NotImplementedError(
@@ -740,8 +812,8 @@ def moe(args: BlockArgs) -> NamedTensor:
     held, first = params.experts_held or n_exp, params.experts_first
     partial = held < n_exp
     held_dim = Dim("experts", held) if partial else params.expert_dim
-    act = next((ACTIVATIONS[a] for a in args.name_extras if a in ACTIVATIONS),
-               ACTIVATIONS["silu"])
+    act_name = next((a for a in args.name_extras if a in ACTIVATIONS), "silu")
+    act = ACTIVATIONS[act_name]
 
     feats = list(params.feature_dims)
     anon = [anonymize_dim(d) for d in feats]
@@ -754,7 +826,7 @@ def moe(args: BlockArgs) -> NamedTensor:
 
     # what the experts read and write: the stream, or the latent
     row_dims, rows_anon, r_sz = feats, anon, f_sz
-    if not router_mlp:
+    if not router_mlp and not early:
         w_router = normal_var(args, anon + [params.expert_dim])
     if biased:
         bias = _small_var(args, SELECTION_BIAS, [params.expert_dim],
@@ -775,10 +847,12 @@ def moe(args: BlockArgs) -> NamedTensor:
     xf = transpose_to(x, token_dims + feats).data.reshape(t_sz, f_sz)
     counts = None
     with jax.named_scope("router"):
-        logits = _router_mlp(args, xf, anon, ctx) if router_mlp else jnp.dot(
-            xf, w_router.data.reshape(f_sz, n_exp),
-            preferred_element_type=None if jax.default_backend() == "cpu"
-            else jnp.float32).astype(jnp.float32)
+        if early:
+            with jax.named_scope("carried"):
+                logits = _early_logits(ctx, t_sz, n_exp)
+        else:
+            logits = _router_mlp(args, xf, anon, ctx) if router_mlp \
+                else matrix_logits(xf, w_router)
         wb, wz = float(params.moe_balance_loss), float(params.moe_router_z_loss)
         if biased:
             if wz:
@@ -798,12 +872,17 @@ def moe(args: BlockArgs) -> NamedTensor:
         # the chosen expert's probability, the mean over the step's tokens:
         # 1 / experts = a router that says nothing
         ctx.layer_stats.append({"moe_top1_weight_mean": jnp.mean(weights)})
+    if ctx.layer_stats is not None and early:
+        # over ALL the experts: the held share below sees its own alone
+        ctx.layer_stats.append({
+            "moe_all_load_max_over_mean": _load_max_over_mean(jnp.sum(
+                experts[..., None] == jnp.arange(n_exp, dtype=experts.dtype),
+                axis=(0, 1), dtype=jnp.float32))})
     if ctx.layer_stats is not None and biased:
         ctx.layer_stats.append({
             "moe_bias_abs_max": jnp.max(jnp.abs(bias)),
             # over ALL the experts, from the counts the bias's rule reads
-            "moe_all_load_max_over_mean":
-                jnp.max(counts) * n_exp / jnp.maximum(jnp.sum(counts), 1.0)})
+            "moe_all_load_max_over_mean": _load_max_over_mean(counts)})
     if latent:
         with jax.named_scope("latent_down"):
             xr = _dense(xf, w_latent_down, (f_sz, r_sz))
@@ -877,6 +956,11 @@ def moe(args: BlockArgs) -> NamedTensor:
                 "moe_up")
             hidden = _gated_held(gated, (gate, up), n_real) if tiled \
                 else gated(gate, up)
+            if ctx.layer_stats is not None and act_name == "relu":
+                real_rows = jnp.sum(sizes)
+                ctx.layer_stats.append({
+                    "moe_gate_live": _gate_live(gate, real_rows),
+                    "moe_gate_values": real_rows.astype(jnp.float32) * i_sz})
         out = checkpoint_name(grouped_dot(
             hidden, w_down.data.reshape(held, i_sz, r_sz), sizes), "moe_down")
     with jax.named_scope("combine"):
@@ -909,15 +993,22 @@ def moe_held_rows(params) -> int:
 
 
 def router_carry_bytes(params) -> int:
-    """Bytes of the router states alive between blocks for the backward: one
-    float32 ``[batch, sequence, moe_router_width]`` for every ``moe`` layer
-    with flag ``router_mlp`` that hands its state to a later one (all but
-    the last).  It passes the blocks in between unchanged, so it is held
-    once however many regions it crosses.  0 where no layer carries one."""
-    carrying = sum(spec is moe.declares and "router_mlp" in extras
-                   for _, extras, spec in layers(params)) * params.depth
-    return max(0, carrying - 1) * params.batch_dim.size \
-        * params.sequence_dim.size * params.moe_router_width * 4 \
+    """Bytes of the carried side values (``Context.side``) alive between
+    blocks for the backward, of both kinds.  The router states: one float32
+    ``[batch, sequence, moe_router_width]`` for every ``moe`` layer with flag
+    ``router_mlp`` that hands its state to a later one (all but the last); it
+    passes the blocks in between unchanged, so it is held once however many
+    regions it crosses.  The early routers' logits: one float32 ``[batch,
+    sequence, experts]`` for every ``route_early`` layer (model/route.py),
+    each held from its block to the ``routed_early`` layer that takes it.  0
+    where no layer carries one."""
+    found = list(layers(params))
+    states = sum(spec is moe.declares and "router_mlp" in extras
+                 for _, extras, spec in found) * params.depth
+    logits = sum(name == "route_early" for name, _, _ in found) * params.depth
+    return (max(0, states - 1) * params.moe_router_width
+            + logits * params.expert_dim.size) * 4 \
+        * params.batch_dim.size * params.sequence_dim.size \
         * max(1, params.macro_batching)
 
 
@@ -1005,8 +1096,17 @@ moe.declares = Layer(
         Stat("moe_all_load_max_over_mean", "gauge",
              "hbnlp_moe_all_load_max_over_mean",
              "pairs of the busiest of ALL the experts over their mean, worst "
-             "sigmoid_bias moe layer of the newest finished step: what the "
-             "selection bias's rule pulls towards 1", "max"),
+             "sigmoid_bias or routed_early moe layer of the newest finished "
+             "step: what the selection bias's rule pulls towards 1", "max"),
+        # an activation of relu: the gate values it leaves above zero
+        Stat("moe_gate_live_share", "gauge", "hbnlp_moe_gate_live_share",
+             "gate values above zero over the gate values of the pairs routed "
+             "to an expert this layer holds, all relu-gated moe layers of "
+             "the newest finished step (what ReLU does not zero: near 0.5 at "
+             "initialisation)",
+             lambda stats, done: jnp.sum(stats["moe_gate_live"])
+             / jnp.maximum(jnp.sum(stats["moe_gate_values"]), 1.0),
+             "moe_gate_live"),
         # the layer whose router says least
         Stat("moe_top1_weight_mean", "gauge", "hbnlp_moe_top1_weight_mean",
              "mean probability of the chosen expert over the tokens of the "
@@ -1014,6 +1114,7 @@ moe.declares = Layer(
              "smallest (1 / experts = a router that says nothing)", "min"),
     ),
     offer=_offer,
+    carried=router_carry_bytes,
     facts=(
         Fact(40, "hbnlp_moe_held_rows_bound",
              "rows of the static dispatch buffer of a moe layer that holds a "
@@ -1022,10 +1123,11 @@ moe.declares = Layer(
              lambda params, mesh, backend: moe_held_rows(params) or None,
              "moe held rows bound {}", zero=False),
         Fact(50, "hbnlp_router_carry_bytes",
-             "bytes of the router states (layer moe, router_mlp) alive "
-             "between blocks for the backward: the carried side value, "
-             "float32 [batch, sequence, moe_router_width] a carrying layer "
-             "but the last",
+             "bytes of the carried side values alive between blocks for the "
+             "backward: the router states (layer moe, router_mlp), float32 "
+             "[batch, sequence, moe_router_width] a carrying layer but the "
+             "last, and the early routers' logits (layer route_early), "
+             "float32 [batch, sequence, experts] a layer",
              lambda params, mesh, backend: router_carry_bytes(params) or None,
              "router carry {} bytes", zero=False),
     ))

@@ -77,7 +77,8 @@ flash call names its outputs).
    and through its own backward they stand where the replay would have put
    them anyway.  ``dense``, last, is charged what every earlier kind took AND
    the block inputs ``checkpoint`` itself keeps, one ``[batch, sequence,
-   features]`` for every ``jax.checkpoint`` region of the step: the 15%
+   features]`` for every ``jax.checkpoint`` region of the step (with the
+   carried side values that enter the regions beside them): the 15%
    bounds what the step holds ACROSS its backward, and those inputs are such
    bytes that no other kind counts.  Both: all the step's executions or none
    under ``scan_layers`` (one traced block for all iterations); every
@@ -96,7 +97,7 @@ import numpy as np
 
 from ..config import ModelParameter
 from ..core import sharding as shardlib
-from .declare import offers, region_offers
+from .declare import carried_bytes, offers, region_offers
 
 #: fraction of per-chip HBM the attention stash may claim (the historical
 #: resolve_stash gate)
@@ -221,14 +222,17 @@ def _block_input_bytes(params: ModelParameter, shards: int) -> int:
     itself keeps across the step's backward: one ``[batch, sequence,
     features]`` in the calculation dtype for every ``jax.checkpoint`` region
     of the step — each block of the body, each time it runs, and each block
-    of a multi-token-prediction module.  (The input and output blocks run
-    outside any region, model/__init__.py: they keep no block input, replay
-    nothing and offer nothing.)"""
+    of a multi-token-prediction module — and, beside them, the carried side
+    values the layers declare (``declare.carried_bytes``: a router state or
+    an early router's logits is an operand of a region like its block input,
+    and as alive).  (The input and output blocks run outside any region,
+    model/__init__.py: they keep no block input, replay nothing and offer
+    nothing.)"""
     one = params.batch_dim.size * params.sequence_dim.size \
         * int(np.prod([d.size for d in params.feature_dims])) \
         * np.dtype(params.calculation_dtype).itemsize
-    return -(-one * region_count(params)
-             * max(1, params.macro_batching) // shards)
+    return -(-(one * region_count(params) * max(1, params.macro_batching)
+               + carried_bytes(params)) // shards)
 
 
 def _regions(params: ModelParameter, kind: str, shards: int,

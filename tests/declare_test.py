@@ -69,6 +69,11 @@ _LAGUNA = _scored(1.125, 6094848 / 4059392)
 #: 1,024)
 _SDAR = _scored((2 * 37748736 + 65536) / 67141632,
                 (2 * 35651584 + 65536) / 67141632)
+#: SmallThinker: the global layers' forward at 16,384; the windowed layers'
+#: backward at 512 x 512 tiles under a window of 4,096 (62,390,272 pairs
+#: scored over ``flash_attention.live_pairs``' 58,714,112), their forward the
+#: band kernel
+_SMALLTHINKER = _scored(1.0625, 62390272 / 58714112)
 _CELLS = {
     "train_32big_mixer_b32": (_kinds(), "", {}),
     "train_32big_mixer_dp2tp2": (
@@ -183,6 +188,19 @@ _CELLS = {
         + "; denoise stream 16384 positions",
         {"hbnlp_moe_held_rows_bound": 131072, **_one_pass(_SDAR, 7)[1],
          "hbnlp_denoise_stream_positions": 16384}),
+    # PR 72: pre-attention routing: eight layers' (out [1, 16384, 28, 128]
+    # bfloat16, lse [28, 16384] float32), the row buffer at six slots a token,
+    # the CARRIED logits (eight float32 [1, 16384, 64]: the gauge counts both
+    # kinds of side value), six band forwards; the worst pass over live pairs
+    # is the global layers' forward and the window-4,096 layers' backward at
+    # 512 x 512 tiles.  The thirteen lines above stand
+    "train_smallthinker_21b_ep8_s16k": (
+        _kinds(attention=(8, 954204160)),
+        "; moe held rows bound 98304; router carry 33554432 bytes; "
+        "flash band 6 layers" + _one_pass(_SMALLTHINKER, 8)[0],
+        {"hbnlp_moe_held_rows_bound": 98304,
+         "hbnlp_router_carry_bytes": 33554432, "hbnlp_flash_band_layers": 6,
+         **_one_pass(_SMALLTHINKER, 8)[1]}),
 }
 #: the facts that read 0 where no layer has the mechanism; the others have no
 #: series there
@@ -313,8 +331,15 @@ def _config_files():
 #: layers, 1255145472`` and ``dense 2 layers, 536870912`` (6, 1610612736),
 #: ``benchmark/configs/nemotron_3_super_120b.json`` ``recurrent 5 layers,
 #: 1520435200`` —, the other 33 files as they were: before it
-#: 8af285da012d194c1f1f2761c2da8d6dc449c633)
-_FILE_DIGEST = "abf9c04a11d6e8f83fd126478a6dd66bb2935c3c"
+#: 8af285da012d194c1f1f2761c2da8d6dc449c633; PR 72 added the two SmallThinker
+#: files — the cell's reads ``attention 8 layers, 954204160`` on both sides
+#: with ``moe held rows bound 98304; router carry 33554432 bytes`` (the
+#: carried logits: until now the gauge counted ``router_mlp`` states alone)
+#: and, on a TPU, ``flash band 6 layers; flash scored over live pairs fwd
+#: 1.0625 bwd 1.06261; flash backward one pass 8 layers`` —: without them the
+#: digest is PR 71's abf9c04a11d6e8f83fd126478a6dd66bb2935c3c, every other
+#: line as it was)
+_FILE_DIGEST = "9e1f0d21625332039d01c4ad2166a4b66a736b4a"
 
 
 def every_configuration_file_starts_as_on_the_parent_test(monkeypatch):
@@ -426,9 +451,10 @@ def statistics_are_all_declared_test():
     and its transform under ``gated_delta``'s name; PR 62: attention flag
     ``indexed``'s index loss and largest kept score), (PR 49) three of a
     looped model's loss and (PR 65) two of a multi-token-prediction
-    module's, and a step whose layers report nothing (or only some) has only
+    module's, (PR 72) the live share of a relu-gated sparse layer's gate
+    values, and a step whose layers report nothing (or only some) has only
     those."""
-    assert len(_LAYER_STATS) == 27 == len(declare.stats())
+    assert len(_LAYER_STATS) == 28 == len(declare.stats())
     assert {name for name in _LAYER_STATS if name.startswith("denoise_")} == {
         "denoise_masked_share", "denoise_weight_mean", "denoise_loss"}
     assert {name for name in _LAYER_STATS if name.startswith("mtp_")} == {
