@@ -23,10 +23,10 @@ from ..core.tensor import (NamedTensor, cumsum as tensor_cumsum, einsum, exp,
                            less, multiply, range_, reduce_max, reduce_sum,
                            stop_gradient, greater_equal)
 from ..parallel.flash_attention import (SAVED_NAMES, SELECT_NAME,
-                                        band_applies,
+                                        backward_form, band_applies,
                                         block_diffusion_scored_over_live,
-                                        call_tiles, one_pass_applies,
-                                        scored_over_live, stepped_applies)
+                                        call_tiles, scored_over_live,
+                                        stepped_applies)
 from . import decode as decode_mod
 from .basic import activated_linear_in, activated_linear_out
 from .declare import Fact, Layer, Offer, Stat, step_offers
@@ -1140,10 +1140,11 @@ def flash_backward_one_pass_layers(params, backend=None
                                    ) -> typing.Optional[int]:
     """How many attention layers of the step run their flash BACKWARD as the
     one-pass kernel (``parallel/flash_attention.py _bwd_flat_one_pass``: dq,
-    dk and dv from one sweep, nothing partial in HBM), by the predicate
-    ``_bwd_flat`` itself calls on the call's keys, widths and tiles — the
-    other layers that reach the causal, windowed or block-diffusion kernels
-    are on the split dq / dk-dv pair.  None (the gauge reads 0, the line
+    dk and dv from one sweep, nothing partial in HBM; a head's dk and dv
+    resident, or its dq), by the predicate ``_bwd_flat`` itself calls on the
+    call's keys, widths and tiles (``backward_form``) — the other layers
+    that reach the causal, windowed or block-diffusion kernels are on the
+    split dq / dk-dv pair.  None (the gauge reads 0, the line
     says nothing) where no call reaches them — ``use_flash_attention`` off,
     the CPU, a sequence of no whole 128-tiles — and where no layer offers
     such a call (a sparse or indexed layer past its dense length has the
@@ -1165,8 +1166,8 @@ def flash_backward_one_pass_layers(params, backend=None
             if not stepped_applies(keys, d_k, offer.block, itemsize, d_v):
                 continue
         blk = call_tiles(keys, d_k, window, itemsize, d_v)[0]
-        layers += times * one_pass_applies(keys, d_k, d_v, blk, blk,
-                                           itemsize)
+        layers += times * (backward_form(keys, keys, d_k, d_v, blk, blk,
+                                         itemsize, window=window) != "split")
     return layers
 
 

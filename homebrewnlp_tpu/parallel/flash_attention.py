@@ -32,8 +32,12 @@ every accumulation in VMEM (``_bwd_one_pass_kernel``, PR 68: a grid of the
 call's LIVE cells, a q block at a time with its k blocks ascending — dq over
 that walk, a head's whole dk and dv resident for its sweep; nothing partial
 reaches HBM, no grid step for a dead cell) where those accumulators fit the VMEM
-the call asks for (``one_pass_applies``: key width 192 at 16,384 positions
-does, 512 does not); a dq and a dk/dv kernel where they do not.
+the call asks for (key width 192 at 16,384 positions does, 512 does not);
+the same pass the other way round (PR 73: a k block at a time with its q
+blocks ascending — dk and dv over that walk, the head's whole dq resident,
+half the bytes) where that fits (width 512 at 16,384 does); a dq and a dk/dv
+kernel where neither does (width 512 at 65,536).  Three forms, one predicate:
+``backward_form``.
 
 A WINDOWED call's forward (``window``: query ``i`` sees keys ``i - window + 1
 .. i``) is a band kernel instead (``_fwd_band``, PR 41) wherever a cell's band
@@ -361,8 +365,7 @@ def _masked_step(qi, ki, block_q: int, block_k: int, causal: bool, step,
              for value in _edge_offsets(block_q, block_k, window)}
 
     shared, shared_mask = full, None
-    if carried and width * (whole.pairs + sum(
-            p.pairs for parts in cells.values() for p in parts)) \
+    if carried and width * _body_pairs(block_q, block_k, window, True) \
             > _FORWARD_BODY_CAP:
         for value, (part,) in list(cells.items()):
             if (part.rows, part.cols) == (whole.rows, whole.cols):
@@ -863,21 +866,26 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, dk_ref,
 
 @functools.lru_cache(maxsize=None)
 def _live_steps(num_q: int, num_k: int, block_q: int, block_k: int,
-                causal: bool, window):
+                causal: bool, window, k_major: bool = False):
     """``(qi, ki, edge)`` int32 ``[steps]``: the LIVE cells of a call's
-    ``num_q`` x ``num_k`` rectangle in the order the one-pass backward walks
-    them — a q block at a time, its k blocks ascending — and whether a step
-    is its q block's first (``edge & 1``) and last (``edge & 2``).  A cell
-    is live where ``_causal_split`` / ``_window_split`` say so (the stepped
-    diagonal's cells are the causal ones); every q block has one."""
+    ``num_q`` x ``num_k`` rectangle in the order a one-pass backward walks
+    them — a q block at a time, its k blocks ascending, or (``k_major``, the
+    dq-resident form's) a k block at a time, its q blocks ascending — and
+    whether a step is its OUTER block's first (``edge & 1``) and last
+    (``edge & 2``).  A cell is live where ``_causal_split`` /
+    ``_window_split`` say so (the stepped diagonal's cells are the causal
+    ones); every outer block has one."""
     import numpy as np
     cells = [(j, c) for j in range(num_q) for c in range(num_k)
              if not causal or _rect_state(j * block_q - c * block_k,
                                           (0, block_q), (0, block_k),
                                           window)[0]]
+    if k_major:
+        cells.sort(key=lambda cell: cell[::-1])
     qi, ki = (np.asarray(x, np.int32) for x in zip(*cells))
-    assert len(set(qi.tolist())) == num_q
-    turn = qi[1:] != qi[:-1]
+    outer = ki if k_major else qi
+    assert len(set(outer.tolist())) == (num_k if k_major else num_q)
+    turn = outer[1:] != outer[:-1]
     edge = np.r_[True, turn] + 2 * np.r_[turn, True]
     return qi, ki, edge.astype(np.int32)
 
@@ -887,45 +895,64 @@ def _bwd_one_pass_kernel(qi_ref, ki_ref, edge_ref, q_ref, k_ref, v_ref,
                          dq_acc, dk_acc, dv_acc, *, block_q: int,
                          block_k: int, scale: float, causal: bool,
                          window=None, step=None):
-    """One-pass backward: grid (b*h, live cells) — a head's live cells a q
-    block at a time, k ascending (``_live_steps``, prefetched: the dq
-    kernel's order with no step for a dead cell).
+    """One-pass backward: grid (b*h, live cells) — a head's live cells an
+    OUTER block at a time, its inner blocks ascending (``_live_steps``,
+    prefetched: no step for a dead cell).
 
     The split dq and dk/dv kernels EACH recompute the two shared per-pair
     tensors p = exp(q·kᵀ − lse) and dp = do·vᵀ — 7 dots + 2 exp per live
     pair across the two passes.  This kernel computes them once and gives
     all three gradients — 5 dots + 1 exp — which also lets the dq
     contribution ride the MXU work that hides the exp.  Every gradient
-    accumulates in float32 VMEM and nothing partial reaches HBM: dq in a
-    ``[bq, d]`` scratch over the inner k walk, as in the dq kernel; dk and
-    dv in ``[k tiles, bk, d]`` scratch that stays for the HEAD's whole sweep
-    (zeroed at its first step; at its last, cast into output blocks whose
-    index changes with the head only, so each is written back once).  What
-    that costs in VMEM decides whether the call is this one
-    (``one_pass_applies``)."""
+    accumulates in float32 VMEM and nothing partial reaches HBM.  ONE side's
+    gradients stay for the HEAD's whole sweep, in ``[tiles, rows, width]``
+    scratch (zeroed at its first step; at its last, cast into output blocks
+    whose index changes with the head only, so each is written back once);
+    the other side's are a ``[rows, width]`` scratch over an outer block's
+    inner walk, as in the split kernels.  Which side is which is in the
+    scratch the caller hands over (``_bwd_flat_one_pass``):
+
+    * q blocks outermost (PR 68): dq over a q block's k walk, a head's whole
+      dk and dv resident — ``sk * (d + d_v)`` floats;
+    * k blocks outermost (PR 73): dk and dv over a k block's q walk, the dk/dv
+      kernel's order, a head's whole dq resident — ``s * d`` floats, half of
+      the above where the widths are equal, which is what lets head width 512
+      at 16,384 positions in.
+
+    Either walk adds a block's contributions in the split kernels' order
+    (dq its k blocks ascending, dk / dv their q blocks ascending).  What each
+    costs in VMEM decides the call's form (``backward_form``)."""
     from jax.experimental import pallas as pl
 
     t = pl.program_id(1)
     qi, ki, edge = qi_ref[t], ki_ref[t], edge_ref[t]
+    grads = ((dq_ref, dq_acc), (dk_ref, dk_acc), (dv_ref, dv_acc))
+    # a head-resident accumulator is [tiles, rows, width]
+    held = [(ref, acc) for ref, acc in grads if len(acc.shape) == 3]
+    walked = [(ref, acc) for ref, acc in grads if len(acc.shape) == 2]
 
-    def _each_k_tile(body):
+    def _each_held_tile(body):
         def run(c, carry):
-            body(c)
+            for ref, acc in held:
+                body(ref, acc, c)
             return carry
-        jax.lax.fori_loop(0, dk_acc.shape[0], run, 0)
+        jax.lax.fori_loop(0, held[0][1].shape[0], run, 0)
 
     @pl.when(t == 0)
     def _init_head():
-        def zero(c):
-            dk_acc[c] = jnp.zeros(dk_acc.shape[1:], dk_acc.dtype)
-            dv_acc[c] = jnp.zeros(dv_acc.shape[1:], dv_acc.dtype)
-        _each_k_tile(zero)
+        def zero(_, acc, c):
+            acc[c] = jnp.zeros(acc.shape[1:], acc.dtype)
+        _each_held_tile(zero)
 
     @pl.when((edge & 1) == 1)
     def _init():
-        dq_acc[...] = jnp.zeros_like(dq_acc)
+        for _, acc in walked:
+            acc[...] = jnp.zeros_like(acc)
 
     pair = _make_pair(q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, scale)
+
+    def at(acc, tile, rows):
+        return (tile, rows) if len(acc.shape) == 3 else (rows,)
 
     def _step(rows, cols, mask):
         # identical dot/rounding structure to the split kernels (numerics
@@ -934,13 +961,13 @@ def _bwd_one_pass_kernel(qi_ref, ki_ref, edge_ref, q_ref, k_ref, v_ref,
         r, c = slice(*rows), slice(*cols)
         p, ds = pair(rows, cols, mask)
         ds = ds.astype(q_ref.dtype)
-        dq_acc[r, :] += jax.lax.dot_general(
+        dq_acc[at(dq_acc, qi, r)] += jax.lax.dot_general(
             ds, k_ref[c, :], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dk_acc[ki, c, :] += jax.lax.dot_general(
+        dk_acc[at(dk_acc, ki, c)] += jax.lax.dot_general(
             ds, q_ref[r, :], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dv_acc[ki, c, :] += jax.lax.dot_general(
+        dv_acc[at(dv_acc, ki, c)] += jax.lax.dot_general(
             p.astype(do_ref.dtype), do_ref[r, :], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
@@ -949,19 +976,35 @@ def _bwd_one_pass_kernel(qi_ref, ki_ref, edge_ref, q_ref, k_ref, v_ref,
 
     @pl.when((edge & 2) == 2)
     def _finish():
-        dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
+        for ref, acc in walked:
+            ref[...] = acc[...].astype(ref.dtype)
 
     @pl.when(t == pl.num_programs(1) - 1)
     def _finish_head():
-        def cast(c):
-            dk_ref[c] = dk_acc[c].astype(dk_ref.dtype)
-            dv_ref[c] = dv_acc[c].astype(dv_ref.dtype)
-        _each_k_tile(cast)
+        def cast(ref, acc, c):
+            rows = acc.shape[1]
+            # the output block is the accumulator's [tiles, rows, width] or
+            # the head's plain [tiles * rows, width]
+            tile = c if len(ref.shape) == 3 \
+                else pl.ds(pl.multiple_of(c * rows, rows), rows)
+            ref[tile] = acc[c].astype(ref.dtype)
+        _each_held_tile(cast)
 
 
 #: scoped VMEM the one-pass backward asks for, of the 128 MiB a v5e / v5p
 #: core has: its head-resident accumulators are what the call is for
 _ONE_PASS_VMEM_BUDGET = 100 * 1024 * 1024
+#: the most multiply-adds (dots x rows x keys x head width, summed over the
+#: body's branches) a one-pass BACKWARD body may hold: the forward's two whole
+#: 1,024 x 2,048 tiles of two dots at head width 512, the largest body
+#: measured to run at speed (``_FORWARD_BODY_CAP``).  The one pass at 1,024 x
+#: 1,024 tiles and width 512 — the interior's branch and the diagonal cell's
+#: three quadrants, 1.75 cells of five dots, 4.70 G — ran 89.06 ms a call on
+#: a v5e where the split pair takes 85.43 and the same pass at k tiles of 512
+#: (3.02 G) 59.61, 93.6% of its 55.8 ms floor; the dk/dv kernel's 1.75 cells
+#: of four dots (3.76 G) run at 93.4% of theirs (PERF.md section 6, PR 73;
+#: the cause, as for the forward's, not established)
+_ONE_PASS_BODY_CAP = 2 * _FORWARD_BODY_CAP
 
 
 def _lane_pad(width: int) -> int:
@@ -969,11 +1012,31 @@ def _lane_pad(width: int) -> int:
     return -(-width // 128) * 128
 
 
+def _one_pass_fits(held: int, walked: int, block_q: int, block_k: int,
+                   wide: int, itemsize: int,
+                   out_itemsize: typing.Optional[int]) -> bool:
+    """The one-pass backward's VMEM inside ``_ONE_PASS_VMEM_BUDGET``:
+    ``held`` elements of gradient resident for a head's sweep (a float32
+    accumulator and two buffers of the output block it is cast into),
+    ``walked`` elements summed over an outer block's inner walk (two buffers
+    of output tile and a float32 accumulator), the q / k / v / do tiles (key
+    and value width together ``wide``) and the lse / delta columns (a lane
+    tile a row), two buffers each, and a cell's score planes: four float32
+    (s, p, dp, ds) and p, ds in the operand dtype."""
+    out_itemsize = itemsize if out_itemsize is None else out_itemsize
+    resident = held * (4 + 2 * out_itemsize)
+    tiles = 2 * ((block_q + block_k) * wide * itemsize
+                 + 2 * block_q * 128 * 4 + walked * out_itemsize) \
+        + walked * 4
+    scores = block_q * block_k * (4 * 4 + 2 * itemsize)
+    return resident + tiles + scores <= _ONE_PASS_VMEM_BUDGET
+
+
 def one_pass_applies(sk: int, d: int, d_v: int, block_q: int, block_k: int,
                      itemsize: int,
                      out_itemsize: typing.Optional[int] = None) -> bool:
-    """Whether a call's BACKWARD is the one-pass kernel
-    (``_bwd_one_pass_kernel``): its VMEM over ``sk`` keys a head at tiles
+    """Whether the one-pass backward with a head's dk and dv RESIDENT (q
+    blocks outermost, PR 68) fits: its VMEM over ``sk`` keys a head at tiles
     ``block_q`` x ``block_k`` inside ``_ONE_PASS_VMEM_BUDGET`` — the float32
     dk and dv accumulators of a whole head, the output blocks they are cast
     into, the q / k / v / do tiles, the lse / delta columns (a lane tile a
@@ -982,40 +1045,122 @@ def one_pass_applies(sk: int, d: int, d_v: int, block_q: int, block_k: int,
     operand dtype.  At 16,384 keys and widths 192 / 128 that is 50 + 7 + 21
     = 79 MB (a width pads to whole lane tiles: 192 holds 256); head width
     512 there (2 x 33.5 MB of accumulators and as much again of output
-    blocks) does not fit and keeps the split dq / dk-dv pair.  Pure in its
-    arguments: the one predicate ``_bwd_flat`` and the
-    ``hbnlp_flash_backward_one_pass_layers`` gauge (model/spatial.py) read."""
-    out_itemsize = itemsize if out_itemsize is None else out_itemsize
+    blocks) does not fit.  The first question ``backward_form`` asks."""
     block_q, block_k = min(block_q, sk), min(block_k, sk)
     wide = _lane_pad(d) + _lane_pad(d_v)
-    resident = sk * wide * (4 + 2 * out_itemsize)
-    tiles = 2 * ((block_q + block_k) * wide * itemsize
-                 + 2 * block_q * 128 * 4
-                 + block_q * _lane_pad(d) * out_itemsize) \
-        + block_q * _lane_pad(d) * 4
-    scores = block_q * block_k * (4 * 4 + 2 * itemsize)
-    return resident + tiles + scores <= _ONE_PASS_VMEM_BUDGET
+    return _one_pass_fits(sk * wide, block_q * _lane_pad(d), block_q, block_k,
+                          wide, itemsize, out_itemsize)
+
+
+def dq_resident_applies(s: int, d: int, d_v: int, block_q: int, block_k: int,
+                        itemsize: int,
+                        out_itemsize: typing.Optional[int] = None) -> bool:
+    """Whether the one-pass backward with a head's dq RESIDENT (k blocks
+    outermost, PR 73) fits: its VMEM over ``s`` queries a head inside
+    ``_ONE_PASS_VMEM_BUDGET``, by ``one_pass_applies``' account with the
+    sides exchanged — the float32 dq accumulator of a whole head and the
+    output block it is cast into (two pipeline buffers), the q / k / v / do
+    tiles, the lse / delta columns and the dk / dv tiles (two buffers each),
+    dk's and dv's accumulators, and a cell's score planes.  At 16,384
+    queries and head width 512: 33.5 + 2 x 16.8 = 67.1 MB resident (HALF of
+    what dk and dv resident take: one gradient, not two) and, at the 1,024 x
+    512 tiles ``one_pass_tiles`` gives that width, 6.3 of operand tiles, 2.1
+    of columns, 2.1 + 2.1 of dk / dv tiles and accumulators, 10.5 of score
+    planes: 90 MB = 86 MiB (107 MB at 1,024 x 1,024).  The second question
+    ``backward_form`` asks."""
+    block_q, block_k = min(block_q, s), min(block_k, s)
+    wide = _lane_pad(d) + _lane_pad(d_v)
+    return _one_pass_fits(s * _lane_pad(d), block_k * wide, block_q, block_k,
+                          wide, itemsize, out_itemsize)
+
+
+def _body_pairs(block_q: int, block_k: int, window, carried: bool) -> int:
+    """The pairs a causal kernel's BODY scores over its branches
+    (``_masked_step``): the interior's whole tile and each edge offset's
+    parts.  What a body holds of matmuls is this by its dots' widths."""
+    return block_q * block_k + sum(
+        part.pairs for off in _edge_offsets(block_q, block_k, window)
+        for part in _cell_parts(block_q, block_k, off, window, carried))
+
+
+def one_pass_tiles(block_q: int, block_k: int, d: int, d_v: int,
+                   causal: bool = True, window=None
+                   ) -> typing.Tuple[int, int]:
+    """The tiles the one-pass backward runs at where its caller asks for
+    ``block_q`` x ``block_k``: the k tile halved (to 128 at least) while the
+    body — three dots over the key's width and two over the value's a pair,
+    over the pairs of ``_body_pairs`` — passes ``_ONE_PASS_BODY_CAP``.  Head
+    width 512 at 1,024 x 1,024 becomes 1,024 x 512; widths 64 to 256 keep
+    what they ask for.  Pure in its arguments: ``backward_form`` holds the
+    one pass's VMEM account to these, ``_bwd_flat`` runs it at them and
+    ``scored_over_live`` counts its pairs by them."""
+    width = 3 * _lane_pad(d) + 2 * _lane_pad(d_v)
+
+    def volume(block_k):
+        pairs = _body_pairs(block_q, block_k, window, False) if causal \
+            else block_q * block_k
+        return width * pairs
+
+    while block_k > 128 and volume(block_k) > _ONE_PASS_BODY_CAP:
+        block_k //= 2
+    return block_q, block_k
+
+
+#: the backward's forms, in the order ``backward_form`` asks for them
+BACKWARD_FORMS = ("dkv_resident", "dq_resident", "split")
+
+
+def backward_form(s: int, sk: int, d: int, d_v: int, block_q: int,
+                  block_k: int, itemsize: int,
+                  out_itemsize: typing.Optional[int] = None,
+                  causal: bool = True, window=None) -> str:
+    """The form a call's BACKWARD takes (one of ``BACKWARD_FORMS``), from
+    its ``s`` queries and ``sk`` keys a head, its widths, the tiles it asks
+    for (the one pass is held to ``one_pass_tiles`` of them) and its dtypes
+    against VMEM:
+
+    * ``"dkv_resident"`` — the one pass, q blocks outermost, where a head's
+      dk and dv fit (``one_pass_applies``): every call of widths 64, 128 and
+      192 / 128 at 4,096 to 16,384 positions, and a ring hop's chunk pair;
+    * else ``"dq_resident"`` — the one pass, k blocks outermost, where a
+      head's dq fits (``dq_resident_applies``): head width 512 at 16,384
+      positions, the long-context recipe's call;
+    * else ``"split"`` — the dq and the dk/dv kernel, O(tile) VMEM whatever
+      the length: width 512 at 65,536, or at 16,384 with float32 outputs.
+
+    Pure in its arguments: the one predicate ``_bwd_flat``, the
+    ``hbnlp_flash_backward_one_pass_layers`` gauge (model/spatial.py) and
+    ``scripts/kernel_parity.py`` read."""
+    tiles = one_pass_tiles(block_q, block_k, d, d_v, causal, window)
+    if one_pass_applies(sk, d, d_v, *tiles, itemsize, out_itemsize):
+        return "dkv_resident"
+    if dq_resident_applies(s, d, d_v, *tiles, itemsize, out_itemsize):
+        return "dq_resident"
+    return "split"
 
 
 def _grad_dtypes(qt, kt, vt, out_dtype):
     """(dq, dk, dv) dtypes: each operand's own, or ``out_dtype`` for all —
-    the same in both backward forms (which one runs is a size decision and
+    the same in every backward form (which one runs is a size decision and
     must not change output precision)."""
     return tuple(x.dtype if out_dtype is None else out_dtype
                  for x in (qt, kt, vt))
 
 
 def _bwd_flat_one_pass(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
-                       interpret, out_dtype=None, window=None, step=None):
+                       interpret, out_dtype=None, window=None, step=None,
+                       dq_resident: bool = False):
     """The one-pass backward (see ``_bwd_one_pass_kernel``): the grid's
-    second dimension is the call's live cells, causal, windowed or all."""
+    second dimension is the call's live cells, causal, windowed or all;
+    ``dq_resident``: k blocks outermost, a head's dq held, instead of q
+    blocks outermost, its dk and dv held."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, s, d = qt.shape
     sk, dv = kt.shape[1], vt.shape[2]
     nq, nk = s // bq, sk // bk
-    steps = _live_steps(nq, nk, bq, bk, causal, window)
+    steps = _live_steps(nq, nk, bq, bk, causal, window, dq_resident)
     dq_dtype, dk_dtype, dv_dtype = _grad_dtypes(qt, kt, vt, out_dtype)
 
     def q_side(width):
@@ -1026,14 +1171,35 @@ def _bwd_flat_one_pass(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
         return pl.BlockSpec((None, bk, width),
                             lambda i, t, qi, ki, edge: (i, ki[t], 0))
 
-    def head_spec(width):
-        # a head's whole dk / dv, [k tiles, bk, width]: the index changes
-        # with the head only, so each is written back once a head — behind
-        # the next head's sweep (two buffers: one measured 0.7-4% slower a
-        # call on a v5e, the write-back waited for; PERF.md section 6, PR 68)
-        return pl.BlockSpec((None, nk, bk, width),
-                            lambda i, t, *_: (i, 0, 0, 0))
+    def walked(side, rows, length, width, dtype):
+        # (out spec, out shape, float32 scratch) of a gradient summed over
+        # an outer block's inner walk: a tile
+        return (side(width), jax.ShapeDtypeStruct((bh, length, width), dtype),
+                pltpu.VMEM((rows, width), jnp.float32))
 
+    def held(tiles, rows, width, dtype):
+        # the same of a gradient a head holds whole, [tiles, rows, width]:
+        # the index changes with the head only, so each is written back once
+        # a head — behind the next head's sweep (two buffers: one measured
+        # 0.7-4% slower a call on a v5e, the write-back waited for; PERF.md
+        # section 6, PR 68)
+        return (pl.BlockSpec((None, tiles, rows, width),
+                             lambda i, t, *_: (i, 0, 0, 0)),
+                jax.ShapeDtypeStruct((bh, tiles, rows, width), dtype),
+                pltpu.VMEM((tiles, rows, width), jnp.float32))
+
+    if dq_resident:
+        # dq leaves as the plain [bh, s, d] it is everywhere else (the trace
+        # names a call by its first output)
+        grads = ((pl.BlockSpec((None, s, d), lambda i, t, *_: (i, 0, 0)),
+                  jax.ShapeDtypeStruct((bh, s, d), dq_dtype),
+                  pltpu.VMEM((nq, bq, d), jnp.float32)),
+                 walked(k_side, bk, sk, d, dk_dtype),
+                 walked(k_side, bk, sk, dv, dv_dtype))
+    else:
+        grads = (walked(q_side, bq, s, d, dq_dtype),
+                 held(nk, bk, d, dk_dtype), held(nk, bk, dv, dv_dtype))
+    out_specs, out_shape, scratch = (list(x) for x in zip(*grads))
     dq, dk, dv_ = pl.pallas_call(
         functools.partial(_bwd_one_pass_kernel, block_q=bq, block_k=bk,
                           scale=scale, causal=causal, window=window,
@@ -1042,15 +1208,10 @@ def _bwd_flat_one_pass(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
             num_scalar_prefetch=3, grid=(bh, len(steps[0])),
             in_specs=[q_side(d), k_side(d), k_side(dv), q_side(dv),
                       q_side(1), q_side(1)],
-            out_specs=[q_side(d), head_spec(d), head_spec(dv)],
-            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),
-                            pltpu.VMEM((nk, bk, d), jnp.float32),
-                            pltpu.VMEM((nk, bk, dv), jnp.float32)]),
-        out_shape=[jax.ShapeDtypeStruct((bh, s, d), dq_dtype),
-                   jax.ShapeDtypeStruct((bh, nk, bk, d), dk_dtype),
-                   jax.ShapeDtypeStruct((bh, nk, bk, dv), dv_dtype)],
-        # dk and dv accumulate across a head's whole walk: only the heads
-        # are independent
+            out_specs=out_specs, scratch_shapes=scratch),
+        out_shape=out_shape,
+        # the held gradients accumulate across a head's whole walk: only
+        # the heads are independent
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_ONE_PASS_VMEM_BUDGET),
@@ -1070,15 +1231,24 @@ def _bwd_flat(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
     (``out_dtype=f32`` there: per-hop grad pieces accumulate across P hops
     and must not round per hop).
 
-    The ONE-PASS kernel (``_bwd_one_pass_kernel`` — 5 dots + 1 exp per pair
-    instead of the split kernels' 7 + 2) where ``one_pass_applies`` says a
-    head's dk and dv fit VMEM; the split dq / dk/dv kernels where not."""
+    Three forms, chosen by ``backward_form`` from the call's shapes: the
+    ONE-PASS kernel (``_bwd_one_pass_kernel`` — 5 dots + 1 exp per pair
+    instead of the split kernels' 7 + 2) with a head's dk and dv resident
+    where they fit VMEM, with its dq resident where that does, the split dq
+    / dk/dv kernels where neither does.  The one pass runs at
+    ``one_pass_tiles`` of the tiles asked for (a narrower k tile where the
+    body would be too large: head width 512), the split pair at those."""
+    d, dv = qt.shape[2], vt.shape[2]
     out_itemsize = jnp.dtype(_grad_dtypes(qt, kt, vt, out_dtype)[0]).itemsize
-    one_pass = one_pass_applies(kt.shape[1], qt.shape[2], vt.shape[2], bq, bk,
-                                qt.dtype.itemsize, out_itemsize)
-    return (_bwd_flat_one_pass if one_pass else _bwd_flat_split)(
-        qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk, interpret,
-        out_dtype, window, step)
+    form = backward_form(qt.shape[1], kt.shape[1], d, dv, bq, bk,
+                         qt.dtype.itemsize, out_itemsize, causal, window)
+    if form == "split":
+        return _bwd_flat_split(qt, kt, vt, dot, lse3, delta, scale, causal,
+                               bq, bk, interpret, out_dtype, window, step)
+    return _bwd_flat_one_pass(qt, kt, vt, dot, lse3, delta, scale, causal,
+                              *one_pass_tiles(bq, bk, d, dv, causal, window),
+                              interpret, out_dtype, window, step,
+                              dq_resident=form == "dq_resident")
 
 
 def _bwd_flat_split(qt, kt, vt, dot, lse3, delta, scale, causal, bq, bk,
@@ -1338,9 +1508,13 @@ def scored_over_live(s: int, d: int, window, itemsize: int
         window = None
     blk, fwd_q, fwd_k, band = call_tiles(s, d, window, itemsize)
     live = live_pairs(s, window)
+    bwd = blk, blk
+    if backward_form(s, s, d, d, blk, blk, itemsize, None, True,
+                     window) != "split":
+        bwd = one_pass_tiles(blk, blk, d, d, True, window)
     return {"fwd": None if band else scored_pairs(s, fwd_q, fwd_k, window,
                                                   carried=True) / live,
-            "bwd": scored_pairs(s, blk, blk, window) / live}
+            "bwd": scored_pairs(s, *bwd, window) / live}
 
 
 def attention(q, k, v, scale: typing.Optional[float] = None,
@@ -1384,23 +1558,30 @@ def attention(q, k, v, scale: typing.Optional[float] = None,
     backward keeps 1024x1024 — measured neutral at wider k standalone, and
     the dq kernel exceeds the in-model scoped-VMEM limit there.
 
-    The backward's form (PR 68; ``_bwd_flat``): ONE pass — 5 dots + 1 exp a
+    The backward's form (PR 68, PR 73; ``_bwd_flat``, ``backward_form``):
+    ONE pass — 5 dots + 1 exp a
     pair — on a grid of the call's live cells (``_live_steps``: prefetched
     tables, so a dead cell costs no step: 0.58 us each on the rectangular
     grid, 120 of a head's 256 at 16,384), dq in VMEM over a q block's k walk
     and a head's whole dk and dv in float32 VMEM for its sweep, where
     ``one_pass_applies`` (the call's keys, widths and tiles against the 100
-    MiB it asks for); the split dq / dk-dv pair — 7 + 2 — where a head's
-    accumulators do not fit (width 512 at 16,384).  Until PR 68 the one pass
+    MiB it asks for); the same pass with k blocks outermost, dk and dv over a
+    k block's q walk and the head's whole dq resident (half the bytes), where
+    ``dq_resident_applies`` (width 512 at 16,384, at the 1,024 x 512 tiles
+    ``one_pass_tiles`` gives a body that wide: 86 of the same 100 MiB); the
+    split dq / dk-dv pair — 7 + 2 — where neither side of a head
+    fits (width 512 at 65,536).  Until PR 68 the one pass
     wrote each pair's dq part to a float32 ``[bh, nk, s, d]`` buffer in HBM
     that XLA summed, and calls whose buffer passed 30% of the chip (width
     192 at 16,384: 6.4 GB) ran the pair.  Measured on a v5e, ms a call: bh
     32 x 16,384 x 192 / 128 70.82 (the pair) -> 49.00; 16 x 16,384 x 128
     20.58 (kernel 16.98 + the sum) -> 16.16 (kernel 15.75); 32 x 4,096 x 128
     2.89 -> 2.39 (PERF.md section 6, PR 68; ``scripts/kernel_parity.py
-    --only-flash-backward`` takes the two forms again).  Width 192 costs the
-    MXU what 256 does (whole 128-lane tiles): the call runs at ~93% of THAT
-    floor and 74% of the floor its FLOPs alone give.
+    --only-flash-backward`` takes the forms again); 16 x 16,384 x 512 85.43
+    (the pair) -> 59.61 with dq resident at 1,024 x 512 tiles (89.06 at
+    1,024 x 1,024: ``_ONE_PASS_BODY_CAP``; PERF.md section 6, PR 73).  Width
+    192 costs the MXU what 256 does (whole 128-lane tiles): the call runs at
+    ~93% of THAT floor and 74% of the floor its FLOPs alone give.
 
     What a cell the diagonal crosses scores (PR 55; ``_cell_parts``).  The
     kernels' time follows the pairs they SCORE (the forward runs at 54-56%

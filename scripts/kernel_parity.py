@@ -106,13 +106,18 @@ is) and, from the non-causal forward at k tiles of 1,024 against 2,048, a
 step's cost as ``us a 1,024 keys + us fixed``.
 
 An eleventh leg, run only by ``--only-flash-backward``, holds the flash
-BACKWARD's two forms — the one-pass kernel (``_bwd_flat_one_pass``: dq in VMEM
-over the k walk, dk / dv in head-resident VMEM, no partials in HBM) and the
-split dq / dk-dv pair (``_bwd_flat_split``) — at the shapes the train cells
-hand it (:data:`FLASH_BACKWARD_SHAPES`: causal, block-diffusion and windowed):
+BACKWARD's three forms (``flash_attention.BACKWARD_FORMS``) — the one-pass
+kernel with a head's dk / dv resident (``_bwd_flat_one_pass``: q blocks
+outermost, dq in VMEM over the k walk), the same with its dq resident (k
+blocks outermost, dk / dv over the q walk; no partials in HBM either way) and
+the split dq / dk-dv pair (``_bwd_flat_split``) — at the shapes the train
+cells hand it (:data:`FLASH_BACKWARD_SHAPES`: causal, block-diffusion and
+windowed), and which of them ``backward_form`` picks:
 each form's ms a call from the device trace, and dq, dk, dv of ONE head
-against the dense form's gradients in float32 ``highest``: the one pass
-inside :data:`TOLERANCE` and no further off than 1.25 x the split pair.
+against the dense form's gradients in float32 ``highest``: a one pass
+inside :data:`TOLERANCE` and no further off than 1.25 x the split pair.  A
+form Mosaic refuses at a shape (dk / dv resident at head width 512) is named
+under ``refused``, and fails the leg only where it is the form picked.
 
 Shapes: flash at the long-context recipe's per-chip shape (seq 16,384, head
 dim 128; two heads so the dense reference's [s, s] scores fit beside it);
@@ -1122,6 +1127,7 @@ def _flash_forward_leg(seq: int = 0, bisect: bool = False) -> bool:
 #: ``(the cells, batch x heads, positions, key width, value width, window,
 #: block-diffusion step)`` of the backward's calls in the train cells
 FLASH_BACKWARD_SHAPES = (
+    ("1b_long_context", 16, 16384, 512, 512, None, None),
     ("joyai_llm_flash, kimi_linear", 32, 16384, 192, 128, None, None),
     ("nemotron", 16, 16384, 128, 128, None, None),
     ("ouro", 32, 4096, 128, 128, None, None),
@@ -1131,7 +1137,7 @@ FLASH_BACKWARD_SHAPES = (
 
 
 def _flash_backward_leg(seq: int = 0) -> bool:
-    """The backward's two forms at its cells' shapes (``seq``: every shape
+    """The backward's three forms at its cells' shapes (``seq``: every shape
     at that many positions and two heads, a CPU rehearsal's size)."""
     import jax
     import jax.numpy as jnp
@@ -1176,18 +1182,25 @@ def _flash_backward_leg(seq: int = 0) -> bool:
                 do))(*(t[:1, :, None].astype(jnp.float32)
                        for t in (q, k, v, do)))
         want = [np.asarray(w[0, :, 0]) for w in want]
-        applies = fa.one_pass_applies(s, d, dv, blk, blk, 2)
+        one_pass = fa.one_pass_tiles(blk, blk, d, dv, True, window)
+        picked = fa.backward_form(s, s, d, dv, blk, blk, 2, window=window)
         line = {"kernel": "flash_bwd", "cells": cells,
                 "implementation": "pallas (interpret)" if interpret
                 else "pallas",
                 "shape": {"bh": bh, "s": s, "d_k": d, "d_v": dv,
                           "window": window, "step": step},
-                "tiles": [blk, blk], "one_pass_applies": bool(applies)}
+                "tiles": [blk, blk], "one_pass_tiles": list(one_pass),
+                "backward_form": picked}
+        forms = {"dkv_resident": (fa._bwd_flat_one_pass, one_pass),
+                 "dq_resident": (functools.partial(fa._bwd_flat_one_pass,
+                                                   dq_resident=True),
+                                 one_pass),
+                 "split": (fa._bwd_flat_split, (blk, blk))}
+        assert tuple(forms) == fa.BACKWARD_FORMS
         got, errs, ms = {}, {}, {}
-        for name, form in (("one_pass", fa._bwd_flat_one_pass),
-                           ("split", fa._bwd_flat_split)):
-            run = jax.jit(lambda *a, form=form: form(
-                *a, scale, True, blk, blk, interpret, window=window,
+        for name, (form, tiles) in forms.items():
+            run = jax.jit(lambda *a, form=form, tiles=tiles: form(
+                *a, scale, True, *tiles, interpret, window=window,
                 step=step))
             try:
                 timed = _kernel_ms(lambda: run(*args),
@@ -1202,16 +1215,17 @@ def _flash_backward_leg(seq: int = 0) -> bool:
                 n: float(np.abs(np.asarray(g[0], np.float32) - w).max()
                          / np.abs(w).max())
                 for n, g, w in zip(("dq", "dk", "dv"), got[name], want)}
-        good = "one_pass" in errs and "split" in errs and all(
+        good = picked in errs and "split" in errs and all(
             e <= TOLERANCE and e <= 1.25 * errs["split"][n] + 1e-6
-            for n, e in errs["one_pass"].items())
+            for name in errs if name != "split"
+            for n, e in errs[name].items())
         ok &= good
-        if len(got) == 2:
-            line["max_abs_diff_one_pass_to_split"] = {
-                n: float(jnp.max(jnp.abs(a.astype(jnp.float32)
-                                         - b.astype(jnp.float32))))
-                for n, a, b in zip(("dq", "dk", "dv"), got["one_pass"],
-                                   got["split"])}
+        line["max_abs_diff_to_split"] = {
+            name: {n: float(jnp.max(jnp.abs(a.astype(jnp.float32)
+                                            - b.astype(jnp.float32))))
+                   for n, a, b in zip(("dq", "dk", "dv"), got[name],
+                                      got["split"])}
+            for name in got if name != "split" and "split" in got}
         line.update({
             "ok": bool(good), "ms_a_call": ms,
             "ms_a_call_total": {n: round(sum(t.values()), 3)

@@ -62,10 +62,11 @@ def pytest_terminal_summary(terminalreporter):
 LONGEST_FIRST = (
     "olmo_hybrid_test.py", "kimi_linear_test.py", "laguna_test.py",
     "sala_test.py", "zaya_test.py", "granite_test.py",
-    "kda_rule_kernel_test.py", "nemotron_test.py", "ouro_test.py",
-    "pod_lowering_test.py", "distributed_test.py", "kernel_steps_test.py",
-    "chip_smoke_test.py", "flash_edge_cells_test.py", "remat_policy_test.py",
-    "olmoe_test.py", "flash_window_test.py", "pipeline_parallel_test.py")
+    "kda_rule_kernel_test.py", "flash_fused_bwd_test.py", "nemotron_test.py",
+    "ouro_test.py", "pod_lowering_test.py", "distributed_test.py",
+    "kernel_steps_test.py", "chip_smoke_test.py", "flash_edge_cells_test.py",
+    "remat_policy_test.py", "olmoe_test.py", "flash_window_test.py",
+    "pipeline_parallel_test.py")
 
 
 def pytest_collection_modifyitems(items):

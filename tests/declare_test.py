@@ -50,7 +50,8 @@ def _one_pass(scored, layers):
     """PR 68 added, after ``scored``'s part of the line, how many attention
     layers run their flash backward as the one-pass kernel
     (``hbnlp_flash_backward_one_pass_layers``; the long-context cell's
-    eight, at head width 512, are on the split pair: 0)."""
+    eight, at head width 512, were on the split pair, 0, until PR 73 held a
+    head's dq resident in place of its dk and dv)."""
     return (scored[0] + f"; flash backward one pass {layers} layers",
             {**scored[1], "hbnlp_flash_backward_one_pass_layers": layers})
 
@@ -79,7 +80,11 @@ _CELLS = {
     "train_32big_mixer_dp2tp2": (
         _kinds(bottleneck=(32, 2147483648)), "", {}),
     "train_1b_long_context_s16k": (
-        _kinds(attention=(8, 2155872256)), *_one_pass(_S16K, 0)),
+        # PR 73: the eight backwards are the one pass with dq resident, at k
+        # tiles of 512 (head width 512: ``one_pass_tiles``), so a diagonal
+        # cell pair scores 10 of its 16 sub-squares of 256
+        _kinds(attention=(8, 2155872256)),
+        *_one_pass(_scored(1.0625, 1.015625), 8)),
     "train_olmoe_1b_7b_s4k": (
         _kinds(attention=(2, 68157440), experts=(2, 1075315200)),
         *_one_pass(_S4K, 2)),
@@ -338,8 +343,17 @@ def _config_files():
 #: and, on a TPU, ``flash band 6 layers; flash scored over live pairs fwd
 #: 1.0625 bwd 1.06261; flash backward one pass 8 layers`` —: without them the
 #: digest is PR 71's abf9c04a11d6e8f83fd126478a6dd66bb2935c3c, every other
-#: line as it was)
-_FILE_DIGEST = "9e1f0d21625332039d01c4ad2166a4b66a736b4a"
+#: line as it was; PR 73: the one pass with a head's dq resident where its dk
+#: and dv do not fit, so the TPU side of four files moved —
+#: ``benchmark/configs/1b_long_context_d8.json`` reads ``flash backward one
+#: pass 8 layers`` (0) and ``bwd 1.01562`` (1.03125: k tiles of 512 at head
+#: width 512), and three published files leave the split pair,
+#: ``configs/1b_long_context_draft_247m.json`` (32,768 positions at head
+#: width 256) 26 layers, ``configs/olmo_hybrid_7b.json`` 8 and
+#: ``configs/ouro_2_6b.json`` 48 (65,536 at 128; 0 each) — and no CPU side:
+#: before it
+#: 9e1f0d21625332039d01c4ad2166a4b66a736b4a)
+_FILE_DIGEST = "66f33ddb4841058f7caace34ee98f2cd4fcb17b9"
 
 
 def every_configuration_file_starts_as_on_the_parent_test(monkeypatch):

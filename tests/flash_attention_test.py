@@ -141,16 +141,18 @@ def _two_width_inputs(s, d_k, d_v, heads=2, seed=17):
     return normal(d_k), normal(d_k), normal(d_v), normal(d_v)
 
 
-@pytest.mark.parametrize("fused", [True, False], ids=["one_pass", "split"])
+@pytest.mark.parametrize("form", fa.BACKWARD_FORMS)
 @pytest.mark.parametrize("window", [None, 96], ids=["causal", "window"])
 @pytest.mark.parametrize("d_k,d_v", [(192, 128), (64, 128)])
-def two_widths_match_the_dense_form_test(d_k, d_v, window, fused,
+def two_widths_match_the_dense_form_test(d_k, d_v, window, form,
                                          monkeypatch):
     """``q, k [.., d_k]``, ``v, out [.., d_v]``: the forward (tiled, and the
-    band under a window), the one-pass backward and the dq / dk-dv pair against
+    band under a window), the one-pass backward either way round and the dq /
+    dk-dv pair against
     ``_xla_reference`` — latent attention's 192 / 128, and the other way
     round."""
-    monkeypatch.setattr(fa, "one_pass_applies", lambda *a: fused)
+    monkeypatch.setattr(fa, "backward_form", lambda *a: form)
+    fused = form != "split"
     q, k, v, do = _two_width_inputs(256, d_k, d_v)
     scale = d_k ** -0.5
 
@@ -195,7 +197,7 @@ def a_precomputed_forward_at_two_widths_test():
 
 
 @pytest.mark.parametrize("heads,s,d,window,digest,precomputed", [
-    (16, 16384, 512, None, "a1fd20a5d5bb767d", "537f93e728be5233"),
+    (16, 16384, 512, None, "14b363877012f97f", "94808be272ac1ba3"),
     (16, 4096, 128, None, "37a48276d3ba36e8", "46c95c3d6eab8c0d"),
     (72, 8192, 128, 512, "3b03c62d29f38cfd", "2d9f785509c10db3")],
     ids=["long_context", "olmoe", "laguna_window"])
@@ -212,7 +214,10 @@ def equal_widths_are_the_parents_calls_test(heads, s, d, window, digest,
     fused backward's body, grid and maps at OLMoE's and Laguna's shapes (the
     one-pass kernel on a grid of its live cells: no dq partials; e9a9ebb4a3aae071 / aca9a0fc8d6e5f85 and
     7f26067a62d06811 / 5c106b71441b2e30 before); the long-context call, on
-    the split pair then and now, is letter for letter PR 66's."""
+    the split pair then, stayed letter for letter PR 66's until PR 73 MEANT
+    to move its backward (the one pass with a head's dq resident, at 1,024 x
+    512 tiles: a1fd20a5d5bb767d / 537f93e728be5233 before) — OLMoE's and
+    Laguna's, dk and dv resident, are PR 68's still."""
     q = jax.ShapeDtypeStruct((1, s, heads, d), jnp.bfloat16)
     blk, fwd_q, fwd_k, _ = fa.call_tiles(s, d, window, 2)
     assert fa.call_tiles(s, d, window, 2, d) == (blk, fwd_q, fwd_k, _)

@@ -20,18 +20,18 @@ def _edge_inputs(s, seed=11, dtype=np.float32):
     return dense_form.inputs(s, seed, dtype=dtype)
 
 
-@pytest.mark.parametrize("fused", [True, False], ids=["one_pass", "split"])
+@pytest.mark.parametrize("form", fa.BACKWARD_FORMS)
 @pytest.mark.parametrize("window", [None, 192], ids=["causal", "window"])
 @pytest.mark.parametrize("s", [512, 1024])
 @pytest.mark.parametrize("bq,bk", EDGE_TILES)
-def edge_cells_score_their_live_part_test(bq, bk, s, window, fused,
+def edge_cells_score_their_live_part_test(bq, bk, s, window, form,
                                           monkeypatch):
     """``out``, ``lse``, ``dq``, ``dk``, ``dv`` of the tiled kernels (the
     windowed forward on the tiled grid too) against the dense form and its
     autodiff, at tiles of several cells a side, so that every branch runs:
     the interior, each edge offset's parts, the dead cells."""
     q, k, v, do = _edge_inputs(s)
-    monkeypatch.setattr(fa, "one_pass_applies", lambda *a: fused)
+    monkeypatch.setattr(fa, "backward_form", lambda *a: form)
     monkeypatch.setattr(fa, "band_applies", lambda *a, **kw: False)
     # the pair ``flash_attention``'s ``custom_vjp`` runs, its forward once
     out, saved = fa._flash_fwd(q, k, v, 0.25, True, bq, bk, True, None, None,

@@ -63,15 +63,16 @@ def window_forward_matches_the_band_mask_test(s, window, bq, bk, band_form):
         rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("fused", [True, False], ids=["one_pass", "split"])
+@pytest.mark.parametrize("form", fa.BACKWARD_FORMS)
 @pytest.mark.parametrize("s,window,bq,bk", WINDOW_CASES)
-def window_backward_matches_the_band_mask_test(s, window, bq, bk, fused,
+def window_backward_matches_the_band_mask_test(s, window, bq, bk, form,
                                                monkeypatch, band_form):
     """The one-pass backward (dq in VMEM over the band's k walk, dk / dv in
-    the head's resident accumulators) and the split dq / dk-dv pair, both on
-    grids as long as the band, both on the ``lse`` the band forward wrote."""
+    the head's resident accumulators; or dk / dv over a k block's q walk, dq
+    resident) and the split dq / dk-dv pair, all on grids as long as the
+    band, all on the ``lse`` the band forward wrote."""
     q, k, v, do = _window_inputs(s)
-    monkeypatch.setattr(fa, "one_pass_applies", lambda *a: fused)
+    monkeypatch.setattr(fa, "backward_form", lambda *a: form)
     assert band_form == "band" and fa.band_applies(s, 16, window, 4)
     got = jax.vjp(lambda q, k, v: flash_attention(
         q, k, v, 0.25, True, bq, bk, True, None, None, window), q, k, v)[1](do)
@@ -128,9 +129,10 @@ def the_band_forward_is_the_windowed_call_test(monkeypatch):
         == (2, 16, 2)
 
 
-@pytest.mark.parametrize("fused,digest", [(True, "0395705bf82abe9c"),
-                                          (False, "aacc61b444fdd5fd")])
-def no_window_is_the_parents_call_test(fused, digest, monkeypatch):
+@pytest.mark.parametrize("form,digest", [("dkv_resident", "0395705bf82abe9c"),
+                                         ("dq_resident", "3783f201be06a2a0"),
+                                         ("split", "aacc61b444fdd5fd")])
+def no_window_is_the_parents_call_test(form, digest, monkeypatch):
     """``window=None`` traces to one call whether the argument is left out
     or given as None: the digests are of this call's jaxpr — kernel bodies,
     grids, block maps and names, source positions stripped — for the fused
@@ -141,8 +143,10 @@ def no_window_is_the_parents_call_test(fused, digest, monkeypatch):
     forward's alone (its row statistics lane-replicated: ff0effe0be83d952 /
     6c1cc156f207ebf9 before); PR 68 the fused backward's (the one-pass
     kernel on a grid of the live cells, no dq partials: d5bbd3f5dd0fee2e before),
-    the split pair's as it was."""
-    monkeypatch.setattr(fa, "one_pass_applies", lambda *a: fused)
+    the split pair's as it was; PR 73 added the one pass with dq resident and
+    left the other two as they were."""
+    monkeypatch.setattr(fa, "backward_form", lambda *a: form)
+    fused = form != "split"
     q = jax.ShapeDtypeStruct((1, 2048, 2, 128), jnp.bfloat16)
 
     def loss(q, k, v, *window):

@@ -91,12 +91,15 @@ def _pallas_eqns(jaxpr, found=None):
     return found
 
 
-@pytest.mark.parametrize("kernel", ["flash_fwd_causal",
-                                    "flash_bwd_fused_causal",
-                                    "flash_bwd_dq_causal",
-                                    "flash_bwd_dkv_causal"])
+@pytest.mark.parametrize("kernel,form", [
+    ("flash_fwd_causal", "split"),
+    ("flash_bwd_fused_causal", "dkv_resident"),
+    ("flash_bwd_fused_causal", "dq_resident"),
+    ("flash_bwd_dq_causal", "split"),
+    ("flash_bwd_dkv_causal", "split")])
 @pytest.mark.parametrize("s", [4096, 8192, 16384])
-def flash_executed_flops_follow_the_scored_pairs_test(s, kernel, monkeypatch):
+def flash_executed_flops_follow_the_scored_pairs_test(s, kernel, form,
+                                                      monkeypatch):
     """PR 55: a cell the diagonal crosses scores its live part only, and the
     counter follows the kernels' own geometry.  At ``attention``'s tiles,
     with ``n = s / 1024``: the forward (1,024 x 2,048; the cell that starts
@@ -105,13 +108,14 @@ def flash_executed_flops_follow_the_scored_pairs_test(s, kernel, monkeypatch):
     4,096 / 8,192 / 16,384, where its whole cells were 12 / 40 / 144 — and
     each backward kernel (1,024 x 1,024; a diagonal cell is three of its
     four quadrants) ``(2n + 1) / 4n``: 9 / 34 / 132 tiles for 10 / 36 / 136
-    cells.  ``full`` stays the full-square convention."""
+    cells — the one pass the same five matmuls a pair whichever side it
+    holds resident.  ``full`` stays the full-square convention."""
     from homebrewnlp_tpu.parallel import flash_attention as fa
     from homebrewnlp_tpu.utils.flops import (_StrippedJaxpr,
                                              count_matmul_flops_split)
     d, n = 128, s // 1024
-    fused = kernel == "flash_bwd_fused_causal"
-    monkeypatch.setattr(fa, "one_pass_applies", lambda *a: fused)
+    fused = form != "split"
+    monkeypatch.setattr(fa, "backward_form", lambda *a: form)
     x = jax.ShapeDtypeStruct((1, s, 1, d), jnp.bfloat16)
     blk, fwd_q, fwd_k, band = fa.call_tiles(s, d, None, 2)
     assert (blk, fwd_q, fwd_k, band) == (1024, 1024, 2048, False)

@@ -145,15 +145,15 @@ def conv_is_shifted_multiplies_test(width):
 
 # ---- grouped-query flash attention -------------------------------------------
 
-@pytest.mark.parametrize("fused", [True, False])
+@pytest.mark.parametrize("form", fa.BACKWARD_FORMS)
 @pytest.mark.parametrize("heads,kv_heads", [(8, 2), (4, 4), (4, 1)])
-def grouped_flash_matches_repeated_reference_test(heads, kv_heads, fused,
+def grouped_flash_matches_repeated_reference_test(heads, kv_heads, form,
                                                   monkeypatch):
     """Grouped queries as the standard attention runs them — K and V
     repeated over their group, then the multi-head kernels (interpret mode),
     autodiff summing dk and dv over the group: forward, dQ, dK, dV against
-    the dense form, one-pass and split backward."""
-    monkeypatch.setattr(fa, "one_pass_applies", lambda *a: fused)
+    the dense form, each form of the backward."""
+    monkeypatch.setattr(fa, "backward_form", lambda *a: form)
     rng = np.random.default_rng(heads * 10 + kv_heads)
     b, s, d = 2, 256, 32
     q, do = (jnp.asarray(rng.normal(size=(b, s, heads, d)).astype(np.float32))

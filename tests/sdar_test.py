@@ -273,20 +273,22 @@ def the_first_blocks_rows_see_their_own_block_only_test():
     assert np.all(np.isfinite(np.asarray(out)))
 
 
-@pytest.mark.parametrize("tiles,block,fused", [
-    ((64, 128), 4, True), ((64, 64), 4, False), ((128, 128), 8, True),
-    ((64, 128), 1, False)],
+@pytest.mark.parametrize("tiles,block,form", [
+    ((64, 128), 4, "dkv_resident"), ((64, 64), 4, "split"),
+    ((128, 128), 8, "dkv_resident"), ((64, 128), 1, "split"),
+    ((64, 64), 4, "dq_resident")],
     ids=["fwd_tile_twice_the_q_fused", "square_tiles_dq_and_dkv",
-         "block_8_fused", "block_1_dq_and_dkv"])
+         "block_8_fused", "block_1_dq_and_dkv", "square_tiles_dq_resident"])
 def blockdiff_kernels_match_the_dense_mask_test(monkeypatch, tiles, block,
-                                                fused):
+                                                form):
     """The ``flash_*_blockdiff`` kernels, interpreted, at 256 positions a half
     in several tiles — the forward with its carried state, the backward as
-    the fused pass and as the dq / dk-dv pair, under both cotangents (the
+    the fused pass (either side resident) and as the dq / dk-dv pair, under
+    both cotangents (the
     merge reads ``lse``) — merged with the own blocks: against ONE softmax
     under the dense mask, value and the three gradients.  1e-5: float32,
     summation order."""
-    monkeypatch.setattr(fa, "one_pass_applies", lambda *_: fused)
+    monkeypatch.setattr(fa, "backward_form", lambda *a: form)
     length, (bq, bk) = 256, tiles
     q2, k2, v2, w = _stream(7 + block, 1, length, 2, 16)
     mask = jnp.asarray(fa.block_diffusion_mask(length, block))
@@ -318,7 +320,7 @@ def blockdiff_kernels_match_the_dense_mask_test(monkeypatch, tiles, block,
             q2, k2, v2)
     calls = sorted(_pallas_calls(jaxpr.jaxpr, {}))
     assert calls == (["flash_bwd_fused_blockdiff", "flash_fwd_blockdiff"]
-                     if fused else ["flash_bwd_dkv_blockdiff",
+                     if form != "split" else ["flash_bwd_dkv_blockdiff",
                                     "flash_bwd_dq_blockdiff",
                                     "flash_fwd_blockdiff"])
 
