@@ -74,9 +74,7 @@ choice of keys a query, one for all heads) and ``block`` 64 (MiniCPM-SALA: 16
 over 1, ``model/sparse.py``'s own selection on the seeded operands).  Each
 kernel's ms a call from the device trace, its us a live cell and a 512 x 512
 cell's worth of pairs, and ``out`` / ``lse`` / dq / dk / dv of ONE query head
-against ``_xla_select_with_lse`` in float32 ``highest``.  ``--select-bisect``
-adds the forward with parts of its cell body swapped or taken out
-(:func:`_select_variant`; ISSUE 63's bisect: which part of a cell costs what).
+against ``_xla_select_with_lse`` in float32 ``highest``.
 
 A ninth leg, run only by ``--only-index-loss``, holds the learned indexer's
 index-loss kernel (``index_loss_pass`` of parallel/index_loss.py) ALONE at
@@ -89,9 +87,6 @@ against the XLA form in float32 ``highest``, the kernel no further off than
 :data:`INDEX_LOSS_ROOM` x what the XLA form is (or :data:`INDEX_LOSS_FLOOR`
 of the largest entry); and what a float32 ``dot`` at the default precision
 carries on the device (the parent's two gradient contractions).
-``--index-loss-bisect`` adds the kernel with parts of its cell body taken
-out, its other forms and other tiles (:func:`_index_loss_variant`; ISSUE 64's
-bisect: which unit binds).
 
 A tenth leg, run only by ``--only-flash-forward``, holds the causal flash
 FORWARD (``flash_fwd_causal``: ``_fwd_flat`` at ``call_tiles``' 1,024 x 2,048
@@ -99,11 +94,7 @@ tiles) ALONE at the six shapes the train cells hand it
 (:data:`FLASH_FORWARD_SHAPES`): its ms a call from the device trace, the
 online-softmax steps of a call and its us a step, and ``out`` / ``lse`` of one
 head against ``_xla_reference_with_lse`` in float32 ``highest`` inside
-:data:`TOLERANCE` / :data:`LSE_TOLERANCE`.  ``--flash-bisect`` adds the forward
-with the row statistics of its step held otherwise
-(:func:`_flash_forward_variant`; ISSUE 66's bisect: what a step's fixed cost
-is) and, from the non-causal forward at k tiles of 1,024 against 2,048, a
-step's cost as ``us a 1,024 keys + us fixed``.
+:data:`TOLERANCE` / :data:`LSE_TOLERANCE`.
 
 An eleventh leg, run only by ``--only-flash-backward``, holds the flash
 BACKWARD's three forms (``flash_attention.BACKWARD_FORMS``) — the one-pass
@@ -603,132 +594,6 @@ def _kernel_ms(run, pattern: str, calls: int = 3):
     return {k: round(v, 3) for k, v in total.items()}
 
 
-#: the forward's cell body as ISSUE 63's bisect varies it.  ``stats``: the
-#: row statistics as 1-D ``(tile,)`` scratch (the parent's), ``[tile, 1]``
-#: columns, lane-replicated ``[tile, 128]``, or none (``p = exp(s)``: a
-#: time, not a result); ``mask``: a select on the scores and one on ``p``
-#: (the parent's), or one select to ``-inf`` under a finite first maximum;
-#: ``unpack``: each word shifted out and masked (the parent's), each word
-#: against its row's bit, or every pair kept (a time, not a result);
-#: ``compare``: positions compared in every cell (the parent's), in the
-#: cells the diagonal crosses, or never (a time)
-SELECT_PARENT = {"stats": "1d", "mask": "two", "unpack": "shift",
-                 "compare": "every"}
-
-
-def _select_variant(name, tiles, stats, mask, unpack, compare):
-    """``(q, k, v, keep) -> out`` of the selected forward with the named
-    parts: the library's tables, specs and call round a cell body made
-    here."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    from homebrewnlp_tpu.parallel import flash_attention as fa
-
-    def forward(q, k, v, keep, scale, block):
-        b, s, h, d = q.shape
-        group, keep_group = h // k.shape[2], h // keep.shape[1]
-        tq, tk = tiles
-        num_q, num_k = s // tq, s // tk
-
-        def kept_of(keep_ref, kk):
-            if unpack == "none":
-                return None
-            if unpack == "and" and block == 1:
-                bit = jnp.left_shift(1, jax.lax.broadcasted_iota(
-                    jnp.int32, (fa.KEEP_WORD, tk), 0))
-                words = keep_ref[...]
-                return jnp.concatenate([
-                    jnp.broadcast_to(words[r:r + 1], (fa.KEEP_WORD, tk)) & bit
-                    for r in range(tq // fa.KEEP_WORD)], axis=0) != 0
-            return fa._select_kept(keep_ref, kk, tq, tk, block)
-
-        def kernel(fetch_ref, q_ref, k_ref, v_ref, keep_ref, o_ref, lse_ref,
-                   m_ref, l_ref, acc_ref):
-            i, qi, kk = (pl.program_id(n) for n in range(3))
-
-            @pl.when(kk == 0)
-            def _init():
-                m_ref[...] = jnp.full_like(m_ref, fa._NEG_INF)
-                l_ref[...] = jnp.zeros_like(l_ref)
-                acc_ref[...] = jnp.zeros_like(acc_ref)
-
-            def pv(p):
-                return jax.lax.dot_general(
-                    p.astype(v_ref.dtype), v_ref[...],
-                    (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-
-            def step(positions):
-                kept = kept_of(keep_ref, kk)
-                if positions:
-                    seen = (qi * tq + jax.lax.broadcasted_iota(
-                        jnp.int32, (tq, 1), 0)) >= (
-                        kk * tk + jax.lax.broadcasted_iota(
-                            jnp.int32, (1, tk), 1))
-                    kept = seen if kept is None else kept & seen
-                s_ = fa._make_score(q_ref, k_ref, scale)()
-                if kept is not None:
-                    s_ = jnp.where(kept, s_, -jnp.inf if mask == "inf"
-                                   else fa._NEG_INF)
-                if stats == "none":
-                    acc_ref[...] += pv(jnp.exp(s_))
-                    return
-                keepdims = stats != "1d"
-                m_prev = m_ref[...]
-                m_new = jnp.maximum(m_prev, s_.max(-1, keepdims=keepdims))
-                alpha = jnp.exp(m_prev - m_new)
-                p = jnp.exp(s_ - (m_new[:, None] if stats == "1d" else m_new
-                                  if stats == "col" else fa._lanes(m_new, tk)))
-                if mask == "two" and kept is not None:
-                    p = jnp.where(kept, p, 0.0)
-                l_ref[...] = l_ref[...] * alpha + p.sum(-1, keepdims=keepdims)
-                if stats == "1d":
-                    alpha = alpha[:, None]
-                elif stats == "lanes":
-                    alpha = fa._lanes(alpha, d)
-                acc_ref[...] = acc_ref[...] * alpha + pv(p)
-                m_ref[...] = m_new
-
-            live = fa._select_live(fetch_ref, i, qi, kk, num_q, num_k,
-                                   keep_group) == kk
-            if compare == "diagonal":
-                below = fa._causal_split(qi, kk, tq, tk)[1]
-                pl.when(live & below)(lambda: step(False))
-                pl.when(live & jnp.logical_not(below))(lambda: step(True))
-            else:
-                pl.when(live)(lambda: step(compare == "every"))
-
-            @pl.when(kk == num_k - 1)
-            def _finish():
-                m, l = m_ref[...], jnp.maximum(l_ref[...], 1e-30)
-                if stats == "1d":
-                    m, l = m[:, None], l[:, None]
-                elif stats == "lanes":
-                    m, l = m[:, :1], l[:, :1]
-                o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
-                lse_ref[...] = m + jnp.log(l)
-
-        rows, fetch_k, _ = fa._select_tables(keep, tq, tk, block)
-        q_spec, k_spec, keep_spec = fa._select_specs(
-            tq, tk, block, d, num_q, num_k, group, False, keep_group)
-        stat = {"1d": (tq,), "lanes": (tq, fa._STAT_LANES)}.get(stats,
-                                                                (tq, 1))
-        out, _ = fa._select_call(
-            kernel, name, (b * h, num_q, num_k),
-            [q_spec(d), k_spec, k_spec, keep_spec], [q_spec(d), q_spec(1)],
-            [jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
-             jax.ShapeDtypeStruct((b * h, s, 1), jnp.float32)],
-            [pltpu.VMEM(stat, jnp.float32), pltpu.VMEM(stat, jnp.float32),
-             pltpu.VMEM((tq, d), jnp.float32)],
-            jax.devices()[0].platform == "cpu",
-            (fetch_k, fa._flat(q), fa._flat(k), fa._flat(v), rows))
-        return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
-    return forward
-
-
 def _select_choice(block: int, q, k, topk: int, seed: int):
     """The choice a cell's layer would hand the kernels on seeded operands:
     ``block`` 1, ``model/indexer.py top_keys`` over seeded scores (exactly
@@ -766,8 +631,7 @@ def _live_cells(keep, tiles, block: int, heads: int) -> int:
 
 
 def _select_leg(block: int, s: int, heads: int, kv_heads: int, d: int = 128,
-                topk: int = 2048, bisect: bool = False,
-                other_tiles=()) -> bool:
+                topk: int = 2048, other_tiles=()) -> bool:
     """The three selected kernels of one form at its cell's shape: ms a call
     by the device trace, us a live cell, and one query head against the
     dense masked form in float32."""
@@ -846,60 +710,6 @@ def _select_leg(block: int, s: int, heads: int, kv_heads: int, d: int = 128,
                               "refused": repr(e)[:400]}), flush=True)
         finally:
             fa.select_tile = chosen
-    if not bisect:
-        return bool(ok)
-    # the forward with parts of its body swapped or taken out, as PR 63 ran
-    # them (PERF.md section 6 has the table); the library's own body is
-    # lane statistics, one mask, the compare in every cell
-    parent = SELECT_PARENT
-    final = {"stats": "col", "mask": "inf", "unpack": "shift",
-             "compare": "diagonal"}
-    fwd = jax.jit(lambda q, k, v: fa._select_fwd_impl(
-        q, k, v, keep, scale, block, interpret))
-    tq = min(tq, 512)
-    square, wide = (tq, tq), (tq, min(2 * tq, s))
-    variants = [
-        ("parent", square, parent),
-        ("no_compare", square, {**parent, "compare": "none"}),
-        ("no_stats", square, {**parent, "stats": "none"}),
-        ("no_unpack_no_mask", square, {**parent, "unpack": "none",
-                                       "compare": "none"}),
-        ("col_stats", square, {**parent, "stats": "col"}),
-        ("lane_stats", square, {**parent, "stats": "lanes"}),
-        ("one_mask", square, {**parent, "stats": "col", "mask": "inf"}),
-        ("diagonal_compare", square, final),
-        ("and_unpack", square, {**final, "unpack": "and"}),
-        ("wide", wide, final),
-        ("wide_lane_stats", wide, {**final, "stats": "lanes"}),
-        ("wide_and_unpack", wide, {**final, "unpack": "and"}),
-        ("wide_parent_body", wide, parent),
-        ("wider", (tq, 4 * tq), final),
-        ("tall_wide", (2 * tq, 2 * tq), final),
-        ("short_wider", (tq // 2, 4 * tq), final),
-    ]
-    want_out = jax.block_until_ready(fwd(q, k, v))[0].astype(jnp.float32)
-    for name, tiles, parts in variants:
-        if s % tiles[1] or (block != 1 and (
-                tiles != square or parts["unpack"] == "and")):
-            continue
-        kernel_name = f"select_fwd_{name}"
-        try:
-            run = jax.jit(functools.partial(_select_variant(
-                kernel_name, tiles, **parts), scale=scale, block=block))
-            ms = _kernel_ms(lambda: run(q, k, v, keep), kernel_name)
-            err = float(jnp.max(jnp.abs(run(q, k, v, keep).astype(
-                jnp.float32) - want_out)))
-        except Exception as e:  # Mosaic refuses a layout: a finding
-            print(json.dumps({"variant": name, "refused": repr(e)[:400]}),
-                  flush=True)
-            continue
-        cells = _live_cells(keep, tiles, block, heads)
-        t_ms = ms.get(kernel_name, ms["wall"])
-        print(json.dumps({
-            "variant": name, "block": block, "tiles": list(tiles), **parts,
-            "ms_a_call": t_ms, "us_a_512x512_of_pairs": round(
-                t_ms * 1000 / (cells * tiles[0] * tiles[1] / 512 ** 2), 3),
-            "max_abs_diff_to_the_library": err}), flush=True)
     return bool(ok)
 
 
@@ -912,118 +722,14 @@ FLASH_FORWARD_SHAPES = (
     ("ouro", 32, 4096, 128, 128),
     ("1b_long_context", 16, 16384, 512, 512),
     ("granite", 32, 8192, 64, 64))
-#: the row statistics of the forward's step as ISSUE 66's bisect varies them:
-#: 1-D ``(rows,)`` scratch widened at every use (the parent's), ``[rows, 1]``
-#: columns, lane-replicated ``[rows, 128]`` (the library's), or none (``p =
-#: exp(s)``: a time, not a result)
-FLASH_FORWARD_STATS = ("1d", "col", "lanes", "none")
-
-
-def _flash_forward_variant(name, stats, causal=True, block_k=None):
-    """``(q, k, v [bh, s, d], scale) -> (out, lse)`` of the tiled forward
-    with the named statistics: the library's tiles, grid, maps, branches
-    (``_masked_step``) and scores round an online-softmax step made here."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    from homebrewnlp_tpu.parallel import flash_attention as fa
-
-    keepdims = stats != "1d"
-
-    def wide(x, width):
-        return x[:, None] if stats == "1d" else fa._lanes(x, width) \
-            if stats == "lanes" else x
-
-    def forward(q, k, v, scale):
-        bh, s, d = q.shape
-        dv = v.shape[-1]
-        _, bq, bk, _ = fa.call_tiles(s, d, None, q.dtype.itemsize, dv)
-        bk = min(block_k or bk, s)
-        num_k = s // bk
-
-        def kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
-                   acc_ref):
-            qi, kk = pl.program_id(1), pl.program_id(2)
-
-            @pl.when(kk == 0)
-            def _init():
-                fa._softmax_init(m_ref, l_ref, acc_ref)
-
-            score = fa._make_score(q_ref, k_ref, scale)
-
-            def step(rows, cols, mask):
-                r = slice(*rows)
-                s_ = score(rows, cols)
-                if mask is not None:
-                    s_ = mask(s_)
-
-                def pv(p):
-                    return jax.lax.dot_general(
-                        p.astype(v_ref.dtype), v_ref[slice(*cols)],
-                        (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32)
-
-                if stats == "none":
-                    acc_ref[r] += pv(jnp.exp(s_))
-                    return
-                m_prev = m_ref[r]
-                m_new = jnp.maximum(m_prev, s_.max(-1, keepdims=keepdims))
-                alpha = jnp.exp(m_prev - m_new)
-                p = jnp.exp(s_ - wide(m_new, s_.shape[-1]))
-                l_ref[r] = l_ref[r] * alpha + p.sum(-1, keepdims=keepdims)
-                acc_ref[r] = acc_ref[r] * wide(alpha, dv) + pv(p)
-                m_ref[r] = m_new
-
-            fa._masked_step(qi, kk, bq, bk, causal, step, carried=True,
-                            width=d)
-
-            @pl.when(kk == num_k - 1)
-            def _finish():
-                if stats == "none":
-                    o_ref[...] = acc_ref[...].astype(o_ref.dtype)
-                    lse_ref[...] = jnp.zeros_like(lse_ref)
-                    return
-                m, l = m_ref[...], jnp.maximum(l_ref[...], 1e-30)
-                o_ref[...] = (acc_ref[...] / wide(l, dv)).astype(o_ref.dtype)
-                lse = m + jnp.log(l)
-                lse_ref[...] = lse[:, None] if stats == "1d" else lse[:, :1]
-
-        stat = {"1d": (bq,), "lanes": (bq, fa._STAT_LANES)}.get(stats,
-                                                                 (bq, 1))
-        kmap = fa._frontier_kv_map(bq, bk, causal)
-        out, lse = pl.pallas_call(
-            kernel, grid=(bh, s // bq, num_k),
-            in_specs=[pl.BlockSpec((None, bq, d), lambda i, j, kk: (i, j, 0)),
-                      pl.BlockSpec((None, bk, d), kmap),
-                      pl.BlockSpec((None, bk, dv), kmap)],
-            out_specs=[pl.BlockSpec((None, bq, dv),
-                                    lambda i, j, kk: (i, j, 0)),
-                       pl.BlockSpec((None, bq, 1),
-                                    lambda i, j, kk: (i, j, 0))],
-            out_shape=[jax.ShapeDtypeStruct((bh, s, dv), q.dtype),
-                       jax.ShapeDtypeStruct((bh, s, 1), jnp.float32)],
-            scratch_shapes=[pltpu.VMEM(stat, jnp.float32),
-                            pltpu.VMEM(stat, jnp.float32),
-                            pltpu.VMEM((bq, dv), jnp.float32)],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary"),
-                vmem_limit_bytes=fa._KERNEL_VMEM_BUDGET),
-            name=name, interpret=jax.devices()[0].platform == "cpu",
-        )(q, k, v)
-        return out, lse[..., 0]
-    return forward
-
-
-def _forward_steps(bh: int, s: int, bq: int, bk: int, causal=True) -> int:
-    """The online-softmax steps of a call: its live cells."""
+def _forward_steps(bh: int, s: int, bq: int, bk: int) -> int:
+    """The online-softmax steps of a causal call: its live cells."""
     bq, bk = min(bq, s), min(bk, s)
     return bh * sum(1 for qi in range(s // bq) for ki in range(s // bk)
-                    if not causal or ki * bk <= qi * bq + bq - 1)
+                    if ki * bk <= qi * bq + bq - 1)
 
 
-def _flash_forward_leg(seq: int = 0, bisect: bool = False) -> bool:
+def _flash_forward_leg(seq: int = 0) -> bool:
     """The causal forward alone at its cells' shapes (``seq``: every shape
     at that many positions and two heads, a CPU rehearsal's size)."""
     import jax
@@ -1077,50 +783,6 @@ def _flash_forward_leg(seq: int = 0, bisect: bool = False) -> bool:
             "max_err_over_max_ref": {n: round(e, 7) for n, e in errs.items()},
             "tolerance": TOLERANCE, "lse_tolerance": LSE_TOLERANCE,
             "dtype": "bfloat16"}), flush=True)
-        if not bisect:
-            continue
-        for stats in FLASH_FORWARD_STATS:
-            name = f"fwd_stats_{stats}"
-            try:
-                run = jax.jit(functools.partial(_flash_forward_variant(
-                    name, stats), scale=scale))
-                v_ms, v_us = timed(name, run, steps, q, k, v)
-                got, got_lse = run(q, k, v)
-                diff = {"out": float(jnp.max(jnp.abs(
-                    got.astype(jnp.float32) - out.astype(jnp.float32)))),
-                    "lse": float(jnp.max(jnp.abs(got_lse - lse)))}
-            except Exception as e:  # Mosaic refuses a layout: a finding
-                print(json.dumps({"variant": name, "cells": cells,
-                                  "refused": repr(e)[:400]}), flush=True)
-                continue
-            print(json.dumps({
-                "variant": name, "cells": cells, "ms_a_call": v_ms,
-                "us_a_step": v_us,
-                "us_a_step_over_the_library": round(v_us - us, 3),
-                "max_abs_diff_to_the_library": diff}), flush=True)
-    if not bisect:
-        return bool(ok)
-    # a step as ``per x (keys / 1,024) + fixed``, PR 55's way: the NON-causal
-    # forward (every cell whole, no mask) at k tiles of 1,024 against 2,048
-    for bh, s, d in ((32, 4096, 128), (16, 16384, 512)):
-        if seq:
-            bh, s = min(bh, 2), max(seq, 2048)
-        scale = d ** -0.5
-        q, k, v = operands(bh, s, d, d, seed=55)
-        for stats in ("lanes", "1d"):
-            us = {}
-            for bk in (1024, 2048):
-                name = f"fwd_flat_{stats}_k{bk}"
-                run = jax.jit(functools.partial(_flash_forward_variant(
-                    name, stats, causal=False, block_k=bk), scale=scale))
-                us[bk] = timed(name, run, _forward_steps(
-                    bh, s, 1024, bk, causal=False), q, k, v)[1]
-            per = us[2048] - us[1024]
-            print(json.dumps({
-                "step_cost": stats, "shape": {"bh": bh, "s": s, "d": d},
-                "us_a_step_at_k1024": us[1024], "us_a_step_at_k2048": us[2048],
-                "us_a_1024_keys": round(per, 3),
-                "us_fixed": round(us[1024] - per, 3)}), flush=True)
     return bool(ok)
 
 
@@ -1239,215 +901,6 @@ def _flash_backward_leg(seq: int = 0) -> bool:
     return bool(ok)
 
 
-#: the index-loss kernel's cell body as ISSUE 64's bisect varies it.
-#: ``pbar``: the 32 heads' probabilities whole, without their ``exp`` (a
-#: time, not a result), or not at all; ``backward``: the index heads'
-#: backward whole, without its two matmuls, or not at all; ``normaliser``:
-#: the scores' ``logsumexp`` by a sweep of its own (the library's form (a)),
-#: by a sweep that also leaves the q tile's whole score ROW in VMEM for the
-#: second to read (form (b)), or none (a time); ``stack``: one matmul a K/V
-#: group and one for all index heads, or one a head; ``rows``: the
-#: elementwise passes over a cell's planes whole (0), or so many query rows
-#: at a time with the running sums of a block of rows carried through the
-#: heads (the same operations in another order: a block's accumulator is 32
-#: vregs at 64 rows)
-INDEX_LOSS_PARTS = {"pbar": "full", "backward": "full", "normaliser": "sweep",
-                    "stack": True, "rows": 0}
-
-
-def _index_loss_variant(name, tiles, pbar, backward, normaliser, stack,
-                        rows=0, interpret=None):
-    """``parallel/index_loss.py index_loss_pass`` with parts of its cell body
-    swapped or taken out (``INDEX_LOSS_PARTS``), as a kernel named ``name``:
-    the same operands, grid, table and results."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    from homebrewnlp_tpu.parallel import flash_attention as fa
-    from homebrewnlp_tpu.parallel import index_loss as il
-
-    tq, tk = tiles
-    lanes = min(128, tk)
-    row = normaliser == "row"
-
-    def kernel(qi_ref, sweep_ref, kk_ref, q_ref, k_ref, qx_ref, kx_ref, w_ref,
-               lse_ref, keep_ref, val_ref, top_ref, dq_ref, gk_ref, dw_ref,
-               lse_rep, w_rep, m_ref, l_ref, norm_ref, dw_acc, val_acc,
-               top_acc, dq_acc, *row_ref, heads, group, index_heads, scale,
-               i_scale, inv_rows, **_):
-        t = pl.program_id(1)
-        qi, sweep, kk = qi_ref[t], sweep_ref[t], kk_ref[t]
-        last = (qi * tq + tq - 1) // tk
-        f = q_ref.shape[1] // heads
-        d = kx_ref.shape[1]
-
-        @pl.when(t == 0)
-        def _first():
-            gk_ref[...] = jnp.zeros_like(gk_ref)
-
-        @pl.when((sweep == 0) & (kk == 0))
-        def _start():
-            for h in range(heads):
-                lse_rep[h] = jnp.broadcast_to(lse_ref[h:h + 1, :],
-                                              (lanes, tq)).T
-            for j in range(index_heads):
-                w_rep[j] = jnp.broadcast_to(w_ref[j:j + 1, :] * i_scale,
-                                            (lanes, tq)).T
-            m_ref[...] = jnp.full_like(m_ref, fa._NEG_INF)
-            for ref in (l_ref, dw_acc, val_acc, top_acc, dq_acc, norm_ref):
-                ref[...] = jnp.zeros_like(ref)
-
-        seen = fa._select_seen(keep_ref, qi, kk, tq, tk, 1)
-        nt = (((1,), (1,)), ((), ()))
-
-        def raw_scores():
-            if stack:
-                return jax.lax.dot_general(
-                    qx_ref[...].reshape(index_heads * tq, d), kx_ref[...],
-                    nt, preferred_element_type=jnp.float32)
-            return jnp.concatenate([jax.lax.dot_general(
-                qx_ref[j], kx_ref[...], nt,
-                preferred_element_type=jnp.float32)
-                for j in range(index_heads)], axis=0)
-
-        blocks = [(r0, rows or tq) for r0 in range(0, tq, rows or tq)]
-
-        def weighted(raw):
-            parts = []
-            for r0, n in blocks:
-                total = jnp.zeros((n, tk), jnp.float32)
-                for j in range(index_heads):
-                    total = total + il._lanes(w_rep[j, r0:r0 + n], tk) \
-                        * jnp.maximum(raw[j * tq + r0:j * tq + r0 + n], 0.0)
-                parts.append(total)
-            return parts[0] if len(parts) == 1 else jnp.concatenate(parts, 0)
-
-        if normaliser != "none":
-            @pl.when(sweep == 0)
-            def _normaliser():
-                score = weighted(raw_scores())
-                if row:
-                    row_ref[0][:, pl.ds(pl.multiple_of(kk * tk, tk), tk)] \
-                        = score
-                score = jnp.where(seen, score, -jnp.inf)
-                m_prev = m_ref[...]
-                m_new = jnp.maximum(m_prev, il._fold(score, lanes,
-                                                     jnp.maximum))
-                l_new = l_ref[...] * jnp.exp(m_prev - m_new) + il._fold(
-                    jnp.exp(score - il._lanes(m_new, tk)), lanes, jnp.add)
-                m_ref[...] = m_new
-                l_ref[...] = l_new
-
-                @pl.when(kk == last)
-                def _merge():
-                    big = jnp.broadcast_to(m_new.max(-1, keepdims=True),
-                                           (tq, lanes))
-                    total = jnp.sum(l_new * jnp.exp(m_new - big), -1,
-                                    keepdims=True)
-                    norm_ref[...] = big + jnp.log(jnp.maximum(
-                        jnp.broadcast_to(total, (tq, lanes)), 1e-30))
-
-        @pl.when(sweep == 1)
-        def _loss():
-            logits = {}
-            for kv in range(heads // group if pbar != "none" else 0):
-                members = range(kv * group, (kv + 1) * group)
-                k_head = k_ref[:, kv * f:(kv + 1) * f]
-                if stack:
-                    whole = jax.lax.dot_general(
-                        jnp.concatenate([q_ref[:, h * f:(h + 1) * f]
-                                         for h in members], axis=0),
-                        k_head, nt, preferred_element_type=jnp.float32)
-                for r, h in enumerate(members):
-                    logits[h] = whole[r * tq:(r + 1) * tq] if stack else \
-                        jax.lax.dot_general(
-                            q_ref[:, h * f:(h + 1) * f], k_head, nt,
-                            preferred_element_type=jnp.float32)
-            parts = []
-            for r0, n in blocks:
-                total = jnp.zeros((n, tk), jnp.float32)
-                for h, plane in logits.items():
-                    part = plane[r0:r0 + n] * scale - il._lanes(
-                        lse_rep[h, r0:r0 + n], tk)
-                    total = total + (jnp.exp(part) if pbar == "full"
-                                     else part)
-                parts.append(total)
-            mean = parts[0] if len(parts) == 1 else jnp.concatenate(parts, 0)
-            mean = jnp.where(seen, mean, 0.0) * (1.0 / heads)
-            raw = raw_scores()
-            score = row_ref[0][:, pl.ds(pl.multiple_of(kk * tk, tk), tk)] \
-                if row else weighted(raw)
-            log_index = score - il._lanes(norm_ref[...], tk)
-            val_acc[...] += il._fold(jnp.where(mean > 0, mean * (jnp.log(
-                jnp.maximum(mean, 1e-38)) - log_index), 0.0), lanes, jnp.add)
-            top_acc[...] = jnp.maximum(top_acc[...], il._fold(
-                jnp.where(seen, jnp.abs(score), 0.0), lanes, jnp.maximum))
-            d_score = jnp.where(seen, jnp.exp(log_index) - mean, 0.0) \
-                * inv_rows
-            if backward != "none":
-                planes = [[] for _ in range(index_heads)]
-                for r0, n in blocks:
-                    d_block = d_score[r0:r0 + n]
-                    for j in range(index_heads):
-                        raw_j = raw[j * tq + r0:j * tq + r0 + n]
-                        dw_acc[j, r0:r0 + n] += il._fold(
-                            d_block * jnp.maximum(raw_j, 0.0), lanes, jnp.add)
-                        planes[j].append(jnp.where(
-                            raw_j > 0, d_block * il._lanes(
-                                w_rep[j, r0:r0 + n], tk), 0.0
-                        ).astype(kx_ref.dtype))
-                d_logits = [x[0] if len(x) == 1 else jnp.concatenate(x, 0)
-                            for x in planes]
-                keys = pl.ds(pl.multiple_of(kk * tk, tk), tk)
-                if backward == "no_matmul":
-                    # the planes are made and read, no matmul eats them
-                    dw_acc[0] += il._fold(sum(
-                        x.astype(jnp.float32) for x in d_logits), lanes,
-                        jnp.add)
-                elif stack:
-                    d_logits = jnp.concatenate(d_logits, axis=0)
-                    dq_acc[...] += jax.lax.dot_general(
-                        d_logits, kx_ref[...], (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32
-                    ).reshape(index_heads, tq, d)
-                    gk_ref[keys, :] += jax.lax.dot_general(
-                        d_logits, qx_ref[...].reshape(index_heads * tq, d),
-                        (((0,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32)
-                else:
-                    for j, plane in enumerate(d_logits):
-                        dq_acc[j] += jax.lax.dot_general(
-                            plane, kx_ref[...], (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-                        gk_ref[keys, :] += jax.lax.dot_general(
-                            plane, qx_ref[j], (((0,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-            else:
-                dw_acc[0] += il._fold(d_score, lanes, jnp.add)
-
-            @pl.when(kk == last)
-            def _finish():
-                fold = (tq // val_ref.shape[0], val_ref.shape[0], lanes)
-                val_ref[...] = val_acc[...].reshape(fold).sum(0)
-                top_ref[...] = top_acc[...].reshape(fold).max(0)
-                for j in range(index_heads):
-                    dw_ref[j:j + 1, :] = jnp.sum(
-                        dw_acc[j].T, axis=0, keepdims=True) * i_scale
-                    dq_ref[:, j * d:(j + 1) * d] = dq_acc[j]
-
-    def run(*operands, scale):
-        s = operands[3].shape[1]
-        return il._call(
-            kernel, name, *operands, scale, tiles,
-            jax.devices()[0].platform == "cpu" if interpret is None
-            else interpret,
-            more_scratch=((tq, s),) if row else (),
-            vmem_limit=100 * 1024 * 1024)
-    return run
-
-
 def _module_ms(run, calls: int = 3) -> float:
     """ms a call of ``run()``'s whole device program by the device trace's
     module line (the host's clock on the CPU, whose trace holds no device)."""
@@ -1531,7 +984,7 @@ def _dot_precision_probe(d_logits, k_index):
 
 def _index_loss_leg(s: int = 16384, heads: int = 32, kv_heads: int = 4,
                     f: int = 128, index_heads: int = 16, d: int = 64,
-                    topk: int = 2048, bisect: bool = False) -> bool:
+                    topk: int = 2048) -> bool:
     """The index-loss kernel ALONE at the Keye-VL-2.0 cell's shape beside the
     XLA form: each's ms a call by the device trace, and the five outputs of
     both against the XLA form in float32 ``highest``."""
@@ -1584,52 +1037,6 @@ def _index_loss_leg(s: int = 16384, heads: int = 32, kv_heads: int = 4,
         "ms_a_call": ms, "float32_dot_at_default_precision": probe,
         "shapes": [list(t.shape) for t in operands],
         "dtype": "bfloat16"}), flush=True)
-    if not bisect:
-        return bool(ok)
-    # the kernel with parts of its body taken out or swapped, and at other
-    # tiles (PERF.md section 6, PR 64, has the table)
-    parts = INDEX_LOSS_PARTS
-    tq, tk = tiles
-    variants = [
-        ("library_body", tiles, parts),
-        ("no_pbar_exp", tiles, {**parts, "pbar": "no_exp"}),
-        ("no_pbar", tiles, {**parts, "pbar": "none"}),
-        ("no_backward_matmuls", tiles, {**parts, "backward": "no_matmul"}),
-        ("no_backward", tiles, {**parts, "backward": "none"}),
-        ("no_normaliser", tiles, {**parts, "normaliser": "none"}),
-        ("score_row_in_vmem", tiles, {**parts, "normaliser": "row"}),
-        ("a_matmul_a_head", tiles, {**parts, "stack": False}),
-        ("wide", (tq, 2 * tk), parts),
-        ("tall", (2 * tq, tk), parts),
-        ("tall_a_matmul_a_head", (2 * tq, tk), {**parts, "stack": False}),
-        ("narrow", (tq, tk // 2), parts),
-        ("rows_64", tiles, {**parts, "rows": min(64, tq)}),
-        ("rows_32", tiles, {**parts, "rows": min(32, tq)}),
-        ("rows_64_a_matmul_a_head", tiles, {**parts, "rows": min(64, tq),
-                                            "stack": False}),
-        ("tall_rows_64", (2 * tq, tk), {**parts, "rows": min(64, tq)}),
-    ]
-    library = [np.asarray(x, np.float32) for x in kernel(*operands)]
-    for name, shape, chosen in variants:
-        if s % shape[0] or s % shape[1]:
-            continue
-        kernel_name = f"index_loss_{name}"
-        try:
-            run = jax.jit(functools.partial(_index_loss_variant(
-                kernel_name, shape, **chosen), scale=scale))
-            ms = _kernel_ms(lambda: run(*operands), kernel_name)
-            diff = {n: float(np.max(np.abs(np.asarray(x, np.float32) - w))
-                             / max(np.max(np.abs(w)), 1e-30))
-                    for n, x, w in zip(names, run(*operands), library)}
-        except Exception as e:  # Mosaic refuses a shape: a finding
-            print(json.dumps({"variant": name, "refused": repr(e)[:400]}),
-                  flush=True)
-            continue
-        print(json.dumps({
-            "variant": name, "tiles": list(shape), **chosen,
-            "ms_a_call": ms.get(kernel_name, ms["wall"]),
-            "max_diff_to_the_library_over_its_max": {
-                n: round(v, 7) for n, v in diff.items()}}), flush=True)
     return bool(ok)
 
 
@@ -1655,10 +1062,6 @@ def main(argv=None) -> int:
     ap.add_argument("--only-index-loss", action="store_true",
                     help="run the index-loss kernel's leg alone, at the "
                          "Keye-VL-2.0 cell's shape")
-    ap.add_argument("--index-loss-bisect", action="store_true",
-                    help="with --only-index-loss: also time the kernel with "
-                         "parts of its cell body taken out and its other "
-                         "forms")
     ap.add_argument("--select-seq", type=int, default=16384)
     ap.add_argument("--only-select", action="store_true",
                     help="run the selected flash kernels' leg alone, both "
@@ -1679,13 +1082,6 @@ def main(argv=None) -> int:
     ap.add_argument("--flash-backward-seq", type=int, default=0,
                     help="with --only-flash-backward: every shape at this "
                          "many positions and two heads (a CPU rehearsal)")
-    ap.add_argument("--flash-bisect", action="store_true",
-                    help="with --only-flash-forward: also time the forward "
-                         "with its step's row statistics held otherwise, and "
-                         "a step's cost a 1,024 keys and fixed")
-    ap.add_argument("--select-bisect", action="store_true",
-                    help="with --only-select: also time the forward with "
-                         "parts of its cell body swapped or taken out")
     args = ap.parse_args(argv)
 
     import jax
@@ -1695,12 +1091,11 @@ def main(argv=None) -> int:
     from homebrewnlp_tpu.parallel import map_mixer
 
     if args.only_index_loss:
-        ok = _index_loss_leg(args.index_loss_seq,
-                             bisect=args.index_loss_bisect)
+        ok = _index_loss_leg(args.index_loss_seq)
         print(json.dumps({"ok": bool(ok)}), flush=True)
         return 0 if ok else 1
     if args.only_flash_forward:
-        ok = _flash_forward_leg(args.flash_forward_seq, args.flash_bisect)
+        ok = _flash_forward_leg(args.flash_forward_seq)
         print(json.dumps({"ok": bool(ok)}), flush=True)
         return 0 if ok else 1
     if args.only_flash_backward:
@@ -1710,10 +1105,8 @@ def main(argv=None) -> int:
     if args.only_select:
         tiles = [tuple(int(n) for n in pair.split("x"))
                  for pair in args.select_tiles.split(",") if pair]
-        ok = _select_leg(1, args.select_seq, 32, 4,
-                         bisect=args.select_bisect, other_tiles=tiles)
-        ok &= _select_leg(64, args.select_seq, 16, 1,
-                          bisect=args.select_bisect, other_tiles=tiles)
+        ok = _select_leg(1, args.select_seq, 32, 4, other_tiles=tiles)
+        ok &= _select_leg(64, args.select_seq, 16, 1, other_tiles=tiles)
         print(json.dumps({"ok": bool(ok)}), flush=True)
         return 0 if ok else 1
     if args.only_scan or args.only_rule or args.only_kda_rule:

@@ -62,6 +62,7 @@ def _inputs(batch, s, channels, taps, dtype, seed=0):
 # (sequence, the sequence tile's cap, a piece's lanes): one tile of one piece
 # (the zeros before position 0); every edge of eight tiles, in both
 # directions; one tile of four pieces; two tiles of two pieces
+@pytest.mark.optimised
 @pytest.mark.parametrize("s,cap,lanes", [
     (128, 2048, 512), (1024, 128, 512), (512, 512, 128), (512, 256, 128)])
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])

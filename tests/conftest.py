@@ -13,6 +13,13 @@ flags = os.environ.get("XLA_FLAGS", "")
 os.environ["JAX_PLATFORMS"] = "cpu"
 if "host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = flags + " --xla_force_host_platform_device_count=8"
+# PR 74: a toy program's seconds are LLVM's, not its own.  With jax's own
+# ``jax_disable_most_optimizations`` (XLA:CPU's backend level 0) the same
+# tests compile for two thirds of the CPU-seconds (tests/kimi_linear_test.py
+# alone: 369 -> 254) and run as long as before; a test that needs LLVM's
+# whole pipeline says so (``optimised`` below).  What is compiled for a
+# described v5e is libtpu's own and reads no such flag
+os.environ.setdefault("JAX_DISABLE_MOST_OPTIMIZATIONS", "1")
 
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
@@ -21,6 +28,28 @@ import pytest  # noqa: E402
 @pytest.fixture
 def rng():
     return np.random.default_rng(0)
+
+
+def pytest_configure(config):
+    config.addinivalue_line(
+        "markers", "optimised: compile this test's programs with XLA:CPU's "
+        "whole pipeline: it holds two programs to the same BITS, or an "
+        "interpreted kernel to its XLA form inside one rounding, and the "
+        "unoptimised code of two programs contracts and vectorises "
+        "differently")
+
+
+@pytest.fixture(autouse=True)
+def _backend_level(request):
+    """A test marked ``optimised`` runs with ``jax_disable_most_optimizations``
+    off, every other with it on (this file's top).  The flag is no part of a
+    jitted function's cache key, so a change of level drops what the worker
+    has compiled: marked cases stand together."""
+    want = request.node.get_closest_marker("optimised") is None
+    import jax
+    if jax.config.read("jax_disable_most_optimizations") != want:
+        jax.clear_caches()
+        jax.config.update("jax_disable_most_optimizations", want)
 
 
 @pytest.fixture(scope="session")
@@ -55,21 +84,15 @@ def pytest_terminal_summary(terminalreporter):
     terminalreporter.write_line(f"{total:8.1f} s over all files and workers")
 
 
-#: files of 100 s and more in the driver's run (ROADMAP.md D0's table), the
-#: longest first: ``--dist loadfile`` hands files out in the order they were
-#: collected, and a long file that starts last (``zaya_test.py``) is a tail
-#: of its own behind five idle workers
-LONGEST_FIRST = (
-    "olmo_hybrid_test.py", "kimi_linear_test.py", "laguna_test.py",
-    "sala_test.py", "zaya_test.py", "granite_test.py",
-    "kda_rule_kernel_test.py", "flash_fused_bwd_test.py", "nemotron_test.py",
-    "ouro_test.py", "pod_lowering_test.py", "distributed_test.py",
-    "kernel_steps_test.py", "chip_smoke_test.py", "flash_edge_cells_test.py",
-    "remat_policy_test.py", "olmoe_test.py", "flash_window_test.py",
-    "pipeline_parallel_test.py")
-
-
 def pytest_collection_modifyitems(items):
-    rank = {name: at for at, name in enumerate(LONGEST_FIRST)}
-    items.sort(key=lambda item: rank.get(
-        os.path.basename(str(item.fspath)), len(rank)))
+    """The longest files of the last measured run first, a file that run did
+    not know before them all (``tests/pins/seconds.json``, written by
+    ``tests/durations.py`` from a run's junit file): ``--dist loadfile``
+    hands files out in the order they were collected, and a long file that
+    starts last is a tail of its own behind five idle workers."""
+    import json
+    with open(os.path.join(os.path.dirname(__file__), "pins",
+                           "seconds.json")) as f:
+        seconds = json.load(f)
+    items.sort(key=lambda item: -seconds.get(
+        item.nodeid.split("::")[0], float("inf")))

@@ -33,6 +33,7 @@ def _error(got, want) -> float:
 
 # batches that are no whole tile (zero systems are appended); two tiles and
 # a part of a third at the smallest size
+@pytest.mark.optimised
 @pytest.mark.parametrize("batch,size", [
     ((19,), 16), ((2, 9), 32), ((17,), 64), ((16,), 128), ((2, 135), 16)])
 def kernel_is_the_inverse_test(batch, size):

@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 import flash_dense as dense_form
+import harness
 from flash_dense import GRAD_ATOL, GRAD_RTOL
 from homebrewnlp_tpu.parallel import flash_attention as fa
 from homebrewnlp_tpu.parallel.flash_attention import (_xla_reference,
@@ -196,28 +197,17 @@ def a_precomputed_forward_at_two_widths_test():
                                rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("heads,s,d,window,digest,precomputed", [
-    (16, 16384, 512, None, "14b363877012f97f", "94808be272ac1ba3"),
-    (16, 4096, 128, None, "37a48276d3ba36e8", "46c95c3d6eab8c0d"),
-    (72, 8192, 128, 512, "3b03c62d29f38cfd", "2d9f785509c10db3")],
+@pytest.mark.parametrize("case,heads,s,d,window", [
+    ("long_context", 16, 16384, 512, None), ("olmoe", 16, 4096, 128, None),
+    ("laguna_window", 72, 8192, 128, 512)],
     ids=["long_context", "olmoe", "laguna_window"])
-def equal_widths_are_the_parents_calls_test(heads, s, d, window, digest,
-                                            precomputed):
+def equal_widths_are_the_parents_calls_test(case, heads, s, d, window):
     """At ``d_k == d_v`` every call is the one it was: the jaxprs — kernel
     bodies, grids, block maps, names, source positions stripped — of
     ``flash_attention``'s and ``flash_precomputed``'s gradients at the
-    long-context cell's, OLMoE's and Laguna's window layers' shapes and
-    tiles, digests taken on PR 58's parent (239ac1f) — the two whose forward
-    is the tiled one taken again by PR 66, which MEANT to move that body (its
-    row statistics lane-replicated: 8085b0b458133d61 / b7ca317183ec284c
-    before); the band forward is the parent's still.  PR 68 MEANT to move the
-    fused backward's body, grid and maps at OLMoE's and Laguna's shapes (the
-    one-pass kernel on a grid of its live cells: no dq partials; e9a9ebb4a3aae071 / aca9a0fc8d6e5f85 and
-    7f26067a62d06811 / 5c106b71441b2e30 before); the long-context call, on
-    the split pair then, stayed letter for letter PR 66's until PR 73 MEANT
-    to move its backward (the one pass with a head's dq resident, at 1,024 x
-    512 tiles: a1fd20a5d5bb767d / 537f93e728be5233 before) — OLMoE's and
-    Laguna's, dk and dv resident, are PR 68's still."""
+    long-context cell's (the one pass with a head's dq resident, at 1,024 x
+    512 tiles), OLMoE's and Laguna's window layers' (dk and dv resident; the
+    band forward) shapes and tiles are the pinned ones."""
     q = jax.ShapeDtypeStruct((1, s, heads, d), jnp.bfloat16)
     blk, fwd_q, fwd_k, _ = fa.call_tiles(s, d, window, 2)
     assert fa.call_tiles(s, d, window, 2, d) == (blk, fwd_q, fwd_k, _)
@@ -226,15 +216,15 @@ def equal_widths_are_the_parents_calls_test(heads, s, d, window, digest,
         return flash_attention(q, k, v, d ** -0.5, True, fwd_q, fwd_k, False,
                                blk, blk, window).astype(jnp.float32).sum()
 
-    assert dense_form.jaxpr_digest(jax.grad(loss, (0, 1, 2)), q, q, q) \
-        == digest
+    harness.pinned(f"kernel/flash_grad/{case}",
+                   dense_form.jaxpr_text(jax.grad(loss, (0, 1, 2)), q, q, q))
 
     def saved(q, k, v, out, lse):
         return jax.grad(lambda q, k, v: fa.flash_precomputed(
             q, k, v, out, lse, d ** -0.5, True, blk, blk, False, window
         ).astype(jnp.float32).sum(), (0, 1, 2))(q, k, v)
 
-    assert dense_form.jaxpr_digest(
-        saved, q, q, q, q, jax.ShapeDtypeStruct((heads, s), jnp.float32)) \
-        == precomputed
+    harness.pinned(f"kernel/flash_precomputed_grad/{case}",
+                   dense_form.jaxpr_text(saved, q, q, q, q, jax.ShapeDtypeStruct(
+                       (heads, s), jnp.float32)))
 

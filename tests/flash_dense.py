@@ -3,13 +3,13 @@ form's ``out``, ``lse`` and gradients — traced and compiled once a ``(sequence
 window, seed, ...)`` and taken from here by every case after — the gradient
 tolerances, and three readers of a traced call."""
 import functools
-import hashlib
 import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+import harness
 from homebrewnlp_tpu.parallel import flash_attention as fa
 
 # jax-0.4.37's pallas INTERPRET mode (how these kernels run on the CPU
@@ -61,17 +61,7 @@ def assert_grads_close(got, want, rtol=GRAD_RTOL, atol=GRAD_ATOL):
 
 def _pallas_calls(fn, *args):
     """Every ``pallas_call`` equation ``fn`` traces to, nested ones too."""
-    found = []
-
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                found.append(eqn)
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                walk(sub)
-
-    walk(jax.make_jaxpr(fn)(*args).jaxpr)
-    return found
+    return harness.pallas_calls(jax.make_jaxpr(fn)(*args).jaxpr)
 
 
 def forward_kernels(fn, *args):
@@ -92,7 +82,6 @@ def kernel_scratch(fn, *args):
     return found
 
 
-def jaxpr_digest(fn, *args) -> str:
-    """Of ``fn``'s jaxpr, source positions stripped."""
-    text = re.sub(r" at \S+:\d+", "", str(jax.make_jaxpr(fn)(*args)))
-    return hashlib.sha256(text.encode()).hexdigest()[:16]
+def jaxpr_text(fn, *args) -> str:
+    """``fn``'s jaxpr, source positions stripped."""
+    return re.sub(r" at \S+:\d+", "", str(jax.make_jaxpr(fn)(*args)))

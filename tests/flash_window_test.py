@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 import flash_dense as dense_form
+import harness
 from homebrewnlp_tpu.parallel import flash_attention as fa
 from homebrewnlp_tpu.parallel.flash_attention import flash_attention
 
@@ -129,22 +130,12 @@ def the_band_forward_is_the_windowed_call_test(monkeypatch):
         == (2, 16, 2)
 
 
-@pytest.mark.parametrize("form,digest", [("dkv_resident", "0395705bf82abe9c"),
-                                         ("dq_resident", "3783f201be06a2a0"),
-                                         ("split", "aacc61b444fdd5fd")])
-def no_window_is_the_parents_call_test(form, digest, monkeypatch):
+@pytest.mark.parametrize("form", ["dkv_resident", "dq_resident", "split"])
+def no_window_is_the_parents_call_test(form, monkeypatch):
     """``window=None`` traces to one call whether the argument is left out
-    or given as None: the digests are of this call's jaxpr — kernel bodies,
-    grids, block maps and names, source positions stripped — for the fused
-    and the split backward.  Until PR 55 they were those of the parent
-    commit of ISSUE 36 (5f633c2: e8c973467ff66f11 / 9626d241fbf329fd);
-    PR 55 MEANT to move the bodies (an edge cell scores its live part), the
-    grids, maps and names are as they were (``flops_test.py``); PR 66 the
-    forward's alone (its row statistics lane-replicated: ff0effe0be83d952 /
-    6c1cc156f207ebf9 before); PR 68 the fused backward's (the one-pass
-    kernel on a grid of the live cells, no dq partials: d5bbd3f5dd0fee2e before),
-    the split pair's as it was; PR 73 added the one pass with dq resident and
-    left the other two as they were."""
+    or given as None: this call's jaxpr — kernel bodies, grids, block maps
+    and names, source positions stripped — is the pinned one under each of
+    the backward's three forms."""
     monkeypatch.setattr(fa, "backward_form", lambda *a: form)
     fused = form != "split"
     q = jax.ShapeDtypeStruct((1, 2048, 2, 128), jnp.bfloat16)
@@ -154,9 +145,9 @@ def no_window_is_the_parents_call_test(form, digest, monkeypatch):
                                1024, 1024, *window).astype(jnp.float32).sum()
 
     grad = jax.grad(loss, (0, 1, 2))
-    assert dense_form.jaxpr_digest(grad, q, q, q) == digest
-    assert dense_form.jaxpr_digest(
-        lambda q, k, v: grad(q, k, v, None), q, q, q) == digest
+    for call in (grad, lambda q, k, v: grad(q, k, v, None)):
+        harness.pinned(f"kernel/flash_grad/no_window/{form}",
+                       dense_form.jaxpr_text(call, q, q, q))
     names = str(jax.make_jaxpr(lambda q, k, v: jax.grad(
         lambda *a: loss(*a, 512), (0, 1, 2))(q, k, v))(q, q, q))
     assert "flash_fwd_window" in names and "_causal" not in names

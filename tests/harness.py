@@ -1,11 +1,15 @@
 """What the cell and kernel test files share (PR 60): a toy model of a
 repository configuration, its loss and gradients, a layer steered to trace as
 a TPU process would, a program compiled for a described v5e — once a module
-for every assertion that reads its text — and the comparison of two gradient
-trees.  A file names its own sizes; the mechanics are here."""
+for every assertion that reads its text — the comparison of two gradient
+trees, and (PR 74) the only readers of ``tests/pins/``: what a cell's step, a
+kernel's body and a configuration's start-up are, written once.  A file names
+its own sizes; the mechanics are here."""
 from __future__ import annotations
 
 import functools
+import glob
+import hashlib
 import importlib
 import json
 import os
@@ -20,6 +24,76 @@ from homebrewnlp_tpu.config import ModelParameter
 from homebrewnlp_tpu.model import Model
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+#: what the parent traced and printed, as data (PR 74): ``traces.json`` (name
+#: -> sha1), ``startup/<configuration file's path>`` and ``seconds.json``
+PINS = os.path.join(REPO, "tests", "pins")
+
+
+@functools.cache
+def _pins(relative: str) -> dict:
+    with open(os.path.join(PINS, relative)) as f:
+        return json.load(f)
+
+
+def pinned(name: str, text: str):
+    """``text`` (a jaxpr, a Pallas call's equation, a lowered module, a help
+    line; object addresses stripped here) is what ``tests/pins/traces.json``
+    holds under ``name``.  A change that MEANS to move it copies the new
+    digest from the message into that file; ``git log -p`` of the file is
+    the history."""
+    got = hashlib.sha1(re.sub(r" at 0x[0-9a-f]+", "", text).encode()
+                       ).hexdigest()
+    want = _pins("traces.json").get(name)
+    assert got == want, (
+        f"{name}: tests/pins/traces.json holds {want}, this tree gives "
+        f"{got}")
+
+
+def startup_pin(path: str) -> dict:
+    """What a trainer of the configuration file ``path`` (from the
+    repository's root) prints and publishes at start-up, ``{"tpu": {"line",
+    "series"}, "cpu": ..}`` with a cell's own under ``"cells"`` where its
+    overrides move them: ``tests/pins/startup/<path>``."""
+    return _pins(os.path.join("startup", path))
+
+
+def config_files() -> list:
+    """Every file under ``configs/`` and ``benchmark/configs/``, as a path
+    from the repository's root."""
+    return sorted(os.path.relpath(path, REPO) for where in (
+        "configs", os.path.join("benchmark", "configs"))
+        for path in glob.glob(os.path.join(REPO, where, "*.json")))
+
+
+def cell_config_file(cell: str) -> str:
+    """The file of benchmark cell ``cell``'s configuration, as
+    ``BENCHMARK.json`` names it."""
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    config = next(w["config"] for w in bench["workloads"] if w["name"] == cell)
+    return next(c["file"] for c in bench["configs"] if c["name"] == config)
+
+
+@functools.cache
+def train_cells(chips: typing.Optional[int] = None) -> tuple:
+    """The names of ``BENCHMARK.json``'s cells that the train driver runs
+    (on ``chips`` chips), in the file's order."""
+    from benchmark.lib.cell import load_cell
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        cells = [load_cell(w["name"]) for w in json.load(f)["workloads"]]
+    return tuple(c.name for c in cells if c.spec["driver"] == "train"
+                 and chips in (None, c.chips))
+
+
+@functools.cache
+def cell_step_jaxpr(cell: str) -> str:
+    """The forward's jaxpr of benchmark cell ``cell`` at its rehearsal size:
+    built and traced once a process, whoever asks."""
+    from benchmark.lib.cell import load_cell
+    config = {**load_cell(cell).model_config(rehearsal=True),
+              "model_path": "/tmp/cell_step", "dataset_configs": []}
+    _, _, model, batch, variables = build(config)
+    return step_jaxpr(model, variables, batch)
 
 
 def reference(name: str):
@@ -70,12 +144,30 @@ def with_input_grads(fn, inputs, weights):
     return jax.jit(run)(*inputs)
 
 
+#: what the program does with its memory: no plain reference reads them
+_PROGRAM_ONLY = ("memory_reduction_strategy", "remat_policy")
+_REFERENCE_VALUES: dict = {}
+
+
 def reference_loss_and_grads(ref, variables, tokens, targets, config):
     """``jax.value_and_grad`` of the plain reference's ``train_loss``, as one
-    program."""
-    v = {k: jnp.asarray(a) for k, a in variables.items()}
-    return jax.jit(jax.value_and_grad(
-        lambda v: ref.train_loss(v, tokens, targets, config)))(v)
+    program — and one value a process for the same weights, batch and
+    configuration: cases that differ by the program's memory strategy alone
+    are held to the same one."""
+    content = hashlib.sha1()
+    for name, value in sorted({**variables, " x": tokens,
+                               " y": targets}.items()):
+        value = np.asarray(value)
+        content.update(f"{name} {value.dtype} {value.shape}".encode())
+        content.update(value.tobytes())
+    key = (ref.__name__, content.hexdigest(), json.dumps(
+        {k: v for k, v in config.items() if k not in _PROGRAM_ONLY},
+        sort_keys=True, default=str))
+    if key not in _REFERENCE_VALUES:
+        v = {k: jnp.asarray(a) for k, a in variables.items()}
+        _REFERENCE_VALUES[key] = jax.jit(jax.value_and_grad(
+            lambda v: ref.train_loss(v, tokens, targets, config)))(v)
+    return _REFERENCE_VALUES[key]
 
 
 def traced_op_names(model, variables, batch, compiled: bool = True) -> set:
@@ -93,6 +185,17 @@ def step_jaxpr(model, variables, batch) -> str:
     """The forward's jaxpr, object addresses stripped."""
     return re.sub(r" at 0x[0-9a-f]+", "", str(jax.make_jaxpr(
         lambda v: loss_of(model)(v, batch))(variables)))
+
+
+def pallas_calls(jaxpr) -> list:
+    """Every ``pallas_call`` equation under ``jaxpr``, nested ones too."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            found.extend(pallas_calls(inner))
+    return found
 
 
 def logits_and_loss(model, variables, batch):

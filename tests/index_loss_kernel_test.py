@@ -2,8 +2,6 @@
 ``parallel/index_loss.py``): interpreted at small tiles against the XLA form
 ``model/indexer.py xla_index_loss`` in float32 on all five outputs; what the
 dispatch runs where; the grid's table; the start-up facts."""
-import hashlib
-import re
 
 import jax
 import jax.numpy as jnp
@@ -121,12 +119,6 @@ def the_grid_walks_the_tiles_under_the_diagonal_test(s, tiles, steps,
                                                              rel=1e-12)
 
 
-#: sha1 of the jaxpr (addresses stripped) that commit 3bd2317 (PR 63) traces
-#: for ``index_loss`` at the shapes below, under a choice and without one
-_PARENT = {"chosen": "dfbc999d09dd532d6919ca4043f1da55ea82d8f5",
-           "dense": "e441edf7337590ae7fcf32934aed9df159109c74"}
-
-
 def _jaxpr_of(fn, chosen: bool) -> str:
     b, s, h, f, g, index_heads, d = 2, 256, 4, 16, 2, 4, 8
 
@@ -142,13 +134,12 @@ def _jaxpr_of(fn, chosen: bool) -> str:
 
 @pytest.mark.parametrize("chosen", [True, False], ids=["chosen", "dense"])
 def off_the_tpu_the_xla_form_runs_as_on_the_parent_test(chosen):
-    """Here ``index_loss`` traces to the parent's jaxpr, letter for letter,
+    """Here ``index_loss`` traces to the pinned jaxpr, letter for letter,
     under a choice and without one."""
     text = _jaxpr_of(indexer.index_loss, chosen)
     assert "pallas_call" not in text
-    assert hashlib.sha1(re.sub(r" at 0x[0-9a-f]+", "", text).encode()
-                        ).hexdigest() == _PARENT["chosen" if chosen
-                                                 else "dense"]
+    harness.pinned("layer/index_loss/xla_" + ("chosen" if chosen else "dense"),
+                   text)
 
 
 def as_a_tpu_process_the_dispatch_takes_the_kernel_test(monkeypatch):

@@ -7,7 +7,6 @@ shares (the shared expert counted once) add up to the uncut layer; the last
 position's weight 0; ``mtp_depth`` 0 and the flag-less latent form trace to
 the parent's jaxprs; refusals, scopes, statistics, the memory rule's counts,
 the repo's configuration."""
-import hashlib
 import json
 import os
 
@@ -427,33 +426,19 @@ def decode_and_prefill_refuse_the_module_at_the_call_test(built):
 
 # ---- what the new keys leave alone ----------------------------------------------
 
-#: sha1 of the parent's jaxprs (PR 64's tree, commit 0342972), object
-#: addresses stripped: the toy model's forward without the module, and layer
-#: attention under Kimi-Linear's flags
-_PARENT = {
-    "no_module": "1765999eaf11c5179b3b02dbc56d892735fcdc70",
-    "kimis_form": "ecbaf2d44be0f12c9bb75920623d302a523d3fb3",
-}
-
-
-def _sha1(text: str) -> str:
-    return hashlib.sha1(text.encode()).hexdigest()
-
-
 def without_the_module_the_step_traces_as_on_the_parent_test():
     """``mtp_depth`` 0 (Kimi-Linear's toy model, which has no module and the
     flag-less latent form): the forward's jaxpr is the parent's."""
     from kimi_linear_test import _config as kimi_config
     config, _, model, batch, variables = harness.build(kimi_config())
     assert config.get("mtp_depth", 0) == 0
-    assert _sha1(harness.step_jaxpr(model, variables, batch)) \
-        == _PARENT["no_module"]
+    harness.pinned("step/kimi_linear_toy/no_mtp_module",
+                   harness.step_jaxpr(model, variables, batch))
 
 
 def the_flagless_latent_form_traces_as_on_the_parent_test():
     """Layer ``attention`` under Kimi-Linear's flags (``nope``, no query
     latent), in Kimi-Linear's toy configuration: the parent's jaxpr."""
-    import re
     from kimi_linear_test import _config as kimi_config
     params = ModelParameter(kimi_config(block_config=[_block(NOPE)]))
     dims = [params.batch_dim, params.sequence_dim] + list(params.feature_dims)
@@ -463,9 +448,8 @@ def the_flagless_latent_form_traces_as_on_the_parent_test():
             return scope.scoped("attention_", spatial.attention, BlockArgs(
                 params, nt(x, dims), NOPE.split("-")[1:])).data
 
-    text = str(jax.make_jaxpr(run)(jnp.zeros((2, 64, 2, 16))))
-    assert _sha1(re.sub(r" at 0x[0-9a-f]+", "", text)) \
-        == _PARENT["kimis_form"]
+    harness.pinned("layer/joyai/flagless_latent",
+                   str(jax.make_jaxpr(run)(jnp.zeros((2, 64, 2, 16)))))
 
 
 # ---- scopes, statistics, the memory rule ------------------------------------------

@@ -390,6 +390,7 @@ def _two_blocks(monkeypatch, policy: str, admit=None):
              for text in regions], jax.jit(fn)(variables))
 
 
+@pytest.mark.optimised
 @pytest.mark.parametrize("admit", [0, 1, 2])
 def saved_interior_is_the_replayed_one_test(monkeypatch, admit):
     """PR 61: a ``kda`` block whose interior is admitted saves what the

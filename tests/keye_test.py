@@ -7,7 +7,7 @@ key-at-a-time select kernels in interpret mode against the dense masked form,
 forward and backward; the dense case; the SHARE test (the eight expert shares
 add up to the uncut layer); M-RoPE on equal streams; what the parent traced
 still traces; refusals, scopes, gauges and the offer."""
-import hashlib
+
 import re
 
 import jax
@@ -434,63 +434,10 @@ def mrope_on_equal_streams_is_rope_test():
 
 # ---- what the parent traced still traces ---------------------------------------
 
-#: sha1 of the jaxpr (addresses stripped) that commit cf244b8 (PR 62) traces:
-#: the block-selected call's two backward kernels, and the forward of every
-#: one-chip train cell at its rehearsal size — off the TPU the two cells with
-#: a selected call run the dense masked form, so PR 63 moves none of them.
-#: ``select`` (SALA's block-selected call whole, forward and backward) is
-#: PR 63's own: it moved from the parent's 77b432de... by the forward
-#: kernel's body (lane-replicated statistics, one select a pair) and by the
-#: tables' rectangular diagonal, and by nothing else — ``select_dq`` and ``select_dkv`` are the
-#: parent's, letter for letter.  PR 71 moved the two cells with a ``mamba``
-#: layer and no other: their steps trace the ``name`` layer ``mamba`` gives
-#: its in-projection's output, and their regions' policies save it (granite
-#: f9c4190e..., Nemotron f4323140... until then)
-_PARENT = {
-    "select": "ab4ea813094509e3835c587a40d64787f5d91bbe",
-    "select_dq": "821f74ffb0e6b4069ff0f4fbd51fdb687cf05cdf",
-    "select_dkv": "f34b72f595c21121767ecb7ce6a9b91974a8f020",
-    "train_32big_mixer_b32": "80cc1d5df38d1036908a3ffc0d498fed970a112f",
-    "train_1b_long_context_s16k": "93c267300f6762eac60b1ad8c26b779106cdd89f",
-    "train_olmoe_1b_7b_s4k": "c762ce67313660f1d9c7a0699dfc40731993a9a1",
-    "train_granite_4_0_h_micro_long":
-        "b01ba6819dc86178a41f2c0753bde7057dff17d9",
-    "train_olmo_hybrid_7b_long": "1fa4aaca6ce9e530c6ffbc8424080f0ada4d7231",
-    "train_laguna_s_2_1_ep32_s8k": "c7c8961295c85dbdf5e393e76e908935320b914e",
-    "train_zaya1_8b_ep2_s16k": "c9d96234ed75811d1d0c675347a5027042175d00",
-    "train_minicpm_sala_tp2_long": "23a2df09e10e61d58895cf748d90e7b175b1f444",
-    "train_ouro_2_6b_loop4_s4k": "40862f4338cc8c697d627e077b74820df0f5ad9b",
-    "train_nemotron_3_super_tp2_ep64_s16k":
-        "d9bafa202444c6a2652e323cbb0d38ef98505702",
-    "train_kimi_linear_ep32_s16k": "816fab234da3b7a594e5e971555744ab986c38dc",
-    "train_keye_vl_2_0_ep8_s16k": "a3379a7a8f6c66f8bc446b44fa9ac9afe2223779",
-}
-
-
-def _sha1(text: str) -> str:
-    return hashlib.sha1(re.sub(r" at 0x[0-9a-f]+", "", text).encode()
-                        ).hexdigest()
-
-
-def _pallas_calls(jaxpr, found):
-    """``{kernel name: its equation's text}`` of every Pallas call under
-    ``jaxpr``."""
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            found[eqn.params["name"]] = str(eqn)
-        for value in eqn.params.values():
-            for inner in value if isinstance(value, (list, tuple)) \
-                    else [value]:
-                inner = getattr(inner, "jaxpr", inner)
-                if hasattr(inner, "eqns"):
-                    _pallas_calls(inner, found)
-    return found
-
-
 def block_selected_call_traces_as_on_the_parent_test():
     """SALA's form of the select kernels — blocks of keys, a choice a K/V
-    group: the whole call traces to PR 63's pinned jaxpr, and its two
-    backward kernels (body, grid, index maps) to the PARENT's."""
+    group: the whole call and its two backward kernels (body, grid, index
+    maps) trace to the pinned jaxprs."""
     q = jnp.zeros((1, 512, 4, 32), jnp.float32)
     k = v = jnp.zeros((1, 512, 2, 32), jnp.float32)
     keep = jnp.zeros((1, 2, 512, 32), bool)
@@ -498,23 +445,13 @@ def block_selected_call_traces_as_on_the_parent_test():
         lambda q, k, v: jnp.sum(fa.flash_select(q, k, v, keep, 0.25, 16,
                                                 True)),
         argnums=(0, 1, 2)))(q, k, v)
-    assert _sha1(str(jaxpr)) == _PARENT["select"]
-    calls = _pallas_calls(jaxpr.jaxpr, {})
+    harness.pinned("kernel/select", str(jaxpr))
+    calls = {eqn.params["name"]: str(eqn)
+             for eqn in harness.pallas_calls(jaxpr.jaxpr)}
     assert sorted(calls) == ["flash_bwd_dkv_select", "flash_bwd_dq_select",
                              "flash_fwd_select"]
-    assert _sha1(calls["flash_bwd_dq_select"]) == _PARENT["select_dq"]
-    assert _sha1(calls["flash_bwd_dkv_select"]) == _PARENT["select_dkv"]
-
-
-@pytest.mark.parametrize("cell", [c for c in _PARENT
-                                  if not c.startswith("select")])
-def other_cells_step_traces_as_on_the_parent_test(cell):
-    from benchmark.lib.cell import load_cell
-    config = {**load_cell(cell).model_config(rehearsal=True),
-              "model_path": "/tmp/keye_test", "dataset_configs": []}
-    _, _, model, batch, variables = harness.build(config)
-    assert hashlib.sha1(harness.step_jaxpr(model, variables, batch).encode()
-                        ).hexdigest() == _PARENT[cell]
+    harness.pinned("kernel/select_dq", calls["flash_bwd_dq_select"])
+    harness.pinned("kernel/select_dkv", calls["flash_bwd_dkv_select"])
 
 
 def a_layer_of_the_cell_holds_the_issues_parameters_test():

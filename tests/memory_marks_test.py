@@ -444,11 +444,8 @@ def benchmark_reader_reads_the_gauges_test(fresh, name):
 
 
 def benchmark_lists_the_three_metrics_test():
-    """``BENCHMARK.json``: the three entries, each on the sixteen train cells
-    (eight until PR 46, nine until PR 49, ten until PR 54, eleven until
-    PR 58, twelve until PR 62, thirteen until PR 65, fourteen until PR 67,
-    fifteen until PR 72), each with its file agreeing on layer and end-to-end
-    metric."""
+    """``BENCHMARK.json``: the three entries, each on every train cell, each
+    with its file agreeing on layer and end-to-end metric."""
     import json
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
@@ -456,7 +453,7 @@ def benchmark_lists_the_three_metrics_test():
     cells = [w["name"] for w in bench["workloads"]]
     for name in METRICS:
         entry, mod = entries[name], _metric(name)
-        assert entry["workloads"] == cells and len(cells) == 16
+        assert entry["workloads"] == cells
         assert (entry["layer"], entry["moves"], entry["source"], entry["unit"]
                 ) == (mod.LAYER, mod.MOVES, "program_counter", "%")
         assert entry["layer"] == "L5_device"

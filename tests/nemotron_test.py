@@ -9,7 +9,6 @@ the grouped scan (Pallas pair interpreted, XLA form, position by position) at
 counted once, and both tensor-parallel halves of a Mamba-2 and an attention
 layer, add up to the uncut layer); refusals, scopes, statistics, the repo's
 configuration."""
-import hashlib
 import json
 import os
 import re
@@ -328,15 +327,12 @@ def the_layer_runs_the_grouped_kernels_test(monkeypatch):
     assert harness.error(got[0], want[0]) < 2e-5 and abs(got[1] - want[1]) < 1e-5
 
 
-#: sha1 of the StableHLO of loss and gradients of ONE ``mamba`` block at toy
-#: widths on the CPU, taken from the PARENT of PR 54 (commit 176c52a, which
-#: has no ``mamba_groups``): one group is that graph, byte for byte — under
-#: ``remat_policy: "recompute"`` since PR 71, where no policy saves the name
-#: layer ``mamba`` gives its in-projection's output and the name is free
-_ONE_GROUP_DIGEST = "2e9ffbbef260f96b50d39042eaf10bdf03607541"
-
-
 def one_group_lowers_to_the_parents_graph_test():
+    """The StableHLO of loss and gradients of ONE ``mamba`` block at toy
+    widths on the CPU is what a tree without ``mamba_groups`` lowered (PR
+    54's parent), byte for byte — under ``remat_policy: "recompute"``, where
+    no policy saves the name layer ``mamba`` gives its in-projection's output
+    and the name is free."""
     config = _config(block_config=[_block("mamba")], mamba_groups=1,
                      remat_policy="recompute")
     params = ModelParameter(config)
@@ -347,7 +343,7 @@ def one_group_lowers_to_the_parents_graph_test():
     text = jax.jit(jax.value_and_grad(
         lambda v, b: model.apply(v, b).total_loss.data)).lower(
         variables, batch).as_text()
-    assert hashlib.sha1(text.encode()).hexdigest() == _ONE_GROUP_DIGEST
+    harness.pinned("layer/mamba/one_group_stablehlo", text)
     # and B / C reach the scan as the parent's [b, s, n]
     seen = []
     real = mamba_mod.ssd_xla

@@ -107,6 +107,7 @@ def chunked_rules_gradients_are_the_recurrences_test(chunk, s, decay):
         assert harness.error(np.asarray(a), np.asarray(r)) < 1e-4, name
 
 
+@pytest.mark.optimised
 @pytest.mark.parametrize("budget,groups", [(48 << 20, 1), (2 * 32 * 8 * 4, 3),
                                            (0, 3)])
 def grouped_rule_is_the_rule_test(monkeypatch, budget, groups):
@@ -548,6 +549,7 @@ def _forward_carries(jaxpr, found=None, path=""):
     return found
 
 
+@pytest.mark.optimised
 @pytest.mark.parametrize("groups", [1, 3])
 @pytest.mark.parametrize("scan_layers", [False, True],
                          ids=["unrolled", "scan_layers"])
@@ -643,16 +645,13 @@ def the_scalar_decay_layers_jaxpr_is_the_parents_test():
     """PR 58 added layer ``kda`` (a decay a channel) BESIDE this one and
     widened the flash kernels to two widths: the toy model's gradient —
     ``gated_delta``'s scalar-decay rule, the attention layer, the MLPs —
-    traces to the jaxpr it had on that PR's parent (239ac1f), source
-    positions and object addresses stripped."""
-    import hashlib
-    import re
+    traces to the pinned jaxpr, source positions and object addresses
+    stripped."""
     _, _, model, batch, variables = _build()
     text = str(jax.make_jaxpr(jax.grad(
         lambda v, b: model.apply(v, b).total_loss.data))(variables, batch))
-    text = re.sub(r" at 0x[0-9a-f]+", "", re.sub(r" at \S+:\d+", "", text))
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
-        == "d162ea973ae1124b"
+    harness.pinned("step/olmo_hybrid_toy/grad",
+                   re.sub(r" at \S+:\d+", "", text))
 
 
 # ---- compiled for a described v5e ---------------------------------------------

@@ -7,7 +7,6 @@ kernels in interpret mode against the dense masked form, forward, fused
 backward, dq and dk/dv; the noise against the reference's and its
 expectation; the SHARE test on the doubled stream; refusals; what the parent
 traced still traces; scopes, gauges, the offer and the cell's parameters."""
-import hashlib
 
 import jax
 import jax.numpy as jnp
@@ -318,7 +317,8 @@ def blockdiff_kernels_match_the_dense_mask_test(monkeypatch, tiles, block,
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda q, k, v: jnp.sum(program(q, k, v)), argnums=(0, 1, 2)))(
             q2, k2, v2)
-    calls = sorted(_pallas_calls(jaxpr.jaxpr, {}))
+    calls = sorted({eqn.params["name"]
+                    for eqn in harness.pallas_calls(jaxpr.jaxpr)})
     assert calls == (["flash_bwd_fused_blockdiff", "flash_fwd_blockdiff"]
                      if form != "split" else ["flash_bwd_dkv_blockdiff",
                                     "flash_bwd_dq_blockdiff",
@@ -441,74 +441,23 @@ def the_flag_needs_the_doubled_stream_and_serving_refuses_test(built):
 
 # ---- what the parent traced still traces ---------------------------------------
 
-#: sha1 of the forward's jaxpr of a cell's rehearsal configuration on the
-#: PARENT (180bb1b, PR 66), as tests/keye_test.py holds the others': the two
-#: cells whose standard attention runs beside the new flag's path
-_PARENT = {
-    "train_keye_vl_2_0_ep8_s16k": "a3379a7a8f6c66f8bc446b44fa9ac9afe2223779",
-    "train_olmoe_1b_7b_s4k": "c762ce67313660f1d9c7a0699dfc40731993a9a1",
-    "train_laguna_s_2_1_ep32_s8k": "c7c8961295c85dbdf5e393e76e908935320b914e",
-}
-#: the causal flash call whole (forward, fused backward) and a windowed one,
-#: by the kernels' equations on the PARENT; PR 68 took the two backwards
-#: again (the one-pass kernel: 688c1baefd5c77e47329d5ab4b87ae7fc48a1802 /
-#: 49dcb72718dac9333ba83cbc851a56fb8ebe3027 before), the forwards stand
-_PARENT_CALLS = {
-    "causal": {
-        "flash_bwd_fused_causal": "2117c0f17e81bd690e7d4418bb6d73981360f0ff",
-        "flash_fwd_causal": "115f8b85e5751943692dee7169296f3b5a5e58a4"},
-    "window": {
-        "flash_bwd_fused_window": "54027c10c0f6287dcaca1574b36a05eff303e821",
-        "flash_fwd_window": "0a3302965fb307ae6ba4b592bd54848fd11f51fa"},
-}
-
-
-def _pallas_calls(jaxpr, found):
-    """``{kernel name: its equation's text}`` of every Pallas call under
-    ``jaxpr``."""
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            found[eqn.params["name"]] = str(eqn)
-        for value in eqn.params.values():
-            for inner in value if isinstance(value, (list, tuple)) \
-                    else [value]:
-                inner = getattr(inner, "jaxpr", inner)
-                if hasattr(inner, "eqns"):
-                    _pallas_calls(inner, found)
-    return found
-
-
-def _sha1(text: str) -> str:
-    import re
-    return hashlib.sha1(re.sub(r" at 0x[0-9a-f]+", "", text).encode()
-                        ).hexdigest()
-
-
-@pytest.mark.parametrize("cell", list(_PARENT))
-def other_cells_step_traces_as_on_the_parent_test(cell):
-    """``diffusion_block`` 0: the parent's build, jaxpr for jaxpr."""
-    from benchmark.lib.cell import load_cell
-    config = {**load_cell(cell).model_config(rehearsal=True),
-              "model_path": "/tmp/sdar_test", "dataset_configs": []}
-    assert config.get("diffusion_block", 0) == 0
-    _, _, model, batch, variables = harness.build(config)
-    assert hashlib.sha1(harness.step_jaxpr(model, variables, batch).encode()
-                        ).hexdigest() == _PARENT[cell]
-
-
 @pytest.mark.parametrize("window", [None, 64], ids=["causal", "window"])
 def causal_flash_calls_trace_as_on_the_parent_test(window):
     """The causal and the windowed call, forward and backward, through the
     kernels the mask's parameter was threaded through: every Pallas call's
-    equation is the parent's, letter for letter."""
+    equation is the pinned one, letter for letter."""
     q = jnp.zeros((1, 256, 2, 32), jnp.float32)
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda q, k, v: jnp.sum(fa.flash_attention(
             q, k, v, 0.25, True, 64, 128, True, 64, 64, window)),
         argnums=(0, 1, 2)))(q, q, q)
-    calls = _pallas_calls(jaxpr.jaxpr, {})
-    got = {name: _sha1(text) for name, text in sorted(calls.items())}
-    assert got == _PARENT_CALLS["causal" if window is None else "window"]
+    calls = {eqn.params["name"]: str(eqn)
+             for eqn in harness.pallas_calls(jaxpr.jaxpr)}
+    kind = "causal" if window is None else "window"
+    assert sorted(calls) == ["flash_bwd_fused_" + kind, "flash_fwd_" + kind]
+    harness.pinned(f"kernel/flash_fwd_{kind}", calls["flash_fwd_" + kind])
+    harness.pinned(f"kernel/flash_bwd_fused_{kind}",
+                   calls["flash_bwd_fused_" + kind])
 
 
 # ---- scopes, the offer, the cell's parameters -----------------------------------

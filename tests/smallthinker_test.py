@@ -8,7 +8,6 @@ through it); ReLU-gated experts on the three held / unheld paths with the live
 gate share counted; the eight shares add up to the uncut layer; the window at
 reach / sub = 16 under 7 query heads a K/V head; refusals, scopes, facts and
 the cut's parameter count."""
-import hashlib
 
 import jax
 import jax.numpy as jnp
@@ -599,27 +598,3 @@ def the_windowed_kernels_compile_at_the_published_widths_test(v5e,
     assert sorted(name for name, _ in calls) \
         == ["flash_bwd_fused_window", "flash_fwd_window"]
     assert all(scope_key(op_name) == "body/attention" for _, op_name in calls)
-
-
-# ---- what the parent traced still traces ---------------------------------------
-
-#: sha1 of the forward's jaxpr of a cell's rehearsal configuration on the
-#: PARENT (582efd6, PR 71), as tests/sdar_test.py holds Keye's, OLMoE's and
-#: Laguna's: the cells whose layers share ``Context.side`` and layer ``moe``'s
-#: router and statistics with the new flags
-_PARENT = {
-    "train_zaya1_8b_ep2_s16k": "c9d96234ed75811d1d0c675347a5027042175d00",
-    "train_nemotron_3_super_tp2_ep64_s16k":
-        "d9bafa202444c6a2652e323cbb0d38ef98505702",
-    "train_sdar_30b_a3b_ep8_s8k": "e1d02a7afb550a3eb5e8f0f76c9b6abf0d7b1246",
-}
-
-
-@pytest.mark.parametrize("cell", list(_PARENT))
-def other_cells_step_traces_as_on_the_parent_test(cell):
-    from benchmark.lib.cell import load_cell
-    config = {**load_cell(cell).model_config(rehearsal=True),
-              "model_path": "/tmp/smallthinker_test", "dataset_configs": []}
-    _, _, model, batch, variables = harness.build(config)
-    assert hashlib.sha1(harness.step_jaxpr(model, variables, batch).encode()
-                        ).hexdigest() == _PARENT[cell]

@@ -454,11 +454,9 @@ def readers_take_the_window_by_index_test(monkeypatch, fresh):
 
 
 def benchmark_lists_the_four_metrics_test():
-    """``BENCHMARK.json``: the four entries in PR 51's order (last until
-    PR 54 appended its cell's six), each on all sixteen train cells (eleven
-    until PR 58, twelve until PR 62, thirteen until PR 65, fourteen until
-    PR 67, fifteen until PR 72), each with its file agreeing on layer and
-    end-to-end metric."""
+    """``BENCHMARK.json``: the four entries in PR 51's order, each on every
+    train cell, each with its file agreeing on layer and end-to-end
+    metric."""
     import json
     from benchmark.lib import cell as cell_mod
     with open(os.path.join(cell_mod.ROOT, "BENCHMARK.json")) as f:
@@ -470,7 +468,7 @@ def benchmark_lists_the_four_metrics_test():
     assert [m["name"] for m in mine] == names
     for entry in mine:
         mod = cell_mod.load_metric(entry["name"])
-        assert entry["workloads"] == cells and len(cells) == 16
+        assert entry["workloads"] == cells
         assert (entry["layer"], entry["moves"], entry["source"],
                 entry["better"]) == (mod.LAYER, mod.MOVES, "program_counter",
                                      "lower")
